@@ -65,7 +65,7 @@ class _Fleet:
             return
         import jax
 
-        if getattr(jax.distributed, "is_initialized", lambda: False)():
+        if jax.distributed.is_initialized():
             return  # benign re-init (second fleet.init() in one process)
         eps = self._role_maker.get_trainer_endpoints()
         # a genuine bootstrap failure (bad coordinator address, port

@@ -8,7 +8,9 @@ TPU translation: on GPU the reference spawns one process per GPU
 seeing all local chips (jax picks them up), with jax.distributed connecting
 hosts (the gen_nccl_id replacement).  --nproc_per_node is still honored for
 CPU-simulation testing (each proc gets a slice of
-xla_force_host_platform_device_count).
+xla_force_host_platform_device_count).  On a real TPU host a chip belongs
+to one process at a time and nothing here partitions the chips among the N
+children: the ones that cannot get a chip exit with the runtime's error.
 """
 
 import argparse

@@ -22,7 +22,7 @@ without being fooled by checkpoint retries:
                                   dead peer is a detected fault the caller
                                   degrades around, not an IO giveup).
 
-The surface taxonomy: ``ckpt_io`` (checkpoint shards/index/commit),
+The surface classification: ``ckpt_io`` (checkpoint shards/index/commit),
 ``dataset_open`` (reader file opens), ``hostps_shard`` (sparse-shard
 save/restore), ``ps_wire`` (the ShardPS request-reply transport), ``other``
 (unlabeled legacy callers).  The chaos drills' gates assert

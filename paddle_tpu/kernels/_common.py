@@ -1,21 +1,17 @@
-"""Shared Pallas kernel plumbing: the 0.4.x CompilerParams compat shim and
-the on-TPU probe every kernel module uses to auto-select interpret mode.
-One copy, so a pallas API rename or a platform-probe fix lands everywhere
-at once.
+"""Shared Pallas kernel plumbing: the on-TPU probe every kernel module uses
+to select interpret mode.  One copy, so a platform-probe fix lands
+everywhere at once.
 """
 
 import jax
 from jax.experimental.pallas import tpu as pltpu
 
-# CompilerParams was TPUCompilerParams on 0.4.x pallas; same fields
-CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
+CompilerParams = pltpu.CompilerParams
 
 
 def on_tpu():
-    """True when the default backend is a real accelerator — kernels run
-    compiled; False (or an unprobeable backend) selects interpret mode."""
-    try:
-        return jax.devices()[0].platform not in ("cpu",)
-    except Exception:
-        return False
+    """False only when the default backend is positively ``cpu`` (kernels
+    then run in Pallas interpret mode); any accelerator compiles through
+    Mosaic.  A backend that cannot be probed raises — it must not read as
+    "no accelerator" and silently interpret."""
+    return jax.devices()[0].platform != "cpu"

@@ -32,11 +32,9 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
+from ._common import on_tpu as _on_tpu
+
 __all__ = ["conv2d"]
-
-
-def _on_tpu():
-    return jax.devices()[0].platform not in ("cpu",)
 
 
 def _plain(x, w, stride, padding):

@@ -223,6 +223,7 @@ def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None):
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -323,6 +324,7 @@ def _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=None):
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_fused",
     )(q, k, v, o, do, lse)
     return dq, dk, dv
 
@@ -458,6 +460,7 @@ def _bwd(scale, causal, bq, bk, interpret, res, do, H=None):
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # dkv sweep: grid is (b, kv, q) — the index-map roles swap
@@ -491,6 +494,7 @@ def _bwd(scale, causal, bq, bk, interpret, res, do, H=None):
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

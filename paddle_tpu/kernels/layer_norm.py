@@ -18,19 +18,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-# CompilerParams was TPUCompilerParams on 0.4.x pallas; same fields
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
 
+from ._common import CompilerParams as _CompilerParams, on_tpu as _on_tpu
 
 __all__ = ["fused_layer_norm"]
-
-
-def _on_tpu():
-    try:
-        return jax.devices()[0].platform not in ("cpu",)
-    except Exception:
-        return False
 
 
 def _fwd_kernel(x_ref, s_ref, b_ref, y_ref, mu_ref, rs_ref, *, eps):
@@ -104,6 +95,7 @@ def _fwd(x, scale, bias, eps, interpret):
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="layer_norm_fwd",
     )(x, scale.reshape(1, E), bias.reshape(1, E))
     return y, mu, rstd
 
@@ -139,6 +131,7 @@ def _bwd(eps, interpret, res, dy):
         compiler_params=_CompilerParams(
             dimension_semantics=("arbitrary",)),   # sequential: dscale accum
         interpret=interpret,
+        name="layer_norm_bwd",
     )(x, scale.reshape(1, E), dy, mu, rstd)
     return dx, ds.reshape(scale.shape), db.reshape(scale.shape)
 

@@ -52,7 +52,7 @@ __all__ = [
     "FleetScope",
 ]
 
-# THE phase taxonomy: training-thread time between two step boundaries is
+# THE phase classification: training-thread time between two step boundaries is
 # attributed to exactly one of these (or to untracked host work).
 #   feed_stall   — waiting on / preparing the input batch (pipe take stall,
 #                  inline feed conversion)

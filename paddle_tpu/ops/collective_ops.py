@@ -93,9 +93,7 @@ def _c_ppermute(ins, attrs, ctx):
     ax = _axis(ctx, attrs)
     if not ax:
         return out(Out=v)
-    from ..parallel.collectives import _axis_size
-
-    n = _axis_size(ax)
+    n = lax.axis_size(ax)
     shift = int(attrs.get("shift", 1))
     perm = [(i, (i + shift) % n) for i in range(n)]
     return out(Out=lax.ppermute(v, ax, perm))

@@ -76,7 +76,7 @@ from ..monitor import trace as _trace
 
 
 def _phase_add(name, ms):
-    """FleetScope phase attribution (monitor/fleetscope.py taxonomy):
+    """FleetScope phase attribution (monitor/fleetscope.py classification):
     checkpoint staging cost lands in ``ckpt``, the COMMIT shard-barrier
     poll in ``barrier_wait`` — THE multi-host skew signal.  One global read
     when no session is active."""

@@ -30,18 +30,10 @@ __all__ = [
 ]
 
 
-def _axis_size(axis):
-    """lax.axis_size where it exists; the classic psum-of-1 idiom (static,
-    no collective is emitted for a constant) on 0.4.x jax."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
-
-
 def _in_scope(axis):
     """True if `axis` is bound as a manual mesh axis in the current trace."""
     try:
-        _axis_size(axis)
+        lax.axis_size(axis)
         return True
     except (NameError, KeyError, ValueError, AssertionError):
         return False
@@ -52,7 +44,7 @@ def axis_present(axis):
 
 
 def axis_size_in(axis):
-    return _axis_size(axis) if axis_present(axis) else 1
+    return lax.axis_size(axis) if axis_present(axis) else 1
 
 
 def axis_index(axis):

@@ -56,11 +56,12 @@ def shard_rows(vocab_size, n_shards):
     return vocab_size // n_shards
 
 
-# per-chip HBM for the capacity guard below (bytes); queried from the device
-# when possible, falling back to the v5e-class constant; overridable via
-# configure_hbm_budget
+# per-chip HBM for the capacity guard below (bytes); queried from the
+# device, overridable via configure_hbm_budget.  The CPU backend reports no
+# bytes_limit, so tests and CPU runs budget against the v5e's 16 GiB; an
+# accelerator that reports none is an error, not a v5e.
 _HBM_BYTES_PER_CHIP = None                    # None = query the device
-_HBM_FALLBACK_BYTES = 16 * 1024 ** 3          # v5e/v5p-lite class
+_HBM_FALLBACK_BYTES = 16 * 1024 ** 3          # cpu platform only
 _HBM_TABLE_FRACTION = 0.6                     # leave room for acts/moments
 
 
@@ -79,15 +80,16 @@ def _hbm_bytes_per_chip():
     # whose chips differ), honoring the same configured override the
     # headroom predictor / admission math uses — router and admission
     # agree on one number by construction
-    try:
-        from ..monitor import memscope
+    from ..monitor import memscope
 
-        limit = memscope.min_device_bytes_limit(
-            fallback=_HBM_FALLBACK_BYTES)
-        if limit:
-            return int(limit)
-    except Exception:
-        pass
+    limit = memscope.min_device_bytes_limit()
+    if limit:
+        return int(limit)
+    platform = jax.devices()[0].platform
+    if platform != "cpu":
+        raise RuntimeError(
+            "%s devices report no memory_stats()['bytes_limit']; set the "
+            "per-chip budget with configure_hbm_budget()" % platform)
     return _HBM_FALLBACK_BYTES
 
 

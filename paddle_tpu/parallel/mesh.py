@@ -70,17 +70,9 @@ def axis_size(mesh, name):
 def local_shard_map(fn, mesh, in_specs, out_specs):
     """shard_map with the varying-manual-axes check off: our kernels mix
     replicated and sharded values freely (e.g. replicated params + sharded
-    activations), which the strict vma checker rejects.  Spans the API move:
-    jax.shard_map(check_vma=) on current jax, the experimental
-    shard_map(check_rep=) on 0.4.x."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm_exp
-
-    return sm_exp(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    activations), which the strict vma checker rejects."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def batch_spec():

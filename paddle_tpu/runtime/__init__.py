@@ -12,6 +12,7 @@ import ctypes
 import os
 import subprocess
 import threading
+import warnings
 
 _build_lock = threading.Lock()
 _cache = {}
@@ -57,7 +58,11 @@ def load(name):
             return _cache[name]
         try:
             lib = ctypes.CDLL(_build(name))
-        except (OSError, subprocess.CalledProcessError):
+        except (OSError, subprocess.CalledProcessError) as e:
+            warnings.warn("native runtime lib%s.so not built (%s: %s); "
+                          "using the pure-Python path"
+                          % (name, type(e).__name__,
+                             getattr(e, "stderr", None) or e))
             lib = None
         _cache[name] = lib
         return lib

@@ -492,9 +492,9 @@ def replica_main(argv=None):
                          "degraded-reads policy applies")
     args = ap.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    from .. import monitor
+    from .. import compile_cache, monitor
 
+    compile_cache.place()
     monitor.enable(args.mon_dir)
     rc = 0
     try:
@@ -531,8 +531,11 @@ class FleetManager:
         self.queue_capacity = int(queue_capacity)
         self.ctr = dict(ctr) if ctr else None
         self.python = python or sys.executable
+        # no default platform: a replica runs on whatever JAX finds, and a
+        # caller that wants CPU replicas passes JAX_PLATFORMS=cpu in env.
+        # One process per chip — a replica that cannot get the chip (the
+        # spawning process already holds it) exits with the runtime's error.
         base = dict(os.environ if env is None else env)
-        base.setdefault("JAX_PLATFORMS", "cpu")
         base["PYTHONPATH"] = (_REPO + os.pathsep + base["PYTHONPATH"]
                               if base.get("PYTHONPATH") else _REPO)
         self.env = base

@@ -329,6 +329,33 @@ class _Refused(Exception):
         self.remove = remove
 
 
+def _exec_devices(compiled):
+    """``{"platform", "ids"}`` of the devices ``compiled`` runs on — the
+    mesh's devices for a sharded step, the one device otherwise."""
+    devs = compiled.runtime_executable().local_devices()
+    return {"platform": devs[0].platform, "ids": [d.id for d in devs]}
+
+
+def _load_on(devices, serialized):
+    """Deserialize onto exactly the devices the executable was compiled
+    for.  ``deserialize_and_load`` defaults to EVERY device of the backend,
+    which turns a one-device executable into one that expects a shard per
+    device on any host with more than one."""
+    from jax.experimental import serialize_executable as _se
+
+    if not devices:
+        raise _Refused("entry names no devices")
+    by_id = {d.id: d for d in jax.devices(devices["platform"])}
+    missing = [i for i in devices["ids"] if i not in by_id]
+    if missing:
+        raise _Refused("compiled for %s devices %s; this process has no %s"
+                       % (devices["platform"], devices["ids"], missing),
+                       remove=False)
+    devs = [by_id[i] for i in devices["ids"]]
+    return _se.deserialize_and_load(*serialized, backend=devs[0].client,
+                                    execution_devices=devs)
+
+
 class ExecutableStore:
     """Disk directory of serialized executables.
 
@@ -336,7 +363,8 @@ class ExecutableStore:
 
         ptwarm1\\n <8-byte big-endian header length> <header JSON> <payload>
 
-    header: ``{"crc": crc32(payload), "versions": {...}, "key": {...}}``;
+    header: ``{"crc": crc32(payload), "versions": {...}, "devices":
+    {"platform", "ids"}, "key": {...}}``;
     payload: ``pickle((serialized, in_tree, out_tree))`` from
     ``jax.experimental.serialize_executable.serialize``.
 
@@ -405,9 +433,8 @@ class ExecutableStore:
             if (zlib.crc32(payload) & 0xFFFFFFFF) != int(header.get("crc",
                                                                     -1)):
                 raise _Refused("payload CRC mismatch")
-            from jax.experimental import serialize_executable as _se
-
-            compiled = _se.deserialize_and_load(*pickle.loads(payload))
+            compiled = _load_on(header.get("devices"),
+                                pickle.loads(payload))
         except Exception as e:
             # poisoned entry: silently fall back to a recompile (which
             # overwrites); the cache must never be able to wedge a step
@@ -441,6 +468,7 @@ class ExecutableStore:
 
             t0 = time.perf_counter()
             payload = pickle.dumps(_se.serialize(compiled))
+            devices = _exec_devices(compiled)
             ms = (time.perf_counter() - t0) * 1e3
         except Exception as e:
             warnings.warn("warm cache: executable not serializable (%s); "
@@ -449,6 +477,7 @@ class ExecutableStore:
         header = json.dumps({
             "crc": zlib.crc32(payload) & 0xFFFFFFFF,
             "versions": version_fingerprint(),
+            "devices": devices,
             "key": _canonical(key_parts),
             "created": time.time(),
         }).encode("utf-8")
@@ -843,7 +872,7 @@ def measure_roundtrip_ms(compiled):
 
         payload = pickle.dumps(_se.serialize(compiled))
         t0 = time.perf_counter()
-        _se.deserialize_and_load(*pickle.loads(payload))
+        _load_on(_exec_devices(compiled), pickle.loads(payload))
         return (time.perf_counter() - t0) * 1e3
     except Exception:
         return None

@@ -242,4 +242,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from paddle_tpu import compile_cache
+
+    compile_cache.place()
     main()

@@ -1,7 +1,7 @@
 """Micro-probes for the r5 ResNet findings:
   1. per-channel reduction of [128,56,56,256] bf16: jnp.mean vs ones-dot
   2. 1x1 wgrad: XLA autodiff's reduce-fusion form vs explicit dot_general
-Calibrated scan harness (see resnet_scanstep_probe).
+Calibrated scan harness.
 """
 
 import time

@@ -85,7 +85,7 @@ def build_artifact(workdir, rng):
         pred = fluid.layers.fc(h, size=1)
         loss = fluid.layers.mean(fluid.layers.square_error_cost(pred, yv))
         fluid.optimizer.SGD(0.05).minimize(loss)
-    exe = fluid.Executor(fluid.CPUPlace())
+    exe = fluid.Executor(fluid.TPUPlace())
     exe.run(startup)
     for _ in range(3):
         exe.run(main, feed={"x": rng.rand(32, 12).astype("f4"),
@@ -739,7 +739,6 @@ def main(argv=None):
                     help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     if args.shard_worker:
         return shard_worker(args)
     if args.trace:

