@@ -21,22 +21,32 @@ DEFAULT_DIR = os.path.join(
 def place():
     """Returns the cache directory in effect (None: no cache).
 
-    ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; nothing is set
-    in code.  Unset, on an accelerator: ``<checkout>/.jax_cache``, with the
-    compile-time floor off so that what a run caches does not depend on how
-    long a compile happened to take (a second run must add nothing)."""
-    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if env:
-        return env
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; no directory is
+    set in code.  Unset, on an accelerator: ``<checkout>/.jax_cache``, with
+    the compile-time floor off so that what a run caches does not depend on
+    how long a compile happened to take (a second run must add nothing).
+    On an accelerator, either way, the cache is keyed on the programs'
+    metadata too (see below)."""
     import jax
 
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if jax.devices()[0].platform == "cpu":
         # CPU compiles are cheap, and under jaxlib 0.9.0 an XLA:CPU
         # executable that was LOADED from this cache re-serializes without
         # its kernels: warm.py would publish it, and the replica that
         # deserializes it dies at first dispatch ("Function
         # concatenate.1_kernel not found")
-        return None
+        return env or None
+    # JAX hashes a program AFTER stripping its debug info, which is where
+    # jax.named_scope names and source lines live: a step that another
+    # commit compiled is then served under the same key with THAT commit's
+    # names, and monitor.devscope (or any profile) reads stale scopes or
+    # none.  Keyed on the metadata, an edit on a program's traced path makes
+    # its next run compile anew; a second run of the same tree still adds
+    # no entry.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    if env:
+        return env
     jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return DEFAULT_DIR

@@ -18,6 +18,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from ..monitor import devscope
 from ..parallel import collectives as col
 from ..parallel.mesh import DP, PP, TP, MeshSpec
 from ..parallel.pipeline import gpipe, split_microbatches
@@ -96,8 +97,13 @@ class BertTrainer:
     specs: dict
     multi_fn: object = None
     batch_keys: tuple = ("ids", "labels", "mask")
+    # which of the two programs monitor.devscope has been told of
+    _step_seen = _multi_seen = False
 
     def step(self, batch, lr):
+        if not self._step_seen:
+            self._step_seen = devscope.register(
+                "bert.step", self.step_fn, (self.state, batch, lr))
         self.state, loss = self.step_fn(self.state, batch, lr)
         return loss
 
@@ -108,6 +114,9 @@ class BertTrainer:
         Returns losses [N]."""
         if self.multi_fn is None:
             raise RuntimeError("trainer built without multi-step support")
+        if not self._multi_seen:
+            self._multi_seen = devscope.register(
+                "bert.run_steps", self.multi_fn, (self.state, batches, lr))
         self.state, losses = self.multi_fn(self.state, batches, lr)
         return losses
 

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ..monitor import devscope
 from ..parallel import collectives as col
 from ..parallel.mesh import DP, MeshSpec
 from ..parallel import optim
@@ -143,6 +144,7 @@ def init_resnet_params(key, cfg: ResNetConfig):
 # forward
 # ---------------------------------------------------------------------------
 
+@devscope.scoped(devscope.CONV)
 def _conv(x, w, stride=1, padding="SAME"):
     # Plain XLA conv (no preferred_element_type: XLA's MXU lowering
     # accumulates bf16 convs in f32 regardless).  The Pallas wgrad kernel
@@ -156,6 +158,7 @@ def _conv(x, w, stride=1, padding="SAME"):
     )
 
 
+@devscope.scoped(devscope.CONV)
 def _conv0_s2d(x, w7):
     """conv0 (7x7/2, cin=3) via 2x2 space-to-depth: a 4x4 stride-1 conv on
     [B, 112, 112, 12].  cin=3 convs run far off the MXU's useful shapes
@@ -197,6 +200,7 @@ def _bn_fused(x, p, s, cfg, train, updates, path):
     return fbn.fused_bn_eval(x, p["scale"], p["bias"], s["mean"], s["var"])
 
 
+@devscope.scoped(devscope.BN)
 def _bn(x, p, s, cfg, train, updates, path):
     # Folded form: y = x*a + b with per-channel a,b.  Stats accumulate in f32
     # via the reduction dtype; the normalize itself stays in x.dtype.  This
@@ -225,6 +229,12 @@ def _bn(x, p, s, cfg, train, updates, path):
     return x * a.astype(x.dtype) + b.astype(x.dtype)
 
 
+# On the chip the ReLU after a batch norm, and the residual add where a block
+# closes, run inside the norm's own pass (XLA fuses them into the one
+# instruction that applies scale and shift), so they carry its scope.
+_relu = devscope.scoped(devscope.BN)(jax.nn.relu)
+
+
 def resnet_forward(params, bn_state, images, cfg: ResNetConfig, train=True):
     """images: [B, H, W, 3].  Returns (logits [B, C], new_bn_state)."""
     updates = {}
@@ -234,9 +244,10 @@ def resnet_forward(params, bn_state, images, cfg: ResNetConfig, train=True):
     else:
         x = _conv(x, params["conv0"], stride=2)
     x = _bn(x, params["bn0"], bn_state["bn0"], cfg, train, updates, "bn0")
-    x = jax.nn.relu(x)
-    x = lax.reduce_window(x, -jnp.inf, lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
-                          "SAME")
+    x = _relu(x)
+    with jax.named_scope(devscope.POOL):
+        x = lax.reduce_window(x, -jnp.inf, lax.max, (1, 3, 3, 1),
+                              (1, 2, 2, 1), "SAME")
 
     for si, nblocks in enumerate(cfg.blocks):
         for bi in range(nblocks):
@@ -248,27 +259,30 @@ def resnet_forward(params, bn_state, images, cfg: ResNetConfig, train=True):
             shortcut = x
             if cfg.bottleneck:
                 y = _conv(x, blk["conv1"], 1)
-                y = jax.nn.relu(_bn(y, blk["bn1"], sblk["bn1"], cfg, train, bupd, "bn1"))
+                y = _relu(_bn(y, blk["bn1"], sblk["bn1"], cfg, train, bupd, "bn1"))
                 y = _conv(y, blk["conv2"], stride)
-                y = jax.nn.relu(_bn(y, blk["bn2"], sblk["bn2"], cfg, train, bupd, "bn2"))
+                y = _relu(_bn(y, blk["bn2"], sblk["bn2"], cfg, train, bupd, "bn2"))
                 y = _conv(y, blk["conv3"], 1)
                 y = _bn(y, blk["bn3"], sblk["bn3"], cfg, train, bupd, "bn3")
             else:
                 y = _conv(x, blk["conv1"], stride)
-                y = jax.nn.relu(_bn(y, blk["bn1"], sblk["bn1"], cfg, train, bupd, "bn1"))
+                y = _relu(_bn(y, blk["bn1"], sblk["bn1"], cfg, train, bupd, "bn1"))
                 y = _conv(y, blk["conv2"], 1)
                 y = _bn(y, blk["bn2"], sblk["bn2"], cfg, train, bupd, "bn2")
             if "proj" in blk:
                 shortcut = _conv(x, blk["proj"], stride)
                 shortcut = _bn(shortcut, blk["bnp"], sblk["bnp"], cfg, train,
                                bupd, "bnp")
-            x = jax.nn.relu(y + shortcut)
+            with jax.named_scope(devscope.BN):
+                x = jax.nn.relu(y + shortcut)
             if bupd:
                 updates[name] = {**{k: sblk[k] for k in sblk if k not in bupd},
                                  **bupd}
 
-    x = jnp.mean(x.astype(jnp.float32), axis=(1, 2))            # global avg pool
-    logits = x.astype(cfg.jdtype) @ params["fc_w"] + params["fc_b"]
+    with jax.named_scope(devscope.POOL):
+        x = jnp.mean(x.astype(jnp.float32), axis=(1, 2))        # global avg pool
+    with jax.named_scope(devscope.FC):
+        logits = x.astype(cfg.jdtype) @ params["fc_w"] + params["fc_b"]
     new_state = {k: updates.get(k, bn_state[k]) for k in bn_state}
     return logits.astype(jnp.float32), new_state
 
@@ -284,10 +298,11 @@ def make_loss_fn(cfg: ResNetConfig):
         logits, new_state = resnet_forward(params, bn_state, batch["image"],
                                            cfg, train=True)
         labels = batch["label"]
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
-        loss = col.psum(jnp.sum(nll), DP) / col.psum(
-            jnp.asarray(nll.shape[0], jnp.float32), DP)
+        with jax.named_scope(devscope.LOSS):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
+            loss = col.psum(jnp.sum(nll), DP) / col.psum(
+                jnp.asarray(nll.shape[0], jnp.float32), DP)
         return loss, new_state
 
     return loss_fn
@@ -301,8 +316,14 @@ class ResNetTrainer:
     bn_state: dict
     step_fn: object
     multi_fn: object = None
+    # which of the two programs monitor.devscope has been told of
+    _step_seen = _multi_seen = False
 
     def step(self, batch, lr):
+        if not self._step_seen:
+            self._step_seen = devscope.register(
+                "resnet.step", self.step_fn,
+                (self.state, self.bn_state, batch, lr))
         self.state, self.bn_state, loss = self.step_fn(self.state,
                                                        self.bn_state, batch, lr)
         return loss
@@ -313,6 +334,10 @@ class ResNetTrainer:
         leading [N] step axis staged via parallel.train.stack_batches."""
         if self.multi_fn is None:
             raise RuntimeError("trainer built without multi-step support")
+        if not self._multi_seen:
+            self._multi_seen = devscope.register(
+                "resnet.run_steps", self.multi_fn,
+                (self.state, self.bn_state, batches, lr))
         self.state, self.bn_state, losses = self.multi_fn(
             self.state, self.bn_state, batches, lr)
         return losses
@@ -348,9 +373,12 @@ def build_resnet_trainer(cfg: ResNetConfig, mesh_spec: MeshSpec = None,
 
         (loss, new_bn), grads = jax.value_and_grad(wrapped, has_aux=True)(
             state["params"])
-        grads = jax.tree.map(lambda g: col.psum(g, DP), grads)
-        new_bn = jax.tree.map(lambda a: col.pmean(a, DP), new_bn)
-        new_params, new_opt = opt_update(grads, state["opt"], state["params"], lr)
+        with jax.named_scope(devscope.GRAD_SYNC):
+            grads = jax.tree.map(lambda g: col.psum(g, DP), grads)
+            new_bn = jax.tree.map(lambda a: col.pmean(a, DP), new_bn)
+        with jax.named_scope(devscope.OPTIMIZER):
+            new_params, new_opt = opt_update(grads, state["opt"],
+                                             state["params"], lr)
         return {"params": new_params, "opt": new_opt}, new_bn, loss
 
     batch_specs = {"image": P(DP), "label": P(DP)}

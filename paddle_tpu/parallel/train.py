@@ -18,7 +18,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from . import collectives as col
 from .mesh import local_shard_map
 from .. import warm as _warm
-from ..monitor import memscope as _memscope
+from ..monitor import devscope as _devscope, memscope as _memscope
 
 __all__ = ["TrainState", "make_train_step", "shard_pytree", "stack_batches",
            "TrainLoop"]
@@ -92,9 +92,11 @@ def make_train_step(loss_fn, mesh, param_specs, grad_syncs, optimizer,
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         flat_g, treedef = jax.tree.flatten(grads)
         flat_s = treedef.flatten_up_to(grad_syncs)
-        flat_g = [_sync_grad(g, axes) for g, axes in zip(flat_g, flat_s)]
+        with jax.named_scope(_devscope.GRAD_SYNC):
+            flat_g = [_sync_grad(g, axes) for g, axes in zip(flat_g, flat_s)]
         grads = jax.tree.unflatten(treedef, flat_g)
-        new_params, new_opt = opt_update(grads, state["opt"], params, lr)
+        with jax.named_scope(_devscope.OPTIMIZER):
+            new_params, new_opt = opt_update(grads, state["opt"], params, lr)
         return {"params": new_params, "opt": new_opt}, loss
 
     def _mapped(state_template):

@@ -31,6 +31,7 @@ from jax.sharding import PartitionSpec as P
 
 from . import collectives as col
 from .mesh import DP, PP, TP
+from ..monitor import devscope
 from .ring_attention import ring_attention
 
 __all__ = ["TransformerConfig", "init_transformer_params", "transformer_param_specs",
@@ -190,6 +191,7 @@ def _gelu_r_bwd(res, dy):
 _gelu_r.defvjp(_gelu_r_fwd, _gelu_r_bwd)
 
 
+@devscope.scoped(devscope.LAYER_NORM)
 def layer_norm(x, scale, bias, eps=1e-6, fused=True):
     """fused=True dispatches to the one-pass Pallas kernel (fwd + fused bwd);
     XLA's decomposition costs several full HBM passes per direction at bench
@@ -209,6 +211,7 @@ def layer_norm(x, scale, bias, eps=1e-6, fused=True):
     return ((xf - mu) * jax.lax.rsqrt(var + eps) * scale + bias).astype(x.dtype)
 
 
+@devscope.scoped(devscope.EMBED)
 def embed(params, ids, cfg: TransformerConfig, seq_offset=None):
     """Vocab-parallel embedding lookup + position embedding; returns the
     sequence-sharded (SP) activation [b, S/tp, E].
@@ -299,22 +302,24 @@ def _attention_ring_mode(pl, h_sp, cfg):
 def transformer_layer(pl, x_sp, cfg: TransformerConfig):
     """One pre-LN transformer block on the SP activation [b, S/tp, E]."""
     heads_mode = cfg.attn_mode == "heads"
-    h = layer_norm(x_sp, pl["ln1_scale"], pl["ln1_bias"])
-    if heads_mode:
-        h = col.all_gather(h, TP, dim=1)
-        attn = _attention_heads_mode(pl, h, cfg)
-    else:
-        attn = _attention_ring_mode(pl, h, cfg)
-    x_sp = x_sp + attn
+    with jax.named_scope(devscope.ATTENTION):
+        h = layer_norm(x_sp, pl["ln1_scale"], pl["ln1_bias"])
+        if heads_mode:
+            h = col.all_gather(h, TP, dim=1)
+            attn = _attention_heads_mode(pl, h, cfg)
+        else:
+            attn = _attention_ring_mode(pl, h, cfg)
+        x_sp = x_sp + attn
 
-    h = layer_norm(x_sp, pl["ln2_scale"], pl["ln2_bias"])
-    if heads_mode:
-        h = col.all_gather(h, TP, dim=1)
-    y = _gelu_r(h @ pl["w1"] + pl["b1"])
-    y = y @ pl["w2"]                                            # partial if heads_mode
-    if heads_mode:
-        y = col.reduce_scatter(y, TP, dim=1)
-    x_sp = x_sp + y + pl["b2"]
+    with jax.named_scope(devscope.MLP):
+        h = layer_norm(x_sp, pl["ln2_scale"], pl["ln2_bias"])
+        if heads_mode:
+            h = col.all_gather(h, TP, dim=1)
+        y = _gelu_r(h @ pl["w1"] + pl["b1"])
+        y = y @ pl["w2"]                                        # partial if heads_mode
+        if heads_mode:
+            y = col.reduce_scatter(y, TP, dim=1)
+        x_sp = x_sp + y + pl["b2"]
     return x_sp
 
 
@@ -358,6 +363,7 @@ def _vocab_chunks(emb, n_chunks):
     return list(zip(offs, sizes))
 
 
+@devscope.scoped(devscope.LM_HEAD)
 def _chunked_vocab_nll_fwd(x, emb, labels, n_chunks):
     xf = x
     m_run = jnp.full(labels.shape, -jnp.inf, jnp.float32)
@@ -381,6 +387,9 @@ def _chunked_vocab_nll_fwd(x, emb, labels, n_chunks):
     return lse - picked, (x, emb, labels, lse)
 
 
+# a custom_vjp backward is traced on its own, in the backward pass: it names
+# its scope itself
+@devscope.scoped(devscope.LM_HEAD)
 def _chunked_vocab_nll_bwd(n_chunks, res, g):
     x, emb, labels, lse = res
     dx = jnp.zeros(x.shape, jnp.float32)
@@ -410,6 +419,7 @@ def _chunked_vocab_nll_bwd(n_chunks, res, g):
 _chunked_vocab_nll.defvjp(_chunked_vocab_nll_fwd, _chunked_vocab_nll_bwd)
 
 
+@devscope.scoped(devscope.LM_HEAD)
 def final_logits_loss(params, x_sp, labels, mask, cfg: TransformerConfig,
                       positions=None):
     """Vocab-parallel softmax cross-entropy with the tied embedding head.

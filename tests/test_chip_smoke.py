@@ -52,24 +52,35 @@ def test_compile_cache_placement(monkeypatch, tmp_path):
     updates = []
     monkeypatch.setattr(jax.config, "update",
                         lambda k, v: updates.append((k, v)))
-    # set from outside: nothing is set in code
+    # on the CPU nothing is set in code, whether the environment names a
+    # directory or not
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert compile_cache.place() == str(tmp_path)
     assert updates == []
     # unset, on the CPU: no cache (XLA:CPU executables reload broken)
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     assert compile_cache.place() is None and updates == []
-    # unset, on a chip: one absolute path, whatever the working directory
+    # unset, on a chip: one absolute path, whatever the working directory,
+    # and a key that holds the programs' metadata (named scopes, lines): a
+    # profile never shows another commit's names
     import types
 
     chip = types.SimpleNamespace(platform="tpu")
     monkeypatch.setattr(jax, "devices", lambda *a: [chip])
+    metadata = ("jax_compilation_cache_include_metadata_in_key", True)
     paths = []
     for cwd in (str(tmp_path), REPO):
         monkeypatch.chdir(cwd)
         paths.append(compile_cache.place())
     assert paths[0] == paths[1] == os.path.join(REPO, ".jax_cache")
     assert ("jax_compilation_cache_dir", paths[0]) in updates
+    assert metadata in updates
+    # set from outside, on a chip: the directory is the environment's, the
+    # key holds the metadata all the same
+    del updates[:]
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.place() == str(tmp_path)
+    assert updates == [metadata]
     assert jax.config.jax_compilation_cache_dir == before   # tests stay cold
 
 

@@ -1,0 +1,231 @@
+"""monitor.devscope: the train path names its work with ``jax.named_scope``,
+the trainers register the programs they dispatch, and ``scope_maps()`` reads
+``instruction name -> op_name`` off the compiled text, on demand only."""
+
+import gc
+import re
+
+import jax
+import numpy as np
+import pytest
+
+from paddle_tpu.models import bert, resnet
+from paddle_tpu.monitor import devscope
+from paddle_tpu.parallel import optim
+from paddle_tpu.parallel.mesh import MeshSpec
+from paddle_tpu.parallel.train import stack_batches
+
+COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                  "/jax/core/compile/backend_compile_duration")
+
+
+@pytest.fixture(autouse=True)
+def empty_registry():
+    saved = devscope._programs[:]
+    devscope._programs[:] = []
+    yield
+    devscope._programs[:] = saved
+
+
+def _bert_batch(rng, b=4, s=32):
+    return {"ids": rng.randint(0, 128, (b, s)).astype("int32"),
+            "labels": rng.randint(0, 128, (b, s)).astype("int32"),
+            "mask": (rng.rand(b, s) < 0.3).astype("float32")}
+
+
+def _run_bert(dp=1, **cfg):
+    tr = bert.build_bert_trainer(bert.bert_tiny_config(**cfg),
+                                 MeshSpec(dp=dp), devices=jax.devices()[:dp])
+    rng = np.random.RandomState(0)
+    staged = stack_batches(tr.mesh, bert.batch_specs(),
+                           [_bert_batch(rng), _bert_batch(rng)])
+    losses = np.asarray(tr.run_steps(staged, 1e-3))
+    assert np.isfinite(losses).all()
+    return tr, staged
+
+
+def _run_resnet(dp=1):
+    tr = resnet.build_resnet_trainer(
+        resnet.resnet_tiny_config(), MeshSpec(dp=dp),
+        optimizer=optim.momentum(0.9), devices=jax.devices()[:dp])
+    rng = np.random.RandomState(0)
+    batch = {"image": rng.rand(4, 32, 32, 3).astype("float32"),
+             "label": rng.randint(0, 10, (4,)).astype("int32")}
+    assert np.isfinite(float(tr.step(batch, 1e-2)))
+    return tr
+
+
+def _classes(names):
+    return {devscope.classify(op) for op in names.values()}
+
+
+def test_classify_on_literal_op_names():
+    table = {
+        "jit(multi)/while/body/closed_call/jvp(lm_head)/dot_general":
+            ("forward", "lm_head"),
+        "jit(multi)/while/body/closed_call/transpose(jvp(lm_head))/lm_head/"
+        "dot_general": ("backward", "lm_head"),
+        # the innermost scope wins
+        "jit(multi)/jvp()/while/body/closed_call/mlp/layer_norm/"
+        "layer_norm_fwd/while/body/div": ("forward", "layer_norm"),
+        "jit(multi)/transpose(jvp())/while/body/closed_call/attention/"
+        "flash_bwd_fused/mul": ("backward", "attention"),
+        # no vocabulary word on the path
+        "jit(multi)/while/body/closed_call/jvp()/while/body/closed_call":
+            ("forward", None),
+        "jit(multi)/while/body/closed_call/transpose(jvp())/concatenate":
+            ("backward", None),
+        "": ("forward", None),
+        # the two phases that are scopes, also under a transform
+        "jit(multi)/while/body/closed_call/optimizer/sqrt":
+            ("optimizer", "optimizer"),
+        "jit(step)/grad_sync/psum": ("grad_sync", "grad_sync"),
+        # jax.checkpoint: the forward run again, and the body's own backward
+        "jit(multi)/transpose(jvp())/while/body/closed_call/checkpoint/"
+        "rematted_computation/attention/dot_general":
+            ("recompute", "attention"),
+        "jit(multi)/transpose(jvp())/while/body/closed_call/checkpoint/mlp/"
+        "transpose": ("backward", "mlp"),
+        # a word of the vocabulary inside another name is none
+        "jit(multi)/jvp(jit(convolve))/pooling/mul": ("forward", None),
+        "jit(step)/jvp(bn)/jit(relu)/max": ("forward", "bn"),
+    }
+    for op_name, want in table.items():
+        assert devscope.classify(op_name) == want, op_name
+    assert set(devscope.PHASES) >= {w[0] for w in table.values()}
+    assert len(set(devscope.VOCABULARY)) == len(devscope.VOCABULARY) == 12
+
+
+def test_bert_program_that_ran_maps_every_scope_it_uses():
+    tr, _ = _run_bert(dp=2)
+    maps = devscope.scope_maps()
+    # step() was never called: it has no map, and run_steps' names are its own
+    assert list(maps) == ["bert.run_steps"]
+    names = maps["bert.run_steps"]
+    assert len(names) > 500
+    got = _classes(names)
+    for scope in ("embed", "attention", "mlp", "layer_norm", "lm_head"):
+        assert ("forward", scope) in got and ("backward", scope) in got, scope
+    assert ("optimizer", "optimizer") in got
+    assert ("grad_sync", "grad_sync") in got          # dp=2: a real psum
+    assert not {s for _, s in got} - set(devscope.VOCABULARY) - {None}
+    # the custom_vjp backward of the chunked vocabulary loss names itself
+    assert any(re.search(r"transpose\(jvp\(lm_head\)\)/lm_head/dot_general",
+                         op) for op in names.values())
+    # the collective is among the named instructions
+    assert any(n.startswith("all-reduce")
+               and devscope.classify(op)[0] == "grad_sync"
+               for n, op in names.items())
+
+
+def test_resnet_step_maps_every_scope_it_uses():
+    tr = _run_resnet(dp=2)
+    maps = devscope.scope_maps()
+    assert list(maps) == ["resnet.step"] and tr.multi_fn is not None
+    got = _classes(maps["resnet.step"])
+    for scope in ("conv", "bn", "pool", "fc", "loss"):
+        assert ("forward", scope) in got and ("backward", scope) in got, scope
+    assert ("optimizer", "optimizer") in got
+    assert ("grad_sync", "grad_sync") in got
+
+
+def test_remat_layers_show_as_recompute():
+    tr, _ = _run_bert(remat=True)
+    got = _classes(devscope.scope_maps()["bert.run_steps"])
+    assert {("recompute", "attention"), ("recompute", "mlp"),
+            ("backward", "attention"), ("backward", "mlp"),
+            ("forward", "attention"), ("forward", "mlp")} <= got
+
+
+def test_registering_traces_lowers_and_compiles_nothing():
+    import jax.monitoring
+
+    seen = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, _secs, **_kw: seen.append(event)
+        if event in COMPILE_EVENTS else None)
+    tr, staged = _run_bert()
+    assert "/jax/core/compile/backend_compile_duration" in seen
+    assert [p[0] for p in devscope._programs] == ["bert.run_steps"]
+    tr.run_steps(staged, 1e-3)      # the returned state retraces, once
+    n = len(seen)
+    tr.run_steps(staged, 1e-3)      # registered already: one attribute test
+    assert len(devscope._programs) == 1 and len(seen) == n
+    # what is kept holds no buffer
+    avals = jax.tree.leaves(devscope._programs[0][2])
+    assert all(isinstance(a, (jax.ShapeDtypeStruct, float)) for a in avals)
+    # and asking finds the step's executable in the process: no new compile
+    assert devscope.scope_maps()["bert.run_steps"]
+    assert seen.count("/jax/core/compile/backend_compile_duration") == \
+        seen[:n].count("/jax/core/compile/backend_compile_duration")
+
+
+def test_both_programs_of_one_trainer_and_two_trainers_of_one_kind():
+    tr, staged = _run_bert()
+    rng = np.random.RandomState(1)
+    tr.step(_bert_batch(rng), 1e-3)
+    _run_bert()                               # dies at once: leaves no entry
+    other, _ = _run_bert()
+    maps = devscope.scope_maps()
+    assert sorted(maps) == ["bert.run_steps", "bert.run_steps#2", "bert.step"]
+    assert maps["bert.run_steps"] == maps["bert.run_steps#2"]
+    assert not any("jit(multi)" in op for op in maps["bert.step"].values())
+    del other
+
+
+def test_a_dead_trainer_leaves_the_registry():
+    tr, staged = _run_bert()
+    keep = _run_resnet()
+    assert len(devscope._programs) == 2
+    del tr, staged
+    gc.collect()
+    assert list(devscope.scope_maps()) == ["resnet.step"]
+    assert [p[0] for p in devscope._programs] == ["resnet.step"]
+    del keep
+
+
+def test_a_warm_callable_is_not_registered():
+    class NoLower:                            # warm.WarmCallable's surface
+        def __call__(self, *args):
+            return args
+
+    assert devscope.register("x", NoLower(), (1.0,)) is True
+    assert devscope._programs == [] and devscope.scope_maps() == {}
+
+
+def test_a_cache_keyed_without_metadata_serves_stale_names(tmp_path):
+    """Why ``compile_cache.place()`` keys the cache on metadata: JAX hashes a
+    program after stripping its debug info, so the same program WITHOUT its
+    scopes (another commit's compile) is found under the same key, and the
+    loaded executable's text has that commit's names."""
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    def compiled_text(scope):
+        def f(x):
+            with jax.named_scope(scope):
+                return jnp.sin(x) * 2
+        return jax.jit(f).lower(jnp.ones((8, 128))).compile().as_text()
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes",
+            "jax_compilation_cache_include_metadata_in_key")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        jax.config.update(keys[1], 0.0)
+        jax.config.update(keys[2], -1)
+        for in_key, entries, found in ((False, 1, False), (True, 2, True)):
+            cache = tmp_path / str(in_key)
+            cache.mkdir()
+            jax.config.update(keys[0], str(cache))
+            jax.config.update(keys[3], in_key)
+            cc.reset_cache()
+            assert "another_commit" in compiled_text("another_commit")
+            assert ("lm_head" in compiled_text("lm_head")) is found
+            assert len(list(cache.glob("jit_f-*"))) == entries
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
