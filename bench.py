@@ -46,7 +46,9 @@ import numpy as np
 
 
 def model_flops_per_token(cfg, S):
-    """Training (fwd+bwd = 3x fwd) matmul FLOPs per token."""
+    """Training (fwd+bwd = 3x fwd) matmul FLOPs per token.  Counts the LM
+    head on every position; the count a job requires (the head on its
+    predicted positions) is benchmark/flops/transformer_mlm_train.py."""
     E, L, F, V = cfg.hidden, cfg.n_layers, cfg.ffn_hidden, cfg.vocab_size
     per_layer_fwd = 8 * E * E + 4 * E * F + 4 * S * E   # qkv+proj, mlp, attn
     head_fwd = 2 * E * V                                 # tied LM head

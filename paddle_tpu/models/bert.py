@@ -11,13 +11,18 @@ TPU-native form (Pallas/XLA attention + parallel/optim.py lamb).
 
 batch dict: ids/labels int32 [B, S], mask float32 [B, S] (1 where the label
 position counts — MLM masked positions, or every position for causal LM).
+``mask`` alone says which positions count: with tp=1 the LM head computes
+only the rows whose mask is non-zero (transformer._chunked_vocab_nll), so an
+MLM batch pays for its 80 predicted positions and not for all 512.
 """
 
 import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from .. import monitor
 from ..monitor import devscope
 from ..parallel import collectives as col
 from ..parallel.mesh import DP, PP, TP, MeshSpec
@@ -29,6 +34,7 @@ from ..parallel.transformer import (
     embed,
     final_logits_loss,
     grad_sync_axes,
+    head_rows_computed,
     init_transformer_params,
     run_layers,
     transformer_param_specs,
@@ -61,7 +67,6 @@ def make_loss_fn(cfg: TransformerConfig, n_microbatches=1):
     def loss_fn(params, batch):
         ids, labels = batch["ids"], batch["labels"]
         mask = batch["mask"].astype(jnp.float32)
-        positions = batch.get("positions")   # [b, P] MLM label positions
 
         x_sp = embed(params, ids, cfg)                       # [b, S/tp, E]
 
@@ -70,15 +75,13 @@ def make_loss_fn(cfg: TransformerConfig, n_microbatches=1):
             x_mb = split_microbatches(x_sp, n_microbatches)
             outs = gpipe(lambda p, x: run_layers(p, x, cfg), lp, x_mb, axis=PP)
             x_sp = outs.reshape((-1,) + outs.shape[2:])
-            loss = final_logits_loss(params, x_sp, labels, mask, cfg,
-                                     positions=positions)
+            loss = final_logits_loss(params, x_sp, labels, mask, cfg)
             npp = col.axis_size_in(PP)
             is_last = (col.axis_index(PP) == npp - 1).astype(jnp.float32)
             loss = col.psum(loss * is_last, PP)
         else:
             x_sp = run_layers(params["params_layers"], x_sp, cfg)
-            loss = final_logits_loss(params, x_sp, labels, mask, cfg,
-                                     positions=positions)
+            loss = final_logits_loss(params, x_sp, labels, mask, cfg)
         return loss
 
     return loss_fn
@@ -100,7 +103,26 @@ class BertTrainer:
     # which of the two programs monitor.devscope has been told of
     _step_seen = _multi_seen = False
 
+    def _count_head_rows(self, mask):
+        """Under a monitor session: the rows the LM head computes for these
+        batches (``mask`` [..., B, S], any leading step axis), by the
+        function the device code takes its trip count from; each dp shard
+        has its own count.  Off the monitor nothing is read back."""
+        mon = monitor.active()
+        if mon is None:
+            return
+        dp = self.mesh.shape[DP]
+        live = (np.asarray(mask) != 0).reshape(
+            -1, dp, mask.shape[-2] // dp * mask.shape[-1])
+        n = live.shape[-1]
+        rows = (head_rows_computed(live.sum(-1), n).sum() if self.cfg.tp == 1
+                else live.size)           # the vocab-parallel head is dense
+        mon.registry.counter("monitor.train.lm_head_rows").incr(int(rows))
+        mon.registry.gauge("monitor.train.lm_head_rows_share").set(
+            rows / live.size)
+
     def step(self, batch, lr):
+        self._count_head_rows(batch["mask"])
         if not self._step_seen:
             self._step_seen = devscope.register(
                 "bert.step", self.step_fn, (self.state, batch, lr))
@@ -114,6 +136,7 @@ class BertTrainer:
         Returns losses [N]."""
         if self.multi_fn is None:
             raise RuntimeError("trainer built without multi-step support")
+        self._count_head_rows(batches["mask"])
         if not self._multi_seen:
             self._multi_seen = devscope.register(
                 "bert.run_steps", self.multi_fn, (self.state, batches, lr))

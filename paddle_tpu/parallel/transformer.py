@@ -22,7 +22,6 @@ vocab-parallel softmax cross-entropy that never materializes gathered logits.
 """
 
 import dataclasses
-import functools
 import math
 
 import jax
@@ -35,7 +34,8 @@ from ..monitor import devscope
 from .ring_attention import ring_attention
 
 __all__ = ["TransformerConfig", "init_transformer_params", "transformer_param_specs",
-           "grad_sync_axes", "embed", "transformer_layer", "final_logits_loss"]
+           "grad_sync_axes", "embed", "transformer_layer", "final_logits_loss",
+           "head_row_block", "head_rows_computed"]
 
 
 @dataclasses.dataclass
@@ -337,19 +337,23 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig):
     return x_sp
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _chunked_vocab_nll(x, emb, labels, n_chunks):
-    """Streaming softmax cross-entropy over the vocab (single-device tp=1).
+_VOCAB_CHUNKS = 4
 
-    Computes per-token nll = lse - picked WITHOUT materializing the
-    [B, S, V] f32 logits: the vocab axis is processed in chunks with a
-    running max/sum (the flash-attention trick applied to the LM head —
-    at bench shapes the full logits tensor is 1.5GB of f32 and its
-    fwd+bwd HBM traffic dominates the head).  The backward recomputes
-    each chunk's logits and feeds bf16 gradients to the MXU.
-    """
-    nll, _ = _chunked_vocab_nll_fwd(x, emb, labels, n_chunks)
-    return nll
+
+def head_row_block(n_rows):
+    """Rows per block of the masked-rows head, from the shape alone: 1024
+    at training sizes (a block's [R, E] x [E, V/4] matmuls fill the MXU as
+    the dense head's did), a sixteenth of the rows below that."""
+    return max(8, min(1024, n_rows // 128 * 8))
+
+
+def head_rows_computed(count, n_rows):
+    """Rows the tp=1 head computes when ``count`` of ``n_rows`` rows have a
+    non-zero mask: whole blocks of ``head_row_block(n_rows)``.  The device
+    code's trip count and the trainer's ``monitor.train.lm_head_rows``
+    counter both come from here (``count`` a traced or a host integer)."""
+    block = head_row_block(n_rows)
+    return (count + block - 1) // block * block
 
 
 def _vocab_chunks(emb, n_chunks):
@@ -363,86 +367,172 @@ def _vocab_chunks(emb, n_chunks):
     return list(zip(offs, sizes))
 
 
+def _live_first(mask):
+    """Stable partition of the rows by ``mask != 0``: ``order`` lists the
+    live rows first (padded with row 0 to whole blocks), ``inv`` is its
+    inverse on the real rows, ``count`` the number of live rows."""
+    n = mask.shape[0]
+    live = mask != 0
+    count = jnp.sum(live, dtype=jnp.int32)
+    order = jnp.argsort(~live, stable=True).astype(jnp.int32)
+    inv = jnp.argsort(order).astype(jnp.int32)
+    return jnp.pad(order, (0, -n % head_row_block(n))), inv, count
+
+
+def _block_rows(i, block, order, count):
+    """Block ``i`` of the compacted order: its rows' indices into the batch,
+    and which of them are live (the last block's tail is not)."""
+    idx = jax.lax.dynamic_slice_in_dim(order, i * block, block)
+    return idx, i * block + jnp.arange(block, dtype=jnp.int32) < count
+
+
+def _vocab_chunk(h, emb, labels, lo, sz):
+    """Vocab rows [lo, lo+sz): their weights [sz, E], the block's logits
+    against them [R, sz] in float32, each row's label as an index into the
+    chunk, and whether it falls inside."""
+    w = jax.lax.dynamic_slice_in_dim(emb, lo, sz, 0)
+    logits = jax.lax.dot_general(h, w, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+    return (w, logits, jnp.clip(labels - lo, 0, sz - 1),
+            (labels >= lo) & (labels < lo + sz))
+
+
+def _head_ln(x, scale, bias):
+    # unfused: XLA fuses the norm into the chunk matmuls around it
+    return layer_norm(x, scale, bias, fused=False)
+
+
+@jax.custom_vjp
+def _chunked_vocab_nll(x, scale, bias, emb, labels, mask):
+    """Per-row ``nll = logsumexp(LN(x) @ emb.T) - picked`` for the rows whose
+    ``mask`` is non-zero, exactly 0 for the others (single-device vocab,
+    tp=1).  x [N, E], labels and mask [N].
+
+    Only the live rows are computed.  They are moved to the front (a stable
+    partition by ``mask != 0``) and the head runs over row blocks of
+    ``head_row_block(N)``, ``ceil(count / R)`` of them: a ``fori_loop`` whose
+    trip count is the mask's own count, in the forward and in the backward
+    below.  An MLM batch predicts 80 positions of 512, so the head does a
+    sixth of the dense work; a causal-LM mask of ones runs every block.
+
+    Inside a block the vocab axis is processed in chunks with a running
+    max/sum, so the [R, V] f32 logits never materialize (the flash-attention
+    trick applied to the LM head); the backward recomputes each chunk's
+    logits and feeds bf16 gradients to the MXU.
+    """
+    nll, _ = _chunked_vocab_nll_fwd(x, scale, bias, emb, labels, mask)
+    return nll
+
+
 @devscope.scoped(devscope.LM_HEAD)
-def _chunked_vocab_nll_fwd(x, emb, labels, n_chunks):
-    xf = x
-    m_run = jnp.full(labels.shape, -jnp.inf, jnp.float32)
-    s_run = jnp.zeros(labels.shape, jnp.float32)
-    picked = jnp.zeros(labels.shape, jnp.float32)
-    for lo, sz in _vocab_chunks(emb, n_chunks):
-        w = jax.lax.dynamic_slice_in_dim(emb, lo, sz, 0)        # [sz, E]
-        logits = jax.lax.dot_general(
-            xf, w, (((xf.ndim - 1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)                 # [..., sz]
-        m_c = jnp.max(logits, axis=-1)
-        m_new = jnp.maximum(m_run, m_c)
-        s_run = s_run * jnp.exp(m_run - m_new) + jnp.sum(
-            jnp.exp(logits - m_new[..., None]), axis=-1)
-        m_run = m_new
-        local = jnp.clip(labels - lo, 0, sz - 1)
-        hit = (labels >= lo) & (labels < lo + sz)
-        pc = jnp.take_along_axis(logits, local[..., None], axis=-1)[..., 0]
-        picked = picked + jnp.where(hit, pc, 0.0)
-    lse = m_run + jnp.log(s_run)
-    return lse - picked, (x, emb, labels, lse)
+def _chunked_vocab_nll_fwd(x, scale, bias, emb, labels, mask):
+    n = x.shape[0]
+    block = head_row_block(n)
+    order, inv, count = _live_first(mask)
+
+    def body(i, carry):
+        nll, lse = carry
+        idx, live = _block_rows(i, block, order, count)
+        lb = labels[idx]
+        h = _head_ln(x[idx], scale, bias)
+        m_run = jnp.full((block,), -jnp.inf, jnp.float32)
+        s_run = jnp.zeros((block,), jnp.float32)
+        picked = jnp.zeros((block,), jnp.float32)
+        for lo, sz in _vocab_chunks(emb, _VOCAB_CHUNKS):
+            _, logits, local, hit = _vocab_chunk(h, emb, lb, lo, sz)
+            m_new = jnp.maximum(m_run, jnp.max(logits, axis=-1))
+            s_run = s_run * jnp.exp(m_run - m_new) + jnp.sum(
+                jnp.exp(logits - m_new[:, None]), axis=-1)
+            m_run = m_new
+            pc = jnp.take_along_axis(logits, local[:, None], axis=-1)[:, 0]
+            picked = picked + jnp.where(hit, pc, 0.0)
+        lse_b = m_run + jnp.log(s_run)
+        nll_b = jnp.where(live, lse_b - picked, 0.0)
+        return (jax.lax.dynamic_update_slice_in_dim(nll, nll_b, i * block, 0),
+                jax.lax.dynamic_update_slice_in_dim(lse, lse_b, i * block, 0))
+
+    zeros = jnp.zeros(order.shape, jnp.float32)
+    n_blocks = head_rows_computed(count, n) // block
+    nll, lse = jax.lax.fori_loop(0, n_blocks, body, (zeros, zeros))
+    # rows past the last block were never touched: their nll is the zero the
+    # carry started from
+    return nll[inv], (x, scale, bias, emb, labels, order, inv, count, lse)
 
 
 # a custom_vjp backward is traced on its own, in the backward pass: it names
 # its scope itself
 @devscope.scoped(devscope.LM_HEAD)
-def _chunked_vocab_nll_bwd(n_chunks, res, g):
-    x, emb, labels, lse = res
-    dx = jnp.zeros(x.shape, jnp.float32)
-    demb = jnp.zeros(emb.shape, jnp.float32)
-    for lo, sz in _vocab_chunks(emb, n_chunks):
-        w = jax.lax.dynamic_slice_in_dim(emb, lo, sz, 0)
-        logits = jax.lax.dot_general(
-            x, w, (((x.ndim - 1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        p = jnp.exp(logits - lse[..., None])                    # softmax chunk
-        local = jnp.clip(labels - lo, 0, sz - 1)
-        hit = (labels >= lo) & (labels < lo + sz)
-        onehot = (jax.nn.one_hot(local, sz, dtype=jnp.float32)
-                  * hit[..., None].astype(jnp.float32))
-        d = ((p - onehot) * g[..., None]).astype(jnp.bfloat16)  # [..., sz]
-        dx = dx + jax.lax.dot_general(
-            d, w, (((d.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dw = jax.lax.dot_general(
-            d.reshape(-1, sz), x.reshape(-1, x.shape[-1]),
-            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        demb = jax.lax.dynamic_update_slice_in_dim(
-            demb, dw, lo, 0)
-    return dx.astype(x.dtype), demb.astype(emb.dtype), None
+def _chunked_vocab_nll_bwd(res, g):
+    x, scale, bias, emb, labels, order, inv, count, lse = res
+    n = x.shape[0]
+    block = head_row_block(n)
+
+    def body(i, carry):
+        dx, dscale, dbias, demb = carry
+        idx, live = _block_rows(i, block, order, count)
+        lb = labels[idx]
+        gb = jnp.where(live, g[idx], 0.0)
+        lse_b = jax.lax.dynamic_slice_in_dim(lse, i * block, block)
+        h, ln_vjp = jax.vjp(_head_ln, x[idx], scale, bias)
+        dh = jnp.zeros(h.shape, jnp.float32)
+        for lo, sz in _vocab_chunks(emb, _VOCAB_CHUNKS):
+            w, logits, local, hit = _vocab_chunk(h, emb, lb, lo, sz)
+            p = jnp.exp(logits - lse_b[:, None])                # softmax chunk
+            onehot = (jax.nn.one_hot(local, sz, dtype=jnp.float32)
+                      * hit[:, None].astype(jnp.float32))
+            d = ((p - onehot) * gb[:, None]).astype(jnp.bfloat16)  # [R, sz]
+            dh = dh + jax.lax.dot_general(
+                d, w, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dw = jax.lax.dot_general(
+                d, h, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)             # [sz, E]
+            demb = jax.lax.dynamic_update_slice_in_dim(
+                demb, jax.lax.dynamic_slice_in_dim(demb, lo, sz, 0) + dw,
+                lo, 0)
+        dxb, dsb, dbb = ln_vjp(dh.astype(h.dtype))
+        dx = jax.lax.dynamic_update_slice_in_dim(dx, dxb, i * block, 0)
+        return dx, dscale + dsb, dbias + dbb, demb
+
+    n_blocks = head_rows_computed(count, n) // block
+    dx, dscale, dbias, demb = jax.lax.fori_loop(0, n_blocks, body, (
+        jnp.zeros((order.shape[0], x.shape[1]), x.dtype),
+        jnp.zeros(scale.shape, scale.dtype), jnp.zeros(bias.shape, bias.dtype),
+        jnp.zeros(emb.shape, jnp.float32)))
+    # a dead row's place in the compacted order holds the zero it started
+    # with, so the way back is a gather through the inverse permutation
+    return dx[inv], dscale, dbias, demb.astype(emb.dtype), None, None
 
 
 _chunked_vocab_nll.defvjp(_chunked_vocab_nll_fwd, _chunked_vocab_nll_bwd)
 
 
 @devscope.scoped(devscope.LM_HEAD)
-def final_logits_loss(params, x_sp, labels, mask, cfg: TransformerConfig,
-                      positions=None):
-    """Vocab-parallel softmax cross-entropy with the tied embedding head.
+def final_logits_loss(params, x_sp, labels, mask, cfg: TransformerConfig):
+    """Softmax cross-entropy with the tied embedding head, averaged over the
+    positions ``mask`` weights: ``sum(nll * mask) / max(sum(mask), 1)`` over
+    the dp-sharded global batch.
 
-    x_sp is sequence-sharded over tp; labels/mask are FULL [b, S] (or [b, P]
-    when `positions` [b, P] selects the MLM label positions — the standard
-    BERT-pretraining optimization that runs the vocab head on only the ~15%
-    masked positions).  The head gathers the sequence (transpose: the gradient
-    reduce-scatters it back) and keeps logits vocab-sharded [b, *, V/tp] —
-    the [*, V] logits never materialize (the vocab-parallel loss the
-    reference's softmax_with_cross_entropy op cannot express).
+    x_sp is sequence-sharded over tp; labels/mask are FULL [b, S].  ``mask``
+    alone says which positions count (MLM: the predicted positions, causal
+    LM: all ones; a weight other than 0/1 is exact).  With one vocab shard
+    (tp=1) the head computes only the rows whose mask is non-zero
+    (``_chunked_vocab_nll``).  With tp>1 it gathers the sequence (transpose:
+    the gradient reduce-scatters it back), runs on every row and keeps logits
+    vocab-sharded [b, S, V/tp] — the [*, V] logits never materialize (the
+    vocab-parallel loss the reference's softmax_with_cross_entropy op cannot
+    express).
     """
-    x = layer_norm(x_sp, params["lnf_scale"], params["lnf_bias"], fused=False)
-    x = col.all_gather(x, TP, dim=1)                            # [b, S, E]
-    if positions is not None:
-        x = jnp.take_along_axis(x, positions[..., None], axis=1)  # [b, P, E]
     emb = params["tok_emb"]                                     # [V/tp, E] local
     if col.axis_size_in(TP) == 1:
-        # single-shard vocab: streaming chunked softmax (no [b,S,V] tensor)
-        nll = _chunked_vocab_nll(x, emb, labels, 4) * mask
-        total = col.psum(jnp.sum(nll), DP)
+        nll = _chunked_vocab_nll(
+            x_sp.reshape(-1, x_sp.shape[-1]), params["lnf_scale"],
+            params["lnf_bias"], emb, labels.reshape(-1), mask.reshape(-1))
+        total = col.psum(jnp.sum(nll * mask.reshape(-1)), DP)
         count = col.psum(jnp.sum(mask.astype(jnp.float32)), DP)
         return total / jnp.maximum(count, 1.0)
+    x = layer_norm(x_sp, params["lnf_scale"], params["lnf_bias"], fused=False)
+    x = col.all_gather(x, TP, dim=1)                            # [b, S, E]
     logits = (x @ emb.T).astype(jnp.float32)                    # [b, S, V/tp]
     vshard = logits.shape[-1]
     lo = col.axis_index(TP) * vshard

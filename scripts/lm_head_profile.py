@@ -1,0 +1,104 @@
+"""Where the LM head's device time goes, by instruction, and how many rows
+it computed: one cell of the benchmark through the program's normal path.
+
+    chiprun -- python3 scripts/lm_head_profile.py bert_base.s512_scan 7 [--ones]
+
+Builds the cell's trainer and stages its batches as the scan driver does,
+runs one dispatch under a monitor session (the scan driver opens none, so
+this is where ``monitor.train.lm_head_rows_share`` is read on the chip),
+traces one more, and joins the trace with THIS process's scope map
+(``monitor.devscope``; a map compiled elsewhere need not number its
+instructions the same way).  ``--ones`` replaces the mask by all ones, the
+causal-LM shape no cell sends.  Needs a TPU; prints no device number
+otherwise.
+"""
+
+import argparse
+import os
+import re
+import sys
+import tempfile
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser()
+    ap.add_argument("cell")
+    ap.add_argument("seed", type=int)
+    ap.add_argument("--ones", action="store_true")
+    ap.add_argument("--top", type=int, default=30)
+    args = ap.parse_args(argv)
+    sys.path.insert(0, ROOT)
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    if jax.devices()[0].platform != "tpu":
+        print("lm_head_profile: no TPU, nothing to measure", file=sys.stderr)
+        return 2
+
+    from benchmark.drivers import train_scan
+    from benchmark.harness import build, manifest as mf, trace_reduce, tracing
+    from benchmark.harness.cellrun import Ctx
+    from benchmark.harness.spans import Spans
+    from paddle_tpu import compile_cache, monitor
+    from paddle_tpu.monitor import devscope
+
+    compile_cache.place()
+    m = mf.load(ROOT)
+    cell = mf.cell(m, args.cell)
+    ctx = Ctx()
+    ctx.config = mf.read_json(ROOT, mf.config_entry(m, cell["config"])["file"])
+    ctx.traffic = mf.read_json(ROOT, "benchmark", "traffic",
+                               args.cell + ".json")
+    ctx.seed, ctx.spans = args.seed, Spans()
+    ctx.dims = build.cell_dims(ctx.config, ctx.traffic)
+    lr = float(ctx.config["lr"])
+    ctx.trainer = tr = build.build_trainer(
+        ctx.config, ctx.traffic, args.seed, jax.devices()[:cell["chips"]])
+    staged = train_scan._stage(ctx)
+    if args.ones:
+        staged["mask"] = jnp.ones_like(staged["mask"])
+    steps = int(ctx.traffic["staged_batches"])
+
+    with tempfile.TemporaryDirectory() as tmp:
+        mon = monitor.enable(os.path.join(tmp, "monitor"), flight=False)
+        rows0 = mon.registry.counter("monitor.train.lm_head_rows").value
+        np.asarray(tr.run_steps(staged, lr))            # compiles or loads
+        print("rows: monitor.train.lm_head_rows_share %s, "
+              "monitor.train.lm_head_rows %d for %d steps"
+              % (mon.registry.gauge("monitor.train.lm_head_rows_share").value,
+                 mon.registry.counter("monitor.train.lm_head_rows").value
+                 - rows0, steps))
+        monitor.disable()
+        np.asarray(tr.run_steps(staged, lr))
+        tracing._start(os.path.join(tmp, "trace"), 0)
+        losses = np.asarray(tr.run_steps(staged, lr))
+        jax.profiler.stop_trace()
+        dev = trace_reduce.Reduced(trace_reduce.load_xplane(
+            trace_reduce.find_xplane(os.path.join(tmp, "trace")))).devices[0]
+
+    names = next(iter(devscope.scope_maps().values()))
+    rows, totals = [], {}
+    for name, ns in dev["by_name"].items():
+        op = names.get(name, "")
+        key = devscope.classify(op) if op else ("unmapped", None)
+        totals[key] = totals.get(key, 0.0) + ns
+        if "lm_head" in op:
+            rows.append((ns / steps / 1e6, name, key[0],
+                         re.sub(r".*?lm_head\)?/", "", op)[-100:]))
+    print("%s, %s: device busy %.3f ms a step, last loss %.6g"
+          % (args.cell, "mask of ones" if args.ones else "the cell's mask",
+             dev["busy_ns"] / steps / 1e6, losses[-1]))
+    for key, ns in sorted(totals.items(), key=lambda kv: -kv[1])[:14]:
+        print("  %-10s %-12s %8.3f ms a step" % (key + (ns / steps / 1e6,)))
+    print("instructions under lm_head:")
+    for ms, name, phase, op in sorted(rows, reverse=True)[:args.top]:
+        print("  %7.3f ms  %-42s %-8s %s" % (ms, name, phase, op))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

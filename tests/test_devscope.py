@@ -110,9 +110,11 @@ def test_bert_program_that_ran_maps_every_scope_it_uses():
     assert ("optimizer", "optimizer") in got
     assert ("grad_sync", "grad_sync") in got          # dp=2: a real psum
     assert not {s for _, s in got} - set(devscope.VOCABULARY) - {None}
-    # the custom_vjp backward of the chunked vocabulary loss names itself
-    assert any(re.search(r"transpose\(jvp\(lm_head\)\)/lm_head/dot_general",
-                         op) for op in names.values())
+    # the custom_vjp backward of the chunked vocabulary loss names itself,
+    # inside its loop over row blocks
+    assert any(re.search(
+        r"transpose\(jvp\(lm_head\)\)/lm_head/while/body/dot_general", op)
+        for op in names.values())
     # the collective is among the named instructions
     assert any(n.startswith("all-reduce")
                and devscope.classify(op)[0] == "grad_sync"
