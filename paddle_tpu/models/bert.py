@@ -23,12 +23,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import monitor
-from ..monitor import devscope
 from ..parallel import collectives as col
 from ..parallel.mesh import DP, PP, TP, MeshSpec
 from ..parallel.pipeline import gpipe, split_microbatches
 from ..parallel import optim
-from ..parallel.train import TrainState, make_train_step, shard_pytree, state_specs
+from ..parallel.train import (StepTrainer, TrainState, make_train_step,
+                              shard_pytree, state_specs)
 from ..parallel.transformer import (
     TransformerConfig,
     embed,
@@ -92,16 +92,12 @@ def batch_specs(keys=("ids", "labels", "mask")):
 
 
 @dataclasses.dataclass
-class BertTrainer:
-    cfg: TransformerConfig
-    mesh: object
-    state: dict
-    step_fn: object
-    specs: dict
-    multi_fn: object = None
+class BertTrainer(StepTrainer):
     batch_keys: tuple = ("ids", "labels", "mask")
-    # which of the two programs monitor.devscope has been told of
-    _step_seen = _multi_seen = False
+    label = "bert"
+
+    def _observe(self, batch):
+        self._count_head_rows(batch["mask"])
 
     def _count_head_rows(self, mask):
         """Under a monitor session: the rows the LM head computes for these
@@ -120,28 +116,6 @@ class BertTrainer:
         mon.registry.counter("monitor.train.lm_head_rows").incr(int(rows))
         mon.registry.gauge("monitor.train.lm_head_rows_share").set(
             rows / live.size)
-
-    def step(self, batch, lr):
-        self._count_head_rows(batch["mask"])
-        if not self._step_seen:
-            self._step_seen = devscope.register(
-                "bert.step", self.step_fn, (self.state, batch, lr))
-        self.state, loss = self.step_fn(self.state, batch, lr)
-        return loss
-
-    def run_steps(self, batches, lr):
-        """Run N steps in one dispatch (device-side lax.scan loop —
-        train.make_train_step build_multi).  batches: pytree with leading
-        [N] step axis, already staged via parallel.train.stack_batches.
-        Returns losses [N]."""
-        if self.multi_fn is None:
-            raise RuntimeError("trainer built without multi-step support")
-        self._count_head_rows(batches["mask"])
-        if not self._multi_seen:
-            self._multi_seen = devscope.register(
-                "bert.run_steps", self.multi_fn, (self.state, batches, lr))
-        self.state, losses = self.multi_fn(self.state, batches, lr)
-        return losses
 
 
 def build_bert_trainer(cfg, mesh_spec: MeshSpec = None, optimizer=None,

@@ -35,8 +35,11 @@ EMBED, ATTENTION, MLP, LAYER_NORM, LM_HEAD = (
     "embed", "attention", "mlp", "layer_norm", "lm_head")
 CONV, BN, POOL, FC, LOSS = "conv", "bn", "pool", "fc", "loss"
 GRAD_SYNC, OPTIMIZER = "grad_sync", "optimizer"
+# an expert FFN (dispatch, grouped matmuls, combine) and, inside it, its
+# router (logits, softmax, top-k, auxiliary losses)
+MOE, ROUTER = "moe", "router"
 VOCABULARY = (EMBED, ATTENTION, MLP, LAYER_NORM, LM_HEAD, CONV, BN, POOL, FC,
-              LOSS, GRAD_SYNC, OPTIMIZER)
+              LOSS, GRAD_SYNC, OPTIMIZER, MOE, ROUTER)
 PHASES = ("forward", "backward", "recompute", GRAD_SYNC, OPTIMIZER)
 
 # `%fusion.12 = bf16[..] fusion(%p.1, %copy-done.2), ..., metadata={op_name="jit(multi)/..." ...}`:
@@ -88,11 +91,14 @@ def _names(text):
     XLA adds itself (the ``copy-start`` / ``copy-done`` of a prefetch, a
     ``slice-done``, a layout ``copy``) has no ``op_name``: it takes that of
     the first instruction that uses its result, through others of its kind
-    (the wait for a prefetch is the consumer's time)."""
+    (the wait for a prefetch is the consumer's time).  So does what the TPU
+    compiler rewrites into calls of its own and names anew, a lone word
+    with no path (``gather``, ``sort``, ``scatter-add``, ``reduce_sum``):
+    the program's paths always hold a ``/``."""
     names, bare, first_user = {}, [], {}
     for name, rest in _INSTRUCTION.findall(text):
         m = _OP_NAME.search(rest)
-        if m:
+        if m and "/" in m.group(1):
             names[name] = m.group(1)
         else:
             bare.append(name)

@@ -255,7 +255,7 @@ def transformer_rules(cfg):
         return P(*(lead + dims))
 
     return [
-        (r"^tok_emb$", P(TP, None)),                 # vocab-parallel
+        (r"^(tok_emb|lm_head)$", P(TP, None)),       # vocab-parallel
         (r"^pos_emb$|^lnf_", P()),
         (r"/ln[12]_(scale|bias)$", L(None)),
         (r"/(wq|wk|wv|bqkv)$", L(None, tp)),
@@ -264,6 +264,11 @@ def transformer_rules(cfg):
         (r"/(w1)$", L(None, tp)),
         (r"/b1$", L(tp)),
         (r"/w2$", L(tp, None)),
+        # qk_norm and the MoE FFN run at tp == 1 (TransformerConfig): their
+        # leaves are whole on every device
+        (r"/(q_norm|k_norm)$", L(None)),
+        (r"/router$", L(None, None)),
+        (r"/we_(gate_up|down)$", L(None, None, None)),
     ]
 
 
@@ -278,9 +283,12 @@ def deepfm_rules(axis=DP):
 
 
 def moe_rules(ep_axis=DP):
-    """MoE: experts sharded over `ep_axis`, router replicated (its grads
-    must be psum'd over ep)."""
+    """MoE: the Switch layer's experts (``w1``, ``w2``) sharded over
+    `ep_axis`, the router replicated (its grads must be psum'd over ep); the
+    dropless layer's experts (``we_gate_up``, ``we_down``) whole on every
+    device, as it has no expert parallelism yet."""
     return [
         (r"^router$", P()),
         (r"^w[12]$", P(ep_axis)),
+        (r"^we_(gate_up|down)$", P()),
     ]

@@ -11,6 +11,8 @@ pure-pytree optimizer update.  Param broadcast at init (BCastParamsToDevices,
 parallel_executor.cc:630-706) becomes jax.device_put with NamedShardings.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -20,8 +22,8 @@ from .mesh import local_shard_map
 from .. import warm as _warm
 from ..monitor import devscope as _devscope, memscope as _memscope
 
-__all__ = ["TrainState", "make_train_step", "shard_pytree", "stack_batches",
-           "TrainLoop"]
+__all__ = ["TrainState", "make_train_step", "StepTrainer", "shard_pytree",
+           "stack_batches", "TrainLoop"]
 
 
 class TrainState(dict):
@@ -149,6 +151,50 @@ def make_train_step(loss_fn, mesh, param_specs, grad_syncs, optimizer,
 
     build.multi = build_multi
     return build
+
+
+@dataclasses.dataclass
+class StepTrainer:
+    """What a ``build_*_trainer`` over ``make_train_step`` returns: the state
+    on the mesh, the jitted step and its scan.  A model's trainer names its
+    programs (``label``) and may count what a call is about to do
+    (``_observe``, under a monitor session only)."""
+
+    cfg: object
+    mesh: object
+    state: dict
+    step_fn: object
+    specs: dict
+    multi_fn: object = None
+    label = "train"
+    # which of the two programs monitor.devscope has been told of
+    _step_seen = _multi_seen = False
+
+    def _observe(self, batch):
+        """``batch`` as ``step`` or ``run_steps`` got it (the latter's with a
+        leading step axis)."""
+
+    def step(self, batch, lr):
+        self._observe(batch)
+        if not self._step_seen:
+            self._step_seen = _devscope.register(
+                self.label + ".step", self.step_fn, (self.state, batch, lr))
+        self.state, loss = self.step_fn(self.state, batch, lr)
+        return loss
+
+    def run_steps(self, batches, lr):
+        """Run N steps in one dispatch (device-side lax.scan loop —
+        make_train_step build_multi).  batches: pytree with leading [N] step
+        axis, already staged via stack_batches.  Returns losses [N]."""
+        if self.multi_fn is None:
+            raise RuntimeError("trainer built without multi-step support")
+        self._observe(batches)
+        if not self._multi_seen:
+            self._multi_seen = _devscope.register(
+                self.label + ".run_steps", self.multi_fn,
+                (self.state, batches, lr))
+        self.state, losses = self.multi_fn(self.state, batches, lr)
+        return losses
 
 
 class TrainLoop:

@@ -22,6 +22,7 @@ vocab-parallel softmax cross-entropy that never materializes gathered logits.
 """
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -34,7 +35,8 @@ from ..monitor import devscope
 from .ring_attention import ring_attention
 
 __all__ = ["TransformerConfig", "init_transformer_params", "transformer_param_specs",
-           "grad_sync_axes", "embed", "transformer_layer", "final_logits_loss",
+           "grad_sync_axes", "embed", "transformer_layer", "run_layers",
+           "rms_norm", "rope", "final_logits_loss",
            "head_row_block", "head_rows_computed"]
 
 
@@ -53,10 +55,41 @@ class TransformerConfig:
     tp: int = 1                      # tensor-parallel degree (mesh tp axis size)
     pp: int = 1                      # pipeline stages (mesh pp axis size)
     use_flash: bool = True           # Pallas flash-attention kernel when shapes allow
-    flash_block_q: int = 512         # Pallas kernel q/kv block sizes (clamped to S)
+    # Pallas kernel q/kv block sizes, clamped to S.  S = 512 is one block
+    # (the fused one-sweep backward); S = 4096 is 8 x 8 blocks and the
+    # two-sweep backward (flash_bwd_dq, flash_bwd_dkv), causal skipping the
+    # blocks above the diagonal
+    flash_block_q: int = 512
     flash_block_k: int = 512
     scan_unroll: int = 1             # lax.scan unroll over layers (1 = rolled;
     # full unroll turns the per-layer dynamic slices into static ones)
+    # The block's shape.  The defaults are the BERT block (LayerNorm, learned
+    # positions, biases, GELU FFN, head tied to tok_emb); a decoder of the
+    # 2024 kind sets them all (models/olmoe.py).
+    norm: str = "layer"              # "layer" | "rms" (scale only, no bias leaf)
+    norm_eps: float = 1e-6
+    positions: str = "learned"       # "learned" (pos_emb) | "rotary" (no pos_emb)
+    rope_theta: float = 10000.0
+    qk_norm: bool = False            # norm of the whole q / k projection, before the heads
+    bias: bool = True                # biases on the attention and FFN matmuls
+    tie_head: bool = True            # False: the head is its own [V, E] leaf, lm_head
+    # n_experts > 0 replaces the GELU FFN by the dropless top-k MoE of
+    # parallel/moe.py: n_experts gated-SiLU experts of width ffn_hidden
+    n_experts: int = 0
+    experts_per_token: int = 0
+    router_aux_coef: float = 0.0     # x load-balance loss, mean over layers
+    router_z_coef: float = 0.0       # x router z-loss, mean over layers
+
+    def __post_init__(self):
+        assert self.norm in ("layer", "rms") and \
+            self.positions in ("learned", "rotary")
+        if self.qk_norm or self.positions == "rotary" or self.n_experts:
+            # the norm spans the whole projection, rotary positions start at
+            # 0 and the MoE routes the tokens it holds: none is sharded yet
+            assert self.tp == 1 and self.attn_mode == "heads", \
+                "qk_norm, rotary positions and the MoE FFN need tp == 1"
+        if self.n_experts:
+            assert 0 < self.experts_per_token <= self.n_experts
 
     @property
     def head_dim(self):
@@ -84,53 +117,75 @@ def _dense_init(key, fan_in, shape, dtype):
 
 
 def init_transformer_params(key, cfg: TransformerConfig):
+    """The parameter tree of ``cfg``'s block.  Which leaves exist follows the
+    configuration: ``*_bias`` of the norms only with LayerNorm, ``bqkv`` /
+    ``bo`` / ``b1`` / ``b2`` only with ``bias``, ``pos_emb`` only with
+    learned positions, ``q_norm`` / ``k_norm`` with ``qk_norm``, ``lm_head``
+    with an untied head, and the FFN's leaves are either ``w1`` / ``w2`` or
+    the MoE's ``router`` / ``we_gate_up`` / ``we_down`` (parallel/moe.py)."""
     E, F, L, V = cfg.hidden, cfg.ffn_hidden, cfg.n_layers, cfg.vocab_size
     dt = cfg.jdtype
     ks = jax.random.split(key, 12)
 
-    def stack(fn):
-        return jax.vmap(fn)(jax.random.split(ks[0], L))
+    def stack(fold, fan_in, shape, dtype=dt):
+        return jax.vmap(lambda k: _dense_init(
+            jax.random.fold_in(k, fold) if fold else k, fan_in, shape, dtype)
+        )(jax.random.split(ks[0], L))
 
     layer = {
         "ln1_scale": jnp.ones((L, E), jnp.float32),
-        "ln1_bias": jnp.zeros((L, E), jnp.float32),
-        "wq": stack(lambda k: _dense_init(k, E, (E, E), dt)),
-        "wk": stack(lambda k: _dense_init(jax.random.fold_in(k, 1), E, (E, E), dt)),
-        "wv": stack(lambda k: _dense_init(jax.random.fold_in(k, 2), E, (E, E), dt)),
-        "bqkv": jnp.zeros((L, 3, E), dt),
-        "wo": stack(lambda k: _dense_init(jax.random.fold_in(k, 3), E, (E, E), dt)),
-        "bo": jnp.zeros((L, E), dt),
+        "wq": stack(0, E, (E, E)),
+        "wk": stack(1, E, (E, E)),
+        "wv": stack(2, E, (E, E)),
+        "wo": stack(3, E, (E, E)),
         "ln2_scale": jnp.ones((L, E), jnp.float32),
-        "ln2_bias": jnp.zeros((L, E), jnp.float32),
-        "w1": stack(lambda k: _dense_init(jax.random.fold_in(k, 4), E, (E, F), dt)),
-        "b1": jnp.zeros((L, F), dt),
-        "w2": stack(lambda k: _dense_init(jax.random.fold_in(k, 5), F, (F, E), dt)),
-        "b2": jnp.zeros((L, E), dt),
     }
+    if cfg.norm == "layer":
+        layer["ln1_bias"] = jnp.zeros((L, E), jnp.float32)
+        layer["ln2_bias"] = jnp.zeros((L, E), jnp.float32)
+    if cfg.bias:
+        layer["bqkv"] = jnp.zeros((L, 3, E), dt)
+        layer["bo"] = jnp.zeros((L, E), dt)
+    if cfg.qk_norm:
+        layer["q_norm"] = jnp.ones((L, E), jnp.float32)
+        layer["k_norm"] = jnp.ones((L, E), jnp.float32)
+    if cfg.n_experts:
+        n = cfg.n_experts
+        layer["router"] = stack(6, E, (E, n), jnp.float32)
+        layer["we_gate_up"] = stack(7, E, (n, E, 2 * F))
+        layer["we_down"] = stack(8, F, (n, F, E))
+    else:
+        layer["w1"] = stack(4, E, (E, F))
+        layer["w2"] = stack(5, F, (F, E))
+        if cfg.bias:
+            layer["b1"] = jnp.zeros((L, F), dt)
+            layer["b2"] = jnp.zeros((L, E), dt)
     if cfg.pp > 1:
         layer = jax.tree.map(
             lambda x: x.reshape((cfg.pp, cfg.layers_per_stage) + x.shape[1:]), layer
         )
-    return {
+    params = {
         "tok_emb": _dense_init(ks[1], E, (V, E), dt),
-        "pos_emb": _dense_init(ks[2], E, (cfg.max_seq, E), dt),
         "lnf_scale": jnp.ones((E,), jnp.float32),
-        "lnf_bias": jnp.zeros((E,), jnp.float32),
         "params_layers": layer,
     }
+    if cfg.positions == "learned":
+        params["pos_emb"] = _dense_init(ks[2], E, (cfg.max_seq, E), dt)
+    if cfg.norm == "layer":
+        params["lnf_bias"] = jnp.zeros((E,), jnp.float32)
+    if not cfg.tie_head:
+        params["lm_head"] = _dense_init(ks[3], E, (V, E), dt)
+    return params
 
 
-def _param_skeleton():
+def _param_skeleton(cfg: TransformerConfig):
     """The init_transformer_params tree STRUCTURE without arrays — what the
     sharding rules resolve against when no live params exist yet."""
     from .rules import SkeletonLeaf
 
-    layer = {k: SkeletonLeaf() for k in (
-        "ln1_scale", "ln1_bias", "wq", "wk", "wv", "bqkv", "wo", "bo",
-        "ln2_scale", "ln2_bias", "w1", "b1", "w2", "b2")}
-    return {"tok_emb": SkeletonLeaf(), "pos_emb": SkeletonLeaf(),
-            "lnf_scale": SkeletonLeaf(), "lnf_bias": SkeletonLeaf(),
-            "params_layers": layer}
+    shapes = jax.eval_shape(lambda: init_transformer_params(
+        jax.random.PRNGKey(0), cfg))
+    return jax.tree.map(lambda _: SkeletonLeaf(), shapes)
 
 
 def transformer_param_specs(cfg: TransformerConfig, params=None):
@@ -142,7 +197,7 @@ def transformer_param_specs(cfg: TransformerConfig, params=None):
 
     return shard_rules.match_partition_rules(
         shard_rules.transformer_rules(cfg),
-        _param_skeleton() if params is None else params)
+        _param_skeleton(cfg) if params is None else params)
 
 
 def grad_sync_axes(cfg: TransformerConfig):
@@ -211,10 +266,46 @@ def layer_norm(x, scale, bias, eps=1e-6, fused=True):
     return ((xf - mu) * jax.lax.rsqrt(var + eps) * scale + bias).astype(x.dtype)
 
 
+@devscope.scoped(devscope.LAYER_NORM)
+def rms_norm(x, scale, eps=1e-5):
+    """``x * rsqrt(mean(x^2) + eps) * scale`` over the last axis, in float32.
+    Plain XLA: it fuses into the matmul that reads it."""
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(ms + eps) * scale).astype(x.dtype)
+
+
+def _norm(x, pl, name, cfg, fused=True):
+    """The configuration's norm with the leaves ``<name>_scale`` (and
+    ``<name>_bias``, LayerNorm only) of ``pl``."""
+    if cfg.norm == "rms":
+        return rms_norm(x, pl[name + "_scale"], cfg.norm_eps)
+    return layer_norm(x, pl[name + "_scale"], pl[name + "_bias"],
+                      eps=cfg.norm_eps, fused=fused)
+
+
+def rope(x, n_heads, theta=10000.0):
+    """Rotary position embedding on a packed projection x [b, S, H*dh],
+    positions 0..S-1, rotate-half convention (the halves of a head are the
+    pairs): ``x * cos + rotate_half(x) * sin`` with angle
+    ``pos * theta^(-2i/dh)`` for both members of pair i.  float32 inside."""
+    b, S, W = x.shape
+    dh = W // n_heads
+    half = dh // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq[None]
+    cos = jnp.tile(jnp.cos(ang), (1, 2))[None, :, None, :]      # [1,S,1,dh]
+    sin = jnp.tile(jnp.sin(ang), (1, 2))[None, :, None, :]
+    xf = x.astype(jnp.float32).reshape(b, S, n_heads, dh)
+    rot = jnp.concatenate([-xf[..., half:], xf[..., :half]], axis=-1)
+    return (xf * cos + rot * sin).reshape(b, S, W).astype(x.dtype)
+
+
 @devscope.scoped(devscope.EMBED)
 def embed(params, ids, cfg: TransformerConfig, seq_offset=None):
-    """Vocab-parallel embedding lookup + position embedding; returns the
-    sequence-sharded (SP) activation [b, S/tp, E].
+    """Vocab-parallel embedding lookup + position embedding (learned
+    positions; rotary ones are applied to q and k in the block); returns
+    the sequence-sharded (SP) activation [b, S/tp, E].
 
     TP generalization of distributed_lookup_table_op.cc (row-sharded embedding
     over pservers): each tp rank holds a vocab slice, masks out-of-range ids,
@@ -227,6 +318,8 @@ def embed(params, ids, cfg: TransformerConfig, seq_offset=None):
     local = jnp.clip(ids - lo, 0, vshard - 1)
     hit = (ids >= lo) & (ids < lo + vshard)
     emb = params["tok_emb"][local] * hit[..., None].astype(params["tok_emb"].dtype)
+    if cfg.positions != "learned":
+        return col.reduce_scatter(emb, TP, dim=1) if ntp > 1 else emb
     S = ids.shape[1]
     pos = params["pos_emb"][:S][None]
     if ntp > 1:
@@ -239,7 +332,10 @@ def embed(params, ids, cfg: TransformerConfig, seq_offset=None):
 
 def _local_attention_dispatch(q, k, v, cfg):
     """Pick the Pallas flash kernel (multihead_matmul_op.cu parity, trained)
-    when the shapes satisfy TPU tiling; otherwise the XLA blockwise path."""
+    when the shapes satisfy TPU tiling; otherwise the XLA blockwise path.
+    The blocks are clamped to S, so S = 4096 causal runs the kernel on an
+    8 x 8 grid of 512-blocks (those above the diagonal skipped) and S = 100
+    takes the XLA path."""
     S = q.shape[1]
     bq = min(cfg.flash_block_q, S)
     bk = min(cfg.flash_block_k, S)
@@ -259,9 +355,15 @@ def _attention_heads_mode(pl, h_full, cfg):
     dh = cfg.head_dim
 
     # params arrive pre-sharded inside shard_map: wq/bqkv are [E, E/tp]/[3, E/tp]
-    q2 = h_full @ pl["wq"] + pl["bqkv"][0]                      # [b, S, hl*dh]
-    k2 = h_full @ pl["wk"] + pl["bqkv"][1]
-    v2 = h_full @ pl["wv"] + pl["bqkv"][2]
+    q2, k2, v2 = (h_full @ pl[w] for w in ("wq", "wk", "wv"))  # [b, S, hl*dh]
+    if cfg.bias:
+        q2, k2, v2 = (y + pl["bqkv"][i] for i, y in enumerate((q2, k2, v2)))
+    if cfg.qk_norm:             # over the whole projection, before the heads
+        q2 = rms_norm(q2, pl["q_norm"], cfg.norm_eps)
+        k2 = rms_norm(k2, pl["k_norm"], cfg.norm_eps)
+    if cfg.positions == "rotary":
+        q2 = rope(q2, hl, cfg.rope_theta)
+        k2 = rope(k2, hl, cfg.rope_theta)
     bq = min(cfg.flash_block_q, S)
     bk = min(cfg.flash_block_k, S)
     from ..kernels.flash_attention import (flash_attention_packed,
@@ -279,7 +381,7 @@ def _attention_heads_mode(pl, h_full, cfg):
         o = _local_attention_dispatch(q, k, v, cfg).reshape(b, S, hl * dh)
     out = o @ pl["wo"]                                          # row-parallel partial
     out = col.reduce_scatter(out, TP, dim=1)                    # sum + seq scatter
-    return out + pl["bo"]
+    return out + pl["bo"] if cfg.bias else out
 
 
 def _attention_ring_mode(pl, h_sp, cfg):
@@ -288,22 +390,23 @@ def _attention_ring_mode(pl, h_sp, cfg):
     dh = cfg.head_dim
     H = cfg.n_heads
 
-    def proj(w, bias):
-        return (h_sp @ w + bias).reshape(b, Sl, H, dh)
+    def proj(w, i):
+        y = h_sp @ pl[w]
+        return (y + pl["bqkv"][i] if cfg.bias else y).reshape(b, Sl, H, dh)
 
-    q = proj(pl["wq"], pl["bqkv"][0])
-    k = proj(pl["wk"], pl["bqkv"][1])
-    v = proj(pl["wv"], pl["bqkv"][2])
+    q, k, v = proj("wq", 0), proj("wk", 1), proj("wv", 2)
     o = ring_attention(q, k, v, axis=TP, causal=cfg.causal)
-    o = o.reshape(b, Sl, H * dh)
-    return o @ pl["wo"] + pl["bo"]
+    o = o.reshape(b, Sl, H * dh) @ pl["wo"]
+    return o + pl["bo"] if cfg.bias else o
 
 
 def transformer_layer(pl, x_sp, cfg: TransformerConfig):
-    """One pre-LN transformer block on the SP activation [b, S/tp, E]."""
+    """One pre-norm block on the SP activation [b, S/tp, E]: the new
+    activation and the FFN's auxiliary values (the MoE's, ``moe.route_top_k``;
+    None for the dense FFN)."""
     heads_mode = cfg.attn_mode == "heads"
     with jax.named_scope(devscope.ATTENTION):
-        h = layer_norm(x_sp, pl["ln1_scale"], pl["ln1_bias"])
+        h = _norm(x_sp, pl, "ln1", cfg)
         if heads_mode:
             h = col.all_gather(h, TP, dim=1)
             attn = _attention_heads_mode(pl, h, cfg)
@@ -311,30 +414,42 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig):
             attn = _attention_ring_mode(pl, h, cfg)
         x_sp = x_sp + attn
 
+    if cfg.n_experts:
+        with jax.named_scope(devscope.MOE):
+            from .moe import dropless_moe_ffn
+
+            h = _norm(x_sp, pl, "ln2", cfg)
+            y, aux = dropless_moe_ffn(pl, h.reshape(-1, h.shape[-1]),
+                                      cfg.experts_per_token)
+            return x_sp + y.reshape(h.shape), aux
+
     with jax.named_scope(devscope.MLP):
-        h = layer_norm(x_sp, pl["ln2_scale"], pl["ln2_bias"])
+        h = _norm(x_sp, pl, "ln2", cfg)
         if heads_mode:
             h = col.all_gather(h, TP, dim=1)
-        y = _gelu_r(h @ pl["w1"] + pl["b1"])
+        y = h @ pl["w1"]
+        y = _gelu_r(y + pl["b1"] if cfg.bias else y)
         y = y @ pl["w2"]                                        # partial if heads_mode
         if heads_mode:
             y = col.reduce_scatter(y, TP, dim=1)
-        x_sp = x_sp + y + pl["b2"]
-    return x_sp
+        x_sp = x_sp + y
+        if cfg.bias:
+            x_sp = x_sp + pl["b2"]
+    return x_sp, None
 
 
-def run_layers(layer_params, x_sp, cfg: TransformerConfig):
-    """scan over the (local) stacked layers; remat per layer if configured."""
+def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False):
+    """scan over the (local) stacked layers; remat per layer if configured.
+    ``with_aux`` also returns the layers' auxiliary values, stacked [L]
+    (``moe.route_top_k``'s of each MoE layer; None for a dense stack)."""
     body = transformer_layer
     if cfg.remat:
         body = jax.checkpoint(body, static_argnums=(2,))
 
-    def step(x, pl):
-        return body(pl, x, cfg), None
-
-    x_sp, _ = jax.lax.scan(lambda x, pl: step(x, pl), x_sp, layer_params,
-                           unroll=max(int(cfg.scan_unroll), 1))
-    return x_sp
+    x_sp, aux = jax.lax.scan(lambda x, pl: body(pl, x, cfg), x_sp,
+                             layer_params,
+                             unroll=max(int(cfg.scan_unroll), 1))
+    return (x_sp, aux) if with_aux else x_sp
 
 
 _VOCAB_CHUNKS = 4
@@ -397,16 +512,23 @@ def _vocab_chunk(h, emb, labels, lo, sz):
             (labels >= lo) & (labels < lo + sz))
 
 
-def _head_ln(x, scale, bias):
+def _head_norm(norm, x, scale, bias):
+    """The final norm on a block of rows; ``norm`` = (kind, eps), ``bias``
+    None for RMS norm."""
+    kind, eps = norm
+    if kind == "rms":
+        return rms_norm(x, scale, eps)
     # unfused: XLA fuses the norm into the chunk matmuls around it
-    return layer_norm(x, scale, bias, fused=False)
+    return layer_norm(x, scale, bias, eps=eps, fused=False)
 
 
-@jax.custom_vjp
-def _chunked_vocab_nll(x, scale, bias, emb, labels, mask):
-    """Per-row ``nll = logsumexp(LN(x) @ emb.T) - picked`` for the rows whose
-    ``mask`` is non-zero, exactly 0 for the others (single-device vocab,
-    tp=1).  x [N, E], labels and mask [N].
+def _chunked_vocab_nll(x, scale, bias, emb, labels, mask,
+                       norm=("layer", 1e-6)):
+    """Per-row ``nll = logsumexp(norm(x) @ emb.T) - picked`` for the rows
+    whose ``mask`` is non-zero, exactly 0 for the others (single-device
+    vocab, tp=1).  x [N, E], labels and mask [N]; ``emb`` [V, E] is the head
+    matrix, tied or not; ``norm`` = (kind, eps) of the final norm, whose
+    ``bias`` is None for RMS norm.
 
     Only the live rows are computed.  They are moved to the front (a stable
     partition by ``mask != 0``) and the head runs over row blocks of
@@ -418,14 +540,20 @@ def _chunked_vocab_nll(x, scale, bias, emb, labels, mask):
     Inside a block the vocab axis is processed in chunks with a running
     max/sum, so the [R, V] f32 logits never materialize (the flash-attention
     trick applied to the LM head); the backward recomputes each chunk's
-    logits and feeds bf16 gradients to the MXU.
+    logits and feeds their gradients to the MXU in the head matrix's dtype
+    (bf16 at training sizes).
     """
-    nll, _ = _chunked_vocab_nll_fwd(x, scale, bias, emb, labels, mask)
+    return _chunked_nll(norm, x, scale, bias, emb, labels, mask)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _chunked_nll(norm, x, scale, bias, emb, labels, mask):
+    nll, _ = _chunked_vocab_nll_fwd(norm, x, scale, bias, emb, labels, mask)
     return nll
 
 
 @devscope.scoped(devscope.LM_HEAD)
-def _chunked_vocab_nll_fwd(x, scale, bias, emb, labels, mask):
+def _chunked_vocab_nll_fwd(norm, x, scale, bias, emb, labels, mask):
     n = x.shape[0]
     block = head_row_block(n)
     order, inv, count = _live_first(mask)
@@ -434,7 +562,7 @@ def _chunked_vocab_nll_fwd(x, scale, bias, emb, labels, mask):
         nll, lse = carry
         idx, live = _block_rows(i, block, order, count)
         lb = labels[idx]
-        h = _head_ln(x[idx], scale, bias)
+        h = _head_norm(norm, x[idx], scale, bias)
         m_run = jnp.full((block,), -jnp.inf, jnp.float32)
         s_run = jnp.zeros((block,), jnp.float32)
         picked = jnp.zeros((block,), jnp.float32)
@@ -462,25 +590,26 @@ def _chunked_vocab_nll_fwd(x, scale, bias, emb, labels, mask):
 # a custom_vjp backward is traced on its own, in the backward pass: it names
 # its scope itself
 @devscope.scoped(devscope.LM_HEAD)
-def _chunked_vocab_nll_bwd(res, g):
+def _chunked_vocab_nll_bwd(norm, res, g):
     x, scale, bias, emb, labels, order, inv, count, lse = res
     n = x.shape[0]
     block = head_row_block(n)
 
     def body(i, carry):
-        dx, dscale, dbias, demb = carry
+        dx, dnorm, demb = carry
         idx, live = _block_rows(i, block, order, count)
         lb = labels[idx]
         gb = jnp.where(live, g[idx], 0.0)
         lse_b = jax.lax.dynamic_slice_in_dim(lse, i * block, block)
-        h, ln_vjp = jax.vjp(_head_ln, x[idx], scale, bias)
+        h, ln_vjp = jax.vjp(functools.partial(_head_norm, norm), x[idx],
+                            scale, bias)
         dh = jnp.zeros(h.shape, jnp.float32)
         for lo, sz in _vocab_chunks(emb, _VOCAB_CHUNKS):
             w, logits, local, hit = _vocab_chunk(h, emb, lb, lo, sz)
             p = jnp.exp(logits - lse_b[:, None])                # softmax chunk
             onehot = (jax.nn.one_hot(local, sz, dtype=jnp.float32)
                       * hit[:, None].astype(jnp.float32))
-            d = ((p - onehot) * gb[:, None]).astype(jnp.bfloat16)  # [R, sz]
+            d = ((p - onehot) * gb[:, None]).astype(emb.dtype)     # [R, sz]
             dh = dh + jax.lax.dot_general(
                 d, w, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -490,28 +619,30 @@ def _chunked_vocab_nll_bwd(res, g):
             demb = jax.lax.dynamic_update_slice_in_dim(
                 demb, jax.lax.dynamic_slice_in_dim(demb, lo, sz, 0) + dw,
                 lo, 0)
-        dxb, dsb, dbb = ln_vjp(dh.astype(h.dtype))
+        dxb, *dnb = ln_vjp(dh.astype(h.dtype))
         dx = jax.lax.dynamic_update_slice_in_dim(dx, dxb, i * block, 0)
-        return dx, dscale + dsb, dbias + dbb, demb
+        # (scale, bias); an RMS norm's bias is None, an empty pytree
+        return dx, jax.tree.map(jnp.add, dnorm, tuple(dnb)), demb
 
     n_blocks = head_rows_computed(count, n) // block
-    dx, dscale, dbias, demb = jax.lax.fori_loop(0, n_blocks, body, (
+    dx, (dscale, dbias), demb = jax.lax.fori_loop(0, n_blocks, body, (
         jnp.zeros((order.shape[0], x.shape[1]), x.dtype),
-        jnp.zeros(scale.shape, scale.dtype), jnp.zeros(bias.shape, bias.dtype),
+        jax.tree.map(jnp.zeros_like, (scale, bias)),
         jnp.zeros(emb.shape, jnp.float32)))
     # a dead row's place in the compacted order holds the zero it started
     # with, so the way back is a gather through the inverse permutation
     return dx[inv], dscale, dbias, demb.astype(emb.dtype), None, None
 
 
-_chunked_vocab_nll.defvjp(_chunked_vocab_nll_fwd, _chunked_vocab_nll_bwd)
+_chunked_nll.defvjp(_chunked_vocab_nll_fwd, _chunked_vocab_nll_bwd)
 
 
 @devscope.scoped(devscope.LM_HEAD)
 def final_logits_loss(params, x_sp, labels, mask, cfg: TransformerConfig):
-    """Softmax cross-entropy with the tied embedding head, averaged over the
-    positions ``mask`` weights: ``sum(nll * mask) / max(sum(mask), 1)`` over
-    the dp-sharded global batch.
+    """Softmax cross-entropy with the LM head (``tok_emb``, or the head's
+    own ``lm_head`` where ``cfg.tie_head`` is off) on the configuration's
+    final norm, averaged over the positions ``mask`` weights:
+    ``sum(nll * mask) / max(sum(mask), 1)`` over the dp-sharded global batch.
 
     x_sp is sequence-sharded over tp; labels/mask are FULL [b, S].  ``mask``
     alone says which positions count (MLM: the predicted positions, causal
@@ -523,15 +654,16 @@ def final_logits_loss(params, x_sp, labels, mask, cfg: TransformerConfig):
     vocab-parallel loss the reference's softmax_with_cross_entropy op cannot
     express).
     """
-    emb = params["tok_emb"]                                     # [V/tp, E] local
+    emb = params["tok_emb" if cfg.tie_head else "lm_head"]      # [V/tp, E] local
     if col.axis_size_in(TP) == 1:
         nll = _chunked_vocab_nll(
             x_sp.reshape(-1, x_sp.shape[-1]), params["lnf_scale"],
-            params["lnf_bias"], emb, labels.reshape(-1), mask.reshape(-1))
+            params.get("lnf_bias"), emb, labels.reshape(-1),
+            mask.reshape(-1), norm=(cfg.norm, cfg.norm_eps))
         total = col.psum(jnp.sum(nll * mask.reshape(-1)), DP)
         count = col.psum(jnp.sum(mask.astype(jnp.float32)), DP)
         return total / jnp.maximum(count, 1.0)
-    x = layer_norm(x_sp, params["lnf_scale"], params["lnf_bias"], fused=False)
+    x = _norm(x_sp, params, "lnf", cfg, fused=False)
     x = col.all_gather(x, TP, dim=1)                            # [b, S, E]
     logits = (x @ emb.T).astype(jnp.float32)                    # [b, S, V/tp]
     vshard = logits.shape[-1]

@@ -1,0 +1,214 @@
+"""The dropless top-k MoE layer (``parallel/moe.py``) against the dense
+all-experts formula it must equal: every expert evaluated on every token,
+the k largest router probabilities as weights, zero elsewhere.  float32 on
+the CPU, so the tolerance can be 1e-5."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.parallel import moe, rules
+
+N, E, F = 8, 16, 8
+TOL = dict(rtol=1e-5, atol=1e-5)
+
+
+def _dense(params, x, k):
+    probs = jax.nn.softmax(x @ params["router"], axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, k)
+    weight = (jax.nn.one_hot(top_e, N) * top_p[..., None]).sum(1)   # [T, n]
+    gu = jnp.einsum("te,nef->ntf", x, params["we_gate_up"])
+    out = jnp.einsum("ntf,nfe->nte", jax.nn.silu(gu[..., :F]) * gu[..., F:],
+                     params["we_down"])
+    return jnp.einsum("nte,tn->te", out, weight)
+
+
+def _case(name):
+    """(params, x, k) of a routing the sort must survive."""
+    ks = jax.random.split(jax.random.PRNGKey(sum(map(ord, name))), 2)
+    params = moe.init_dropless_moe_params(ks[0], N, E, F)
+    tokens, k = {"top_1": (12, 1), "top_2": (12, 2), "top_8_of_8": (12, 8),
+                 "every_token_to_one_expert": (10, 1),
+                 "an_expert_with_no_token": (16, 2),
+                 "odd_number_of_assignments": (7, 3),
+                 "non_uniform_router": (33, 2)}[name]
+    x = jax.random.normal(ks[1], (tokens, E), jnp.float32)
+    router = params["router"]
+    if name == "every_token_to_one_expert":
+        x = jnp.abs(x) + 0.1                       # expert 3 wins every token
+        router = jnp.zeros_like(router).at[:, 3].set(5.0)
+    elif name == "an_expert_with_no_token":
+        x = jnp.abs(x) + 0.1                       # expert 5 loses every token
+        router = router.at[:, 5].set(-5.0)
+    elif name == "non_uniform_router":
+        router = router * 6.0                      # peaky: a few experts busy
+    return dict(params, router=router), x, k
+
+
+CASES = ("top_1", "top_2", "top_8_of_8", "every_token_to_one_expert",
+         "an_expert_with_no_token", "odd_number_of_assignments",
+         "non_uniform_router")
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_layer_equals_the_dense_formula(name):
+    params, x, k = _case(name)
+    y, aux = jax.jit(moe.dropless_moe_ffn, static_argnums=2)(params, x, k)
+    np.testing.assert_allclose(y, _dense(params, x, k), **TOL)
+    counts = np.bincount(np.asarray(jax.lax.top_k(
+        jax.nn.softmax(x @ params["router"]), k)[1]).ravel(), minlength=N)
+    assert counts.sum() == x.shape[0] * k          # nothing dropped
+    if name == "every_token_to_one_expert":
+        assert counts[3] == x.shape[0] and float(aux["load_max_over_mean"]) == N
+    if name == "an_expert_with_no_token":
+        assert counts[5] == 0
+    np.testing.assert_allclose(aux["load_max_over_mean"],
+                               counts.max() / counts.mean(), rtol=1e-6)
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_gradients_equal_the_dense_formulas(name):
+    """Every input's gradient: the hand-written backward of the dispatch and
+    the combine (gathers by the inverse permutation, where autodiff would
+    scatter) and the grouped matmul's (``gmm`` transposed for dX, ``tgmm``
+    for dW)."""
+    params, x, k = _case(name)
+    probe = jax.random.normal(jax.random.PRNGKey(9), x.shape, jnp.float32)
+
+    def scalar(fn):
+        return lambda p, x: jnp.sum(fn(p, x) * probe)
+
+    got = jax.jit(jax.grad(scalar(
+        lambda p, x: moe.dropless_moe_ffn(p, x, k)[0]), argnums=(0, 1)))(params, x)
+    want = jax.grad(scalar(lambda p, x: _dense(p, x, k)), argnums=(0, 1))(params, x)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, **TOL)
+
+
+# -- the kernels at their real tiling ------------------------------------------
+# Above, every case is one whole-dimension tile.  At training sizes the
+# megablox kernels run 512 x 1024 x 1024 tiles (``moe._tiling``): a row tile
+# holds the end of one group and the start of the next, a group spans tiles,
+# the last row tile is padding past the groups (``_whole_row_tiles``), and a
+# contraction or a column dimension of 2048 is two tiles.  Interpret mode
+# runs the same grid and index maps on the CPU.
+
+# rows, K, N, group sizes (their sum is the rows: nothing dropped)
+TILED = {
+    # 1,300 rows in three row tiles (236 rows of padding), whole K and N
+    "groups_straddle_row_tiles": (1300, 16, 24,
+                                  (0, 700, 3, 0, 88, 509)),
+    # two tiles in K and in N as well, a group boundary ON a tile's edge
+    "two_tiles_each_way": (1100, 2048, 2048, (512, 0, 1, 587)),
+    # fewer rows than one tile and an empty first and last group
+    "one_short_tile": (520, 1536, 1024, (0, 519, 1, 0)),
+}
+
+
+def _tiled_case(name):
+    m, k, n, sizes = TILED[name]
+    assert sum(sizes) == m and moe._tiling(m, k, n) == (
+        512, min(k, 1024), min(n, 1024))
+    ks = jax.random.split(jax.random.PRNGKey(len(name)), 3)
+    rows = jax.random.normal(ks[0], (m, k), jnp.float32)
+    weights = jax.random.normal(ks[1], (len(sizes), k, n), jnp.float32) * k ** -0.5
+    probe = jax.random.normal(ks[2], (m, n), jnp.float32)
+    return rows, weights, jnp.asarray(sizes, jnp.int32), probe
+
+
+def _row_by_row(rows, weights, sizes):
+    """Row i times the weights of its own group, one dense matmul a group."""
+    group = np.repeat(np.arange(len(sizes)), np.asarray(sizes))
+    per_group = jnp.einsum("mk,gkn->gmn", rows, weights,
+                           precision=jax.lax.Precision.HIGHEST)
+    return per_group[group, jnp.arange(rows.shape[0])]
+
+
+@pytest.mark.parametrize("name", TILED)
+def test_grouped_matmul_at_the_real_tiling(name):
+    """``gmm`` forward, and ``gmm`` transposed and ``tgmm`` as the backward,
+    against the dense formula and ITS gradients."""
+    rows, weights, sizes, probe = _tiled_case(name)
+    tol = dict(rtol=2e-5, atol=2e-5)
+    got = jax.jit(moe._grouped_matmul)(rows, weights, sizes)
+    np.testing.assert_allclose(got, _row_by_row(rows, weights, sizes), **tol)
+    d_rows, d_weights = jax.jit(jax.grad(
+        lambda r, w: jnp.sum(moe._grouped_matmul(r, w, sizes) * probe),
+        argnums=(0, 1)))(rows, weights)
+    want_rows, want_weights = jax.grad(
+        lambda r, w: jnp.sum(_row_by_row(r, w, sizes) * probe),
+        argnums=(0, 1))(rows, weights)
+    np.testing.assert_allclose(d_rows, want_rows, **tol)
+    # dW sums over a group's rows: up to 700 terms of size one
+    np.testing.assert_allclose(d_weights, want_weights, rtol=2e-5, atol=2e-4)
+    # an empty group's weights get a gradient of exactly zero, not a stale tile
+    for g, size in enumerate(TILED[name][3]):
+        assert size or not np.asarray(d_weights[g]).any()
+
+
+def test_layer_over_several_row_tiles_equals_the_dense_formula():
+    """The whole layer on 656 tokens, top-2: 1,312 sorted rows in three row
+    tiles; the tokens share an offset, which the router turns into a bias per
+    expert, so the groups are uneven and straddle the tiles; output and every
+    gradient."""
+    ks = jax.random.split(jax.random.PRNGKey(27), 3)
+    params = moe.init_dropless_moe_params(ks[0], N, E, F)
+    x = jax.random.normal(ks[1], (656, E), jnp.float32) + 1.0
+    probe = jax.random.normal(ks[2], x.shape, jnp.float32)
+    counts = np.bincount(np.asarray(jax.lax.top_k(
+        jax.nn.softmax(x @ params["router"]), 2)[1]).ravel(), minlength=N)
+    edges = np.cumsum(counts)[:-1]
+    assert counts.sum() == 1312 and (edges % 512 != 0).all()
+    assert counts.max() > 2 * counts.min()
+    y, _ = jax.jit(moe.dropless_moe_ffn, static_argnums=2)(params, x, 2)
+    np.testing.assert_allclose(y, _dense(params, x, 2), **TOL)
+    got = jax.jit(jax.grad(lambda p, x: jnp.sum(
+        moe.dropless_moe_ffn(p, x, 2)[0] * probe), argnums=(0, 1)))(params, x)
+    want = jax.grad(lambda p, x: jnp.sum(_dense(p, x, 2) * probe),
+                    argnums=(0, 1))(params, x)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-4)
+
+
+def test_auxiliary_values_and_their_gradient():
+    params, x, k = _case("non_uniform_router")
+    _, _, aux = moe.route_top_k(params["router"], x, k)
+    logits = np.asarray(x @ params["router"], np.float64)
+    lse = np.log(np.exp(logits).sum(-1))
+    probs = np.exp(logits - lse[:, None])
+    chosen = np.argsort(-probs, axis=-1)[:, :k]
+    share = np.bincount(chosen.ravel(), minlength=N) / chosen.size
+    np.testing.assert_allclose(aux["load_balance"],
+                               N * (share * probs.mean(0)).sum(), rtol=1e-5)
+    np.testing.assert_allclose(aux["router_z"], (lse ** 2).mean(), rtol=1e-5)
+    # a uniform router balances exactly: lb = 1, z = log(n)^2
+    _, _, flat = moe.route_top_k(jnp.zeros_like(params["router"]), x, k)
+    np.testing.assert_allclose(flat["load_balance"], 1.0, rtol=1e-6)
+    np.testing.assert_allclose(flat["router_z"], np.log(N) ** 2, rtol=1e-6)
+    # the counts carry no gradient; the mean probabilities and the z do
+    g = jax.grad(lambda r: sum(
+        moe.route_top_k(r, x, k)[2][n] for n in ("load_balance", "router_z")
+    ))(params["router"])
+    assert np.isfinite(g).all() and np.abs(g).max() > 0
+
+
+def test_the_switch_layer_still_runs_and_drops():
+    """The top-1 capacity layer kept for the expert-parallel dry run: a
+    token over its expert's capacity comes back as zero."""
+    params = moe.init_moe_params(jax.random.PRNGKey(0), 4, E, F)
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (16, E))) + 0.1
+    crowded = dict(params, router=jnp.zeros((E, 4)).at[:, 2].set(5.0))
+    y = moe.switch_moe_ffn(crowded, x, ep_axis=None, capacity_factor=1.0)
+    kept = np.abs(np.asarray(y)).sum(-1) > 0
+    assert kept.sum() == 4 and kept[:4].all()      # capacity 16 / 4
+
+
+def test_rule_trees_cover_both_layers():
+    leaf = rules.SkeletonLeaf
+    specs = rules.match_partition_rules(rules.moe_rules("dp"), {
+        "router": leaf(), "w1": leaf(), "w2": leaf(),
+        "we_gate_up": leaf(), "we_down": leaf()})
+    assert specs["w1"] == specs["w2"] == jax.sharding.PartitionSpec("dp")
+    assert specs["we_gate_up"] == specs["we_down"] == specs["router"] \
+        == jax.sharding.PartitionSpec()

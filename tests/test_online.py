@@ -392,8 +392,10 @@ def test_swap_refused_when_not_serving_or_already_pending(artifact):
     rng = np.random.RandomState(8)
     with eng:
         # a multi-step request holds the loop busy: the swap stays PENDING
-        # (not yet applied) until the in-flight set drains
-        big = eng.submit({"x": rng.rand(400, 12).astype("f4")})
+        # (not yet applied) until the in-flight set drains.  15,000 steps,
+        # about 0.8 s: 100 steps drain in 5 ms, before the swap thread has
+        # run on a loaded host (6 xdist workers), and the swap applies at once
+        big = eng.submit({"x": rng.rand(60000, 12).astype("f4")})
         while eng.stats.registry.counter("swap_refuse.admitted").value < 1:
             time.sleep(0.001)
         t = threading.Thread(target=lambda: results.append(
