@@ -6,12 +6,26 @@ ir/multihead_matmul_fuse_pass.cc) — but trained-path capable: blockwise
 streaming softmax never materializes the [S, S] score matrix in HBM, so both
 memory and HBM traffic drop from O(S^2) to O(S * block).
 
-Layout: q, k, v are [BH, S, D] (batch*heads flattened).  Grid is
-(BH, q_blocks, kv_blocks) with the kv axis innermost; the running max (m),
+Two layouts, one set of kernels (``_Geom``): the packed [B, S, H*D] entry the
+models use (``flash_attention_packed``: the projections' own layout, each
+128-lane head-block addressed in place by the BlockSpec index maps) and
+[BH, S, D] behind ``flash_attention`` for head shapes the packed layout
+cannot tile.  Grid is (row-groups x head-block-groups, q_blocks, kv_blocks),
+kv innermost.
+
+Several kv blocks (S above the block size): the running max (m),
 denominator (l) and output accumulator live in VMEM scratch across the kv
-sweep (the standard TPU flash schedule).  The backward pass recomputes
-probabilities blockwise from the saved row logsumexp L (two kernels: a dq
-sweep and a dk/dv sweep), per the FlashAttention-2 formulation.
+sweep (the standard TPU flash schedule), and the backward recomputes the
+probabilities blockwise from the saved row logsumexp in two kernels
+(``flash_bwd_dq``, ``flash_bwd_dkv``: FlashAttention-2).  One kv block (S up
+to the block size, every BERT shape): the forward needs no running
+statistics and the backward is ONE kernel (``flash_bwd_fused``) that shares
+one recomputed probability tile between dq, dk and dv.  There, where the
+whole sequence is one block, a grid step carries a fixed amount of work
+whatever S is: ``step_geometry`` packs G batch rows and Hg head-blocks into
+the step's blocks; the kernel bodies loop over the rows and unroll over the
+head-blocks (the compiler interleaves the independent heads), each
+(row, head) computed exactly as a step of its own would.
 
 All matmuls feed the MXU in the input dtype with f32 accumulation.
 interpret=True (CPU tests) is selected automatically off-TPU.
@@ -54,16 +68,226 @@ def packed_layout_supported(n_heads, head_dim):
     return (head_dim * hpb) % LANES == 0 and n_heads % hpb == 0
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-                scale, causal, bq, bk, hpb=1):
-    """hpb = heads per block.  The packed [B, S, H*D] layout needs 128-wide
-    lane blocks (Mosaic tiling rule), so for D=64 each kernel instance
-    processes 2 adjacent heads: the block's columns are per-head slices and
-    every head keeps independent running stats.  hpb=1 is the [BH, S, D]
-    layout.  Heads never mix: each dot contracts only its own D columns."""
+def _heads_per_block(D):
+    """Packed layout: Mosaic requires the last block dim be a multiple of 128
+    (or the full array dim), so D=64 heads pair up 2-per-block; D>=128 heads
+    stand alone."""
+    return max(1, LANES // D)
+
+
+# ---------------------------------------------------------------------------
+# geometry of one grid step
+# ---------------------------------------------------------------------------
+
+STEP_ROWS = 512              # rows of 128 lanes per operand that one grid step
+                             # should move: what a step at S=512 moves
+STEP_HEAD_BLOCKS = 3         # head-blocks a step holds at most.  The bodies
+                             # unroll over them and the compiler interleaves
+                             # the independent heads' matmuls, exp and
+                             # reductions, which is where most of the gain at
+                             # S=128 comes from (one head-block: a chain of
+                             # dependent steps, latencies exposed).  B=256,
+                             # S=128, 12 heads of 64 on a v5e, forward /
+                             # backward us a layer: 1 head-block 1125 / 1514,
+                             # 2: 632 / 1214, 3: 602 / 1140, 6: 787 / 1059;
+                             # the same six as separate loops 918 / 1344, so
+                             # it is the unrolling, not the wider DMA
+VMEM_BUDGET = 12 * 2 ** 20   # bytes one step may hold: the double-buffered
+                             # operand and statistics blocks of the widest
+                             # kernel (flash_bwd_fused) and its live f32 tiles;
+                             # under the 16 MiB Mosaic scopes by default
+
+
+def _divisors(n):
+    return [d for d in range(1, n + 1) if n % d == 0]
+
+
+def step_vmem_bytes(G, S, Hg, lanes, itemsize):
+    """What one grid step of ``flash_bwd_fused`` (eight operand blocks; the
+    forward has four) holds in VMEM at blocks of G rows x S x Hg*lanes."""
+    width = Hg * max(lanes, LANES)                 # narrow blocks pad to a tile
+    operands = 8 * 2 * G * S * width * itemsize    # double-buffered
+    stats = 2 * G * S * LANES * 4                  # [G, S, <=128] f32, padded
+    tiles = 4 * S * S * 4 + 4 * S * LANES * 4      # s, p, dov, ds; dq/dk/dv
+    return operands + stats + tiles
+
+
+def step_geometry(B, S, n_head_blocks, lanes, itemsize):
+    """(G, Hg): the batch rows and the head-blocks (``lanes`` wide) that one
+    grid step holds where the whole sequence is one block.  From the shapes
+    alone: head-blocks of one row first, at most STEP_HEAD_BLOCKS (the
+    kernel bodies unroll over them), then rows (a loop), until the step
+    moves STEP_ROWS rows of 128 lanes per operand; G divides B and Hg the
+    head-blocks (so 1 for a prime B), and the step stays under VMEM_BUDGET.
+    S=512: (1, 1), the step as it always was."""
+    def fits(G, Hg):
+        return step_vmem_bytes(G, S, Hg, lanes, itemsize) <= VMEM_BUDGET
+
+    def enough(G, Hg):
+        return G * Hg * S * max(lanes, LANES) >= STEP_ROWS * LANES
+
+    G = Hg = 1
+    for h in _divisors(n_head_blocks):
+        if enough(G, Hg) or h > STEP_HEAD_BLOCKS or not fits(G, h):
+            break
+        Hg = h
+    for g in _divisors(B):
+        if enough(G, Hg) or not fits(g, Hg):
+            break
+        G = g
+    return G, Hg
+
+
+def grid_geometry(B, S, Sk, n_head_blocks, lanes, itemsize, bq, bk):
+    """(G, Hg, grid steps along the batch/head axis) for blocks of bq x bk:
+    ``step_geometry`` where the sequence is one block both ways, one
+    (row, head-block) pair a step otherwise."""
+    G, Hg = (step_geometry(B, max(S, Sk), n_head_blocks, lanes, itemsize)
+             if S == bq and Sk == bk else (1, 1))
+    return G, Hg, (B // G) * (n_head_blocks // Hg)
+
+
+def packed_grid(B, S, n_heads, head_dim, block_q, block_k, itemsize=2):
+    """What ``flash_attention_packed`` runs for these shapes, for whoever
+    wants to say so without tracing it (the trainers' monitor gauges):
+    (pairs per grid step, grid steps of one layer's pass)."""
+    hpb = _heads_per_block(head_dim)
+    bq, bk = min(block_q, S), min(block_k, S)
+    G, Hg, steps = grid_geometry(B, S, S, n_heads // hpb, head_dim * hpb,
+                                 itemsize, bq, bk)
+    return G * Hg, steps * (S // bq)
+
+
+class _Geom:
+    """Grid/block geometry for the two layouts.  H=None: [BH, S, D]
+    separate-heads.  H=int: packed [B, S, H*D] — per-head column slices are
+    addressed by the BlockSpec index maps, so the model never materializes a
+    [B, H, S, D] transpose (the r2 wrapper's main HBM cost).  A block is G
+    rows of the leading axis by Hg head-blocks (``grid_geometry``; 1 by 1
+    wherever the sequence is more than one block)."""
+
+    def __init__(self, q, k, H, bq, bk):
+        B, self.S, E = q.shape
+        self.Sk = k.shape[1]
+        if H is None:
+            self.D, self.hpb, self.Hb = E, 1, 1
+        else:
+            self.D = E // H
+            self.hpb = _heads_per_block(self.D)
+            assert H % self.hpb == 0 and (self.D * self.hpb) % LANES == 0, (H, self.D)
+            self.Hb = H // self.hpb   # head-blocks per batch row
+        self.qw = self.D * self.hpb   # width of one head-block (lane dim)
+        self.G, self.Hg, self.grid_b = grid_geometry(
+            B, self.S, self.Sk, self.Hb, self.qw, q.dtype.itemsize, bq, bk)
+        self.o_shape = q.shape
+        self.dkv_shape = k.shape
+        # stats are 4-D so the block's last dim equals the array's (Mosaic
+        # tiling rule): [row, head-block group, S, heads of the group]
+        self.stat_shape = (B, self.Hb // self.Hg, self.S, self.Hg * self.hpb)
+
+    # index maps: 3-arg (b, i, j) with i indexing q rows, j kv rows; b runs
+    # over (row group, head-block group), head-block groups fastest
+    def qmap(self):
+        n = self.Hb // self.Hg
+        return lambda b, i, j=0: (b // n, i, b % n)
+
+    def kmap(self):
+        n = self.Hb // self.Hg
+        return lambda b, i, j=0: (b // n, j, b % n)
+
+    def smap(self):
+        n = self.Hb // self.Hg
+        return lambda b, i, j=0: (b // n, b % n, i, 0)
+
+    def q_spec(self, bq, index_map=None):
+        return pl.BlockSpec((self.G, bq, self.Hg * self.qw),
+                            index_map or self.qmap())
+
+    def kv_spec(self, bk, index_map=None):
+        return pl.BlockSpec((self.G, bk, self.Hg * self.qw),
+                            index_map or self.kmap())
+
+    def stat_spec(self, bq, index_map=None):
+        return pl.BlockSpec((self.G, 1, bq, self.Hg * self.hpb),
+                            index_map or self.smap())
+
+
+def _rows(G, row):
+    """Run row(g) for the G rows of a block, in turn: a loop with a dynamic
+    leading index, so the body is compiled once however many rows."""
+    if G == 1:
+        row(0)
+    else:
+        def step(g, carry):
+            row(g)
+            return carry
+        jax.lax.fori_loop(0, G, step, 0)
+
+
+def _cat(cols):
+    """The heads of one head-block side by side."""
+    return cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
+
+
+def _scores(q, k, scale, causal, q0, k0):
+    """[bq, bk] f32 scaled scores of one head, future positions masked."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if causal:
+        qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(qpos >= kpos, s, NEG_INF)
+    return s
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
+                scale, causal, bq, bk, hpb, nk, G, Hg):
+    """hpb = heads per head-block.  The packed [B, S, H*D] layout needs
+    128-wide lane blocks (Mosaic tiling rule), so for D=64 a head-block is 2
+    adjacent heads: its columns are per-head slices and every head keeps
+    independent statistics.  hpb=1 is the [BH, S, D] layout.  Heads never
+    mix: each dot contracts only its own D columns.
+
+    The block is [G, bq, Hg*hpb*D]: G rows by Hg head-blocks, every
+    (row, head) computed on its own."""
+    D = q_ref.shape[-1] // (Hg * hpb)
+    i = pl.program_id(1)
+
+    if nk == 1:
+        # the whole of K/V is in the block: softmax in one pass, no running
+        # statistics (the numbers are the sweep's own: its first block meets
+        # m = -inf, l = 0, acc = 0)
+        def row(g):
+            for hb in range(Hg):
+                cols = pl.ds(hb * hpb * D, hpb * D)
+                qb, kb, vb = q_ref[g, :, cols], k_ref[g, :, cols], v_ref[g, :, cols]
+                out = []
+                for hh in range(hpb):
+                    cs = slice(hh * D, (hh + 1) * D)
+                    s = _scores(qb[:, cs], kb[:, cs], scale, causal, i * bq, 0)
+                    m = jnp.max(s, axis=1)[:, None]            # [bq, 1]
+                    p = jnp.exp(s - m)                          # [bq, bk] f32
+                    l = jnp.maximum(jnp.sum(p, axis=1)[:, None], 1e-30)
+                    out.append(jax.lax.dot_general(
+                        p.astype(vb.dtype), vb[:, cs],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32) / l)
+                    # lse rides a [bq, heads] lane-narrow block, a column a
+                    # head: the DMA transfers only the valid lanes, and no
+                    # in-kernel transpose is needed (a lane-replicated
+                    # [bq, 128] output costs ~150MB/layer of HBM traffic at
+                    # bench shapes; a lane-oriented [1, bq] output costs a
+                    # Mosaic relayout per block — both measured slower)
+                    lse_ref[g, 0, :, pl.ds(hb * hpb + hh, 1)] = m + jnp.log(l)
+                o_ref[g, :, cols] = _cat(out).astype(o_ref.dtype)
+
+        _rows(G, row)
+        return
+
+    # several kv blocks (one pair a step): running max, denominator and
+    # accumulator in scratch across the kv sweep
+    m_scr, l_scr, acc_scr = scratch
     j = pl.program_id(2)
-    nk = pl.num_programs(2)
-    D = q_ref.shape[-1] // hpb
 
     @pl.when(j == 0)
     def _init():
@@ -71,7 +295,6 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    i = pl.program_id(1)
     run = True
     if causal:
         # whole kv block strictly in the future -> skip
@@ -82,16 +305,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         for hh in range(hpb):
             cs = slice(hh * D, (hh + 1) * D)
             ls = slice(hh * LANES, (hh + 1) * LANES)
-            q = q_ref[0][:, cs]                            # [bq, D]
-            k = k_ref[0][:, cs]                            # [bk, D]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                                      # [bq, bk]
-            if causal:
-                qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-                kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-                s = jnp.where(qpos >= kpos, s, NEG_INF)
+            s = _scores(q_ref[0][:, cs], k_ref[0][:, cs], scale, causal,
+                        i * bq, j * bk)                    # [bq, bk]
 
             m_prev = m_scr[:, ls]                          # [bq, LANES]
             m_cur = jnp.max(s, axis=1)[:, None]            # [bq, 1]
@@ -114,90 +329,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
             [_lanes_to(l[:, hh * LANES:(hh + 1) * LANES], D)
              for hh in range(hpb)], axis=1) if hpb > 1 else _lanes_to(l, D)
         o_ref[0] = (acc_scr[:] / alpha_cols).astype(o_ref.dtype)
-        # lse rides a [bq, hpb] lane-narrow block: the DMA transfers only the
-        # valid lanes, and no in-kernel transpose is needed (a lane-replicated
-        # [bq, 128] output costs ~150MB/layer of HBM traffic at bench shapes;
-        # a lane-oriented [1, bq] output costs a Mosaic relayout per block —
-        # both measured slower than this form)
         lse_ref[0, 0] = jnp.concatenate(
             [m_scr[:, hh * LANES:hh * LANES + 1]
              + jnp.log(l[:, hh * LANES:hh * LANES + 1]) for hh in range(hpb)],
             axis=1)
 
 
-def _heads_per_block(D):
-    """Packed layout: Mosaic requires the last block dim be a multiple of 128
-    (or the full array dim), so D=64 heads pair up 2-per-block; D>=128 heads
-    stand alone."""
-    return max(1, LANES // D)
-
-
-class _Geom:
-    """Grid/block geometry for the two layouts.  H=None: [BH, S, D]
-    separate-heads.  H=int: packed [B, S, H*D] — per-head column slices are
-    addressed by the BlockSpec index maps, so the model never materializes a
-    [B, H, S, D] transpose (the r2 wrapper's main HBM cost)."""
-
-    def __init__(self, q, k, H):
-        if H is None:
-            self.BH, self.S, self.D = q.shape
-            self.hpb = 1
-            self.qw = self.D          # block width (lane dim)
-            self.o_shape = q.shape
-            # stats are 4-D so the block's last dim equals the array's
-            # (Mosaic tiling rule): [outer, head-block, S, heads-per-block]
-            self.stat_shape = (self.BH, 1, self.S, 1)
-            self.dkv_shape = k.shape
-            self.grid_b = self.BH
-            self.Hb = None
-        else:
-            B, self.S, E = q.shape
-            self.D = E // H
-            self.hpb = _heads_per_block(self.D)
-            assert H % self.hpb == 0 and (self.D * self.hpb) % LANES == 0, (H, self.D)
-            self.qw = self.D * self.hpb
-            self.o_shape = q.shape
-            self.Hb = H // self.hpb   # head-blocks per batch
-            self.stat_shape = (B, self.Hb, self.S, self.hpb)
-            self.dkv_shape = k.shape
-            self.grid_b = B * self.Hb
-        self.Sk = k.shape[1]
-
-    # index maps: 3-arg (b, i, j) with i indexing q rows, j kv rows
-    def qmap(self):
-        Hb = self.Hb
-        if Hb is None:
-            return lambda b, i, j=0: (b, i, 0)
-        return lambda b, i, j=0: (b // Hb, i, b % Hb)
-
-    def kmap(self):
-        Hb = self.Hb
-        if Hb is None:
-            return lambda b, i, j=0: (b, j, 0)
-        return lambda b, i, j=0: (b // Hb, j, b % Hb)
-
-    def smap(self):
-        Hb = self.Hb
-        if Hb is None:
-            return lambda b, i, j=0: (b, 0, i, 0)
-        return lambda b, i, j=0: (b // Hb, b % Hb, i, 0)
-
-    def q_spec(self, bq):
-        return pl.BlockSpec((1, bq, self.qw), self.qmap())
-
-    def kv_spec(self, bk):
-        return pl.BlockSpec((1, bk, self.qw), self.kmap())
-
-    def stat_spec(self, bq):
-        return pl.BlockSpec((1, 1, bq, self.hpb), self.smap())
-
-
 def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None):
     """H=None: q/k/v are [BH, S, D].  H=int: q/k/v are [B, S, H*D]."""
-    g = _Geom(q, k, H)
+    g = _Geom(q, k, H, bq, bk)
     nq, nk = g.S // bq, g.Sk // bk
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               bq=bq, bk=bk, hpb=g.hpb)
+                               bq=bq, bk=bk, hpb=g.hpb, nk=nk, G=g.G, Hg=g.Hg)
     o, lse = pl.pallas_call(
         kernel,
         grid=(g.grid_b, nq, nk),
@@ -208,14 +351,14 @@ def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None):
         ],
         out_specs=[
             g.q_spec(bq),
-            # row stats as narrow-lane blocks (see _final)
+            # row stats as narrow-lane blocks (see the kernel)
             g.stat_spec(bq),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(g.o_shape, q.dtype),
             jax.ShapeDtypeStruct(g.stat_shape, jnp.float32),
         ],
-        scratch_shapes=[
+        scratch_shapes=[] if nk == 1 else [
             pltpu.VMEM((bq, g.hpb * LANES), jnp.float32),
             pltpu.VMEM((bq, g.hpb * LANES), jnp.float32),
             pltpu.VMEM((bq, g.qw), jnp.float32),
@@ -233,91 +376,91 @@ def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None):
 # K/V fits one block (Sk == bk), dq/dk/dv share ONE recomputed probability
 # matrix — one exp pass and 5 matmuls instead of the two-sweep schedule's
 # two exp passes and 7 matmuls.  This is the hot path for the bench shapes
-# (S=512, block 512).
+# (S=512, block 512; S=128, one block, G x Hg pairs a step).
 # ---------------------------------------------------------------------------
 
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                      dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                      scale, causal, bq, bk, hpb=1):
+                      dq_ref, dk_ref, dv_ref, *scratch,
+                      scale, causal, bq, bk, hpb, nq, G, Hg):
     i = pl.program_id(1)
-    nq = pl.num_programs(1)
-    D = q_ref.shape[-1] // hpb
+    D = q_ref.shape[-1] // (Hg * hpb)
 
-    @pl.when(i == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+    if nq > 1:
+        # several q blocks (one pair a step): dk, dv accumulate in scratch
+        dk_scr, dv_scr = scratch
 
-    dq_cols = []
-    for hh in range(hpb):
-        cs = slice(hh * D, (hh + 1) * D)
-        q = q_ref[0][:, cs]
-        k = k_ref[0][:, cs]
-        v = v_ref[0][:, cs]
-        do = do_ref[0][:, cs]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0][:, hh:hh + 1])       # [bq, bk] — the ONE exp
-        pv = p.astype(do.dtype)
-        dv_scr[:, cs] += jax.lax.dot_general(pv, do, (((0,), (0,)), ((), ())),
-                                             preferred_element_type=jnp.float32)
-        delta = jnp.sum(do.astype(jnp.float32)
-                        * o_ref[0][:, cs].astype(jnp.float32),
-                        axis=1)[:, None]                # [bq, 1]
-        dov = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        ds = (p * (dov - delta) * scale).astype(q.dtype)  # [bq, bk]
-        dq_cols.append(jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32))
-        dk_scr[:, cs] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                             preferred_element_type=jnp.float32)
-    dq_ref[0] = (jnp.concatenate(dq_cols, axis=1) if hpb > 1
-                 else dq_cols[0]).astype(dq_ref.dtype)
+        @pl.when(i == 0)
+        def _init():
+            dk_scr[:] = jnp.zeros_like(dk_scr)
+            dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(i == nq - 1)
-    def _final():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+    def row(g):
+        lse = lse_ref[g, 0]                                 # [bq, heads]
+        for hb in range(Hg):
+            cols = pl.ds(hb * hpb * D, hpb * D)
+            qb, kb, vb = q_ref[g, :, cols], k_ref[g, :, cols], v_ref[g, :, cols]
+            ob, dob = o_ref[g, :, cols], do_ref[g, :, cols]
+            dq_cols, dk_cols, dv_cols = [], [], []
+            for hh in range(hpb):
+                cs = slice(hh * D, (hh + 1) * D)
+                q, k, v, do = qb[:, cs], kb[:, cs], vb[:, cs], dob[:, cs]
+                s = _scores(q, k, scale, causal, i * bq, 0)
+                h = hb * hpb + hh
+                p = jnp.exp(s - lse[:, h:h + 1])            # [bq, bk] — the ONE exp
+                dv_cols.append(jax.lax.dot_general(
+                    p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+                delta = jnp.sum(do.astype(jnp.float32)
+                                * ob[:, cs].astype(jnp.float32),
+                                axis=1)[:, None]            # [bq, 1]
+                dov = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                          preferred_element_type=jnp.float32)
+                ds = (p * (dov - delta) * scale).astype(q.dtype)  # [bq, bk]
+                dq_cols.append(jax.lax.dot_general(
+                    ds, k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+                dk_cols.append(jax.lax.dot_general(
+                    ds, q, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+            dq_ref[g, :, cols] = _cat(dq_cols).astype(dq_ref.dtype)
+            if nq > 1:
+                dk_scr[:] += _cat(dk_cols)
+                dv_scr[:] += _cat(dv_cols)
+            else:
+                dk_ref[g, :, cols] = _cat(dk_cols).astype(dk_ref.dtype)
+                dv_ref[g, :, cols] = _cat(dv_cols).astype(dv_ref.dtype)
+
+    _rows(G, row)
+
+    if nq > 1:
+        @pl.when(i == nq - 1)
+        def _final():
+            dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+            dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
 def _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=None):
     q, k, v, o, lse = res
-    g = _Geom(q, k, H)
+    g = _Geom(q, k, H, bq, bk)
     nq = g.S // bq
     # 2-arg index maps (grid has no kv axis): kv lives at block 0
     qm, km, sm = g.qmap(), g.kmap(), g.smap()
-    qb = lambda b, i: qm(b, i, 0)
-    kb = lambda b, i: km(b, i, 0)
-    sb = lambda b, i: sm(b, i, 0)
+    qs = g.q_spec(bq, lambda b, i: qm(b, i, 0))
+    ks = g.kv_spec(bk, lambda b, i: km(b, i, 0))
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, hpb=g.hpb),
+                          bq=bq, bk=bk, hpb=g.hpb, nq=nq, G=g.G, Hg=g.Hg),
         grid=(g.grid_b, nq),
-        in_specs=[
-            pl.BlockSpec((1, bq, g.qw), qb),
-            pl.BlockSpec((1, bk, g.qw), kb),
-            pl.BlockSpec((1, bk, g.qw), kb),
-            pl.BlockSpec((1, bq, g.qw), qb),
-            pl.BlockSpec((1, bq, g.qw), qb),
-            pl.BlockSpec((1, 1, bq, g.hpb), sb),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, g.qw), qb),
-            pl.BlockSpec((1, bk, g.qw), kb),
-            pl.BlockSpec((1, bk, g.qw), kb),
-        ],
+        in_specs=[qs, ks, ks, qs, qs,
+                  g.stat_spec(bq, lambda b, i: sm(b, i, 0))],
+        out_specs=[qs, ks, ks],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(g.dkv_shape, k.dtype),
             jax.ShapeDtypeStruct(g.dkv_shape, v.dtype),
         ],
-        scratch_shapes=[
+        scratch_shapes=[] if nq == 1 else [
             pltpu.VMEM((bk, g.qw), jnp.float32),
             pltpu.VMEM((bk, g.qw), jnp.float32),
         ],
@@ -427,7 +570,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd(scale, causal, bq, bk, interpret, res, do, H=None):
     q, k, v, o, lse = res
-    g = _Geom(q, k, H)
+    g = _Geom(q, k, H, bq, bk)
     nq, nk = g.S // bq, g.Sk // bk
     if nk == 1:
         return _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=H)

@@ -33,6 +33,7 @@ from ..parallel.transformer import (
     TransformerConfig,
     embed,
     final_logits_loss,
+    gauge_flash_grid,
     grad_sync_axes,
     head_rows_computed,
     init_transformer_params,
@@ -94,10 +95,15 @@ def batch_specs(keys=("ids", "labels", "mask")):
 @dataclasses.dataclass
 class BertTrainer(StepTrainer):
     batch_keys: tuple = ("ids", "labels", "mask")
+    n_microbatches: int = 1
     label = "bert"
 
     def _observe(self, batch):
         self._count_head_rows(batch["mask"])
+        shape = batch["mask"].shape
+        gauge_flash_grid(
+            self.cfg, shape[-2] // self.mesh.shape[DP] // self.n_microbatches,
+            shape[-1])
 
     def _count_head_rows(self, mask):
         """Under a monitor session: the rows the LM head computes for these
@@ -152,4 +158,5 @@ def build_bert_trainer(cfg, mesh_spec: MeshSpec = None, optimizer=None,
         state = shard_pytree(state, sspecs, mesh)
     return BertTrainer(cfg=cfg, mesh=mesh, state=state, step_fn=step_fn,
                        specs=sspecs, multi_fn=multi_fn,
-                       batch_keys=tuple(batch_keys))
+                       batch_keys=tuple(batch_keys),
+                       n_microbatches=n_microbatches if cfg.pp > 1 else 1)

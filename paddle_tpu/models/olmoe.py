@@ -32,6 +32,7 @@ from ..parallel.transformer import (
     TransformerConfig,
     embed,
     final_logits_loss,
+    gauge_flash_grid,
     grad_sync_axes,
     init_transformer_params,
     run_layers,
@@ -97,7 +98,10 @@ class OlmoeTrainer(StepTrainer):
     _load_fn = None
 
     def _observe(self, batch):
-        self._count_moe(batch["ids"])
+        ids = batch["ids"]
+        self._count_moe(ids)
+        gauge_flash_grid(self.cfg, ids.shape[-2] // self.mesh.shape[DP],
+                         ids.shape[-1])
 
     def _count_moe(self, ids):
         """Under a monitor session: the token-slots this call routes

@@ -31,6 +31,7 @@ from jax.sharding import PartitionSpec as P
 
 from . import collectives as col
 from .mesh import DP, PP, TP
+from .. import monitor
 from ..monitor import devscope
 from .ring_attention import ring_attention
 
@@ -347,6 +348,43 @@ def _local_attention_dispatch(q, k, v, cfg):
     return ring_attention(q, k, v, axis=None, causal=cfg.causal)
 
 
+def _packed_flash_blocks(cfg, hl, S):
+    """(block_q, block_k) where attention over ``hl`` local heads of S
+    positions goes to the packed flash kernel, else None."""
+    from ..kernels.flash_attention import packed_layout_supported
+
+    bq = min(cfg.flash_block_q, S)
+    bk = min(cfg.flash_block_k, S)
+    if (cfg.use_flash and S % bq == 0 and S % bk == 0
+            and packed_layout_supported(hl, cfg.head_dim)):
+        return bq, bk
+    return None
+
+
+def gauge_flash_grid(cfg, b, S):
+    """Under a monitor session: what one grid step of the flash kernels holds
+    for ``b`` local sequences of S positions (one kernel call: a dp shard's
+    batch, or a pipeline microbatch of it), from the function the kernels
+    take their grid from.  ``monitor.kernels.flash_pairs_per_grid_step`` is
+    the (batch row, head-block) pairs a step computes, 1 where every pair is
+    a step of its own; ``monitor.kernels.flash_grid_steps`` the steps of one
+    layer's pass.  Both fixed when the step is traced, so gauges; nothing is
+    set where attention does not take the packed kernel."""
+    mon = monitor.active()
+    if mon is None or cfg.attn_mode != "heads":
+        return
+    hl = cfg.n_heads // cfg.tp
+    blocks = _packed_flash_blocks(cfg, hl, S)
+    if blocks is None:
+        return
+    from ..kernels.flash_attention import packed_grid
+
+    pairs, steps = packed_grid(b, S, hl, cfg.head_dim, *blocks,
+                               itemsize=cfg.jdtype.itemsize)
+    mon.registry.gauge("monitor.kernels.flash_pairs_per_grid_step").set(pairs)
+    mon.registry.gauge("monitor.kernels.flash_grid_steps").set(steps)
+
+
 def _attention_heads_mode(pl, h_full, cfg):
     """Megatron attention: input full-sequence [b,S,E], heads sharded over tp."""
     b, S, E = h_full.shape
@@ -364,16 +402,14 @@ def _attention_heads_mode(pl, h_full, cfg):
     if cfg.positions == "rotary":
         q2 = rope(q2, hl, cfg.rope_theta)
         k2 = rope(k2, hl, cfg.rope_theta)
-    bq = min(cfg.flash_block_q, S)
-    bk = min(cfg.flash_block_k, S)
-    from ..kernels.flash_attention import (flash_attention_packed,
-                                           packed_layout_supported)
-    if (cfg.use_flash and S % bq == 0 and S % bk == 0
-            and packed_layout_supported(hl, dh)):
+    blocks = _packed_flash_blocks(cfg, hl, S)
+    if blocks:
         # packed layout: the kernel reads each head's column slice in place —
         # no [b, hl, S, dh] transpose round-trips (flash_attention_packed)
+        from ..kernels.flash_attention import flash_attention_packed
+
         o = flash_attention_packed(q2, k2, v2, hl, causal=cfg.causal,
-                                   block_q=bq, block_k=bk)
+                                   block_q=blocks[0], block_k=blocks[1])
     else:
         q = q2.reshape(b, S, hl, dh)
         k = k2.reshape(b, S, hl, dh)

@@ -1,88 +1,223 @@
-"""Standalone kernel shootout: our flash kernel vs JAX's reference TPU kernel
-vs plain XLA softmax attention, fwd and fwd+bwd, B=24 S=512 H=12 D=64."""
+"""Standalone attention-kernel timings at one shape, on the chip.
 
-import functools
+    chiprun -- python3 scripts/attn_kernel_cmp.py --batch 256 --seq 128 \
+        [--heads 12 --head-dim 64 --block 512 --causal] \
+        [--force 1x1,4x1,1x6] [--others]
+
+Times ``flash_attention_packed`` (the entry the models call), forward and
+backward, by DEVICE time per kernel name read from a profiler trace, as the
+benchmark reads ``flash_roofline`` (``flash_fwd``, ``flash_bwd_fused``, ...),
+beside the least time the chip could take by the benchmark's own formula.
+``--force GxHg,...`` also times the kernels with the grid step's geometry
+forced to G batch rows by Hg head-blocks (``step_geometry`` replaced for
+that compile: an experiment of this script, not an option of the program)
+and holds every output to the unforced one.  ``--others`` adds, by host
+clock, the [B, S, H, D] entry, JAX's own TPU flash kernel and plain XLA
+softmax attention.  Needs a TPU.
+"""
+
+import argparse
+import os
+import re
+import sys
+import tempfile
 import time
 
-import jax
-import jax.numpy as jnp
-
-import importlib
-ours = importlib.import_module("paddle_tpu.kernels.flash_attention")
-from jax.experimental.pallas.ops.tpu import flash_attention as ref
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def timeit(name, fn, *args, iters=30):
-    float(fn(*args))
+def device_us(fn, args, iters, tmp):
+    """{kernel name: microseconds an event} over ``iters`` traced calls."""
+    import jax
+
+    from benchmark.harness import trace_reduce, tracing
+
+    jax.block_until_ready(fn(*args))
+    tracing._start(tmp, 0)
+    for _ in range(iters):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    jax.profiler.stop_trace()
+    dev = trace_reduce.Reduced(trace_reduce.load_xplane(
+        trace_reduce.find_xplane(tmp))).devices[0]
+    ns, calls = {}, {}
+    for name, t in dev["by_name"].items():
+        # under jax.vjp alone the instruction is `transpose_jvp_flash_..__.1`
+        kernel = re.search(r"flash_[a-z_]*[a-z]|$", name).group() \
+            or name.split(".")[0]
+        ns[kernel] = ns.get(kernel, 0.0) + t
+        calls[kernel] = calls.get(kernel, 0) + dev["count"][name]
+    return {kernel: t / calls[kernel] / 1e3 for kernel, t in ns.items()}
+
+
+def host_ms(name, fn, args, iters=30):
+    import jax
+
+    jax.block_until_ready(fn(*args))
     t0 = time.perf_counter()
     for _ in range(iters):
-        s = fn(*args)
-    float(s)
-    dt = (time.perf_counter() - t0) / iters * 1000
-    print(f"{name:44s} {dt:8.3f} ms", flush=True)
-    return dt
+        out = fn(*args)
+    jax.block_until_ready(out)
+    print("  %-40s %8.3f ms (host clock)"
+          % (name, (time.perf_counter() - t0) / iters * 1e3), flush=True)
 
 
-def main():
-    B, S, H, D = 24, 512, 12, 64
+def xla_attention(q, k, v, H, causal):
+    """Plain softmax attention on the packed [B, S, H*D] layout."""
+    import jax
+    import jax.numpy as jnp
+
+    B, S, E = q.shape
+    q4, k4, v4 = (t.reshape(B, S, H, E // H) for t in (q, k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q4, k4,
+                   preferred_element_type=jnp.float32) * (E // H) ** -0.5
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v4,
+                      preferred_element_type=jnp.float32
+                      ).astype(v.dtype).reshape(B, S, E)
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--batch", type=int, default=64)
+    ap.add_argument("--seq", type=int, default=512)
+    ap.add_argument("--heads", type=int, default=12)
+    ap.add_argument("--head-dim", type=int, default=64)
+    ap.add_argument("--block", type=int, default=512)
+    ap.add_argument("--causal", action="store_true")
+    ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--force", default="")
+    ap.add_argument("--vmem-mib", type=int, default=0)
+    ap.add_argument("--others", action="store_true")
+    args = ap.parse_args(argv)
+    sys.path.insert(0, ROOT)
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    if jax.devices()[0].platform != "tpu":
+        print("attn_kernel_cmp: no TPU, nothing to measure", file=sys.stderr)
+        return 2
+
+    from benchmark.flops import flash_attention as need_of
+    from benchmark.harness import flops, peaks
+    import importlib
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+
+    B, S, H, D = args.batch, args.seq, args.heads, args.head_dim
     key = jax.random.PRNGKey(0)
-    # model layout [B, S, H, D] for ours; ref wants [B, H, S, D]
-    q = jax.random.normal(key, (B, S, H, D), jnp.bfloat16)
-    k = jax.random.normal(jax.random.fold_in(key, 1), (B, S, H, D), jnp.bfloat16)
-    v = jax.random.normal(jax.random.fold_in(key, 2), (B, S, H, D), jnp.bfloat16)
-    qh = q.transpose(0, 2, 1, 3)
-    kh = k.transpose(0, 2, 1, 3)
-    vh = v.transpose(0, 2, 1, 3)
+    q, k, v, do = (jax.random.normal(jax.random.fold_in(key, n),
+                                     (B, S, H * D), jnp.bfloat16)
+                   for n in range(4))
+    need = need_of.required(B, S, H * D, causal=args.causal)
+    peak = peaks.peaks_for(jax.devices()[0].device_kind)
+    least = {p: flops.least_seconds(need[p]["flops"], need[p]["bytes"], peak)
+             for p in ("fwd", "bwd")}
+    print("B=%d S=%d H=%d D=%d block=%d causal=%s: least fwd %.1f us (%s), "
+          "bwd %.1f us (%s)"
+          % (B, S, H, D, args.block, args.causal, least["fwd"][0] * 1e6,
+             least["fwd"][1], least["bwd"][0] * 1e6, least["bwd"][1]))
 
-    def s_of(x):
-        return jnp.sum(x.astype(jnp.float32))
+    def both():
+        def f(q, k, v, do):
+            o, vjp = jax.vjp(lambda a, b, c: fa.flash_attention_packed(
+                a, b, c, H, causal=args.causal, block_q=args.block,
+                block_k=args.block), q, k, v)
+            return (o,) + vjp(do)
+        return jax.jit(f)
 
-    # ours fwd
-    o_fwd = jax.jit(lambda a, b, c: s_of(
-        ours.flash_attention(a, b, c, block_q=512, block_k=512)))
-    timeit("ours fwd 512x512", o_fwd, q, k, v)
+    rule = getattr(fa, "step_geometry", None)   # a checkout before PR 28
+    forced = [tuple(int(n) for n in g.split("x"))
+              for g in args.force.split(",") if g]
+    if args.vmem_mib:       # for a forced geometry over Mosaic's default scope
+        import functools
+        fa._CompilerParams = functools.partial(
+            fa._CompilerParams, vmem_limit_bytes=args.vmem_mib * 2 ** 20)
+    n8 = min(B, 8)
+    ref = [np.asarray(x.astype(jnp.float32)) for x in jax.jit(
+        lambda q, k, v, do: (lambda o, vjp: (o,) + vjp(do))(*jax.vjp(
+            lambda a, b, c: xla_attention(a, b, c, H, args.causal), q, k, v))
+    )(q[:n8], k[:n8], v[:n8], do[:n8])]
+    want = None
+    for geom in [None] + forced:
+        if rule is None:
+            pairs, steps = 1, -1
+        else:
+            fa.step_geometry = rule if geom is None else (lambda *a, g=geom: g)
+            pairs, steps = fa.packed_grid(B, S, H, D, args.block, args.block)
+        fn = both()
+        t0 = time.perf_counter()
+        got = [np.asarray(x.astype(jnp.float32)) for x in fn(q, k, v, do)]
+        compiled = time.perf_counter() - t0
+        with tempfile.TemporaryDirectory() as tmp:
+            per = device_us(fn, (q, k, v, do), args.iters, tmp)
+        if want is None:
+            want = got
+        worst = max(float(np.abs(a - b).max()) for a, b in zip(got, want))
+        off = max(float(np.abs(a[:n8] - b).max()) for a, b in zip(got, ref))
+        fwd = per.pop("flash_fwd", 0.0)
+        bwd = sum(per.pop(n) for n in list(per) if n.startswith("flash_bwd"))
+        print("%-8s %2d pairs a step, %5d steps: fwd %8.1f us, bwd %8.1f us, "
+              "roofline %5.1f %%; first call %.1f s; off the rule's by %.3g, "
+              "off XLA's softmax attention by %.3g"
+              % ("rule" if geom is None else "%dx%d" % geom, pairs, steps,
+                 fwd, bwd,
+                 100e6 * (least["fwd"][0] + least["bwd"][0]) / (fwd + bwd or 1e30),
+                 compiled, worst, off), flush=True)
+        print("         beside them: " + ", ".join(
+            "%s %.1f" % kv for kv in sorted(per.items(), key=lambda kv: -kv[1])[:6]))
+    if rule is not None:
+        fa.step_geometry = rule
 
-    # ours fwd+bwd
-    o_vg = jax.jit(lambda a, b, c: s_of(jax.grad(
-        lambda x, y, z: s_of(ours.flash_attention(x, y, z, block_q=512, block_k=512)),
-        argnums=(0, 1, 2))(a, b, c)[0]))
-    timeit("ours fwd+bwd 512x512", o_vg, q, k, v)
+    if args.others:
+        others(args, q, k, v, do)
+    return 0
 
-    # ref fwd
-    bs = ref.BlockSizes(block_q=512, block_k_major=512, block_k=512, block_b=1,
-                        block_q_major_dkv=512, block_k_major_dkv=512,
-                        block_k_dkv=512, block_q_dkv=512,
-                        block_k_major_dq=512, block_k_dq=512, block_q_dq=512)
+
+def others(args, q, k, v, do):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas.ops.tpu import flash_attention as ref
+
+    import importlib
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+
+    B, S, H, D = args.batch, args.seq, args.heads, args.head_dim
     sc = 1.0 / D ** 0.5
-    r_fwd = jax.jit(lambda a, b, c: s_of(
-        ref.flash_attention(a, b, c, sm_scale=sc, block_sizes=bs)))
-    timeit("jax-ref fwd 512", r_fwd, qh, kh, vh)
+    blk = min(args.block, S)
+    q4, k4, v4, do4 = (t.reshape(B, S, H, D) for t in (q, k, v, do))
+    to_bhsd = lambda t: t.transpose(0, 2, 1, 3)
 
-    r_vg = jax.jit(lambda a, b, c: s_of(jax.grad(
-        lambda x, y, z: s_of(ref.flash_attention(x, y, z, sm_scale=sc, block_sizes=bs)),
-        argnums=(0, 1, 2))(a, b, c)[0]))
-    timeit("jax-ref fwd+bwd 512", r_vg, qh, kh, vh)
+    def fwd_bwd(attn):
+        def f(q, k, v, do):
+            o, vjp = jax.vjp(attn, q, k, v)
+            return (o,) + vjp(do)
+        return jax.jit(f)
 
-    # plain XLA softmax attention (single layer won't OOM)
-    def xla_attn(a, b, c):
-        s = jnp.einsum("bqhd,bkhd->bhqk", a * jnp.bfloat16(sc), b,
-                       preferred_element_type=jnp.float32)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(c.dtype), c,
-                          preferred_element_type=jnp.float32)
-    x_fwd = jax.jit(lambda a, b, c: s_of(xla_attn(a, b, c)))
-    timeit("xla softmax fwd", x_fwd, q, k, v)
-    x_vg = jax.jit(lambda a, b, c: s_of(jax.grad(
-        lambda x, y, z: s_of(xla_attn(x, y, z)), argnums=(0, 1, 2))(a, b, c)[0]))
-    timeit("xla softmax fwd+bwd", x_vg, q, k, v)
+    host_ms("ours packed fwd+bwd", fwd_bwd(
+        lambda a, b, c: fa.flash_attention_packed(
+            a, b, c, H, causal=args.causal, block_q=blk, block_k=blk)),
+        (q, k, v, do))
+    host_ms("ours [B,S,H,D] fwd+bwd", fwd_bwd(
+        lambda a, b, c: fa.flash_attention(
+            a, b, c, causal=args.causal, block_q=blk, block_k=blk)),
+        (q4, k4, v4, do4))
+    bs = ref.BlockSizes(
+        block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
+        block_q_major_dkv=blk, block_k_major_dkv=blk, block_k_dkv=blk,
+        block_q_dkv=blk, block_k_major_dq=blk, block_k_dq=blk, block_q_dq=blk)
+    host_ms("jax's TPU flash kernel fwd+bwd, [B,H,S,D]", fwd_bwd(
+        lambda a, b, c: ref.flash_attention(
+            a, b, c, causal=args.causal, sm_scale=sc, block_sizes=bs)),
+        tuple(to_bhsd(t) for t in (q4, k4, v4, do4)))
 
-    # ideal: the two matmuls as pure dense matmuls (MXU ceiling probe)
-    def mm(a, b, c):
-        s = jnp.einsum("bqhd,bkhd->bhqk", a, b, preferred_element_type=jnp.bfloat16)
-        return jnp.einsum("bhqk,bkhd->bqhd", s, c, preferred_element_type=jnp.float32)
-    m_fwd = jax.jit(lambda a, b, c: s_of(mm(a, b, c)))
-    timeit("bare matmuls fwd (ceiling)", m_fwd, q, k, v)
+    host_ms("xla softmax attention fwd+bwd", fwd_bwd(
+        lambda a, b, c: xla_attention(a, b, c, H, args.causal)),
+        (q, k, v, do))
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -5,7 +5,8 @@ it computed: one cell of the benchmark through the program's normal path.
 
 Builds the cell's trainer and stages its batches as the scan driver does,
 runs one dispatch under a monitor session (the scan driver opens none, so
-this is where ``monitor.train.lm_head_rows_share`` is read on the chip),
+this is where ``monitor.train.lm_head_rows_share`` and the two
+``monitor.kernels.flash_*`` gauges are read on the chip),
 traces one more, and joins the trace with THIS process's scope map
 (``monitor.devscope``; a map compiled elsewhere need not number its
 instructions the same way).  ``--ones`` replaces the mask by all ones, the
@@ -72,6 +73,10 @@ def main(argv=None):
               % (mon.registry.gauge("monitor.train.lm_head_rows_share").value,
                  mon.registry.counter("monitor.train.lm_head_rows").value
                  - rows0, steps))
+        print("flash: monitor.kernels.flash_pairs_per_grid_step %s, "
+              "monitor.kernels.flash_grid_steps %s a layer and pass"
+              % tuple(mon.registry.gauge("monitor.kernels.flash_" + g).value
+                      for g in ("pairs_per_grid_step", "grid_steps")))
         monitor.disable()
         np.asarray(tr.run_steps(staged, lr))
         tracing._start(os.path.join(tmp, "trace"), 0)

@@ -75,7 +75,9 @@ def test_dispatch_block_choice():
 
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("S,bq,bk", [(256, 256, 256),   # fused single-kv-block bwd
-                                     (256, 128, 128)])  # two-sweep bwd
+                                     (256, 128, 128),   # two-sweep bwd
+                                     (256, 128, 256)])  # fused, two q blocks:
+                                                        # dk, dv through scratch
 def test_packed_layout_matches_bshd(causal, S, bq, bk):
     """flash_attention_packed on [B,S,H*D] == flash_attention on [B,S,H,D],
     values and gradients (the head-column BlockSpec addressing)."""
@@ -109,3 +111,167 @@ def test_packed_layout_matches_bshd(causal, S, bq, bk):
                                    np.asarray(b).reshape(B, S, H * D),
                                    atol=5e-5, rtol=1e-4,
                                    err_msg="d%s mismatch" % n)
+
+
+# ---------------------------------------------------------------------------
+# one grid step carries a fixed amount of work (PR 28): where the sequence is
+# one block a step holds G batch rows by Hg head-blocks, from the shapes
+# ---------------------------------------------------------------------------
+
+import importlib
+
+fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+
+
+#   entry      B  S    H  D    G, Hg the rule gives for float32 inputs
+GROUPED = [
+    ("packed", 8, 128, 4, 64, (2, 2)),
+    ("packed", 3, 128, 2, 64, (3, 1)),     # one head-block: rows only
+    ("packed", 1, 128, 4, 64, (1, 2)),     # B = 1: head-blocks only
+    ("packed", 8, 256, 4, 64, (1, 2)),
+    ("packed", 3, 256, 2, 64, (3, 1)),     # a prime B goes whole
+    ("packed", 3, 384, 2, 128, (1, 2)),
+    ("packed", 8, 128, 2, 128, (2, 2)),
+    ("packed", 1, 384, 2, 64, (1, 1)),     # nothing to group: falls back to 1, 1
+    ("bshd", 3, 128, 4, 64, (4, 1)),       # G consecutive rows of [B*H, S, D]
+    ("bshd", 8, 256, 2, 64, (2, 1)),
+    ("bshd", 1, 384, 2, 128, (2, 1)),
+    ("bshd", 1, 128, 1, 64, (1, 1)),
+]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("entry,B,S,H,D,geom", GROUPED)
+def test_grouped_step_matches_reference_and_one_pair_a_step(
+        monkeypatch, entry, B, S, H, D, geom, causal):
+    """Forward and dq, dk, dv of the grouped geometry against ring_attention,
+    and bit for bit against the same call forced to G = Hg = 1: every pair
+    is computed as a step of its own would compute it."""
+    q, k, v = _qkv(11, B=B, S=S, H=H, D=D)
+    w = jnp.array(np.random.RandomState(12).randn(B, S, H, D)
+                  .astype(np.float32))
+    if entry == "packed":
+        assert fa.grid_geometry(B, S, S, H // max(1, 128 // D),
+                                max(D, 128), 4, S, S)[:2] == geom
+        flat = lambda t: t.reshape(B, S, H * D)
+        attn = lambda a, b, c: fa.flash_attention_packed(
+            flat(a), flat(b), flat(c), H, causal=causal, block_q=512,
+            block_k=512).reshape(B, S, H, D)
+    else:
+        assert fa.grid_geometry(B * H, S, S, 1, D, 4, S, S)[:2] == geom
+        attn = lambda a, b, c: fa.flash_attention(
+            a, b, c, causal=causal, block_q=512, block_k=512)
+
+    def both(f):
+        o, vjp = jax.vjp(f, q, k, v)
+        return (o,) + vjp(w)
+
+    got = both(attn)
+    want = both(lambda a, b, c: ring_attention(a, b, c, axis=None,
+                                               causal=causal))
+    for a, b, n in zip(got, want, ("o", "dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                                   rtol=1e-4, err_msg=n)
+    # the same call with every (row, head-block) pair a step of its own
+    monkeypatch.setattr(fa, "step_geometry", lambda *a: (1, 1))
+    for a, b, n in zip(got, both(attn), ("o", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=n)
+
+
+@pytest.mark.parametrize("what,args,want", [
+    # B, S, head-blocks, lanes, itemsize
+    ("bert_base.s128_scan", (256, 128, 6, 128, 2), (2, 3)),
+    ("bert_base.s512_scan: the step as it was", (64, 512, 6, 128, 2), (1, 1)),
+    ("olmoe, were S one block", (4, 512, 16, 128, 2), (1, 1)),
+    ("fine-tuning, one block of 384", (32, 384, 6, 128, 2), (1, 2)),
+    ("S=256", (128, 256, 6, 128, 2), (1, 2)),
+    ("B = 1", (1, 128, 6, 128, 2), (1, 3)),
+    ("a prime B goes whole or not at all", (7, 128, 6, 128, 2), (7, 3)),
+    ("a prime B too large for the budget", (61, 128, 1, 128, 2), (1, 1)),
+    ("tp=2: six local heads", (256, 128, 3, 128, 2), (2, 3)),
+    ("tp=4: three local heads of 128", (256, 128, 3, 128, 2), (2, 3)),
+    ("tp=3: four local heads", (256, 128, 2, 128, 2), (2, 2)),
+    ("tp=6: one local head-block", (256, 128, 1, 128, 2), (4, 1)),
+    ("16 head-blocks: 4 divides, 3 is the most", (8, 128, 16, 128, 2), (2, 2)),
+    ("[BH, S, 64] float32", (96, 128, 1, 64, 4), (4, 1)),
+])
+def test_step_geometry_table(what, args, want):
+    G, Hg = fa.step_geometry(*args)
+    assert (G, Hg) == want, what
+    assert args[0] % G == 0 and args[2] % Hg == 0
+    assert Hg <= fa.STEP_HEAD_BLOCKS
+
+
+@pytest.mark.parametrize("S", [128, 256, 384, 512])
+def test_step_geometry_stays_under_the_vmem_budget(S):
+    """Whatever the batch and the heads: G and Hg divide, and a step that
+    holds more than one pair fits the budget (one pair is the floor the
+    kernels always ran at)."""
+    for B in (1, 2, 3, 5, 7, 8, 12, 61, 64, 96, 256, 1024):
+        for Hb in (1, 2, 3, 4, 6, 8, 12, 16):
+            for lanes, itemsize in ((128, 2), (128, 4), (256, 2), (64, 4)):
+                G, Hg = fa.step_geometry(B, S, Hb, lanes, itemsize)
+                assert B % G == 0 and Hb % Hg == 0, (B, S, Hb, lanes)
+                if (G, Hg) != (1, 1):
+                    assert fa.step_vmem_bytes(G, S, Hg, lanes, itemsize) \
+                        <= fa.VMEM_BUDGET, (B, S, Hb, lanes, itemsize)
+                    # no more rows than it takes to fill the step
+                    smaller = [g for g in range(1, G) if B % g == 0]
+                    assert not smaller or (smaller[-1] * Hg * S
+                                           * max(lanes, 128)
+                                           < fa.STEP_ROWS * 128)
+
+
+@pytest.mark.parametrize("B,S,H,D,want", [
+    (256, 128, 12, 64, (6, 256)),       # bert_base.s128_scan: 1,536 before
+    (64, 512, 12, 64, (1, 384)),        # bert_base.s512_scan: untouched
+    (4, 4096, 16, 128, (1, 4 * 16 * 8)),  # olmoe: 8 x 8 blocks, untouched
+    (8, 640, 12, 64, (1, 8 * 6 * 5)),   # S = 640 in five blocks of 128
+])
+def test_packed_grid_of_the_cells(B, S, H, D, want):
+    bq = 512 if S % 512 == 0 or S < 512 else 128
+    assert fa.packed_grid(B, S, H, D, bq, bq) == want
+
+
+def test_several_blocks_are_one_pair_a_step(monkeypatch):
+    """S above the block size never asks the rule: the two-sweep backward
+    and the causal skip run the geometry they always had."""
+    def never(*a):
+        raise AssertionError("step_geometry asked for a multi-block grid")
+    monkeypatch.setattr(fa, "step_geometry", never)
+    assert fa.grid_geometry(4, 4096, 4096, 16, 128, 2, 512, 512) == (1, 1, 64)
+    assert fa.grid_geometry(8, 256, 512, 2, 128, 4, 128, 512) == (1, 1, 16)
+    q, k, v = _qkv(13, B=2, S=256, H=2, D=64)
+    got = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
+    ref = ring_attention(q, k, v, axis=None, causal=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               atol=2e-6, rtol=1e-5)
+
+
+def test_a_layer_at_s128_is_one_forward_and_one_backward_kernel():
+    """The jaxpr of one transformer layer's forward and backward at the
+    S=128 cell's shape holds exactly one ``flash_fwd`` and one
+    ``flash_bwd_fused`` call: the metrics that find the kernels by name
+    count one event as one layer's pass over the chip's batch."""
+    import re
+
+    from paddle_tpu.models.bert import bert_base_config
+    from paddle_tpu.parallel import transformer as T
+
+    cfg = bert_base_config()
+    params = jax.eval_shape(
+        lambda: T.init_transformer_params(jax.random.PRNGKey(0), cfg))
+    layer = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+                         params["params_layers"])
+    x = jax.ShapeDtypeStruct((256, 128, cfg.hidden), cfg.jdtype)
+
+    def loss(pl, x):
+        return jnp.sum(T.transformer_layer(pl, x, cfg)[0].astype(jnp.float32))
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(layer, x))
+    calls = re.findall(r"name=(flash_\w+)", text)
+    assert sorted(calls) == ["flash_bwd_fused", "flash_fwd"], calls
+    # 2 rows by 3 head-blocks a step: 256 steps where there were 1,536
+    steps = fa.packed_grid(256, 128, cfg.n_heads, cfg.head_dim, 512, 512)[1]
+    assert steps == 256
+    assert "grid=(%d, 1, 1)" % steps in text and "grid=(%d, 1)" % steps in text

@@ -1,0 +1,60 @@
+"""The flash kernels compiled for a described v5e, at the benchmark cells'
+real shapes: what interpret mode cannot see (Mosaic's tiling rules, the
+scoped VMEM a grid step may hold).  Nothing runs; no chip is needed.  All
+such compiles live in this one file and describe the chip inside a fixture,
+so that only the worker that is given the file loads the TPU's library."""
+
+import importlib
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("what,entry,B,S,H,D,causal,pairs", [
+    ("bert_base.s128_scan", "packed", 256, 128, 12, 64, False, 6),
+    ("bert_base.s512_scan", "packed", 64, 512, 12, 64, False, 1),
+    ("fine-tuning at 384", "packed", 32, 384, 12, 64, False, 2),
+    ("olmoe_1b_7b.s4096_scan", "packed", 4, 4096, 16, 128, True, 1),
+    ("a prime batch, causal", "packed", 7, 128, 12, 64, True, 21),
+    ("heads the packed layout cannot tile", "bshd", 32, 128, 3, 64, False, 4),
+])
+def test_forward_and_backward_compile_for_a_v5e(one_chip, what, entry, B, S,
+                                                H, D, causal, pairs):
+    if entry == "packed":
+        assert fa.packed_grid(B, S, H, D, 512, 512)[0] == pairs
+        x = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16,
+                                 sharding=one_chip)
+        attn = lambda q, k, v: fa.flash_attention_packed(
+            q, k, v, H, causal=causal, block_q=512, block_k=512,
+            interpret=False)
+    else:
+        assert fa.grid_geometry(B * H, S, S, 1, D, 2, S, S)[0] == pairs
+        x = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16,
+                                 sharding=one_chip)
+        attn = lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=causal, block_q=512, block_k=512,
+            interpret=False)
+
+    def both(q, k, v, do):
+        o, vjp = jax.vjp(attn, q, k, v)
+        return (o,) + vjp(do)
+
+    text = jax.jit(both).lower(x, x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2, what

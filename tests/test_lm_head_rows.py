@@ -278,6 +278,51 @@ def test_rows_gauge_and_counter_only_under_a_monitor_session(tmp_path):
         monitor.disable()
 
 
+def test_flash_grid_gauges_only_under_a_monitor_session(tmp_path):
+    """``monitor.kernels.flash_pairs_per_grid_step`` and ``flash_grid_steps``
+    are what ``kernels.flash_attention.packed_grid`` says of the call's
+    shapes, the function the kernels take their grid from."""
+    import dataclasses
+
+    from paddle_tpu.kernels.flash_attention import packed_grid
+    from paddle_tpu.models import olmoe
+
+    # heads the packed layout can tile (two of 64), so the kernel runs
+    cfg = bert.bert_tiny_config(hidden=128, n_heads=2)
+    tr = bert.build_bert_trainer(cfg, MeshSpec(dp=1),
+                                 optimizer=optim.momentum(0.9),
+                                 devices=jax.devices()[:1])
+    batch = _batch(np.random.RandomState(9))
+    assert monitor.active() is None
+    T.gauge_flash_grid(cfg, B, S)               # off: nothing to set
+    mon = monitor.enable(str(tmp_path), flight=False)
+    try:
+        pairs = mon.registry.gauge("monitor.kernels.flash_pairs_per_grid_step")
+        steps = mon.registry.gauge("monitor.kernels.flash_grid_steps")
+        tr.step(batch, 1e-3)
+        assert (pairs.value, steps.value) == packed_grid(
+            B, S, 2, 64, 32, 32, itemsize=4) == (8, 1)
+        # two dp shards of four rows each
+        bert.BertTrainer._observe(
+            dataclasses.replace(tr, mesh=MeshSpec(dp=2).build(
+                devices=jax.devices()[:2])), batch)
+        assert (pairs.value, steps.value) == (4, 1)
+        # the cells' shapes, by configuration alone
+        base = bert.bert_base_config()
+        for c, b, s, want in [
+                (base, 256, 128, (6, 256)),     # bert_base.s128_scan
+                (base, 64, 512, (1, 384)),      # bert_base.s512_scan, _dp4
+                (dataclasses.replace(base, tp=2), 256, 128, (6, 128)),
+                (olmoe.olmoe_1b_7b_config(), 4, 4096, (1, 512))]:
+            T.gauge_flash_grid(c, b, s)
+            assert (pairs.value, steps.value) == want
+        # heads the packed layout cannot tile take another path: left alone
+        T.gauge_flash_grid(bert.bert_tiny_config(), B, S)
+        assert (pairs.value, steps.value) == (1, 512)
+    finally:
+        monitor.disable()
+
+
 # ---------------------------------------------------------------------------
 # what compiled
 # ---------------------------------------------------------------------------
