@@ -338,9 +338,9 @@ def bench_bert():
 
     if on_tpu:
         # scan_unroll: unrolling the layer scan turns the per-layer dynamic
-        # param slices into static ones (+6% MFU measured, r5
-        # scripts/bert_batch_sweep.py); B=64 is the sweet spot (96 hits a
-        # compiler limit, 128+remat trades the win back for recompute)
+        # param slices into static ones (+6% MFU measured in r5's batch
+        # sweep); B=64 is the sweet spot (96 hits a compiler limit,
+        # 128+remat trades the win back for recompute)
         cfg = bert.bert_base_config(scan_unroll=12)
         B, S, N, reps = 64, 512, 10, 3
     else:
@@ -402,31 +402,18 @@ def bench_bert():
     })
 
 
-def _fuse_bn_enabled():
-    """Fused-BN Pallas epilogue (kernels/fused_bn.py): default ON for the
-    bench resnet50 line — the named ~13 ms/step of extra BN HBM traffic is
-    exactly the roofline gap the line is gated on; PADDLE_TPU_FUSE_BN=0
-    reverts to the seed XLA lowering for A/B.  The CPU tiny path runs the
-    same kernels in interpret mode."""
-    import os
-
-    return os.environ.get("PADDLE_TPU_FUSE_BN", "1").strip() != "0"
-
-
 def bench_resnet50():
     devs, on_tpu, peaks = _env()
     from paddle_tpu.models import resnet
     from paddle_tpu.parallel import MeshSpec, optim
     from paddle_tpu.parallel.train import stack_batches
-    from jax.sharding import PartitionSpec as P
 
-    fuse_bn = _fuse_bn_enabled()
     if on_tpu:
-        cfg = resnet.resnet50_config(dtype="bfloat16", fuse_bn=fuse_bn)
+        cfg = resnet.resnet50_config(dtype="bfloat16")
         B, N, reps = 128, 25, 2
         flops_per_image = RESNET50_FLOPS_PER_IMAGE
     else:
-        cfg = resnet.resnet_tiny_config(fuse_bn=fuse_bn)
+        cfg = resnet.resnet_tiny_config()
         B, N, reps = 8, 2, 1
         flops_per_image = 3 * 2 * 1e6
 
@@ -442,8 +429,7 @@ def bench_resnet50():
             "label": rng.randint(0, cfg.num_classes, (B,)).astype(np.int32),
         }
 
-    batch_specs = {"image": P("dp"), "label": P("dp")}
-    batches = stack_batches(trainer.mesh, batch_specs,
+    batches = stack_batches(trainer.mesh, resnet.BATCH_SPECS,
                             [mk_batch() for _ in range(N)])
     if on_tpu:
         # stage images in bf16: halves the staged-batch HBM footprint and the
@@ -477,8 +463,8 @@ def bench_resnet50():
                          / peaks["bf16_flops"], 4),
             **_roofline(
                 lambda: trainer.multi_fn.lower(
-                    trainer.state, trainer.bn_state, batches,
-                    1e-2).compile().cost_analysis(), peaks),
+                    trainer.state, batches, 1e-2).compile().cost_analysis(),
+                peaks),
         }
     _emit({
         "metric": "resnet50_imagenet_images_per_sec_per_chip",
@@ -486,13 +472,12 @@ def bench_resnet50():
         "unit": "images/s",
         "vs_baseline": round(images_per_sec / 1000.0, 4),
         **device_metrics,
-        "fuse_bn": fuse_bn,
         "batch": B,
         "image_size": size,
         "loss": _finite(float(losses[-1])),
         **_telemetry("resnet50", steps, dt, B,
                      compile_probe=lambda: trainer.multi_fn.lower(
-                         trainer.state, trainer.bn_state, batches, 1e-2)),
+                         trainer.state, batches, 1e-2)),
     })
 
 

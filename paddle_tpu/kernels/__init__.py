@@ -2,15 +2,11 @@
 
 The TPU-native replacement for the reference's hand-written CUDA kernels:
 fused attention (operators/fused/multihead_matmul_op.cu and the
-multihead_matmul_fuse_pass), the fused conv+batch_norm epilogue
-(batch_norm_op.cc / the kOutput conv-BN fusion — fused_bn.py one-pass
-statistics + folded apply + fused backward), and the sparse embedding
-update path (SelectedRows, selected_rows.h:32 — segment_update.py deduped
-segment-sum, one scatter per unique row).  Everything else rides XLA
-fusion (SURVEY.md §7 design translation).
+multihead_matmul_fuse_pass) and the sparse embedding update path
+(SelectedRows, selected_rows.h:32 — segment_update.py deduped segment-sum,
+one scatter per unique row).  Everything else rides XLA fusion (SURVEY.md
+§7 design translation), convolution and batch norm included.
 """
 
 from .flash_attention import flash_attention  # noqa: F401
-from .fused_bn import (bn_stats, fused_bn_eval, fused_bn_train,  # noqa: F401
-                       fused_scale_shift)
 from .segment_update import apply_rows_update, dedup_segment_sum  # noqa: F401

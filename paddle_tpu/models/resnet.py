@@ -9,12 +9,14 @@ pool_op.cc).  TPU-native choices: NHWC layout (XLA's preferred conv layout on
 TPU), bf16 compute with f32 BN statistics, batch-stat psum over the dp axis
 when sync-BN is requested (sync_batch_norm_pass parity).
 
-Usage mirrors models/bert.py: init_params -> param/state pytrees,
-make_loss_fn -> per-device loss for parallel/train.make_train_step.
+Usage mirrors models/bert.py: init_resnet_params -> param and running-
+statistics pytrees, make_loss_fn -> per-device loss for
+parallel/train.make_train_step (its shape for a model with running state:
+the running statistics ride in the TrainState under RUNNING),
+build_resnet_trainer -> a StepTrainer.
 """
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +27,8 @@ from ..monitor import devscope
 from ..parallel import collectives as col
 from ..parallel.mesh import DP, MeshSpec
 from ..parallel import optim
-from ..parallel.train import TrainState, make_train_step, shard_pytree, state_specs
+from ..parallel.train import (RUNNING, StepTrainer, TrainState,
+                              make_train_step, shard_pytree, state_specs)
 
 __all__ = ["ResNetConfig", "resnet50_config", "resnet_tiny_config",
            "init_resnet_params", "make_loss_fn", "build_resnet_trainer"]
@@ -40,15 +43,6 @@ class ResNetConfig:
     sync_bn: bool = False
     bn_momentum: float = 0.9
     image_size: int = 224
-    # Route batch norm through the fused Pallas epilogue
-    # (kernels/fused_bn.py): ONE statistics sweep over the conv output
-    # instead of XLA's two, normalize in the folded form, and a custom-VJP
-    # backward that folds the dγ/dβ reductions into the joint (dy, x)
-    # sweep the dx pass already needs.  Default OFF so every existing
-    # config reproduces seed numerics bit-for-bit; the bench turns it on
-    # (PADDLE_TPU_FUSE_BN=0 reverts).  Off-TPU the kernels run in Pallas
-    # interpret mode — tier-1 exercises the exact TPU code path.
-    fuse_bn: bool = False
 
     @property
     def blocks(self):
@@ -147,11 +141,11 @@ def init_resnet_params(key, cfg: ResNetConfig):
 @devscope.scoped(devscope.CONV)
 def _conv(x, w, stride=1, padding="SAME"):
     # Plain XLA conv (no preferred_element_type: XLA's MXU lowering
-    # accumulates bf16 convs in f32 regardless).  The Pallas wgrad kernel
-    # (kernels/conv.py) beats XLA's wgrad emitter ~1.5x in isolation, but
-    # forcing a custom VJP here unfuses XLA's conv+BN-grad kOutput fusions
-    # and nets out slower on the full step (measured r4: 1940 vs 2300
-    # img/s), so the model keeps XLA's autodiff for the block convs.
+    # accumulates bf16 convs in f32 regardless) under XLA's own autodiff: a
+    # Pallas weight-gradient kernel beat XLA's wgrad emitter ~1.5x in
+    # isolation, but a custom VJP here unfuses XLA's conv+BN-grad kOutput
+    # fusions and the full step came out slower (measured r4: 1940 vs 2300
+    # img/s).
     return lax.conv_general_dilated(
         x, w, (stride, stride), padding,
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
@@ -178,28 +172,6 @@ def _conv0_s2d(x, w7):
     return _conv(x, w4, 1, ((1, 2), (1, 2)))
 
 
-def _bn_fused(x, p, s, cfg, train, updates, path):
-    """cfg.fuse_bn path: same math as _bn, through the Pallas kernels.
-    Train mode takes the one-sweep statistics + fused-backward custom VJP
-    (batch stats are stop-gradient outputs — exactly how this function
-    consumes them); sync-BN composes via the same cross-replica pmean,
-    applied to per-channel stats between kernels.  Eval is the folded
-    scale-shift with grads flowing through the tiny a/b arithmetic."""
-    from ..kernels import fused_bn as fbn
-
-    if train:
-        y, m, v = fbn.fused_bn_train(
-            x, p["scale"], p["bias"], 1e-5,
-            DP if cfg.sync_bn else None)
-        mom = cfg.bn_momentum
-        updates[path] = {
-            "mean": mom * s["mean"] + (1 - mom) * lax.stop_gradient(m),
-            "var": mom * s["var"] + (1 - mom) * lax.stop_gradient(v),
-        }
-        return y
-    return fbn.fused_bn_eval(x, p["scale"], p["bias"], s["mean"], s["var"])
-
-
 @devscope.scoped(devscope.BN)
 def _bn(x, p, s, cfg, train, updates, path):
     # Folded form: y = x*a + b with per-channel a,b.  Stats accumulate in f32
@@ -207,9 +179,10 @@ def _bn(x, p, s, cfg, train, updates, path):
     # keeps the big elementwise chain bf16 — the naive (x-m)*rsqrt(...) form
     # makes XLA materialize an f32 copy of the whole activation (3 consumers
     # of the cast), which roughly doubles HBM traffic and is why the r3 bench
-    # sat at 14.5% MFU on a memory-bound-on-v5e model.
-    if cfg.fuse_bn:
-        return _bn_fused(x, p, s, cfg, train, updates, path)
+    # sat at 14.5% MFU on a memory-bound-on-v5e model.  No kernel: XLA fuses
+    # this into the neighbouring convolutions, and a Pallas epilogue in its
+    # place broke those fusions apart (1,109 vs 2,788 images/s on the chip,
+    # PERF.md section 6).
     if train:
         m = jnp.mean(x, axis=(0, 1, 2), dtype=jnp.float32)
         m2 = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=(0, 1, 2))
@@ -288,13 +261,12 @@ def resnet_forward(params, bn_state, images, cfg: ResNetConfig, train=True):
 
 
 def make_loss_fn(cfg: ResNetConfig):
-    """Per-device loss for the sharded train step; bn_state rides inside the
-    params pytree under '_bn' (non-trainable: its 'grads' are zeroed by
-    stop_gradient inside the step — see build_resnet_trainer)."""
+    """Per-device loss for parallel/train.make_train_step, in its shape for
+    a model with running state: (params, running statistics, batch) ->
+    (loss, new running statistics).  The statistics are batch statistics,
+    so what a step hands on is their mean over the dp shards."""
 
-    def loss_fn(bundle, batch):
-        params = bundle["params"]
-        bn_state = bundle["_bn"]
+    def loss_fn(params, bn_state, batch):
         logits, new_state = resnet_forward(params, bn_state, batch["image"],
                                            cfg, train=True)
         labels = batch["label"]
@@ -303,44 +275,19 @@ def make_loss_fn(cfg: ResNetConfig):
             nll = -jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
             loss = col.psum(jnp.sum(nll), DP) / col.psum(
                 jnp.asarray(nll.shape[0], jnp.float32), DP)
+        with jax.named_scope(devscope.GRAD_SYNC):
+            new_state = jax.tree.map(lambda a: col.pmean(a, DP), new_state)
         return loss, new_state
 
     return loss_fn
 
 
+BATCH_SPECS = {"image": P(DP), "label": P(DP)}
+
+
 @dataclasses.dataclass
-class ResNetTrainer:
-    cfg: ResNetConfig
-    mesh: object
-    state: dict
-    bn_state: dict
-    step_fn: object
-    multi_fn: object = None
-    # which of the two programs monitor.devscope has been told of
-    _step_seen = _multi_seen = False
-
-    def step(self, batch, lr):
-        if not self._step_seen:
-            self._step_seen = devscope.register(
-                "resnet.step", self.step_fn,
-                (self.state, self.bn_state, batch, lr))
-        self.state, self.bn_state, loss = self.step_fn(self.state,
-                                                       self.bn_state, batch, lr)
-        return loss
-
-    def run_steps(self, batches, lr):
-        """N steps in one dispatch (device-side lax.scan; see
-        parallel.train.make_train_step build_multi).  batches: pytree with a
-        leading [N] step axis staged via parallel.train.stack_batches."""
-        if self.multi_fn is None:
-            raise RuntimeError("trainer built without multi-step support")
-        if not self._multi_seen:
-            self._multi_seen = devscope.register(
-                "resnet.run_steps", self.multi_fn,
-                (self.state, self.bn_state, batches, lr))
-        self.state, self.bn_state, losses = self.multi_fn(
-            self.state, self.bn_state, batches, lr)
-        return losses
+class ResNetTrainer(StepTrainer):
+    label = "resnet"
 
 
 def build_resnet_trainer(cfg: ResNetConfig, mesh_spec: MeshSpec = None,
@@ -348,55 +295,20 @@ def build_resnet_trainer(cfg: ResNetConfig, mesh_spec: MeshSpec = None,
     """DP trainer: params replicated, batch sharded over dp, grads psum'd —
     the ParallelExecutor AllReduce mode (parallel_executor.cc:393) as one
     jitted SPMD program."""
-    from ..parallel.mesh import local_shard_map, make_mesh
-
     mesh_spec = mesh_spec or MeshSpec(1, 1, 1)
     mesh = mesh_spec.build(devices=devices)
     optimizer = optimizer or optim.momentum(0.9)
-    opt_init, opt_update = optimizer
 
     params, bn_state = init_resnet_params(jax.random.PRNGKey(seed), cfg)
     state = TrainState.create(params, optimizer)
-
+    state[RUNNING] = bn_state
     pspecs = jax.tree.map(lambda _: P(), params)
     sspecs = state_specs(pspecs, state)
-    bspecs = jax.tree.map(lambda _: P(), bn_state)
+    build = make_train_step(make_loss_fn(cfg), mesh, pspecs,
+                            jax.tree.map(lambda _: (DP,), params),
+                            optimizer, BATCH_SPECS)
+    step_fn, multi_fn = build(state), build.multi(state)
     with mesh:
         state = shard_pytree(state, sspecs, mesh)
-        bn_state = shard_pytree(bn_state, bspecs, mesh)
-
-    loss_fn = make_loss_fn(cfg)
-
-    def device_step(state, bn_state, batch, lr):
-        def wrapped(params):
-            return loss_fn({"params": params, "_bn": bn_state}, batch)
-
-        (loss, new_bn), grads = jax.value_and_grad(wrapped, has_aux=True)(
-            state["params"])
-        with jax.named_scope(devscope.GRAD_SYNC):
-            grads = jax.tree.map(lambda g: col.psum(g, DP), grads)
-            new_bn = jax.tree.map(lambda a: col.pmean(a, DP), new_bn)
-        with jax.named_scope(devscope.OPTIMIZER):
-            new_params, new_opt = opt_update(grads, state["opt"],
-                                             state["params"], lr)
-        return {"params": new_params, "opt": new_opt}, new_bn, loss
-
-    batch_specs = {"image": P(DP), "label": P(DP)}
-    mapped = local_shard_map(
-        device_step, mesh,
-        in_specs=(sspecs, bspecs, batch_specs, P()),
-        out_specs=(sspecs, bspecs, P()),
-    )
-    step_fn = jax.jit(mapped, donate_argnums=(0, 1))
-
-    def multi(state, bn_state, batches, lr):
-        def body(carry, batch):
-            st, bn = carry
-            st, bn, loss = mapped(st, bn, batch, lr)
-            return (st, bn), loss
-        (state, bn_state), losses = jax.lax.scan(body, (state, bn_state), batches)
-        return state, bn_state, losses
-
-    multi_fn = jax.jit(multi, donate_argnums=(0, 1))
-    return ResNetTrainer(cfg=cfg, mesh=mesh, state=state, bn_state=bn_state,
-                         step_fn=step_fn, multi_fn=multi_fn)
+    return ResNetTrainer(cfg=cfg, mesh=mesh, state=state, step_fn=step_fn,
+                         specs=sspecs, multi_fn=multi_fn)

@@ -22,12 +22,21 @@ from .mesh import local_shard_map
 from .. import warm as _warm
 from ..monitor import devscope as _devscope, memscope as _memscope
 
-__all__ = ["TrainState", "make_train_step", "StepTrainer", "shard_pytree",
-           "stack_batches", "TrainLoop"]
+__all__ = ["TrainState", "RUNNING", "make_train_step", "StepTrainer",
+           "shard_pytree", "stack_batches", "TrainLoop"]
+
+# The key of a TrainState's third entry, where a model has one: running
+# state, which a step hands on to the next and does not train (batch norm's
+# running statistics; an average of the weights would be another).  It sorts
+# after "params", so a TrainState flattens as (opt, params, running): with
+# these leaves ahead of the others the chip's compiler scheduled one more
+# copy into ResNet's one-step program.
+RUNNING = "running"
 
 
 class TrainState(dict):
-    """{'params': pytree, 'opt': pytree} — kept a plain dict so it is a
+    """{'params': pytree, 'opt': pytree} and, for a model with running
+    state, {RUNNING: pytree} beside them — kept a plain dict so it is a
     pytree (the Scope-of-persistables analogue, scope.h:46)."""
 
     @staticmethod
@@ -51,7 +60,12 @@ def _opt_state_specs(param_specs, opt_state):
 
 
 def state_specs(param_specs, state):
-    return {"params": param_specs, "opt": _opt_state_specs(param_specs, state["opt"])}
+    """Sharding specs of a TrainState; running state is replicated."""
+    specs = {"params": param_specs,
+             "opt": _opt_state_specs(param_specs, state["opt"])}
+    if RUNNING in state:
+        specs[RUNNING] = jax.tree.map(lambda _: P(), state[RUNNING])
+    return specs
 
 
 def shard_pytree(tree, specs, mesh):
@@ -65,14 +79,31 @@ def shard_pytree(tree, specs, mesh):
 
 def make_train_step(loss_fn, mesh, param_specs, grad_syncs, optimizer,
                     batch_specs, donate=True, warm_key=None):
-    """Build the jitted sharded train step.
+    """Build the jitted sharded train step: the one builder every
+    ``build_*_trainer`` goes through.  It decides where the gradients are
+    summed, what is donated, how N steps become one dispatch and, through
+    ``StepTrainer``, how the programs are named for a trace.
 
-    loss_fn(params_local, batch_local) -> scalar loss, written as per-device
-    shard_map code whose final loss is already globally reduced (replicated).
+    loss_fn is per-device shard_map code whose final loss is already
+    globally reduced (replicated), in one of two shapes, told apart by the
+    state template ``build(state)`` is handed, not by an argument:
+
+    - ``loss_fn(params_local, batch_local) -> loss`` where the state is
+      ``{params, opt}`` (BERT, OLMoE);
+    - ``loss_fn(params_local, running, batch_local) -> (loss, new_running)``
+      where the state holds ``RUNNING`` as well (ResNet's running
+      statistics).  Running state is replicated over the whole mesh, gets
+      no gradient and no optimizer slot, and is donated and scanned with the
+      rest of the state.  What it means across ``dp`` is the model's to
+      say: the loss function returns it already the same on every shard
+      (ResNet takes the ``pmean`` of its batch statistics under the
+      ``grad_sync`` scope); the builder reduces nothing of it.
+
     grad_syncs: pytree (matching params) of tuples of mesh axis names whose
     partial gradients must be psum'd (transformer.grad_sync_axes).
     batch_specs: pytree of PartitionSpec for the batch dict.
-    Returns step(state, batch, lr) -> (state, loss).
+    Returns build(state) -> step(state, batch, lr) -> (state, loss), and
+    build.multi(state) for the scan over staged batches.
 
     warm_key: a durable model identity (e.g. ``"bert_base"``) that routes
     compilation through the WarmStart executable store (warm.py): the step
@@ -91,15 +122,21 @@ def make_train_step(loss_fn, mesh, param_specs, grad_syncs, optimizer,
 
     def device_step(state, batch, lr):
         params = state["params"]
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        new_state = {}
+        if RUNNING in state:
+            (loss, new_state[RUNNING]), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, state[RUNNING], batch)
+        else:
+            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         flat_g, treedef = jax.tree.flatten(grads)
         flat_s = treedef.flatten_up_to(grad_syncs)
         with jax.named_scope(_devscope.GRAD_SYNC):
             flat_g = [_sync_grad(g, axes) for g, axes in zip(flat_g, flat_s)]
         grads = jax.tree.unflatten(treedef, flat_g)
         with jax.named_scope(_devscope.OPTIMIZER):
-            new_params, new_opt = opt_update(grads, state["opt"], params, lr)
-        return {"params": new_params, "opt": new_opt}, loss
+            new_state["params"], new_state["opt"] = opt_update(
+                grads, state["opt"], params, lr)
+        return new_state, loss
 
     def _mapped(state_template):
         """The shard_map'ed per-step function — single source of the
@@ -155,9 +192,10 @@ def make_train_step(loss_fn, mesh, param_specs, grad_syncs, optimizer,
 
 @dataclasses.dataclass
 class StepTrainer:
-    """What a ``build_*_trainer`` over ``make_train_step`` returns: the state
-    on the mesh, the jitted step and its scan.  A model's trainer names its
-    programs (``label``) and may count what a call is about to do
+    """What all three ``build_*_trainer`` (BERT, OLMoE, ResNet) return: the
+    state on the mesh (``params``, ``opt`` and, for ResNet, ``RUNNING``),
+    the jitted step of ``make_train_step`` and its scan.  A model's trainer
+    names its programs (``label``) and may count what a call is about to do
     (``_observe``, under a monitor session only)."""
 
     cfg: object

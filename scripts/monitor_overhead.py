@@ -122,58 +122,6 @@ def spans_per_step(exe, main_prog, feed, loss, steps=64):
         monitor.disable()
 
 
-def kernel_path_probe(steps=8):
-    """Confirm the manual-kernel path (ResNet ``fuse_bn`` — the Pallas
-    fused-BN epilogue) adds NO tracer-visible step overhead: all kernel
-    work lives INSIDE the jitted program (no io_callbacks, no extra spans,
-    no timeline events), so a monitored fused step emits exactly as many
-    tracer records as the reference step.  Wall time is reported for
-    context only — off-TPU the kernels run in the Pallas interpreter,
-    whose slowdown is expected and not what this gate bounds."""
-    import tempfile
-
-    import jax
-    from paddle_tpu import monitor
-    from paddle_tpu.models import resnet
-    from paddle_tpu.parallel import MeshSpec, optim
-
-    rng = np.random.RandomState(0)
-    batch = {"image": np.asarray(rng.rand(4, 32, 32, 3), np.float32),
-             "label": rng.randint(0, 10, (4,)).astype(np.int32)}
-    out = {}
-    for mode, fused in (("ref", False), ("fused", True)):
-        cfg = resnet.resnet_tiny_config(fuse_bn=fused)
-        tr = resnet.build_resnet_trainer(cfg, MeshSpec(1, 1, 1),
-                                         optimizer=optim.momentum(0.9))
-        mon = monitor.enable(tempfile.mkdtemp(prefix="mon_ovh_kernel_"),
-                             tracing=True, trace_ring=4096)
-        try:
-            float(tr.step(batch, 1e-2))            # compile + warm
-            c0 = mon.tracer.record_count()
-            e0 = len(mon.timeline.tail())
-            t0 = time.perf_counter()
-            for _ in range(steps):
-                loss = tr.step(batch, 1e-2)
-            float(loss)
-            dt = (time.perf_counter() - t0) / steps
-            out["step_ms_%s" % mode] = round(dt * 1e3, 4)
-            out["spans_per_step_%s" % mode] = round(
-                (mon.tracer.record_count() - c0) / steps, 4)
-            out["timeline_events_per_step_%s" % mode] = round(
-                (len(mon.timeline.tail()) - e0) / steps, 4)
-        finally:
-            monitor.disable()
-    out["kernel_extra_spans_per_step"] = round(
-        out["spans_per_step_fused"] - out["spans_per_step_ref"], 4)
-    out["kernel_extra_events_per_step"] = round(
-        out["timeline_events_per_step_fused"]
-        - out["timeline_events_per_step_ref"], 4)
-    out["pass_kernel_no_tracer_overhead"] = (
-        out["kernel_extra_spans_per_step"] <= 0
-        and out["kernel_extra_events_per_step"] <= 0)
-    return out
-
-
 def warm_precompile_probe(steps=48):
     """Confirm the WarmStart background pre-compile thread (warm.py
     notify_commit) adds NO tracer-visible step overhead: a monitored
@@ -536,10 +484,6 @@ def main():
                          "FleetRouter dispatch/reply bookkeeping costs "
                          "<= 0.5%% of a 1ms request floor (small "
                          "program, short loop — the tier-1 smoke shape)")
-    ap.add_argument("--kernels", action="store_true",
-                    help="probe the manual-kernel (fuse_bn) path for "
-                         "tracer-visible step overhead instead of the "
-                         "monitor-mode sweep")
     ap.add_argument("--warm", action="store_true",
                     help="probe the WarmStart background pre-compile "
                          "thread for tracer-visible step overhead")
@@ -561,9 +505,6 @@ def main():
         print(json.dumps(out))
         return 0 if (out["pass_trace_disabled_lt_0_5pct"]
                      and out["pass_router_dispatch_lt_0_5pct"]) else 2
-    if args.kernels:
-        print(json.dumps(kernel_path_probe(steps=max(2, args.steps // 40))))
-        return
     if args.warm:
         print(json.dumps(warm_precompile_probe(steps=max(8, args.steps // 6))))
         return

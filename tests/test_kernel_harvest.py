@@ -1,7 +1,6 @@
 """KernelHarvest receipts: bench mfu_ceiling_rel emission, the
 perf_ledger mfu_ceiling_rel gate (tolerated-absent for historical
-snapshots), chip_microbench sparse probes + --json artifact, and the
-monitor_overhead kernel-path tracer gate."""
+snapshots) and chip_microbench sparse probes + --json artifact."""
 
 import json
 import os
@@ -183,42 +182,3 @@ def test_chip_microbench_sparse_json(tmp_path):
     # independently rounded to 4 decimals, so allow that much slack)
     assert abs(roof["deepfm_step_floor_ms"]
                - (roof["gather_ms"] + roof["best_update_ms"])) < 5e-4
-
-
-# ---------------------------------------------------------------------------
-# monitor_overhead: the kernel path must be tracer-invisible
-# ---------------------------------------------------------------------------
-
-@pytest.mark.slow
-def test_kernel_path_adds_no_tracer_visible_overhead():
-    """slow: two full trainer compiles under the monitor (the
-    scripts/monitor_overhead.py --kernels gate, exercised end-to-end)."""
-    import monitor_overhead
-
-    out = monitor_overhead.kernel_path_probe(steps=2)
-    assert out["pass_kernel_no_tracer_overhead"] is True
-    assert out["kernel_extra_spans_per_step"] <= 0
-    assert out["kernel_extra_events_per_step"] <= 0
-    assert out["step_ms_fused"] > 0 and out["step_ms_ref"] > 0
-
-
-# ---------------------------------------------------------------------------
-# bench resnet line: fuse_bn knob reaches the config
-# ---------------------------------------------------------------------------
-
-def test_bench_resnet_fuse_bn_env_hatch(monkeypatch):
-    """PADDLE_TPU_FUSE_BN=0 must strip the kernel path from the bench
-    config (the A/B hatch); default is on."""
-    import bench
-
-    monkeypatch.delenv("PADDLE_TPU_FUSE_BN", raising=False)
-    assert bench._fuse_bn_enabled() is True          # bench default: on
-    monkeypatch.setenv("PADDLE_TPU_FUSE_BN", "0")
-    assert bench._fuse_bn_enabled() is False
-    monkeypatch.setenv("PADDLE_TPU_FUSE_BN", "1")
-    assert bench._fuse_bn_enabled() is True
-    # and the knob lands in the model config that the bench constructs
-    from paddle_tpu.models import resnet
-
-    assert resnet.resnet_tiny_config(
-        fuse_bn=bench._fuse_bn_enabled()).fuse_bn is True
