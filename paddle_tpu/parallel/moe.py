@@ -5,9 +5,10 @@
   expert, the rows gathered in that order, and two GROUPED matmuls run over
   the sorted rows (the Pallas ``megablox`` kernels that ship with JAX: row i
   meets the weights of its own group only, so nothing is computed for a
-  pair that was not routed), then the sort is undone and each token's k
-  rows are summed.  The FFN of a
-  ``TransformerConfig`` with ``n_experts > 0`` (models/olmoe.py).
+  pair that was not routed), the router weight riding the hidden rows
+  between them so that nothing after the down projection is kept for the
+  backward; then the sort is undone and each token's k rows are summed.
+  The FFN of a ``TransformerConfig`` with ``n_experts > 0`` (models/olmoe.py).
 - ``switch_moe_ffn``: top-1 (Switch) routing with a capacity limit that
   DROPS the overflow, experts sharded over a mesh axis (by default ``dp``,
   "EP rides DP") and exchanged with ``lax.all_to_all`` over ICI.  Net-new
@@ -73,6 +74,13 @@ def moe_param_specs(ep_axis=DP):
         {"router": leaf(), "w1": leaf(), "w2": leaf()})
 
 
+def _per_expert(top_e, n):
+    """Assignments to each of ``n`` experts, int32 [n]: a compare and a column
+    sum (0.2 ms for 131,072 on the chip; ``jnp.bincount`` scatters: 1.2)."""
+    return jnp.sum(top_e.reshape(-1, 1) == jnp.arange(n), axis=0,
+                   dtype=jnp.int32)
+
+
 @devscope.scoped(devscope.ROUTER)
 def route_top_k(router, x, k):
     """Router of a dropless layer on the tokens ``x`` [T, E]: the k largest
@@ -91,7 +99,7 @@ def route_top_k(router, x, k):
     lse = jax.nn.logsumexp(logits, axis=-1)
     probs = jnp.exp(logits - lse[:, None])
     top_p, top_e = jax.lax.top_k(probs, k)
-    counts = col.psum(jnp.bincount(top_e.reshape(-1), length=n), DP)
+    counts = col.psum(_per_expert(top_e, n), DP)
     tokens = x.shape[0] * col.axis_size_in(DP)
     share = counts.astype(jnp.float32) / (tokens * k)
     mean_p = col.psum(jnp.sum(probs, axis=0), DP) / tokens
@@ -103,53 +111,44 @@ def route_top_k(router, x, k):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _dispatch(x, order, inv, k):
-    """Row i of the result is token ``order[i] // k``: x [T, E] gathered
-    into the sorted order of its T*k assignments.  The transpose of that
-    gather is a scatter-add; ``order`` is a permutation with inverse
-    ``inv``, so the backward is a gather too, and a sum over each token's
-    k rows."""
+    """Row i of the result is token ``order[i] // k``: x [T, E] gathered into
+    the sorted order of its T*k assignments.  ``order`` is a permutation with
+    inverse ``inv``, so the transpose is a gather too, ``_combine``."""
     return x[order // k]
 
 
-def _dispatch_fwd(x, order, inv, k):
-    return x[order // k], inv
-
-
-@devscope.scoped(devscope.MOE)
-def _dispatch_bwd(k, inv, g):
-    dx = jnp.sum(g[inv].reshape(-1, k, g.shape[-1]).astype(jnp.float32),
-                 axis=1).astype(g.dtype)
-    return dx, None, None
-
-
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine(rows, order, inv, k):
+    """Token t of the result is the float32 sum of its k rows, ``rows[inv]``
+    (the sort undone) k at a time: ``_dispatch``'s transpose, as it is its."""
+    back = rows[inv].reshape((-1, k) + rows.shape[1:])
+    return jnp.sum(back.astype(jnp.float32), axis=1).astype(rows.dtype)
 
 
 @jax.custom_vjp
-def _unsort(rows, order, inv):
-    """``rows[inv]``: the sorted rows back in assignment order (token-major).
-    Backward ``g[order]``, a gather where autodiff would scatter."""
-    return rows[inv]
+def _move(v, to, back):
+    """Element i of the vector ``v`` moved to place ``to[i]`` (a permutation,
+    ``back`` its inverse) by a sort: 0.2 ms for 131,072 floats on the chip
+    where the gather ``v[back]`` takes 1.2.  Its transpose moves them back."""
+    return jax.lax.sort((to, v), num_keys=1)[1]
 
 
-def _unsort_fwd(rows, order, inv):
-    return rows[inv], order
-
-
-@devscope.scoped(devscope.MOE)
-def _unsort_bwd(order, g):
-    return g[order], None, None
-
-
-_unsort.defvjp(_unsort_fwd, _unsort_bwd)
+# each one's backward is the other on the cotangent; a custom_vjp backward is
+# traced on its own, in the backward pass, and names its scope itself
+_scoped = devscope.scoped(devscope.MOE)
+_dispatch.defvjp(lambda *a: (_dispatch(*a), a[1:3]),      # keeps order, inv
+                 _scoped(lambda k, res, g: (_combine(g, *res, k), None, None)))
+_combine.defvjp(lambda *a: (_combine(*a), a[1:3]),
+                _scoped(lambda k, res, g: (_dispatch(g, *res, k), None, None)))
+_move.defvjp(lambda v, to, back: (_move(v, to, back), (back, to)),
+             _scoped(lambda res, g: (_move(g, *res), None, None)))
 
 
 def _tiling(m, k, n):
-    """Tiles (rows, contraction, columns) of the megablox kernels: 512 x
-    1024 x 1024 at training sizes (the expert FFN of one OLMoE layer,
-    forward and backward, took 36.4 ms with it, 39.4 ms at 512 x 512 x 1024
-    and 452 ms at the kernel's default 128^3; PERF.md section 6, PR 27),
-    the whole dimension where that is smaller."""
+    """Tiles (rows, contraction, columns) of the megablox kernels: 512 x 1024
+    x 1024 at training sizes (one OLMoE layer's expert FFN, forward and
+    backward, took 36.4 ms with it, 39.4 at 512 x 512 x 1024, 452 at the
+    default 128^3; PERF.md section 6, PR 27), a smaller dimension whole."""
     return min(m, 512), min(k, 1024), min(n, 1024)
 
 
@@ -169,34 +168,30 @@ def _gmm(rows, weights, group_sizes, transpose_rhs=False):
 
 @jax.custom_vjp
 def _grouped_matmul(rows, weights, group_sizes):
-    """rows [M, K] sorted by group, weights [G, K, N]: row i times the
-    weights of its own group, nothing for a pair that was not routed.  The
-    Pallas grouped matmul that ships with JAX (``megablox``): a quarter
-    faster here than XLA's lowering of ``jax.lax.ragged_dot`` (36.4 against
-    48.2 ms), and its instructions keep the program's scope in their
-    ``op_name``, which XLA's own ragged-dot calls do not."""
+    """rows [M, K] sorted by group, weights [G, K, N]: row i times the weights
+    of its own group, nothing for a pair that was not routed.  The Pallas
+    grouped matmul that ships with JAX (``megablox``): a quarter faster here
+    than XLA's lowering of ``jax.lax.ragged_dot`` (36.4 against 48.2 ms), and
+    its instructions keep the program's scope in their ``op_name``."""
     return _gmm(rows, weights, group_sizes)
 
 
-def _grouped_matmul_fwd(rows, weights, group_sizes):
-    return _gmm(rows, weights, group_sizes), (rows, weights, group_sizes)
-
-
-# a custom_vjp backward is traced on its own, in the backward pass: it names
-# its scope itself
 @devscope.scoped(devscope.MOE)
 def _grouped_matmul_bwd(res, g):
     rows, weights, group_sizes = res
-    m = rows.shape[0]
-    tiling = _tiling(m, rows.shape[1], g.shape[1])
+    tiling = _tiling(*rows.shape, g.shape[1])
     d_weights = tgmm(
         _whole_row_tiles(rows, tiling[0]).swapaxes(0, 1),
         _whole_row_tiles(g, tiling[0]), group_sizes, weights.dtype, tiling,
         num_actual_groups=weights.shape[0], interpret=not on_tpu())
-    return _gmm(g, weights, group_sizes, transpose_rhs=True), d_weights, None
+    d_rows = _gmm(g, weights, group_sizes, transpose_rhs=True)
+    # dW where its operands are live: left to itself the scheduler puts all
+    # six tgmm at the end of the step and gathers their rows a second time
+    d_rows, d_weights = jax.lax.optimization_barrier((d_rows, d_weights))
+    return d_rows, d_weights, None
 
 
-_grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+_grouped_matmul.defvjp(lambda *args: (_gmm(*args), args), _grouped_matmul_bwd)
 
 
 @devscope.scoped(devscope.MOE)
@@ -204,23 +199,28 @@ def dropless_moe_ffn(params, x, k):
     """Top-k dropless expert FFN.  x [T, E] (flatten batch and sequence
     before the call); returns ``(y [T, E], aux)`` with
     ``y_t = sum_{e in top k} p_te * down_e(silu(gate_e x_t) * up_e x_t)``
-    and ``aux`` as ``route_top_k`` gives it."""
-    T = x.shape[0]
+    and ``aux`` as ``route_top_k`` gives it.
+
+    ``p_te`` multiplies the HIDDEN rows (the triple product in float32,
+    rounded once); the down projection is linear, so ``y`` is the same.  Its
+    gradient then needs the hidden rows, which the down matmul keeps anyway,
+    and nothing computed after them: no residual follows the down
+    projection, and a rematerialised forward stops at the gate/up matmul."""
     n = params["router"].shape[-1]
     top_p, top_e, aux = route_top_k(params["router"], x, k)
 
-    expert = top_e.reshape(-1)                                   # [T*k]
-    order = jnp.argsort(expert, stable=True).astype(jnp.int32)
+    order = jnp.argsort(top_e.reshape(-1), stable=True).astype(jnp.int32)
     inv = jnp.argsort(order).astype(jnp.int32)
-    group_sizes = jnp.bincount(expert, length=n).astype(jnp.int32)
+    group_sizes = _per_expert(top_e, n)
 
     rows = _dispatch(x, order, inv, k)                           # [T*k, E]
+    weight = _move(top_p.reshape(-1), inv, order)                # [T*k]
     gate, up = jnp.split(
         _grouped_matmul(rows, params["we_gate_up"], group_sizes), 2, axis=-1)
-    out = _grouped_matmul(jax.nn.silu(gate) * up, params["we_down"],
-                          group_sizes)                           # [T*k, E]
-    out = _unsort(out, order, inv).reshape(T, k, -1).astype(jnp.float32)
-    return jnp.sum(out * top_p[..., None], axis=1).astype(x.dtype), aux
+    hidden = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+              * weight[:, None]).astype(x.dtype)
+    out = _grouped_matmul(hidden, params["we_down"], group_sizes)
+    return _combine(out, order, inv, k), aux
 
 
 def switch_moe_ffn(params, x, ep_axis=DP, capacity_factor=1.25):

@@ -2,6 +2,13 @@
 it computed: one cell of the benchmark through the program's normal path.
 
     chiprun -- python3 scripts/lm_head_profile.py bert_base.s512_scan 7 [--ones]
+    chiprun -- python3 scripts/lm_head_profile.py olmoe_1b_7b.s4096_scan 7 \
+        --scope moe --scope router --top 80
+
+``--scope`` lists another scope's instructions (``monitor.devscope``'s
+vocabulary, several allowed; an instruction belongs to its innermost scope,
+as the benchmark's shares count it), and the step's busiest instructions
+are printed with their ``op_name`` whatever their scope.
 
 Builds the cell's trainer and stages its batches as the scan driver does,
 runs one dispatch under a monitor session (the scan driver opens none, so
@@ -28,6 +35,7 @@ def main(argv=None):
     ap.add_argument("cell")
     ap.add_argument("seed", type=int)
     ap.add_argument("--ones", action="store_true")
+    ap.add_argument("--scope", action="append")
     ap.add_argument("--top", type=int, default=30)
     args = ap.parse_args(argv)
     sys.path.insert(0, ROOT)
@@ -86,22 +94,28 @@ def main(argv=None):
             trace_reduce.find_xplane(os.path.join(tmp, "trace")))).devices[0]
 
     names = next(iter(devscope.scope_maps().values()))
-    rows, totals = [], {}
+    scopes = args.scope or ["lm_head"]
+    rows, busiest, totals = [], [], {}
     for name, ns in dev["by_name"].items():
         op = names.get(name, "")
         key = devscope.classify(op) if op else ("unmapped", None)
         totals[key] = totals.get(key, 0.0) + ns
-        if "lm_head" in op:
-            rows.append((ns / steps / 1e6, name, key[0],
-                         re.sub(r".*?lm_head\)?/", "", op)[-100:]))
+        busiest.append((ns / steps / 1e6, name, key[0], op[-130:]))
+        if key[1] in scopes:
+            rows.append((ns / steps / 1e6, name, key[0], re.sub(
+                r".*?(%s)\)*/" % "|".join(scopes), "", op)[-100:]))
     print("%s, %s: device busy %.3f ms a step, last loss %.6g"
           % (args.cell, "mask of ones" if args.ones else "the cell's mask",
              dev["busy_ns"] / steps / 1e6, losses[-1]))
     for key, ns in sorted(totals.items(), key=lambda kv: -kv[1])[:14]:
         print("  %-10s %-12s %8.3f ms a step" % (key + (ns / steps / 1e6,)))
-    print("instructions under lm_head:")
-    for ms, name, phase, op in sorted(rows, reverse=True)[:args.top]:
-        print("  %7.3f ms  %-42s %-8s %s" % (ms, name, phase, op))
+    for title, found, top in (
+            ("the step's busiest instructions", busiest, 12),
+            ("instructions under " + ", ".join(scopes), rows, args.top)):
+        print("%s (%d, %.3f ms a step):"
+              % (title, len(found), sum(r[0] for r in found)))
+        for ms, name, phase, op in sorted(found, reverse=True)[:top]:
+            print("  %7.3f ms  %-42s %-9s %s" % (ms, name, phase, op))
     return 0
 
 

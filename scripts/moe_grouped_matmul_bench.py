@@ -16,7 +16,10 @@ warm-up:
 - ``layer.gather_backward`` / ``layer.scatter_backward``: the whole layer
   (``moe.dropless_moe_ffn``: router, sort, dispatch, experts, combine) as the
   program ships it, and with the dispatch and combine left to autodiff,
-  whose transposes are scatter-adds.
+  whose transposes are scatter-adds;
+- ``layer.remat``: the layer under ``jax.checkpoint``, as the cell runs it
+  (``transformer.run_layers``): forward, the forward again as far as the
+  backward needs it, backward.
 
 Before any time is taken it holds the shipped primitive to
 ``jax.lax.ragged_dot`` at that shape and the real 512 x 1024 x 1024 tiling
@@ -147,20 +150,27 @@ def main(out_path=None):
                 a, b, s, preferred_element_type=a.dtype, tiling=tiling)),
             rows, *w, sizes)
 
+    def layer(p, x):
+        return moe.dropless_moe_ffn(p, x, K)[0]
+
     def layer_grad():
-        return jax.jit(jax.grad(lambda p, x: jnp.sum(moe.dropless_moe_ffn(
-            p, x, K)[0].astype(jnp.float32)), argnums=(0, 1)))
+        return jax.jit(jax.grad(lambda p, x: jnp.sum(
+            layer(p, x).astype(jnp.float32)), argnums=(0, 1)))
+
+    def remat(p, x, g):
+        # the result is kept, so the first forward runs whole
+        y, vjp = jax.vjp(jax.checkpoint(layer), p, x)
+        return y, vjp(g)
 
     note("layer.gather_backward", layer_grad(), params, x)
-    note("layer.forward", jax.jit(lambda p, x: moe.dropless_moe_ffn(p, x, K)[0]),
-         params, x)
-    keep = moe._dispatch, moe._unsort
-    moe._dispatch = lambda x, order, inv, k: x[order // k]
-    moe._unsort = lambda rows, order, inv: rows[inv]
+    note("layer.remat", jax.jit(remat), params, x, probe[:T])
+    note("layer.forward", jax.jit(layer), params, x)
+    keep = moe._dispatch, moe._combine
+    moe._dispatch, moe._combine = (f.fun for f in keep)   # no custom_vjp
     try:
         note("layer.scatter_backward", layer_grad(), params, x)
     finally:
-        moe._dispatch, moe._unsort = keep
+        moe._dispatch, moe._combine = keep
     flops = 3 * K * 6.0 * E * F * T
     got["required_tflop_fwd_bwd"] = flops / 1e12
     got["least_ms_at_197_tflops"] = flops / 197e12 * 1e3

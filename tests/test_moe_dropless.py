@@ -4,6 +4,7 @@ the k largest router probabilities as weights, zero elsewhere.  float32 on
 the CPU, so the tolerance can be 1e-5."""
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -69,10 +70,10 @@ def test_layer_equals_the_dense_formula(name):
 
 @pytest.mark.parametrize("name", CASES)
 def test_gradients_equal_the_dense_formulas(name):
-    """Every input's gradient: the hand-written backward of the dispatch and
-    the combine (gathers by the inverse permutation, where autodiff would
-    scatter) and the grouped matmul's (``gmm`` transposed for dX, ``tgmm``
-    for dW)."""
+    """Every input's gradient: the dispatch and the combine as each other's
+    backward (gathers by the inverse permutation, where autodiff would
+    scatter), the router weight's through the hidden rows it rides on, and
+    the grouped matmul's (``gmm`` transposed for dX, ``tgmm`` for dW)."""
     params, x, k = _case(name)
     probe = jax.random.normal(jax.random.PRNGKey(9), x.shape, jnp.float32)
 
@@ -84,6 +85,119 @@ def test_gradients_equal_the_dense_formulas(name):
     want = jax.grad(scalar(lambda p, x: _dense(p, x, k)), argnums=(0, 1))(params, x)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, w, **TOL)
+
+
+def _skewed_sort(tokens, k):
+    """``order`` and ``inv`` of a routing where expert 1 takes half the
+    assignments and expert 5 none."""
+    expert = jax.random.choice(
+        jax.random.PRNGKey(tokens), N, (tokens * k,),
+        p=jnp.array([.1, .5, .1, .1, .1, 0., .05, .05]))
+    assert (np.bincount(np.asarray(expert), minlength=N)[5] == 0
+            and np.bincount(np.asarray(expert)).max() > tokens * k // 3)
+    order = jnp.argsort(expert, stable=True).astype(jnp.int32)
+    return order, jnp.argsort(order).astype(jnp.int32)
+
+
+@pytest.mark.parametrize("k", (1, 2, 8))
+@pytest.mark.parametrize("which", ("dispatch", "combine"))
+def test_dispatch_and_combine_are_each_other_s_transpose(which, k):
+    """Each one's result and ``vjp`` equal the plain gather / un-sort and
+    sum written without ``custom_vjp`` and differentiated by autodiff (which
+    scatters): so the backward of one IS the other."""
+    tokens = 11
+    order, inv = _skewed_sort(tokens, k)
+    ks = jax.random.split(jax.random.PRNGKey(k), 2)
+    if which == "dispatch":
+        fn = lambda a: moe._dispatch(a, order, inv, k)
+        plain = lambda a: a[order // k]
+        a = jax.random.normal(ks[0], (tokens, E), jnp.float32)
+    else:
+        fn = lambda a: moe._combine(a, order, inv, k)
+        plain = lambda a: jnp.sum(a[inv].reshape(tokens, k, E), axis=1)
+        a = jax.random.normal(ks[0], (tokens * k, E), jnp.float32)
+    got, got_vjp = jax.vjp(fn, a)
+    want, want_vjp = jax.vjp(plain, a)
+    np.testing.assert_allclose(got, want, **TOL)
+    g = jax.random.normal(ks[1], want.shape, jnp.float32)
+    np.testing.assert_allclose(got_vjp(g)[0], want_vjp(g)[0], **TOL)
+    # and of a vector, as the router weights ride: k = 1 is a permutation
+    v = g[:tokens, 0]
+    np.testing.assert_allclose(
+        jax.grad(lambda v: jnp.sum(moe._dispatch(v, order, inv, k) ** 2))(v),
+        jax.grad(lambda v: jnp.sum(v[order // k] ** 2))(v), **TOL)
+
+
+def test_a_vector_moves_by_a_sort_as_it_would_by_a_gather():
+    """The router weights reach the sorted rows by ``_move`` (a sort by the
+    target place: a scalar gather is six times slower on the chip); result
+    and ``vjp`` equal the gather's, differentiated by autodiff."""
+    order, inv = _skewed_sort(13, 4)
+    v, g = jax.random.normal(jax.random.PRNGKey(4), (2, 52), jnp.float32)
+    got, got_vjp = jax.vjp(lambda v: moe._move(v, inv, order), v)
+    want, want_vjp = jax.vjp(lambda v: v[order], v)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got_vjp(g)[0], want_vjp(g)[0])
+
+
+@pytest.mark.parametrize("routing", ("random", "skewed", "one_expert_takes_all"))
+def test_assignments_per_expert_equal_bincount(routing):
+    """Group sizes and the router's shares come from a compare and a column
+    sum (``jnp.bincount``'s scatter-add took 1.2 ms a layer and pass on the
+    chip): the same counts, and the stable sort they describe."""
+    key = jax.random.PRNGKey(len(routing))
+    top_e = {"random": jax.random.randint(key, (37, 3), 0, N),
+             "skewed": jax.random.choice(key, N, (37, 3), p=jnp.array(
+                 [.02, .6, .02, .3, .02, 0., .02, .02])),
+             "one_expert_takes_all": jnp.full((37, 3), 6)}[routing]
+    counts = moe._per_expert(top_e, N)
+    assert counts.dtype == jnp.int32
+    np.testing.assert_array_equal(
+        counts, np.bincount(np.asarray(top_e).ravel(), minlength=N))
+    # row i of the sorted rows belongs to the group the sizes say
+    order = np.argsort(np.asarray(top_e).ravel(), kind="stable")
+    np.testing.assert_array_equal(np.asarray(top_e).ravel()[order],
+                                  np.repeat(np.arange(N), np.asarray(counts)))
+
+
+def _eqns_named(jaxpr, name):
+    return [e for e in jaxpr.eqns if e.params.get("name") == name]
+
+
+def test_nothing_after_the_down_projection_is_kept_for_the_backward():
+    """The router weight is applied BEFORE the down projection, so the
+    backward needs nothing computed from that matmul's output, and under
+    ``jax.checkpoint`` the recomputed forward stops at the gate/up matmul.
+    Read off the forward's jaxpr: its outputs are the layer's result and
+    every array the backward keeps."""
+    params, x, k = _case("an_expert_with_no_token")
+    fwd = jax.make_jaxpr(lambda p, x: jax.vjp(
+        lambda p, x: moe.dropless_moe_ffn(p, x, k)[0], p, x))(params, x).jaxpr
+    gate_up, down = _eqns_named(fwd, "gmm")
+    assert down.outvars[0].aval.shape[1] == E          # [T*k, E]: the down one
+    tainted = set(down.outvars)
+    for eqn in fwd.eqns[fwd.eqns.index(down) + 1:]:
+        if tainted.intersection(v for v in eqn.invars
+                                if not isinstance(v, jax.extend.core.Literal)):
+            tainted.update(eqn.outvars)
+    y, *kept = fwd.outvars
+    assert y in tainted                     # the walk does follow the data
+    assert kept and not tainted.intersection(kept)
+    # so the remat'd backward runs 5 grouped matmuls and 2 transposed ones
+    # where a weight applied after the down projection made it 6 and 2
+    grad = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(jax.checkpoint(
+        lambda p, x: moe.dropless_moe_ffn(p, x, k)[0])(p, x)),
+        argnums=(0, 1)))(params, x)
+    assert _count(grad.jaxpr, "gmm") == 5 and _count(grad.jaxpr, "tgmm") == 2
+
+
+def _count(jaxpr, name):
+    """Calls of the jitted function ``name`` in ``jaxpr``, nested ones too."""
+    total = len(_eqns_named(jaxpr, name))
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            total += _count(sub, name)
+    return total
 
 
 # -- the kernels at their real tiling ------------------------------------------
