@@ -27,6 +27,24 @@ the step's blocks; the kernel bodies loop over the rows and unroll over the
 head-blocks (the compiler interleaves the independent heads), each
 (row, head) computed exactly as a step of its own would.
 
+Two more modes of the packed entry, both of the same kernels:
+
+- grouped queries (``n_kv_heads`` < ``n_heads``, head width a multiple of
+  128): k and v are [B, S, n_kv_heads*D] and query head h reads key/value
+  head ``h // (n_heads // n_kv_heads)``, addressed by the index maps alone;
+  the dk/dv sweep runs once per KEY/VALUE head and its innermost grid axis
+  walks the group's query heads, so dk and dv are summed over the group in
+  the kernel's scratch.  One (row, head) pair a grid step whatever S is,
+  and the two-sweep backward even at one block.
+- a sliding window (``window`` = W < S, causal): query i sees keys j with
+  i - W < j <= i.  The kv axis of the grid is the BAND (``band_steps``: 9
+  blocks of 512 for W = 4096, not S / 512), the index maps place step j of
+  q block i at kv block ``last(i) - (steps - 1) + j`` (below 0: skipped),
+  every visited block is masked, and the kernels carry names of their own
+  (``flash_swa_fwd``, ``flash_swa_bwd_dq``, ``flash_swa_bwd_dkv``) so that a
+  trace's reader can tell a windowed layer's calls from a full one's.  A
+  window of S or more is the causal mask and runs the causal kernels.
+
 All matmuls feed the MXU in the input dtype with f32 accumulation.
 interpret=True (CPU tests) is selected automatically off-TPU.
 """
@@ -61,10 +79,14 @@ def _lanes_to(x, n):
     return x[:, :n]
 
 
-def packed_layout_supported(n_heads, head_dim):
+def packed_layout_supported(n_heads, head_dim, n_kv_heads=None):
     """True when the packed [B, S, H*D] entry can address this head shape
-    (Mosaic lane-tiling rule; see _heads_per_block)."""
+    (Mosaic lane-tiling rule; see _heads_per_block).  Grouped queries need
+    a head-block that is one head (D a multiple of 128)."""
     hpb = max(1, LANES // head_dim)
+    if n_kv_heads not in (None, n_heads) and (
+            hpb != 1 or n_heads % n_kv_heads):
+        return False
     return (head_dim * hpb) % LANES == 0 and n_heads % hpb == 0
 
 
@@ -138,23 +160,63 @@ def step_geometry(B, S, n_head_blocks, lanes, itemsize):
     return G, Hg
 
 
-def grid_geometry(B, S, Sk, n_head_blocks, lanes, itemsize, bq, bk):
+def grid_geometry(B, S, Sk, n_head_blocks, lanes, itemsize, bq, bk, group=1):
     """(G, Hg, grid steps along the batch/head axis) for blocks of bq x bk:
     ``step_geometry`` where the sequence is one block both ways, one
-    (row, head-block) pair a step otherwise."""
+    (row, head-block) pair a step otherwise (and wherever ``group`` query
+    heads share a key/value head)."""
     G, Hg = (step_geometry(B, max(S, Sk), n_head_blocks, lanes, itemsize)
-             if S == bq and Sk == bk else (1, 1))
+             if S == bq and Sk == bk and group == 1 else (1, 1))
     return G, Hg, (B // G) * (n_head_blocks // Hg)
 
 
-def packed_grid(B, S, n_heads, head_dim, block_q, block_k, itemsize=2):
+def _kv_span(i, bq, bk, window):
+    """(first, last) kv block that q block i sees through the window: query
+    rows [i*bq, i*bq + bq) see keys from ``i*bq - window + 1`` to
+    ``i*bq + bq - 1``."""
+    return max((i * bq - window + 1) // bk, 0), (i * bq + bq - 1) // bk
+
+
+def band_steps(S, bq, bk, window):
+    """Windowed mode: (kv blocks one q block visits, q blocks one kv block
+    visits), the largest over the blocks, so the static length of the
+    sweeps' inner grid axes."""
+    nq, nk = S // bq, S // bk
+    kv = max(hi - lo + 1 for lo, hi in
+             (_kv_span(i, bq, bk, window) for i in range(nq)))
+    q = max(min((j * bk + bk - 2 + window) // bq, nq - 1) - (j * bk) // bq + 1
+            for j in range(nk))
+    return kv, q
+
+
+def kv_blocks(S, bq, bk, causal=True, window=None):
+    """(visited, skipped): the (q block, kv block) grid steps of one head's
+    forward sweep that compute, and those the grid holds and skips.  A
+    windowed sweep's grid is its band, so it skips only the band's steps
+    that fall before the sequence's start."""
+    nq, nk = S // bq, S // bk
+    steps = nk
+    if window is not None and window < S:
+        steps = band_steps(S, bq, bk, window)[0]
+    elif causal:
+        window = S
+    if window is None:
+        return nq * nk, 0
+    seen = sum(hi - lo + 1 for lo, hi in
+               (_kv_span(i, bq, bk, window) for i in range(nq)))
+    return seen, nq * steps - seen
+
+
+def packed_grid(B, S, n_heads, head_dim, block_q, block_k, itemsize=2,
+                n_kv_heads=None):
     """What ``flash_attention_packed`` runs for these shapes, for whoever
     wants to say so without tracing it (the trainers' monitor gauges):
     (pairs per grid step, grid steps of one layer's pass)."""
     hpb = _heads_per_block(head_dim)
     bq, bk = min(block_q, S), min(block_k, S)
     G, Hg, steps = grid_geometry(B, S, S, n_heads // hpb, head_dim * hpb,
-                                 itemsize, bq, bk)
+                                 itemsize, bq, bk,
+                                 n_heads // (n_kv_heads or n_heads))
     return G * Hg, steps * (S // bq)
 
 
@@ -164,9 +226,15 @@ class _Geom:
     addressed by the BlockSpec index maps, so the model never materializes a
     [B, H, S, D] transpose (the r2 wrapper's main HBM cost).  A block is G
     rows of the leading axis by Hg head-blocks (``grid_geometry``; 1 by 1
-    wherever the sequence is more than one block)."""
+    wherever the sequence is more than one block).
 
-    def __init__(self, q, k, H, bq, bk):
+    ``Hkv`` < H: grouped queries, k and v hold Hkv heads and q head h reads
+    kv head ``h // group``.  ``window`` (None: none): the kv axis of the
+    q-major sweeps has ``kv_steps`` steps and the q axis of the kv-major
+    sweep ``q_steps`` a head (``band_steps``), placed by ``kv_at`` /
+    ``q_at``."""
+
+    def __init__(self, q, k, H, bq, bk, Hkv=None, window=None):
         B, self.S, E = q.shape
         self.Sk = k.shape[1]
         if H is None:
@@ -176,14 +244,42 @@ class _Geom:
             self.hpb = _heads_per_block(self.D)
             assert H % self.hpb == 0 and (self.D * self.hpb) % LANES == 0, (H, self.D)
             self.Hb = H // self.hpb   # head-blocks per batch row
+        self.group = 1 if Hkv in (None, H) else H // Hkv
+        assert self.group == 1 or (self.hpb == 1 and H == Hkv * self.group)
         self.qw = self.D * self.hpb   # width of one head-block (lane dim)
         self.G, self.Hg, self.grid_b = grid_geometry(
-            B, self.S, self.Sk, self.Hb, self.qw, q.dtype.itemsize, bq, bk)
+            B, self.S, self.Sk, self.Hb, self.qw, q.dtype.itemsize, bq, bk,
+            self.group)
+        self.bq, self.bk = bq, bk
+        self.nq, self.nk = self.S // bq, self.Sk // bk
+        self.one_block = self.Sk == bk
+        # the band: only where the sequence is several kv blocks (in one
+        # block a window is its mask alone)
+        self.window = window
+        self.band = window is not None and not self.one_block
+        self.kv_steps, self.q_steps = (
+            band_steps(self.S, bq, bk, window) if self.band
+            else (self.nk, self.nq))
         self.o_shape = q.shape
         self.dkv_shape = k.shape
         # stats are 4-D so the block's last dim equals the array's (Mosaic
         # tiling rule): [row, head-block group, S, heads of the group]
         self.stat_shape = (B, self.Hb // self.Hg, self.S, self.Hg * self.hpb)
+
+    def kv_at(self, i, j):
+        """The kv block that step j of q block i's sweep visits (below 0:
+        before the sequence's start, skipped)."""
+        if not self.band:
+            return j
+        return (i * self.bq + self.bq - 1) // self.bk - (self.kv_steps - 1) + j
+
+    def q_at(self, j, t):
+        """The q block that step t of kv block j's sweep visits (nq and
+        above: past the end, skipped); with grouped queries the axis walks
+        the group's heads, ``q_steps`` steps each."""
+        if self.group > 1:
+            t = t % self.q_steps
+        return (j * self.bk) // self.bq + t if self.band else t
 
     # index maps: 3-arg (b, i, j) with i indexing q rows, j kv rows; b runs
     # over (row group, head-block group), head-block groups fastest
@@ -193,11 +289,35 @@ class _Geom:
 
     def kmap(self):
         n = self.Hb // self.Hg
-        return lambda b, i, j=0: (b // n, j, b % n)
+        if self.group == 1 and not self.band:
+            return lambda b, i, j=0: (b // n, j, b % n)
+        return lambda b, i, j=0: (b // n, jnp.maximum(self.kv_at(i, j), 0),
+                                  (b % n) // self.group)
 
     def smap(self):
         n = self.Hb // self.Hg
         return lambda b, i, j=0: (b // n, b % n, i, 0)
+
+    def dkv_maps(self):
+        """(q rows, kv rows, row statistics) index maps of the kv-major
+        sweep, grid (b, kv block, t): b runs over (row, KEY/VALUE head) and
+        t over the group's query heads times the q blocks a kv block
+        visits."""
+        if self.group == 1 and not self.band:
+            qm, km, sm = self.qmap(), self.kmap(), self.smap()
+            return (lambda b, j, i: qm(b, i, j), lambda b, j, i: km(b, i, j),
+                    lambda b, j, i: sm(b, i, j))
+        n = self.Hb // self.group           # kv heads
+
+        def head(b, t):
+            return (b % n) * self.group + t // self.q_steps
+
+        def rows(j, t):
+            return jnp.minimum(self.q_at(j, t), self.nq - 1)
+
+        return (lambda b, j, t: (b // n, rows(j, t), head(b, t)),
+                lambda b, j, t: (b // n, j, b % n),
+                lambda b, j, t: (b // n, head(b, t), rows(j, t), 0))
 
     def q_spec(self, bq, index_map=None):
         return pl.BlockSpec((self.G, bq, self.Hg * self.qw),
@@ -229,19 +349,38 @@ def _cat(cols):
     return cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
 
 
-def _scores(q, k, scale, causal, q0, k0):
-    """[bq, bk] f32 scaled scores of one head, future positions masked."""
+def _seen(shape, q0, k0, window):
+    """[bq, bk] bool: key position <= query position, and inside the window
+    (query - key < window) where there is one."""
+    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    if window is None:
+        return qpos >= kpos
+    return (qpos >= kpos) & (qpos - kpos < window)
+
+
+def _scores(q, k, scale, causal, q0, k0, window=None):
+    """[bq, bk] f32 scaled scores of one head, future positions (and those
+    behind the window) masked."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if causal:
-        qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(qpos >= kpos, s, NEG_INF)
+        s = jnp.where(_seen(s.shape, q0, k0, window), s, NEG_INF)
     return s
 
 
+def _kv_runs(i, jb, bq, bk, causal, window, band):
+    """Whether kv block ``jb`` holds a key that q block i sees; None where
+    every block does."""
+    if band:        # jb <= the diagonal's block by construction
+        return (jb >= 0) & (jb * bk + bk - 1 > i * bq - window)
+    if causal:      # whole kv block strictly in the future -> skip
+        return (jb * bk) <= (i * bq + bq - 1)
+    return None
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
-                scale, causal, bq, bk, hpb, nk, G, Hg):
+                scale, causal, bq, bk, hpb, nk, G, Hg, geom):
     """hpb = heads per head-block.  The packed [B, S, H*D] layout needs
     128-wide lane blocks (Mosaic tiling rule), so for D=64 a head-block is 2
     adjacent heads: its columns are per-head slices and every head keeps
@@ -253,7 +392,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     D = q_ref.shape[-1] // (Hg * hpb)
     i = pl.program_id(1)
 
-    if nk == 1:
+    window = geom.window
+    if geom.one_block:
         # the whole of K/V is in the block: softmax in one pass, no running
         # statistics (the numbers are the sweep's own: its first block meets
         # m = -inf, l = 0, acc = 0)
@@ -264,7 +404,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 out = []
                 for hh in range(hpb):
                     cs = slice(hh * D, (hh + 1) * D)
-                    s = _scores(qb[:, cs], kb[:, cs], scale, causal, i * bq, 0)
+                    s = _scores(qb[:, cs], kb[:, cs], scale, causal, i * bq, 0,
+                                window)
                     m = jnp.max(s, axis=1)[:, None]            # [bq, 1]
                     p = jnp.exp(s - m)                          # [bq, bk] f32
                     l = jnp.maximum(jnp.sum(p, axis=1)[:, None], 1e-30)
@@ -295,18 +436,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    run = True
-    if causal:
-        # whole kv block strictly in the future -> skip
-        run = (j * bk) <= (i * bq + bq - 1)
+    jb = geom.kv_at(i, j)
+    run = _kv_runs(i, jb, bq, bk, causal, window, geom.band)
 
-    @pl.when(run if causal else (j >= 0))
+    @pl.when((j >= 0) if run is None else run)
     def _body():
         for hh in range(hpb):
             cs = slice(hh * D, (hh + 1) * D)
             ls = slice(hh * LANES, (hh + 1) * LANES)
             s = _scores(q_ref[0][:, cs], k_ref[0][:, cs], scale, causal,
-                        i * bq, j * bk)                    # [bq, bk]
+                        i * bq, jb * bk, window)           # [bq, bk]
 
             m_prev = m_scr[:, ls]                          # [bq, LANES]
             m_cur = jnp.max(s, axis=1)[:, None]            # [bq, 1]
@@ -335,12 +474,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             axis=1)
 
 
-def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None):
-    """H=None: q/k/v are [BH, S, D].  H=int: q/k/v are [B, S, H*D]."""
-    g = _Geom(q, k, H, bq, bk)
-    nq, nk = g.S // bq, g.Sk // bk
+def _name(kernel, g):
+    """``flash_<kernel>``; ``flash_swa_<kernel>`` for a windowed call."""
+    return ("flash_swa_" if g.window is not None else "flash_") + kernel
+
+
+def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None, Hkv=None,
+         window=None):
+    """H=None: q/k/v are [BH, S, D].  H=int: q/k/v are [B, S, H*D] (k, v
+    [B, S, Hkv*D] with grouped queries)."""
+    g = _Geom(q, k, H, bq, bk, Hkv, window)
+    nq, nk = g.nq, g.kv_steps
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               bq=bq, bk=bk, hpb=g.hpb, nk=nk, G=g.G, Hg=g.Hg)
+                               bq=bq, bk=bk, hpb=g.hpb, nk=nk, G=g.G, Hg=g.Hg,
+                               geom=g)
     o, lse = pl.pallas_call(
         kernel,
         grid=(g.grid_b, nq, nk),
@@ -358,7 +505,7 @@ def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None):
             jax.ShapeDtypeStruct(g.o_shape, q.dtype),
             jax.ShapeDtypeStruct(g.stat_shape, jnp.float32),
         ],
-        scratch_shapes=[] if nk == 1 else [
+        scratch_shapes=[] if g.one_block else [
             pltpu.VMEM((bq, g.hpb * LANES), jnp.float32),
             pltpu.VMEM((bq, g.hpb * LANES), jnp.float32),
             pltpu.VMEM((bq, g.qw), jnp.float32),
@@ -366,7 +513,7 @@ def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None):
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_fwd",
+        name=_name("fwd", g),
     )(q, k, v)
     return o, lse
 
@@ -382,7 +529,7 @@ def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None):
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                       dq_ref, dk_ref, dv_ref, *scratch,
-                      scale, causal, bq, bk, hpb, nq, G, Hg):
+                      scale, causal, bq, bk, hpb, nq, G, Hg, window=None):
     i = pl.program_id(1)
     D = q_ref.shape[-1] // (Hg * hpb)
 
@@ -405,7 +552,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             for hh in range(hpb):
                 cs = slice(hh * D, (hh + 1) * D)
                 q, k, v, do = qb[:, cs], kb[:, cs], vb[:, cs], dob[:, cs]
-                s = _scores(q, k, scale, causal, i * bq, 0)
+                s = _scores(q, k, scale, causal, i * bq, 0, window)
                 h = hb * hpb + hh
                 p = jnp.exp(s - lse[:, h:h + 1])            # [bq, bk] — the ONE exp
                 dv_cols.append(jax.lax.dot_general(
@@ -440,9 +587,10 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=None):
+def _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=None,
+               window=None):
     q, k, v, o, lse = res
-    g = _Geom(q, k, H, bq, bk)
+    g = _Geom(q, k, H, bq, bk, window=window)
     nq = g.S // bq
     # 2-arg index maps (grid has no kv axis): kv lives at block 0
     qm, km, sm = g.qmap(), g.kmap(), g.smap()
@@ -450,7 +598,8 @@ def _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=None):
     ks = g.kv_spec(bk, lambda b, i: km(b, i, 0))
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, hpb=g.hpb, nq=nq, G=g.G, Hg=g.Hg),
+                          bq=bq, bk=bk, hpb=g.hpb, nq=nq, G=g.G, Hg=g.Hg,
+                          window=window),
         grid=(g.grid_b, nq),
         in_specs=[qs, ks, ks, qs, qs,
                   g.stat_spec(bq, lambda b, i: sm(b, i, 0))],
@@ -467,7 +616,7 @@ def _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=None):
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_bwd_fused",
+        name=_name("bwd_fused", g),
     )(q, k, v, o, do, lse)
     return dq, dk, dv
 
@@ -477,7 +626,7 @@ def _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=None):
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   acc_scr, *, scale, causal, bq, bk, hpb=1):
+                   acc_scr, *, scale, causal, bq, bk, geom, hpb=1):
     j = pl.program_id(2)
     nk = pl.num_programs(2)
     i = pl.program_id(1)
@@ -487,23 +636,17 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    run = True
-    if causal:
-        run = (j * bk) <= (i * bq + bq - 1)
+    jb = geom.kv_at(i, j)
+    run = _kv_runs(i, jb, bq, bk, causal, geom.window, geom.band)
 
-    @pl.when(run if causal else (j >= 0))
+    @pl.when((j >= 0) if run is None else run)
     def _body():
         for hh in range(hpb):
             cs = slice(hh * D, (hh + 1) * D)
             q = q_ref[0][:, cs]
             k = k_ref[0][:, cs]
             v = v_ref[0][:, cs]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            if causal:
-                qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-                kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-                s = jnp.where(qpos >= kpos, s, NEG_INF)
+            s = _scores(q, k, scale, causal, i * bq, jb * bk, geom.window)
             p = jnp.exp(s - lse_ref[0, 0][:, hh:hh + 1])   # [bq, bk]
             dov = jax.lax.dot_general(do_ref[0][:, cs], v,
                                       (((1,), (1,)), ((), ())),
@@ -520,22 +663,24 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal, bq, bk,
-                    hpb=1):
-    i = pl.program_id(2)           # q blocks innermost here
-    nq = pl.num_programs(2)
+                    geom, hpb=1):
+    t = pl.program_id(2)           # q blocks innermost here (of each of the
+    nt = pl.num_programs(2)        # group's query heads in turn)
     j = pl.program_id(1)
     D = q_ref.shape[-1] // hpb
 
-    @pl.when(i == 0)
+    @pl.when(t == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    run = True
-    if causal:
-        run = (j * bk) <= (i * bq + bq - 1)
+    i = geom.q_at(j, t)
+    if geom.band:   # i >= the diagonal's block by construction
+        run = (i < geom.nq) & (i * bq < j * bk + bk - 1 + geom.window)
+    else:
+        run = (j * bk) <= (i * bq + bq - 1) if causal else (t >= 0)
 
-    @pl.when(run if causal else (i >= 0))
+    @pl.when(run)
     def _body():
         for hh in range(hpb):
             cs = slice(hh * D, (hh + 1) * D)
@@ -543,12 +688,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             k = k_ref[0][:, cs]
             v = v_ref[0][:, cs]
             do = do_ref[0][:, cs]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            if causal:
-                qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-                kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-                s = jnp.where(qpos >= kpos, s, NEG_INF)
+            s = _scores(q, k, scale, causal, i * bq, j * bk, geom.window)
             p = jnp.exp(s - lse_ref[0, 0][:, hh:hh + 1])   # [bq, bk]
             # dv_j += p^T dO
             dv_scr[:, cs] += jax.lax.dot_general(
@@ -562,18 +702,20 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    @pl.when(i == nq - 1)
+    @pl.when(t == nt - 1)
     def _final():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd(scale, causal, bq, bk, interpret, res, do, H=None):
+def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
+         window=None):
     q, k, v, o, lse = res
-    g = _Geom(q, k, H, bq, bk)
-    nq, nk = g.S // bq, g.Sk // bk
-    if nk == 1:
-        return _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=H)
+    g = _Geom(q, k, H, bq, bk, Hkv, window)
+    nq, nk = g.nq, g.nk
+    if g.one_block and g.group == 1:
+        return _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=H,
+                          window=window)
     if H is None:
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=-1, keepdims=True).reshape(g.stat_shape)
@@ -587,8 +729,8 @@ def _bwd(scale, causal, bq, bk, interpret, res, do, H=None):
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, hpb=g.hpb),
-        grid=(g.grid_b, nq, nk),
+                          bq=bq, bk=bk, hpb=g.hpb, geom=g),
+        grid=(g.grid_b, nq, g.kv_steps),
         in_specs=[
             pl.BlockSpec((1, bq, g.qw), qb),
             pl.BlockSpec((1, bk, g.qw), kb),
@@ -603,17 +745,16 @@ def _bwd(scale, causal, bq, bk, interpret, res, do, H=None):
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_bwd_dq",
+        name=_name("bwd_dq", g),
     )(q, k, v, do, lse, delta)
 
-    # dkv sweep: grid is (b, kv, q) — the index-map roles swap
-    qb2 = (lambda b, j, i: qb(b, i, j))
-    kb2 = (lambda b, j, i: kb(b, i, j))
-    sb2 = (lambda b, j, i: sb(b, i, j))
+    # dkv sweep: grid is (b, kv, q) — the index-map roles swap, and b runs
+    # over the key/value heads
+    qb2, kb2, sb2 = g.dkv_maps()
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, hpb=g.hpb),
-        grid=(g.grid_b, nk, nq),
+                          bq=bq, bk=bk, hpb=g.hpb, geom=g),
+        grid=(g.grid_b // g.group, nk, g.group * g.q_steps),
         in_specs=[
             pl.BlockSpec((1, bq, g.qw), qb2),
             pl.BlockSpec((1, bk, g.qw), kb2),
@@ -637,7 +778,7 @@ def _bwd(scale, causal, bq, bk, interpret, res, do, H=None):
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name=_name("bwd_dkv", g),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -665,18 +806,19 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_packed(q, k, v, H, scale, causal, bq, bk, interpret):
-    o, _ = _fwd(q, k, v, scale, causal, bq, bk, interpret, H=H)
+def _flash_packed(q, k, v, heads, scale, causal, bq, bk, interpret):
+    """``heads`` = (H, Hkv, window)."""
+    o, _ = _fwd(q, k, v, scale, causal, bq, bk, interpret, *heads)
     return o
 
 
-def _flash_packed_fwd(q, k, v, H, scale, causal, bq, bk, interpret):
-    o, lse = _fwd(q, k, v, scale, causal, bq, bk, interpret, H=H)
+def _flash_packed_fwd(q, k, v, heads, scale, causal, bq, bk, interpret):
+    o, lse = _fwd(q, k, v, scale, causal, bq, bk, interpret, *heads)
     return o, (q, k, v, o, lse)
 
 
-def _flash_packed_bwd(H, scale, causal, bq, bk, interpret, res, do):
-    return _bwd(scale, causal, bq, bk, interpret, res, do, H=H)
+def _flash_packed_bwd(heads, scale, causal, bq, bk, interpret, res, do):
+    return _bwd(scale, causal, bq, bk, interpret, res, do, *heads)
 
 
 _flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
@@ -708,22 +850,34 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
 
 
 def flash_attention_packed(q, k, v, n_heads, causal=False, scale=None,
-                           block_q=256, block_k=256, interpret=None):
+                           block_q=256, block_k=256, interpret=None,
+                           n_kv_heads=None, window=None):
     """Packed-layout flash attention: q, k, v are [B, S, H*D] exactly as the
     qkv projections produce them; returns [B, S, H*D] ready for the output
     projection.  The per-head D-wide column slices are addressed by the
     Pallas BlockSpec index maps, so no [B, H, S, D] transpose or reshape ever
     touches HBM (~8 layout copies/layer saved vs the bshd entry at bench
-    shapes)."""
+    shapes).
+
+    ``n_kv_heads`` < ``n_heads``: grouped queries, k and v [B, S, Hkv*D].
+    ``window``: query i sees keys j with i - window < j <= i (causal only;
+    a window of S or more is the causal mask and changes nothing)."""
     B, S, E = q.shape
     H = n_heads
     assert E % H == 0, (E, H)
     D = E // H
-    if not packed_layout_supported(H, D):
+    Hkv = n_kv_heads or H
+    if not packed_layout_supported(H, D, Hkv):
         raise ValueError(
-            "packed layout cannot tile H=%d heads of D=%d (needs D*hpb a "
-            "multiple of %d lanes with hpb dividing H); use flash_attention "
-            "on [B, S, H, D]" % (H, D, LANES))
+            "packed layout cannot tile H=%d (kv %d) heads of D=%d (needs "
+            "D*hpb a multiple of %d lanes with hpb dividing H, and hpb 1 "
+            "for grouped queries); use flash_attention on [B, S, H, D]"
+            % (H, Hkv, D, LANES))
+    assert k.shape[-1] == v.shape[-1] == Hkv * D, (k.shape, Hkv, D)
+    if window is not None:
+        assert causal and window >= 1 and k.shape[1] == S, (causal, window)
+        if window >= S:
+            window = None
     Sk = k.shape[1]
     if scale is None:
         scale = 1.0 / (D ** 0.5)
@@ -732,5 +886,5 @@ def flash_attention_packed(q, k, v, n_heads, causal=False, scale=None,
     bq = min(block_q, S)
     bk = min(block_k, Sk)
     assert S % bq == 0 and Sk % bk == 0, (S, Sk, bq, bk)
-    return _flash_packed(q, k, v, H, float(scale), bool(causal),
+    return _flash_packed(q, k, v, (H, Hkv, window), float(scale), bool(causal),
                          bq, bk, bool(interpret))

@@ -34,6 +34,7 @@ from ..parallel.transformer import (
     final_logits_loss,
     gauge_flash_grid,
     grad_sync_axes,
+    head_logits,
     init_transformer_params,
     run_layers,
     transformer_param_specs,
@@ -86,6 +87,8 @@ def make_loss_fn(cfg: TransformerConfig):
             ids.shape)
         x, aux = _forward(params, ids, cfg)
         ce = final_logits_loss(params, x, labels, mask, cfg)
+        if not (cfg.router_aux_coef or cfg.router_z_coef):
+            return ce               # a configuration with no auxiliary loss
         return (ce + cfg.router_aux_coef * jnp.mean(aux["load_balance"])
                 + cfg.router_z_coef * jnp.mean(aux["router_z"]))
 
@@ -96,6 +99,23 @@ def make_loss_fn(cfg: TransformerConfig):
 class OlmoeTrainer(StepTrainer):
     label = "olmoe"
     _load_fn = None
+    _logits_fn = None
+
+    def logits_at(self, ids, positions):
+        """The head's float32 logits [B, P, V] at ``positions`` [P] of
+        ``ids`` [B, S], at the weights as they stand: the step's own forward
+        (block, kernels, MoE, the head's norm and matmul) without the loss.
+        What a check against a reference reads where the scalar loss cannot
+        tell (``benchmark/drivers/train_scan_witnessed.py``)."""
+        cfg = self.cfg
+        if self._logits_fn is None:
+            self._logits_fn = jax.jit(local_shard_map(
+                lambda params, ids, at: head_logits(
+                    params, _forward(params, ids, cfg)[0][:, at], cfg),
+                self.mesh, in_specs=(self.specs["params"], P(DP), P()),
+                out_specs=P(DP)))
+        return self._logits_fn(self.state["params"], jnp.asarray(ids),
+                               jnp.asarray(positions, jnp.int32))
 
     def _observe(self, batch):
         ids = batch["ids"]
@@ -127,10 +147,11 @@ class OlmoeTrainer(StepTrainer):
 
 
 def build_olmoe_trainer(cfg, mesh_spec: MeshSpec = None, optimizer=None,
-                        seed=0, devices=None):
+                        seed=0, devices=None, trainer=None):
     """Mesh, parameters on the mesh, the jitted sharded step and its scan.
     Data parallel only: the block has no tensor-, pipeline- or
-    expert-parallel layout yet."""
+    expert-parallel layout yet.  ``trainer``: the ``OlmoeTrainer`` subclass
+    of another sparse decoder of this block (models/smallthinker.py)."""
     mesh_spec = mesh_spec or MeshSpec()
     assert mesh_spec.tp == mesh_spec.pp == cfg.tp == cfg.pp == 1, \
         "the OLMoE block runs at tp == pp == 1"
@@ -146,5 +167,6 @@ def build_olmoe_trainer(cfg, mesh_spec: MeshSpec = None, optimizer=None,
     step_fn, multi_fn = build(state), build.multi(state)
     with mesh:
         state = shard_pytree(state, sspecs, mesh)
-    return OlmoeTrainer(cfg=cfg, mesh=mesh, state=state, step_fn=step_fn,
-                        specs=sspecs, multi_fn=multi_fn)
+    return (trainer or OlmoeTrainer)(
+        cfg=cfg, mesh=mesh, state=state, step_fn=step_fn, specs=sspecs,
+        multi_fn=multi_fn)

@@ -8,7 +8,17 @@
   pair that was not routed), the router weight riding the hidden rows
   between them so that nothing after the down projection is kept for the
   backward; then the sort is undone and each token's k rows are summed.
-  The FFN of a ``TransformerConfig`` with ``n_experts > 0`` (models/olmoe.py).
+  The FFN of a ``TransformerConfig`` with ``n_experts > 0`` (models/olmoe.py,
+  models/smallthinker.py).  By configuration: the routing rule (``RULES``:
+  softmax over all experts and the k largest as they are, or the k largest
+  logits and a softmax over those), router logits the caller computed from
+  another input, the gate's activation (``ACTIVATIONS``), and WHICH EXPERTS
+  THIS DEVICE HOLDS (``first_held`` and the leading size of the experts'
+  leaves): the router still ranks all n, the pairs whose expert is held are
+  sorted to the front, only a static number of rows that covers them is
+  gathered and multiplied (``_held_capacities``; the sum back reads every
+  pair's place, a held pair's row or zero), and what the absent experts
+  would add is left out.  No pair that meets a held expert is dropped, whatever the routing.
 - ``switch_moe_ffn``: top-1 (Switch) routing with a capacity limit that
   DROPS the overflow, experts sharded over a mesh axis (by default ``dp``,
   "EP rides DP") and exchanged with ``lax.all_to_all`` over ICI.  Net-new
@@ -32,7 +42,14 @@ from ..kernels._common import on_tpu
 from ..monitor import devscope
 
 __all__ = ["init_moe_params", "switch_moe_ffn", "init_dropless_moe_params",
-           "dropless_moe_ffn", "route_top_k"]
+           "dropless_moe_ffn", "route_top_k", "router_logits", "RULES",
+           "ACTIVATIONS"]
+
+# routing rules: how the k experts' weights come from the router's logits
+SOFTMAX_TOP_K = "softmax_top_k"     # softmax over all n, the k largest as they are
+TOP_K_SOFTMAX = "top_k_softmax"     # the k largest logits, softmax over those k
+RULES = (SOFTMAX_TOP_K, TOP_K_SOFTMAX)
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def _normal(key, shape, fan_in, dtype):
@@ -82,11 +99,20 @@ def _per_expert(top_e, n):
 
 
 @devscope.scoped(devscope.ROUTER)
-def route_top_k(router, x, k):
-    """Router of a dropless layer on the tokens ``x`` [T, E]: the k largest
-    of ``softmax(x @ router)`` (float32, over ALL experts; as they are, not
-    renormalised to sum to one) and their experts, [T, k] each, and the
-    layer's auxiliary values over the dp-global batch:
+def router_logits(router, x):
+    """``x @ router`` in float32: [T, n]."""
+    return x.astype(jnp.float32) @ router.astype(jnp.float32)
+
+
+@devscope.scoped(devscope.ROUTER)
+def route_top_k(router, x, k, rule=SOFTMAX_TOP_K, logits=None):
+    """Router of a dropless layer on the tokens ``x`` [T, E] (or on
+    ``logits`` [T, n] where the caller computed them from another input):
+    the k experts of each token and their weights, [T, k] each.  By
+    ``rule``: the k largest of ``softmax(logits)`` (float32, over ALL
+    experts; as they are, not renormalised to sum to one), or the k largest
+    logits and a softmax over those k (the same as renormalising the
+    first).  And the layer's auxiliary values over the dp-global batch:
 
     - ``load_balance`` = n * sum_e f_e * P_e, ``f_e`` the share of the T*k
       assignments that went to expert e (a count: no gradient), ``P_e`` the
@@ -94,13 +120,19 @@ def route_top_k(router, x, k):
     - ``router_z`` = mean_t logsumexp(logits_t)^2;
     - ``load_max_over_mean``: the busiest expert's assignments over the mean.
     """
+    assert rule in RULES, rule
     n = router.shape[-1]
-    logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
+    if logits is None:
+        logits = router_logits(router, x)
     lse = jax.nn.logsumexp(logits, axis=-1)
     probs = jnp.exp(logits - lse[:, None])
-    top_p, top_e = jax.lax.top_k(probs, k)
+    if rule == SOFTMAX_TOP_K:
+        top_p, top_e = jax.lax.top_k(probs, k)
+    else:
+        top_l, top_e = jax.lax.top_k(logits, k)
+        top_p = jax.nn.softmax(top_l, axis=-1)
     counts = col.psum(_per_expert(top_e, n), DP)
-    tokens = x.shape[0] * col.axis_size_in(DP)
+    tokens = logits.shape[0] * col.axis_size_in(DP)
     share = counts.astype(jnp.float32) / (tokens * k)
     mean_p = col.psum(jnp.sum(probs, axis=0), DP) / tokens
     aux = {"load_balance": n * jnp.sum(share * mean_p),
@@ -109,19 +141,28 @@ def route_top_k(router, x, k):
     return top_p, top_e, aux
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch(x, order, inv, k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _dispatch(x, order, inv, k, absent=False):
     """Row i of the result is token ``order[i] // k``: x [T, E] gathered into
     the sorted order of its T*k assignments.  ``order`` is a permutation with
-    inverse ``inv``, so the transpose is a gather too, ``_combine``."""
+    inverse ``inv``, so the transpose is a gather too, ``_combine``.
+
+    With experts ``absent``, ``order`` is the first M places of the sort
+    (the held pairs come first) and ``inv`` [T*k] gives a held pair's row
+    and M for every other pair; rows past the held pairs are some token's,
+    and nothing reads what is computed from them."""
     return x[order // k]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _combine(rows, order, inv, k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _combine(rows, order, inv, k, absent=False):
     """Token t of the result is the float32 sum of its k rows, ``rows[inv]``
-    (the sort undone) k at a time: ``_dispatch``'s transpose, as it is its."""
-    back = rows[inv].reshape((-1, k) + rows.shape[1:])
+    (the sort undone) k at a time: ``_dispatch``'s transpose, as it is its.
+    A pair whose place is past the rows (its expert is absent) adds zero."""
+    if absent:      # gathered as [T, k, E]: no copy between gather and sum
+        back = rows.at[inv.reshape(-1, k)].get(mode="fill", fill_value=0)
+    else:
+        back = rows[inv].reshape((-1, k) + rows.shape[1:])
     return jnp.sum(back.astype(jnp.float32), axis=1).astype(rows.dtype)
 
 
@@ -137,9 +178,11 @@ def _move(v, to, back):
 # traced on its own, in the backward pass, and names its scope itself
 _scoped = devscope.scoped(devscope.MOE)
 _dispatch.defvjp(lambda *a: (_dispatch(*a), a[1:3]),      # keeps order, inv
-                 _scoped(lambda k, res, g: (_combine(g, *res, k), None, None)))
+                 _scoped(lambda k, absent, res, g: (
+                     _combine(g, *res, k, absent), None, None)))
 _combine.defvjp(lambda *a: (_combine(*a), a[1:3]),
-                _scoped(lambda k, res, g: (_dispatch(g, *res, k), None, None)))
+                _scoped(lambda k, absent, res, g: (
+                    _dispatch(g, *res, k, absent), None, None)))
 _move.defvjp(lambda v, to, back: (_move(v, to, back), (back, to)),
              _scoped(lambda res, g: (_move(g, *res), None, None)))
 
@@ -194,33 +237,138 @@ def _grouped_matmul_bwd(res, g):
 _grouped_matmul.defvjp(lambda *args: (_gmm(*args), args), _grouped_matmul_bwd)
 
 
+HELD_GRANULE = 512           # a capacity is whole row tiles of the kernels
+HELD_HEADROOM = 1.25         # first capacity over the rows uniform routing gives
+
+
+def _held_capacities(pairs, count, n):
+    """The static row counts a layer that holds ``count`` of ``n`` experts
+    is compiled for: HELD_HEADROOM times the rows uniform routing brings
+    (``pairs * count / n``) in whole tiles, and all ``pairs`` (T*k), so no
+    routing overflows; at T*k = 98,304 and 16 of 64, (30720, 98304).  A
+    step runs the first where it covers its held pairs (``lax.switch``), so
+    the gathers and the kernels' grids follow the rows held as long as the
+    routing stays within the headroom of balance, and a step past it pays
+    for every pair."""
+    cap = -int(-HELD_HEADROOM * pairs * count / n // HELD_GRANULE) * HELD_GRANULE
+    return (cap, pairs) if cap < pairs else (pairs,)
+
+
+def _held(top_e, first, count):
+    """Which (token, expert) pairs meet an expert in [first, first + count)."""
+    return (top_e >= first) & (top_e < first + count)
+
+
+def _expert_ffn(x, top_p, top_e, w_gate_up, w_down, k, act, first, rows_max):
+    """``sum_e p_te * down_e(act(gate_e x_t) * up_e x_t)`` over the experts
+    [first, first + count) that ``w_gate_up`` [count, E, 2F] / ``w_down``
+    hold, through ``rows_max`` sorted rows, which cover the pairs that meet
+    a held expert (None: every expert is held and every pair has a row)."""
+    count, absent = w_gate_up.shape[0], rows_max is not None
+    key = top_e
+    if absent:
+        held = _held(top_e.reshape(-1), first, count)
+        key = jnp.where(held, top_e.reshape(-1) - first, count)   # absent: last
+    order = jnp.argsort(key.reshape(-1), stable=True).astype(jnp.int32)
+    inv = jnp.argsort(order).astype(jnp.int32)
+    group_sizes = _per_expert(key, count)
+    # with experts absent: the sort's first rows_max places, and a pair's row
+    # or, for a pair that has none, the place past the last
+    place = (order[:rows_max], jnp.where(held, inv, rows_max)) if absent \
+        else (order, inv)
+
+    rows = _dispatch(x, *place, k, absent)                       # [M, E]
+    weight = _move(top_p.reshape(-1), inv, order)                # [T*k]
+    if absent:
+        # rows past the held pairs belong to no group: the kernels leave
+        # their outputs unwritten, so their weight's gradient is cut here,
+        # as no pair's place points at them
+        live = jnp.arange(rows_max) < jnp.sum(group_sizes)
+        weight = jnp.where(live, weight[:rows_max], 0.0)
+    gate, up = jnp.split(
+        _grouped_matmul(rows, w_gate_up, group_sizes), 2, axis=-1)
+    hidden = (ACTIVATIONS[act](gate.astype(jnp.float32))
+              * up.astype(jnp.float32) * weight[:, None]).astype(x.dtype)
+    out = _grouped_matmul(hidden, w_down, group_sizes)
+    return _combine(out, *place, k, absent)
+
+
+def _held_tier(top_e, first, count, caps):
+    """Index of the smallest capacity that covers the held pairs."""
+    held = jnp.sum(_held(top_e, first, count), dtype=jnp.int32)
+    return sum((held > cap).astype(jnp.int32) for cap in caps[:-1])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _held_expert_ffn(x, top_p, top_e, w_gate_up, w_down, static):
+    """``_expert_ffn`` at the smallest of the static capacities that covers
+    this step's held pairs.  ``static`` = (k, act, first, capacities).
+
+    A ``custom_vjp`` whose residuals are its arguments and whose backward
+    makes the choice again: differentiated as it stands, ``lax.switch``
+    would hand the backward every branch's residuals, the largest
+    capacity's among them, zero-filled for the branches not taken.  The
+    chosen branch's forward up to the hidden rows is computed again in the
+    backward; under ``jax.checkpoint`` the layer's recomputed forward then
+    has nothing of the expert FFN to keep and the compiler drops it, so the
+    kernel calls a step makes are those of the all-held layer."""
+    k, act, first, caps = static
+    branches = [functools.partial(_expert_ffn, k=k, act=act, first=first,
+                                  rows_max=cap) for cap in caps]
+    return jax.lax.switch(
+        _held_tier(top_e, first, w_gate_up.shape[0], caps), branches,
+        x, top_p, top_e, w_gate_up, w_down)
+
+
 @devscope.scoped(devscope.MOE)
-def dropless_moe_ffn(params, x, k):
+def _held_expert_ffn_bwd(static, res, g):
+    k, act, first, caps = static
+    x, top_p, top_e, w_gate_up, w_down = res
+
+    def branch(cap):
+        def ffn(x, top_p, w_gate_up, w_down):
+            return _expert_ffn(x, top_p, top_e, w_gate_up, w_down, k, act,
+                               first, cap)
+        return lambda *a: jax.vjp(ffn, *a[:4])[1](a[4])
+
+    dx, dp, dgu, dd = jax.lax.switch(
+        _held_tier(top_e, first, w_gate_up.shape[0], caps),
+        [branch(cap) for cap in caps], x, top_p, w_gate_up, w_down, g)
+    return dx, dp, None, dgu, dd
+
+
+_held_expert_ffn.defvjp(lambda *a: (_held_expert_ffn(*a), a[:5]),
+                        _held_expert_ffn_bwd)
+
+
+@devscope.scoped(devscope.MOE)
+def dropless_moe_ffn(params, x, k, rule=SOFTMAX_TOP_K, act="silu",
+                     logits=None, first_held=0):
     """Top-k dropless expert FFN.  x [T, E] (flatten batch and sequence
     before the call); returns ``(y [T, E], aux)`` with
-    ``y_t = sum_{e in top k} p_te * down_e(silu(gate_e x_t) * up_e x_t)``
-    and ``aux`` as ``route_top_k`` gives it.
+    ``y_t = sum_{e in top k, held} p_te * down_e(act(gate_e x_t) * up_e x_t)``
+    and ``aux`` as ``route_top_k`` gives it (``rule`` and ``logits`` are
+    its).  ``we_gate_up`` / ``we_down`` hold the experts
+    [first_held, first_held + their leading size) of the router's n: all of
+    them (OLMoE), or this device's share, and then ``y`` is the part of the
+    layer's result that its experts give, and ``aux`` also counts the pairs
+    that met one of them (``rows_held``, over the dp-global batch).
 
     ``p_te`` multiplies the HIDDEN rows (the triple product in float32,
     rounded once); the down projection is linear, so ``y`` is the same.  Its
     gradient then needs the hidden rows, which the down matmul keeps anyway,
     and nothing computed after them: no residual follows the down
     projection, and a rematerialised forward stops at the gate/up matmul."""
-    n = params["router"].shape[-1]
-    top_p, top_e, aux = route_top_k(params["router"], x, k)
-
-    order = jnp.argsort(top_e.reshape(-1), stable=True).astype(jnp.int32)
-    inv = jnp.argsort(order).astype(jnp.int32)
-    group_sizes = _per_expert(top_e, n)
-
-    rows = _dispatch(x, order, inv, k)                           # [T*k, E]
-    weight = _move(top_p.reshape(-1), inv, order)                # [T*k]
-    gate, up = jnp.split(
-        _grouped_matmul(rows, params["we_gate_up"], group_sizes), 2, axis=-1)
-    hidden = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-              * weight[:, None]).astype(x.dtype)
-    out = _grouped_matmul(hidden, params["we_down"], group_sizes)
-    return _combine(out, order, inv, k), aux
+    n, count = params["router"].shape[-1], params["we_gate_up"].shape[0]
+    assert 0 <= first_held and first_held + count <= n, (first_held, count, n)
+    top_p, top_e, aux = route_top_k(params["router"], x, k, rule, logits)
+    ffn = (x, top_p, top_e, params["we_gate_up"], params["we_down"])
+    if count == n:
+        return _expert_ffn(*ffn, k, act, 0, None), aux
+    caps = _held_capacities(top_e.size, count, n)
+    aux = dict(aux, rows_held=col.psum(jnp.sum(
+        _held(top_e, first_held, count), dtype=jnp.int32), DP))
+    return _held_expert_ffn(*ffn, (k, act, first_held, caps)), aux
 
 
 def switch_moe_ffn(params, x, ep_axis=DP, capacity_factor=1.25):

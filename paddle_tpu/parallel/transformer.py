@@ -37,7 +37,7 @@ from .ring_attention import ring_attention
 
 __all__ = ["TransformerConfig", "init_transformer_params", "transformer_param_specs",
            "grad_sync_axes", "embed", "transformer_layer", "run_layers",
-           "rms_norm", "rope", "final_logits_loss",
+           "rms_norm", "rope", "final_logits_loss", "head_logits",
            "head_row_block", "head_rows_computed"]
 
 
@@ -75,15 +75,33 @@ class TransformerConfig:
     bias: bool = True                # biases on the attention and FFN matmuls
     tie_head: bool = True            # False: the head is its own [V, E] leaf, lm_head
     # n_experts > 0 replaces the GELU FFN by the dropless top-k MoE of
-    # parallel/moe.py: n_experts gated-SiLU experts of width ffn_hidden
+    # parallel/moe.py: n_experts gated experts of width ffn_hidden
     n_experts: int = 0
     experts_per_token: int = 0
     router_aux_coef: float = 0.0     # x load-balance loss, mean over layers
     router_z_coef: float = 0.0       # x router z-loss, mean over layers
+    routing: str = "softmax_top_k"   # moe.RULES: how the k weights are formed
+    expert_act: str = "silu"         # the gate's activation (moe.ACTIVATIONS)
+    router_input: str = "ffn"        # "ffn": the normed FFN input | "block":
+    # the block's input, before its first norm and before attention
+    # the experts this device holds, of the router's n_experts (0: all):
+    # experts [first_expert, first_expert + experts_held)
+    experts_held: int = 0
+    first_expert: int = 0
+    # Attention's shape.  head_width 0: hidden // n_heads; n_kv_heads 0:
+    # n_heads (else grouped queries: wk, wv [E, n_kv_heads * head_dim])
+    head_width: int = 0
+    n_kv_heads: int = 0
+    # One period of the stack's layer kinds, (window, rotary) a position:
+    # window 0 is full attention, rotary off is NO positional encoding in
+    # that layer.  Empty: one kind, full attention, rotary as ``positions``
+    # says.  n_layers is whole periods.
+    layer_pattern: tuple = ()
 
     def __post_init__(self):
         assert self.norm in ("layer", "rms") and \
             self.positions in ("learned", "rotary")
+        assert self.router_input in ("ffn", "block")
         if self.qk_norm or self.positions == "rotary" or self.n_experts:
             # the norm spans the whole projection, rotary positions start at
             # 0 and the MoE routes the tokens it holds: none is sharded yet
@@ -91,10 +109,35 @@ class TransformerConfig:
                 "qk_norm, rotary positions and the MoE FFN need tp == 1"
         if self.n_experts:
             assert 0 < self.experts_per_token <= self.n_experts
+            assert self.first_expert + self.experts_here <= self.n_experts
+        if self.kv_heads != self.n_heads or self.head_width:
+            assert self.tp == 1 and self.attn_mode == "heads" \
+                and not self.bias and self.n_heads % self.kv_heads == 0
+        self.layer_pattern = tuple((int(w), bool(r))
+                                   for w, r in self.layer_pattern)
+        if self.layer_pattern:
+            assert self.positions == "rotary" and self.causal \
+                and self.tp == self.pp == 1 \
+                and self.n_layers % len(self.layer_pattern) == 0
 
     @property
     def head_dim(self):
-        return self.hidden // self.n_heads
+        return self.head_width or self.hidden // self.n_heads
+
+    @property
+    def kv_heads(self):
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def experts_here(self):
+        return self.experts_held or self.n_experts
+
+    @property
+    def layer_kinds(self):
+        """(window or None, rotary) of each layer of one period."""
+        if not self.layer_pattern:
+            return ((None, self.positions == "rotary"),)
+        return tuple((w or None, r) for w, r in self.layer_pattern)
 
     @property
     def jdtype(self):
@@ -123,8 +166,12 @@ def init_transformer_params(key, cfg: TransformerConfig):
     ``bo`` / ``b1`` / ``b2`` only with ``bias``, ``pos_emb`` only with
     learned positions, ``q_norm`` / ``k_norm`` with ``qk_norm``, ``lm_head``
     with an untied head, and the FFN's leaves are either ``w1`` / ``w2`` or
-    the MoE's ``router`` / ``we_gate_up`` / ``we_down`` (parallel/moe.py)."""
+    the MoE's ``router`` / ``we_gate_up`` / ``we_down`` (parallel/moe.py;
+    the experts' leaves hold ``experts_here`` of them).  ``wq`` / ``wo``
+    are n_heads * head_dim wide, ``wk`` / ``wv`` kv_heads * head_dim.  Every
+    layer kind of ``layer_pattern`` has the same leaves."""
     E, F, L, V = cfg.hidden, cfg.ffn_hidden, cfg.n_layers, cfg.vocab_size
+    Q, KV = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
     dt = cfg.jdtype
     ks = jax.random.split(key, 12)
 
@@ -135,10 +182,10 @@ def init_transformer_params(key, cfg: TransformerConfig):
 
     layer = {
         "ln1_scale": jnp.ones((L, E), jnp.float32),
-        "wq": stack(0, E, (E, E)),
-        "wk": stack(1, E, (E, E)),
-        "wv": stack(2, E, (E, E)),
-        "wo": stack(3, E, (E, E)),
+        "wq": stack(0, E, (E, Q)),
+        "wk": stack(1, E, (E, KV)),
+        "wv": stack(2, E, (E, KV)),
+        "wo": stack(3, Q, (Q, E)),
         "ln2_scale": jnp.ones((L, E), jnp.float32),
     }
     if cfg.norm == "layer":
@@ -148,11 +195,11 @@ def init_transformer_params(key, cfg: TransformerConfig):
         layer["bqkv"] = jnp.zeros((L, 3, E), dt)
         layer["bo"] = jnp.zeros((L, E), dt)
     if cfg.qk_norm:
-        layer["q_norm"] = jnp.ones((L, E), jnp.float32)
-        layer["k_norm"] = jnp.ones((L, E), jnp.float32)
+        layer["q_norm"] = jnp.ones((L, Q), jnp.float32)
+        layer["k_norm"] = jnp.ones((L, KV), jnp.float32)
     if cfg.n_experts:
-        n = cfg.n_experts
-        layer["router"] = stack(6, E, (E, n), jnp.float32)
+        n = cfg.experts_here          # the router ranks all n_experts
+        layer["router"] = stack(6, E, (E, cfg.n_experts), jnp.float32)
         layer["we_gate_up"] = stack(7, E, (n, E, 2 * F))
         layer["we_down"] = stack(8, F, (n, F, E))
     else:
@@ -166,7 +213,13 @@ def init_transformer_params(key, cfg: TransformerConfig):
             lambda x: x.reshape((cfg.pp, cfg.layers_per_stage) + x.shape[1:]), layer
         )
     params = {
-        "tok_emb": _dense_init(ks[1], E, (V, E), dt),
+        # a lookup averages nothing: where a block reads the un-normed stream
+        # (router_input "block": a router before the first norm) the rows
+        # are seeded N(0, 1), the scale of the branches' outputs, so that a
+        # token's own row and not attention's mean ranks its experts
+        "tok_emb": _dense_init(
+            ks[1], 1 if cfg.n_experts and cfg.router_input == "block" else E,
+            (V, E), dt),
         "lnf_scale": jnp.ones((E,), jnp.float32),
         "params_layers": layer,
     }
@@ -348,15 +401,16 @@ def _local_attention_dispatch(q, k, v, cfg):
     return ring_attention(q, k, v, axis=None, causal=cfg.causal)
 
 
-def _packed_flash_blocks(cfg, hl, S):
-    """(block_q, block_k) where attention over ``hl`` local heads of S
-    positions goes to the packed flash kernel, else None."""
+def _packed_flash_blocks(cfg, hl, S, kvl=None):
+    """(block_q, block_k) where attention over ``hl`` local heads (on
+    ``kvl`` key/value heads) of S positions goes to the packed flash kernel,
+    else None."""
     from ..kernels.flash_attention import packed_layout_supported
 
     bq = min(cfg.flash_block_q, S)
     bk = min(cfg.flash_block_k, S)
     if (cfg.use_flash and S % bq == 0 and S % bk == 0
-            and packed_layout_supported(hl, cfg.head_dim)):
+            and packed_layout_supported(hl, cfg.head_dim, kvl)):
         return bq, bk
     return None
 
@@ -373,24 +427,37 @@ def gauge_flash_grid(cfg, b, S):
     mon = monitor.active()
     if mon is None or cfg.attn_mode != "heads":
         return
-    hl = cfg.n_heads // cfg.tp
-    blocks = _packed_flash_blocks(cfg, hl, S)
+    hl, kvl = cfg.n_heads // cfg.tp, cfg.kv_heads // cfg.tp
+    blocks = _packed_flash_blocks(cfg, hl, S, kvl)
     if blocks is None:
         return
-    from ..kernels.flash_attention import packed_grid
+    from ..kernels.flash_attention import kv_blocks, packed_grid
 
     pairs, steps = packed_grid(b, S, hl, cfg.head_dim, *blocks,
-                               itemsize=cfg.jdtype.itemsize)
+                               itemsize=cfg.jdtype.itemsize, n_kv_heads=kvl)
     mon.registry.gauge("monitor.kernels.flash_pairs_per_grid_step").set(pairs)
     mon.registry.gauge("monitor.kernels.flash_grid_steps").set(steps)
+    if cfg.layer_pattern:
+        # (q block, kv block) steps of one head's forward sweep that compute
+        # and that its grid holds and skips, by layer kind
+        window = max(w or 0 for w, _ in cfg.layer_kinds) or None
+        for name, w in (("full", None), ("windowed", window)):
+            seen, skipped = kv_blocks(S, *blocks, cfg.causal, w)
+            mon.registry.gauge(
+                "monitor.kernels.flash_kv_blocks_visited_" + name).set(seen)
+            mon.registry.gauge(
+                "monitor.kernels.flash_kv_blocks_skipped_" + name).set(skipped)
 
 
-def _attention_heads_mode(pl, h_full, cfg):
-    """Megatron attention: input full-sequence [b,S,E], heads sharded over tp."""
+def _attention_heads_mode(pl, h_full, cfg, kind):
+    """Megatron attention: input full-sequence [b,S,E], heads sharded over tp.
+    ``kind`` = (window or None, rotary) of this layer."""
     b, S, E = h_full.shape
     ntp = col.axis_size_in(TP)
     hl = cfg.n_heads // ntp if ntp > 1 else cfg.n_heads
+    kvl = cfg.kv_heads // ntp if ntp > 1 else cfg.kv_heads
     dh = cfg.head_dim
+    window, rotary = kind
 
     # params arrive pre-sharded inside shard_map: wq/bqkv are [E, E/tp]/[3, E/tp]
     q2, k2, v2 = (h_full @ pl[w] for w in ("wq", "wk", "wv"))  # [b, S, hl*dh]
@@ -399,21 +466,25 @@ def _attention_heads_mode(pl, h_full, cfg):
     if cfg.qk_norm:             # over the whole projection, before the heads
         q2 = rms_norm(q2, pl["q_norm"], cfg.norm_eps)
         k2 = rms_norm(k2, pl["k_norm"], cfg.norm_eps)
-    if cfg.positions == "rotary":
+    if rotary:
         q2 = rope(q2, hl, cfg.rope_theta)
-        k2 = rope(k2, hl, cfg.rope_theta)
-    blocks = _packed_flash_blocks(cfg, hl, S)
+        k2 = rope(k2, kvl, cfg.rope_theta)
+    blocks = _packed_flash_blocks(cfg, hl, S, kvl)
     if blocks:
         # packed layout: the kernel reads each head's column slice in place —
         # no [b, hl, S, dh] transpose round-trips (flash_attention_packed)
         from ..kernels.flash_attention import flash_attention_packed
 
         o = flash_attention_packed(q2, k2, v2, hl, causal=cfg.causal,
-                                   block_q=blocks[0], block_k=blocks[1])
+                                   block_q=blocks[0], block_k=blocks[1],
+                                   n_kv_heads=kvl, window=window)
     else:
         q = q2.reshape(b, S, hl, dh)
-        k = k2.reshape(b, S, hl, dh)
-        v = v2.reshape(b, S, hl, dh)
+        k = k2.reshape(b, S, kvl, dh)
+        v = v2.reshape(b, S, kvl, dh)
+        assert kvl == hl and window is None, \
+            "grouped queries and a window run on the packed flash kernel " \
+            "only (head width a multiple of 128, S whole blocks)"
         o = _local_attention_dispatch(q, k, v, cfg).reshape(b, S, hl * dh)
     out = o @ pl["wo"]                                          # row-parallel partial
     out = col.reduce_scatter(out, TP, dim=1)                    # sum + seq scatter
@@ -436,16 +507,24 @@ def _attention_ring_mode(pl, h_sp, cfg):
     return o + pl["bo"] if cfg.bias else o
 
 
-def transformer_layer(pl, x_sp, cfg: TransformerConfig):
+def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None):
     """One pre-norm block on the SP activation [b, S/tp, E]: the new
     activation and the FFN's auxiliary values (the MoE's, ``moe.route_top_k``;
-    None for the dense FFN)."""
+    None for the dense FFN).  ``kind`` = (window or None, rotary): which of
+    ``cfg.layer_kinds`` this layer is (None: the first)."""
     heads_mode = cfg.attn_mode == "heads"
+    logits = None
+    if cfg.n_experts and cfg.router_input == "block":
+        from .moe import router_logits
+
+        # the router reads the residual stream as it ENTERS the block
+        logits = router_logits(pl["router"], x_sp.reshape(-1, x_sp.shape[-1]))
     with jax.named_scope(devscope.ATTENTION):
         h = _norm(x_sp, pl, "ln1", cfg)
         if heads_mode:
             h = col.all_gather(h, TP, dim=1)
-            attn = _attention_heads_mode(pl, h, cfg)
+            attn = _attention_heads_mode(pl, h, cfg,
+                                         kind or cfg.layer_kinds[0])
         else:
             attn = _attention_ring_mode(pl, h, cfg)
         x_sp = x_sp + attn
@@ -455,8 +534,10 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig):
             from .moe import dropless_moe_ffn
 
             h = _norm(x_sp, pl, "ln2", cfg)
-            y, aux = dropless_moe_ffn(pl, h.reshape(-1, h.shape[-1]),
-                                      cfg.experts_per_token)
+            y, aux = dropless_moe_ffn(
+                pl, h.reshape(-1, h.shape[-1]), cfg.experts_per_token,
+                rule=cfg.routing, act=cfg.expert_act, logits=logits,
+                first_held=cfg.first_expert)
             return x_sp + y.reshape(h.shape), aux
 
     with jax.named_scope(devscope.MLP):
@@ -477,14 +558,34 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig):
 def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False):
     """scan over the (local) stacked layers; remat per layer if configured.
     ``with_aux`` also returns the layers' auxiliary values, stacked [L]
-    (``moe.route_top_k``'s of each MoE layer; None for a dense stack)."""
+    (``moe.route_top_k``'s of each MoE layer; None for a dense stack).
+
+    A pattern of several layer kinds is scanned a PERIOD at a time: the
+    stacked leaves [L, ...] are read as [L / period, period, ...] and the
+    body runs the period's layers in turn, each with its static kind
+    (``scan_unroll`` then counts periods).  One kind is the scan over
+    layers it always was."""
+    kinds = cfg.layer_kinds
     body = transformer_layer
     if cfg.remat:
-        body = jax.checkpoint(body, static_argnums=(2,))
+        body = jax.checkpoint(body, static_argnums=(2, 3))
+    unroll = max(int(cfg.scan_unroll), 1)
+    if len(kinds) == 1:
+        x_sp, aux = jax.lax.scan(lambda x, pl: body(pl, x, cfg, kinds[0]),
+                                 x_sp, layer_params, unroll=unroll)
+        return (x_sp, aux) if with_aux else x_sp
 
-    x_sp, aux = jax.lax.scan(lambda x, pl: body(pl, x, cfg), x_sp,
-                             layer_params,
-                             unroll=max(int(cfg.scan_unroll), 1))
+    def period(x, pls):
+        auxes = []
+        for at, kind in enumerate(kinds):
+            x, aux = body(jax.tree.map(lambda a: a[at], pls), x, cfg, kind)
+            auxes.append(aux)
+        return x, jax.tree.map(lambda *a: jnp.stack(a), *auxes)
+
+    x_sp, aux = jax.lax.scan(period, x_sp, jax.tree.map(
+        lambda a: a.reshape((-1, len(kinds)) + a.shape[1:]), layer_params),
+        unroll=unroll)
+    aux = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), aux)
     return (x_sp, aux) if with_aux else x_sp
 
 
@@ -671,6 +772,18 @@ def _chunked_vocab_nll_bwd(norm, res, g):
 
 
 _chunked_nll.defvjp(_chunked_vocab_nll_fwd, _chunked_vocab_nll_bwd)
+
+
+@devscope.scoped(devscope.LM_HEAD)
+def head_logits(params, x, cfg: TransformerConfig):
+    """The LM head's float32 logits [..., V] on ``x`` [..., E]: the final
+    norm and the head's matmul as ``final_logits_loss`` computes them, kept.
+    For a few rows (a check against a reference), at tp == 1."""
+    emb = params["tok_emb" if cfg.tie_head else "lm_head"]
+    h = _head_norm((cfg.norm, cfg.norm_eps), x, params["lnf_scale"],
+                   params.get("lnf_bias"))
+    return jax.lax.dot_general(h, emb, (((x.ndim - 1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 @devscope.scoped(devscope.LM_HEAD)

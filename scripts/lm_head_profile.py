@@ -12,8 +12,10 @@ are printed with their ``op_name`` whatever their scope.
 
 Builds the cell's trainer and stages its batches as the scan driver does,
 runs one dispatch under a monitor session (the scan driver opens none, so
-this is where ``monitor.train.lm_head_rows_share`` and the two
-``monitor.kernels.flash_*`` gauges are read on the chip),
+this is where ``monitor.train.lm_head_rows_share``, the two
+``monitor.kernels.flash_*`` gauges and, for a sparse decoder, its
+``monitor.train.moe_*`` and ``monitor.kernels.flash_kv_blocks_*`` values are
+read on the chip),
 traces one more, and joins the trace with THIS process's scope map
 (``monitor.devscope``; a map compiled elsewhere need not number its
 instructions the same way).  ``--ones`` replaces the mask by all ones, the
@@ -85,6 +87,10 @@ def main(argv=None):
               "monitor.kernels.flash_grid_steps %s a layer and pass"
               % tuple(mon.registry.gauge("monitor.kernels.flash_" + g).value
                       for g in ("pairs_per_grid_step", "grid_steps")))
+        for row in mon.registry.snapshot():    # a sparse decoder's own
+            if row["name"].startswith(("monitor.train.moe_",
+                                       "monitor.kernels.flash_kv_blocks_")):
+                print("monitor: %s %s" % (row["name"], row.get("value")))
         monitor.disable()
         np.asarray(tr.run_steps(staged, lr))
         tracing._start(os.path.join(tmp, "trace"), 0)

@@ -275,3 +275,130 @@ def test_a_layer_at_s128_is_one_forward_and_one_backward_kernel():
     steps = fa.packed_grid(256, 128, cfg.n_heads, cfg.head_dim, 512, 512)[1]
     assert steps == 256
     assert "grid=(%d, 1, 1)" % steps in text and "grid=(%d, 1)" % steps in text
+
+
+# ---------------------------------------------------------------------------
+# grouped queries and a sliding window (PR 31): modes of the same kernels
+# ---------------------------------------------------------------------------
+
+def _plain_gqa(q, k, v, H, Hkv, window=None, causal=True):
+    """Softmax attention on the packed layout, float32, no kernel: query
+    head h reads kv head h // (H // Hkv); query i sees keys j with
+    i - window < j <= i."""
+    B, S, _ = q.shape
+    D = q.shape[-1] // H
+    qh = q.reshape(B, S, H, D)
+    kh = jnp.repeat(k.reshape(B, S, Hkv, D), H // Hkv, axis=2)
+    vh = jnp.repeat(v.reshape(B, S, Hkv, D), H // Hkv, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) / np.sqrt(D)
+    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    seen = (j <= i) if causal else jnp.ones((S, S), bool)
+    if window is not None:
+        seen = seen & (i - j < window)
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, vh).reshape(B, S, H * D)
+
+
+def _packed_qkv(seed, B, S, H, Hkv, D):
+    rng = np.random.RandomState(seed)
+    mk = lambda h: jnp.array((rng.randn(B, S, h * D) * 0.5).astype(np.float32))
+    return mk(H), mk(Hkv), mk(Hkv), mk(H)
+
+
+#   what                          B  S    H   Hkv D    bq   bk   window causal
+MODES = [
+    ("group 7, causal",           1, 256, 7,  1, 128, 64,  64,  None, True),
+    ("group 3, causal",           2, 256, 6,  2, 128, 64,  64,  None, True),
+    ("group 3, bidirectional",    1, 256, 3,  1, 128, 128, 64,  None, False),
+    ("group 3, one block",        2, 128, 6,  2, 128, 128, 128, None, True),
+    ("window, whole blocks",      1, 512, 2,  2, 128, 64,  64,  128,  True),
+    ("window 100 of blocks 64",   1, 512, 2,  2, 128, 64,  64,  100,  True),
+    ("window, bq != bk",          1, 512, 2,  2, 128, 128, 64,  100,  True),
+    ("window, bq < bk",           1, 512, 2,  2, 128, 64,  128, 200,  True),
+    ("window, heads of 64",       1, 256, 4,  4, 64,  64,  64,  72,   True),
+    ("window in one block",       2, 128, 2,  2, 128, 128, 128, 50,   True),
+    ("window and group 7",        1, 512, 7,  1, 128, 64,  64,  100,  True),
+    ("window and group 3",        2, 256, 6,  2, 128, 64,  64,  72,   True),
+    ("window of S: causal",       1, 256, 3,  1, 128, 64,  64,  256,  True),
+]
+
+
+@pytest.mark.parametrize("what,B,S,H,Hkv,D,bq,bk,window,causal", MODES,
+                         ids=[m[0] for m in MODES])
+def test_grouped_and_windowed_modes_equal_plain_attention(
+        what, B, S, H, Hkv, D, bq, bk, window, causal):
+    """Forward and dq / dk / dv of ``flash_attention_packed`` with fewer
+    key/value heads than query heads and with a window, against plain
+    softmax attention; dk and dv are the sums over a group's query heads."""
+    q, k, v, w = _packed_qkv(21, B, S, H, Hkv, D)
+
+    def flash(q, k, v):
+        return fa.flash_attention_packed(
+            q, k, v, H, causal=causal, block_q=bq, block_k=bk,
+            n_kv_heads=Hkv, window=window)
+
+    def plain(q, k, v):
+        return _plain_gqa(q, k, v, H, Hkv, window, causal)
+
+    np.testing.assert_allclose(flash(q, k, v), plain(q, k, v),
+                               atol=3e-6, rtol=1e-5)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(plain(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    for a, b, n in zip(got, want, "qkv"):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=1e-4,
+                                   err_msg="d%s of %s" % (n, what))
+
+
+def _kernel_names(fn, *args):
+    import re
+
+    return sorted(re.findall(r"name=(flash_\w+)", str(jax.make_jaxpr(fn)(*args))))
+
+
+def test_windowed_kernels_carry_names_of_their_own():
+    q, k, v, w = _packed_qkv(22, 1, 512, 2, 2, 128)
+
+    def both(window):
+        def loss(q, k, v):
+            return jnp.sum(fa.flash_attention_packed(
+                q, k, v, 2, causal=True, block_q=64, block_k=64,
+                window=window) * w)
+        return _kernel_names(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+
+    assert both(None) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    assert both(100) == ["flash_swa_bwd_dkv", "flash_swa_bwd_dq",
+                         "flash_swa_fwd"]
+    assert both(512) == both(None)          # the window is the causal mask
+
+
+@pytest.mark.parametrize("S,bq,bk,window,steps,blocks", [
+    (16384, 512, 512, 4096, (9, 9), (252, 36)),   # the cell's windowed layers
+    (16384, 512, 512, None, (32, 32), (528, 496)),   # and its full ones
+    (4096, 512, 512, None, (8, 8), (36, 28)),     # olmoe's
+    (512, 64, 64, 100, (3, 3), (21, 3)),
+    (512, 128, 64, 100, (4, 2), (14, 2)),
+])
+def test_the_band_s_grid(S, bq, bk, window, steps, blocks):
+    """The kv axis of a windowed sweep is the band, not S / bk: 9 blocks of
+    32 at the cell's shape; and what the gauges say of a layer kind."""
+    if window is not None:
+        assert fa.band_steps(S, bq, bk, window) == steps
+    assert fa.kv_blocks(S, bq, bk, True, window) == blocks
+    # every (q block, kv block) that holds a seen pair is visited, no other
+    i, j = np.arange(S)[:, None], np.arange(S)[None, :]
+    seen = (j <= i) & ((i - j < window) if window else True) if S <= 4096 \
+        else None
+    if seen is not None:
+        tiles = seen.reshape(S // bq, bq, S // bk, bk).any(axis=(1, 3))
+        assert tiles.sum() == blocks[0]
+
+
+def test_grouped_queries_need_whole_head_blocks():
+    assert fa.packed_layout_supported(28, 128, 4)
+    assert fa.packed_layout_supported(12, 64)
+    assert not fa.packed_layout_supported(12, 64, 4)    # two heads a block
+    assert not fa.packed_layout_supported(28, 128, 5)   # 5 does not divide 28
+    q, k, v, _ = _packed_qkv(23, 1, 128, 4, 2, 64)
+    with pytest.raises(ValueError, match="grouped"):
+        fa.flash_attention_packed(q, k, v, 4, n_kv_heads=2)

@@ -58,3 +58,30 @@ def test_forward_and_backward_compile_for_a_v5e(one_chip, what, entry, B, S,
 
     text = jax.jit(both).lower(x, x, x, x).compile().as_text()
     assert text.count("tpu_custom_call") >= 2, what
+
+
+@pytest.mark.parametrize("what,window,names", [
+    ("smallthinker_21b_a3b.s16384_scan, a full layer", None,
+     ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")),
+    ("smallthinker_21b_a3b.s16384_scan, a windowed layer", 4096,
+     ("flash_swa_fwd", "flash_swa_bwd_dq", "flash_swa_bwd_dkv")),
+])
+def test_grouped_and_windowed_kernels_compile_for_a_v5e(one_chip, what,
+                                                        window, names):
+    """28 query heads on 4 key/value heads of 128 over 16,384 positions:
+    the index maps' integer arithmetic and the dk/dv sweep over a group's
+    heads are what Mosaic has to take."""
+    B, S, H, Hkv, D = 1, 16384, 28, 4, 128
+    xq = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
+    xk = jax.ShapeDtypeStruct((B, S, Hkv * D), jnp.bfloat16, sharding=one_chip)
+    attn = lambda q, k, v: fa.flash_attention_packed(
+        q, k, v, H, causal=True, block_q=512, block_k=512, interpret=False,
+        n_kv_heads=Hkv, window=window)
+
+    def both(q, k, v, do):
+        o, vjp = jax.vjp(attn, q, k, v)
+        return (o,) + vjp(do)
+
+    text = jax.jit(both).lower(xq, xk, xk, xq).compile().as_text()
+    for name in names:
+        assert name in text, (what, name)

@@ -326,3 +326,152 @@ def test_rule_trees_cover_both_layers():
     assert specs["w1"] == specs["w2"] == jax.sharding.PartitionSpec("dp")
     assert specs["we_gate_up"] == specs["we_down"] == specs["router"] \
         == jax.sharding.PartitionSpec()
+
+
+# -- a share of the experts (PR 31) ----------------------------------------------
+# The layer holds ``count`` of the router's N experts from ``first``: it routes
+# over all N, computes its own experts' part for the pairs that meet them, and
+# leaves the rest out.  Rules and activation are configuration too.
+
+def _dense_share(params, x, k, first, count, rule, act, logits=None):
+    """Every HELD expert on every token, the rule's top-k weights at their
+    experts' columns, zero elsewhere; ``params`` holds all N experts."""
+    logits = x @ params["router"] if logits is None else logits
+    if rule == moe.SOFTMAX_TOP_K:
+        top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    else:
+        top_l, top_e = jax.lax.top_k(logits, k)
+        top_p = jax.nn.softmax(top_l, axis=-1)
+    weight = (jax.nn.one_hot(top_e, N) * top_p[..., None]).sum(1)   # [T, n]
+    held = slice(first, first + count)
+    gu = jnp.einsum("te,nef->ntf", x, params["we_gate_up"][held])
+    out = jnp.einsum("ntf,nfe->nte",
+                     moe.ACTIVATIONS[act](gu[..., :F]) * gu[..., F:],
+                     params["we_down"][held])
+    return jnp.einsum("nte,tn->te", out, weight[:, held])
+
+
+def _share(params, first, count):
+    return dict(params,
+                we_gate_up=params["we_gate_up"][first:first + count],
+                we_down=params["we_down"][first:first + count])
+
+
+#   what                             case                         first count rule                act
+SHARES = [
+    ("first quarter",                "top_2",                     0, 2, moe.TOP_K_SOFTMAX, "relu"),
+    ("last quarter",                 "non_uniform_router",        6, 2, moe.TOP_K_SOFTMAX, "relu"),
+    ("a middle half, OLMoE's rule",  "top_2",                     2, 4, moe.SOFTMAX_TOP_K, "silu"),
+    ("a share that receives no row", "every_token_to_one_expert", 4, 2, moe.TOP_K_SOFTMAX, "relu"),
+    ("a share that receives all",    "every_token_to_one_expert", 2, 2, moe.TOP_K_SOFTMAX, "relu"),
+    ("top 3 of odd rows",            "odd_number_of_assignments", 1, 3, moe.TOP_K_SOFTMAX, "silu"),
+]
+
+
+@pytest.fixture
+def small_granule(monkeypatch):
+    """Capacities in tiles of 8 rows, so that the tiny cases have two."""
+    monkeypatch.setattr(moe, "HELD_GRANULE", 8)
+
+
+@pytest.mark.parametrize("what,case,first,count,rule,act", SHARES,
+                         ids=[s[0] for s in SHARES])
+def test_a_share_of_the_experts_gives_its_part(small_granule, what, case,
+                                               first, count, rule, act):
+    """Result and every gradient of the layer holding experts
+    [first, first + count), against the dense formula over those experts."""
+    params, x, k = _case(case)
+    caps = moe._held_capacities(x.shape[0] * k, count, N)
+    assert caps[-1] == x.shape[0] * k and list(caps) == sorted(set(caps))
+
+    def layer(p, x):
+        return moe.dropless_moe_ffn(_share(p, first, count), x, k, rule=rule,
+                                    act=act, first_held=first)[0]
+
+    def dense(p, x):
+        return _dense_share(p, x, k, first, count, rule, act)
+
+    np.testing.assert_allclose(jax.jit(layer)(params, x), dense(params, x),
+                               **TOL)
+    probe = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+    got = jax.jit(jax.grad(lambda p, x: jnp.sum(layer(p, x) * probe),
+                           argnums=(0, 1)))(params, x)
+    want = jax.grad(lambda p, x: jnp.sum(dense(p, x) * probe),
+                    argnums=(0, 1))(params, x)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.isfinite(g).all()
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
+
+
+def test_both_capacities_give_the_same_part(small_granule):
+    """The capacity is only a size: at the first, which covers this case's
+    held pairs, and at every pair, ``_expert_ffn`` gives the same rows'
+    sum."""
+    params, x, k = _case("non_uniform_router")
+    first, count = 2, 2
+    p = _share(params, first, count)
+    top_p, top_e, _ = moe.route_top_k(p["router"], x, k, moe.TOP_K_SOFTMAX)
+    local = np.asarray(top_e) - first
+    held = int(((local >= 0) & (local < count)).sum())
+    caps = moe._held_capacities(top_e.size, count, N)
+    assert len(caps) == 2 and held <= caps[0] < caps[1] == top_e.size
+    assert int(moe._held_tier(top_e, first, count, caps)) == 0
+    want = _dense_share(params, x, k, first, count, moe.TOP_K_SOFTMAX, "relu")
+    for cap in caps:
+        got = moe._expert_ffn(x, top_p, top_e, p["we_gate_up"], p["we_down"],
+                              k, "relu", first, cap)
+        np.testing.assert_allclose(got, want, **TOL)
+
+
+def test_the_shares_parts_add_up_to_the_whole_layer():
+    params, x, k = _case("non_uniform_router")
+    whole = _dense_share(params, x, k, 0, N, moe.TOP_K_SOFTMAX, "relu")
+    parts = sum(moe.dropless_moe_ffn(_share(params, f, 2), x, k,
+                                     rule=moe.TOP_K_SOFTMAX, act="relu",
+                                     first_held=f)[0] for f in range(0, N, 2))
+    np.testing.assert_allclose(parts, whole, **TOL)
+    all_held = moe.dropless_moe_ffn(params, x, k, rule=moe.TOP_K_SOFTMAX,
+                                    act="relu")[0]
+    np.testing.assert_allclose(all_held, whole, **TOL)
+
+
+def test_logits_from_the_caller_route_the_layer():
+    """Router logits computed from ANOTHER input than the experts': the
+    layer ranks by them and the router's gradient flows through them."""
+    params, x, k = _case("top_2")
+    other = jax.random.normal(jax.random.PRNGKey(4), x.shape)
+
+    def layer(p, x, other):
+        return moe.dropless_moe_ffn(
+            p, x, k, rule=moe.TOP_K_SOFTMAX, act="relu",
+            logits=moe.router_logits(p["router"], other))[0]
+
+    def dense(p, x, other):
+        return _dense_share(p, x, k, 0, N, moe.TOP_K_SOFTMAX, "relu",
+                            logits=other @ p["router"])
+
+    np.testing.assert_allclose(layer(params, x, other),
+                               dense(params, x, other), **TOL)
+    got = jax.grad(lambda *a: jnp.sum(layer(*a) ** 2), argnums=(0, 2))(
+        params, x, other)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) ** 2), argnums=(0, 2))(
+        params, x, other)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
+
+
+def test_a_share_under_remat_keeps_nothing_of_the_expert_ffn(small_granule):
+    """Per capacity, a rematerialised step's jaxpr holds 6 ``gmm`` + 2
+    ``tgmm``: the forward's two; NONE in the recomputed forward (the expert
+    FFN's residuals are its arguments, so nothing of it is kept); and in the
+    backward, which makes its own forward, gate/up and down (the down one's
+    result is the ``vjp``'s unused primal: dead code to the compiler, which
+    leaves the all-held layer's 5 + 2) and the two transposed ones."""
+    params, x, k = _case("non_uniform_router")
+    p = _share(params, 2, 2)
+    caps = moe._held_capacities(x.shape[0] * k, 2, N)
+    grad = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(jax.checkpoint(
+        lambda p, x: moe.dropless_moe_ffn(p, x, k, first_held=2)[0])(p, x)),
+        argnums=(0, 1)))(p, x)
+    assert _count(grad.jaxpr, "gmm") == len(caps) * (2 + 0 + 4)
+    assert _count(grad.jaxpr, "tgmm") == len(caps) * 2
