@@ -608,15 +608,22 @@ def head_rows_computed(count, n_rows):
     return (count + block - 1) // block * block
 
 
-def _vocab_chunks(emb, n_chunks):
+def _vocab_chunks(emb):
+    """The head's partition of the vocabulary axis, ``(offset, rows)`` a
+    chunk, from the head matrix's shape alone: about ``_VOCAB_CHUNKS``
+    chunks, every one but the last a whole multiple of ``head_row_block(V)``
+    rows (1024 at training sizes), the last the remainder.
+
+    The TPU compiler walks a chunk's float32 ``[rows, E]`` gradient in
+    windows of whole 8-row tiles, as many tiles a window as divide the
+    chunk.  A quarter of the vocabulary need not have a divisor: 37,984 / 4
+    is 8 x 1,187, a prime, and ran that matmul in 1,187 windows of one
+    tile, at seven times its time.  The remainder is the shortest chunk, so
+    one that tiles badly costs little."""
     V = emb.shape[0]
-    base = V // n_chunks
-    sizes = [base] * (n_chunks - 1) + [V - base * (n_chunks - 1)]
-    offs, o = [], 0
-    for s in sizes:
-        offs.append(o)
-        o += s
-    return list(zip(offs, sizes))
+    granule = head_row_block(V)
+    rows = -(-V // (_VOCAB_CHUNKS * granule)) * granule
+    return [(lo, min(rows, V - lo)) for lo in range(0, V, rows)]
 
 
 def _live_first(mask):
@@ -703,7 +710,7 @@ def _chunked_vocab_nll_fwd(norm, x, scale, bias, emb, labels, mask):
         m_run = jnp.full((block,), -jnp.inf, jnp.float32)
         s_run = jnp.zeros((block,), jnp.float32)
         picked = jnp.zeros((block,), jnp.float32)
-        for lo, sz in _vocab_chunks(emb, _VOCAB_CHUNKS):
+        for lo, sz in _vocab_chunks(emb):
             _, logits, local, hit = _vocab_chunk(h, emb, lb, lo, sz)
             m_new = jnp.maximum(m_run, jnp.max(logits, axis=-1))
             s_run = s_run * jnp.exp(m_run - m_new) + jnp.sum(
@@ -741,7 +748,7 @@ def _chunked_vocab_nll_bwd(norm, res, g):
         h, ln_vjp = jax.vjp(functools.partial(_head_norm, norm), x[idx],
                             scale, bias)
         dh = jnp.zeros(h.shape, jnp.float32)
-        for lo, sz in _vocab_chunks(emb, _VOCAB_CHUNKS):
+        for lo, sz in _vocab_chunks(emb):
             w, logits, local, hit = _vocab_chunk(h, emb, lb, lo, sz)
             p = jnp.exp(logits - lse_b[:, None])                # softmax chunk
             onehot = (jax.nn.one_hot(local, sz, dtype=jnp.float32)

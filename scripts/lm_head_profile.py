@@ -19,8 +19,12 @@ read on the chip),
 traces one more, and joins the trace with THIS process's scope map
 (``monitor.devscope``; a map compiled elsewhere need not number its
 instructions the same way).  ``--ones`` replaces the mask by all ones, the
-causal-LM shape no cell sends.  Needs a TPU; prints no device number
-otherwise.
+causal-LM shape no cell sends.  The head's partition of the vocabulary
+(``transformer._vocab_chunks`` of the cell's head matrix) is printed beside
+its milliseconds; ``--cut 9728,9728,9728,8800`` replaces it for this
+process's compile (chunk rows that sum to the vocabulary: the script's
+experiment, the program has no such option).  Needs a TPU; prints no
+device number otherwise.
 """
 
 import argparse
@@ -39,6 +43,7 @@ def main(argv=None):
     ap.add_argument("--ones", action="store_true")
     ap.add_argument("--scope", action="append")
     ap.add_argument("--top", type=int, default=30)
+    ap.add_argument("--cut", type=lambda s: [int(r) for r in s.split(",")])
     args = ap.parse_args(argv)
     sys.path.insert(0, ROOT)
 
@@ -56,6 +61,7 @@ def main(argv=None):
     from benchmark.harness.spans import Spans
     from paddle_tpu import compile_cache, monitor
     from paddle_tpu.monitor import devscope
+    from paddle_tpu.parallel import transformer
 
     compile_cache.place()
     m = mf.load(ROOT)
@@ -69,6 +75,17 @@ def main(argv=None):
     lr = float(ctx.config["lr"])
     ctx.trainer = tr = build.build_trainer(
         ctx.config, ctx.traffic, args.seed, jax.devices()[:cell["chips"]])
+    params = tr.state["params"]
+    emb = params["lm_head"] if "lm_head" in params else params["tok_emb"]
+    if args.cut:
+        if sum(args.cut) != emb.shape[0] or min(args.cut) < 1:
+            ap.error("--cut has to sum to the vocabulary, %d rows"
+                     % emb.shape[0])
+        offsets = np.cumsum([0] + args.cut[:-1]).tolist()
+        transformer._vocab_chunks = lambda emb: list(zip(offsets, args.cut))
+    print("head: %s %s, %s partition of the vocabulary (offset, rows): %s"
+          % (emb.dtype, emb.shape, "--cut's" if args.cut else "the head's",
+             transformer._vocab_chunks(emb)))
     staged = train_scan._stage(ctx)
     if args.ones:
         staged["mask"] = jnp.ones_like(staged["mask"])
@@ -113,6 +130,9 @@ def main(argv=None):
     print("%s, %s: device busy %.3f ms a step, last loss %.6g"
           % (args.cell, "mask of ones" if args.ones else "the cell's mask",
              dev["busy_ns"] / steps / 1e6, losses[-1]))
+    stats = jax.devices()[0].memory_stats()
+    print("memory: peak_bytes_in_use %d + peak_bytes_reserved %d"
+          % (stats["peak_bytes_in_use"], stats.get("peak_bytes_reserved", 0)))
     for key, ns in sorted(totals.items(), key=lambda kv: -kv[1])[:14]:
         print("  %-10s %-12s %8.3f ms a step" % (key + (ns / steps / 1e6,)))
     for title, found, top in (

@@ -5,7 +5,10 @@ such compiles live in this one file and describe the chip inside a fixture,
 so that only the worker that is given the file loads the TPU's library."""
 
 import importlib
+import json
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -85,3 +88,40 @@ def test_grouped_and_windowed_kernels_compile_for_a_v5e(one_chip, what,
     text = jax.jit(both).lower(xq, xk, xk, xq).compile().as_text()
     for name in names:
         assert name in text, (what, name)
+
+
+@pytest.mark.parametrize("what,N,V,E,norm", [
+    ("smallthinker_21b_a3b.s16384_scan", 16384, 37984, 2560, "rms"),
+    ("olmoe_1b_7b.s4096_scan", 16384, 50304, 2048, "rms"),
+    ("bert_base.s512_scan", 32768, 30528, 768, "layer"),
+])
+def test_head_matrix_gradient_is_tiled_in_a_few_windows(one_chip, what, N, V,
+                                                        E, norm):
+    """The tp=1 head's backward at a cell's head shape.  A vocabulary
+    chunk's float32 dW matmul, accumulated into ``demb`` in place, is one
+    fusion a chunk whose result is ``f32[V, E]``; the compiler walks it in
+    ``iteration_bounds`` windows.  With chunks of 9,496 = 8 x 1,187 rows
+    (37,984 / 4; 1,187 is prime) it found 1,187 windows of one 8-row tile,
+    and the four fusions took a fifth of the SmallThinker cell's step."""
+    T = importlib.import_module("paddle_tpu.parallel.transformer")
+    sds = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    emb = sds((V, E))
+
+    def loss(x, scale, bias, emb, labels, mask):
+        return jnp.sum(T._chunked_vocab_nll(x, scale, bias, emb, labels, mask,
+                                            norm=(norm, 1e-5)) * mask)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 3))).lower(
+        sds((N, E)), sds((E,)), sds((E,)) if norm == "layer" else None, emb,
+        sds((N,), jnp.int32), sds((N,), jnp.float32)).compile().as_text()
+    windows = []
+    for line in text.splitlines():
+        if (re.match(r"\s*%%?[\w.\-]+ = f32\[%d,%d\]" % (V, E), line)
+                and "/while/body/" in line and "window_config" in line):
+            config = json.loads(
+                line[line.index("backend_config=") + 15:])["window_config"]
+            windows.append(math.prod(
+                int(b) for b in config["iteration_bounds"]))
+    assert len(windows) == len(T._vocab_chunks(emb)), (what, windows)
+    assert max(windows) <= 300, (what, windows)

@@ -5,6 +5,7 @@ against the plain formula, under ``jit(scan)``, across a dp=4 mesh with a
 different count on every device, through the tiny trainer, and in the
 lowered text of the step."""
 
+import functools
 import re
 
 import jax
@@ -24,16 +25,19 @@ N, E, V = 256, 16, 50
 R = T.head_row_block(N)                                         # 16
 
 
-def _plain_loss(x, scale, bias, emb, labels, mask):
-    h = T.layer_norm(x, scale, bias, fused=False)
+LAYER_NORM = ("layer", 1e-6)
+
+
+def _plain_loss(x, scale, bias, emb, labels, mask, norm=LAYER_NORM):
+    h = T._head_norm(norm, x, scale, bias)
     logits = (h @ emb.T).astype(jnp.float32)
     lse = jax.nn.logsumexp(logits, axis=-1)
     picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
     return jnp.sum((lse - picked) * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
-def _compact_loss(x, scale, bias, emb, labels, mask):
-    nll = T._chunked_vocab_nll(x, scale, bias, emb, labels, mask)
+def _compact_loss(x, scale, bias, emb, labels, mask, norm=LAYER_NORM):
+    nll = T._chunked_vocab_nll(x, scale, bias, emb, labels, mask, norm=norm)
     return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
@@ -145,6 +149,80 @@ def test_row_block_comes_from_the_shape_alone():
     assert T.head_row_block(64 * 512) == T.head_row_block(256 * 128) == 1024
     assert T.head_row_block(4 * 32) == 8 and T.head_row_block(3) == 8
     assert all(T.head_row_block(n) % 8 == 0 for n in (100, 1000, 5000, 10**6))
+
+
+# ---------------------------------------------------------------------------
+# where the vocabulary is cut (``transformer._vocab_chunks``)
+# ---------------------------------------------------------------------------
+
+# vocabularies the rule treats differently; ``rows`` is the cut it makes
+CUTS = {
+    "four_times_8_times_a_prime": (4 * 8 * 37, [360, 360, 360, 104]),
+    "below_one_granule": (5, [5]),
+    "an_exact_multiple": (1024, [256] * 4),
+    "a_remainder_under_128_rows": (1064, [320, 320, 320, 104]),
+}
+
+
+@pytest.mark.parametrize("mask_kind", ["zero_one", "ones"])
+@pytest.mark.parametrize("norm", ["rms", "layer"])
+@pytest.mark.parametrize("vocab", sorted(CUTS))
+def test_any_cut_of_the_vocabulary_gives_the_dense_formula(vocab, norm,
+                                                           mask_kind):
+    V_, rows = CUTS[vocab]
+    n, e = 64, 16
+    rng = np.random.RandomState(V_)
+    emb = jnp.asarray(0.3 * rng.randn(V_, e), jnp.float32)
+    assert [r for _, r in T._vocab_chunks(emb)] == rows
+    x = jnp.asarray(rng.randn(n, e), jnp.float32)
+    scale = jnp.asarray(1 + 0.1 * rng.randn(e), jnp.float32)
+    bias = jnp.asarray(0.1 * rng.randn(e), jnp.float32)
+    labels = rng.randint(0, V_, n)
+    labels[:4] = [0, V_ - 1, rows[0] - 1, min(rows[0], V_ - 1)]   # the edges
+    labels = jnp.asarray(labels, jnp.int32)
+    mask = jnp.asarray(np.ones(n) if mask_kind == "ones"
+                       else rng.rand(n) < 0.4, jnp.float32)
+    wrt = (0, 1, 3) if norm == "rms" else (0, 1, 2, 3)
+    args = (x, scale, None if norm == "rms" else bias, emb, labels, mask)
+    want, dwant = jax.value_and_grad(functools.partial(
+        _plain_loss, norm=(norm, 1e-5)), argnums=wrt)(*args)
+    got, dgot = jax.jit(jax.value_and_grad(functools.partial(
+        _compact_loss, norm=(norm, 1e-5)), argnums=wrt))(*args)
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-6)
+    for g, w in zip(dgot, dwant):                # dx, norm gradients, demb
+        assert g.shape == w.shape
+        _close(g, w, GRAD_TOL)
+
+
+def test_the_partition_covers_the_vocabulary_once_and_in_order():
+    sweep = list(range(1, 2200)) + [
+        30522, 30528, 32000, 37984, 50257, 50304, 128256, 151936, 262144]
+    for V_ in sweep:
+        chunks = T._vocab_chunks(jax.ShapeDtypeStruct((V_, 8), jnp.bfloat16))
+        offsets, rows = zip(*chunks)
+        assert offsets[0] == 0 and min(rows) >= 1, V_
+        assert all(o + r == nxt for (o, r), nxt in zip(
+            chunks, offsets[1:] + (V_,))), V_
+        granule = T.head_row_block(V_)
+        assert all(r % granule == 0 for r in rows[:-1]), V_
+        assert rows[-1] <= rows[0] and len(rows) <= T._VOCAB_CHUNKS, V_
+        # the float32 [R, rows] logits tile is a quarter of the vocabulary's,
+        # to a granule
+        assert rows[0] < V_ / T._VOCAB_CHUNKS + granule, V_
+
+
+@pytest.mark.parametrize("what,V_,rows", [
+    ("smallthinker_21b_a3b", 37984, [10240, 10240, 10240, 7264]),
+    ("olmoe_1b_7b", 50304, [13312, 13312, 13312, 10368]),
+    ("bert_base", 30528, [8192, 8192, 8192, 5952]),
+])
+def test_the_cells_vocabularies_are_cut_on_whole_kilorows(what, V_, rows):
+    """37,984 / 4 = 8 x 1,187, a prime: the equal cut the head made until
+    PR 32 left the TPU compiler one 8-row window to tile a chunk's gradient
+    with (``tests/test_flash_tpu_compile.py`` holds the compiled head to
+    it)."""
+    chunks = T._vocab_chunks(jax.ShapeDtypeStruct((V_, 64), jnp.bfloat16))
+    assert [r for _, r in chunks] == rows, what
 
 
 # ---------------------------------------------------------------------------
