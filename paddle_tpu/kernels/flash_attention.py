@@ -29,12 +29,18 @@ head-blocks (the compiler interleaves the independent heads), each
 
 Two more modes of the packed entry, both of the same kernels:
 
-- grouped queries (``n_kv_heads`` < ``n_heads``, head width a multiple of
-  128): k and v are [B, S, n_kv_heads*D] and query head h reads key/value
-  head ``h // (n_heads // n_kv_heads)``, addressed by the index maps alone;
-  the dk/dv sweep runs once per KEY/VALUE head and its innermost grid axis
-  walks the group's query heads, so dk and dv are summed over the group in
-  the kernel's scratch.  One (row, head) pair a grid step whatever S is,
+- grouped queries (``n_kv_heads`` < ``n_heads``): k and v are
+  [B, S, n_kv_heads*D] and query head h reads key/value head
+  ``h // (n_heads // n_kv_heads)``.  At a head width of 128 lanes or more
+  a lane block is one head and the index maps alone address it.  At 64 a
+  lane block is two heads: a group is a whole number of query blocks, so
+  both heads of a query block read ONE key/value head, which is one HALF
+  of a key/value lane block; the index maps bring the block and the kernel
+  takes the half (``_Geom.kv_half``: a select, a thousandth of a step's
+  work).  The dk/dv sweep runs once per KEY/VALUE lane block and its
+  innermost grid axis walks the query blocks that read it, so dk and dv
+  are summed over the group in the kernel's scratch, each query block into
+  the half it read.  One (row, head-block) pair a grid step whatever S is,
   and the two-sweep backward even at one block.
 - a sliding window (``window`` = W < S, causal): query i sees keys j with
   i - W < j <= i.  The kv axis of the grid is the BAND (``band_steps``: 9
@@ -82,10 +88,13 @@ def _lanes_to(x, n):
 def packed_layout_supported(n_heads, head_dim, n_kv_heads=None):
     """True when the packed [B, S, H*D] entry can address this head shape
     (Mosaic lane-tiling rule; see _heads_per_block).  Grouped queries need
-    a head-block that is one head (D a multiple of 128)."""
+    a lane block whose heads all read one key/value head: a block that is
+    one head (D a multiple of 128), or a group that is whole blocks (D = 64:
+    an even group) over key/value heads that fill whole blocks too."""
     hpb = max(1, LANES // head_dim)
     if n_kv_heads not in (None, n_heads) and (
-            hpb != 1 or n_heads % n_kv_heads):
+            n_heads % n_kv_heads or (n_heads // n_kv_heads) % hpb
+            or n_kv_heads % hpb):
         return False
     return (head_dim * hpb) % LANES == 0 and n_heads % hpb == 0
 
@@ -244,8 +253,16 @@ class _Geom:
             self.hpb = _heads_per_block(self.D)
             assert H % self.hpb == 0 and (self.D * self.hpb) % LANES == 0, (H, self.D)
             self.Hb = H // self.hpb   # head-blocks per batch row
+        # with grouped queries ``group`` counts in lane blocks: the query
+        # blocks that read one key/value block (the query heads of a group
+        # where a block is one head; with hpb heads a block, hpb key/value
+        # heads are read by hpb * heads-per-group / hpb query blocks)
         self.group = 1 if Hkv in (None, H) else H // Hkv
-        assert self.group == 1 or (self.hpb == 1 and H == Hkv * self.group)
+        assert self.group == 1 or (H == Hkv * self.group
+                                   and self.group % self.hpb == 0
+                                   and Hkv % self.hpb == 0)
+        # a key/value lane block holds hpb heads and a query block reads one
+        self.halves = self.hpb if self.group > 1 else 1
         self.qw = self.D * self.hpb   # width of one head-block (lane dim)
         self.G, self.Hg, self.grid_b = grid_geometry(
             B, self.S, self.Sk, self.Hb, self.qw, q.dtype.itemsize, bq, bk,
@@ -280,6 +297,21 @@ class _Geom:
         if self.group > 1:
             t = t % self.q_steps
         return (j * self.bk) // self.bq + t if self.band else t
+
+    def kv_half(self, b):
+        """Which head of its key/value lane block the query block of grid
+        index ``b`` reads (the q-major sweeps); None where a block is one
+        head or the heads pair up one to one."""
+        if self.halves == 1:
+            return None
+        return ((b % self.Hb) * self.hpb // self.group) % self.hpb
+
+    def kv_half_of_step(self, t):
+        """The same for step ``t`` of the kv-major sweep, whose innermost
+        axis walks the ``group`` query blocks of one key/value block."""
+        if self.halves == 1:
+            return None
+        return (t // self.q_steps) * self.hpb // self.group
 
     # index maps: 3-arg (b, i, j) with i indexing q rows, j kv rows; b runs
     # over (row group, head-block group), head-block groups fastest
@@ -349,6 +381,18 @@ def _cat(cols):
     return cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
 
 
+def _kv_cols(block, hh, D, half, halves):
+    """Head ``hh``'s key/value columns of a lane block [rows, hpb * D]: its
+    own slice, or where the block's query heads all read ONE key/value head
+    (``half`` of ``halves``, a traced scalar) that head's."""
+    if half is None:
+        return block[:, hh * D:(hh + 1) * D]
+    cols = block[:, :D]
+    for at in range(1, halves):
+        cols = jnp.where(half == at, block[:, at * D:(at + 1) * D], cols)
+    return cols
+
+
 def _seen(shape, q0, k0, window):
     """[bq, bk] bool: key position <= query position, and inside the window
     (query - key < window) where there is one."""
@@ -393,6 +437,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     i = pl.program_id(1)
 
     window = geom.window
+    half = geom.kv_half(pl.program_id(0))
     if geom.one_block:
         # the whole of K/V is in the block: softmax in one pass, no running
         # statistics (the numbers are the sweep's own: its first block meets
@@ -404,13 +449,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 out = []
                 for hh in range(hpb):
                     cs = slice(hh * D, (hh + 1) * D)
-                    s = _scores(qb[:, cs], kb[:, cs], scale, causal, i * bq, 0,
+                    kh = _kv_cols(kb, hh, D, half, geom.halves)
+                    vh = _kv_cols(vb, hh, D, half, geom.halves)
+                    s = _scores(qb[:, cs], kh, scale, causal, i * bq, 0,
                                 window)
                     m = jnp.max(s, axis=1)[:, None]            # [bq, 1]
                     p = jnp.exp(s - m)                          # [bq, bk] f32
                     l = jnp.maximum(jnp.sum(p, axis=1)[:, None], 1e-30)
                     out.append(jax.lax.dot_general(
-                        p.astype(vb.dtype), vb[:, cs],
+                        p.astype(vb.dtype), vh,
                         (((1,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32) / l)
                     # lse rides a [bq, heads] lane-narrow block, a column a
@@ -444,8 +491,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         for hh in range(hpb):
             cs = slice(hh * D, (hh + 1) * D)
             ls = slice(hh * LANES, (hh + 1) * LANES)
-            s = _scores(q_ref[0][:, cs], k_ref[0][:, cs], scale, causal,
-                        i * bq, jb * bk, window)           # [bq, bk]
+            s = _scores(q_ref[0][:, cs],
+                        _kv_cols(k_ref[0], hh, D, half, geom.halves),
+                        scale, causal, i * bq, jb * bk, window)    # [bq, bk]
 
             m_prev = m_scr[:, ls]                          # [bq, LANES]
             m_cur = jnp.max(s, axis=1)[:, None]            # [bq, 1]
@@ -455,7 +503,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             l_scr[:, ls] = l_scr[:, ls] * alpha + jnp.sum(p, axis=1)[:, None]
             acc_scr[:, cs] = acc_scr[:, cs] * _lanes_to(alpha, D) \
                 + jax.lax.dot_general(
-                    p.astype(v_ref.dtype), v_ref[0][:, cs],
+                    p.astype(v_ref.dtype),
+                    _kv_cols(v_ref[0], hh, D, half, geom.halves),
                     (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
@@ -638,14 +687,15 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     jb = geom.kv_at(i, j)
     run = _kv_runs(i, jb, bq, bk, causal, geom.window, geom.band)
+    half = geom.kv_half(pl.program_id(0))
 
     @pl.when((j >= 0) if run is None else run)
     def _body():
         for hh in range(hpb):
             cs = slice(hh * D, (hh + 1) * D)
             q = q_ref[0][:, cs]
-            k = k_ref[0][:, cs]
-            v = v_ref[0][:, cs]
+            k = _kv_cols(k_ref[0], hh, D, half, geom.halves)
+            v = _kv_cols(v_ref[0], hh, D, half, geom.halves)
             s = _scores(q, k, scale, causal, i * bq, jb * bk, geom.window)
             p = jnp.exp(s - lse_ref[0, 0][:, hh:hh + 1])   # [bq, bk]
             dov = jax.lax.dot_general(do_ref[0][:, cs], v,
@@ -680,27 +730,40 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         run = (j * bk) <= (i * bq + bq - 1) if causal else (t >= 0)
 
+    half = geom.kv_half_of_step(t)
+
+    def add(scr, hh, part):
+        """``part`` [bk, D] of query head ``hh`` into its key/value head's
+        columns of the scratch: its own, or the half the block read."""
+        if half is None:
+            scr[:, hh * D:(hh + 1) * D] += part
+            return
+        for at in range(geom.halves):
+            @pl.when(half == at)
+            def _into():
+                scr[:, at * D:(at + 1) * D] += part
+
     @pl.when(run)
     def _body():
         for hh in range(hpb):
             cs = slice(hh * D, (hh + 1) * D)
             q = q_ref[0][:, cs]
-            k = k_ref[0][:, cs]
-            v = v_ref[0][:, cs]
+            k = _kv_cols(k_ref[0], hh, D, half, geom.halves)
+            v = _kv_cols(v_ref[0], hh, D, half, geom.halves)
             do = do_ref[0][:, cs]
             s = _scores(q, k, scale, causal, i * bq, j * bk, geom.window)
             p = jnp.exp(s - lse_ref[0, 0][:, hh:hh + 1])   # [bq, bk]
             # dv_j += p^T dO
-            dv_scr[:, cs] += jax.lax.dot_general(
+            add(dv_scr, hh, jax.lax.dot_general(
                 p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                preferred_element_type=jnp.float32))
             dov = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                       preferred_element_type=jnp.float32)
             ds = p * (dov - delta_ref[0, 0][:, hh:hh + 1]) * scale
             # dk_j += ds^T q
-            dk_scr[:, cs] += jax.lax.dot_general(
+            add(dk_scr, hh, jax.lax.dot_general(
                 ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                preferred_element_type=jnp.float32))
 
     @pl.when(t == nt - 1)
     def _final():
@@ -870,8 +933,9 @@ def flash_attention_packed(q, k, v, n_heads, causal=False, scale=None,
     if not packed_layout_supported(H, D, Hkv):
         raise ValueError(
             "packed layout cannot tile H=%d (kv %d) heads of D=%d (needs "
-            "D*hpb a multiple of %d lanes with hpb dividing H, and hpb 1 "
-            "for grouped queries); use flash_attention on [B, S, H, D]"
+            "D*hpb a multiple of %d lanes with hpb dividing H, and with "
+            "grouped queries hpb dividing the group and the key/value "
+            "heads); use flash_attention on [B, S, H, D]"
             % (H, Hkv, D, LANES))
     assert k.shape[-1] == v.shape[-1] == Hkv * D, (k.shape, Hkv, D)
     if window is not None:

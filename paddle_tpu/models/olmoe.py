@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .. import monitor
-from ..parallel import optim
+from ..parallel import moe, optim
 from ..parallel.mesh import DP, MeshSpec, local_shard_map
 from ..parallel.train import (StepTrainer, TrainState, make_train_step,
                               shard_pytree, state_specs)
@@ -44,6 +44,7 @@ __all__ = ["olmoe_1b_7b_config", "olmoe_tiny_config", "make_loss_fn",
            "OlmoeTrainer", "build_olmoe_trainer"]
 
 BATCH_SPECS = {"ids": P(DP)}
+STEPPED = {"router_bias"}       # leaves a step sets itself (make_train_step)
 
 
 def olmoe_1b_7b_config(**kw):
@@ -73,11 +74,15 @@ def _forward(params, ids, cfg):
     """The stack on ``ids`` [b, S]: the last activation and the layers'
     router values, each stacked [L]."""
     return run_layers(params["params_layers"], embed(params, ids, cfg), cfg,
-                      with_aux=True)
+                      with_aux=True, prefix=params.get("prefix_layers"),
+                      router_bias=params.get("router_bias"))
 
 
 def make_loss_fn(cfg: TransformerConfig):
-    """Per-device training loss on a batch of ``ids``."""
+    """Per-device training loss on a batch of ``ids``.  Where the routing
+    rule has selection biases (``moe.SIGMOID_BIASED``): ``(loss, {"router_bias":
+    their next values})``, each layer's moved against that layer's load in
+    this step (``moe.balance_bias``; ``make_train_step``'s ``stepped``)."""
 
     def loss_fn(params, batch):
         ids = batch["ids"]
@@ -87,6 +92,9 @@ def make_loss_fn(cfg: TransformerConfig):
             ids.shape)
         x, aux = _forward(params, ids, cfg)
         ce = final_logits_loss(params, x, labels, mask, cfg)
+        if cfg.routing == moe.SIGMOID_BIASED:
+            return ce, {"router_bias": moe.balance_bias(
+                params["router_bias"], aux["load"], cfg.router_bias_rate)}
         if not (cfg.router_aux_coef or cfg.router_z_coef):
             return ce               # a configuration with no auxiliary loss
         return (ce + cfg.router_aux_coef * jnp.mean(aux["load_balance"])
@@ -125,7 +133,7 @@ class OlmoeTrainer(StepTrainer):
 
     def _count_moe(self, ids):
         """Under a monitor session: the token-slots this call routes
-        (``ids`` [..., B, S], any leading step axis; T * k * L a step) and how
+        (``ids`` [..., B, S], any leading step axis; T * k a MoE layer and step) and how
         uneven the routing of the call's first batch is, busiest expert over
         the mean, the largest over layers: a forward of its own that stops
         before the head.  Off the monitor nothing runs or is read back."""
@@ -134,7 +142,7 @@ class OlmoeTrainer(StepTrainer):
             return
         cfg = self.cfg
         mon.registry.counter("monitor.train.moe_assignments").incr(
-            int(ids.size) * cfg.experts_per_token * cfg.n_layers)
+            int(ids.size) * cfg.experts_per_token * cfg.moe_layers)
         if self._load_fn is None:
             self._load_fn = jax.jit(local_shard_map(
                 lambda params, ids: jnp.max(
@@ -151,7 +159,9 @@ def build_olmoe_trainer(cfg, mesh_spec: MeshSpec = None, optimizer=None,
     """Mesh, parameters on the mesh, the jitted sharded step and its scan.
     Data parallel only: the block has no tensor-, pipeline- or
     expert-parallel layout yet.  ``trainer``: the ``OlmoeTrainer`` subclass
-    of another sparse decoder of this block (models/smallthinker.py)."""
+    of another sparse decoder of this block (models/smallthinker.py,
+    models/lfm2.py).  A router's selection biases, where the parameters
+    hold them, are the step's to set and not the optimizer's."""
     mesh_spec = mesh_spec or MeshSpec()
     assert mesh_spec.tp == mesh_spec.pp == cfg.tp == cfg.pp == 1, \
         "the OLMoE block runs at tp == pp == 1"
@@ -163,7 +173,8 @@ def build_olmoe_trainer(cfg, mesh_spec: MeshSpec = None, optimizer=None,
     state = TrainState.create(params, optimizer)
     sspecs = state_specs(pspecs, state)
     build = make_train_step(make_loss_fn(cfg), mesh, pspecs,
-                            grad_sync_axes(cfg), optimizer, BATCH_SPECS)
+                            grad_sync_axes(cfg), optimizer, BATCH_SPECS,
+                            stepped=tuple(STEPPED & set(params)))
     step_fn, multi_fn = build(state), build.multi(state)
     with mesh:
         state = shard_pytree(state, sspecs, mesh)

@@ -120,7 +120,7 @@ class SmallThinkerTrainer(olmoe.OlmoeTrainer):
                    for batch in ids.reshape((-1,) + ids.shape[-2:]))
         mon.registry.counter("monitor.train.moe_rows_held").incr(held)
         mon.registry.gauge("monitor.train.moe_held_rows_share").set(
-            held / (int(ids.size) * cfg.experts_per_token * cfg.n_layers))
+            held / (int(ids.size) * cfg.experts_per_token * cfg.moe_layers))
 
 
 build_smallthinker_trainer = functools.partial(

@@ -38,8 +38,11 @@ GRAD_SYNC, OPTIMIZER = "grad_sync", "optimizer"
 # an expert FFN (dispatch, grouped matmuls, combine) and, inside it, its
 # router (logits, softmax, top-k, auxiliary losses)
 MOE, ROUTER = "moe", "router"
+# the gated short convolution that stands where attention does in some
+# layers of a stack (both projections, the gates and the taps between them)
+SHORT_CONV = "short_conv"
 VOCABULARY = (EMBED, ATTENTION, MLP, LAYER_NORM, LM_HEAD, CONV, BN, POOL, FC,
-              LOSS, GRAD_SYNC, OPTIMIZER, MOE, ROUTER)
+              LOSS, GRAD_SYNC, OPTIMIZER, MOE, ROUTER, SHORT_CONV)
 PHASES = ("forward", "backward", "recompute", GRAD_SYNC, OPTIMIZER)
 
 # `%fusion.12 = bf16[..] fusion(%p.1, %copy-done.2), ..., metadata={op_name="jit(multi)/..." ...}`:

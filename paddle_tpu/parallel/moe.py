@@ -9,10 +9,13 @@
   between them so that nothing after the down projection is kept for the
   backward; then the sort is undone and each token's k rows are summed.
   The FFN of a ``TransformerConfig`` with ``n_experts > 0`` (models/olmoe.py,
-  models/smallthinker.py).  By configuration: the routing rule (``RULES``:
-  softmax over all experts and the k largest as they are, or the k largest
-  logits and a softmax over those), router logits the caller computed from
-  another input, the gate's activation (``ACTIVATIONS``), and WHICH EXPERTS
+  models/smallthinker.py, models/lfm2.py).  By configuration: the routing
+  rule (``RULES``: softmax over all experts and the k largest as they are;
+  the k largest logits and a softmax over those; or sigmoid scores, the k
+  experts chosen by score PLUS a per-expert bias and weighted by the
+  scores WITHOUT it, renormalised, the bias a running state that
+  ``balance_bias`` moves against the load and no gradient reaches), router
+  logits the caller computed from another input, the gate's activation (``ACTIVATIONS``), and WHICH EXPERTS
   THIS DEVICE HOLDS (``first_held`` and the leading size of the experts'
   leaves): the router still ranks all n, the pairs whose expert is held are
   sorted to the front, only a static number of rows that covers them is
@@ -43,12 +46,15 @@ from ..monitor import devscope
 
 __all__ = ["init_moe_params", "switch_moe_ffn", "init_dropless_moe_params",
            "dropless_moe_ffn", "route_top_k", "router_logits", "RULES",
-           "ACTIVATIONS"]
+           "ACTIVATIONS", "balance_bias"]
 
 # routing rules: how the k experts' weights come from the router's logits
 SOFTMAX_TOP_K = "softmax_top_k"     # softmax over all n, the k largest as they are
 TOP_K_SOFTMAX = "top_k_softmax"     # the k largest logits, softmax over those k
-RULES = (SOFTMAX_TOP_K, TOP_K_SOFTMAX)
+# sigmoid scores; the k largest of score + bias; their scores, without the
+# bias, over their sum
+SIGMOID_BIASED = "sigmoid_biased_top_k"
+RULES = (SOFTMAX_TOP_K, TOP_K_SOFTMAX, SIGMOID_BIASED)
 ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
@@ -105,7 +111,7 @@ def router_logits(router, x):
 
 
 @devscope.scoped(devscope.ROUTER)
-def route_top_k(router, x, k, rule=SOFTMAX_TOP_K, logits=None):
+def route_top_k(router, x, k, rule=SOFTMAX_TOP_K, logits=None, bias=None):
     """Router of a dropless layer on the tokens ``x`` [T, E] (or on
     ``logits`` [T, n] where the caller computed them from another input):
     the k experts of each token and their weights, [T, k] each.  By
@@ -119,11 +125,27 @@ def route_top_k(router, x, k, rule=SOFTMAX_TOP_K, logits=None):
       mean of p_e over tokens; 1 at a uniform router;
     - ``router_z`` = mean_t logsumexp(logits_t)^2;
     - ``load_max_over_mean``: the busiest expert's assignments over the mean.
-    """
+
+    SIGMOID_BIASED: ``s = sigmoid(logits)``; the k experts with the largest
+    ``s + bias`` (``bias`` [n] float32: it decides who is chosen and
+    nothing else, so no gradient reaches it); weights ``s_e / (sum of the
+    chosen s + 1e-6)``.  A softmax's auxiliary losses mean nothing to it:
+    ``aux`` holds ``load_max_over_mean`` and ``load`` [n], the assignments
+    to each expert, which is what ``balance_bias`` reads."""
     assert rule in RULES, rule
     n = router.shape[-1]
     if logits is None:
         logits = router_logits(router, x)
+    tokens = logits.shape[0] * col.axis_size_in(DP)
+    if rule == SIGMOID_BIASED:
+        scores = jax.nn.sigmoid(logits)
+        _, top_e = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
+        top_s = jnp.take_along_axis(scores, top_e, axis=-1)
+        top_p = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-6)
+        counts = col.psum(_per_expert(top_e, n), DP)
+        return top_p, top_e, {
+            "load": counts,
+            "load_max_over_mean": jnp.max(counts) * (n / (tokens * k))}
     lse = jax.nn.logsumexp(logits, axis=-1)
     probs = jnp.exp(logits - lse[:, None])
     if rule == SOFTMAX_TOP_K:
@@ -132,13 +154,23 @@ def route_top_k(router, x, k, rule=SOFTMAX_TOP_K, logits=None):
         top_l, top_e = jax.lax.top_k(logits, k)
         top_p = jax.nn.softmax(top_l, axis=-1)
     counts = col.psum(_per_expert(top_e, n), DP)
-    tokens = logits.shape[0] * col.axis_size_in(DP)
     share = counts.astype(jnp.float32) / (tokens * k)
     mean_p = col.psum(jnp.sum(probs, axis=0), DP) / tokens
     aux = {"load_balance": n * jnp.sum(share * mean_p),
            "router_z": col.psum(jnp.sum(jnp.square(lse)), DP) / tokens,
            "load_max_over_mean": jnp.max(share) * n}
     return top_p, top_e, aux
+
+
+def balance_bias(bias, load, rate):
+    """The selection biases after a step that routed ``load`` [..., n]
+    assignments to each expert: ``bias + rate * sign(mean(load) - load)``,
+    an expert under the mean up and one over it down by the same ``rate``
+    whatever the distance (the auxiliary-loss-free balance of Wang et al.,
+    arXiv:2408.15664).  The one rule that moves them."""
+    load = load.astype(jnp.float32)
+    return bias + rate * jnp.sign(
+        jnp.mean(load, axis=-1, keepdims=True) - load)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -343,12 +375,12 @@ _held_expert_ffn.defvjp(lambda *a: (_held_expert_ffn(*a), a[:5]),
 
 @devscope.scoped(devscope.MOE)
 def dropless_moe_ffn(params, x, k, rule=SOFTMAX_TOP_K, act="silu",
-                     logits=None, first_held=0):
+                     logits=None, first_held=0, bias=None):
     """Top-k dropless expert FFN.  x [T, E] (flatten batch and sequence
     before the call); returns ``(y [T, E], aux)`` with
     ``y_t = sum_{e in top k, held} p_te * down_e(act(gate_e x_t) * up_e x_t)``
-    and ``aux`` as ``route_top_k`` gives it (``rule`` and ``logits`` are
-    its).  ``we_gate_up`` / ``we_down`` hold the experts
+    and ``aux`` as ``route_top_k`` gives it (``rule``, ``logits`` and
+    ``bias`` are its).  ``we_gate_up`` / ``we_down`` hold the experts
     [first_held, first_held + their leading size) of the router's n: all of
     them (OLMoE), or this device's share, and then ``y`` is the part of the
     layer's result that its experts give, and ``aux`` also counts the pairs
@@ -361,7 +393,8 @@ def dropless_moe_ffn(params, x, k, rule=SOFTMAX_TOP_K, act="silu",
     projection, and a rematerialised forward stops at the gate/up matmul."""
     n, count = params["router"].shape[-1], params["we_gate_up"].shape[0]
     assert 0 <= first_held and first_held + count <= n, (first_held, count, n)
-    top_p, top_e, aux = route_top_k(params["router"], x, k, rule, logits)
+    top_p, top_e, aux = route_top_k(params["router"], x, k, rule, logits,
+                                    bias)
     ffn = (x, top_p, top_e, params["we_gate_up"], params["we_down"])
     if count == n:
         return _expert_ffn(*ffn, k, act, 0, None), aux

@@ -255,6 +255,11 @@ def transformer_rules(cfg):
         return P(*(lead + dims))
 
     return [
+        # a stack whose layers own different leaves runs at tp == pp == 1
+        # (TransformerConfig): its leading layers' leaves are not stacked,
+        # and all of it is whole on every device
+        (r"^prefix_layers/|^router_bias$", P()),
+        (r"/(conv_in|conv_w|conv_out|w_gate_up|w_down)$", L(None, None)),
         (r"^(tok_emb|lm_head)$", P(TP, None)),       # vocab-parallel
         (r"^pos_emb$|^lnf_", P()),
         (r"/ln[12]_(scale|bias)$", L(None)),

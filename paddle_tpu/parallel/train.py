@@ -78,7 +78,7 @@ def shard_pytree(tree, specs, mesh):
 
 
 def make_train_step(loss_fn, mesh, param_specs, grad_syncs, optimizer,
-                    batch_specs, donate=True, warm_key=None):
+                    batch_specs, donate=True, warm_key=None, stepped=()):
     """Build the jitted sharded train step: the one builder every
     ``build_*_trainer`` goes through.  It decides where the gradients are
     summed, what is donated, how N steps become one dispatch and, through
@@ -98,6 +98,14 @@ def make_train_step(loss_fn, mesh, param_specs, grad_syncs, optimizer,
       say: the loss function returns it already the same on every shard
       (ResNet takes the ``pmean`` of its batch statistics under the
       ``grad_sync`` scope); the builder reduces nothing of it.
+
+    ``stepped``: names of top-level leaves of ``params`` that a STEP sets
+    and the optimizer does not (a router's selection biases, which the
+    load moves): ``loss_fn(params_local, batch_local) -> (loss, {name: next
+    value})``, the same on every shard.  They stay in ``params``, where
+    whoever reads the model finds them (a checkpoint, an export, a
+    reference), and not under ``RUNNING``; whatever the optimizer made of
+    their zero gradient and its weight decay is dropped.
 
     grad_syncs: pytree (matching params) of tuples of mesh axis names whose
     partial gradients must be psum'd (transformer.grad_sync_axes).
@@ -126,6 +134,10 @@ def make_train_step(loss_fn, mesh, param_specs, grad_syncs, optimizer,
         if RUNNING in state:
             (loss, new_state[RUNNING]), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, state[RUNNING], batch)
+        elif stepped:
+            (loss, own), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, batch)
+            assert set(own) == set(stepped), (sorted(own), stepped)
         else:
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         flat_g, treedef = jax.tree.flatten(grads)
@@ -136,6 +148,8 @@ def make_train_step(loss_fn, mesh, param_specs, grad_syncs, optimizer,
         with jax.named_scope(_devscope.OPTIMIZER):
             new_state["params"], new_state["opt"] = opt_update(
                 grads, state["opt"], params, lr)
+        if stepped:
+            new_state["params"] = dict(new_state["params"], **own)
         return new_state, loss
 
     def _mapped(state_template):

@@ -41,6 +41,13 @@ __all__ = ["TransformerConfig", "init_transformer_params", "transformer_param_sp
            "head_row_block", "head_rows_computed"]
 
 
+CONV = "conv"       # a layer kind: the gated short convolution, no attention
+
+
+def _kinds(pattern):
+    return tuple(k if k == CONV else (k[0] or None, k[1]) for k in pattern)
+
+
 @dataclasses.dataclass
 class TransformerConfig:
     vocab_size: int = 32000
@@ -71,7 +78,9 @@ class TransformerConfig:
     norm_eps: float = 1e-6
     positions: str = "learned"       # "learned" (pos_emb) | "rotary" (no pos_emb)
     rope_theta: float = 10000.0
-    qk_norm: bool = False            # norm of the whole q / k projection, before the heads
+    # False | True: RMS norm of the whole q / k projection, before the heads
+    # | "head": of each head on its own, one weight [head_dim] for all heads
+    qk_norm: object = False
     bias: bool = True                # biases on the attention and FFN matmuls
     tie_head: bool = True            # False: the head is its own [V, E] leaf, lm_head
     # n_experts > 0 replaces the GELU FFN by the dropless top-k MoE of
@@ -81,6 +90,9 @@ class TransformerConfig:
     router_aux_coef: float = 0.0     # x load-balance loss, mean over layers
     router_z_coef: float = 0.0       # x router z-loss, mean over layers
     routing: str = "softmax_top_k"   # moe.RULES: how the k weights are formed
+    # moe.SIGMOID_BIASED alone: what a step moves each expert's selection
+    # bias by, against its load (``moe.balance_bias``)
+    router_bias_rate: float = 0.0
     expert_act: str = "silu"         # the gate's activation (moe.ACTIVATIONS)
     router_input: str = "ffn"        # "ffn": the normed FFN input | "block":
     # the block's input, before its first norm and before attention
@@ -92,16 +104,25 @@ class TransformerConfig:
     # n_heads (else grouped queries: wk, wv [E, n_kv_heads * head_dim])
     head_width: int = 0
     n_kv_heads: int = 0
-    # One period of the stack's layer kinds, (window, rotary) a position:
-    # window 0 is full attention, rotary off is NO positional encoding in
-    # that layer.  Empty: one kind, full attention, rotary as ``positions``
-    # says.  n_layers is whole periods.
+    # One period of the stack's layer kinds, a position either (window,
+    # rotary), an attention layer: window 0 is full attention, rotary off is
+    # NO positional encoding in that layer; or CONV, a layer whose operator
+    # is the gated short convolution (``short_conv``) and which has no
+    # attention leaves.  Empty: one kind, full attention, rotary as
+    # ``positions`` says.  n_layers is ``prefix_pattern`` and whole periods.
     layer_pattern: tuple = ()
+    # The kinds of the LEADING layers, which run before the scan over
+    # periods and whose FFN, where the others' is the MoE, is one dense
+    # gated FFN (``expert_act``) of width ``dense_ffn_hidden``
+    prefix_pattern: tuple = ()
+    dense_ffn_hidden: int = 0
+    conv_taps: int = 3               # CONV: taps of the causal depthwise filter
 
     def __post_init__(self):
         assert self.norm in ("layer", "rms") and \
             self.positions in ("learned", "rotary")
         assert self.router_input in ("ffn", "block")
+        assert self.qk_norm in (False, True, "head"), self.qk_norm
         if self.qk_norm or self.positions == "rotary" or self.n_experts:
             # the norm spans the whole projection, rotary positions start at
             # 0 and the MoE routes the tokens it holds: none is sharded yet
@@ -113,12 +134,18 @@ class TransformerConfig:
         if self.kv_heads != self.n_heads or self.head_width:
             assert self.tp == 1 and self.attn_mode == "heads" \
                 and not self.bias and self.n_heads % self.kv_heads == 0
-        self.layer_pattern = tuple((int(w), bool(r))
-                                   for w, r in self.layer_pattern)
+        self.layer_pattern, self.prefix_pattern = (
+            tuple(CONV if k == CONV else (int(k[0]), bool(k[1]))
+                  for k in pattern)
+            for pattern in (self.layer_pattern, self.prefix_pattern))
         if self.layer_pattern:
             assert self.positions == "rotary" and self.causal \
                 and self.tp == self.pp == 1 \
-                and self.n_layers % len(self.layer_pattern) == 0
+                and (self.n_layers - len(self.prefix_pattern)) \
+                % len(self.layer_pattern) == 0
+        if self.prefix_pattern:
+            assert self.layer_pattern and self.n_experts \
+                and self.dense_ffn_hidden and not self.bias
 
     @property
     def head_dim(self):
@@ -134,10 +161,32 @@ class TransformerConfig:
 
     @property
     def layer_kinds(self):
-        """(window or None, rotary) of each layer of one period."""
+        """(window or None, rotary), or CONV, of each layer of one period."""
         if not self.layer_pattern:
             return ((None, self.positions == "rotary"),)
-        return tuple((w or None, r) for w, r in self.layer_pattern)
+        return _kinds(self.layer_pattern)
+
+    @property
+    def prefix_kinds(self):
+        """The same of each leading layer."""
+        return _kinds(self.prefix_pattern)
+
+    @property
+    def per_position(self):
+        """Whether the layers own different leaves, so that the tree holds
+        them by position of the period (``init_transformer_params``)."""
+        return bool(self.prefix_pattern) or CONV in self.layer_pattern
+
+    @property
+    def n_periods(self):
+        return (self.n_layers - len(self.prefix_pattern)) \
+            // len(self.layer_kinds)
+
+    @property
+    def moe_layers(self):
+        """Layers whose FFN is the MoE: all but the leading ones."""
+        return self.n_layers - len(self.prefix_pattern) if self.n_experts \
+            else 0
 
     @property
     def jdtype(self):
@@ -168,12 +217,51 @@ def init_transformer_params(key, cfg: TransformerConfig):
     with an untied head, and the FFN's leaves are either ``w1`` / ``w2`` or
     the MoE's ``router`` / ``we_gate_up`` / ``we_down`` (parallel/moe.py;
     the experts' leaves hold ``experts_here`` of them).  ``wq`` / ``wo``
-    are n_heads * head_dim wide, ``wk`` / ``wv`` kv_heads * head_dim.  Every
-    layer kind of ``layer_pattern`` has the same leaves."""
-    E, F, L, V = cfg.hidden, cfg.ffn_hidden, cfg.n_layers, cfg.vocab_size
+    are n_heads * head_dim wide, ``wk`` / ``wv`` kv_heads * head_dim;
+    ``q_norm`` / ``k_norm`` are as wide as their projection, or one head
+    wide with ``qk_norm="head"``.
+
+    Where every layer has the same leaves (attention layers that differ in
+    window and rotary alone) ``params_layers`` is ONE tree stacked [L, ...].
+    Where they do not (``cfg.per_position``: a CONV position, leading layers
+    with a dense FFN) it holds a tree for each position of the period,
+    ``params_layers["p<i>"]`` stacked [n_periods, ...] with the leaves of
+    that position's kind (``_position_leaves``), ``prefix_layers["l<i>"]``
+    holds each leading layer's, unstacked, and the selection biases of the
+    MoE layers, where the routing rule has them, are ONE top-level leaf
+    ``router_bias`` [moe_layers, n_experts] float32, which takes no
+    gradient and which a step moves itself (``moe.balance_bias``)."""
+    E, V, dt = cfg.hidden, cfg.vocab_size, cfg.jdtype
+    ks = jax.random.split(key, 12)
+    layers = _per_position_layers(ks, cfg) if cfg.per_position \
+        else {"params_layers": _stacked_layers(ks, cfg)}
+    params = {
+        # a lookup averages nothing: where a block reads the un-normed stream
+        # (router_input "block": a router before the first norm) the rows
+        # are seeded N(0, 1), the scale of the branches' outputs, so that a
+        # token's own row and not attention's mean ranks its experts
+        "tok_emb": _dense_init(
+            ks[1], 1 if cfg.n_experts and cfg.router_input == "block" else E,
+            (V, E), dt),
+        "lnf_scale": jnp.ones((E,), jnp.float32),
+        **layers,
+    }
+    if cfg.positions == "learned":
+        params["pos_emb"] = _dense_init(ks[2], E, (cfg.max_seq, E), dt)
+    if cfg.norm == "layer":
+        params["lnf_bias"] = jnp.zeros((E,), jnp.float32)
+    if not cfg.tie_head:
+        params["lm_head"] = _dense_init(ks[3], E, (V, E), dt)
+    return params
+
+
+def _stacked_layers(ks, cfg):
+    """``params_layers`` where every layer has the same leaves: ONE tree,
+    stacked [n_layers, ...] ([pp, layers_per_stage, ...] under pipeline
+    parallelism)."""
+    E, F, L = cfg.hidden, cfg.ffn_hidden, cfg.n_layers
     Q, KV = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
     dt = cfg.jdtype
-    ks = jax.random.split(key, 12)
 
     def stack(fold, fan_in, shape, dtype=dt):
         return jax.vmap(lambda k: _dense_init(
@@ -194,9 +282,7 @@ def init_transformer_params(key, cfg: TransformerConfig):
     if cfg.bias:
         layer["bqkv"] = jnp.zeros((L, 3, E), dt)
         layer["bo"] = jnp.zeros((L, E), dt)
-    if cfg.qk_norm:
-        layer["q_norm"] = jnp.ones((L, Q), jnp.float32)
-        layer["k_norm"] = jnp.ones((L, KV), jnp.float32)
+    layer.update(_qk_norm_leaves(cfg, L))
     if cfg.n_experts:
         n = cfg.experts_here          # the router ranks all n_experts
         layer["router"] = stack(6, E, (E, cfg.n_experts), jnp.float32)
@@ -212,24 +298,94 @@ def init_transformer_params(key, cfg: TransformerConfig):
         layer = jax.tree.map(
             lambda x: x.reshape((cfg.pp, cfg.layers_per_stage) + x.shape[1:]), layer
         )
-    params = {
-        # a lookup averages nothing: where a block reads the un-normed stream
-        # (router_input "block": a router before the first norm) the rows
-        # are seeded N(0, 1), the scale of the branches' outputs, so that a
-        # token's own row and not attention's mean ranks its experts
-        "tok_emb": _dense_init(
-            ks[1], 1 if cfg.n_experts and cfg.router_input == "block" else E,
-            (V, E), dt),
-        "lnf_scale": jnp.ones((E,), jnp.float32),
-        "params_layers": layer,
+    return layer
+
+
+def _qk_norm_leaves(cfg, n):
+    """``q_norm`` / ``k_norm`` of ``n`` stacked attention layers: as wide as
+    the projection, or one head wide with ``qk_norm="head"``; none without
+    ``qk_norm``."""
+    if not cfg.qk_norm:
+        return {}
+    widths = (1, 1) if cfg.qk_norm == "head" \
+        else (cfg.n_heads, cfg.kv_heads)
+    return {name: jnp.ones((n, heads * cfg.head_dim), jnp.float32)
+            for name, heads in zip(("q_norm", "k_norm"), widths)}
+
+
+def _position_leaves(key, cfg, kind, n, dense):
+    """The leaves of ``n`` layers of one ``kind``, stacked [n, ...]: the two
+    norms' scales; attention's ``wq`` / ``wk`` / ``wv`` / ``wo`` (and
+    ``q_norm`` / ``k_norm``), or for CONV ``conv_in`` [E, 3E] (the gates B
+    and C and the value, side by side), ``conv_w`` [taps, E] (tap j meets
+    position t - taps + 1 + j) and ``conv_out`` [E, E]; then the FFN's: the
+    MoE's, or where ``dense`` ``w_gate_up`` [E, 2F] (gate in columns [0, F))
+    and ``w_down`` [F, E] at F = ``dense_ffn_hidden``."""
+    assert cfg.norm == "rms" and not cfg.bias
+    E, dt = cfg.hidden, cfg.jdtype
+    Q, KV = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    keys = jax.random.split(key, n)
+
+    def stack(fold, fan_in, shape, dtype=dt):
+        return jax.vmap(lambda k: _dense_init(
+            jax.random.fold_in(k, fold), fan_in, shape, dtype))(keys)
+
+    leaves = {"ln1_scale": jnp.ones((n, E), jnp.float32),
+              "ln2_scale": jnp.ones((n, E), jnp.float32)}
+    if kind == CONV:
+        leaves.update(conv_in=stack(1, E, (E, 3 * E)),
+                      conv_w=stack(2, cfg.conv_taps, (cfg.conv_taps, E)),
+                      conv_out=stack(3, E, (E, E)))
+    else:
+        leaves.update(wq=stack(1, E, (E, Q)), wk=stack(2, E, (E, KV)),
+                      wv=stack(3, E, (E, KV)), wo=stack(4, Q, (Q, E)))
+        leaves.update(_qk_norm_leaves(cfg, n))
+    if dense:
+        F = cfg.dense_ffn_hidden
+        leaves.update(w_gate_up=stack(5, E, (E, 2 * F)),
+                      w_down=stack(6, F, (F, E)))
+    else:
+        F, held = cfg.ffn_hidden, cfg.experts_here
+        leaves.update(
+            router=stack(7, E, (E, cfg.n_experts), jnp.float32),
+            we_gate_up=stack(8, E, (held, E, 2 * F)),
+            we_down=stack(9, F, (held, F, E)))
+    return leaves
+
+
+ROUTER_BIAS_STD = 0.1
+
+
+def _per_position_layers(ks, cfg):
+    """``prefix_layers``, ``params_layers`` and, where the routing rule has
+    them, ``router_bias`` of a stack whose layers own different leaves."""
+    assert cfg.n_experts and cfg.positions == "rotary"
+    layers = {
+        "prefix_layers": {
+            "l%d" % i: jax.tree.map(lambda a: a[0], _position_leaves(
+                jax.random.fold_in(ks[4], i), cfg, kind, 1, dense=True))
+            for i, kind in enumerate(cfg.prefix_kinds)},
+        "params_layers": {
+            "p%d" % i: _position_leaves(jax.random.fold_in(ks[0], i), cfg,
+                                        kind, cfg.n_periods, dense=False)
+            for i, kind in enumerate(cfg.layer_kinds)},
     }
-    if cfg.positions == "learned":
-        params["pos_emb"] = _dense_init(ks[2], E, (cfg.max_seq, E), dt)
-    if cfg.norm == "layer":
-        params["lnf_bias"] = jnp.zeros((E,), jnp.float32)
-    if not cfg.tie_head:
-        params["lm_head"] = _dense_init(ks[3], E, (V, E), dt)
-    return params
+    from .moe import SIGMOID_BIASED
+
+    if cfg.routing == SIGMOID_BIASED:
+        # seeded off zero, where a trained model's stand: a rule that
+        # weighted by them, or left them out of the choice, gives other
+        # numbers than the rule.  Each share of ``experts_here`` experts
+        # seeds its own from the same key, as the chips of an
+        # expert-parallel layer would: every share then holds the same
+        # biases and, at seeded weights, draws the same load
+        held = cfg.experts_here
+        assert cfg.n_experts % held == 0, (cfg.n_experts, held)
+        layers["router_bias"] = jnp.tile(
+            ROUTER_BIAS_STD * jax.random.normal(
+                ks[5], (cfg.moe_layers, held), jnp.float32),
+            (1, cfg.n_experts // held))
+    return layers
 
 
 def _param_skeleton(cfg: TransformerConfig):
@@ -440,7 +596,8 @@ def gauge_flash_grid(cfg, b, S):
     if cfg.layer_pattern:
         # (q block, kv block) steps of one head's forward sweep that compute
         # and that its grid holds and skips, by layer kind
-        window = max(w or 0 for w, _ in cfg.layer_kinds) or None
+        window = max((k[0] or 0 for k in cfg.layer_kinds if k != CONV),
+                     default=0) or None
         for name, w in (("full", None), ("windowed", window)):
             seen, skipped = kv_blocks(S, *blocks, cfg.causal, w)
             mon.registry.gauge(
@@ -463,7 +620,12 @@ def _attention_heads_mode(pl, h_full, cfg, kind):
     q2, k2, v2 = (h_full @ pl[w] for w in ("wq", "wk", "wv"))  # [b, S, hl*dh]
     if cfg.bias:
         q2, k2, v2 = (y + pl["bqkv"][i] for i, y in enumerate((q2, k2, v2)))
-    if cfg.qk_norm:             # over the whole projection, before the heads
+    if cfg.qk_norm == "head":   # each head on its own, one weight for all
+        q2 = rms_norm(q2.reshape(b, S, hl, dh), pl["q_norm"],
+                      cfg.norm_eps).reshape(q2.shape)
+        k2 = rms_norm(k2.reshape(b, S, kvl, dh), pl["k_norm"],
+                      cfg.norm_eps).reshape(k2.shape)
+    elif cfg.qk_norm:           # over the whole projection, before the heads
         q2 = rms_norm(q2, pl["q_norm"], cfg.norm_eps)
         k2 = rms_norm(k2, pl["k_norm"], cfg.norm_eps)
     if rotary:
@@ -484,7 +646,8 @@ def _attention_heads_mode(pl, h_full, cfg, kind):
         v = v2.reshape(b, S, kvl, dh)
         assert kvl == hl and window is None, \
             "grouped queries and a window run on the packed flash kernel " \
-            "only (head width a multiple of 128, S whole blocks)"
+            "only (flash_attention.packed_layout_supported: a lane block " \
+            "of whole heads that share one key/value head; S whole blocks)"
         o = _local_attention_dispatch(q, k, v, cfg).reshape(b, S, hl * dh)
     out = o @ pl["wo"]                                          # row-parallel partial
     out = col.reduce_scatter(out, TP, dim=1)                    # sum + seq scatter
@@ -507,27 +670,67 @@ def _attention_ring_mode(pl, h_sp, cfg):
     return o + pl["bo"] if cfg.bias else o
 
 
-def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None):
+@devscope.scoped(devscope.SHORT_CONV)
+def short_conv(pl, h):
+    """The gated short convolution on ``h`` [b, S, E], the whole sequence:
+    ``(C * conv(B * z)) @ conv_out`` with ``[B, C, z] = split3(h @
+    conv_in)`` and ``conv(v)_t = sum_j conv_w[j] * v[t - taps + 1 + j]``, a
+    causal depthwise filter whose input is zero before position 0.  The
+    filter and the two gates in float32, rounded once before ``conv_out``.
+
+    Plain ``jnp``: the taps are shifts of the sequence axis, and XLA fuses
+    them with the gates into the elementwise pass between the two matmuls
+    (PERF.md section 6, PR 33, has the trace that left it so)."""
+    taps = pl["conv_w"].astype(jnp.float32)
+    gate_b, gate_c, z = jnp.split(h @ pl["conv_in"], 3, axis=-1)
+    v = gate_b.astype(jnp.float32) * z.astype(jnp.float32)
+    n, S = taps.shape[0], h.shape[1]
+    conv = taps[n - 1] * v
+    for back in range(1, n):        # tap n-1-back meets position t - back
+        conv = conv + taps[n - 1 - back] * jnp.pad(
+            v, ((0, 0), (back, 0), (0, 0)))[:, :S]
+    return (gate_c.astype(jnp.float32) * conv).astype(h.dtype) \
+        @ pl["conv_out"]
+
+
+def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
+                      dense=False, router_bias=None):
     """One pre-norm block on the SP activation [b, S/tp, E]: the new
     activation and the FFN's auxiliary values (the MoE's, ``moe.route_top_k``;
-    None for the dense FFN).  ``kind`` = (window or None, rotary): which of
-    ``cfg.layer_kinds`` this layer is (None: the first)."""
+    None for a dense FFN).  ``kind`` = (window or None, rotary), or CONV:
+    which of ``cfg.layer_kinds`` this layer is (None: the first); ``dense``:
+    a leading layer, whose FFN is the dense gated one; ``router_bias`` [n]:
+    this layer's selection biases, where the routing rule has them."""
     heads_mode = cfg.attn_mode == "heads"
     logits = None
-    if cfg.n_experts and cfg.router_input == "block":
+    if cfg.n_experts and cfg.router_input == "block" and not dense:
         from .moe import router_logits
 
         # the router reads the residual stream as it ENTERS the block
         logits = router_logits(pl["router"], x_sp.reshape(-1, x_sp.shape[-1]))
-    with jax.named_scope(devscope.ATTENTION):
-        h = _norm(x_sp, pl, "ln1", cfg)
-        if heads_mode:
-            h = col.all_gather(h, TP, dim=1)
-            attn = _attention_heads_mode(pl, h, cfg,
-                                         kind or cfg.layer_kinds[0])
-        else:
-            attn = _attention_ring_mode(pl, h, cfg)
-        x_sp = x_sp + attn
+    if kind == CONV:
+        with jax.named_scope(devscope.SHORT_CONV):
+            x_sp = x_sp + short_conv(pl, _norm(x_sp, pl, "ln1", cfg))
+    else:
+        with jax.named_scope(devscope.ATTENTION):
+            h = _norm(x_sp, pl, "ln1", cfg)
+            if heads_mode:
+                h = col.all_gather(h, TP, dim=1)
+                attn = _attention_heads_mode(pl, h, cfg,
+                                             kind or cfg.layer_kinds[0])
+            else:
+                attn = _attention_ring_mode(pl, h, cfg)
+            x_sp = x_sp + attn
+
+    if dense:
+        with jax.named_scope(devscope.MLP):
+            from .moe import ACTIVATIONS
+
+            gate, up = jnp.split(
+                _norm(x_sp, pl, "ln2", cfg) @ pl["w_gate_up"], 2, axis=-1)
+            hidden = (ACTIVATIONS[cfg.expert_act](gate.astype(jnp.float32))
+                      * up.astype(jnp.float32)).astype(x_sp.dtype)
+            return x_sp + hidden @ pl["w_down"], None
 
     if cfg.n_experts:
         with jax.named_scope(devscope.MOE):
@@ -537,7 +740,7 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None):
             y, aux = dropless_moe_ffn(
                 pl, h.reshape(-1, h.shape[-1]), cfg.experts_per_token,
                 rule=cfg.routing, act=cfg.expert_act, logits=logits,
-                first_held=cfg.first_expert)
+                first_held=cfg.first_expert, bias=router_bias)
             return x_sp + y.reshape(h.shape), aux
 
     with jax.named_scope(devscope.MLP):
@@ -555,36 +758,56 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None):
     return x_sp, None
 
 
-def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False):
+def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
+               prefix=None, router_bias=None):
     """scan over the (local) stacked layers; remat per layer if configured.
     ``with_aux`` also returns the layers' auxiliary values, stacked [L]
     (``moe.route_top_k``'s of each MoE layer; None for a dense stack).
 
-    A pattern of several layer kinds is scanned a PERIOD at a time: the
-    stacked leaves [L, ...] are read as [L / period, period, ...] and the
+    A pattern of several layer kinds is scanned a PERIOD at a time and the
     body runs the period's layers in turn, each with its static kind
-    (``scan_unroll`` then counts periods).  One kind is the scan over
-    layers it always was."""
+    (``scan_unroll`` then counts periods).  Where the kinds have the same
+    leaves, the stacked leaves [L, ...] are read as [L / period, period,
+    ...]; where they do not (``cfg.per_position``) ``layer_params`` holds a
+    tree for each position, stacked [L / period, ...], the leading layers
+    (``prefix``, a tree each) run before the scan under the same remat, and
+    ``router_bias`` [moe_layers, n] is read a period's rows a turn.  One
+    kind is the scan over layers it always was."""
     kinds = cfg.layer_kinds
     body = transformer_layer
     if cfg.remat:
-        body = jax.checkpoint(body, static_argnums=(2, 3))
+        body = jax.checkpoint(body, static_argnums=(2, 3, 4))
     unroll = max(int(cfg.scan_unroll), 1)
-    if len(kinds) == 1:
-        x_sp, aux = jax.lax.scan(lambda x, pl: body(pl, x, cfg, kinds[0]),
-                                 x_sp, layer_params, unroll=unroll)
+    if len(kinds) == 1 and not cfg.per_position:
+        x_sp, aux = jax.lax.scan(
+            lambda x, pl: body(pl, x, cfg, kinds[0], False),
+            x_sp, layer_params, unroll=unroll)
         return (x_sp, aux) if with_aux else x_sp
 
-    def period(x, pls):
+    if cfg.per_position:
+        for i, kind in enumerate(cfg.prefix_kinds):
+            x_sp, _ = body(prefix["l%d" % i], x_sp, cfg, kind, True)
+        at_position = [layer_params["p%d" % i] for i in range(len(kinds))]
+    else:
+        at_position = jax.tree.map(
+            lambda a: a.reshape((-1, len(kinds)) + a.shape[1:]), layer_params)
+    if router_bias is not None:
+        router_bias = router_bias.reshape((-1, len(kinds))
+                                          + router_bias.shape[1:])
+
+    def period(x, turn):
+        pls, biases = turn
         auxes = []
         for at, kind in enumerate(kinds):
-            x, aux = body(jax.tree.map(lambda a: a[at], pls), x, cfg, kind)
+            pl = pls[at] if cfg.per_position \
+                else jax.tree.map(lambda a: a[at], pls)
+            x, aux = body(pl, x, cfg, kind, False,
+                          None if biases is None else biases[at])
             auxes.append(aux)
         return x, jax.tree.map(lambda *a: jnp.stack(a), *auxes)
 
-    x_sp, aux = jax.lax.scan(period, x_sp, jax.tree.map(
-        lambda a: a.reshape((-1, len(kinds)) + a.shape[1:]), layer_params),
-        unroll=unroll)
+    x_sp, aux = jax.lax.scan(period, x_sp, (at_position, router_bias),
+                             unroll=unroll)
     aux = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), aux)
     return (x_sp, aux) if with_aux else x_sp
 

@@ -106,6 +106,7 @@ def main(argv=None):
                       for g in ("pairs_per_grid_step", "grid_steps")))
         for row in mon.registry.snapshot():    # a sparse decoder's own
             if row["name"].startswith(("monitor.train.moe_",
+                                       "monitor.train.router_",
                                        "monitor.kernels.flash_kv_blocks_")):
                 print("monitor: %s %s" % (row["name"], row.get("value")))
         monitor.disable()

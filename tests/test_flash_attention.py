@@ -320,6 +320,13 @@ MODES = [
     ("window and group 7",        1, 512, 7,  1, 128, 64,  64,  100,  True),
     ("window and group 3",        2, 256, 6,  2, 128, 64,  64,  72,   True),
     ("window of S: causal",       1, 256, 3,  1, 128, 64,  64,  256,  True),
+    # two heads a lane block, both on one key/value head (PR 33)
+    ("group 4 at width 64",       2, 256, 8,  2, 64,  64,  64,  None, True),
+    ("32 on 8 heads of 64",       1, 128, 32, 8, 64,  64,  64,  None, True),
+    ("group 4 at 64, one block",  1, 128, 8,  2, 64,  128, 128, None, True),
+    ("group 2 at 64, both ways",  1, 256, 4,  2, 64,  128, 64,  None, False),
+    ("group 6 at 64, bq < bk",    1, 256, 12, 2, 64,  64,  128, None, True),
+    ("window and group 4 at 64",  1, 512, 8,  2, 64,  64,  64,  100,  True),
 ]
 
 
@@ -397,8 +404,51 @@ def test_the_band_s_grid(S, bq, bk, window, steps, blocks):
 def test_grouped_queries_need_whole_head_blocks():
     assert fa.packed_layout_supported(28, 128, 4)
     assert fa.packed_layout_supported(12, 64)
-    assert not fa.packed_layout_supported(12, 64, 4)    # two heads a block
+    assert fa.packed_layout_supported(32, 64, 8)        # a group is 2 blocks
+    assert fa.packed_layout_supported(4, 64, 2)         # a group is 1 block
+    assert not fa.packed_layout_supported(12, 64, 4)    # a group of 3 heads
+    # is a block and a half
+    assert not fa.packed_layout_supported(6, 64, 3)     # 3 kv heads: 1.5 blocks
     assert not fa.packed_layout_supported(28, 128, 5)   # 5 does not divide 28
-    q, k, v, _ = _packed_qkv(23, 1, 128, 4, 2, 64)
+    q, k, v, _ = _packed_qkv(23, 1, 128, 6, 3, 64)
     with pytest.raises(ValueError, match="grouped"):
-        fa.flash_attention_packed(q, k, v, 4, n_kv_heads=2)
+        fa.flash_attention_packed(q, k, v, 6, n_kv_heads=3)
+
+
+@pytest.mark.parametrize("what,B,S,H,Hkv,D,want", [
+    # a (row, head-block) pair a step; steps = B x head-blocks x q blocks
+    ("smallthinker_21b_a3b.s16384_scan", 1, 16384, 28, 4, 128, (1, 28 * 32)),
+    ("lfm2_8b_a1b.s8192_scan", 2, 8192, 32, 8, 64, (1, 2 * 16 * 16)),
+    ("bert_base.s128_scan, ungrouped", 256, 128, 12, 12, 64, (6, 256)),
+    ("bert_base.s512_scan, ungrouped", 64, 512, 12, None, 64, (1, 384)),
+])
+def test_packed_grid_of_the_grouped_cells(what, B, S, H, Hkv, D, want):
+    """The grids of the width-128 grouped mode and of BERT's ungrouped
+    width-64 mode are what they were before grouped queries ran at two
+    heads a lane block."""
+    assert fa.packed_grid(B, S, H, D, 512, 512, n_kv_heads=Hkv) == want
+
+
+def test_a_query_block_reads_one_half_of_its_key_value_block():
+    """32 query heads on 8 key/value heads of 64: query block b (heads 2b,
+    2b + 1) reads key/value head b // 2, which is half (b // 2) % 2 of
+    key/value block b // 4; the kv-major sweep walks a block's 4 query
+    blocks, the first two into half 0."""
+    q = jnp.zeros((1, 1024, 32 * 64), jnp.bfloat16)
+    k = jnp.zeros((1, 1024, 8 * 64), jnp.bfloat16)
+    g = fa._Geom(q, k, 32, 512, 512, Hkv=8)
+    assert (g.hpb, g.Hb, g.group, g.halves, g.grid_b) == (2, 16, 4, 2, 16)
+    for b in range(16):
+        assert g.kmap()(b, 0, 0)[2] == b // 4
+        assert g.kv_half(b) == (b // 2) % 2 == ((2 * b) // 4) % 2
+    assert [g.kv_half_of_step(t) for t in range(0, 8, 2)] == [0, 0, 1, 1]
+    qm, km, _ = g.dkv_maps()
+    assert [qm(1, 0, t)[2] for t in range(0, 8, 2)] == [4, 5, 6, 7]
+    assert km(1, 0, 0) == (0, 0, 1)
+    # a block that is one head, or heads that pair up one to one: no half
+    wide = fa._Geom(jnp.zeros((1, 512, 28 * 128)), jnp.zeros((1, 512, 512)),
+                    28, 512, 512, Hkv=4)
+    bert = fa._Geom(jnp.zeros((1, 512, 768)), jnp.zeros((1, 512, 768)), 12,
+                    512, 512)
+    assert wide.kv_half(3) is None and bert.kv_half(3) is None
+    assert wide.kv_half_of_step(3) is None and bert.halves == 1

@@ -63,18 +63,25 @@ def test_forward_and_backward_compile_for_a_v5e(one_chip, what, entry, B, S,
     assert text.count("tpu_custom_call") >= 2, what
 
 
-@pytest.mark.parametrize("what,window,names", [
-    ("smallthinker_21b_a3b.s16384_scan, a full layer", None,
+SMALLTHINKER, LFM2 = (1, 16384, 28, 4, 128), (2, 8192, 32, 8, 64)
+
+
+@pytest.mark.parametrize("what,shape,window,names", [
+    ("smallthinker_21b_a3b.s16384_scan, a full layer", SMALLTHINKER, None,
      ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")),
-    ("smallthinker_21b_a3b.s16384_scan, a windowed layer", 4096,
+    ("smallthinker_21b_a3b.s16384_scan, a windowed layer", SMALLTHINKER, 4096,
      ("flash_swa_fwd", "flash_swa_bwd_dq", "flash_swa_bwd_dkv")),
+    ("lfm2_8b_a1b.s8192_scan, two heads a lane block", LFM2, None,
+     ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")),
 ])
-def test_grouped_and_windowed_kernels_compile_for_a_v5e(one_chip, what,
+def test_grouped_and_windowed_kernels_compile_for_a_v5e(one_chip, what, shape,
                                                         window, names):
     """28 query heads on 4 key/value heads of 128 over 16,384 positions:
     the index maps' integer arithmetic and the dk/dv sweep over a group's
-    heads are what Mosaic has to take."""
-    B, S, H, Hkv, D = 1, 16384, 28, 4, 128
+    heads are what Mosaic has to take; at 32 on 8 heads of 64, the select
+    of a key/value block's half by a traced scalar and the dk/dv sums into
+    that half as well."""
+    B, S, H, Hkv, D = shape
     xq = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
     xk = jax.ShapeDtypeStruct((B, S, Hkv * D), jnp.bfloat16, sharding=one_chip)
     attn = lambda q, k, v: fa.flash_attention_packed(

@@ -1,0 +1,423 @@
+"""The LFM2-MoE hybrid decoder through the normal path (``models/lfm2.py``
+over ``parallel/transformer.py``'s per-position leaves, prefix layer and
+short convolution, ``parallel/moe.py``'s biased sigmoid rule and the flash
+kernels' grouped mode at two heads a lane block) against the benchmark's
+plain float32 reference (``benchmark/reference/lfm2_8b_a1b.py``), on seeded
+weights at ``lfm2_tiny_config``: one dense layer (a convolution) and one
+period (attention, three convolutions), hidden 64, 4 query heads on 2
+key/value heads of 64, 8 experts of width 32 of which this share holds 2,
+top-2, vocab 256, S = 64.
+
+The tiny configuration computes in float32, so the tolerance is 1e-5 (the
+two differ by accumulation order only)."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.reference import lfm2_8b_a1b as reference  # noqa: E402
+from paddle_tpu import monitor  # noqa: E402
+from paddle_tpu.models import lfm2, olmoe  # noqa: E402
+from paddle_tpu.monitor import devscope  # noqa: E402
+from paddle_tpu.parallel import moe, optim, transformer as T  # noqa: E402
+from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
+from paddle_tpu.parallel.train import stack_batches  # noqa: E402
+
+B, S, TOL = 2, 64, 1e-5
+# the reference reads the published keys
+MODEL = {"num_attention_heads": 4, "num_key_value_heads": 2,
+         "num_hidden_layers": 5, "num_dense_layers": 1,
+         "first_expert_layer": 2, "layer_types": list(lfm2.LAYER_TYPES),
+         "norm_eps": 1e-5, "rope_theta": 1000000, "conv_L_cache": 3,
+         "num_experts_per_tok": 2, "num_experts": 2, "moe_router_width": 8,
+         "moe_first_expert_held": 2, "norm_topk_prob": True,
+         "use_expert_bias": True, "routed_scaling_factor": 1}
+CONV = ("ln1_scale", "ln2_scale", "conv_in", "conv_w", "conv_out")
+ATTN = ("ln1_scale", "ln2_scale", "wq", "wk", "wv", "wo", "q_norm", "k_norm")
+EXPERTS = ("router", "we_gate_up", "we_down")
+LEAVES = (["tok_emb", "lnf_scale"]
+          + ["prefix_layers/l0/" + n for n in CONV + ("w_gate_up", "w_down")]
+          + ["params_layers/p0/" + n for n in ATTN + EXPERTS]
+          + ["params_layers/p%d/%s" % (p, n) for p in (1, 2, 3)
+             for n in CONV + EXPERTS])
+
+
+def _trainer(seed=3, optimizer=None, **cfg):
+    return lfm2.build_lfm2_trainer(
+        lfm2.lfm2_tiny_config(**cfg), MeshSpec(dp=1),
+        optimizer=optimizer or optim.adamw(), seed=seed,
+        devices=jax.devices()[:1])
+
+
+def _ids(seed=0, n=1):
+    rng = np.random.RandomState(seed)
+    return [rng.randint(0, 256, (B, S)).astype(np.int32) for _ in range(n)]
+
+
+def _seeded_params(tr):
+    """The trainer's seeded weights with the norm scales moved off 1, so
+    that a missing or misplaced scale shows, a router steep enough that the
+    scores are not all one half, and biases large enough to change who is
+    chosen at many tokens."""
+    rng = np.random.RandomState(11)
+
+    def moved(path, a):
+        name = jax.tree_util.keystr(path)
+        if "scale" in name or "_norm" in name:
+            return np.asarray(a) * rng.uniform(0.5, 1.5, a.shape).astype("f4")
+        return np.asarray(a) * (3.0 if "router" in name else 1.0)
+
+    return jax.tree_util.tree_map_with_path(moved, tr.state["params"])
+
+
+def _leaf(tree, path):
+    for part in path.split("/"):
+        tree = tree[part]
+    return tree
+
+
+@pytest.fixture(scope="module")
+def both():
+    """Loss and gradients of program and reference on the same weights."""
+    tr = _trainer()
+    params = _seeded_params(tr)
+    ids = _ids()[0]
+    loss_fn = olmoe.make_loss_fn(tr.cfg)
+    got = jax.jit(jax.value_and_grad(
+        lambda p: loss_fn(p, {"ids": jnp.asarray(ids)}), has_aux=True))(params)
+    want = jax.value_and_grad(
+        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
+            jax.tree.map(jnp.asarray, params))
+    return tr.cfg, params, ids, got, want
+
+
+def test_the_tiny_configuration_keeps_every_mechanism():
+    cfg = lfm2.lfm2_tiny_config()
+    assert cfg.prefix_kinds == (T.CONV,)
+    assert cfg.layer_kinds == ((None, True), T.CONV, T.CONV, T.CONV)
+    assert cfg.per_position and cfg.n_periods == 1 and cfg.moe_layers == 4
+    assert (cfg.n_heads, cfg.kv_heads, cfg.head_dim) == (4, 2, 64)
+    assert cfg.qk_norm == "head" and cfg.tie_head
+    assert (cfg.n_experts, cfg.experts_here, cfg.first_expert) == (8, 2, 2)
+    assert cfg.routing == moe.SIGMOID_BIASED and cfg.router_bias_rate == 1e-3
+    assert T._packed_flash_blocks(cfg, 4, S, 2) == (16, 16)   # the kernels run
+    big = lfm2.lfm2_8b_a1b_config()
+    assert big.prefix_kinds == (T.CONV, T.CONV) and big.n_periods == 4
+    assert (big.n_layers, big.hidden, big.n_heads, big.kv_heads,
+            big.head_dim, big.ffn_hidden, big.dense_ffn_hidden,
+            big.n_experts, big.experts_per_token, big.experts_here,
+            big.vocab_size, big.conv_taps) == (
+        18, 2048, 32, 8, 64, 1792, 7168, 32, 4, 32, 65536, 3)
+    assert lfm2.LAYER_TYPES.count("full_attention") == 6
+    cut = lfm2.lfm2_8b_a1b_config(n_layers=9, n_dense_layers=1)
+    assert cut.prefix_kinds == (T.CONV,) and cut.n_periods == 2
+    with pytest.raises(AssertionError):
+        lfm2.lfm2_8b_a1b_config(n_layers=24)     # no whole period past 18
+
+
+def test_loss_equals_the_reference(both):
+    _, _, _, ((got, _), _), (want, _) = both
+    assert abs(float(got) - float(want)) / float(want) < TOL
+
+
+def test_every_position_s_logits_equal_the_reference(both):
+    cfg, params, ids, _, _ = both
+    x, _ = jax.jit(lambda p, i: olmoe._forward(p, i, cfg))(params, ids)
+    got = T.rms_norm(x, params["lnf_scale"], cfg.norm_eps) @ params["tok_emb"].T
+    _, want = reference.forward(params, ids, MODEL)
+    np.testing.assert_allclose(got, np.stack(want), rtol=1e-4, atol=TOL)
+
+
+@pytest.mark.parametrize("path", LEAVES)
+def test_gradient_of_every_leaf_equals_the_reference(both, path):
+    _, params, _, (_, got), (_, want) = both
+    g, w = np.asarray(_leaf(got, path)), np.asarray(_leaf(want, path))
+    assert g.shape == _leaf(params, path).shape and np.abs(w).max() > 0
+    np.testing.assert_allclose(g, w, rtol=1e-4, atol=TOL * np.abs(w).max())
+
+
+def test_the_leaves_tested_are_all_there_are_but_the_bias(both):
+    _, params, _, (_, got), (_, want) = both
+    paths, _, _ = __import__(
+        "paddle_tpu.parallel.rules", fromlist=["leaf_paths"]).leaf_paths(params)
+    assert set(paths) == set(LEAVES) | {"router_bias"}
+    # no gradient reaches the bias: it decides who is chosen, nothing else
+    assert params["router_bias"].shape == (4, 8)
+    assert not np.asarray(got["router_bias"]).any()
+    assert not np.asarray(want["router_bias"]).any()
+
+
+def test_every_share_seeds_the_same_biases():
+    """Each share of ``experts_here`` experts draws its own biases from the
+    same key: b_e = x_(e mod held), off zero, another draw a layer."""
+    cfg = lfm2.lfm2_tiny_config()
+    bias = np.asarray(T.init_transformer_params(
+        jax.random.PRNGKey(7), cfg)["router_bias"])
+    assert bias.shape == (4, 8) and bias.dtype == np.float32
+    for first in range(2, 8, 2):
+        np.testing.assert_array_equal(bias[:, first:first + 2], bias[:, :2])
+    assert 0.02 < np.abs(bias).mean() < 0.3 and len(np.unique(bias[:, 0])) == 4
+    whole = np.asarray(T.init_transformer_params(
+        jax.random.PRNGKey(7), lfm2.lfm2_tiny_config(experts_held=8,
+                                                     first_expert=0))[
+        "router_bias"])
+    assert len(np.unique(whole)) == 32          # every expert its own
+
+
+def test_sharding_specs_and_gradient_syncs_follow_the_tree():
+    cfg = lfm2.lfm2_tiny_config()
+    params = jax.eval_shape(
+        lambda: T.init_transformer_params(jax.random.PRNGKey(0), cfg))
+    for tree in (T.transformer_param_specs(cfg), T.grad_sync_axes(cfg)):
+        assert jax.tree.structure(
+            tree, is_leaf=lambda x: isinstance(x, (tuple, T.P))) == \
+            jax.tree.structure(params)
+    specs = T.transformer_param_specs(cfg)
+    assert specs["router_bias"] == T.P()
+    assert specs["prefix_layers"]["l0"]["conv_in"] == T.P()
+
+
+@pytest.fixture(scope="module")
+def witnessed():
+    """A trainer that holds HALF the experts (4 of 8, the second half), its
+    weights moved as ``both``'s, and its own logits at the witness's
+    positions: with 2 of 8 held, half the positions meet no held expert in
+    any layer, a routing fault does not touch them and the witness's first
+    quartile is theirs; with 4 held, two in a thousand are such (and at the
+    cell's sizes, 8 layers of top-4 with 8 of 32 held, six in a hundred
+    thousand)."""
+    tr = _trainer(experts_held=4, first_expert=4)
+    params = _seeded_params(tr)
+    tr.state["params"] = jax.tree.map(jnp.asarray, params)
+    ids = _ids(seed=9)[0]
+    program = np.asarray(tr.logits_at(ids, reference.witness_positions(S)))
+    assert program.shape == (B, S, 256)
+    return params, ids, program, dict(MODEL, num_experts=4,
+                                      moe_first_expert_held=4)
+
+
+def test_the_witness_holds_the_program_s_logits(witnessed):
+    """What ``benchmark/drivers/train_scan_witnessed.py`` checks on the chip:
+    ``StepTrainer``'s own forward at the witness's positions against the
+    reference's logits, as one relative error."""
+    params, ids, program, model = witnessed
+    each = reference.position_errors(program, params, {"ids": ids}, model)
+    assert each.shape == (B * S,) and each.max() < TOL
+    assert reference.logits_error(program, params, {"ids": ids}, model) \
+        == np.quantile(each, 0.25)
+
+
+@pytest.mark.parametrize("fault", reference.FAULTS[:-1])
+def test_the_witness_sees_every_fault(witnessed, fault, monkeypatch):
+    """Each fault in the reference moves its logits away from the program's
+    by a thousand times what the two differ by when both are sound, at the
+    witness's own statistic."""
+    params, ids, program, model = witnessed
+    monkeypatch.setattr(reference, "DENSE_CHUNK", 32)   # 3 chunks of 96
+    assert reference.logits_error(program, params, {"ids": ids}, model,
+                                  faults=(fault,)) > 1e3 * TOL
+
+
+def test_bfloat16_throughout_moves_the_reference_s_loss(both):
+    _, params, ids, _, (want, _) = both
+    bad = reference.loss(params, {"ids": ids}, MODEL,
+                         faults=("bfloat16_throughout",))
+    assert abs(bad - float(want)) / float(want) > 2 * TOL
+    assert reference.witness_positions(8192)[[0, 1, -1]].tolist() == [
+        16, 48, 8176]
+
+
+def test_the_reference_in_small_pieces_equals_itself_whole(both, monkeypatch):
+    _, params, ids, _, (want, want_grad) = both
+    params = jax.tree.map(jnp.asarray, params)
+    monkeypatch.setattr(reference, "QUERY_BLOCK", 16)       # 4 blocks of 64
+    monkeypatch.setattr(reference, "VOCAB_CHUNK", 100)      # 100, 100, 56
+    monkeypatch.setattr(reference, "EXPERT_GROUP", 1)
+    monkeypatch.setattr(reference, "DENSE_CHUNK", 40)       # 40, 40, 16
+    loss, grad = jax.value_and_grad(
+        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
+            params)
+    assert abs(float(loss) - float(want)) / float(want) < 1e-6
+    for g, w in zip(jax.tree.leaves(grad), jax.tree.leaves(want_grad)):
+        np.testing.assert_allclose(g, w, rtol=1e-4,
+                                   atol=1e-5 * max(np.abs(w).max(), 1e-30))
+
+
+def _layer_inputs():
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    whole = moe.init_dropless_moe_params(ks[0], 8, 64, 32)
+    whole["router"] = whole["router"] * 3.0
+    m = jax.random.normal(ks[1], (S, 64))
+    bias = 0.3 * jax.random.normal(ks[2], (8,))
+    return whole, m, bias
+
+
+def test_the_four_shares_add_up_to_the_uncut_reference_layer():
+    """The PROGRAM's expert layer on each of the four shares of 2 experts,
+    summed, is the REFERENCE's layer with all 8 experts held: what a share
+    leaves out is exactly what the other three compute."""
+    whole, m, bias = _layer_inputs()
+    want = reference.moe_part(m, whole["router"], bias, whole["we_gate_up"],
+                              whole["we_down"], 0, 2)
+    parts = []
+    for first in range(0, 8, 2):
+        share = dict(whole, we_gate_up=whole["we_gate_up"][first:first + 2],
+                     we_down=whole["we_down"][first:first + 2])
+        y, aux = moe.dropless_moe_ffn(share, m, 2, rule=moe.SIGMOID_BIASED,
+                                      first_held=first, bias=bias)
+        parts.append(y)
+        assert int(jnp.sum(aux["load"])) == 2 * S       # over all 8 experts
+        np.testing.assert_allclose(y, reference.moe_part(
+            m, whole["router"], bias, share["we_gate_up"], share["we_down"],
+            first, 2), rtol=1e-5, atol=1e-5)
+    assert all(float(jnp.abs(p).max()) > 0 for p in parts)
+    np.testing.assert_allclose(sum(parts), want, rtol=1e-5, atol=1e-5)
+
+
+def test_the_bias_changes_who_is_chosen_and_never_a_weight():
+    whole, m, bias = _layer_inputs()
+    logits = moe.router_logits(whole["router"], m)
+    scores = np.asarray(jax.nn.sigmoid(logits))
+    plain_p, plain_e, _ = moe.route_top_k(
+        whole["router"], m, 2, moe.SIGMOID_BIASED, bias=jnp.zeros(8))
+    top_p, top_e, aux = moe.route_top_k(
+        whole["router"], m, 2, moe.SIGMOID_BIASED, bias=bias)
+    moved = np.asarray(jnp.sort(top_e, -1) != jnp.sort(plain_e, -1)).any(-1)
+    assert 0.1 < moved.mean() < 1.0             # many tokens, not all
+    # the chosen are the two largest of score + bias ...
+    want_e = np.argsort(-(scores + np.asarray(bias)), axis=-1)[:, :2]
+    assert (np.sort(want_e, -1) == np.sort(np.asarray(top_e), -1)).all()
+    # ... and their weights are the scores WITHOUT it, over their sum
+    picked = np.take_along_axis(scores, np.asarray(top_e), -1)
+    np.testing.assert_allclose(
+        top_p, picked / (picked.sum(-1, keepdims=True) + 1e-6), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(top_p).sum(-1), 1.0, atol=1e-4)
+    assert np.asarray(aux["load"]).tolist() == np.bincount(
+        np.asarray(top_e).ravel(), minlength=8).tolist()
+    # no gradient reaches it, through the weights or anything else
+    grad = jax.grad(lambda b: jnp.sum(moe.dropless_moe_ffn(
+        whole, m, 2, rule=moe.SIGMOID_BIASED, bias=b)[0] ** 2))(bias)
+    assert not np.asarray(grad).any()
+
+
+def test_a_step_moves_the_bias_by_the_rule_and_nothing_else_does():
+    """After one step ``b += u * sign(mean_load - load)`` from that step's
+    own counts; Adam's update and a weight decay large enough to show have
+    not touched it, and have moved the rest."""
+    tr = _trainer(optimizer=optim.adamw(weight_decay=0.5))
+    ids = _ids(seed=6)[0]
+    params0 = jax.tree.map(np.asarray, tr.state["params"])
+    _, aux = jax.jit(lambda p, i: olmoe._forward(p, i, tr.cfg))(
+        tr.state["params"], ids)
+    load = np.asarray(aux["load"], np.float32)              # [4 layers, 8]
+    assert load.shape == (4, 8) and (load.sum(-1) == B * S * 2).all()
+    tr.step({"ids": jnp.asarray(ids)}, 0.1)
+    after = jax.tree.map(np.asarray, tr.state["params"])
+    want = params0["router_bias"] + np.float32(1e-3) * np.sign(
+        load.mean(-1, keepdims=True) - load)
+    np.testing.assert_array_equal(after["router_bias"], want.astype("f4"))
+    assert (np.abs(after["router_bias"] - params0["router_bias"])
+            <= 1.0001e-3).all()
+    router = "params_layers/p1/router"
+    assert np.abs(_leaf(after, router) - _leaf(params0, router)).max() > 1e-3
+    # the balance rule itself
+    np.testing.assert_allclose(
+        moe.balance_bias(jnp.zeros(4), jnp.array([5, 1, 3, 3]), 0.5),
+        [-0.5, 0.5, 0.0, 0.0])
+
+
+def test_the_convolution_is_causal_and_starts_from_zeros():
+    """A change at position t moves no output before t; positions 0 and 1
+    see zeros where the sequence has no history."""
+    E = 64
+    pl = jax.tree.map(lambda a: a[0], T._position_leaves(
+        jax.random.PRNGKey(2), lfm2.lfm2_tiny_config(), T.CONV, 1, True))
+    h = jax.random.normal(jax.random.PRNGKey(3), (1, S, E))
+    out = np.asarray(T.short_conv(pl, h))
+    t = 20
+    moved = np.asarray(T.short_conv(pl, h.at[:, t].add(1.0)))
+    assert np.array_equal(moved[:, :t], out[:, :t])
+    for at in (t, t + 1, t + 2):                # three taps reach two ahead
+        assert np.abs(moved[:, at] - out[:, at]).max() > 1e-3
+    np.testing.assert_allclose(moved[:, t + 3:], out[:, t + 3:], atol=1e-6)
+    # by hand at the sequence's start: c_0 = w_2 v_0, c_1 = w_1 v_0 + w_2 v_1
+    gate_b, gate_c, z = np.split(np.asarray(h[0] @ pl["conv_in"]), 3, -1)
+    v, w = gate_b * z, np.asarray(pl["conv_w"])
+    c = np.stack([w[2] * v[0], w[1] * v[0] + w[2] * v[1],
+                  w[0] * v[0] + w[1] * v[1] + w[2] * v[2]])
+    np.testing.assert_allclose(
+        out[0, :3], (gate_c[:3] * c) @ np.asarray(pl["conv_out"]),
+        rtol=1e-4, atol=1e-5)
+
+
+def test_run_steps_over_three_batches_equals_three_steps():
+    batches = [{"ids": i} for i in _ids(seed=5, n=3)]
+    one, scan = _trainer(remat=True), _trainer(remat=True)
+    singly = [float(one.step(b, 1e-3)) for b in batches]
+    scanned = scan.run_steps(
+        stack_batches(scan.mesh, olmoe.BATCH_SPECS, batches), 1e-3)
+    np.testing.assert_allclose(scanned, singly, rtol=1e-5)
+    assert singly[0] != singly[1]
+    for a, b in zip(jax.tree.leaves(one.state["params"]),
+                    jax.tree.leaves(scan.state["params"])):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+    moved = np.asarray(scan.state["params"]["router_bias"])
+    assert np.abs(moved).max() > 0
+
+
+def test_two_periods_scanned_equal_the_reference():
+    """9 layers are the dense layer and two periods: the scan's second turn
+    runs the same four kinds on the second half of each position's leaves
+    and the second four rows of the biases."""
+    tr = _trainer(n_layers=9)
+    assert tr.cfg.n_periods == 2 and tr.cfg.moe_layers == 8
+    params = _seeded_params(tr)
+    ids = _ids(seed=2)[0]
+    got, _ = jax.jit(olmoe.make_loss_fn(tr.cfg))(
+        params, {"ids": jnp.asarray(ids)})
+    want = reference.loss(params, {"ids": ids},
+                          dict(MODEL, num_hidden_layers=9))
+    assert abs(float(got) - want) / want < TOL
+
+
+def test_counters_and_gauges_only_under_a_monitor_session(tmp_path):
+    tr = _trainer()
+    assert monitor.active() is None
+    batches = [{"ids": i} for i in _ids(seed=8, n=2)]
+    mon = monitor.enable(str(tmp_path), flight=False)
+    try:
+        reg = mon.registry
+        slots = reg.counter("monitor.train.moe_assignments")
+        held = reg.counter("monitor.train.moe_rows_held")
+        start, held_start = slots.value, held.value
+        tr.run_steps(stack_batches(tr.mesh, olmoe.BATCH_SPECS, batches), 1e-3)
+        pairs = 2 * B * S * 2 * 4       # batches x tokens x top-2 x MoE layers
+        assert slots.value - start == pairs
+        got = held.value - held_start
+        assert 0 < got < pairs
+        np.testing.assert_allclose(
+            reg.gauge("monitor.train.moe_held_rows_share").value, got / pairs)
+        bias = reg.gauge("monitor.train.router_bias_abs_max").value
+        assert 0.1 < bias < 0.6                 # N(0, 0.1^2), 32 draws
+    finally:
+        monitor.disable()
+
+
+def test_the_short_convolution_s_instructions_are_under_their_scope():
+    tr = _trainer(remat=True)
+    tr.run_steps(stack_batches(tr.mesh, olmoe.BATCH_SPECS,
+                               [{"ids": i} for i in _ids(n=2)]), 1e-3)
+    names = devscope.scope_maps()["lfm2.run_steps"]
+    got = {devscope.classify(op) for op in names.values()}
+    for scope in ("short_conv", "moe", "router", "attention", "mlp",
+                  "layer_norm", "lm_head", "embed"):
+        assert ("forward", scope) in got and ("backward", scope) in got, scope
+    assert ("recompute", "short_conv") in got
