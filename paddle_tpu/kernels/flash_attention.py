@@ -10,20 +10,25 @@ Two layouts, one set of kernels (``_Geom``): the packed [B, S, H*D] entry the
 models use (``flash_attention_packed``: the projections' own layout, each
 128-lane head-block addressed in place by the BlockSpec index maps) and
 [BH, S, D] behind ``flash_attention`` for head shapes the packed layout
-cannot tile.  Grid is (row-groups x head-block-groups, q_blocks, kv_blocks),
-kv innermost.
+cannot tile.
 
-Several kv blocks (S above the block size): the running max (m),
-denominator (l) and output accumulator live in VMEM scratch across the kv
-sweep (the standard TPU flash schedule), and the backward recomputes the
-probabilities blockwise from the saved row logsumexp in two kernels
-(``flash_bwd_dq``, ``flash_bwd_dkv``: FlashAttention-2).  One kv block (S up
-to the block size, every BERT shape): the forward needs no running
-statistics and the backward is ONE kernel (``flash_bwd_fused``) that shares
-one recomputed probability tile between dq, dk and dv.  There, where the
-whole sequence is one block, a grid step carries a fixed amount of work
-whatever S is: ``step_geometry`` packs G batch rows and Hg head-blocks into
-the step's blocks; the kernel bodies loop over the rows and unroll over the
+Several kv blocks (S above the block size): a grid row is one (batch row,
+head-block) pair and a grid step one (q block, kv block) pair of
+``step_table``, built on the host from the shapes and the mask and read by
+the index maps as scalar-prefetch operands: only the pairs the mask lets
+something through are steps (the triangle under the diagonal, a window's
+band, the rectangle).  The running max
+(m), denominator (l) and output accumulator live in VMEM scratch across a q
+block's sweep (the standard TPU flash schedule), and the backward
+recomputes the probabilities blockwise from the saved row logsumexp in two
+kernels (``flash_bwd_dq``, ``flash_bwd_dkv``: FlashAttention-2).  One kv
+block (S up to the block size, every BERT shape): grid (row-groups x
+head-block-groups, q blocks, 1), the forward needs no running statistics
+and the backward is ONE kernel (``flash_bwd_fused``) that shares one
+recomputed probability tile between dq, dk and dv.  There, where the whole
+sequence is one block, a grid step carries a fixed amount of work whatever
+S is: ``step_geometry`` packs G batch rows and Hg head-blocks into the
+step's blocks; the kernel bodies loop over the rows and unroll over the
 head-blocks (the compiler interleaves the independent heads), each
 (row, head) computed exactly as a step of its own would.
 
@@ -38,18 +43,17 @@ Two more modes of the packed entry, both of the same kernels:
   of a key/value lane block; the index maps bring the block and the kernel
   takes the half (``_Geom.kv_half``: a select, a thousandth of a step's
   work).  The dk/dv sweep runs once per KEY/VALUE lane block and its
-  innermost grid axis walks the query blocks that read it, so dk and dv
-  are summed over the group in the kernel's scratch, each query block into
-  the half it read.  One (row, head-block) pair a grid step whatever S is,
-  and the two-sweep backward even at one block.
+  table walks the query blocks that read it, so dk and dv are summed over
+  the group in the kernel's scratch, each query block into the half it
+  read.  One (row, head-block) pair a grid row whatever S is, and the
+  two-sweep backward even at one block.
 - a sliding window (``window`` = W < S, causal): query i sees keys j with
-  i - W < j <= i.  The kv axis of the grid is the BAND (``band_steps``: 9
-  blocks of 512 for W = 4096, not S / 512), the index maps place step j of
-  q block i at kv block ``last(i) - (steps - 1) + j`` (below 0: skipped),
-  every visited block is masked, and the kernels carry names of their own
-  (``flash_swa_fwd``, ``flash_swa_bwd_dq``, ``flash_swa_bwd_dkv``) so that a
-  trace's reader can tell a windowed layer's calls from a full one's.  A
-  window of S or more is the causal mask and runs the causal kernels.
+  i - W < j <= i.  The sweeps' tables hold the BAND (at most 9 kv blocks of
+  512 a q block for W = 4096, not S / 512), and the kernels carry names of
+  their own (``flash_swa_fwd``, ``flash_swa_bwd_dq``, ``flash_swa_bwd_dkv``)
+  so that a trace's reader can tell a windowed layer's calls from a full
+  one's.  A window of S or more is the causal mask and runs the causal
+  kernels.
 
 All matmuls feed the MXU in the input dtype with f32 accumulation.
 interpret=True (CPU tests) is selected automatically off-TPU.
@@ -59,6 +63,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -179,54 +184,64 @@ def grid_geometry(B, S, Sk, n_head_blocks, lanes, itemsize, bq, bk, group=1):
     return G, Hg, (B // G) * (n_head_blocks // Hg)
 
 
-def _kv_span(i, bq, bk, window):
-    """(first, last) kv block that q block i sees through the window: query
-    rows [i*bq, i*bq + bq) see keys from ``i*bq - window + 1`` to
-    ``i*bq + bq - 1``."""
-    return max((i * bq - window + 1) // bk, 0), (i * bq + bq - 1) // bk
+FIRST, LAST = 1, 2    # a step's flags in ``step_table``
 
 
-def band_steps(S, bq, bk, window):
-    """Windowed mode: (kv blocks one q block visits, q blocks one kv block
-    visits), the largest over the blocks, so the static length of the
-    sweeps' inner grid axes."""
-    nq, nk = S // bq, S // bk
-    kv = max(hi - lo + 1 for lo, hi in
-             (_kv_span(i, bq, bk, window) for i in range(nq)))
-    q = max(min((j * bk + bk - 2 + window) // bq, nq - 1) - (j * bk) // bq + 1
-            for j in range(nk))
-    return kv, q
+def step_table(S, Sk, bq, bk, causal, window=None, group=1, kv_major=False):
+    """The grid steps of one multi-block sweep, in order: int32 [4, steps],
+    the columns ``(q block, kv block, head, flags)`` of each step.  A step
+    is a (q block, kv block) pair that holds at least one (query, key) pair
+    the mask lets through (key <= query, and query - key < ``window`` where
+    there is one; every pair where not ``causal``), so the triangle, the band
+    and the rectangle are three tables of this one builder and no step of a
+    grid is empty.
+
+    q-major (the forward and the dq sweep): by q block, its kv blocks
+    ascending; ``head`` is 0.  kv-major (the dk/dv sweep): by kv block, then
+    the ``group`` query head-blocks that read it (``head``), then q block
+    ascending, so the sum over the group stays in the kernel's scratch.
+    Flags: FIRST and LAST open and close a sweep (zero the scratch, write the
+    output block).  Every step masks its scores: a third flag for the blocks
+    the mask cuts, with an unmasked body for the others, was slower on the
+    chip at head width 128 (PERF.md section 6, PR 34)."""
+    far = float("inf") if window is None else window - 1
+
+    def seen(i, j):
+        """query - key runs from lo to hi over block (i, j) and the mask
+        lets 0 .. far through."""
+        lo, hi = i * bq - (j * bk + bk - 1), i * bq + bq - 1 - j * bk
+        return not causal or (hi >= 0 and lo <= far)
+
+    nq, nk = S // bq, Sk // bk
+    sweeps = [[(i, j, h) for h in range(group) for i in range(nq)
+               if seen(i, j)] for j in range(nk)] if kv_major else \
+        [[(i, j, 0) for j in range(nk) if seen(i, j)] for i in range(nq)]
+    assert all(sweeps), "a block no query and key meet in: %r" % (
+        (S, Sk, bq, bk, window),)
+    return np.array([
+        (i, j, h, FIRST * (n == 0) | LAST * (n == len(sweep) - 1))
+        for sweep in sweeps for n, (i, j, h) in enumerate(sweep)], np.int32).T
 
 
 def kv_blocks(S, bq, bk, causal=True, window=None):
-    """(visited, skipped): the (q block, kv block) grid steps of one head's
-    forward sweep that compute, and those the grid holds and skips.  A
-    windowed sweep's grid is its band, so it skips only the band's steps
-    that fall before the sequence's start."""
-    nq, nk = S // bq, S // bk
-    steps = nk
-    if window is not None and window < S:
-        steps = band_steps(S, bq, bk, window)[0]
-    elif causal:
-        window = S
-    if window is None:
-        return nq * nk, 0
-    seen = sum(hi - lo + 1 for lo, hi in
-               (_kv_span(i, bq, bk, window) for i in range(nq)))
-    return seen, nq * steps - seen
+    """The (q block, kv block) grid steps of one head's forward sweep."""
+    return step_table(S, S, bq, bk, causal, window).shape[1]
 
 
 def packed_grid(B, S, n_heads, head_dim, block_q, block_k, itemsize=2,
-                n_kv_heads=None):
+                n_kv_heads=None, causal=False, window=None):
     """What ``flash_attention_packed`` runs for these shapes, for whoever
     wants to say so without tracing it (the trainers' monitor gauges):
-    (pairs per grid step, grid steps of one layer's pass)."""
+    (pairs per grid step, grid steps of one layer's forward pass).  Several
+    blocks: a (row, head-block) pair times its sweeps' ``step_table``."""
     hpb = _heads_per_block(head_dim)
     bq, bk = min(block_q, S), min(block_k, S)
     G, Hg, steps = grid_geometry(B, S, S, n_heads // hpb, head_dim * hpb,
                                  itemsize, bq, bk,
                                  n_heads // (n_kv_heads or n_heads))
-    return G * Hg, steps * (S // bq)
+    if S == bk:
+        return G * Hg, steps * (S // bq)
+    return 1, steps * kv_blocks(S, bq, bk, causal, window)
 
 
 class _Geom:
@@ -238,10 +253,8 @@ class _Geom:
     wherever the sequence is more than one block).
 
     ``Hkv`` < H: grouped queries, k and v hold Hkv heads and q head h reads
-    kv head ``h // group``.  ``window`` (None: none): the kv axis of the
-    q-major sweeps has ``kv_steps`` steps and the q axis of the kv-major
-    sweep ``q_steps`` a head (``band_steps``), placed by ``kv_at`` /
-    ``q_at``."""
+    kv head ``h // group``.  ``window`` (None: none) and the causal mask
+    shape the several-block sweeps through their ``step_table`` alone."""
 
     def __init__(self, q, k, H, bq, bk, Hkv=None, window=None):
         B, self.S, E = q.shape
@@ -267,89 +280,67 @@ class _Geom:
         self.G, self.Hg, self.grid_b = grid_geometry(
             B, self.S, self.Sk, self.Hb, self.qw, q.dtype.itemsize, bq, bk,
             self.group)
-        self.bq, self.bk = bq, bk
-        self.nq, self.nk = self.S // bq, self.Sk // bk
+        self.nq = self.S // bq
         self.one_block = self.Sk == bk
-        # the band: only where the sequence is several kv blocks (in one
-        # block a window is its mask alone)
         self.window = window
-        self.band = window is not None and not self.one_block
-        self.kv_steps, self.q_steps = (
-            band_steps(self.S, bq, bk, window) if self.band
-            else (self.nk, self.nq))
         self.o_shape = q.shape
         self.dkv_shape = k.shape
         # stats are 4-D so the block's last dim equals the array's (Mosaic
         # tiling rule): [row, head-block group, S, heads of the group]
         self.stat_shape = (B, self.Hb // self.Hg, self.S, self.Hg * self.hpb)
 
-    def kv_at(self, i, j):
-        """The kv block that step j of q block i's sweep visits (below 0:
-        before the sequence's start, skipped)."""
-        if not self.band:
-            return j
-        return (i * self.bq + self.bq - 1) // self.bk - (self.kv_steps - 1) + j
-
-    def q_at(self, j, t):
-        """The q block that step t of kv block j's sweep visits (nq and
-        above: past the end, skipped); with grouped queries the axis walks
-        the group's heads, ``q_steps`` steps each."""
-        if self.group > 1:
-            t = t % self.q_steps
-        return (j * self.bk) // self.bq + t if self.band else t
-
-    def kv_half(self, b):
-        """Which head of its key/value lane block the query block of grid
-        index ``b`` reads (the q-major sweeps); None where a block is one
-        head or the heads pair up one to one."""
+    def kv_half(self, q_block):
+        """Which head of its key/value lane block a query head-block reads;
+        None where a block is one head or the heads pair up one to one."""
         if self.halves == 1:
             return None
-        return ((b % self.Hb) * self.hpb // self.group) % self.hpb
+        return (q_block * self.hpb // self.group) % self.hpb
 
-    def kv_half_of_step(self, t):
-        """The same for step ``t`` of the kv-major sweep, whose innermost
-        axis walks the ``group`` query blocks of one key/value block."""
-        if self.halves == 1:
-            return None
-        return (t // self.q_steps) * self.hpb // self.group
-
-    # index maps: 3-arg (b, i, j) with i indexing q rows, j kv rows; b runs
-    # over (row group, head-block group), head-block groups fastest
+    # index maps of the one-block kernels: (b, i, j) with i indexing q rows,
+    # j kv rows; b runs over (row group, head-block group), head-block
+    # groups fastest
     def qmap(self):
         n = self.Hb // self.Hg
         return lambda b, i, j=0: (b // n, i, b % n)
 
     def kmap(self):
         n = self.Hb // self.Hg
-        if self.group == 1 and not self.band:
+        if self.group == 1:
             return lambda b, i, j=0: (b // n, j, b % n)
-        return lambda b, i, j=0: (b // n, jnp.maximum(self.kv_at(i, j), 0),
-                                  (b % n) // self.group)
+        return lambda b, i, j=0: (b // n, j, (b % n) // self.group)
 
     def smap(self):
         n = self.Hb // self.Hg
         return lambda b, i, j=0: (b // n, b % n, i, 0)
 
-    def dkv_maps(self):
-        """(q rows, kv rows, row statistics) index maps of the kv-major
-        sweep, grid (b, kv block, t): b runs over (row, KEY/VALUE head) and
-        t over the group's query heads times the q blocks a kv block
-        visits."""
-        if self.group == 1 and not self.band:
-            qm, km, sm = self.qmap(), self.kmap(), self.smap()
-            return (lambda b, j, i: qm(b, i, j), lambda b, j, i: km(b, i, j),
-                    lambda b, j, i: sm(b, i, j))
-        n = self.Hb // self.group           # kv heads
+    def sweep_maps(self, kv_major=False):
+        """(q rows, kv rows, row statistics) index maps of a several-block
+        sweep.  Its grid is (batch row, key/value head-block, query
+        head-block of that one's group, step t of its ``step_table``), the
+        dk/dv sweep's without the third axis (its table walks the group:
+        ``head_of``); the table's columns arrive as scalar-prefetch
+        operands.  No map divides: on the chip a (row, head-block) pair
+        unpacked from one grid index by ``//`` and ``%`` cost each of the
+        sweep's steps 30 to 60 ns (PERF.md section 6, PR 34)."""
+        def at(pick):
+            if kv_major:
+                return lambda r, kh, t, q_of, kv_of, head_of, flags: pick(
+                    r, kh, kh * self.group + head_of[t], q_of[t], kv_of[t])
+            return lambda r, kh, g, t, q_of, kv_of, head_of, flags: pick(
+                r, kh, kh * self.group + g, q_of[t], kv_of[t])
 
-        def head(b, t):
-            return (b % n) * self.group + t // self.q_steps
+        return (at(lambda r, kh, qh, i, j: (r, i, qh)),
+                at(lambda r, kh, qh, i, j: (r, j, kh)),
+                at(lambda r, kh, qh, i, j: (r, qh, i, 0)))
 
-        def rows(j, t):
-            return jnp.minimum(self.q_at(j, t), self.nq - 1)
-
-        return (lambda b, j, t: (b // n, rows(j, t), head(b, t)),
-                lambda b, j, t: (b // n, j, b % n),
-                lambda b, j, t: (b // n, head(b, t), rows(j, t), 0))
+    def step(self, head_of=None):
+        """(t, query head-block) of a sweep's grid position; ``head_of``:
+        the dk/dv sweep's."""
+        if head_of is None:
+            return pl.program_id(3), \
+                pl.program_id(1) * self.group + pl.program_id(2)
+        t = pl.program_id(2)
+        return t, pl.program_id(1) * self.group + head_of[t]
 
     def q_spec(self, bq, index_map=None):
         return pl.BlockSpec((self.G, bq, self.Hg * self.qw),
@@ -413,104 +404,92 @@ def _scores(q, k, scale, causal, q0, k0, window=None):
     return s
 
 
-def _kv_runs(i, jb, bq, bk, causal, window, band):
-    """Whether kv block ``jb`` holds a key that q block i sees; None where
-    every block does."""
-    if band:        # jb <= the diagonal's block by construction
-        return (jb >= 0) & (jb * bk + bk - 1 > i * bq - window)
-    if causal:      # whole kv block strictly in the future -> skip
-        return (jb * bk) <= (i * bq + bq - 1)
-    return None
-
-
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
-                scale, causal, bq, bk, hpb, nk, G, Hg, geom):
-    """hpb = heads per head-block.  The packed [B, S, H*D] layout needs
-    128-wide lane blocks (Mosaic tiling rule), so for D=64 a head-block is 2
-    adjacent heads: its columns are per-head slices and every head keeps
-    independent statistics.  hpb=1 is the [BH, S, D] layout.  Heads never
-    mix: each dot contracts only its own D columns.
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq,
+                hpb, G, Hg, geom):
+    """One kv block.  hpb = heads per head-block.  The packed [B, S, H*D]
+    layout needs 128-wide lane blocks (Mosaic tiling rule), so for D=64 a
+    head-block is 2 adjacent heads: its columns are per-head slices and
+    every head keeps independent statistics.  hpb=1 is the [BH, S, D]
+    layout.  Heads never mix: each dot contracts only its own D columns.
 
     The block is [G, bq, Hg*hpb*D]: G rows by Hg head-blocks, every
-    (row, head) computed on its own."""
+    (row, head) computed on its own.  The whole of K/V is in the block:
+    softmax in one pass, no running statistics (the numbers are the sweep's
+    own: its first block meets m = -inf, l = 0, acc = 0)."""
     D = q_ref.shape[-1] // (Hg * hpb)
     i = pl.program_id(1)
-
     window = geom.window
-    half = geom.kv_half(pl.program_id(0))
-    if geom.one_block:
-        # the whole of K/V is in the block: softmax in one pass, no running
-        # statistics (the numbers are the sweep's own: its first block meets
-        # m = -inf, l = 0, acc = 0)
-        def row(g):
-            for hb in range(Hg):
-                cols = pl.ds(hb * hpb * D, hpb * D)
-                qb, kb, vb = q_ref[g, :, cols], k_ref[g, :, cols], v_ref[g, :, cols]
-                out = []
-                for hh in range(hpb):
-                    cs = slice(hh * D, (hh + 1) * D)
-                    kh = _kv_cols(kb, hh, D, half, geom.halves)
-                    vh = _kv_cols(vb, hh, D, half, geom.halves)
-                    s = _scores(qb[:, cs], kh, scale, causal, i * bq, 0,
-                                window)
-                    m = jnp.max(s, axis=1)[:, None]            # [bq, 1]
-                    p = jnp.exp(s - m)                          # [bq, bk] f32
-                    l = jnp.maximum(jnp.sum(p, axis=1)[:, None], 1e-30)
-                    out.append(jax.lax.dot_general(
-                        p.astype(vb.dtype), vh,
-                        (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32) / l)
-                    # lse rides a [bq, heads] lane-narrow block, a column a
-                    # head: the DMA transfers only the valid lanes, and no
-                    # in-kernel transpose is needed (a lane-replicated
-                    # [bq, 128] output costs ~150MB/layer of HBM traffic at
-                    # bench shapes; a lane-oriented [1, bq] output costs a
-                    # Mosaic relayout per block — both measured slower)
-                    lse_ref[g, 0, :, pl.ds(hb * hpb + hh, 1)] = m + jnp.log(l)
-                o_ref[g, :, cols] = _cat(out).astype(o_ref.dtype)
+    half = geom.kv_half(pl.program_id(0) % geom.Hb)
 
-        _rows(G, row)
-        return
+    def row(g):
+        for hb in range(Hg):
+            cols = pl.ds(hb * hpb * D, hpb * D)
+            qb, kb, vb = q_ref[g, :, cols], k_ref[g, :, cols], v_ref[g, :, cols]
+            out = []
+            for hh in range(hpb):
+                cs = slice(hh * D, (hh + 1) * D)
+                kh = _kv_cols(kb, hh, D, half, geom.halves)
+                vh = _kv_cols(vb, hh, D, half, geom.halves)
+                s = _scores(qb[:, cs], kh, scale, causal, i * bq, 0, window)
+                m = jnp.max(s, axis=1)[:, None]            # [bq, 1]
+                p = jnp.exp(s - m)                          # [bq, bk] f32
+                l = jnp.maximum(jnp.sum(p, axis=1)[:, None], 1e-30)
+                out.append(jax.lax.dot_general(
+                    p.astype(vb.dtype), vh,
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32) / l)
+                # lse rides a [bq, heads] lane-narrow block, a column a
+                # head: the DMA transfers only the valid lanes, and no
+                # in-kernel transpose is needed (a lane-replicated
+                # [bq, 128] output costs ~150MB/layer of HBM traffic at
+                # bench shapes; a lane-oriented [1, bq] output costs a
+                # Mosaic relayout per block — both measured slower)
+                lse_ref[g, 0, :, pl.ds(hb * hpb + hh, 1)] = m + jnp.log(l)
+            o_ref[g, :, cols] = _cat(out).astype(o_ref.dtype)
 
-    # several kv blocks (one pair a step): running max, denominator and
-    # accumulator in scratch across the kv sweep
-    m_scr, l_scr, acc_scr = scratch
-    j = pl.program_id(2)
+    _rows(G, row)
 
-    @pl.when(j == 0)
+
+def _fwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, o_ref,
+                      lse_ref, m_scr, l_scr, acc_scr, *, scale, causal, bq, bk,
+                      hpb, geom):
+    """Several kv blocks, one (row, head-block) pair a grid row and one
+    (q block, kv block) pair a step (``step_table``): running max,
+    denominator and accumulator in scratch across a q block's sweep."""
+    D = q_ref.shape[-1] // hpb
+    t, q_block = geom.step()
+    half = geom.kv_half(q_block)
+
+    @pl.when((flags[t] & FIRST) != 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    jb = geom.kv_at(i, j)
-    run = _kv_runs(i, jb, bq, bk, causal, window, geom.band)
+    for hh in range(hpb):
+        cs = slice(hh * D, (hh + 1) * D)
+        ls = slice(hh * LANES, (hh + 1) * LANES)
+        s = _scores(q_ref[0][:, cs],
+                    _kv_cols(k_ref[0], hh, D, half, geom.halves),
+                    scale, causal, q_of[t] * bq, kv_of[t] * bk,
+                    geom.window)                       # [bq, bk]
 
-    @pl.when((j >= 0) if run is None else run)
-    def _body():
-        for hh in range(hpb):
-            cs = slice(hh * D, (hh + 1) * D)
-            ls = slice(hh * LANES, (hh + 1) * LANES)
-            s = _scores(q_ref[0][:, cs],
-                        _kv_cols(k_ref[0], hh, D, half, geom.halves),
-                        scale, causal, i * bq, jb * bk, window)    # [bq, bk]
+        m_prev = m_scr[:, ls]                          # [bq, LANES]
+        m_cur = jnp.max(s, axis=1)[:, None]            # [bq, 1]
+        m_new = jnp.maximum(m_prev, m_cur)             # [bq, LANES]
+        p = jnp.exp(s - _lanes_to(m_new, bk))          # [bq, bk] f32
+        alpha = jnp.exp(m_prev - m_new)                # [bq, LANES]
+        l_scr[:, ls] = l_scr[:, ls] * alpha + jnp.sum(p, axis=1)[:, None]
+        acc_scr[:, cs] = acc_scr[:, cs] * _lanes_to(alpha, D) \
+            + jax.lax.dot_general(
+                p.astype(v_ref.dtype),
+                _kv_cols(v_ref[0], hh, D, half, geom.halves),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        m_scr[:, ls] = m_new
 
-            m_prev = m_scr[:, ls]                          # [bq, LANES]
-            m_cur = jnp.max(s, axis=1)[:, None]            # [bq, 1]
-            m_new = jnp.maximum(m_prev, m_cur)             # [bq, LANES]
-            p = jnp.exp(s - _lanes_to(m_new, bk))          # [bq, bk] f32
-            alpha = jnp.exp(m_prev - m_new)                # [bq, LANES]
-            l_scr[:, ls] = l_scr[:, ls] * alpha + jnp.sum(p, axis=1)[:, None]
-            acc_scr[:, cs] = acc_scr[:, cs] * _lanes_to(alpha, D) \
-                + jax.lax.dot_general(
-                    p.astype(v_ref.dtype),
-                    _kv_cols(v_ref[0], hh, D, half, geom.halves),
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-            m_scr[:, ls] = m_new
-
-    @pl.when(j == nk - 1)
+    @pl.when((flags[t] & LAST) != 0)
     def _final():
         l = jnp.maximum(l_scr[:], 1e-30)
         alpha_cols = jnp.concatenate(
@@ -528,18 +507,52 @@ def _name(kernel, g):
     return ("flash_swa_" if g.window is not None else "flash_") + kernel
 
 
+def _sweep_call(kernel, g, table, operands, in_specs, out_specs, out_shape,
+                scratch_shapes, kv_major, interpret, name):
+    """One several-block sweep over the steps of ``table`` (its columns
+    scalar-prefetched), for the grid positions ``_Geom.sweep_maps`` names."""
+    heads = (g.Hb // g.group,) + (() if kv_major else (g.group,))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(table),
+            grid=(operands[0].shape[0],) + heads + (table.shape[1],),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape,
+        compiler_params=_CompilerParams(dimension_semantics=(
+            "parallel",) * (1 + len(heads)) + ("arbitrary",)),
+        interpret=interpret,
+        name=_name(name, g),
+    )(*(jnp.asarray(column) for column in table), *operands)
+
+
 def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None, Hkv=None,
          window=None):
     """H=None: q/k/v are [BH, S, D].  H=int: q/k/v are [B, S, H*D] (k, v
     [B, S, Hkv*D] with grouped queries)."""
     g = _Geom(q, k, H, bq, bk, Hkv, window)
-    nq, nk = g.nq, g.kv_steps
+    out_shape = [
+        jax.ShapeDtypeStruct(g.o_shape, q.dtype),
+        jax.ShapeDtypeStruct(g.stat_shape, jnp.float32),
+    ]
+    if not g.one_block:
+        qm, km, sm = g.sweep_maps()
+        return _sweep_call(
+            functools.partial(_fwd_sweep_kernel, scale=scale, causal=causal,
+                              bq=bq, bk=bk, hpb=g.hpb, geom=g),
+            g, step_table(g.S, g.Sk, bq, bk, causal, window), (q, k, v),
+            [g.q_spec(bq, qm), g.kv_spec(bk, km), g.kv_spec(bk, km)],
+            [g.q_spec(bq, qm), g.stat_spec(bq, sm)], out_shape,
+            [pltpu.VMEM((bq, g.hpb * LANES), jnp.float32),
+             pltpu.VMEM((bq, g.hpb * LANES), jnp.float32),
+             pltpu.VMEM((bq, g.qw), jnp.float32)],
+            False, interpret, "fwd")
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               bq=bq, bk=bk, hpb=g.hpb, nk=nk, G=g.G, Hg=g.Hg,
-                               geom=g)
+                               bq=bq, hpb=g.hpb, G=g.G, Hg=g.Hg, geom=g)
     o, lse = pl.pallas_call(
         kernel,
-        grid=(g.grid_b, nq, nk),
+        grid=(g.grid_b, g.nq, 1),
         in_specs=[
             g.q_spec(bq),
             g.kv_spec(bk),
@@ -550,15 +563,7 @@ def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None, Hkv=None,
             # row stats as narrow-lane blocks (see the kernel)
             g.stat_spec(bq),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct(g.o_shape, q.dtype),
-            jax.ShapeDtypeStruct(g.stat_shape, jnp.float32),
-        ],
-        scratch_shapes=[] if g.one_block else [
-            pltpu.VMEM((bq, g.hpb * LANES), jnp.float32),
-            pltpu.VMEM((bq, g.hpb * LANES), jnp.float32),
-            pltpu.VMEM((bq, g.qw), jnp.float32),
-        ],
+        out_shape=out_shape,
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -674,63 +679,49 @@ def _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=None,
 # backward: dq sweep (grid kv-innermost) and dk/dv sweep (grid q-innermost)
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   acc_scr, *, scale, causal, bq, bk, geom, hpb=1):
-    j = pl.program_id(2)
-    nk = pl.num_programs(2)
-    i = pl.program_id(1)
+def _bwd_dq_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
+                   lse_ref, delta_ref, dq_ref, acc_scr, *, scale, causal, bq,
+                   bk, geom, hpb=1):
+    t, q_block = geom.step()
     D = q_ref.shape[-1] // hpb
+    half = geom.kv_half(q_block)
 
-    @pl.when(j == 0)
+    @pl.when((flags[t] & FIRST) != 0)
     def _init():
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    jb = geom.kv_at(i, j)
-    run = _kv_runs(i, jb, bq, bk, causal, geom.window, geom.band)
-    half = geom.kv_half(pl.program_id(0))
+    for hh in range(hpb):
+        cs = slice(hh * D, (hh + 1) * D)
+        q = q_ref[0][:, cs]
+        k = _kv_cols(k_ref[0], hh, D, half, geom.halves)
+        v = _kv_cols(v_ref[0], hh, D, half, geom.halves)
+        s = _scores(q, k, scale, causal, q_of[t] * bq, kv_of[t] * bk,
+                    geom.window)
+        p = jnp.exp(s - lse_ref[0, 0][:, hh:hh + 1])   # [bq, bk]
+        dov = jax.lax.dot_general(do_ref[0][:, cs], v,
+                                  (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        ds = p * (dov - delta_ref[0, 0][:, hh:hh + 1]) * scale  # [bq, bk] f32
+        acc_scr[:, cs] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when((j >= 0) if run is None else run)
-    def _body():
-        for hh in range(hpb):
-            cs = slice(hh * D, (hh + 1) * D)
-            q = q_ref[0][:, cs]
-            k = _kv_cols(k_ref[0], hh, D, half, geom.halves)
-            v = _kv_cols(v_ref[0], hh, D, half, geom.halves)
-            s = _scores(q, k, scale, causal, i * bq, jb * bk, geom.window)
-            p = jnp.exp(s - lse_ref[0, 0][:, hh:hh + 1])   # [bq, bk]
-            dov = jax.lax.dot_general(do_ref[0][:, cs], v,
-                                      (((1,), (1,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-            ds = p * (dov - delta_ref[0, 0][:, hh:hh + 1]) * scale  # [bq, bk] f32
-            acc_scr[:, cs] += jax.lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-    @pl.when(j == nk - 1)
+    @pl.when((flags[t] & LAST) != 0)
     def _final():
         dq_ref[0] = acc_scr[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal, bq, bk,
-                    geom, hpb=1):
-    t = pl.program_id(2)           # q blocks innermost here (of each of the
-    nt = pl.num_programs(2)        # group's query heads in turn)
-    j = pl.program_id(1)
-    D = q_ref.shape[-1] // hpb
+def _bwd_dkv_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
+                    lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
+                    scale, causal, bq, bk, geom, hpb=1):
+    t, q_block = geom.step(head_of)    # q blocks innermost here (of each
+    D = q_ref.shape[-1] // hpb         # of the group's query heads in turn)
+    half = geom.kv_half(q_block)
 
-    @pl.when(t == 0)
+    @pl.when((flags[t] & FIRST) != 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
-
-    i = geom.q_at(j, t)
-    if geom.band:   # i >= the diagonal's block by construction
-        run = (i < geom.nq) & (i * bq < j * bk + bk - 1 + geom.window)
-    else:
-        run = (j * bk) <= (i * bq + bq - 1) if causal else (t >= 0)
-
-    half = geom.kv_half_of_step(t)
 
     def add(scr, hh, part):
         """``part`` [bk, D] of query head ``hh`` into its key/value head's
@@ -743,29 +734,28 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             def _into():
                 scr[:, at * D:(at + 1) * D] += part
 
-    @pl.when(run)
-    def _body():
-        for hh in range(hpb):
-            cs = slice(hh * D, (hh + 1) * D)
-            q = q_ref[0][:, cs]
-            k = _kv_cols(k_ref[0], hh, D, half, geom.halves)
-            v = _kv_cols(v_ref[0], hh, D, half, geom.halves)
-            do = do_ref[0][:, cs]
-            s = _scores(q, k, scale, causal, i * bq, j * bk, geom.window)
-            p = jnp.exp(s - lse_ref[0, 0][:, hh:hh + 1])   # [bq, bk]
-            # dv_j += p^T dO
-            add(dv_scr, hh, jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
-            dov = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-            ds = p * (dov - delta_ref[0, 0][:, hh:hh + 1]) * scale
-            # dk_j += ds^T q
-            add(dk_scr, hh, jax.lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
+    for hh in range(hpb):
+        cs = slice(hh * D, (hh + 1) * D)
+        q = q_ref[0][:, cs]
+        k = _kv_cols(k_ref[0], hh, D, half, geom.halves)
+        v = _kv_cols(v_ref[0], hh, D, half, geom.halves)
+        do = do_ref[0][:, cs]
+        s = _scores(q, k, scale, causal, q_of[t] * bq, kv_of[t] * bk,
+                    geom.window)
+        p = jnp.exp(s - lse_ref[0, 0][:, hh:hh + 1])   # [bq, bk]
+        # dv_j += p^T dO
+        add(dv_scr, hh, jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32))
+        dov = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        ds = p * (dov - delta_ref[0, 0][:, hh:hh + 1]) * scale
+        # dk_j += ds^T q
+        add(dk_scr, hh, jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32))
 
-    @pl.when(t == nt - 1)
+    @pl.when((flags[t] & LAST) != 0)
     def _final():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -775,7 +765,6 @@ def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
          window=None):
     q, k, v, o, lse = res
     g = _Geom(q, k, H, bq, bk, Hkv, window)
-    nq, nk = g.nq, g.nk
     if g.one_block and g.group == 1:
         return _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=H,
                           window=window)
@@ -788,61 +777,29 @@ def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
             (do.astype(jnp.float32) * o.astype(jnp.float32))
             .reshape(B, g.S, g.Hb, g.hpb, g.D), axis=-1
         ).transpose(0, 2, 1, 3)                           # [B, Hb, S, hpb]
-    qb, kb, sb = g.qmap(), g.kmap(), g.smap()
 
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, hpb=g.hpb, geom=g),
-        grid=(g.grid_b, nq, g.kv_steps),
-        in_specs=[
-            pl.BlockSpec((1, bq, g.qw), qb),
-            pl.BlockSpec((1, bk, g.qw), kb),
-            pl.BlockSpec((1, bk, g.qw), kb),
-            pl.BlockSpec((1, bq, g.qw), qb),
-            pl.BlockSpec((1, 1, bq, g.hpb), sb),
-            pl.BlockSpec((1, 1, bq, g.hpb), sb),
-        ],
-        out_specs=pl.BlockSpec((1, bq, g.qw), qb),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, g.qw), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name=_name("bwd_dq", g),
-    )(q, k, v, do, lse, delta)
+    def sweep(kernel, name, kv_major, out_specs, out_shape, scratch_shapes):
+        qm, km, sm = g.sweep_maps(kv_major)
+        qs, ks = g.q_spec(bq, qm), g.kv_spec(bk, km)
+        return _sweep_call(
+            functools.partial(kernel, scale=scale, causal=causal, bq=bq,
+                              bk=bk, hpb=g.hpb, geom=g),
+            g, step_table(g.S, g.Sk, bq, bk, causal, window, g.group,
+                          kv_major),
+            (q, k, v, do, lse, delta),
+            [qs, ks, ks, qs, g.stat_spec(bq, sm), g.stat_spec(bq, sm)],
+            out_specs(qs, ks), out_shape, scratch_shapes, kv_major,
+            interpret, name)
 
-    # dkv sweep: grid is (b, kv, q) — the index-map roles swap, and b runs
-    # over the key/value heads
-    qb2, kb2, sb2 = g.dkv_maps()
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, hpb=g.hpb, geom=g),
-        grid=(g.grid_b // g.group, nk, g.group * g.q_steps),
-        in_specs=[
-            pl.BlockSpec((1, bq, g.qw), qb2),
-            pl.BlockSpec((1, bk, g.qw), kb2),
-            pl.BlockSpec((1, bk, g.qw), kb2),
-            pl.BlockSpec((1, bq, g.qw), qb2),
-            pl.BlockSpec((1, 1, bq, g.hpb), sb2),
-            pl.BlockSpec((1, 1, bq, g.hpb), sb2),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, g.qw), kb2),
-            pl.BlockSpec((1, bk, g.qw), kb2),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(g.dkv_shape, k.dtype),
-            jax.ShapeDtypeStruct(g.dkv_shape, v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, g.qw), jnp.float32),
-            pltpu.VMEM((bk, g.qw), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name=_name("bwd_dkv", g),
-    )(q, k, v, do, lse, delta)
+    dq = sweep(_bwd_dq_kernel, "bwd_dq", False, lambda qs, ks: qs,
+               jax.ShapeDtypeStruct(q.shape, q.dtype),
+               [pltpu.VMEM((bq, g.qw), jnp.float32)])
+    # the dk/dv sweep's rows run over the key/value heads
+    dk, dv = sweep(_bwd_dkv_kernel, "bwd_dkv", True, lambda qs, ks: [ks, ks],
+                   [jax.ShapeDtypeStruct(g.dkv_shape, k.dtype),
+                    jax.ShapeDtypeStruct(g.dkv_shape, v.dtype)],
+                   [pltpu.VMEM((bk, g.qw), jnp.float32),
+                    pltpu.VMEM((bk, g.qw), jnp.float32)])
     return dq, dk, dv
 
 

@@ -578,8 +578,15 @@ def gauge_flash_grid(cfg, b, S):
     take their grid from.  ``monitor.kernels.flash_pairs_per_grid_step`` is
     the (batch row, head-block) pairs a step computes, 1 where every pair is
     a step of its own; ``monitor.kernels.flash_grid_steps`` the steps of one
-    layer's pass.  Both fixed when the step is traced, so gauges; nothing is
-    set where attention does not take the packed kernel."""
+    layer's forward pass (of a full layer's where the kinds differ).  A stack
+    of several layer kinds also says, by kind (``_full``, ``_windowed``), the
+    (q block, kv block) steps of one head's forward sweep:
+    ``monitor.kernels.flash_kv_blocks_visited_*`` those that hold a
+    (query, key) pair the mask lets through and so compute, and
+    ``flash_kv_blocks_skipped_*`` the steps the grid holds beyond them (0:
+    the grid is the sweep's step table).  All fixed when the step is traced,
+    so gauges; nothing is set where attention does not take the packed
+    kernel."""
     mon = monitor.active()
     if mon is None or cfg.attn_mode != "heads":
         return
@@ -590,20 +597,20 @@ def gauge_flash_grid(cfg, b, S):
     from ..kernels.flash_attention import kv_blocks, packed_grid
 
     pairs, steps = packed_grid(b, S, hl, cfg.head_dim, *blocks,
-                               itemsize=cfg.jdtype.itemsize, n_kv_heads=kvl)
+                               itemsize=cfg.jdtype.itemsize, n_kv_heads=kvl,
+                               causal=cfg.causal)
     mon.registry.gauge("monitor.kernels.flash_pairs_per_grid_step").set(pairs)
     mon.registry.gauge("monitor.kernels.flash_grid_steps").set(steps)
     if cfg.layer_pattern:
-        # (q block, kv block) steps of one head's forward sweep that compute
-        # and that its grid holds and skips, by layer kind
         window = max((k[0] or 0 for k in cfg.layer_kinds if k != CONV),
                      default=0) or None
         for name, w in (("full", None), ("windowed", window)):
-            seen, skipped = kv_blocks(S, *blocks, cfg.causal, w)
             mon.registry.gauge(
-                "monitor.kernels.flash_kv_blocks_visited_" + name).set(seen)
+                "monitor.kernels.flash_kv_blocks_visited_" + name).set(
+                    kv_blocks(S, *blocks, cfg.causal, w))
+            # the grid is the table of the visited steps: it holds no other
             mon.registry.gauge(
-                "monitor.kernels.flash_kv_blocks_skipped_" + name).set(skipped)
+                "monitor.kernels.flash_kv_blocks_skipped_" + name).set(0)
 
 
 def _attention_heads_mode(pl, h_full, cfg, kind):

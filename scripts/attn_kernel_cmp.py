@@ -2,12 +2,19 @@
 
     chiprun -- python3 scripts/attn_kernel_cmp.py --batch 256 --seq 128 \
         [--heads 12 --head-dim 64 --block 512 --causal] \
+        [--kv-heads 4 --window 4096] [--tree _checkout/parent] \
         [--force 1x1,4x1,1x6] [--others]
 
 Times ``flash_attention_packed`` (the entry the models call), forward and
 backward, by DEVICE time per kernel name read from a profiler trace, as the
 benchmark reads ``flash_roofline`` (``flash_fwd``, ``flash_bwd_fused``, ...),
 beside the least time the chip could take by the benchmark's own formula.
+``--kv-heads`` (grouped queries) and ``--window`` give the sparse decoders'
+shapes (1 x 16384, 28 on 4 heads of 128, full and W = 4096; 2 x 8192, 32 on
+8 of 64; 4 x 4096, 16 of 128); ``--tree DIR`` times the kernels of another
+checkout (a parent commit unpacked beside this one) with this script, and
+the ``digest`` of the outputs' bytes says whether two trees' kernels gave
+the same numbers bit for bit.
 ``--force GxHg,...`` also times the kernels with the grid step's geometry
 forced to G batch rows by Hg head-blocks (``step_geometry`` replaced for
 that compile: an experiment of this script, not an option of the program)
@@ -62,17 +69,23 @@ def host_ms(name, fn, args, iters=30):
           % (name, (time.perf_counter() - t0) / iters * 1e3), flush=True)
 
 
-def xla_attention(q, k, v, H, causal):
-    """Plain softmax attention on the packed [B, S, H*D] layout."""
+def xla_attention(q, k, v, H, causal, window=None):
+    """Plain softmax attention on the packed [B, S, H*D] layout (k, v at
+    fewer heads: query head h reads key/value head h // group)."""
     import jax
     import jax.numpy as jnp
 
     B, S, E = q.shape
-    q4, k4, v4 = (t.reshape(B, S, H, E // H) for t in (q, k, v))
+    q4 = q.reshape(B, S, H, E // H)
+    k4, v4 = (jnp.repeat(t.reshape(B, S, -1, E // H),
+                         E // t.shape[-1], axis=2) for t in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q4, k4,
                    preferred_element_type=jnp.float32) * (E // H) ** -0.5
     if causal:
-        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -1e30)
+        seen = jnp.tril(jnp.ones((S, S), bool))
+        if window:
+            seen &= ~jnp.tril(jnp.ones((S, S), bool), -window)
+        s = jnp.where(seen, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v4,
                       preferred_element_type=jnp.float32
@@ -87,12 +100,16 @@ def main(argv=None):
     ap.add_argument("--head-dim", type=int, default=64)
     ap.add_argument("--block", type=int, default=512)
     ap.add_argument("--causal", action="store_true")
+    ap.add_argument("--kv-heads", type=int, default=0)
+    ap.add_argument("--window", type=int, default=0)
+    ap.add_argument("--tree", default=ROOT)
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--force", default="")
     ap.add_argument("--vmem-mib", type=int, default=0)
     ap.add_argument("--others", action="store_true")
     args = ap.parse_args(argv)
-    sys.path.insert(0, ROOT)
+    sys.path.insert(0, ROOT)                    # the benchmark's formulas
+    sys.path.insert(0, os.path.abspath(args.tree))      # the kernels
 
     import jax
     import jax.numpy as jnp
@@ -103,29 +120,36 @@ def main(argv=None):
         return 2
 
     from benchmark.flops import flash_attention as need_of
+    from benchmark.flops import flash_attention_gqa
     from benchmark.harness import flops, peaks
+    import hashlib
     import importlib
     fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
 
     B, S, H, D = args.batch, args.seq, args.heads, args.head_dim
+    Hkv, window = args.kv_heads or H, args.window or None
+    sparse = Hkv != H or window is not None      # the sparse decoders' modes
     key = jax.random.PRNGKey(0)
     q, k, v, do = (jax.random.normal(jax.random.fold_in(key, n),
-                                     (B, S, H * D), jnp.bfloat16)
-                   for n in range(4))
-    need = need_of.required(B, S, H * D, causal=args.causal)
+                                     (B, S, h * D), jnp.bfloat16)
+                   for n, h in enumerate((H, Hkv, Hkv, H)))
+    need = (flash_attention_gqa.required(B, S, H, Hkv, D, window) if sparse
+            else need_of.required(B, S, H * D, causal=args.causal))
     peak = peaks.peaks_for(jax.devices()[0].device_kind)
     least = {p: flops.least_seconds(need[p]["flops"], need[p]["bytes"], peak)
              for p in ("fwd", "bwd")}
-    print("B=%d S=%d H=%d D=%d block=%d causal=%s: least fwd %.1f us (%s), "
-          "bwd %.1f us (%s)"
-          % (B, S, H, D, args.block, args.causal, least["fwd"][0] * 1e6,
-             least["fwd"][1], least["bwd"][0] * 1e6, least["bwd"][1]))
+    print("%s: B=%d S=%d H=%d on %d D=%d block=%d causal=%s window=%s: least "
+          "fwd %.1f us (%s), bwd %.1f us (%s)"
+          % (os.path.relpath(fa.__file__, ROOT), B, S, H, Hkv, D, args.block,
+             args.causal, window, least["fwd"][0] * 1e6, least["fwd"][1],
+             least["bwd"][0] * 1e6, least["bwd"][1]))
+    more = dict(n_kv_heads=Hkv, window=window) if sparse else {}
 
     def both():
         def f(q, k, v, do):
             o, vjp = jax.vjp(lambda a, b, c: fa.flash_attention_packed(
                 a, b, c, H, causal=args.causal, block_q=args.block,
-                block_k=args.block), q, k, v)
+                block_k=args.block, **more), q, k, v)
             return (o,) + vjp(do)
         return jax.jit(f)
 
@@ -136,18 +160,26 @@ def main(argv=None):
         import functools
         fa._CompilerParams = functools.partial(
             fa._CompilerParams, vmem_limit_bytes=args.vmem_mib * 2 ** 20)
-    n8 = min(B, 8)
+    # XLA's attention holds the [S, S] scores: a few rows, and short ones
+    n8 = min(B, 8) if S <= 4096 else 0
     ref = [np.asarray(x.astype(jnp.float32)) for x in jax.jit(
         lambda q, k, v, do: (lambda o, vjp: (o,) + vjp(do))(*jax.vjp(
-            lambda a, b, c: xla_attention(a, b, c, H, args.causal), q, k, v))
-    )(q[:n8], k[:n8], v[:n8], do[:n8])]
+            lambda a, b, c: xla_attention(a, b, c, H, args.causal, window),
+            q, k, v))
+    )(q[:n8], k[:n8], v[:n8], do[:n8])] if n8 else None
     want = None
     for geom in [None] + forced:
         if rule is None:
             pairs, steps = 1, -1
         else:
             fa.step_geometry = rule if geom is None else (lambda *a, g=geom: g)
-            pairs, steps = fa.packed_grid(B, S, H, D, args.block, args.block)
+            try:
+                pairs, steps = fa.packed_grid(
+                    B, S, H, D, args.block, args.block, n_kv_heads=Hkv,
+                    causal=args.causal, window=window)
+            except TypeError:       # a checkout whose grids are not tables
+                pairs, steps = fa.packed_grid(B, S, H, D, args.block,
+                                              args.block, n_kv_heads=Hkv)
         fn = both()
         t0 = time.perf_counter()
         got = [np.asarray(x.astype(jnp.float32)) for x in fn(q, k, v, do)]
@@ -157,16 +189,21 @@ def main(argv=None):
         if want is None:
             want = got
         worst = max(float(np.abs(a - b).max()) for a, b in zip(got, want))
-        off = max(float(np.abs(a[:n8] - b).max()) for a, b in zip(got, ref))
-        fwd = per.pop("flash_fwd", 0.0)
-        bwd = sum(per.pop(n) for n in list(per) if n.startswith("flash_bwd"))
+        off = max(float(np.abs(a[:n8] - b).max())
+                  for a, b in zip(got, ref)) if ref else float("nan")
+        digest = hashlib.sha1(b"".join(a.tobytes() for a in got)).hexdigest()
+        kernels = {n: per.pop(n) for n in list(per) if n.startswith("flash_")}
+        fwd = sum(t for n, t in kernels.items() if n.endswith("fwd"))
+        bwd = sum(kernels.values()) - fwd
         print("%-8s %2d pairs a step, %5d steps: fwd %8.1f us, bwd %8.1f us, "
               "roofline %5.1f %%; first call %.1f s; off the rule's by %.3g, "
-              "off XLA's softmax attention by %.3g"
+              "off XLA's softmax attention by %.3g; digest %s"
               % ("rule" if geom is None else "%dx%d" % geom, pairs, steps,
                  fwd, bwd,
                  100e6 * (least["fwd"][0] + least["bwd"][0]) / (fwd + bwd or 1e30),
-                 compiled, worst, off), flush=True)
+                 compiled, worst, off, digest[:12]), flush=True)
+        print("         by kernel: " + ", ".join(
+            "%s %.1f us" % kv for kv in sorted(kernels.items())))
         print("         beside them: " + ", ".join(
             "%s %.1f" % kv for kv in sorted(per.items(), key=lambda kv: -kv[1])[:6]))
     if rule is not None:

@@ -222,15 +222,16 @@ def test_step_geometry_stays_under_the_vmem_budget(S):
                                            < fa.STEP_ROWS * 128)
 
 
-@pytest.mark.parametrize("B,S,H,D,want", [
-    (256, 128, 12, 64, (6, 256)),       # bert_base.s128_scan: 1,536 before
-    (64, 512, 12, 64, (1, 384)),        # bert_base.s512_scan: untouched
-    (4, 4096, 16, 128, (1, 4 * 16 * 8)),  # olmoe: 8 x 8 blocks, untouched
-    (8, 640, 12, 64, (1, 8 * 6 * 5)),   # S = 640 in five blocks of 128
+@pytest.mark.parametrize("B,S,H,D,causal,want", [
+    (256, 128, 12, 64, False, (6, 256)),  # bert_base.s128_scan: 1,536 before
+    (64, 512, 12, 64, False, (1, 384)),   # bert_base.s512_scan: untouched
+    # olmoe: the triangle of 8 x 8 blocks, 36 steps a (row, head) where 64
+    (4, 4096, 16, 128, True, (1, 4 * 16 * 36)),
+    (8, 640, 12, 64, False, (1, 8 * 6 * 25)),  # S = 640 in five blocks of 128
 ])
-def test_packed_grid_of_the_cells(B, S, H, D, want):
+def test_packed_grid_of_the_cells(B, S, H, D, causal, want):
     bq = 512 if S % 512 == 0 or S < 512 else 128
-    assert fa.packed_grid(B, S, H, D, bq, bq) == want
+    assert fa.packed_grid(B, S, H, D, bq, bq, causal=causal) == want
 
 
 def test_several_blocks_are_one_pair_a_step(monkeypatch):
@@ -327,6 +328,17 @@ MODES = [
     ("group 2 at 64, both ways",  1, 256, 4,  2, 64,  128, 64,  None, False),
     ("group 6 at 64, bq < bk",    1, 256, 12, 2, 64,  64,  128, None, True),
     ("window and group 4 at 64",  1, 512, 8,  2, 64,  64,  64,  100,  True),
+    # the sweeps' step tables (PR 34): the triangle, bands of windows on and
+    # off a block's edge, the rectangle
+    ("group 1, eight kv blocks",  1, 512, 2,  2, 128, 64,  64,  None, True),
+    ("window of one block",       1, 512, 7,  1, 128, 64,  64,  64,   True),
+    ("window of a block and one", 1, 512, 3,  1, 128, 64,  64,  65,   True),
+    ("window of 1",               1, 256, 2,  2, 128, 64,  64,  1,    True),
+    ("group 3, bq > bk, causal",  1, 512, 3,  1, 128, 128, 64,  None, True),
+    ("group 7, both ways, 4 x 4", 1, 256, 7,  1, 128, 64,  64,  None, False),
+    ("halves, eight kv blocks",   1, 512, 8,  2, 64,  64,  64,  None, True),
+    ("halves, window of a block", 1, 512, 8,  2, 64,  64,  64,  64,   True),
+    ("halves, window 1, bq < bk", 1, 256, 4,  2, 64,  64,  128, 1,    True),
 ]
 
 
@@ -379,26 +391,87 @@ def test_windowed_kernels_carry_names_of_their_own():
     assert both(512) == both(None)          # the window is the causal mask
 
 
-@pytest.mark.parametrize("S,bq,bk,window,steps,blocks", [
-    (16384, 512, 512, 4096, (9, 9), (252, 36)),   # the cell's windowed layers
-    (16384, 512, 512, None, (32, 32), (528, 496)),   # and its full ones
-    (4096, 512, 512, None, (8, 8), (36, 28)),     # olmoe's
-    (512, 64, 64, 100, (3, 3), (21, 3)),
-    (512, 128, 64, 100, (4, 2), (14, 2)),
+@pytest.mark.parametrize("S,bq,bk,window,blocks", [
+    (16384, 512, 512, 4096, 252),       # the cell's windowed layers: the
+    (16384, 512, 512, None, 528),       # band; and its full one
+    (8192, 512, 512, None, 136),        # lfm2's
+    (4096, 512, 512, None, 36),         # olmoe's
+    (512, 64, 64, 100, 21),
+    (512, 128, 64, 100, 14),
 ])
-def test_the_band_s_grid(S, bq, bk, window, steps, blocks):
-    """The kv axis of a windowed sweep is the band, not S / bk: 9 blocks of
-    32 at the cell's shape; and what the gauges say of a layer kind."""
-    if window is not None:
-        assert fa.band_steps(S, bq, bk, window) == steps
+def test_the_sweeps_visit_the_triangle_and_the_band(S, bq, bk, window, blocks):
+    """A sweep's grid is its table: the blocks under the diagonal (and
+    inside the band), none skipped; what the gauges say of a layer kind."""
     assert fa.kv_blocks(S, bq, bk, True, window) == blocks
-    # every (q block, kv block) that holds a seen pair is visited, no other
-    i, j = np.arange(S)[:, None], np.arange(S)[None, :]
-    seen = (j <= i) & ((i - j < window) if window else True) if S <= 4096 \
-        else None
-    if seen is not None:
-        tiles = seen.reshape(S // bq, bq, S // bk, bk).any(axis=(1, 3))
-        assert tiles.sum() == blocks[0]
+    assert fa.packed_grid(3, S, 4, 128, bq, bk, causal=True, window=window) \
+        == (1, 3 * 4 * blocks)
+    if S <= 4096:
+        assert _tiles(S, S, bq, bk, True, window).sum() == blocks
+
+
+def _tiles(S, Sk, bq, bk, causal, window):
+    """[nq, nk] bool: the blocks that hold a pair the mask lets through, by
+    ``_seen`` itself."""
+    seen = np.asarray(fa._seen((S, Sk), 0, 0, window)) if causal \
+        else np.ones((S, Sk), bool)
+    return seen.reshape(S // bq, bq, Sk // bk, bk).any(axis=(1, 3))
+
+
+#   S     Sk    bq   bk   causal window group
+TABLES = [
+    (2048, 2048, 128, 128, True,  None, 1),     # the triangle
+    (2048, 2048, 128, 128, True,  1024, 7),     # W on a block's edge: 4096
+    (2048, 2048, 128, 128, True,  1000, 3),     # and off it: 4000 of 512s,
+    (2048, 2048, 128, 128, True,  129,  1),     # 513
+    (2048, 2048, 128, 128, True,  1,    4),     # the diagonal alone
+    (2048, 2048, 128, 128, True,  128,  1),
+    (1024, 1024, 256, 128, True,  None, 2),     # bq != bk
+    (1024, 1024, 128, 256, True,  300,  7),
+    (1024, 1024, 64,  256, True,  1,    1),
+    (512,  1024, 128, 128, False, None, 3),     # the rectangle
+    (1024, 1024, 128, 128, False, None, 1),
+]
+
+
+@pytest.mark.parametrize("kv_major", [False, True],
+                         ids=["q-major", "kv-major"])
+@pytest.mark.parametrize("S,Sk,bq,bk,causal,window,group", TABLES)
+def test_step_table(S, Sk, bq, bk, causal, window, group, kv_major):
+    """Every block that holds a visible pair is a step exactly once (once a
+    head of the group in the kv-major sweep), no other block is; FIRST and
+    LAST open and close each sweep once, in sweep order."""
+    visible = _tiles(S, Sk, bq, bk, causal, window)
+    qs, ks, heads, flags = fa.step_table(S, Sk, bq, bk, causal, window,
+                                         group, kv_major)
+    assert qs.dtype == np.int32 and qs.ndim == 1
+    steps = list(zip(qs.tolist(), ks.tolist(), heads.tolist()))
+    assert len(set(steps)) == len(steps)
+    want = {(i, j, h) for i, j in zip(*np.nonzero(visible))
+            for h in range(group if kv_major else 1)}
+    assert set(steps) == want
+    assert not (flags & ~(fa.FIRST | fa.LAST)).any()
+    # sweep order: one run of steps a q block (a kv block), ascending, each
+    # opened and closed once; inside a kv block's, head by head
+    owner, inner = (ks, qs) if kv_major else (qs, ks)
+    assert (np.diff(owner) >= 0).all()
+    first, last = (flags & fa.FIRST) != 0, (flags & fa.LAST) != 0
+    edge = np.r_[True, np.diff(owner) != 0]
+    np.testing.assert_array_equal(first, edge)
+    np.testing.assert_array_equal(last, np.r_[edge[1:], True])
+    for o in np.unique(owner):
+        run = owner == o
+        assert (np.diff(heads[run]) >= 0).all()
+        for h in np.unique(heads[run]):
+            assert (np.diff(inner[run & (heads == h)]) > 0).all()
+    if not kv_major:
+        assert not heads.any()
+
+
+def test_a_block_nobody_sees_is_refused():
+    """Keys past the last query under the causal mask: a dk/dv sweep of no
+    step would leave its output block unwritten."""
+    with pytest.raises(AssertionError, match="no query and key meet"):
+        fa.step_table(256, 512, 128, 128, True, kv_major=True)
 
 
 def test_grouped_queries_need_whole_head_blocks():
@@ -416,17 +489,19 @@ def test_grouped_queries_need_whole_head_blocks():
 
 
 @pytest.mark.parametrize("what,B,S,H,Hkv,D,want", [
-    # a (row, head-block) pair a step; steps = B x head-blocks x q blocks
-    ("smallthinker_21b_a3b.s16384_scan", 1, 16384, 28, 4, 128, (1, 28 * 32)),
-    ("lfm2_8b_a1b.s8192_scan", 2, 8192, 32, 8, 64, (1, 2 * 16 * 16)),
+    # several blocks: steps = B x head-blocks x the triangle's blocks; one
+    # block: (row, head-block) pairs a step, steps = B x head-blocks / pairs
+    ("smallthinker_21b_a3b.s16384_scan", 1, 16384, 28, 4, 128, (1, 28 * 528)),
+    ("lfm2_8b_a1b.s8192_scan", 2, 8192, 32, 8, 64, (1, 2 * 16 * 136)),
     ("bert_base.s128_scan, ungrouped", 256, 128, 12, 12, 64, (6, 256)),
     ("bert_base.s512_scan, ungrouped", 64, 512, 12, None, 64, (1, 384)),
 ])
 def test_packed_grid_of_the_grouped_cells(what, B, S, H, Hkv, D, want):
-    """The grids of the width-128 grouped mode and of BERT's ungrouped
-    width-64 mode are what they were before grouped queries ran at two
-    heads a lane block."""
-    assert fa.packed_grid(B, S, H, D, 512, 512, n_kv_heads=Hkv) == want
+    """The grids of the grouped modes are a (row, head-block) pair's
+    triangle of blocks; BERT's ungrouped width-64 mode is what it was before
+    grouped queries ran at two heads a lane block."""
+    assert fa.packed_grid(B, S, H, D, 512, 512, n_kv_heads=Hkv,
+                          causal=S > 512) == want
 
 
 def test_a_query_block_reads_one_half_of_its_key_value_block():
@@ -438,17 +513,28 @@ def test_a_query_block_reads_one_half_of_its_key_value_block():
     k = jnp.zeros((1, 1024, 8 * 64), jnp.bfloat16)
     g = fa._Geom(q, k, 32, 512, 512, Hkv=8)
     assert (g.hpb, g.Hb, g.group, g.halves, g.grid_b) == (2, 16, 4, 2, 16)
+    table = fa.step_table(1024, 1024, 512, 512, True, None, g.group, True)
+    assert table[2].tolist() == [0, 0, 1, 1, 2, 2, 3, 3, 0, 1, 2, 3]
+    # grid (row, key/value block, query block of its 4, step): query block
+    # b = 4 kh + g reads key/value block kh
+    zero = np.zeros(1, np.int32)
+    qm, km, sm = g.sweep_maps()
     for b in range(16):
         assert g.kmap()(b, 0, 0)[2] == b // 4
+        at = lambda m: m(0, b // 4, b % 4, 0, zero, zero, zero, zero)
+        assert (at(qm)[2], at(km)[2], at(sm)[1]) == (b, b // 4, b)
         assert g.kv_half(b) == (b // 2) % 2 == ((2 * b) // 4) % 2
-    assert [g.kv_half_of_step(t) for t in range(0, 8, 2)] == [0, 0, 1, 1]
-    qm, km, _ = g.dkv_maps()
-    assert [qm(1, 0, t)[2] for t in range(0, 8, 2)] == [4, 5, 6, 7]
-    assert km(1, 0, 0) == (0, 0, 1)
+    # the dk/dv sweep of key/value block 1: its table walks query blocks 4-7
+    qm, km, sm = g.sweep_maps(kv_major=True)
+    at = lambda m, t: m(0, 1, t, *table)
+    assert [at(qm, t)[2] for t in range(0, 8, 2)] == [4, 5, 6, 7]
+    assert [at(sm, t)[1] for t in range(0, 8, 2)] == [4, 5, 6, 7]
+    assert [g.kv_half(at(qm, t)[2]) for t in range(0, 8, 2)] == [0, 0, 1, 1]
+    assert at(km, 0) == (0, 0, 1) and at(km, 11) == (0, 1, 1)
     # a block that is one head, or heads that pair up one to one: no half
     wide = fa._Geom(jnp.zeros((1, 512, 28 * 128)), jnp.zeros((1, 512, 512)),
                     28, 512, 512, Hkv=4)
     bert = fa._Geom(jnp.zeros((1, 512, 768)), jnp.zeros((1, 512, 768)), 12,
                     512, 512)
     assert wide.kv_half(3) is None and bert.kv_half(3) is None
-    assert wide.kv_half_of_step(3) is None and bert.halves == 1
+    assert bert.halves == 1

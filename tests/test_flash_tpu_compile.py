@@ -55,32 +55,51 @@ def test_forward_and_backward_compile_for_a_v5e(one_chip, what, entry, B, S,
             q, k, v, causal=causal, block_q=512, block_k=512,
             interpret=False)
 
+    text, grids = _compiled(attn, x, x, x, x)
+    assert text.count("tpu_custom_call") >= 2, what
+    if S > 512:     # several blocks: the sweeps' step tables are the grids
+        heads, steps = H * D // 128, fa.kv_blocks(S, 512, 512, causal)
+        assert grids == {"flash_fwd": (B, heads, 1, steps),
+                         "flash_bwd_dq": (B, heads, 1, steps),
+                         "flash_bwd_dkv": (B, heads, steps)} and steps == 36, what
+
+
+def _compiled(attn, *shapes):
+    """(the compiled program's text, {kernel name: grid}) of ``attn``'s
+    forward and backward."""
     def both(q, k, v, do):
         o, vjp = jax.vjp(attn, q, k, v)
         return (o,) + vjp(do)
 
-    text = jax.jit(both).lower(x, x, x, x).compile().as_text()
-    assert text.count("tpu_custom_call") >= 2, what
+    traced = jax.jit(both).trace(*shapes)
+    grids = {name: tuple(int(n) for n in grid.split(",") if n.strip())
+             for grid, name in re.findall(
+                 r"grid=\(([\d, ]*)\).*?name=(flash_\w+)", str(traced.jaxpr),
+                 re.S)}
+    return traced.lower().compile().as_text(), grids
 
 
 SMALLTHINKER, LFM2 = (1, 16384, 28, 4, 128), (2, 8192, 32, 8, 64)
 
 
-@pytest.mark.parametrize("what,shape,window,names", [
+@pytest.mark.parametrize("what,shape,window,names,steps", [
     ("smallthinker_21b_a3b.s16384_scan, a full layer", SMALLTHINKER, None,
-     ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")),
+     ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"), 528),
     ("smallthinker_21b_a3b.s16384_scan, a windowed layer", SMALLTHINKER, 4096,
-     ("flash_swa_fwd", "flash_swa_bwd_dq", "flash_swa_bwd_dkv")),
+     ("flash_swa_fwd", "flash_swa_bwd_dq", "flash_swa_bwd_dkv"), 252),
     ("lfm2_8b_a1b.s8192_scan, two heads a lane block", LFM2, None,
-     ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")),
+     ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"), 136),
 ])
 def test_grouped_and_windowed_kernels_compile_for_a_v5e(one_chip, what, shape,
-                                                        window, names):
+                                                        window, names, steps):
     """28 query heads on 4 key/value heads of 128 over 16,384 positions:
-    the index maps' integer arithmetic and the dk/dv sweep over a group's
-    heads are what Mosaic has to take; at 32 on 8 heads of 64, the select
-    of a key/value block's half by a traced scalar and the dk/dv sums into
-    that half as well."""
+    the index maps' reads of the scalar-prefetched step table and the dk/dv
+    sweep over a group's heads are what Mosaic has to take; at 32 on 8 heads
+    of 64, the select of a key/value block's half by a traced scalar and the
+    dk/dv sums into that half as well.  The grids are the tables: (row,
+    key/value head-block, query head-block of its group) by the blocks under
+    the diagonal (in the band), the dk/dv sweep's (row, key/value
+    head-block) by the group's times as many."""
     B, S, H, Hkv, D = shape
     xq = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
     xk = jax.ShapeDtypeStruct((B, S, Hkv * D), jnp.bfloat16, sharding=one_chip)
@@ -88,13 +107,13 @@ def test_grouped_and_windowed_kernels_compile_for_a_v5e(one_chip, what, shape,
         q, k, v, H, causal=True, block_q=512, block_k=512, interpret=False,
         n_kv_heads=Hkv, window=window)
 
-    def both(q, k, v, do):
-        o, vjp = jax.vjp(attn, q, k, v)
-        return (o,) + vjp(do)
-
-    text = jax.jit(both).lower(xq, xk, xk, xq).compile().as_text()
+    text, grids = _compiled(attn, xq, xk, xk, xq)
     for name in names:
         assert name in text, (what, name)
+    kv_blocks, group = Hkv * D // 128, H // Hkv
+    assert fa.kv_blocks(S, 512, 512, True, window) == steps
+    assert grids == dict(zip(names, [(B, kv_blocks, group, steps)] * 2
+                             + [(B, kv_blocks, group * steps)])), what
 
 
 @pytest.mark.parametrize("what,N,V,E,norm", [

@@ -391,12 +391,13 @@ def test_flash_grid_gauges_only_under_a_monitor_session(tmp_path):
                 (base, 256, 128, (6, 256)),     # bert_base.s128_scan
                 (base, 64, 512, (1, 384)),      # bert_base.s512_scan, _dp4
                 (dataclasses.replace(base, tp=2), 256, 128, (6, 128)),
-                (olmoe.olmoe_1b_7b_config(), 4, 4096, (1, 512))]:
+                # the causal triangle: 36 of 8 x 8 blocks a (row, head)
+                (olmoe.olmoe_1b_7b_config(), 4, 4096, (1, 4 * 16 * 36))]:
             T.gauge_flash_grid(c, b, s)
             assert (pairs.value, steps.value) == want
         # heads the packed layout cannot tile take another path: left alone
         T.gauge_flash_grid(bert.bert_tiny_config(), B, S)
-        assert (pairs.value, steps.value) == (1, 512)
+        assert (pairs.value, steps.value) == (1, 4 * 16 * 36)
     finally:
         monitor.disable()
 
