@@ -125,20 +125,6 @@ def kernels_phase(batch=2, seq=512, heads=12, head_dim=64):
 
 # ------------------------------------------------------------------ train --
 
-def _count_backend_compiles():
-    """A list that grows by one per XLA backend compile in this process."""
-    import jax.monitoring
-
-    seen = []
-
-    def _on(event, _secs, **_kw):
-        if event == "/jax/core/compile/backend_compile_duration":
-            seen.append(event)
-
-    jax.monitoring.register_event_duration_secs_listener(_on)
-    return seen
-
-
 def train_phase(devices, cfg, batch, seq):
     """``bench_bert``'s trainer: two ``run_steps`` calls of three steps each
     on one repeated batch.  Asserts finite losses that fall, that the second
@@ -148,6 +134,7 @@ def train_phase(devices, cfg, batch, seq):
     import re
 
     from paddle_tpu.models import bert
+    from paddle_tpu.monitor.recompile import compile_ledger
     from paddle_tpu.parallel import MeshSpec, optim
     from paddle_tpu.parallel.train import stack_batches
 
@@ -164,13 +151,13 @@ def train_phase(devices, cfg, batch, seq):
     batches = stack_batches(trainer.mesh, bert.batch_specs(),
                             [one] * n_steps)
 
-    compiles = _count_backend_compiles()
     first = np.asarray(trainer.run_steps(batches, lr), np.float32)
-    n_first = len(compiles)
+    t_second = time.perf_counter()
     second = np.asarray(trainer.run_steps(batches, lr), np.float32)
-    assert len(compiles) == n_first, (
-        "the second run_steps call compiled %d programs"
-        % (len(compiles) - n_first))
+    # a backend record is a program built, or loaded from the cache
+    again = [r["name"] for r in compile_ledger().between(
+        t_second, time.perf_counter()) if r["kind"] == "backend"]
+    assert not again, "the second run_steps call compiled %s" % again
     losses = np.concatenate([first, second])
     assert losses.shape == (2 * n_steps,), losses.shape
     assert np.isfinite(losses).all(), losses

@@ -29,6 +29,11 @@ def place():
     metadata too (see below)."""
     import jax
 
+    from .monitor.recompile import compile_ledger
+
+    # every chip entry point comes through here before its first compile:
+    # from now on the process accounts for what it builds or loads
+    compile_ledger()
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if jax.devices()[0].platform == "cpu":
         # CPU compiles are cheap, and under jaxlib 0.9.0 an XLA:CPU

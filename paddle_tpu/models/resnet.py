@@ -24,6 +24,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..monitor import devscope
+from ..monitor.recompile import compile_ledger
 from ..parallel import collectives as col
 from ..parallel.mesh import DP, MeshSpec
 from ..parallel import optim
@@ -90,6 +91,13 @@ def _bn_state_init(c):
 
 def init_resnet_params(key, cfg: ResNetConfig):
     """Returns (params, bn_state) pytrees.  Layers are dicts keyed by path."""
+    with compile_ledger().phase("init_params") as labels:
+        params, state = jax.block_until_ready(_init_params(key, cfg))
+        labels["leaves"] = len(jax.tree.leaves((params, state)))
+    return params, state
+
+
+def _init_params(key, cfg):
     dt = cfg.jdtype
     keys = iter(jax.random.split(key, 256))
     params, state = {}, {}

@@ -8,7 +8,9 @@ Four pieces, one registry:
 - ``timeline``  — JSONL per-step event log (host dispatch ms, sampled
   device ms, batch size, examples/sec) + compile/memory/run events;
 - ``recompile`` — compile-cache-miss detector with key diffs and a warning
-  after N recompiles of the same program (the TPU perf footgun);
+  after N recompiles of the same program (the TPU perf footgun); and the
+  process's ``compile_ledger()``: every trace, lowering, compile or cache
+  load that ``jax.monitoring`` reports, under the phases of set-up;
 - ``memory``    — device memory watermark sampling (live arrays + backend
   allocator stats);
 - ``memscope``  — full-stack memory attribution: per-compiled-program

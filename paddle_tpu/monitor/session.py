@@ -30,7 +30,7 @@ import time
 
 from . import fleetscope as _fleetscope
 from .memory import sample_memory
-from .recompile import RecompileDetector
+from .recompile import RecompileDetector, compile_ledger
 from .registry import default_registry
 from .timeline import Timeline
 
@@ -246,6 +246,7 @@ def enable(out_dir=None, **kwargs):
     global _active
     if _active is not None:
         _active.close()
+    compile_ledger()     # the session's trace shows set-up phases from here
     out_dir = out_dir or os.environ.get(
         "PADDLE_TPU_MONITOR_DIR", "/tmp/paddle_tpu_monitor")
     _active = Monitor(out_dir, **kwargs)

@@ -21,6 +21,7 @@ from . import collectives as col
 from .mesh import local_shard_map
 from .. import warm as _warm
 from ..monitor import devscope as _devscope, memscope as _memscope
+from ..monitor.recompile import FIRST_CALL, compile_ledger
 
 __all__ = ["TrainState", "RUNNING", "make_train_step", "StepTrainer",
            "shard_pytree", "stack_batches", "TrainLoop"]
@@ -41,8 +42,9 @@ class TrainState(dict):
 
     @staticmethod
     def create(params, optimizer):
-        init, _ = optimizer
-        return {"params": params, "opt": init(params)}
+        with compile_ledger().phase("init_opt_state"):
+            opt = jax.block_until_ready(optimizer[0](params))
+        return {"params": params, "opt": opt}
 
 
 def _opt_state_specs(param_specs, opt_state):
@@ -72,9 +74,14 @@ def shard_pytree(tree, specs, mesh):
     """Place a host pytree onto the mesh per spec (BCastParamsToDevices
     parity, parallel_executor.cc:630 — XLA shards/replicates instead of
     ncclBcast loops)."""
-    return jax.tree.map(
-        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), tree, specs
-    )
+    with compile_ledger().phase("place", bytes=_tree_bytes(tree)):
+        return jax.tree.map(
+            lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
+            tree, specs)
+
+
+def _tree_bytes(tree):
+    return sum(getattr(x, "nbytes", 0) for x in jax.tree.leaves(tree))
 
 
 def make_train_step(loss_fn, mesh, param_specs, grad_syncs, optimizer,
@@ -229,8 +236,13 @@ class StepTrainer:
     def step(self, batch, lr):
         self._observe(batch)
         if not self._step_seen:
-            self._step_seen = _devscope.register(
-                self.label + ".step", self.step_fn, (self.state, batch, lr))
+            # the call that traces, lowers and compiles or loads the program
+            program = self.label + ".step"
+            with compile_ledger().phase(FIRST_CALL, program=program):
+                self._step_seen = _devscope.register(
+                    program, self.step_fn, (self.state, batch, lr))
+                self.state, loss = self.step_fn(self.state, batch, lr)
+            return loss
         self.state, loss = self.step_fn(self.state, batch, lr)
         return loss
 
@@ -242,9 +254,12 @@ class StepTrainer:
             raise RuntimeError("trainer built without multi-step support")
         self._observe(batches)
         if not self._multi_seen:
-            self._multi_seen = _devscope.register(
-                self.label + ".run_steps", self.multi_fn,
-                (self.state, batches, lr))
+            program = self.label + ".run_steps"
+            with compile_ledger().phase(FIRST_CALL, program=program):
+                self._multi_seen = _devscope.register(
+                    program, self.multi_fn, (self.state, batches, lr))
+                self.state, losses = self.multi_fn(self.state, batches, lr)
+            return losses
         self.state, losses = self.multi_fn(self.state, batches, lr)
         return losses
 
@@ -380,7 +395,9 @@ def stack_batches(mesh, batch_specs, batches):
     place them on the mesh (step axis replicated, batch dims per spec)."""
     import numpy as np
 
-    stacked = jax.tree.map(lambda *xs: np.stack(xs), *batches)
-    specs = jax.tree.map(lambda s: P(None, *tuple(s)), batch_specs,
-                         is_leaf=lambda x: isinstance(x, P))
-    return shard_pytree(stacked, specs, mesh)
+    with compile_ledger().phase("stage_batches") as labels:
+        stacked = jax.tree.map(lambda *xs: np.stack(xs), *batches)
+        specs = jax.tree.map(lambda s: P(None, *tuple(s)), batch_specs,
+                             is_leaf=lambda x: isinstance(x, P))
+        labels["bytes"] = _tree_bytes(stacked)
+        return shard_pytree(stacked, specs, mesh)

@@ -33,6 +33,7 @@ from . import collectives as col
 from .mesh import DP, PP, TP
 from .. import monitor
 from ..monitor import devscope
+from ..monitor.recompile import compile_ledger
 from .ring_attention import ring_attention
 
 __all__ = ["TransformerConfig", "init_transformer_params", "transformer_param_specs",
@@ -231,6 +232,15 @@ def init_transformer_params(key, cfg: TransformerConfig):
     MoE layers, where the routing rule has them, are ONE top-level leaf
     ``router_bias`` [moe_layers, n_experts] float32, which takes no
     gradient and which a step moves itself (``moe.balance_bias``)."""
+    # the leaves are made eagerly, one small program each: the ledger shows
+    # them beneath this phase, which waits for their device time as well
+    with compile_ledger().phase("init_params") as labels:
+        params = jax.block_until_ready(_init_params(key, cfg))
+        labels["leaves"] = len(jax.tree.leaves(params))
+    return params
+
+
+def _init_params(key, cfg):
     E, V, dt = cfg.hidden, cfg.vocab_size, cfg.jdtype
     ks = jax.random.split(key, 12)
     layers = _per_position_layers(ks, cfg) if cfg.per_position \
