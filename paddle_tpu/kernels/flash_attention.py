@@ -19,13 +19,26 @@ the index maps as scalar-prefetch operands: only the pairs the mask lets
 something through are steps (the triangle under the diagonal, a window's
 band, the rectangle).  The running max
 (m), denominator (l) and output accumulator live in VMEM scratch across a q
-block's sweep (the standard TPU flash schedule), and the backward
-recomputes the probabilities blockwise from the saved row logsumexp in two
-kernels (``flash_bwd_dq``, ``flash_bwd_dkv``: FlashAttention-2).  One kv
+block's sweep (the standard TPU flash schedule).  The backward recomputes
+the probabilities blockwise from the saved row logsumexp, in ONE kernel
+(``flash_bwd_fused``): a grid row is a (batch row, key/value head-block)
+pair, its steps the forward's table over each query head-block of the
+group in turn, and a step computes one score tile, one ``exp`` and from
+them all three products (5 matmuls): dq into the q sweep's scratch, dk and
+dv into rows ``kv block`` of two float32 accumulators that hold the WHOLE
+sequence in VMEM ([Sk, lanes] each: 16 MiB at S = 16,384), which sum over
+the group because they are the key/value head's own, and leave once at the
+grid row's last step.  That runs wherever the accumulators and their output
+blocks fit SWEEP_VMEM (``bwd_sweeps``, from the shapes alone; the call
+states its ``vmem_limit_bytes``); a longer sequence takes the
+FlashAttention-2 schedule of two kernels (``flash_bwd_dq`` q-major,
+``flash_bwd_dkv`` kv-major: 7 matmuls and two ``exp`` passes a pair) with
+the same sums in the same order.  One kv
 block (S up to the block size, every BERT shape): grid (row-groups x
 head-block-groups, q blocks, 1), the forward needs no running statistics
-and the backward is ONE kernel (``flash_bwd_fused``) that shares one
-recomputed probability tile between dq, dk and dv.  There, where the whole
+and the backward is the one-block ``flash_bwd_fused``, which shares its
+recomputed probability tile between dq, dk and dv in the same way.  There,
+where the whole
 sequence is one block, a grid step carries a fixed amount of work whatever
 S is: ``step_geometry`` packs G batch rows and Hg head-blocks into the
 step's blocks; the kernel bodies loop over the rows and unroll over the
@@ -42,18 +55,18 @@ Two more modes of the packed entry, both of the same kernels:
   both heads of a query block read ONE key/value head, which is one HALF
   of a key/value lane block; the index maps bring the block and the kernel
   takes the half (``_Geom.kv_half``: a select, a thousandth of a step's
-  work).  The dk/dv sweep runs once per KEY/VALUE lane block and its
-  table walks the query blocks that read it, so dk and dv are summed over
-  the group in the kernel's scratch, each query block into the half it
+  work).  The backward runs once per KEY/VALUE lane block and its table
+  walks the query blocks that read it, so dk and dv are summed over the
+  group in the kernel's accumulators, each query block into the half it
   read.  One (row, head-block) pair a grid row whatever S is, and the
-  two-sweep backward even at one block.
+  several-block backward even at one block.
 - a sliding window (``window`` = W < S, causal): query i sees keys j with
   i - W < j <= i.  The sweeps' tables hold the BAND (at most 9 kv blocks of
   512 a q block for W = 4096, not S / 512), and the kernels carry names of
-  their own (``flash_swa_fwd``, ``flash_swa_bwd_dq``, ``flash_swa_bwd_dkv``)
-  so that a trace's reader can tell a windowed layer's calls from a full
-  one's.  A window of S or more is the causal mask and runs the causal
-  kernels.
+  their own (``flash_swa_fwd``, ``flash_swa_bwd_fused``; ``flash_swa_bwd_dq``
+  and ``_dkv``) so that a trace's reader can tell a windowed layer's calls
+  from a full one's.  A window of S or more is the causal mask and runs the
+  causal kernels.
 
 All matmuls feed the MXU in the input dtype with f32 accumulation.
 interpret=True (CPU tests) is selected automatically off-TPU.
@@ -132,6 +145,12 @@ VMEM_BUDGET = 12 * 2 ** 20   # bytes one step may hold: the double-buffered
                              # operand and statistics blocks of the widest
                              # kernel (flash_bwd_fused) and its live f32 tiles;
                              # under the 16 MiB Mosaic scopes by default
+SCOPED_VMEM = 16 * 2 ** 20  # what Mosaic gives a kernel unless told otherwise
+SWEEP_VMEM = 64 * 2 ** 20   # bytes the one-sweep backward of several blocks
+                            # may ask for (``fused_sweep_vmem_bytes``): half
+                            # of a v5e core's 128 MiB.  S = 16,384 at 128
+                            # lanes asks for 40 MiB, 32,768 for 64; past it
+                            # the backward is two sweeps
 
 
 def _divisors(n):
@@ -174,6 +193,29 @@ def step_geometry(B, S, n_head_blocks, lanes, itemsize):
     return G, Hg
 
 
+def fused_sweep_vmem_bytes(Sk, lanes, itemsize):
+    """What ``flash_bwd_fused`` over several blocks asks of VMEM at a
+    key/value length of Sk and head-blocks ``lanes`` wide: the two float32
+    accumulators that hold dk and dv of the whole sequence, their two output
+    blocks (one buffer each: they leave once a grid row), and Mosaic's own
+    scope for what a step holds, which is what the two sweeps' steps live in
+    (double-buffered [512, lanes] operand blocks, the [512, 512] tiles)."""
+    width = max(lanes, LANES)                      # narrow blocks pad to a tile
+    return 2 * Sk * width * 4 + 2 * Sk * width * itemsize + SCOPED_VMEM
+
+
+def bwd_sweeps(Sk, bk, lanes, itemsize, group=1):
+    """The kernels of one layer's backward, from the shapes alone: 1
+    (``flash_bwd_fused``: dq, dk and dv off one recomputed probability tile)
+    where the Sk keys are one block of bk and the queries are not grouped,
+    and over several blocks wherever dk and dv of the whole sequence fit
+    SWEEP_VMEM; else 2 (``flash_bwd_dq``, ``flash_bwd_dkv``)."""
+    if Sk == bk and group == 1:
+        return 1
+    fits = fused_sweep_vmem_bytes(Sk, lanes, itemsize) <= SWEEP_VMEM
+    return 1 if fits else 2
+
+
 def grid_geometry(B, S, Sk, n_head_blocks, lanes, itemsize, bq, bk, group=1):
     """(G, Hg, grid steps along the batch/head axis) for blocks of bq x bk:
     ``step_geometry`` where the sequence is one block both ways, one
@@ -196,10 +238,14 @@ def step_table(S, Sk, bq, bk, causal, window=None, group=1, kv_major=False):
     and the rectangle are three tables of this one builder and no step of a
     grid is empty.
 
-    q-major (the forward and the dq sweep): by q block, its kv blocks
-    ascending; ``head`` is 0.  kv-major (the dk/dv sweep): by kv block, then
-    the ``group`` query head-blocks that read it (``head``), then q block
-    ascending, so the sum over the group stays in the kernel's scratch.
+    q-major (the forward and the dq sweep, whose grids hold the group as an
+    axis and ask for ``group`` 1; the fused backward, whose table walks it):
+    the ``group`` query head-blocks of a key/value head-block in turn
+    (``head``), of each its q blocks, of each its kv blocks ascending.
+    kv-major (the dk/dv sweep): by kv block, then the query head-blocks that
+    read it, then q block ascending, so the sum over the group stays in the
+    kernel's scratch.  Either way a kv block meets its (head, q block) pairs
+    in the same order, head first: the order dk and dv are summed in.
     Flags: FIRST and LAST open and close a sweep (zero the scratch, write the
     output block).  Every step masks its scores: a third flag for the blocks
     the mask cuts, with an unmasked body for the others, was slower on the
@@ -215,7 +261,8 @@ def step_table(S, Sk, bq, bk, causal, window=None, group=1, kv_major=False):
     nq, nk = S // bq, Sk // bk
     sweeps = [[(i, j, h) for h in range(group) for i in range(nq)
                if seen(i, j)] for j in range(nk)] if kv_major else \
-        [[(i, j, 0) for j in range(nk) if seen(i, j)] for i in range(nq)]
+        [[(i, j, h) for j in range(nk) if seen(i, j)]
+         for h in range(group) for i in range(nq)]
     assert all(sweeps), "a block no query and key meet in: %r" % (
         (S, Sk, bq, bk, window),)
     return np.array([
@@ -242,6 +289,15 @@ def packed_grid(B, S, n_heads, head_dim, block_q, block_k, itemsize=2,
     if S == bk:
         return G * Hg, steps * (S // bq)
     return 1, steps * kv_blocks(S, bq, bk, causal, window)
+
+
+def packed_bwd_sweeps(S, n_heads, head_dim, block_k, itemsize=2,
+                      n_kv_heads=None):
+    """``bwd_sweeps`` of what ``flash_attention_packed`` runs for these
+    shapes (the trainers' monitor gauges)."""
+    hpb = _heads_per_block(head_dim)
+    return bwd_sweeps(S, min(block_k, S), head_dim * hpb, itemsize,
+                      n_heads // (n_kv_heads or n_heads))
 
 
 class _Geom:
@@ -282,6 +338,8 @@ class _Geom:
             self.group)
         self.nq = self.S // bq
         self.one_block = self.Sk == bk
+        self.bwd_sweeps = bwd_sweeps(self.Sk, bk, self.qw, q.dtype.itemsize,
+                                     self.group)
         self.window = window
         self.o_shape = q.shape
         self.dkv_shape = k.shape
@@ -313,17 +371,18 @@ class _Geom:
         n = self.Hb // self.Hg
         return lambda b, i, j=0: (b // n, b % n, i, 0)
 
-    def sweep_maps(self, kv_major=False):
+    def sweep_maps(self, walks_group=False):
         """(q rows, kv rows, row statistics) index maps of a several-block
         sweep.  Its grid is (batch row, key/value head-block, query
-        head-block of that one's group, step t of its ``step_table``), the
-        dk/dv sweep's without the third axis (its table walks the group:
-        ``head_of``); the table's columns arrive as scalar-prefetch
+        head-block of that one's group, step t of its ``step_table``), or
+        without the third axis where the table walks the group
+        (``head_of``: the dk/dv sweep and the fused backward, which sum
+        over it); the table's columns arrive as scalar-prefetch
         operands.  No map divides: on the chip a (row, head-block) pair
         unpacked from one grid index by ``//`` and ``%`` cost each of the
         sweep's steps 30 to 60 ns (PERF.md section 6, PR 34)."""
         def at(pick):
-            if kv_major:
+            if walks_group:
                 return lambda r, kh, t, q_of, kv_of, head_of, flags: pick(
                     r, kh, kh * self.group + head_of[t], q_of[t], kv_of[t])
             return lambda r, kh, g, t, q_of, kv_of, head_of, flags: pick(
@@ -335,7 +394,7 @@ class _Geom:
 
     def step(self, head_of=None):
         """(t, query head-block) of a sweep's grid position; ``head_of``:
-        the dk/dv sweep's."""
+        of a sweep whose table walks the group."""
         if head_of is None:
             return pl.program_id(3), \
                 pl.program_id(1) * self.group + pl.program_id(2)
@@ -508,10 +567,11 @@ def _name(kernel, g):
 
 
 def _sweep_call(kernel, g, table, operands, in_specs, out_specs, out_shape,
-                scratch_shapes, kv_major, interpret, name):
+                scratch_shapes, walks_group, interpret, name, **params):
     """One several-block sweep over the steps of ``table`` (its columns
-    scalar-prefetched), for the grid positions ``_Geom.sweep_maps`` names."""
-    heads = (g.Hb // g.group,) + (() if kv_major else (g.group,))
+    scalar-prefetched), for the grid positions ``_Geom.sweep_maps`` names;
+    ``params``: further compiler parameters."""
+    heads = (g.Hb // g.group,) + (() if walks_group else (g.group,))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -521,7 +581,7 @@ def _sweep_call(kernel, g, table, operands, in_specs, out_specs, out_shape,
             scratch_shapes=scratch_shapes),
         out_shape=out_shape,
         compiler_params=_CompilerParams(dimension_semantics=(
-            "parallel",) * (1 + len(heads)) + ("arbitrary",)),
+            "parallel",) * (1 + len(heads)) + ("arbitrary",), **params),
         interpret=interpret,
         name=_name(name, g),
     )(*(jnp.asarray(column) for column in table), *operands)
@@ -575,9 +635,9 @@ def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None, Hkv=None,
 # ---------------------------------------------------------------------------
 # fused backward (single kernel) for the single-kv-block case: when all of
 # K/V fits one block (Sk == bk), dq/dk/dv share ONE recomputed probability
-# matrix — one exp pass and 5 matmuls instead of the two-sweep schedule's
-# two exp passes and 7 matmuls.  This is the hot path for the bench shapes
-# (S=512, block 512; S=128, one block, G x Hg pairs a step).
+# matrix — one exp pass and 5 matmuls.  This is the hot path for the BERT
+# shapes (S=512, block 512; S=128, one block, G x Hg pairs a step); several
+# kv blocks share the tile the same way in ``_bwd_sweep_kernel``.
 # ---------------------------------------------------------------------------
 
 
@@ -676,7 +736,9 @@ def _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=None,
 
 
 # ---------------------------------------------------------------------------
-# backward: dq sweep (grid kv-innermost) and dk/dv sweep (grid q-innermost)
+# backward over several blocks.  One sweep (``_bwd_sweep_kernel``) where dk
+# and dv of the whole sequence fit VMEM; else the dq sweep (grid
+# kv-innermost) and the dk/dv sweep (grid q-innermost)
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
@@ -711,6 +773,18 @@ def _bwd_dq_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
         dq_ref[0] = acc_scr[:].astype(dq_ref.dtype)
 
 
+def _kv_add(acc, rows, hh, D, half, halves, part):
+    """``part`` [bk, D] of query head ``hh`` into its key/value head's
+    columns of ``acc`` at ``rows``: its own, or the half the block read."""
+    if half is None:
+        acc[rows, hh * D:(hh + 1) * D] += part
+        return
+    for at in range(halves):
+        @pl.when(half == at)
+        def _into():
+            acc[rows, at * D:(at + 1) * D] += part
+
+
 def _bwd_dkv_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
                     scale, causal, bq, bk, geom, hpb=1):
@@ -723,17 +797,6 @@ def _bwd_dkv_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def add(scr, hh, part):
-        """``part`` [bk, D] of query head ``hh`` into its key/value head's
-        columns of the scratch: its own, or the half the block read."""
-        if half is None:
-            scr[:, hh * D:(hh + 1) * D] += part
-            return
-        for at in range(geom.halves):
-            @pl.when(half == at)
-            def _into():
-                scr[:, at * D:(at + 1) * D] += part
-
     for hh in range(hpb):
         cs = slice(hh * D, (hh + 1) * D)
         q = q_ref[0][:, cs]
@@ -744,21 +807,96 @@ def _bwd_dkv_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
                     geom.window)
         p = jnp.exp(s - lse_ref[0, 0][:, hh:hh + 1])   # [bq, bk]
         # dv_j += p^T dO
-        add(dv_scr, hh, jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32))
+        _kv_add(dv_scr, slice(None), hh, D, half, geom.halves,
+                jax.lax.dot_general(
+                    p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
         dov = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
         ds = p * (dov - delta_ref[0, 0][:, hh:hh + 1]) * scale
         # dk_j += ds^T q
-        add(dk_scr, hh, jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32))
+        _kv_add(dk_scr, slice(None), hh, D, half, geom.halves,
+                jax.lax.dot_general(
+                    ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
 
     @pl.when((flags[t] & LAST) != 0)
     def _final():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _bwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
+                      lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_scr,
+                      dk_acc, dv_acc, *, scale, causal, bq, bk, geom, hpb=1):
+    """Several blocks, ONE sweep: a grid row is a (batch row, key/value
+    head-block) pair and its steps walk the group's query head-blocks, of
+    each its q blocks, of each its visible kv blocks (``step_table``,
+    q-major over the group).  A step computes one probability tile and from
+    it all three products: dq into the q sweep's scratch, dk and dv into
+    rows ``kv block`` of two float32 accumulators that hold the whole
+    sequence and are the key/value head's own, so they sum over the group;
+    both leave once, at the grid row's last step."""
+    t, q_block = geom.step(head_of)
+    D = q_ref.shape[-1] // hpb
+    half = geom.kv_half(q_block)
+    def rows_of(kv_block):
+        return pl.ds(pl.multiple_of(kv_block * bk, bk), bk)
+
+    def kv_blocks_of_the_sequence(block):
+        def step(n, carry):
+            block(rows_of(n))
+            return carry
+        jax.lax.fori_loop(0, geom.Sk // bk, step, 0)
+
+    rows = rows_of(kv_of[t])
+
+    @pl.when(t == 0)
+    def _open():
+        def zero(at):
+            dk_acc[at, :] = dv_acc[at, :] = jnp.zeros(
+                (bk, dk_acc.shape[1]), jnp.float32)
+        kv_blocks_of_the_sequence(zero)
+
+    @pl.when((flags[t] & FIRST) != 0)
+    def _init():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    for hh in range(hpb):
+        cs = slice(hh * D, (hh + 1) * D)
+        q = q_ref[0][:, cs]
+        k = _kv_cols(k_ref[0], hh, D, half, geom.halves)
+        v = _kv_cols(v_ref[0], hh, D, half, geom.halves)
+        do = do_ref[0][:, cs]
+        s = _scores(q, k, scale, causal, q_of[t] * bq, kv_of[t] * bk,
+                    geom.window)
+        p = jnp.exp(s - lse_ref[0, 0][:, hh:hh + 1])   # [bq, bk] - the ONE exp
+        # dv_j += p^T dO
+        _kv_add(dv_acc, rows, hh, D, half, geom.halves, jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32))
+        dov = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        ds = (p * (dov - delta_ref[0, 0][:, hh:hh + 1]) * scale
+              ).astype(q.dtype)                        # [bq, bk]
+        dq_scr[:, cs] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        # dk_j += ds^T q
+        _kv_add(dk_acc, rows, hh, D, half, geom.halves, jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32))
+
+    @pl.when((flags[t] & LAST) != 0)
+    def _final():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+    @pl.when(t == pl.num_programs(2) - 1)
+    def _close():
+        def leave(at):
+            dk_ref[0, at, :] = dk_acc[at, :].astype(dk_ref.dtype)
+            dv_ref[0, at, :] = dv_acc[at, :].astype(dv_ref.dtype)
+        kv_blocks_of_the_sequence(leave)
 
 
 def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
@@ -778,28 +916,48 @@ def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
             .reshape(B, g.S, g.Hb, g.hpb, g.D), axis=-1
         ).transpose(0, 2, 1, 3)                           # [B, Hb, S, hpb]
 
-    def sweep(kernel, name, kv_major, out_specs, out_shape, scratch_shapes):
-        qm, km, sm = g.sweep_maps(kv_major)
+    dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    dkv_shapes = [jax.ShapeDtypeStruct(g.dkv_shape, k.dtype),
+                  jax.ShapeDtypeStruct(g.dkv_shape, v.dtype)]
+
+    def sweep(kernel, name, out_specs, out_shape, scratch_shapes,
+              walks_group=False, kv_major=False, **params):
+        qm, km, sm = g.sweep_maps(walks_group)
         qs, ks = g.q_spec(bq, qm), g.kv_spec(bk, km)
         return _sweep_call(
             functools.partial(kernel, scale=scale, causal=causal, bq=bq,
                               bk=bk, hpb=g.hpb, geom=g),
-            g, step_table(g.S, g.Sk, bq, bk, causal, window, g.group,
-                          kv_major),
+            g, step_table(g.S, g.Sk, bq, bk, causal, window,
+                          g.group if walks_group else 1, kv_major),
             (q, k, v, do, lse, delta),
             [qs, ks, ks, qs, g.stat_spec(bq, sm), g.stat_spec(bq, sm)],
-            out_specs(qs, ks), out_shape, scratch_shapes, kv_major,
-            interpret, name)
+            out_specs(qs, ks), out_shape, scratch_shapes, walks_group,
+            interpret, name, **params)
 
-    dq = sweep(_bwd_dq_kernel, "bwd_dq", False, lambda qs, ks: qs,
-               jax.ShapeDtypeStruct(q.shape, q.dtype),
+    if g.bwd_sweeps == 1:
+        # dk and dv of the whole sequence: one block a grid row, so one
+        # buffer (it leaves VMEM once, and the next row's has nothing to
+        # overlap with but that)
+        whole = pl.BlockSpec((1, g.Sk, g.qw),
+                             lambda r, kh, t, *table: (r, 0, kh),
+                             pipeline_mode=pl.Buffered(1))
+        return sweep(
+            _bwd_sweep_kernel, "bwd_fused",
+            lambda qs, ks: [qs, whole, whole], [dq_shape] + dkv_shapes,
+            [pltpu.VMEM((bq, g.qw), jnp.float32),
+             pltpu.VMEM((g.Sk, g.qw), jnp.float32),
+             pltpu.VMEM((g.Sk, g.qw), jnp.float32)],
+            walks_group=True,
+            vmem_limit_bytes=fused_sweep_vmem_bytes(g.Sk, g.qw,
+                                                    k.dtype.itemsize))
+    dq = sweep(_bwd_dq_kernel, "bwd_dq", lambda qs, ks: qs, dq_shape,
                [pltpu.VMEM((bq, g.qw), jnp.float32)])
     # the dk/dv sweep's rows run over the key/value heads
-    dk, dv = sweep(_bwd_dkv_kernel, "bwd_dkv", True, lambda qs, ks: [ks, ks],
-                   [jax.ShapeDtypeStruct(g.dkv_shape, k.dtype),
-                    jax.ShapeDtypeStruct(g.dkv_shape, v.dtype)],
+    dk, dv = sweep(_bwd_dkv_kernel, "bwd_dkv", lambda qs, ks: [ks, ks],
+                   dkv_shapes,
                    [pltpu.VMEM((bk, g.qw), jnp.float32),
-                    pltpu.VMEM((bk, g.qw), jnp.float32)])
+                    pltpu.VMEM((bk, g.qw), jnp.float32)],
+                   walks_group=True, kv_major=True)
     return dq, dk, dv
 
 

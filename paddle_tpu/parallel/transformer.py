@@ -64,10 +64,11 @@ class TransformerConfig:
     tp: int = 1                      # tensor-parallel degree (mesh tp axis size)
     pp: int = 1                      # pipeline stages (mesh pp axis size)
     use_flash: bool = True           # Pallas flash-attention kernel when shapes allow
-    # Pallas kernel q/kv block sizes, clamped to S.  S = 512 is one block
-    # (the fused one-sweep backward); S = 4096 is 8 x 8 blocks and the
-    # two-sweep backward (flash_bwd_dq, flash_bwd_dkv), causal skipping the
-    # blocks above the diagonal
+    # Pallas kernel q/kv block sizes, clamped to S.  S = 512 is one block;
+    # S = 4096 is 8 x 8 blocks, the causal sweeps' steps the 36 under the
+    # diagonal.  Either way the backward is one kernel (flash_bwd_fused),
+    # over several blocks with dk and dv of the whole sequence in VMEM; two
+    # sweeps (flash_bwd_dq, flash_bwd_dkv) only where those do not fit
     flash_block_q: int = 512
     flash_block_k: int = 512
     scan_unroll: int = 1             # lax.scan unroll over layers (1 = rolled;
@@ -594,9 +595,13 @@ def gauge_flash_grid(cfg, b, S):
     ``monitor.kernels.flash_kv_blocks_visited_*`` those that hold a
     (query, key) pair the mask lets through and so compute, and
     ``flash_kv_blocks_skipped_*`` the steps the grid holds beyond them (0:
-    the grid is the sweep's step table).  All fixed when the step is traced,
-    so gauges; nothing is set where attention does not take the packed
-    kernel."""
+    the grid is the sweep's step table).
+    ``monitor.kernels.flash_bwd_sweeps_full`` (and ``_windowed`` in a stack
+    of several kinds) is the kernels of that kind's backward: 1
+    where dq, dk and dv come off one sweep, 2 where the sequence is past
+    what VMEM holds of dk and dv (``flash_attention.bwd_sweeps``, which the
+    kernel asks).  All fixed when the step is traced, so gauges; nothing is
+    set where attention does not take the packed kernel."""
     mon = monitor.active()
     if mon is None or cfg.attn_mode != "heads":
         return
@@ -604,13 +609,20 @@ def gauge_flash_grid(cfg, b, S):
     blocks = _packed_flash_blocks(cfg, hl, S, kvl)
     if blocks is None:
         return
-    from ..kernels.flash_attention import kv_blocks, packed_grid
+    from ..kernels.flash_attention import (kv_blocks, packed_bwd_sweeps,
+                                           packed_grid)
 
     pairs, steps = packed_grid(b, S, hl, cfg.head_dim, *blocks,
                                itemsize=cfg.jdtype.itemsize, n_kv_heads=kvl,
                                causal=cfg.causal)
     mon.registry.gauge("monitor.kernels.flash_pairs_per_grid_step").set(pairs)
     mon.registry.gauge("monitor.kernels.flash_grid_steps").set(steps)
+    # a window changes the table, not what VMEM holds: one answer a stack
+    sweeps = packed_bwd_sweeps(S, hl, cfg.head_dim, blocks[1],
+                               itemsize=cfg.jdtype.itemsize, n_kv_heads=kvl)
+    for name in ("full", "windowed") if cfg.layer_pattern else ("full",):
+        mon.registry.gauge("monitor.kernels.flash_bwd_sweeps_" + name).set(
+            sweeps)
     if cfg.layer_pattern:
         window = max((k[0] or 0 for k in cfg.layer_kinds if k != CONV),
                      default=0) or None

@@ -3,7 +3,7 @@
     chiprun -- python3 scripts/attn_kernel_cmp.py --batch 256 --seq 128 \
         [--heads 12 --head-dim 64 --block 512 --causal] \
         [--kv-heads 4 --window 4096] [--tree _checkout/parent] \
-        [--force 1x1,4x1,1x6] [--others]
+        [--two-sweeps] [--force 1x1,4x1,1x6] [--others]
 
 Times ``flash_attention_packed`` (the entry the models call), forward and
 backward, by DEVICE time per kernel name read from a profiler trace, as the
@@ -14,7 +14,10 @@ shapes (1 x 16384, 28 on 4 heads of 128, full and W = 4096; 2 x 8192, 32 on
 8 of 64; 4 x 4096, 16 of 128); ``--tree DIR`` times the kernels of another
 checkout (a parent commit unpacked beside this one) with this script, and
 the ``digest`` of the outputs' bytes says whether two trees' kernels gave
-the same numbers bit for bit.
+the same numbers bit for bit.  ``--two-sweeps`` gives the several-block
+backward no VMEM for its whole-sequence accumulators (``SWEEP_VMEM`` = 0
+for this process), so that it runs ``flash_bwd_dq`` and ``flash_bwd_dkv``
+as a sequence past the rule does.
 ``--force GxHg,...`` also times the kernels with the grid step's geometry
 forced to G batch rows by Hg head-blocks (``step_geometry`` replaced for
 that compile: an experiment of this script, not an option of the program)
@@ -104,6 +107,7 @@ def main(argv=None):
     ap.add_argument("--window", type=int, default=0)
     ap.add_argument("--tree", default=ROOT)
     ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--two-sweeps", action="store_true")
     ap.add_argument("--force", default="")
     ap.add_argument("--vmem-mib", type=int, default=0)
     ap.add_argument("--others", action="store_true")
@@ -153,6 +157,8 @@ def main(argv=None):
             return (o,) + vjp(do)
         return jax.jit(f)
 
+    if args.two_sweeps:
+        fa.SWEEP_VMEM = 0
     rule = getattr(fa, "step_geometry", None)   # a checkout before PR 28
     forced = [tuple(int(n) for n in g.split("x"))
               for g in args.force.split(",") if g]

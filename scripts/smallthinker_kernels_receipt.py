@@ -11,9 +11,9 @@ plain ``jax.numpy`` in float32 at ``highest`` precision on the same
 bf16-rounded inputs, forward and every gradient against a random cotangent:
 
 - ``flash.window`` / ``flash.full``: ``flash_attention_packed(n_kv_heads=4,
-  window=4096 | None)`` in 512-blocks (``flash_swa_fwd`` / ``flash_swa_bwd_dq``
-  / ``flash_swa_bwd_dkv``, and the causal ``flash_fwd`` / ``flash_bwd_dq`` /
-  ``flash_bwd_dkv`` with grouped queries): o, dq, dk, dv against attention by
+  window=4096 | None)`` in 512-blocks (``flash_swa_fwd`` /
+  ``flash_swa_bwd_fused``, and the causal ``flash_fwd`` / ``flash_bwd_fused``
+  with grouped queries): o, dq, dk, dv against attention by
   query blocks, one key/value head's group at a time.  The error is each
   HEAD's ``|got - want| / |want|`` and the worst head is reported, so a
   group summed into the wrong key/value head cannot hide in a mean.

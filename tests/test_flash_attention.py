@@ -235,8 +235,8 @@ def test_packed_grid_of_the_cells(B, S, H, D, causal, want):
 
 
 def test_several_blocks_are_one_pair_a_step(monkeypatch):
-    """S above the block size never asks the rule: the two-sweep backward
-    and the causal skip run the geometry they always had."""
+    """S above the block size never asks the rule: the several-block
+    sweeps and the causal skip run the geometry they always had."""
     def never(*a):
         raise AssertionError("step_geometry asked for a multi-block grid")
     monkeypatch.setattr(fa, "step_geometry", never)
@@ -282,22 +282,27 @@ def test_a_layer_at_s128_is_one_forward_and_one_backward_kernel():
 # grouped queries and a sliding window (PR 31): modes of the same kernels
 # ---------------------------------------------------------------------------
 
-def _plain_gqa(q, k, v, H, Hkv, window=None, causal=True):
-    """Softmax attention on the packed layout, float32, no kernel: query
-    head h reads kv head h // (H // Hkv); query i sees keys j with
-    i - window < j <= i."""
-    B, S, _ = q.shape
-    D = q.shape[-1] // H
-    qh = q.reshape(B, S, H, D)
-    kh = jnp.repeat(k.reshape(B, S, Hkv, D), H // Hkv, axis=2)
-    vh = jnp.repeat(v.reshape(B, S, Hkv, D), H // Hkv, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", qh, kh) / np.sqrt(D)
-    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
-    seen = (j <= i) if causal else jnp.ones((S, S), bool)
+def _plain(q, k, v, causal, window=None):
+    """Softmax attention on [B, S, H, D] against [B, Sk, Hkv, D], float32,
+    no kernel."""
+    S, Sk, H = q.shape[1], k.shape[1], q.shape[2]
+    kh, vh = (jnp.repeat(t, H // t.shape[2], axis=2) for t in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, kh) / np.sqrt(q.shape[-1])
+    i, j = jnp.arange(S)[:, None], jnp.arange(Sk)[None, :]
+    seen = (j <= i) if causal else jnp.ones((S, Sk), bool)
     if window is not None:
         seen = seen & (i - j < window)
     p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, vh).reshape(B, S, H * D)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, vh)
+
+
+def _plain_gqa(q, k, v, H, Hkv, window=None, causal=True):
+    """``_plain`` on the packed layout: query head h reads kv head
+    h // (H // Hkv); query i sees keys j with i - window < j <= i."""
+    B, S, _ = q.shape
+    heads = lambda t, h: t.reshape(B, t.shape[1], h, -1)
+    return _plain(heads(q, H), heads(k, Hkv), heads(v, Hkv), causal,
+                  window).reshape(B, S, -1)
 
 
 def _packed_qkv(seed, B, S, H, Hkv, D):
@@ -375,7 +380,7 @@ def _kernel_names(fn, *args):
     return sorted(re.findall(r"name=(flash_\w+)", str(jax.make_jaxpr(fn)(*args))))
 
 
-def test_windowed_kernels_carry_names_of_their_own():
+def test_windowed_kernels_carry_names_of_their_own(monkeypatch):
     q, k, v, w = _packed_qkv(22, 1, 512, 2, 2, 128)
 
     def both(window):
@@ -385,10 +390,115 @@ def test_windowed_kernels_carry_names_of_their_own():
                 window=window) * w)
         return _kernel_names(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
 
+    # ONE backward kernel a layer, of the one-block backward's name: the
+    # benchmark's readers count an event of it as one layer's backward
+    assert both(None) == ["flash_bwd_fused", "flash_fwd"]
+    assert both(100) == ["flash_swa_bwd_fused", "flash_swa_fwd"]
+    assert both(512) == both(None)          # the window is the causal mask
+    # a sequence whose dk and dv do not fit VMEM: the two sweeps
+    monkeypatch.setattr(fa, "SWEEP_VMEM", 0)
     assert both(None) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
     assert both(100) == ["flash_swa_bwd_dkv", "flash_swa_bwd_dq",
                          "flash_swa_fwd"]
-    assert both(512) == both(None)          # the window is the causal mask
+
+
+# ---------------------------------------------------------------------------
+# the several-block backward is one sweep (PR 36) wherever dk and dv of the
+# whole sequence fit VMEM, and two past that
+# ---------------------------------------------------------------------------
+
+#   what                           entry     B  S    Sk   H  Hkv D    bq   bk   window causal
+ONE_SWEEP = [
+    ("triangle, ungrouped",        "packed", 1, 512, 512, 2, 2, 128, 64,  64,  None, True),
+    ("triangle, group 2",          "packed", 2, 256, 256, 4, 2, 128, 64,  64,  None, True),
+    ("triangle, group 7",          "packed", 1, 256, 256, 7, 1, 128, 64,  64,  None, True),
+    ("band, group 7",              "packed", 1, 512, 512, 7, 1, 128, 64,  64,  100,  True),
+    ("band, bq > bk",              "packed", 1, 512, 512, 2, 2, 128, 128, 64,  100,  True),
+    ("triangle, bq < bk, group 2", "packed", 1, 512, 512, 4, 2, 128, 64,  128, None, True),
+    ("rectangle, S != Sk, group 2", "packed", 1, 256, 512, 4, 2, 128, 64,  128, None, False),
+    ("rectangle, group 7",         "packed", 1, 256, 256, 7, 1, 128, 64,  64,  None, False),
+    ("two heads a block, no group", "packed", 1, 256, 256, 4, 4, 64,  64,  64,  None, True),
+    ("halves, two query blocks",   "packed", 1, 512, 512, 8, 2, 64,  64,  64,  None, True),
+    ("halves, band, bq < bk",      "packed", 1, 256, 256, 4, 2, 64,  64,  128, 72,   True),
+    ("halves, rectangle",          "packed", 1, 256, 256, 8, 2, 64,  128, 64,  None, False),
+    ("group 3 at one block",       "packed", 2, 128, 128, 6, 2, 128, 128, 128, None, True),
+    ("[BH, S, 64], triangle",      "bshd",   2, 256, 256, 2, 2, 64,  64,  128, None, True),
+    ("[BH, S, 128], rectangle",    "bshd",   1, 128, 256, 3, 3, 128, 64,  64,  None, False),
+]
+
+
+@pytest.mark.parametrize(
+    "what,entry,B,S,Sk,H,Hkv,D,bq,bk,window,causal", ONE_SWEEP,
+    ids=[m[0] for m in ONE_SWEEP])
+def test_one_sweep_backward_equals_plain_attention_and_the_two_sweeps(
+        monkeypatch, what, entry, B, S, Sk, H, Hkv, D, bq, bk, window,
+        causal):
+    """dq, dk, dv of ``flash_bwd_fused`` over several blocks against plain
+    attention's, and BIT FOR BIT against ``flash_bwd_dq`` / ``flash_bwd_dkv``
+    (the same call with no VMEM for the accumulators): one probability tile
+    a step in place of two, the same sums in the same order."""
+    rng = np.random.RandomState(31)
+    mk = lambda s, h: jnp.array((rng.randn(B, s, h, D) * 0.5)
+                                .astype(np.float32))
+    q, k, v, w = mk(S, H), mk(Sk, Hkv), mk(Sk, Hkv), mk(S, H)
+    if entry == "packed":
+        flat = lambda t: t.reshape(t.shape[0], t.shape[1], -1)
+        attn = lambda a, b, c: fa.flash_attention_packed(
+            flat(a), flat(b), flat(c), H, causal=causal, block_q=bq,
+            block_k=bk, n_kv_heads=Hkv, window=window).reshape(a.shape)
+    else:
+        attn = lambda a, b, c: fa.flash_attention(
+            a, b, c, causal=causal, block_q=bq, block_k=bk)
+    grads = lambda f: jax.grad(lambda *a: jnp.sum(f(*a) * w),
+                               argnums=(0, 1, 2))
+    names = _kernel_names(grads(attn), q, k, v)
+    assert [n for n in names if "bwd" in n] == [
+        "flash_swa_bwd_fused" if window else "flash_bwd_fused"], what
+    one = grads(attn)(q, k, v)
+    want = grads(lambda a, b, c: _plain(a, b, c, causal, window))(q, k, v)
+    for a, b, n in zip(one, want, "qkv"):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=1e-4,
+                                   err_msg="d%s of %s" % (n, what))
+    monkeypatch.setattr(fa, "SWEEP_VMEM", 0)
+    assert len([n for n in _kernel_names(grads(attn), q, k, v)
+                if "bwd" in n]) == 2, what
+    for a, b, n in zip(one, grads(attn)(q, k, v), "qkv"):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg="d%s of %s" % (n, what))
+
+
+@pytest.mark.parametrize("what,S,lanes,group,sweeps,mib", [
+    ("olmoe_1b_7b.s4096_scan", 4096, 128, 1, 1, 22),
+    ("lfm2_8b_a1b.s8192_scan", 8192, 128, 4, 1, 28),
+    ("smallthinker_21b_a3b.s16384_scan", 16384, 128, 7, 1, 40),
+    ("twice that", 32768, 128, 7, 1, 64),
+    ("four times", 65536, 128, 7, 2, 112),
+    ("Reach 8's sequence", 131072, 128, 8, 2, 208),
+    ("[BH, S, 64] pads to a lane tile", 16384, 64, 1, 1, 40),
+    ("heads of 256", 16384, 256, 1, 1, 64),
+    ("heads of 256, twice the sequence", 32768, 256, 1, 2, 112),
+])
+def test_the_rule_of_the_one_sweep_backward(what, S, lanes, group, sweeps,
+                                            mib):
+    """From the shapes: the accumulators and output blocks of dk and dv over
+    the whole sequence (float32 and bf16, [S, lanes] twice each) beside
+    Mosaic's own 16 MiB; one sweep up to half a v5e core's VMEM."""
+    need = fa.fused_sweep_vmem_bytes(S, lanes, 2)
+    assert need == mib * 2 ** 20, what
+    assert need == (2 * S * max(lanes, 128) * (4 + 2)) + fa.SCOPED_VMEM
+    assert fa.bwd_sweeps(S, 512, lanes, 2, group) == sweeps, what
+    assert (need <= fa.SWEEP_VMEM) == (sweeps == 1)
+    assert fa.SWEEP_VMEM <= 64 * 2 ** 20     # of a v5e core's 128 MiB
+
+
+def test_one_block_ungrouped_is_one_kernel_whatever_vmem_holds(monkeypatch):
+    """Every BERT shape: ``_bwd_fused``, which never asks the rule's bytes."""
+    monkeypatch.setattr(fa, "SWEEP_VMEM", 0)
+    assert fa.bwd_sweeps(512, 512, 128, 2) == 1
+    assert fa.packed_bwd_sweeps(512, 12, 64, 512) == 1
+    assert fa.bwd_sweeps(512, 512, 128, 2, group=7) == 2
+    assert fa.packed_bwd_sweeps(4096, 16, 128, 512) == 2
 
 
 @pytest.mark.parametrize("S,bq,bk,window,blocks", [
@@ -437,9 +547,11 @@ TABLES = [
                          ids=["q-major", "kv-major"])
 @pytest.mark.parametrize("S,Sk,bq,bk,causal,window,group", TABLES)
 def test_step_table(S, Sk, bq, bk, causal, window, group, kv_major):
-    """Every block that holds a visible pair is a step exactly once (once a
-    head of the group in the kv-major sweep), no other block is; FIRST and
-    LAST open and close each sweep once, in sweep order."""
+    """Every block that holds a visible pair is a step exactly once a head
+    of the group, no other block is; FIRST and LAST open and close each
+    sweep once, in sweep order.  Both orders bring a kv block its
+    (head, q block) pairs head first, then q block ascending: the order dk
+    and dv are summed in, whichever backward runs."""
     visible = _tiles(S, Sk, bq, bk, causal, window)
     qs, ks, heads, flags = fa.step_table(S, Sk, bq, bk, causal, window,
                                          group, kv_major)
@@ -447,12 +559,13 @@ def test_step_table(S, Sk, bq, bk, causal, window, group, kv_major):
     steps = list(zip(qs.tolist(), ks.tolist(), heads.tolist()))
     assert len(set(steps)) == len(steps)
     want = {(i, j, h) for i, j in zip(*np.nonzero(visible))
-            for h in range(group if kv_major else 1)}
+            for h in range(group)}
     assert set(steps) == want
     assert not (flags & ~(fa.FIRST | fa.LAST)).any()
-    # sweep order: one run of steps a q block (a kv block), ascending, each
-    # opened and closed once; inside a kv block's, head by head
-    owner, inner = (ks, qs) if kv_major else (qs, ks)
+    # sweep order: one run of steps a kv block (a q block of a head),
+    # ascending, each opened and closed once; inside a kv block's, head by
+    # head
+    owner, inner = (ks, qs) if kv_major else (heads * (S // bq) + qs, ks)
     assert (np.diff(owner) >= 0).all()
     first, last = (flags & fa.FIRST) != 0, (flags & fa.LAST) != 0
     edge = np.r_[True, np.diff(owner) != 0]
@@ -463,8 +576,15 @@ def test_step_table(S, Sk, bq, bk, causal, window, group, kv_major):
         assert (np.diff(heads[run]) >= 0).all()
         for h in np.unique(heads[run]):
             assert (np.diff(inner[run & (heads == h)]) > 0).all()
+    for j in np.unique(ks):
+        met = [(h, i) for i, jj, h in steps if jj == j]
+        assert met == sorted(met)
+    # the forward's and the dq sweep's grids hold the group as an axis
     if not kv_major:
-        assert not heads.any()
+        one = fa.step_table(S, Sk, bq, bk, causal, window)
+        assert not one[2].any() and one.shape[1] * group == len(steps)
+        np.testing.assert_array_equal(
+            np.tile(one[[0, 1, 3]], group), np.stack([qs, ks, flags]))
 
 
 def test_a_block_nobody_sees_is_refused():
@@ -525,7 +645,7 @@ def test_a_query_block_reads_one_half_of_its_key_value_block():
         assert (at(qm)[2], at(km)[2], at(sm)[1]) == (b, b // 4, b)
         assert g.kv_half(b) == (b // 2) % 2 == ((2 * b) // 4) % 2
     # the dk/dv sweep of key/value block 1: its table walks query blocks 4-7
-    qm, km, sm = g.sweep_maps(kv_major=True)
+    qm, km, sm = g.sweep_maps(walks_group=True)
     at = lambda m, t: m(0, 1, t, *table)
     assert [at(qm, t)[2] for t in range(0, 8, 2)] == [4, 5, 6, 7]
     assert [at(sm, t)[1] for t in range(0, 8, 2)] == [4, 5, 6, 7]

@@ -4,6 +4,8 @@ scoped VMEM a grid step may hold).  Nothing runs; no chip is needed.  All
 such compiles live in this one file and describe the chip inside a fixture,
 so that only the worker that is given the file loads the TPU's library."""
 
+import base64
+import hashlib
 import importlib
 import json
 import math
@@ -55,18 +57,43 @@ def test_forward_and_backward_compile_for_a_v5e(one_chip, what, entry, B, S,
             q, k, v, causal=causal, block_q=512, block_k=512,
             interpret=False)
 
-    text, grids = _compiled(attn, x, x, x, x)
+    text, grids, mosaic = _compiled(attn, x, x, x, x)
     assert text.count("tpu_custom_call") >= 2, what
-    if S > 512:     # several blocks: the sweeps' step tables are the grids
+    if S > 512:     # several blocks: the sweeps' step tables are the grids,
+        # and the backward ONE sweep, dk and dv of all 4,096 positions in
+        # VMEM (22 MiB asked of Mosaic)
         heads, steps = H * D // 128, fa.kv_blocks(S, 512, 512, causal)
         assert grids == {"flash_fwd": (B, heads, 1, steps),
-                         "flash_bwd_dq": (B, heads, 1, steps),
-                         "flash_bwd_dkv": (B, heads, steps)} and steps == 36, what
+                         "flash_bwd_fused": (B, heads, steps)} and steps == 36, what
+        asked, took = _vmem(text, "flash_bwd_fused")
+        assert asked == fa.fused_sweep_vmem_bytes(S, 128, 2) == 22 * 2 ** 20
+        assert 6 * 2 ** 20 < took < asked, what
+    else:           # one block: the kernels PR 28 left, to the letter
+        assert set(grids) == {"flash_fwd", "flash_bwd_fused"}, what
+        assert _vmem(text, "flash_bwd_fused")[0] is None, what
+        assert mosaic == ONE_BLOCK_MOSAIC[what], what
+
+
+# The one-block kernels' Mosaic modules as the several-block backward's
+# parent (6d15aec) lowers them, forward and backward: sha1 of each
+# ``tpu_custom_call`` body's text without debug info (``_compiled``).  A PR
+# that means to change these kernels replaces the digests; any other finds
+# here that it changed what every BERT cell runs.
+ONE_BLOCK_MOSAIC = {
+    "bert_base.s128_scan": ["1d482125fe7a", "496403bedea7"],
+    "bert_base.s512_scan": ["d6584da870c2", "a0feeacea941"],
+    "fine-tuning at 384": ["a2edfc8f7477", "4de8b028195f"],
+    "a prime batch, causal": ["f7cb281f682b", "40b5c4ca2615"],
+    "heads the packed layout cannot tile": ["72dc7b47f49c", "85a570491d08"],
+}
 
 
 def _compiled(attn, *shapes):
-    """(the compiled program's text, {kernel name: grid}) of ``attn``'s
-    forward and backward."""
+    """(the compiled program's text, {kernel name: grid}, the digests of the
+    kernels' Mosaic modules in call order) of ``attn``'s forward and
+    backward."""
+    from jaxlib.mlir import ir
+
     def both(q, k, v, do):
         o, vjp = jax.vjp(attn, q, k, v)
         return (o,) + vjp(do)
@@ -76,30 +103,54 @@ def _compiled(attn, *shapes):
              for grid, name in re.findall(
                  r"grid=\(([\d, ]*)\).*?name=(flash_\w+)", str(traced.jaxpr),
                  re.S)}
-    return traced.lower().compile().as_text(), grids
+    lowered = traced.lower()
+    mosaic = []
+    for body in re.findall(r"body\\22: \\22([A-Za-z0-9+/=]+)",
+                           lowered.as_text()):
+        context = ir.Context()
+        context.allow_unregistered_dialects = True
+        with context:
+            module = ir.Module.parse(base64.b64decode(body))
+            mosaic.append(hashlib.sha1(module.operation.get_asm(
+                enable_debug_info=False).encode()).hexdigest()[:12])
+    return lowered.compile().as_text(), grids, mosaic
+
+
+def _vmem(text, kernel):
+    """(bytes of VMEM the call of ``kernel`` asks Mosaic for, None where it
+    leaves the scope at its default; bytes the compiled kernel took)."""
+    size = r'\{"memory_space":"1","offset":"0","size":"(\d+)"\}'
+    line, = [l for l in text.splitlines()
+             if "tpu_custom_call" in l and re.search(
+                 r"%%?[\w.\-]*%s[\w.\-]* = " % kernel, l)]
+    asked, = re.findall(r'"scoped_memory_configs":\[(?:%s)?\]' % size, line)
+    took, = re.findall(r'"used_scoped_memory_configs":\[%s\]' % size, line)
+    return (int(asked) if asked else None), int(took)
 
 
 SMALLTHINKER, LFM2 = (1, 16384, 28, 4, 128), (2, 8192, 32, 8, 64)
 
 
-@pytest.mark.parametrize("what,shape,window,names,steps", [
+@pytest.mark.parametrize("what,shape,window,names,steps,mib", [
     ("smallthinker_21b_a3b.s16384_scan, a full layer", SMALLTHINKER, None,
-     ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"), 528),
+     ("flash_fwd", "flash_bwd_fused"), 528, 40),
     ("smallthinker_21b_a3b.s16384_scan, a windowed layer", SMALLTHINKER, 4096,
-     ("flash_swa_fwd", "flash_swa_bwd_dq", "flash_swa_bwd_dkv"), 252),
+     ("flash_swa_fwd", "flash_swa_bwd_fused"), 252, 40),
     ("lfm2_8b_a1b.s8192_scan, two heads a lane block", LFM2, None,
-     ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"), 136),
+     ("flash_fwd", "flash_bwd_fused"), 136, 28),
 ])
-def test_grouped_and_windowed_kernels_compile_for_a_v5e(one_chip, what, shape,
-                                                        window, names, steps):
+def test_grouped_and_windowed_kernels_compile_for_a_v5e(
+        one_chip, what, shape, window, names, steps, mib):
     """28 query heads on 4 key/value heads of 128 over 16,384 positions:
-    the index maps' reads of the scalar-prefetched step table and the dk/dv
-    sweep over a group's heads are what Mosaic has to take; at 32 on 8 heads
-    of 64, the select of a key/value block's half by a traced scalar and the
-    dk/dv sums into that half as well.  The grids are the tables: (row,
+    the index maps' reads of the scalar-prefetched step table and the
+    backward's one sweep over a group's heads, dk and dv of the whole
+    sequence in two float32 accumulators (16 MiB of the 40 the call asks
+    for), are what Mosaic has to take; at 32 on 8 heads of 64, the select of
+    a key/value block's half by a traced scalar and the dk/dv sums into that
+    half of the accumulators' rows as well.  The grids are the tables: (row,
     key/value head-block, query head-block of its group) by the blocks under
-    the diagonal (in the band), the dk/dv sweep's (row, key/value
-    head-block) by the group's times as many."""
+    the diagonal (in the band), the backward's (row, key/value head-block)
+    by the group's times as many."""
     B, S, H, Hkv, D = shape
     xq = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
     xk = jax.ShapeDtypeStruct((B, S, Hkv * D), jnp.bfloat16, sharding=one_chip)
@@ -107,13 +158,38 @@ def test_grouped_and_windowed_kernels_compile_for_a_v5e(one_chip, what, shape,
         q, k, v, H, causal=True, block_q=512, block_k=512, interpret=False,
         n_kv_heads=Hkv, window=window)
 
-    text, grids = _compiled(attn, xq, xk, xk, xq)
+    text, grids, _ = _compiled(attn, xq, xk, xk, xq)
     for name in names:
         assert name in text, (what, name)
     kv_blocks, group = Hkv * D // 128, H // Hkv
     assert fa.kv_blocks(S, 512, 512, True, window) == steps
-    assert grids == dict(zip(names, [(B, kv_blocks, group, steps)] * 2
-                             + [(B, kv_blocks, group * steps)])), what
+    assert grids == dict(zip(names, [(B, kv_blocks, group, steps),
+                                     (B, kv_blocks, group * steps)])), what
+    asked, took = _vmem(text, names[1])
+    assert asked == fa.fused_sweep_vmem_bytes(S, 128, 2) == mib * 2 ** 20
+    # the accumulators and the single-buffered output blocks, and a step's
+    # own blocks and tiles beside them
+    assert S * 128 * (4 + 2) * 2 < took < asked, what
+
+
+def test_a_sequence_past_the_rule_compiles_as_two_sweeps(one_chip):
+    """S = 65,536 at 128 lanes would ask for 112 MiB: ``flash_bwd_dq`` and
+    ``flash_bwd_dkv`` in Mosaic's own scope, as every several-block shape
+    ran before the one sweep."""
+    B, S, H, Hkv, D = 1, 65536, 4, 2, 128
+    assert fa.packed_bwd_sweeps(S, H, D, 512, n_kv_heads=Hkv) == 2
+    xq = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
+    xk = jax.ShapeDtypeStruct((B, S, Hkv * D), jnp.bfloat16, sharding=one_chip)
+    attn = lambda q, k, v: fa.flash_attention_packed(
+        q, k, v, H, causal=True, block_q=512, block_k=512, interpret=False,
+        n_kv_heads=Hkv, window=4096)
+    text, grids, _ = _compiled(attn, xq, xk, xk, xq)
+    steps = fa.kv_blocks(S, 512, 512, True, 4096)
+    assert grids == {"flash_swa_fwd": (B, 2, 2, steps),
+                     "flash_swa_bwd_dq": (B, 2, 2, steps),
+                     "flash_swa_bwd_dkv": (B, 2, 2 * steps)}
+    for kernel in ("flash_swa_bwd_dq", "flash_swa_bwd_dkv"):
+        assert _vmem(text, kernel)[0] is None
 
 
 @pytest.mark.parametrize("what,N,V,E,norm", [

@@ -402,6 +402,53 @@ def test_flash_grid_gauges_only_under_a_monitor_session(tmp_path):
         monitor.disable()
 
 
+def test_flash_backward_sweeps_gauges(tmp_path):
+    """``monitor.kernels.flash_bwd_sweeps_full`` / ``_windowed``: the
+    kernels of a layer kind's backward, from ``flash_attention.bwd_sweeps``,
+    which the kernel asks: 1 at the three sparse cells' shapes (dq, dk and
+    dv off one sweep), 2 where dk and dv of the sequence are past VMEM;
+    nothing outside a monitor session."""
+    import dataclasses
+    import importlib
+
+    from paddle_tpu.models import lfm2, olmoe, smallthinker
+
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+
+    one_kind = [(olmoe.olmoe_1b_7b_config(), 4, 4096),
+                (bert.bert_base_config(), 64, 512)]
+    kinds = [(smallthinker.smallthinker_21b_a3b_config(), 1, 16384),
+             (lfm2.lfm2_8b_a1b_config(), 2, 8192)]
+    assert monitor.active() is None
+    for cell in one_kind + kinds:
+        T.gauge_flash_grid(*cell)               # off: nothing to set
+    mon = monitor.enable(str(tmp_path), flight=False)
+    try:
+        def read(kind):
+            stat = mon.registry.get_stat(
+                "monitor.kernels.flash_bwd_sweeps_" + kind)
+            return None if stat is None else stat.value
+
+        assert read("windowed") is None     # no test before this set it
+        for cell in one_kind:
+            mon.registry.gauge("monitor.kernels.flash_bwd_sweeps_full").set(0)
+            T.gauge_flash_grid(*cell)
+            assert (read("full"), read("windowed")) == (1, None)
+        for cell in kinds:
+            for kind in ("full", "windowed"):
+                mon.registry.gauge(
+                    "monitor.kernels.flash_bwd_sweeps_" + kind).set(0)
+            T.gauge_flash_grid(*cell)
+            assert (read("full"), read("windowed")) == (1, 1)
+        # SmallThinker's layers at eight times the sequence: two sweeps
+        assert fa.fused_sweep_vmem_bytes(131072, 128, 2) > fa.SWEEP_VMEM
+        T.gauge_flash_grid(dataclasses.replace(kinds[0][0], max_seq=131072),
+                           1, 131072)
+        assert (read("full"), read("windowed")) == (2, 2)
+    finally:
+        monitor.disable()
+
+
 # ---------------------------------------------------------------------------
 # what compiled
 # ---------------------------------------------------------------------------
