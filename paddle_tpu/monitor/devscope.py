@@ -41,8 +41,11 @@ MOE, ROUTER = "moe", "router"
 # the gated short convolution that stands where attention does in some
 # layers of a stack (both projections, the gates and the taps between them)
 SHORT_CONV = "short_conv"
+# power retention where attention stands: projections, q/k norm, rotary
+# positions, the gate, the chunked-scan kernels, the output projection
+RETENTION = "retention"
 VOCABULARY = (EMBED, ATTENTION, MLP, LAYER_NORM, LM_HEAD, CONV, BN, POOL, FC,
-              LOSS, GRAD_SYNC, OPTIMIZER, MOE, ROUTER, SHORT_CONV)
+              LOSS, GRAD_SYNC, OPTIMIZER, MOE, ROUTER, SHORT_CONV, RETENTION)
 PHASES = ("forward", "backward", "recompute", GRAD_SYNC, OPTIMIZER)
 
 # `%fusion.12 = bf16[..] fusion(%p.1, %copy-done.2), ..., metadata={op_name="jit(multi)/..." ...}`:

@@ -272,7 +272,7 @@ def transformer_rules(cfg):
         # qk_norm and the MoE FFN run at tp == 1 (TransformerConfig): their
         # leaves are whole on every device
         (r"/(q_norm|k_norm)$", L(None)),
-        (r"/router$", L(None, None)),
+        (r"/(router|wg)$", L(None, None)),
         (r"/we_(gate_up|down)$", L(None, None, None)),
     ]
 

@@ -36,17 +36,23 @@ from ..monitor import devscope
 from ..monitor.recompile import compile_ledger
 from .ring_attention import ring_attention
 
-__all__ = ["TransformerConfig", "init_transformer_params", "transformer_param_specs",
+__all__ = ["TransformerConfig", "CONV", "RETENTION",
+           "init_transformer_params", "transformer_param_specs",
            "grad_sync_axes", "embed", "transformer_layer", "run_layers",
            "rms_norm", "rope", "final_logits_loss", "head_logits",
            "head_row_block", "head_rows_computed"]
 
 
 CONV = "conv"       # a layer kind: the gated short convolution, no attention
+# a layer kind: power retention on attention's projections, a learned
+# per-token decay and a state carried along the sequence; no softmax
+RETENTION = "retention"
+_OWN_LEAVES = (CONV, RETENTION)     # kinds whose position owns other leaves
 
 
 def _kinds(pattern):
-    return tuple(k if k == CONV else (k[0] or None, k[1]) for k in pattern)
+    return tuple(k if k in _OWN_LEAVES else (k[0] or None, k[1])
+                 for k in pattern)
 
 
 @dataclasses.dataclass
@@ -110,15 +116,22 @@ class TransformerConfig:
     # rotary), an attention layer: window 0 is full attention, rotary off is
     # NO positional encoding in that layer; or CONV, a layer whose operator
     # is the gated short convolution (``short_conv``) and which has no
-    # attention leaves.  Empty: one kind, full attention, rotary as
-    # ``positions`` says.  n_layers is ``prefix_pattern`` and whole periods.
+    # attention leaves; or RETENTION, a layer that keeps attention's
+    # projections (and ``qk_norm``, rotary positions, the grouping on
+    # ``n_kv_heads``) and replaces the softmax by power retention
+    # (``power_retention``), with a gate projection ``wg`` of its own.
+    # Empty: one kind, full attention, rotary as ``positions`` says.
+    # n_layers is ``prefix_pattern`` and whole periods.
     layer_pattern: tuple = ()
     # The kinds of the LEADING layers, which run before the scan over
     # periods and whose FFN, where the others' is the MoE, is one dense
-    # gated FFN (``expert_act``) of width ``dense_ffn_hidden``
+    # gated FFN (``expert_act``) of width ``dense_ffn_hidden``.  A stack
+    # WITHOUT experts whose layers own their leaves carries that FFN in
+    # every layer
     prefix_pattern: tuple = ()
     dense_ffn_hidden: int = 0
     conv_taps: int = 3               # CONV: taps of the causal depthwise filter
+    retention_chunk: int = 1024      # RETENTION: tokens between two states
 
     def __post_init__(self):
         assert self.norm in ("layer", "rms") and \
@@ -137,7 +150,7 @@ class TransformerConfig:
             assert self.tp == 1 and self.attn_mode == "heads" \
                 and not self.bias and self.n_heads % self.kv_heads == 0
         self.layer_pattern, self.prefix_pattern = (
-            tuple(CONV if k == CONV else (int(k[0]), bool(k[1]))
+            tuple(k if k in _OWN_LEAVES else (int(k[0]), bool(k[1]))
                   for k in pattern)
             for pattern in (self.layer_pattern, self.prefix_pattern))
         if self.layer_pattern:
@@ -148,6 +161,8 @@ class TransformerConfig:
         if self.prefix_pattern:
             assert self.layer_pattern and self.n_experts \
                 and self.dense_ffn_hidden and not self.bias
+        if self.per_position and not self.n_experts:
+            assert self.dense_ffn_hidden and not self.bias
 
     @property
     def head_dim(self):
@@ -163,7 +178,8 @@ class TransformerConfig:
 
     @property
     def layer_kinds(self):
-        """(window or None, rotary), or CONV, of each layer of one period."""
+        """(window or None, rotary), or CONV or RETENTION, of each layer of
+        one period."""
         if not self.layer_pattern:
             return ((None, self.positions == "rotary"),)
         return _kinds(self.layer_pattern)
@@ -177,7 +193,8 @@ class TransformerConfig:
     def per_position(self):
         """Whether the layers own different leaves, so that the tree holds
         them by position of the period (``init_transformer_params``)."""
-        return bool(self.prefix_pattern) or CONV in self.layer_pattern
+        return bool(self.prefix_pattern) or any(
+            k in _OWN_LEAVES for k in self.layer_pattern)
 
     @property
     def n_periods(self):
@@ -329,9 +346,11 @@ def _position_leaves(key, cfg, kind, n, dense):
     norms' scales; attention's ``wq`` / ``wk`` / ``wv`` / ``wo`` (and
     ``q_norm`` / ``k_norm``), or for CONV ``conv_in`` [E, 3E] (the gates B
     and C and the value, side by side), ``conv_w`` [taps, E] (tap j meets
-    position t - taps + 1 + j) and ``conv_out`` [E, E]; then the FFN's: the
-    MoE's, or where ``dense`` ``w_gate_up`` [E, 2F] (gate in columns [0, F))
-    and ``w_down`` [F, E] at F = ``dense_ffn_hidden``."""
+    position t - taps + 1 + j) and ``conv_out`` [E, E], for RETENTION
+    attention's and the gate projection ``wg`` [E, kv_heads] float32 (one
+    log-decay a key/value head and token); then the FFN's: the MoE's, or
+    where ``dense`` ``w_gate_up`` [E, 2F] (gate in columns [0, F)) and
+    ``w_down`` [F, E] at F = ``dense_ffn_hidden``."""
     assert cfg.norm == "rms" and not cfg.bias
     E, dt = cfg.hidden, cfg.jdtype
     Q, KV = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
@@ -351,6 +370,8 @@ def _position_leaves(key, cfg, kind, n, dense):
         leaves.update(wq=stack(1, E, (E, Q)), wk=stack(2, E, (E, KV)),
                       wv=stack(3, E, (E, KV)), wo=stack(4, Q, (Q, E)))
         leaves.update(_qk_norm_leaves(cfg, n))
+        if kind == RETENTION:
+            leaves["wg"] = stack(10, E, (E, cfg.kv_heads), jnp.float32)
     if dense:
         F = cfg.dense_ffn_hidden
         leaves.update(w_gate_up=stack(5, E, (E, 2 * F)),
@@ -370,17 +391,19 @@ ROUTER_BIAS_STD = 0.1
 def _per_position_layers(ks, cfg):
     """``prefix_layers``, ``params_layers`` and, where the routing rule has
     them, ``router_bias`` of a stack whose layers own different leaves."""
-    assert cfg.n_experts and cfg.positions == "rotary"
+    assert cfg.positions == "rotary"
     layers = {
-        "prefix_layers": {
-            "l%d" % i: jax.tree.map(lambda a: a[0], _position_leaves(
-                jax.random.fold_in(ks[4], i), cfg, kind, 1, dense=True))
-            for i, kind in enumerate(cfg.prefix_kinds)},
         "params_layers": {
             "p%d" % i: _position_leaves(jax.random.fold_in(ks[0], i), cfg,
-                                        kind, cfg.n_periods, dense=False)
+                                        kind, cfg.n_periods,
+                                        dense=not cfg.n_experts)
             for i, kind in enumerate(cfg.layer_kinds)},
     }
+    if cfg.prefix_pattern:
+        layers["prefix_layers"] = {
+            "l%d" % i: jax.tree.map(lambda a: a[0], _position_leaves(
+                jax.random.fold_in(ks[4], i), cfg, kind, 1, dense=True))
+            for i, kind in enumerate(cfg.prefix_kinds)}
     from .moe import SIGMOID_BIASED
 
     if cfg.routing == SIGMOID_BIASED:
@@ -505,16 +528,20 @@ def _norm(x, pl, name, cfg, fused=True):
                       eps=cfg.norm_eps, fused=fused)
 
 
-def rope(x, n_heads, theta=10000.0):
+def rope(x, n_heads, theta=10000.0, first=0):
     """Rotary position embedding on a packed projection x [b, S, H*dh],
-    positions 0..S-1, rotate-half convention (the halves of a head are the
-    pairs): ``x * cos + rotate_half(x) * sin`` with angle
+    positions ``first``..``first`` + S - 1 (``first`` may be traced: a block
+    of rows of a longer sequence), rotate-half convention (the halves of a
+    head are the pairs): ``x * cos + rotate_half(x) * sin`` with angle
     ``pos * theta^(-2i/dh)`` for both members of pair i.  float32 inside."""
     b, S, W = x.shape
     dh = W // n_heads
     half = dh // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq[None]
+    pos = jnp.arange(S, dtype=jnp.float32)
+    if not (isinstance(first, int) and first == 0):
+        pos = pos + first
+    ang = pos[:, None] * inv_freq[None]
     cos = jnp.tile(jnp.cos(ang), (1, 2))[None, :, None, :]      # [1,S,1,dh]
     sin = jnp.tile(jnp.sin(ang), (1, 2))[None, :, None, :]
     xf = x.astype(jnp.float32).reshape(b, S, n_heads, dh)
@@ -635,16 +662,22 @@ def gauge_flash_grid(cfg, b, S):
                 "monitor.kernels.flash_kv_blocks_skipped_" + name).set(0)
 
 
-def _attention_heads_mode(pl, h_full, cfg, kind):
-    """Megatron attention: input full-sequence [b,S,E], heads sharded over tp.
-    ``kind`` = (window or None, rotary) of this layer."""
-    b, S, E = h_full.shape
+def _local_heads(cfg):
+    """(query heads, key/value heads) this device holds."""
     ntp = col.axis_size_in(TP)
-    hl = cfg.n_heads // ntp if ntp > 1 else cfg.n_heads
-    kvl = cfg.kv_heads // ntp if ntp > 1 else cfg.kv_heads
-    dh = cfg.head_dim
-    window, rotary = kind
+    return (cfg.n_heads // ntp, cfg.kv_heads // ntp) if ntp > 1 \
+        else (cfg.n_heads, cfg.kv_heads)
 
+
+def _qkv(pl, h_full, cfg, rotary, first=0):
+    """The packed projections q [b, S, hl*dh] and k, v [b, S, kvl*dh] of the
+    full sequence ``h_full`` [b, S, E] (or of its rows from position
+    ``first`` on), with their biases, the configured q/k norm and, where
+    ``rotary``, rotary positions: what attention and power retention both
+    start from."""
+    b, S, E = h_full.shape
+    hl, kvl = _local_heads(cfg)
+    dh = cfg.head_dim
     # params arrive pre-sharded inside shard_map: wq/bqkv are [E, E/tp]/[3, E/tp]
     q2, k2, v2 = (h_full @ pl[w] for w in ("wq", "wk", "wv"))  # [b, S, hl*dh]
     if cfg.bias:
@@ -658,8 +691,19 @@ def _attention_heads_mode(pl, h_full, cfg, kind):
         q2 = rms_norm(q2, pl["q_norm"], cfg.norm_eps)
         k2 = rms_norm(k2, pl["k_norm"], cfg.norm_eps)
     if rotary:
-        q2 = rope(q2, hl, cfg.rope_theta)
-        k2 = rope(k2, kvl, cfg.rope_theta)
+        q2 = rope(q2, hl, cfg.rope_theta, first)
+        k2 = rope(k2, kvl, cfg.rope_theta, first)
+    return q2, k2, v2
+
+
+def _attention_heads_mode(pl, h_full, cfg, kind):
+    """Megatron attention: input full-sequence [b,S,E], heads sharded over tp.
+    ``kind`` = (window or None, rotary) of this layer."""
+    b, S, E = h_full.shape
+    hl, kvl = _local_heads(cfg)
+    dh = cfg.head_dim
+    window, rotary = kind
+    q2, k2, v2 = _qkv(pl, h_full, cfg, rotary)
     blocks = _packed_flash_blocks(cfg, hl, S, kvl)
     if blocks:
         # packed layout: the kernel reads each head's column slice in place —
@@ -722,13 +766,99 @@ def short_conv(pl, h):
         @ pl["conv_out"]
 
 
+def retention_log_decay(pl, h):
+    """The log-decay of every token and key/value head, [b, S, kv_heads]
+    float32: ``logsigmoid(h @ wg)``, in (-inf, 0)."""
+    return jax.nn.log_sigmoid(
+        h.astype(jnp.float32) @ pl["wg"].astype(jnp.float32))
+
+
+@devscope.scoped(devscope.RETENTION)
+def power_retention(pl, h, cfg):
+    """Power retention of degree 2 on ``h`` [b, S, E], the whole sequence,
+    by the carried-state algorithm (``kernels/power_retention.py``: chunks
+    of ``cfg.retention_chunk`` tokens, clamped to S): attention's
+    projections, q/k norm, rotary positions and grouping, weights
+    ``(q.k / sqrt(dh))^2`` decayed by the gate's running sum in place of
+    the softmax, the sum of the weights (+ eps) as normaliser; then
+    ``wo``."""
+    from ..kernels.power_retention import power_retention as kernel
+
+    def operands(rows, first):
+        return _qkv(pl, rows, cfg, True, first) \
+            + (retention_log_decay(pl, rows),)
+
+    # the norm and the rotation work in float32 (two bf16s a value) on all
+    # three projections at once
+    width = (cfg.n_heads + 2 * cfg.kv_heads) * cfg.head_dim
+    q2, k2, v2, log_decay = _by_row_blocks(operands, h, 2 * width)
+    o = kernel(q2, k2, v2, log_decay,
+               chunk=min(cfg.retention_chunk, h.shape[1]))
+    return o @ pl["wo"]
+
+
+# rows x width of a pointwise stage's widest activation (an FFN's hidden
+# rows, the projections between their matmuls and the kernel) past which it
+# runs a block of positions at a time, each block's forward run again in its
+# backward: 2^27 elements are 256 MB in bf16, and the gate, the up
+# projection, their product and the three gradients would each be that large
+ROW_BLOCK_ELEMENTS = 1 << 27
+
+
+def row_block(rows, width):
+    """Rows of one block of ``rows`` rows at activation width ``width``,
+    from the shapes alone: all of them up to ROW_BLOCK_ELEMENTS elements,
+    else the largest divisor of ``rows`` (whole sublane tiles) that keeps a
+    block under a quarter of that."""
+    if rows * width <= ROW_BLOCK_ELEMENTS:
+        return rows
+    fits = [r for r in range(8, rows, 8)
+            if rows % r == 0 and 4 * r * width <= ROW_BLOCK_ELEMENTS]
+    return max(fits, default=rows)
+
+
+def _by_row_blocks(fn, h, width):
+    """``fn(rows, first)`` on ``h`` [b, S, E] whole (``first`` = 0), or,
+    where b * S * ``width`` passes ROW_BLOCK_ELEMENTS, on ``row_block``
+    positions at a time (``first`` the block's first position) under a
+    ``jax.checkpoint`` of their own, so that a backward holds one block's
+    wide activations and never the sequence's; the blocks' results [b,
+    block, ...] put together along the positions."""
+    b, S, E = h.shape
+    block = row_block(S, b * width)
+    if block == S:
+        return fn(h, 0)
+    out = jax.lax.map(jax.checkpoint(lambda turn: fn(*turn)),
+                      (h.reshape(b, -1, block, E).swapaxes(0, 1),
+                       jnp.arange(0, S, block)))
+    return jax.tree.map(
+        lambda a: a.swapaxes(0, 1).reshape((b, S) + a.shape[3:]), out)
+
+
+def gated_ffn(pl, h, cfg):
+    """The dense gated FFN ``(act(h @ Wg) * (h @ Wu)) @ w_down`` on ``h``
+    [b, S, E], ``[Wg, Wu] = w_gate_up`` [E, 2F]; gate and product in
+    float32; in row blocks where the hidden activation is large
+    (``_by_row_blocks``)."""
+    from .moe import ACTIVATIONS
+
+    def rows_ffn(rows, first):
+        gate, up = jnp.split(rows @ pl["w_gate_up"], 2, axis=-1)
+        hidden = (ACTIVATIONS[cfg.expert_act](gate.astype(jnp.float32))
+                  * up.astype(jnp.float32)).astype(rows.dtype)
+        return hidden @ pl["w_down"]
+
+    return _by_row_blocks(rows_ffn, h, pl["w_down"].shape[0])
+
+
 def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
                       dense=False, router_bias=None):
     """One pre-norm block on the SP activation [b, S/tp, E]: the new
     activation and the FFN's auxiliary values (the MoE's, ``moe.route_top_k``;
-    None for a dense FFN).  ``kind`` = (window or None, rotary), or CONV:
-    which of ``cfg.layer_kinds`` this layer is (None: the first); ``dense``:
-    a leading layer, whose FFN is the dense gated one; ``router_bias`` [n]:
+    None for a dense FFN).  ``kind`` = (window or None, rotary), or CONV, or
+    RETENTION: which of ``cfg.layer_kinds`` this layer is (None: the first);
+    ``dense``: a layer whose FFN is the dense gated one (a leading layer, or
+    any layer of a stack without experts); ``router_bias`` [n]:
     this layer's selection biases, where the routing rule has them."""
     heads_mode = cfg.attn_mode == "heads"
     logits = None
@@ -740,6 +870,10 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
     if kind == CONV:
         with jax.named_scope(devscope.SHORT_CONV):
             x_sp = x_sp + short_conv(pl, _norm(x_sp, pl, "ln1", cfg))
+    elif kind == RETENTION:
+        with jax.named_scope(devscope.RETENTION):
+            x_sp = x_sp + power_retention(pl, _norm(x_sp, pl, "ln1", cfg),
+                                          cfg)
     else:
         with jax.named_scope(devscope.ATTENTION):
             h = _norm(x_sp, pl, "ln1", cfg)
@@ -753,13 +887,8 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
 
     if dense:
         with jax.named_scope(devscope.MLP):
-            from .moe import ACTIVATIONS
-
-            gate, up = jnp.split(
-                _norm(x_sp, pl, "ln2", cfg) @ pl["w_gate_up"], 2, axis=-1)
-            hidden = (ACTIVATIONS[cfg.expert_act](gate.astype(jnp.float32))
-                      * up.astype(jnp.float32)).astype(x_sp.dtype)
-            return x_sp + hidden @ pl["w_down"], None
+            return x_sp + gated_ffn(pl, _norm(x_sp, pl, "ln2", cfg),
+                                    cfg), None
 
     if cfg.n_experts:
         with jax.named_scope(devscope.MOE):
@@ -830,7 +959,8 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
         for at, kind in enumerate(kinds):
             pl = pls[at] if cfg.per_position \
                 else jax.tree.map(lambda a: a[at], pls)
-            x, aux = body(pl, x, cfg, kind, False,
+            x, aux = body(pl, x, cfg, kind,
+                          cfg.per_position and not cfg.n_experts,
                           None if biases is None else biases[at])
             auxes.append(aux)
         return x, jax.tree.map(lambda *a: jnp.stack(a), *auxes)

@@ -1,0 +1,497 @@
+"""The Brumby decoder through the normal path (``models/brumby.py`` over
+``parallel/transformer.py``'s RETENTION position, the gated FFN of a stack
+without experts and ``kernels/power_retention.py``'s carried-state kernels,
+in interpret mode) against the benchmark's plain float32 reference
+(``benchmark/reference/brumby_14b.py``, the score-matrix form), on seeded
+weights at ``brumby_tiny_config``: two layers, hidden 64, 10 query heads on 2
+key/value heads of 128 (a group of 5), chunks of 16 under S = 64 (4 chunks),
+FFN width 96, vocab 256.
+
+The tiny configuration computes in float32, so the tolerance is 1e-5 on the
+loss (the two differ by accumulation order only) and three times that on a
+single logit row or gradient element, against the largest of its leaf: the
+quotient of two sums of up to 64 squared products rounds more than a
+softmax does."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.reference import brumby_14b as reference  # noqa: E402
+from paddle_tpu import monitor  # noqa: E402
+from paddle_tpu.kernels import power_retention as pr  # noqa: E402
+from paddle_tpu.models import (bert, brumby, lfm2, olmoe,  # noqa: E402
+                               smallthinker)
+from paddle_tpu.monitor import devscope  # noqa: E402
+from paddle_tpu.parallel import optim, transformer as T  # noqa: E402
+from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
+from paddle_tpu.parallel.train import stack_batches  # noqa: E402
+
+B, S, TOL = 2, 64, 1e-5
+EACH = 3 * TOL         # one logit row, one gradient element
+# the reference reads the published keys and the operator's assumed sizes
+MODEL = {"num_attention_heads": 10, "num_key_value_heads": 2, "head_dim": 128,
+         "num_hidden_layers": 2, "rms_norm_eps": 1e-6, "rope_theta": 1000000,
+         "retention_degree": 2, "retention_eps": 1e-6, "retention_chunk": 16}
+NAMES = ("ln1_scale", "ln2_scale", "wq", "wk", "wv", "wo", "q_norm", "k_norm",
+         "wg", "w_gate_up", "w_down")
+LEAVES = ["tok_emb", "lm_head", "lnf_scale"] \
+    + ["params_layers/p0/" + n for n in NAMES]
+
+
+def _trainer(seed=3, optimizer=None, **cfg):
+    return brumby.build_brumby_trainer(
+        brumby.brumby_tiny_config(**cfg), MeshSpec(dp=1),
+        optimizer=optimizer or optim.adamw(), seed=seed,
+        devices=jax.devices()[:1])
+
+
+def _ids(seed=0, n=1):
+    rng = np.random.RandomState(seed)
+    return [rng.randint(0, 256, (B, S)).astype(np.int32) for _ in range(n)]
+
+
+def _seeded_params(tr):
+    """The trainer's seeded weights with the norm scales moved off 1, so
+    that a missing or misplaced scale shows, and a gate projection steep
+    enough that the decays differ from token to token."""
+    rng = np.random.RandomState(11)
+
+    def moved(path, a):
+        name = jax.tree_util.keystr(path)
+        if "scale" in name or "_norm" in name:
+            return np.asarray(a) * rng.uniform(0.5, 1.5, a.shape).astype("f4")
+        return np.asarray(a) * (3.0 if "wg" in name else 1.0)
+
+    return jax.tree_util.tree_map_with_path(moved, tr.state["params"])
+
+
+def _leaf(tree, path):
+    for part in path.split("/"):
+        tree = tree[part]
+    return tree
+
+
+@pytest.fixture(scope="module")
+def both():
+    """Loss and gradients of program and reference on the same weights."""
+    tr = _trainer()
+    params = _seeded_params(tr)
+    ids = _ids()[0]
+    loss_fn = olmoe.make_loss_fn(tr.cfg)
+    got = jax.jit(jax.value_and_grad(
+        lambda p: loss_fn(p, {"ids": jnp.asarray(ids)})))(params)
+    want = jax.value_and_grad(
+        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
+            jax.tree.map(jnp.asarray, params))
+    return tr.cfg, params, ids, got, want
+
+
+def test_the_tiny_configuration_keeps_every_mechanism():
+    cfg = brumby.brumby_tiny_config()
+    assert cfg.layer_kinds == (T.RETENTION,) and cfg.prefix_kinds == ()
+    assert cfg.per_position and cfg.n_periods == 2 and cfg.moe_layers == 0
+    assert (cfg.n_heads, cfg.kv_heads, cfg.head_dim) == (10, 2, 128)
+    assert cfg.qk_norm == "head" and not cfg.tie_head and not cfg.n_experts
+    assert brumby.retention_chunks(cfg, S) == 4
+    assert pr.supported(cfg.head_dim, S, cfg.retention_chunk)
+    big = brumby.brumby_14b_config()
+    assert (big.n_layers, big.hidden, big.n_heads, big.kv_heads, big.head_dim,
+            big.dense_ffn_hidden, big.vocab_size, big.rope_theta,
+            big.norm_eps, big.retention_chunk) == (
+        40, 5120, 40, 8, 128, 17408, 151936, 1e6, 1e-6, 1024)
+    assert brumby.retention_chunks(big, 16384) == 16
+    # 8 heads x 8,320 x 128 float32
+    np.testing.assert_allclose(brumby.retention_state_mb(big), 34.08, rtol=1e-3)
+    assert pr.STATE_COLUMNS == 8320 and pr.DIAGONALS == 65
+
+
+def test_loss_equals_the_reference(both):
+    _, _, _, (got, _), (want, _) = both
+    assert abs(float(got) - float(want)) / float(want) < TOL
+
+
+def test_every_position_s_logits_equal_the_reference(both):
+    cfg, params, ids, _, _ = both
+    x, _ = jax.jit(lambda p, i: olmoe._forward(p, i, cfg))(params, ids)
+    got = T.rms_norm(x, params["lnf_scale"], cfg.norm_eps) @ params["lm_head"].T
+    want = np.stack(reference.forward(params, ids, MODEL)[1])
+    np.testing.assert_allclose(got, want, rtol=1e-4,
+                               atol=EACH * np.abs(want).max())
+
+
+@pytest.mark.parametrize("path", LEAVES)
+def test_gradient_of_every_leaf_equals_the_reference(both, path):
+    _, params, _, (_, got), (_, want) = both
+    g, w = np.asarray(_leaf(got, path)), np.asarray(_leaf(want, path))
+    assert g.shape == _leaf(params, path).shape and np.abs(w).max() > 0
+    np.testing.assert_allclose(g, w, rtol=1e-4, atol=EACH * np.abs(w).max())
+
+
+def test_the_leaves_tested_are_all_there_are(both):
+    _, params, _, _, _ = both
+    paths, _, _ = __import__(
+        "paddle_tpu.parallel.rules", fromlist=["leaf_paths"]).leaf_paths(params)
+    assert set(paths) == set(LEAVES)
+    assert params["params_layers"]["p0"]["wg"].shape == (2, 64, 2)
+    assert params["params_layers"]["p0"]["wg"].dtype == np.float32
+    assert params["params_layers"]["p0"]["q_norm"].shape == (2, 128)
+
+
+def test_sharding_specs_and_gradient_syncs_follow_the_tree():
+    cfg = brumby.brumby_tiny_config()
+    params = jax.eval_shape(
+        lambda: T.init_transformer_params(jax.random.PRNGKey(0), cfg))
+    for tree in (T.transformer_param_specs(cfg), T.grad_sync_axes(cfg)):
+        assert jax.tree.structure(
+            tree, is_leaf=lambda x: isinstance(x, (tuple, T.P))) == \
+            jax.tree.structure(params)
+    assert T.transformer_param_specs(cfg)["params_layers"]["p0"]["wg"] == \
+        T.P(None, None, None)
+
+
+# ---------------------------------------------------------------------------
+# the operator alone, against the score-matrix form
+# ---------------------------------------------------------------------------
+
+def _score_matrix_form(q, k, v, g, n_heads, n_kv):
+    """``reference._retain`` a key/value head at a time: o [B, S, H * dh]."""
+    b, s, _ = q.shape
+    group = n_heads // n_kv
+    q, k, v = (a.reshape(b, s, n, 128) for a, n in
+               ((q, n_heads), (k, n_kv), (v, n_kv)))
+    with jax.default_matmul_precision("highest"):
+        return jnp.stack([jnp.concatenate([reference._retain(
+            q[i, :, h * group:(h + 1) * group], k[i, :, h], v[i, :, h],
+            g[i, :, h], 1e-6, s, None) for h in range(n_kv)], axis=1)
+            for i in range(b)]).reshape(b, s, -1)
+
+
+def _operands(gates, seq=S):
+    ks = jax.random.split(jax.random.PRNGKey(4), 5)
+    q = jax.random.normal(ks[0], (1, seq, 10 * 128))
+    k = jax.random.normal(ks[1], (1, seq, 2 * 128))
+    v = jax.random.normal(ks[2], (1, seq, 2 * 128))
+    g = -0.01 * jax.random.uniform(ks[3], (1, seq, 2)) if gates == "near one" \
+        else jax.nn.log_sigmoid(jax.random.normal(ks[3], (1, seq, 2)))
+    return q, k, v, g, jax.random.normal(ks[4], q.shape)
+
+
+@pytest.mark.parametrize("chunk", [8, 32])
+@pytest.mark.parametrize("gates", ["near one", "seeded"])
+def test_the_kernels_equal_the_score_matrix_form(gates, chunk):
+    """Output and the gradients of q, k, v and the log-decay, with decays
+    in [-0.01, 0) (a state that reaches across every chunk of the sequence)
+    and from a seeded gate (mean one half), at two chunk lengths: 8 and 2
+    chunks, the same numbers beyond rounding."""
+    q, k, v, g, w = _operands(gates)
+
+    def program(*a):
+        return pr.power_retention(*a, chunk=chunk)
+
+    def plain(*a):
+        return _score_matrix_form(*a, 10, 2)
+
+    got, want = program(q, k, v, g), plain(q, k, v, g)
+    np.testing.assert_allclose(got, want, rtol=1e-4,
+                               atol=TOL * float(jnp.abs(want).max()))
+    got = jax.grad(lambda *a: jnp.sum(program(*a) * w), (0, 1, 2, 3))(
+        q, k, v, g)
+    want = jax.grad(lambda *a: jnp.sum(plain(*a) * w), (0, 1, 2, 3))(
+        q, k, v, g)
+    for name, a, b in zip("qkvg", got, want):
+        assert float(jnp.abs(b).max()) > 0, name
+        np.testing.assert_allclose(
+            a, b, rtol=1e-3, atol=TOL * float(jnp.abs(b).max()),
+            err_msg=name)
+
+
+def test_the_state_carries_across_every_chunk_where_the_gates_are_near_one():
+    """With decays near one the first token still weighs at the last: moving
+    v_0 moves the last chunk's output, through three folds of the state."""
+    q, k, v, g, _ = _operands("near one")
+    out = pr.power_retention(q, k, v, g, chunk=16)
+    moved = pr.power_retention(q, k, v.at[:, 0].add(1.0), g, chunk=16)
+    assert float(jnp.abs(moved - out)[:, 48:].max()) > 1e-3
+    # and retention is causal: nothing before a change moves
+    late = pr.power_retention(q, k, v.at[:, 40].add(1.0), g, chunk=16)
+    np.testing.assert_array_equal(late[:, :40], out[:, :40])
+
+
+def test_the_feature_tiles_multiply_out_to_the_squared_product():
+    """65 wrapped diagonals, weighted 1, 2, ..., 2, 1, are the 8,256
+    distinct products with the off-diagonal ones twice."""
+    x, y = np.random.RandomState(0).randn(2, 128)
+    total = sum((1.0 if d in (0, 64) else 2.0)
+                * np.dot(x * np.roll(x, d), y * np.roll(y, d))
+                for d in range(pr.DIAGONALS))
+    np.testing.assert_allclose(total, np.dot(x, y) ** 2, rtol=1e-10)
+
+
+def test_the_row_blocked_ffn_equals_the_unblocked_one(monkeypatch):
+    cfg = brumby.brumby_tiny_config()
+    pl = jax.tree.map(lambda a: a[0], T._position_leaves(
+        jax.random.PRNGKey(2), cfg, T.RETENTION, 1, True))
+    h = jax.random.normal(jax.random.PRNGKey(3), (B, S, 64))
+    w = jax.random.normal(jax.random.PRNGKey(4), h.shape)
+    assert T.row_block(S, B * 96) == S                      # one block
+    # from the shapes: the cell's FFN runs 1,024 rows at a time, and no
+    # FFN of another configuration's cell is blocked
+    assert T.row_block(16384, 17408) == 1024
+    assert T.row_block(16384, 2 * 7168) == 2048             # its projections
+    assert T.row_block(8192, 2 * 7168) == 8192              # lfm2's prefix
+    whole = jax.value_and_grad(
+        lambda pl, h: jnp.sum(T.gated_ffn(pl, h, cfg) * w), (0, 1))(pl, h)
+    monkeypatch.setattr(T, "ROW_BLOCK_ELEMENTS", 96 * 64)
+    assert T.row_block(S, B * 96) == 8                      # eight blocks
+    blocked = jax.value_and_grad(
+        lambda pl, h: jnp.sum(T.gated_ffn(pl, h, cfg) * w), (0, 1))(pl, h)
+    for a, b in zip(jax.tree.leaves(blocked), jax.tree.leaves(whole)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+def test_the_eight_vocabulary_slices_logits_are_the_uncut_model_s_columns():
+    """A chip's slice of the vocabulary is a smaller vocabulary: with the
+    head's rows [lo, lo + V/8) the logits are those columns of the uncut
+    model's, for ids inside the slice."""
+    ids = jnp.asarray(_ids(seed=4)[0] % 32)
+
+    def logits(cfg):
+        return jax.jit(lambda p: T.head_logits(
+            p, olmoe._forward(p, ids, cfg)[0], cfg))
+
+    uncut, cut = (brumby.brumby_tiny_config(vocab_size=v) for v in (256, 32))
+    params = T.init_transformer_params(jax.random.PRNGKey(3), uncut)
+    whole, share = np.asarray(logits(uncut)(params)), logits(cut)
+    for lo in range(0, 256, 32):
+        got = share(dict(params, lm_head=params["lm_head"][lo:lo + 32],
+                         tok_emb=params["tok_emb"][:32]))
+        np.testing.assert_allclose(got, whole[..., lo:lo + 32], rtol=1e-5,
+                                   atol=1e-5)
+
+
+@pytest.mark.parametrize("config", [
+    bert.bert_tiny_config, olmoe.olmoe_tiny_config,
+    smallthinker.smallthinker_tiny_config, lfm2.lfm2_tiny_config])
+def test_the_other_configurations_trees_and_seeds_are_unchanged(config):
+    """The RETENTION kind and the gated FFN of a stack without experts took
+    nothing from the four transformers the benchmark holds: the same
+    leaves, and the same seeded numbers (a digest of every leaf held in
+    ``SEEDED``, taken on the parent commit)."""
+    params = T.init_transformer_params(jax.random.PRNGKey(7), config())
+    flat = jax.tree_util.tree_leaves_with_path(params)
+    got = {jax.tree_util.keystr(p): float(np.float64(
+        np.abs(np.asarray(a, np.float64)).sum())) for p, a in flat}
+    assert got == pytest.approx(SEEDED[config.__name__], rel=1e-6)
+
+
+# every leaf's sum of magnitudes at PRNGKey(7), taken on the parent commit
+SEEDED = {'bert_tiny_config': {"['lnf_bias']": 0.0,
+                      "['lnf_scale']": 32.0,
+                      "['params_layers']['b1']": 0.0,
+                      "['params_layers']['b2']": 0.0,
+                      "['params_layers']['bo']": 0.0,
+                      "['params_layers']['bqkv']": 0.0,
+                      "['params_layers']['ln1_bias']": 0.0,
+                      "['params_layers']['ln1_scale']": 128.0,
+                      "['params_layers']['ln2_bias']": 0.0,
+                      "['params_layers']['ln2_scale']": 128.0,
+                      "['params_layers']['w1']": 1152.8355756563942,
+                      "['params_layers']['w2']": 808.2289385568729,
+                      "['params_layers']['wk']": 592.1522415721083,
+                      "['params_layers']['wo']": 574.7196429537144,
+                      "['params_layers']['wq']": 588.4103693639227,
+                      "['params_layers']['wv']": 579.7487703325514,
+                      "['pos_emb']": 143.66492584527805,
+                      "['tok_emb']": 569.3203954972032},
+ 'lfm2_tiny_config': {"['lnf_scale']": 64.0,
+                      "['params_layers']['p0']['k_norm']": 64.0,
+                      "['params_layers']['p0']['ln1_scale']": 64.0,
+                      "['params_layers']['p0']['ln2_scale']": 64.0,
+                      "['params_layers']['p0']['q_norm']": 64.0,
+                      "['params_layers']['p0']['router']": 54.1657983098703,
+                      "['params_layers']['p0']['we_down']": 587.6754246161472,
+                      "['params_layers']['p0']['we_gate_up']": 823.1441512249457,
+                      "['params_layers']['p0']['wk']": 816.5909004715668,
+                      "['params_layers']['p0']['wo']": 822.264226873272,
+                      "['params_layers']['p0']['wq']": 1635.71336963069,
+                      "['params_layers']['p0']['wv']": 822.4447295245268,
+                      "['params_layers']['p1']['conv_in']": 1227.8499064550597,
+                      "['params_layers']['p1']['conv_out']": 405.60675256537706,
+                      "['params_layers']['p1']['conv_w']": 92.46371063939296,
+                      "['params_layers']['p1']['ln1_scale']": 64.0,
+                      "['params_layers']['p1']['ln2_scale']": 64.0,
+                      "['params_layers']['p1']['router']": 52.07485518039903,
+                      "['params_layers']['p1']['we_down']": 588.8123617785568,
+                      "['params_layers']['p1']['we_gate_up']": 827.2239650608913,
+                      "['params_layers']['p2']['conv_in']": 1215.3631965017703,
+                      "['params_layers']['p2']['conv_out']": 401.857793078394,
+                      "['params_layers']['p2']['conv_w']": 83.68283341638744,
+                      "['params_layers']['p2']['ln1_scale']": 64.0,
+                      "['params_layers']['p2']['ln2_scale']": 64.0,
+                      "['params_layers']['p2']['router']": 52.60504949082315,
+                      "['params_layers']['p2']['we_down']": 572.210527533156,
+                      "['params_layers']['p2']['we_gate_up']": 824.212584609777,
+                      "['params_layers']['p3']['conv_in']": 1214.78922535883,
+                      "['params_layers']['p3']['conv_out']": 414.4740955226516,
+                      "['params_layers']['p3']['conv_w']": 95.52531716157682,
+                      "['params_layers']['p3']['ln1_scale']": 64.0,
+                      "['params_layers']['p3']['ln2_scale']": 64.0,
+                      "['params_layers']['p3']['router']": 52.70977442455478,
+                      "['params_layers']['p3']['we_down']": 570.0817819327187,
+                      "['params_layers']['p3']['we_gate_up']": 806.7620937885613,
+                      "['prefix_layers']['l0']['conv_in']": 1224.633957261458,
+                      "['prefix_layers']['l0']['conv_out']": 418.40880030640847,
+                      "['prefix_layers']['l0']['conv_w']": 82.72618536796654,
+                      "['prefix_layers']['l0']['ln1_scale']": 64.0,
+                      "['prefix_layers']['l0']['ln2_scale']": 64.0,
+                      "['prefix_layers']['l0']['w_down']": 499.64167449623346,
+                      "['prefix_layers']['l0']['w_gate_up']": 1232.4543042174964,
+                      "['router_bias']": 1.8799528190866113,
+                      "['tok_emb']": 1633.9137341165888},
+ 'olmoe_tiny_config': {"['lm_head']": 1632.9334373973475,
+                       "['lnf_scale']": 64.0,
+                       "['params_layers']['k_norm']": 128.0,
+                       "['params_layers']['ln1_scale']": 128.0,
+                       "['params_layers']['ln2_scale']": 128.0,
+                       "['params_layers']['q_norm']": 128.0,
+                       "['params_layers']['router']": 103.56748182419688,
+                       "['params_layers']['we_down']": 4618.478713639468,
+                       "['params_layers']['we_gate_up']": 6546.293675803162,
+                       "['params_layers']['wk']": 818.7573912261068,
+                       "['params_layers']['wo']": 809.5457860952924,
+                       "['params_layers']['wq']": 823.2149566229631,
+                       "['params_layers']['wv']": 823.1931161046712,
+                       "['tok_emb']": 1633.9137341165888},
+ 'smallthinker_tiny_config': {"['lm_head']": 1632.9334373973475,
+                              "['lnf_scale']": 64.0,
+                              "['params_layers']['ln1_scale']": 256.0,
+                              "['params_layers']['ln2_scale']": 256.0,
+                              "['params_layers']['router']": 204.00814175308915,
+                              "['params_layers']['we_down']": 2296.916480960748,
+                              "['params_layers']['we_gate_up']": 3284.987979393266,
+                              "['params_layers']['wk']": 6560.700681847619,
+                              "['params_layers']['wo']": 5651.161562121702,
+                              "['params_layers']['wq']": 19613.929149552085,
+                              "['params_layers']['wv']": 6524.984493576052,
+                              "['tok_emb']": 13071.30987293271}}
+
+
+@pytest.fixture(scope="module")
+def witnessed():
+    tr = _trainer()
+    params = _seeded_params(tr)
+    tr.state["params"] = jax.tree.map(jnp.asarray, params)
+    ids = _ids(seed=9)[0][:1]       # one sequence: the cell's batch
+    program = np.asarray(tr.logits_at(ids, reference.witness_positions(S)))
+    return params, ids, program
+
+
+def test_the_witness_reads_both_groups(witnessed):
+    """What ``benchmark/drivers/train_scan_witnessed.py`` checks on the chip:
+    the trainer's own forward at the witness's positions against the
+    reference's logits; the statistic is the larger group's third
+    quartile."""
+    params, ids, program = witnessed
+    groups = reference.witness_groups(S)
+    assert groups["edge"].tolist() == [
+        at + i for at in (16, 32, 48) for i in range(8)]
+    assert not set(groups["edge"]) & set(groups["spread"])
+    big = reference.witness_groups(16384)
+    assert big["edge"][:9].tolist() == list(range(2048, 2056)) + [4096]
+    assert len(big["edge"]) == 56 and len(big["spread"]) == 256
+    each = reference.position_errors(program, params, {"ids": ids}, MODEL)
+    assert each.shape == (S,) and each.max() < EACH
+    parts = reference.group_errors(program, params, {"ids": ids}, MODEL)
+    assert reference.logits_error(program, params, {"ids": ids}, MODEL) \
+        == max(parts.values())
+    per = each.reshape(1, -1)
+    assert parts["edge"] == np.quantile(per[:, :24], 0.75)
+
+
+@pytest.mark.parametrize("fault", reference.FAULTS[:-1])
+def test_the_witness_sees_every_fault(witnessed, fault):
+    """Each fault in the reference moves its logits away from the program's
+    by a thousand times what the two differ by when both are sound, at the
+    witness's own statistic; the three faults of the carried state show in
+    the ``edge`` group."""
+    params, ids, program = witnessed
+    args = (program, params, {"ids": ids}, MODEL)
+    assert reference.logits_error(*args, faults=(fault,)) > 1e3 * TOL
+    if fault in ("state_dropped_at_chunk_edges", "state_read_undecayed",
+                 "sqrt2_left_out_of_state"):
+        assert reference.group_errors(*args, faults=(fault,))["edge"] \
+            > 1e3 * TOL
+
+
+def test_bfloat16_throughout_moves_the_reference_s_loss(both):
+    _, params, ids, _, (want, _) = both
+    bad = reference.loss(params, {"ids": ids}, MODEL,
+                         faults=("bfloat16_throughout",))
+    assert abs(bad - float(want)) / float(want) > 2 * TOL
+
+
+def test_the_reference_in_small_pieces_equals_itself_whole(both, monkeypatch):
+    _, params, ids, _, (want, want_grad) = both
+    params = jax.tree.map(jnp.asarray, params)
+    monkeypatch.setattr(reference, "QUERY_BLOCK", 16)       # 4 blocks of 64
+    monkeypatch.setattr(reference, "VOCAB_CHUNK", 100)      # 100, 100, 56
+    monkeypatch.setattr(reference, "DENSE_CHUNK", 40)       # 40, 40, 16
+    loss, grad = jax.value_and_grad(
+        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
+            params)
+    assert abs(float(loss) - float(want)) / float(want) < 1e-6
+    for g, w in zip(jax.tree.leaves(grad), jax.tree.leaves(want_grad)):
+        np.testing.assert_allclose(g, w, rtol=1e-4,
+                                   atol=1e-5 * max(np.abs(w).max(), 1e-30))
+
+
+def test_run_steps_over_two_batches_equals_two_steps():
+    batches = [{"ids": i} for i in _ids(seed=5, n=2)]
+    one, scan = (_trainer(remat=True, n_layers=1) for _ in range(2))
+    singly = [float(one.step(b, 1e-3)) for b in batches]
+    scanned = scan.run_steps(
+        stack_batches(scan.mesh, olmoe.BATCH_SPECS, batches), 1e-3)
+    np.testing.assert_allclose(scanned, singly, rtol=1e-5)
+    assert singly[0] != singly[1]
+    for a, b in zip(jax.tree.leaves(one.state["params"]),
+                    jax.tree.leaves(scan.state["params"])):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+
+
+def test_gauges_only_under_a_monitor_session(tmp_path):
+    tr = _trainer(n_layers=1)
+    assert monitor.active() is None
+    batches = [{"ids": i} for i in _ids(seed=8, n=2)]
+    mon = monitor.enable(str(tmp_path), flight=False)
+    try:
+        reg = mon.registry
+        tr.run_steps(stack_batches(tr.mesh, olmoe.BATCH_SPECS, batches), 1e-3)
+        assert reg.gauge("monitor.train.retention_chunks").value == 4
+        np.testing.assert_allclose(
+            reg.gauge("monitor.train.retention_state_mb").value,
+            2 * 8320 * 128 * 4 / 1e6)
+        # seeded gates: sigmoid of a unit-scale projection, mean one half
+        assert 0.4 < reg.gauge(
+            "monitor.train.retention_gate_mean").value < 0.6
+    finally:
+        monitor.disable()
+
+
+def test_the_retention_s_instructions_are_under_their_scope():
+    tr = _trainer(remat=True, n_layers=1)
+    tr.run_steps(stack_batches(tr.mesh, olmoe.BATCH_SPECS,
+                               [{"ids": i} for i in _ids(n=2)]), 1e-3)
+    names = devscope.scope_maps()["brumby.run_steps"]
+    got = {devscope.classify(op) for op in names.values()}
+    for scope in ("retention", "mlp", "layer_norm", "lm_head", "embed"):
+        assert ("forward", scope) in got and ("backward", scope) in got, scope
+    assert ("recompute", "retention") in got

@@ -227,3 +227,42 @@ def test_head_matrix_gradient_is_tiled_in_a_few_windows(one_chip, what, N, V,
                 int(b) for b in config["iteration_bounds"]))
     assert len(windows) == len(T._vocab_chunks(emb)), (what, windows)
     assert max(windows) <= 300, (what, windows)
+
+
+@pytest.mark.parametrize("chunk", [1024, 2048])
+def test_power_retention_compiles_for_a_v5e_at_the_cell_s_shapes(one_chip,
+                                                                 chunk):
+    """``brumby_14b.s16384_scan``: [1, 16384, 40 x 128] queries on 8
+    key/value heads, bf16, forward and backward through Mosaic at the
+    configured chunk length and at the longest the configuration allows:
+    the grid is (batch, key/value head, chunk, query head of the group),
+    the state of 65 x 128 x 128 float32 and its gradient are VMEM scratch,
+    and nothing tokens x 8,320 wide is among the program's buffers."""
+    pr = importlib.import_module("paddle_tpu.kernels.power_retention")
+    S, Hq, Hkv = 16384, 40, 8
+    q = jax.ShapeDtypeStruct((1, S, Hq * 128), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, S, Hkv * 128), jnp.bfloat16,
+                             sharding=one_chip)
+    g = jax.ShapeDtypeStruct((1, S, Hkv), jnp.float32, sharding=one_chip)
+
+    def both(q, k, v, g, do):
+        o, vjp = jax.vjp(lambda *a: pr.power_retention(
+            *a, chunk=chunk, interpret=False), q, k, v, g)
+        return (o,) + vjp(do)
+
+    traced = jax.jit(both).trace(q, k, k, g, q)
+    grids = {name: tuple(int(n) for n in grid.split(",") if n.strip())
+             for grid, name in re.findall(
+                 r"grid=\(([\d, ]*)\).*?name=(power_retention_\w+)",
+                 str(traced.jaxpr), re.S)}
+    assert grids == {"power_retention_fwd": (1, Hkv, S // chunk, 5),
+                     "power_retention_bwd": (1, Hkv, S // chunk, 5)}
+    compiled = traced.lower().compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    for kernel in grids:
+        asked, took = _vmem(text, kernel)
+        assert asked == pr.VMEM_LIMIT and took < asked, (kernel, took)
+    # the saved chunk states, and no expansion of the tokens
+    assert "f32[1,8,%d,65,128,128]" % (S // chunk) in text
+    assert not re.search(r"\[(?:\d+,)*16384,(?:\d+,)*(?:8320|8256)", text)
