@@ -32,14 +32,15 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .. import monitor
-from ..kernels.power_retention import STATE_COLUMNS
+from ..kernels.power_retention import STATE_COLUMNS, state_sweeps
 from ..parallel.mesh import DP, local_shard_map
 from ..parallel.transformer import (RETENTION, TransformerConfig, embed,
                                     retention_log_decay, rms_norm)
 from . import olmoe
 
 __all__ = ["brumby_14b_config", "brumby_tiny_config", "BrumbyTrainer",
-           "build_brumby_trainer", "retention_chunks", "retention_state_mb"]
+           "build_brumby_trainer", "retention_chunks", "retention_state_mb",
+           "retention_state_sweeps"]
 
 
 def brumby_14b_config(n_layers=40, vocab_size=151936, **kw):
@@ -81,6 +82,16 @@ def retention_state_mb(cfg):
     return cfg.kv_heads * STATE_COLUMNS * cfg.head_dim * 4 / 1e6
 
 
+def retention_state_sweeps(cfg, seq):
+    """Sweeps of the state's 65 tiles a layer's forward runs over a sequence
+    of ``seq`` tokens, as the kernels' own rule has it: one a key/value
+    head and chunk where a group's query heads ride one grid step, one a
+    query head and chunk where they would not fit the kernels' VMEM."""
+    return state_sweeps(cfg.n_heads, cfg.kv_heads, seq,
+                        min(cfg.retention_chunk, seq),
+                        jnp.dtype(cfg.dtype).itemsize)
+
+
 @dataclasses.dataclass
 class BrumbyTrainer(olmoe.OlmoeTrainer):
     label = "brumby"
@@ -89,7 +100,8 @@ class BrumbyTrainer(olmoe.OlmoeTrainer):
     def _observe(self, batch):
         """Under a monitor session: ``monitor.train.retention_chunks``
         (chunks a layer and sequence), ``monitor.train.retention_state_mb``
-        (the state a layer carries) and ``monitor.train.retention_gate_mean``
+        (the state a layer carries), ``monitor.train.retention_state_sweeps``
+        (``retention_state_sweeps``) and ``monitor.train.retention_gate_mean``
         (the mean ``e^g`` over tokens and heads of the call's first batch in
         layer 0, at the weights the call starts from: a state decays to 1/e
         in ``1 / (1 - mean)`` tokens or so).  Off the monitor nothing
@@ -102,6 +114,8 @@ class BrumbyTrainer(olmoe.OlmoeTrainer):
             retention_chunks(cfg, ids.shape[-1]))
         mon.registry.gauge("monitor.train.retention_state_mb").set(
             retention_state_mb(cfg))
+        mon.registry.gauge("monitor.train.retention_state_sweeps").set(
+            retention_state_sweeps(cfg, ids.shape[-1]))
         if self._gate_fn is None:
             def gate_mean(params, ids):
                 pl = jax.tree.map(lambda a: a[0],

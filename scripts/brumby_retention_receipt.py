@@ -16,11 +16,18 @@ head's group; the limit is 2e-2 on every one (bf16 operands: 2^-8 a product,
 a few of them in sequence), and a fault control (the state dropped at chunk
 edges, put into the reference) has to read over it with the near-one
 gates.  Also times the two kernels (device seconds by kernel name from a
-trace) at each chunk length given (default 1024 and 2048).  Exit 1 where a
-reading is off, 2 off a TPU."""
+trace) at each chunk length given (default 512, 1024 and 2048), beside the
+grid each call runs, and fits a grid step's time to the chunk length
+``c``: ``fixed + per_row * c + per_row_squared * c * c`` microseconds (three
+lengths give the three exactly), the fixed part being what a step pays
+whatever its rows (``a`` = a tile's share of it, ``b`` a tile's share of a
+row's) and the square the chunk's own block.  Exit 1 where a reading is
+off or a kernel's name matched no instruction of the trace, 2 off a TPU."""
 
 import json
+import math
 import os
+import re
 import sys
 import tempfile
 import time
@@ -35,6 +42,7 @@ import numpy as np  # noqa: E402
 from benchmark.reference import brumby_14b as reference  # noqa: E402
 from paddle_tpu.kernels import power_retention as pr  # noqa: E402
 
+KERNELS = ("power_retention_fwd", "power_retention_bwd")
 S, HQ, HKV, DH = 16384, 40, 8, 128
 GROUP = HQ // HKV
 LIMIT = 2e-2
@@ -93,11 +101,41 @@ def kernel_seconds(run, names):
             for name in names}
 
 
+def kernel_grids(fn, *args):
+    """The grid of each kernel ``fn`` calls, by kernel name, read off the
+    traced program as ``tests/test_flash_tpu_compile.py`` reads it."""
+    return {name: [int(n) for n in grid.split(",") if n.strip()]
+            for grid, name in re.findall(
+                r"grid=\(([\d, ]*)\).*?name=(power_retention_\w+)",
+                str(fn.trace(*args).jaxpr), re.S)}
+
+
+def fit_step(us_by_chunk, grids):
+    """``fixed + per_row * c + per_row_squared * c^2`` microseconds a grid
+    step, through three chunk lengths' readings (least squares past
+    three); ``a`` and ``b`` are a tile's share of the first two.  Only
+    the chunk lengths that run the shortest chunk's schedule (a grid of as
+    many axes) are fitted: a step of a group in parts is another step.
+    None with fewer than three."""
+    chunks = sorted(us_by_chunk)
+    chunks = [n for n in chunks if len(grids[n]) == len(grids[chunks[0]])]
+    if len(chunks) < 3:
+        return None
+    c = np.array(chunks, np.float64)
+    coef, *_ = np.linalg.lstsq(
+        np.stack([np.ones_like(c), c, c * c], axis=1),
+        np.array([us_by_chunk[n] for n in chunks], np.float64), rcond=None)
+    fixed, per_row, per_row_squared = (float(x) for x in coef)
+    return {"fixed_us": fixed, "per_row_us": per_row,
+            "per_row_squared_us": per_row_squared,
+            "a_us": fixed / pr.DIAGONALS, "b_us": per_row / pr.DIAGONALS}
+
+
 def main(out_path=None, *chunks):
     if jax.devices()[0].platform != "tpu":
         print("the receipt is the chip's: no TPU here")
         return 2
-    return run(out_path, [int(c) for c in chunks] or [1024, 2048])
+    return run(out_path, [int(c) for c in chunks] or [512, 1024, 2048])
 
 
 def run(out_path, chunks):
@@ -110,7 +148,8 @@ def run(out_path, chunks):
              "seeded": jax.nn.log_sigmoid(jax.random.normal(ks[5],
                                                             (1, S, HKV)))}
     out = {"device": jax.devices()[0].device_kind, "limit": LIMIT,
-           "shape": [1, S, HQ, HKV, DH], "readings": {}, "seconds": {}}
+           "shape": [1, S, HQ, HKV, DH], "readings": {}, "seconds": {},
+           "grid": {}, "step_us": {}, "fit": {}}
     ok = True
 
     def program(chunk):
@@ -166,13 +205,28 @@ def run(out_path, chunks):
     out["control_state_dropped"] = control
     ok = ok and control > LIMIT
     print("control (state dropped at chunk edges):", control, flush=True)
+    step_us = {name: {} for name in KERNELS}
     for chunk in chunks:
         run = program(chunk)
-        out["seconds"][str(chunk)] = kernel_seconds(
-            lambda: run(q, k, v, gates["seeded"]),
-            ("power_retention_fwd", "power_retention_bwd"))
-        print("chunk", chunk, "kernel seconds a layer:",
-              out["seconds"][str(chunk)], flush=True)
+        seconds = kernel_seconds(lambda: run(q, k, v, gates["seeded"]),
+                                 KERNELS)
+        grids = kernel_grids(run, q, k, v, gates["seeded"])
+        out["seconds"][str(chunk)], out["grid"][str(chunk)] = seconds, grids
+        out["step_us"][str(chunk)] = {}
+        for name in KERNELS:
+            if not seconds[name] > 0 or name not in grids:
+                print("chunk", chunk, name, "matched no instruction of the "
+                      "trace or no call of the program", flush=True)
+                ok = False
+                continue
+            step_us[name][chunk] = out["step_us"][str(chunk)][name] = \
+                seconds[name] * 1e6 / math.prod(grids[name])
+        print("chunk", chunk, "kernel seconds a layer:", seconds, "grids:",
+              grids, "microseconds a grid step:", out["step_us"][str(chunk)],
+              flush=True)
+    out["fit"] = {name: fit_step(step_us[name], {
+        chunk: out["grid"][str(chunk)][name] for chunk in step_us[name]})
+        for name in KERNELS}
     worst = max(out["readings"].items(), key=lambda kv: kv[1])
     out["worst"], out["ok"] = list(worst), bool(ok)
     print(json.dumps({k_: v_ for k_, v_ in out.items() if k_ != "readings"}))

@@ -14,6 +14,7 @@ quotient of two sums of up to 64 squared products rounds more than a
 softmax does."""
 
 import os
+import re
 import sys
 
 import jax
@@ -175,9 +176,9 @@ def _score_matrix_form(q, k, v, g, n_heads, n_kv):
             for i in range(b)]).reshape(b, s, -1)
 
 
-def _operands(gates, seq=S):
+def _operands(gates, seq=S, group=5):
     ks = jax.random.split(jax.random.PRNGKey(4), 5)
-    q = jax.random.normal(ks[0], (1, seq, 10 * 128))
+    q = jax.random.normal(ks[0], (1, seq, 2 * group * 128))
     k = jax.random.normal(ks[1], (1, seq, 2 * 128))
     v = jax.random.normal(ks[2], (1, seq, 2 * 128))
     g = -0.01 * jax.random.uniform(ks[3], (1, seq, 2)) if gates == "near one" \
@@ -185,20 +186,33 @@ def _operands(gates, seq=S):
     return q, k, v, g, jax.random.normal(ks[4], q.shape)
 
 
-@pytest.mark.parametrize("chunk", [8, 32])
-@pytest.mark.parametrize("gates", ["near one", "seeded"])
-def test_the_kernels_equal_the_score_matrix_form(gates, chunk):
-    """Output and the gradients of q, k, v and the log-decay, with decays
-    in [-0.01, 0) (a state that reaches across every chunk of the sequence)
-    and from a seeded gate (mean one half), at two chunk lengths: 8 and 2
-    chunks, the same numbers beyond rounding."""
-    q, k, v, g, w = _operands(gates)
-
+def _program_and_plain(chunk, group):
     def program(*a):
         return pr.power_retention(*a, chunk=chunk)
 
     def plain(*a):
-        return _score_matrix_form(*a, 10, 2)
+        return _score_matrix_form(*a, 2 * group, 2)
+
+    return program, plain
+
+
+def _value_and_grads(f, q, k, v, g, w):
+    return (f(q, k, v, g),) + jax.grad(
+        lambda *a: jnp.sum(f(*a) * w), (0, 1, 2, 3))(q, k, v, g)
+
+
+@pytest.mark.parametrize("group", [1, 5])
+@pytest.mark.parametrize("chunk", [8, 32])
+@pytest.mark.parametrize("gates", ["near one", "seeded"])
+def test_the_kernels_equal_the_score_matrix_form(gates, chunk, group):
+    """Output and the gradients of q, k, v and the log-decay, with decays
+    in [-0.01, 0) (a state that reaches across every chunk of the sequence)
+    and from a seeded gate (mean one half), at two chunk lengths (8 and 2
+    chunks) and with one and five query heads a key/value head (the five
+    stacked along rows in one grid step): the same numbers beyond
+    rounding."""
+    q, k, v, g, w = _operands(gates, group=group)
+    program, plain = _program_and_plain(chunk, group)
 
     got, want = program(q, k, v, g), plain(q, k, v, g)
     np.testing.assert_allclose(got, want, rtol=1e-4,
@@ -211,6 +225,56 @@ def test_the_kernels_equal_the_score_matrix_form(gates, chunk):
         assert float(jnp.abs(b).max()) > 0, name
         np.testing.assert_allclose(
             a, b, rtol=1e-3, atol=TOL * float(jnp.abs(b).max()),
+            err_msg=name)
+
+
+def test_a_group_that_does_not_fit_goes_in_parts_to_the_same_numbers(
+        monkeypatch):
+    """The kernels' VMEM rule: a group whose stacked rows would pass
+    ``VMEM_LIMIT`` is swept in the largest divisor of its heads that fits,
+    on a fourth grid axis; with the limit patched under the five stacked
+    heads' need the group goes a head at a time, to the whole group's
+    numbers, and ``state_sweeps`` (the trainer's gauge) counts a sweep a
+    query head and chunk where it counted one a key/value head and chunk."""
+    q, k, v, g, w = _operands("near one")
+    program, _ = _program_and_plain(16, 5)
+    assert pr.sweep_heads(5, 16, 4) == 5
+    assert pr.state_sweeps(10, 2, S, 16, 4) == 2 * 4
+    whole = _value_and_grads(program, q, k, v, g, w)
+    monkeypatch.setattr(pr, "VMEM_LIMIT", pr._step_vmem_bytes(5, 16, 4) - 1)
+    assert pr.sweep_heads(5, 16, 4) == 1
+    assert pr.state_sweeps(10, 2, S, 16, 4) == 2 * 4 * 5
+    grids = [tuple(int(n) for n in grid.split(",")) for grid in re.findall(
+        r"grid=\(([\d, ]*)\)", str(jax.make_jaxpr(
+            lambda *a: _value_and_grads(program, *a))(q, k, v, g, w)))]
+    assert grids and set(grids) == {(1, 2, 4, 5)}
+    in_parts = _value_and_grads(program, q, k, v, g, w)
+    for name, a, b in zip("oqkvg", in_parts, whole):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-5, atol=1e-6 * float(jnp.abs(b).max()),
+            err_msg=name)
+    cfg = brumby.brumby_tiny_config()
+    assert brumby.retention_state_sweeps(cfg, S) == 2 * 4 * 5
+    # six heads a group go in threes where two such steps fit and six do not
+    monkeypatch.setattr(pr, "VMEM_LIMIT", pr._step_vmem_bytes(3, 16, 4))
+    assert pr.sweep_heads(6, 16, 4) == 3
+
+
+def test_the_in_chunk_block_goes_in_row_blocks_to_the_same_numbers(
+        monkeypatch):
+    """At the cell's shape a chunk's own block goes 256 query rows of every
+    head at a time, the stacked arrays holding one row block of every head
+    after another; at these sizes a chunk is one row block.  With
+    ``ROW_BLOCK`` patched to 8 the chunk of 32 goes in four row blocks, to
+    the one block's numbers."""
+    q, k, v, g, w = _operands("seeded")
+    program, _ = _program_and_plain(32, 5)
+    whole = _value_and_grads(program, q, k, v, g, w)
+    monkeypatch.setattr(pr, "ROW_BLOCK", 8)
+    in_blocks = _value_and_grads(program, q, k, v, g, w)
+    for name, a, b in zip("oqkvg", in_blocks, whole):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-5, atol=1e-6 * float(jnp.abs(b).max()),
             err_msg=name)
 
 
@@ -476,6 +540,9 @@ def test_gauges_only_under_a_monitor_session(tmp_path):
         reg = mon.registry
         tr.run_steps(stack_batches(tr.mesh, olmoe.BATCH_SPECS, batches), 1e-3)
         assert reg.gauge("monitor.train.retention_chunks").value == 4
+        # a sweep of the state's tiles a key/value head and chunk: the
+        # five heads of a group ride one grid step
+        assert reg.gauge("monitor.train.retention_state_sweeps").value == 8
         np.testing.assert_allclose(
             reg.gauge("monitor.train.retention_state_mb").value,
             2 * 8320 * 128 * 4 / 1e6)

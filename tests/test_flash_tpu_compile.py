@@ -234,10 +234,15 @@ def test_power_retention_compiles_for_a_v5e_at_the_cell_s_shapes(one_chip,
                                                                  chunk):
     """``brumby_14b.s16384_scan``: [1, 16384, 40 x 128] queries on 8
     key/value heads, bf16, forward and backward through Mosaic at the
-    configured chunk length and at the longest the configuration allows:
-    the grid is (batch, key/value head, chunk, query head of the group),
-    the state of 65 x 128 x 128 float32 and its gradient are VMEM scratch,
-    and nothing tokens x 8,320 wide is among the program's buffers."""
+    configured chunk length and at the longest the configuration allows.
+    The grid is (batch, key/value head, chunk): a step serves the five query
+    heads of a group, stacked along rows, in one sweep of the state's 65
+    tiles.  At chunks of 2,048 the stacked step would hold 142 MiB by the
+    compiler's count, over ``VMEM_LIMIT``: there the kernels' own rule
+    (``sweep_heads``) sweeps the group a head at a time on a fourth grid
+    axis, the path Mosaic has to take as well.  The state of 65 x 128 x 128
+    float32 and its gradient are VMEM scratch, and nothing tokens x 8,320
+    wide is among the program's buffers."""
     pr = importlib.import_module("paddle_tpu.kernels.power_retention")
     S, Hq, Hkv = 16384, 40, 8
     q = jax.ShapeDtypeStruct((1, S, Hq * 128), jnp.bfloat16, sharding=one_chip)
@@ -255,14 +260,20 @@ def test_power_retention_compiles_for_a_v5e_at_the_cell_s_shapes(one_chip,
              for grid, name in re.findall(
                  r"grid=\(([\d, ]*)\).*?name=(power_retention_\w+)",
                  str(traced.jaxpr), re.S)}
-    assert grids == {"power_retention_fwd": (1, Hkv, S // chunk, 5),
-                     "power_retention_bwd": (1, Hkv, S // chunk, 5)}
+    parts = {1024: (), 2048: (5,)}[chunk]
+    assert pr.sweep_heads(Hq // Hkv, chunk) == (1 if parts else 5)
+    assert pr.state_sweeps(Hq, Hkv, S, chunk) == {1024: 128, 2048: 320}[chunk]
+    assert grids == {"power_retention_fwd": (1, Hkv, S // chunk) + parts,
+                     "power_retention_bwd": (1, Hkv, S // chunk) + parts}
     compiled = traced.lower().compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 2
     for kernel in grids:
         asked, took = _vmem(text, kernel)
         assert asked == pr.VMEM_LIMIT and took < asked, (kernel, took)
+        # the rule that decides how a group is swept counts no less
+        assert took <= pr._step_vmem_bytes(
+            pr.sweep_heads(Hq // Hkv, chunk), chunk, 2), (kernel, took)
     # the saved chunk states, and no expansion of the tokens
     assert "f32[1,8,%d,65,128,128]" % (S // chunk) in text
     assert not re.search(r"\[(?:\d+,)*16384,(?:\d+,)*(?:8320|8256)", text)
