@@ -44,8 +44,19 @@ SHORT_CONV = "short_conv"
 # power retention where attention stands: projections, q/k norm, rotary
 # positions, the gate, the chunked-scan kernels, the output projection
 RETENTION = "retention"
+# latent attention where attention stands: both low-rank chains and their
+# latents' norms, rotation and assembly of the heads, the flash kernels, the
+# output projection
+LATENT_ATTENTION = "latent_attention"
+# the dense gated FFN every token meets beside its routed experts
+SHARED_EXPERT = "shared_expert"
+# the scan over the stacked layers itself: its slices of each layer's leaves,
+# the activations it keeps for the backward pass and the gradients it stacks
+# (a layer's own work carries the layer's scopes, which lie further in)
+LAYER_SCAN = "layer_scan"
 VOCABULARY = (EMBED, ATTENTION, MLP, LAYER_NORM, LM_HEAD, CONV, BN, POOL, FC,
-              LOSS, GRAD_SYNC, OPTIMIZER, MOE, ROUTER, SHORT_CONV, RETENTION)
+              LOSS, GRAD_SYNC, OPTIMIZER, MOE, ROUTER, SHORT_CONV, RETENTION,
+              LATENT_ATTENTION, SHARED_EXPERT, LAYER_SCAN)
 PHASES = ("forward", "backward", "recompute", GRAD_SYNC, OPTIMIZER)
 
 # `%fusion.12 = bf16[..] fusion(%p.1, %copy-done.2), ..., metadata={op_name="jit(multi)/..." ...}`:

@@ -259,7 +259,11 @@ def transformer_rules(cfg):
         # (TransformerConfig): its leading layers' leaves are not stacked,
         # and all of it is whole on every device
         (r"^prefix_layers/|^router_bias$", P()),
-        (r"/(conv_in|conv_w|conv_out|w_gate_up|w_down)$", L(None, None)),
+        (r"/(conv_in|conv_w|conv_out|w_gate_up|w_down|ws_gate_up|ws_down)$",
+         L(None, None)),
+        # latent attention runs at tp == 1 (TransformerConfig)
+        (r"/(wq_a|wq_b|wkv_a|wkv_b)$", L(None, None)),
+        (r"/(q_a_norm|kv_a_norm)$", L(None)),
         (r"^(tok_emb|lm_head)$", P(TP, None)),       # vocab-parallel
         (r"^pos_emb$|^lnf_", P()),
         (r"/ln[12]_(scale|bias)$", L(None)),

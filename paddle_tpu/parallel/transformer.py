@@ -27,6 +27,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from . import collectives as col
@@ -39,8 +40,10 @@ from .ring_attention import ring_attention
 __all__ = ["TransformerConfig", "CONV", "RETENTION",
            "init_transformer_params", "transformer_param_specs",
            "grad_sync_axes", "embed", "transformer_layer", "run_layers",
-           "rms_norm", "rope", "final_logits_loss", "head_logits",
-           "head_row_block", "head_rows_computed"]
+           "rms_norm", "rope", "rope_pairs", "yarn_blend_range",
+           "yarn_frequencies",
+           "yarn_softmax_scale", "yarn_rotary_factor", "final_logits_loss",
+           "head_logits", "head_row_block", "head_rows_computed"]
 
 
 CONV = "conv"       # a layer kind: the gated short convolution, no attention
@@ -132,6 +135,34 @@ class TransformerConfig:
     dense_ffn_hidden: int = 0
     conv_taps: int = 3               # CONV: taps of the causal depthwise filter
     retention_chunk: int = 1024      # RETENTION: tokens between two states
+    # Latent attention (kv_lora_rank > 0; every layer, in place of wq / wk /
+    # wv): queries off a latent of q_lora_rank, RMS-normed; keys and values
+    # off ONE latent of kv_lora_rank, RMS-normed, beside which the same
+    # projection gives qk_rope_dim columns that are rotated and stand, the
+    # same for every head, as the last columns of each head's key.  A head
+    # is [qk_nope_dim | qk_rope_dim] = head_dim wide, its first part without
+    # positions; rotary pairs are ADJACENT columns (``rope_pairs``).  A
+    # value is v_head_dim wide, head_dim today (the flash kernels have one
+    # width)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN positions of the latent form (rope_factor > 1; ``yarn_frequencies``,
+    # ``yarn_softmax_scale``): the factor, the positions the extension starts
+    # from, the rotations that bound the blend, the two mscales
+    rope_factor: float = 0.0
+    rope_original_max: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # the query of position p times 1 + beta * ln(1 + p // rope_original_max)
+    q_scale_beta: float = 0.0
+    # a dense gated FFN of this width (``expert_act``) beside the routed
+    # experts in every MoE layer, on the same input, for every token
+    shared_ffn_hidden: int = 0
 
     def __post_init__(self):
         assert self.norm in ("layer", "rms") and \
@@ -163,6 +194,16 @@ class TransformerConfig:
                 and self.dense_ffn_hidden and not self.bias
         if self.per_position and not self.n_experts:
             assert self.dense_ffn_hidden and not self.bias
+        if self.latent:
+            assert self.positions == "rotary" and self.tp == 1 \
+                and not (self.bias or self.qk_norm or self.layer_pattern) \
+                and self.kv_heads == self.n_heads and self.q_lora_rank \
+                and self.qk_rope_dim % 2 == 0 and self.head_dim \
+                == self.qk_nope_dim + self.qk_rope_dim == self.v_head_dim
+            assert self.rope_original_max or not (
+                self.rope_factor or self.q_scale_beta)
+        if self.shared_ffn_hidden:
+            assert self.n_experts and not self.bias
 
     @property
     def head_dim(self):
@@ -171,6 +212,11 @@ class TransformerConfig:
     @property
     def kv_heads(self):
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def latent(self):
+        """Whether attention is the latent form."""
+        return self.kv_lora_rank > 0
 
     @property
     def experts_here(self):
@@ -238,7 +284,11 @@ def init_transformer_params(key, cfg: TransformerConfig):
     the experts' leaves hold ``experts_here`` of them).  ``wq`` / ``wo``
     are n_heads * head_dim wide, ``wk`` / ``wv`` kv_heads * head_dim;
     ``q_norm`` / ``k_norm`` are as wide as their projection, or one head
-    wide with ``qk_norm="head"``.
+    wide with ``qk_norm="head"``.  The latent form (``cfg.latent``) has
+    ``wq_a`` / ``q_a_norm`` / ``wq_b`` and ``wkv_a`` / ``kv_a_norm`` /
+    ``wkv_b`` where the others have ``wq`` / ``wk`` / ``wv``
+    (``_latent_qkv``); ``ws_gate_up`` [E, 2Fs] / ``ws_down`` [Fs, E] are the
+    shared expert's (``shared_ffn_hidden``).
 
     Where every layer has the same leaves (attention layers that differ in
     window and rotary alone) ``params_layers`` is ONE tree stacked [L, ...].
@@ -267,9 +317,15 @@ def _init_params(key, cfg):
         # a lookup averages nothing: where a block reads the un-normed stream
         # (router_input "block": a router before the first norm) the rows
         # are seeded N(0, 1), the scale of the branches' outputs, so that a
-        # token's own row and not attention's mean ranks its experts
+        # token's own row and not attention's mean ranks its experts; and
+        # where a chip holds a SHARE of the experts that no selection bias
+        # balances, whatever its router reads: at the fan-in scale the rows
+        # are a sixtieth of attention's output, neighbouring tokens rank the
+        # experts alike and a share's rows, and its step's time, follow the
+        # seed (PERF.md section 6, PRs 31 and 39)
         "tok_emb": _dense_init(
-            ks[1], 1 if cfg.n_experts and cfg.router_input == "block" else E,
+            ks[1], 1 if cfg.n_experts and (
+                cfg.router_input == "block" or _unbalanced_share(cfg)) else E,
             (V, E), dt),
         "lnf_scale": jnp.ones((E,), jnp.float32),
         **layers,
@@ -281,6 +337,14 @@ def _init_params(key, cfg):
     if not cfg.tie_head:
         params["lm_head"] = _dense_init(ks[3], E, (V, E), dt)
     return params
+
+
+def _unbalanced_share(cfg):
+    """Whether this device holds a share of the router's experts and the
+    routing rule has no selection bias that a step moves against the load."""
+    from .moe import SIGMOID_BIASED
+
+    return cfg.experts_here < cfg.n_experts and cfg.routing != SIGMOID_BIASED
 
 
 def _stacked_layers(ks, cfg):
@@ -311,11 +375,29 @@ def _stacked_layers(ks, cfg):
         layer["bqkv"] = jnp.zeros((L, 3, E), dt)
         layer["bo"] = jnp.zeros((L, E), dt)
     layer.update(_qk_norm_leaves(cfg, L))
+    if cfg.latent:
+        for name in ("wq", "wk", "wv"):
+            del layer[name]
+        rq, rkv, H = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.n_heads
+        layer.update(
+            wq_a=stack(9, E, (E, rq)),
+            q_a_norm=jnp.ones((L, rq), jnp.float32),
+            wq_b=stack(10, rq, (rq, Q)),
+            # the latent's columns, then the shared rotary key's
+            wkv_a=stack(11, E, (E, rkv + cfg.qk_rope_dim)),
+            kv_a_norm=jnp.ones((L, rkv), jnp.float32),
+            # head by head [k_nope | v]
+            wkv_b=stack(12, rkv, (rkv, H * (cfg.qk_nope_dim
+                                            + cfg.v_head_dim))))
     if cfg.n_experts:
         n = cfg.experts_here          # the router ranks all n_experts
         layer["router"] = stack(6, E, (E, cfg.n_experts), jnp.float32)
         layer["we_gate_up"] = stack(7, E, (n, E, 2 * F))
         layer["we_down"] = stack(8, F, (n, F, E))
+        if cfg.shared_ffn_hidden:
+            Fs = cfg.shared_ffn_hidden
+            layer["ws_gate_up"] = stack(13, E, (E, 2 * Fs))
+            layer["ws_down"] = stack(14, Fs, (Fs, E))
     else:
         layer["w1"] = stack(4, E, (E, F))
         layer["w2"] = stack(5, F, (F, E))
@@ -510,13 +592,17 @@ def layer_norm(x, scale, bias, eps=1e-6, fused=True):
     return ((xf - mu) * jax.lax.rsqrt(var + eps) * scale + bias).astype(x.dtype)
 
 
+def _rms(x, scale, eps):
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(ms + eps) * scale).astype(x.dtype)
+
+
 @devscope.scoped(devscope.LAYER_NORM)
 def rms_norm(x, scale, eps=1e-5):
     """``x * rsqrt(mean(x^2) + eps) * scale`` over the last axis, in float32.
     Plain XLA: it fuses into the matmul that reads it."""
-    xf = x.astype(jnp.float32)
-    ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    return (xf * jax.lax.rsqrt(ms + eps) * scale).astype(x.dtype)
+    return _rms(x, scale, eps)
 
 
 def _norm(x, pl, name, cfg, fused=True):
@@ -547,6 +633,72 @@ def rope(x, n_heads, theta=10000.0, first=0):
     xf = x.astype(jnp.float32).reshape(b, S, n_heads, dh)
     rot = jnp.concatenate([-xf[..., half:], xf[..., :half]], axis=-1)
     return (xf * cos + rot * sin).reshape(b, S, W).astype(x.dtype)
+
+
+def yarn_blend_range(cfg):
+    """``(lo, hi)`` of YaRN's blend over the rotated pairs of the latent
+    form: the floor / ceiling of the pair that turns ``rope_beta_fast`` /
+    ``rope_beta_slow`` times over the ``rope_original_max`` positions,
+    inside [0, pairs - 1].  Pairs up to ``lo`` keep their frequency, pairs
+    from ``hi`` on are wholly interpolated, a linear ramp between."""
+    dim = cfg.qk_rope_dim
+
+    def pair_of(rotations):
+        return dim * math.log(cfg.rope_original_max / (
+            rotations * 2 * math.pi)) / (2 * math.log(cfg.rope_theta))
+
+    return (max(math.floor(pair_of(cfg.rope_beta_fast)), 0),
+            min(math.ceil(pair_of(cfg.rope_beta_slow)), dim // 2 - 1))
+
+
+def yarn_frequencies(cfg):
+    """The angular frequency of each rotated pair of the latent form,
+    float64 [qk_rope_dim / 2].  Plain rotary positions: ``theta^(-j /
+    pairs)``.  With ``rope_factor`` > 1 YaRN's blend ``(1 - m_j) * plain_j /
+    factor + m_j * plain_j``, ``m_j = 1 - clip((j - lo) / (hi - lo), 0, 1)``
+    over ``yarn_blend_range``: a pair that turns often keeps its frequency,
+    one that turns less than once is interpolated by the factor."""
+    pairs = cfg.qk_rope_dim // 2
+    plain = cfg.rope_theta ** (-np.arange(pairs, dtype=np.float64) / pairs)
+    if not cfg.rope_factor > 1:
+        return plain
+    lo, hi = yarn_blend_range(cfg)
+    m = 1 - np.clip((np.arange(pairs) - lo) / ((hi - lo) or 1e-3), 0, 1)
+    return (1 - m) * plain / cfg.rope_factor + m * plain
+
+
+def _yarn_mscale(factor, mscale):
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 and mscale \
+        else 1.0
+
+
+def yarn_softmax_scale(cfg):
+    """What the latent form's scores are multiplied by beside
+    ``head_dim^(-1/2)``: ``mscale(factor, rope_mscale_all_dim)^2``
+    (``mscale(f, m) = 0.1 m ln f + 1``), 1 without YaRN."""
+    return _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2
+
+
+def yarn_rotary_factor(cfg):
+    """What YaRN multiplies cos and sin by: ``mscale(factor, rope_mscale)
+    / mscale(factor, rope_mscale_all_dim)``."""
+    return _yarn_mscale(cfg.rope_factor, cfg.rope_mscale) \
+        / _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+
+
+def rope_pairs(x, ang, factor=1.0):
+    """Rotary positions on x [b, S, ..., d] float32 in the ADJACENT-pair
+    convention: columns (2j, 2j + 1) are pair j, rotated by ``ang`` [S, d/2]:
+    ``(x0 cos - x1 sin, x0 sin + x1 cos)``, cos and sin times ``factor``.
+    In place, by a swap of neighbours: no column leaves its lane."""
+    d = x.shape[-1]
+    shape = (1, ang.shape[0]) + (1,) * (x.ndim - 3) + (d,)
+    cos = (factor * jnp.repeat(jnp.cos(ang), 2, axis=-1)).reshape(shape)
+    sin = (factor * jnp.repeat(jnp.sin(ang), 2, axis=-1)).reshape(shape)
+    even = jnp.arange(d) % 2 == 0
+    # the other member of a column's pair, its sign as the rotation has it
+    other = jnp.where(even, -jnp.roll(x, -1, axis=-1), jnp.roll(x, 1, axis=-1))
+    return x * cos + other * sin
 
 
 @devscope.scoped(devscope.EMBED)
@@ -674,7 +826,14 @@ def _qkv(pl, h_full, cfg, rotary, first=0):
     full sequence ``h_full`` [b, S, E] (or of its rows from position
     ``first`` on), with their biases, the configured q/k norm and, where
     ``rotary``, rotary positions: what attention and power retention both
-    start from."""
+    start from.  The latent form: ``_latent_qkv``, a block of positions at a
+    time where the sequence is long (no whole-sequence float32 q stands)."""
+    if cfg.latent:
+        # the rotation and the scale work in float32 (two bf16s a value) on
+        # q and k at once
+        return _by_row_blocks(
+            lambda rows, first: _latent_qkv(pl, rows, cfg, first), h_full,
+            2 * 3 * cfg.n_heads * cfg.head_dim)
     b, S, E = h_full.shape
     hl, kvl = _local_heads(cfg)
     dh = cfg.head_dim
@@ -694,6 +853,43 @@ def _qkv(pl, h_full, cfg, rotary, first=0):
         q2 = rope(q2, hl, cfg.rope_theta, first)
         k2 = rope(k2, kvl, cfg.rope_theta, first)
     return q2, k2, v2
+
+
+def _latent_qkv(pl, h, cfg, first=0):
+    """The latent form's packed q, k and v, [b, S, H * head_dim] each, of
+    the rows ``h`` [b, S, E] at positions ``first``..: ``q = rms(h @ wq_a)
+    @ wq_b``, head i ``[q_nope_i | q_rope_i]``; ``[ckv | kr] = h @ wkv_a``,
+    ``rms(ckv) @ wkv_b`` head i ``[k_nope_i | v_i]``; ``kr`` is ONE rotary
+    key a token.  ``q_rope_i`` and ``kr`` are rotated (``rope_pairs`` at
+    ``yarn_frequencies``), ``k_i = [k_nope_i | rot(kr)]`` with the same
+    ``rot(kr)`` in every head, and the whole query head is scaled by what
+    the kernel's ``head_dim^(-1/2)`` lacks: ``yarn_softmax_scale`` and the
+    position's ``1 + q_scale_beta * ln(1 + pos // rope_original_max)``.
+    Rotation and scale in float32."""
+    b, S, _ = h.shape
+    H, dn, dr = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+    f32 = jnp.float32
+    # the latents' norms stay under the caller's scope (``_rms``)
+    q = (_rms(h @ pl["wq_a"], pl["q_a_norm"], cfg.norm_eps)
+         @ pl["wq_b"]).reshape(b, S, H, dn + dr)
+    ckv, kr = jnp.split(h @ pl["wkv_a"], [cfg.kv_lora_rank], axis=-1)
+    kv = (_rms(ckv, pl["kv_a_norm"], cfg.norm_eps)
+          @ pl["wkv_b"]).reshape(b, S, H, dn + cfg.v_head_dim)
+    pos = jnp.arange(S, dtype=f32) + first
+    ang = pos[:, None] * jnp.asarray(yarn_frequencies(cfg), f32)[None]
+    factor = yarn_rotary_factor(cfg)
+    scale = jnp.full((S,), yarn_softmax_scale(cfg), f32)
+    if cfg.q_scale_beta:
+        scale = scale * (1.0 + cfg.q_scale_beta * jnp.log1p(
+            jnp.floor(pos / cfg.rope_original_max)))
+    q = q.astype(f32)
+    q = jnp.concatenate([q[..., :dn], rope_pairs(q[..., dn:], ang, factor)],
+                        axis=-1) * scale[None, :, None, None]
+    kr = rope_pairs(kr.astype(f32)[:, :, None, :], ang, factor)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+        kr.astype(h.dtype), (b, S, H, dr))], axis=-1)
+    return (q.astype(h.dtype).reshape(b, S, -1), k.reshape(b, S, -1),
+            kv[..., dn:].reshape(b, S, -1))
 
 
 def _attention_heads_mode(pl, h_full, cfg, kind):
@@ -875,7 +1071,8 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
             x_sp = x_sp + power_retention(pl, _norm(x_sp, pl, "ln1", cfg),
                                           cfg)
     else:
-        with jax.named_scope(devscope.ATTENTION):
+        with jax.named_scope(devscope.LATENT_ATTENTION if cfg.latent
+                             else devscope.ATTENTION):
             h = _norm(x_sp, pl, "ln1", cfg)
             if heads_mode:
                 h = col.all_gather(h, TP, dim=1)
@@ -899,7 +1096,15 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
                 pl, h.reshape(-1, h.shape[-1]), cfg.experts_per_token,
                 rule=cfg.routing, act=cfg.expert_act, logits=logits,
                 first_held=cfg.first_expert, bias=router_bias)
-            return x_sp + y.reshape(h.shape), aux
+            x_sp = x_sp + y.reshape(h.shape)
+        if cfg.shared_ffn_hidden:
+            # every share of the experts computes it, and a sum over the
+            # shares counts it once: no 129th group of the grouped matmul
+            with jax.named_scope(devscope.SHARED_EXPERT):
+                x_sp = x_sp + gated_ffn(
+                    {"w_gate_up": pl["ws_gate_up"], "w_down": pl["ws_down"]},
+                    h, cfg)
+        return x_sp, aux
 
     with jax.named_scope(devscope.MLP):
         h = _norm(x_sp, pl, "ln2", cfg)
@@ -930,16 +1135,20 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
     tree for each position, stacked [L / period, ...], the leading layers
     (``prefix``, a tree each) run before the scan under the same remat, and
     ``router_bias`` [moe_layers, n] is read a period's rows a turn.  One
-    kind is the scan over layers it always was."""
+    kind is the scan over layers it always was.  The scan's own work (its
+    slices of the stacked leaves, what it keeps for the backward pass, the
+    gradients it stacks: 4 % of a step where a layer's leaves are 0.5 GB)
+    goes under the scope ``layer_scan``; a layer's under the layer's."""
     kinds = cfg.layer_kinds
     body = transformer_layer
     if cfg.remat:
         body = jax.checkpoint(body, static_argnums=(2, 3, 4))
     unroll = max(int(cfg.scan_unroll), 1)
     if len(kinds) == 1 and not cfg.per_position:
-        x_sp, aux = jax.lax.scan(
-            lambda x, pl: body(pl, x, cfg, kinds[0], False),
-            x_sp, layer_params, unroll=unroll)
+        with jax.named_scope(devscope.LAYER_SCAN):
+            x_sp, aux = jax.lax.scan(
+                lambda x, pl: body(pl, x, cfg, kinds[0], False),
+                x_sp, layer_params, unroll=unroll)
         return (x_sp, aux) if with_aux else x_sp
 
     if cfg.per_position:
@@ -965,8 +1174,9 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
             auxes.append(aux)
         return x, jax.tree.map(lambda *a: jnp.stack(a), *auxes)
 
-    x_sp, aux = jax.lax.scan(period, x_sp, (at_position, router_bias),
-                             unroll=unroll)
+    with jax.named_scope(devscope.LAYER_SCAN):
+        x_sp, aux = jax.lax.scan(period, x_sp, (at_position, router_bias),
+                                 unroll=unroll)
     aux = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), aux)
     return (x_sp, aux) if with_aux else x_sp
 

@@ -93,11 +93,23 @@ def test_classify_on_literal_op_names():
         # a custom_vjp's backward kernel keeps its call's scope
         "jit(multi)/transpose(jvp())/while/body/closed_call/checkpoint/"
         "retention/retention/power_retention_bwd": ("backward", "retention"),
+        # a scope whose name ends in another's is its own; a scope inside
+        # the MoE's is the innermost
+        "jit(multi)/transpose(jvp())/while/body/closed_call/checkpoint/"
+        "latent_attention/flash_bwd_dq": ("backward", "latent_attention"),
+        "jit(multi)/jvp()/while/body/closed_call/moe/shared_expert/"
+        "dot_general": ("forward", "shared_expert"),
+        # the scan over the layers: its own slices are its, a layer's work
+        # the layer's
+        "jit(multi)/while/body/closed_call/transpose(jvp(layer_scan))/while/"
+        "body/dynamic_update_slice": ("backward", "layer_scan"),
+        "jit(multi)/while/body/closed_call/jvp(layer_scan)/while/body/"
+        "closed_call/attention/dot_general": ("forward", "attention"),
     }
     for op_name, want in table.items():
         assert devscope.classify(op_name) == want, op_name
     assert set(devscope.PHASES) >= {w[0] for w in table.values()}
-    assert len(set(devscope.VOCABULARY)) == len(devscope.VOCABULARY) == 16
+    assert len(set(devscope.VOCABULARY)) == len(devscope.VOCABULARY) == 19
 
 
 def test_bert_program_that_ran_maps_every_scope_it_uses():

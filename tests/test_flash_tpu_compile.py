@@ -129,6 +129,7 @@ def _vmem(text, kernel):
 
 
 SMALLTHINKER, LFM2 = (1, 16384, 28, 4, 128), (2, 8192, 32, 8, 64)
+MISTRAL4 = (1, 16384, 32, 32, 128)      # every head its own key and value
 
 
 @pytest.mark.parametrize("what,shape,window,names,steps,mib", [
@@ -138,6 +139,8 @@ SMALLTHINKER, LFM2 = (1, 16384, 28, 4, 128), (2, 8192, 32, 8, 64)
      ("flash_swa_fwd", "flash_swa_bwd_fused"), 252, 40),
     ("lfm2_8b_a1b.s8192_scan, two heads a lane block", LFM2, None,
      ("flash_fwd", "flash_bwd_fused"), 136, 28),
+    ("mistral_small_4_119b.s16384_scan, the latent expanded to 32 heads",
+     MISTRAL4, None, ("flash_fwd", "flash_bwd_fused"), 528, 40),
 ])
 def test_grouped_and_windowed_kernels_compile_for_a_v5e(
         one_chip, what, shape, window, names, steps, mib):

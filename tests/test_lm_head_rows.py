@@ -429,7 +429,10 @@ def test_flash_backward_sweeps_gauges(tmp_path):
                 "monitor.kernels.flash_bwd_sweeps_" + kind)
             return None if stat is None else stat.value
 
-        assert read("windowed") is None     # no test before this set it
+        # the registry is the process's, and which files share a worker is
+        # xdist's choice: a sparse decoder's test may have set the gauges
+        mon.registry.reset(kinds=("gauge",))
+        assert read("windowed") is None
         for cell in one_kind:
             mon.registry.gauge("monitor.kernels.flash_bwd_sweeps_full").set(0)
             T.gauge_flash_grid(*cell)
