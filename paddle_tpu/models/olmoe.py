@@ -33,6 +33,7 @@ from ..parallel.transformer import (
     embed,
     final_logits_loss,
     gauge_flash_grid,
+    gauge_moe_rows,
     grad_sync_axes,
     head_logits,
     init_transformer_params,
@@ -128,8 +129,9 @@ class OlmoeTrainer(StepTrainer):
     def _observe(self, batch):
         ids = batch["ids"]
         self._count_moe(ids)
-        gauge_flash_grid(self.cfg, ids.shape[-2] // self.mesh.shape[DP],
-                         ids.shape[-1])
+        local = ids.shape[-2] // self.mesh.shape[DP]
+        gauge_flash_grid(self.cfg, local, ids.shape[-1])
+        gauge_moe_rows(self.cfg, local * ids.shape[-1])
 
     def _count_moe(self, ids):
         """Under a monitor session: the token-slots this call routes

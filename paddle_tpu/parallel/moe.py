@@ -7,7 +7,9 @@
   meets the weights of its own group only, so nothing is computed for a
   pair that was not routed), the router weight riding the hidden rows
   between them so that nothing after the down projection is kept for the
-  backward; then the sort is undone and each token's k rows are summed.
+  backward; then the sort is undone and each token's k rows are summed
+  (the Pallas row kernel ``kernels/moe_rows.py``: a block of tokens' pairs
+  that have a row, one row DMA each, summed in float32 in slot order).
   The FFN of a ``TransformerConfig`` with ``n_experts > 0`` (models/olmoe.py,
   models/smallthinker.py, models/lfm2.py).  By configuration: the routing
   rule (``RULES``: softmax over all experts and the k largest as they are;
@@ -19,9 +21,11 @@
   THIS DEVICE HOLDS (``first_held`` and the leading size of the experts'
   leaves): the router still ranks all n, the pairs whose expert is held are
   sorted to the front, only a static number of rows that covers them is
-  gathered and multiplied (``_held_capacities``; the sum back reads every
-  pair's place, a held pair's row or zero), and what the absent experts
-  would add is left out.  No pair that meets a held expert is dropped, whatever the routing.
+  gathered and multiplied (``_held_capacities``), the sum back fetches the
+  rows that exist and skips the pair slots that have none (a gather would
+  fetch a row or the zero row for every slot), and what the absent experts
+  would add is left out.  No pair that meets a held expert is dropped,
+  whatever the routing.
 - ``switch_moe_ffn``: top-1 (Switch) routing with a capacity limit that
   DROPS the overflow, experts sharded over a mesh axis (by default ``dp``,
   "EP rides DP") and exchanged with ``lax.all_to_all`` over ICI.  Net-new
@@ -42,6 +46,7 @@ from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 from . import collectives as col
 from .mesh import DP
 from ..kernels._common import on_tpu
+from ..kernels.moe_rows import moe_rows_sum
 from ..monitor import devscope
 
 __all__ = ["init_moe_params", "switch_moe_ffn", "init_dropless_moe_params",
@@ -173,29 +178,29 @@ def balance_bias(bias, load, rate):
         jnp.mean(load, axis=-1, keepdims=True) - load)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _dispatch(x, order, inv, k, absent=False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, order, inv, k):
     """Row i of the result is token ``order[i] // k``: x [T, E] gathered into
     the sorted order of its T*k assignments.  ``order`` is a permutation with
-    inverse ``inv``, so the transpose is a gather too, ``_combine``.
+    inverse ``inv``, so the transpose is ``_combine``.
 
-    With experts ``absent``, ``order`` is the first M places of the sort
-    (the held pairs come first) and ``inv`` [T*k] gives a held pair's row
-    and M for every other pair; rows past the held pairs are some token's,
-    and nothing reads what is computed from them."""
+    With experts absent, ``order`` is the first M places of the sort (the
+    held pairs come first) and ``inv`` [T*k] gives a held pair's row and M
+    for every other pair; rows past the held pairs are some token's, and
+    nothing reads what is computed from them."""
     return x[order // k]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _combine(rows, order, inv, k, absent=False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine(rows, order, inv, k):
     """Token t of the result is the float32 sum of its k rows, ``rows[inv]``
-    (the sort undone) k at a time: ``_dispatch``'s transpose, as it is its.
-    A pair whose place is past the rows (its expert is absent) adds zero."""
-    if absent:      # gathered as [T, k, E]: no copy between gather and sum
-        back = rows.at[inv.reshape(-1, k)].get(mode="fill", fill_value=0)
-    else:
-        back = rows[inv].reshape((-1, k) + rows.shape[1:])
-    return jnp.sum(back.astype(jnp.float32), axis=1).astype(rows.dtype)
+    (the sort undone) k at a time in slot order: ``_dispatch``'s transpose,
+    as it is its.  A pair whose place is past the rows (its expert is
+    absent) adds zero, and costs no fetch: the sum is the row kernel's
+    (``kernels/moe_rows.py``), which starts one row copy for each pair that
+    HAS a row, where XLA's gather fetches a row, or the zero row, for every
+    pair slot, one at a time."""
+    return moe_rows_sum(rows, inv, k, interpret=not on_tpu())
 
 
 @jax.custom_vjp
@@ -210,11 +215,9 @@ def _move(v, to, back):
 # traced on its own, in the backward pass, and names its scope itself
 _scoped = devscope.scoped(devscope.MOE)
 _dispatch.defvjp(lambda *a: (_dispatch(*a), a[1:3]),      # keeps order, inv
-                 _scoped(lambda k, absent, res, g: (
-                     _combine(g, *res, k, absent), None, None)))
+                 _scoped(lambda k, res, g: (_combine(g, *res, k), None, None)))
 _combine.defvjp(lambda *a: (_combine(*a), a[1:3]),
-                _scoped(lambda k, absent, res, g: (
-                    _dispatch(g, *res, k, absent), None, None)))
+                _scoped(lambda k, res, g: (_dispatch(g, *res, k), None, None)))
 _move.defvjp(lambda v, to, back: (_move(v, to, back), (back, to)),
              _scoped(lambda res, g: (_move(g, *res), None, None)))
 
@@ -279,9 +282,11 @@ def _held_capacities(pairs, count, n):
     (``pairs * count / n``) in whole tiles, and all ``pairs`` (T*k), so no
     routing overflows; at T*k = 98,304 and 16 of 64, (30720, 98304).  A
     step runs the first where it covers its held pairs (``lax.switch``), so
-    the gathers and the kernels' grids follow the rows held as long as the
-    routing stays within the headroom of balance, and a step past it pays
-    for every pair."""
+    the dispatch's gather and the kernels' grids follow the rows held as
+    long as the routing stays within the headroom of balance, and a step
+    past it pays them for every pair.  The sum back (``_combine``, and as
+    ``_dispatch``'s transpose) follows the rows HELD at either capacity: it
+    starts one row copy a pair that has a row, whatever the rows' count."""
     cap = -int(-HELD_HEADROOM * pairs * count / n // HELD_GRANULE) * HELD_GRANULE
     return (cap, pairs) if cap < pairs else (pairs,)
 
@@ -309,7 +314,7 @@ def _expert_ffn(x, top_p, top_e, w_gate_up, w_down, k, act, first, rows_max):
     place = (order[:rows_max], jnp.where(held, inv, rows_max)) if absent \
         else (order, inv)
 
-    rows = _dispatch(x, *place, k, absent)                       # [M, E]
+    rows = _dispatch(x, *place, k)                               # [M, E]
     weight = _move(top_p.reshape(-1), inv, order)                # [T*k]
     if absent:
         # rows past the held pairs belong to no group: the kernels leave
@@ -322,7 +327,7 @@ def _expert_ffn(x, top_p, top_e, w_gate_up, w_down, k, act, first, rows_max):
     hidden = (ACTIVATIONS[act](gate.astype(jnp.float32))
               * up.astype(jnp.float32) * weight[:, None]).astype(x.dtype)
     out = _grouped_matmul(hidden, w_down, group_sizes)
-    return _combine(out, *place, k, absent)
+    return _combine(out, *place, k)
 
 
 def _held_tier(top_e, first, count, caps):

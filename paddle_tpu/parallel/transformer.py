@@ -814,6 +814,30 @@ def gauge_flash_grid(cfg, b, S):
                 "monitor.kernels.flash_kv_blocks_skipped_" + name).set(0)
 
 
+def gauge_moe_rows(cfg, tokens):
+    """Under a monitor session, of one expert layer's call on ``tokens``
+    local tokens, whose sum back is the row kernel's
+    (``kernels/moe_rows.py``): ``monitor.kernels.moe_pair_slots`` is the
+    (token, expert) pair slots the sum back covers, T * k, which a gather
+    would fetch a row for each; ``monitor.kernels.moe_rows_fetch_bound`` the
+    rows the kernel can be asked for at the layer's first capacity
+    (``moe._held_capacities``: a step past it runs the capacity that has a
+    row for every slot; with every expert held, the slots).  Both fixed
+    when the step is traced, so gauges.  Where a share of the experts is
+    held, the rows fetched over the slots is
+    ``monitor.train.moe_rows_held`` / (MoE layers x steps x
+    ``moe_pair_slots``): the held experts' share at balanced routing."""
+    mon = monitor.active()
+    if mon is None or not cfg.n_experts:
+        return
+    from .moe import _held_capacities
+
+    slots = tokens * cfg.experts_per_token
+    mon.registry.gauge("monitor.kernels.moe_pair_slots").set(slots)
+    mon.registry.gauge("monitor.kernels.moe_rows_fetch_bound").set(
+        _held_capacities(slots, cfg.experts_here, cfg.n_experts)[0])
+
+
 def _local_heads(cfg):
     """(query heads, key/value heads) this device holds."""
     ntp = col.axis_size_in(TP)

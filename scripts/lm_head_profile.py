@@ -14,7 +14,8 @@ Builds the cell's trainer and stages its batches as the scan driver does,
 runs one dispatch under a monitor session (the scan driver opens none, so
 this is where ``monitor.train.lm_head_rows_share``, the two
 ``monitor.kernels.flash_*`` gauges and, for a sparse decoder, its
-``monitor.train.moe_*``, ``monitor.kernels.flash_kv_blocks_*`` and
+``monitor.train.moe_*``, ``monitor.kernels.moe_*``,
+``monitor.kernels.flash_kv_blocks_*`` and
 ``flash_bwd_sweeps_*`` values are read on the chip),
 traces one more, and joins the trace with THIS process's scope map
 (``monitor.devscope``; a map compiled elsewhere need not number its
@@ -106,6 +107,7 @@ def main(argv=None):
                       for g in ("pairs_per_grid_step", "grid_steps")))
         for row in mon.registry.snapshot():    # a sparse decoder's own
             if row["name"].startswith(("monitor.train.moe_",
+                                       "monitor.kernels.moe_",
                                        "monitor.train.router_",
                                        "monitor.kernels.flash_kv_blocks_",
                                        "monitor.kernels.flash_bwd_sweeps_")):

@@ -166,7 +166,12 @@ def main(out_path=None):
     note("layer.remat", jax.jit(remat), params, x, probe[:T])
     note("layer.forward", jax.jit(layer), params, x)
     keep = moe._dispatch, moe._combine
-    moe._dispatch, moe._combine = (f.fun for f in keep)   # no custom_vjp
+    # plain gathers, no custom_vjp (the shipped sum back is a Pallas kernel,
+    # which autodiff cannot transpose)
+    moe._dispatch = lambda x, order, inv, k: x[order // k]
+    moe._combine = lambda rows, order, inv, k: jnp.sum(
+        rows[inv].reshape(-1, k, rows.shape[1]).astype(jnp.float32),
+        axis=1).astype(rows.dtype)
     try:
         note("layer.scatter_backward", layer_grad(), params, x)
     finally:

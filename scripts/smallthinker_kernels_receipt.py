@@ -23,14 +23,19 @@ bf16-rounded inputs, forward and every gradient against a random cotangent:
   EXPERT's d_gate_up / d_down.  Balanced routing runs the first capacity
   (30,720 rows); with the held experts' logits raised more than 30,720
   pairs meet them and the step runs the branch that has a row for every
-  pair (``rows_held`` says which ran).
+  pair (``rows_held`` says which ran).  Both sum back through the row
+  kernel (``kernels/moe_rows.py``), forward and as the dispatch's backward.
+- ``moe.rows_sum``: that kernel alone (``moe_rows_words`` and
+  ``moe_rows_sum``) at [30,720, 2,560] rows and 98,304 pair slots of which a
+  quarter hold a row, against the float32 gather and sum, by COLUMN.
 
 Controls, so that the limit is known to stand below a fault: the same
 reference with the band one block too wide (4,608), with no band, and with
 query head h on key/value head h % 4; the MoE reference at top-5 and with
-SiLU.  It exits 1 where a sound reading is over EQUAL_TOLERANCE or a control
-under five times it; off a TPU it exits 2 (a CPU run in interpret mode proves
-nothing about Mosaic)."""
+SiLU; the sum back's with each token's last slot left out and with every
+pair's row the one after.  It exits 1 where a sound reading is over
+EQUAL_TOLERANCE or a control under five times it; off a TPU it exits 2 (a CPU
+run in interpret mode proves nothing about Mosaic)."""
 
 import functools
 import json
@@ -190,6 +195,31 @@ def moe_receipt(raise_held):
                          "silu_gate": against(act=jax.nn.silu)}}
 
 
+def rows_sum_receipt():
+    """``kernels.moe_rows.moe_rows_sum`` alone at the layer's first capacity:
+    30,720 rows of 2,560, a quarter of the 98,304 pair slots holding one."""
+    from paddle_tpu.kernels.moe_rows import moe_rows_sum
+    from paddle_tpu.parallel.moe import _held_capacities
+
+    m, held = _held_capacities(T * K, HELD, N)[0], T * K * HELD // N
+    ks = jax.random.split(jax.random.PRNGKey(34), 3)
+    rows = jax.random.normal(ks[0], (m, E), jnp.bfloat16)
+    at = jax.random.permutation(ks[1], T * K)[:held]
+    inv = jnp.full((T * K,), m, jnp.int32).at[at].set(
+        jax.random.permutation(ks[2], held).astype(jnp.int32))
+    got = jax.jit(moe_rows_sum, static_argnums=(2,))(rows, inv, K)
+
+    def against(places):
+        back = rows.astype(jnp.float32).at[places.reshape(T, K)].get(
+            mode="fill", fill_value=0)
+        return {"sum": _worst(got, jnp.sum(back, axis=1), 1)}  # by column
+
+    return {"rows_fetched": held, "sound": against(inv),
+            "controls": {
+                "last_slot_left_out": against(inv.at[K - 1::K].set(m)),
+                "the_row_after": against(jnp.where(inv < m, inv + 1, m))}}
+
+
 def main(out_path=None):
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -200,7 +230,8 @@ def main(out_path=None):
     for name, run in (("flash.window", lambda: flash_receipt(WINDOW)),
                       ("flash.full", lambda: flash_receipt(None)),
                       ("moe.balanced", lambda: moe_receipt(0.0)),
-                      ("moe.skewed", lambda: moe_receipt(1.0))):
+                      ("moe.skewed", lambda: moe_receipt(1.0)),
+                      ("moe.rows_sum", rows_sum_receipt)):
         out[name] = run()
         print(name, json.dumps(out[name]), flush=True)
     sound = max(max(out[n]["sound"].values()) for n in out if "." in n)

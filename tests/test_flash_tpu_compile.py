@@ -280,3 +280,41 @@ def test_power_retention_compiles_for_a_v5e_at_the_cell_s_shapes(one_chip,
     # the saved chunk states, and no expansion of the tokens
     assert "f32[1,8,%d,65,128,128]" % (S // chunk) in text
     assert not re.search(r"\[(?:\d+,)*16384,(?:\d+,)*(?:8320|8256)", text)
+
+
+@pytest.mark.parametrize("what,slots,k,width,m", [
+    ("smallthinker_21b_a3b.s16384_scan", 98304, 6, 2560, 30720),
+    ("lfm2_8b_a1b.s8192_scan", 65536, 4, 2048, 20480),
+    ("mistral_small_4_119b.s16384_scan", 65536, 4, 4096, 5120),
+    ("olmoe_1b_7b.s4096_scan, every expert held", 131072, 8, 2048, 131072),
+])
+def test_the_moe_row_kernel_compiles_for_a_v5e(one_chip, what, slots, k,
+                                               width, m):
+    """``moe_rows_sum`` at one layer's shapes of the three cells that hold a
+    share of their experts and of the one that holds them all (pair slots
+    T*k, k, E, the first capacity's rows M; bf16): the row DMAs from an HBM array whose rows lie contiguous (the
+    words ``moe_rows_words`` writes, handed over as they lie: a bitcast, no
+    copy between the kernels), the strided reads of the fetched rows and the
+    bf16 tiles of the result are what Mosaic has to take.  A grid step holds
+    256 tokens: the buffer of fetched rows and the result's two blocks
+    within the VMEM the call asks for, and in scalar memory that block's
+    pairs alone (two lists of 256 * k, twice for the pipeline, and the
+    blocks' counts), not the layer's."""
+    mr = importlib.import_module("paddle_tpu.kernels.moe_rows")
+    rows = jax.ShapeDtypeStruct((m, width), jnp.bfloat16, sharding=one_chip)
+    inv = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    text = jax.jit(lambda r, i: mr.moe_rows_sum(r, i, k, interpret=False)
+                   ).lower(rows, inv).compile().as_text()
+    tb, blocks = mr.token_block(k, width // 2), slots // k // 256
+    assert tb == 256 and text.count("tpu_custom_call") == 2, what
+    asked, took = _vmem(text, "moe_rows_sum")
+    assert asked == mr.vmem_bytes(k, tb, width // 2) <= 18 * 2 ** 20, what
+    assert (k * tb + 1) * width * 2 + 2 * tb * width * 2 <= took < asked, what
+    call, = [l for l in text.splitlines() if " custom-call(" in l
+             and re.search(r"%?moe_rows_sum[\w.\-]* = ", l)]
+    lists = "s32[%d,1,%d]" % (blocks, tb * k)
+    assert call.count(lists + "{2,1,0}") == 2, what
+    assert "s32[%d]" % slots not in call, what
+    assert 2 * 2 * tb * k * 4 + blocks * 4 < 64 * 2 ** 10, what
+    assert re.search(r"u32\[%d,1,%d\]\S* bitcast\(\S*moe_rows_words"
+                     % (m, width // 2), text), what

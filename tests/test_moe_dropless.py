@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu.kernels import moe_rows
 from paddle_tpu.parallel import moe, rules
 
 N, E, F = 8, 16, 8
@@ -99,14 +100,28 @@ def _skewed_sort(tokens, k):
     return order, jnp.argsort(order).astype(jnp.int32)
 
 
+def _absent(order, inv, k, tokens):
+    """``_expert_ffn``'s places where the last experts' pairs have no row:
+    the sort's first M places, and M for a pair past them (M no multiple of
+    8, and fewer than the pairs)."""
+    m = tokens * k * 5 // 8 + 1
+    return order[:m], jnp.where(inv < m, inv, m), m
+
+
+@pytest.mark.parametrize("absent", (False, True), ids=("all_held", "absent"))
 @pytest.mark.parametrize("k", (1, 2, 8))
 @pytest.mark.parametrize("which", ("dispatch", "combine"))
-def test_dispatch_and_combine_are_each_other_s_transpose(which, k):
+def test_dispatch_and_combine_are_each_other_s_transpose(which, k, absent):
     """Each one's result and ``vjp`` equal the plain gather / un-sort and
     sum written without ``custom_vjp`` and differentiated by autodiff (which
-    scatters): so the backward of one IS the other."""
+    scatters): so the backward of one IS the other.  The sum back, and so
+    the dispatch's backward, is the row kernel's (``kernels/moe_rows.py``),
+    with every pair's row there and with experts ``absent``."""
     tokens = 11
     order, inv = _skewed_sort(tokens, k)
+    m = tokens * k
+    if absent:
+        order, inv, m = _absent(order, inv, k, tokens)
     ks = jax.random.split(jax.random.PRNGKey(k), 2)
     if which == "dispatch":
         fn = lambda a: moe._dispatch(a, order, inv, k)
@@ -114,18 +129,71 @@ def test_dispatch_and_combine_are_each_other_s_transpose(which, k):
         a = jax.random.normal(ks[0], (tokens, E), jnp.float32)
     else:
         fn = lambda a: moe._combine(a, order, inv, k)
-        plain = lambda a: jnp.sum(a[inv].reshape(tokens, k, E), axis=1)
-        a = jax.random.normal(ks[0], (tokens * k, E), jnp.float32)
+        plain = lambda a: jnp.sum(
+            a.at[inv].get(mode="fill", fill_value=0).reshape(tokens, k, E),
+            axis=1)
+        a = jax.random.normal(ks[0], (m, E), jnp.float32)
     got, got_vjp = jax.vjp(fn, a)
     want, want_vjp = jax.vjp(plain, a)
     np.testing.assert_allclose(got, want, **TOL)
     g = jax.random.normal(ks[1], want.shape, jnp.float32)
     np.testing.assert_allclose(got_vjp(g)[0], want_vjp(g)[0], **TOL)
-    # and of a vector, as the router weights ride: k = 1 is a permutation
-    v = g[:tokens, 0]
-    np.testing.assert_allclose(
-        jax.grad(lambda v: jnp.sum(moe._dispatch(v, order, inv, k) ** 2))(v),
-        jax.grad(lambda v: jnp.sum(v[order // k] ** 2))(v), **TOL)
+
+
+def _gather_and_sum(rows, inv, k, interpret=None):
+    """The sum back as it was before the row kernel, over every pair slot."""
+    back = rows.at[inv.reshape(-1, k)].get(mode="fill", fill_value=0)
+    return jnp.sum(back.astype(jnp.float32), axis=1).astype(rows.dtype)
+
+
+def _places(tokens, k, m, share, seed):
+    """``inv`` [tokens * k] of a routing that holds a row for ``share`` of
+    the pair slots (at most m): the rows [0, held) in a random order at
+    random slots, m at the others."""
+    rng = np.random.RandomState(seed)
+    held = min(int(round(tokens * k * share)), m)
+    inv = np.full(tokens * k, m, np.int32)
+    inv[rng.choice(tokens * k, held, replace=False)] = rng.permutation(held)
+    return jnp.asarray(inv), held
+
+
+@pytest.mark.parametrize("share", (0.0, 1 / 16, 1 / 4, 1.0),
+                         ids=("none", "a_sixteenth", "a_quarter", "all"))
+@pytest.mark.parametrize("k", (1, 4, 6, 8))
+def test_the_row_kernel_is_the_gather_and_sum_bit_for_bit(k, share):
+    """bfloat16 rows as the cells run them: 275 tokens (a block of 256 and
+    a part of one), M no multiple of 8 (but where every slot holds a row:
+    then M is the slots), 512 columns (two registers of words
+    a row, so the strided reads and the packed halves both count)."""
+    tokens, width = 275, 512
+    m = tokens * k if share == 1.0 else tokens * k // 3 + 4
+    inv, held = _places(tokens, k, m, share, seed=k)
+    assert (m % 8 or share == 1.0) and tokens % moe_rows.token_block(
+        k, width // 2)
+    assert held == (m if share == 1.0 else round(tokens * k * share))
+    rows = jax.random.normal(jax.random.PRNGKey(k), (m, width),
+                             jnp.float32).astype(jnp.bfloat16)
+    got = moe_rows.moe_rows_sum(rows, inv, k)
+    want = _gather_and_sum(rows, inv, k)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(
+        np.asarray(got).view(np.uint16), np.asarray(want).view(np.uint16))
+
+
+@pytest.mark.parametrize("what,tokens,k,width,share", [
+    ("a row narrower than a register", 40, 3, 24, 0.5),
+    ("whole registers", 19, 2, 256, 0.25),
+    ("minus zero alone sums to plus zero", 16, 1, 16, 1.0),
+], ids=lambda v: v.replace(" ", "_") if isinstance(v, str) else None)
+def test_the_row_kernel_on_float32_rows(what, tokens, k, width, share):
+    m = tokens * k if share == 1.0 else tokens * k // 2 + 1
+    inv, _ = _places(tokens, k, m, share, seed=tokens)
+    rows = jax.random.normal(jax.random.PRNGKey(tokens), (m, width))
+    rows = rows.at[0].set(-0.0)
+    got = moe_rows.moe_rows_sum(rows, inv, k)
+    np.testing.assert_array_equal(
+        np.asarray(got).view(np.uint32),
+        np.asarray(_gather_and_sum(rows, inv, k)).view(np.uint32))
 
 
 def test_a_vector_moves_by_a_sort_as_it_would_by_a_gather():
@@ -423,6 +491,54 @@ def test_both_capacities_give_the_same_part(small_granule):
         np.testing.assert_allclose(got, want, **TOL)
 
 
+@pytest.mark.parametrize("tier", (0, 1), ids=("first_capacity", "every_pair"))
+def test_the_layer_through_the_row_kernel_is_the_gather_s(small_granule,
+                                                          monkeypatch, tier):
+    """Output and all four gradients of ``_expert_ffn`` at each capacity,
+    with the sum back by the row kernel and by the gather over every pair
+    slot that it replaced: the same bits."""
+    params, x, k = _case("non_uniform_router")
+    first, count = 2, 2
+    p = _share(params, first, count)
+    top_p, top_e, _ = moe.route_top_k(p["router"], x, k, moe.TOP_K_SOFTMAX)
+    cap = moe._held_capacities(top_e.size, count, N)[tier]
+    probe = jax.random.normal(jax.random.PRNGKey(4), x.shape)
+
+    def run():
+        def ffn(x, top_p, w_gate_up, w_down):
+            return moe._expert_ffn(x, top_p, top_e, w_gate_up, w_down, k,
+                                   "relu", first, cap)
+        out, vjp = jax.vjp(ffn, x, top_p, p["we_gate_up"], p["we_down"])
+        return (out,) + vjp(probe)
+
+    got = run()
+    monkeypatch.setattr(moe, "moe_rows_sum", _gather_and_sum)
+    for g, w in zip(got, run()):
+        assert np.abs(np.asarray(w)).max() > 0
+        np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("name", ("top_2", "top_8_of_8",
+                                  "odd_number_of_assignments"))
+def test_the_all_held_layer_through_the_row_kernel_is_the_gather_s(
+        monkeypatch, name):
+    """Every expert on the device (OLMoE): the layer sums back through the
+    same kernel (it gained 1.6 % there on the chip, PR 40), and its output
+    and gradients are those of ``rows[inv]`` summed k at a time."""
+    params, x, k = _case(name)
+    run = lambda: jax.value_and_grad(
+        lambda p, x: jnp.sum(jnp.sin(moe.dropless_moe_ffn(p, x, k)[0])),
+        argnums=(0, 1))(params, x)
+    assert "moe_rows_sum" in str(jax.make_jaxpr(
+        lambda p, x: moe.dropless_moe_ffn(p, x, k)[0])(params, x))
+    got = run()
+    monkeypatch.setattr(
+        moe, "moe_rows_sum", lambda rows, inv, k, interpret=None: jnp.sum(
+            rows[inv].reshape((-1, k) + rows.shape[1:]), axis=1))
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(run())):
+        np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-6)
+
+
 def test_the_shares_parts_add_up_to_the_whole_layer():
     params, x, k = _case("non_uniform_router")
     whole = _dense_share(params, x, k, 0, N, moe.TOP_K_SOFTMAX, "relu")
@@ -475,3 +591,36 @@ def test_a_share_under_remat_keeps_nothing_of_the_expert_ffn(small_granule):
         argnums=(0, 1)))(p, x)
     assert _count(grad.jaxpr, "gmm") == len(caps) * (2 + 0 + 4)
     assert _count(grad.jaxpr, "tgmm") == len(caps) * 2
+
+
+@pytest.mark.parametrize("model,held,n,k", [
+    ("smallthinker", 2, 8, 2), ("lfm2", 2, 8, 2), ("mistral4", 2, 8, 2),
+    ("olmoe", 8, 8, 2)])
+def test_the_row_kernel_s_gauges_follow_the_share_held(tmp_path, model, held,
+                                                       n, k):
+    """``monitor.kernels.moe_pair_slots`` and ``moe_rows_fetch_bound`` of
+    each sparse decoder's tiny configuration: set under a monitor session,
+    from the function the layer takes its capacities from (with every
+    expert held the rows are the slots), and nothing off the monitor."""
+    import importlib
+
+    from paddle_tpu import monitor
+    from paddle_tpu.parallel import transformer as T
+
+    module = importlib.import_module("paddle_tpu.models." + model)
+    cfg = getattr(module, model + "_tiny_config")()
+    assert (cfg.experts_here, cfg.n_experts, cfg.experts_per_token) == (
+        held, n, k)
+    T.gauge_moe_rows(cfg, 4096)                  # off: nothing is touched
+    mon = monitor.enable(str(tmp_path), flight=False)
+    try:
+        gauges = [mon.registry.gauge("monitor.kernels." + name)
+                  for name in ("moe_pair_slots", "moe_rows_fetch_bound")]
+        for gauge in gauges:
+            gauge.set(-1)
+        T.gauge_moe_rows(cfg, 4096)
+        got = [gauge.value for gauge in gauges]
+    finally:
+        monitor.disable()
+    # 1.25 x the quarter of 8,192 slots that balance brings, in 512-row tiles
+    assert got == [8192, 2560 if held < n else 8192]

@@ -346,6 +346,12 @@ def test_counters_and_gauges_only_under_a_monitor_session(tmp_path):
         share = reg.gauge("monitor.train.moe_held_rows_share").value
         np.testing.assert_allclose(share, got / pairs)
         assert 0.1 < share < 0.5                # 2 of 8 experts held
+        # what the sum back's row kernel is sized by: a layer's pair slots,
+        # and the rows of its first capacity (at this size one 512-row tile
+        # would pass the slots, so they are the only capacity)
+        assert reg.gauge("monitor.kernels.moe_pair_slots").value == B * S * 2
+        assert reg.gauge("monitor.kernels.moe_rows_fetch_bound").value == \
+            moe._held_capacities(B * S * 2, 2, 8)[0] == B * S * 2
         # the flash kernels' grids by layer kind: S = 64 in 16-blocks, a
         # window of 24 visits 2 or 3 kv blocks a q block; the grid is the
         # table of visited blocks, so it skips none
