@@ -53,13 +53,22 @@ Two more modes of the packed entry, both of the same kernels:
   a lane block is one head and the index maps alone address it.  At 64 a
   lane block is two heads: a group is a whole number of query blocks, so
   both heads of a query block read ONE key/value head, which is one HALF
-  of a key/value lane block; the index maps bring the block and the kernel
-  takes the half (``_Geom.kv_half``: a select, a thousandth of a step's
-  work).  The backward runs once per KEY/VALUE lane block and its table
-  walks the query blocks that read it, so dk and dv are summed over the
-  group in the kernel's accumulators, each query block into the half it
-  read.  One (row, head-block) pair a grid row whatever S is, and the
-  several-block backward even at one block.
+  of a key/value lane block (``_Geom.kv_half``); the index maps bring the
+  block.  The several-block kernels then take no half at all: at a q
+  sweep's first step the two heads are stacked along rows into scratch,
+  each moved to the lanes of that half with zeros in the others
+  (``_stack_heads``: q; in the backward do, lse and delta too), and every
+  step is ONE [2 * bq, bk] tile against the WHOLE k and v lane blocks: one
+  score matmul (the zeros drop the other head's keys from the
+  contraction), one softmax pass with a statistic a stacked row, one matmul
+  a product; dk and dv contract over both heads' rows and land in the
+  half's lanes of the accumulators (zeros beside them), and the sweep's
+  last step puts the two heads side by side again (``_unstack_heads``).
+  Only the one-block forward still selects the half of k and v a head.
+  The backward runs once per KEY/VALUE lane block and its table walks the
+  query blocks that read it, so dk and dv are summed over the group in the
+  kernel's accumulators.  One (row, head-block) pair a grid row whatever S
+  is, and the several-block backward even at one block.
 - a sliding window (``window`` = W < S, causal): query i sees keys j with
   i - W < j <= i.  The sweeps' tables hold the BAND (at most 9 kv blocks of
   512 a q block for W = 4096, not S / 512), and the kernels carry names of
@@ -300,6 +309,15 @@ def packed_bwd_sweeps(S, n_heads, head_dim, block_k, itemsize=2,
                       n_heads // (n_kv_heads or n_heads))
 
 
+def packed_heads_stacked(n_heads, head_dim, n_kv_heads=None):
+    """The query heads that one step of ``flash_attention_packed``'s
+    several-block kernels computes as ONE tile, stacked along rows
+    (``_Geom.halves``): the heads of a lane block where they all read one
+    key/value head (head width 64 and grouped queries: 2), else 1."""
+    grouped = n_kv_heads not in (None, n_heads)
+    return _heads_per_block(head_dim) if grouped else 1
+
+
 class _Geom:
     """Grid/block geometry for the two layouts.  H=None: [BH, S, D]
     separate-heads.  H=int: packed [B, S, H*D] — per-head column slices are
@@ -330,7 +348,8 @@ class _Geom:
         assert self.group == 1 or (H == Hkv * self.group
                                    and self.group % self.hpb == 0
                                    and Hkv % self.hpb == 0)
-        # a key/value lane block holds hpb heads and a query block reads one
+        # a key/value lane block holds hpb heads and a query block reads one:
+        # its heads ride the several-block sweeps stacked (``_stack_heads``)
         self.halves = self.hpb if self.group > 1 else 1
         self.qw = self.D * self.hpb   # width of one head-block (lane dim)
         self.G, self.Hg, self.grid_b = grid_geometry(
@@ -443,23 +462,98 @@ def _kv_cols(block, hh, D, half, halves):
     return cols
 
 
-def _seen(shape, q0, k0, window):
+def _stack_heads(block, hpb, D, half):
+    """[rows, hpb * D] -> [hpb * rows, hpb * D]: the heads of a query lane
+    block one under the other, each moved to the columns of the ONE
+    key/value head they all read (``half``, a traced scalar) and zeros in the
+    other columns.  A product of the stack with the whole key/value lane
+    block then contracts over that head alone, and one that lands on the
+    key/value rows (dk, dv) lands in that head's columns, summed over the
+    stacked heads, zeros beside them: no half is taken of k or v."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
+    into = (lane >= half * D) & (lane < (half + 1) * D)
+    wide = block.astype(jnp.float32)            # lanes rotate 32 bits wide
+    heads = []
+    for hh in range(hpb):
+        moved = wide                            # half == hh: where it lies
+        for at in range(hpb):
+            if at != hh:
+                moved = jnp.where(
+                    half == at,
+                    pltpu.roll(wide, (at - hh) % hpb * D, 1), moved)
+        heads.append(jnp.where(into, moved, 0.0).astype(block.dtype))
+    return jnp.concatenate(heads, axis=0)
+
+
+def _unstack_heads(stacked, hpb, D, half):
+    """[hpb * rows, hpb * D] -> [rows, hpb * D]: the stacked heads side by
+    side again, of each the columns of the key/value head it read."""
+    rows = stacked.shape[0] // hpb
+    return _cat([_kv_cols(stacked[hh * rows:(hh + 1) * rows], hh, D, half, hpb)
+                 for hh in range(hpb)])
+
+
+def _stack_stat(stat, hpb):
+    """[rows, hpb] -> [hpb * rows, LANES]: a row statistic of the stacked
+    heads, a head's column under the other's, replicated along lanes (the
+    layout ``m_scr`` has: no relayout against a score tile)."""
+    return jnp.concatenate(
+        [jnp.broadcast_to(stat[:, hh:hh + 1], (stat.shape[0], LANES))
+         for hh in range(hpb)], axis=0)
+
+
+def _stacked(q_ref, do_ref, lse_ref, delta_ref, hpb, D, half):
+    """(q, do, lse, delta) of a backward step's q block, stacked."""
+    return (_stack_heads(q_ref[0], hpb, D, half),
+            _stack_heads(do_ref[0], hpb, D, half),
+            _stack_stat(lse_ref[0, 0], hpb), _stack_stat(delta_ref[0, 0], hpb))
+
+
+def _stack_sweep(stk, *refs):
+    """A backward q sweep's first step: ``_stacked`` into the scratch."""
+    for scr, value in zip(stk, _stacked(*refs)):
+        scr[:] = value
+
+
+def _bwd_tiles(tile, stk, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               hpb, bq, bk):
+    """A backward step's tiles, ``tile(q, k, v, do, lse, delta, cs, wrap)``:
+    ONE over the stack ``stk`` (q, do, lse, delta: ``_stack_sweep``'s scratch
+    or ``_stacked``'s values) against the whole k and v lane blocks, or, with
+    no stack, a head at a time on its own columns ``cs``.  lse and delta go
+    over as thunks, so ``tile`` reads them where it uses them."""
+    if stk:
+        q, do, lse, delta = stk
+        tile(q[:], k_ref[0], v_ref[0], do[:], lambda: _lanes_to(lse[:], bk),
+             lambda: _lanes_to(delta[:], bk), slice(None), wrap=bq)
+        return
+    D = q_ref.shape[-1] // hpb
+    for hh in range(hpb):
+        cs = slice(hh * D, (hh + 1) * D)
+        tile(q_ref[0][:, cs], k_ref[0][:, cs], v_ref[0][:, cs],
+             do_ref[0][:, cs], lambda: lse_ref[0, 0][:, hh:hh + 1],
+             lambda: delta_ref[0, 0][:, hh:hh + 1], cs)
+
+
+def _seen(shape, q0, k0, window, wrap=None):
     """[bq, bk] bool: key position <= query position, and inside the window
-    (query - key < window) where there is one."""
-    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    (query - key < window) where there is one.  ``wrap``: the rows are
+    several heads' query blocks of ``wrap`` rows, one under the other."""
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    qpos = q0 + (row if wrap is None else jax.lax.rem(row, wrap))
     kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     if window is None:
         return qpos >= kpos
     return (qpos >= kpos) & (qpos - kpos < window)
 
 
-def _scores(q, k, scale, causal, q0, k0, window=None):
-    """[bq, bk] f32 scaled scores of one head, future positions (and those
-    behind the window) masked."""
+def _scores(q, k, scale, causal, q0, k0, window=None, wrap=None):
+    """[bq, bk] f32 scaled scores of one head (of ``_stack_heads``' rows:
+    ``wrap``), future positions (and those behind the window) masked."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if causal:
-        s = jnp.where(_seen(s.shape, q0, k0, window), s, NEG_INF)
+        s = jnp.where(_seen(s.shape, q0, k0, window, wrap), s, NEG_INF)
     return s
 
 
@@ -510,11 +604,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq,
 
 
 def _fwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, o_ref,
-                      lse_ref, m_scr, l_scr, acc_scr, *, scale, causal, bq, bk,
-                      hpb, geom):
+                      lse_ref, m_scr, l_scr, acc_scr, *q_stk, scale, causal,
+                      bq, bk, hpb, geom):
     """Several kv blocks, one (row, head-block) pair a grid row and one
     (q block, kv block) pair a step (``step_table``): running max,
-    denominator and accumulator in scratch across a q block's sweep."""
+    denominator and accumulator in scratch across a q block's sweep.
+
+    Where the heads of the lane block read one key/value head
+    (``geom.halves`` > 1) they ride the sweep stacked along rows: q is
+    restacked once, at the sweep's first step (``_stack_heads`` into
+    ``q_stk``), a step is ONE [hpb * bq, bk] tile against the whole k and v
+    lane blocks, the statistics a row of the stack each, and the last step
+    puts the heads side by side again."""
     D = q_ref.shape[-1] // hpb
     t, q_block = geom.step()
     half = geom.kv_half(q_block)
@@ -524,33 +625,46 @@ def _fwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, o_ref,
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
+        if q_stk:
+            q_stk[0][:] = _stack_heads(q_ref[0], hpb, D, half)
 
-    for hh in range(hpb):
-        cs = slice(hh * D, (hh + 1) * D)
-        ls = slice(hh * LANES, (hh + 1) * LANES)
-        s = _scores(q_ref[0][:, cs],
-                    _kv_cols(k_ref[0], hh, D, half, geom.halves),
-                    scale, causal, q_of[t] * bq, kv_of[t] * bk,
-                    geom.window)                       # [bq, bk]
+    def tile(q, cs, ls, wrap=None):
+        """The rows of q against columns ``cs`` of this kv block."""
+        s = _scores(q, k_ref[0][:, cs], scale, causal, q_of[t] * bq,
+                    kv_of[t] * bk, geom.window, wrap)  # [rows, bk]
 
-        m_prev = m_scr[:, ls]                          # [bq, LANES]
-        m_cur = jnp.max(s, axis=1)[:, None]            # [bq, 1]
-        m_new = jnp.maximum(m_prev, m_cur)             # [bq, LANES]
-        p = jnp.exp(s - _lanes_to(m_new, bk))          # [bq, bk] f32
-        alpha = jnp.exp(m_prev - m_new)                # [bq, LANES]
+        m_prev = m_scr[:, ls]                          # [rows, LANES]
+        m_cur = jnp.max(s, axis=1)[:, None]            # [rows, 1]
+        m_new = jnp.maximum(m_prev, m_cur)             # [rows, LANES]
+        p = jnp.exp(s - _lanes_to(m_new, bk))          # [rows, bk] f32
+        alpha = jnp.exp(m_prev - m_new)                # [rows, LANES]
         l_scr[:, ls] = l_scr[:, ls] * alpha + jnp.sum(p, axis=1)[:, None]
-        acc_scr[:, cs] = acc_scr[:, cs] * _lanes_to(alpha, D) \
+        acc_scr[:, cs] = acc_scr[:, cs] * _lanes_to(alpha, q.shape[1]) \
             + jax.lax.dot_general(
-                p.astype(v_ref.dtype),
-                _kv_cols(v_ref[0], hh, D, half, geom.halves),
+                p.astype(v_ref.dtype), v_ref[0][:, cs],
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
         m_scr[:, ls] = m_new
 
+    if q_stk:
+        tile(q_stk[0][:], slice(None), slice(None), wrap=bq)
+    else:
+        for hh in range(hpb):
+            cs = slice(hh * D, (hh + 1) * D)
+            tile(q_ref[0][:, cs], cs, slice(hh * LANES, (hh + 1) * LANES))
+
     @pl.when((flags[t] & LAST) != 0)
     def _final():
         l = jnp.maximum(l_scr[:], 1e-30)
+        if q_stk:
+            o_ref[0] = _unstack_heads(
+                acc_scr[:] / _lanes_to(l, hpb * D), hpb, D, half
+            ).astype(o_ref.dtype)
+            lse = m_scr[:, :1] + jnp.log(l[:, :1])     # [hpb * bq, 1]
+            lse_ref[0, 0] = jnp.concatenate(
+                [lse[hh * bq:(hh + 1) * bq] for hh in range(hpb)], axis=1)
+            return
         alpha_cols = jnp.concatenate(
             [_lanes_to(l[:, hh * LANES:(hh + 1) * LANES], D)
              for hh in range(hpb)], axis=1) if hpb > 1 else _lanes_to(l, D)
@@ -598,15 +712,18 @@ def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None, Hkv=None,
     ]
     if not g.one_block:
         qm, km, sm = g.sweep_maps()
+        # statistics: a head a group of lanes, or a row of the stack
+        rows, lanes = g.halves * bq, g.hpb // g.halves * LANES
         return _sweep_call(
             functools.partial(_fwd_sweep_kernel, scale=scale, causal=causal,
                               bq=bq, bk=bk, hpb=g.hpb, geom=g),
             g, step_table(g.S, g.Sk, bq, bk, causal, window), (q, k, v),
             [g.q_spec(bq, qm), g.kv_spec(bk, km), g.kv_spec(bk, km)],
             [g.q_spec(bq, qm), g.stat_spec(bq, sm)], out_shape,
-            [pltpu.VMEM((bq, g.hpb * LANES), jnp.float32),
-             pltpu.VMEM((bq, g.hpb * LANES), jnp.float32),
-             pltpu.VMEM((bq, g.qw), jnp.float32)],
+            [pltpu.VMEM((rows, lanes), jnp.float32),
+             pltpu.VMEM((rows, lanes), jnp.float32),
+             pltpu.VMEM((rows, g.qw), jnp.float32)]
+            + [pltpu.VMEM((rows, g.qw), q.dtype)] * (g.halves > 1),
             False, interpret, "fwd")
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                bq=bq, hpb=g.hpb, G=g.G, Hg=g.Hg, geom=g)
@@ -742,8 +859,10 @@ def _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=None,
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
-                   lse_ref, delta_ref, dq_ref, acc_scr, *, scale, causal, bq,
-                   bk, geom, hpb=1):
+                   lse_ref, delta_ref, dq_ref, acc_scr, *stk, scale, causal,
+                   bq, bk, geom, hpb=1):
+    """The q-major sweep of the two: dq of a q block over its kv blocks
+    (``stk``: as ``_bwd_sweep_kernel``)."""
     t, q_block = geom.step()
     D = q_ref.shape[-1] // hpb
     half = geom.kv_half(q_block)
@@ -751,43 +870,37 @@ def _bwd_dq_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
     @pl.when((flags[t] & FIRST) != 0)
     def _init():
         acc_scr[:] = jnp.zeros_like(acc_scr)
+        if stk:
+            _stack_sweep(stk, q_ref, do_ref, lse_ref, delta_ref, hpb, D, half)
 
-    for hh in range(hpb):
-        cs = slice(hh * D, (hh + 1) * D)
-        q = q_ref[0][:, cs]
-        k = _kv_cols(k_ref[0], hh, D, half, geom.halves)
-        v = _kv_cols(v_ref[0], hh, D, half, geom.halves)
+    def tile(q, k, v, do, lse, delta, cs, wrap=None):
         s = _scores(q, k, scale, causal, q_of[t] * bq, kv_of[t] * bk,
-                    geom.window)
-        p = jnp.exp(s - lse_ref[0, 0][:, hh:hh + 1])   # [bq, bk]
-        dov = jax.lax.dot_general(do_ref[0][:, cs], v,
+                    geom.window, wrap)
+        p = jnp.exp(s - lse())                         # [rows, bk]
+        dov = jax.lax.dot_general(do, v,
                                   (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-        ds = p * (dov - delta_ref[0, 0][:, hh:hh + 1]) * scale  # [bq, bk] f32
+        ds = p * (dov - delta()) * scale               # [rows, bk] f32
         acc_scr[:, cs] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
+    _bwd_tiles(tile, stk, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               hpb, bq, bk)
+
     @pl.when((flags[t] & LAST) != 0)
     def _final():
-        dq_ref[0] = acc_scr[:].astype(dq_ref.dtype)
-
-
-def _kv_add(acc, rows, hh, D, half, halves, part):
-    """``part`` [bk, D] of query head ``hh`` into its key/value head's
-    columns of ``acc`` at ``rows``: its own, or the half the block read."""
-    if half is None:
-        acc[rows, hh * D:(hh + 1) * D] += part
-        return
-    for at in range(halves):
-        @pl.when(half == at)
-        def _into():
-            acc[rows, at * D:(at + 1) * D] += part
+        dq = acc_scr[:]
+        dq_ref[0] = (_unstack_heads(dq, hpb, D, half) if stk else dq
+                     ).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
                     scale, causal, bq, bk, geom, hpb=1):
+    """The kv-major sweep of the two: dk and dv of a kv block over the q
+    blocks that see it.  Every step meets another q block, so where the
+    heads ride stacked (``geom.halves`` > 1) a step stacks its own."""
     t, q_block = geom.step(head_of)    # q blocks innermost here (of each
     D = q_ref.shape[-1] // hpb         # of the group's query heads in turn)
     half = geom.kv_half(q_block)
@@ -797,28 +910,28 @@ def _bwd_dkv_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    for hh in range(hpb):
-        cs = slice(hh * D, (hh + 1) * D)
-        q = q_ref[0][:, cs]
-        k = _kv_cols(k_ref[0], hh, D, half, geom.halves)
-        v = _kv_cols(v_ref[0], hh, D, half, geom.halves)
-        do = do_ref[0][:, cs]
+    def tile(q, k, v, do, lse, delta, cs, wrap=None):
         s = _scores(q, k, scale, causal, q_of[t] * bq, kv_of[t] * bk,
-                    geom.window)
-        p = jnp.exp(s - lse_ref[0, 0][:, hh:hh + 1])   # [bq, bk]
+                    geom.window, wrap)
+        p = jnp.exp(s - lse())                         # [rows, bk]
         # dv_j += p^T dO
-        _kv_add(dv_scr, slice(None), hh, D, half, geom.halves,
-                jax.lax.dot_general(
-                    p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32))
+        dv = jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dv_scr[:, cs] += dv
         dov = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-        ds = p * (dov - delta_ref[0, 0][:, hh:hh + 1]) * scale
+        ds = p * (dov - delta()) * scale
         # dk_j += ds^T q
-        _kv_add(dk_scr, slice(None), hh, D, half, geom.halves,
-                jax.lax.dot_general(
-                    ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32))
+        dk = jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_scr[:, cs] += dk
+
+    stk = _stacked(q_ref, do_ref, lse_ref, delta_ref, hpb, D,
+                   half) if geom.halves > 1 else ()
+    _bwd_tiles(tile, stk, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               hpb, bq, bk)
 
     @pl.when((flags[t] & LAST) != 0)
     def _final():
@@ -828,7 +941,8 @@ def _bwd_dkv_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
 
 def _bwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
                       lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_scr,
-                      dk_acc, dv_acc, *, scale, causal, bq, bk, geom, hpb=1):
+                      dk_acc, dv_acc, *stk, scale, causal, bq, bk, geom,
+                      hpb=1):
     """Several blocks, ONE sweep: a grid row is a (batch row, key/value
     head-block) pair and its steps walk the group's query head-blocks, of
     each its q blocks, of each its visible kv blocks (``step_table``,
@@ -836,7 +950,14 @@ def _bwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
     it all three products: dq into the q sweep's scratch, dk and dv into
     rows ``kv block`` of two float32 accumulators that hold the whole
     sequence and are the key/value head's own, so they sum over the group;
-    both leave once, at the grid row's last step."""
+    both leave once, at the grid row's last step.
+
+    Where the heads of the lane block read one key/value head
+    (``geom.halves`` > 1) they ride the q sweep stacked along rows
+    (``stk``: q, do, lse and delta restacked at its first step): ONE
+    [hpb * bq, bk] tile a step against the whole k and v lane blocks, dk and
+    dv contracted over both heads' rows into the accumulators' full width
+    (``_stack_heads``: zeros beside the half), dq unstacked at the last."""
     t, q_block = geom.step(head_of)
     D = q_ref.shape[-1] // hpb
     half = geom.kv_half(q_block)
@@ -861,35 +982,39 @@ def _bwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
     @pl.when((flags[t] & FIRST) != 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
+        if stk:
+            _stack_sweep(stk, q_ref, do_ref, lse_ref, delta_ref, hpb, D, half)
 
-    for hh in range(hpb):
-        cs = slice(hh * D, (hh + 1) * D)
-        q = q_ref[0][:, cs]
-        k = _kv_cols(k_ref[0], hh, D, half, geom.halves)
-        v = _kv_cols(v_ref[0], hh, D, half, geom.halves)
-        do = do_ref[0][:, cs]
+    def tile(q, k, v, do, lse, delta, cs, wrap=None):
+        """The rows of q and do against columns ``cs`` of this kv block."""
         s = _scores(q, k, scale, causal, q_of[t] * bq, kv_of[t] * bk,
-                    geom.window)
-        p = jnp.exp(s - lse_ref[0, 0][:, hh:hh + 1])   # [bq, bk] - the ONE exp
+                    geom.window, wrap)
+        p = jnp.exp(s - lse())                     # [rows, bk] - the ONE exp
         # dv_j += p^T dO
-        _kv_add(dv_acc, rows, hh, D, half, geom.halves, jax.lax.dot_general(
+        dv = jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32))
+            preferred_element_type=jnp.float32)
+        dv_acc[rows, cs] += dv
         dov = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-        ds = (p * (dov - delta_ref[0, 0][:, hh:hh + 1]) * scale
-              ).astype(q.dtype)                        # [bq, bk]
+        ds = (p * (dov - delta()) * scale).astype(q.dtype)     # [rows, bk]
         dq_scr[:, cs] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         # dk_j += ds^T q
-        _kv_add(dk_acc, rows, hh, D, half, geom.halves, jax.lax.dot_general(
+        dk = jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32))
+            preferred_element_type=jnp.float32)
+        dk_acc[rows, cs] += dk
+
+    _bwd_tiles(tile, stk, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               hpb, bq, bk)
 
     @pl.when((flags[t] & LAST) != 0)
     def _final():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        dq = dq_scr[:]
+        dq_ref[0] = (_unstack_heads(dq, hpb, D, half) if stk else dq
+                     ).astype(dq_ref.dtype)
 
     @pl.when(t == pl.num_programs(2) - 1)
     def _close():
@@ -920,6 +1045,13 @@ def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
     dkv_shapes = [jax.ShapeDtypeStruct(g.dkv_shape, k.dtype),
                   jax.ShapeDtypeStruct(g.dkv_shape, v.dtype)]
 
+    stack = g.halves * bq       # rows of a step's tile, and what a q sweep
+    stacked = [                 # keeps of its q block where it is a stack
+        pltpu.VMEM((stack, g.qw), q.dtype),
+        pltpu.VMEM((stack, g.qw), do.dtype),
+        pltpu.VMEM((stack, LANES), jnp.float32),            # lse, delta
+        pltpu.VMEM((stack, LANES), jnp.float32)] * (g.halves > 1)
+
     def sweep(kernel, name, out_specs, out_shape, scratch_shapes,
               walks_group=False, kv_major=False, **params):
         qm, km, sm = g.sweep_maps(walks_group)
@@ -944,14 +1076,14 @@ def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
         return sweep(
             _bwd_sweep_kernel, "bwd_fused",
             lambda qs, ks: [qs, whole, whole], [dq_shape] + dkv_shapes,
-            [pltpu.VMEM((bq, g.qw), jnp.float32),
+            [pltpu.VMEM((stack, g.qw), jnp.float32),
              pltpu.VMEM((g.Sk, g.qw), jnp.float32),
-             pltpu.VMEM((g.Sk, g.qw), jnp.float32)],
+             pltpu.VMEM((g.Sk, g.qw), jnp.float32)] + stacked,
             walks_group=True,
             vmem_limit_bytes=fused_sweep_vmem_bytes(g.Sk, g.qw,
                                                     k.dtype.itemsize))
     dq = sweep(_bwd_dq_kernel, "bwd_dq", lambda qs, ks: qs, dq_shape,
-               [pltpu.VMEM((bq, g.qw), jnp.float32)])
+               [pltpu.VMEM((stack, g.qw), jnp.float32)] + stacked)
     # the dk/dv sweep's rows run over the key/value heads
     dk, dv = sweep(_bwd_dkv_kernel, "bwd_dkv", lambda qs, ks: [ks, ks],
                    dkv_shapes,

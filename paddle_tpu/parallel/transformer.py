@@ -768,7 +768,11 @@ def gauge_flash_grid(cfg, b, S):
     take their grid from.  ``monitor.kernels.flash_pairs_per_grid_step`` is
     the (batch row, head-block) pairs a step computes, 1 where every pair is
     a step of its own; ``monitor.kernels.flash_grid_steps`` the steps of one
-    layer's forward pass (of a full layer's where the kinds differ).  A stack
+    layer's forward pass (of a full layer's where the kinds differ);
+    ``monitor.kernels.flash_heads_stacked`` the query heads a kv step of the
+    several-block kernels computes as one tile, stacked along rows (2 where
+    the two heads of a 64-wide lane block read one key/value head, else 1:
+    ``flash_attention.packed_heads_stacked``).  A stack
     of several layer kinds also says, by kind (``_full``, ``_windowed``), the
     (q block, kv block) steps of one head's forward sweep:
     ``monitor.kernels.flash_kv_blocks_visited_*`` those that hold a
@@ -789,13 +793,15 @@ def gauge_flash_grid(cfg, b, S):
     if blocks is None:
         return
     from ..kernels.flash_attention import (kv_blocks, packed_bwd_sweeps,
-                                           packed_grid)
+                                           packed_grid, packed_heads_stacked)
 
     pairs, steps = packed_grid(b, S, hl, cfg.head_dim, *blocks,
                                itemsize=cfg.jdtype.itemsize, n_kv_heads=kvl,
                                causal=cfg.causal)
     mon.registry.gauge("monitor.kernels.flash_pairs_per_grid_step").set(pairs)
     mon.registry.gauge("monitor.kernels.flash_grid_steps").set(steps)
+    mon.registry.gauge("monitor.kernels.flash_heads_stacked").set(
+        packed_heads_stacked(hl, cfg.head_dim, kvl))
     # a window changes the table, not what VMEM holds: one answer a stack
     sweeps = packed_bwd_sweeps(S, hl, cfg.head_dim, blocks[1],
                                itemsize=cfg.jdtype.itemsize, n_kv_heads=kvl)

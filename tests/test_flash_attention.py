@@ -133,6 +133,7 @@ GROUPED = [
     ("packed", 3, 384, 2, 128, (1, 2)),
     ("packed", 8, 128, 2, 128, (2, 2)),
     ("packed", 1, 384, 2, 64, (1, 1)),     # nothing to group: falls back to 1, 1
+    ("packed", 4, 128, 8, 64, (2, 2)),     # width 64, every head its own k/v
     ("bshd", 3, 128, 4, 64, (4, 1)),       # G consecutive rows of [B*H, S, D]
     ("bshd", 8, 256, 2, 64, (2, 1)),
     ("bshd", 1, 384, 2, 128, (2, 1)),
@@ -344,6 +345,15 @@ MODES = [
     ("halves, eight kv blocks",   1, 512, 8,  2, 64,  64,  64,  None, True),
     ("halves, window of a block", 1, 512, 8,  2, 64,  64,  64,  64,   True),
     ("halves, window 1, bq < bk", 1, 256, 4,  2, 64,  64,  128, 1,    True),
+    # the two heads of a lane block stacked along rows (PR 41): a group of 2
+    # is one query block a key/value head (block 0 reads half 0 of the
+    # key/value block, block 1 half 1), a group of 4 two
+    ("stacked, group 2, causal",  2, 256, 4,  2, 64,  64,  64,  None, True),
+    ("stacked, group 2, window",  1, 512, 4,  2, 64,  64,  64,  100,  True),
+    ("stacked, group 2, bq > bk", 1, 256, 4,  2, 64,  128, 64,  None, True),
+    ("stacked, group 4, both ways", 1, 256, 8, 2, 64, 64,  128, None, False),
+    ("stacked, group 4, window, bq > bk", 1, 512, 8, 2, 64, 128, 64, 72, True),
+    ("stacked, 16 on 4 heads",    1, 256, 16, 4, 64,  64,  64,  None, True),
 ]
 
 
@@ -421,6 +431,11 @@ ONE_SWEEP = [
     ("halves, two query blocks",   "packed", 1, 512, 512, 8, 2, 64,  64,  64,  None, True),
     ("halves, band, bq < bk",      "packed", 1, 256, 256, 4, 2, 64,  64,  128, 72,   True),
     ("halves, rectangle",          "packed", 1, 256, 256, 8, 2, 64,  128, 64,  None, False),
+    ("stacked, group 2, triangle", "packed", 2, 256, 256, 4, 2, 64,  64,  64,  None, True),
+    ("stacked, group 2, band",     "packed", 1, 512, 512, 4, 2, 64,  64,  64,  100,  True),
+    ("stacked, group 4, band, bq > bk", "packed", 1, 512, 512, 8, 2, 64, 128, 64, 72, True),
+    ("stacked, group 2, S != Sk",  "packed", 1, 256, 512, 4, 2, 64,  64,  128, None, False),
+    ("stacked, group 4 at one block", "packed", 1, 128, 128, 8, 2, 64, 128, 128, None, True),
     ("group 3 at one block",       "packed", 2, 128, 128, 6, 2, 128, 128, 128, None, True),
     ("[BH, S, 64], triangle",      "bshd",   2, 256, 256, 2, 2, 64,  64,  128, None, True),
     ("[BH, S, 128], rectangle",    "bshd",   1, 128, 256, 3, 3, 128, 64,  64,  None, False),
@@ -585,6 +600,54 @@ def test_step_table(S, Sk, bq, bk, causal, window, group, kv_major):
         assert not one[2].any() and one.shape[1] * group == len(steps)
         np.testing.assert_array_equal(
             np.tile(one[[0, 1, 3]], group), np.stack([qs, ks, flags]))
+
+
+#   what                              H   Hkv D    S    block stacked
+STACKED = [
+    ("lfm2's heads, several blocks",   32, 8,  64,  256, 64,   2),
+    ("a group of 2 at width 64",       4,  2,  64,  256, 64,   2),
+    ("grouped at 64, one block",       8,  2,  64,  128, 128,  2),
+    ("bert's heads, several blocks",   4,  4,  64,  256, 64,   1),
+    ("bert's heads, one block",        4,  4,  64,  128, 128,  1),
+    ("smallthinker's: a head a block", 7,  1,  128, 256, 64,   1),
+    ("olmoe's: ungrouped at 128",      2,  2,  128, 256, 64,   1),
+]
+
+
+@pytest.mark.parametrize("what,H,Hkv,D,S,block,stacked", STACKED,
+                         ids=[m[0] for m in STACKED])
+def test_heads_ride_stacked_exactly_where_a_lane_block_reads_one_kv_head(
+        monkeypatch, what, H, Hkv, D, S, block, stacked):
+    """The score tiles of a traced forward and backward: [hpb * bq, 128]
+    against the whole key/value lane block where ``_Geom.halves`` > 1 (two
+    heads a lane block AND grouped queries), a head's own [bq, D] everywhere
+    else, and ``_stack_heads`` met for q (forward) and for q and do
+    (backward) or not at all; what the gauge's function says from the
+    shapes.  One block and grouped: the forward is the one-block kernel,
+    which stacks nothing, the backward the sweep."""
+    tiles, stacks = set(), []
+    scores, stack = fa._scores, fa._stack_heads
+    monkeypatch.setattr(fa, "_scores", lambda q, k, *a: (
+        tiles.add((q.shape, k.shape, a[5] if len(a) > 5 else None)),
+        scores(q, k, *a))[1])
+    monkeypatch.setattr(fa, "_stack_heads", lambda block, *a: (
+        stacks.append(block.shape), stack(block, *a))[1])
+    q, k, v, w = _packed_qkv(24, 1, S, H, Hkv, D)
+    jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(fa.flash_attention_packed(
+        *a, H, causal=True, block_q=block, block_k=block, n_kv_heads=Hkv) * w),
+        argnums=(0, 1, 2)))(q, k, v)
+    g = fa._Geom(q, k, H, block, block, Hkv=Hkv)
+    assert fa.packed_heads_stacked(H, D, Hkv) == g.halves == stacked, what
+    lanes = max(D, 128)
+    if stacked == 1:
+        assert not stacks and tiles == {((block, D), (block, D), None)}, what
+        return
+    several = {((stacked * block, lanes), (block, lanes), block)}
+    if S == block:      # the one-block forward takes the half of k and v
+        assert tiles == several | {((block, D), (block, D), None)}, what
+        assert stacks == [(block, lanes)] * 2, what
+    else:
+        assert tiles == several and stacks == [(block, lanes)] * 3, what
 
 
 def test_a_block_nobody_sees_is_refused():

@@ -68,6 +68,7 @@ def test_forward_and_backward_compile_for_a_v5e(one_chip, what, entry, B, S,
         asked, took = _vmem(text, "flash_bwd_fused")
         assert asked == fa.fused_sweep_vmem_bytes(S, 128, 2) == 22 * 2 ** 20
         assert 6 * 2 ** 20 < took < asked, what
+        assert mosaic == SWEEP_MOSAIC[what], what
     else:           # one block: the kernels PR 28 left, to the letter
         assert set(grids) == {"flash_fwd", "flash_bwd_fused"}, what
         assert _vmem(text, "flash_bwd_fused")[0] is None, what
@@ -85,6 +86,21 @@ ONE_BLOCK_MOSAIC = {
     "fine-tuning at 384": ["a2edfc8f7477", "4de8b028195f"],
     "a prime batch, causal": ["f7cb281f682b", "40b5c4ca2615"],
     "heads the packed layout cannot tile": ["72dc7b47f49c", "85a570491d08"],
+}
+
+
+# The several-block kernels at a head a lane block (width 128), forward and
+# backward, as PR 41's parent (c1b744a) lowers them: stacking the heads of a
+# 64-wide lane block (LFM2's row, not pinned) left every other cell's module
+# as it was.  Replaced like ``ONE_BLOCK_MOSAIC``.
+SWEEP_MOSAIC = {
+    "olmoe_1b_7b.s4096_scan": ["05626943d473", "eba4a626459f"],
+    "smallthinker_21b_a3b.s16384_scan, a full layer":
+        ["55126c1bd654", "170f86a03873"],
+    "smallthinker_21b_a3b.s16384_scan, a windowed layer":
+        ["985173cce04a", "42134345da2e"],
+    "mistral_small_4_119b.s16384_scan, the latent expanded to 32 heads":
+        ["e267681a781d", "ad2a365dd0c8"],
 }
 
 
@@ -148,12 +164,15 @@ def test_grouped_and_windowed_kernels_compile_for_a_v5e(
     the index maps' reads of the scalar-prefetched step table and the
     backward's one sweep over a group's heads, dk and dv of the whole
     sequence in two float32 accumulators (16 MiB of the 40 the call asks
-    for), are what Mosaic has to take; at 32 on 8 heads of 64, the select of
-    a key/value block's half by a traced scalar and the dk/dv sums into that
-    half of the accumulators' rows as well.  The grids are the tables: (row,
-    key/value head-block, query head-block of its group) by the blocks under
-    the diagonal (in the band), the backward's (row, key/value head-block)
-    by the group's times as many."""
+    for), are what Mosaic has to take; at 32 on 8 heads of 64, the two heads
+    of a lane block stacked along rows (PR 41): the lane rotation that moves
+    a head to its key/value head's columns, the [1024, 512] tiles of a step
+    and the stack's scratch (q, do, lse, delta, dq: 18.8 MiB where the
+    parent took 16.7 of the 28 asked for; the forward 6.4 MiB where 4.1).
+    The width-128 modules are the parent's (``SWEEP_MOSAIC``).  The grids
+    are the tables: (row, key/value head-block, query head-block of its
+    group) by the blocks under the diagonal (in the band), the backward's
+    (row, key/value head-block) by the group's times as many."""
     B, S, H, Hkv, D = shape
     xq = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
     xk = jax.ShapeDtypeStruct((B, S, Hkv * D), jnp.bfloat16, sharding=one_chip)
@@ -161,9 +180,12 @@ def test_grouped_and_windowed_kernels_compile_for_a_v5e(
         q, k, v, H, causal=True, block_q=512, block_k=512, interpret=False,
         n_kv_heads=Hkv, window=window)
 
-    text, grids, _ = _compiled(attn, xq, xk, xk, xq)
+    text, grids, mosaic = _compiled(attn, xq, xk, xk, xq)
     for name in names:
         assert name in text, (what, name)
+    stacked = fa.packed_heads_stacked(H, D, Hkv)
+    assert stacked == (2 if shape is LFM2 else 1)
+    assert stacked > 1 or mosaic == SWEEP_MOSAIC[what], what
     kv_blocks, group = Hkv * D // 128, H // Hkv
     assert fa.kv_blocks(S, 512, 512, True, window) == steps
     assert grids == dict(zip(names, [(B, kv_blocks, group, steps),
@@ -171,8 +193,11 @@ def test_grouped_and_windowed_kernels_compile_for_a_v5e(
     asked, took = _vmem(text, names[1])
     assert asked == fa.fused_sweep_vmem_bytes(S, 128, 2) == mib * 2 ** 20
     # the accumulators and the single-buffered output blocks, and a step's
-    # own blocks and tiles beside them
-    assert S * 128 * (4 + 2) * 2 < took < asked, what
+    # own blocks and tiles beside them: [stacked * 512, 512] float32, five
+    # of them live at the most, and the stack's scratch
+    least = S * 128 * (4 + 2) * 2 + (stacked - 1) * 6 * 2 ** 20
+    assert least < took < asked, what
+    assert (_vmem(text, names[0])[1] > 6 * 2 ** 20) == (stacked > 1), what
 
 
 def test_a_sequence_past_the_rule_compiles_as_two_sweeps(one_chip):

@@ -359,11 +359,14 @@ def test_rows_gauge_and_counter_only_under_a_monitor_session(tmp_path):
 def test_flash_grid_gauges_only_under_a_monitor_session(tmp_path):
     """``monitor.kernels.flash_pairs_per_grid_step`` and ``flash_grid_steps``
     are what ``kernels.flash_attention.packed_grid`` says of the call's
-    shapes, the function the kernels take their grid from."""
+    shapes, the function the kernels take their grid from;
+    ``flash_heads_stacked`` the heads one kv step computes as one tile: 2
+    where the two heads of a 64-wide lane block read one key/value head
+    (LFM2's 32 on 8), 1 in every other cell."""
     import dataclasses
 
     from paddle_tpu.kernels.flash_attention import packed_grid
-    from paddle_tpu.models import olmoe
+    from paddle_tpu.models import lfm2, olmoe, smallthinker
 
     # heads the packed layout can tile (two of 64), so the kernel runs
     cfg = bert.bert_tiny_config(hidden=128, n_heads=2)
@@ -377,9 +380,11 @@ def test_flash_grid_gauges_only_under_a_monitor_session(tmp_path):
     try:
         pairs = mon.registry.gauge("monitor.kernels.flash_pairs_per_grid_step")
         steps = mon.registry.gauge("monitor.kernels.flash_grid_steps")
+        stacked = mon.registry.gauge("monitor.kernels.flash_heads_stacked")
         tr.step(batch, 1e-3)
         assert (pairs.value, steps.value) == packed_grid(
             B, S, 2, 64, 32, 32, itemsize=4) == (8, 1)
+        assert stacked.value == 1
         # two dp shards of four rows each
         bert.BertTrainer._observe(
             dataclasses.replace(tr, mesh=MeshSpec(dp=2).build(
@@ -387,17 +392,24 @@ def test_flash_grid_gauges_only_under_a_monitor_session(tmp_path):
         assert (pairs.value, steps.value) == (4, 1)
         # the cells' shapes, by configuration alone
         base = bert.bert_base_config()
-        for c, b, s, want in [
-                (base, 256, 128, (6, 256)),     # bert_base.s128_scan
-                (base, 64, 512, (1, 384)),      # bert_base.s512_scan, _dp4
-                (dataclasses.replace(base, tp=2), 256, 128, (6, 128)),
+        for c, b, s, want, heads in [
+                (base, 256, 128, (6, 256), 1),  # bert_base.s128_scan
+                (base, 64, 512, (1, 384), 1),   # bert_base.s512_scan, _dp4
+                (dataclasses.replace(base, tp=2), 256, 128, (6, 128), 1),
                 # the causal triangle: 36 of 8 x 8 blocks a (row, head)
-                (olmoe.olmoe_1b_7b_config(), 4, 4096, (1, 4 * 16 * 36))]:
+                (olmoe.olmoe_1b_7b_config(), 4, 4096, (1, 4 * 16 * 36), 1),
+                # 28 on 4 heads of 128: a lane block is one head
+                (smallthinker.smallthinker_21b_a3b_config(), 1, 16384,
+                 (1, 28 * 528), 1),
+                # 32 on 8 heads of 64: the two heads of a lane block stacked
+                (lfm2.lfm2_8b_a1b_config(), 2, 8192, (1, 2 * 16 * 136), 2)]:
+            stacked.set(0)
             T.gauge_flash_grid(c, b, s)
-            assert (pairs.value, steps.value) == want
+            assert (pairs.value, steps.value, stacked.value) == want + (heads,)
         # heads the packed layout cannot tile take another path: left alone
         T.gauge_flash_grid(bert.bert_tiny_config(), B, S)
-        assert (pairs.value, steps.value) == (1, 4 * 16 * 36)
+        assert (pairs.value, steps.value, stacked.value) == (
+            1, 2 * 16 * 136, 2)
     finally:
         monitor.disable()
 

@@ -506,15 +506,18 @@ def test_the_new_scopes_hold_their_instructions_and_attention_none():
 # options, off, leave as it was, byte for byte.  A PR that changes one of
 # these programs on purpose takes the digests anew: PR 40 did for the three
 # sparse decoders, whose expert layers sum back through the row kernel
-# (``kernels/moe_rows.py``); BERT's and Brumby's are still 03fc114's.
+# (``kernels/moe_rows.py``), and PR 41 for LFM2 alone, whose grouped heads of
+# 64 ride the flash sweeps stacked (``kernels/flash_attention.py``: OLMoE's
+# and SmallThinker's held through it); BERT's and Brumby's are still
+# 03fc114's.
 PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "bert.run_steps": "00de5403506fdc87",
             "olmoe.step": "231114fcd62341f2",
             "olmoe.run_steps": "054338e92270f130",
             "smallthinker.step": "f20200134b1ebe0f",
             "smallthinker.run_steps": "504a2d6d86c4dda7",
-            "lfm2.step": "783d3222af566a46",
-            "lfm2.run_steps": "e94c674f34733e78",
+            "lfm2.step": "d97a02ce8b817454",
+            "lfm2.run_steps": "9c116ad10b0f2cd7",
             "brumby.step": "be3328df1ffdda0a",
             "brumby.run_steps": "07985218bf230094"}
 OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
