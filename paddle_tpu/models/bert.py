@@ -27,13 +27,12 @@ from ..parallel import collectives as col
 from ..parallel.mesh import DP, PP, TP, MeshSpec
 from ..parallel.pipeline import gpipe, split_microbatches
 from ..parallel import optim
-from ..parallel.train import (StepTrainer, TrainState, make_train_step,
-                              shard_pytree, state_specs)
+from ..parallel.train import (StepTrainer, TrainState, gauge_flash_grid,
+                              make_train_step, shard_pytree, state_specs)
 from ..parallel.transformer import (
     TransformerConfig,
     embed,
     final_logits_loss,
-    gauge_flash_grid,
     grad_sync_axes,
     head_rows_computed,
     init_transformer_params,

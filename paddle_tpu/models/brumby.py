@@ -14,7 +14,8 @@ which is how it runs (``kernels/power_retention.py``, chunk by chunk).
 Nothing here is a second block: it is ``parallel/transformer.py``'s, by
 configuration (``layer_pattern`` of one RETENTION position,
 ``dense_ffn_hidden`` without experts, ``qk_norm="head"``, ``n_kv_heads``,
-``tie_head``); loss, step and builder are ``models/olmoe.py``'s.
+``tie_head``); forward, loss, trainer and builder are
+``parallel/decoder.py``'s.
 
 A chip may hold its SHARE of a layer: a slice of the vocabulary (a smaller
 vocabulary: ids, logits and loss are over the slice).  Retention and the FFN
@@ -24,23 +25,12 @@ batch dict: ``ids`` int32 [B, S] alone; the loss is next-token cross
 entropy and nothing else.
 """
 
-import dataclasses
 import functools
 
-import jax
-import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from ..parallel import decoder
+from ..parallel.transformer import RETENTION, TransformerConfig
 
-from .. import monitor
-from ..kernels.power_retention import STATE_COLUMNS, state_sweeps
-from ..parallel.mesh import DP, local_shard_map
-from ..parallel.transformer import (RETENTION, TransformerConfig, embed,
-                                    retention_log_decay, rms_norm)
-from . import olmoe
-
-__all__ = ["brumby_14b_config", "brumby_tiny_config", "BrumbyTrainer",
-           "build_brumby_trainer", "retention_chunks", "retention_state_mb",
-           "retention_state_sweeps"]
+__all__ = ["brumby_14b_config", "brumby_tiny_config", "build_brumby_trainer"]
 
 
 def brumby_14b_config(n_layers=40, vocab_size=151936, **kw):
@@ -71,66 +61,5 @@ def brumby_tiny_config(**kw):
         retention_chunk=16), **kw))
 
 
-def retention_chunks(cfg, seq):
-    """Chunks a layer walks over a sequence of ``seq`` tokens."""
-    return seq // min(cfg.retention_chunk, seq)
-
-
-def retention_state_mb(cfg):
-    """The state one layer carries along a sequence, in MB: a float32
-    ``STATE_COLUMNS`` x head width a key/value head."""
-    return cfg.kv_heads * STATE_COLUMNS * cfg.head_dim * 4 / 1e6
-
-
-def retention_state_sweeps(cfg, seq):
-    """Sweeps of the state's 65 tiles a layer's forward runs over a sequence
-    of ``seq`` tokens, as the kernels' own rule has it: one a key/value
-    head and chunk where a group's query heads ride one grid step, one a
-    query head and chunk where they would not fit the kernels' VMEM."""
-    return state_sweeps(cfg.n_heads, cfg.kv_heads, seq,
-                        min(cfg.retention_chunk, seq),
-                        jnp.dtype(cfg.dtype).itemsize)
-
-
-@dataclasses.dataclass
-class BrumbyTrainer(olmoe.OlmoeTrainer):
-    label = "brumby"
-    _gate_fn = None
-
-    def _observe(self, batch):
-        """Under a monitor session: ``monitor.train.retention_chunks``
-        (chunks a layer and sequence), ``monitor.train.retention_state_mb``
-        (the state a layer carries), ``monitor.train.retention_state_sweeps``
-        (``retention_state_sweeps``) and ``monitor.train.retention_gate_mean``
-        (the mean ``e^g`` over tokens and heads of the call's first batch in
-        layer 0, at the weights the call starts from: a state decays to 1/e
-        in ``1 / (1 - mean)`` tokens or so).  Off the monitor nothing
-        runs."""
-        mon = monitor.active()
-        if mon is None:
-            return
-        cfg, ids = self.cfg, batch["ids"]
-        mon.registry.gauge("monitor.train.retention_chunks").set(
-            retention_chunks(cfg, ids.shape[-1]))
-        mon.registry.gauge("monitor.train.retention_state_mb").set(
-            retention_state_mb(cfg))
-        mon.registry.gauge("monitor.train.retention_state_sweeps").set(
-            retention_state_sweeps(cfg, ids.shape[-1]))
-        if self._gate_fn is None:
-            def gate_mean(params, ids):
-                pl = jax.tree.map(lambda a: a[0],
-                                  params["params_layers"]["p0"])
-                h = rms_norm(embed(params, ids, cfg), pl["ln1_scale"],
-                             cfg.norm_eps)
-                return jnp.mean(jnp.exp(retention_log_decay(pl, h)))
-
-            self._gate_fn = jax.jit(local_shard_map(
-                gate_mean, self.mesh, in_specs=(self.specs["params"], P(DP)),
-                out_specs=P()))
-        first = ids.reshape((-1,) + ids.shape[-2:])[0]
-        mon.registry.gauge("monitor.train.retention_gate_mean").set(
-            float(self._gate_fn(self.state["params"], first)))
-
-
 build_brumby_trainer = functools.partial(
-    olmoe.build_olmoe_trainer, trainer=BrumbyTrainer)
+    decoder.build_decoder_trainer, label="brumby")

@@ -17,8 +17,8 @@ Nothing here is a second block: it is ``parallel/transformer.py``'s, by
 configuration (``layer_pattern`` with CONV positions, ``prefix_pattern`` /
 ``dense_ffn_hidden``, ``qk_norm="head"``, ``n_kv_heads``, ``routing``,
 ``experts_held``, ``tie_head``), on the flash kernels' grouped mode at two
-heads a lane block and ``parallel/moe.py``'s ``dropless_moe_ffn``; loss,
-trainer and builder are ``models/olmoe.py``'s.
+heads a lane block and ``parallel/moe.py``'s ``dropless_moe_ffn``; forward,
+loss, trainer and builder are ``parallel/decoder.py``'s.
 
 A chip may hold its SHARE of a layer, as in ``models/smallthinker.py``:
 ``experts_held`` of the 32 experts from ``first_expert`` and a slice of the
@@ -30,16 +30,13 @@ batch dict: ``ids`` int32 [B, S] alone; the loss is next-token cross
 entropy and nothing else (no auxiliary loss: the bias balances).
 """
 
-import dataclasses
 import functools
 
-from .. import monitor
-from ..parallel import moe
+from ..parallel import decoder, moe
 from ..parallel.transformer import CONV, TransformerConfig
-from . import olmoe, smallthinker
 
-__all__ = ["lfm2_8b_a1b_config", "lfm2_tiny_config", "Lfm2Trainer",
-           "build_lfm2_trainer", "LAYER_TYPES", "layer_kinds"]
+__all__ = ["lfm2_8b_a1b_config", "lfm2_tiny_config", "build_lfm2_trainer",
+           "LAYER_TYPES", "layer_kinds"]
 
 # the published ``layer_types``, 24 entries
 LAYER_TYPES = tuple(
@@ -100,20 +97,5 @@ def lfm2_tiny_config(**kw):
         dtype="float32", flash_block_q=16, flash_block_k=16), **kw))
 
 
-@dataclasses.dataclass
-class Lfm2Trainer(smallthinker.SmallThinkerTrainer):
-    label = "lfm2"
-
-    def _count_moe(self, ids):
-        """SmallThinker's counters and gauges (the MoE layers alone carry
-        them), and ``monitor.train.router_bias_abs_max``: the largest
-        selection bias, any layer, as the call starts."""
-        super()._count_moe(ids)
-        mon = monitor.active()
-        if mon is not None:
-            mon.registry.gauge("monitor.train.router_bias_abs_max").set(
-                float(abs(self.state["params"]["router_bias"]).max()))
-
-
 build_lfm2_trainer = functools.partial(
-    olmoe.build_olmoe_trainer, trainer=Lfm2Trainer)
+    decoder.build_decoder_trainer, label="lfm2")

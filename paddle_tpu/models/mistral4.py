@@ -20,8 +20,8 @@ Nothing here is a second block: it is ``parallel/transformer.py``'s, by
 configuration (``kv_lora_rank`` and the other latent sizes, the ``rope_*``
 keys, ``q_scale_beta``, ``shared_ffn_hidden``, ``routing``,
 ``experts_held``), on the flash kernels' packed causal mode and
-``parallel/moe.py``'s ``dropless_moe_ffn``; loss, trainer and builder are
-``models/olmoe.py``'s.
+``parallel/moe.py``'s ``dropless_moe_ffn``; forward, loss, trainer and
+builder are ``parallel/decoder.py``'s.
 
 A chip may hold its SHARE of a layer, as in ``models/smallthinker.py``:
 ``experts_held`` of the 128 routed experts from ``first_expert`` and a slice
@@ -46,17 +46,13 @@ entropy and nothing else (the published configuration carries no auxiliary
 coefficient).
 """
 
-import dataclasses
 import functools
 
-from .. import monitor
-from ..parallel import moe
-from ..parallel.transformer import TransformerConfig, yarn_blend_range
-from . import olmoe, smallthinker
+from ..parallel import decoder, moe
+from ..parallel.transformer import TransformerConfig
 
 __all__ = ["mistral_small_4_config", "mistral4_tiny_config",
-           "Mistral4Trainer", "build_mistral4_trainer",
-           "interpolated_pairs", "scaled_positions"]
+           "build_mistral4_trainer"]
 
 
 def mistral_small_4_config(n_layers=36, experts_held=128, first_expert=0,
@@ -101,53 +97,5 @@ def mistral4_tiny_config(**kw):
         flash_block_k=16), **kw))
 
 
-def interpolated_pairs(cfg):
-    """(first, last) of the rotated pairs whose frequency is WHOLLY the
-    interpolated one, ``plain / rope_factor``; None without YaRN."""
-    if not cfg.rope_factor > 1:
-        return None
-    return yarn_blend_range(cfg)[1], cfg.qk_rope_dim // 2 - 1
-
-
-def scaled_positions(cfg, seq):
-    """Positions of a sequence of ``seq`` tokens whose query is scaled by
-    more than 1: those from ``rope_original_max`` on."""
-    if not cfg.q_scale_beta:
-        return 0
-    return max(seq - cfg.rope_original_max, 0)
-
-
-@dataclasses.dataclass
-class Mistral4Trainer(smallthinker.SmallThinkerTrainer):
-    label = "mistral4"
-
-    def _observe(self, batch):
-        """SmallThinker's counters and gauges (assignments, the busiest
-        expert over the mean, the rows held; the flash grid), and, all
-        fixed when the step is traced, under ``monitor.train.``:
-        ``mla_latent_bytes_per_token`` (what a layer's keys and values come
-        from: the latent and the shared rotary key) beside
-        ``mla_expanded_kv_bytes_per_token`` (what the flash kernels read:
-        every head's key and value), ``yarn_first_interpolated_pair`` /
-        ``_last_`` (``interpolated_pairs``) and ``q_scaled_positions``
-        (``scaled_positions``).  Off the monitor nothing runs."""
-        super()._observe(batch)
-        mon = monitor.active()
-        if mon is None:
-            return
-        cfg, gauge = self.cfg, mon.registry.gauge
-        itemsize = cfg.jdtype.itemsize
-        gauge("monitor.train.mla_latent_bytes_per_token").set(
-            (cfg.kv_lora_rank + cfg.qk_rope_dim) * itemsize)
-        gauge("monitor.train.mla_expanded_kv_bytes_per_token").set(
-            cfg.n_heads * (cfg.head_dim + cfg.v_head_dim) * itemsize)
-        whole = interpolated_pairs(cfg)
-        if whole:
-            gauge("monitor.train.yarn_first_interpolated_pair").set(whole[0])
-            gauge("monitor.train.yarn_last_interpolated_pair").set(whole[1])
-        gauge("monitor.train.q_scaled_positions").set(
-            scaled_positions(cfg, batch["ids"].shape[-1]))
-
-
 build_mistral4_trainer = functools.partial(
-    olmoe.build_olmoe_trainer, trainer=Mistral4Trainer)
+    decoder.build_decoder_trainer, label="mistral4")

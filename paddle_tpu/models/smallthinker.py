@@ -12,7 +12,8 @@ Nothing here is a second block: it is ``parallel/transformer.py``'s, by
 configuration (``head_width`` / ``n_kv_heads`` / ``layer_pattern`` /
 ``router_input`` / ``routing`` / ``expert_act`` / ``experts_held``), on the
 flash kernels' grouped and windowed modes and ``parallel/moe.py``'s
-``dropless_moe_ffn``; loss, trainer and builder are ``models/olmoe.py``'s.
+``dropless_moe_ffn``; forward, loss, trainer and builder are
+``parallel/decoder.py``'s.
 
 A chip may hold its SHARE of a layer, as one of the chips that divide it
 would: ``experts_held`` of the 64 experts from ``first_expert`` (the router
@@ -38,21 +39,13 @@ entropy and nothing else (the published configuration carries no auxiliary
 coefficient).
 """
 
-import dataclasses
 import functools
 
-import jax
-import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-
-from .. import monitor
-from ..parallel.mesh import DP, local_shard_map
+from ..parallel import decoder
 from ..parallel.transformer import TransformerConfig
-from . import olmoe
 
 __all__ = ["smallthinker_21b_a3b_config", "smallthinker_tiny_config",
-           "SmallThinkerTrainer", "build_smallthinker_trainer", "PERIOD",
-           "WINDOW"]
+           "build_smallthinker_trainer", "PERIOD", "WINDOW"]
 
 WINDOW = 4096
 # the published rope_layout and sliding_window_layout are this period,
@@ -92,36 +85,5 @@ def smallthinker_tiny_config(**kw):
         layer_pattern=((0, False),) + ((24, True),) * 3), **kw))
 
 
-@dataclasses.dataclass
-class SmallThinkerTrainer(olmoe.OlmoeTrainer):
-    label = "smallthinker"
-    _held_fn = None
-
-    def _count_moe(self, ids):
-        """OLMoE's counter and gauge, and for a share of the experts: the
-        (token, expert) pairs of this call that meet a held expert
-        (``monitor.train.moe_rows_held``, a counter: every batch of the call
-        at the weights the call starts from, a forward of its own that stops
-        before the head) and their share of the call's pairs
-        (``monitor.train.moe_held_rows_share``, a gauge; experts_held / 64
-        at uniform routing).  Off the monitor nothing runs."""
-        super()._count_moe(ids)
-        mon = monitor.active()
-        if mon is None:
-            return
-        cfg = self.cfg
-        if self._held_fn is None:
-            self._held_fn = jax.jit(local_shard_map(
-                lambda params, ids: jnp.sum(
-                    olmoe._forward(params, ids, cfg)[1]["rows_held"]),
-                self.mesh, in_specs=(self.specs["params"], P(DP)),
-                out_specs=P()))
-        held = sum(int(self._held_fn(self.state["params"], batch))
-                   for batch in ids.reshape((-1,) + ids.shape[-2:]))
-        mon.registry.counter("monitor.train.moe_rows_held").incr(held)
-        mon.registry.gauge("monitor.train.moe_held_rows_share").set(
-            held / (int(ids.size) * cfg.experts_per_token * cfg.moe_layers))
-
-
 build_smallthinker_trainer = functools.partial(
-    olmoe.build_olmoe_trainer, trainer=SmallThinkerTrainer)
+    decoder.build_decoder_trainer, label="smallthinker")

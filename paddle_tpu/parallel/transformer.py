@@ -32,7 +32,6 @@ from jax.sharding import PartitionSpec as P
 
 from . import collectives as col
 from .mesh import DP, PP, TP
-from .. import monitor
 from ..monitor import devscope
 from ..monitor.recompile import compile_ledger
 from .ring_attention import ring_attention
@@ -72,7 +71,6 @@ class TransformerConfig:
     remat: bool = False              # jax.checkpoint per layer (RecomputeOptimizer parity)
     tp: int = 1                      # tensor-parallel degree (mesh tp axis size)
     pp: int = 1                      # pipeline stages (mesh pp axis size)
-    use_flash: bool = True           # Pallas flash-attention kernel when shapes allow
     # Pallas kernel q/kv block sizes, clamped to S.  S = 512 is one block;
     # S = 4096 is 8 x 8 blocks, the causal sweeps' steps the 36 under the
     # diagonal.  Either way the backward is one kernel (flash_bwd_fused),
@@ -739,7 +737,7 @@ def _local_attention_dispatch(q, k, v, cfg):
     S = q.shape[1]
     bq = min(cfg.flash_block_q, S)
     bk = min(cfg.flash_block_k, S)
-    if cfg.use_flash and S % bq == 0 and k.shape[1] % bk == 0:
+    if S % bq == 0 and k.shape[1] % bk == 0:
         from ..kernels.flash_attention import flash_attention
 
         return flash_attention(q, k, v, causal=cfg.causal,
@@ -755,93 +753,10 @@ def _packed_flash_blocks(cfg, hl, S, kvl=None):
 
     bq = min(cfg.flash_block_q, S)
     bk = min(cfg.flash_block_k, S)
-    if (cfg.use_flash and S % bq == 0 and S % bk == 0
+    if (S % bq == 0 and S % bk == 0
             and packed_layout_supported(hl, cfg.head_dim, kvl)):
         return bq, bk
     return None
-
-
-def gauge_flash_grid(cfg, b, S):
-    """Under a monitor session: what one grid step of the flash kernels holds
-    for ``b`` local sequences of S positions (one kernel call: a dp shard's
-    batch, or a pipeline microbatch of it), from the function the kernels
-    take their grid from.  ``monitor.kernels.flash_pairs_per_grid_step`` is
-    the (batch row, head-block) pairs a step computes, 1 where every pair is
-    a step of its own; ``monitor.kernels.flash_grid_steps`` the steps of one
-    layer's forward pass (of a full layer's where the kinds differ);
-    ``monitor.kernels.flash_heads_stacked`` the query heads a kv step of the
-    several-block kernels computes as one tile, stacked along rows (2 where
-    the two heads of a 64-wide lane block read one key/value head, else 1:
-    ``flash_attention.packed_heads_stacked``).  A stack
-    of several layer kinds also says, by kind (``_full``, ``_windowed``), the
-    (q block, kv block) steps of one head's forward sweep:
-    ``monitor.kernels.flash_kv_blocks_visited_*`` those that hold a
-    (query, key) pair the mask lets through and so compute, and
-    ``flash_kv_blocks_skipped_*`` the steps the grid holds beyond them (0:
-    the grid is the sweep's step table).
-    ``monitor.kernels.flash_bwd_sweeps_full`` (and ``_windowed`` in a stack
-    of several kinds) is the kernels of that kind's backward: 1
-    where dq, dk and dv come off one sweep, 2 where the sequence is past
-    what VMEM holds of dk and dv (``flash_attention.bwd_sweeps``, which the
-    kernel asks).  All fixed when the step is traced, so gauges; nothing is
-    set where attention does not take the packed kernel."""
-    mon = monitor.active()
-    if mon is None or cfg.attn_mode != "heads":
-        return
-    hl, kvl = cfg.n_heads // cfg.tp, cfg.kv_heads // cfg.tp
-    blocks = _packed_flash_blocks(cfg, hl, S, kvl)
-    if blocks is None:
-        return
-    from ..kernels.flash_attention import (kv_blocks, packed_bwd_sweeps,
-                                           packed_grid, packed_heads_stacked)
-
-    pairs, steps = packed_grid(b, S, hl, cfg.head_dim, *blocks,
-                               itemsize=cfg.jdtype.itemsize, n_kv_heads=kvl,
-                               causal=cfg.causal)
-    mon.registry.gauge("monitor.kernels.flash_pairs_per_grid_step").set(pairs)
-    mon.registry.gauge("monitor.kernels.flash_grid_steps").set(steps)
-    mon.registry.gauge("monitor.kernels.flash_heads_stacked").set(
-        packed_heads_stacked(hl, cfg.head_dim, kvl))
-    # a window changes the table, not what VMEM holds: one answer a stack
-    sweeps = packed_bwd_sweeps(S, hl, cfg.head_dim, blocks[1],
-                               itemsize=cfg.jdtype.itemsize, n_kv_heads=kvl)
-    for name in ("full", "windowed") if cfg.layer_pattern else ("full",):
-        mon.registry.gauge("monitor.kernels.flash_bwd_sweeps_" + name).set(
-            sweeps)
-    if cfg.layer_pattern:
-        window = max((k[0] or 0 for k in cfg.layer_kinds if k != CONV),
-                     default=0) or None
-        for name, w in (("full", None), ("windowed", window)):
-            mon.registry.gauge(
-                "monitor.kernels.flash_kv_blocks_visited_" + name).set(
-                    kv_blocks(S, *blocks, cfg.causal, w))
-            # the grid is the table of the visited steps: it holds no other
-            mon.registry.gauge(
-                "monitor.kernels.flash_kv_blocks_skipped_" + name).set(0)
-
-
-def gauge_moe_rows(cfg, tokens):
-    """Under a monitor session, of one expert layer's call on ``tokens``
-    local tokens, whose sum back is the row kernel's
-    (``kernels/moe_rows.py``): ``monitor.kernels.moe_pair_slots`` is the
-    (token, expert) pair slots the sum back covers, T * k, which a gather
-    would fetch a row for each; ``monitor.kernels.moe_rows_fetch_bound`` the
-    rows the kernel can be asked for at the layer's first capacity
-    (``moe._held_capacities``: a step past it runs the capacity that has a
-    row for every slot; with every expert held, the slots).  Both fixed
-    when the step is traced, so gauges.  Where a share of the experts is
-    held, the rows fetched over the slots is
-    ``monitor.train.moe_rows_held`` / (MoE layers x steps x
-    ``moe_pair_slots``): the held experts' share at balanced routing."""
-    mon = monitor.active()
-    if mon is None or not cfg.n_experts:
-        return
-    from .moe import _held_capacities
-
-    slots = tokens * cfg.experts_per_token
-    mon.registry.gauge("monitor.kernels.moe_pair_slots").set(slots)
-    mon.registry.gauge("monitor.kernels.moe_rows_fetch_bound").set(
-        _held_capacities(slots, cfg.experts_here, cfg.n_experts)[0])
 
 
 def _local_heads(cfg):

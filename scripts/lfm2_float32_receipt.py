@@ -31,8 +31,7 @@ def main(seed=0, out_path=None):
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.models import olmoe
-    from paddle_tpu.parallel import moe, transformer as T
+    from paddle_tpu.parallel import decoder, moe, transformer as T
 
     # float32 rows and weights are twice the bytes: the grouped matmuls'
     # training tiles do not fit the kernel's VMEM, so this process takes
@@ -54,7 +53,7 @@ def main(seed=0, out_path=None):
 
     @jax.jit
     def logits(params, ids):
-        x, _ = olmoe._forward(params, ids, cfg)
+        x, _ = decoder.forward(params, ids, cfg)
         return T.head_logits(params, x[:, at], cfg)
 
     with jax.default_matmul_precision("highest"):

@@ -32,7 +32,7 @@ from paddle_tpu.kernels import power_retention as pr  # noqa: E402
 from paddle_tpu.models import (bert, brumby, lfm2, olmoe,  # noqa: E402
                                smallthinker)
 from paddle_tpu.monitor import devscope  # noqa: E402
-from paddle_tpu.parallel import optim, transformer as T  # noqa: E402
+from paddle_tpu.parallel import decoder, optim, transformer as T  # noqa: E402
 from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
 from paddle_tpu.parallel.train import stack_batches  # noqa: E402
 
@@ -87,7 +87,7 @@ def both():
     tr = _trainer()
     params = _seeded_params(tr)
     ids = _ids()[0]
-    loss_fn = olmoe.make_loss_fn(tr.cfg)
+    loss_fn = decoder.make_loss_fn(tr.cfg)
     got = jax.jit(jax.value_and_grad(
         lambda p: loss_fn(p, {"ids": jnp.asarray(ids)})))(params)
     want = jax.value_and_grad(
@@ -102,16 +102,16 @@ def test_the_tiny_configuration_keeps_every_mechanism():
     assert cfg.per_position and cfg.n_periods == 2 and cfg.moe_layers == 0
     assert (cfg.n_heads, cfg.kv_heads, cfg.head_dim) == (10, 2, 128)
     assert cfg.qk_norm == "head" and not cfg.tie_head and not cfg.n_experts
-    assert brumby.retention_chunks(cfg, S) == 4
+    assert decoder.retention_chunks(cfg, S) == 4
     assert pr.supported(cfg.head_dim, S, cfg.retention_chunk)
     big = brumby.brumby_14b_config()
     assert (big.n_layers, big.hidden, big.n_heads, big.kv_heads, big.head_dim,
             big.dense_ffn_hidden, big.vocab_size, big.rope_theta,
             big.norm_eps, big.retention_chunk) == (
         40, 5120, 40, 8, 128, 17408, 151936, 1e6, 1e-6, 1024)
-    assert brumby.retention_chunks(big, 16384) == 16
+    assert decoder.retention_chunks(big, 16384) == 16
     # 8 heads x 8,320 x 128 float32
-    np.testing.assert_allclose(brumby.retention_state_mb(big), 34.08, rtol=1e-3)
+    np.testing.assert_allclose(decoder.retention_state_mb(big), 34.08, rtol=1e-3)
     assert pr.STATE_COLUMNS == 8320 and pr.DIAGONALS == 65
 
 
@@ -122,7 +122,7 @@ def test_loss_equals_the_reference(both):
 
 def test_every_position_s_logits_equal_the_reference(both):
     cfg, params, ids, _, _ = both
-    x, _ = jax.jit(lambda p, i: olmoe._forward(p, i, cfg))(params, ids)
+    x, _ = jax.jit(lambda p, i: decoder.forward(p, i, cfg))(params, ids)
     got = T.rms_norm(x, params["lnf_scale"], cfg.norm_eps) @ params["lm_head"].T
     want = np.stack(reference.forward(params, ids, MODEL)[1])
     np.testing.assert_allclose(got, want, rtol=1e-4,
@@ -254,7 +254,7 @@ def test_a_group_that_does_not_fit_goes_in_parts_to_the_same_numbers(
             a, b, rtol=1e-5, atol=1e-6 * float(jnp.abs(b).max()),
             err_msg=name)
     cfg = brumby.brumby_tiny_config()
-    assert brumby.retention_state_sweeps(cfg, S) == 2 * 4 * 5
+    assert decoder.retention_state_sweeps(cfg, S) == 2 * 4 * 5
     # six heads a group go in threes where two such steps fit and six do not
     monkeypatch.setattr(pr, "VMEM_LIMIT", pr._step_vmem_bytes(3, 16, 4))
     assert pr.sweep_heads(6, 16, 4) == 3
@@ -330,7 +330,7 @@ def test_the_eight_vocabulary_slices_logits_are_the_uncut_model_s_columns():
 
     def logits(cfg):
         return jax.jit(lambda p: T.head_logits(
-            p, olmoe._forward(p, ids, cfg)[0], cfg))
+            p, decoder.forward(p, ids, cfg)[0], cfg))
 
     uncut, cut = (brumby.brumby_tiny_config(vocab_size=v) for v in (256, 32))
     params = T.init_transformer_params(jax.random.PRNGKey(3), uncut)
@@ -523,7 +523,7 @@ def test_run_steps_over_two_batches_equals_two_steps():
     one, scan = (_trainer(remat=True, n_layers=1) for _ in range(2))
     singly = [float(one.step(b, 1e-3)) for b in batches]
     scanned = scan.run_steps(
-        stack_batches(scan.mesh, olmoe.BATCH_SPECS, batches), 1e-3)
+        stack_batches(scan.mesh, decoder.BATCH_SPECS, batches), 1e-3)
     np.testing.assert_allclose(scanned, singly, rtol=1e-5)
     assert singly[0] != singly[1]
     for a, b in zip(jax.tree.leaves(one.state["params"]),
@@ -538,7 +538,7 @@ def test_gauges_only_under_a_monitor_session(tmp_path):
     mon = monitor.enable(str(tmp_path), flight=False)
     try:
         reg = mon.registry
-        tr.run_steps(stack_batches(tr.mesh, olmoe.BATCH_SPECS, batches), 1e-3)
+        tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS, batches), 1e-3)
         assert reg.gauge("monitor.train.retention_chunks").value == 4
         # a sweep of the state's tiles a key/value head and chunk: the
         # five heads of a group ride one grid step
@@ -555,7 +555,7 @@ def test_gauges_only_under_a_monitor_session(tmp_path):
 
 def test_the_retention_s_instructions_are_under_their_scope():
     tr = _trainer(remat=True, n_layers=1)
-    tr.run_steps(stack_batches(tr.mesh, olmoe.BATCH_SPECS,
+    tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS,
                                [{"ids": i} for i in _ids(n=2)]), 1e-3)
     names = devscope.scope_maps()["brumby.run_steps"]
     got = {devscope.classify(op) for op in names.values()}

@@ -63,7 +63,7 @@ def test_dispatch_block_choice():
     from paddle_tpu.parallel.transformer import (_local_attention_dispatch,
                                                  TransformerConfig)
 
-    cfg = TransformerConfig(use_flash=True, causal=False)
+    cfg = TransformerConfig(causal=False)
     rng = np.random.RandomState(5)
     for S in (128, 384, 640):
         x = jnp.array((rng.randn(1, S, 2, 64) * 0.5).astype(np.float32))

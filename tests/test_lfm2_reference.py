@@ -25,9 +25,10 @@ if ROOT not in sys.path:
 
 from benchmark.reference import lfm2_8b_a1b as reference  # noqa: E402
 from paddle_tpu import monitor  # noqa: E402
-from paddle_tpu.models import lfm2, olmoe  # noqa: E402
+from paddle_tpu.models import lfm2  # noqa: E402
 from paddle_tpu.monitor import devscope  # noqa: E402
-from paddle_tpu.parallel import moe, optim, transformer as T  # noqa: E402
+from paddle_tpu.parallel import (decoder, moe, optim,  # noqa: E402
+                                 transformer as T)
 from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
 from paddle_tpu.parallel.train import stack_batches  # noqa: E402
 
@@ -90,7 +91,7 @@ def both():
     tr = _trainer()
     params = _seeded_params(tr)
     ids = _ids()[0]
-    loss_fn = olmoe.make_loss_fn(tr.cfg)
+    loss_fn = decoder.make_loss_fn(tr.cfg)
     got = jax.jit(jax.value_and_grad(
         lambda p: loss_fn(p, {"ids": jnp.asarray(ids)}), has_aux=True))(params)
     want = jax.value_and_grad(
@@ -130,7 +131,7 @@ def test_loss_equals_the_reference(both):
 
 def test_every_position_s_logits_equal_the_reference(both):
     cfg, params, ids, _, _ = both
-    x, _ = jax.jit(lambda p, i: olmoe._forward(p, i, cfg))(params, ids)
+    x, _ = jax.jit(lambda p, i: decoder.forward(p, i, cfg))(params, ids)
     got = T.rms_norm(x, params["lnf_scale"], cfg.norm_eps) @ params["tok_emb"].T
     _, want = reference.forward(params, ids, MODEL)
     np.testing.assert_allclose(got, np.stack(want), rtol=1e-4, atol=TOL)
@@ -315,7 +316,7 @@ def test_a_step_moves_the_bias_by_the_rule_and_nothing_else_does():
     tr = _trainer(optimizer=optim.adamw(weight_decay=0.5))
     ids = _ids(seed=6)[0]
     params0 = jax.tree.map(np.asarray, tr.state["params"])
-    _, aux = jax.jit(lambda p, i: olmoe._forward(p, i, tr.cfg))(
+    _, aux = jax.jit(lambda p, i: decoder.forward(p, i, tr.cfg))(
         tr.state["params"], ids)
     load = np.asarray(aux["load"], np.float32)              # [4 layers, 8]
     assert load.shape == (4, 8) and (load.sum(-1) == B * S * 2).all()
@@ -363,7 +364,7 @@ def test_run_steps_over_three_batches_equals_three_steps():
     one, scan = _trainer(remat=True), _trainer(remat=True)
     singly = [float(one.step(b, 1e-3)) for b in batches]
     scanned = scan.run_steps(
-        stack_batches(scan.mesh, olmoe.BATCH_SPECS, batches), 1e-3)
+        stack_batches(scan.mesh, decoder.BATCH_SPECS, batches), 1e-3)
     np.testing.assert_allclose(scanned, singly, rtol=1e-5)
     assert singly[0] != singly[1]
     for a, b in zip(jax.tree.leaves(one.state["params"]),
@@ -381,7 +382,7 @@ def test_two_periods_scanned_equal_the_reference():
     assert tr.cfg.n_periods == 2 and tr.cfg.moe_layers == 8
     params = _seeded_params(tr)
     ids = _ids(seed=2)[0]
-    got, _ = jax.jit(olmoe.make_loss_fn(tr.cfg))(
+    got, _ = jax.jit(decoder.make_loss_fn(tr.cfg))(
         params, {"ids": jnp.asarray(ids)})
     want = reference.loss(params, {"ids": ids},
                           dict(MODEL, num_hidden_layers=9))
@@ -398,7 +399,7 @@ def test_counters_and_gauges_only_under_a_monitor_session(tmp_path):
         slots = reg.counter("monitor.train.moe_assignments")
         held = reg.counter("monitor.train.moe_rows_held")
         start, held_start = slots.value, held.value
-        tr.run_steps(stack_batches(tr.mesh, olmoe.BATCH_SPECS, batches), 1e-3)
+        tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS, batches), 1e-3)
         pairs = 2 * B * S * 2 * 4       # batches x tokens x top-2 x MoE layers
         assert slots.value - start == pairs
         got = held.value - held_start
@@ -413,7 +414,7 @@ def test_counters_and_gauges_only_under_a_monitor_session(tmp_path):
 
 def test_the_short_convolution_s_instructions_are_under_their_scope():
     tr = _trainer(remat=True)
-    tr.run_steps(stack_batches(tr.mesh, olmoe.BATCH_SPECS,
+    tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS,
                                [{"ids": i} for i in _ids(n=2)]), 1e-3)
     names = devscope.scope_maps()["lfm2.run_steps"]
     got = {devscope.classify(op) for op in names.values()}

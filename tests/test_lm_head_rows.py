@@ -17,9 +17,9 @@ from paddle_tpu import monitor
 from paddle_tpu.models import bert
 from paddle_tpu.parallel import optim, transformer as T
 from paddle_tpu.parallel.mesh import MeshSpec
-from paddle_tpu.parallel.train import (TrainState, make_train_step,
-                                       shard_pytree, stack_batches,
-                                       state_specs)
+from paddle_tpu.parallel.train import (TrainState, gauge_flash_grid,
+                                       make_train_step, shard_pytree,
+                                       stack_batches, state_specs)
 
 N, E, V = 256, 16, 50
 R = T.head_row_block(N)                                         # 16
@@ -375,7 +375,7 @@ def test_flash_grid_gauges_only_under_a_monitor_session(tmp_path):
                                  devices=jax.devices()[:1])
     batch = _batch(np.random.RandomState(9))
     assert monitor.active() is None
-    T.gauge_flash_grid(cfg, B, S)               # off: nothing to set
+    gauge_flash_grid(cfg, B, S)                 # off: nothing to set
     mon = monitor.enable(str(tmp_path), flight=False)
     try:
         pairs = mon.registry.gauge("monitor.kernels.flash_pairs_per_grid_step")
@@ -404,10 +404,10 @@ def test_flash_grid_gauges_only_under_a_monitor_session(tmp_path):
                 # 32 on 8 heads of 64: the two heads of a lane block stacked
                 (lfm2.lfm2_8b_a1b_config(), 2, 8192, (1, 2 * 16 * 136), 2)]:
             stacked.set(0)
-            T.gauge_flash_grid(c, b, s)
+            gauge_flash_grid(c, b, s)
             assert (pairs.value, steps.value, stacked.value) == want + (heads,)
         # heads the packed layout cannot tile take another path: left alone
-        T.gauge_flash_grid(bert.bert_tiny_config(), B, S)
+        gauge_flash_grid(bert.bert_tiny_config(), B, S)
         assert (pairs.value, steps.value, stacked.value) == (
             1, 2 * 16 * 136, 2)
     finally:
@@ -433,7 +433,7 @@ def test_flash_backward_sweeps_gauges(tmp_path):
              (lfm2.lfm2_8b_a1b_config(), 2, 8192)]
     assert monitor.active() is None
     for cell in one_kind + kinds:
-        T.gauge_flash_grid(*cell)               # off: nothing to set
+        gauge_flash_grid(*cell)                 # off: nothing to set
     mon = monitor.enable(str(tmp_path), flight=False)
     try:
         def read(kind):
@@ -447,18 +447,18 @@ def test_flash_backward_sweeps_gauges(tmp_path):
         assert read("windowed") is None
         for cell in one_kind:
             mon.registry.gauge("monitor.kernels.flash_bwd_sweeps_full").set(0)
-            T.gauge_flash_grid(*cell)
+            gauge_flash_grid(*cell)
             assert (read("full"), read("windowed")) == (1, None)
         for cell in kinds:
             for kind in ("full", "windowed"):
                 mon.registry.gauge(
                     "monitor.kernels.flash_bwd_sweeps_" + kind).set(0)
-            T.gauge_flash_grid(*cell)
+            gauge_flash_grid(*cell)
             assert (read("full"), read("windowed")) == (1, 1)
         # SmallThinker's layers at eight times the sequence: two sweeps
         assert fa.fused_sweep_vmem_bytes(131072, 128, 2) > fa.SWEEP_VMEM
-        T.gauge_flash_grid(dataclasses.replace(kinds[0][0], max_seq=131072),
-                           1, 131072)
+        gauge_flash_grid(dataclasses.replace(kinds[0][0], max_seq=131072),
+                         1, 131072)
         assert (read("full"), read("windowed")) == (2, 2)
     finally:
         monitor.disable()

@@ -29,7 +29,8 @@ from paddle_tpu import monitor  # noqa: E402
 from paddle_tpu.models import (bert, brumby, lfm2, mistral4,  # noqa: E402
                                olmoe, smallthinker)
 from paddle_tpu.monitor import devscope  # noqa: E402
-from paddle_tpu.parallel import moe, optim, transformer as T  # noqa: E402
+from paddle_tpu.parallel import (decoder, moe, optim,  # noqa: E402
+                                 transformer as T)
 from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
 from paddle_tpu.parallel.train import stack_batches  # noqa: E402
 
@@ -95,7 +96,7 @@ def both():
     tr = _trainer()
     params = _seeded_params(tr)
     ids = _ids()[0]
-    loss_fn = olmoe.make_loss_fn(tr.cfg)
+    loss_fn = decoder.make_loss_fn(tr.cfg)
     got = jax.jit(jax.value_and_grad(
         lambda p: loss_fn(p, {"ids": jnp.asarray(ids)})))(params)
     want = jax.value_and_grad(
@@ -121,8 +122,8 @@ def test_the_tiny_configuration_keeps_every_mechanism():
     plain = 10000.0 ** (-np.arange(16) / 16)
     assert f[0] == plain[0] and (f[3:] == plain[3:] / 8).all()
     assert (plain[1:3] / 8 < f[1:3]).all() and (f[1:3] < plain[1:3]).all()
-    assert mistral4.interpolated_pairs(cfg) == (3, 15)
-    assert mistral4.scaled_positions(cfg, S) == 48
+    assert decoder.interpolated_pairs(cfg) == (3, 15)
+    assert decoder.scaled_positions(cfg, S) == 48
     big = mistral4.mistral_small_4_config()
     assert (big.n_layers, big.hidden, big.n_heads, big.head_dim,
             big.q_lora_rank, big.kv_lora_rank, big.qk_nope_dim,
@@ -131,8 +132,8 @@ def test_the_tiny_configuration_keeps_every_mechanism():
             big.experts_per_token, big.vocab_size, big.norm_eps) == (
         36, 4096, 32, 128, 1024, 256, 64, 64, 128, 2048, 2048, 128, 128, 4,
         131072, 1e-6)
-    assert mistral4.interpolated_pairs(big) == (25, 31)
-    assert mistral4.scaled_positions(big, 16384) == 8192
+    assert decoder.interpolated_pairs(big) == (25, 31)
+    assert decoder.scaled_positions(big, 16384) == 8192
     np.testing.assert_allclose(T.yarn_softmax_scale(big), 1.4852 ** 2,
                                rtol=1e-4)
     assert T.yarn_rotary_factor(big) == 1.0
@@ -147,7 +148,7 @@ def test_loss_equals_the_reference(both):
 
 def test_every_position_s_logits_equal_the_reference(both):
     cfg, params, ids, _, _ = both
-    x, _ = jax.jit(lambda p, i: olmoe._forward(p, i, cfg))(params, ids)
+    x, _ = jax.jit(lambda p, i: decoder.forward(p, i, cfg))(params, ids)
     got = T.rms_norm(x, params["lnf_scale"], cfg.norm_eps) @ params["lm_head"].T
     _, want = reference.forward(params, ids, MODEL)
     np.testing.assert_allclose(got, np.stack(want), rtol=1e-4, atol=TOL)
@@ -453,7 +454,7 @@ def test_run_steps_over_two_batches_equals_two_steps():
     one, scan = _trainer(remat=True), _trainer(remat=True)
     singly = [float(one.step(b, 1e-3)) for b in batches]
     scanned = scan.run_steps(
-        stack_batches(scan.mesh, olmoe.BATCH_SPECS, batches), 1e-3)
+        stack_batches(scan.mesh, decoder.BATCH_SPECS, batches), 1e-3)
     np.testing.assert_allclose(scanned, singly, rtol=1e-5)
     assert singly[0] != singly[1]
     for a, b in zip(jax.tree.leaves(one.state["params"]),
@@ -471,7 +472,7 @@ def test_counters_and_gauges_only_under_a_monitor_session(tmp_path):
         slots = reg.counter("monitor.train.moe_assignments")
         held = reg.counter("monitor.train.moe_rows_held")
         start, held_start = slots.value, held.value
-        tr.run_steps(stack_batches(tr.mesh, olmoe.BATCH_SPECS, batches), 1e-3)
+        tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS, batches), 1e-3)
         pairs = 2 * B * S * 2 * 2       # batches x tokens x top-2 x layers
         assert slots.value - start == pairs
         assert 0 < held.value - held_start < pairs
@@ -488,7 +489,7 @@ def test_counters_and_gauges_only_under_a_monitor_session(tmp_path):
 
 def test_the_new_scopes_hold_their_instructions_and_attention_none():
     tr = _trainer(remat=True)
-    tr.run_steps(stack_batches(tr.mesh, olmoe.BATCH_SPECS,
+    tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS,
                                [{"ids": i} for i in _ids(n=2)]), 1e-3)
     names = devscope.scope_maps()["mistral4.run_steps"]
     got = {devscope.classify(op) for op in names.values()}
@@ -535,7 +536,7 @@ def test_the_older_transformers_programs_lower_to_the_parent_s_text(name):
     tr = build(config(remat=True), MeshSpec(dp=1), seed=3,
                devices=jax.devices()[:1])
     ids = np.zeros((2, seq), np.int32)
-    batch, specs = {"ids": ids}, olmoe.BATCH_SPECS
+    batch, specs = {"ids": ids}, decoder.BATCH_SPECS
     if name == "bert":
         batch = {"ids": ids, "labels": ids,
                  "mask": np.ones((2, seq), np.float32)}

@@ -605,20 +605,20 @@ def test_the_row_kernel_s_gauges_follow_the_share_held(tmp_path, model, held,
     import importlib
 
     from paddle_tpu import monitor
-    from paddle_tpu.parallel import transformer as T
+    from paddle_tpu.parallel import decoder
 
     module = importlib.import_module("paddle_tpu.models." + model)
     cfg = getattr(module, model + "_tiny_config")()
     assert (cfg.experts_here, cfg.n_experts, cfg.experts_per_token) == (
         held, n, k)
-    T.gauge_moe_rows(cfg, 4096)                  # off: nothing is touched
+    decoder.gauge_moe_rows(cfg, 4096)            # off: nothing is touched
     mon = monitor.enable(str(tmp_path), flight=False)
     try:
         gauges = [mon.registry.gauge("monitor.kernels." + name)
                   for name in ("moe_pair_slots", "moe_rows_fetch_bound")]
         for gauge in gauges:
             gauge.set(-1)
-        T.gauge_moe_rows(cfg, 4096)
+        decoder.gauge_moe_rows(cfg, 4096)
         got = [gauge.value for gauge in gauges]
     finally:
         monitor.disable()

@@ -1,5 +1,6 @@
-"""The OLMoE decoder through the normal path (``models/olmoe.py`` over
-``parallel/transformer.py`` and ``parallel/moe.py``) against the benchmark's
+"""The OLMoE decoder through the normal path (``models/olmoe.py``'s
+configuration through ``parallel/decoder.py``, ``parallel/transformer.py``
+and ``parallel/moe.py``) against the benchmark's
 plain float32 reference (``benchmark/reference/olmoe_1b_7b.py``), on seeded
 weights at ``olmoe_tiny_config``: 2 layers, hidden 64, 4 heads of 16, 8
 experts of width 32, top-2, vocab 256, S = 32.
@@ -25,7 +26,7 @@ from benchmark.reference import olmoe_1b_7b as reference  # noqa: E402
 from paddle_tpu import monitor  # noqa: E402
 from paddle_tpu.models import olmoe  # noqa: E402
 from paddle_tpu.monitor import devscope  # noqa: E402
-from paddle_tpu.parallel import optim, transformer as T  # noqa: E402
+from paddle_tpu.parallel import decoder, optim, transformer as T  # noqa: E402
 from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
 from paddle_tpu.parallel.train import stack_batches  # noqa: E402
 
@@ -70,7 +71,7 @@ def both():
     tr = _trainer()
     params = _seeded_params(tr)
     ids = _ids()[0]
-    loss_fn = olmoe.make_loss_fn(tr.cfg)
+    loss_fn = decoder.make_loss_fn(tr.cfg)
     got = jax.jit(jax.value_and_grad(
         lambda p: loss_fn(p, {"ids": jnp.asarray(ids)})))(params)
     want = jax.value_and_grad(
@@ -91,7 +92,7 @@ def test_loss_equals_the_reference(both):
 
 def test_every_position_s_logits_equal_the_reference(both):
     cfg, params, ids, _, _ = both
-    x, _ = jax.jit(lambda p, i: olmoe._forward(p, i, cfg))(params, ids)
+    x, _ = jax.jit(lambda p, i: decoder.forward(p, i, cfg))(params, ids)
     got = T.rms_norm(x, params["lnf_scale"], cfg.norm_eps) @ params["lm_head"].T
     _, want = reference.forward(params, ids, MODEL)
     np.testing.assert_allclose(got, np.stack(want), rtol=1e-4, atol=TOL)
@@ -117,7 +118,7 @@ def test_a_bfloat16_shortcut_would_fail_the_tolerance(both):
     bf16 = jax.tree.map(
         lambda a: a.astype(jnp.bfloat16) if a.ndim > 1 else a,
         jax.tree.map(jnp.asarray, params))
-    low = olmoe.make_loss_fn(olmoe.olmoe_tiny_config(dtype="bfloat16"))(
+    low = decoder.make_loss_fn(olmoe.olmoe_tiny_config(dtype="bfloat16"))(
         bf16, {"ids": jnp.asarray(ids)})
     assert abs(float(low) - float(f32_loss)) / float(f32_loss) > 5 * TOL
 
@@ -157,7 +158,7 @@ def test_run_steps_over_three_batches_equals_three_steps():
     one, scan = _trainer(), _trainer()
     singly = [float(one.step(b, 1e-3)) for b in batches]
     scanned = scan.run_steps(
-        stack_batches(scan.mesh, olmoe.BATCH_SPECS, batches), 1e-3)
+        stack_batches(scan.mesh, decoder.BATCH_SPECS, batches), 1e-3)
     np.testing.assert_allclose(scanned, singly, rtol=1e-5)
     assert singly[0] != singly[1]
     for a, b in zip(jax.tree.leaves(one.state["params"]),
@@ -184,8 +185,8 @@ class _Unreadable:
 def test_moe_counter_and_gauge_only_under_a_monitor_session(tmp_path):
     tr = _trainer()
     assert monitor.active() is None
-    tr._count_moe(_Unreadable())                # off: nothing runs
-    assert tr._load_fn is None
+    tr._observe({"ids": _Unreadable()})         # off: nothing runs
+    assert tr._routing_fn is None
     batches = [{"ids": i} for i in _ids(seed=8, n=2)]
     mon = monitor.enable(str(tmp_path), flight=False)
     try:
@@ -199,10 +200,10 @@ def test_moe_counter_and_gauge_only_under_a_monitor_session(tmp_path):
         assert 1.0 <= first <= 8.0
         # the gauge is the busiest expert over the mean, largest over layers,
         # of the call's first batch at the weights the call starts from
-        _, aux = olmoe._forward(tr.state["params"],
+        _, aux = decoder.forward(tr.state["params"],
                                 jnp.asarray(batches[0]["ids"]), tr.cfg)
         want = float(jnp.max(aux["load_max_over_mean"]))
-        tr.run_steps(stack_batches(tr.mesh, olmoe.BATCH_SPECS, batches), 1e-3)
+        tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS, batches), 1e-3)
         assert slots.value - start == 3 * per_step
         np.testing.assert_allclose(load.value, want, rtol=1e-6)
     finally:
@@ -211,7 +212,7 @@ def test_moe_counter_and_gauge_only_under_a_monitor_session(tmp_path):
 
 def test_the_compiled_step_s_moe_instructions_are_under_moe_and_router():
     tr = _trainer(remat=True)
-    tr.run_steps(stack_batches(tr.mesh, olmoe.BATCH_SPECS,
+    tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS,
                                [{"ids": i} for i in _ids(n=2)]), 1e-3)
     names = devscope.scope_maps()["olmoe.run_steps"]
     got = {devscope.classify(op) for op in names.values()}

@@ -28,7 +28,8 @@ from benchmark.reference import smallthinker_21b_a3b as reference  # noqa: E402
 from paddle_tpu import monitor  # noqa: E402
 from paddle_tpu.models import bert, olmoe, smallthinker  # noqa: E402
 from paddle_tpu.monitor import devscope  # noqa: E402
-from paddle_tpu.parallel import moe, optim, transformer as T  # noqa: E402
+from paddle_tpu.parallel import (decoder, moe, optim,  # noqa: E402
+                                 transformer as T)
 from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
 from paddle_tpu.parallel.train import stack_batches  # noqa: E402
 
@@ -76,7 +77,7 @@ def both():
     tr = _trainer()
     params = _seeded_params(tr)
     ids = _ids()[0]
-    loss_fn = olmoe.make_loss_fn(tr.cfg)
+    loss_fn = decoder.make_loss_fn(tr.cfg)
     got = jax.jit(jax.value_and_grad(
         lambda p: loss_fn(p, {"ids": jnp.asarray(ids)})))(params)
     want = jax.value_and_grad(
@@ -112,7 +113,7 @@ def test_loss_equals_the_reference(both):
 
 def test_every_position_s_logits_equal_the_reference(both):
     cfg, params, ids, _, _ = both
-    x, _ = jax.jit(lambda p, i: olmoe._forward(p, i, cfg))(params, ids)
+    x, _ = jax.jit(lambda p, i: decoder.forward(p, i, cfg))(params, ids)
     got = T.rms_norm(x, params["lnf_scale"], cfg.norm_eps) @ params["lm_head"].T
     _, want = reference.forward(params, ids, MODEL)
     np.testing.assert_allclose(got, np.stack(want), rtol=1e-4, atol=TOL)
@@ -145,7 +146,7 @@ def test_a_bfloat16_shortcut_would_fail_the_tolerance(both):
         jax.tree.map(jnp.asarray, params))
 
     def logits(c, p):
-        x, _ = olmoe._forward(p, jnp.asarray(ids), c)
+        x, _ = decoder.forward(p, jnp.asarray(ids), c)
         return (T.rms_norm(x, p["lnf_scale"], c.norm_eps).astype(jnp.float32)
                 @ p["lm_head"].T.astype(jnp.float32))
 
@@ -251,7 +252,7 @@ def test_run_steps_over_three_batches_equals_three_steps():
     one, scan = _trainer(remat=True), _trainer(remat=True)
     singly = [float(one.step(b, 1e-3)) for b in batches]
     scanned = scan.run_steps(
-        stack_batches(scan.mesh, olmoe.BATCH_SPECS, batches), 1e-3)
+        stack_batches(scan.mesh, decoder.BATCH_SPECS, batches), 1e-3)
     np.testing.assert_allclose(scanned, singly, rtol=1e-5)
     assert singly[0] != singly[1]
     for a, b in zip(jax.tree.leaves(one.state["params"]),
@@ -265,7 +266,7 @@ def test_two_periods_scanned_equal_the_reference():
     tr = _trainer(n_layers=8)
     params = _seeded_params(tr)
     ids = _ids(seed=2)[0]
-    got = jax.jit(olmoe.make_loss_fn(tr.cfg))(params, {"ids": jnp.asarray(ids)})
+    got = jax.jit(decoder.make_loss_fn(tr.cfg))(params, {"ids": jnp.asarray(ids)})
     model = dict(MODEL, num_hidden_layers=8, rope_layout=[0, 1, 1, 1] * 2,
                  sliding_window_layout=[0, 1, 1, 1] * 2)
     want = reference.loss(params, {"ids": ids}, model)
@@ -278,7 +279,7 @@ def test_heads_the_kernel_cannot_tile_are_refused():
     tr = _trainer(head_width=16)
     assert T._packed_flash_blocks(tr.cfg, 6, S, 2) is None
     with pytest.raises(AssertionError, match="packed flash kernel"):
-        olmoe.make_loss_fn(tr.cfg)(tr.state["params"],
+        decoder.make_loss_fn(tr.cfg)(tr.state["params"],
                                    {"ids": jnp.asarray(_ids(seed=4)[0])})
 
 
@@ -330,7 +331,7 @@ def test_counters_and_gauges_only_under_a_monitor_session(tmp_path):
     tr = _trainer()
     assert monitor.active() is None
     tr._observe({"ids": _Unreadable()})         # off: nothing runs
-    assert tr._held_fn is None and tr._load_fn is None
+    assert tr._routing_fn is None
     batches = [{"ids": i} for i in _ids(seed=8, n=2)]
     mon = monitor.enable(str(tmp_path), flight=False)
     try:
@@ -338,7 +339,7 @@ def test_counters_and_gauges_only_under_a_monitor_session(tmp_path):
         slots = reg.counter("monitor.train.moe_assignments")
         held = reg.counter("monitor.train.moe_rows_held")
         start, held_start = slots.value, held.value
-        tr.run_steps(stack_batches(tr.mesh, olmoe.BATCH_SPECS, batches), 1e-3)
+        tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS, batches), 1e-3)
         pairs = 2 * B * S * 2 * 4               # batches x tokens x top-2 x L
         assert slots.value - start == pairs
         got = held.value - held_start
@@ -368,7 +369,7 @@ def test_the_pre_attention_router_s_instructions_are_under_router():
     the block's input: in the compiled step they carry the scope ``router``
     (forward and backward), and every scope of the block is there."""
     tr = _trainer(remat=True)
-    tr.run_steps(stack_batches(tr.mesh, olmoe.BATCH_SPECS,
+    tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS,
                                [{"ids": i} for i in _ids(n=2)]), 1e-3)
     names = devscope.scope_maps()["smallthinker.run_steps"]
     got = {devscope.classify(op) for op in names.values()}
