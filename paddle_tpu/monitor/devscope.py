@@ -50,13 +50,20 @@ RETENTION = "retention"
 LATENT_ATTENTION = "latent_attention"
 # the dense gated FFN every token meets beside its routed experts
 SHARED_EXPERT = "shared_expert"
+# attention's sigmoid output gate: the sigmoid of the gate's projection and
+# its product with the heads' output, between the flash kernel and ``wo``
+# (the projection itself is attention's)
+ATTN_GATE = "attn_gate"
+# sandwich norms: the RMS norm on a branch's OUTPUT (attention's, the FFN's)
+POST_NORM = "post_norm"
 # the scan over the stacked layers itself: its slices of each layer's leaves,
 # the activations it keeps for the backward pass and the gradients it stacks
 # (a layer's own work carries the layer's scopes, which lie further in)
 LAYER_SCAN = "layer_scan"
 VOCABULARY = (EMBED, ATTENTION, MLP, LAYER_NORM, LM_HEAD, CONV, BN, POOL, FC,
               LOSS, GRAD_SYNC, OPTIMIZER, MOE, ROUTER, SHORT_CONV, RETENTION,
-              LATENT_ATTENTION, SHARED_EXPERT, LAYER_SCAN)
+              LATENT_ATTENTION, SHARED_EXPERT, LAYER_SCAN, ATTN_GATE,
+              POST_NORM)
 PHASES = ("forward", "backward", "recompute", GRAD_SYNC, OPTIMIZER)
 
 # `%fusion.12 = bf16[..] fusion(%p.1, %copy-done.2), ..., metadata={op_name="jit(multi)/..." ...}`:

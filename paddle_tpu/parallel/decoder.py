@@ -2,9 +2,9 @@
 (``transformer.py``) and the step it runs (``train.py``): forward, loss,
 ``logits_at``, a call's observations and the builder, ONE of each for every
 decoder of this block.  A model (``models/olmoe.py``, ``smallthinker.py``,
-``lfm2.py``, ``brumby.py``, ``mistral4.py``) is a ``TransformerConfig`` and
-a label; what its trainer computes and what it observes follow from the
-configuration, never from which model it is.
+``lfm2.py``, ``brumby.py``, ``mistral4.py``, ``trinity.py``) is a
+``TransformerConfig`` and a label; what its trainer computes and what it
+observes follow from the configuration, never from which model it is.
 
 batch dict: ``ids`` int32 [B, S] alone.  The loss builds the next-token
 labels itself (``labels[t] = ids[t + 1]``, positions 0..S-2 count) and adds
@@ -120,6 +120,18 @@ def scaled_positions(cfg, seq):
     return max(seq - cfg.rope_original_max, 0)
 
 
+def _first_layer_input(params, ids, cfg):
+    """The first layer's leaves and the normed rows its operator reads."""
+    if "prefix_layers" in params:
+        pl = params["prefix_layers"]["l0"]
+    else:
+        layers = params["params_layers"]
+        pl = jax.tree.map(lambda a: a[0],
+                          layers["p0"] if cfg.per_position else layers)
+    return pl, rms_norm(embed(params, ids, cfg), pl["ln1_scale"],
+                        cfg.norm_eps)
+
+
 def gauge_moe_rows(cfg, tokens):
     """Under a monitor session, of one expert layer's call on ``tokens``
     local tokens, whose sum back is the row kernel's
@@ -148,7 +160,7 @@ class DecoderTrainer(StepTrainer):
     (``<label>.step``, ``<label>.run_steps``)."""
 
     label: str = "decoder"
-    _logits_fn = _routing_fn = _gate_fn = None
+    _logits_fn = _routing_fn = _gate_fn = _attn_gate_fn = None
 
     def _on_mesh(self, fn, out_specs, *more):
         """``fn(params, ids [b, S], *more)`` jitted over the mesh, the
@@ -198,6 +210,9 @@ class DecoderTrainer(StepTrainer):
           ``retention_state_sweeps`` and ``retention_gate_mean``, the mean
           ``e^g`` over tokens and heads of the call's first batch in layer
           0: a state decays to 1/e in ``1 / (1 - mean)`` tokens or so;
+        - ``attn_gate``: ``attn_gate_mean``, the mean of the output gate's
+          sigmoid over tokens, heads and columns of the call's first batch
+          in the first layer: a gate stuck at 0 or 1 is a dead branch;
         - ``latent``: ``mla_latent_bytes_per_token`` (what a layer's keys
           and values come from: the latent and the shared rotary key)
           beside ``mla_expanded_kv_bytes_per_token`` (what the flash
@@ -251,15 +266,22 @@ class DecoderTrainer(StepTrainer):
             gauge("retention_state_sweeps", retention_state_sweeps(cfg, seq))
             if self._gate_fn is None:
                 def gate_mean(params, ids):
-                    pl = jax.tree.map(lambda a: a[0],
-                                      params["params_layers"]["p0"])
-                    h = rms_norm(embed(params, ids, cfg), pl["ln1_scale"],
-                                 cfg.norm_eps)
+                    pl, h = _first_layer_input(params, ids, cfg)
                     return jnp.mean(jnp.exp(retention_log_decay(pl, h)))
 
                 self._gate_fn = self._on_mesh(gate_mean, P())
             gauge("retention_gate_mean",
                   float(self._gate_fn(params, batches[0])))
+        if cfg.attn_gate:
+            if self._attn_gate_fn is None:
+                def attn_gate_mean(params, ids):
+                    pl, h = _first_layer_input(params, ids, cfg)
+                    return jnp.mean(jax.nn.sigmoid(
+                        (h @ pl["wz"]).astype(jnp.float32)))
+
+                self._attn_gate_fn = self._on_mesh(attn_gate_mean, P())
+            gauge("attn_gate_mean",
+                  float(self._attn_gate_fn(params, batches[0])))
         if cfg.latent:
             itemsize = cfg.jdtype.itemsize
             gauge("mla_latent_bytes_per_token",

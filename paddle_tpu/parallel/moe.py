@@ -116,14 +116,16 @@ def router_logits(router, x):
 
 
 @devscope.scoped(devscope.ROUTER)
-def route_top_k(router, x, k, rule=SOFTMAX_TOP_K, logits=None, bias=None):
+def route_top_k(router, x, k, rule=SOFTMAX_TOP_K, logits=None, bias=None,
+                scale=1.0):
     """Router of a dropless layer on the tokens ``x`` [T, E] (or on
     ``logits`` [T, n] where the caller computed them from another input):
     the k experts of each token and their weights, [T, k] each.  By
     ``rule``: the k largest of ``softmax(logits)`` (float32, over ALL
     experts; as they are, not renormalised to sum to one), or the k largest
     logits and a softmax over those k (the same as renormalising the
-    first).  And the layer's auxiliary values over the dp-global batch:
+    first); whichever the rule, times ``scale`` once formed.  And the
+    layer's auxiliary values over the dp-global batch:
 
     - ``load_balance`` = n * sum_e f_e * P_e, ``f_e`` the share of the T*k
       assignments that went to expert e (a count: no gradient), ``P_e`` the
@@ -148,7 +150,7 @@ def route_top_k(router, x, k, rule=SOFTMAX_TOP_K, logits=None, bias=None):
         top_s = jnp.take_along_axis(scores, top_e, axis=-1)
         top_p = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-6)
         counts = col.psum(_per_expert(top_e, n), DP)
-        return top_p, top_e, {
+        return _scaled(top_p, scale), top_e, {
             "load": counts,
             "load_max_over_mean": jnp.max(counts) * (n / (tokens * k))}
     lse = jax.nn.logsumexp(logits, axis=-1)
@@ -164,7 +166,11 @@ def route_top_k(router, x, k, rule=SOFTMAX_TOP_K, logits=None, bias=None):
     aux = {"load_balance": n * jnp.sum(share * mean_p),
            "router_z": col.psum(jnp.sum(jnp.square(lse)), DP) / tokens,
            "load_max_over_mean": jnp.max(share) * n}
-    return top_p, top_e, aux
+    return _scaled(top_p, scale), top_e, aux
+
+
+def _scaled(top_p, scale):
+    return top_p if scale == 1.0 else top_p * scale
 
 
 def balance_bias(bias, load, rate):
@@ -380,12 +386,12 @@ _held_expert_ffn.defvjp(lambda *a: (_held_expert_ffn(*a), a[:5]),
 
 @devscope.scoped(devscope.MOE)
 def dropless_moe_ffn(params, x, k, rule=SOFTMAX_TOP_K, act="silu",
-                     logits=None, first_held=0, bias=None):
+                     logits=None, first_held=0, bias=None, scale=1.0):
     """Top-k dropless expert FFN.  x [T, E] (flatten batch and sequence
     before the call); returns ``(y [T, E], aux)`` with
     ``y_t = sum_{e in top k, held} p_te * down_e(act(gate_e x_t) * up_e x_t)``
-    and ``aux`` as ``route_top_k`` gives it (``rule``, ``logits`` and
-    ``bias`` are its).  ``we_gate_up`` / ``we_down`` hold the experts
+    and ``aux`` as ``route_top_k`` gives it (``rule``, ``logits``, ``bias``
+    and ``scale`` are its).  ``we_gate_up`` / ``we_down`` hold the experts
     [first_held, first_held + their leading size) of the router's n: all of
     them (OLMoE), or this device's share, and then ``y`` is the part of the
     layer's result that its experts give, and ``aux`` also counts the pairs
@@ -399,7 +405,7 @@ def dropless_moe_ffn(params, x, k, rule=SOFTMAX_TOP_K, act="silu",
     n, count = params["router"].shape[-1], params["we_gate_up"].shape[0]
     assert 0 <= first_held and first_held + count <= n, (first_held, count, n)
     top_p, top_e, aux = route_top_k(params["router"], x, k, rule, logits,
-                                    bias)
+                                    bias, scale)
     ffn = (x, top_p, top_e, params["we_gate_up"], params["we_down"])
     if count == n:
         return _expert_ffn(*ffn, k, act, 0, None), aux

@@ -266,8 +266,8 @@ def transformer_rules(cfg):
         (r"/(q_a_norm|kv_a_norm)$", L(None)),
         (r"^(tok_emb|lm_head)$", P(TP, None)),       # vocab-parallel
         (r"^pos_emb$|^lnf_", P()),
-        (r"/ln[12]_(scale|bias)$", L(None)),
-        (r"/(wq|wk|wv|bqkv)$", L(None, tp)),
+        (r"/ln[12](_post)?_(scale|bias)$", L(None)),
+        (r"/(wq|wk|wv|wz|bqkv)$", L(None, tp)),
         (r"/wo$", L(tp, None)),
         (r"/(bo|b2)$", L(None)),
         (r"/(w1)$", L(None, tp)),

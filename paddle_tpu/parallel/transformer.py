@@ -161,6 +161,29 @@ class TransformerConfig:
     # a dense gated FFN of this width (``expert_act``) beside the routed
     # experts in every MoE layer, on the same input, for every token
     shared_ffn_hidden: int = 0
+    # a sigmoid OUTPUT GATE on attention: a fifth projection ``wz`` [E,
+    # n_heads * head_dim] off the same normed input as q, k and v, whose
+    # sigmoid multiplies the heads' output before ``wo``
+    attn_gate: bool = False
+    # sandwich norms: an RMS norm on each branch's OUTPUT beside the one on
+    # its input (``ln1_post_scale``, ``ln2_post_scale``); in a layer with a
+    # shared expert the FFN's takes the SUM of the routed part and the shared
+    post_norm: bool = False
+    # what the output norms' scales are SEEDED at (the input norms' at one).
+    # At one every branch re-enters the stream at unit scale whatever it
+    # computed, attention's near-constant mean among them, and a router
+    # downstream ranks the experts alike for every token
+    post_norm_gain: float = 1.0
+    # moe.SIGMOID_BIASED alone: the standard deviation the selection biases
+    # are SEEDED with.  A bias is added to a SCORE, and the k-th of n sigmoid
+    # scores lies where the sigmoid is flat: 0.1 is half a unit of the logit
+    # at 4 of 32 and a whole one at 4 of 256, where it empties some experts
+    # and sends others eight times the mean
+    router_bias_std: float = 0.1
+    # what the k routing weights are multiplied by, once formed
+    route_scale: float = 1.0
+    # what the embedding's rows are multiplied by as they enter the stream
+    embed_scale: float = 1.0
 
     def __post_init__(self):
         assert self.norm in ("layer", "rms") and \
@@ -202,6 +225,15 @@ class TransformerConfig:
                 self.rope_factor or self.q_scale_beta)
         if self.shared_ffn_hidden:
             assert self.n_experts and not self.bias
+        if self.attn_gate:
+            # RETENTION owns a gate of its own; the latent form has none yet
+            assert self.tp == 1 and self.attn_mode == "heads" \
+                and not (self.bias or self.latent) \
+                and RETENTION not in self.layer_pattern
+        if self.post_norm:
+            # the norm of a branch's output needs the whole row: no tp yet
+            assert self.norm == "rms" and self.tp == 1 and not self.bias
+        assert self.route_scale == 1.0 or self.n_experts
 
     @property
     def head_dim(self):
@@ -286,7 +318,9 @@ def init_transformer_params(key, cfg: TransformerConfig):
     ``wq_a`` / ``q_a_norm`` / ``wq_b`` and ``wkv_a`` / ``kv_a_norm`` /
     ``wkv_b`` where the others have ``wq`` / ``wk`` / ``wv``
     (``_latent_qkv``); ``ws_gate_up`` [E, 2Fs] / ``ws_down`` [Fs, E] are the
-    shared expert's (``shared_ffn_hidden``).
+    shared expert's (``shared_ffn_hidden``); ``wz`` [E, n_heads * head_dim]
+    is attention's output gate (``attn_gate``), ``ln1_post_scale`` /
+    ``ln2_post_scale`` the output norms' (``post_norm``).
 
     Where every layer has the same leaves (attention layers that differ in
     window and rotary alone) ``params_layers`` is ONE tree stacked [L, ...].
@@ -294,7 +328,8 @@ def init_transformer_params(key, cfg: TransformerConfig):
     with a dense FFN) it holds a tree for each position of the period,
     ``params_layers["p<i>"]`` stacked [n_periods, ...] with the leaves of
     that position's kind (``_position_leaves``), ``prefix_layers["l<i>"]``
-    holds each leading layer's, unstacked, and the selection biases of the
+    holds each leading layer's, unstacked.  Either way a layer's FFN leaves
+    come from ONE rule (``_ffn_leaves``), and the selection biases of the
     MoE layers, where the routing rule has them, are ONE top-level leaf
     ``router_bias`` [moe_layers, n_experts] float32, which takes no
     gradient and which a step moves itself (``moe.balance_bias``)."""
@@ -327,6 +362,7 @@ def _init_params(key, cfg):
             (V, E), dt),
         "lnf_scale": jnp.ones((E,), jnp.float32),
         **layers,
+        **_router_bias(ks[5], cfg),
     }
     if cfg.positions == "learned":
         params["pos_emb"] = _dense_init(ks[2], E, (cfg.max_seq, E), dt)
@@ -387,15 +423,9 @@ def _stacked_layers(ks, cfg):
             # head by head [k_nope | v]
             wkv_b=stack(12, rkv, (rkv, H * (cfg.qk_nope_dim
                                             + cfg.v_head_dim))))
+    layer.update(_branch_leaves(stack, cfg, L, 15, attention=True))
     if cfg.n_experts:
-        n = cfg.experts_here          # the router ranks all n_experts
-        layer["router"] = stack(6, E, (E, cfg.n_experts), jnp.float32)
-        layer["we_gate_up"] = stack(7, E, (n, E, 2 * F))
-        layer["we_down"] = stack(8, F, (n, F, E))
-        if cfg.shared_ffn_hidden:
-            Fs = cfg.shared_ffn_hidden
-            layer["ws_gate_up"] = stack(13, E, (E, 2 * Fs))
-            layer["ws_down"] = stack(14, Fs, (Fs, E))
+        layer.update(_ffn_leaves(stack, cfg, 6, dense=False))
     else:
         layer["w1"] = stack(4, E, (E, F))
         layer["w2"] = stack(5, F, (F, E))
@@ -421,6 +451,48 @@ def _qk_norm_leaves(cfg, n):
             for name, heads in zip(("q_norm", "k_norm"), widths)}
 
 
+def _ffn_leaves(stack, cfg, fold, dense):
+    """A layer's FFN leaves, the ONE rule of both ways of building the tree
+    (``stack(fold, fan_in, shape[, dtype])`` seeds a stacked leaf; ``fold``
+    the first of this call's): where ``dense`` the gated FFN's ``w_gate_up``
+    [E, 2F] (gate in columns [0, F)) and ``w_down`` [F, E] at F =
+    ``dense_ffn_hidden``; else the MoE's ``router`` [E, n_experts] float32
+    (it ranks all of them), ``we_gate_up`` / ``we_down`` of the
+    ``experts_here`` held and, with ``shared_ffn_hidden``, the shared
+    expert's ``ws_gate_up`` [E, 2Fs] / ``ws_down`` [Fs, E]."""
+    E = cfg.hidden
+    if dense:
+        F = cfg.dense_ffn_hidden
+        return dict(w_gate_up=stack(fold, E, (E, 2 * F)),
+                    w_down=stack(fold + 1, F, (F, E)))
+    F, held = cfg.ffn_hidden, cfg.experts_here
+    leaves = dict(router=stack(fold, E, (E, cfg.n_experts), jnp.float32),
+                  we_gate_up=stack(fold + 1, E, (held, E, 2 * F)),
+                  we_down=stack(fold + 2, F, (held, F, E)))
+    if cfg.shared_ffn_hidden:
+        Fs = cfg.shared_ffn_hidden
+        leaves.update(ws_gate_up=stack(13, E, (E, 2 * Fs)),
+                      ws_down=stack(14, Fs, (Fs, E)))
+    return leaves
+
+
+def _branch_leaves(stack, cfg, n, fold, attention):
+    """What the configuration adds to the two branches of ``n`` stacked
+    layers: the output gate's projection ``wz`` [E, n_heads * head_dim]
+    (``attn_gate``; an ``attention`` layer's alone) and the output norms'
+    scales ``ln1_post_scale`` / ``ln2_post_scale`` (``post_norm``)."""
+    leaves = {}
+    if cfg.attn_gate and attention:
+        leaves["wz"] = stack(fold, cfg.hidden,
+                             (cfg.hidden, cfg.n_heads * cfg.head_dim))
+    if cfg.post_norm:
+        # a buffer each: a step donates its state's leaves
+        leaves.update({name: jnp.full((n, cfg.hidden), cfg.post_norm_gain,
+                                      jnp.float32)
+                       for name in ("ln1_post_scale", "ln2_post_scale")})
+    return leaves
+
+
 def _position_leaves(key, cfg, kind, n, dense):
     """The leaves of ``n`` layers of one ``kind``, stacked [n, ...]: the two
     norms' scales; attention's ``wq`` / ``wk`` / ``wv`` / ``wo`` (and
@@ -428,9 +500,8 @@ def _position_leaves(key, cfg, kind, n, dense):
     and C and the value, side by side), ``conv_w`` [taps, E] (tap j meets
     position t - taps + 1 + j) and ``conv_out`` [E, E], for RETENTION
     attention's and the gate projection ``wg`` [E, kv_heads] float32 (one
-    log-decay a key/value head and token); then the FFN's: the MoE's, or
-    where ``dense`` ``w_gate_up`` [E, 2F] (gate in columns [0, F)) and
-    ``w_down`` [F, E] at F = ``dense_ffn_hidden``."""
+    log-decay a key/value head and token); what ``_branch_leaves`` adds;
+    then the FFN's (``_ffn_leaves``), dense or the MoE's."""
     assert cfg.norm == "rms" and not cfg.bias
     E, dt = cfg.hidden, cfg.jdtype
     Q, KV = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
@@ -452,25 +523,34 @@ def _position_leaves(key, cfg, kind, n, dense):
         leaves.update(_qk_norm_leaves(cfg, n))
         if kind == RETENTION:
             leaves["wg"] = stack(10, E, (E, cfg.kv_heads), jnp.float32)
-    if dense:
-        F = cfg.dense_ffn_hidden
-        leaves.update(w_gate_up=stack(5, E, (E, 2 * F)),
-                      w_down=stack(6, F, (F, E)))
-    else:
-        F, held = cfg.ffn_hidden, cfg.experts_here
-        leaves.update(
-            router=stack(7, E, (E, cfg.n_experts), jnp.float32),
-            we_gate_up=stack(8, E, (held, E, 2 * F)),
-            we_down=stack(9, F, (held, F, E)))
+    leaves.update(_branch_leaves(stack, cfg, n, 11, attention=kind != CONV))
+    leaves.update(_ffn_leaves(stack, cfg, 5 if dense else 7, dense))
     return leaves
 
 
-ROUTER_BIAS_STD = 0.1
+def _router_bias(key, cfg):
+    """``{"router_bias": [moe_layers, n_experts] float32}`` where the
+    routing rule has selection biases, else nothing."""
+    from .moe import SIGMOID_BIASED
+
+    if not cfg.n_experts or cfg.routing != SIGMOID_BIASED:
+        return {}
+    # seeded off zero, where a trained model's stand: a rule that weighted
+    # by them, or left them out of the choice, gives other numbers than the
+    # rule.  Each share of ``experts_here`` experts seeds its own from the
+    # same key, as the chips of an expert-parallel layer would: every share
+    # then holds the same biases and, at seeded weights, draws the same load
+    held = cfg.experts_here
+    assert cfg.n_experts % held == 0, (cfg.n_experts, held)
+    return {"router_bias": jnp.tile(
+        cfg.router_bias_std * jax.random.normal(
+            key, (cfg.moe_layers, held), jnp.float32),
+        (1, cfg.n_experts // held))}
 
 
 def _per_position_layers(ks, cfg):
-    """``prefix_layers``, ``params_layers`` and, where the routing rule has
-    them, ``router_bias`` of a stack whose layers own different leaves."""
+    """``prefix_layers`` and ``params_layers`` of a stack whose layers own
+    different leaves."""
     assert cfg.positions == "rotary"
     layers = {
         "params_layers": {
@@ -484,21 +564,6 @@ def _per_position_layers(ks, cfg):
             "l%d" % i: jax.tree.map(lambda a: a[0], _position_leaves(
                 jax.random.fold_in(ks[4], i), cfg, kind, 1, dense=True))
             for i, kind in enumerate(cfg.prefix_kinds)}
-    from .moe import SIGMOID_BIASED
-
-    if cfg.routing == SIGMOID_BIASED:
-        # seeded off zero, where a trained model's stand: a rule that
-        # weighted by them, or left them out of the choice, gives other
-        # numbers than the rule.  Each share of ``experts_here`` experts
-        # seeds its own from the same key, as the chips of an
-        # expert-parallel layer would: every share then holds the same
-        # biases and, at seeded weights, draws the same load
-        held = cfg.experts_here
-        assert cfg.n_experts % held == 0, (cfg.n_experts, held)
-        layers["router_bias"] = jnp.tile(
-            ROUTER_BIAS_STD * jax.random.normal(
-                ks[5], (cfg.moe_layers, held), jnp.float32),
-            (1, cfg.n_experts // held))
     return layers
 
 
@@ -716,6 +781,8 @@ def embed(params, ids, cfg: TransformerConfig, seq_offset=None):
     local = jnp.clip(ids - lo, 0, vshard - 1)
     hit = (ids >= lo) & (ids < lo + vshard)
     emb = params["tok_emb"][local] * hit[..., None].astype(params["tok_emb"].dtype)
+    if cfg.embed_scale != 1.0:
+        emb = (emb.astype(jnp.float32) * cfg.embed_scale).astype(emb.dtype)
     if cfg.positions != "learned":
         return col.reduce_scatter(emb, TP, dim=1) if ntp > 1 else emb
     S = ids.shape[1]
@@ -837,6 +904,14 @@ def _latent_qkv(pl, h, cfg, first=0):
             kv[..., dn:].reshape(b, S, -1))
 
 
+@devscope.scoped(devscope.ATTN_GATE)
+def _gate_heads(o, z):
+    """The heads' output ``o`` [b, S, H * dh] times ``sigmoid(z)``, ``z`` the
+    gate's projection of the same shape; in float32, rounded once."""
+    return (o.astype(jnp.float32)
+            * jax.nn.sigmoid(z.astype(jnp.float32))).astype(o.dtype)
+
+
 def _attention_heads_mode(pl, h_full, cfg, kind):
     """Megatron attention: input full-sequence [b,S,E], heads sharded over tp.
     ``kind`` = (window or None, rotary) of this layer."""
@@ -863,6 +938,8 @@ def _attention_heads_mode(pl, h_full, cfg, kind):
             "only (flash_attention.packed_layout_supported: a lane block " \
             "of whole heads that share one key/value head; S whole blocks)"
         o = _local_attention_dispatch(q, k, v, cfg).reshape(b, S, hl * dh)
+    if cfg.attn_gate:
+        o = _gate_heads(o, h_full @ pl["wz"])
     out = o @ pl["wo"]                                          # row-parallel partial
     out = col.reduce_scatter(out, TP, dim=1)                    # sum + seq scatter
     return out + pl["bo"] if cfg.bias else out
@@ -992,6 +1069,15 @@ def gated_ffn(pl, h, cfg):
     return _by_row_blocks(rows_ffn, h, pl["w_down"].shape[0])
 
 
+def _add_branch(x, branch, pl, name, cfg):
+    """``x + branch``; with sandwich norms ``x + rms(branch)`` by the leaf
+    ``<name>_post_scale``, under the caller's scope and ``post_norm``."""
+    if cfg.post_norm:
+        with jax.named_scope(devscope.POST_NORM):
+            branch = _rms(branch, pl[name + "_post_scale"], cfg.norm_eps)
+    return x + branch
+
+
 def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
                       dense=False, router_bias=None):
     """One pre-norm block on the SP activation [b, S/tp, E]: the new
@@ -1010,11 +1096,12 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
         logits = router_logits(pl["router"], x_sp.reshape(-1, x_sp.shape[-1]))
     if kind == CONV:
         with jax.named_scope(devscope.SHORT_CONV):
-            x_sp = x_sp + short_conv(pl, _norm(x_sp, pl, "ln1", cfg))
+            x_sp = _add_branch(x_sp, short_conv(
+                pl, _norm(x_sp, pl, "ln1", cfg)), pl, "ln1", cfg)
     elif kind == RETENTION:
         with jax.named_scope(devscope.RETENTION):
-            x_sp = x_sp + power_retention(pl, _norm(x_sp, pl, "ln1", cfg),
-                                          cfg)
+            x_sp = _add_branch(x_sp, power_retention(
+                pl, _norm(x_sp, pl, "ln1", cfg), cfg), pl, "ln1", cfg)
     else:
         with jax.named_scope(devscope.LATENT_ATTENTION if cfg.latent
                              else devscope.ATTENTION):
@@ -1025,12 +1112,12 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
                                              kind or cfg.layer_kinds[0])
             else:
                 attn = _attention_ring_mode(pl, h, cfg)
-            x_sp = x_sp + attn
+            x_sp = _add_branch(x_sp, attn, pl, "ln1", cfg)
 
     if dense:
         with jax.named_scope(devscope.MLP):
-            return x_sp + gated_ffn(pl, _norm(x_sp, pl, "ln2", cfg),
-                                    cfg), None
+            return _add_branch(x_sp, gated_ffn(
+                pl, _norm(x_sp, pl, "ln2", cfg), cfg), pl, "ln2", cfg), None
 
     if cfg.n_experts:
         with jax.named_scope(devscope.MOE):
@@ -1040,16 +1127,24 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
             y, aux = dropless_moe_ffn(
                 pl, h.reshape(-1, h.shape[-1]), cfg.experts_per_token,
                 rule=cfg.routing, act=cfg.expert_act, logits=logits,
-                first_held=cfg.first_expert, bias=router_bias)
-            x_sp = x_sp + y.reshape(h.shape)
-        if cfg.shared_ffn_hidden:
-            # every share of the experts computes it, and a sum over the
-            # shares counts it once: no 129th group of the grouped matmul
-            with jax.named_scope(devscope.SHARED_EXPERT):
-                x_sp = x_sp + gated_ffn(
-                    {"w_gate_up": pl["ws_gate_up"], "w_down": pl["ws_down"]},
-                    h, cfg)
-        return x_sp, aux
+                first_held=cfg.first_expert, bias=router_bias,
+                scale=cfg.route_scale)
+            y = y.reshape(h.shape)
+            if not cfg.shared_ffn_hidden:
+                return _add_branch(x_sp, y, pl, "ln2", cfg), aux
+            if not cfg.post_norm:
+                x_sp = x_sp + y
+        # every share of the experts computes it, and a sum over the
+        # shares counts it once: no 129th group of the grouped matmul
+        with jax.named_scope(devscope.SHARED_EXPERT):
+            shared = gated_ffn(
+                {"w_gate_up": pl["ws_gate_up"], "w_down": pl["ws_down"]},
+                h, cfg)
+            if not cfg.post_norm:
+                return x_sp + shared, aux
+        with jax.named_scope(devscope.MOE):
+            # the output norm is not linear: it takes the branch's SUM
+            return _add_branch(x_sp, y + shared, pl, "ln2", cfg), aux
 
     with jax.named_scope(devscope.MLP):
         h = _norm(x_sp, pl, "ln2", cfg)
@@ -1060,7 +1155,7 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
         y = y @ pl["w2"]                                        # partial if heads_mode
         if heads_mode:
             y = col.reduce_scatter(y, TP, dim=1)
-        x_sp = x_sp + y
+        x_sp = _add_branch(x_sp, y, pl, "ln2", cfg)
         if cfg.bias:
             x_sp = x_sp + pl["b2"]
     return x_sp, None
@@ -1092,8 +1187,9 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
     if len(kinds) == 1 and not cfg.per_position:
         with jax.named_scope(devscope.LAYER_SCAN):
             x_sp, aux = jax.lax.scan(
-                lambda x, pl: body(pl, x, cfg, kinds[0], False),
-                x_sp, layer_params, unroll=unroll)
+                lambda x, turn: body(turn[0], x, cfg, kinds[0], False,
+                                     turn[1]),
+                x_sp, (layer_params, router_bias), unroll=unroll)
         return (x_sp, aux) if with_aux else x_sp
 
     if cfg.per_position:

@@ -109,7 +109,35 @@ def test_classify_on_literal_op_names():
     for op_name, want in table.items():
         assert devscope.classify(op_name) == want, op_name
     assert set(devscope.PHASES) >= {w[0] for w in table.values()}
-    assert len(set(devscope.VOCABULARY)) == len(devscope.VOCABULARY) == 19
+    assert len(set(devscope.VOCABULARY)) == len(devscope.VOCABULARY) == 21
+
+
+@pytest.mark.parametrize("op_name, want", [
+    # the output gate lies inside attention's scope and is its own
+    ("jit(multi)/jvp()/while/body/closed_call/attention/attn_gate/logistic",
+     ("forward", "attn_gate")),
+    ("jit(multi)/transpose(jvp())/while/body/closed_call/checkpoint/"
+     "rematted_computation/attention/attn_gate/mul",
+     ("recompute", "attn_gate")),
+    ("jit(multi)/transpose(jvp())/while/body/closed_call/checkpoint/"
+     "attention/attn_gate/mul", ("backward", "attn_gate")),
+    # the gate's projection is attention's
+    ("jit(multi)/jvp()/while/body/closed_call/attention/dot_general",
+     ("forward", "attention")),
+    # an output norm lies inside its branch's scope, whichever that is, and
+    # is no ``layer_norm`` (the input norms are)
+    ("jit(multi)/jvp()/while/body/closed_call/attention/post_norm/rsqrt",
+     ("forward", "post_norm")),
+    ("jit(multi)/transpose(jvp())/while/body/closed_call/checkpoint/moe/"
+     "post_norm/mul", ("backward", "post_norm")),
+    ("jit(multi)/transpose(jvp())/checkpoint/rematted_computation/mlp/"
+     "post_norm/mul", ("recompute", "post_norm")),
+    ("jit(multi)/jvp()/while/body/closed_call/moe/layer_norm/rsqrt",
+     ("forward", "layer_norm")),
+])
+def test_classify_the_gate_and_the_output_norms(op_name, want):
+    assert devscope.classify(op_name) == want
+    assert {"attn_gate", "post_norm"} <= set(devscope.VOCABULARY)
 
 
 def test_bert_program_that_ran_maps_every_scope_it_uses():

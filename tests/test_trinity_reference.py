@@ -1,0 +1,638 @@
+"""The Trinity decoder through the normal path (``models/trinity.py`` over
+``parallel/transformer.py``'s gated attention and sandwich norms,
+``parallel/moe.py``'s held-experts path under a sigmoid router with a bias
+and a route scale, and the flash kernels' grouped mode, full and windowed,
+in interpret mode) against the benchmark's plain float32 reference
+(``benchmark/reference/trinity_large_preview.py``), on seeded weights at
+``trinity_tiny_config``: one dense layer and one period (sliding, full,
+sliding, sliding), hidden 64, 6 query heads on 2 key/value heads of 128, a
+window of 24 under S = 64, 8 routed experts of width 32 of which this share
+holds 2, top-2, a shared expert of width 48, vocab 256.
+
+The tiny configuration computes in float32, so the tolerance is 1e-5 (the
+two differ by accumulation order only)."""
+
+import math
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.reference import trinity_large_preview as reference  # noqa: E402
+from paddle_tpu import monitor  # noqa: E402
+from paddle_tpu.models import trinity  # noqa: E402
+from paddle_tpu.monitor import devscope  # noqa: E402
+from paddle_tpu.parallel import (decoder, moe, optim,  # noqa: E402
+                                 transformer as T)
+from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
+from paddle_tpu.parallel.rules import leaf_paths  # noqa: E402
+from paddle_tpu.parallel.train import stack_batches  # noqa: E402
+
+B, S, TOL = 2, 64, 1e-5
+# the reference reads the published keys
+MODEL = {"num_attention_heads": 6, "num_key_value_heads": 2, "head_dim": 128,
+         "hidden_size": 64, "rms_norm_eps": 1e-5, "rope_theta": 10000,
+         "sliding_window": 24, "layer_types": list(trinity.LAYER_TYPES),
+         "score_func": "sigmoid", "route_norm": True, "route_scale": 2.448,
+         "mup_enabled": True, "num_shared_experts": 1,
+         "tie_word_embeddings": False, "num_experts_per_tok": 2,
+         "num_experts": 2, "moe_router_width": 8, "moe_first_expert_held": 2,
+         "num_dense_layers": 1, "first_expert_layer": 6,
+         "num_hidden_layers": 5}
+ATTENTION = ("ln1_scale", "ln1_post_scale", "ln2_scale", "ln2_post_scale",
+             "wq", "wk", "wv", "wz", "wo", "q_norm", "k_norm")
+SPARSE = ATTENTION + ("router", "we_gate_up", "we_down", "ws_gate_up",
+                      "ws_down")
+LEAVES = ["tok_emb", "lm_head", "lnf_scale"] \
+    + ["prefix_layers/l0/" + n for n in ATTENTION + ("w_gate_up", "w_down")] \
+    + ["params_layers/p%d/%s" % (i, n) for i in range(4) for n in SPARSE]
+
+
+def _trainer(seed=3, optimizer=None, **cfg):
+    return trinity.build_trinity_trainer(
+        trinity.trinity_tiny_config(**cfg), MeshSpec(dp=1),
+        optimizer=optimizer or optim.adamw(), seed=seed,
+        devices=jax.devices()[:1])
+
+
+def _ids(seed=0, n=1):
+    rng = np.random.RandomState(seed)
+    return [rng.randint(0, 256, (B, S)).astype(np.int32) for _ in range(n)]
+
+
+def _moved(params):
+    """Seeded weights with the norm scales moved off 1, so that a missing
+    or misplaced scale shows, a router steep enough that the scores are not
+    all one half, and biases large enough to change who is chosen at many
+    tokens."""
+    rng = np.random.RandomState(11)
+
+    def moved(path, a):
+        name = jax.tree_util.keystr(path)
+        if "scale" in name or "_norm" in name:
+            return np.asarray(a) * rng.uniform(0.5, 1.5, a.shape).astype("f4")
+        return np.asarray(a) * (3.0 if "router" in name else 1.0)
+
+    return jax.tree_util.tree_map_with_path(moved, params)
+
+
+def _leaf(tree, path):
+    for part in path.split("/"):
+        tree = tree[part]
+    return tree
+
+
+@pytest.fixture(scope="module")
+def both():
+    """Loss and gradients of program and reference on the same weights."""
+    tr = _trainer()
+    params = _moved(tr.state["params"])
+    ids = _ids()[0]
+    loss_fn = decoder.make_loss_fn(tr.cfg)
+    got = jax.jit(jax.value_and_grad(
+        lambda p: loss_fn(p, {"ids": jnp.asarray(ids)}), has_aux=True))(params)
+    want = jax.value_and_grad(
+        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
+            jax.tree.map(jnp.asarray, params))
+    return tr.cfg, params, ids, got, want
+
+
+def test_the_tiny_configuration_keeps_every_mechanism():
+    cfg = trinity.trinity_tiny_config()
+    assert cfg.prefix_kinds == ((24, True),)
+    assert cfg.layer_kinds == ((24, True), (None, False), (24, True),
+                               (24, True))
+    assert cfg.per_position and cfg.n_periods == 1 and cfg.moe_layers == 4
+    assert (cfg.n_heads, cfg.kv_heads, cfg.head_dim) == (6, 2, 128)
+    assert cfg.qk_norm == "head" and not cfg.tie_head
+    assert cfg.attn_gate and cfg.post_norm
+    assert cfg.route_scale == 2.448 and cfg.embed_scale == 8.0
+    assert (cfg.n_experts, cfg.experts_here, cfg.first_expert,
+            cfg.experts_per_token, cfg.shared_ffn_hidden) == (8, 2, 2, 2, 48)
+    assert cfg.routing == moe.SIGMOID_BIASED and cfg.router_bias_rate == 5e-5
+    assert T._packed_flash_blocks(cfg, 6, S, 2) == (16, 16)   # the kernels run
+    big = trinity.trinity_large_preview_config()
+    assert big.prefix_kinds == ((4096, True),) * 3 + ((None, False),) \
+        + ((4096, True),) * 2
+    assert big.layer_kinds == ((4096, True), (None, False), (4096, True),
+                               (4096, True)) and big.n_periods == 13
+    assert (big.n_layers, big.hidden, big.n_heads, big.kv_heads,
+            big.head_dim, big.ffn_hidden, big.dense_ffn_hidden,
+            big.shared_ffn_hidden, big.n_experts, big.experts_per_token,
+            big.experts_here, big.vocab_size, big.norm_eps, big.rope_theta
+            ) == (58, 3072, 48, 8, 128, 3072, 12288, 3072, 256, 4, 256,
+                  200192, 1e-5, 10000.0)
+    assert big.embed_scale == math.sqrt(3072)
+    assert trinity.LAYER_TYPES.count("full_attention") == 15
+    # the cell's cut: published layers 5 to 9
+    cut = trinity.trinity_large_preview_config(
+        n_layers=5, n_dense_layers=1, experts_held=8, vocab_size=25024)
+    assert cut.prefix_kinds == ((4096, True),) and cut.n_periods == 1
+    with pytest.raises(AssertionError):     # the published 60 end mid-period
+        trinity.trinity_large_preview_config(n_layers=60)
+
+
+def test_loss_equals_the_reference(both):
+    _, _, _, ((got, _), _), (want, _) = both
+    assert abs(float(got) - float(want)) / float(want) < TOL
+
+
+def test_every_position_s_logits_equal_the_reference(both):
+    cfg, params, ids, _, _ = both
+    x, _ = jax.jit(lambda p, i: decoder.forward(p, i, cfg))(params, ids)
+    got = T.rms_norm(x, params["lnf_scale"], cfg.norm_eps) @ params["lm_head"].T
+    _, want = reference.forward(params, ids, MODEL)
+    np.testing.assert_allclose(got, np.stack(want), rtol=1e-4, atol=TOL)
+
+
+@pytest.mark.parametrize("path", LEAVES)
+def test_gradient_of_every_leaf_equals_the_reference(both, path):
+    _, params, _, (_, got), (_, want) = both
+    g, w = np.asarray(_leaf(got, path)), np.asarray(_leaf(want, path))
+    assert g.shape == _leaf(params, path).shape and np.abs(w).max() > 0
+    np.testing.assert_allclose(g, w, rtol=1e-4, atol=TOL * np.abs(w).max())
+
+
+def test_the_leaves_tested_are_all_there_are(both):
+    _, params, _, (_, got), (_, want) = both
+    paths, _, _ = leaf_paths(params)
+    assert set(paths) == set(LEAVES) | {"router_bias"}
+    # the bias chooses and nothing else: no gradient reaches it
+    assert params["router_bias"].shape == (4, 8)
+    assert not np.asarray(got["router_bias"]).any()
+    assert not np.asarray(want["router_bias"]).any()
+    p1 = params["params_layers"]["p1"]
+    assert p1["wz"].shape == p1["wq"].shape == (1, 64, 6 * 128)
+    assert p1["wk"].shape == (1, 64, 2 * 128)
+    assert p1["ln1_post_scale"].shape == p1["ln2_post_scale"].shape == (1, 64)
+    assert p1["ws_gate_up"].shape == (1, 64, 96)
+    assert p1["we_gate_up"].shape == (1, 2, 64, 64)
+    assert p1["router"].shape == (1, 64, 8)
+    assert params["prefix_layers"]["l0"]["w_gate_up"].shape == (64, 192)
+
+
+def test_sharding_specs_and_gradient_syncs_follow_the_tree():
+    cfg = trinity.trinity_tiny_config()
+    params = jax.eval_shape(
+        lambda: T.init_transformer_params(jax.random.PRNGKey(0), cfg))
+    for tree in (T.transformer_param_specs(cfg), T.grad_sync_axes(cfg)):
+        assert jax.tree.structure(
+            tree, is_leaf=lambda x: isinstance(x, (tuple, T.P))) == \
+            jax.tree.structure(params)
+    specs = T.transformer_param_specs(cfg)
+    # the gate's projection is cut as the queries' are (columns by head)
+    assert specs["params_layers"]["p0"]["wz"] \
+        == specs["params_layers"]["p0"]["wq"] == T.P(None, None, "tp")
+    assert specs["params_layers"]["p0"]["ln1_post_scale"] == T.P(None, None)
+    assert specs["prefix_layers"]["l0"]["wz"] == specs["router_bias"] == T.P()
+
+
+def test_one_rule_builds_the_ffn_leaves_of_both_trees(both):
+    """Without the dense prefix the layers own the same leaves and the tree
+    is ONE stack: it holds the shared expert, the selection biases, the gate
+    and the output norms as the per-position tree does, and its loss is the
+    reference's (read a position at a time)."""
+    cfg = trinity.trinity_tiny_config(n_layers=4, n_dense_layers=0)
+    assert not cfg.per_position and cfg.moe_layers == 4
+    params = _moved(T.init_transformer_params(jax.random.PRNGKey(5), cfg))
+    stacked = params["params_layers"]
+    assert set(stacked) == set(SPARSE) and "prefix_layers" not in params
+    assert params["router_bias"].shape == (4, 8)
+    assert np.abs(params["router_bias"]).min() > 0
+    ids = _ids(seed=2)[0]
+    got, stepped = jax.jit(decoder.make_loss_fn(cfg))(
+        params, {"ids": jnp.asarray(ids)})
+    by_position = dict(params, params_layers={
+        "p%d" % i: {k: v[i::4] for k, v in stacked.items()}
+        for i in range(4)})
+    want = reference.forward(
+        by_position, ids, dict(MODEL, num_dense_layers=0, num_hidden_layers=4),
+        keep_logits=False)[0]
+    assert abs(float(got) - float(want)) / float(want) < TOL
+    assert stepped["router_bias"].shape == (4, 8)
+
+
+def _layer(seed=4, at="p1", **kw):
+    """A sparse layer's leaves (position 1: the full layer), unstacked, and
+    a stream to run it on."""
+    cfg = trinity.trinity_tiny_config(**kw)
+    params = _moved(T.init_transformer_params(jax.random.PRNGKey(seed), cfg))
+    pl = jax.tree.map(lambda a: jnp.asarray(a[0]),
+                      params["params_layers"][at])
+    x = jax.random.normal(jax.random.PRNGKey(seed + 1), (B, S, 64))
+    return cfg, pl, x, jnp.asarray(params["router_bias"][1])
+
+
+def test_the_gate_multiplies_the_heads_before_wo():
+    """With ``wz`` zeroed every gate is one half, and the attention branch
+    BEFORE its output norm is half the ungated block's; a gate after ``wo``
+    has no shape to stand on (6 x 128 columns against 64), so what the
+    reference's faults misplace is what it reads and what it multiplies."""
+    cfg, pl, x, bias = _layer(post_norm=False)
+    quiet = dict(pl, we_down=jnp.zeros_like(pl["we_down"]),
+                 ws_down=jnp.zeros_like(pl["ws_down"]))
+    kind = cfg.layer_kinds[1]
+    halved, _ = T.transformer_layer(
+        dict(quiet, wz=jnp.zeros_like(pl["wz"])), x, cfg, kind, False, bias)
+    ungated, _ = T.transformer_layer(
+        quiet, x, trinity.trinity_tiny_config(post_norm=False,
+                                              attn_gate=False),
+        kind, False, bias)
+    np.testing.assert_allclose(halved - x, 0.5 * (ungated - x), rtol=1e-4,
+                               atol=1e-5)
+    gated, _ = T.transformer_layer(quiet, x, cfg, kind, False, bias)
+    assert np.abs(gated - halved).max() > 1e-2
+    # the gate itself: the sigmoid of the projection, in float32
+    o = jax.random.normal(jax.random.PRNGKey(1), (B, S, 768))
+    z = jax.random.normal(jax.random.PRNGKey(2), (B, S, 768))
+    np.testing.assert_allclose(T._gate_heads(o, z), o / (1 + np.exp(-z)),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_each_branch_s_output_is_normed_before_it_is_added():
+    """Sandwich norms: scaling ``wo`` (or the experts' down projections) by
+    ten leaves the layer's output where it was, because the branch's output
+    norm divides it out; without the output norms it does not."""
+    cfg, pl, x, bias = _layer()
+    kind = cfg.layer_kinds[1]
+    out, _ = T.transformer_layer(pl, x, cfg, kind, False, bias)
+    scaled = dict(pl, wo=10 * pl["wo"], we_down=10 * pl["we_down"],
+                  ws_down=10 * pl["ws_down"])
+    again, _ = T.transformer_layer(scaled, x, cfg, kind, False, bias)
+    # (up to the norm's eps beside a branch's small mean square)
+    np.testing.assert_allclose(again, out, rtol=1e-2, atol=5e-3)
+    bare = trinity.trinity_tiny_config(post_norm=False)
+    plain = {k: v for k, v in pl.items() if "post" not in k}
+    a, _ = T.transformer_layer(plain, x, bare, kind, False, bias)
+    b, _ = T.transformer_layer(
+        {k: scaled[k] for k in plain}, x, bare, kind, False, bias)
+    assert np.abs(a - b).max() > 1.0
+    # a branch's norm weight reaches the stream as it is
+    zeroed, _ = T.transformer_layer(
+        dict(pl, ln1_post_scale=jnp.zeros(64), ln2_post_scale=jnp.zeros(64)),
+        x, cfg, kind, False, bias)
+    np.testing.assert_array_equal(zeroed, x)
+
+
+@pytest.mark.parametrize("gain", [None, 1.0, 0.25])
+def test_the_output_norms_scales_are_seeded_at_the_gain(gain):
+    """``post_norm_gain`` seeds the output norms' scales and nothing else:
+    the input norms' stay at one, and the model's own gain is
+    ``POST_NORM_GAIN``."""
+    kw = {} if gain is None else {"post_norm_gain": gain}
+    cfg = trinity.trinity_tiny_config(**kw)
+    want = trinity.POST_NORM_GAIN if gain is None else gain
+    assert cfg.post_norm_gain == want
+    params = T.init_transformer_params(jax.random.PRNGKey(0), cfg)
+    for tree in (params["prefix_layers"]["l0"], params["params_layers"]["p2"]):
+        for name in ("ln1", "ln2"):
+            np.testing.assert_array_equal(
+                tree[name + "_post_scale"], np.float32(want))
+            np.testing.assert_array_equal(tree[name + "_scale"], 1.0)
+
+
+@pytest.mark.parametrize("std", [None, 0.1, 0.02])
+def test_the_selection_biases_are_seeded_with_the_std(std):
+    """``router_bias_std`` scales the seeded selection biases and nothing
+    else: one draw a share, tiled over the shares; the published size's own
+    is ``ROUTER_BIAS_STD``, the tiny size's the block's default."""
+    assert trinity.trinity_large_preview_config().router_bias_std \
+        == trinity.ROUTER_BIAS_STD
+    kw = {} if std is None else {"router_bias_std": std}
+    cfg = trinity.trinity_tiny_config(**kw)
+    want = 0.1 if std is None else std
+    assert cfg.router_bias_std == want
+    key = jax.random.PRNGKey(5)
+    bias = T._router_bias(key, cfg)["router_bias"]
+    draw = jax.random.normal(key, (cfg.moe_layers, cfg.experts_here))
+    assert bias.shape == (4, 8) and bias.dtype == jnp.float32
+    np.testing.assert_allclose(
+        bias, np.tile(want * np.asarray(draw), (1, 4)), rtol=1e-6)
+
+
+@pytest.mark.parametrize("std, balanced", [(trinity.ROUTER_BIAS_STD, True),
+                                           (0.1, False)])
+def test_the_seeded_biases_leave_every_held_expert_near_the_mean(std,
+                                                                 balanced):
+    """Why ``ROUTER_BIAS_STD``: a bias is added to a sigmoid SCORE, and the
+    fourth of 256 scores stands where the sigmoid is flat.  On unit-normal
+    logits (what seeded weights give a token's own row) the cell's 6,144
+    tokens leave each of a share's 8 experts between a third of and twice
+    the mean's 96 pairs at the model's std; at the block's default 0.1 some
+    expert of some layer draws under a tenth of the mean or over five times
+    it, and the grouped matmuls' tiles, so the step's time, follow the
+    seed."""
+    cfg = trinity.trinity_large_preview_config(
+        n_layers=5, n_dense_layers=1, experts_held=8, router_bias_std=std)
+    bias = T._router_bias(jax.random.PRNGKey(11), cfg)["router_bias"]
+    logits = jax.random.normal(jax.random.PRNGKey(12), (6144, 256))
+    held = []
+    for layer in range(cfg.moe_layers):
+        _, top_e, _ = moe.route_top_k(jnp.zeros((1, 256)), None, 4,
+                                      moe.SIGMOID_BIASED, logits=logits,
+                                      bias=bias[layer])
+        held.append(np.bincount(np.asarray(top_e).ravel(),
+                                minlength=256)[:8])
+    held = np.stack(held)
+    assert 512 < held.sum(1).min() and held.sum(1).max() < 1024
+    if balanced:
+        assert 32 <= held.min() and held.max() <= 192, held
+    else:
+        assert held.min() < 10 or held.max() > 480, held
+
+
+def test_the_full_layer_carries_no_positions_and_the_sliding_ones_a_window():
+    """The full layer's q and k are blind to WHERE their rows stand (the
+    same rows with ``first`` moved give the same q and k), the sliding
+    layers' are not; and a sliding layer's rows past the window do not see
+    the first tokens, while the full layer's do."""
+    cfg, pl, x, bias = _layer()
+    a = T._qkv(pl, x, cfg, False)
+    b = T._qkv(pl, x, cfg, False, first=7)
+    c = T._qkv(pl, x, cfg, True, first=7)
+    np.testing.assert_array_equal(a[0], b[0])
+    assert np.abs(np.asarray(a[0]) - np.asarray(c[0])).max() > 1e-2
+    other = x.at[:, :8].set(jax.random.normal(jax.random.PRNGKey(3),
+                                              (B, 8, 64)))
+    quiet = dict(pl, we_down=jnp.zeros_like(pl["we_down"]),
+                 ws_down=jnp.zeros_like(pl["ws_down"]))
+    for kind, sees in (((24, True), False), ((None, False), True)):
+        one, _ = T.transformer_layer(quiet, x, cfg, kind, False, bias)
+        two, _ = T.transformer_layer(quiet, other, cfg, kind, False, bias)
+        late = np.abs(np.asarray(one - two))[:, 8 + 24:].max()
+        assert (late > 1e-3) == sees, (kind, late)
+
+
+def _layer_inputs():
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    whole = moe.init_dropless_moe_params(ks[0], 8, 64, 32)
+    whole["router"] = whole["router"] * 3.0
+    whole["ws_gate_up"] = jax.random.normal(ks[2], (64, 96)) / 8
+    whole["ws_down"] = jax.random.normal(ks[3], (48, 64)) / 7
+    bias = 0.3 * jax.random.normal(ks[4], (8,))
+    return whole, jax.random.normal(ks[1], (S, 64)), bias
+
+
+def test_the_shares_routed_parts_and_the_shared_expert_once_are_the_layer():
+    """The PROGRAM's FFN branch of a sparse layer on each of the four shares
+    of 2 routed experts: every share computes the shared expert, so the four
+    routed parts summed, plus the shared expert counted ONCE, is the
+    REFERENCE's ``f`` with all 8 experts held, BEFORE the output norm.  The
+    norm is not linear: the shares' NORMED branches do not add up to the
+    uncut layer's, so the sum over shares is taken before it (as an exchange
+    between the chips would)."""
+    whole, m, bias = _layer_inputs()
+    cfg = trinity.trinity_tiny_config()
+    model = dict(MODEL, num_experts=8, moe_first_expert_held=0)
+    routed_want, shared_want = reference.ffn_sum(
+        m, whole.__getitem__, bias, model)
+
+    def ffn_branch(first):
+        """``transformer_layer``'s FFN branch on normed rows ``m``, for the
+        share that holds experts [first, first + 2)."""
+        share = dict(whole, we_gate_up=whole["we_gate_up"][first:first + 2],
+                     we_down=whole["we_down"][first:first + 2])
+        y, aux = moe.dropless_moe_ffn(
+            share, m, 2, rule=moe.SIGMOID_BIASED, first_held=first,
+            bias=bias, scale=cfg.route_scale)
+        shared = T.gated_ffn({"w_gate_up": share["ws_gate_up"],
+                              "w_down": share["ws_down"]}, m[None], cfg)[0]
+        return y, shared, aux
+
+    parts = [ffn_branch(first) for first in range(0, 8, 2)]
+    for first, (y, shared, aux) in zip(range(0, 8, 2), parts):
+        np.testing.assert_allclose(shared, shared_want, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(y, reference.ffn_sum(
+            m, dict(whole, we_gate_up=whole["we_gate_up"][first:first + 2],
+                    we_down=whole["we_down"][first:first + 2]).__getitem__,
+            bias, dict(model, moe_first_expert_held=first))[0],
+            rtol=1e-5, atol=1e-5)
+    assert sum(int(p[2]["rows_held"]) for p in parts) == 2 * S
+    assert all(float(jnp.abs(p[0]).max()) > 0 for p in parts)
+    np.testing.assert_allclose(sum(p[0] for p in parts) + parts[0][1],
+                               routed_want + shared_want, rtol=1e-5,
+                               atol=1e-5)
+    # ... counted four times it is not
+    assert np.abs(sum(p[0] + p[1] for p in parts)
+                  - (routed_want + shared_want)).max() > 0.1
+    # ... and the norm is not linear: the normed shares do not add up
+    g = jnp.ones(64)
+    normed = sum(T.rms_norm(p[0] + p[1], g, 1e-5) for p in parts)
+    assert np.abs(normed - T.rms_norm(routed_want + shared_want, g, 1e-5)
+                  ).max() > 0.1
+
+
+def test_the_route_scale_multiplies_the_weights_and_nothing_else():
+    whole, m, bias = _layer_inputs()
+    for rule in moe.RULES:
+        one = moe.route_top_k(whole["router"], m, 2, rule, bias=bias)
+        two = moe.route_top_k(whole["router"], m, 2, rule, bias=bias,
+                              scale=2.448)
+        np.testing.assert_allclose(two[0], 2.448 * one[0], rtol=1e-6)
+        np.testing.assert_array_equal(two[1], one[1])
+    top_p, _, _ = moe.route_top_k(whole["router"], m, 2, moe.SIGMOID_BIASED,
+                                  bias=bias, scale=2.448)
+    np.testing.assert_allclose(top_p.sum(-1), 2.448, rtol=1e-5)
+
+
+def test_the_bias_chooses_and_does_not_weigh():
+    """A bias that lifts one expert over all others has every token choose
+    it, and the weights are still the scores' (its own sigmoid over the
+    chosen sigmoids' sum, times the scale)."""
+    whole, m, _ = _layer_inputs()
+    bias = jnp.zeros(8).at[5].set(10.0)
+    top_p, top_e, aux = moe.route_top_k(
+        whole["router"], m, 2, moe.SIGMOID_BIASED, bias=bias, scale=2.448)
+    assert (np.asarray(top_e)[:, 0] == 5).all() and int(aux["load"][5]) == S
+    s = np.asarray(jax.nn.sigmoid(m @ whole["router"]))
+    chosen = np.take_along_axis(s, np.asarray(top_e), 1)
+    np.testing.assert_allclose(
+        top_p, 2.448 * chosen / chosen.sum(-1, keepdims=True), rtol=1e-5)
+
+
+def test_the_embedding_enters_the_stream_times_the_multiplier():
+    cfg = trinity.trinity_tiny_config()
+    params = T.init_transformer_params(jax.random.PRNGKey(2), cfg)
+    ids = jnp.asarray(_ids()[0])
+    got = T.embed(params, ids, cfg)
+    np.testing.assert_allclose(got, 8.0 * params["tok_emb"][ids], rtol=1e-6)
+    # the rows are seeded at the fan-in scale: the stream starts at unit scale
+    big = T.init_transformer_params(
+        jax.random.PRNGKey(2), trinity.trinity_tiny_config(vocab_size=4096))
+    assert abs(float(jnp.std(T.embed(
+        big, jnp.arange(4096)[None], trinity.trinity_tiny_config(
+            vocab_size=4096)))) - 1.0) < 0.02
+
+
+@pytest.fixture(scope="module")
+def witnessed():
+    """A trainer that holds HALF the experts (4 of 8, the second half), its
+    weights moved as ``both``'s, and its own logits at the witness's
+    positions (with 2 of 8 held, many positions meet no held expert and a
+    routing fault does not touch them)."""
+    tr = _trainer(experts_held=4, first_expert=4)
+    params = _moved(tr.state["params"])
+    tr.state["params"] = jax.tree.map(jnp.asarray, params)
+    ids = _ids(seed=9)[0][:1]       # one sequence: the cell's batch
+    program = np.asarray(tr.logits_at(ids, reference.witness_positions(S)))
+    return params, ids, program, dict(MODEL, num_experts=4,
+                                      moe_first_expert_held=4)
+
+
+def test_the_witness_reads_both_sides_of_the_window_s_edge(witnessed):
+    """What ``benchmark/drivers/train_scan_witnessed.py`` checks on the chip:
+    the trainer's own forward at the witness's positions against the
+    reference's logits; the statistic is the larger group's third
+    quartile."""
+    params, ids, program, model = witnessed
+    groups = reference.witness_groups(S)
+    assert groups["edge"].tolist() == list(range(12, 20)) + [60, 61, 62, 63]
+    assert not set(groups["edge"]) & set(groups["spread"])
+    big = reference.witness_groups(6144)
+    assert big["edge"].tolist() == list(range(4088, 4104)) + list(
+        range(6136, 6144))
+    # (one of the 256 spread positions, 4,092, stands in the edge group)
+    assert len(big["spread"]) == 255 and (big["spread"] >= 4096).sum() == 85
+    each = reference.position_errors(program, params, {"ids": ids}, model)
+    assert each.shape == (S,) and each.max() < TOL
+    parts = reference.group_errors(program, params, {"ids": ids}, model)
+    assert reference.logits_error(program, params, {"ids": ids}, model) \
+        == max(parts.values())
+    assert parts["edge"] == np.quantile(each[:12], 0.75)
+
+
+@pytest.mark.parametrize("fault", reference.FAULTS[:-1])
+def test_the_witness_sees_every_fault(witnessed, fault):
+    """Each fault in the reference (the gate dropped, fed the un-normed
+    stream or moved onto the values; an output norm dropped, or the shared
+    expert added past it; rotary on the full layer; no window; the
+    multiplier dropped; the route scale 1; the bias leaking into the
+    weights or left out of the choice; ...) moves its logits away from the
+    program's by a thousand times what the two differ by when both are
+    sound, at the witness's own statistic."""
+    params, ids, program, model = witnessed
+    moved = reference.logits_error(program, params, {"ids": ids}, model,
+                                   faults=(fault,))
+    assert moved > 1e3 * TOL
+
+
+def test_bfloat16_throughout_moves_the_reference_s_loss(both):
+    _, params, ids, _, (want, _) = both
+    bad = reference.loss(params, {"ids": ids}, MODEL,
+                         faults=("bfloat16_throughout",))
+    assert abs(bad - float(want)) / float(want) > 2 * TOL
+
+
+def test_the_reference_in_small_pieces_equals_itself_whole(both, monkeypatch):
+    _, params, ids, _, (want, want_grad) = both
+    params = jax.tree.map(jnp.asarray, params)
+    monkeypatch.setattr(reference, "QUERY_BLOCK", 16)       # 4 blocks of 64
+    monkeypatch.setattr(reference, "VOCAB_CHUNK", 100)      # 100, 100, 56
+    monkeypatch.setattr(reference, "EXPERT_GROUP", 1)
+    monkeypatch.setattr(reference, "DENSE_CHUNK", 20)       # 20, 20, 8
+    loss, grad = jax.value_and_grad(
+        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
+            params)
+    assert abs(float(loss) - float(want)) / float(want) < 1e-6
+    for g, w in zip(jax.tree.leaves(grad), jax.tree.leaves(want_grad)):
+        np.testing.assert_allclose(g, w, rtol=1e-4,
+                                   atol=1e-5 * max(np.abs(w).max(), 1e-30))
+
+
+@pytest.fixture(scope="module")
+def trained(tmp_path_factory):
+    """Two trainers of one seed (remat on) over the same two batches: ``one``
+    takes two steps, ``scan`` one ``run_steps`` under a monitor session; what
+    the tests below read of them, gathered once (each trainer compiles its
+    programs anew)."""
+    batches = [{"ids": i} for i in _ids(seed=5, n=2)]
+    one, scan = _trainer(remat=True), _trainer(remat=True)
+    out = {"bias0": np.asarray(one.state["params"]["router_bias"])}
+    _, aux = jax.jit(lambda p, i: decoder.forward(p, i, one.cfg))(
+        one.state["params"], batches[0]["ids"])
+    out["load"] = np.asarray(aux["load"], np.float32)
+    out["singly"] = [float(one.step(batches[0], 1e-3))]
+    out["bias1"] = np.asarray(one.state["params"]["router_bias"])
+    out["singly"].append(float(one.step(batches[1], 1e-3)))
+    assert monitor.active() is None
+    mon = monitor.enable(str(tmp_path_factory.mktemp("monitor")),
+                         flight=False)
+    try:
+        reg = mon.registry
+        counters = {n: reg.counter("monitor.train." + n)
+                    for n in ("moe_assignments", "moe_rows_held")}
+        start = {n: c.value for n, c in counters.items()}
+        out["scanned"] = np.asarray(scan.run_steps(
+            stack_batches(scan.mesh, decoder.BATCH_SPECS, batches), 1e-3))
+        out["counted"] = {n: c.value - start[n] for n, c in counters.items()}
+        out["gauges"] = {n: reg.gauge(n).value for n in (
+            "monitor.train.moe_held_rows_share",
+            "monitor.train.moe_load_max_over_mean",
+            "monitor.train.attn_gate_mean",
+            "monitor.train.router_bias_abs_max",
+            "monitor.kernels.flash_grid_steps")}
+    finally:
+        monitor.disable()
+    out["params"] = [jax.tree.map(np.asarray, t.state["params"])
+                     for t in (one, scan)]
+    out["names"] = devscope.scope_maps()["trinity.run_steps"]
+    return out
+
+
+def test_a_step_moves_the_biases_by_the_rate_against_the_load(trained):
+    """``load_balance_coeff`` as the sign rule's rate: after one step every
+    layer's biases stand 5e-5 up or down, against that layer's load over
+    this chip's tokens, for all 8 experts."""
+    load = trained["load"]
+    assert load.shape == (4, 8) and (load.sum(-1) == B * S * 2).all()
+    want = trained["bias0"] + np.float32(5e-5) * np.sign(
+        load.mean(-1, keepdims=True) - load)
+    np.testing.assert_array_equal(trained["bias1"], want.astype("f4"))
+    assert np.abs(trained["bias1"] - trained["bias0"]).max() > 0
+
+
+def test_run_steps_over_two_batches_equals_two_steps(trained):
+    singly = trained["singly"]
+    np.testing.assert_allclose(trained["scanned"], singly, rtol=1e-5)
+    assert singly[0] != singly[1]
+    for a, b in zip(*(jax.tree.leaves(p) for p in trained["params"])):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=5e-5)
+
+
+def test_counters_and_gauges_only_under_a_monitor_session(trained):
+    pairs = 2 * B * S * 2 * 4       # batches x tokens x top-2 x MoE layers
+    counted, gauges = trained["counted"], trained["gauges"]
+    assert counted["moe_assignments"] == pairs
+    assert 0 < counted["moe_rows_held"] < pairs
+    share = gauges["monitor.train.moe_held_rows_share"]
+    assert share == counted["moe_rows_held"] / pairs and 0.1 < share < 0.5
+    assert gauges["monitor.train.moe_load_max_over_mean"] >= 1.0
+    # seeded gates stand near one half: neither stuck shut nor open
+    assert 0.4 < gauges["monitor.train.attn_gate_mean"] < 0.6
+    assert gauges["monitor.train.router_bias_abs_max"] > 0
+    assert gauges["monitor.kernels.flash_grid_steps"] > 0
+
+
+def test_the_new_scopes_hold_their_instructions(trained):
+    names = trained["names"]
+    got = {devscope.classify(op) for op in names.values()}
+    for scope in ("attention", "attn_gate", "post_norm", "shared_expert",
+                  "moe", "router", "mlp", "layer_norm", "lm_head", "embed"):
+        assert ("forward", scope) in got and ("backward", scope) in got, scope
+    for scope in ("attention", "attn_gate", "post_norm", "shared_expert"):
+        assert ("recompute", scope) in got, scope
+    # the gate and the output norms lie INSIDE the layer's scopes
+    bare = [devscope._WRAPPERS.sub("", op) for op in names.values()]
+    paths = [op for op in bare if "/post_norm/" in op]
+    assert any("/attention/post_norm/" in op for op in paths)
+    assert any("/moe/post_norm/" in op for op in paths)
+    assert any("/mlp/post_norm/" in op for op in paths)
+    assert all("/attention/attn_gate/" in op for op in bare
+               if "/attn_gate/" in op)
