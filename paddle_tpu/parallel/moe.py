@@ -5,7 +5,12 @@
   expert, the rows gathered in that order, and two GROUPED matmuls run over
   the sorted rows (the Pallas ``megablox`` kernels that ship with JAX: row i
   meets the weights of its own group only, so nothing is computed for a
-  pair that was not routed), the router weight riding the hidden rows
+  pair that was not routed; their tiles follow each call's static shapes,
+  ``_tiling``: a row tile of which a group expects four, the contraction
+  whole where the blocks fit the kernel's VMEM, every cut in equal parts;
+  measured on the v5e by ``scripts/moe_grouped_matmul_bench.py``, PERF.md
+  section 6, PR 46),
+  the router weight riding the hidden rows
   between them so that nothing after the down projection is kept for the
   backward; then the sort is undone and each token's k rows are summed
   (the Pallas row kernel ``kernels/moe_rows.py``: a block of tokens' pairs
@@ -45,6 +50,7 @@ from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
 from . import collectives as col
 from .mesh import DP
+from .. import monitor
 from ..kernels._common import on_tpu
 from ..kernels.moe_rows import moe_rows_sum
 from ..monitor import devscope
@@ -228,12 +234,93 @@ _move.defvjp(lambda v, to, back: (_move(v, to, back), (back, to)),
              _scoped(lambda res, g: (_move(g, *res), None, None)))
 
 
-def _tiling(m, k, n):
-    """Tiles (rows, contraction, columns) of the megablox kernels: 512 x 1024
-    x 1024 at training sizes (one OLMoE layer's expert FFN, forward and
-    backward, took 36.4 ms with it, 39.4 at 512 x 512 x 1024, 452 at the
-    default 128^3; PERF.md section 6, PR 27), a smaller dimension whole."""
-    return min(m, 512), min(k, 1024), min(n, 1024)
+ROW_TILES = (512, 256, 128)      # tm: the tallest a group expects TILES_A_GROUP of
+TILES_A_GROUP = 4                # so the tile a group's edge wastes is a quarter of it
+LANES = 128                      # a cut dimension is whole lane tiles
+MIN_COLUMNS = 256                # gmm's column tile is not under this, nor under tm
+VMEM_BUDGET = 12 * 2 ** 20       # of the 16 MiB a v5e kernel's scope has by default
+
+
+def _vmem_bytes(tm, tk, tn, itemsize, dw=False):
+    """What a grid step of ``gmm`` (``tgmm`` where ``dw``) holds in VMEM at
+    these tiles: both operand blocks and the result's, twice each for the
+    pipeline, and the float32 accumulator of the result's block.  ``gmm``
+    reads [tm, tk] rows and [tk, tn] weights for a [tm, tn] block; ``tgmm``
+    reads [tm, tk] and [tm, tn] rows for a [tk, tn] block of dW.  The
+    compiled kernels take up to 2.1 MiB more at the cells' shapes (the
+    masks' float32 copies, a transposed weight block's copy):
+    ``tests/test_flash_tpu_compile.py`` holds that against the scope."""
+    read = tm * tk + (tm * tn if dw else tk * tn)
+    out = tk * tn if dw else tm * tn
+    return 2 * (read + out) * itemsize + 4 * out
+
+
+def _parts(size, least=LANES):
+    """``size`` whole, then its equal parts of whole lane tiles that are not
+    under ``least``, widest first."""
+    return [size] + [size // p for p in range(2, size // least + 1)
+                     if size % p == 0 and size // p % LANES == 0]
+
+
+def _tiling(m, k, n, groups=1, itemsize=2, dw=False):
+    """Tiles (rows, contraction, columns) of one megablox call, from what
+    the call is compiled for: ``m`` rows in ``groups`` groups, contraction
+    ``k`` and ``n`` columns of ``itemsize`` bytes (``dw``: ``tgmm``, whose
+    ``k`` x ``n`` is a group's block of dW).
+
+    - ROWS.  ``gmm`` and ``tgmm`` visit a whole row tile for every (tile,
+      group) pair that has a row, so a call pays about ``rows + groups *
+      tm`` rows of MXU work: ``tm`` is the tallest of ROW_TILES of which a
+      group expects TILES_A_GROUP (``m // groups`` rows; 128 at the least),
+      and all the rows where they are fewer.
+    - Only tiles that DIVIDE their dimension (a remainder tile of the
+      contraction is masked element by element, one of the columns computed
+      whole and cut) and keep ``_vmem_bytes`` within VMEM_BUDGET.
+    - ``gmm``, whose grid is (column tiles, visits, k tiles): the
+      contraction WHOLE where a column tile fits beside it, else in the
+      fewest equal parts that leave room for one, and then the widest
+      column tile.  With one k tile a group's weight block keeps its index
+      over the group's consecutive visits and is fetched once, where a cut
+      contraction fetches it again every visit and reads its partial sums
+      back: a thin group's call (Trinity's 96 rows an expert) becomes bound
+      by its weights' bytes, which is what the shapes require.  Every
+      column tile sweeps all the rows again, so it is not narrower than the
+      row tile (MIN_COLUMNS at the least).
+    - ``tgmm``, whose grid is (column tiles, k tiles, visits): the block of
+      dW that has the rows read the fewest times, ``k / tk`` sweeps of the
+      cotangent's and ``n / tn`` of the rows'.
+
+    Measured on the v5e at one layer's shapes of the five sparse cells
+    (``scripts/moe_grouped_matmul_bench.py``; PERF.md section 6, PRs 27 and
+    46): 512 x 1024 x 1024, PR 27's best of three at OLMoE's shape, is
+    still what that shape's dW and down projection get."""
+    expect = m // groups
+    tm = min(m, next((t for t in ROW_TILES if TILES_A_GROUP * t <= expect),
+                     ROW_TILES[-1]))
+    fits = lambda tk, tn: _vmem_bytes(tm, tk, tn, itemsize, dw) <= VMEM_BUDGET
+    if dw:
+        fitting = [(tk, tn) for tk in _parts(k) for tn in _parts(n)
+                   if fits(tk, tn)]
+        return (tm,) + min(fitting, key=lambda t: (
+            k // t[0] * n + n // t[1] * k, -t[1]))
+    columns = _parts(n, min(n, max(tm, MIN_COLUMNS)))
+    return (tm,) + next(((tk, tn) for tk in _parts(k) for tn in columns
+                         if fits(tk, tn)), (LANES, columns[-1]))
+
+
+def _note_tiling(kernel, tiling, m, groups):
+    """Under a monitor session, one count a COMPILED call of a megablox
+    kernel (this runs when a program is traced, not when it runs):
+    ``monitor.kernels.moe_grouped_matmul_calls`` by kernel, tiles and
+    ``thin`` (1 where a group expects fewer rows than the tallest row
+    tile), so a summary says how many of a program's grouped matmuls were
+    compiled off the 512-row tile."""
+    mon = monitor.active()
+    if mon is not None:
+        mon.registry.counter(
+            "monitor.kernels.moe_grouped_matmul_calls", kernel=kernel,
+            tm=tiling[0], tk=tiling[1], tn=tiling[2],
+            thin=int(m // groups < ROW_TILES[0])).incr()
 
 
 def _whole_row_tiles(rows, tm):
@@ -243,7 +330,9 @@ def _whole_row_tiles(rows, tm):
 
 def _gmm(rows, weights, group_sizes, transpose_rhs=False):
     m, k = rows.shape
-    tiling = _tiling(m, k, weights.shape[1 if transpose_rhs else 2])
+    tiling = _tiling(m, k, weights.shape[1 if transpose_rhs else 2],
+                     weights.shape[0], rows.dtype.itemsize)
+    _note_tiling("gmm", tiling, m, weights.shape[0])
     out = gmm(_whole_row_tiles(rows, tiling[0]), weights, group_sizes,
               rows.dtype, tiling, transpose_rhs=transpose_rhs,
               interpret=not on_tpu())
@@ -254,16 +343,21 @@ def _gmm(rows, weights, group_sizes, transpose_rhs=False):
 def _grouped_matmul(rows, weights, group_sizes):
     """rows [M, K] sorted by group, weights [G, K, N]: row i times the weights
     of its own group, nothing for a pair that was not routed.  The Pallas
-    grouped matmul that ships with JAX (``megablox``): a quarter faster here
-    than XLA's lowering of ``jax.lax.ragged_dot`` (36.4 against 48.2 ms), and
-    its instructions keep the program's scope in their ``op_name``."""
+    grouped matmul that ships with JAX (``megablox``) at the tiles
+    ``_tiling`` gives each call: a quarter faster at OLMoE's shape than
+    XLA's lowering of ``jax.lax.ragged_dot`` (35.9 against 48.3 ms a
+    layer's forward and backward; 2.8 against 6.4 at Trinity's thin
+    groups), and its instructions keep the program's scope in their
+    ``op_name``."""
     return _gmm(rows, weights, group_sizes)
 
 
 @devscope.scoped(devscope.MOE)
 def _grouped_matmul_bwd(res, g):
     rows, weights, group_sizes = res
-    tiling = _tiling(*rows.shape, g.shape[1])
+    tiling = _tiling(*rows.shape, g.shape[1], weights.shape[0],
+                     rows.dtype.itemsize, dw=True)
+    _note_tiling("tgmm", tiling, rows.shape[0], weights.shape[0])
     d_weights = tgmm(
         _whole_row_tiles(rows, tiling[0]).swapaxes(0, 1),
         _whole_row_tiles(g, tiling[0]), group_sizes, weights.dtype, tiling,
@@ -278,15 +372,18 @@ def _grouped_matmul_bwd(res, g):
 _grouped_matmul.defvjp(lambda *args: (_gmm(*args), args), _grouped_matmul_bwd)
 
 
-HELD_GRANULE = 512           # a capacity is whole row tiles of the kernels
+HELD_GRANULE = 512           # a capacity is whole row tiles, whichever of ROW_TILES
 HELD_HEADROOM = 1.25         # first capacity over the rows uniform routing gives
 
 
 def _held_capacities(pairs, count, n):
     """The static row counts a layer that holds ``count`` of ``n`` experts
     is compiled for: HELD_HEADROOM times the rows uniform routing brings
-    (``pairs * count / n``) in whole tiles, and all ``pairs`` (T*k), so no
-    routing overflows; at T*k = 98,304 and 16 of 64, (30720, 98304).  A
+    (``pairs * count / n``) in whole HELD_GRANULE rows (every row tile
+    ``_tiling`` may choose divides it, so no capacity is padded), and all
+    ``pairs`` (T*k), so no routing overflows; at T*k = 98,304 and 16 of 64,
+    (30720, 98304).  The first capacity over ``count`` is the rows a group
+    EXPECTS, which the kernels' row tile follows.  A
     step runs the first where it covers its held pairs (``lax.switch``), so
     the dispatch's gather and the kernels' grids follow the rows held as
     long as the routing stays within the headroom of balance, and a step

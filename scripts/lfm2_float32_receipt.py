@@ -31,12 +31,11 @@ def main(seed=0, out_path=None):
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.parallel import decoder, moe, transformer as T
+    from paddle_tpu.parallel import decoder, transformer as T
 
     # float32 rows and weights are twice the bytes: the grouped matmuls'
-    # training tiles do not fit the kernel's VMEM, so this process takes
-    # smaller ones (the script's own; the program has no such option)
-    moe._tiling = lambda m, k, n: (min(m, 256), min(k, 512), min(n, 512))
+    # tiles follow the element size (``moe._tiling`` keeps a grid step's
+    # blocks within its VMEM budget), so this process sets none of its own
 
     if jax.devices()[0].platform != "tpu":
         print("no TPU here", file=sys.stderr)

@@ -111,7 +111,8 @@ def main(argv=None):
                                        "monitor.train.router_",
                                        "monitor.kernels.flash_kv_blocks_",
                                        "monitor.kernels.flash_bwd_sweeps_")):
-                print("monitor: %s %s" % (row["name"], row.get("value")))
+                print("monitor: %s%s %s" % (
+                    row["name"], row["labels"] or "", row.get("value")))
         monitor.disable()
         np.asarray(tr.run_steps(staged, lr))
         tracing._start(os.path.join(tmp, "trace"), 0)

@@ -27,9 +27,12 @@ KINDS = {"monitor.kernels.flash_" + g for g in (
     "kv_blocks_skipped_windowed", "kv_blocks_visited_full",
     "kv_blocks_visited_windowed")}
 MOE = {"monitor.kernels.moe_pair_slots", "monitor.kernels.moe_rows_fetch_bound",
-       "monitor.train.moe_assignments", "monitor.train.moe_load_max_over_mean"}
+       "monitor.train.moe_assignments", "monitor.train.moe_load_max_over_mean",
+       # since PR 46: a count a compiled gmm / tgmm call, by its tiles
+       "monitor.kernels.moe_grouped_matmul_calls"}
 HELD = {"monitor.train.moe_held_rows_share", "monitor.train.moe_rows_held"}
-# tiny model -> (sequence, the names one run_steps wrote on ad87b08)
+# tiny model -> (sequence, the names one run_steps wrote on ad87b08, and
+# PR 46's counter of compiled grouped-matmul calls)
 WRITTEN = {
     # 4 heads of 16: no packed layout, so no flash gauge
     "olmoe": (32, MOE),

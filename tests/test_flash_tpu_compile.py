@@ -343,3 +343,50 @@ def test_the_moe_row_kernel_compiles_for_a_v5e(one_chip, what, slots, k,
     assert 2 * 2 * tb * k * 4 + blocks * 4 < 64 * 2 ** 10, what
     assert re.search(r"u32\[%d,1,%d\]\S* bitcast\(\S*moe_rows_words"
                      % (m, width // 2), text), what
+
+
+MOE_CELLS = {      # rows at the first capacity, groups, E, F of one layer
+    "olmoe_1b_7b.s4096_scan": (131072, 64, 2048, 1024),
+    "lfm2_8b_a1b.s8192_scan": (20480, 8, 2048, 1792),
+    "smallthinker_21b_a3b.s16384_scan": (30720, 16, 2560, 768),
+    "mistral_small_4_119b.s16384_scan": (5120, 8, 4096, 2048),
+    "trinity_large_preview.s6144_scan": (1024, 8, 3072, 3072),
+}
+
+
+@pytest.mark.parametrize("what", MOE_CELLS)
+def test_the_grouped_matmuls_compile_for_a_v5e_at_the_rule_s_tiles(
+        one_chip, monkeypatch, what):
+    """``megablox``'s ``gmm``, ``gmm`` with the weights transposed and
+    ``tgmm`` as ``parallel/moe.py`` calls them at one layer's shapes of the
+    five sparse cells, bf16, at the tiles ``moe._tiling`` gives each call:
+    ``megablox`` asks Mosaic for no VMEM of its own, so what a grid step
+    holds has to fit the scope a v5e kernel has by default (16 MiB).  What
+    the compiled kernel took is at most the rule's own count
+    (``moe._vmem_bytes``, within VMEM_BUDGET) and 3 MiB of the kernels'
+    temporaries (2.1 MiB read: the transposed weight block's copy at
+    LFM2's 512 x 1792 x 512)."""
+    moe = importlib.import_module("paddle_tpu.parallel.moe")
+    m, groups, E, F = MOE_CELLS[what]
+    S = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                            sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=one_chip)
+    monkeypatch.setattr(moe, "on_tpu", lambda: True)   # compile, not interpret
+    for k, n in ((E, 2 * F), (F, E)):
+        fwd = jax.jit(moe._gmm).lower(
+            S(m, k), S(groups, k, n), sizes).compile().as_text()
+        dx = jax.jit(lambda g, w, s: moe._gmm(g, w, s, transpose_rhs=True)
+                     ).lower(S(m, n), S(groups, k, n), sizes
+                             ).compile().as_text()
+        dw = jax.jit(lambda r, w, s, g: moe._grouped_matmul_bwd(
+            (r, w, s), g)[1]).lower(
+                S(m, k), S(groups, k, n), sizes, S(m, n)).compile().as_text()
+        for text, kernel, (kk, nn), is_dw in (
+                (fwd, "gmm", (k, n), False), (dx, "gmm", (n, k), False),
+                (dw, "tgmm", (k, n), True)):
+            asked, took = _vmem(text, kernel)
+            count = moe._vmem_bytes(
+                *moe._tiling(m, kk, nn, groups, 2, dw=is_dw), 2, is_dw)
+            assert asked is None, (what, kernel)
+            assert count // 2 < took <= count + 3 * 2 ** 20 < 16 * 2 ** 20, (
+                what, kernel, kk, nn, count, took)

@@ -509,16 +509,18 @@ def test_the_new_scopes_hold_their_instructions_and_attention_none():
 # sparse decoders, whose expert layers sum back through the row kernel
 # (``kernels/moe_rows.py``), and PR 41 for LFM2 alone, whose grouped heads of
 # 64 ride the flash sweeps stacked (``kernels/flash_attention.py``: OLMoE's
-# and SmallThinker's held through it); BERT's and Brumby's are still
-# 03fc114's.
+# and SmallThinker's held through it), and PR 46 for SmallThinker and LFM2,
+# whose held share's 256 rows in two groups now go in 128-row tiles
+# (``moe._tiling``; OLMoE's tiny layer is one tile either way and held
+# through it); BERT's and Brumby's are still 03fc114's.
 PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "bert.run_steps": "00de5403506fdc87",
             "olmoe.step": "231114fcd62341f2",
             "olmoe.run_steps": "054338e92270f130",
-            "smallthinker.step": "f20200134b1ebe0f",
-            "smallthinker.run_steps": "504a2d6d86c4dda7",
-            "lfm2.step": "d97a02ce8b817454",
-            "lfm2.run_steps": "9c116ad10b0f2cd7",
+            "smallthinker.step": "0fbba21c5758d020",
+            "smallthinker.run_steps": "45f181056c27661f",
+            "lfm2.step": "d34653f0ac3afdc6",
+            "lfm2.run_steps": "4bfad11158397378",
             "brumby.step": "be3328df1ffdda0a",
             "brumby.run_steps": "07985218bf230094"}
 OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),

@@ -270,40 +270,60 @@ def _count(jaxpr, name):
 
 # -- the kernels at their real tiling ------------------------------------------
 # Above, every case is one whole-dimension tile.  At training sizes the
-# megablox kernels run 512 x 1024 x 1024 tiles (``moe._tiling``): a row tile
+# megablox kernels run the tiles ``moe._tiling`` gives a call (a row tile of
+# which a group expects four, 128 rows at the least; the contraction whole
+# where a column tile fits beside it): a row tile
 # holds the end of one group and the start of the next, a group spans tiles,
 # the last row tile is padding past the groups (``_whole_row_tiles``), and a
 # contraction or a column dimension of 2048 is two tiles.  Interpret mode
 # runs the same grid and index maps on the CPU.
 
-# rows, K, N, group sizes (their sum is the rows: nothing dropped)
+# rows, K, N, group sizes, the forward call's tiles (float32 rows)
 TILED = {
-    # 1,300 rows in three row tiles (236 rows of padding), whole K and N
-    "groups_straddle_row_tiles": (1300, 16, 24,
-                                  (0, 700, 3, 0, 88, 509)),
-    # two tiles in K and in N as well, a group boundary ON a tile's edge
-    "two_tiles_each_way": (1100, 2048, 2048, (512, 0, 1, 587)),
-    # fewer rows than one tile and an empty first and last group
-    "one_short_tile": (520, 1536, 1024, (0, 519, 1, 0)),
+    # 1,300 rows in eleven row tiles (108 rows of padding), whole K and N
+    "groups_straddle_row_tiles": (1300, 16, 24, (0, 700, 3, 0, 88, 509),
+                                  (128, 16, 24)),
+    # several tiles in N, a group boundary ON a tile's edge
+    "two_tiles_each_way": (1100, 2048, 2048, (512, 0, 1, 587),
+                           (128, 2048, 512)),
+    # fewer rows than five tiles and an empty first and last group
+    "one_short_tile": (520, 1536, 1024, (0, 519, 1, 0), (128, 1536, 512)),
+    # a contraction too long for VMEM beside any column tile: two k tiles
+    "contraction_in_two_parts": (260, 4096, 256, (129, 131), (128, 2048, 256)),
+    # THIN groups, a held share's first capacity: eight groups of 60 to 140
+    # rows in 1,024, about one 128-row tile each, the contraction whole
+    "thin_groups_fill_the_rows": (
+        1024, 1536, 2048, (140, 140, 140, 140, 140, 140, 124, 60),
+        (128, 1536, 512)),
+    # an empty group, the first group's edge ON a tile's edge, and 244 rows
+    # past the groups, which are nobody's (a step's rows past its held pairs)
+    "thin_groups_an_empty_one_and_rows_past_them": (
+        1024, 1280, 1536, (128, 0, 100, 140, 60, 96, 120, 136),
+        (128, 1280, 768)),
 }
 
 
 def _tiled_case(name):
-    m, k, n, sizes = TILED[name]
-    assert sum(sizes) == m and moe._tiling(m, k, n) == (
-        512, min(k, 1024), min(n, 1024))
+    m, k, n, sizes, tiles = TILED[name]
+    assert sum(sizes) <= m and moe._tiling(m, k, n, len(sizes), 4) == tiles
     ks = jax.random.split(jax.random.PRNGKey(len(name)), 3)
     rows = jax.random.normal(ks[0], (m, k), jnp.float32)
     weights = jax.random.normal(ks[1], (len(sizes), k, n), jnp.float32) * k ** -0.5
-    probe = jax.random.normal(ks[2], (m, n), jnp.float32)
+    # rows past the groups are no group's: the kernels leave theirs unwritten
+    probe = jax.random.normal(ks[2], (m, n), jnp.float32) * (
+        jnp.arange(m) < sum(sizes))[:, None]
     return rows, weights, jnp.asarray(sizes, jnp.int32), probe
 
 
 def _row_by_row(rows, weights, sizes):
-    """Row i times the weights of its own group, one dense matmul a group."""
-    group = np.repeat(np.arange(len(sizes)), np.asarray(sizes))
-    per_group = jnp.einsum("mk,gkn->gmn", rows, weights,
-                           precision=jax.lax.Precision.HIGHEST)
+    """Row i times the weights of its own group, one dense matmul a group;
+    zero for a row past the groups."""
+    sizes = np.asarray(sizes)
+    group = np.repeat(np.arange(len(sizes) + 1),
+                      np.append(sizes, rows.shape[0] - sizes.sum()))
+    per_group = jnp.einsum(
+        "mk,gkn->gmn", rows, jnp.pad(weights, ((0, 1), (0, 0), (0, 0))),
+        precision=jax.lax.Precision.HIGHEST)
     return per_group[group, jnp.arange(rows.shape[0])]
 
 
@@ -313,20 +333,115 @@ def test_grouped_matmul_at_the_real_tiling(name):
     against the dense formula and ITS gradients."""
     rows, weights, sizes, probe = _tiled_case(name)
     tol = dict(rtol=2e-5, atol=2e-5)
+    live = int(sum(TILED[name][3]))
     got = jax.jit(moe._grouped_matmul)(rows, weights, sizes)
-    np.testing.assert_allclose(got, _row_by_row(rows, weights, sizes), **tol)
+    np.testing.assert_allclose(
+        got[:live], _row_by_row(rows, weights, sizes)[:live], **tol)
     d_rows, d_weights = jax.jit(jax.grad(
         lambda r, w: jnp.sum(moe._grouped_matmul(r, w, sizes) * probe),
         argnums=(0, 1)))(rows, weights)
     want_rows, want_weights = jax.grad(
         lambda r, w: jnp.sum(_row_by_row(r, w, sizes) * probe),
         argnums=(0, 1))(rows, weights)
-    np.testing.assert_allclose(d_rows, want_rows, **tol)
+    np.testing.assert_allclose(d_rows[:live], want_rows[:live], **tol)
     # dW sums over a group's rows: up to 700 terms of size one
     np.testing.assert_allclose(d_weights, want_weights, rtol=2e-5, atol=2e-4)
     # an empty group's weights get a gradient of exactly zero, not a stale tile
     for g, size in enumerate(TILED[name][3]):
         assert size or not np.asarray(d_weights[g]).any()
+
+
+# One layer's grouped matmuls of the benchmark's five sparse cells at the
+# FIRST capacity (``_held_capacities``), Trinity's all-pairs tier and a tiny
+# shape: (rows M, groups, E, F) and the tiles of the six calls a layer's
+# forward and backward make, bf16: gate/up and down forward, their dX
+# (``gmm`` with the weights transposed: the contraction is the forward's
+# columns), their dW (``tgmm``: k x n is a group's block of dW).
+CELL_SHAPES = {
+    "olmoe_1b_7b": (131072, 64, 2048, 1024),              # 2,048 rows a group
+    "lfm2_8b_a1b": (20480, 8, 2048, 1792),                # 2,560
+    "smallthinker_21b_a3b": (30720, 16, 2560, 768),       # 1,920
+    "mistral_small_4_119b": (5120, 8, 4096, 2048),        # 640
+    "trinity_large_preview": (1024, 8, 3072, 3072),       # 128
+    "trinity_large_preview, every pair": (24576, 8, 3072, 3072),
+    "tiny": (24, 2, 16, 8),
+}
+CALLS = {      # (k, n) of a call from (E, F), and whether it is tgmm's
+    "gate_up.fwd": (lambda E, F: (E, 2 * F), False),
+    "down.fwd": (lambda E, F: (F, E), False),
+    "gate_up.dx": (lambda E, F: (2 * F, E), False),
+    "down.dx": (lambda E, F: (E, F), False),
+    "gate_up.dw": (lambda E, F: (E, 2 * F), True),
+    "down.dw": (lambda E, F: (F, E), True),
+}
+CELL_TILES = {cell: dict(zip(CALLS, tiles)) for cell, tiles in {
+    "olmoe_1b_7b": ((512, 2048, 512), (512, 1024, 1024), (512, 2048, 512),
+                    (512, 2048, 512), (512, 1024, 1024), (512, 1024, 1024)),
+    "lfm2_8b_a1b": ((512, 2048, 512), (512, 1792, 512), (512, 1792, 512),
+                    (512, 1024, 896), (512, 1024, 896), (512, 896, 1024)),
+    "smallthinker_21b_a3b": (
+        (256, 2560, 768), (256, 768, 1280), (256, 1536, 1280),
+        (256, 2560, 768), (256, 1280, 768), (256, 768, 1280)),
+    "mistral_small_4_119b": (
+        (128, 4096, 512), (128, 2048, 1024), (128, 4096, 512),
+        (128, 4096, 512), (128, 1024, 1024), (128, 1024, 1024)),
+    "trinity_large_preview": (
+        (128, 3072, 768), (128, 3072, 768), (128, 6144, 256),
+        (128, 3072, 768), (128, 768, 1536), (128, 768, 1536)),
+    "trinity_large_preview, every pair": (
+        (512, 1536, 768), (512, 1536, 768), (512, 2048, 512),
+        (512, 1536, 768), (512, 1024, 1024), (512, 1024, 1024)),
+    "tiny": ((24, 16, 16), (24, 8, 16), (24, 16, 16), (24, 16, 8),
+             (24, 16, 16), (24, 8, 16)),
+}.items()}       # a cell's tiles in CALLS' order
+
+
+@pytest.mark.parametrize("call", CALLS)
+@pytest.mark.parametrize("cell", CELL_SHAPES)
+def test_the_tiles_follow_the_call_s_shapes(cell, call):
+    """``moe._tiling``: the tallest row tile of which a group expects four
+    (128 rows at the least), the contraction whole where a column tile fits
+    beside it (else in the fewest equal parts), dW in the block that reads
+    the rows the fewest times; every cut dimension in equal parts of whole
+    lane tiles, within VMEM_BUDGET; ``HELD_GRANULE`` rows are whole row
+    tiles, so no capacity is padded."""
+    m, groups, E, F = CELL_SHAPES[cell]
+    (k, n), dw = CALLS[call][0](E, F), CALLS[call][1]
+    tm, tk, tn = got = moe._tiling(m, k, n, groups, 2, dw=dw)
+    assert got == CELL_TILES[cell][call]
+    assert moe._vmem_bytes(tm, tk, tn, 2, dw) <= moe.VMEM_BUDGET < 16 * 2 ** 20
+    assert tm == m or (tm in moe.ROW_TILES and moe.HELD_GRANULE % tm == 0
+                       and tm <= max(m // groups // 4, 128))
+    assert k % tk == 0 and n % tn == 0
+    assert (tk == k or tk % 128 == 0) and (tn == n or tn % 128 == 0)
+    # float32 operands are twice the bytes: the same rule, smaller blocks
+    assert moe._vmem_bytes(*moe._tiling(m, k, n, groups, 4, dw=dw), 4,
+                           dw) <= moe.VMEM_BUDGET
+
+
+def test_compiled_calls_are_counted_by_their_tiles(tmp_path):
+    """Under a monitor session a traced call of either kernel counts once in
+    ``monitor.kernels.moe_grouped_matmul_calls`` under its tiles and whether
+    its groups are thin; off the monitor nothing is touched."""
+    from paddle_tpu import monitor
+
+    rows, weights, sizes, probe = _tiled_case("thin_groups_fill_the_rows")
+    grad = lambda: jax.make_jaxpr(jax.grad(lambda r, w: jnp.sum(
+        moe._grouped_matmul(r, w, sizes) * probe), argnums=(0, 1)))(
+            rows, weights)
+    grad()                                       # off: nothing is touched
+    mon = monitor.enable(str(tmp_path), flight=False)
+    try:
+        grad()
+        got = {(r["labels"]["kernel"], r["labels"]["tm"], r["labels"]["tk"],
+                r["labels"]["tn"], r["labels"]["thin"]): r["value"]
+               for r in mon.registry.snapshot()
+               if r["name"] == "monitor.kernels.moe_grouped_matmul_calls"}
+    finally:
+        monitor.disable()
+    assert got == {("gmm", 128, 1536, 512, 1): 1,     # forward
+                   ("gmm", 128, 2048, 512, 1): 1,     # dX, weights transposed
+                   ("tgmm", 128, 768, 1024, 1): 1}    # dW
 
 
 def test_layer_over_several_row_tiles_equals_the_dense_formula():
