@@ -1,0 +1,333 @@
+"""The q/k norm and the rotary positions of a packed projection in ONE pass,
+as a Pallas TPU row kernel (fwd + custom-VJP bwd): ``qk_rope``.
+
+``x`` [b, S, W] is a packed projection, W = heads * head_dim.  The result is
+what ``rope(rms_norm(x))`` of ``parallel/transformer.py`` gives (the tests'
+reference), computed in float32 and rounded ONCE:
+
+    n = x * rsqrt(mean(x^2) + eps) * weight     per head with one [dh] weight
+                                                ("head"), over the whole
+                                                projection with a [W] weight
+                                                ("whole"), or n = x (None)
+    y = n * cos + rotate_half(n) * sin          per head, at the angles
+                                                ``pos * theta^(-2i / dh)``
+                                                (or y = n: no positions)
+
+Why a kernel (PERF.md section 6, PR 47): the compiled text of one rotary
+layer at Trinity's shape moves 9.0 GB outside its matmuls and flash kernels
+where this work needs 0.45: XLA broadcasts ``cos`` and ``sin`` to float32
+``[S, H, dh]`` in HBM, makes ``rotate_half``'s halves in a transposed layout
+and copies them back, and a reduction over a reshaped minor dimension fuses
+into no matmul.  Here a block of rows comes in, the same block goes out, and
+nothing float32 reaches HBM but the angles' tables:
+
+- a lane block (128 lanes) holds one head of 128 or ``128 / dh`` whole heads;
+  a head's sum of squares is a lane reduce (masked to the head's lanes where
+  a block holds several), ``rotate_half`` one lane rotation by ``dh / 2``
+  (a select between two where a block holds several heads) with the sign in
+  the sine's table;
+- the angles are two float32 tables ``[S, 128]`` (``angle_tables``: cosine,
+  and sine with ``rotate_half``'s sign), made by XLA from ``first``, which
+  may be traced, by ``rope``'s own formula: 3 to 8 MB a layer, read once a
+  block of rows whatever the batch (the batch is the grid's inner axis);
+- the backward reads ``dy`` and the saved RAW projection (saved only where
+  a norm reads it: the rotation alone is linear), recomputes the statistics
+  (no lane-narrow block of them is saved), and writes ``dx`` over ``dy``;
+  the weight's gradient leaves as per-block partial sums ``[blocks * 8,
+  lanes]`` float32 (eight sublanes a block: no cross-sublane reduce in the
+  kernel), summed outside.
+
+interpret=None auto-selects the Pallas interpreter off-TPU, so the CPU tests
+run the same code (kernels/flash_attention.py idiom).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ._common import CompilerParams as _CompilerParams, on_tpu as _on_tpu
+
+__all__ = ["qk_rope", "angle_tables", "supported", "block_rows",
+           "vmem_bytes"]
+
+LANES = 128
+SUBLANES = 8
+ROW_BLOCKS = (256, 128, 64, 32, 16, 8)
+# what the backward's six pipelined blocks (x, dy, dx; two copies each) may
+# take of VMEM; the forward's four are under it
+BLOCK_VMEM = 12 * 2 ** 20
+
+
+def block_rows(S, W, itemsize):
+    """Rows of a grid step's block of a ``[b, S, W]`` projection, from the
+    shapes alone: the tallest of ROW_BLOCKS in whole tiles of the element
+    type (8 rows of 32 bits, 16 of 16) that divides S and keeps the
+    backward's six blocks within BLOCK_VMEM; None where there is none."""
+    tile = SUBLANES * 4 // itemsize
+    for bs in ROW_BLOCKS:
+        if (bs % tile == 0 and S % bs == 0
+                and 6 * bs * W * itemsize <= BLOCK_VMEM):
+            return bs
+    return None
+
+
+def vmem_bytes(bs, W, itemsize):
+    """What a call asks Mosaic for: the backward's six pipelined blocks of
+    the projection, the angles', the weight's and the partial sums' blocks
+    twice each, thirty-two float32 temporaries of a lane block's rows
+    (Mosaic's stack does not reuse every one; the compiled kernels take 1 to
+    7 MiB of the 8 to 17 asked, ``tests/test_flash_tpu_compile.py``), and
+    room."""
+    return (6 * bs * W * itemsize + (4 + 32) * bs * LANES * 4
+            + 2 * (SUBLANES + 1) * W * 4 + 2 * 2 ** 20)
+
+
+def supported(shape, head_dim, itemsize):
+    """Whether ``qk_rope`` takes a packed projection of this shape: W whole
+    lane blocks of whole heads (``head_dim`` 128 or a divisor of it), S in
+    whole sublane tiles, and a block of rows within BLOCK_VMEM."""
+    _, S, W = shape
+    return (W % LANES == 0 and LANES % head_dim == 0 and head_dim % 2 == 0
+            and block_rows(S, W, itemsize) is not None)
+
+
+def angle_tables(S, head_dim, theta, first=0):
+    """(cos, signed sin) [S, 128] float32 of positions ``first``..``first``
+    + S - 1 (``first`` may be traced), each head's ``head_dim`` lanes
+    ``rope``'s own ``tile(cos(pos * theta^(-i / half)), 2)``, the sine with
+    ``rotate_half``'s sign (minus on a head's first half)."""
+    half = head_dim // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    pos = jnp.arange(S, dtype=jnp.float32)
+    if not (isinstance(first, int) and first == 0):
+        pos = pos + first
+    ang = pos[:, None] * inv_freq[None]
+    heads = LANES // head_dim
+    return (jnp.tile(jnp.cos(ang), (1, 2 * heads)),
+            jnp.tile(jnp.concatenate([-jnp.sin(ang), jnp.sin(ang)], axis=1),
+                     (1, heads)))
+
+
+def _lane(shape):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+
+
+def _partner(y, dh):
+    """``y[..., j ^ (dh / 2)]`` along the 128 lanes: the other half of lane
+    j's head, ``rotate_half`` without its sign (its own transpose)."""
+    half = dh // 2
+    if dh == LANES:
+        return pltpu.roll(y, half, 1)
+    return jnp.where((_lane(y.shape) & half) != 0, pltpu.roll(y, half, 1),
+                     pltpu.roll(y, LANES - half, 1))
+
+
+def _head_sum(v, dh):
+    """The sum of ``v`` [rows, 128] over each head's lanes, at every lane of
+    that head (``[rows, 1]`` where the block is one head)."""
+    if dh == LANES:
+        return jnp.sum(v, axis=1, keepdims=True)
+    head = _lane(v.shape) // dh
+    out = jnp.zeros_like(v)
+    for i in range(LANES // dh):
+        mine = head == i
+        out = jnp.where(mine, jnp.sum(jnp.where(mine, v, 0.0), axis=1,
+                                      keepdims=True), out)
+    return out
+
+
+def _rotate(y, cos, sin, dh):
+    return y * cos + _partner(y, dh) * sin
+
+
+def _over_blocks(ref, body, carry=None):
+    """``carry = body(sl, carry)`` for every lane block ``sl`` of a ``[rows,
+    W]`` ref, in a ``fori_loop``: ONE block's operations are traced and
+    lowered whatever the heads (48 unrolled blocks a kernel cost Trinity's
+    set-up 8 s of tracing and lowering)."""
+    def step(i, carry):
+        return body(pl.ds(pl.multiple_of(i * LANES, LANES), LANES), carry)
+    return jax.lax.fori_loop(0, ref.shape[-1] // LANES, step, carry)
+
+
+def _row_rstd(x_ref, eps):
+    """``rsqrt(mean(x^2) + eps)`` [rows, 1] over the whole projection: the
+    lane blocks' squares summed elementwise, ONE lane reduce."""
+    def squares(sl, acc):
+        xf = x_ref[:, sl].astype(jnp.float32)
+        return acc + xf * xf
+    acc = _over_blocks(x_ref, squares,
+                       jnp.zeros((x_ref.shape[0], LANES), jnp.float32))
+    return jax.lax.rsqrt(
+        jnp.sum(acc, axis=1, keepdims=True) / x_ref.shape[-1] + eps)
+
+
+def _fwd_kernel(*refs, dh, norm, rotary, eps):
+    x_ref, refs = refs[0], refs[1:]
+    if norm:
+        w_ref, refs = refs[0], refs[1:]
+    if rotary:
+        cos_ref, sin_ref = refs[0], refs[1]
+    o_ref = refs[-1]
+    rstd = _row_rstd(x_ref, eps) if norm == "whole" else None
+
+    def block(sl, _):
+        y = x_ref[:, sl].astype(jnp.float32)
+        if norm == "head":
+            y = y * jax.lax.rsqrt(_head_sum(y * y, dh) / dh + eps) \
+                * w_ref[...]
+        elif norm:
+            y = y * rstd * w_ref[:, sl]
+        if rotary:
+            y = _rotate(y, cos_ref[...], sin_ref[...], dh)
+        o_ref[:, sl] = y.astype(o_ref.dtype)
+
+    _over_blocks(x_ref, block)
+
+
+def _sublane_sums(v):
+    """``v`` [rows, lanes] summed into eight sublanes: elementwise adds of
+    its 8-row tiles, no cross-sublane reduce."""
+    return jnp.sum(v.reshape(-1, SUBLANES, v.shape[-1]), axis=0)
+
+
+def _bwd_kernel(*refs, dh, norm, rotary, eps):
+    if norm:
+        x_ref, refs = refs[0], refs[1:]
+    g_ref, refs = refs[0], refs[1:]
+    if norm:
+        w_ref, refs = refs[0], refs[1:]
+    if rotary:
+        cos_ref, sin_ref = refs[0], refs[1]
+    dx_ref = refs[-2] if norm else refs[-1]
+    dw_ref = refs[-1] if norm else None
+
+    def d_normed(sl):
+        g = g_ref[:, sl].astype(jnp.float32)
+        # the rotation's transpose: its partner map is its own inverse
+        return g * cos_ref[...] + _partner(g * sin_ref[...], dh) \
+            if rotary else g
+
+    if not norm:        # the rotation alone is linear: no x
+        def block(sl, _):
+            dx_ref[:, sl] = d_normed(sl).astype(dx_ref.dtype)
+        _over_blocks(g_ref, block)
+        return
+    rows, W = x_ref.shape
+    rstd = proj = None
+    if norm == "whole":
+        # two trips over the block: the row's sum of dn * w * x, then dx
+        rstd = _row_rstd(x_ref, eps)
+        acc = _over_blocks(
+            x_ref, lambda sl, acc: acc + d_normed(sl) * w_ref[:, sl]
+            * x_ref[:, sl].astype(jnp.float32),
+            jnp.zeros((rows, LANES), jnp.float32))
+        proj = jnp.sum(acc, axis=1, keepdims=True) * (rstd * rstd / W)
+
+    def block(sl, dw):
+        xf = x_ref[:, sl].astype(jnp.float32)
+        dn = d_normed(sl)
+        if norm == "head":
+            r = jax.lax.rsqrt(_head_sum(xf * xf, dh) / dh + eps)
+            w = w_ref[...]
+            mean = _head_sum(dn * w * xf, dh) * (r * r / dh)
+        else:
+            r, w, mean = rstd, w_ref[:, sl], proj
+        # n = x * rstd; dx = rstd * (dn * w - n * mean(dn * w * n))
+        dx_ref[:, sl] = (r * (dn * w - xf * mean)).astype(dx_ref.dtype)
+        part = _sublane_sums(dn * xf * r)
+        if norm == "head":      # one weight for every head: the blocks' sum
+            return dw + part
+        dw_ref[:, sl] = part
+        return dw
+
+    dw = _over_blocks(x_ref, block,
+                      jnp.zeros((SUBLANES, LANES), jnp.float32))
+    if norm == "head":
+        dw_ref[...] = dw
+
+
+def _call(kernel, name, rows, weight, tables, dh, norm, eps, interpret,
+          backward=False):
+    """One pallas_call over grid (S / bs, b), the batch innermost so that a
+    block of the angles' tables is fetched once for all b.  ``rows``: the
+    operands of the projection's shape (x; in the backward x, where there
+    is a norm, then dy, over which dx is written: nothing reads dy after
+    it)."""
+    b, S, W = rows[0].shape
+    dtype = rows[0].dtype
+    bs = block_rows(S, W, dtype.itemsize)
+    block = pl.BlockSpec((None, bs, W), lambda si, bi: (bi, si, 0))
+    operands, specs = list(rows), [block] * len(rows)
+    wl = LANES if norm == "head" else W
+    if norm:
+        operands.append(weight.reshape(1, wl))
+        specs.append(pl.BlockSpec((1, wl), lambda si, bi: (0, 0)))
+    if tables is not None:
+        operands += list(tables)
+        specs += [pl.BlockSpec((bs, LANES), lambda si, bi: (si, 0))] * 2
+    out_shape = [jax.ShapeDtypeStruct((b, S, W), dtype)]
+    out_specs = [block]
+    if backward and norm:       # the weight's gradient, eight sublanes a block
+        out_shape.append(jax.ShapeDtypeStruct(
+            (S // bs * b * SUBLANES, wl), jnp.float32))
+        out_specs.append(pl.BlockSpec(
+            (SUBLANES, wl), lambda si, bi: (si * b + bi, 0)))
+    return pl.pallas_call(
+        functools.partial(kernel, dh=dh, norm=norm,
+                          rotary=tables is not None, eps=eps),
+        grid=(S // bs, b), in_specs=specs, out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=_CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=vmem_bytes(bs, W, dtype.itemsize)),
+        input_output_aliases={len(rows) - 1: 0} if backward else {},
+        interpret=interpret, name=name,
+    )(*operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _qk_rope(x, weight, tables, dh, norm, eps, interpret):
+    return _call(_fwd_kernel, "qk_rope_fwd", [x], weight, tables, dh, norm,
+                 eps, interpret)[0]
+
+
+def _qk_rope_fwd(x, weight, tables, dh, norm, eps, interpret):
+    # the RAW projection is the residual, and only where a norm reads it
+    return (_qk_rope(x, weight, tables, dh, norm, eps, interpret),
+            (x if norm else None, weight, tables))
+
+
+def _qk_rope_bwd(dh, norm, eps, interpret, res, dy):
+    x, weight, tables = res
+    out = _call(_bwd_kernel, "qk_rope_bwd", [x, dy] if norm else [dy],
+                weight, tables, dh, norm, eps, interpret, backward=True)
+    # the angles' tables hang on positions alone: no cotangent
+    return (out[0], jnp.sum(out[1], axis=0) if norm else None,
+            jax.tree.map(jnp.zeros_like, tables))
+
+
+_qk_rope.defvjp(_qk_rope_fwd, _qk_rope_bwd)
+
+
+def qk_rope(x, weight=None, tables=None, *, head_dim, norm=None, eps=1e-5,
+            interpret=None):
+    """``x`` [b, S, W] packed heads of ``head_dim``; ``norm`` "head" (RMS
+    norm of each head, ``weight`` [head_dim]), "whole" (of the projection,
+    ``weight`` [W]) or None; ``tables`` = ``angle_tables(S, head_dim, theta,
+    first)`` for rotary positions, or None.  ``supported(x.shape, head_dim,
+    itemsize)`` must hold.  Float32 inside, rounded once to ``x.dtype``."""
+    if not supported(x.shape, head_dim, x.dtype.itemsize):
+        raise ValueError("qk_rope: shape %s at head_dim %d is not supported"
+                         % (x.shape, head_dim))
+    if interpret is None:
+        interpret = not _on_tpu()
+    if norm == "head":          # one weight for every head of a lane block
+        weight = jnp.tile(weight.astype(jnp.float32), LANES // head_dim)
+    elif norm:
+        weight = weight.astype(jnp.float32)
+    return _qk_rope(x, weight, tables, head_dim, norm or None, float(eps),
+                    bool(interpret))

@@ -32,6 +32,7 @@ from jax.sharding import PartitionSpec as P
 
 from . import collectives as col
 from .mesh import DP, PP, TP
+from .. import monitor
 from ..monitor import devscope
 from ..monitor.recompile import compile_ledger
 from .ring_attention import ring_attention
@@ -664,7 +665,11 @@ def _rms(x, scale, eps):
 @devscope.scoped(devscope.LAYER_NORM)
 def rms_norm(x, scale, eps=1e-5):
     """``x * rsqrt(mean(x^2) + eps) * scale`` over the last axis, in float32.
-    Plain XLA: it fuses into the matmul that reads it."""
+    Plain XLA: a layer's input norm fuses into the matmuls that read it.  A
+    q/k norm does not (a reduction over a reshaped minor dimension fuses
+    into neither the matmul that made it nor the kernel that reads it: 9 GB
+    a layer with the rotation behind it, PERF.md section 6, PR 47), which is
+    why ``_qkv`` takes ``kernels/qk_rope.py`` where the shapes allow."""
     return _rms(x, scale, eps)
 
 
@@ -849,22 +854,76 @@ def _qkv(pl, h_full, cfg, rotary, first=0):
     b, S, E = h_full.shape
     hl, kvl = _local_heads(cfg)
     dh = cfg.head_dim
+    from ..kernels import qk_rope
+
+    # which of q and k the row kernel takes; where it takes either, the
+    # three weights' gradients are made beside their inputs' (``_project``)
+    fused = [(cfg.qk_norm or rotary) and qk_rope.supported(
+        (b, S, n * dh), dh, h_full.dtype.itemsize) for n in (hl, kvl)]
     # params arrive pre-sharded inside shard_map: wq/bqkv are [E, E/tp]/[3, E/tp]
-    q2, k2, v2 = (h_full @ pl[w] for w in ("wq", "wk", "wv"))  # [b, S, hl*dh]
+    q2, k2, v2 = ((_project if any(fused) else jnp.matmul)(h_full, pl[w])
+                  for w in ("wq", "wk", "wv"))              # [b, S, hl*dh]
     if cfg.bias:
         q2, k2, v2 = (y + pl["bqkv"][i] for i, y in enumerate((q2, k2, v2)))
-    if cfg.qk_norm == "head":   # each head on its own, one weight for all
-        q2 = rms_norm(q2.reshape(b, S, hl, dh), pl["q_norm"],
-                      cfg.norm_eps).reshape(q2.shape)
-        k2 = rms_norm(k2.reshape(b, S, kvl, dh), pl["k_norm"],
-                      cfg.norm_eps).reshape(k2.shape)
-    elif cfg.qk_norm:           # over the whole projection, before the heads
-        q2 = rms_norm(q2, pl["q_norm"], cfg.norm_eps)
-        k2 = rms_norm(k2, pl["k_norm"], cfg.norm_eps)
-    if rotary:
-        q2 = rope(q2, hl, cfg.rope_theta, first)
-        k2 = rope(k2, kvl, cfg.rope_theta, first)
+    if cfg.qk_norm or rotary:
+        q2, k2 = _norm_and_rotate(
+            (q2, k2), (pl.get("q_norm"), pl.get("k_norm")), (hl, kvl), fused,
+            cfg, rotary, first)
     return q2, k2, v2
+
+
+@jax.custom_vjp
+def _project(h, w):
+    """``h @ w``, h [b, S, E], whose backward makes the weight's gradient
+    WHERE the projection's own gradient is live: left to itself the
+    scheduler puts every layer's dW at the end of the step, and a row
+    kernel's output (``qk_rope_bwd``'s dx), which no fusion can recompute,
+    waits there for it, 75 MB a layer and projection at Trinity's shape."""
+    return h @ w
+
+
+def _project_bwd(res, g):
+    h, w = res
+    return jax.lax.optimization_barrier(
+        (g @ w.T, jnp.einsum("bse,bsf->ef", h, g).astype(w.dtype)))
+
+
+_project.defvjp(lambda h, w: (h @ w, (h, w)), _project_bwd)
+
+
+def _norm_and_rotate(xs, weights, heads, fused, cfg, rotary, first):
+    """The configured q/k norm and, where ``rotary``, rotary positions on
+    the packed projections ``xs`` ([b, S, heads * dh] each): ONE pass of the
+    row kernel (``kernels/qk_rope.py``) over each that ``fused`` says it
+    takes, the ``rms_norm`` / ``rope`` lines, which the tests hold that
+    kernel to, over the others.  Under a monitor session every projection
+    of a traced call counts in ``monitor.kernels.qk_rope_calls`` (``fused``
+    1 for the kernel)."""
+    from ..kernels import qk_rope
+
+    dh = cfg.head_dim
+    norm = cfg.qk_norm and ("head" if cfg.qk_norm == "head" else "whole")
+    mon = monitor.active()
+    if mon is not None:
+        for took in fused:
+            mon.registry.counter(
+                "monitor.kernels.qk_rope_calls", dh=dh, norm=norm or "none",
+                rotary=int(rotary), fused=int(took)).incr()
+    tables = qk_rope.angle_tables(xs[0].shape[1], dh, cfg.rope_theta, first) \
+        if rotary and any(fused) else None
+
+    def normed(x, weight, n):
+        if norm == "head":      # each head on its own, one weight for all
+            return rms_norm(x.reshape(x.shape[:2] + (n, dh)), weight,
+                            cfg.norm_eps).reshape(x.shape)
+        # over the whole projection, before the heads
+        return rms_norm(x, weight, cfg.norm_eps) if norm else x
+
+    xs = [qk_rope.qk_rope(x, w, tables, head_dim=dh, norm=norm,
+                          eps=cfg.norm_eps) if took else normed(x, w, n)
+          for x, w, n, took in zip(xs, weights, heads, fused)]
+    return [rope(x, n, cfg.rope_theta, first) if rotary and not took else x
+            for x, n, took in zip(xs, heads, fused)]
 
 
 def _latent_qkv(pl, h, cfg, first=0):
@@ -939,7 +998,8 @@ def _attention_heads_mode(pl, h_full, cfg, kind):
             "of whole heads that share one key/value head; S whole blocks)"
         o = _local_attention_dispatch(q, k, v, cfg).reshape(b, S, hl * dh)
     if cfg.attn_gate:
-        o = _gate_heads(o, h_full @ pl["wz"])
+        # the gate's projection is as wide as q: its dW beside its dx too
+        o = _gate_heads(o, _project(h_full, pl["wz"]))
     out = o @ pl["wo"]                                          # row-parallel partial
     out = col.reduce_scatter(out, TP, dim=1)                    # sum + seq scatter
     return out + pl["bo"] if cfg.bias else out

@@ -15,8 +15,9 @@ runs one dispatch under a monitor session (the scan driver opens none, so
 this is where ``monitor.train.lm_head_rows_share``, the two
 ``monitor.kernels.flash_*`` gauges and, for a sparse decoder, its
 ``monitor.train.moe_*``, ``monitor.kernels.moe_*``,
-``monitor.kernels.flash_kv_blocks_*`` and
-``flash_bwd_sweeps_*`` values are read on the chip),
+``monitor.kernels.flash_kv_blocks_*``,
+``flash_bwd_sweeps_*`` and ``monitor.kernels.qk_rope_calls`` values are read
+on the chip),
 traces one more, and joins the trace with THIS process's scope map
 (``monitor.devscope``; a map compiled elsewhere need not number its
 instructions the same way).  ``--ones`` replaces the mask by all ones, the
@@ -110,7 +111,8 @@ def main(argv=None):
                                        "monitor.kernels.moe_",
                                        "monitor.train.router_",
                                        "monitor.kernels.flash_kv_blocks_",
-                                       "monitor.kernels.flash_bwd_sweeps_")):
+                                       "monitor.kernels.flash_bwd_sweeps_",
+                                       "monitor.kernels.qk_rope_calls")):
                 print("monitor: %s%s %s" % (
                     row["name"], row["labels"] or "", row.get("value")))
         monitor.disable()

@@ -31,15 +31,18 @@ MOE = {"monitor.kernels.moe_pair_slots", "monitor.kernels.moe_rows_fetch_bound",
        # since PR 46: a count a compiled gmm / tgmm call, by its tiles
        "monitor.kernels.moe_grouped_matmul_calls"}
 HELD = {"monitor.train.moe_held_rows_share", "monitor.train.moe_rows_held"}
+# since PR 47: a count a traced q or k of ``_qkv`` with a q/k norm or rotary
+# positions, by whether the row kernel took it (Mistral's latent chain: none)
+QK = {"monitor.kernels.qk_rope_calls"}
 # tiny model -> (sequence, the names one run_steps wrote on ad87b08, and
-# PR 46's counter of compiled grouped-matmul calls)
+# PR 46's counter of compiled grouped-matmul calls, PR 47's of q/k passes)
 WRITTEN = {
     # 4 heads of 16: no packed layout, so no flash gauge
-    "olmoe": (32, MOE),
-    "smallthinker": (64, FLASH | KINDS | MOE | HELD),
-    "lfm2": (64, FLASH | KINDS | MOE | HELD
+    "olmoe": (32, MOE | QK),
+    "smallthinker": (64, FLASH | KINDS | MOE | HELD | QK),
+    "lfm2": (64, FLASH | KINDS | MOE | HELD | QK
              | {"monitor.train.router_bias_abs_max"}),
-    "brumby": (64, {"monitor.train.retention_" + g for g in (
+    "brumby": (64, QK | {"monitor.train.retention_" + g for g in (
         "chunks", "gate_mean", "state_mb", "state_sweeps")}),
     "mistral4": (64, FLASH | MOE | HELD | {"monitor.train." + g for g in (
         "mla_expanded_kv_bytes_per_token", "mla_latent_bytes_per_token",

@@ -390,3 +390,96 @@ def test_the_grouped_matmuls_compile_for_a_v5e_at_the_rule_s_tiles(
             assert asked is None, (what, kernel)
             assert count // 2 < took <= count + 3 * 2 ** 20 < 16 * 2 ** 20, (
                 what, kernel, kk, nn, count, took)
+
+
+QK_ROPE_CELLS = {   # batch, positions, query heads, kv heads, head width, norm
+    "trinity_large_preview.s6144_scan": (1, 6144, 48, 8, 128, "head"),
+    "olmoe_1b_7b.s4096_scan": (4, 4096, 16, 16, 128, "whole"),
+    "lfm2_8b_a1b.s8192_scan": (2, 8192, 32, 8, 64, "head"),
+    "smallthinker_21b_a3b.s16384_scan": (1, 16384, 28, 4, 128, None),
+    # one of ``_by_row_blocks``' eight blocks of rows, its first traced
+    "brumby_14b.s16384_scan": (1, 2048, 40, 8, 128, "head"),
+}
+
+
+@pytest.mark.parametrize("what", QK_ROPE_CELLS)
+def test_the_qk_rope_kernel_compiles_for_a_v5e(one_chip, what):
+    """``kernels/qk_rope.py`` forward and backward on q and on k at the five
+    decoders' shapes, bf16, rotary with a traced first position, and
+    Trinity's full layer's norm alone: two lane-aligned loads a head, a lane
+    rotation, the lane reduces and the backward's eight-sublane partial sums
+    are what Mosaic has to take.  A call asks for what its own estimate says
+    (``vmem_bytes``) and the compiled kernel takes less."""
+    qr = importlib.import_module("paddle_tpu.kernels.qk_rope")
+    b, S, heads, kv_heads, dh, norm = QK_ROPE_CELLS[what]
+    first = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    cases = [(heads, True), (kv_heads, True)]
+    if what.startswith("trinity"):
+        cases.append((heads, False))
+    for n, rotary in cases:
+        W = n * dh
+        x = jax.ShapeDtypeStruct((b, S, W), jnp.bfloat16, sharding=one_chip)
+        w = norm and jax.ShapeDtypeStruct(
+            (dh if norm == "head" else W,), jnp.float32, sharding=one_chip)
+
+        def both(x, w, first, g):
+            out, vjp = jax.vjp(lambda x, w: qr.qk_rope(
+                x, w, qr.angle_tables(S, dh, 1e4, first) if rotary else None,
+                head_dim=dh, norm=norm, eps=1e-5, interpret=False), x, w)
+            return (out,) + vjp(g)
+
+        text = jax.jit(both).lower(x, w, first, x).compile().as_text()
+        rows = qr.block_rows(S, W, 2)
+        assert rows in (128, 256) and qr.supported(x.shape, dh, 2), what
+        for kernel in ("qk_rope_fwd", "qk_rope_bwd"):
+            asked, took = _vmem(text, kernel)
+            assert asked == qr.vmem_bytes(rows, W, 2) < 20 * 2 ** 20, what
+            assert took < asked, (what, n, kernel, took, asked)
+
+
+def test_a_rotary_layer_s_text_holds_no_float32_heads_outside_the_kernels(
+        one_chip):
+    """Trinity's windowed rotary layer, recompute + backward, through
+    ``scripts/attn_outside_hlo.py`` (the no-chip reading ISSUE 47 was sized
+    by): no float32 array of the q projection's size is left in HBM by the
+    norm or the rotation (the parent broadcast ``cos`` and ``sin`` to
+    ``f32[6144,48,128]`` and moved 9.3 GB outside its matmuls and kernels;
+    what is left is the gate's, the flash backward's ``delta`` and the
+    copies around the matmuls), and both row kernels are in the text."""
+    import sys
+
+    sys.path.insert(0, os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "scripts"))
+    hlo = importlib.import_module("attn_outside_hlo")
+    cfg, batch, seq = hlo.cell_config("trinity_large_preview.s6144_scan",
+                                      tiny=False)
+    kind = hlo.default_kind(cfg)
+    assert (batch, seq, kind) == (1, 6144, (4096, True))
+    text = hlo.compiled_text(cfg, batch, seq, kind)
+    kernels = importlib.import_module("paddle_tpu.kernels._common")
+    assert kernels.on_tpu() is False            # the probes are put back
+    groups, by_kernel, others = hlo.account(text)
+    assert {"qk_rope_fwd", "qk_rope_bwd", "flash_swa_fwd",
+            "flash_swa_bwd_fused"} <= set(by_kernel)
+    # the entry computation's own instructions, fusions' insides left out
+    assert not [o for o in others if o[0] > 140e6 and o[3].startswith("f32")
+                and (o[2] == "broadcast" or "6144,48,64" in o[3])], others[:9]
+    assert groups["other"] < 3.5e9 and groups["matmul"] > 1.8e9
+
+
+def test_attn_outside_hlo_smoke(one_chip, capsys):
+    """The script end to end at a tiny configuration: LFM2's two heads a
+    lane block, the kernels compiled for the described chip."""
+    import sys
+
+    sys.path.insert(0, os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "scripts"))
+    hlo = importlib.import_module("attn_outside_hlo")
+    report = hlo.main(["lfm2_8b_a1b.s8192_scan", "--tiny", "--top", "3"])
+    assert report["kind"] == "(None, True)" and report["seq"] == 256
+    assert set(report["kernels_gb"]) == {
+        "qk_rope_fwd", "qk_rope_bwd", "flash_fwd", "flash_bwd_fused"}
+    assert 0 < report["gb"]["other"] < report["gb"]["matmul"]
+    assert json.loads(capsys.readouterr().out.splitlines()[-1]) == report

@@ -512,17 +512,22 @@ def test_the_new_scopes_hold_their_instructions_and_attention_none():
 # and SmallThinker's held through it), and PR 46 for SmallThinker and LFM2,
 # whose held share's 256 rows in two groups now go in 128-row tiles
 # (``moe._tiling``; OLMoE's tiny layer is one tile either way and held
-# through it); BERT's and Brumby's are still 03fc114's.
+# through it), and PR 47 for SmallThinker, LFM2 and Brumby, whose tiny q and
+# k are whole lane blocks (heads of 128, or two of 64) and so go through the
+# row kernel (``kernels/qk_rope.py``, their three projections through
+# ``_project``; OLMoE's 4 heads of 16 are half a lane block and keep the
+# plain matmuls and the ``rms_norm`` / ``rope`` lines, in their old order);
+# BERT's are still 03fc114's.
 PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "bert.run_steps": "00de5403506fdc87",
             "olmoe.step": "231114fcd62341f2",
             "olmoe.run_steps": "054338e92270f130",
-            "smallthinker.step": "0fbba21c5758d020",
-            "smallthinker.run_steps": "45f181056c27661f",
-            "lfm2.step": "d34653f0ac3afdc6",
-            "lfm2.run_steps": "4bfad11158397378",
-            "brumby.step": "be3328df1ffdda0a",
-            "brumby.run_steps": "07985218bf230094"}
+            "smallthinker.step": "2e8c6a9b2ddcf175",
+            "smallthinker.run_steps": "bd224212f334b389",
+            "lfm2.step": "bb536069bbd7206a",
+            "lfm2.run_steps": "f5ad889769877a83",
+            "brumby.step": "84e6b6d548803a44",
+            "brumby.run_steps": "5a063ea89a19f1a4"}
 OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
          "olmoe": (olmoe.build_olmoe_trainer, olmoe.olmoe_tiny_config, 32),
          "smallthinker": (smallthinker.build_smallthinker_trainer,

@@ -1,0 +1,244 @@
+"""``kernels/qk_rope.py`` (q/k norm and rotary positions in one pass) in
+Pallas interpret mode against the lines it replaces, ``rope(rms_norm(...))``
+of ``parallel/transformer.py``: outputs and every gradient; and ``_qkv``
+taking the kernel where the shapes allow and those lines where not."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu import monitor
+from paddle_tpu.kernels import qk_rope as K
+from paddle_tpu.parallel import transformer as T
+
+EPS, THETA = 1e-5, 1e4
+
+
+def reference(x, w, heads, dh, norm, rotary, first=0):
+    """Today's lines of ``_qkv`` on one projection."""
+    b, S, W = x.shape
+    if norm == "head":
+        x = T.rms_norm(x.reshape(b, S, heads, dh), w, EPS).reshape(b, S, W)
+    elif norm:
+        x = T.rms_norm(x, w, EPS)
+    return T.rope(x, heads, THETA, first) if rotary else x
+
+
+def kernel(x, w, heads, dh, norm, rotary, first=0):
+    tables = K.angle_tables(x.shape[1], dh, THETA, first) if rotary else None
+    return K.qk_rope(x, w, tables, head_dim=dh, norm=norm, eps=EPS)
+
+
+def operands(b, S, heads, dh, norm, dtype=jnp.float32, seed=0):
+    kx, kw, kg = jax.random.split(jax.random.PRNGKey(seed), 3)
+    W = heads * dh
+    x = (2 * jax.random.normal(kx, (b, S, W))).astype(dtype)
+    w = None if not norm else 1 + 0.3 * jax.random.normal(
+        kw, (dh if norm == "head" else W,))
+    return x, w, jax.random.normal(kg, (b, S, W))
+
+
+def value_and_grads(fn, x, w, g, *static, first=0):
+    """(output, dx, dw) of ``sum(fn(x, w) * g)``; no dw without a norm."""
+    def loss(x, w):
+        out = fn(x, w, *static, first)
+        return jnp.sum(out.astype(jnp.float32) * g), out
+    (_, out), grads = jax.value_and_grad(
+        loss, (0, 1) if w is not None else (0,), has_aux=True)(x, w)
+    return (out,) + tuple(grads)
+
+
+# rows: 48 fill no taller block than 16 (three grid steps of positions), 8 are
+# one block of one sublane tile; heads 3 on 1: grouped k widths beside q's
+@pytest.mark.parametrize("norm,rotary", [
+    ("head", True), ("head", False), ("whole", True), ("whole", False),
+    (None, True)])      # neither: ``_qkv`` makes no call
+@pytest.mark.parametrize("dh,heads,b,S", [(128, 3, 2, 48), (128, 1, 1, 8),
+                                          (64, 4, 2, 48), (64, 2, 1, 24),
+                                          (32, 4, 1, 16)])
+def test_kernel_equals_the_lines_it_replaces(dh, heads, b, S, norm, rotary):
+    x, w, g = operands(b, S, heads, dh, norm)
+    static = (heads, dh, norm, rotary)
+    got = value_and_grads(kernel, x, w, g, *static, first=5)
+    want = value_and_grads(reference, x, w, g, *static, first=5)
+    for name, a, r in zip(("out", "dx", "dw"), got, want):
+        assert a.shape == r.shape and a.dtype == r.dtype, name
+        # float32 both ways; a sum over rows for dw
+        np.testing.assert_allclose(a, r, rtol=2e-5, atol=5e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("norm", ["head", "whole", None])
+@pytest.mark.parametrize("dh,heads", [(128, 2), (64, 4)])
+def test_bf16_is_rounded_once_and_no_further_from_float32(dh, heads, norm):
+    """The replaced lines round after the norm and again after the rotation;
+    the kernel rounds its float32 result once: the float32 lines' result
+    rounded, and never further from them than today's path."""
+    x, w, g = operands(2, 32, heads, dh, norm, jnp.bfloat16, seed=1)
+    static = (heads, dh, norm, True)
+    exact = value_and_grads(reference, x.astype(jnp.float32), w, g, *static)
+    got = value_and_grads(kernel, x, w, g, *static)
+    old = value_and_grads(reference, x, w, g, *static)
+    assert got[0].dtype == got[1].dtype == jnp.bfloat16
+    f32 = lambda a: np.asarray(a, np.float32)
+    # the float32 lines rounded once, but for a value in a thousand whose
+    # float32 sums, in another order, lie either side of a rounding boundary
+    once = f32(exact[0].astype(jnp.bfloat16))
+    assert np.mean(f32(got[0]) != once) < 1e-3
+    np.testing.assert_allclose(f32(got[0]), once, rtol=2 ** -7, atol=1e-6)
+    for a, o, e in zip(got, old, exact):
+        assert np.abs(f32(a) - f32(e)).max() \
+            <= 1.001 * np.abs(f32(o) - f32(e)).max() + 1e-6
+
+
+def test_a_traced_first_under_lax_map_is_a_block_of_a_longer_sequence():
+    """Brumby's call: ``_by_row_blocks`` hands ``_qkv`` a block of rows and
+    its first position, traced, under ``lax.map`` and ``jax.checkpoint``."""
+    heads, dh, block = 2, 128, 16
+    x, w, g = operands(1, 4 * block, heads, dh, "head", seed=2)
+
+    def blocked(fn):
+        def whole(x, w, *static_and_first):
+            def rows(turn):
+                return fn(turn[0], w, heads, dh, "head", True, turn[1])
+            out = jax.lax.map(jax.checkpoint(rows), (
+                x.reshape(1, -1, block, heads * dh).swapaxes(0, 1),
+                jnp.arange(0, x.shape[1], block)))
+            return out.swapaxes(0, 1).reshape(x.shape)
+        return whole
+
+    got = value_and_grads(blocked(kernel), x, w, g)
+    want = value_and_grads(reference, x, w, g, heads, dh, "head", True)
+    for a, r in zip(got, want):
+        np.testing.assert_allclose(a, r, rtol=2e-5, atol=5e-5)
+
+
+@pytest.mark.parametrize("shape,dh,itemsize,rows", [
+    ((1, 6144, 6144), 128, 2, 128),     # Trinity's q: six blocks in 12 MiB
+    ((1, 6144, 1024), 128, 2, 256),     # its k
+    ((4, 4096, 2048), 128, 2, 256),     # OLMoE
+    ((2, 8192, 2048), 64, 2, 256),      # LFM2, two heads a lane block
+    ((1, 16384, 3584), 128, 2, 256),    # SmallThinker
+    ((1, 2048, 5120), 128, 2, 128),     # Brumby's row block
+    ((2, 48, 384), 128, 4, 16),
+    ((2, 24, 256), 64, 2, None),        # 24 rows are no whole bf16 tiles
+    ((2, 32, 64), 16, 4, None),         # tiny OLMoE: half a lane block
+    ((2, 32, 768), 96, 4, None),        # a head across lane blocks
+    ((1, 16, 256 * 1024), 128, 4, None),  # no block within BLOCK_VMEM
+])
+def test_the_shapes_the_kernel_takes(shape, dh, itemsize, rows):
+    assert K.supported(shape, dh, itemsize) == (rows is not None)
+    if rows:
+        assert K.block_rows(shape[1], shape[2], itemsize) == rows
+        assert 6 * rows * shape[2] * itemsize <= K.BLOCK_VMEM
+        assert K.vmem_bytes(rows, shape[2], itemsize) < 24 * 2 ** 20
+    else:
+        x = jnp.zeros(shape, jnp.float32 if itemsize == 4 else jnp.bfloat16)
+        with pytest.raises(ValueError, match="not supported"):
+            K.qk_rope(x, None, None, head_dim=dh)
+
+
+def _config(**kw):
+    d = dict(vocab_size=64, hidden=32, n_layers=1, n_heads=2, n_kv_heads=1,
+             head_width=128, ffn_hidden=64, max_seq=64, causal=True,
+             norm="rms", positions="rotary", qk_norm="head", bias=False,
+             dtype="float32")
+    d.update(kw)
+    return T.TransformerConfig(**d)
+
+
+def _counted(tmp_path, trace):
+    """{(dh, norm, rotary, fused): calls} that ``trace()`` counts in
+    ``monitor.kernels.qk_rope_calls`` under a monitor session."""
+    mon = monitor.enable(str(tmp_path), flight=False)
+    try:
+        mon.registry.reset()        # the registry is the process's
+        trace()
+        return {tuple(r["labels"][k] for k in ("dh", "norm", "rotary",
+                                               "fused")): r["value"]
+                for r in mon.registry.snapshot()
+                if r["name"] == "monitor.kernels.qk_rope_calls"}
+    finally:
+        monitor.disable()
+
+
+@pytest.mark.parametrize("S,fused", [(16, 1), (12, 0)])
+def test_qkv_takes_the_kernel_where_the_shapes_allow(tmp_path, S, fused):
+    """2 query heads on 1 key/value head of 128: the kernel takes q and k
+    at 16 positions, and at 12 (no whole sublane tiles) ``_qkv`` keeps the
+    ``rms_norm`` / ``rope`` lines and counts ``fused=0``; both ways the
+    result is those lines'."""
+    cfg = _config()
+    keys = jax.random.split(jax.random.PRNGKey(3), 6)
+    h = jax.random.normal(keys[0], (2, S, 32))
+    pl = {"wq": jax.random.normal(keys[1], (32, 256)) / 6,
+          "wk": jax.random.normal(keys[2], (32, 128)) / 6,
+          "wv": jax.random.normal(keys[3], (32, 128)) / 6,
+          "q_norm": 1 + 0.2 * jax.random.normal(keys[4], (128,)),
+          "k_norm": 1 + 0.2 * jax.random.normal(keys[5], (128,))}
+    T._qkv(pl, h, cfg, True, 3)             # off the monitor: nothing counts
+    out = []
+    assert _counted(tmp_path, lambda: out.extend(
+        T._qkv(pl, h, cfg, True, 3))) == {(128, "head", 1, fused): 2}
+    q, k, v = out
+    np.testing.assert_allclose(q, reference(
+        h @ pl["wq"], pl["q_norm"], 2, 128, "head", True, 3),
+        rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(k, reference(
+        h @ pl["wk"], pl["k_norm"], 1, 128, "head", True, 3),
+        rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(v, h @ pl["wv"])
+    # a layer without positions and without a norm makes no call at all
+    assert _counted(tmp_path, lambda: T._qkv(
+        pl, h, _config(qk_norm=False), False)) == {}
+
+
+# tiny model -> (sequence, the projections its forward counts): BERT (learned
+# positions, no q/k norm) and Mistral-Small-4 (``_latent_qkv``) make no call;
+# OLMoE's 4 heads of 16 are half a lane block, so it keeps the XLA lines; the
+# others' widths are whole lane blocks and take the kernel (SmallThinker's
+# and Trinity's layers without positions: no call, and the norm alone)
+ENGAGED = {
+    "bert": (32, set()),
+    "mistral4": (64, set()),
+    "olmoe": (32, {(16, "whole", 1, 0)}),
+    "smallthinker": (64, {(128, "none", 1, 1)}),
+    "lfm2": (64, {(64, "head", 1, 1)}),
+    "brumby": (64, {(128, "head", 1, 1)}),
+    "trinity": (64, {(128, "head", 1, 1), (128, "head", 0, 1)}),
+}
+
+
+@pytest.mark.parametrize("model", list(ENGAGED))
+def test_which_tiny_models_take_the_kernel(tmp_path, model):
+    import importlib
+
+    from paddle_tpu.parallel import decoder
+
+    seq, want = ENGAGED[model]
+    module = importlib.import_module("paddle_tpu.models." + model)
+    cfg = getattr(module, model + "_tiny_config")(remat=True)
+    params = jax.eval_shape(lambda: T._init_params(jax.random.PRNGKey(0), cfg))
+    ids = jax.ShapeDtypeStruct((2, seq), jnp.int32)
+    got = _counted(tmp_path, lambda: jax.eval_shape(
+        lambda p, i: decoder.forward(p, i, cfg)[0], params, ids))
+    assert set(got) == want
+    assert all(n % 2 == 0 for n in got.values())    # q and k, every call
+
+
+def test_project_is_the_matmul_and_its_gradients():
+    """``_project`` (the weight's gradient made beside the input's, behind
+    one ``optimization_barrier``) is ``h @ w`` to the bit forward, and both
+    gradients to a float32 sum's order: the barrier orders, it computes
+    nothing."""
+    keys = jax.random.split(jax.random.PRNGKey(4), 3)
+    h = jax.random.normal(keys[0], (2, 16, 32))
+    w = jax.random.normal(keys[1], (32, 128)) / 6
+    g = jax.random.normal(keys[2], (2, 16, 128))
+    want = jax.value_and_grad(lambda h, w: jnp.sum((h @ w) * g), (0, 1))(h, w)
+    got = jax.value_and_grad(
+        lambda h, w: jnp.sum(T._project(h, w) * g), (0, 1))(h, w)
+    np.testing.assert_array_equal(got[0], want[0])
+    for a, r in zip(got[1], want[1]):
+        assert a.dtype == r.dtype
+        np.testing.assert_allclose(a, r, rtol=1e-5, atol=1e-5)
