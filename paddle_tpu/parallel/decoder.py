@@ -2,9 +2,9 @@
 (``transformer.py``) and the step it runs (``train.py``): forward, loss,
 ``logits_at``, a call's observations and the builder, ONE of each for every
 decoder of this block.  A model (``models/olmoe.py``, ``smallthinker.py``,
-``lfm2.py``, ``brumby.py``, ``mistral4.py``, ``trinity.py``) is a
-``TransformerConfig`` and a label; what its trainer computes and what it
-observes follow from the configuration, never from which model it is.
+``lfm2.py``, ``brumby.py``, ``mistral4.py``, ``trinity.py``, ``jamba.py``)
+is a ``TransformerConfig`` and a label; what its trainer computes and what
+it observes follow from the configuration, never from which model it is.
 
 batch dict: ``ids`` int32 [B, S] alone.  The loss builds the next-token
 labels itself (``labels[t] = ids[t + 1]``, positions 0..S-2 count) and adds
@@ -27,6 +27,7 @@ from .train import (StepTrainer, TrainState, gauge_flash_grid,
                     make_train_step, shard_pytree, state_specs)
 from .transformer import (
     CONV,
+    MAMBA,
     RETENTION,
     TransformerConfig,
     embed,
@@ -34,6 +35,7 @@ from .transformer import (
     grad_sync_axes,
     head_logits,
     init_transformer_params,
+    mamba_operands,
     retention_log_decay,
     rms_norm,
     run_layers,
@@ -126,8 +128,11 @@ def _first_layer_input(params, ids, cfg):
         pl = params["prefix_layers"]["l0"]
     else:
         layers = params["params_layers"]
-        pl = jax.tree.map(lambda a: a[0],
-                          layers["p0"] if cfg.per_position else layers)
+        if cfg.run_scan:        # [periods, run length, ...]
+            pl = jax.tree.map(lambda a: a[0, 0], layers["r0"])
+        else:
+            pl = jax.tree.map(lambda a: a[0],
+                              layers["p0"] if cfg.per_position else layers)
     return pl, rms_norm(embed(params, ids, cfg), pl["ln1_scale"],
                         cfg.norm_eps)
 
@@ -160,7 +165,7 @@ class DecoderTrainer(StepTrainer):
     (``<label>.step``, ``<label>.run_steps``)."""
 
     label: str = "decoder"
-    _logits_fn = _routing_fn = _gate_fn = _attn_gate_fn = None
+    _logits_fn = _routing_fn = _gate_fn = _attn_gate_fn = _mamba_fn = None
 
     def _on_mesh(self, fn, out_specs, *more):
         """``fn(params, ids [b, S], *more)`` jitted over the mesh, the
@@ -210,6 +215,12 @@ class DecoderTrainer(StepTrainer):
           ``retention_state_sweeps`` and ``retention_gate_mean``, the mean
           ``e^g`` over tokens and heads of the call's first batch in layer
           0: a state decays to 1/e in ``1 / (1 - mean)`` tokens or so;
+        - the first layer is MAMBA: ``mamba_dt_mean``, the mean step size
+          over tokens and channels of the call's first batch in that layer,
+          and ``mamba_decay_min``, the smallest ``exp(dt * A)`` of any
+          token, channel and state cell there: with the mean's own decay
+          ``exp(-dt)`` it tells a state that never carries (both near 0)
+          from one that never forgets (both near 1);
         - ``attn_gate``: ``attn_gate_mean``, the mean of the output gate's
           sigmoid over tokens, heads and columns of the call's first batch
           in the first layer: a gate stuck at 0 or 1 is a dead branch;
@@ -234,7 +245,7 @@ class DecoderTrainer(StepTrainer):
         def count(name, amount):
             mon.registry.counter("monitor.train." + name).incr(amount)
 
-        if any(k not in (CONV, RETENTION) for k in cfg.layer_kinds):
+        if any(k not in (CONV, RETENTION, MAMBA) for k in cfg.layer_kinds):
             gauge_flash_grid(cfg, local, seq)
         if cfg.n_experts:
             pairs = int(ids.size) * cfg.experts_per_token * cfg.moe_layers
@@ -272,6 +283,20 @@ class DecoderTrainer(StepTrainer):
                 self._gate_fn = self._on_mesh(gate_mean, P())
             gauge("retention_gate_mean",
                   float(self._gate_fn(params, batches[0])))
+        if cfg.layer_kinds[0] == MAMBA and not cfg.prefix_pattern:
+            if self._mamba_fn is None:
+                def step_sizes(params, ids):
+                    pl, h = _first_layer_input(params, ids, cfg)
+                    dt = mamba_operands(pl, h, cfg, h, 0)[2]
+                    # the fastest cell of each channel under its largest step
+                    rate = jnp.max(jnp.exp(pl["a_log"]), axis=-1)
+                    return jnp.mean(dt), jnp.exp(-jnp.max(
+                        jnp.max(dt, axis=(0, 1)) * rate))
+
+                self._mamba_fn = self._on_mesh(step_sizes, (P(), P()))
+            dt_mean, decay_min = self._mamba_fn(params, batches[0])
+            gauge("mamba_dt_mean", float(dt_mean))
+            gauge("mamba_decay_min", float(decay_min))
         if cfg.attn_gate:
             if self._attn_gate_fn is None:
                 def attn_gate_mean(params, ids):

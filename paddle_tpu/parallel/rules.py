@@ -259,6 +259,11 @@ def transformer_rules(cfg):
         # (TransformerConfig): its leading layers' leaves are not stacked,
         # and all of it is whole on every device
         (r"^prefix_layers/|^router_bias$", P()),
+        # so does a stack whose runs are stacked [periods, run length, ...]
+        # (``run_scan``), and the Mamba mixer's leaves wherever they stand
+        (r"^params_layers/r\d+/", P()),
+        (r"/(w_in|conv_b|w_x|dt_norm|b_norm|c_norm|w_dt|b_dt|a_log|d_skip"
+         r"|w_out)$", P()),
         (r"/(conv_in|conv_w|conv_out|w_gate_up|w_down|ws_gate_up|ws_down)$",
          L(None, None)),
         # latent attention runs at tp == 1 (TransformerConfig)

@@ -19,7 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import collectives as col
 from .mesh import local_shard_map
-from .transformer import CONV, _packed_flash_blocks
+from .transformer import _packed_flash_blocks
 from .. import monitor, warm as _warm
 from ..monitor import devscope as _devscope, memscope as _memscope
 from ..monitor.recompile import FIRST_CALL, compile_ledger
@@ -314,8 +314,9 @@ def gauge_flash_grid(cfg, b, S):
         mon.registry.gauge("monitor.kernels.flash_bwd_sweeps_" + name).set(
             sweeps)
     if cfg.layer_pattern:
-        window = max((k[0] or 0 for k in cfg.layer_kinds if k != CONV),
-                     default=0) or None
+        # the attention positions: the others are names
+        window = max((k[0] or 0 for k in cfg.layer_kinds
+                      if isinstance(k, tuple)), default=0) or None
         for name, w in (("full", None), ("windowed", window)):
             mon.registry.gauge(
                 "monitor.kernels.flash_kv_blocks_visited_" + name).set(

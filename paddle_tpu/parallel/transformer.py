@@ -37,9 +37,10 @@ from ..monitor import devscope
 from ..monitor.recompile import compile_ledger
 from .ring_attention import ring_attention
 
-__all__ = ["TransformerConfig", "CONV", "RETENTION",
+__all__ = ["TransformerConfig", "CONV", "RETENTION", "MAMBA",
            "init_transformer_params", "transformer_param_specs",
            "grad_sync_axes", "embed", "transformer_layer", "run_layers",
+           "mamba_mixer", "mamba_operands",
            "rms_norm", "rope", "rope_pairs", "yarn_blend_range",
            "yarn_frequencies",
            "yarn_softmax_scale", "yarn_rotary_factor", "final_logits_loss",
@@ -50,7 +51,11 @@ CONV = "conv"       # a layer kind: the gated short convolution, no attention
 # a layer kind: power retention on attention's projections, a learned
 # per-token decay and a state carried along the sequence; no softmax
 RETENTION = "retention"
-_OWN_LEAVES = (CONV, RETENTION)     # kinds whose position owns other leaves
+# a layer kind: the Mamba-1 mixer where attention stands: a causal depthwise
+# convolution, step sizes and rates of its own for every channel and state
+# cell, a selective scan carried along the sequence, an output gate
+MAMBA = "mamba"
+_OWN_LEAVES = (CONV, RETENTION, MAMBA)  # kinds whose position owns other leaves
 
 
 def _kinds(pattern):
@@ -86,7 +91,9 @@ class TransformerConfig:
     # 2024 kind sets them all (models/olmoe.py).
     norm: str = "layer"              # "layer" | "rms" (scale only, no bias leaf)
     norm_eps: float = 1e-6
-    positions: str = "learned"       # "learned" (pos_emb) | "rotary" (no pos_emb)
+    # "learned" (pos_emb) | "rotary" (no pos_emb) | None (a stack of several
+    # layer kinds none of which adds or rotates positions)
+    positions: object = "learned"
     rope_theta: float = 10000.0
     # False | True: RMS norm of the whole q / k projection, before the heads
     # | "head": of each head on its own, one weight [head_dim] for all heads
@@ -121,7 +128,9 @@ class TransformerConfig:
     # attention leaves; or RETENTION, a layer that keeps attention's
     # projections (and ``qk_norm``, rotary positions, the grouping on
     # ``n_kv_heads``) and replaces the softmax by power retention
-    # (``power_retention``), with a gate projection ``wg`` of its own.
+    # (``power_retention``), with a gate projection ``wg`` of its own; or
+    # MAMBA, a layer whose operator is the Mamba-1 mixer (``mamba_mixer``)
+    # and which has no attention leaves.
     # Empty: one kind, full attention, rotary as ``positions`` says.
     # n_layers is ``prefix_pattern`` and whole periods.
     layer_pattern: tuple = ()
@@ -134,6 +143,19 @@ class TransformerConfig:
     dense_ffn_hidden: int = 0
     conv_taps: int = 3               # CONV: taps of the causal depthwise filter
     retention_chunk: int = 1024      # RETENTION: tokens between two states
+    # MAMBA: the mixer's inner width (channels), the state cells a channel,
+    # the taps of its causal depthwise filter, the rank the step sizes are
+    # projected through, and the tokens between two states the scan keeps
+    d_inner: int = 0
+    d_state: int = 0
+    d_conv: int = 0
+    dt_rank: int = 0
+    scan_chunk: int = 128
+    # a RUN, consecutive positions of one kind inside a period, is one tree
+    # ``params_layers["r<i>"]`` stacked [n_periods, run length, ...] and one
+    # inner scan of the period's body (``run_layers``), where each position
+    # would else be a tree ``p<i>`` and a copy of its layer in the body
+    run_scan: bool = False
     # Latent attention (kv_lora_rank > 0; every layer, in place of wq / wk /
     # wv): queries off a latent of q_lora_rank, RMS-normed; keys and values
     # off ONE latent of kv_lora_rank, RMS-normed, beside which the same
@@ -188,7 +210,7 @@ class TransformerConfig:
 
     def __post_init__(self):
         assert self.norm in ("layer", "rms") and \
-            self.positions in ("learned", "rotary")
+            self.positions in ("learned", "rotary", None)
         assert self.router_input in ("ffn", "block")
         assert self.qk_norm in (False, True, "head"), self.qk_norm
         if self.qk_norm or self.positions == "rotary" or self.n_experts:
@@ -206,8 +228,13 @@ class TransformerConfig:
             tuple(k if k in _OWN_LEAVES else (int(k[0]), bool(k[1]))
                   for k in pattern)
             for pattern in (self.layer_pattern, self.prefix_pattern))
+        if self.positions is None:
+            # no table and no rotation: every attention position says so
+            assert self.layer_pattern and not any(
+                k[1] for k in self.layer_pattern + self.prefix_pattern
+                if k not in _OWN_LEAVES)
         if self.layer_pattern:
-            assert self.positions == "rotary" and self.causal \
+            assert self.positions != "learned" and self.causal \
                 and self.tp == self.pp == 1 \
                 and (self.n_layers - len(self.prefix_pattern)) \
                 % len(self.layer_pattern) == 0
@@ -216,6 +243,10 @@ class TransformerConfig:
                 and self.dense_ffn_hidden and not self.bias
         if self.per_position and not self.n_experts:
             assert self.dense_ffn_hidden and not self.bias
+        if MAMBA in self.layer_pattern + self.prefix_pattern:
+            assert self.d_inner and self.d_state and self.d_conv \
+                and self.dt_rank
+        assert self.per_position or not self.run_scan
         if self.latent:
             assert self.positions == "rotary" and self.tp == 1 \
                 and not (self.bias or self.qk_norm or self.layer_pattern) \
@@ -255,8 +286,8 @@ class TransformerConfig:
 
     @property
     def layer_kinds(self):
-        """(window or None, rotary), or CONV or RETENTION, of each layer of
-        one period."""
+        """(window or None, rotary), or CONV, RETENTION or MAMBA, of each
+        layer of one period."""
         if not self.layer_pattern:
             return ((None, self.positions == "rotary"),)
         return _kinds(self.layer_pattern)
@@ -272,6 +303,19 @@ class TransformerConfig:
         them by position of the period (``init_transformer_params``)."""
         return bool(self.prefix_pattern) or any(
             k in _OWN_LEAVES for k in self.layer_pattern)
+
+    @property
+    def runs(self):
+        """``(first position, kind, length)`` of each run of one period:
+        with ``run_scan`` the consecutive positions of one kind, else every
+        position a run of its own."""
+        runs = []
+        for at, kind in enumerate(self.layer_kinds):
+            if self.run_scan and runs and runs[-1][1] == kind:
+                runs[-1][2] += 1
+            else:
+                runs.append([at, kind, 1])
+        return tuple(tuple(r) for r in runs)
 
     @property
     def n_periods(self):
@@ -501,8 +545,9 @@ def _position_leaves(key, cfg, kind, n, dense):
     and C and the value, side by side), ``conv_w`` [taps, E] (tap j meets
     position t - taps + 1 + j) and ``conv_out`` [E, E], for RETENTION
     attention's and the gate projection ``wg`` [E, kv_heads] float32 (one
-    log-decay a key/value head and token); what ``_branch_leaves`` adds;
-    then the FFN's (``_ffn_leaves``), dense or the MoE's."""
+    log-decay a key/value head and token), for MAMBA ``_mamba_leaves``';
+    what ``_branch_leaves`` adds; then the FFN's (``_ffn_leaves``), dense or
+    the MoE's."""
     assert cfg.norm == "rms" and not cfg.bias
     E, dt = cfg.hidden, cfg.jdtype
     Q, KV = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
@@ -518,15 +563,51 @@ def _position_leaves(key, cfg, kind, n, dense):
         leaves.update(conv_in=stack(1, E, (E, 3 * E)),
                       conv_w=stack(2, cfg.conv_taps, (cfg.conv_taps, E)),
                       conv_out=stack(3, E, (E, E)))
+    elif kind == MAMBA:
+        leaves.update(_mamba_leaves(stack, keys, cfg))
     else:
         leaves.update(wq=stack(1, E, (E, Q)), wk=stack(2, E, (E, KV)),
                       wv=stack(3, E, (E, KV)), wo=stack(4, Q, (Q, E)))
         leaves.update(_qk_norm_leaves(cfg, n))
         if kind == RETENTION:
             leaves["wg"] = stack(10, E, (E, cfg.kv_heads), jnp.float32)
-    leaves.update(_branch_leaves(stack, cfg, n, 11, attention=kind != CONV))
+    leaves.update(_branch_leaves(stack, cfg, n, 11,
+                                 attention=kind not in (CONV, MAMBA)))
     leaves.update(_ffn_leaves(stack, cfg, 5 if dense else 7, dense))
     return leaves
+
+
+def _mamba_leaves(stack, keys, cfg):
+    """The Mamba-1 mixer's leaves of ``len(keys)`` stacked layers: ``w_in``
+    [E, 2d] (the scan's input x and the gate z, side by side), ``conv_w``
+    [taps, d] (tap j meets position t - taps + 1 + j) and ``conv_b`` [d],
+    ``w_x`` [d, R + 2N] (the step sizes' low-rank input, then B, then C),
+    the three inner norms' weights ``dt_norm`` [R], ``b_norm`` / ``c_norm``
+    [N], ``w_dt`` [R, d] and ``b_dt`` [d], ``a_log`` [d, N], ``d_skip`` [d],
+    ``w_out`` [d, E]; the norms, ``b_dt``, ``a_log`` and ``d_skip`` float32.
+
+    Matrices, the filter and its bias at their fan-in's scale; ``a_log`` =
+    log(1..N) in every channel and ``d_skip`` = 1 (the published mixer's own
+    constructor); ``b_dt`` the inverse softplus of a step size drawn
+    log-uniform in [1e-3, 1e-1] (arXiv:2312.00752, section 3.6): a cell's
+    decay ``exp(-dt n)`` then runs from 0.999 to 0.2 a token."""
+    E, d, N, R = cfg.hidden, cfg.d_inner, cfg.d_state, cfg.dt_rank
+    n, f32 = len(keys), jnp.float32
+    dt0 = jnp.exp(jax.vmap(lambda k: jax.random.uniform(
+        jax.random.fold_in(k, 26), (d,), f32, math.log(1e-3),
+        math.log(1e-1)))(keys))
+    return dict(
+        w_in=stack(20, E, (E, 2 * d)),
+        conv_w=stack(21, cfg.d_conv, (cfg.d_conv, d)),
+        conv_b=stack(22, cfg.d_conv, (d,)),
+        w_x=stack(23, d, (d, R + 2 * N)),
+        dt_norm=jnp.ones((n, R), f32), b_norm=jnp.ones((n, N), f32),
+        c_norm=jnp.ones((n, N), f32),
+        w_dt=stack(24, R, (R, d)),
+        b_dt=dt0 + jnp.log(-jnp.expm1(-dt0)),       # softplus^-1(dt0)
+        a_log=jnp.tile(jnp.log(jnp.arange(1, N + 1, dtype=f32)), (n, d, 1)),
+        d_skip=jnp.ones((n, d), f32),
+        w_out=stack(25, d, (d, E)))
 
 
 def _router_bias(key, cfg):
@@ -551,15 +632,27 @@ def _router_bias(key, cfg):
 
 def _per_position_layers(ks, cfg):
     """``prefix_layers`` and ``params_layers`` of a stack whose layers own
-    different leaves."""
-    assert cfg.positions == "rotary"
-    layers = {
-        "params_layers": {
-            "p%d" % i: _position_leaves(jax.random.fold_in(ks[0], i), cfg,
-                                        kind, cfg.n_periods,
-                                        dense=not cfg.n_experts)
-            for i, kind in enumerate(cfg.layer_kinds)},
-    }
+    different leaves: a tree ``p<i>`` each position of the period, stacked
+    [n_periods, ...], or with ``cfg.run_scan`` a tree ``r<i>`` each run,
+    its positions' trees (seeded as they would be alone) stacked behind the
+    periods: [n_periods, run length, ...]."""
+    # a table of learned positions is no position's leaf; rotary positions,
+    # or none at all, are each attention position's own
+    assert cfg.positions != "learned"
+
+    def position(i, kind):
+        return _position_leaves(jax.random.fold_in(ks[0], i), cfg, kind,
+                                cfg.n_periods, dense=not cfg.n_experts)
+
+    if cfg.run_scan:
+        stacked = {"r%d" % at: jax.tree.map(
+            lambda *a: jnp.stack(a, axis=1),
+            *[position(first + i, kind) for i in range(length)])
+            for at, (first, kind, length) in enumerate(cfg.runs)}
+    else:
+        stacked = {"p%d" % i: position(i, kind)
+                   for i, kind in enumerate(cfg.layer_kinds)}
+    layers = {"params_layers": stacked}
     if cfg.prefix_pattern:
         layers["prefix_layers"] = {
             "l%d" % i: jax.tree.map(lambda a: a[0], _position_leaves(
@@ -1075,6 +1168,79 @@ def power_retention(pl, h, cfg):
     return o @ pl["wo"]
 
 
+def _mamba_step_sizes(pl, x, cfg):
+    """Of the convolved rows ``x`` [b, S, d]: the step sizes ``dt`` [b, S,
+    d] and the scan's ``B`` and ``C`` [b, S, N], float32: ``[delta, B, C] =
+    split(x @ w_x)``, each RMS-normed by its own weight, ``dt =
+    softplus(delta @ w_dt + b_dt)``."""
+    R, N, f32 = cfg.dt_rank, cfg.d_state, jnp.float32
+    # 192 columns: kept in float32 on their way to the norms
+    delta, bmat, cmat = jnp.split(
+        jnp.matmul(x, pl["w_x"], preferred_element_type=f32), [R, R + N],
+        axis=-1)
+    delta, bmat, cmat = (_rms(t, pl[w], cfg.norm_eps) for t, w in (
+        (delta, "dt_norm"), (bmat, "b_norm"), (cmat, "c_norm")))
+    dt = jax.nn.softplus(jnp.matmul(
+        delta.astype(x.dtype), pl["w_dt"], preferred_element_type=f32)
+        + pl["b_dt"])
+    return dt, bmat, cmat
+
+
+def mamba_operands(pl, h, cfg, rows, first):
+    """What the selective scan reads of the positions ``first``.. of ``h``
+    [b, S, E] (``rows``, a block of them or all): the convolved x, the gate
+    z, ``_mamba_step_sizes``.  The filter reaches ``d_conv - 1`` tokens back:
+    a block past the first projects those rows of ``h`` again, its halo."""
+    taps = pl["conv_w"].astype(jnp.float32)
+    halo, n = taps.shape[0] - 1, rows.shape[1]
+    if isinstance(first, int) and first == 0:       # the whole sequence
+        before = jnp.zeros(rows.shape[:1] + (halo, cfg.d_inner), jnp.float32)
+    else:
+        back = jax.lax.dynamic_slice_in_dim(
+            h, jnp.maximum(first - halo, 0), halo, axis=1)
+        # the first block's halo lies before position 0: zeros
+        before = jnp.where(first > 0, (back @ pl["w_in"][:, :cfg.d_inner])
+                           .astype(jnp.float32), 0.0)
+    x, z = jnp.split(rows @ pl["w_in"], 2, axis=-1)
+    padded = jnp.concatenate([before, x.astype(jnp.float32)], axis=1)
+    conv = pl["conv_b"].astype(jnp.float32) + sum(
+        taps[j] * padded[:, j:j + n] for j in range(halo + 1))
+    x = jax.nn.silu(conv).astype(rows.dtype)
+    return (x, z) + _mamba_step_sizes(pl, x, cfg)
+
+
+@devscope.scoped(devscope.MAMBA)
+def mamba_mixer(pl, h, cfg):
+    """The Mamba-1 mixer on ``h`` [b, S, E], the whole sequence: ``[x, z] =
+    split(h @ w_in)``; ``x = silu(conv(x) + conv_b)``, a causal depthwise
+    filter of ``d_conv`` taps whose input is zero before position 0; step
+    sizes, B and C off x (``_mamba_step_sizes``); the selective scan at the
+    rates ``-exp(a_log)`` with the skip ``d_skip`` and the gate ``silu(z)``
+    (``kernels/selective_scan.py``, chunks of ``cfg.scan_chunk`` tokens
+    clamped to S; the per-token scan in ``jnp`` where the kernels do not
+    take the shapes); then ``w_out``.  The filter, the norms, the step sizes
+    and the scan's state in float32.  What lies between the projections and
+    the scan runs a block of positions at a time where the activations are
+    large (``_by_row_blocks``)."""
+    from ..kernels import selective_scan as scan
+
+    # the stage's widest activation is the projection [x | z]
+    x, z, dt, bmat, cmat = _by_row_blocks(
+        lambda rows, first: mamba_operands(pl, h, cfg, rows, first), h,
+        2 * cfg.d_inner)
+    chunk = min(cfg.scan_chunk, h.shape[1])
+    kernel = scan.supported(x.shape, cfg.d_state, chunk)
+    mon = monitor.active()
+    if mon is not None:
+        mon.registry.counter("monitor.kernels.selective_scan_calls",
+                             fused=int(kernel)).incr()
+    with jax.named_scope(devscope.SELECTIVE_SCAN):
+        operands = (x, dt, bmat, cmat, z, -jnp.exp(pl["a_log"]), pl["d_skip"])
+        y = scan.selective_scan(*operands, chunk=chunk) if kernel \
+            else scan.selective_scan_reference(*operands)
+    return y @ pl["w_out"]
+
+
 # rows x width of a pointwise stage's widest activation (an FFN's hidden
 # rows, the projections between their matmuls and the kernel) past which it
 # runs a block of positions at a time, each block's forward run again in its
@@ -1142,8 +1308,9 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
                       dense=False, router_bias=None):
     """One pre-norm block on the SP activation [b, S/tp, E]: the new
     activation and the FFN's auxiliary values (the MoE's, ``moe.route_top_k``;
-    None for a dense FFN).  ``kind`` = (window or None, rotary), or CONV, or
-    RETENTION: which of ``cfg.layer_kinds`` this layer is (None: the first);
+    None for a dense FFN).  ``kind`` = (window or None, rotary), or CONV,
+    RETENTION or MAMBA: which of ``cfg.layer_kinds`` this layer is (None: the
+    first);
     ``dense``: a layer whose FFN is the dense gated one (a leading layer, or
     any layer of a stack without experts); ``router_bias`` [n]:
     this layer's selection biases, where the routing rule has them."""
@@ -1161,6 +1328,10 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
     elif kind == RETENTION:
         with jax.named_scope(devscope.RETENTION):
             x_sp = _add_branch(x_sp, power_retention(
+                pl, _norm(x_sp, pl, "ln1", cfg), cfg), pl, "ln1", cfg)
+    elif kind == MAMBA:
+        with jax.named_scope(devscope.MAMBA):
+            x_sp = _add_branch(x_sp, mamba_mixer(
                 pl, _norm(x_sp, pl, "ln1", cfg), cfg), pl, "ln1", cfg)
     else:
         with jax.named_scope(devscope.LATENT_ATTENTION if cfg.latent
@@ -1235,7 +1406,14 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
     tree for each position, stacked [L / period, ...], the leading layers
     (``prefix``, a tree each) run before the scan under the same remat, and
     ``router_bias`` [moe_layers, n] is read a period's rows a turn.  One
-    kind is the scan over layers it always was.  The scan's own work (its
+    kind is the scan over layers it always was.
+
+    A RUN is consecutive positions of one kind inside a period.  With
+    ``cfg.run_scan`` ``layer_params`` holds a tree for each run, stacked
+    [L / period, run length, ...], and the period's body scans each run's
+    layers under the same remat: the traced program holds ONE layer of each
+    run, not one of each position (a period of 13 layers of one kind around
+    one of another is three bodies, not fourteen).  The scan's own work (its
     slices of the stacked leaves, what it keeps for the backward pass, the
     gradients it stacks: 4 % of a step where a layer's leaves are 0.5 GB)
     goes under the scope ``layer_scan``; a layer's under the layer's."""
@@ -1255,7 +1433,8 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
     if cfg.per_position:
         for i, kind in enumerate(cfg.prefix_kinds):
             x_sp, _ = body(prefix["l%d" % i], x_sp, cfg, kind, True)
-        at_position = [layer_params["p%d" % i] for i in range(len(kinds))]
+        at_position = layer_params if cfg.run_scan \
+            else [layer_params["p%d" % i] for i in range(len(kinds))]
     else:
         at_position = jax.tree.map(
             lambda a: a.reshape((-1, len(kinds)) + a.shape[1:]), layer_params)
@@ -1275,8 +1454,24 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
             auxes.append(aux)
         return x, jax.tree.map(lambda *a: jnp.stack(a), *auxes)
 
+    def period_of_runs(x, turn):
+        """The same over a tree for each run, stacked [run length, ...]: a
+        scan over each run's layers."""
+        pls, biases = turn
+        dense, auxes = not cfg.n_experts, []
+        for at, (first, kind, length) in enumerate(cfg.runs):
+            own = None if biases is None else biases[first:first + length]
+            x, aux = jax.lax.scan(
+                lambda x, layer: body(layer[0], x, cfg, kind, dense,
+                                      layer[1]),
+                x, (pls["r%d" % at], own))
+            auxes.append(aux)
+        return x, None if dense else jax.tree.map(
+            lambda *a: jnp.concatenate(a), *auxes)
+
     with jax.named_scope(devscope.LAYER_SCAN):
-        x_sp, aux = jax.lax.scan(period, x_sp, (at_position, router_bias),
+        x_sp, aux = jax.lax.scan(period_of_runs if cfg.run_scan else period,
+                                 x_sp, (at_position, router_bias),
                                  unroll=unroll)
     aux = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), aux)
     return (x_sp, aux) if with_aux else x_sp

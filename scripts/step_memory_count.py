@@ -1,0 +1,105 @@
+"""The TPU compiler's own count of what a cell's ``run_steps`` holds, no chip:
+
+    JAX_PLATFORMS=cpu python3 scripts/step_memory_count.py <cell> [key=value ...]
+
+The cell's trainer is built from SHAPES (``jax.eval_shape`` of the seeded
+tree and of the optimizer's state) on a mesh of one described ``v5e:2x2``
+device, the kernels' ``_on_tpu`` patched True in THIS process, and
+``run_steps`` over the cell's staged batches is compiled for it with
+``--xla_dump_to`` set: the dump's memory-usage report is the count PERF.md
+section 4 gives for every decoder cell (PR 37's recipe), printed beside
+``memory_analysis()`` and the largest buffers of the report.  ``key=value``
+overrides the configuration factory's arguments (``n_layers=28``).  A
+compile that passes is not a chip run: what the chip reserves is read off
+the cell's own run (``peak_hbm_gb`` and the line's ``memory`` fields)."""
+
+import glob
+import importlib
+import json
+import math
+import os
+import pkgutil
+import re
+import sys
+import tempfile
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+DUMP = tempfile.mkdtemp(prefix="step_memory_")
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") \
+    + " --xla_dump_to=%s --xla_dump_hlo_as_text=false" % DUMP
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.experimental import topologies  # noqa: E402
+from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+
+from benchmark.harness import build, manifest as mf  # noqa: E402
+from paddle_tpu import kernels  # noqa: E402
+from paddle_tpu.parallel import decoder, optim, transformer as T  # noqa: E402
+from paddle_tpu.parallel.mesh import DP, MeshSpec  # noqa: E402
+from paddle_tpu.parallel.train import (TrainState, make_train_step,  # noqa: E402
+                                       state_specs)
+
+
+def main(cell, *overrides):
+    for info in pkgutil.iter_modules(kernels.__path__):
+        module = importlib.import_module("paddle_tpu.kernels." + info.name)
+        if hasattr(module, "_on_tpu"):
+            module._on_tpu = lambda: True
+    manifest = mf.load(ROOT)
+    entry = mf.cell(manifest, cell)
+    config = mf.read_json(ROOT, "benchmark", "configs",
+                          entry["config"] + ".json")
+    traffic = mf.read_json(ROOT, "benchmark", "traffic", cell + ".json")
+    kwargs = dict(config["config_factory"]["kwargs"])
+    for item in overrides:
+        key, value = item.split("=")
+        kwargs[key] = json.loads(value)
+    path, name = config["config_factory"]["path"].rsplit(".", 1)
+    cfg = getattr(importlib.import_module(path), name)(**kwargs)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = MeshSpec(dp=1).build(devices=topo.devices[:1])
+    optimizer = optim.adamw()
+    params = jax.eval_shape(
+        lambda: T._init_params(jax.random.PRNGKey(0), cfg))
+    state = jax.eval_shape(lambda p: TrainState.create(p, optimizer), params)
+    pspecs = T.transformer_param_specs(cfg)
+    sspecs = state_specs(pspecs, state)
+    multi = make_train_step(
+        decoder.make_loss_fn(cfg), mesh, pspecs, T.grad_sync_axes(cfg),
+        optimizer, decoder.BATCH_SPECS,
+        stepped=tuple(decoder.STEPPED & set(params))).multi(state)
+    state = jax.tree.map(
+        lambda a, spec: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec)),
+        state, sspecs)
+    dims = build.cell_dims(config, traffic)
+    batches = {"ids": jax.ShapeDtypeStruct(
+        (int(traffic["staged_batches"]), dims["B"], dims["S"]), jnp.int32,
+        sharding=NamedSharding(mesh, P(None, DP)))}
+    n_params = sum(a.size for a in jax.tree.leaves(params))
+    print("parameters: %.1f M; state leaves: %.3f GB"
+          % (n_params / 1e6, sum(a.size * a.dtype.itemsize
+                                 for a in jax.tree.leaves(state)) / 1e9))
+    compiled = multi.lower(state, batches, 1e-5).compile()
+    print("memory_analysis():", compiled.memory_analysis())
+    reports = sorted(glob.glob(os.path.join(DUMP, "*memory-usage-report*")),
+                     key=os.path.getsize)
+    if not reports:
+        print("no memory-usage report under", DUMP)
+        return 1
+    text = open(reports[-1]).read()
+    print(reports[-1])
+    print("\n".join(text.splitlines()[:40]))
+    shapes = set(re.findall(r"\b(?:f32|bf16)\[[\d,]+\]", text))
+    for shape in sorted(shapes, key=lambda s: -math.prod(
+            int(n) for n in s[s.index("[") + 1:-1].split(",")))[:25]:
+        print(shape)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(*sys.argv[1:]))

@@ -71,6 +71,12 @@ def test_classify_on_literal_op_names():
         "layer_norm_fwd/while/body/div": ("forward", "layer_norm"),
         "jit(multi)/transpose(jvp())/while/body/closed_call/attention/"
         "flash_bwd_fused/mul": ("backward", "attention"),
+        # the scan's kernels inside the mixer's scope, and the mixer's own
+        "jit(multi)/transpose(jvp())/while/body/closed_call/checkpoint/mamba/"
+        "mamba/selective_scan/selective_scan_bwd":
+            ("backward", "selective_scan"),
+        "jit(multi)/jvp()/while/body/closed_call/while/body/mamba/mamba/"
+        "dot_general": ("forward", "mamba"),
         # no vocabulary word on the path
         "jit(multi)/while/body/closed_call/jvp()/while/body/closed_call":
             ("forward", None),
@@ -109,7 +115,7 @@ def test_classify_on_literal_op_names():
     for op_name, want in table.items():
         assert devscope.classify(op_name) == want, op_name
     assert set(devscope.PHASES) >= {w[0] for w in table.values()}
-    assert len(set(devscope.VOCABULARY)) == len(devscope.VOCABULARY) == 21
+    assert len(set(devscope.VOCABULARY)) == len(devscope.VOCABULARY) == 23
 
 
 @pytest.mark.parametrize("op_name, want", [

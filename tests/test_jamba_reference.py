@@ -1,0 +1,347 @@
+"""The Jamba decoder through the normal path (``models/jamba.py`` over
+``parallel/transformer.py``'s MAMBA position beside position-free
+multi-query attention, the run-scan of a period's runs, the gated FFN of a
+stack without experts and ``kernels/selective_scan.py``'s kernels, in
+interpret mode) against the benchmark's plain float32 reference
+(``benchmark/reference/jamba2_3b.py``, the per-token scan), on seeded
+weights at ``jamba_tiny_config``: two periods of four layers, attention at
+offset 2 (runs of 2, 1 and 1 layers), hidden 64, 5 query heads on ONE
+key/value head of 128, an inner width of 128, 16 state cells, 4 taps, rank
+8, chunks of 16 under S = 64 (4 chunks), FFN width 96, vocab 256, tied head.
+
+What the tiny configuration keeps of the published one: every leaf and
+every line of the mixer, the inner norms, a period with Mamba runs of
+different lengths on both sides of the attention layer, multi-query
+attention without positions, the tied head, the state carried over three
+chunk edges.  What it drops: the period's length (14, attention at 7) and
+the widths.
+
+The tiny configuration computes in float32, so the tolerance is 1e-5 on the
+loss (the two differ by accumulation order only) and three times that on a
+single logit row or gradient element, against the largest of its leaf."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.reference import jamba2_3b as reference  # noqa: E402
+from paddle_tpu import monitor  # noqa: E402
+from paddle_tpu.kernels import selective_scan as ss  # noqa: E402
+from paddle_tpu.models import jamba  # noqa: E402
+from paddle_tpu.monitor import devscope  # noqa: E402
+from paddle_tpu.parallel import decoder, optim, transformer as T  # noqa: E402
+from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
+from paddle_tpu.parallel.train import stack_batches  # noqa: E402
+
+B, S, TOL = 2, 64, 1e-5
+EACH = 3 * TOL         # one logit row, one gradient element
+# the reference reads the published keys and the scan's chunk
+MODEL = {"num_attention_heads": 5, "num_key_value_heads": 1,
+         "num_hidden_layers": 8, "attn_layer_period": 4,
+         "attn_layer_offset": 2, "num_experts": 1, "rms_norm_eps": 1e-6,
+         "mamba_d_state": 16, "mamba_dt_rank": 8, "mamba_d_conv": 4,
+         "mamba_expand": 2, "tie_word_embeddings": True, "scan_chunk": 16}
+FFN = ("ln1_scale", "ln2_scale", "w_gate_up", "w_down")
+LEAVES = ["tok_emb", "lnf_scale"] \
+    + ["params_layers/%s/%s" % (run, n) for run in ("r0", "r2")
+       for n in FFN + reference.MAMBA_LEAVES] \
+    + ["params_layers/r1/" + n for n in FFN + reference.ATTENTION_LEAVES]
+
+
+def _trainer(seed=3, optimizer=None, **cfg):
+    return jamba.build_jamba_trainer(
+        jamba.jamba_tiny_config(**cfg), MeshSpec(dp=1),
+        optimizer=optimizer or optim.adamw(), seed=seed,
+        devices=jax.devices()[:1])
+
+
+def _ids(seed=0, n=1):
+    rng = np.random.RandomState(seed)
+    return [rng.randint(0, 256, (B, S)).astype(np.int32) for _ in range(n)]
+
+
+def _seeded_params(tr):
+    """The trainer's seeded weights with the norm scales, the skip and the
+    rates moved off their seeds, so that a missing or misplaced one shows."""
+    rng = np.random.RandomState(11)
+
+    def moved(path, a):
+        name = jax.tree_util.keystr(path)
+        if any(w in name for w in ("scale", "_norm", "d_skip", "a_log")):
+            return np.asarray(a) * rng.uniform(0.5, 1.5, a.shape).astype("f4")
+        return np.asarray(a)
+
+    return jax.tree_util.tree_map_with_path(moved, tr.state["params"])
+
+
+def _leaf(tree, path):
+    for part in path.split("/"):
+        tree = tree[part]
+    return tree
+
+
+@pytest.fixture(scope="module")
+def both():
+    """Loss and gradients of program and reference on the same weights."""
+    tr = _trainer()
+    params = _seeded_params(tr)
+    ids = _ids()[0]
+    loss_fn = decoder.make_loss_fn(tr.cfg)
+    got = jax.jit(jax.value_and_grad(
+        lambda p: loss_fn(p, {"ids": jnp.asarray(ids)})))(params)
+    want = jax.value_and_grad(
+        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
+            jax.tree.map(jnp.asarray, params))
+    return tr.cfg, params, ids, got, want
+
+
+def test_the_tiny_configuration_keeps_every_mechanism():
+    cfg = jamba.jamba_tiny_config()
+    attention = (None, False)
+    assert cfg.layer_kinds == (T.MAMBA, T.MAMBA, attention, T.MAMBA)
+    assert cfg.runs == ((0, T.MAMBA, 2), (2, attention, 1), (3, T.MAMBA, 1))
+    assert cfg.per_position and cfg.n_periods == 2 and cfg.positions is None
+    assert (cfg.n_heads, cfg.kv_heads, cfg.head_dim) == (5, 1, 128)
+    assert cfg.tie_head and not cfg.n_experts and not cfg.qk_norm
+    assert ss.supported((B, S, cfg.d_inner), cfg.d_state, cfg.scan_chunk)
+    big = jamba.jamba2_3b_config()
+    assert (big.n_layers, big.hidden, big.n_heads, big.kv_heads, big.head_dim,
+            big.dense_ffn_hidden, big.vocab_size, big.norm_eps, big.d_inner,
+            big.d_state, big.d_conv, big.dt_rank) == (
+        28, 2560, 20, 1, 128, 8192, 65536, 1e-6, 5120, 16, 4, 160)
+    assert big.n_periods == 2 and [r[2] for r in big.runs] == [7, 1, 6]
+    assert big.layer_kinds[7] == attention and big.layer_kinds.count(
+        T.MAMBA) == 13
+    assert ss.supported((1, 8192, big.d_inner), big.d_state, big.scan_chunk)
+
+
+def test_loss_equals_the_reference(both):
+    _, _, _, (got, _), (want, _) = both
+    assert abs(float(got) - float(want)) / float(want) < TOL
+
+
+def test_every_position_s_logits_equal_the_reference(both):
+    cfg, params, ids, _, _ = both
+    x, _ = jax.jit(lambda p, i: decoder.forward(p, i, cfg))(params, ids)
+    got = T.rms_norm(x, params["lnf_scale"], cfg.norm_eps) @ params["tok_emb"].T
+    want = np.stack(reference.forward(params, ids, MODEL)[1])
+    np.testing.assert_allclose(got, want, rtol=1e-4,
+                               atol=EACH * np.abs(want).max())
+
+
+@pytest.mark.parametrize("path", LEAVES)
+def test_gradient_of_every_leaf_equals_the_reference(both, path):
+    _, params, _, (_, got), (_, want) = both
+    g, w = np.asarray(_leaf(got, path)), np.asarray(_leaf(want, path))
+    assert g.shape == _leaf(params, path).shape and np.abs(w).max() > 0
+    np.testing.assert_allclose(g, w, rtol=1e-4, atol=EACH * np.abs(w).max())
+
+
+def test_the_leaves_tested_are_all_there_are(both):
+    _, params, _, _, _ = both
+    flat = jax.tree_util.tree_leaves_with_path(params)
+    assert sorted("/".join(k.key for k in p) for p, _ in flat) \
+        == sorted(LEAVES)
+    floats = {"a_log", "d_skip", "b_dt", "dt_norm", "b_norm", "c_norm"}
+    for p, a in flat:
+        if p[-1].key in floats:
+            assert a.dtype == np.float32, p
+
+
+def test_logits_at_reads_the_step_s_own_forward(both):
+    _, params, ids, _, _ = both
+    tr = _trainer()
+    tr.state = dict(tr.state, params=jax.tree.map(jnp.asarray, params))
+    at = reference.witness_positions(S)
+    got = np.asarray(tr.logits_at(ids, at))
+    assert reference.logits_error(got, params, {"ids": ids}, MODEL) < EACH
+    groups = reference.witness_groups(S)
+    # the eight tokens past each multiple of the tiny chunk, then the spread
+    assert list(groups["edge"][:9]) == list(range(16, 24)) + [32]
+    assert not set(groups["edge"]) & set(groups["spread"])
+
+
+@pytest.mark.parametrize("fault", reference.FAULTS)
+def test_every_fault_moves_the_reference_s_logits(both, fault):
+    """At the tiny size, in float32: a fault put into the reference moves
+    the witnessed logits by far more than the comparison's tolerance (the
+    precision faults by more than float32 rounds)."""
+    _, params, ids, _, _ = both
+    # the first period alone: half the layers to walk a fault
+    model = dict(MODEL, num_hidden_layers=4)
+    params = dict(params, params_layers=jax.tree.map(
+        lambda a: a[:1], params["params_layers"]))
+    batch = {"ids": ids[:1]}
+    sound = reference.logits(params, batch, model)
+    err = reference.logits_error(sound, params, batch, model,
+                                 faults=(fault,))
+    # without its softplus a step size is negative and the state overflows:
+    # no number is a failed comparison too
+    assert not err <= (100 if "bfloat16" in fault else 1000) * TOL, (fault,
+                                                                     err)
+
+
+# --- the kernels against the per-token scan --------------------------------
+
+def _operands(b, s, d, n, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    x, z, w = (jax.random.normal(k, (b, s, d)) for k in (ks[0], ks[1], ks[7]))
+    dt = jax.nn.softplus(jax.random.normal(ks[2], (b, s, d)) - 2.0)
+    bmat, cmat = (jax.random.normal(k, (b, s, n)) for k in (ks[3], ks[4]))
+    a = -jnp.exp(0.5 * jax.random.normal(ks[5], (d, n)))
+    return (x, dt, bmat, cmat, z, a, jax.random.normal(ks[6], (d,))), w
+
+
+@pytest.fixture(scope="module")
+def scan_reference():
+    args, w = _operands(2, 48, 256, 16)
+    out = ss.selective_scan_reference(*args)
+    grads = jax.grad(lambda *a: jnp.sum(ss.selective_scan_reference(*a) * w),
+                     argnums=tuple(range(7)))(*args)
+    return args, w, out, grads
+
+
+@pytest.mark.parametrize("chunk", (8, 16, 48))
+def test_the_kernels_equal_the_per_token_scan(scan_reference, chunk):
+    """Output and all seven gradients, at two chunk lengths under a sequence
+    of several chunks and at one chunk the sequence: the result does not
+    depend on the chunk beyond rounding."""
+    args, w, want, want_g = scan_reference
+    got = ss.selective_scan(*args, chunk=chunk, interpret=True)
+    np.testing.assert_allclose(got, want, rtol=1e-4,
+                               atol=TOL * np.abs(want).max())
+    got_g = jax.grad(lambda *a: jnp.sum(ss.selective_scan(
+        *a, chunk=chunk, interpret=True) * w), argnums=tuple(range(7)))(*args)
+    for name, g, wg in zip("x dt B C z a D".split(), got_g, want_g):
+        np.testing.assert_allclose(g, wg, rtol=1e-4, err_msg=name,
+                                   atol=TOL * np.abs(wg).max())
+
+
+def test_the_kernels_take_whole_tiles_of_channels_and_bfloat16():
+    """1,024 channels are one float32 tile a state cell and two groups of
+    rows at 2,048; bf16 x and z round once on the way out."""
+    args, w = _operands(1, 32, 2048, 4, seed=1)
+    want = ss.selective_scan_reference(*args)
+    got = ss.selective_scan(*args, chunk=16, interpret=True)
+    np.testing.assert_allclose(got, want, rtol=1e-4,
+                               atol=TOL * np.abs(want).max())
+    low = tuple(t.astype(jnp.bfloat16) if i in (0, 4) else t
+                for i, t in enumerate(args))
+    got = ss.selective_scan(*low, chunk=16, interpret=True)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        got.astype(jnp.float32), ss.selective_scan_reference(*low).astype(
+            jnp.float32), rtol=2e-2, atol=2e-2 * np.abs(want).max())
+    assert not ss.supported((1, 32, 192), 16, 16)
+    assert not ss.supported((1, 40, 256), 16, 16)
+    assert ss.group_rows(5120) == 8 and ss.group_rows(256) == 2
+
+
+# --- the block ---------------------------------------------------------------
+
+def test_the_run_scan_equals_the_inlined_period(both):
+    """The same leaves read a position at a time (``p<i>``, the period's
+    body inlining every layer) give the run-scan's activations."""
+    cfg, params, ids, _, _ = both
+    inlined = jamba.jamba_tiny_config(run_scan=False)
+    assert [r[2] for r in inlined.runs] == [1, 1, 1, 1]
+    by_position = {}
+    for at, (first, _, length) in enumerate(cfg.runs):
+        for i in range(length):
+            by_position["p%d" % (first + i)] = jax.tree.map(
+                lambda a: a[:, i], params["params_layers"]["r%d" % at])
+    # seeded alone, a position's leaves are the run's
+    alone = T.init_transformer_params(jax.random.PRNGKey(3), inlined)
+    np.testing.assert_array_equal(
+        alone["params_layers"]["p1"]["w_in"],
+        _trainer().state["params"]["params_layers"]["r0"]["w_in"][:, 1])
+    got = jax.jit(lambda p, i: decoder.forward(p, i, cfg)[0])(params, ids)
+    want = jax.jit(lambda p, i: decoder.forward(p, i, inlined)[0])(
+        dict(params, params_layers=by_position), ids)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def test_the_filter_s_halo_crosses_a_row_block_edge(both, monkeypatch):
+    """Past ROW_BLOCK_ELEMENTS the mixer's operands are made a block of
+    positions at a time, each block projecting the three rows before it
+    again for the filter: the same numbers, and gradients."""
+    cfg, params, ids, _, _ = both
+    pl = jax.tree.map(lambda a: jnp.asarray(a[0, 0]),
+                      params["params_layers"]["r0"])
+    h = jax.random.normal(jax.random.PRNGKey(5), (B, S, cfg.hidden))
+
+    def run(pl, h):
+        return jnp.sum(T.mamba_mixer(pl, h, cfg) ** 2)
+
+    whole = jax.value_and_grad(run, argnums=(0, 1))(pl, h)
+    monkeypatch.setattr(T, "ROW_BLOCK_ELEMENTS", 4 * 8 * B * 2 * cfg.d_inner)
+    assert T.row_block(S, B * 2 * cfg.d_inner) == 8
+    blocked = jax.value_and_grad(run, argnums=(0, 1))(pl, h)
+    for got, want in zip(jax.tree.leaves(blocked), jax.tree.leaves(whole)):
+        np.testing.assert_allclose(got, want, rtol=1e-4,
+                                   atol=EACH * np.abs(want).max())
+
+
+def test_a_per_position_stack_may_carry_no_positions():
+    """``positions`` None: no table, no rotation; a per-position stack with
+    rotary positions still builds; learned positions are refused."""
+    params = T.init_transformer_params(jax.random.PRNGKey(0),
+                                       jamba.jamba_tiny_config())
+    assert "pos_emb" not in params
+    rotary = jamba.jamba_tiny_config(
+        positions="rotary", layer_pattern=(T.MAMBA, (0, True)), n_layers=2)
+    assert rotary.layer_kinds == (T.MAMBA, (None, True))
+    with pytest.raises(AssertionError):
+        jamba.jamba_tiny_config(positions="learned")
+    with pytest.raises(AssertionError):     # None, and a layer that rotates
+        jamba.jamba_tiny_config(layer_pattern=(T.MAMBA, (0, True)),
+                                n_layers=2)
+
+
+@pytest.fixture(scope="module")
+def ran():
+    """One trainer under remat, a step and a scan of six under a monitor
+    session: the losses, the session's registry and the program's scopes."""
+    tr = _trainer(optimizer=optim.adamw(), remat=True)
+    batches = [{"ids": i} for i in _ids(n=2)]
+    mon = monitor.enable()
+    try:
+        first = float(tr.step(batches[0], 1e-3))
+        many = np.asarray(tr.run_steps(
+            stack_batches(tr.mesh, decoder.BATCH_SPECS, batches * 3), 1e-3))
+        names = devscope.scope_maps()["jamba.run_steps"]
+        return first, many, mon.registry, names
+    finally:
+        monitor.disable()
+
+
+def test_the_trainer_steps_and_its_loss_falls(ran):
+    first, many, _, _ = ran
+    assert np.isfinite(many).all() and many[-1] < first
+
+
+def test_the_gauges_of_a_call(ran):
+    reg = ran[2]
+    # step sizes seeded log-uniform in [1e-3, 1e-1] under unit noise
+    assert 1e-3 < reg.gauge("monitor.train.mamba_dt_mean").value < 0.2
+    # the fastest cell (rate 16) under the largest step of the batch
+    assert 0.0 < reg.gauge("monitor.train.mamba_decay_min").value < 0.5
+    assert reg.counter("monitor.kernels.selective_scan_calls",
+                       fused=1).value > 0
+    # the one attention layer's grid: 5 heads on one key/value head
+    assert reg.gauge("monitor.kernels.flash_grid_steps").value > 0
+
+
+def test_the_mixer_s_instructions_are_under_their_scopes(ran):
+    got = {devscope.classify(op) for op in ran[3].values()}
+    for scope in ("mamba", "selective_scan", "attention", "mlp", "layer_norm",
+                  "lm_head", "embed"):
+        assert ("forward", scope) in got and ("backward", scope) in got, scope

@@ -27,7 +27,7 @@ if ROOT not in sys.path:
 from benchmark.reference import mistral_small_4_119b as reference  # noqa: E402
 from paddle_tpu import monitor  # noqa: E402
 from paddle_tpu.models import (bert, brumby, lfm2, mistral4,  # noqa: E402
-                               olmoe, smallthinker)
+                               olmoe, smallthinker, trinity)
 from paddle_tpu.monitor import devscope  # noqa: E402
 from paddle_tpu.parallel import (decoder, moe, optim,  # noqa: E402
                                  transformer as T)
@@ -517,7 +517,8 @@ def test_the_new_scopes_hold_their_instructions_and_attention_none():
 # row kernel (``kernels/qk_rope.py``, their three projections through
 # ``_project``; OLMoE's 4 heads of 16 are half a lane block and keep the
 # plain matmuls and the ``rms_norm`` / ``rope`` lines, in their old order);
-# BERT's are still 03fc114's.
+# BERT's are still 03fc114's.  Mistral's and Trinity's joined the table in
+# PR 48, taken on its parent (3ea462c).
 PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "bert.run_steps": "00de5403506fdc87",
             "olmoe.step": "231114fcd62341f2",
@@ -527,14 +528,22 @@ PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "lfm2.step": "bb536069bbd7206a",
             "lfm2.run_steps": "f5ad889769877a83",
             "brumby.step": "84e6b6d548803a44",
-            "brumby.run_steps": "5a063ea89a19f1a4"}
+            "brumby.run_steps": "5a063ea89a19f1a4",
+            "mistral4.step": "278496583ed8fb80",
+            "mistral4.run_steps": "c9f856c8337ed276",
+            "trinity.step": "2ee67522b53eb798",
+            "trinity.run_steps": "4337fe8cf4863f5f"}
 OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
          "olmoe": (olmoe.build_olmoe_trainer, olmoe.olmoe_tiny_config, 32),
          "smallthinker": (smallthinker.build_smallthinker_trainer,
                           smallthinker.smallthinker_tiny_config, 64),
          "lfm2": (lfm2.build_lfm2_trainer, lfm2.lfm2_tiny_config, 64),
          "brumby": (brumby.build_brumby_trainer, brumby.brumby_tiny_config,
-                    64)}
+                    64),
+         "mistral4": (mistral4.build_mistral4_trainer,
+                      mistral4.mistral4_tiny_config, 64),
+         "trinity": (trinity.build_trinity_trainer,
+                     trinity.trinity_tiny_config, 64)}
 
 
 @pytest.mark.parametrize("name", list(OLDER))
