@@ -14,16 +14,40 @@ walked a token at a time on the vector unit, in float32.  What a kernel is
 for is the state: ``[S, d, N]`` float32 is 2.7 GB a layer at S = 8,192 and
 d = 5,120, and here it never reaches HBM.
 
-Layout.  ``d`` lies on lanes AND sublanes: ``[b, S, d]`` is read as ``[b, S,
-d / 128, 128]`` (the same bytes), so that a token's 1,024 channels are one
-whole float32 tile ``[8, 128]`` and a state cell n of those channels is one
-tile too.  A token's step is then sixteen tile recurrences whose ``B_t[n]``
-and ``C_t[n]`` are SCALARS, read from SMEM and splat: no value crosses a
-lane or a sublane in the forward.  The sequence is walked in chunks of
-``chunk`` tokens (the grid's inner, sequential axis); the state ``[N, d /
-128, 128]`` stays in VMEM scratch from chunk to chunk, and inside a chunk
-each group of 8 sublane rows keeps its sixteen state tiles in registers
-while it walks the chunk's tokens.
+Layout.  ``d`` lies on lanes AND sublanes: a token's 1,024 channels are one
+whole float32 tile ``[8, 128]`` (eight lane tiles of its row, one a sublane)
+and a state cell n of those channels is one tile too.  A token's step is
+then sixteen tile recurrences whose ``B_t[n]`` and ``C_t[n]`` are SCALARS,
+read from SMEM and splat: no value crosses a lane or a sublane in the
+forward.  The sequence is walked in chunks of ``chunk`` tokens (the grid's
+inner, sequential axis); the state ``[N, d / 128, 128]`` stays in VMEM
+scratch from chunk to chunk, and inside a chunk each BAND of 8 sublane rows
+(1,024 channels; a loop over the bands, one body) keeps its sixteen state
+tiles in registers while it walks the chunk's tokens.
+
+The door.  ``[b, S, d]`` and ``[b, S, d / 128, 128]`` are the same bytes in
+row-major order and NOT on the chip: HBM holds ``[S, d]`` in tiles of (8,
+128) over (tokens, channels), and the second shape's tiles lie over (channel
+rows, channels), so XLA answers that reshape with a relayout copy of every
+operand and gradient, each way, at half of HBM's rate: eight copies, 3.8 ms
+a layer and step at the jamba cell's shape beside 10.3 in the kernels (PR
+48 did just that).  The kernels therefore address the projections' OWN
+tiling.  Tiled (8, 128), float32 ``[S, d]`` is byte for byte the row-major
+array ``[S / 8, d / 16, 128]`` whose row 8 r + s of group g is lane tile r
+of token 8 g + s (``_tiles``: a reshape, a transpose and a reshape that XLA
+compiles to a bitcast).  There the tile of a token and a band is eight
+sublanes a fixed stride apart, ``ref[g, pl.ds(64 band + s, 8, stride=8)]``:
+ONE strided load or store (``_tokens``; an index ``[g, rows, s, :]`` into
+``[S / 8, d / 128, 8, 128]`` says the same and Mosaic makes of it eight
+one-sublane loads, seven rotates and seven selects).  The float32 per-token
+arrays, ``dt`` in and ``ddt`` out, cross as that view; every whole-chunk
+float32 scratch has its shape.  x, z and the output's gradient (in) and the
+output, ``dx`` and ``dz`` (out), bfloat16 in the cell, whose tiles hold 16
+tokens and have no such view at 8, cross as plain ``[chunk, d]`` blocks of
+the 2-D array: the cast to or from float32 that each had anyway goes a lane
+tile at a time, ``[chunk, 128]`` read as ``[chunk / 8, 8, 128]`` (whole
+float32 tiles renumbered, nothing moved) into or out of the scratch.
+Nothing re-tiles an operand or a gradient in HBM.
 
 Backward.  The forward (under ``jax.vjp``) also writes the state as each
 chunk FOUND it: ``[b, S / chunk, N, d]`` float32, 21 MB a layer at chunks of
@@ -31,7 +55,7 @@ chunk FOUND it: ``[b, S / chunk, N, d]`` float32, 21 MB a layer at chunks of
 chunk's states again from that edge (kept in VMEM: chunk + 1 tiles a cell),
 then walks the tokens backwards with ``dh`` carried in registers and from
 chunk to chunk in scratch.  ``dB_t[n]`` and ``dC_t[n]`` are sums over ALL
-channels: they are gathered a tile a (token, cell) in VMEM over the groups
+channels: they are gathered a tile a (token, cell) in VMEM over the bands
 of rows and reduced once a chunk (strided sublane loads, a lane reduce),
 which is the one place a value crosses lanes.  ``dA`` and ``dD`` are
 accumulated in their output blocks, a batch row each, summed outside.
@@ -44,7 +68,11 @@ token: what the tests hold the kernels to, and what shapes the kernels do
 not take (``supported``) run on.
 
 interpret=None auto-selects the Pallas interpreter off-TPU, so the CPU tests
-run the same code (kernels/flash_attention.py idiom).
+run the same code (kernels/flash_attention.py idiom), but for ONE hint: the
+eight tokens of a group are unrolled on the chip and stay a loop under the
+interpreter, whose XLA compile of eight copies of every body was two of the
+CPU tests' minutes (``tests/test_flash_tpu_compile.py`` compiles the
+unrolled bodies for a described v5e).
 """
 
 import functools
@@ -75,9 +103,11 @@ def group_rows(d):
 def supported(shape, n_state, chunk):
     """Whether the kernels take x ``[b, S, d]`` at ``n_state`` cells and
     chunks of ``chunk`` tokens: whole lane blocks of channels, whole chunks,
-    chunks of whole sublane tiles."""
+    chunks of whole sublane tiles, so that S is groups of 8 tokens too
+    (``_tiles``)."""
     _, S, d = shape
-    return d % LANES == 0 and S % chunk == 0 and chunk % SUBLANES == 0
+    return d % LANES == 0 and S % chunk == 0 and chunk % SUBLANES == 0 \
+        and S % SUBLANES == 0
 
 
 def vmem_bytes(chunk, d, n_state, itemsize):
@@ -120,14 +150,60 @@ def _silu(zf):
     return zf * jax.nn.sigmoid(zf)
 
 
-def _groups(d):
+def _band(g, d):
+    """The ``g``-th walk's rows of ``[d / 128, 128]``, ``group_rows`` of
+    them."""
     rows = group_rows(d)
-    return [slice(g, g + rows) for g in range(0, d // LANES, rows)]
+    return pl.ds(pl.multiple_of(g * rows, rows), rows)
+
+
+def _lanes(r):
+    return slice(r * LANES, (r + 1) * LANES)
+
+
+def _rows(r):
+    return slice(r * SUBLANES, (r + 1) * SUBLANES)
+
+
+def _in_tiles(ref, r):
+    """Lane tile ``r`` of a ``[chunk, d]`` block in float32, as the scratch
+    holds it: ``[chunk / 8, 8, 128]``."""
+    return ref[:, _lanes(r)].astype(F32).reshape(-1, SUBLANES, LANES)
+
+
+def _out_of_tiles(ref, r, tiles):
+    """``tiles`` [chunk / 8, 8, 128] float32 as lane tile ``r`` of the
+    ``[chunk, d]`` block ``ref``, rounded to its type."""
+    ref[:, _lanes(r)] = tiles.reshape(-1, LANES).astype(ref.dtype)
+
+
+def _tokens(chunk, rows, step, carry, unroll, back=False):
+    """``step(t, at, carry)`` for every token ``t`` of a chunk in order
+    (``back``: from the last to the first), ``at`` where a ``[chunk / 8, 8
+    R, 128]`` ref keeps the lane tiles ``rows`` of the token's channels: one
+    sublane of each tile, 8 apart, ONE strided load or store.  A loop over
+    the chunk's groups of 8 tokens; ``unroll``: a group's 8 tokens unrolled,
+    their sublanes static, so that the compiler schedules a token's loads
+    and ``exp`` under the token before it (PERF.md section 6, PR 49)."""
+    groups = chunk // SUBLANES
+
+    def group_step(k, carry):
+        group = groups - 1 - k if back else k
+
+        def token(j, carry):
+            s = SUBLANES - 1 - j if back else j
+            return step(group * SUBLANES + s,
+                        (group, pl.ds(rows.start * SUBLANES + s, rows.size,
+                                      stride=SUBLANES)), carry)
+
+        return jax.lax.fori_loop(0, SUBLANES, token, carry, unroll=unroll)
+
+    return jax.lax.fori_loop(0, groups, group_step, carry)
 
 
 def _walk(dt_ref, xf_ref, b_ref, c_ref, a_ref, d_ref, ys_ref, rows, h, chunk,
-          n_state, hist_ref=None):
-    """The recurrence over a chunk's tokens for one group of ``rows``, from
+          n_state, unroll, hist_ref=None):
+    """The recurrence over a chunk's tokens for one band of ``rows``, from
     the state tiles ``h`` (a tuple, a cell each): ``y`` before its gate into
     ``ys_ref``, each token's state into ``hist_ref[t + 1]`` where given;
     the state the chunk leaves."""
@@ -135,8 +211,8 @@ def _walk(dt_ref, xf_ref, b_ref, c_ref, a_ref, d_ref, ys_ref, rows, h, chunk,
     a = [a_ref[n, rows, :] for n in range(N)]
     skip = d_ref[rows, :]
 
-    def step(t, h):
-        dt, x = dt_ref[t, rows, :], xf_ref[t, rows, :]
+    def step(t, at, h):
+        dt, x = dt_ref[at], xf_ref[at]
         dtx, y, new = dt * x, skip * x, []
         for n in range(N):
             hn = jnp.exp(dt * a[n]) * h[n] + dtx * b_ref[0, t * N + n]
@@ -144,21 +220,22 @@ def _walk(dt_ref, xf_ref, b_ref, c_ref, a_ref, d_ref, ys_ref, rows, h, chunk,
             if hist_ref is not None:
                 hist_ref[t + 1, n] = hn
             new.append(hn)
-        ys_ref[t, rows, :] = y
+        ys_ref[at] = y
         return tuple(new)
 
-    return jax.lax.fori_loop(0, chunk, step, h)
+    return _tokens(chunk, rows, step, h, unroll)
 
 
 def _fwd_kernel(x_ref, dt_ref, z_ref, b_ref, c_ref, a_ref, d_ref, *rest,
-                chunk, n_state, save):
-    """One chunk of one sequence.  x, z [chunk, R, 128]; dt the same,
-    float32; b, c SMEM [1, chunk * N]; a [N, R, 128]; d [R, 128]."""
+                chunk, n_state, save, unroll):
+    """One chunk of one sequence.  x, z [chunk, d]; dt [chunk / 8, 8 R,
+    128] float32; b, c SMEM [1, chunk * N]; a [N, R, 128]; d [R, 128]."""
     if save:
         y_ref, edge_ref, h_ref, xf_ref, ys_ref = rest
     else:
         y_ref, h_ref, xf_ref, ys_ref = rest
     N = n_state
+    R = a_ref.shape[1]
 
     @pl.when(pl.program_id(1) == 0)
     def _():
@@ -166,14 +243,22 @@ def _fwd_kernel(x_ref, dt_ref, z_ref, b_ref, c_ref, a_ref, d_ref, *rest,
 
     if save:
         edge_ref[...] = h_ref[...]
-    xf_ref[...] = x_ref[...].astype(F32)
-    for rows in _groups(xf_ref.shape[1] * LANES):
+    for r in range(R):
+        xf_ref[:, _rows(r)] = _in_tiles(x_ref, r)
+
+    def walk_band(g, carry):
+        rows = _band(g, R * LANES)
         h = _walk(dt_ref, xf_ref, b_ref, c_ref, a_ref, d_ref, ys_ref, rows,
-                  tuple(h_ref[n, rows, :] for n in range(N)), chunk, N)
+                  tuple(h_ref[n, rows, :] for n in range(N)), chunk, N,
+                  unroll)
         for n in range(N):
             h_ref[n, rows, :] = h[n]
-    y_ref[...] = (ys_ref[...] * _silu(z_ref[...].astype(F32))).astype(
-        y_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, R // group_rows(R * LANES), walk_band, 0)
+    for r in range(R):
+        _out_of_tiles(y_ref, r,
+                      ys_ref[:, _rows(r)] * _silu(_in_tiles(z_ref, r)))
 
 
 def _sum_tiles(acc_ref, chunk, n_state, rows):
@@ -194,12 +279,12 @@ def _sum_tiles(acc_ref, chunk, n_state, rows):
 def _bwd_kernel(x_ref, dt_ref, z_ref, b_ref, c_ref, a_ref, d_ref, edge_ref,
                 do_ref, dx_ref, ddt_ref, dz_ref, db_ref, dc_ref, da_ref,
                 dd_ref, dh_ref, xf_ref, g_ref, ys_ref, dxf_ref, hist_ref,
-                accb_ref, accc_ref, *, chunk, n_state):
+                accb_ref, accc_ref, *, chunk, n_state, unroll):
     """One chunk of one sequence, the chunks from the last to the first.
     ``edge_ref`` [N, R, 128] is the state the chunk found; ``dh_ref`` holds
     what the later chunk's first token hands back: ``exp(dt A) dh``."""
     N = n_state
-    R = xf_ref.shape[1]
+    R = a_ref.shape[1]
     rows_n = group_rows(R * LANES)
 
     @pl.when(pl.program_id(1) == 0)
@@ -208,12 +293,14 @@ def _bwd_kernel(x_ref, dt_ref, z_ref, b_ref, c_ref, a_ref, d_ref, edge_ref,
         da_ref[...] = jnp.zeros(da_ref.shape, F32)
         dd_ref[...] = jnp.zeros(dd_ref.shape, F32)
 
-    xf_ref[...] = x_ref[...].astype(F32)
-    zf = z_ref[...].astype(F32)
-    sig = jax.nn.sigmoid(zf)
-    dof = do_ref[...].astype(F32)
-    g_ref[...] = dof * zf * sig             # the gradient of y
-    for at, rows in enumerate(_groups(R * LANES)):
+    for r in range(R):
+        xf_ref[:, _rows(r)] = _in_tiles(x_ref, r)
+        zf = _in_tiles(z_ref, r)
+        # the gradient of y
+        g_ref[:, _rows(r)] = _in_tiles(do_ref, r) * zf * jax.nn.sigmoid(zf)
+
+    def band(g, carry):
+        rows, first = _band(g, R * LANES), g == 0
         a = [a_ref[n, rows, :] for n in range(N)]
         skip = d_ref[rows, :]
         for n in range(N):
@@ -221,43 +308,48 @@ def _bwd_kernel(x_ref, dt_ref, z_ref, b_ref, c_ref, a_ref, d_ref, edge_ref,
         # the chunk's states again, from the state it found
         _walk(dt_ref, xf_ref, b_ref, c_ref, a_ref, d_ref, ys_ref, rows,
               tuple(edge_ref[n, rows, :] for n in range(N)), chunk, N,
-              hist_ref)
+              unroll, hist_ref)
 
-        def backward(i, carry):
-            t = chunk - 1 - i
-            dt, x, g = dt_ref[t, rows, :], xf_ref[t, rows, :], \
-                g_ref[t, rows, :]
+        def backward(t, at, later):
+            dt, x, gy = dt_ref[at], xf_ref[at], g_ref[at]
             dtx = dt * x
             ddt, dx, new = jnp.zeros_like(dt), jnp.zeros_like(dt), []
             for n in range(N):
                 b_tn, c_tn = b_ref[0, t * N + n], c_ref[0, t * N + n]
                 at_tile = pl.multiple_of((t * N + n) * rows_n, rows_n)
                 tile = pl.ds(at_tile, rows_n)
-                dh = g * c_tn + carry[n]
+                dh = gy * c_tn + later[n]
                 decay = jnp.exp(dt * a[n])
                 q = dh * hist_ref[t, n] * decay
                 ddt = ddt + q * a[n] + dh * (x * b_tn)
                 dx = dx + dh * (dt * b_tn)
                 da_ref[n, rows, :] += q * dt
-                if at == 0:
-                    accc_ref[tile, :] = g * hist_ref[t + 1, n]
-                    accb_ref[tile, :] = dh * dtx
-                else:
-                    accc_ref[tile, :] += g * hist_ref[t + 1, n]
-                    accb_ref[tile, :] += dh * dtx
+                # the first band's sums start the (token, cell) tiles
+                accc_ref[tile, :] = jnp.where(
+                    first, 0.0, accc_ref[tile, :]) + gy * hist_ref[t + 1, n]
+                accb_ref[tile, :] = jnp.where(
+                    first, 0.0, accb_ref[tile, :]) + dh * dtx
                 new.append(decay * dh)
-            ddt_ref[t, rows, :] = ddt
-            dxf_ref[t, rows, :] = dx + skip * g
+            ddt_ref[at] = ddt
+            dxf_ref[at] = dx + skip * gy
             return tuple(new)
 
-        carry = jax.lax.fori_loop(
-            0, chunk, backward, tuple(dh_ref[n, rows, :] for n in range(N)))
+        earlier = _tokens(chunk, rows, backward,
+                          tuple(dh_ref[n, rows, :] for n in range(N)), unroll,
+                          back=True)
         for n in range(N):
-            dh_ref[n, rows, :] = carry[n]
-    dx_ref[...] = dxf_ref[...].astype(dx_ref.dtype)
-    # d silu(z) = sigmoid(z) (1 + z (1 - sigmoid(z)))
-    dz_ref[...] = (dof * ys_ref[...] * sig * (1.0 + zf * (1.0 - sig))).astype(
-        dz_ref.dtype)
+            dh_ref[n, rows, :] = earlier[n]
+        return carry
+
+    jax.lax.fori_loop(0, R // rows_n, band, 0)
+    for r in range(R):
+        _out_of_tiles(dx_ref, r, dxf_ref[:, _rows(r)])
+        zf = _in_tiles(z_ref, r)
+        sig = jax.nn.sigmoid(zf)
+        # d silu(z) = sigmoid(z) (1 + z (1 - sigmoid(z)))
+        _out_of_tiles(dz_ref, r, _in_tiles(do_ref, r) * ys_ref[:, _rows(r)]
+                      * sig * (1.0 + zf * (1.0 - sig)))
+    # summed over the token groups here, over a group's tokens outside
     dd_ref[...] += jnp.sum(g_ref[...] * xf_ref[...], axis=0)
     db_ref[...] = _sum_tiles(accb_ref, chunk, N, rows_n)
     dc_ref[...] = _sum_tiles(accc_ref, chunk, N, rows_n)
@@ -269,88 +361,106 @@ def _params(chunk, d, n_state, itemsize):
         vmem_limit_bytes=vmem_bytes(chunk, d, n_state, itemsize))
 
 
-def _tiled(t):
-    """``[b, S, d]`` read as ``[b, S, d / 128, 128]``."""
-    return t.reshape(t.shape[:2] + (t.shape[2] // LANES, LANES))
+def _tiles(t):
+    """Float32 ``[b, S, d]`` as ``[b, S / 8, d / 16, 128]``, row 8 r + s of
+    group g the lane tile r of token 8 g + s: the array's own (8, 128) tiles
+    in the order HBM holds them, a bitcast on the chip."""
+    b, S, d = t.shape
+    return t.reshape(b, S // SUBLANES, SUBLANES, d // LANES, LANES) \
+        .transpose(0, 1, 3, 2, 4) \
+        .reshape(b, S // SUBLANES, SUBLANES * (d // LANES), LANES)
+
+
+def _of_tiles(t):
+    """``_tiles`` back: ``[b, S, d]``."""
+    b, groups, rows, _ = t.shape
+    return t.reshape(b, groups, rows // SUBLANES, SUBLANES, LANES) \
+        .transpose(0, 1, 3, 2, 4) \
+        .reshape(b, groups * SUBLANES, rows // SUBLANES * LANES)
 
 
 def _fwd(x, dt, bmat, cmat, z, a_t, dskip, chunk, interpret, save):
     """``out`` [b, S, d] and, where ``save``, the state each chunk found
     [b, S / chunk, N, d / 128, 128].  ``a_t`` [N, d] float32."""
     b, S, d = x.shape
-    N, R, nc = a_t.shape[0], d // LANES, S // chunk
-    rows = pl.BlockSpec((None, chunk, R, LANES), lambda i, j: (i, j, 0, 0))
+    N, R, nc, G = a_t.shape[0], d // LANES, S // chunk, chunk // SUBLANES
+    rows = pl.BlockSpec((None, chunk, d), lambda i, j: (i, j, 0))
+    tiles = pl.BlockSpec((None, G, SUBLANES * R, LANES),
+                         lambda i, j: (i, j, 0, 0))
     scalars = pl.BlockSpec((1, chunk * N), lambda i, j: (i, j),
                            memory_space=pltpu.SMEM)
     cells = pl.BlockSpec((N, R, LANES), lambda i, j: (0, 0, 0))
-    out_shape = [jax.ShapeDtypeStruct((b, S, R, LANES), x.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((b, S, d), x.dtype)]
     out_specs = [rows]
     if save:
         out_shape.append(jax.ShapeDtypeStruct((b, nc, N, R, LANES), F32))
         out_specs.append(pl.BlockSpec((None, None, N, R, LANES),
                                       lambda i, j: (i, j, 0, 0, 0)))
-    out = pl.pallas_call(
-        functools.partial(_fwd_kernel, chunk=chunk, n_state=N, save=save),
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, chunk=chunk, n_state=N, save=save,
+                          unroll=not interpret),
         grid=(b, nc),
-        in_specs=[rows, rows, rows, scalars, scalars, cells,
+        in_specs=[rows, tiles, rows, scalars, scalars, cells,
                   pl.BlockSpec((R, LANES), lambda i, j: (0, 0))],
         out_specs=out_specs, out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((N, R, LANES), F32),
-                        pltpu.VMEM((chunk, R, LANES), F32),
-                        pltpu.VMEM((chunk, R, LANES), F32)],
+        scratch_shapes=[pltpu.VMEM((N, R, LANES), F32)]
+        + [pltpu.VMEM((G, SUBLANES * R, LANES), F32)] * 2,
         compiler_params=_params(chunk, d, N, x.dtype.itemsize),
         interpret=interpret, name="selective_scan_fwd",
-    )(_tiled(x), _tiled(dt), _tiled(z), bmat.reshape(b, S * N),
-      cmat.reshape(b, S * N), a_t.reshape(N, R, LANES),
-      dskip.reshape(R, LANES))
-    return (out[0].reshape(b, S, d),) + tuple(out[1:])
+    )(x, _tiles(dt), z, bmat.reshape(b, S * N), cmat.reshape(b, S * N),
+      a_t.reshape(N, R, LANES), dskip.reshape(R, LANES))
 
 
 def _bwd(chunk, interpret, res, dout):
     x, dt, bmat, cmat, z, a_t, dskip, edges = res
     b, S, d = x.shape
-    N, R, nc = a_t.shape[0], d // LANES, S // chunk
+    N, R, nc, G = a_t.shape[0], d // LANES, S // chunk, chunk // SUBLANES
     gr = group_rows(d)
 
     def back(i, j):         # the chunks from the last to the first
         return nc - 1 - j
 
-    rows = pl.BlockSpec((None, chunk, R, LANES),
-                        lambda i, j: (i, back(i, j), 0, 0))
+    rows = pl.BlockSpec((None, chunk, d), lambda i, j: (i, back(i, j), 0))
+    tiles = pl.BlockSpec((None, G, SUBLANES * R, LANES),
+                         lambda i, j: (i, back(i, j), 0, 0))
     scalars = pl.BlockSpec((1, chunk * N), lambda i, j: (i, back(i, j)),
                            memory_space=pltpu.SMEM)
     cells = pl.BlockSpec((N, R, LANES), lambda i, j: (0, 0, 0))
     per_token = pl.BlockSpec((None, chunk, N), lambda i, j: (i, back(i, j), 0))
-    like_x = jax.ShapeDtypeStruct((b, S, R, LANES), x.dtype)
+    like_x = jax.ShapeDtypeStruct((b, S, d), x.dtype)
     dx, ddt, dz, db, dc, da, dd = pl.pallas_call(
-        functools.partial(_bwd_kernel, chunk=chunk, n_state=N),
+        functools.partial(_bwd_kernel, chunk=chunk, n_state=N,
+                          unroll=not interpret),
         grid=(b, nc),
-        in_specs=[rows, rows, rows, scalars, scalars, cells,
+        in_specs=[rows, tiles, rows, scalars, scalars, cells,
                   pl.BlockSpec((R, LANES), lambda i, j: (0, 0)),
                   pl.BlockSpec((None, None, N, R, LANES),
                                lambda i, j: (i, back(i, j), 0, 0, 0)),
                   rows],
-        out_specs=[rows, rows, rows, per_token, per_token,
+        out_specs=[rows, tiles, rows, per_token, per_token,
                    pl.BlockSpec((None, N, R, LANES),
                                 lambda i, j: (i, 0, 0, 0)),
-                   pl.BlockSpec((None, R, LANES), lambda i, j: (i, 0, 0))],
-        out_shape=[like_x, jax.ShapeDtypeStruct((b, S, R, LANES), F32),
+                   pl.BlockSpec((None, SUBLANES * R, LANES),
+                                lambda i, j: (i, 0, 0))],
+        out_shape=[like_x,
+                   jax.ShapeDtypeStruct((b, S // SUBLANES, SUBLANES * R,
+                                         LANES), F32),
                    like_x, jax.ShapeDtypeStruct((b, S, N), F32),
                    jax.ShapeDtypeStruct((b, S, N), F32),
                    jax.ShapeDtypeStruct((b, N, R, LANES), F32),
-                   jax.ShapeDtypeStruct((b, R, LANES), F32)],
+                   jax.ShapeDtypeStruct((b, SUBLANES * R, LANES), F32)],
         scratch_shapes=[pltpu.VMEM((N, R, LANES), F32)]
-        + [pltpu.VMEM((chunk, R, LANES), F32)] * 4
+        + [pltpu.VMEM((G, SUBLANES * R, LANES), F32)] * 4
         + [pltpu.VMEM((chunk + 1, N, gr, LANES), F32)]
         + [pltpu.VMEM((chunk * N * gr, LANES), F32)] * 2,
         compiler_params=_params(chunk, d, N, x.dtype.itemsize),
         interpret=interpret, name="selective_scan_bwd",
-    )(_tiled(x), _tiled(dt), _tiled(z), bmat.reshape(b, S * N),
-      cmat.reshape(b, S * N), a_t.reshape(N, R, LANES),
-      dskip.reshape(R, LANES), edges, _tiled(dout))
-    return (dx.reshape(b, S, d), ddt.reshape(b, S, d), db, dc,
-            dz.reshape(b, S, d), jnp.sum(da, axis=0).reshape(N, d),
-            jnp.sum(dd, axis=0).reshape(d))
+    )(x, _tiles(dt), z, bmat.reshape(b, S * N), cmat.reshape(b, S * N),
+      a_t.reshape(N, R, LANES), dskip.reshape(R, LANES), edges, dout)
+    return (dx, _of_tiles(ddt), db, dc, dz,
+            jnp.sum(da, axis=0).reshape(N, d),
+            jnp.sum(dd.reshape(b, R, SUBLANES, LANES), axis=(0, 2))
+            .reshape(d))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))

@@ -1232,8 +1232,11 @@ def mamba_mixer(pl, h, cfg):
     kernel = scan.supported(x.shape, cfg.d_state, chunk)
     mon = monitor.active()
     if mon is not None:
+        # door: how the per-token operands reach the scan: the kernels read
+        # the projections' own tiles; the per-token scan transposes copies
         mon.registry.counter("monitor.kernels.selective_scan_calls",
-                             fused=int(kernel)).incr()
+                             fused=int(kernel),
+                             door="tiles" if kernel else "copied").incr()
     with jax.named_scope(devscope.SELECTIVE_SCAN):
         operands = (x, dt, bmat, cmat, z, -jnp.exp(pl["a_log"]), pl["d_skip"])
         y = scan.selective_scan(*operands, chunk=chunk) if kernel \

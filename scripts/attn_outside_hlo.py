@@ -1,12 +1,13 @@
-"""What ONE attention (or retention) layer of a cell moves through HBM outside
-its matmuls and kernels, read off the compiled text, no chip:
+"""What ONE attention (or retention, or Mamba) layer of a cell moves through
+HBM outside its matmuls and kernels, read off the compiled text, no chip:
 
     python3 scripts/attn_outside_hlo.py trinity_large_preview.s6144_scan
         [--kind 3] [--top 30] [--tiny]
 
-The branch the block runs (``transformer._attention_heads_mode``, or
-``power_retention``) on the cell's batch and sequence at the configuration's
-widths, under ``jax.checkpoint``; its vjp alone is compiled (the recomputed
+The branch the block runs (``transformer._attention_heads_mode``,
+``power_retention`` or ``mamba_mixer``) on the cell's batch and sequence at
+the configuration's widths, under ``jax.checkpoint``; its vjp alone is
+compiled (the recomputed
 forward and the backward: what a layer costs a second time in the step) for
 a described ``v5e:2x2``, the kernels' ``_on_tpu`` patched True in THIS
 process, shapes not arrays.  Bytes = operands + results of every
@@ -21,10 +22,10 @@ groups:
 - ``other``: what is left, the norm, rotation, gate and relayouts; its
   instructions over ``--big`` MB (140) are listed.
 
-``--kind i`` takes the i-th layer kind of the period (default: the first
-with rotary positions, or retention); ``--tiny`` takes the model's tiny
-configuration at S = 256 (the smoke test's).  This is the reading ISSUE 47
-was sized by (PERF.md section 6, PR 47).  Bytes over 819 GB/s are a LEAST
+``--kind i`` takes the i-th layer kind of the period (default: the first with
+rotary positions, or retention, or Mamba); ``--tiny`` takes the model's tiny
+configuration at S = 256 (the smoke test's). This is the reading ISSUEs 47 and
+49 were sized by (PERF.md section 6). Bytes over 819 GB/s are a LEAST
 time, not a time: a time comes from the chip."""
 
 import argparse
@@ -193,16 +194,22 @@ def compiled_text(cfg, batch, seq, kind):
     one_chip = SingleDeviceSharding(topo.devices[0])
     params = jax.eval_shape(
         lambda: T._init_params(jax.random.PRNGKey(0), cfg))["params_layers"]
-    if cfg.per_position:
+    stacked = 1             # leading axes of a layer's leaves: the periods
+    if cfg.run_scan:        # a run's tree: [periods, run length, ...]
+        params = params["r%d" % [k for _, k, _ in cfg.runs].index(kind)]
+        stacked = 2
+    elif cfg.per_position:
         params = params["p%d" % cfg.layer_kinds.index(kind)]
     leaves = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-        a.shape[1:], a.dtype, sharding=one_chip), params)
+        a.shape[stacked:], a.dtype, sharding=one_chip), params)
     h = jax.ShapeDtypeStruct((batch, seq, cfg.hidden), cfg.jdtype,
                              sharding=one_chip)
 
     def branch(pl, h):
         if kind == T.RETENTION:
             return T.power_retention(pl, h, cfg)
+        if kind == T.MAMBA:
+            return T.mamba_mixer(pl, h, cfg)
         return T._attention_heads_mode(pl, h, cfg, kind)
 
     def recompute_and_backward(pl, h, g):
@@ -236,7 +243,8 @@ def default_kind(cfg):
 
     kinds = cfg.layer_kinds
     return next(k for k in kinds
-                if k == T.RETENTION or (isinstance(k, tuple) and k[1]))
+                if k in (T.RETENTION, T.MAMBA)
+                or (isinstance(k, tuple) and k[1]))
 
 
 def main(argv=None):

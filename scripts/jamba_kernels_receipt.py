@@ -1,28 +1,41 @@
 """The selective-scan kernels compiled by Mosaic at the jamba cell's shapes
-([1, 8192, 5120] channels of 16 state cells, bf16 x and z, float32 step
-sizes; compared over the first 4,096 tokens) against the per-token
-``lax.scan`` in float32 (``kernels/selective_scan.py:
-selective_scan_reference``, whose gradient holds the ``[S, d, N]`` state in
-HBM three times over: 4 GB at 4,096): the output and the seven gradients, with step sizes drawn as the cell's weights seed them
-(log-uniform in [1e-3, 1e-1], rates -1..-16: a cell's decay runs from 0.9999
-to 0.2 a token) and with every step a tenth of that (a state that still
-weighs ten thousand tokens on, carried over every chunk edge).  What the
-cell's ``correct`` cannot see (PERF.md section 7): the backward.
+([1, 8192, 5120] channels of 16 state cells, bf16 x and z, float32 step sizes;
+compared over the first 4,096 tokens) against the per-token ``lax.scan`` in
+float32 (``kernels/selective_scan.py: selective_scan_reference``, whose
+gradient holds the ``[S, d, N]`` state in HBM three times over: 4 GB at
+4,096): the output and the seven gradients, with step sizes drawn as the
+cell's weights seed them (log-uniform in [1e-3, 1e-1], rates -1..-16: a cell's
+decay runs from 0.9999 to 0.2 a token) and with every step a tenth of that (a
+state that still weighs ten thousand tokens on, carried over every chunk
+edge). What the cell's ``correct`` cannot see (PERF.md section 7): the
+backward.
 
-    chiprun -- python3 scripts/jamba_kernels_receipt.py [out.json] [chunk ...]
+    chiprun -- python3 scripts/jamba_kernels_receipt.py [out.json]
+        [--against <another tree's kernels/selective_scan.py>] [chunk ...]
 
 Each reading is ``|program - reference| / |reference|``; the limit is 2e-2
 (bf16 x and z: 2^-8 a value) on every one, and a fault control (the state
-dropped at chunk edges, put into the reference) has to read over it.  Also
-times the forward and the forward with its backward at each chunk length
-given (default 64 and 128): the host's clock around ``block_until_ready``,
-the mean of ``CALLS`` calls after a warm one.  Exit 1 where a reading is
-off, 2 off a TPU."""
+dropped at chunk edges, put into the reference) has to read over it.
 
+Then the times, by DEVICE trace (the host's clock around a call read 1.7
+times the kernels' own in PR 48): the forward and its backward under
+``jax.vjp`` as one program whose parameters and results are ``[1, S, d]``
+arrays as the mixer hands them over, at each chunk length given (default 64
+and 128), the mean of ``CALLS`` calls: the two kernels by name, and the DOOR,
+a line each for x, z, dt, out and the four per-token gradients: the
+instructions the compiled program runs between that array and the kernels
+(``door``: followed through the entry computation's text; a view that XLA
+takes as a bitcast has none and reads 0).  ``--against`` times another tree's
+kernels the same way in the same process (the parent's from ``git archive``:
+PR 48's door is XLA's re-tiling copies).  Exit 1 where a reading is off or a
+kernel's name matched nothing in the trace, 2 off a TPU."""
+
+import importlib.util
 import json
 import os
+import re
 import sys
-import time
+import tempfile
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -31,6 +44,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from attn_outside_hlo import computations  # noqa: E402  (beside this file)
 from paddle_tpu.kernels import selective_scan as ss  # noqa: E402
 
 S, D, N = 8192, 5120, 16
@@ -38,6 +52,11 @@ S_COMPARED = 4096       # the reference's gradient holds [S, d, N] three times
 LIMIT = 2e-2
 CALLS = 5
 NAMES = ("x", "dt", "B", "C", "z", "a", "D")
+KERNELS = ("selective_scan_fwd", "selective_scan_bwd")
+# the per-token arrays at the door: (kernel, "in" | "out", its place there)
+DOOR = {"x": (1, "in", 0), "dt": (1, "in", 1), "z": (1, "in", 2),
+        "out": (0, "out", 0), "dout": (1, "in", 8), "dx": (1, "out", 0),
+        "ddt": (1, "out", 1), "dz": (1, "out", 2)}
 
 
 def _rel(got, want):
@@ -68,13 +87,111 @@ def dropped_at_edges(args, chunk, S=S_COMPARED):
     return jnp.concatenate(parts, axis=1)
 
 
-def main(out_path=None, *chunks):
+def door(text):
+    """{array of ``DOOR``: the entry computation's instructions between it
+    and the kernels}, of the compiled text of a program whose parameters and
+    results are the kernels' operands and results: back from a call's
+    operand to a parameter, on from a call's result to the root."""
+    comps, entry = computations(text)
+    by = {name: (op, operands, attrs)
+          for name, _, op, operands, attrs in comps[entry]}
+    users = {}
+    for name, (_, operands, _) in by.items():
+        for o in operands:
+            users.setdefault(o, []).append(name)
+    calls = [next(n for n, (op, _, _) in by.items()
+                  if op == "custom-call" and kernel in n)
+             for kernel in KERNELS]
+
+    def back(name):
+        found = []
+        while by[name][0] != "parameter" and by[name][1]:
+            found.append(name)
+            name = by[name][1][0]
+        return found
+
+    def on(name):
+        found, frontier = [], [name]
+        while frontier:
+            for u in users.get(frontier.pop(), ()):
+                if by[u][0] not in ("tuple", "custom-call"):
+                    found.append(u)
+                    frontier.append(u)
+        return found
+
+    out = {}
+    for array, (call, side, at) in DOOR.items():
+        call = calls[call]
+        if side == "in":
+            out[array] = back(by[call][1][at])
+        else:
+            out[array] = [n for u in users[call]
+                          if by[u][0] == "get-tuple-element"
+                          and re.search(r"\bindex=%d\b" % at, by[u][2])
+                          for n in [u] + on(u)]
+    return out
+
+
+def device_us(fn, args):
+    """{instruction: device microseconds a call of ``fn``}, from a trace."""
+    from benchmark.harness import trace_reduce, tracing
+
+    jax.block_until_ready(fn(*args))
+    with tempfile.TemporaryDirectory() as tmp:
+        tracing._start(tmp, 0)
+        for _ in range(CALLS):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        dev = trace_reduce.Reduced(trace_reduce.load_xplane(
+            trace_reduce.find_xplane(tmp))).devices[0]
+    return {name: t / CALLS / 1e3 for name, t in dev["by_name"].items()}
+
+
+def times(mod, chunk, args, g):
+    """Device microseconds a call of ``mod``'s forward + backward at the
+    cell's shape: the kernels by name, the door by array, and the rest."""
+    def both(*a):
+        out, vjp = jax.vjp(lambda *q: mod.selective_scan(*q, chunk=chunk),
+                           *a[:-1])
+        return (out,) + vjp(a[-1])
+
+    fn = jax.jit(both)
+    by_name = device_us(fn, args + (g,))
+    at_door = door(fn.lower(*args, g).compile().as_text())
+    took = {k: sum(t for n, t in by_name.items() if k in n) for k in KERNELS}
+    took["door"] = {
+        array: {"us": sum(by_name.get(n.lstrip("%"), 0.0) for n in names),
+                "instructions": [n for n in names
+                                 if n.lstrip("%") in by_name]}
+        for array, names in at_door.items()}
+    took["all"] = sum(by_name.values())
+    return took
+
+
+def _other_tree(path):
+    """Another tree's ``kernels/selective_scan.py`` beside this tree's
+    ``_common``."""
+    spec = importlib.util.spec_from_file_location(
+        "paddle_tpu.kernels._selective_scan_against", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def main(*argv):
     if jax.devices()[0].platform != "tpu":
         print("needs a TPU")
         return 2
-    chunks = [int(c) for c in chunks] or [64, 128]
+    argv, against = list(argv), None
+    if "--against" in argv:
+        at = argv.index("--against")
+        against = _other_tree(argv[at + 1])
+        del argv[at:at + 2]
+    out_path = argv[0] if argv and not argv[0].isdigit() else None
+    chunks = [int(c) for c in argv[bool(out_path):]] or [64, 128]
     out = {"device_kind": jax.devices()[0].device_kind, "readings": {},
-           "seconds": {}}
+           "device_us": {}}
     ok = True
     for label, scale in (("seeded", 1.0), ("slow_decay", 0.1)):
         args, w = operands(11, scale, S_COMPARED)
@@ -107,20 +224,21 @@ def main(out_path=None, *chunks):
     print("control (state dropped at chunk edges):",
           out["control_state_dropped"], flush=True)
     args, w = operands(12, 1.0)
+    g = w.astype(args[0].dtype)
+    trees = [("this", ss)] + ([("against", against)] if against else [])
     for chunk in chunks:
-        fwd = jax.jit(lambda *a_: ss.selective_scan(*a_, chunk=chunk))
-        both = jax.jit(jax.grad(
-            lambda *a_: jnp.sum(ss.selective_scan(*a_, chunk=chunk).astype(
-                jnp.float32) * w), argnums=tuple(range(7))))
-        took = {}
-        for name, fn in (("forward", fwd), ("forward_and_backward", both)):
-            jax.block_until_ready(fn(*args))
-            t0 = time.perf_counter()
-            for _ in range(CALLS):
-                jax.block_until_ready(fn(*args))
-            took[name] = (time.perf_counter() - t0) / CALLS
-        out["seconds"][str(chunk)] = took
-        print("chunk", chunk, "host seconds a call:", took, flush=True)
+        for tree, mod in trees:
+            took = times(mod, chunk, args, g)
+            out["device_us"]["%s.chunk%d" % (tree, chunk)] = took
+            print("%s, chunk %d, device us a call: %s; all %.1f" % (
+                tree, chunk, ", ".join("%s %.1f" % (k, took[k])
+                                       for k in KERNELS), took["all"]),
+                flush=True)
+            for array, at in took["door"].items():
+                print("    door %-5s %9.1f us  %s" % (
+                    array, at["us"], " ".join(at["instructions"]) or "-"),
+                    flush=True)
+            ok = ok and all(took[k] > 0 for k in KERNELS)
     worst = max(out["readings"].items(), key=lambda kv: kv[1])
     out["worst"], out["ok"] = list(worst), bool(ok)
     print(json.dumps({k: v for k, v in out.items() if k != "readings"}))

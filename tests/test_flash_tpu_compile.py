@@ -11,6 +11,7 @@ import json
 import math
 import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -483,3 +484,86 @@ def test_attn_outside_hlo_smoke(one_chip, capsys):
         "qk_rope_fwd", "qk_rope_bwd", "flash_fwd", "flash_bwd_fused"}
     assert 0 < report["gb"]["other"] < report["gb"]["matmul"]
     assert json.loads(capsys.readouterr().out.splitlines()[-1]) == report
+
+
+# --- the selective scan at its door (PR 49) ----------------------------------
+
+def _script(name):
+    """A module of ``scripts/``."""
+    scripts = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts")
+    if scripts not in sys.path:
+        sys.path.insert(0, scripts)
+    return importlib.import_module(name)
+
+
+@pytest.mark.parametrize("what,shape,chunk,dtype", [
+    ("jamba2_3b.s8192_scan", (1, 8192, 5120), 128, jnp.bfloat16),
+    ("a token group that is a whole chunk", (1, 64, 1024), 8, jnp.bfloat16),
+    ("a bfloat16 tile split by a chunk edge", (1, 48, 1024), 24, jnp.bfloat16),
+    ("float32 x and z, two groups of rows", (1, 256, 2048), 128, jnp.float32),
+])
+def test_the_selective_scan_compiles_for_a_v5e(one_chip, what, shape, chunk,
+                                               dtype):
+    """Both kernels through Mosaic at the cell's shape and at the shapes the
+    door's addressing adds (a dynamic strided sublane index; ``[chunk, d]``
+    blocks of a 16-row-tiled array at chunks of 8 and 24), within the VMEM
+    their call asks for; and the compiled program re-tiles NOTHING: no
+    instruction but the kernels touches a per-token array."""
+    ss = importlib.import_module("paddle_tpu.kernels.selective_scan")
+    b, S, d = shape
+    N = 16
+
+    def sds(shape_, dtype_):
+        return jax.ShapeDtypeStruct(shape_, dtype_, sharding=one_chip)
+
+    args = (sds(shape, dtype), sds(shape, jnp.float32),
+            sds((b, S, N), jnp.float32), sds((b, S, N), jnp.float32),
+            sds(shape, dtype), sds((d, N), jnp.float32),
+            sds((d,), jnp.float32))
+
+    def both(*a):
+        out, vjp = jax.vjp(lambda *q: ss.selective_scan(
+            *q, chunk=chunk, interpret=False), *a[:-1])
+        return (out,) + vjp(a[-1])
+
+    assert ss.supported(shape, N, chunk)
+    text = jax.jit(both).lower(*args, sds(shape, dtype)).compile().as_text()
+    for kernel in ("selective_scan_fwd", "selective_scan_bwd"):
+        asked, took = _vmem(text, kernel)
+        assert asked == ss.vmem_bytes(chunk, d, N, jnp.dtype(dtype).itemsize)
+        assert took < asked < 128 * 2 ** 20, (what, kernel, took, asked)
+    # what the receipt times as the door (scripts/jamba_kernels_receipt.py)
+    at_door = _script("jamba_kernels_receipt").door(text)
+    comps, entry = _script("attn_outside_hlo").computations(text)
+    ops = {name: op for name, _, op, _, _ in comps[entry]}
+    # XLA's own prefetch of a small operand (``copy-start``) keeps its tiles
+    moved = {array: [n for n in names if ops[n] in (
+        "copy", "reshape", "transpose", "fusion")]
+        for array, names in at_door.items()}
+    assert set(moved) == {"x", "dt", "z", "out", "dout", "dx", "ddt", "dz"}
+    assert not any(moved.values()), (what, moved)
+
+
+def test_a_mamba_layer_s_text_holds_no_float32_copy_at_the_scan_s_door(
+        one_chip):
+    """The mixer's recompute + backward at d = 1,024 channels, S = 256,
+    bfloat16, through ``scripts/attn_outside_hlo.py``: the step sizes reach
+    the kernels, and their gradient the ``dt_proj`` matmuls, with no ``copy``
+    (and no ``reshape`` or ``transpose`` that moves) of a float32 per-token
+    array in the entry computation: XLA takes the door's view as a
+    bitcast."""
+    hlo = _script("attn_outside_hlo")
+    jamba = importlib.import_module("paddle_tpu.models.jamba")
+    T = importlib.import_module("paddle_tpu.parallel.transformer")
+    cfg = jamba.jamba_tiny_config(d_inner=1024, dtype="bfloat16",
+                                  scan_chunk=128, max_seq=256)
+    text = hlo.compiled_text(cfg, 1, 256, T.MAMBA)
+    groups, by_kernel, others = hlo.account(text)
+    assert set(by_kernel) == {"selective_scan_fwd", "selective_scan_bwd"}
+    elements = 256 * 1024
+    relayouts = [o for o in others if o[2] in ("copy", "reshape", "transpose")
+                 and o[3].startswith("f32") and o[0] >= 2 * 4 * elements]
+    assert not relayouts, relayouts
+    # the softplus writes the kernels' view itself: one float32 pass
+    assert re.search(r"= f32\[1,32,64,128\]\S* fusion\(", text)

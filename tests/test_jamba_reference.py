@@ -245,6 +245,61 @@ def test_the_kernels_take_whole_tiles_of_channels_and_bfloat16():
     assert ss.group_rows(5120) == 8 and ss.group_rows(256) == 2
 
 
+@pytest.mark.parametrize("b,s,d,chunk,dtype", [
+    # a token group of eight that is a whole chunk, one and two groups of rows
+    (1, 16, 1024, 8, "bfloat16"), (1, 16, 2048, 8, "bfloat16"),
+    # a bfloat16 tile's sixteen tokens split by a chunk edge
+    (1, 48, 256, 8, "bfloat16"), (1, 48, 256, 24, "bfloat16"),
+    # one row of channels; two sequences
+    (1, 32, 128, 16, "float32"), (2, 32, 128, 8, "bfloat16"),
+])
+def test_the_kernels_read_the_projections_own_tiles(b, s, d, chunk, dtype):
+    """The door's addressing (token ``t`` of a chunk at ``[t // 8, rows, t %
+    8]``; x, z and the gradients beside them as plain ``[chunk, d]`` blocks,
+    cast a lane tile at a time): output and all seven gradients against the
+    per-token scan on the same operands, where bfloat16 rounds x, z, the
+    output and their gradients once."""
+    args, w = _operands(b, s, d, 4, seed=2)
+    args = tuple(t.astype(dtype) if i in (0, 4) else t
+                 for i, t in enumerate(args))
+    tol = 2e-2 if dtype == "bfloat16" else TOL
+
+    def both(fn):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
+            argnums=tuple(range(7)))(*args)
+
+    (got, got_g), (want, want_g) = (
+        both(lambda *a: ss.selective_scan(*a, chunk=chunk, interpret=True)),
+        both(ss.selective_scan_reference))
+    assert abs(float(got) - float(want)) <= tol * float(jnp.sum(jnp.abs(
+        ss.selective_scan_reference(*args).astype(jnp.float32) * w)))
+    for name, g, wg in zip("x dt B C z a D".split(), got_g, want_g):
+        assert g.dtype == wg.dtype and g.shape == wg.shape, name
+        g, wg = (np.asarray(t, np.float32) for t in (g, wg))
+        np.testing.assert_allclose(g, wg, rtol=max(tol, 1e-4), err_msg=name,
+                                   atol=tol * np.abs(wg).max())
+
+
+def test_the_door_s_view_is_the_array_s_own_tiles():
+    """``_tiles``: row 8 r + s of group g of a sequence's view is token 8 g
+    + s, channels 128 r ..; ``_of_tiles`` puts it back."""
+    t = jnp.arange(2 * 24 * 384, dtype=jnp.float32).reshape(2, 24, 384)
+    view = ss._tiles(t)
+    assert view.shape == (2, 3, 24, 128)
+    np.testing.assert_array_equal(view[1, 2, 8 * 1 + 5], t[1, 21, 128:256])
+    np.testing.assert_array_equal(ss._of_tiles(view), t)
+
+
+@pytest.mark.parametrize("shape,chunk", [
+    ((1, 44, 256), 44), ((1, 36, 256), 12), ((1, 20, 128), 4)])
+def test_supported_refuses_sequences_off_whole_sublane_tiles(shape, chunk):
+    """The view ``[S / 8, d / 128, 8, 128]`` needs S in whole groups of 8
+    tokens: such shapes run the per-token scan."""
+    assert shape[1] % chunk == 0 and not ss.supported(shape, 16, chunk)
+    assert ss.supported((1, 48, 256), 16, 8)
+
+
 # --- the block ---------------------------------------------------------------
 
 def test_the_run_scan_equals_the_inlined_period(both):
@@ -334,8 +389,11 @@ def test_the_gauges_of_a_call(ran):
     assert 1e-3 < reg.gauge("monitor.train.mamba_dt_mean").value < 0.2
     # the fastest cell (rate 16) under the largest step of the batch
     assert 0.0 < reg.gauge("monitor.train.mamba_decay_min").value < 0.5
+    # the kernels ran, on views of the projections' own tiles
     assert reg.counter("monitor.kernels.selective_scan_calls",
-                       fused=1).value > 0
+                       fused=1, door="tiles").value > 0
+    assert reg.counter("monitor.kernels.selective_scan_calls",
+                       fused=0, door="copied").value == 0
     # the one attention layer's grid: 5 heads on one key/value head
     assert reg.gauge("monitor.kernels.flash_grid_steps").value > 0
 
