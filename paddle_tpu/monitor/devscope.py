@@ -10,7 +10,10 @@ changes no instruction.  This module keeps, for every program a trainer
 dispatched, what is needed to read ``instruction name -> op_name`` off the
 program's own compiled text, and reads it only when asked.  It records no
 time: ``monitor/trace.py`` is the host's tracer and the profiler the
-device's; this says what the profiler's instruction names mean.
+device's; this says what the profiler's instruction names mean.  The
+executable it reads them from is fetched once a program and kept
+(``executables()``): ``monitor/memscope.py`` reads the same one for the
+program's memory ledger and for what is large in it (``value_sizes``).
 
     trainer.run_steps(batches, lr)        # registers the program, once
     ...profile...
@@ -22,14 +25,15 @@ Only plain ``jax.jit`` programs are registered.  A step built with a
 skipped (neither ``build_*_trainer`` nor the benchmark passes one).
 """
 
+import collections
 import functools
 import re
 import weakref
 
 import jax
 
-__all__ = ["VOCABULARY", "PHASES", "scoped", "register", "scope_maps",
-           "classify"]
+__all__ = ["VOCABULARY", "PHASES", "scoped", "register", "executables",
+           "scope_maps", "classify", "value_sizes"]
 
 EMBED, ATTENTION, MLP, LAYER_NORM, LM_HEAD = (
     "embed", "attention", "mlp", "layer_norm", "lm_head")
@@ -78,7 +82,23 @@ _OPERAND = re.compile(r"%([\w.\-]+)")
 _WRAPPERS = re.compile(r"(?:jvp|transpose)\(|\)")
 _RECOMPUTE = "rematted_computation"
 
-_programs = []          # (label, weak reference to the jitted function, avals)
+# [label, weak reference to the jitted function, avals, its executable once
+# somebody has asked for it]
+_programs = []
+
+# for ``value_sizes``: a computation's first line, the halves of an
+# instruction's right-hand side, and what an element of a shape occupies
+_COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+) \(.*\{$")
+_ARRAY = re.compile(r"([a-z]\w*)\[([\d,]*)\]")
+_CALL = re.compile(r"^([\w\-]+)\((.*?)\)(?:, |$)")
+_COMMENT = re.compile(r"/\*.*?\*/")
+_BODY = re.compile(r"\bbody=%?([\w.\-]+)")
+_ITEMSIZE = {"pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2,
+             "u16": 2, "f16": 2, "bf16": 2, "s32": 4, "u32": 4, "f32": 4,
+             "s64": 8, "u64": 8, "f64": 8, "c64": 8, "c128": 16}
+# results that are another value's bytes, or no bytes of the step's
+_NO_VALUE = ("parameter", "tuple", "get-tuple-element", "bitcast", "constant",
+             "while", "after-all", "partition-id", "replica-id")
 
 
 def scoped(name):
@@ -109,8 +129,8 @@ def register(label, jitted, args):
     Lowers, compiles and reads nothing.  Returns True, for the caller's
     once-only flag."""
     if hasattr(jitted, "lower"):
-        _programs.append((label, weakref.ref(jitted),
-                          jax.tree.map(_aval, tuple(args))))
+        _programs.append([label, weakref.ref(jitted),
+                          jax.tree.map(_aval, tuple(args)), None])
     return True
 
 
@@ -141,17 +161,18 @@ def _names(text):
     return names
 
 
-def scope_maps():
-    """``{label: {instruction name: op_name}}`` for every registered program
-    whose owner is alive, read off ``lower(*avals).compile().as_text()``.
-    The executable comes from JAX's caches where they hold it (the trace of
-    the arguments' shapes and the compile are cached in the process, the
-    compile in the persistent cache too), so this costs one pass over the
-    text; it is for after a profiled run, never for a hot path.  Two live
-    programs under one label are told apart as ``label`` and ``label#2``."""
+def executables():
+    """``{label: compiled executable}`` for every registered program whose
+    owner is alive: ``lower(*avals).compile()``, fetched once a program,
+    whoever asks first, and kept while its owner lives.  The executable
+    comes from JAX's caches where they hold it (the trace of the arguments'
+    shapes and the compile are cached in the process, the compile in the
+    persistent cache too); it is for after a run, never for a hot path.
+    Two live programs under one label are told apart as ``label`` and
+    ``label#2``."""
     out, live = {}, []
     for entry in _programs:
-        label, ref, avals = entry
+        label, ref, avals, compiled = entry
         jitted = ref()
         if jitted is None:
             continue                        # its trainer is gone
@@ -160,9 +181,18 @@ def scope_maps():
         while key in out:
             n += 1
             key = "%s#%d" % (label, n)
-        out[key] = _names(jitted.lower(*avals).compile().as_text())
+        if compiled is None:
+            compiled = entry[3] = jitted.lower(*avals).compile()
+        out[key] = compiled
     _programs[:] = live
     return out
+
+
+def scope_maps():
+    """``{label: {instruction name: op_name}}`` of ``executables()``, read
+    off each one's text: one pass over it a call."""
+    return {key: _names(compiled.as_text())
+            for key, compiled in executables().items()}
 
 
 def classify(op_name):
@@ -187,3 +217,142 @@ def classify(op_name):
     if "transpose(" in op_name:
         return "backward", scope
     return "forward", scope
+
+
+# one instruction of a compiled module's text: ``shape`` as written (a
+# tuple's keeps its parentheses), ``rest`` the whole right-hand side
+_Instr = collections.namedtuple(
+    "_Instr", "name is_root shape opcode operands rest")
+
+
+def _instruction(name, line, rest):
+    if rest.startswith("("):
+        depth = 0
+        for i, c in enumerate(rest):
+            depth += (c == "(") - (c == ")")
+            if depth == 0:
+                break
+        shape, call = rest[:i + 1], rest[i + 2:]
+    else:
+        shape, _, call = rest.partition(" ")
+    m = _CALL.match(call)
+    return _Instr(name, line.lstrip().startswith("ROOT"), shape,
+                  m.group(1) if m else None,
+                  _OPERAND.findall(m.group(2)) if m else [], rest)
+
+
+def _computations(text):
+    """``({computation: [_Instr]}, the ENTRY computation's name)``."""
+    comps, entry, cur = {}, None, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(2), [])
+            entry = m.group(2) if m.group(1) else entry
+            continue
+        m = _INSTRUCTION.match(line)
+        if m and cur is not None:
+            cur.append(_instruction(m.group(1), line, m.group(2)))
+    return comps, entry
+
+
+def _arrays(shape):
+    """``[(shape as written, bytes)]`` of the arrays in a shape's text, in
+    order: one for an array, one an element for a (flat) tuple."""
+    out = []
+    for m in _ARRAY.finditer(_COMMENT.sub("", shape)):
+        size = _ITEMSIZE.get(m.group(1), 1 if m.group(1).startswith("f8")
+                             else 0)
+        for d in m.group(2).split(","):
+            size *= int(d) if d else 1
+        out.append((m.group(0), size))
+    return out
+
+
+def _body(comps, loop):
+    m = _BODY.search(loop.rest)
+    return comps.get(m.group(1), ()) if m else ()
+
+
+def value_sizes(text):
+    """What is large in a compiled module, by its own text: ``[(bytes,
+    shape, instruction, op_name)]``, largest first, of
+
+    - the result of every instruction of the ENTRY computation and of the
+      bodies of its loops (under a scan over steps the step is one loop
+      down), parameters, tuples and views left out, and
+    - every array that a ``while`` loop of any computation CARRIES and its
+      body changes, as ``<while>[<index>]`` under the loop's ``op_name``:
+      the layer scan's stacked residuals and stacked gradients.  An element
+      the body hands on as it came (the stacked weights, a batch) is the
+      caller's, not the loop's, and one that a loop of the entry computation
+      takes from the program's parameters (the train state under a scan over
+      steps) is an argument.
+
+    It has no liveness: two of these may never be held together, and a
+    loop's body holds more inside.  It names candidates for a peak; the
+    peak is the compiler's (``memscope.program_ledger``)."""
+    comps, entry = _computations(text)
+    names, out = _names(text), []
+    top = comps.get(entry, ())
+    # the step's own level: the entry computation and, under a scan over
+    # steps, the bodies of its loops
+    for comp in [top] + [_body(comps, i) for i in top if i.opcode == "while"]:
+        # what a loop starts from is the loop's own buffer: listed there
+        inits = {i.operands[0] for i in comp
+                 if i.opcode == "while" and i.operands}
+        started = {operand for i in comp if i.name in inits
+                   for operand in i.operands}
+        for i in comp:
+            if i.opcode not in _NO_VALUE and not i.shape.startswith("(") \
+                    and i.name not in started:
+                out += [(size, written, i.name, names.get(i.name))
+                        for written, size in _arrays(i.shape)]
+    for comp in comps.values():
+        for loop in comp:
+            if loop.opcode != "while":
+                continue
+            kept = _handed_on(_body(comps, loop))
+            if comp is top and loop.operands:
+                kept |= _arguments(comp, loop.operands[0])
+            out += [(size, written, "%s[%d]" % (loop.name, n),
+                     names.get(loop.name))
+                    for n, (written, size) in enumerate(_arrays(loop.shape))
+                    if n not in kept]
+    return sorted(out, key=lambda v: (-v[0], v[2]))
+
+
+def _arguments(comp, init):
+    """The indices of the tuple ``init`` of the entry computation that are
+    the program's own parameters (through a copy or a view): the train
+    state that a scan over steps carries, which is counted with the
+    arguments."""
+    by_name = {i.name: i for i in comp}
+    init = by_name.get(init)
+    found = set()
+    for n, name in enumerate(init.operands if init is not None
+                             and init.opcode == "tuple" else ()):
+        i = by_name.get(name)
+        while i is not None and i.opcode in ("copy", "bitcast") \
+                and i.operands:
+            i = by_name.get(i.operands[0])
+        if i is not None and i.opcode == "parameter":
+            found.add(n)
+    return found
+
+
+def _handed_on(body):
+    """The indices of a loop's tuple that ``body`` returns as it got them:
+    its ROOT ``tuple``'s operand ``n`` is ``get-tuple-element(parameter),
+    index=n``."""
+    by_name = {i.name: i for i in body}
+    root = next((i.operands for i in body
+                 if i.is_root and i.opcode == "tuple"), ())
+    kept = set()
+    for n, operand in enumerate(root):
+        i = by_name.get(operand)
+        if i is not None and i.opcode == "get-tuple-element" and i.operands \
+                and getattr(by_name.get(i.operands[0]), "opcode", None) \
+                == "parameter" and re.search(r"\bindex=%d\b" % n, i.rest):
+            kept.add(n)
+    return kept

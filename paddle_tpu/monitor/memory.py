@@ -17,10 +17,14 @@ allocator stats; the TPU backend does):
   owners) with an explicit ``unattributed`` remainder, plus host-side
   accounting (process RSS, HostPS resident tables, ShardPS replay logs).
 
-Each sample sets gauges in the registry; ``*_peak`` gauges only ratchet up
+Each sample sets gauges in the registry, each one an operator's to act on
+(README, "Memory attribution (MemScope)", lists them with the action; the
+sample's other numbers ride the ``memory`` event alone); ``*_peak`` gauges
+only ratchet up
 (``Gauge.set_max``) — the high-water mark survives between samples, so a
 transient spike between two steps still shows if any sample lands on it.
-The owner split lands in ``monitor.mem.owner_bytes{owner=}`` /
+The owner split lands in ``monitor.mem.owner_bytes{owner=}`` (the
+``unattributed`` remainder is an owner of it) /
 ``monitor.mem.unattributed_frac`` and the per-device occupancy in
 ``monitor.mem.hbm_frac{device=}`` (+ the unlabeled ``hbm_frac_max`` the
 fleet console reads), and the whole classified snapshot rides the
@@ -100,14 +104,9 @@ def sample_memory(registry, timeline=None):
     optionally emit a ``memory`` timeline event.  Returns the snapshot."""
     snap = memory_snapshot()
     if "live_bytes" in snap:
-        registry.gauge("monitor.mem.live_bytes").set(snap["live_bytes"])
         registry.gauge("monitor.mem.live_bytes_peak").set_max(
             snap["live_bytes"])
-        registry.gauge("monitor.mem.arrays").set(snap["arrays"])
     for dev, stats in snap.get("devices", {}).items():
-        if "bytes_in_use" in stats:
-            registry.gauge("monitor.mem.device_bytes_in_use",
-                           device=dev).set(stats["bytes_in_use"])
         peak = stats.get("peak_bytes_in_use", stats.get("bytes_in_use"))
         if peak is not None:
             registry.gauge("monitor.mem.device_bytes_peak",
@@ -125,12 +124,10 @@ def sample_memory(registry, timeline=None):
         for o in _PUBLISHED_OWNERS - set(owners):
             registry.gauge("monitor.mem.owner_bytes", owner=o).set(0)
         _PUBLISHED_OWNERS.update(owners)
-        unattr = owners.get("unattributed", 0)
-        registry.gauge("monitor.mem.unattributed_bytes").set(unattr)
         total = snap.get("live_bytes") or sum(owners.values())
         if total:
             registry.gauge("monitor.mem.unattributed_frac").set(
-                round(unattr / total, 4))
+                round(owners.get("unattributed", 0) / total, 4))
     fracs = snap.get("hbm_frac")
     if fracs:
         for dev, f in fracs.items():

@@ -38,6 +38,18 @@ bytes per device — the framework-visible lower bound (flagged
 ``estimated``), which is exactly what the deterministic ``oom_step`` drill
 exercises off-TPU.
 
+The trainer path (``StepTrainer`` on ``make_train_step``, which never meets
+an executor) has the same three parts, each read when asked and never in a
+step: ``trainer_ledgers()`` (the ledgers of the programs the trainers
+dispatched, off the executables ``monitor/devscope.py`` keeps, and
+``need_bytes``: what one chip must hold to run one), ``track_state`` /
+``track_arrays`` (the owners ``params``, ``opt_state``, ``running``,
+``staged_batches``) and ``watermark()`` (the allocator's four counters on the
+fullest device, which the compile ledger's phases record as they close);
+``largest_values`` names what is large in a step by the program's scopes,
+and ``loaded_code_bytes`` the code of the loaded executables, which the
+allocator counts in ``bytes_in_use`` beside the arrays.
+
 An actual RESOURCE_EXHAUSTED (or the injected ``oom_step`` chaos fault) is
 caught at the executor dispatch and the TrainLoop and turned into a flight
 postmortem ``mem_oom`` section: the failing program's ledger, the headroom
@@ -53,10 +65,13 @@ import weakref
 __all__ = [
     "MemoryBudgetError", "InjectedOOMError",
     "configure", "reset", "refuse_enabled",
-    "register_owner", "unregister_owner", "track",
-    "attribution", "headroom", "host_accounting",
-    "min_device_bytes_limit",
+    "register_owner", "unregister_owner", "track", "track_state",
+    "track_arrays", "attribution", "headroom", "host_accounting",
+    "min_device_bytes_limit", "watermark", "WATERMARK_FIELDS",
+    "loaded_code_bytes",
     "program_ledger", "record_program", "ledgers", "model_bytes",
+    "temp_held_bytes", "need_bytes", "need_line", "trainer_ledgers",
+    "largest_values",
     "predict_dispatch",
     "is_resource_exhausted", "oom_extra", "note_oom",
 ]
@@ -122,9 +137,11 @@ def reset():
         _CONFIG["refuse"] = None
         _OWNERS.clear()
         _TRACKED[:] = []
+        _PRUNE_AT[0] = 64
         _LEDGERS.clear()
         _LEDGER_ORDER[:] = []
         _HEADROOM_SEEN.clear()
+        _PEAK_ESTIMATE.clear()
 
 
 # ------------------------------------------------------------- ownership --
@@ -136,6 +153,7 @@ _OWNERS = {}
 # instances (pipes, train loops) register here so their death needs no
 # unregister call.
 _TRACKED = []
+_PRUNE_AT = [64]        # the length at which ``track`` drops the dead
 
 
 def register_owner(name, provider):
@@ -155,9 +173,67 @@ def unregister_owner(name):
 
 def track(name, obj, extract):
     """Weakref registration: ``extract(obj)`` yields the arrays ``obj``
-    holds; the entry dies with the object."""
+    holds; the entry dies with the object (dropped at the next walk, or
+    here once the dead could be half the list: a process that registers
+    for a week and never asks must not grow)."""
     with _LOCK:
         _TRACKED.append((str(name), weakref.ref(obj), extract))
+        if len(_TRACKED) > _PRUNE_AT[0]:
+            _TRACKED[:] = [e for e in _TRACKED if e[1]() is not None]
+            _PRUNE_AT[0] = 2 * len(_TRACKED) + 64
+
+
+# the parts of a train state (``parallel.train.TrainState``: a dict of
+# ``params``, ``opt`` and, for some models, ``running``) and the owner each
+# is attributed to: what a user can act on (the model's size, the
+# optimizer's choice and its sharding, the statistics a model carries)
+STATE_OWNERS = {"params": "params", "opt": "opt_state", "running": "running"}
+
+
+def _state_part(state, key):
+    """The leaves of a train state under ``key``; under None, what no
+    owner of ``STATE_OWNERS`` names (all of a state that is no such dict)."""
+    import jax
+
+    parted = isinstance(state, dict) and "params" in state
+    if key is None:
+        rest = state if not parted else \
+            {k: v for k, v in state.items() if k not in STATE_OWNERS}
+        return jax.tree.leaves(rest)
+    return jax.tree.leaves(state.get(key)) if parted else ()
+
+
+def track_state(holder, read):
+    """Registers, weakly, the train state that ``holder`` carries:
+    ``read(holder)`` is its CURRENT state (a step donates the one before, so
+    it is read at every walk, never kept), or None.  Its ``params``, ``opt``
+    and ``running`` go to the owners ``params``, ``opt_state`` and
+    ``running``; whatever else it holds, and the whole of a state that is not
+    such a dict, to ``train_state``.  The one way a state's parts are named:
+    ``StepTrainer`` and ``TrainLoop`` both register through it."""
+    for key, owner in list(STATE_OWNERS.items()) + [(None, "train_state")]:
+        track(owner, holder,
+              lambda h, key=key: _state_part(read(h), key))
+
+
+def _itself(a):
+    return (a,)
+
+
+def track_arrays(name, tree):
+    """Registers the arrays of ``tree`` themselves, each weakly: an entry
+    goes when its array does (batches staged for ``run_steps``, which no
+    object of the program holds)."""
+    import jax
+
+    with _LOCK:
+        have = {id(ref()) for n, ref, _ in _TRACKED if n == name}
+    for a in jax.tree.leaves(tree):
+        if hasattr(a, "nbytes") and id(a) not in have:
+            try:
+                track(name, a, _itself)
+            except TypeError:
+                pass                        # a numpy array takes no weakref
 
 
 def _iter_owned():
@@ -173,12 +249,11 @@ def _iter_owned():
                 yield name, a
         except Exception:
             continue
-    dead = []
-    for entry in tracked:
-        name, ref, extract = entry
+    dead = False
+    for name, ref, extract in tracked:
         obj = ref()
         if obj is None:
-            dead.append(entry)
+            dead = True
             continue
         try:
             for a in extract(obj) or ():
@@ -187,11 +262,7 @@ def _iter_owned():
             continue
     if dead:
         with _LOCK:
-            for entry in dead:
-                try:
-                    _TRACKED.remove(entry)
-                except ValueError:
-                    pass
+            _TRACKED[:] = [e for e in _TRACKED if e[1]() is not None]
     # built-in: executor scope state (the persistables every step re-writes)
     try:
         from ..scope import global_scope
@@ -241,11 +312,28 @@ def _array_devices(a):
         return [str(dev)] if dev is not None else ["?"]
 
 
+def _device_shares(a, nb):
+    """``[(device, bytes)]`` of one live array.  Per-device footprint: a
+    REPLICATED array costs its full nbytes on every device (each holds a
+    copy); only a sharded one splits.  Getting this wrong would
+    overestimate headroom on the estimated path by exactly the
+    replicated-params factor."""
+    devs = _array_devices(a)
+    try:
+        replicated = a.sharding.is_fully_replicated
+    except Exception:
+        replicated = False
+    share = nb if replicated and len(devs) > 1 else nb / max(len(devs), 1)
+    return [(d, share) for d in devs]
+
+
 def attribution():
     """Classify ``jax.live_arrays()`` by owner: ``{"owners": {owner: bytes,
-    ..., "unattributed": bytes}, "device_live_bytes": {device: bytes},
-    "live_bytes": total, "arrays": n}``.  A sharded array's bytes split
-    evenly across its devices.  ``device_live_bytes`` feeds the headroom
+    ..., "unattributed": bytes}, "device_owners": {device: {owner: bytes}},
+    "device_live_bytes": {device: bytes}, "live_bytes": total, "arrays":
+    n}``.  A sharded array's bytes split evenly across its devices, a
+    replicated one counts whole on each, in ``device_owners`` (with its own
+    ``unattributed``) as in ``device_live_bytes``, which feeds the headroom
     estimate so one sample pays exactly one live_arrays() walk."""
     import jax
 
@@ -264,27 +352,28 @@ def attribution():
         total += nb
         owner = owner_of.get(id(a), "unattributed")
         owners[owner] = owners.get(owner, 0) + nb
-        devs = _array_devices(a)
-        # per-device footprint: a REPLICATED array costs its full nbytes
-        # on every device (each holds a copy); only a sharded one splits.
-        # Getting this wrong would overestimate headroom on the estimated
-        # path by exactly the replicated-params factor.
-        try:
-            replicated = a.sharding.is_fully_replicated
-        except Exception:
-            replicated = False
-        share = nb if replicated and len(devs) > 1 \
-            else nb / max(len(devs), 1)
-        for d in devs:
-            per_dev[d] = per_dev.get(d, 0) + share
+        for d, share in _device_shares(a, nb):
+            by_owner = per_dev.setdefault(d, {})
+            by_owner[owner] = by_owner.get(owner, 0) + share
     owners.setdefault("unattributed", 0)
     return {"owners": owners,
-            "device_live_bytes": {d: int(b) for d, b in per_dev.items()},
+            "device_owners": {d: {o: int(b) for o, b in by.items()}
+                              for d, by in per_dev.items()},
+            "device_live_bytes": {d: int(sum(by.values()))
+                                  for d, by in per_dev.items()},
             "live_bytes": total, "arrays": n}
 
 
 def _live_bytes_per_device():
-    return attribution()["device_live_bytes"]
+    """Summed live-array bytes a device, nobody's owner asked."""
+    import jax
+
+    per_dev = {}
+    for a in jax.live_arrays():
+        nb = int(getattr(a, "nbytes", 0) or 0)
+        for d, share in _device_shares(a, nb) if nb else ():
+            per_dev[d] = per_dev.get(d, 0) + share
+    return {d: int(b) for d, b in per_dev.items()}
 
 
 # -------------------------------------------------------------- headroom --
@@ -362,6 +451,75 @@ def min_device_bytes_limit(fallback=None):
     return fallback
 
 
+def loaded_code_bytes():
+    """``{device: bytes}``: the generated code of every executable the
+    process has loaded, by the device it is loaded on.  The allocator's
+    ``bytes_in_use`` holds it beside the arrays (seen on the v5e: a train
+    step's 0.05 to 0.17 GB, the float32 programs of a reference check 0.05),
+    so an account of ``bytes_in_use`` by owner needs it; a backend that says
+    nothing of an executable's size (the CPU) gives 0."""
+    import jax
+
+    out = {}
+    for client in {d.client for d in jax.local_devices()}:
+        for ex in client.live_executables():
+            try:
+                size = int(ex.size_of_generated_code_in_bytes())
+                devices = [str(d) for d in ex.local_devices()]
+            except Exception:
+                continue
+            for d in devices:
+                out[d] = out.get(d, 0) + size
+    return out
+
+
+# the allocator's four counters a watermark holds
+WATERMARK_FIELDS = ("bytes_in_use", "peak_bytes_in_use", "bytes_reserved",
+                    "peak_bytes_reserved")
+_PEAK_ESTIMATE = {}     # device -> the largest live-array estimate so far
+
+
+def watermark(devices=None):
+    """``WATERMARK_FIELDS`` and ``device`` of the FULLEST of ``devices``
+    (default: the local ones) by its two peaks together, straight from
+    ``memory_stats()``: one call a device.  Where the backend keeps no such
+    counters (the CPU) the summed live-array bytes stand for
+    ``bytes_in_use``, their largest so far for its peak, 0 for the
+    reserved pair, and ``estimated`` is True.  None where nothing can be
+    said.  Never called from a step: the compile ledger's phases take one as
+    they close (``CompileLedger.phase``), and a reader after a run."""
+    import jax
+
+    devices = list(devices) if devices is not None else jax.local_devices()
+    marks = []
+    for d in devices:
+        try:
+            stats = d.memory_stats() or {}
+        except Exception:
+            stats = {}
+        if "bytes_in_use" in stats:
+            marks.append(dict({f: int(stats.get(f, 0))
+                               for f in WATERMARK_FIELDS}, device=str(d)))
+    if not marks and devices:
+        try:
+            live = _live_bytes_per_device()
+        except Exception:
+            return None
+        for d in map(str, devices):
+            in_use = live.get(d, 0)
+            with _LOCK:
+                peak = _PEAK_ESTIMATE[d] = max(_PEAK_ESTIMATE.get(d, 0),
+                                               in_use)
+            marks.append({"bytes_in_use": in_use, "peak_bytes_in_use": peak,
+                          "bytes_reserved": 0, "peak_bytes_reserved": 0,
+                          "device": d, "estimated": True})
+    return max(marks, key=_two_peaks, default=None)
+
+
+def _two_peaks(mark):
+    return mark["peak_bytes_in_use"] + mark["peak_bytes_reserved"]
+
+
 # -------------------------------------------------- host-side accounting --
 
 def host_accounting():
@@ -415,8 +573,10 @@ _LEDGER_FIELDS = ("argument_bytes", "output_bytes", "temp_bytes",
 
 def program_ledger(compiled):
     """``Compiled.memory_analysis()`` as a plain dict, or None when the
-    backend cannot say.  Accepts the executor's warm wrapper (unwraps its
-    ``.compiled``)."""
+    backend cannot say: the five ``_LEDGER_FIELDS`` as the backend counts
+    them and, where it gives one, ``peak_bytes`` (``peak_memory_in_bytes``;
+    ``temp_held_bytes`` says what it is for).  Accepts the executor's warm
+    wrapper (unwraps its ``.compiled``)."""
     compiled = getattr(compiled, "compiled", compiled)
     try:
         ma = compiled.memory_analysis()
@@ -445,6 +605,8 @@ def program_ledger(compiled):
            "alias_bytes": field("alias_size")}
     if all(v is None for v in led.values()):
         return None
+    # the buffer assignment's total, where the backend says (0: it does not)
+    led["peak_bytes"] = field("peak_memory") or None
     return {k: v for k, v in led.items() if v is not None}
 
 
@@ -463,24 +625,83 @@ def model_bytes(ledger):
     o = ledger.get("output_bytes")
     if t is None and o is None:
         return None
-    return int(t or 0) + int(o or 0)
+    return temp_held_bytes(ledger) + int(o or 0)
 
 
-def record_program(mon, ident, compiled, source="compile"):
-    """The compiled-program memory ledger hook (executor: cold compile /
-    process-cache adoption / warm disk hit).  Gauges
-    ``monitor.mem.program.*{program=ident}`` + one ``mem_program`` timeline
-    event carrying ``source``.  Returns the ledger (also kept process-wide
-    for the headroom predictor and the OOM postmortem)."""
-    led = program_ledger(compiled)
-    if led is None:
-        try:
-            mon.registry.counter("monitor.mem.program.unavailable").incr()
-            mon.timeline.emit("mem_program", ident=ident, source=source,
-                              available=False)
-        except Exception:
-            pass
+def _io_bytes(ledger):
+    """Arguments and outputs, a donated pair once."""
+    return (int(ledger.get("argument_bytes") or 0)
+            + int(ledger.get("output_bytes") or 0)
+            - int(ledger.get("alias_bytes") or 0))
+
+
+def temp_held_bytes(ledger):
+    """The temporaries the program holds beside its arguments and outputs,
+    by the buffer assignment.  ``temp_bytes`` is that on the CPU.  The TPU's
+    is not: its count holds AGAIN what the program's loops carry of the
+    arguments (the stacked weights that a scan over layers hands from trip
+    to trip, the state a scan over steps carries), so a donated train step's
+    reads near the program's whole footprint (seen on steps compiled for a
+    described v5e: 10.09 GB where the assignment's report holds 6.46 of
+    temporaries beside 9.60 of state).  There ``peak_bytes`` is the
+    assignment's total, the ``Total bytes used`` of the compiler's
+    memory-usage report to the byte, and the temporaries are what it holds
+    beyond arguments and outputs.  ``peak_bytes`` is believed only where the
+    two counts can both be true: it takes off ``temp_bytes`` no more than the
+    arguments and a sixty-fourth (the CPU's ``peak_memory_in_bytes`` is another quantity, under
+    its arguments' own bytes, and fails this)."""
+    raw = int(ledger.get("temp_bytes") or 0)
+    if ledger.get("peak_bytes"):
+        held = int(ledger["peak_bytes"]) - _io_bytes(ledger)
+        least = raw - int(ledger.get("argument_bytes") or 0) - raw // 64
+        if least <= held <= raw:        # raw // 64: the loops' own counters
+            return held
+    return raw
+
+
+def need_bytes(ledger):
+    """What one chip must hold to run the program once: argument + output
+    - alias + temp + generated code, ``temp`` the buffer assignment's
+    (``temp_held_bytes``).  Donation is why alias comes off: an output that
+    takes a donated argument's buffer is counted once.  The one definition:
+    the chip's account (``trainer_ledgers``) and the described chip's count
+    (``scripts/step_memory_count.py``) both print it through
+    ``need_line``."""
+    if not ledger:
         return None
+    return (_io_bytes(ledger) + temp_held_bytes(ledger)
+            + int(ledger.get("generated_code_bytes") or 0))
+
+
+def need_line(label, ledger):
+    """The one line that says what a program needs, in GB (1e9 bytes)."""
+    gb = {k: (ledger.get(k) or 0) / 1e9 for k in _LEDGER_FIELDS}
+    held = temp_held_bytes(ledger)
+    return ("need: %s argument %.6f + output %.6f - alias %.6f + temp %.6f "
+            "+ generated code %.6f = %.6f GB%s"
+            % (label, gb["argument_bytes"], gb["output_bytes"],
+               gb["alias_bytes"], held / 1e9, gb["generated_code_bytes"],
+               need_bytes(ledger) / 1e9,
+               "" if held == (ledger.get("temp_bytes") or 0) else
+               " (temp by the buffer assignment's total %.6f; "
+               "memory_analysis() counts %.6f, with what the loops carry "
+               "of the arguments)" % (ledger["peak_bytes"] / 1e9,
+                                      gb["temp_bytes"])))
+
+
+def _publish(registry, ident, led):
+    """The ledger's gauges, one set a program."""
+    for k in _LEDGER_FIELDS:
+        if led.get(k) is not None:
+            registry.gauge("monitor.mem.program.%s" % k,
+                           program=ident).set(led[k])
+    registry.gauge("monitor.mem.program.need_bytes",
+                   program=ident).set(need_bytes(led))
+
+
+def _remember(ident, led):
+    """Keeps the ledger process-wide (the headroom predictor, the OOM
+    postmortem, ``ledgers()``)."""
     with _LOCK:
         prev = _LEDGERS.get(ident)
         if prev is None:
@@ -492,11 +713,73 @@ def record_program(mon, ident, compiled, source="compile"):
             # predictor re-runs against the bigger ledger instead of
             # resting on the old verdict
             _HEADROOM_SEEN.discard(ident)
+
+
+def trainer_ledgers():
+    """``{label: ledger}`` of the programs the trainers dispatched
+    (``<label>.step``, ``<label>.run_steps``: ``monitor/devscope.py``'s
+    registry), a device's share each, by the same ``program_ledger`` as the
+    executor path, kept with that path's ledgers and mirrored into its
+    ``monitor.mem.program.*{program=}`` gauges (the active session's
+    registry, else the default one).  The executables are devscope's, fetched
+    once whoever asks first; a program the backend says nothing of is left
+    out.  For after a run or at a set-up phase, never for a step."""
+    from . import devscope, session
+    from .registry import default_registry
+
+    mon = session.active()
+    registry = mon.registry if mon is not None else default_registry()
+    out = {}
+    for label, compiled in devscope.executables().items():
+        led = program_ledger(compiled)
+        if led is None:
+            continue
+        out[label] = led
+        _remember(label, led)
+        try:
+            _publish(registry, label, led)
+        except Exception:
+            pass
+    return out
+
+
+def largest_values(label, n=10):
+    """The ``n`` largest values of the trainer program ``label`` by its
+    compiled text (``devscope.value_sizes``: the entry computation's results
+    and what its loops carry), each ``{"bytes", "shape", "instruction",
+    "phase", "scope", "op_name"}`` with ``devscope.classify``'s reading of
+    its ``op_name``.  No liveness, so no peak: the candidates for one."""
+    from . import devscope
+
+    compiled = devscope.executables().get(label)
+    if compiled is None:
+        return []
+    out = []
+    for size, shape, name, op_name in \
+            devscope.value_sizes(compiled.as_text())[:n]:
+        phase, scope = devscope.classify(op_name) if op_name else (None, None)
+        out.append({"bytes": size, "shape": shape, "instruction": name,
+                    "phase": phase, "scope": scope, "op_name": op_name})
+    return out
+
+
+def record_program(mon, ident, compiled, source="compile"):
+    """The compiled-program memory ledger hook (executor: cold compile /
+    process-cache adoption / warm disk hit).  Gauges
+    ``monitor.mem.program.*{program=ident}`` + one ``mem_program`` timeline
+    event carrying ``source``.  Returns the ledger (also kept process-wide
+    for the headroom predictor and the OOM postmortem)."""
+    led = program_ledger(compiled)
+    if led is None:
+        try:
+            mon.timeline.emit("mem_program", ident=ident, source=source,
+                              available=False)
+        except Exception:
+            pass
+        return None
+    _remember(ident, led)
     try:
-        for k in _LEDGER_FIELDS:
-            if led.get(k) is not None:
-                mon.registry.gauge("monitor.mem.program.%s" % k,
-                                   program=ident).set(led[k])
+        _publish(mon.registry, ident, led)
         mon.timeline.emit("mem_program", ident=ident, source=source,
                           available=True, **led)
     except Exception:

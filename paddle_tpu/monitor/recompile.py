@@ -26,7 +26,7 @@ import threading
 import time
 import warnings
 
-from . import trace as _trace
+from . import memscope as _memscope, trace as _trace
 from .registry import default_registry
 
 __all__ = ["RecompileDetector", "RecompileStorm", "CompileLedger",
@@ -212,7 +212,11 @@ class CompileLedger:
       also says whether the persistent cache served the program
       (``cached``) and, if so, the compile seconds that saved (``saved_s``);
     - ``kind`` ``phase``: a closed ``phase(name, **labels)``, with its
-      ``labels``.
+      ``labels`` and, under ``memory``, the device memory's watermark as it
+      closed (``memscope.watermark``: ``bytes_in_use``, ``peak_bytes_in_use``,
+      ``bytes_reserved``, ``peak_bytes_reserved`` of the fullest local
+      device, ``estimated`` where the backend keeps no counters).  The peaks
+      only rise, so two records say which stretch raised one.
 
     ``compile_ledger()`` is the process's one; a test makes its own and
     feeds it events by hand."""
@@ -280,7 +284,8 @@ class CompileLedger:
     def phase(self, name, **labels):
         """Marks a stretch of set-up on this thread; yields ``labels``, which
         the caller may add to until the phase closes.  Two clock reads, one
-        record; a monitor session's trace shows it as ``setup.<name>``."""
+        record and, after the second read, one ``memory_stats()`` a local
+        device; a monitor session's trace shows it as ``setup.<name>``."""
         st = self._state()
         parent = st.stack[-1][0] if st.stack else None
         frame = [name, None]
@@ -294,9 +299,16 @@ class CompileLedger:
             st.stack.pop()
             if name == FIRST_CALL and frame[1] is not None:
                 self._first_called.add(frame[1])
-            self._append({"kind": "phase", "name": name, "t0": t0, "t1": t1,
-                          "thread": threading.current_thread().name,
-                          "parent": parent, "labels": labels})
+            record = {"kind": "phase", "name": name, "t0": t0, "t1": t1,
+                      "thread": threading.current_thread().name,
+                      "parent": parent, "labels": labels}
+            try:
+                mark = _memscope.watermark()
+            except Exception:
+                mark = None
+            if mark is not None:
+                record["memory"] = mark
+            self._append(record)
             self.registry.histogram("monitor.setup.phase_ms",
                                     phase=name).observe((t1 - t0) * 1e3)
 

@@ -231,6 +231,11 @@ class StepTrainer:
     # which of the two programs monitor.devscope has been told of
     _step_seen = _multi_seen = False
 
+    def __post_init__(self):
+        # MemScope owners (weakly; the state is donated every step, so the
+        # walk reads the trainer's current one): params, opt_state, running
+        _memscope.track_state(self, lambda tr: tr.state)
+
     def _observe(self, batch):
         """``batch`` as ``step`` or ``run_steps`` got it (the latter's with a
         leading step axis)."""
@@ -260,6 +265,9 @@ class StepTrainer:
             with compile_ledger().phase(FIRST_CALL, program=program):
                 self._multi_seen = _devscope.register(
                     program, self.multi_fn, (self.state, batches, lr))
+                # whoever staged them (``stack_batches``, or a caller's own
+                # program on the device): what a scan runs over is staged
+                _memscope.track_arrays("staged_batches", batches)
                 self.state, losses = self.multi_fn(self.state, batches, lr)
             return losses
         self.state, losses = self.multi_fn(self.state, batches, lr)
@@ -376,12 +384,10 @@ class TrainLoop:
         self._state = None
         self.last_aux = None
         self.resumed_step = 0
-        # MemScope owner registration (weakref — dies with the loop): the
-        # params + optimizer slots this loop carries classify as
-        # "train_state" in the live-buffer attribution
-        _memscope.track("train_state", self,
-                        lambda lp: (jax.tree.leaves(lp._state)
-                                    if lp._state is not None else ()))
+        # MemScope owner registration (weakref — dies with the loop): a
+        # TrainState's parts classify as the trainers' do (params,
+        # opt_state, running), any other pytree whole as "train_state"
+        _memscope.track_state(self, lambda lp: lp._state)
 
     def _current_state(self):
         return self._state
@@ -462,4 +468,6 @@ def stack_batches(mesh, batch_specs, batches):
         specs = jax.tree.map(lambda s: P(None, *tuple(s)), batch_specs,
                              is_leaf=lambda x: isinstance(x, P))
         labels["bytes"] = _tree_bytes(stacked)
-        return shard_pytree(stacked, specs, mesh)
+        staged = shard_pytree(stacked, specs, mesh)
+        _memscope.track_arrays("staged_batches", staged)
+        return staged

@@ -8,7 +8,9 @@ device, the kernels' ``_on_tpu`` patched True in THIS process, and
 ``run_steps`` over the cell's staged batches is compiled for it with
 ``--xla_dump_to`` set: the dump's memory-usage report is the count PERF.md
 section 4 gives for every decoder cell (PR 37's recipe), printed beside
-``memory_analysis()`` and the largest buffers of the report.  ``key=value``
+``memory_analysis()``, the program's NEED by it (``memscope.need_line``:
+the line a chip run's memory account prints for the same program, one
+definition) and the largest buffers of the report.  ``key=value``
 overrides the configuration factory's arguments (``n_layers=28``).  A
 compile that passes is not a chip run: what the chip reserves is read off
 the cell's own run (``peak_hbm_gb`` and the line's ``memory`` fields)."""
@@ -37,6 +39,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from benchmark.harness import build, manifest as mf  # noqa: E402
 from paddle_tpu import kernels  # noqa: E402
+from paddle_tpu.monitor import memscope  # noqa: E402
 from paddle_tpu.parallel import decoder, optim, transformer as T  # noqa: E402
 from paddle_tpu.parallel.mesh import DP, MeshSpec  # noqa: E402
 from paddle_tpu.parallel.train import (TrainState, make_train_step,  # noqa: E402
@@ -86,6 +89,8 @@ def main(cell, *overrides):
                                  for a in jax.tree.leaves(state)) / 1e9))
     compiled = multi.lower(state, batches, 1e-5).compile()
     print("memory_analysis():", compiled.memory_analysis())
+    print(memscope.need_line(entry["config"] + ".run_steps (described v5e)",
+                             memscope.program_ledger(compiled)))
     reports = sorted(glob.glob(os.path.join(DUMP, "*memory-usage-report*")),
                      key=os.path.getsize)
     if not reports:

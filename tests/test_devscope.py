@@ -236,6 +236,111 @@ def test_a_dead_trainer_leaves_the_registry():
     del keep
 
 
+def test_the_executable_is_fetched_once_whoever_asks_first():
+    """``scope_maps()``, then the memory ledgers, then what is large: three
+    readers, ONE ``lower().compile()`` a program, by the compile ledger's
+    records (a lowering's trace is a record, a compile a ``backend`` one)."""
+    import time
+
+    from paddle_tpu.monitor import memscope
+    from paddle_tpu.monitor.recompile import compile_ledger
+
+    led = compile_ledger()
+    tr, staged = _run_bert()
+    tr.step(_bert_batch(np.random.RandomState(1)), 1e-3)
+    t0 = time.perf_counter()
+    maps = devscope.scope_maps()
+    t1 = time.perf_counter()
+    first = led.between(t0, t1)
+    assert sum(1 for r in first if r["kind"] == "backend") <= len(maps) == 2
+    ledgers = memscope.trainer_ledgers()
+    largest = memscope.largest_values("bert.run_steps", 3)
+    again = devscope.scope_maps()
+    assert led.between(t1, time.perf_counter()) == []      # nothing asked
+    assert sorted(ledgers) == sorted(maps) == sorted(again)
+    assert len(largest) == 3
+    kept = {id(c) for c in devscope.executables().values()}
+    assert kept == {id(p[3]) for p in devscope._programs}
+    # the kept executable does not keep its trainer
+    del tr, staged
+    gc.collect()
+    assert devscope.executables() == {} and devscope._programs == []
+
+
+def test_value_sizes_names_the_layer_scan_s_stacked_residual():
+    """A two-layer scanned toy: the forward loop carries what it stacks for
+    the backward pass, [2, ...] a value, under ``layer_scan``; the weights it
+    only hands on are not its values."""
+    import jax.numpy as jnp
+
+    def loss(w, x):
+        def layer(h, wl):
+            return jnp.tanh(h @ wl), None
+
+        with jax.named_scope(devscope.LAYER_SCAN):
+            h, _ = jax.lax.scan(layer, x, w)
+        return (h * h).sum()
+
+    w = jnp.ones((2, 64, 64), jnp.float32) * 0.01
+    x = jnp.ones((128, 64), jnp.float32)
+    text = jax.jit(jax.grad(loss)).lower(w, x).compile().as_text()
+    values = devscope.value_sizes(text)
+    assert values == sorted(values, key=lambda v: (-v[0], v[2]))
+    carried = [v for v in values if "[" in v[2]]         # <while>[<index>]
+    stacked = [v for v in carried if v[1].startswith("f32[2,128,64]")]
+    assert stacked and stacked[0][0] == 2 * 128 * 64 * 4
+    assert devscope.classify(stacked[0][3]) == ("forward", "layer_scan")
+    # the stacked weights go through both loops unchanged: nobody's value
+    forward = stacked[0][2].split("[")[0]
+    assert not [v for v in carried if v[2].startswith(forward + "[")
+                and v[1].startswith("f32[2,64,64]")]
+    # the entry's own results are there, parameters and tuples are not
+    entry = [v for v in values if "[" not in v[2]]
+    assert entry and all(v[0] > 0 for v in entry)
+    assert not [v for v in entry if v[2].startswith(("Arg_", "tuple"))]
+
+
+def test_value_sizes_on_literal_text():
+    text = """HloModule m
+
+%body (p: (s32[], bf16[4,8]{1,0}, f32[2,4,8]{2,1,0})) -> (s32[], bf16[4,8]{1,0}, f32[2,4,8]{2,1,0}) {
+  %p = (s32[], bf16[4,8]{1,0}, /*index=2*/f32[2,4,8]{2,1,0}) parameter(0)
+  %i = s32[] get-tuple-element(%p), index=0
+  %w = bf16[4,8]{1,0} get-tuple-element(%p), index=1
+  %acc = f32[2,4,8]{2,1,0} get-tuple-element(%p), index=2
+  %one = s32[] constant(1)
+  %next = s32[] add(%i, %one)
+  %upd = f32[2,4,8]{2,1,0} fusion(%acc, %w, %i), kind=kLoop, calls=%f, metadata={op_name="jit(f)/layer_scan/while/body/dynamic_update_slice"}
+  ROOT %out = (s32[], bf16[4,8]{1,0}, f32[2,4,8]{2,1,0}) tuple(%next, %w, %upd)
+}
+
+ENTRY %main (a: bf16[4,8], b: f32[2,4,8]) -> f32[2,4,8] {
+  %a = bf16[4,8]{1,0} parameter(0)
+  %b = f32[2,4,8]{2,1,0:T(8,128)} parameter(1)
+  %zero = s32[] constant(0)
+  %init = (s32[], bf16[4,8]{1,0}, f32[2,4,8]{2,1,0}) tuple(%zero, %a, %b)
+  %loop = (s32[], bf16[4,8]{1,0}, /*index=2*/f32[2,4,8]{2,1,0}) while(%init), condition=%cond, body=%body, metadata={op_name="jit(f)/layer_scan/while"}
+  %res = f32[2,4,8]{2,1,0} get-tuple-element(%loop), index=2
+  ROOT %neg = f32[2,4,8]{2,1,0:T(8,128)} negate(%res), metadata={op_name="jit(f)/mlp/neg"}
+}
+"""
+    # of the entry's loop: [1] is handed on, [2] starts from a parameter (an
+    # argument the loop carries); its body is the step's own level
+    assert devscope.value_sizes(text) == [
+        (256, "f32[2,4,8]", "neg", "jit(f)/mlp/neg"),
+        (256, "f32[2,4,8]", "upd",
+         "jit(f)/layer_scan/while/body/dynamic_update_slice"),
+        (4, "s32[]", "loop[0]", "jit(f)/layer_scan/while"),
+        (4, "s32[]", "next", None)]      # its first user is the ROOT tuple
+    # the same loop fed a value the program made: the accumulator is its own
+    made = text.replace("tuple(%zero, %a, %b)", "tuple(%zero, %a, %acc0)") \
+        .replace("  %zero = s32[] constant(0)\n",
+                 "  %zero = s32[] constant(0)\n  %acc0 = f32[2,4,8]{2,1,0} "
+                 "broadcast(%zero), dimensions={}\n")
+    assert (256, "f32[2,4,8]", "loop[2]", "jit(f)/layer_scan/while") \
+        in devscope.value_sizes(made)
+
+
 def test_a_warm_callable_is_not_registered():
     class NoLower:                            # warm.WarmCallable's surface
         def __call__(self, *args):

@@ -111,8 +111,7 @@ def test_owner_attribution_classifies_live_arrays():
     assert snap["owners"]["ballast"] == bb
     rows = _gauge_rows("monitor.mem.owner_bytes")
     assert rows[(("owner", "ballast"),)] == bb
-    assert reg.gauge("monitor.mem.unattributed_bytes").value \
-        >= anon.nbytes
+    assert rows[(("owner", "unattributed"),)] >= anon.nbytes
     # host-side accounting: process RSS is always known on linux
     assert snap.get("host", {}).get("rss_bytes", 0) > 0
     # an owner that disappears reads 0 on the next sample, never stale
@@ -473,3 +472,289 @@ def test_memory_snapshot_still_best_effort_without_owners():
     # owners section present with everything filed (scope empty here) —
     # the unattributed remainder is explicit, never silently dropped
     assert "unattributed" in snap.get("owners", {"unattributed": 0})
+
+
+# -- the trainer path: owners, program ledgers, phase watermarks -----------
+
+def _tiny_trainer(model):
+    """A trainer of tiny BERT, of a tiny decoder or of tiny ResNet with its
+    staged batches and one batch, on one device."""
+    import jax
+
+    from paddle_tpu.models import bert, olmoe, resnet
+    from paddle_tpu.parallel import decoder, optim
+    from paddle_tpu.parallel.mesh import MeshSpec
+    from paddle_tpu.parallel.train import stack_batches
+
+    rng = np.random.RandomState(0)
+    one = jax.devices()[:1]
+    if model == "bert":
+        tr = bert.build_bert_trainer(bert.bert_tiny_config(), MeshSpec(dp=1),
+                                     devices=one)
+        specs = bert.batch_specs()
+
+        def batch():
+            return {"ids": rng.randint(0, 128, (4, 32)).astype("int32"),
+                    "labels": rng.randint(0, 128, (4, 32)).astype("int32"),
+                    "mask": (rng.rand(4, 32) < 0.3).astype("float32")}
+    elif model == "olmoe":
+        tr = olmoe.build_olmoe_trainer(olmoe.olmoe_tiny_config(),
+                                       MeshSpec(dp=1), optimizer=optim.adamw(),
+                                       devices=one)
+        specs = decoder.BATCH_SPECS
+
+        def batch():
+            return {"ids": rng.randint(0, 256, (2, 32)).astype("int32")}
+    else:
+        tr = resnet.build_resnet_trainer(
+            resnet.resnet_tiny_config(), MeshSpec(dp=1),
+            optimizer=optim.momentum(0.9), devices=one)
+        specs = resnet.BATCH_SPECS
+
+        def batch():
+            return {"image": rng.rand(4, 32, 32, 3).astype("float32"),
+                    "label": rng.randint(0, 10, (4,)).astype("int32")}
+    staged = stack_batches(tr.mesh, specs, [batch(), batch()])
+    return tr, staged, batch
+
+
+def _tree_nbytes(tree):
+    import jax
+
+    return sum(int(a.nbytes) for a in jax.tree.leaves(tree))
+
+
+@pytest.mark.parametrize("model", ["bert", "olmoe", "resnet"])
+def test_trainer_owners_cover_the_live_bytes_under_donation(model):
+    """Two ``step``s and one ``run_steps`` donate the state three times: the
+    owners read the trainer's CURRENT state, name its parts, cover the live
+    bytes, and a dropped trainer leaves no owner behind."""
+    import gc
+
+    gc.collect()
+    before = memscope.attribution()["live_bytes"]
+    tr, staged, batch = _tiny_trainer(model)
+    float(tr.step(batch(), 1e-3))
+    float(tr.step(batch(), 1e-3))
+    losses = np.asarray(tr.run_steps(staged, 1e-3))
+    assert np.isfinite(losses).all()
+    del losses
+    gc.collect()
+    attr = memscope.attribution()
+    owners = attr["owners"]
+    assert owners["params"] == _tree_nbytes(tr.state["params"])
+    assert owners["opt_state"] == _tree_nbytes(tr.state["opt"])
+    assert owners["staged_batches"] == _tree_nbytes(staged)
+    if model == "resnet":
+        assert owners["running"] == _tree_nbytes(tr.state["running"]) > 0
+    else:
+        assert "running" not in owners and "train_state" not in owners
+    ours = attr["live_bytes"] - before
+    owned = sum(owners.get(o, 0) for o in (
+        "params", "opt_state", "running", "staged_batches"))
+    assert owned >= 0.95 * ours
+    # one device: the per-device split is the whole
+    (device, by_owner), = [(d, o) for d, o in attr["device_owners"].items()
+                           if o.get("params")]
+    assert by_owner["params"] == owners["params"]
+    assert by_owner["opt_state"] == owners["opt_state"]
+    del tr, staged
+    gc.collect()
+    after = memscope.attribution()["owners"]
+    assert not {"params", "opt_state", "running", "staged_batches"} & {
+        o for o, b in after.items() if b}
+    assert not [e for e in memscope._TRACKED if e[1]() is not None
+                and e[0] in ("params", "opt_state", "staged_batches")]
+
+
+def test_a_replicated_state_counts_whole_on_every_device():
+    import jax
+
+    from paddle_tpu.models import bert
+    from paddle_tpu.parallel.mesh import MeshSpec
+
+    tr = bert.build_bert_trainer(bert.bert_tiny_config(), MeshSpec(dp=2),
+                                 devices=jax.devices()[:2])
+    attr = memscope.attribution()
+    params = _tree_nbytes(tr.state["params"])
+    held = [o["params"] for o in attr["device_owners"].values()
+            if o.get("params")]
+    assert held == [params, params] and attr["owners"]["params"] == params
+
+
+def test_train_loop_names_a_state_s_parts_as_the_trainers_do():
+    import jax.numpy as jnp
+
+    from paddle_tpu.parallel.train import TrainLoop
+
+    state = {"params": {"w": jnp.ones((8, 8))},
+             "opt": {"m": jnp.ones((8, 8)), "t": jnp.zeros(())}}
+    loop = TrainLoop(lambda s, b: (s, b))
+    loop.run(state, [jnp.ones(())])
+    owners = memscope.attribution()["owners"]
+    assert owners["params"] == 256 and owners["opt_state"] == 260
+    # a state that is no such dict is one owner's, whole
+    other = TrainLoop(lambda s, b: (s, b))
+    other.run([jnp.ones((4, 4))], [jnp.ones(())])
+    assert memscope.attribution()["owners"]["train_state"] == 64
+    del loop, other
+
+
+def test_tracked_entries_do_not_pile_up_unasked():
+    import jax.numpy as jnp
+
+    for _ in range(400):
+        memscope.track_arrays("staged_batches", {"x": jnp.ones((2,))})
+    assert len(memscope._TRACKED) < 200
+    keep = {"x": jnp.ones((2,))}
+    memscope.track_arrays("staged_batches", keep)
+    n = len(memscope._TRACKED)
+    memscope.track_arrays("staged_batches", keep)      # the same arrays: once
+    assert len(memscope._TRACKED) == n
+
+
+@pytest.mark.parametrize("model", ["bert", "olmoe"])
+def test_trainer_ledgers_hold_both_programs_and_their_need(model):
+    from paddle_tpu.monitor import devscope
+
+    saved, devscope._programs[:] = devscope._programs[:], []
+    try:
+        tr, staged, batch = _tiny_trainer(model)
+        tr.step(batch(), 1e-3)
+        tr.run_steps(staged, 1e-3)
+        ledgers = memscope.trainer_ledgers()
+        assert sorted(ledgers) == [tr.label + ".run_steps", tr.label + ".step"]
+        for label, led in ledgers.items():
+            five = {"argument_bytes", "output_bytes", "alias_bytes",
+                    "temp_bytes", "generated_code_bytes"}
+            assert five <= set(led) <= five | {"peak_bytes"}
+            # the CPU's temp_bytes IS the assignment's (its peak_bytes is
+            # another quantity, and is not believed)
+            assert memscope.temp_held_bytes(led) == led["temp_bytes"]
+            assert memscope.need_bytes(led) == (
+                led["argument_bytes"] + led["output_bytes"]
+                - led["alias_bytes"] + led["temp_bytes"]
+                + led["generated_code_bytes"])
+            # the donated state is counted once: the need holds the state
+            # and the temporaries, not the state twice
+            state = _tree_nbytes(tr.state)
+            assert led["alias_bytes"] >= 0.99 * state
+            assert state + led["temp_bytes"] <= memscope.need_bytes(led) \
+                < 2 * state + led["temp_bytes"]
+            assert label in memscope.need_line(label, led)
+            # mirrored where the executor path's ledgers go
+            rows = _gauge_rows("monitor.mem.program.temp_bytes")
+            assert rows[(("program", label),)] == led["temp_bytes"]
+            assert _gauge_rows("monitor.mem.program.need_bytes")[
+                (("program", label),)] == memscope.need_bytes(led)
+        assert dict(memscope.ledgers())[tr.label + ".step"] == \
+            ledgers[tr.label + ".step"]
+    finally:
+        devscope._programs[:] = saved
+
+
+@pytest.mark.parametrize("ledger, held, need", [
+    # steps compiled for a described v5e (the compiler's memory-usage
+    # report beside memory_analysis(), PR 50): a donated SGD step over a
+    # scan of 8 layers, whose temp_bytes reads the whole footprint
+    ({"argument_bytes": 1207959552, "output_bytes": 1073741824,
+      "alias_bytes": 1073741824, "temp_bytes": 5771490816,
+      "generated_code_bytes": 1423872, "peak_bytes": 5771367424},
+     4563407872, 5772791296),
+    # the same step not donated: the arguments once too many
+    ({"argument_bytes": 1207959552, "output_bytes": 1073741824,
+      "alias_bytes": 0, "temp_bytes": 4697748992,
+      "generated_code_bytes": 1420288, "peak_bytes": 5771367424},
+     3489666048, 5772787712),
+    # jamba2_3b.s8192_scan's run_steps: 10.09 GB counted, 6.46 held
+    ({"argument_bytes": 9599200256, "output_bytes": 9599135232,
+      "alias_bytes": 9599134208, "temp_bytes": 10085458944,
+      "generated_code_bytes": 60462592, "peak_bytes": 16057676948},
+     6458475668, 16118139540),
+    # no loop: the two counts agree
+    ({"argument_bytes": 671088640, "output_bytes": 134217728,
+      "alias_bytes": 0, "temp_bytes": 268435456,
+      "generated_code_bytes": 1918976, "peak_bytes": 1073741824},
+     268435456, 1075660800),
+    # the CPU: a peak under the arguments' own bytes is not believed
+    ({"argument_bytes": 2359296, "output_bytes": 2097152,
+      "alias_bytes": 2097152, "temp_bytes": 9437380,
+      "generated_code_bytes": 0, "peak_bytes": 2359320},
+     9437380, 11796676),
+    # a backend with no peak at all
+    ({"argument_bytes": 100, "output_bytes": 40, "alias_bytes": 40,
+      "temp_bytes": 60, "generated_code_bytes": 5}, 60, 165),
+])
+def test_need_takes_the_buffer_assignment_s_temporaries(ledger, held, need):
+    assert memscope.temp_held_bytes(ledger) == held
+    assert memscope.need_bytes(ledger) == need
+    line = memscope.need_line("toy.step", ledger)
+    assert line.startswith("need: toy.step argument ")
+    assert ("memory_analysis() counts" in line) == (
+        held != ledger["temp_bytes"])
+    # the predictor's dispatch-time requirement takes the same temporaries
+    assert memscope.model_bytes(ledger) == held + ledger["output_bytes"]
+
+
+def test_phase_records_carry_the_watermark_on_the_spans_clock():
+    import time
+
+    from paddle_tpu.monitor.recompile import compile_ledger
+
+    led = compile_ledger()
+    t0 = time.perf_counter()
+    tr, staged, batch = _tiny_trainer("bert")
+    tr.step(batch(), 1e-3)
+    tr.run_steps(staged, 1e-3)
+    t1 = time.perf_counter()
+    phases = [r for r in led.between(t0, t1) if r["kind"] == "phase"]
+    assert {"init_params", "init_opt_state", "place", "stage_batches",
+            "first_call"} <= {r["name"] for r in phases}
+    assert [r["t1"] for r in phases] == sorted(r["t1"] for r in phases)
+    for r in phases:
+        mark = r["memory"]
+        assert set(memscope.WATERMARK_FIELDS) <= set(mark)
+        assert mark["estimated"] is True and "CPU" in mark["device"].upper()
+        assert mark["peak_bytes_in_use"] >= mark["bytes_in_use"] > 0
+    # the estimate's peak only rises, and the state is on the device before
+    # the first call closes
+    peaks = [r["memory"]["peak_bytes_in_use"] for r in phases]
+    assert peaks == sorted(peaks)
+    first_call = [r for r in phases if r["name"] == "first_call"][-1]
+    assert first_call["memory"]["bytes_in_use"] >= _tree_nbytes(tr.state)
+    # the same function after a run, on the devices a reader names
+    import jax
+
+    mark = memscope.watermark(jax.devices()[:1])
+    assert mark["estimated"] and mark["device"] == str(jax.devices()[0])
+    assert memscope.watermark([]) is None
+
+
+def test_loaded_code_bytes_sums_the_live_executables_by_device(monkeypatch):
+    import jax
+
+    class Executable:
+        def __init__(self, size, devices):
+            self.size, self.devices = size, devices
+
+        def size_of_generated_code_in_bytes(self):
+            if self.size is None:
+                raise RuntimeError("the backend does not say")
+            return self.size
+
+        def local_devices(self):
+            return self.devices
+
+    class Client:
+        def live_executables(self):
+            return [Executable(100, ["d0"]), Executable(7, ["d0", "d1"]),
+                    Executable(None, ["d1"])]
+
+    class Device:
+        client = Client()
+
+    monkeypatch.setattr(jax, "local_devices", lambda: [Device(), Device()])
+    assert memscope.loaded_code_bytes() == {"d0": 107, "d1": 7}
+    monkeypatch.undo()
+    # the CPU says nothing of an executable's size: zeros, never an error
+    assert all(b == 0 for b in memscope.loaded_code_bytes().values())
