@@ -379,12 +379,14 @@ def _of_tiles(t):
         .reshape(b, groups * SUBLANES, rows // SUBLANES * LANES)
 
 
-def _fwd(x, dt, bmat, cmat, z, a_t, dskip, chunk, interpret, save):
+def _fwd(x, dt, bmat, cmat, z, a_t, dskip, chunk, interpret, save, z_at=0):
     """``out`` [b, S, d] and, where ``save``, the state each chunk found
-    [b, S / chunk, N, d / 128, 128].  ``a_t`` [N, d] float32."""
+    [b, S / chunk, N, d / 128, 128].  ``a_t`` [N, d] float32; z's block the
+    ``z_at``-th of d lanes of a wider array."""
     b, S, d = x.shape
     N, R, nc, G = a_t.shape[0], d // LANES, S // chunk, chunk // SUBLANES
     rows = pl.BlockSpec((None, chunk, d), lambda i, j: (i, j, 0))
+    z_rows = pl.BlockSpec((None, chunk, d), lambda i, j: (i, j, z_at))
     tiles = pl.BlockSpec((None, G, SUBLANES * R, LANES),
                          lambda i, j: (i, j, 0, 0))
     scalars = pl.BlockSpec((1, chunk * N), lambda i, j: (i, j),
@@ -400,7 +402,7 @@ def _fwd(x, dt, bmat, cmat, z, a_t, dskip, chunk, interpret, save):
         functools.partial(_fwd_kernel, chunk=chunk, n_state=N, save=save,
                           unroll=not interpret),
         grid=(b, nc),
-        in_specs=[rows, tiles, rows, scalars, scalars, cells,
+        in_specs=[rows, tiles, z_rows, scalars, scalars, cells,
                   pl.BlockSpec((R, LANES), lambda i, j: (0, 0))],
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((N, R, LANES), F32)]
@@ -411,7 +413,7 @@ def _fwd(x, dt, bmat, cmat, z, a_t, dskip, chunk, interpret, save):
       a_t.reshape(N, R, LANES), dskip.reshape(R, LANES))
 
 
-def _bwd(chunk, interpret, res, dout):
+def _bwd(chunk, interpret, z_at, res, dout):
     x, dt, bmat, cmat, z, a_t, dskip, edges = res
     b, S, d = x.shape
     N, R, nc, G = a_t.shape[0], d // LANES, S // chunk, chunk // SUBLANES
@@ -421,6 +423,8 @@ def _bwd(chunk, interpret, res, dout):
         return nc - 1 - j
 
     rows = pl.BlockSpec((None, chunk, d), lambda i, j: (i, back(i, j), 0))
+    z_rows = pl.BlockSpec((None, chunk, d),
+                          lambda i, j: (i, back(i, j), z_at))
     tiles = pl.BlockSpec((None, G, SUBLANES * R, LANES),
                          lambda i, j: (i, back(i, j), 0, 0))
     scalars = pl.BlockSpec((1, chunk * N), lambda i, j: (i, back(i, j)),
@@ -432,7 +436,7 @@ def _bwd(chunk, interpret, res, dout):
         functools.partial(_bwd_kernel, chunk=chunk, n_state=N,
                           unroll=not interpret),
         grid=(b, nc),
-        in_specs=[rows, tiles, rows, scalars, scalars, cells,
+        in_specs=[rows, tiles, z_rows, scalars, scalars, cells,
                   pl.BlockSpec((R, LANES), lambda i, j: (0, 0)),
                   pl.BlockSpec((None, None, N, R, LANES),
                                lambda i, j: (i, back(i, j), 0, 0, 0)),
@@ -457,37 +461,49 @@ def _bwd(chunk, interpret, res, dout):
         interpret=interpret, name="selective_scan_bwd",
     )(x, _tiles(dt), z, bmat.reshape(b, S * N), cmat.reshape(b, S * N),
       a_t.reshape(N, R, LANES), dskip.reshape(R, LANES), edges, dout)
+    # the lanes of a wider z it did not read: zeros, a pad that XLA fuses
+    # into whatever reads that gradient
+    if z.shape[-1] != d:
+        dz = jnp.pad(dz, ((0, 0), (0, 0),
+                          (z_at * d, z.shape[-1] - (z_at + 1) * d)))
     return (dx, _of_tiles(ddt), db, dc, dz,
             jnp.sum(da, axis=0).reshape(N, d),
             jnp.sum(dd.reshape(b, R, SUBLANES, LANES), axis=(0, 2))
             .reshape(d))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _scan(x, dt, bmat, cmat, z, a_t, dskip, chunk, interpret):
-    return _fwd(x, dt, bmat, cmat, z, a_t, dskip, chunk, interpret, False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _scan(x, dt, bmat, cmat, z, a_t, dskip, chunk, interpret, z_at):
+    return _fwd(x, dt, bmat, cmat, z, a_t, dskip, chunk, interpret, False,
+                z_at)[0]
 
 
-def _scan_fwd(x, dt, bmat, cmat, z, a_t, dskip, chunk, interpret):
+def _scan_fwd(x, dt, bmat, cmat, z, a_t, dskip, chunk, interpret, z_at):
     out, edges = _fwd(x, dt, bmat, cmat, z, a_t, dskip, chunk, interpret,
-                      True)
+                      True, z_at)
     return out, (x, dt, bmat, cmat, z, a_t, dskip, edges)
 
 
 _scan.defvjp(_scan_fwd, _bwd)
 
 
-def selective_scan(x, dt, bmat, cmat, z, a, dskip, chunk=128, interpret=None):
+def selective_scan(x, dt, bmat, cmat, z, a, dskip, chunk=128, interpret=None,
+                   z_at=0):
     """``out`` [b, S, d] of the module's three lines: x, z [b, S, d] (any
     float type; ``out``, ``dx`` and ``dz`` have x's), dt [b, S, d] the step
     sizes AFTER their softplus, bmat and cmat [b, S, N], a [d, N] the
     NEGATIVE rates (``-exp(a_log)``), dskip [d]; the recurrence, the state
     and every sum in float32.  Differentiable in all seven.  ``chunk``
     tokens between two kept states (clamp it to S); the result does not
-    depend on it beyond the rounding of the sums ``dB`` and ``dC``."""
+    depend on it beyond the rounding of the sums ``dB`` and ``dC``.  z may
+    be a wider array [b, S, k d] read IN PLACE (``in_proj``'s packed ``[x |
+    z]``): its lanes ``z_at`` d .. are the gate, and its gradient is zero
+    in the others."""
     assert supported(x.shape, a.shape[1], chunk), (x.shape, a.shape, chunk)
+    assert z.shape[-1] % x.shape[-1] == 0 \
+        and z_at < z.shape[-1] // x.shape[-1], (z.shape, x.shape, z_at)
     if interpret is None:
         interpret = not _on_tpu()
     dt, bmat, cmat = (t.astype(F32) for t in (dt, bmat, cmat))
     return _scan(x, dt, bmat, cmat, z, a.astype(F32).T, dskip.astype(F32),
-                 int(chunk), bool(interpret))
+                 int(chunk), bool(interpret), int(z_at))

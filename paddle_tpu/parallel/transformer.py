@@ -1189,23 +1189,39 @@ def _mamba_step_sizes(pl, x, cfg):
 def mamba_operands(pl, h, cfg, rows, first):
     """What the selective scan reads of the positions ``first``.. of ``h``
     [b, S, E] (``rows``, a block of them or all): the convolved x, the gate
-    z, ``_mamba_step_sizes``.  The filter reaches ``d_conv - 1`` tokens back:
-    a block past the first projects those rows of ``h`` again, its halo."""
-    taps = pl["conv_w"].astype(jnp.float32)
-    halo, n = taps.shape[0] - 1, rows.shape[1]
-    if isinstance(first, int) and first == 0:       # the whole sequence
-        before = jnp.zeros(rows.shape[:1] + (halo, cfg.d_inner), jnp.float32)
-    else:
+    z (or the packed projection ``[x | z]`` that holds it), ``_mamba_step_
+    sizes``.  The filter reaches ``d_conv - 1`` tokens back: a block past
+    the first projects those rows of ``h`` again, its halo.  The filter, its
+    bias and ``silu`` are ONE kernel each way on the packed projection's own
+    x half where ``kernels/mamba_filter.py`` takes the shapes, and the
+    ``jnp`` lines (its reference) elsewhere."""
+    from ..kernels import mamba_filter as mf
+
+    halo, d = cfg.d_conv - 1, cfg.d_inner
+    whole = isinstance(first, int) and first == 0
+    before = None                                   # the whole sequence
+    if not whole:
         back = jax.lax.dynamic_slice_in_dim(
             h, jnp.maximum(first - halo, 0), halo, axis=1)
         # the first block's halo lies before position 0: zeros
-        before = jnp.where(first > 0, (back @ pl["w_in"][:, :cfg.d_inner])
+        before = jnp.where(first > 0, (back @ pl["w_in"][:, :d])
                            .astype(jnp.float32), 0.0)
-    x, z = jnp.split(rows @ pl["w_in"], 2, axis=-1)
-    padded = jnp.concatenate([before, x.astype(jnp.float32)], axis=1)
-    conv = pl["conv_b"].astype(jnp.float32) + sum(
-        taps[j] * padded[:, j:j + n] for j in range(halo + 1))
-    x = jax.nn.silu(conv).astype(rows.dtype)
+    fused = mf.supported(rows.shape[:2] + (d,), cfg.d_conv,
+                         rows.dtype.itemsize)
+    mon = monitor.active()
+    if mon is not None:
+        mon.registry.counter("monitor.kernels.mamba_filter_calls",
+                             fused=int(fused),
+                             halo="zeros" if whole else "rows").incr()
+    xz = rows @ pl["w_in"]
+    if fused:
+        x = mf.mamba_filter(xz, pl["conv_w"], pl["conv_b"], before, width=d)
+        # the whole sequence's gate stays where it is: the scan's kernels
+        # read z's lanes of the packed projection (no copy of them)
+        z = xz if whole else xz[..., d:]
+    else:
+        x, z = jnp.split(xz, 2, axis=-1)
+        x = mf.mamba_filter_reference(x, pl["conv_w"], pl["conv_b"], before)
     return (x, z) + _mamba_step_sizes(pl, x, cfg)
 
 
@@ -1237,9 +1253,12 @@ def mamba_mixer(pl, h, cfg):
         mon.registry.counter("monitor.kernels.selective_scan_calls",
                              fused=int(kernel),
                              door="tiles" if kernel else "copied").incr()
+    z_at = z.shape[-1] // cfg.d_inner - 1       # behind x where packed
+    if not kernel:
+        z = z[..., z_at * cfg.d_inner:]
     with jax.named_scope(devscope.SELECTIVE_SCAN):
         operands = (x, dt, bmat, cmat, z, -jnp.exp(pl["a_log"]), pl["d_skip"])
-        y = scan.selective_scan(*operands, chunk=chunk) if kernel \
+        y = scan.selective_scan(*operands, chunk=chunk, z_at=z_at) if kernel \
             else scan.selective_scan_reference(*operands)
     return y @ pl["w_out"]
 

@@ -27,7 +27,18 @@ instructions the compiled program runs between that array and the kernels
 (``door``: followed through the entry computation's text; a view that XLA
 takes as a bitcast has none and reads 0).  ``--against`` times another tree's
 kernels the same way in the same process (the parent's from ``git archive``:
-PR 48's door is XLA's re-tiling copies).  Exit 1 where a reading is off or a
+PR 48's door is XLA's re-tiling copies).
+
+Then the filter in front of the scan (``kernels/mamba_filter.py``, PR 51):
+the kernels on the packed projection ``[1, S, 2 d]`` against the ``jnp``
+lines they replace (``mamba_filter_reference`` behind a split, the parent's
+lines and fusions) in float32: the output and the gradients of the
+projection, the taps and the bias, with and without rows before position 0
+(limit ``FILTER_LIMIT``, and never further from float32 than the ``jnp``
+lines in bf16 are); and the times of forward + backward under ``jax.vjp``
+by device trace: the two kernels by name beside the whole ``jnp`` program,
+with a door line for the x half (0: read in place).  ``--only filter`` or
+``--only scan`` runs one of the two.  Exit 1 where a reading is off or a
 kernel's name matched nothing in the trace, 2 off a TPU."""
 
 import importlib.util
@@ -45,6 +56,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from attn_outside_hlo import computations  # noqa: E402  (beside this file)
+from paddle_tpu.kernels import mamba_filter as mf  # noqa: E402
 from paddle_tpu.kernels import selective_scan as ss  # noqa: E402
 
 S, D, N = 8192, 5120, 16
@@ -54,6 +66,11 @@ CALLS = 5
 NAMES = ("x", "dt", "B", "C", "z", "a", "D")
 KERNELS = ("selective_scan_fwd", "selective_scan_bwd")
 # the per-token arrays at the door: (kernel, "in" | "out", its place there)
+FILTER_LIMIT = 1e-2     # one bf16 rounding of the output; sums of 8,192 rows
+FILTER_KERNELS = ("mamba_filter_fwd", "mamba_filter_bwd")
+TAPS = 4
+# the projection at the filter's door: both kernels' first operand
+FILTER_DOOR = {"xz": (0, "in", 0), "xz_again": (1, "in", 0)}
 DOOR = {"x": (1, "in", 0), "dt": (1, "in", 1), "z": (1, "in", 2),
         "out": (0, "out", 0), "dout": (1, "in", 8), "dx": (1, "out", 0),
         "ddt": (1, "out", 1), "dz": (1, "out", 2)}
@@ -87,7 +104,7 @@ def dropped_at_edges(args, chunk, S=S_COMPARED):
     return jnp.concatenate(parts, axis=1)
 
 
-def door(text):
+def door(text, kernels=KERNELS, arrays=DOOR):
     """{array of ``DOOR``: the entry computation's instructions between it
     and the kernels}, of the compiled text of a program whose parameters and
     results are the kernels' operands and results: back from a call's
@@ -101,7 +118,7 @@ def door(text):
             users.setdefault(o, []).append(name)
     calls = [next(n for n, (op, _, _) in by.items()
                   if op == "custom-call" and kernel in n)
-             for kernel in KERNELS]
+             for kernel in kernels]
 
     def back(name):
         found = []
@@ -120,7 +137,7 @@ def door(text):
         return found
 
     out = {}
-    for array, (call, side, at) in DOOR.items():
+    for array, (call, side, at) in arrays.items():
         call = calls[call]
         if side == "in":
             out[array] = back(by[call][1][at])
@@ -148,25 +165,124 @@ def device_us(fn, args):
     return {name: t / CALLS / 1e3 for name, t in dev["by_name"].items()}
 
 
-def times(mod, chunk, args, g):
+def _door_us(at_door, by_name):
+    """{array: its door's device microseconds and the instructions that ran}
+    of ``door``'s names and a trace's ``{instruction: us}``."""
+    return {array: {"us": sum(by_name.get(n.lstrip("%"), 0.0) for n in names),
+                    "instructions": [n for n in names
+                                     if n.lstrip("%") in by_name]}
+            for array, names in at_door.items()}
+
+
+def times(mod, chunk, args, g, packed=False):
     """Device microseconds a call of ``mod``'s forward + backward at the
-    cell's shape: the kernels by name, the door by array, and the rest."""
+    cell's shape: the kernels by name, the door by array, and the rest.
+    ``packed``: z read in place, the second half of a ``[1, S, 2 d]`` array
+    as ``in_proj`` leaves it (PR 51; its gradient's pad, which the layer's
+    matmuls take as a fused operand, is a pass of its own in this program:
+    the door of ``dz``)."""
+    kw = {"z_at": 1} if packed else {}
+    if packed:
+        args = args[:4] + (jnp.concatenate([args[0], args[4]], axis=-1),) \
+            + args[5:]
+
     def both(*a):
-        out, vjp = jax.vjp(lambda *q: mod.selective_scan(*q, chunk=chunk),
-                           *a[:-1])
+        out, vjp = jax.vjp(lambda *q: mod.selective_scan(*q, chunk=chunk,
+                                                         **kw), *a[:-1])
         return (out,) + vjp(a[-1])
 
     fn = jax.jit(both)
     by_name = device_us(fn, args + (g,))
     at_door = door(fn.lower(*args, g).compile().as_text())
     took = {k: sum(t for n, t in by_name.items() if k in n) for k in KERNELS}
-    took["door"] = {
-        array: {"us": sum(by_name.get(n.lstrip("%"), 0.0) for n in names),
-                "instructions": [n for n in names
-                                 if n.lstrip("%") in by_name]}
-        for array, names in at_door.items()}
+    took["door"] = _door_us(at_door, by_name)
     took["all"] = sum(by_name.values())
     return took
+
+
+def filter_operands(seed, dtype=jnp.bfloat16, S=S, before=False):
+    """The packed projection, the taps, the bias, the rows before position 0
+    (or None) and the output's gradient, seeded as the cell's weights are
+    (taps uniform in +-1/2, unit projections)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    xz = jax.random.normal(ks[0], (1, S, 2 * D)).astype(dtype)
+    conv_w = jax.random.uniform(ks[1], (TAPS, D), minval=-0.5, maxval=0.5)
+    conv_b = 0.1 * jax.random.normal(ks[2], (D,))
+    rows = jax.random.normal(ks[3], (1, TAPS - 1, D)) if before else None
+    return (xz, conv_w, conv_b, rows), \
+        jax.random.normal(ks[4], (1, S, D)).astype(dtype)
+
+
+def _filter_programs():
+    """{"kernel", "jnp": (xz, conv_w, conv_b, before, g) -> (out, the four
+    gradients)}: the kernels on the packed projection, and the lines they
+    replace behind the split."""
+    def program(fn):
+        def both(xz, conv_w, conv_b, before, g):
+            out, vjp = jax.vjp(fn, xz, conv_w, conv_b, before)
+            return (out,) + vjp(g)
+        return jax.jit(both)
+
+    return {
+        "kernel": program(lambda xz, w, b, rows: mf.mamba_filter(
+            xz, w, b, rows, width=D)),
+        "jnp": program(lambda xz, w, b, rows: mf.mamba_filter_reference(
+            jnp.split(xz, 2, axis=-1)[0], w, b, rows))}
+
+
+def filter_receipt(out):
+    """The filter's readings and times into ``out``; whether all held."""
+    programs, ok = _filter_programs(), True
+    names = ("out", "dxz", "dconv_w", "dconv_b", "dbefore")
+    for label, before in (("zeros", False), ("rows", True)):
+        args, g = filter_operands(13, before=before)
+        exact = programs["jnp"](args[0].astype(jnp.float32), *args[1:],
+                                g.astype(jnp.float32))
+        got, old = programs["kernel"](*args, g), programs["jnp"](*args, g)
+        for name, a, o, e in zip(names, got, old, exact):
+            if e is None:
+                continue
+            reading, jnp_reads = _rel(a, e), _rel(o, e)
+            key = "filter.%s.%s" % (label, name)
+            out["readings"][key] = reading
+            print(key, reading, "(the jnp lines in bf16: %g)" % jnp_reads,
+                  flush=True)
+            ok = ok and reading <= max(FILTER_LIMIT, 1.5 * jnp_reads)
+    # a fault control: the halo dropped (the filter restarted at row 16)
+    args, g = filter_operands(13)
+    dropped = jnp.concatenate([mf.mamba_filter(
+        args[0][:, at:at + 16], *args[1:3], width=D)
+        for at in (0, 16)], axis=1)
+    out["control_halo_dropped"] = _rel(
+        dropped, programs["kernel"](*args, g)[0][:, :32])
+    print("control (the halo dropped at row 16):",
+          out["control_halo_dropped"], flush=True)
+    ok = ok and out["control_halo_dropped"] > FILTER_LIMIT
+    args, g = filter_operands(14)
+    for tree, fn in programs.items():
+        by_name = device_us(fn, args + (g,))
+        took = {k: sum(t for n, t in by_name.items() if k in n)
+                for k in FILTER_KERNELS}
+        took["all"] = sum(by_name.values())
+        took["by_name"] = dict(sorted(by_name.items(),
+                                      key=lambda kv: -kv[1])[:8])
+        if tree == "kernel":
+            at_door = door(fn.lower(*args, g).compile().as_text(),
+                           FILTER_KERNELS, FILTER_DOOR)
+            took["door"] = _door_us(at_door, by_name)
+            ok = ok and all(took[k] > 0 for k in FILTER_KERNELS)
+        out["device_us"]["filter." + tree] = took
+        print("filter, %s, device us a call: %s; all %.1f" % (
+            tree, ", ".join("%s %.1f" % (k, took[k])
+                            for k in FILTER_KERNELS), took["all"]),
+            flush=True)
+        for name, t in took["by_name"].items():
+            print("    %9.1f us  %s" % (t, name), flush=True)
+        for array, at in took.get("door", {}).items():
+            print("    door %-8s %9.1f us  %s" % (
+                array, at["us"], " ".join(at["instructions"]) or "-"),
+                flush=True)
+    return ok
 
 
 def _other_tree(path):
@@ -179,19 +295,8 @@ def _other_tree(path):
     return mod
 
 
-def main(*argv):
-    if jax.devices()[0].platform != "tpu":
-        print("needs a TPU")
-        return 2
-    argv, against = list(argv), None
-    if "--against" in argv:
-        at = argv.index("--against")
-        against = _other_tree(argv[at + 1])
-        del argv[at:at + 2]
-    out_path = argv[0] if argv and not argv[0].isdigit() else None
-    chunks = [int(c) for c in argv[bool(out_path):]] or [64, 128]
-    out = {"device_kind": jax.devices()[0].device_kind, "readings": {},
-           "device_us": {}}
+def scan_receipt(out, chunks, against):
+    """The scan's readings and times into ``out``; whether all held."""
     ok = True
     for label, scale in (("seeded", 1.0), ("slow_decay", 0.1)):
         args, w = operands(11, scale, S_COMPARED)
@@ -225,10 +330,11 @@ def main(*argv):
           out["control_state_dropped"], flush=True)
     args, w = operands(12, 1.0)
     g = w.astype(args[0].dtype)
-    trees = [("this", ss)] + ([("against", against)] if against else [])
+    trees = [("this", ss, False), ("this, z in place", ss, True)] \
+        + ([("against", against, False)] if against else [])
     for chunk in chunks:
-        for tree, mod in trees:
-            took = times(mod, chunk, args, g)
+        for tree, mod, packed in trees:
+            took = times(mod, chunk, args, g, packed)
             out["device_us"]["%s.chunk%d" % (tree, chunk)] = took
             print("%s, chunk %d, device us a call: %s; all %.1f" % (
                 tree, chunk, ", ".join("%s %.1f" % (k, took[k])
@@ -239,6 +345,31 @@ def main(*argv):
                     array, at["us"], " ".join(at["instructions"]) or "-"),
                     flush=True)
             ok = ok and all(took[k] > 0 for k in KERNELS)
+    return ok
+
+
+def main(*argv):
+    if jax.devices()[0].platform != "tpu":
+        print("needs a TPU")
+        return 2
+    argv, against, only = list(argv), None, ("scan", "filter")
+    if "--against" in argv:
+        at = argv.index("--against")
+        against = _other_tree(argv[at + 1])
+        del argv[at:at + 2]
+    if "--only" in argv:
+        at = argv.index("--only")
+        only = (argv[at + 1],)
+        del argv[at:at + 2]
+    out_path = argv[0] if argv and not argv[0].isdigit() else None
+    chunks = [int(c) for c in argv[bool(out_path):]] or [64, 128]
+    out = {"device_kind": jax.devices()[0].device_kind, "readings": {},
+           "device_us": {}}
+    ok = True
+    if "scan" in only:
+        ok = scan_receipt(out, chunks, against) and ok
+    if "filter" in only:
+        ok = filter_receipt(out) and ok
     worst = max(out["readings"].items(), key=lambda kv: kv[1])
     out["worst"], out["ok"] = list(worst), bool(ok)
     print(json.dumps({k: v for k, v in out.items() if k != "readings"}))

@@ -136,7 +136,7 @@ def _compiled(attn, *shapes):
 def _vmem(text, kernel):
     """(bytes of VMEM the call of ``kernel`` asks Mosaic for, None where it
     leaves the scope at its default; bytes the compiled kernel took)."""
-    size = r'\{"memory_space":"1","offset":"0","size":"(\d+)"\}'
+    size = r'\{"memory_space":"1","offset":"\d+","size":"(\d+)"\}'
     line, = [l for l in text.splitlines()
              if "tpu_custom_call" in l and re.search(
                  r"%%?[\w.\-]*%s[\w.\-]* = " % kernel, l)]
@@ -497,6 +497,17 @@ def _script(name):
     return importlib.import_module(name)
 
 
+def _moved(text, at_door):
+    """Of a door's instructions ({array: names}, ``jamba_kernels_receipt.
+    door``) those that move the array; XLA's own prefetch of a small
+    operand (``copy-start``) keeps its tiles."""
+    comps, entry = _script("attn_outside_hlo").computations(text)
+    ops = {name: op for name, _, op, _, _ in comps[entry]}
+    return {array: [n for n in names if ops[n] in (
+        "copy", "reshape", "transpose", "fusion", "slice")]
+        for array, names in at_door.items()}
+
+
 @pytest.mark.parametrize("what,shape,chunk,dtype", [
     ("jamba2_3b.s8192_scan", (1, 8192, 5120), 128, jnp.bfloat16),
     ("a token group that is a whole chunk", (1, 64, 1024), 8, jnp.bfloat16),
@@ -534,15 +545,47 @@ def test_the_selective_scan_compiles_for_a_v5e(one_chip, what, shape, chunk,
         assert asked == ss.vmem_bytes(chunk, d, N, jnp.dtype(dtype).itemsize)
         assert took < asked < 128 * 2 ** 20, (what, kernel, took, asked)
     # what the receipt times as the door (scripts/jamba_kernels_receipt.py)
-    at_door = _script("jamba_kernels_receipt").door(text)
-    comps, entry = _script("attn_outside_hlo").computations(text)
-    ops = {name: op for name, _, op, _, _ in comps[entry]}
-    # XLA's own prefetch of a small operand (``copy-start``) keeps its tiles
-    moved = {array: [n for n in names if ops[n] in (
-        "copy", "reshape", "transpose", "fusion")]
-        for array, names in at_door.items()}
+    moved = _moved(text, _script("jamba_kernels_receipt").door(text))
     assert set(moved) == {"x", "dt", "z", "out", "dout", "dx", "ddt", "dz"}
     assert not any(moved.values()), (what, moved)
+
+
+def test_the_selective_scan_reads_z_in_the_packed_projection(one_chip):
+    """The cell's call with z the second half of ``in_proj``'s ``[1, 8192,
+    10240]`` (``z_at=1``): the same kernels within the same VMEM, the packed
+    array their operand as it is (no slice of it, no copy), and z's
+    gradient padded back to the packed width by XLA."""
+    ss = importlib.import_module("paddle_tpu.kernels.selective_scan")
+    shape, N, chunk = (1, 8192, 5120), 16, 128
+
+    def sds(shape_, dtype_):
+        return jax.ShapeDtypeStruct(shape_, dtype_, sharding=one_chip)
+
+    args = (sds(shape, jnp.bfloat16), sds(shape, jnp.float32),
+            sds((1, 8192, N), jnp.float32), sds((1, 8192, N), jnp.float32),
+            sds((1, 8192, 10240), jnp.bfloat16), sds((5120, N), jnp.float32),
+            sds((5120,), jnp.float32))
+
+    def both(*a):
+        out, vjp = jax.vjp(lambda *q: ss.selective_scan(
+            *q, chunk=chunk, interpret=False, z_at=1), *a[:-1])
+        return (out,) + vjp(a[-1])
+
+    text = jax.jit(both).lower(*args, sds(shape, jnp.bfloat16)) \
+        .compile().as_text()
+    for kernel in ("selective_scan_fwd", "selective_scan_bwd"):
+        asked, took = _vmem(text, kernel)
+        assert took < asked == ss.vmem_bytes(chunk, 5120, N, 2)
+    at_door = _script("jamba_kernels_receipt").door(text)
+    assert at_door["z"] == [] and at_door["x"] == []
+    comps, entry = _script("attn_outside_hlo").computations(text)
+    dz, = [types for name, types, _, _, _ in comps[entry]
+           if name in at_door["dz"] and "10240" in types]
+    assert dz.startswith("bf16[1,8192,10240]")
+
+
+MAMBA_KERNELS = {"selective_scan_fwd", "selective_scan_bwd",
+                 "mamba_filter_fwd", "mamba_filter_bwd"}
 
 
 def test_a_mamba_layer_s_text_holds_no_float32_copy_at_the_scan_s_door(
@@ -560,10 +603,90 @@ def test_a_mamba_layer_s_text_holds_no_float32_copy_at_the_scan_s_door(
                                   scan_chunk=128, max_seq=256)
     text = hlo.compiled_text(cfg, 1, 256, T.MAMBA)
     groups, by_kernel, others = hlo.account(text)
-    assert set(by_kernel) == {"selective_scan_fwd", "selective_scan_bwd"}
+    assert set(by_kernel) == MAMBA_KERNELS
     elements = 256 * 1024
     relayouts = [o for o in others if o[2] in ("copy", "reshape", "transpose")
                  and o[3].startswith("f32") and o[0] >= 2 * 4 * elements]
     assert not relayouts, relayouts
     # the softplus writes the kernels' view itself: one float32 pass
     assert re.search(r"= f32\[1,32,64,128\]\S* fusion\(", text)
+
+
+# --- the filter in front of the scan (PR 51) ---------------------------------
+
+@pytest.mark.parametrize("what,shape,width,before,dtype", [
+    ("jamba2_3b.s8192_scan, the packed projection", (1, 8192, 10240), 5120,
+     False, jnp.bfloat16),
+    ("a block of positions past the first", (1, 2048, 10240), 5120, True,
+     jnp.bfloat16),
+    ("float32 alone, one block of 8 rows", (2, 8, 128), 128, True,
+     jnp.float32),
+    ("float32, three lane blocks of 128", (1, 1024, 768), 384, False,
+     jnp.float32),
+])
+def test_the_mamba_filter_compiles_for_a_v5e(one_chip, what, shape, width,
+                                             before, dtype):
+    """Both kernels through Mosaic at the cell's shape (the x half of the
+    packed projection, blocks of 2,048 x 512 walked 32 rows a turn: a sublane
+    rotation of a 40-row window, a 16-row bf16 tile before and after each
+    block) and at the other shapes ``supported`` takes, within the VMEM
+    their call asks for; the projection reaches both kernels as it is."""
+    mf = importlib.import_module("paddle_tpu.kernels.mamba_filter")
+    b, S, W = shape
+    taps, itemsize = 4, jnp.dtype(dtype).itemsize
+
+    def sds(shape_, dtype_):
+        return jax.ShapeDtypeStruct(shape_, dtype_, sharding=one_chip)
+
+    args = (sds(shape, dtype), sds((taps, width), jnp.float32),
+            sds((width,), jnp.float32),
+            sds((b, taps - 1, width), jnp.float32) if before else None)
+
+    def both(x, conv_w, conv_b, rows, g):
+        out, vjp = jax.vjp(lambda *q: mf.mamba_filter(
+            *q, width=width, interpret=False), x, conv_w, conv_b, rows)
+        return (out,) + vjp(g)
+
+    assert mf.supported((b, S, width), taps, itemsize)
+    text = jax.jit(both).lower(*args, sds((b, S, width), dtype)) \
+        .compile().as_text()
+    bs, lb = mf.block_rows(S, itemsize), mf.block_lanes(width)
+    for kernel in ("mamba_filter_fwd", "mamba_filter_bwd"):
+        # the scope starts behind what XLA itself keeps in VMEM (the taps)
+        asked, took = _vmem(text, kernel)
+        assert asked == mf.vmem_bytes(bs, lb, itemsize)
+        assert took < asked <= 32 * 2 ** 20, (what, kernel, took, asked)
+    receipt = _script("jamba_kernels_receipt")
+    moved = _moved(text, receipt.door(text, receipt.FILTER_KERNELS,
+                                      receipt.FILTER_DOOR))
+    assert moved == {"xz": [], "xz_again": []}, (what, moved)
+
+
+def test_a_mamba_layer_s_filter_reads_the_projection_in_place(one_chip):
+    """The cell's Mamba layer, recompute + backward, through
+    ``scripts/attn_outside_hlo.py`` (the no-chip reading ISSUE 51 was sized
+    by): its kernels are exactly the scan's and the filter's; ``in_proj``'s
+    matmul hands its packed result to ``mamba_filter_fwd`` itself; and no
+    float32 array of x's size is left in HBM between them or anywhere
+    else in the entry computation (the parent wrote x in float32 for the
+    shifts and the float32 pre-activation for the backward: 3.2 GB outside
+    the matmuls and kernels where 1.7 are left)."""
+    hlo = _script("attn_outside_hlo")
+    cfg, batch, seq = hlo.cell_config("jamba2_3b.s8192_scan", tiny=False)
+    kind = hlo.default_kind(cfg)
+    assert (batch, seq, kind, cfg.d_inner) == (1, 8192, "mamba", 5120)
+    text = hlo.compiled_text(cfg, batch, seq, kind)
+    groups, by_kernel, others = hlo.account(text)
+    assert set(by_kernel) == MAMBA_KERNELS
+    comps, entry = hlo.computations(text)
+    by = {name: (types, op, operands, attrs)
+          for name, types, op, operands, attrs in comps[entry]}
+    assert not [n for n, (types, op, _, _) in by.items()
+                if "f32[1,8192,5120]" in types and op != "custom-call"]
+    call, = [n for n, (_, op, _, _) in by.items()
+             if op == "custom-call" and "mamba_filter_fwd" in n]
+    types, op, _, attrs = by[by[call][2][0]]
+    assert types.startswith("bf16[1,8192,10240]") and op == "fusion"
+    called = re.search(r"calls=%?([\w.\-]+)", attrs).group(1)
+    assert any(o in ("convolution", "dot") for _, _, o, _, _ in comps[called])
+    assert groups["other"] < 2.4e9 and groups["matmul"] > 2.0e9

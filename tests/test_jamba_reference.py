@@ -281,6 +281,42 @@ def test_the_kernels_read_the_projections_own_tiles(b, s, d, chunk, dtype):
                                    atol=tol * np.abs(wg).max())
 
 
+@pytest.mark.parametrize("b,s,d,chunk,dtype,z_at,blocks", [
+    (2, 32, 128, 8, "bfloat16", 1, 2),      # in_proj's [x | z]
+    (1, 48, 256, 24, "bfloat16", 1, 2), (1, 32, 128, 16, "float32", 0, 3),
+])
+def test_the_kernels_read_z_in_place_in_a_wider_array(b, s, d, chunk, dtype,
+                                                      z_at, blocks):
+    """z handed over as block ``z_at`` of ``blocks`` blocks of d lanes (the
+    packed projection, no copy of its z half): the output and the six other
+    gradients to the bit those of the same z alone, and z's gradient that
+    one in its own lanes and zero in the others."""
+    args, w = _operands(b, s, d, 4, seed=3)
+    args = tuple(t.astype(dtype) if i in (0, 4) else t
+                 for i, t in enumerate(args))
+    others = jax.random.normal(jax.random.PRNGKey(9), (b, s, blocks * d)) \
+        .astype(dtype)
+    wide = others.at[..., z_at * d:(z_at + 1) * d].set(args[4])
+
+    def both(z, **kw):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(ss.selective_scan(
+                *a, chunk=chunk, interpret=True, **kw).astype(jnp.float32)
+                * w), argnums=tuple(range(7)))(*args[:4], z, *args[5:])
+
+    (got, got_g), (want, want_g) = both(wide, z_at=z_at), both(args[4])
+    assert float(got) == float(want)
+    for i, (g, wg) in enumerate(zip(got_g, want_g)):
+        if i == 4:
+            assert g.shape == wide.shape and g.dtype == wide.dtype
+            mine = g[..., z_at * d:(z_at + 1) * d]
+            assert float(jnp.abs(g.astype(jnp.float32)).sum()) == float(
+                jnp.abs(mine.astype(jnp.float32)).sum())    # zero elsewhere
+            g = mine
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(wg, np.float32))
+
+
 def test_the_door_s_view_is_the_array_s_own_tiles():
     """``_tiles``: row 8 r + s of group g of a sequence's view is token 8 g
     + s, channels 128 r ..; ``_of_tiles`` puts it back."""
