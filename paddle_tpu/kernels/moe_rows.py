@@ -17,7 +17,8 @@ SmallThinker and LFM2, a sixteenth in Mistral-Small-4).  Here the work
 follows the rows that exist:
 
 - ``moe_rows_words``, one pass over the rows: a bfloat16 row becomes E/2
-  32-bit words that lie CONTIGUOUS in HBM.  A tiled 16-bit array keeps two
+  32-bit words (whole registers of them: ``_half``) that lie CONTIGUOUS in
+  HBM.  A tiled 16-bit array keeps two
   rows in each word and eight words' rows in a tile, so no DMA can lift one
   row out of it; the word pairs column c of the row's left half with column
   c of its right half, which a shift and a mask undo.  32-bit rows go as
@@ -90,35 +91,48 @@ def _lanes(width):
     return 128 if width % 128 == 0 else width
 
 
+def _half(width):
+    """Words a bfloat16 row of ``width`` columns goes in: half the columns,
+    in whole registers where the row is whole registers (an ODD number of
+    them goes as the larger half: 2,688 = 21 x 128 columns are 11 x 128
+    words, the last register's high bits zero)."""
+    return -(-width // 256) * 128 if width % 128 == 0 else width // 2
+
+
 def _words_kernel(rows_ref, out_ref):
     """rows_ref: [bm, E] bfloat16; out_ref: [bm * chunks, lanes] uint32, row
     r's words in its rows [r * chunks, (r + 1) * chunks): a register column
-    of the block goes out one sublane every ``chunks``."""
-    bm, half = rows_ref.shape[0], rows_ref.shape[1] // 2
+    of the block goes out one sublane every ``chunks``.  Word c holds column
+    c in its low bits and column ``_half(E) + c`` in its high bits (zero
+    past the row's end)."""
+    bm, width = rows_ref.shape
     lanes = out_ref.shape[1]
-    chunks = half // lanes
+    chunks = out_ref.shape[0] // bm
+    half = chunks * lanes
     bits = lambda v: jax.lax.bitcast_convert_type(
         v.astype(jnp.float32), jnp.uint32)
     for chunk in range(chunks):
-        low = bits(rows_ref[:, pl.ds(chunk * lanes, lanes)])
-        high = bits(rows_ref[:, pl.ds(half + chunk * lanes, lanes)])
-        out_ref[pl.ds(chunk, bm, stride=chunks)] = (
-            (low >> 16) | (high & jnp.uint32(0xFFFF0000)))
+        word = bits(rows_ref[:, pl.ds(chunk * lanes, lanes)]) >> 16
+        if half + chunk * lanes < width:
+            word = word | (bits(rows_ref[:, pl.ds(half + chunk * lanes,
+                                                  lanes)])
+                           & jnp.uint32(0xFFFF0000))
+        out_ref[pl.ds(chunk, bm, stride=chunks)] = word
 
 
 def _words(rows, interpret):
-    """bfloat16 ``rows`` [M, E] as 32-bit words [M, 1, E/2], column c of
-    the left half in a word's low bits and column c of the right half in its
-    high bits, each row's words contiguous in memory: a row DMA moves whole
-    32-bit words of ONE row, and a tiled 16-bit array keeps two rows in each
-    word and eight such words' rows in a tile.  One pass."""
-    m, width = rows.shape[0], rows.shape[1] // 2
+    """bfloat16 ``rows`` [M, E] as 32-bit words [M, 1, ``_half(E)``], column
+    c of the left half in a word's low bits and column c of the right half
+    in its high bits, each row's words contiguous in memory: a row DMA moves
+    whole 32-bit words of ONE row, and a tiled 16-bit array keeps two rows
+    in each word and eight such words' rows in a tile.  One pass."""
+    m, width = rows.shape[0], _half(rows.shape[1])
     lanes = _lanes(width)
     chunks = width // lanes
     bm = min(WORDS_BLOCK, m)
     return pl.pallas_call(
         _words_kernel, grid=(-(-m // bm),),
-        in_specs=[pl.BlockSpec((bm, 2 * width), lambda b: (b, 0))],
+        in_specs=[pl.BlockSpec((bm, rows.shape[1]), lambda b: (b, 0))],
         out_specs=pl.BlockSpec((bm * chunks, lanes), lambda b: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((m * chunks, lanes), jnp.uint32),
         compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
@@ -185,7 +199,8 @@ def _rows_sum_kernel(count_ref, to_ref, place_ref, rows_ref, out_ref, buf,
                     low = low + some.astype(jnp.float32)
             out_ref[tokens, pl.ds(chunk * lanes, lanes)] = low.astype(
                 out_ref.dtype)
-            if words:
+            # (of an odd number of registers the last word has no high half)
+            if words and width + chunk * lanes < out_ref.shape[1]:
                 out_ref[tokens, pl.ds(width + chunk * lanes, lanes)] = (
                     high.astype(out_ref.dtype))
         return c

@@ -64,6 +64,10 @@ POST_NORM = "post_norm"
 # filter, the step sizes' chain, the output gate) and, inside it, the
 # selective scan's kernels
 MAMBA, SELECTIVE_SCAN = "mamba", "selective_scan"
+# the Mamba-2 mixer as a layer's one branch (both projections, the causal
+# filter, the step sizes, the gated group norm) and, inside it, the chunked
+# state-space dual form's kernels
+MAMBA2, SSD_SCAN = "mamba2", "ssd_scan"
 # the scan over the stacked layers itself: its slices of each layer's leaves,
 # the activations it keeps for the backward pass and the gradients it stacks
 # (a layer's own work carries the layer's scopes, which lie further in)
@@ -71,7 +75,7 @@ LAYER_SCAN = "layer_scan"
 VOCABULARY = (EMBED, ATTENTION, MLP, LAYER_NORM, LM_HEAD, CONV, BN, POOL, FC,
               LOSS, GRAD_SYNC, OPTIMIZER, MOE, ROUTER, SHORT_CONV, RETENTION,
               LATENT_ATTENTION, SHARED_EXPERT, LAYER_SCAN, ATTN_GATE,
-              POST_NORM, MAMBA, SELECTIVE_SCAN)
+              POST_NORM, MAMBA, SELECTIVE_SCAN, MAMBA2, SSD_SCAN)
 PHASES = ("forward", "backward", "recompute", GRAD_SYNC, OPTIMIZER)
 
 # `%fusion.12 = bf16[..] fusion(%p.1, %copy-done.2), ..., metadata={op_name="jit(multi)/..." ...}`:

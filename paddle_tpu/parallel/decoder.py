@@ -2,7 +2,8 @@
 (``transformer.py``) and the step it runs (``train.py``): forward, loss,
 ``logits_at``, a call's observations and the builder, ONE of each for every
 decoder of this block.  A model (``models/olmoe.py``, ``smallthinker.py``,
-``lfm2.py``, ``brumby.py``, ``mistral4.py``, ``trinity.py``, ``jamba.py``)
+``lfm2.py``, ``brumby.py``, ``mistral4.py``, ``trinity.py``, ``jamba.py``,
+``nemotron_h.py``)
 is a ``TransformerConfig`` and a label; what its trainer computes and what
 it observes follow from the configuration, never from which model it is.
 
@@ -26,8 +27,8 @@ from .mesh import DP, MeshSpec, local_shard_map
 from .train import (StepTrainer, TrainState, gauge_flash_grid,
                     make_train_step, shard_pytree, state_specs)
 from .transformer import (
-    CONV,
     MAMBA,
+    MAMBA2,
     RETENTION,
     TransformerConfig,
     embed,
@@ -35,6 +36,7 @@ from .transformer import (
     grad_sync_axes,
     head_logits,
     init_transformer_params,
+    mamba2_operands,
     mamba_operands,
     retention_log_decay,
     rms_norm,
@@ -166,6 +168,7 @@ class DecoderTrainer(StepTrainer):
 
     label: str = "decoder"
     _logits_fn = _routing_fn = _gate_fn = _attn_gate_fn = _mamba_fn = None
+    _mamba2_fn = None
 
     def _on_mesh(self, fn, out_specs, *more):
         """``fn(params, ids [b, S], *more)`` jitted over the mesh, the
@@ -221,6 +224,12 @@ class DecoderTrainer(StepTrainer):
           token, channel and state cell there: with the mean's own decay
           ``exp(-dt)`` it tells a state that never carries (both near 0)
           from one that never forgets (both near 1);
+        - a layer kind is MAMBA2: ``mamba2_dt_mean`` and
+          ``mamba2_decay_min`` (the smallest ``exp(dt A)`` of any token and
+          head: how far the carry reaches), of the period's first such
+          position on the call's first batch as the EMBEDDING hands it over
+          (the stream as it enters the stack, not as that layer finds it:
+          at seeded weights the step sizes are their bias's);
         - ``attn_gate``: ``attn_gate_mean``, the mean of the output gate's
           sigmoid over tokens, heads and columns of the call's first batch
           in the first layer: a gate stuck at 0 or 1 is a dead branch;
@@ -245,7 +254,7 @@ class DecoderTrainer(StepTrainer):
         def count(name, amount):
             mon.registry.counter("monitor.train." + name).incr(amount)
 
-        if any(k not in (CONV, RETENTION, MAMBA) for k in cfg.layer_kinds):
+        if any(isinstance(k, tuple) for k in cfg.layer_kinds):
             gauge_flash_grid(cfg, local, seq)
         if cfg.n_experts:
             pairs = int(ids.size) * cfg.experts_per_token * cfg.moe_layers
@@ -297,6 +306,23 @@ class DecoderTrainer(StepTrainer):
             dt_mean, decay_min = self._mamba_fn(params, batches[0])
             gauge("mamba_dt_mean", float(dt_mean))
             gauge("mamba_decay_min", float(decay_min))
+        if MAMBA2 in cfg.layer_kinds:
+            if self._mamba2_fn is None:
+                at = cfg.layer_kinds.index(MAMBA2)
+
+                def step_sizes2(params, ids):
+                    pl = jax.tree.map(lambda a: a[0],
+                                      params["params_layers"]["p%d" % at])
+                    h = rms_norm(embed(params, ids, cfg), pl["ln1_scale"],
+                                 cfg.norm_eps)
+                    dt = mamba2_operands(pl, h, cfg)[2]
+                    return jnp.mean(dt), jnp.exp(-jnp.max(
+                        jnp.max(dt, axis=(0, 1)) * jnp.exp(pl["a_log"])))
+
+                self._mamba2_fn = self._on_mesh(step_sizes2, (P(), P()))
+            dt_mean, decay_min = self._mamba2_fn(params, batches[0])
+            gauge("mamba2_dt_mean", float(dt_mean))
+            gauge("mamba2_decay_min", float(decay_min))
         if cfg.attn_gate:
             if self._attn_gate_fn is None:
                 def attn_gate_mean(params, ids):

@@ -66,7 +66,8 @@ TOP_K_SOFTMAX = "top_k_softmax"     # the k largest logits, softmax over those k
 # bias, over their sum
 SIGMOID_BIASED = "sigmoid_biased_top_k"
 RULES = (SOFTMAX_TOP_K, TOP_K_SOFTMAX, SIGMOID_BIASED)
-ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+               "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
 
 def _normal(key, shape, fan_in, dtype):
@@ -403,8 +404,11 @@ def _expert_ffn(x, top_p, top_e, w_gate_up, w_down, k, act, first, rows_max):
     """``sum_e p_te * down_e(act(gate_e x_t) * up_e x_t)`` over the experts
     [first, first + count) that ``w_gate_up`` [count, E, 2F] / ``w_down``
     hold, through ``rows_max`` sorted rows, which cover the pairs that meet
-    a held expert (None: every expert is held and every pair has a row)."""
+    a held expert (None: every expert is held and every pair has a row).
+    Where ``w_gate_up`` is as wide as ``w_down`` is tall ([count, E, F]) the
+    experts are UNGATED: ``down_e(act(up_e x_t))``."""
     count, absent = w_gate_up.shape[0], rows_max is not None
+    gated = w_gate_up.shape[2] == 2 * w_down.shape[1]
     key = top_e
     if absent:
         held = _held(top_e.reshape(-1), first, count)
@@ -425,10 +429,14 @@ def _expert_ffn(x, top_p, top_e, w_gate_up, w_down, k, act, first, rows_max):
         # as no pair's place points at them
         live = jnp.arange(rows_max) < jnp.sum(group_sizes)
         weight = jnp.where(live, weight[:rows_max], 0.0)
-    gate, up = jnp.split(
-        _grouped_matmul(rows, w_gate_up, group_sizes), 2, axis=-1)
-    hidden = (ACTIVATIONS[act](gate.astype(jnp.float32))
-              * up.astype(jnp.float32) * weight[:, None]).astype(x.dtype)
+    up = _grouped_matmul(rows, w_gate_up, group_sizes)
+    if gated:
+        gate, up = jnp.split(up, 2, axis=-1)
+        hidden = (ACTIVATIONS[act](gate.astype(jnp.float32))
+                  * up.astype(jnp.float32) * weight[:, None]).astype(x.dtype)
+    else:
+        hidden = (ACTIVATIONS[act](up.astype(jnp.float32))
+                  * weight[:, None]).astype(x.dtype)
     out = _grouped_matmul(hidden, w_down, group_sizes)
     return _combine(out, *place, k)
 
@@ -487,6 +495,8 @@ def dropless_moe_ffn(params, x, k, rule=SOFTMAX_TOP_K, act="silu",
     """Top-k dropless expert FFN.  x [T, E] (flatten batch and sequence
     before the call); returns ``(y [T, E], aux)`` with
     ``y_t = sum_{e in top k, held} p_te * down_e(act(gate_e x_t) * up_e x_t)``
+    (ungated experts, whose one up matrix is the leaf ``we_up``:
+    ``down_e(act(up_e x_t))``)
     and ``aux`` as ``route_top_k`` gives it (``rule``, ``logits``, ``bias``
     and ``scale`` are its).  ``we_gate_up`` / ``we_down`` hold the experts
     [first_held, first_held + their leading size) of the router's n: all of
@@ -499,11 +509,12 @@ def dropless_moe_ffn(params, x, k, rule=SOFTMAX_TOP_K, act="silu",
     gradient then needs the hidden rows, which the down matmul keeps anyway,
     and nothing computed after them: no residual follows the down
     projection, and a rematerialised forward stops at the gate/up matmul."""
-    n, count = params["router"].shape[-1], params["we_gate_up"].shape[0]
+    w_up = params["we_gate_up" if "we_gate_up" in params else "we_up"]
+    n, count = params["router"].shape[-1], w_up.shape[0]
     assert 0 <= first_held and first_held + count <= n, (first_held, count, n)
     top_p, top_e, aux = route_top_k(params["router"], x, k, rule, logits,
                                     bias, scale)
-    ffn = (x, top_p, top_e, params["we_gate_up"], params["we_down"])
+    ffn = (x, top_p, top_e, w_up, params["we_down"])
     if count == n:
         return _expert_ffn(*ffn, k, act, 0, None), aux
     caps = _held_capacities(top_e.size, count, n)

@@ -262,10 +262,11 @@ def transformer_rules(cfg):
         # so does a stack whose runs are stacked [periods, run length, ...]
         # (``run_scan``), and the Mamba mixer's leaves wherever they stand
         (r"^params_layers/r\d+/", P()),
+        # (Mamba-1's or Mamba-2's)
         (r"/(w_in|conv_b|w_x|dt_norm|b_norm|c_norm|w_dt|b_dt|a_log|d_skip"
-         r"|w_out)$", P()),
-        (r"/(conv_in|conv_w|conv_out|w_gate_up|w_down|ws_gate_up|ws_down)$",
-         L(None, None)),
+         r"|gate_norm|w_out)$", P()),
+        (r"/(conv_in|conv_w|conv_out|w_gate_up|w_down|ws_gate_up|ws_up"
+         r"|ws_down)$", L(None, None)),
         # latent attention runs at tp == 1 (TransformerConfig)
         (r"/(wq_a|wq_b|wkv_a|wkv_b)$", L(None, None)),
         (r"/(q_a_norm|kv_a_norm)$", L(None)),
@@ -282,7 +283,7 @@ def transformer_rules(cfg):
         # leaves are whole on every device
         (r"/(q_norm|k_norm)$", L(None)),
         (r"/(router|wg)$", L(None, None)),
-        (r"/we_(gate_up|down)$", L(None, None, None)),
+        (r"/we_(gate_up|up|down)$", L(None, None, None)),
     ]
 
 

@@ -37,10 +37,10 @@ from ..monitor import devscope
 from ..monitor.recompile import compile_ledger
 from .ring_attention import ring_attention
 
-__all__ = ["TransformerConfig", "CONV", "RETENTION", "MAMBA",
+__all__ = ["TransformerConfig", "CONV", "RETENTION", "MAMBA", "MAMBA2", "FFN",
            "init_transformer_params", "transformer_param_specs",
            "grad_sync_axes", "embed", "transformer_layer", "run_layers",
-           "mamba_mixer", "mamba_operands",
+           "mamba_mixer", "mamba_operands", "mamba2_mixer", "mamba2_operands",
            "rms_norm", "rope", "rope_pairs", "yarn_blend_range",
            "yarn_frequencies",
            "yarn_softmax_scale", "yarn_rotary_factor", "final_logits_loss",
@@ -55,7 +55,14 @@ RETENTION = "retention"
 # convolution, step sizes and rates of its own for every channel and state
 # cell, a selective scan carried along the sequence, an output gate
 MAMBA = "mamba"
-_OWN_LEAVES = (CONV, RETENTION, MAMBA)  # kinds whose position owns other leaves
+# a layer kind: the Mamba-2 mixer (the state-space dual form): ONE scalar
+# decay a head, B and C shared by the heads of a group, a [head width, state
+# cells] state a head carried from chunk to chunk, a gated group norm
+MAMBA2 = "mamba2"
+# a layer kind of a ``single_branch`` stack: the feed-forward part alone
+FFN = "ffn"
+# kinds whose position owns other leaves
+_OWN_LEAVES = (CONV, RETENTION, MAMBA, MAMBA2, FFN)
 
 
 def _kinds(pattern):
@@ -111,6 +118,10 @@ class TransformerConfig:
     # bias by, against its load (``moe.balance_bias``)
     router_bias_rate: float = 0.0
     expert_act: str = "silu"         # the gate's activation (moe.ACTIVATIONS)
+    # False: UNGATED experts, ``down(act(up(x)))``, one up matrix (leaves
+    # ``we_up`` [held, E, F], ``ws_up`` [E, Fs]) where the gated ones hold
+    # gate and up side by side
+    expert_gated: bool = True
     router_input: str = "ffn"        # "ffn": the normed FFN input | "block":
     # the block's input, before its first norm and before attention
     # the experts this device holds, of the router's n_experts (0: all):
@@ -130,7 +141,8 @@ class TransformerConfig:
     # ``n_kv_heads``) and replaces the softmax by power retention
     # (``power_retention``), with a gate projection ``wg`` of its own; or
     # MAMBA, a layer whose operator is the Mamba-1 mixer (``mamba_mixer``)
-    # and which has no attention leaves.
+    # and which has no attention leaves; or MAMBA2, the same of the Mamba-2
+    # mixer (``mamba2_mixer``); or, in a ``single_branch`` stack, FFN.
     # Empty: one kind, full attention, rotary as ``positions`` says.
     # n_layers is ``prefix_pattern`` and whole periods.
     layer_pattern: tuple = ()
@@ -151,6 +163,15 @@ class TransformerConfig:
     d_conv: int = 0
     dt_rank: int = 0
     scan_chunk: int = 128
+    # MAMBA2 (with d_inner, d_state, d_conv and scan_chunk): the heads
+    # (``d_inner / ssm_heads`` channels and ONE decay each) and the groups
+    # of heads that share B and C
+    ssm_heads: int = 0
+    ssm_groups: int = 0
+    # every layer is ONE pre-norm residual branch, ``x + branch(norm(x))``:
+    # a mixer position (attention, MAMBA2, ...) has no FFN and no second
+    # norm, and the feed-forward part is a position of its own, FFN
+    single_branch: bool = False
     # a RUN, consecutive positions of one kind inside a period, is one tree
     # ``params_layers["r<i>"]`` stacked [n_periods, run length, ...] and one
     # inner scan of the period's body (``run_layers``), where each position
@@ -203,6 +224,15 @@ class TransformerConfig:
     # at 4 of 32 and a whole one at 4 of 256, where it empties some experts
     # and sends others eight times the mean
     router_bias_std: float = 0.1
+    # what every branch's OUTPUT projection (attention's ``wo``, the Mamba-2
+    # mixer's ``w_out``, the experts' and the shared expert's down matrices)
+    # is SEEDED times, in a stack whose layers own their leaves; off 1 the
+    # embedding's rows are seeded N(0, 1), so that the stream a router reads
+    # is the token's own row and small branch outputs.  At 1 a branch re-enters
+    # the stream at unit scale with what it computes for EVERY token alike
+    # (the positive mean of ``relu^2`` hidden rows, a slow state's running
+    # mean), and a router downstream ranks the experts alike for every token
+    residual_out_gain: float = 1.0
     # what the k routing weights are multiplied by, once formed
     route_scale: float = 1.0
     # what the embedding's rows are multiplied by as they enter the stream
@@ -246,6 +276,17 @@ class TransformerConfig:
         if MAMBA in self.layer_pattern + self.prefix_pattern:
             assert self.d_inner and self.d_state and self.d_conv \
                 and self.dt_rank
+        if MAMBA2 in self.layer_pattern + self.prefix_pattern:
+            assert self.d_state and self.d_conv and self.ssm_groups \
+                and self.ssm_heads % self.ssm_groups == 0 \
+                and self.d_inner % self.ssm_heads == 0
+        # a feed-forward position exists in a single-branch stack alone,
+        # which has one and whose layers are the period's (no leading ones,
+        # no output norms, every run of length one)
+        assert (FFN in self.layer_pattern) == self.single_branch \
+            and FFN not in self.prefix_pattern
+        assert not self.single_branch or not (
+            self.prefix_pattern or self.post_norm or self.run_scan)
         assert self.per_position or not self.run_scan
         if self.latent:
             assert self.positions == "rotary" and self.tp == 1 \
@@ -266,6 +307,7 @@ class TransformerConfig:
             # the norm of a branch's output needs the whole row: no tp yet
             assert self.norm == "rms" and self.tp == 1 and not self.bias
         assert self.route_scale == 1.0 or self.n_experts
+        assert self.residual_out_gain == 1.0 or self.per_position
 
     @property
     def head_dim(self):
@@ -286,8 +328,8 @@ class TransformerConfig:
 
     @property
     def layer_kinds(self):
-        """(window or None, rotary), or CONV, RETENTION or MAMBA, of each
-        layer of one period."""
+        """(window or None, rotary), or CONV, RETENTION, MAMBA, MAMBA2 or
+        FFN, of each layer of one period."""
         if not self.layer_pattern:
             return ((None, self.positions == "rotary"),)
         return _kinds(self.layer_pattern)
@@ -323,9 +365,17 @@ class TransformerConfig:
             // len(self.layer_kinds)
 
     @property
+    def ffn_positions(self):
+        """Which positions of one period have a feed-forward part: all of
+        them, or in a ``single_branch`` stack the FFN positions."""
+        return tuple(k == FFN or not self.single_branch
+                     for k in self.layer_kinds)
+
+    @property
     def moe_layers(self):
-        """Layers whose FFN is the MoE: all but the leading ones."""
-        return self.n_layers - len(self.prefix_pattern) if self.n_experts \
+        """Layers whose FFN is the MoE: every feed-forward part but the
+        leading layers'."""
+        return self.n_periods * sum(self.ffn_positions) if self.n_experts \
             else 0
 
     @property
@@ -403,7 +453,8 @@ def _init_params(key, cfg):
         # seed (PERF.md section 6, PRs 31 and 39)
         "tok_emb": _dense_init(
             ks[1], 1 if cfg.n_experts and (
-                cfg.router_input == "block" or _unbalanced_share(cfg)) else E,
+                cfg.router_input == "block" or _unbalanced_share(cfg)
+                or cfg.residual_out_gain != 1.0) else E,
             (V, E), dt),
         "lnf_scale": jnp.ones((E,), jnp.float32),
         **layers,
@@ -511,13 +562,16 @@ def _ffn_leaves(stack, cfg, fold, dense):
         return dict(w_gate_up=stack(fold, E, (E, 2 * F)),
                     w_down=stack(fold + 1, F, (F, E)))
     F, held = cfg.ffn_hidden, cfg.experts_here
-    leaves = dict(router=stack(fold, E, (E, cfg.n_experts), jnp.float32),
-                  we_gate_up=stack(fold + 1, E, (held, E, 2 * F)),
-                  we_down=stack(fold + 2, F, (held, F, E)))
+    # ungated experts (``expert_gated`` off) hold ONE up matrix, ``we_up``
+    # [held, E, F] / ``ws_up`` [E, Fs], where the gated hold two side by side
+    up, columns = ("gate_up", 2) if cfg.expert_gated else ("up", 1)
+    leaves = {"router": stack(fold, E, (E, cfg.n_experts), jnp.float32),
+              "we_" + up: stack(fold + 1, E, (held, E, columns * F)),
+              "we_down": stack(fold + 2, F, (held, F, E))}
     if cfg.shared_ffn_hidden:
         Fs = cfg.shared_ffn_hidden
-        leaves.update(ws_gate_up=stack(13, E, (E, 2 * Fs)),
-                      ws_down=stack(14, Fs, (Fs, E)))
+        leaves.update({"ws_" + up: stack(13, E, (E, columns * Fs)),
+                       "ws_down": stack(14, Fs, (Fs, E))})
     return leaves
 
 
@@ -545,9 +599,11 @@ def _position_leaves(key, cfg, kind, n, dense):
     and C and the value, side by side), ``conv_w`` [taps, E] (tap j meets
     position t - taps + 1 + j) and ``conv_out`` [E, E], for RETENTION
     attention's and the gate projection ``wg`` [E, kv_heads] float32 (one
-    log-decay a key/value head and token), for MAMBA ``_mamba_leaves``';
-    what ``_branch_leaves`` adds; then the FFN's (``_ffn_leaves``), dense or
-    the MoE's."""
+    log-decay a key/value head and token), for MAMBA ``_mamba_leaves``', for
+    MAMBA2 ``_mamba2_leaves``'; what ``_branch_leaves`` adds; then the FFN's
+    (``_ffn_leaves``), dense or the MoE's.  In a ``single_branch`` stack a
+    position owns its ONE branch's leaves: the mixer's behind ``ln1_scale``,
+    or (FFN) the feed-forward part's behind ``ln2_scale``."""
     assert cfg.norm == "rms" and not cfg.bias
     E, dt = cfg.hidden, cfg.jdtype
     Q, KV = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
@@ -557,23 +613,34 @@ def _position_leaves(key, cfg, kind, n, dense):
         return jax.vmap(lambda k: _dense_init(
             jax.random.fold_in(k, fold), fan_in, shape, dtype))(keys)
 
-    leaves = {"ln1_scale": jnp.ones((n, E), jnp.float32),
-              "ln2_scale": jnp.ones((n, E), jnp.float32)}
+    leaves = {}
+    if kind != FFN:
+        leaves["ln1_scale"] = jnp.ones((n, E), jnp.float32)
     if kind == CONV:
         leaves.update(conv_in=stack(1, E, (E, 3 * E)),
                       conv_w=stack(2, cfg.conv_taps, (cfg.conv_taps, E)),
                       conv_out=stack(3, E, (E, E)))
     elif kind == MAMBA:
         leaves.update(_mamba_leaves(stack, keys, cfg))
-    else:
+    elif kind == MAMBA2:
+        leaves.update(_mamba2_leaves(stack, keys, cfg))
+    elif kind != FFN:
         leaves.update(wq=stack(1, E, (E, Q)), wk=stack(2, E, (E, KV)),
                       wv=stack(3, E, (E, KV)), wo=stack(4, Q, (Q, E)))
         leaves.update(_qk_norm_leaves(cfg, n))
         if kind == RETENTION:
             leaves["wg"] = stack(10, E, (E, cfg.kv_heads), jnp.float32)
-    leaves.update(_branch_leaves(stack, cfg, n, 11,
-                                 attention=kind not in (CONV, MAMBA)))
-    leaves.update(_ffn_leaves(stack, cfg, 5 if dense else 7, dense))
+    leaves.update(_branch_leaves(
+        stack, cfg, n, 11,
+        attention=kind not in (CONV, MAMBA, MAMBA2, FFN)))
+    if kind == FFN or not cfg.single_branch:
+        leaves["ln2_scale"] = jnp.ones((n, E), jnp.float32)
+        leaves.update(_ffn_leaves(stack, cfg, 5 if dense else 7, dense))
+    if cfg.residual_out_gain != 1.0:
+        for name in ("wo", "w_out", "w_down", "we_down", "ws_down"):
+            if name in leaves:
+                leaves[name] = (leaves[name].astype(jnp.float32)
+                                * cfg.residual_out_gain).astype(dt)
     return leaves
 
 
@@ -608,6 +675,45 @@ def _mamba_leaves(stack, keys, cfg):
         a_log=jnp.tile(jnp.log(jnp.arange(1, N + 1, dtype=f32)), (n, d, 1)),
         d_skip=jnp.ones((n, d), f32),
         w_out=stack(25, d, (d, E)))
+
+
+def _mamba2_leaves(stack, keys, cfg):
+    """The Mamba-2 mixer's leaves of ``len(keys)`` stacked layers, W = d + 2
+    G N the filter's channels (x, then each group's B, then each group's C):
+    ``w_in`` [E, W + d], the published ``in_proj``'s ``xBC`` columns and then
+    its ``z`` columns (the filter's kernel reads a packed projection's FIRST
+    lanes in place), ``w_dt`` [E, heads], its step-size columns (a
+    projection of its own: 64 columns behind 10,240 would leave the packed
+    width no whole lane block); ``conv_w`` [taps, W] (tap j meets position t
+    - taps + 1 + j) and ``conv_b`` [W]; ``b_dt``, ``a_log`` and ``d_skip``
+    [heads] and ``gate_norm`` [d], float32; ``w_out`` [d, E].
+
+    Matrices, the filter and its bias at their fan-in's scale; ``a_log`` the
+    log of a rate drawn uniform in [1, 16] a head and ``d_skip`` = 1 (the
+    published mixer's own constructor); ``b_dt`` the inverse softplus of a
+    step size drawn log-uniform in [1e-3, 1e-1] and floored at 1e-4 (the
+    published ``time_step_min`` / ``_max`` / ``_floor``): a head's decay
+    ``exp(-dt rate)`` then runs from 0.999 to 0.2 a token."""
+    E, d, nh = cfg.hidden, cfg.d_inner, cfg.ssm_heads
+    W = d + 2 * cfg.ssm_groups * cfg.d_state
+    n, f32 = len(keys), jnp.float32
+
+    def drawn(fold, low, high):
+        return jax.vmap(lambda k: jax.random.uniform(
+            jax.random.fold_in(k, fold), (nh,), f32, low, high))(keys)
+
+    dt0 = jnp.maximum(jnp.exp(drawn(36, math.log(1e-3), math.log(1e-1))),
+                      1e-4)
+    return dict(
+        w_in=stack(30, E, (E, W + d)),
+        w_dt=stack(31, E, (E, nh)),
+        conv_w=stack(32, cfg.d_conv, (cfg.d_conv, W)),
+        conv_b=stack(33, cfg.d_conv, (W,)),
+        b_dt=dt0 + jnp.log(-jnp.expm1(-dt0)),       # softplus^-1(dt0)
+        a_log=jnp.log(drawn(37, 1.0, 16.0)),
+        d_skip=jnp.ones((n, nh), f32),
+        gate_norm=jnp.ones((n, d), f32),
+        w_out=stack(34, d, (d, E)))
 
 
 def _router_bias(key, cfg):
@@ -1263,6 +1369,70 @@ def mamba_mixer(pl, h, cfg):
     return y @ pl["w_out"]
 
 
+def mamba2_operands(pl, h, cfg):
+    """What the Mamba-2 scan reads of ``h`` [b, S, E], the whole sequence:
+    the filtered channels ``xBC = silu(conv(.) + conv_b)`` [b, S, W] (x in
+    the first ``d_inner`` lanes, then each group's B, then each group's C),
+    the packed projection ``[xBC | z]`` that holds the gate behind them, and
+    the step sizes ``softplus(h @ w_dt + b_dt)`` [b, S, heads] float32.  The
+    filter, its bias and ``silu`` are ``kernels/mamba_filter.py``'s one pass
+    each way on the packed projection's own first lanes where it takes the
+    shapes, and the ``jnp`` lines (its reference) elsewhere."""
+    from ..kernels import mamba_filter as mf
+
+    W = cfg.d_inner + 2 * cfg.ssm_groups * cfg.d_state
+    packed = h @ pl["w_in"]
+    fused = mf.supported(h.shape[:2] + (W,), cfg.d_conv,
+                         h.dtype.itemsize) \
+        and packed.shape[-1] % mf.block_lanes(W) == 0
+    mon = monitor.active()
+    if mon is not None:
+        mon.registry.counter("monitor.kernels.mamba_filter_calls",
+                             fused=int(fused), halo="zeros").incr()
+    if fused:
+        xbc = mf.mamba_filter(packed, pl["conv_w"], pl["conv_b"], width=W)
+    else:
+        xbc = mf.mamba_filter_reference(packed[..., :W], pl["conv_w"],
+                                        pl["conv_b"])
+    dt = jax.nn.softplus(jnp.matmul(
+        h, pl["w_dt"], preferred_element_type=jnp.float32) + pl["b_dt"])
+    return xbc, packed, dt
+
+
+@devscope.scoped(devscope.MAMBA2)
+def mamba2_mixer(pl, h, cfg):
+    """The Mamba-2 mixer (Dao and Gu, arXiv:2405.21060) on ``h`` [b, S, E],
+    the whole sequence: ``[xBC | z] = h @ w_in`` and the step sizes off
+    ``w_dt`` (``mamba2_operands``); for head i of group g, at the rate ``A_i
+    = -exp(a_log_i)``, the state ``H_t = exp(dt_t A_i) H_{t-1} + dt_t x_t (x)
+    B_t`` [head width, d_state] float32 and ``y_t = H_t C_t + d_skip_i x_t``
+    (``kernels/ssd_scan.py``, the chunked dual form in chunks of
+    ``cfg.scan_chunk`` tokens clamped to S; the same form in ``jnp`` where
+    the kernels do not take the shapes); the gate BEFORE the norm, ``y *
+    silu(z)``, RMS-normed over each group's ``d_inner / ssm_groups``
+    channels by ``gate_norm``; then ``w_out``.  Filter, step sizes, state
+    and norm in float32."""
+    from ..kernels import ssd_scan as ssd
+
+    d, G, N, nh = cfg.d_inner, cfg.ssm_groups, cfg.d_state, cfg.ssm_heads
+    xbc, packed, dt = mamba2_operands(pl, h, cfg)
+    chunk = min(cfg.scan_chunk, h.shape[1])
+    kernel = ssd.supported(xbc.shape, nh, G, N, chunk)
+    mon = monitor.active()
+    if mon is not None:
+        mon.registry.counter("monitor.kernels.ssd_scan_calls",
+                             fused=int(kernel)).incr()
+    with jax.named_scope(devscope.SSD_SCAN):
+        y = (ssd.ssd_scan if kernel else ssd.ssd_scan_chunked)(
+            xbc, dt, -jnp.exp(pl["a_log"]), pl["d_skip"], heads=nh,
+            groups=G, d_state=N, chunk=chunk)
+    gated = y.astype(jnp.float32) * jax.nn.silu(
+        packed[..., xbc.shape[-1]:].astype(jnp.float32))
+    normed = _rms(gated.reshape(gated.shape[:2] + (G, d // G)), 1.0,
+                  cfg.norm_eps).reshape(gated.shape) * pl["gate_norm"]
+    return normed.astype(h.dtype) @ pl["w_out"]
+
+
 # rows x width of a pointwise stage's widest activation (an FFN's hidden
 # rows, the projections between their matmuls and the kernel) past which it
 # runs a block of positions at a time, each block's forward run again in its
@@ -1305,14 +1475,19 @@ def gated_ffn(pl, h, cfg):
     """The dense gated FFN ``(act(h @ Wg) * (h @ Wu)) @ w_down`` on ``h``
     [b, S, E], ``[Wg, Wu] = w_gate_up`` [E, 2F]; gate and product in
     float32; in row blocks where the hidden activation is large
-    (``_by_row_blocks``)."""
+    (``_by_row_blocks``).  Where ``pl`` holds ``w_up`` [E, F] instead, the
+    UNGATED ``act(h @ w_up) @ w_down``."""
     from .moe import ACTIVATIONS
 
+    act = ACTIVATIONS[cfg.expert_act]
+
     def rows_ffn(rows, first):
-        gate, up = jnp.split(rows @ pl["w_gate_up"], 2, axis=-1)
-        hidden = (ACTIVATIONS[cfg.expert_act](gate.astype(jnp.float32))
-                  * up.astype(jnp.float32)).astype(rows.dtype)
-        return hidden @ pl["w_down"]
+        if "w_up" in pl:
+            hidden = act((rows @ pl["w_up"]).astype(jnp.float32))
+        else:
+            gate, up = jnp.split(rows @ pl["w_gate_up"], 2, axis=-1)
+            hidden = act(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+        return hidden.astype(rows.dtype) @ pl["w_down"]
 
     return _by_row_blocks(rows_ffn, h, pl["w_down"].shape[0])
 
@@ -1331,19 +1506,24 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
     """One pre-norm block on the SP activation [b, S/tp, E]: the new
     activation and the FFN's auxiliary values (the MoE's, ``moe.route_top_k``;
     None for a dense FFN).  ``kind`` = (window or None, rotary), or CONV,
-    RETENTION or MAMBA: which of ``cfg.layer_kinds`` this layer is (None: the
-    first);
+    RETENTION, MAMBA or MAMBA2: which of ``cfg.layer_kinds`` this layer is
+    (None: the first).  In a ``single_branch`` stack the layer is ONE of the
+    two branches: the mixer's alone (auxiliary values None), or where
+    ``kind`` is FFN the feed-forward part's alone;
     ``dense``: a layer whose FFN is the dense gated one (a leading layer, or
     any layer of a stack without experts); ``router_bias`` [n]:
     this layer's selection biases, where the routing rule has them."""
     heads_mode = cfg.attn_mode == "heads"
     logits = None
-    if cfg.n_experts and cfg.router_input == "block" and not dense:
+    if cfg.n_experts and cfg.router_input == "block" and not dense \
+            and (kind == FFN or not cfg.single_branch):
         from .moe import router_logits
 
         # the router reads the residual stream as it ENTERS the block
         logits = router_logits(pl["router"], x_sp.reshape(-1, x_sp.shape[-1]))
-    if kind == CONV:
+    if kind == FFN:
+        pass                            # the feed-forward part alone
+    elif kind == CONV:
         with jax.named_scope(devscope.SHORT_CONV):
             x_sp = _add_branch(x_sp, short_conv(
                 pl, _norm(x_sp, pl, "ln1", cfg)), pl, "ln1", cfg)
@@ -1354,6 +1534,10 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
     elif kind == MAMBA:
         with jax.named_scope(devscope.MAMBA):
             x_sp = _add_branch(x_sp, mamba_mixer(
+                pl, _norm(x_sp, pl, "ln1", cfg), cfg), pl, "ln1", cfg)
+    elif kind == MAMBA2:
+        with jax.named_scope(devscope.MAMBA2):
+            x_sp = _add_branch(x_sp, mamba2_mixer(
                 pl, _norm(x_sp, pl, "ln1", cfg), cfg), pl, "ln1", cfg)
     else:
         with jax.named_scope(devscope.LATENT_ATTENTION if cfg.latent
@@ -1367,6 +1551,8 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
                 attn = _attention_ring_mode(pl, h, cfg)
             x_sp = _add_branch(x_sp, attn, pl, "ln1", cfg)
 
+    if cfg.single_branch and kind != FFN:
+        return x_sp, None
     if dense:
         with jax.named_scope(devscope.MLP):
             return _add_branch(x_sp, gated_ffn(
@@ -1391,7 +1577,9 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
         # shares counts it once: no 129th group of the grouped matmul
         with jax.named_scope(devscope.SHARED_EXPERT):
             shared = gated_ffn(
-                {"w_gate_up": pl["ws_gate_up"], "w_down": pl["ws_down"]},
+                {name.replace("ws_", "w_"): pl[name]
+                 for name in ("ws_gate_up", "ws_up", "ws_down")
+                 if name in pl},
                 h, cfg)
             if not cfg.post_norm:
                 return x_sp + shared, aux
@@ -1428,7 +1616,9 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
     tree for each position, stacked [L / period, ...], the leading layers
     (``prefix``, a tree each) run before the scan under the same remat, and
     ``router_bias`` [moe_layers, n] is read a period's rows a turn.  One
-    kind is the scan over layers it always was.
+    kind is the scan over layers it always was.  A ``single_branch`` stack
+    is the same per-position scan: each position one branch, the auxiliary
+    values and the biases' rows those of its FFN positions.
 
     A RUN is consecutive positions of one kind inside a period.  With
     ``cfg.run_scan`` ``layer_params`` holds a tree for each run, stacked
@@ -1460,8 +1650,11 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
     else:
         at_position = jax.tree.map(
             lambda a: a.reshape((-1, len(kinds)) + a.shape[1:]), layer_params)
+    # a period's feed-forward parts, which the biases' rows follow: every
+    # position's, or a single-branch stack's FFN positions'
+    ffn_at = np.cumsum(cfg.ffn_positions) - 1
     if router_bias is not None:
-        router_bias = router_bias.reshape((-1, len(kinds))
+        router_bias = router_bias.reshape((-1, sum(cfg.ffn_positions))
                                           + router_bias.shape[1:])
 
     def period(x, turn):
@@ -1470,10 +1663,12 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
         for at, kind in enumerate(kinds):
             pl = pls[at] if cfg.per_position \
                 else jax.tree.map(lambda a: a[at], pls)
+            own = biases is not None and cfg.ffn_positions[at]
             x, aux = body(pl, x, cfg, kind,
                           cfg.per_position and not cfg.n_experts,
-                          None if biases is None else biases[at])
-            auxes.append(aux)
+                          biases[int(ffn_at[at])] if own else None)
+            if cfg.ffn_positions[at]:
+                auxes.append(aux)
         return x, jax.tree.map(lambda *a: jnp.stack(a), *auxes)
 
     def period_of_runs(x, turn):

@@ -77,6 +77,11 @@ def test_classify_on_literal_op_names():
             ("backward", "selective_scan"),
         "jit(multi)/jvp()/while/body/closed_call/while/body/mamba/mamba/"
         "dot_general": ("forward", "mamba"),
+        # the dual form's kernels inside the Mamba-2 branch's scope
+        "jit(multi)/transpose(jvp())/while/body/closed_call/checkpoint/"
+        "mamba2/mamba2/ssd_scan/ssd_scan_bwd": ("backward", "ssd_scan"),
+        "jit(multi)/jvp()/while/body/closed_call/mamba2/mamba2/"
+        "dot_general": ("forward", "mamba2"),
         # no vocabulary word on the path
         "jit(multi)/while/body/closed_call/jvp()/while/body/closed_call":
             ("forward", None),
@@ -115,7 +120,7 @@ def test_classify_on_literal_op_names():
     for op_name, want in table.items():
         assert devscope.classify(op_name) == want, op_name
     assert set(devscope.PHASES) >= {w[0] for w in table.values()}
-    assert len(set(devscope.VOCABULARY)) == len(devscope.VOCABULARY) == 23
+    assert len(set(devscope.VOCABULARY)) == len(devscope.VOCABULARY) == 25
 
 
 @pytest.mark.parametrize("op_name, want", [

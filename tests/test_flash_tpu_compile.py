@@ -393,6 +393,108 @@ def test_the_grouped_matmuls_compile_for_a_v5e_at_the_rule_s_tiles(
                 what, kernel, kk, nn, count, took)
 
 
+def test_the_moe_row_kernel_compiles_for_rows_of_an_odd_number_of_registers(
+        one_chip):
+    """``moe_rows_sum`` at one sparse layer's shape of
+    ``nemotron3_nano_30b_a3b.s8192_scan`` (98,304 pair slots, k = 6, 15,360
+    rows at the first capacity, bf16) whose rows are 2,688 = 21 x 128
+    columns: 1,344 words would be ten registers and a half, which no row DMA
+    may slice, so a row goes as ``_half`` = 1,408 words, the last register's
+    high bits zero."""
+    mr = importlib.import_module("paddle_tpu.kernels.moe_rows")
+    slots, k, width, m = 98304, 6, 2688, 15360
+    assert mr._half(width) == 1408 and mr._half(2560) == 1280
+    rows = jax.ShapeDtypeStruct((m, width), jnp.bfloat16, sharding=one_chip)
+    inv = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    text = jax.jit(lambda r, i: mr.moe_rows_sum(r, i, k, interpret=False)
+                   ).lower(rows, inv).compile().as_text()
+    tb = mr.token_block(k, mr._half(width))
+    assert tb == 256 and text.count("tpu_custom_call") == 2
+    asked, took = _vmem(text, "moe_rows_sum")
+    assert took < asked == mr.vmem_bytes(k, tb, 1408) <= 18 * 2 ** 20
+    assert re.search(r"u32\[%d,1,1408\]\S* bitcast\(\S*moe_rows_words" % m,
+                     text)
+
+
+def test_the_ungated_grouped_matmuls_compile_at_a_width_off_the_lane_tile(
+        one_chip, monkeypatch):
+    """The six calls of one sparse layer of ``nemotron3_nano_30b_a3b.
+    s8192_scan`` (15,360 rows at the first capacity, 16 groups, E = 2,688,
+    UNGATED experts of width 1,856 = 29 x 64, no whole number of lane
+    tiles): ``moe._tiling`` leaves 1,856 whole, as one column tile and as
+    one contraction tile, and Mosaic takes a block that wide (a block's
+    last dimension may be the array's own) within the default scope."""
+    moe = importlib.import_module("paddle_tpu.parallel.moe")
+    m, groups, E, F = 15360, 16, 2688, 1856
+    S = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                            sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=one_chip)
+    monkeypatch.setattr(moe, "on_tpu", lambda: True)   # compile, not interpret
+    tiles = {}
+    for k, n in ((E, F), (F, E)):
+        fwd = jax.jit(moe._gmm).lower(
+            S(m, k), S(groups, k, n), sizes).compile().as_text()
+        dx = jax.jit(lambda g, w, s: moe._gmm(g, w, s, transpose_rhs=True)
+                     ).lower(S(m, n), S(groups, k, n), sizes
+                             ).compile().as_text()
+        dw = jax.jit(lambda r, w, s, g: moe._grouped_matmul_bwd(
+            (r, w, s), g)[1]).lower(
+                S(m, k), S(groups, k, n), sizes, S(m, n)).compile().as_text()
+        for text, kernel, (kk, nn), is_dw in (
+                (fwd, "gmm", (k, n), False), (dx, "gmm", (n, k), False),
+                (dw, "tgmm", (k, n), True)):
+            asked, took = _vmem(text, kernel)
+            tiling = moe._tiling(m, kk, nn, groups, 2, dw=is_dw)
+            tiles[kernel, kk, nn] = tiling
+            count = moe._vmem_bytes(*tiling, 2, is_dw)
+            assert asked is None, kernel
+            assert count // 2 < took <= count + 3 * 2 ** 20 < 16 * 2 ** 20, (
+                kernel, kk, nn, count, took)
+    assert tiles == {("gmm", E, F): (128, 896, F), ("gmm", F, E): (128, F, 896),
+                     ("tgmm", E, F): (128, 384, F),
+                     ("tgmm", F, E): (128, F, 384)}
+
+
+@pytest.mark.parametrize("what,shape,heads,groups,chunk,dtype", [
+    ("nemotron3_nano_30b_a3b.s8192_scan", (2, 8192, 6144), 64, 8, 128,
+     jnp.bfloat16),
+    ("heads a lane tile wide, float32", (1, 512, 2048 + 256), 16, 1, 64,
+     jnp.float32),
+])
+def test_the_ssd_scan_compiles_for_a_v5e(one_chip, what, shape, heads, groups,
+                                         chunk, dtype):
+    """Both kernels of the chunked Mamba-2 scan through Mosaic at the cell's
+    shape (64 heads of 64 in 8 groups: two heads a lane tile, a group's 512
+    channels a block, B and C a lane block each of the filter's ONE output)
+    and at heads a whole lane tile wide, within the VMEM their call asks
+    for."""
+    ssd = importlib.import_module("paddle_tpu.kernels.ssd_scan")
+    b, S, W = shape
+    N = 128
+    d = W - 2 * groups * N
+
+    def sds(shape_, dtype_):
+        return jax.ShapeDtypeStruct(shape_, dtype_, sharding=one_chip)
+
+    args = (sds(shape, dtype), sds((b, S, heads), jnp.float32),
+            sds((heads,), jnp.float32), sds((heads,), jnp.float32))
+
+    def both(*a):
+        out, vjp = jax.vjp(lambda *q: ssd.ssd_scan(
+            *q, heads=heads, groups=groups, d_state=N, chunk=chunk,
+            interpret=False), *a[:-1])
+        return (out,) + vjp(a[-1])
+
+    assert ssd.supported(shape, heads, groups, N, chunk)
+    text = jax.jit(both).lower(*args, sds((b, S, d), dtype)) \
+        .compile().as_text()
+    for kernel in ("ssd_scan_fwd", "ssd_scan_bwd"):
+        asked, took = _vmem(text, kernel)
+        assert asked == ssd.vmem_bytes(chunk, d // groups, N,
+                                       jnp.dtype(dtype).itemsize)
+        assert took < asked < 64 * 2 ** 20, (what, kernel, took, asked)
+
+
 QK_ROPE_CELLS = {   # batch, positions, query heads, kv heads, head width, norm
     "trinity_large_preview.s6144_scan": (1, 6144, 48, 8, 128, "head"),
     "olmoe_1b_7b.s4096_scan": (4, 4096, 16, 16, 128, "whole"),
