@@ -1410,9 +1410,11 @@ def mamba2_mixer(pl, h, cfg):
     ``cfg.scan_chunk`` tokens clamped to S; the same form in ``jnp`` where
     the kernels do not take the shapes); the gate BEFORE the norm, ``y *
     silu(z)``, RMS-normed over each group's ``d_inner / ssm_groups``
-    channels by ``gate_norm``; then ``w_out``.  Filter, step sizes, state
-    and norm in float32."""
-    from ..kernels import ssd_scan as ssd
+    channels by ``gate_norm`` (``kernels/gated_norm.py``'s one pass each way
+    on y and the packed projection's own z lanes where it takes the shapes,
+    and the ``jnp`` lines, its reference, elsewhere); then ``w_out``.
+    Filter, step sizes, state and norm in float32."""
+    from ..kernels import gated_norm as gn, ssd_scan as ssd
 
     d, G, N, nh = cfg.d_inner, cfg.ssm_groups, cfg.d_state, cfg.ssm_heads
     xbc, packed, dt = mamba2_operands(pl, h, cfg)
@@ -1426,11 +1428,17 @@ def mamba2_mixer(pl, h, cfg):
         y = (ssd.ssd_scan if kernel else ssd.ssd_scan_chunked)(
             xbc, dt, -jnp.exp(pl["a_log"]), pl["d_skip"], heads=nh,
             groups=G, d_state=N, chunk=chunk)
-    gated = y.astype(jnp.float32) * jax.nn.silu(
-        packed[..., xbc.shape[-1]:].astype(jnp.float32))
-    normed = _rms(gated.reshape(gated.shape[:2] + (G, d // G)), 1.0,
-                  cfg.norm_eps).reshape(gated.shape) * pl["gate_norm"]
-    return normed.astype(h.dtype) @ pl["w_out"]
+    fused = gn.supported(y.shape, G, packed.shape[-1], y.dtype.itemsize)
+    if mon is not None:
+        mon.registry.counter("monitor.kernels.gated_norm_calls",
+                             fused=int(fused)).incr()
+    if fused:
+        normed = gn.gated_norm(y, packed, pl["gate_norm"], groups=G,
+                               eps=cfg.norm_eps)
+    else:
+        normed = gn.gated_norm_reference(y, packed[..., -d:],
+                                         pl["gate_norm"], G, cfg.norm_eps)
+    return normed @ pl["w_out"]
 
 
 # rows x width of a pointwise stage's widest activation (an FFN's hidden

@@ -1,14 +1,15 @@
-"""What ONE attention (or retention, or Mamba) layer of a cell moves through
-HBM outside its matmuls and kernels, read off the compiled text, no chip:
+"""What ONE attention (or retention, Mamba or Mamba-2) layer of a cell moves
+through HBM outside its matmuls and kernels, read off the compiled text, no
+chip:
 
     python3 scripts/attn_outside_hlo.py trinity_large_preview.s6144_scan
         [--kind 3] [--top 30] [--tiny]
 
 The branch the block runs (``transformer._attention_heads_mode``,
-``power_retention`` or ``mamba_mixer``) on the cell's batch and sequence at
-the configuration's widths, under ``jax.checkpoint``; its vjp alone is
-compiled (the recomputed
-forward and the backward: what a layer costs a second time in the step) for
+``power_retention``, ``mamba_mixer`` or ``mamba2_mixer``) on the cell's batch
+and sequence at the configuration's widths, under ``jax.checkpoint``; its vjp
+alone is compiled (the recomputed forward and the backward: what a layer
+costs a second time in the step) for
 a described ``v5e:2x2``, the kernels' ``_on_tpu`` patched True in THIS
 process, shapes not arrays.  Bytes = operands + results of every
 instruction the entry computation runs (a ``while``'s body times its trip
@@ -23,9 +24,9 @@ groups:
   instructions over ``--big`` MB (140) are listed.
 
 ``--kind i`` takes the i-th layer kind of the period (default: the first with
-rotary positions, or retention, or Mamba); ``--tiny`` takes the model's tiny
-configuration at S = 256 (the smoke test's). This is the reading ISSUEs 47 and
-49 were sized by (PERF.md section 6). Bytes over 819 GB/s are a LEAST
+rotary positions, or retention, or a Mamba kind); ``--tiny`` takes the
+model's tiny configuration at S = 256 (the smoke test's). This is the reading
+ISSUEs 47, 49 and 53 were sized by (PERF.md section 6). Bytes over 819 GB/s are a LEAST
 time, not a time: a time comes from the chip."""
 
 import argparse
@@ -210,6 +211,8 @@ def compiled_text(cfg, batch, seq, kind):
             return T.power_retention(pl, h, cfg)
         if kind == T.MAMBA:
             return T.mamba_mixer(pl, h, cfg)
+        if kind == T.MAMBA2:
+            return T.mamba2_mixer(pl, h, cfg)
         return T._attention_heads_mode(pl, h, cfg, kind)
 
     def recompute_and_backward(pl, h, g):
@@ -243,7 +246,7 @@ def default_kind(cfg):
 
     kinds = cfg.layer_kinds
     return next(k for k in kinds
-                if k in (T.RETENTION, T.MAMBA)
+                if k in (T.RETENTION, T.MAMBA, T.MAMBA2)
                 or (isinstance(k, tuple) and k[1]))
 
 

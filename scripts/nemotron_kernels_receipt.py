@@ -1,7 +1,7 @@
 """The Nemotron-H cell's kernels, Mosaic-compiled on the chip, against
 float32 ``jax.numpy`` at the cell's shapes:
 
-    chiprun --timeout 1500 -- python3 scripts/nemotron_kernels_receipt.py [out.json] [--only scan|experts|flash]
+    chiprun --timeout 1500 -- python3 scripts/nemotron_kernels_receipt.py [out.json] [--only scan|norm|experts|flash]
 
 - ``scan``: ``kernels/ssd_scan.py``'s ``ssd_scan_fwd`` / ``ssd_scan_bwd`` at
   [2, 8192, 6144] bf16, 64 heads of 64 in 8 groups of 128 state cells,
@@ -14,6 +14,15 @@ float32 ``jax.numpy`` at the cell's shapes:
   the output without the skip's part), which must NOT pass; then both kernels' device microseconds a call at the
   cell's whole shape, by name off a trace, beside the least HBM's bytes
   allow.
+- ``norm``: ``kernels/gated_norm.py``'s ``gated_norm_fwd`` / ``gated_norm_bwd``
+  at y [2, 8192, 4096] bf16 in 8 groups of 512 with z at lane 6,144 of the
+  packed projection [2, 8192, 10240], against the ``jnp`` lines they replace
+  (``gated_norm_reference``) in float32: the output and the gradients of y,
+  z and ``gate_norm``, each no further from float32 than the lines' own bf16
+  path is; a control with the statistic over the whole row (one group),
+  which must NOT pass; then both kernels' device microseconds a call by
+  name off a trace, beside the lines' whole program and the least HBM's
+  bytes allow (three arrays of y's size forward, five backward).
 - ``experts``: the ungated grouped matmuls at the width 1,856 (no whole
   number of lane tiles), 15,360 rows in 16 groups of E = 2,688, through
   ``moe._grouped_matmul`` (``down(relu(up x)^2)``, forward and the
@@ -39,7 +48,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from benchmark.flops import nemotron_h_train  # noqa: E402
-from paddle_tpu.kernels import ssd_scan as ssd  # noqa: E402
+from paddle_tpu.kernels import gated_norm as gn, ssd_scan as ssd  # noqa: E402
 from paddle_tpu.kernels.flash_attention import flash_attention_packed  # noqa: E402
 from paddle_tpu.parallel import moe  # noqa: E402
 
@@ -53,6 +62,10 @@ LIMIT, RATE_LIMIT = 1e-2, 3e-2
 CALLS = 5
 NAMES = ("xbc", "dt", "a", "d_skip")
 KERNELS = ("ssd_scan_fwd", "ssd_scan_bwd")
+# one rounding to bf16 of the output and of two gradients
+NORM_LIMIT, EPS = 3e-3, 1e-5
+NORM_NAMES = ("out", "dy", "dz", "dgate_norm")
+NORM_KERNELS = ("gated_norm_fwd", "gated_norm_bwd")
 
 
 def _rel(got, want):
@@ -180,6 +193,72 @@ def scan_receipt(out):
     return ok and all(took[k] > 0 for k in KERNELS)
 
 
+def norm_operands(seed, s=S, d=D, packed=D + 2 * G * N + D):
+    """(y, the packed projection with z its last d lanes, the scale), the
+    output's cotangent."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return ((jax.random.normal(ks[0], (B, s, d)).astype(jnp.bfloat16),
+             jax.random.normal(ks[1], (B, s, packed)).astype(jnp.bfloat16),
+             1.0 + 0.2 * jax.random.normal(ks[2], (d,))),
+            jax.random.normal(ks[3], (B, s, d)).astype(jnp.bfloat16))
+
+
+def norm_programs(groups=G):
+    """{"kernel", "jnp", "float32"}: jitted ``(y, packed, scale, g) -> (out,
+    dy, dz, dgate_norm)``, dz of the gate's lanes alone: the kernels on the
+    packed projection, the lines they replace behind its slice, and those
+    lines on float32 copies."""
+    def program(norm, cast):
+        def run(y, packed, scale, g):
+            d = y.shape[-1]
+            out, vjp = jax.vjp(norm, cast(y), cast(packed), scale)
+            dy, dz, dscale = vjp(g.astype(out.dtype))
+            return out, dy, dz[..., -d:], dscale
+        return jax.jit(run)
+
+    lines = lambda y, packed, scale: gn.gated_norm_reference(
+        y, packed[..., -y.shape[-1]:], scale, groups, EPS)
+    return {"kernel": program(lambda *o: gn.gated_norm(
+                *o, groups=groups, eps=EPS), lambda t: t),
+            "jnp": program(lines, lambda t: t),
+            "float32": program(lines, lambda t: t.astype(jnp.float32))}
+
+
+def norm_receipt(out):
+    programs = norm_programs()
+    args, g = norm_operands(17)
+    want = programs["float32"](*args, g)
+    got, old = programs["kernel"](*args, g), programs["jnp"](*args, g)
+    ok = True
+    for name, a, o, w in zip(NORM_NAMES, got, old, want):
+        new, lines = _rel(a, w), _rel(o, w)
+        out["readings"]["norm." + name] = new
+        out["readings"]["norm_lines." + name] = lines
+        print("norm." + name, new, "the lines'", lines, "limit", NORM_LIMIT,
+              flush=True)
+        ok = ok and new <= NORM_LIMIT and new <= 1.02 * lines + 1e-6
+    out["control_one_group"] = _rel(
+        got[0], norm_programs(groups=1)["float32"](*args, g)[0])
+    print("control (the statistic over the whole row):",
+          out["control_one_group"], flush=True)
+    ok = ok and out["control_one_group"] > NORM_LIMIT
+    del want, got, old
+    by_name = device_us(programs["kernel"], args + (g,))
+    took = {k: sum(us for n, us in by_name.items() if k in n)
+            for k in NORM_KERNELS}
+    took["all"] = sum(by_name.values())
+    took["jnp_all"] = sum(device_us(programs["jnp"], args + (g,)).values())
+    array = B * S * D * 2
+    took["least_us_by_bytes"] = {"gated_norm_fwd": 3 * array / 819e9 * 1e6,
+                                 "gated_norm_bwd": 5 * array / 819e9 * 1e6}
+    rows = gn.block_rows(S, D // G, 2)
+    took["blocks"] = [rows, D // G, gn.walk_rows(rows, D // G, 2)]
+    out["device_us"]["norm"] = took
+    print("norm, device us a call (forward + backward): %s" % json.dumps(
+        took), flush=True)
+    return ok and all(took[k] > 0 for k in NORM_KERNELS)
+
+
 def experts_receipt(out):
     rows, groups, E, F = 15360, 16, 2688, 1856
     ks = jax.random.split(jax.random.PRNGKey(5), 4)
@@ -277,7 +356,7 @@ def main(*argv):
     if jax.devices()[0].platform != "tpu":
         print("needs a TPU")
         return 2
-    argv, only = list(argv), ("scan", "experts", "flash")
+    argv, only = list(argv), ("scan", "norm", "experts", "flash")
     if "--only" in argv:
         at = argv.index("--only")
         only = (argv[at + 1],)
@@ -286,7 +365,8 @@ def main(*argv):
     out = {"device_kind": jax.devices()[0].device_kind, "readings": {},
            "device_us": {}}
     ok = True
-    for name, receipt in (("scan", scan_receipt), ("experts", experts_receipt),
+    for name, receipt in (("scan", scan_receipt), ("norm", norm_receipt),
+                          ("experts", experts_receipt),
                           ("flash", flash_receipt)):
         if name in only:
             ok = receipt(out) and ok

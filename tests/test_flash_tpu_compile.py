@@ -571,20 +571,30 @@ def test_a_rotary_layer_s_text_holds_no_float32_heads_outside_the_kernels(
     assert groups["other"] < 3.5e9 and groups["matmul"] > 1.8e9
 
 
-def test_attn_outside_hlo_smoke(one_chip, capsys):
-    """The script end to end at a tiny configuration: LFM2's two heads a
-    lane block, the kernels compiled for the described chip."""
+@pytest.mark.parametrize("cell,kind,kernels", [
+    # LFM2's two heads a lane block
+    ("lfm2_8b_a1b.s8192_scan", "(None, True)",
+     {"qk_rope_fwd", "qk_rope_bwd", "flash_fwd", "flash_bwd_fused"}),
+    # Nemotron-H's Mamba-2 mixer: two groups of 128 channels, float32
+    ("nemotron3_nano_30b_a3b.s8192_scan", "mamba2",
+     {"mamba_filter_fwd", "mamba_filter_bwd", "ssd_scan_fwd", "ssd_scan_bwd",
+      "gated_norm_fwd", "gated_norm_bwd"}),
+])
+def test_attn_outside_hlo_smoke(one_chip, capsys, cell, kind, kernels):
+    """The script end to end at a tiny configuration, the kernels compiled
+    for the described chip."""
     import sys
 
     sys.path.insert(0, os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "scripts"))
     hlo = importlib.import_module("attn_outside_hlo")
-    report = hlo.main(["lfm2_8b_a1b.s8192_scan", "--tiny", "--top", "3"])
-    assert report["kind"] == "(None, True)" and report["seq"] == 256
-    assert set(report["kernels_gb"]) == {
-        "qk_rope_fwd", "qk_rope_bwd", "flash_fwd", "flash_bwd_fused"}
-    assert 0 < report["gb"]["other"] < report["gb"]["matmul"]
+    report = hlo.main([cell, "--tiny", "--top", "3"])
+    assert report["kind"] == kind and report["seq"] == 256
+    assert set(report["kernels_gb"]) == kernels
+    assert 0 < report["gb"]["other"]
+    if kind != "mamba2":        # a tiny mixer's matmuls are its least part
+        assert report["gb"]["other"] < report["gb"]["matmul"]
     assert json.loads(capsys.readouterr().out.splitlines()[-1]) == report
 
 
@@ -792,3 +802,90 @@ def test_a_mamba_layer_s_filter_reads_the_projection_in_place(one_chip):
     called = re.search(r"calls=%?([\w.\-]+)", attrs).group(1)
     assert any(o in ("convolution", "dot") for _, _, o, _, _ in comps[called])
     assert groups["other"] < 2.4e9 and groups["matmul"] > 2.0e9
+
+
+# --- the gate and the group norm behind the SSD scan (PR 53) -----------------
+
+@pytest.mark.parametrize("what,shape,groups,packed,dtype", [
+    ("nemotron3_nano_30b_a3b.s8192_scan, z at lane 6,144 of the projection",
+     (2, 8192, 4096), 8, 10240, jnp.bfloat16),
+    ("the tiny configuration, float32", (2, 64, 256), 2, 768, jnp.float32),
+    ("one group of 1,024 lanes, the gate alone", (1, 1024, 1024), 1, 1024,
+     jnp.bfloat16),
+    ("float32, one block of 40 rows", (1, 40, 128), 1, 128, jnp.float32),
+])
+def test_the_gated_norm_compiles_for_a_v5e(one_chip, what, shape, groups,
+                                           packed, dtype):
+    """Both kernels through Mosaic at the cell's shape (a group's 512
+    channels a lane block, 1,024 rows a grid step walked 128 a turn, z read
+    at lane block 12 of the packed projection) and at the other shapes
+    ``supported`` takes; a call asks for the module's own count
+    (``vmem_bytes``) and the compiled kernel takes less; the projection
+    reaches both kernels as it is, and z's gradient leaves padded to the
+    packed width by XLA."""
+    gn = importlib.import_module("paddle_tpu.kernels.gated_norm")
+    b, S, d = shape
+    itemsize = jnp.dtype(dtype).itemsize
+
+    def sds(shape_, dtype_):
+        return jax.ShapeDtypeStruct(shape_, dtype_, sharding=one_chip)
+
+    def both(y, z, scale, g):
+        out, vjp = jax.vjp(lambda *q: gn.gated_norm(
+            *q, groups=groups, eps=1e-5, interpret=False), y, z, scale)
+        return (out,) + vjp(g)
+
+    assert gn.supported(shape, groups, packed, itemsize)
+    text = jax.jit(both).lower(
+        sds(shape, dtype), sds((b, S, packed), dtype),
+        sds((d,), jnp.float32), sds(shape, dtype)).compile().as_text()
+    bs = gn.block_rows(S, d // groups, itemsize)
+    for kernel in ("gated_norm_fwd", "gated_norm_bwd"):
+        asked, took = _vmem(text, kernel)
+        assert asked == gn.vmem_bytes(bs, d // groups, itemsize)
+        assert took < asked < 16 * 2 ** 20, (what, kernel, took, asked)
+    comps, entry = _script("attn_outside_hlo").computations(text)
+    by = {name: (types, op, operands)
+          for name, types, op, operands, _ in comps[entry]}
+    for name, (types, op, operands) in by.items():
+        if op == "custom-call" and "gated_norm" in name:
+            # z: the argument itself (or XLA's own prefetch of a small one)
+            types, op, _ = by[operands[1]]
+            assert op in ("parameter", "copy-done") and types.startswith(
+                "%s[%d,%d,%d]" % ("bf16" if itemsize == 2 else "f32", b, S,
+                                  packed)), (what, name, types, op)
+
+
+def test_a_mamba2_layer_s_text_holds_no_float32_pass_behind_the_scan(
+        one_chip):
+    """The cell's Mamba-2 layer, recompute + backward, through
+    ``scripts/attn_outside_hlo.py`` (the no-chip reading ISSUE 53 was sized
+    by): its kernels are the filter's, the scan's and the norm's; no float32
+    array of y's size ([2, 8192, 4096], or its [2048, 8, 8, 512] tiles, or
+    [2, 8192, 8, 512]) is left in HBM by a ``copy``, ``reshape``,
+    ``broadcast`` or fusion of the entry computation (the parent wrote five
+    such and moved 5.5 GB outside its matmuls and kernels where 1.0 is
+    left), and z's gradient reaches ``w_in``'s backward matmuls beside the
+    filter's as pads inside their fusions: no array of the packed width but
+    the projection itself."""
+    hlo = _script("attn_outside_hlo")
+    cfg, batch, seq = hlo.cell_config("nemotron3_nano_30b_a3b.s8192_scan",
+                                      tiny=False)
+    kind = hlo.default_kind(cfg)
+    assert (batch, seq, kind, cfg.d_inner, cfg.ssm_groups) \
+        == (2, 8192, "mamba2", 4096, 8)
+    text = hlo.compiled_text(cfg, batch, seq, kind)
+    groups, by_kernel, others = hlo.account(text)
+    assert set(by_kernel) == {
+        "mamba_filter_fwd", "mamba_filter_bwd", "ssd_scan_fwd",
+        "ssd_scan_bwd", "gated_norm_fwd", "gated_norm_bwd"}
+    elements = 2 * 8192 * 4096
+    assert not [o for o in others if o[3].lstrip("(").startswith("f32")
+                and o[0] >= 4 * elements], others[:9]
+    comps, entry = hlo.computations(text)
+    wide = [(name, op) for name, types, op, _, _ in comps[entry]
+            if "[2,8192,10240]" in types and op != "parameter"]
+    assert len(wide) == 1 and wide[0][1] == "fusion", wide   # h @ w_in
+    assert not [name for name, _, op, _, _ in comps[entry]
+                if op == "concatenate"]
+    assert groups["other"] < 1.2e9 and groups["matmul"] > 2.0e9

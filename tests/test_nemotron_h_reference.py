@@ -354,6 +354,7 @@ def test_the_trainer_steps_under_remat_and_the_gauges_of_a_call():
     # the fastest head (a rate up to 16) under the largest step of the batch
     assert 0.0 < reg.gauge("monitor.train.mamba2_decay_min").value < 0.9
     assert reg.counter("monitor.kernels.ssd_scan_calls", fused=1).value > 0
+    assert reg.counter("monitor.kernels.gated_norm_calls", fused=1).value > 0
     assert reg.gauge("monitor.train.moe_load_max_over_mean").value >= 1.0
     assert 0.0 < reg.gauge("monitor.train.moe_held_rows_share").value < 1.0
     assert reg.gauge("monitor.kernels.flash_grid_steps").value > 0
