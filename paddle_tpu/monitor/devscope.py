@@ -72,10 +72,17 @@ MAMBA2, SSD_SCAN = "mamba2", "ssd_scan"
 # the activations it keeps for the backward pass and the gradients it stacks
 # (a layer's own work carries the layer's scopes, which lie further in)
 LAYER_SCAN = "layer_scan"
+# a looped stack's passes themselves (the whole stack applied several times
+# over one set of leaves): the stream carried from pass to pass, the exits
+# kept, the running sum of the stacked gradients over the passes; and the
+# exit gate at the end of every pass with what the loss makes of it (the
+# distribution over the exits, its entropy, the weighted sum)
+LOOP_SCAN, EXIT_GATE = "loop_scan", "exit_gate"
 VOCABULARY = (EMBED, ATTENTION, MLP, LAYER_NORM, LM_HEAD, CONV, BN, POOL, FC,
               LOSS, GRAD_SYNC, OPTIMIZER, MOE, ROUTER, SHORT_CONV, RETENTION,
               LATENT_ATTENTION, SHARED_EXPERT, LAYER_SCAN, ATTN_GATE,
-              POST_NORM, MAMBA, SELECTIVE_SCAN, MAMBA2, SSD_SCAN)
+              POST_NORM, MAMBA, SELECTIVE_SCAN, MAMBA2, SSD_SCAN, LOOP_SCAN,
+              EXIT_GATE)
 PHASES = ("forward", "backward", "recompute", GRAD_SYNC, OPTIMIZER)
 
 # `%fusion.12 = bf16[..] fusion(%p.1, %copy-done.2), ..., metadata={op_name="jit(multi)/..." ...}`:

@@ -3,7 +3,7 @@
 ``logits_at``, a call's observations and the builder, ONE of each for every
 decoder of this block.  A model (``models/olmoe.py``, ``smallthinker.py``,
 ``lfm2.py``, ``brumby.py``, ``mistral4.py``, ``trinity.py``, ``jamba.py``,
-``nemotron_h.py``)
+``nemotron_h.py``, ``ouro.py``)
 is a ``TransformerConfig`` and a label; what its trainer computes and what
 it observes follow from the configuration, never from which model it is.
 
@@ -11,7 +11,11 @@ batch dict: ``ids`` int32 [B, S] alone.  The loss builds the next-token
 labels itself (``labels[t] = ids[t + 1]``, positions 0..S-2 count) and adds
 what the configuration's router asks for: the auxiliary losses, mean over
 layers (``ce + router_aux_coef * load_balance + router_z_coef * router_z``),
-or the selection biases' next values, or nothing.
+or the selection biases' next values, or nothing.  A LOOPED stack
+(``loop_passes`` > 1: the whole stack run several times over one set of
+leaves) has an exit at the end of every pass, and its loss is the exits'
+cross entropies weighted by a learned per-token exit distribution
+(``transformer.exit_weighted_loss``).
 """
 
 import dataclasses
@@ -32,6 +36,8 @@ from .transformer import (
     RETENTION,
     TransformerConfig,
     embed,
+    exit_log_probs,
+    exit_weighted_loss,
     final_logits_loss,
     grad_sync_axes,
     head_logits,
@@ -41,11 +47,13 @@ from .transformer import (
     retention_log_decay,
     rms_norm,
     run_layers,
+    run_passes,
     transformer_param_specs,
     yarn_blend_range,
 )
 
-__all__ = ["BATCH_SPECS", "STEPPED", "forward", "make_loss_fn",
+__all__ = ["BATCH_SPECS", "STEPPED", "forward", "weighted_exit_logits",
+           "make_loss_fn",
            "DecoderTrainer", "build_decoder_trainer", "gauge_moe_rows",
            "retention_chunks", "retention_state_mb", "retention_state_sweeps",
            "interpolated_pairs", "scaled_positions"]
@@ -56,17 +64,34 @@ STEPPED = {"router_bias"}       # leaves a step sets itself (make_train_step)
 
 def forward(params, ids, cfg):
     """The stack on ``ids`` [b, S]: the last activation and the layers'
-    router values, each stacked [L]."""
-    return run_layers(params["params_layers"], embed(params, ids, cfg), cfg,
-                      with_aux=True, prefix=params.get("prefix_layers"),
+    router values, each stacked [L]; of a looped stack (``loop_passes`` >
+    1) every pass's last activation [T, b, S, E] and the exit gates' logits
+    [T, b, S] (``transformer.run_passes``)."""
+    x = embed(params, ids, cfg)
+    if cfg.loop_passes > 1:
+        return run_passes(params, x, cfg)
+    return run_layers(params["params_layers"], x, cfg, with_aux=True,
+                      prefix=params.get("prefix_layers"),
                       router_bias=params.get("router_bias"))
+
+
+def weighted_exit_logits(params, ids, at, cfg):
+    """A looped stack's float32 logits [b, P, V] at positions ``at`` [P] of
+    ``ids`` [b, S]: ``sum_t p_t z_t``, every exit's logits ``z_t`` (the
+    final norm and the head on that pass's state) weighted by the
+    position's exit distribution."""
+    exits, gates = forward(params, ids, cfg)
+    p = jnp.exp(exit_log_probs(gates[:, :, at]))
+    return sum(p[t][..., None] * head_logits(params, exits[t][:, at], cfg)
+               for t in range(cfg.loop_passes))
 
 
 def make_loss_fn(cfg: TransformerConfig):
     """Per-device training loss on a batch of ``ids``.  Where the routing
     rule has selection biases (``moe.SIGMOID_BIASED``): ``(loss, {"router_bias":
     their next values})``, each layer's moved against that layer's load in
-    this step (``moe.balance_bias``; ``make_train_step``'s ``stepped``)."""
+    this step (``moe.balance_bias``; ``make_train_step``'s ``stepped``).
+    Of a looped stack the exit-weighted loss of its passes."""
 
     def loss_fn(params, batch):
         ids = batch["ids"]
@@ -75,6 +100,8 @@ def make_loss_fn(cfg: TransformerConfig):
             (jnp.arange(ids.shape[1]) < ids.shape[1] - 1).astype(jnp.float32),
             ids.shape)
         x, aux = forward(params, ids, cfg)
+        if cfg.loop_passes > 1:
+            return exit_weighted_loss(params, x, aux, labels, mask, cfg)
         ce = final_logits_loss(params, x, labels, mask, cfg)
         if cfg.routing == moe.SIGMOID_BIASED:
             return ce, {"router_bias": moe.balance_bias(
@@ -168,7 +195,7 @@ class DecoderTrainer(StepTrainer):
 
     label: str = "decoder"
     _logits_fn = _routing_fn = _gate_fn = _attn_gate_fn = _mamba_fn = None
-    _mamba2_fn = None
+    _mamba2_fn = _exits_fn = None
 
     def _on_mesh(self, fn, out_specs, *more):
         """``fn(params, ids [b, S], *more)`` jitted over the mesh, the
@@ -183,13 +210,20 @@ class DecoderTrainer(StepTrainer):
         ``ids`` [B, S], at the weights as they stand: the step's own forward
         (block, kernels, MoE, the head's norm and matmul) without the loss.
         What a check against a reference reads where the scalar loss cannot
-        tell (``benchmark/drivers/train_scan_witnessed.py``)."""
+        tell (``benchmark/drivers/train_scan_witnessed.py``).  Of a looped
+        stack (``loop_passes`` > 1) the WEIGHTED-EXIT logits ``sum_t p_t
+        z_t`` (``weighted_exit_logits``): one array in which every pass,
+        every gate (its bias, the survival product, the last exit taking
+        what is left) and every call of the head shows."""
         cfg = self.cfg
         if self._logits_fn is None:
-            self._logits_fn = self._on_mesh(
-                lambda params, ids, at: head_logits(
-                    params, forward(params, ids, cfg)[0][:, at], cfg),
-                P(DP), P())
+            def logits(params, ids, at):
+                if cfg.loop_passes > 1:
+                    return weighted_exit_logits(params, ids, at, cfg)
+                return head_logits(
+                    params, forward(params, ids, cfg)[0][:, at], cfg)
+
+            self._logits_fn = self._on_mesh(logits, P(DP), P())
         return self._logits_fn(self.state["params"], jnp.asarray(ids),
                                jnp.asarray(positions, jnp.int32))
 
@@ -239,7 +273,14 @@ class DecoderTrainer(StepTrainer):
           kernels read: every head's key and value),
           ``yarn_first_interpolated_pair`` / ``_last_``
           (``interpolated_pairs``) and ``q_scaled_positions``
-          (``scaled_positions``); all fixed when the step is traced."""
+          (``scaled_positions``); all fixed when the step is traced;
+        - ``loop_passes`` > 1: ``loop_passes`` (a gauge) and
+          ``layer_applications``, passes x layers x the call's steps (a
+          counter); of the call's first batch, ``exit_prob_mean{exit}``,
+          the mean over tokens of each exit's probability (a gate stuck at
+          0 or 1 is a dead exit), and ``exit_entropy_mean``, the mean
+          entropy of a token's exit distribution in nats (ln T at most; 0
+          is a collapsed gate)."""
         mon = monitor.active()
         if mon is None:
             return
@@ -344,6 +385,23 @@ class DecoderTrainer(StepTrainer):
                 gauge("yarn_first_interpolated_pair", whole[0])
                 gauge("yarn_last_interpolated_pair", whole[1])
             gauge("q_scaled_positions", scaled_positions(cfg, seq))
+        if cfg.loop_passes > 1:
+            gauge("loop_passes", cfg.loop_passes)
+            count("layer_applications",
+                  cfg.loop_passes * cfg.n_layers * len(batches))
+            if self._exits_fn is None:
+                def exits(params, ids):
+                    log_p = exit_log_probs(forward(params, ids, cfg)[1])
+                    p = jnp.exp(log_p)
+                    return (jnp.mean(p, axis=(1, 2)),
+                            jnp.mean(-jnp.sum(p * log_p, axis=0)))
+
+                self._exits_fn = self._on_mesh(exits, (P(), P()))
+            probs, entropy = self._exits_fn(params, batches[0])
+            for t, prob in enumerate(probs):
+                mon.registry.gauge("monitor.train.exit_prob_mean",
+                                   exit=t + 1).set(float(prob))
+            gauge("exit_entropy_mean", float(entropy))
 
 
 def build_decoder_trainer(cfg, mesh_spec: MeshSpec = None, optimizer=None,

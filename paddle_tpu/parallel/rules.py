@@ -271,7 +271,7 @@ def transformer_rules(cfg):
         (r"/(wq_a|wq_b|wkv_a|wkv_b)$", L(None, None)),
         (r"/(q_a_norm|kv_a_norm)$", L(None)),
         (r"^(tok_emb|lm_head)$", P(TP, None)),       # vocab-parallel
-        (r"^pos_emb$|^lnf_", P()),
+        (r"^pos_emb$|^lnf_|^exit_gate_", P()),
         (r"/ln[12](_post)?_(scale|bias)$", L(None)),
         (r"/(wq|wk|wv|wz|bqkv)$", L(None, tp)),
         (r"/wo$", L(tp, None)),

@@ -44,7 +44,8 @@ __all__ = ["TransformerConfig", "CONV", "RETENTION", "MAMBA", "MAMBA2", "FFN",
            "rms_norm", "rope", "rope_pairs", "yarn_blend_range",
            "yarn_frequencies",
            "yarn_softmax_scale", "yarn_rotary_factor", "final_logits_loss",
-           "head_logits", "head_row_block", "head_rows_computed"]
+           "head_logits", "head_row_block", "head_rows_computed",
+           "run_passes", "exit_log_probs", "exit_weighted_loss"]
 
 
 CONV = "conv"       # a layer kind: the gated short convolution, no attention
@@ -149,8 +150,8 @@ class TransformerConfig:
     # The kinds of the LEADING layers, which run before the scan over
     # periods and whose FFN, where the others' is the MoE, is one dense
     # gated FFN (``expert_act``) of width ``dense_ffn_hidden``.  A stack
-    # WITHOUT experts whose layers own their leaves carries that FFN in
-    # every layer
+    # WITHOUT experts that gives this width carries that FFN in every layer
+    # (``dense_stack``), whichever way its tree is built
     prefix_pattern: tuple = ()
     dense_ffn_hidden: int = 0
     conv_taps: int = 3               # CONV: taps of the causal depthwise filter
@@ -237,6 +238,15 @@ class TransformerConfig:
     route_scale: float = 1.0
     # what the embedding's rows are multiplied by as they enter the stream
     embed_scale: float = 1.0
+    # how many times a step applies the WHOLE stack to the stream, every
+    # pass over the same leaves (a looped model): the final norm stands at
+    # the end of every pass, its output is the next pass's input, and an
+    # exit gate ``exit_gate_w`` [E] / ``exit_gate_b`` (float32) and the head
+    # read it after every pass (``exit_weighted_loss``).  1: a plain stack
+    loop_passes: int = 1
+    # what the entropy of a token's distribution over the exits is rewarded
+    # by in the loss (a uniform prior over the exits)
+    exit_entropy_coef: float = 0.0
 
     def __post_init__(self):
         assert self.norm in ("layer", "rms") and \
@@ -272,7 +282,9 @@ class TransformerConfig:
             assert self.layer_pattern and self.n_experts \
                 and self.dense_ffn_hidden and not self.bias
         if self.per_position and not self.n_experts:
-            assert self.dense_ffn_hidden and not self.bias
+            assert self.dense_ffn_hidden
+        if self.dense_stack:
+            assert not self.bias
         if MAMBA in self.layer_pattern + self.prefix_pattern:
             assert self.d_inner and self.d_state and self.d_conv \
                 and self.dt_rank
@@ -308,6 +320,12 @@ class TransformerConfig:
             assert self.norm == "rms" and self.tp == 1 and not self.bias
         assert self.route_scale == 1.0 or self.n_experts
         assert self.residual_out_gain == 1.0 or self.per_position
+        if self.loop_passes != 1:
+            # the router's values are one pass's, the gate reads an RMS norm
+            assert self.loop_passes > 1 and self.causal \
+                and self.norm == "rms" and self.tp == self.pp == 1 \
+                and not self.n_experts
+        assert not self.exit_entropy_coef or self.loop_passes > 1
 
     @property
     def head_dim(self):
@@ -345,6 +363,12 @@ class TransformerConfig:
         them by position of the period (``init_transformer_params``)."""
         return bool(self.prefix_pattern) or any(
             k in _OWN_LEAVES for k in self.layer_pattern)
+
+    @property
+    def dense_stack(self):
+        """Whether every layer's FFN is the dense gated one
+        (``gated_ffn``): a stack without experts that gives its width."""
+        return bool(self.dense_ffn_hidden) and not self.n_experts
 
     @property
     def runs(self):
@@ -404,7 +428,8 @@ def init_transformer_params(key, cfg: TransformerConfig):
     configuration: ``*_bias`` of the norms only with LayerNorm, ``bqkv`` /
     ``bo`` / ``b1`` / ``b2`` only with ``bias``, ``pos_emb`` only with
     learned positions, ``q_norm`` / ``k_norm`` with ``qk_norm``, ``lm_head``
-    with an untied head, and the FFN's leaves are either ``w1`` / ``w2`` or
+    with an untied head, and the FFN's leaves are either ``w1`` / ``w2``,
+    the dense gated FFN's ``w_gate_up`` / ``w_down`` (``dense_stack``) or
     the MoE's ``router`` / ``we_gate_up`` / ``we_down`` (parallel/moe.py;
     the experts' leaves hold ``experts_here`` of them).  ``wq`` / ``wo``
     are n_heads * head_dim wide, ``wk`` / ``wv`` kv_heads * head_dim;
@@ -415,7 +440,9 @@ def init_transformer_params(key, cfg: TransformerConfig):
     (``_latent_qkv``); ``ws_gate_up`` [E, 2Fs] / ``ws_down`` [Fs, E] are the
     shared expert's (``shared_ffn_hidden``); ``wz`` [E, n_heads * head_dim]
     is attention's output gate (``attn_gate``), ``ln1_post_scale`` /
-    ``ln2_post_scale`` the output norms' (``post_norm``).
+    ``ln2_post_scale`` the output norms' (``post_norm``); ``exit_gate_w`` [E]
+    / ``exit_gate_b`` (a scalar), float32, are a looped stack's exit gate
+    (``loop_passes``), beside ``lnf_scale``.
 
     Where every layer has the same leaves (attention layers that differ in
     window and rotary alone) ``params_layers`` is ONE tree stacked [L, ...].
@@ -466,6 +493,14 @@ def _init_params(key, cfg):
         params["lnf_bias"] = jnp.zeros((E,), jnp.float32)
     if not cfg.tie_head:
         params["lm_head"] = _dense_init(ks[3], E, (V, E), dt)
+    if cfg.loop_passes > 1:
+        # the bias is seeded OFF zero (-1/2) so that a gate that lost its
+        # bias computes other numbers: at 0 no comparison could tell.  The
+        # logit is -1/2 plus ``h . w``, N(0, 1) over seeds and nearly one
+        # value a seed (the normed state is mostly a part every token
+        # shares): every exit weighs in, the later ones the more
+        params["exit_gate_w"] = _dense_init(ks[6], E, (E,), jnp.float32)
+        params["exit_gate_b"] = jnp.full((), -0.5, jnp.float32)
     return params
 
 
@@ -522,6 +557,8 @@ def _stacked_layers(ks, cfg):
     layer.update(_branch_leaves(stack, cfg, L, 15, attention=True))
     if cfg.n_experts:
         layer.update(_ffn_leaves(stack, cfg, 6, dense=False))
+    elif cfg.dense_stack:
+        layer.update(_ffn_leaves(stack, cfg, 4, dense=True))
     else:
         layer["w1"] = stack(4, E, (E, F))
         layer["w2"] = stack(5, F, (F, E))
@@ -1645,8 +1682,8 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
     if len(kinds) == 1 and not cfg.per_position:
         with jax.named_scope(devscope.LAYER_SCAN):
             x_sp, aux = jax.lax.scan(
-                lambda x, turn: body(turn[0], x, cfg, kinds[0], False,
-                                     turn[1]),
+                lambda x, turn: body(turn[0], x, cfg, kinds[0],
+                                     cfg.dense_stack, turn[1]),
                 x_sp, (layer_params, router_bias), unroll=unroll)
         return (x_sp, aux) if with_aux else x_sp
 
@@ -1672,8 +1709,7 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
             pl = pls[at] if cfg.per_position \
                 else jax.tree.map(lambda a: a[at], pls)
             own = biases is not None and cfg.ffn_positions[at]
-            x, aux = body(pl, x, cfg, kind,
-                          cfg.per_position and not cfg.n_experts,
+            x, aux = body(pl, x, cfg, kind, cfg.dense_stack,
                           biases[int(ffn_at[at])] if own else None)
             if cfg.ffn_positions[at]:
                 auxes.append(aux)
@@ -1683,7 +1719,7 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
         """The same over a tree for each run, stacked [run length, ...]: a
         scan over each run's layers."""
         pls, biases = turn
-        dense, auxes = not cfg.n_experts, []
+        dense, auxes = cfg.dense_stack, []
         for at, (first, kind, length) in enumerate(cfg.runs):
             own = None if biases is None else biases[first:first + length]
             x, aux = jax.lax.scan(
@@ -1700,6 +1736,51 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
                                  unroll=unroll)
     aux = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), aux)
     return (x_sp, aux) if with_aux else x_sp
+
+
+def run_passes(params, x_sp, cfg: TransformerConfig):
+    """A looped stack on the stream ``x_sp`` [b, S, E]: ``cfg.loop_passes``
+    times ``run_layers`` over the SAME ``params["params_layers"]``, the
+    model's one final norm at the end of every pass, whose output the next
+    pass reads.  Returns ``(exits, gates)``: every pass's last activation
+    BEFORE that norm, [T, b, S, E] (the head norms the rows it reads with
+    the same weight: ``_chunked_vocab_nll``, ``head_logits``), and the exit
+    gate's logit on the normed state, ``h_t . exit_gate_w + exit_gate_b``,
+    float32 [T, b, S].
+
+    The passes are an outer ``lax.scan`` with the leaves closed over: the
+    traced program holds the stack once.  Its backward runs the passes in
+    reverse, each the layers' scan with its own per-layer remat (T * L
+    activations kept), and SUMS the T contributions to a leaf's gradient in
+    the carry, in the leaf's own type: bf16 leaves take the stacked [L, ...]
+    gradient of each pass rounded to bf16 and one more rounding at each of
+    the T additions.  The loop's own work (the carried stream, the exits
+    kept, that running sum) goes under the scope ``loop_scan``; the gate's
+    under ``exit_gate``; the layers keep theirs further in."""
+    def one_pass(x, _):
+        u = run_layers(params["params_layers"], x, cfg)
+        h = rms_norm(u, params["lnf_scale"], cfg.norm_eps)
+        with jax.named_scope(devscope.EXIT_GATE):
+            gate = h.astype(jnp.float32) @ params["exit_gate_w"] \
+                + params["exit_gate_b"]
+        return h, (u, gate)
+
+    with jax.named_scope(devscope.LOOP_SCAN):
+        _, (exits, gates) = jax.lax.scan(one_pass, x_sp, None,
+                                         length=cfg.loop_passes)
+    return exits, gates
+
+
+def exit_log_probs(gates):
+    """``ln p`` [T, ...] of the exit distribution from the gates' logits
+    [T, ...], float32: with ``lam_t = sigmoid(gate_t)``, ``p_t = lam_t *
+    prod_{j<t} (1 - lam_j)`` for t < T and ``p_T = prod_{j<T} (1 - lam_j)``,
+    what is left (the last gate is not read).  In logarithms, so that a
+    saturated gate gives no ``0 * ln 0``.  ``sum_t p_t = 1``."""
+    gates = gates.astype(jnp.float32)[:-1]
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-gates), axis=0)   # ln prod_{j<=t}
+    before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]])
+    return jnp.concatenate([jax.nn.log_sigmoid(gates) + before, stay[-1:]])
 
 
 _VOCAB_CHUNKS = 4
@@ -1949,5 +2030,33 @@ def final_logits_loss(params, x_sp, labels, mask, cfg: TransformerConfig):
     nll = (lse - picked) * mask
     # token-mean over the dp-sharded global batch (nll is tp-replicated)
     total = col.psum(jnp.sum(nll), DP)
+    count = col.psum(jnp.sum(mask.astype(jnp.float32)), DP)
+    return total / jnp.maximum(count, 1.0)
+
+
+def exit_weighted_loss(params, exits, gates, labels, mask,
+                       cfg: TransformerConfig):
+    """A looped stack's training loss from ``run_passes``' ``exits`` [T, b,
+    S, E] and ``gates`` [T, b, S]: per position ``sum_t p_t * nll_t -
+    exit_entropy_coef * H(p)``, ``nll_t`` the cross entropy of exit t's
+    logits (the final norm and the ONE head on that pass's state), ``p``
+    the position's exit distribution (``exit_log_probs``), ``H(p) = -sum_t
+    p_t ln p_t``; averaged over the positions ``mask`` weights as
+    ``final_logits_loss`` does.  The T exits' rows go through the head in
+    ONE call (one float32 [V, E] gradient buffer, one loop over row blocks);
+    the weights multiply its per-row ``nll`` outside it, so the gradient
+    reaches the gate and, through the state it reads, the stack.  tp == 1."""
+    T = cfg.loop_passes
+    emb = params["tok_emb" if cfg.tie_head else "lm_head"]
+    mask = mask.reshape(-1)
+    nll = _chunked_vocab_nll(
+        exits.reshape(-1, exits.shape[-1]), params["lnf_scale"],
+        params.get("lnf_bias"), emb, jnp.tile(labels.reshape(-1), T),
+        jnp.tile(mask, T), norm=(cfg.norm, cfg.norm_eps)).reshape(T, -1)
+    with jax.named_scope(devscope.EXIT_GATE):
+        log_p = exit_log_probs(gates.reshape(T, -1))
+        p = jnp.exp(log_p)
+        each = jnp.sum(p * (nll + cfg.exit_entropy_coef * log_p), axis=0)
+    total = col.psum(jnp.sum(each * mask), DP)
     count = col.psum(jnp.sum(mask.astype(jnp.float32)), DP)
     return total / jnp.maximum(count, 1.0)

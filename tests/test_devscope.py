@@ -120,7 +120,7 @@ def test_classify_on_literal_op_names():
     for op_name, want in table.items():
         assert devscope.classify(op_name) == want, op_name
     assert set(devscope.PHASES) >= {w[0] for w in table.values()}
-    assert len(set(devscope.VOCABULARY)) == len(devscope.VOCABULARY) == 25
+    assert len(set(devscope.VOCABULARY)) == len(devscope.VOCABULARY) == 27
 
 
 @pytest.mark.parametrize("op_name, want", [
@@ -145,10 +145,28 @@ def test_classify_on_literal_op_names():
      "post_norm/mul", ("recompute", "post_norm")),
     ("jit(multi)/jvp()/while/body/closed_call/moe/layer_norm/rsqrt",
      ("forward", "layer_norm")),
+    # a looped stack's passes: the running sum of the stacked gradients and
+    # the exits kept are the loop's own, the layers' scan inside a pass its
+    # own, a layer's work the layer's, the gate's the gate's (at the end of
+    # a pass, and in the loss outside the loop)
+    ("jit(multi)/while/body/closed_call/transpose(jvp(loop_scan))/while/"
+     "body/add_any", ("backward", "loop_scan")),
+    ("jit(multi)/while/body/closed_call/jvp(loop_scan)/while/body/"
+     "dynamic_update_slice", ("forward", "loop_scan")),
+    ("jit(multi)/while/body/closed_call/jvp(loop_scan)/while/body/"
+     "layer_scan/while/body/dynamic_slice", ("forward", "layer_scan")),
+    ("jit(multi)/while/body/closed_call/transpose(jvp(loop_scan))/while/"
+     "body/layer_scan/while/body/closed_call/checkpoint/"
+     "rematted_computation/mlp/dot_general", ("recompute", "mlp")),
+    ("jit(multi)/while/body/closed_call/jvp(loop_scan)/while/body/"
+     "exit_gate/dot_general", ("forward", "exit_gate")),
+    ("jit(multi)/while/body/closed_call/transpose(jvp(exit_gate))/mul",
+     ("backward", "exit_gate")),
 ])
 def test_classify_the_gate_and_the_output_norms(op_name, want):
     assert devscope.classify(op_name) == want
-    assert {"attn_gate", "post_norm"} <= set(devscope.VOCABULARY)
+    assert {"attn_gate", "post_norm", "loop_scan", "exit_gate"} \
+        <= set(devscope.VOCABULARY)
 
 
 def test_bert_program_that_ran_maps_every_scope_it_uses():

@@ -38,6 +38,9 @@ def one_chip():
     ("bert_base.s512_scan", "packed", 64, 512, 12, 64, False, 1),
     ("fine-tuning at 384", "packed", 32, 384, 12, 64, False, 2),
     ("olmoe_1b_7b.s4096_scan", "packed", 4, 4096, 16, 128, True, 1),
+    # the same heads and length at the looped stack's batch: 48 layer
+    # applications a step call these
+    ("ouro_2_6b.s4096_scan", "packed", 2, 4096, 16, 128, True, 1),
     ("a prime batch, causal", "packed", 7, 128, 12, 64, True, 21),
     ("heads the packed layout cannot tile", "bshd", 32, 128, 3, 64, False, 4),
 ])
@@ -96,6 +99,7 @@ ONE_BLOCK_MOSAIC = {
 # as it was.  Replaced like ``ONE_BLOCK_MOSAIC``.
 SWEEP_MOSAIC = {
     "olmoe_1b_7b.s4096_scan": ["05626943d473", "eba4a626459f"],
+    "ouro_2_6b.s4096_scan": ["8aec72d32a18", "afa8aab872da"],
     "smallthinker_21b_a3b.s16384_scan, a full layer":
         ["55126c1bd654", "170f86a03873"],
     "smallthinker_21b_a3b.s16384_scan, a windowed layer":
@@ -224,6 +228,8 @@ def test_a_sequence_past_the_rule_compiles_as_two_sweeps(one_chip):
 @pytest.mark.parametrize("what,N,V,E,norm", [
     ("smallthinker_21b_a3b.s16384_scan", 16384, 37984, 2560, "rms"),
     ("olmoe_1b_7b.s4096_scan", 16384, 50304, 2048, "rms"),
+    # a looped stack's four exits' rows through the head in one call
+    ("ouro_2_6b.s4096_scan", 4 * 8192, 49152, 2048, "rms"),
     ("bert_base.s512_scan", 32768, 30528, 768, "layer"),
 ])
 def test_head_matrix_gradient_is_tiled_in_a_few_windows(one_chip, what, N, V,

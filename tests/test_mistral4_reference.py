@@ -26,8 +26,9 @@ if ROOT not in sys.path:
 
 from benchmark.reference import mistral_small_4_119b as reference  # noqa: E402
 from paddle_tpu import monitor  # noqa: E402
-from paddle_tpu.models import (bert, brumby, lfm2, mistral4,  # noqa: E402
-                               olmoe, smallthinker, trinity)
+from paddle_tpu.models import (bert, brumby, jamba, lfm2,  # noqa: E402
+                               mistral4, nemotron_h, olmoe, smallthinker,
+                               trinity)
 from paddle_tpu.monitor import devscope  # noqa: E402
 from paddle_tpu.parallel import (decoder, moe, optim,  # noqa: E402
                                  transformer as T)
@@ -518,7 +519,9 @@ def test_the_new_scopes_hold_their_instructions_and_attention_none():
 # ``_project``; OLMoE's 4 heads of 16 are half a lane block and keep the
 # plain matmuls and the ``rms_norm`` / ``rope`` lines, in their old order);
 # BERT's are still 03fc114's.  Mistral's and Trinity's joined the table in
-# PR 48, taken on its parent (3ea462c).
+# PR 48, taken on its parent (3ea462c); Jamba's and Nemotron-H's in PR 54, on
+# its parent (37c698c): the passes of a looped stack (``loop_passes``) and
+# the one rule for the dense gated FFN (``cfg.dense_stack``) left all nine.
 PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "bert.run_steps": "00de5403506fdc87",
             "olmoe.step": "231114fcd62341f2",
@@ -532,7 +535,11 @@ PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "mistral4.step": "278496583ed8fb80",
             "mistral4.run_steps": "c9f856c8337ed276",
             "trinity.step": "2ee67522b53eb798",
-            "trinity.run_steps": "4337fe8cf4863f5f"}
+            "trinity.run_steps": "4337fe8cf4863f5f",
+            "jamba.step": "6dfded4d28caec90",
+            "jamba.run_steps": "8ef0306cc8ddd47d",
+            "nemotron_h.step": "4cf5a76a79eea76f",
+            "nemotron_h.run_steps": "59e10ab9a58a2cf9"}
 OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
          "olmoe": (olmoe.build_olmoe_trainer, olmoe.olmoe_tiny_config, 32),
          "smallthinker": (smallthinker.build_smallthinker_trainer,
@@ -543,7 +550,10 @@ OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
          "mistral4": (mistral4.build_mistral4_trainer,
                       mistral4.mistral4_tiny_config, 64),
          "trinity": (trinity.build_trinity_trainer,
-                     trinity.trinity_tiny_config, 64)}
+                     trinity.trinity_tiny_config, 64),
+         "jamba": (jamba.build_jamba_trainer, jamba.jamba_tiny_config, 64),
+         "nemotron_h": (nemotron_h.build_nemotron_h_trainer,
+                        nemotron_h.nemotron_h_tiny_config, 64)}
 
 
 @pytest.mark.parametrize("name", list(OLDER))
