@@ -89,6 +89,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import monitor
+from . import flash_delta
 from ._common import CompilerParams as _CompilerParams, on_tpu as _on_tpu
 
 __all__ = ["flash_attention", "flash_attention_packed"]
@@ -1024,6 +1026,29 @@ def _bwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
         kv_blocks_of_the_sequence(leave)
 
 
+def _delta(o, do, g, packed, interpret):
+    """``sum_d(o * do)`` a head, float32 ``g.stat_shape``: the several-block
+    backward kernels' row statistic.  The packed layout's in ONE pass of the
+    row kernel (``kernels/flash_delta.py``) wherever it takes the shape; a
+    shape it refuses and the [BH, S, D] layout keep the ``jnp`` lines, which
+    the tests hold that kernel to.  Under a monitor session every traced
+    call counts in ``monitor.kernels.flash_delta_calls`` (``fused`` 1 for
+    the kernel)."""
+    fused = packed and o.dtype == do.dtype and flash_delta.supported(
+        o.shape, g.D, o.dtype.itemsize)
+    mon = monitor.active()
+    if mon is not None:
+        mon.registry.counter("monitor.kernels.flash_delta_calls",
+                             fused=int(fused), head_dim=g.D).incr()
+    if fused:
+        return flash_delta.flash_delta(o, do, head_dim=g.D,
+                                       interpret=interpret)
+    if packed:
+        return flash_delta.flash_delta_reference(o, do, g.D)
+    return jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                   axis=-1, keepdims=True).reshape(g.stat_shape)
+
+
 def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
          window=None):
     q, k, v, o, lse = res
@@ -1031,15 +1056,7 @@ def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
     if g.one_block and g.group == 1:
         return _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=H,
                           window=window)
-    if H is None:
-        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1, keepdims=True).reshape(g.stat_shape)
-    else:
-        B = q.shape[0]
-        delta = jnp.sum(
-            (do.astype(jnp.float32) * o.astype(jnp.float32))
-            .reshape(B, g.S, g.Hb, g.hpb, g.D), axis=-1
-        ).transpose(0, 2, 1, 3)                           # [B, Hb, S, hpb]
+    delta = _delta(o, do, g, H is not None, interpret)
 
     dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
     dkv_shapes = [jax.ShapeDtypeStruct(g.dkv_shape, k.dtype),

@@ -21,12 +21,21 @@ groups:
 - ``matmul``: fusions that hold a ``convolution`` / ``dot``;
 - ``kernel``: ``tpu_custom_call``s, by the kernels' names;
 - ``other``: what is left, the norm, rotation, gate and relayouts; its
-  instructions over ``--big`` MB (140) are listed.
+  instructions over ``--big`` MB (140) are listed, each with XLA's own
+  ``estimated_cycles`` (the cost model's count for the described chip, at
+  ``CLOCK`` cycles a second; ``other_estimated_ms`` is their sum: ISSUE 55's
+  table was made so.  An estimate, as the bytes are a least: PERF.md
+  section 6, PR 55, has what the chip read beside it).
+
+The branch is differentiated with respect to the leaves it READS (and its
+input): a layer's other leaves, the experts' and the MLP's, would come back
+as zero gradients, broadcasts of their whole size that no step makes (0.51
+GB of Trinity's "other" before PR 55).
 
 ``--kind i`` takes the i-th layer kind of the period (default: the first with
 rotary positions, or retention, or a Mamba kind); ``--tiny`` takes the
 model's tiny configuration at S = 256 (the smoke test's). This is the reading
-ISSUEs 47, 49 and 53 were sized by (PERF.md section 6). Bytes over 819 GB/s are a LEAST
+ISSUEs 47, 49, 53 and 55 were sized by (PERF.md section 6). Bytes over 819 GB/s are a LEAST
 time, not a time: a time comes from the chip."""
 
 import argparse
@@ -41,6 +50,7 @@ import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM = 819e9
+CLOCK = 1.5e9       # a v5e's cycles a second: 197e12 / (4 * 128 * 128 * 2)
 ITEM = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2, "u16": 2,
         "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8, "u64": 8}
 MOVES_NOTHING = {"parameter", "constant", "tuple", "get-tuple-element",
@@ -97,9 +107,17 @@ def _kernel_name(name):
                   name.lstrip("%"))
 
 
+def _cycles(attrs):
+    """XLA's ``estimated_cycles`` of an instruction (0 where it gives
+    none: a custom call, an async pair)."""
+    m = re.search(r'"estimated_cycles":"(\d+)"', attrs)
+    return int(m.group(1)) if m else 0
+
+
 def account(text):
     """{"matmul", "kernel", "other": bytes}, {kernel name: bytes}, and the
-    other group's instructions [(bytes, name, opcode, result type)]."""
+    other group's instructions [(bytes, name, opcode, result type, XLA's
+    estimated cycles)]."""
     comps, entry = computations(text)
     has_matmul = {name: any(op in ("convolution", "dot")
                             for _, _, op, _, _ in body)
@@ -152,7 +170,8 @@ def account(text):
                 groups["matmul"] += moved
             else:
                 groups["other"] += moved
-                others.append((moved, name, op, types.split("{")[0]))
+                others.append((moved, name, op, types.split("{")[0],
+                               times * _cycles(attrs)))
 
     walk(entry, 1)
     return dict(groups), dict(kernels), sorted(others, reverse=True)
@@ -180,9 +199,9 @@ def kernels_as_on_a_tpu():
             setattr(mod, attr, probe)
 
 
-def compiled_text(cfg, batch, seq, kind):
-    """The compiled text of one layer's recompute + backward for a described
-    v5e; ``kind`` an entry of ``cfg.layer_kinds``."""
+def layer_shapes(cfg, batch, seq, kind):
+    """(one layer's leaves of ``kind``, its input [batch, seq, hidden]) as
+    shapes on one chip of a described ``v5e:2x2``."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
     from jax.experimental import topologies
@@ -203,8 +222,13 @@ def compiled_text(cfg, batch, seq, kind):
         params = params["p%d" % cfg.layer_kinds.index(kind)]
     leaves = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
         a.shape[stacked:], a.dtype, sharding=one_chip), params)
-    h = jax.ShapeDtypeStruct((batch, seq, cfg.hidden), cfg.jdtype,
-                             sharding=one_chip)
+    return leaves, jax.ShapeDtypeStruct((batch, seq, cfg.hidden), cfg.jdtype,
+                                        sharding=one_chip)
+
+
+def branch_of(cfg, kind):
+    """``branch(leaves, h)``: the mixer a layer of ``kind`` runs."""
+    from paddle_tpu.parallel import transformer as T
 
     def branch(pl, h):
         if kind == T.RETENTION:
@@ -215,12 +239,41 @@ def compiled_text(cfg, batch, seq, kind):
             return T.mamba2_mixer(pl, h, cfg)
         return T._attention_heads_mode(pl, h, cfg, kind)
 
-    def recompute_and_backward(pl, h, g):
-        return jax.vjp(jax.checkpoint(branch), pl, h)[1](g)
+    return branch
+
+
+def compiled_text(cfg, batch, seq, kind):
+    """The compiled text of one layer's recompute + backward for a described
+    v5e; ``kind`` an entry of ``cfg.layer_kinds``."""
+    import jax
+
+    leaves, h = layer_shapes(cfg, batch, seq, kind)
+    branch = branch_of(cfg, kind)
+
+    def recompute_and_backward(read, rest, h, g):
+        return jax.vjp(jax.checkpoint(
+            lambda read, h: branch({**rest, **read}, h)), read, h)[1](g)
 
     with kernels_as_on_a_tpu():
-        return jax.jit(recompute_and_backward).lower(leaves, h, h) \
+        read = leaves_read(branch, leaves, h)
+        rest = {k: v for k, v in leaves.items() if k not in read}
+        return jax.jit(recompute_and_backward).lower(read, rest, h, h) \
             .compile().as_text()
+
+
+def leaves_read(branch, leaves, h):
+    """The entries of a layer's ``leaves`` that ``branch(leaves, h)``
+    reads: those with a leaf among the operands of its traced equations."""
+    import jax
+
+    flat, tree = jax.tree.flatten(leaves)
+    jaxpr = jax.make_jaxpr(
+        lambda flat, h: branch(tree.unflatten(flat), h))(flat, h).jaxpr
+    seen = {id(v) for eqn in jaxpr.eqns for v in eqn.invars} \
+        | {id(v) for v in jaxpr.outvars}
+    used = tree.unflatten([id(v) in seen for v in jaxpr.invars[:len(flat)]])
+    return {k: leaves[k] for k, u in used.items()
+            if any(jax.tree.leaves(u))}
 
 
 def cell_config(cell, tiny):
@@ -269,10 +322,12 @@ def main(argv=None):
               "seq": seq, "gb": {k: v / 1e9 for k, v in groups.items()},
               "kernels_gb": {k: v / 1e9 for k, v in kernels.items()},
               "other_least_ms": groups.get("other", 0) / HBM * 1e3,
+              "other_estimated_ms": sum(o[4] for o in others) / CLOCK * 1e3,
               "other_big": {"count": len(big),
                             "gb": sum(o[0] for o in big) / 1e9}}
-    for moved, name, op, types in others[:args.top]:
-        print("%9.1f MB  %-36s %-14s %s" % (moved / 1e6, name, op, types))
+    for moved, name, op, types, cycles in others[:args.top]:
+        print("%9.1f MB %10d cycles  %-36s %-14s %s" % (
+            moved / 1e6, cycles, name, op, types))
     print(json.dumps(report))
     return report
 

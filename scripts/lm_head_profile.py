@@ -16,8 +16,8 @@ this is where ``monitor.train.lm_head_rows_share``, the two
 ``monitor.kernels.flash_*`` gauges and, for a sparse decoder, its
 ``monitor.train.moe_*``, ``monitor.kernels.moe_*``,
 ``monitor.kernels.flash_kv_blocks_*``,
-``flash_bwd_sweeps_*`` and ``monitor.kernels.qk_rope_calls`` values and a
-looped stack's ``monitor.train.loop_passes``, ``layer_applications`` and
+``flash_bwd_sweeps_*``, ``monitor.kernels.qk_rope_calls`` and
+``monitor.kernels.flash_delta_calls`` values and a looped stack's ``monitor.train.loop_passes``, ``layer_applications`` and
 ``exit_*`` are read on the chip),
 traces one more, and joins the trace with THIS process's scope map
 (``monitor.devscope``; a map compiled elsewhere need not number its
@@ -114,6 +114,7 @@ def main(argv=None):
                                        "monitor.kernels.flash_kv_blocks_",
                                        "monitor.kernels.flash_bwd_sweeps_",
                                        "monitor.kernels.qk_rope_calls",
+                                       "monitor.kernels.flash_delta_calls",
                                        # a looped stack's own
                                        "monitor.train.loop_passes",
                                        "monitor.train.layer_applications",

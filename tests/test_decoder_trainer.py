@@ -34,20 +34,25 @@ HELD = {"monitor.train.moe_held_rows_share", "monitor.train.moe_rows_held"}
 # since PR 47: a count a traced q or k of ``_qkv`` with a q/k norm or rotary
 # positions, by whether the row kernel took it (Mistral's latent chain: none)
 QK = {"monitor.kernels.qk_rope_calls"}
+# since PR 55: a count a traced several-block flash backward, by whether the
+# row kernel made its ``delta`` (OLMoE's heads of 16: no flash call)
+DELTA = {"monitor.kernels.flash_delta_calls"}
 # tiny model -> (sequence, the names one run_steps wrote on ad87b08, and
-# PR 46's counter of compiled grouped-matmul calls, PR 47's of q/k passes)
+# PR 46's counter of compiled grouped-matmul calls, PR 47's of q/k passes,
+# PR 55's of several-block flash backwards)
 WRITTEN = {
     # 4 heads of 16: no packed layout, so no flash gauge
     "olmoe": (32, MOE | QK),
-    "smallthinker": (64, FLASH | KINDS | MOE | HELD | QK),
-    "lfm2": (64, FLASH | KINDS | MOE | HELD | QK
+    "smallthinker": (64, FLASH | KINDS | MOE | HELD | QK | DELTA),
+    "lfm2": (64, FLASH | KINDS | MOE | HELD | QK | DELTA
              | {"monitor.train.router_bias_abs_max"}),
     "brumby": (64, QK | {"monitor.train.retention_" + g for g in (
         "chunks", "gate_mean", "state_mb", "state_sweeps")}),
-    "mistral4": (64, FLASH | MOE | HELD | {"monitor.train." + g for g in (
-        "mla_expanded_kv_bytes_per_token", "mla_latent_bytes_per_token",
-        "q_scaled_positions", "yarn_first_interpolated_pair",
-        "yarn_last_interpolated_pair")}),
+    "mistral4": (64, FLASH | MOE | HELD | DELTA | {
+        "monitor.train." + g for g in (
+            "mla_expanded_kv_bytes_per_token", "mla_latent_bytes_per_token",
+            "q_scaled_positions", "yarn_first_interpolated_pair",
+            "yarn_last_interpolated_pair")}),
 }
 
 

@@ -401,15 +401,19 @@ def test_windowed_kernels_carry_names_of_their_own(monkeypatch):
         return _kernel_names(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
 
     # ONE backward kernel a layer, of the one-block backward's name: the
-    # benchmark's readers count an event of it as one layer's backward
-    assert both(None) == ["flash_bwd_fused", "flash_fwd"]
-    assert both(100) == ["flash_swa_bwd_fused", "flash_swa_fwd"]
+    # benchmark's readers count an event of it as one layer's backward.  The
+    # row kernel that makes its ``delta`` (``flash_delta``, PR 55) is no
+    # flash kernel to them, windowed layer or not: they match whole names
+    assert both(None) == ["flash_bwd_fused", "flash_delta", "flash_fwd"]
+    assert both(100) == ["flash_delta", "flash_swa_bwd_fused",
+                         "flash_swa_fwd"]
     assert both(512) == both(None)          # the window is the causal mask
     # a sequence whose dk and dv do not fit VMEM: the two sweeps
     monkeypatch.setattr(fa, "SWEEP_VMEM", 0)
-    assert both(None) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
-    assert both(100) == ["flash_swa_bwd_dkv", "flash_swa_bwd_dq",
-                         "flash_swa_fwd"]
+    assert both(None) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_delta",
+                          "flash_fwd"]
+    assert both(100) == ["flash_delta", "flash_swa_bwd_dkv",
+                         "flash_swa_bwd_dq", "flash_swa_fwd"]
 
 
 # ---------------------------------------------------------------------------

@@ -112,7 +112,9 @@ SWEEP_MOSAIC = {
 def _compiled(attn, *shapes):
     """(the compiled program's text, {kernel name: grid}, the digests of the
     kernels' Mosaic modules in call order) of ``attn``'s forward and
-    backward."""
+    backward: the flash kernels' own.  The row kernel in front of a
+    several-block backward (``flash_delta``, PR 55) is another module's and
+    has a test of its own below."""
     from jaxlib.mlir import ir
 
     def both(q, k, v, do):
@@ -124,10 +126,14 @@ def _compiled(attn, *shapes):
              for grid, name in re.findall(
                  r"grid=\(([\d, ]*)\).*?name=(flash_\w+)", str(traced.jaxpr),
                  re.S)}
+    grids.pop("flash_delta", None)
     lowered = traced.lower()
     mosaic = []
-    for body in re.findall(r"body\\22: \\22([A-Za-z0-9+/=]+)",
-                           lowered.as_text()):
+    for body, name in re.findall(
+            r'body\\22: \\22([A-Za-z0-9+/=]+).*?kernel_name = "(\w+)"',
+            lowered.as_text()):
+        if name == "flash_delta":
+            continue
         context = ir.Context()
         context.allow_unregistered_dialects = True
         with context:
@@ -139,14 +145,21 @@ def _compiled(attn, *shapes):
 
 def _vmem(text, kernel):
     """(bytes of VMEM the call of ``kernel`` asks Mosaic for, None where it
-    leaves the scope at its default; bytes the compiled kernel took)."""
-    size = r'\{"memory_space":"1","offset":"\d+","size":"(\d+)"\}'
+    leaves the scope at its default; bytes the compiled kernel took).  XLA
+    may keep an array of its own in VMEM beside the call (the forward's
+    ``o`` between the kernels that read it, where nothing of XLA's does:
+    64 MiB at OLMoE's shape, PR 55): the call's scope then starts past it,
+    and what the kernel took is counted from the scope's start."""
+    scope = r'\{"memory_space":"1","offset":"(\d+)","size":"(\d+)"\}'
     line, = [l for l in text.splitlines()
              if "tpu_custom_call" in l and re.search(
                  r"%%?[\w.\-]*%s[\w.\-]* = " % kernel, l)]
-    asked, = re.findall(r'"scoped_memory_configs":\[(?:%s)?\]' % size, line)
-    took, = re.findall(r'"used_scoped_memory_configs":\[%s\]' % size, line)
-    return (int(asked) if asked else None), int(took)
+    (start, asked), = re.findall(
+        r'"scoped_memory_configs":\[(?:%s)?\]' % scope, line)
+    (first, took), = re.findall(
+        r'"used_scoped_memory_configs":\[%s\]' % scope, line)
+    took = int(first) + int(took) - max(int(start or 0), int(first))
+    return (int(asked) if asked else None), took
 
 
 SMALLTHINKER, LFM2 = (1, 16384, 28, 4, 128), (2, 8192, 32, 8, 64)
@@ -221,8 +234,10 @@ def test_a_sequence_past_the_rule_compiles_as_two_sweeps(one_chip):
     assert grids == {"flash_swa_fwd": (B, 2, 2, steps),
                      "flash_swa_bwd_dq": (B, 2, 2, steps),
                      "flash_swa_bwd_dkv": (B, 2, 2 * steps)}
+    # the default scope, which the text spells out where XLA keeps an array
+    # of its own in VMEM beside the call (``_vmem``)
     for kernel in ("flash_swa_bwd_dq", "flash_swa_bwd_dkv"):
-        assert _vmem(text, kernel)[0] is None
+        assert _vmem(text, kernel)[0] in (None, fa.SCOPED_VMEM)
 
 
 @pytest.mark.parametrize("what,N,V,E,norm", [
@@ -580,7 +595,8 @@ def test_a_rotary_layer_s_text_holds_no_float32_heads_outside_the_kernels(
 @pytest.mark.parametrize("cell,kind,kernels", [
     # LFM2's two heads a lane block
     ("lfm2_8b_a1b.s8192_scan", "(None, True)",
-     {"qk_rope_fwd", "qk_rope_bwd", "flash_fwd", "flash_bwd_fused"}),
+     {"qk_rope_fwd", "qk_rope_bwd", "flash_fwd", "flash_delta",
+      "flash_bwd_fused"}),
     # Nemotron-H's Mamba-2 mixer: two groups of 128 channels, float32
     ("nemotron3_nano_30b_a3b.s8192_scan", "mamba2",
      {"mamba_filter_fwd", "mamba_filter_bwd", "ssd_scan_fwd", "ssd_scan_bwd",
@@ -601,7 +617,24 @@ def test_attn_outside_hlo_smoke(one_chip, capsys, cell, kind, kernels):
     assert 0 < report["gb"]["other"]
     if kind != "mamba2":        # a tiny mixer's matmuls are its least part
         assert report["gb"]["other"] < report["gb"]["matmul"]
-    assert json.loads(capsys.readouterr().out.splitlines()[-1]) == report
+    printed = capsys.readouterr().out.splitlines()
+    assert json.loads(printed[-1]) == report
+    # XLA's estimated cycles beside each listed instruction's bytes, and
+    # their sum over "other" in milliseconds (PR 55)
+    assert len(printed) == 4 and all(
+        re.match(r"\s*[\d.]+ MB\s+\d+ cycles  %", l) for l in printed[:3])
+    assert 0 < report["other_estimated_ms"] < 1
+    # the gradients are the leaves' the branch reads (PR 55): a layer's
+    # experts and norms of the other branch are none of them
+    cfg, batch, seq = hlo.cell_config(cell, tiny=True)
+    layer = hlo.default_kind(cfg)
+    leaves, h = hlo.layer_shapes(cfg, batch, seq, layer)
+    read = hlo.leaves_read(hlo.branch_of(cfg, layer), leaves, h)
+    assert set(leaves) - set(read) == (
+        {"ln1_scale"} if kind == "mamba2" else
+        {"ln1_scale", "ln2_scale", "router", "we_down", "we_gate_up"})
+    assert {"w_in", "w_out"} <= set(read) if kind == "mamba2" \
+        else {"wq", "wk", "wv", "wo", "q_norm", "k_norm"} == set(read)
 
 
 # --- the selective scan at its door (PR 49) ----------------------------------
@@ -895,3 +928,90 @@ def test_a_mamba2_layer_s_text_holds_no_float32_pass_behind_the_scan(
     assert not [name for name, _, op, _, _ in comps[entry]
                 if op == "concatenate"]
     assert groups["other"] < 1.2e9 and groups["matmul"] > 2.0e9
+
+
+# --- the flash backward's delta in one pass (PR 55) --------------------------
+
+FLASH_DELTA_CELLS = {   # batch, positions, query heads, head width
+    "smallthinker_21b_a3b.s16384_scan": (1, 16384, 28, 128),
+    "trinity_large_preview.s6144_scan": (1, 6144, 48, 128),
+    "mistral_small_4_119b.s16384_scan": (1, 16384, 32, 128),
+    "nemotron3_nano_30b_a3b.s8192_scan": (2, 8192, 32, 128),
+    "olmoe_1b_7b.s4096_scan": (4, 4096, 16, 128),
+    "ouro_2_6b.s4096_scan": (2, 4096, 16, 128),
+    "lfm2_8b_a1b.s8192_scan": (2, 8192, 32, 64),
+    "jamba2_3b.s8192_scan": (1, 8192, 20, 128),
+}
+
+
+@pytest.mark.parametrize("what", FLASH_DELTA_CELLS)
+def test_the_flash_delta_kernel_compiles_for_a_v5e(one_chip, what):
+    """``kernels/flash_delta.py`` at the eight decoder cells' ``o`` and
+    ``do``, bf16: a dynamic lane-block slice of both, a lane reduce (two
+    masked ones at LFM2's two heads a lane block) and a store of a [rows,
+    heads a block] column into a dynamically indexed plane of the
+    statistic's block are what Mosaic has to take.  A call asks for what
+    its own estimate says (``vmem_bytes``) and the compiled kernel takes no
+    more; the result is the backward kernels' array as it lies (the default
+    tiled layout a Pallas operand has: 128 lanes a row of numbers)."""
+    fd = importlib.import_module("paddle_tpu.kernels.flash_delta")
+    B, S, H, D = FLASH_DELTA_CELLS[what]
+    x = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
+    assert fd.supported(x.shape, D, 2), what
+    text = jax.jit(lambda o, do: fd.flash_delta(
+        o, do, head_dim=D, interpret=False)).lower(x, x).compile().as_text()
+    rows = fd.block_rows(S, H * D, 2)
+    assert rows == (256 if H * D > 2560 else 512), what
+    asked, took = _vmem(text, "flash_delta")
+    assert asked == fd.vmem_bytes(rows, H * D, 2) <= 32 * 2 ** 20, what
+    # (a small result, Ouro's 64 MiB and Jamba's 80, XLA keeps in VMEM
+    # itself in this standalone program: below the scope's start, ``_vmem``)
+    assert took <= asked, (what, took, asked)
+    assert "f32[%d,%d,%d,%d]{3,2,1,0:T(8,128)" % (
+        B, H * D // 128, S, 128 // D) in text, what
+
+
+@pytest.mark.parametrize("cell,shape,parent_other,chain", [
+    # the chain at PR 54 (ISSUE 55's table; "other" by this PR's script on
+    # the parent's tree): copy.16 + reduce + copy.17
+    ("smallthinker_21b_a3b.s16384_scan", (1, 16384, 28), 2.1415e9, 0.71e9),
+    # copy.35 + reduce + copy.28; a third of fusion.2 rode a matmul
+    ("trinity_large_preview.s6144_scan", (1, 6144, 48), 2.4386e9, 0.456e9),
+])
+def test_a_flash_layer_s_text_holds_no_float32_product_of_o_and_do(
+        one_chip, cell, shape, parent_other, chain):
+    """SmallThinker's and Trinity's windowed layer, recompute + backward,
+    through ``scripts/attn_outside_hlo.py`` (the no-chip reading ISSUE 55
+    was sized by): ``flash_delta`` stands between the forward's ``o``, the
+    cotangent ``wo``'s dX matmul hands on, and the backward kernel; no
+    float32 array of tokens x H x D elements is an instruction's result
+    anywhere in the entry computation, a matmul fusion's second output
+    included (the parent's f32[1,16384,3584] product rode
+    ``convert_multiply_fusion``, was copied into another tiling, reduced,
+    and the result copied again); and "other" is lower than the parent's by
+    at least the chain's bytes."""
+    hlo = _script("attn_outside_hlo")
+    cfg, batch, seq = hlo.cell_config(cell, tiny=False)
+    kind = hlo.default_kind(cfg)
+    assert (batch, seq, cfg.n_heads, cfg.head_dim, kind) == shape + (
+        128, (4096, True))
+    text = hlo.compiled_text(cfg, batch, seq, kind)
+    groups, by_kernel, others = hlo.account(text)
+    assert {"flash_swa_fwd", "flash_delta", "flash_swa_bwd_fused"} \
+        <= set(by_kernel)
+    comps, entry = hlo.computations(text)
+    elements = batch * seq * cfg.n_heads * cfg.head_dim
+    wide = [(name, op, types) for name, types, op, _, _ in comps[entry]
+            for dims in re.findall(r"\bf32\[([\d,]+)\]", types)
+            if math.prod(int(d) for d in dims.split(",")) >= elements]
+    assert not wide, wide
+    by = {name: (op, operands) for name, _, op, operands, _ in comps[entry]}
+    delta, = [n for n in by if "flash_delta" in n and by[n][0] == "custom-call"]
+    # the statistic goes to the backward kernel as it is
+    bwd, = [n for n in by if "flash_swa_bwd_fused" in n
+            and by[n][0] == "custom-call"]
+    assert delta in by[bwd][1], by[bwd][1]
+    assert groups["other"] <= parent_other - chain, groups
+    # XLA's own estimate rides beside the bytes
+    assert all(len(o) == 5 for o in others)
+    assert 0 < sum(o[4] for o in others) < 1.5e6, groups

@@ -522,24 +522,30 @@ def test_the_new_scopes_hold_their_instructions_and_attention_none():
 # PR 48, taken on its parent (3ea462c); Jamba's and Nemotron-H's in PR 54, on
 # its parent (37c698c): the passes of a looped stack (``loop_passes``) and
 # the one rule for the dense gated FFN (``cfg.dense_stack``) left all nine.
+# PR 55 took the six anew whose tiny backward is a several-block flash
+# backward at heads of whole lane blocks (SmallThinker, LFM2, Mistral,
+# Trinity, Jamba, Nemotron-H): its ``delta`` is the row kernel's
+# (``kernels/flash_delta.py``) where it was three ``jnp`` lines; BERT's (one
+# kv block: ``delta`` inside the kernel), OLMoE's (heads of 16: no flash
+# call) and Brumby's (retention) stand.
 PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "bert.run_steps": "00de5403506fdc87",
             "olmoe.step": "231114fcd62341f2",
             "olmoe.run_steps": "054338e92270f130",
-            "smallthinker.step": "2e8c6a9b2ddcf175",
-            "smallthinker.run_steps": "bd224212f334b389",
-            "lfm2.step": "bb536069bbd7206a",
-            "lfm2.run_steps": "f5ad889769877a83",
+            "smallthinker.step": "7c77df5736c908d4",
+            "smallthinker.run_steps": "cdbc3b5d4be19b90",
+            "lfm2.step": "7d3dd46e83117d83",
+            "lfm2.run_steps": "55c703e13aaa5114",
             "brumby.step": "84e6b6d548803a44",
             "brumby.run_steps": "5a063ea89a19f1a4",
-            "mistral4.step": "278496583ed8fb80",
-            "mistral4.run_steps": "c9f856c8337ed276",
-            "trinity.step": "2ee67522b53eb798",
-            "trinity.run_steps": "4337fe8cf4863f5f",
-            "jamba.step": "6dfded4d28caec90",
-            "jamba.run_steps": "8ef0306cc8ddd47d",
-            "nemotron_h.step": "4cf5a76a79eea76f",
-            "nemotron_h.run_steps": "59e10ab9a58a2cf9"}
+            "mistral4.step": "835f4ec090f0f6c0",
+            "mistral4.run_steps": "c377a701ae4693ca",
+            "trinity.step": "86340ecdb523bc2f",
+            "trinity.run_steps": "c7581de1b602b474",
+            "jamba.step": "21dc4e9f64565ee9",
+            "jamba.run_steps": "3f4c6780114491e5",
+            "nemotron_h.step": "6bc4605ce6a59ef4",
+            "nemotron_h.run_steps": "3b52cbcf423cbc76"}
 OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
          "olmoe": (olmoe.build_olmoe_trainer, olmoe.olmoe_tiny_config, 32),
          "smallthinker": (smallthinker.build_smallthinker_trainer,
