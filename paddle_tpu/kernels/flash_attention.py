@@ -89,9 +89,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import monitor
 from . import flash_delta
-from ._common import CompilerParams as _CompilerParams, on_tpu as _on_tpu
+from ._common import (CompilerParams as _CompilerParams,
+                      count_call as _count_call, on_tpu as _on_tpu)
 
 __all__ = ["flash_attention", "flash_attention_packed"]
 
@@ -289,7 +289,7 @@ def kv_blocks(S, bq, bk, causal=True, window=None):
 def packed_grid(B, S, n_heads, head_dim, block_q, block_k, itemsize=2,
                 n_kv_heads=None, causal=False, window=None):
     """What ``flash_attention_packed`` runs for these shapes, for whoever
-    wants to say so without tracing it (the trainers' monitor gauges):
+    wants to say so without tracing it (the tests, ``scripts/``):
     (pairs per grid step, grid steps of one layer's forward pass).  Several
     blocks: a (row, head-block) pair times its sweeps' ``step_table``."""
     hpb = _heads_per_block(head_dim)
@@ -300,24 +300,6 @@ def packed_grid(B, S, n_heads, head_dim, block_q, block_k, itemsize=2,
     if S == bk:
         return G * Hg, steps * (S // bq)
     return 1, steps * kv_blocks(S, bq, bk, causal, window)
-
-
-def packed_bwd_sweeps(S, n_heads, head_dim, block_k, itemsize=2,
-                      n_kv_heads=None):
-    """``bwd_sweeps`` of what ``flash_attention_packed`` runs for these
-    shapes (the trainers' monitor gauges)."""
-    hpb = _heads_per_block(head_dim)
-    return bwd_sweeps(S, min(block_k, S), head_dim * hpb, itemsize,
-                      n_heads // (n_kv_heads or n_heads))
-
-
-def packed_heads_stacked(n_heads, head_dim, n_kv_heads=None):
-    """The query heads that one step of ``flash_attention_packed``'s
-    several-block kernels computes as ONE tile, stacked along rows
-    (``_Geom.halves``): the heads of a lane block where they all read one
-    key/value head (head width 64 and grouped queries: 2), else 1."""
-    grouped = n_kv_heads not in (None, n_heads)
-    return _heads_per_block(head_dim) if grouped else 1
 
 
 class _Geom:
@@ -1036,10 +1018,7 @@ def _delta(o, do, g, packed, interpret):
     the kernel)."""
     fused = packed and o.dtype == do.dtype and flash_delta.supported(
         o.shape, g.D, o.dtype.itemsize)
-    mon = monitor.active()
-    if mon is not None:
-        mon.registry.counter("monitor.kernels.flash_delta_calls",
-                             fused=int(fused), head_dim=g.D).incr()
+    _count_call("flash_delta", fused=int(fused), head_dim=g.D)
     if fused:
         return flash_delta.flash_delta(o, do, head_dim=g.D,
                                        interpret=interpret)

@@ -33,13 +33,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._common import CompilerParams as _CompilerParams, on_tpu as _on_tpu
+from ._common import (LANES, CompilerParams as _CompilerParams,
+                      on_tpu as _on_tpu, sublane_tile as _tile)
 
 __all__ = ["flash_delta", "flash_delta_reference", "supported", "block_rows",
            "vmem_bytes"]
 
-LANES = 128
-SUBLANES = 8
 ROW_BLOCKS = (512, 256, 128, 64, 32, 16, 8)
 # what a grid step's pipelined blocks may take of VMEM: o and do, and the
 # statistic's block, whose [rows, heads a block] columns pad to 128 lanes
@@ -57,9 +56,8 @@ def block_rows(S, W, itemsize):
     the tallest of ROW_BLOCKS in whole tiles of the element type (8 rows of
     32 bits, 16 of 16) that divides S and keeps the step's blocks within
     BLOCK_VMEM; None where there is none."""
-    tile = SUBLANES * 4 // itemsize
     return next((bs for bs in ROW_BLOCKS
-                 if bs % tile == 0 and S % bs == 0
+                 if bs % _tile(itemsize) == 0 and S % bs == 0
                  and _block_bytes(bs, W, itemsize) <= BLOCK_VMEM), None)
 
 

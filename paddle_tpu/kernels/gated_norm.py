@@ -60,23 +60,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._common import CompilerParams as _CompilerParams, on_tpu as _on_tpu
+from ._common import (LANES, SUBLANES, CompilerParams as _CompilerParams,
+                      on_tpu as _on_tpu, sublane_sums as _sublane_sums,
+                      sublane_tile as _tile)
 
 __all__ = ["gated_norm", "gated_norm_reference", "supported", "block_rows",
            "walk_rows", "vmem_bytes"]
 
-SUBLANES, LANES = 8, 128
 MAX_GROUP_LANES = 1024      # the widest group tried through Mosaic
 BLOCK_ELEMENTS = 1 << 19    # of one grid step's block of one array
 WALK_ELEMENTS = 1 << 16     # of one turn of the walk (the geometry below)
 ROW_BLOCKS = (2048, 1024, 512, 256, 128, 64, 32, 16, 8)
 WALKS = (512, 256, 128, 64, 32, 16, 8)
 F32 = jnp.float32
-
-
-def _tile(itemsize):
-    """Rows of a sublane tile of the element type: 8 of 32 bits, 16 of 16."""
-    return SUBLANES * 4 // itemsize
 
 
 def block_rows(S, lanes, itemsize):
@@ -154,12 +150,6 @@ def _fwd_kernel(y_ref, z_ref, w_ref, o_ref, *, eps, walk):
         return carry
 
     jax.lax.fori_loop(0, y_ref.shape[0] // walk, turn, 0)
-
-
-def _sublane_sums(v):
-    """``v`` [rows, lanes] summed into eight sublanes: elementwise adds of
-    its 8-row tiles, no cross-sublane reduce."""
-    return jnp.sum(v.reshape(-1, SUBLANES, v.shape[-1]), axis=0)
 
 
 def _bwd_kernel(y_ref, z_ref, g_ref, w_ref, dy_ref, dz_ref, dw_ref, *, eps,

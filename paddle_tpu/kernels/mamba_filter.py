@@ -56,22 +56,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._common import CompilerParams as _CompilerParams, on_tpu as _on_tpu
+from ._common import (SUBLANES, CompilerParams as _CompilerParams,
+                      on_tpu as _on_tpu, sublane_sums as _sublane_sums,
+                      sublane_tile as _tile)
 
 __all__ = ["mamba_filter", "mamba_filter_reference", "supported",
            "block_rows", "block_lanes", "walk_rows", "vmem_bytes"]
 
-SUBLANES = 8            # the halo a walk carries: taps reach at most 7 back
-MAX_TAPS = SUBLANES
+MAX_TAPS = SUBLANES     # the halo a walk carries: taps reach at most 7 back
 ROW_BLOCKS = (2048, 1024, 512, 256, 128, 64, 32, 16, 8)
 LANE_BLOCKS = (512, 256, 128)
 WALKS = (32, 16, 8)
 F32 = jnp.float32
-
-
-def _tile(itemsize):
-    """Rows of a sublane tile of the element type: 8 of 32 bits, 16 of 16."""
-    return SUBLANES * 4 // itemsize
 
 
 def block_rows(S, itemsize):
@@ -164,12 +160,6 @@ def _fwd_kernel(x_ref, halo_ref, before_ref, w_ref, b_ref, o_ref, *, taps,
 
     jax.lax.fori_loop(0, x_ref.shape[0] // walk, turn, _first_halo(
         before_ref, halo_ref, pl.program_id(1) == 0))
-
-
-def _sublane_sums(v):
-    """``v`` [rows, lanes] summed into eight sublanes: elementwise adds of
-    its 8-row tiles, no cross-sublane reduce."""
-    return jnp.sum(v.reshape(-1, SUBLANES, v.shape[-1]), axis=0)
 
 
 def _bwd_kernel(x_ref, halo_ref, after_ref, before_ref, g_ref, g_after_ref,

@@ -48,13 +48,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._common import CompilerParams as _CompilerParams, on_tpu as _on_tpu
+from ._common import (LANES, SUBLANES, CompilerParams as _CompilerParams,
+                      on_tpu as _on_tpu, sublane_sums as _sublane_sums,
+                      sublane_tile as _tile)
 
 __all__ = ["qk_rope", "angle_tables", "supported", "block_rows",
            "vmem_bytes"]
 
-LANES = 128
-SUBLANES = 8
 ROW_BLOCKS = (256, 128, 64, 32, 16, 8)
 # what the backward's six pipelined blocks (x, dy, dx; two copies each) may
 # take of VMEM; the forward's four are under it
@@ -66,9 +66,8 @@ def block_rows(S, W, itemsize):
     shapes alone: the tallest of ROW_BLOCKS in whole tiles of the element
     type (8 rows of 32 bits, 16 of 16) that divides S and keeps the
     backward's six blocks within BLOCK_VMEM; None where there is none."""
-    tile = SUBLANES * 4 // itemsize
     for bs in ROW_BLOCKS:
-        if (bs % tile == 0 and S % bs == 0
+        if (bs % _tile(itemsize) == 0 and S % bs == 0
                 and 6 * bs * W * itemsize <= BLOCK_VMEM):
             return bs
     return None
@@ -186,12 +185,6 @@ def _fwd_kernel(*refs, dh, norm, rotary, eps):
         o_ref[:, sl] = y.astype(o_ref.dtype)
 
     _over_blocks(x_ref, block)
-
-
-def _sublane_sums(v):
-    """``v`` [rows, lanes] summed into eight sublanes: elementwise adds of
-    its 8-row tiles, no cross-sublane reduce."""
-    return jnp.sum(v.reshape(-1, SUBLANES, v.shape[-1]), axis=0)
 
 
 def _bwd_kernel(*refs, dh, norm, rotary, eps):

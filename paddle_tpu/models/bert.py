@@ -27,7 +27,7 @@ from ..parallel import collectives as col
 from ..parallel.mesh import DP, PP, TP, MeshSpec
 from ..parallel.pipeline import gpipe, split_microbatches
 from ..parallel import optim
-from ..parallel.train import (StepTrainer, TrainState, gauge_flash_grid,
+from ..parallel.train import (StepTrainer, TrainState,
                               make_train_step, shard_pytree, state_specs)
 from ..parallel.transformer import (
     TransformerConfig,
@@ -94,15 +94,10 @@ def batch_specs(keys=("ids", "labels", "mask")):
 @dataclasses.dataclass
 class BertTrainer(StepTrainer):
     batch_keys: tuple = ("ids", "labels", "mask")
-    n_microbatches: int = 1
     label = "bert"
 
     def _observe(self, batch):
         self._count_head_rows(batch["mask"])
-        shape = batch["mask"].shape
-        gauge_flash_grid(
-            self.cfg, shape[-2] // self.mesh.shape[DP] // self.n_microbatches,
-            shape[-1])
 
     def _count_head_rows(self, mask):
         """Under a monitor session: the rows the LM head computes for these
@@ -157,5 +152,4 @@ def build_bert_trainer(cfg, mesh_spec: MeshSpec = None, optimizer=None,
         state = shard_pytree(state, sspecs, mesh)
     return BertTrainer(cfg=cfg, mesh=mesh, state=state, step_fn=step_fn,
                        specs=sspecs, multi_fn=multi_fn,
-                       batch_keys=tuple(batch_keys),
-                       n_microbatches=n_microbatches if cfg.pp > 1 else 1)
+                       batch_keys=tuple(batch_keys))

@@ -19,6 +19,7 @@ cross entropies weighted by a learned per-token exit distribution
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -26,10 +27,9 @@ from jax.sharding import PartitionSpec as P
 
 from . import moe, optim
 from .. import monitor
-from ..kernels.power_retention import STATE_COLUMNS, state_sweeps
 from .mesh import DP, MeshSpec, local_shard_map
-from .train import (StepTrainer, TrainState, gauge_flash_grid,
-                    make_train_step, shard_pytree, state_specs)
+from .train import (StepTrainer, TrainState, make_train_step, shard_pytree,
+                    state_specs)
 from .transformer import (
     MAMBA,
     MAMBA2,
@@ -49,14 +49,11 @@ from .transformer import (
     run_layers,
     run_passes,
     transformer_param_specs,
-    yarn_blend_range,
 )
 
 __all__ = ["BATCH_SPECS", "STEPPED", "forward", "weighted_exit_logits",
-           "make_loss_fn",
-           "DecoderTrainer", "build_decoder_trainer", "gauge_moe_rows",
-           "retention_chunks", "retention_state_mb", "retention_state_sweeps",
-           "interpolated_pairs", "scaled_positions"]
+           "make_loss_fn", "probe", "DecoderTrainer",
+           "build_decoder_trainer"]
 
 BATCH_SPECS = {"ids": P(DP)}
 STEPPED = {"router_bias"}       # leaves a step sets itself (make_train_step)
@@ -114,43 +111,6 @@ def make_loss_fn(cfg: TransformerConfig):
     return loss_fn
 
 
-def retention_chunks(cfg, seq):
-    """Chunks a retention layer walks over a sequence of ``seq`` tokens."""
-    return seq // min(cfg.retention_chunk, seq)
-
-
-def retention_state_mb(cfg):
-    """The state one retention layer carries along a sequence, in MB: a
-    float32 ``STATE_COLUMNS`` x head width a key/value head."""
-    return cfg.kv_heads * STATE_COLUMNS * cfg.head_dim * 4 / 1e6
-
-
-def retention_state_sweeps(cfg, seq):
-    """Sweeps of the state's 65 tiles a layer's forward runs over a sequence
-    of ``seq`` tokens, as the kernels' own rule has it: one a key/value
-    head and chunk where a group's query heads ride one grid step, one a
-    query head and chunk where they would not fit the kernels' VMEM."""
-    return state_sweeps(cfg.n_heads, cfg.kv_heads, seq,
-                        min(cfg.retention_chunk, seq),
-                        jnp.dtype(cfg.dtype).itemsize)
-
-
-def interpolated_pairs(cfg):
-    """(first, last) of the rotated pairs whose frequency is WHOLLY the
-    interpolated one, ``plain / rope_factor``; None without YaRN."""
-    if not cfg.rope_factor > 1:
-        return None
-    return yarn_blend_range(cfg)[1], cfg.qk_rope_dim // 2 - 1
-
-
-def scaled_positions(cfg, seq):
-    """Positions of a sequence of ``seq`` tokens whose query is scaled by
-    more than 1: those from ``rope_original_max`` on."""
-    if not cfg.q_scale_beta:
-        return 0
-    return max(seq - cfg.rope_original_max, 0)
-
-
 def _first_layer_input(params, ids, cfg):
     """The first layer's leaves and the normed rows its operator reads."""
     if "prefix_layers" in params:
@@ -166,26 +126,80 @@ def _first_layer_input(params, ids, cfg):
                         cfg.norm_eps)
 
 
-def gauge_moe_rows(cfg, tokens):
-    """Under a monitor session, of one expert layer's call on ``tokens``
-    local tokens, whose sum back is the row kernel's
-    (``kernels/moe_rows.py``): ``monitor.kernels.moe_pair_slots`` is the
-    (token, expert) pair slots the sum back covers, T * k, which a gather
-    would fetch a row for each; ``monitor.kernels.moe_rows_fetch_bound`` the
-    rows the kernel can be asked for at the layer's first capacity
-    (``moe._held_capacities``: a step past it runs the capacity that has a
-    row for every slot; with every expert held, the slots).  Both fixed
-    when the step is traced, so gauges.  Where a share of the experts is
-    held, the rows fetched over the slots is
-    ``monitor.train.moe_rows_held`` / (MoE layers x steps x
-    ``moe_pair_slots``): the held experts' share at balanced routing."""
-    mon = monitor.active()
-    if mon is None:
-        return
-    slots = tokens * cfg.experts_per_token
-    mon.registry.gauge("monitor.kernels.moe_pair_slots").set(slots)
-    mon.registry.gauge("monitor.kernels.moe_rows_fetch_bound").set(
-        moe._held_capacities(slots, cfg.experts_here, cfg.n_experts)[0])
+def probe(params, ids, cfg):
+    """``{name: scalar, or [T] of the exits}`` of ``ids`` [b, S]: the
+    readings ``DecoderTrainer._observe`` takes of one batch, the entries the
+    CONFIGURATION has, each under its ``monitor.train.*`` name, at the
+    weights it is handed.  A reading of the whole stack takes the step's
+    own forward (run once, stopped before the head); one of a first layer
+    takes that layer's leaves and input alone.
+
+    - ``n_experts``: ``moe_load_max_over_mean``, how uneven the routing is,
+      busiest expert over the mean, the largest over layers; where a share
+      of the experts is held, ``moe_rows_held``, the (token, expert) pairs
+      that meet a held expert, summed over layers (the layer counts them);
+    - ``routing`` with selection biases: ``router_bias_abs_max``, the
+      largest, any layer;
+    - a layer kind is RETENTION: ``retention_gate_mean``, the mean ``e^g``
+      over tokens and heads in layer 0: a state decays to 1/e in ``1 / (1 -
+      mean)`` tokens or so;
+    - the first layer is MAMBA: ``mamba_dt_mean``, the mean step size over
+      tokens and channels in that layer, and ``mamba_decay_min``, the
+      smallest ``exp(dt * A)`` of any token, channel and state cell there:
+      with the mean's own decay ``exp(-dt)`` it tells a state that never
+      carries (both near 0) from one that never forgets (both near 1);
+    - a layer kind is MAMBA2: ``mamba2_dt_mean`` and ``mamba2_decay_min``
+      (the smallest ``exp(dt A)`` of any token and head: how far the carry
+      reaches), of the period's first such position as the EMBEDDING hands
+      the batch over (the stream as it enters the stack, not as that layer
+      finds it: at seeded weights the step sizes are their bias's);
+    - ``attn_gate``: ``attn_gate_mean``, the mean of the output gate's
+      sigmoid over tokens, heads and columns in the first layer: a gate
+      stuck at 0 or 1 is a dead branch;
+    - ``loop_passes`` > 1: ``exit_prob_mean`` [T], the mean over tokens of
+      each exit's probability (a gate stuck at 0 or 1 is a dead exit), and
+      ``exit_entropy_mean``, the mean entropy of a token's exit
+      distribution in nats (ln T at most; 0 is a collapsed gate)."""
+    out = {}
+    if cfg.loop_passes > 1:
+        log_p = exit_log_probs(forward(params, ids, cfg)[1])
+        p = jnp.exp(log_p)
+        out["exit_prob_mean"] = jnp.mean(p, axis=(1, 2))
+        out["exit_entropy_mean"] = jnp.mean(-jnp.sum(p * log_p, axis=0))
+    elif cfg.n_experts:
+        aux = forward(params, ids, cfg)[1]
+        out["moe_load_max_over_mean"] = jnp.max(aux["load_max_over_mean"])
+        if "rows_held" in aux:
+            out["moe_rows_held"] = jnp.sum(aux["rows_held"])
+    if cfg.routing == moe.SIGMOID_BIASED:
+        out["router_bias_abs_max"] = jnp.max(abs(params["router_bias"]))
+    mamba_first = cfg.layer_kinds[0] == MAMBA and not cfg.prefix_pattern
+    if RETENTION in cfg.layer_kinds or cfg.attn_gate or mamba_first:
+        pl, h = _first_layer_input(params, ids, cfg)
+    if RETENTION in cfg.layer_kinds:
+        out["retention_gate_mean"] = jnp.mean(
+            jnp.exp(retention_log_decay(pl, h)))
+    if mamba_first:
+        dt = mamba_operands(pl, h, cfg, h, 0)[2]
+        # the fastest cell of each channel under its largest step
+        rate = jnp.max(jnp.exp(pl["a_log"]), axis=-1)
+        out["mamba_dt_mean"] = jnp.mean(dt)
+        out["mamba_decay_min"] = jnp.exp(
+            -jnp.max(jnp.max(dt, axis=(0, 1)) * rate))
+    if MAMBA2 in cfg.layer_kinds:
+        at = cfg.layer_kinds.index(MAMBA2)
+        pl2 = jax.tree.map(lambda a: a[0],
+                           params["params_layers"]["p%d" % at])
+        dt = mamba2_operands(pl2, rms_norm(
+            embed(params, ids, cfg), pl2["ln1_scale"], cfg.norm_eps),
+            cfg)[2]
+        out["mamba2_dt_mean"] = jnp.mean(dt)
+        out["mamba2_decay_min"] = jnp.exp(-jnp.max(
+            jnp.max(dt, axis=(0, 1)) * jnp.exp(pl2["a_log"])))
+    if cfg.attn_gate:
+        out["attn_gate_mean"] = jnp.mean(jax.nn.sigmoid(
+            (h @ pl["wz"]).astype(jnp.float32)))
+    return out
 
 
 @dataclasses.dataclass
@@ -194,8 +208,7 @@ class DecoderTrainer(StepTrainer):
     (``<label>.step``, ``<label>.run_steps``)."""
 
     label: str = "decoder"
-    _logits_fn = _routing_fn = _gate_fn = _attn_gate_fn = _mamba_fn = None
-    _mamba2_fn = _exits_fn = None
+    _logits_fn = _probe_fn = None
 
     def _on_mesh(self, fn, out_specs, *more):
         """``fn(params, ids [b, S], *more)`` jitted over the mesh, the
@@ -229,179 +242,39 @@ class DecoderTrainer(StepTrainer):
 
     def _observe(self, batch):
         """Under a monitor session, of a call on ``batch["ids"]`` [..., B,
-        S] (any leading step axis), what the CONFIGURATION has; a forward
-        that a reading needs is one of its own that stops before the head,
-        at the weights the call starts from.  Off the monitor nothing runs
-        or is read back.
-
-        - a layer kind is attention: the flash kernels' grid
-          (``train.gauge_flash_grid``);
-        - ``n_experts``: ``monitor.train.moe_assignments``, the token-slots
-          the call routes (T * k a MoE layer and step; a counter);
-          ``moe_load_max_over_mean``, how uneven the routing of the call's
-          first batch is, busiest expert over the mean, the largest over
-          layers; the row kernel's two (``gauge_moe_rows``);
-        - ``experts_held``: ``moe_rows_held``, the (token, expert) pairs of
-          EVERY batch of the call that meet a held expert (a counter), and
-          ``moe_held_rows_share``, their share of the call's pairs
-          (experts_held / n_experts at uniform routing; 1 of a full set);
-        - ``routing`` with selection biases: ``router_bias_abs_max``, the
-          largest, any layer, as the call starts;
-        - a layer kind is RETENTION: ``retention_chunks`` (a layer and
-          sequence), ``retention_state_mb`` (the state a layer carries),
-          ``retention_state_sweeps`` and ``retention_gate_mean``, the mean
-          ``e^g`` over tokens and heads of the call's first batch in layer
-          0: a state decays to 1/e in ``1 / (1 - mean)`` tokens or so;
-        - the first layer is MAMBA: ``mamba_dt_mean``, the mean step size
-          over tokens and channels of the call's first batch in that layer,
-          and ``mamba_decay_min``, the smallest ``exp(dt * A)`` of any
-          token, channel and state cell there: with the mean's own decay
-          ``exp(-dt)`` it tells a state that never carries (both near 0)
-          from one that never forgets (both near 1);
-        - a layer kind is MAMBA2: ``mamba2_dt_mean`` and
-          ``mamba2_decay_min`` (the smallest ``exp(dt A)`` of any token and
-          head: how far the carry reaches), of the period's first such
-          position on the call's first batch as the EMBEDDING hands it over
-          (the stream as it enters the stack, not as that layer finds it:
-          at seeded weights the step sizes are their bias's);
-        - ``attn_gate``: ``attn_gate_mean``, the mean of the output gate's
-          sigmoid over tokens, heads and columns of the call's first batch
-          in the first layer: a gate stuck at 0 or 1 is a dead branch;
-        - ``latent``: ``mla_latent_bytes_per_token`` (what a layer's keys
-          and values come from: the latent and the shared rotary key)
-          beside ``mla_expanded_kv_bytes_per_token`` (what the flash
-          kernels read: every head's key and value),
-          ``yarn_first_interpolated_pair`` / ``_last_``
-          (``interpolated_pairs``) and ``q_scaled_positions``
-          (``scaled_positions``); all fixed when the step is traced;
-        - ``loop_passes`` > 1: ``loop_passes`` (a gauge) and
-          ``layer_applications``, passes x layers x the call's steps (a
-          counter); of the call's first batch, ``exit_prob_mean{exit}``,
-          the mean over tokens of each exit's probability (a gate stuck at
-          0 or 1 is a dead exit), and ``exit_entropy_mean``, the mean
-          entropy of a token's exit distribution in nats (ln T at most; 0
-          is a collapsed gate)."""
+        S] (any leading step axis): ``probe``'s readings of the call's
+        FIRST batch at the weights the call starts from, a gauge an entry
+        (``monitor.train.<name>``; ``exit_prob_mean{exit}`` one an exit),
+        from ONE program a trainer.  With ``experts_held`` also
+        ``moe_rows_held``, the (token, expert) pairs of EVERY batch of the
+        call that meet a held expert (a counter: the probe runs on the other
+        batches too), and ``moe_held_rows_share``, their share of the call's
+        pairs (experts_held / n_experts at uniform routing; 1 of a full set,
+        whose every pair is held).  Off the monitor nothing is built, run or
+        read back."""
         mon = monitor.active()
         if mon is None:
             return
         cfg, ids, params = self.cfg, batch["ids"], self.state["params"]
-        seq = ids.shape[-1]
-        local = ids.shape[-2] // self.mesh.shape[DP]
         batches = ids.reshape((-1,) + ids.shape[-2:])
-
-        def gauge(name, value):
-            mon.registry.gauge("monitor.train." + name).set(value)
-
-        def count(name, amount):
-            mon.registry.counter("monitor.train." + name).incr(amount)
-
-        if any(isinstance(k, tuple) for k in cfg.layer_kinds):
-            gauge_flash_grid(cfg, local, seq)
-        if cfg.n_experts:
+        if self._probe_fn is None:
+            self._probe_fn = self._on_mesh(
+                functools.partial(probe, cfg=cfg), P())
+        read = jax.device_get(self._probe_fn(params, batches[0]))
+        held = read.pop("moe_rows_held", None)
+        for t, prob in enumerate(read.pop("exit_prob_mean", ())):
+            mon.registry.gauge("monitor.train.exit_prob_mean",
+                               exit=t + 1).set(float(prob))
+        for name, value in read.items():
+            mon.registry.gauge("monitor.train." + name).set(float(value))
+        if cfg.experts_held:
             pairs = int(ids.size) * cfg.experts_per_token * cfg.moe_layers
-            count("moe_assignments", pairs)
-            if self._routing_fn is None:
-                def routing(params, ids):
-                    aux = forward(params, ids, cfg)[1]
-                    # the layer counts the pairs held where it holds a share
-                    return (jnp.max(aux["load_max_over_mean"]),
-                            jnp.sum(aux.get("rows_held", 0)))
-
-                self._routing_fn = self._on_mesh(routing, (P(), P()))
-            share = cfg.experts_here < cfg.n_experts
-            read = [self._routing_fn(params, b)
-                    for b in (batches if share else batches[:1])]
-            gauge("moe_load_max_over_mean", float(read[0][0]))
-            if cfg.experts_held:
-                # of a full set every pair is held
-                held = sum(int(h) for _, h in read) if share else pairs
-                count("moe_rows_held", held)
-                gauge("moe_held_rows_share", held / pairs)
-            gauge_moe_rows(cfg, local * seq)
-        if cfg.routing == moe.SIGMOID_BIASED:
-            gauge("router_bias_abs_max",
-                  float(abs(params["router_bias"]).max()))
-        if RETENTION in cfg.layer_kinds:
-            gauge("retention_chunks", retention_chunks(cfg, seq))
-            gauge("retention_state_mb", retention_state_mb(cfg))
-            gauge("retention_state_sweeps", retention_state_sweeps(cfg, seq))
-            if self._gate_fn is None:
-                def gate_mean(params, ids):
-                    pl, h = _first_layer_input(params, ids, cfg)
-                    return jnp.mean(jnp.exp(retention_log_decay(pl, h)))
-
-                self._gate_fn = self._on_mesh(gate_mean, P())
-            gauge("retention_gate_mean",
-                  float(self._gate_fn(params, batches[0])))
-        if cfg.layer_kinds[0] == MAMBA and not cfg.prefix_pattern:
-            if self._mamba_fn is None:
-                def step_sizes(params, ids):
-                    pl, h = _first_layer_input(params, ids, cfg)
-                    dt = mamba_operands(pl, h, cfg, h, 0)[2]
-                    # the fastest cell of each channel under its largest step
-                    rate = jnp.max(jnp.exp(pl["a_log"]), axis=-1)
-                    return jnp.mean(dt), jnp.exp(-jnp.max(
-                        jnp.max(dt, axis=(0, 1)) * rate))
-
-                self._mamba_fn = self._on_mesh(step_sizes, (P(), P()))
-            dt_mean, decay_min = self._mamba_fn(params, batches[0])
-            gauge("mamba_dt_mean", float(dt_mean))
-            gauge("mamba_decay_min", float(decay_min))
-        if MAMBA2 in cfg.layer_kinds:
-            if self._mamba2_fn is None:
-                at = cfg.layer_kinds.index(MAMBA2)
-
-                def step_sizes2(params, ids):
-                    pl = jax.tree.map(lambda a: a[0],
-                                      params["params_layers"]["p%d" % at])
-                    h = rms_norm(embed(params, ids, cfg), pl["ln1_scale"],
-                                 cfg.norm_eps)
-                    dt = mamba2_operands(pl, h, cfg)[2]
-                    return jnp.mean(dt), jnp.exp(-jnp.max(
-                        jnp.max(dt, axis=(0, 1)) * jnp.exp(pl["a_log"])))
-
-                self._mamba2_fn = self._on_mesh(step_sizes2, (P(), P()))
-            dt_mean, decay_min = self._mamba2_fn(params, batches[0])
-            gauge("mamba2_dt_mean", float(dt_mean))
-            gauge("mamba2_decay_min", float(decay_min))
-        if cfg.attn_gate:
-            if self._attn_gate_fn is None:
-                def attn_gate_mean(params, ids):
-                    pl, h = _first_layer_input(params, ids, cfg)
-                    return jnp.mean(jax.nn.sigmoid(
-                        (h @ pl["wz"]).astype(jnp.float32)))
-
-                self._attn_gate_fn = self._on_mesh(attn_gate_mean, P())
-            gauge("attn_gate_mean",
-                  float(self._attn_gate_fn(params, batches[0])))
-        if cfg.latent:
-            itemsize = cfg.jdtype.itemsize
-            gauge("mla_latent_bytes_per_token",
-                  (cfg.kv_lora_rank + cfg.qk_rope_dim) * itemsize)
-            gauge("mla_expanded_kv_bytes_per_token",
-                  cfg.n_heads * (cfg.head_dim + cfg.v_head_dim) * itemsize)
-            whole = interpolated_pairs(cfg)
-            if whole:
-                gauge("yarn_first_interpolated_pair", whole[0])
-                gauge("yarn_last_interpolated_pair", whole[1])
-            gauge("q_scaled_positions", scaled_positions(cfg, seq))
-        if cfg.loop_passes > 1:
-            gauge("loop_passes", cfg.loop_passes)
-            count("layer_applications",
-                  cfg.loop_passes * cfg.n_layers * len(batches))
-            if self._exits_fn is None:
-                def exits(params, ids):
-                    log_p = exit_log_probs(forward(params, ids, cfg)[1])
-                    p = jnp.exp(log_p)
-                    return (jnp.mean(p, axis=(1, 2)),
-                            jnp.mean(-jnp.sum(p * log_p, axis=0)))
-
-                self._exits_fn = self._on_mesh(exits, (P(), P()))
-            probs, entropy = self._exits_fn(params, batches[0])
-            for t, prob in enumerate(probs):
-                mon.registry.gauge("monitor.train.exit_prob_mean",
-                                   exit=t + 1).set(float(prob))
-            gauge("exit_entropy_mean", float(entropy))
+            held = pairs if held is None else int(held) + sum(
+                int(self._probe_fn(params, b)["moe_rows_held"])
+                for b in batches[1:])
+            mon.registry.counter("monitor.train.moe_rows_held").incr(held)
+            mon.registry.gauge("monitor.train.moe_held_rows_share").set(
+                held / pairs)
 
 
 def build_decoder_trainer(cfg, mesh_spec: MeshSpec = None, optimizer=None,

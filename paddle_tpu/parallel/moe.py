@@ -50,8 +50,7 @@ from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
 from . import collectives as col
 from .mesh import DP
-from .. import monitor
-from ..kernels._common import on_tpu
+from ..kernels._common import count_call, on_tpu
 from ..kernels.moe_rows import moe_rows_sum
 from ..monitor import devscope
 
@@ -316,12 +315,9 @@ def _note_tiling(kernel, tiling, m, groups):
     ``thin`` (1 where a group expects fewer rows than the tallest row
     tile), so a summary says how many of a program's grouped matmuls were
     compiled off the 512-row tile."""
-    mon = monitor.active()
-    if mon is not None:
-        mon.registry.counter(
-            "monitor.kernels.moe_grouped_matmul_calls", kernel=kernel,
-            tm=tiling[0], tk=tiling[1], tn=tiling[2],
-            thin=int(m // groups < ROW_TILES[0])).incr()
+    count_call("moe_grouped_matmul", kernel=kernel, tm=tiling[0],
+               tk=tiling[1], tn=tiling[2],
+               thin=int(m // groups < ROW_TILES[0]))
 
 
 def _whole_row_tiles(rows, tm):

@@ -19,13 +19,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import collectives as col
 from .mesh import local_shard_map
-from .transformer import _packed_flash_blocks
-from .. import monitor, warm as _warm
+from .. import warm as _warm
 from ..monitor import devscope as _devscope, memscope as _memscope
 from ..monitor.recompile import FIRST_CALL, compile_ledger
 
 __all__ = ["TrainState", "RUNNING", "make_train_step", "StepTrainer",
-           "gauge_flash_grid", "shard_pytree", "stack_batches", "TrainLoop"]
+           "shard_pytree", "stack_batches", "TrainLoop"]
 
 # The key of a TrainState's third entry, where a model has one: running
 # state, which a step hands on to the next and does not train (batch norm's
@@ -238,7 +237,10 @@ class StepTrainer:
 
     def _observe(self, batch):
         """``batch`` as ``step`` or ``run_steps`` got it (the latter's with a
-        leading step axis)."""
+        leading step axis).  The rule for what a trainer writes here: a name
+        under ``monitor.train.*`` is a reading that depends on the batch's
+        data or on the weights; what the configuration and the shapes fix
+        is a function, and its test calls the function."""
 
     def step(self, batch, lr):
         self._observe(batch)
@@ -272,66 +274,6 @@ class StepTrainer:
             return losses
         self.state, losses = self.multi_fn(self.state, batches, lr)
         return losses
-
-
-def gauge_flash_grid(cfg, b, S):
-    """Under a monitor session: what one grid step of the flash kernels holds
-    for ``b`` local sequences of S positions (one kernel call: a dp shard's
-    batch, or a pipeline microbatch of it), from the function the kernels
-    take their grid from.  ``monitor.kernels.flash_pairs_per_grid_step`` is
-    the (batch row, head-block) pairs a step computes, 1 where every pair is
-    a step of its own; ``monitor.kernels.flash_grid_steps`` the steps of one
-    layer's forward pass (of a full layer's where the kinds differ);
-    ``monitor.kernels.flash_heads_stacked`` the query heads a kv step of the
-    several-block kernels computes as one tile, stacked along rows (2 where
-    the two heads of a 64-wide lane block read one key/value head, else 1:
-    ``flash_attention.packed_heads_stacked``).  A stack
-    of several layer kinds also says, by kind (``_full``, ``_windowed``), the
-    (q block, kv block) steps of one head's forward sweep:
-    ``monitor.kernels.flash_kv_blocks_visited_*`` those that hold a
-    (query, key) pair the mask lets through and so compute, and
-    ``flash_kv_blocks_skipped_*`` the steps the grid holds beyond them (0:
-    the grid is the sweep's step table).
-    ``monitor.kernels.flash_bwd_sweeps_full`` (and ``_windowed`` in a stack
-    of several kinds) is the kernels of that kind's backward: 1
-    where dq, dk and dv come off one sweep, 2 where the sequence is past
-    what VMEM holds of dk and dv (``flash_attention.bwd_sweeps``, which the
-    kernel asks).  All fixed when the step is traced, so gauges; nothing is
-    set where attention does not take the packed kernel."""
-    mon = monitor.active()
-    if mon is None or cfg.attn_mode != "heads":
-        return
-    hl, kvl = cfg.n_heads // cfg.tp, cfg.kv_heads // cfg.tp
-    blocks = _packed_flash_blocks(cfg, hl, S, kvl)
-    if blocks is None:
-        return
-    from ..kernels.flash_attention import (kv_blocks, packed_bwd_sweeps,
-                                           packed_grid, packed_heads_stacked)
-
-    pairs, steps = packed_grid(b, S, hl, cfg.head_dim, *blocks,
-                               itemsize=cfg.jdtype.itemsize, n_kv_heads=kvl,
-                               causal=cfg.causal)
-    mon.registry.gauge("monitor.kernels.flash_pairs_per_grid_step").set(pairs)
-    mon.registry.gauge("monitor.kernels.flash_grid_steps").set(steps)
-    mon.registry.gauge("monitor.kernels.flash_heads_stacked").set(
-        packed_heads_stacked(hl, cfg.head_dim, kvl))
-    # a window changes the table, not what VMEM holds: one answer a stack
-    sweeps = packed_bwd_sweeps(S, hl, cfg.head_dim, blocks[1],
-                               itemsize=cfg.jdtype.itemsize, n_kv_heads=kvl)
-    for name in ("full", "windowed") if cfg.layer_pattern else ("full",):
-        mon.registry.gauge("monitor.kernels.flash_bwd_sweeps_" + name).set(
-            sweeps)
-    if cfg.layer_pattern:
-        # the attention positions: the others are names
-        window = max((k[0] or 0 for k in cfg.layer_kinds
-                      if isinstance(k, tuple)), default=0) or None
-        for name, w in (("full", None), ("windowed", window)):
-            mon.registry.gauge(
-                "monitor.kernels.flash_kv_blocks_visited_" + name).set(
-                    kv_blocks(S, *blocks, cfg.causal, w))
-            # the grid is the table of the visited steps: it holds no other
-            mon.registry.gauge(
-                "monitor.kernels.flash_kv_blocks_skipped_" + name).set(0)
 
 
 class TrainLoop:

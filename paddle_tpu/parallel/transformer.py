@@ -32,7 +32,6 @@ from jax.sharding import PartitionSpec as P
 
 from . import collectives as col
 from .mesh import DP, PP, TP
-from .. import monitor
 from ..monitor import devscope
 from ..monitor.recompile import compile_ledger
 from .ring_attention import ring_attention
@@ -1136,15 +1135,13 @@ def _norm_and_rotate(xs, weights, heads, fused, cfg, rotary, first):
     of a traced call counts in ``monitor.kernels.qk_rope_calls`` (``fused``
     1 for the kernel)."""
     from ..kernels import qk_rope
+    from ..kernels._common import count_call
 
     dh = cfg.head_dim
     norm = cfg.qk_norm and ("head" if cfg.qk_norm == "head" else "whole")
-    mon = monitor.active()
-    if mon is not None:
-        for took in fused:
-            mon.registry.counter(
-                "monitor.kernels.qk_rope_calls", dh=dh, norm=norm or "none",
-                rotary=int(rotary), fused=int(took)).incr()
+    for took in fused:
+        count_call("qk_rope", dh=dh, norm=norm or "none", rotary=int(rotary),
+                   fused=int(took))
     tables = qk_rope.angle_tables(xs[0].shape[1], dh, cfg.rope_theta, first) \
         if rotary and any(fused) else None
 
@@ -1339,6 +1336,7 @@ def mamba_operands(pl, h, cfg, rows, first):
     x half where ``kernels/mamba_filter.py`` takes the shapes, and the
     ``jnp`` lines (its reference) elsewhere."""
     from ..kernels import mamba_filter as mf
+    from ..kernels._common import count_call
 
     halo, d = cfg.d_conv - 1, cfg.d_inner
     whole = isinstance(first, int) and first == 0
@@ -1351,11 +1349,8 @@ def mamba_operands(pl, h, cfg, rows, first):
                            .astype(jnp.float32), 0.0)
     fused = mf.supported(rows.shape[:2] + (d,), cfg.d_conv,
                          rows.dtype.itemsize)
-    mon = monitor.active()
-    if mon is not None:
-        mon.registry.counter("monitor.kernels.mamba_filter_calls",
-                             fused=int(fused),
-                             halo="zeros" if whole else "rows").incr()
+    count_call("mamba_filter", fused=int(fused),
+               halo="zeros" if whole else "rows")
     xz = rows @ pl["w_in"]
     if fused:
         x = mf.mamba_filter(xz, pl["conv_w"], pl["conv_b"], before, width=d)
@@ -1382,6 +1377,7 @@ def mamba_mixer(pl, h, cfg):
     the scan runs a block of positions at a time where the activations are
     large (``_by_row_blocks``)."""
     from ..kernels import selective_scan as scan
+    from ..kernels._common import count_call
 
     # the stage's widest activation is the projection [x | z]
     x, z, dt, bmat, cmat = _by_row_blocks(
@@ -1389,13 +1385,10 @@ def mamba_mixer(pl, h, cfg):
         2 * cfg.d_inner)
     chunk = min(cfg.scan_chunk, h.shape[1])
     kernel = scan.supported(x.shape, cfg.d_state, chunk)
-    mon = monitor.active()
-    if mon is not None:
-        # door: how the per-token operands reach the scan: the kernels read
-        # the projections' own tiles; the per-token scan transposes copies
-        mon.registry.counter("monitor.kernels.selective_scan_calls",
-                             fused=int(kernel),
-                             door="tiles" if kernel else "copied").incr()
+    # door: how the per-token operands reach the scan: the kernels read the
+    # projections' own tiles; the per-token scan transposes copies
+    count_call("selective_scan", fused=int(kernel),
+               door="tiles" if kernel else "copied")
     z_at = z.shape[-1] // cfg.d_inner - 1       # behind x where packed
     if not kernel:
         z = z[..., z_at * cfg.d_inner:]
@@ -1416,16 +1409,14 @@ def mamba2_operands(pl, h, cfg):
     each way on the packed projection's own first lanes where it takes the
     shapes, and the ``jnp`` lines (its reference) elsewhere."""
     from ..kernels import mamba_filter as mf
+    from ..kernels._common import count_call
 
     W = cfg.d_inner + 2 * cfg.ssm_groups * cfg.d_state
     packed = h @ pl["w_in"]
     fused = mf.supported(h.shape[:2] + (W,), cfg.d_conv,
                          h.dtype.itemsize) \
         and packed.shape[-1] % mf.block_lanes(W) == 0
-    mon = monitor.active()
-    if mon is not None:
-        mon.registry.counter("monitor.kernels.mamba_filter_calls",
-                             fused=int(fused), halo="zeros").incr()
+    count_call("mamba_filter", fused=int(fused), halo="zeros")
     if fused:
         xbc = mf.mamba_filter(packed, pl["conv_w"], pl["conv_b"], width=W)
     else:
@@ -1452,23 +1443,19 @@ def mamba2_mixer(pl, h, cfg):
     and the ``jnp`` lines, its reference, elsewhere); then ``w_out``.
     Filter, step sizes, state and norm in float32."""
     from ..kernels import gated_norm as gn, ssd_scan as ssd
+    from ..kernels._common import count_call
 
     d, G, N, nh = cfg.d_inner, cfg.ssm_groups, cfg.d_state, cfg.ssm_heads
     xbc, packed, dt = mamba2_operands(pl, h, cfg)
     chunk = min(cfg.scan_chunk, h.shape[1])
     kernel = ssd.supported(xbc.shape, nh, G, N, chunk)
-    mon = monitor.active()
-    if mon is not None:
-        mon.registry.counter("monitor.kernels.ssd_scan_calls",
-                             fused=int(kernel)).incr()
+    count_call("ssd_scan", fused=int(kernel))
     with jax.named_scope(devscope.SSD_SCAN):
         y = (ssd.ssd_scan if kernel else ssd.ssd_scan_chunked)(
             xbc, dt, -jnp.exp(pl["a_log"]), pl["d_skip"], heads=nh,
             groups=G, d_state=N, chunk=chunk)
     fused = gn.supported(y.shape, G, packed.shape[-1], y.dtype.itemsize)
-    if mon is not None:
-        mon.registry.counter("monitor.kernels.gated_norm_calls",
-                             fused=int(fused)).incr()
+    count_call("gated_norm", fused=int(fused))
     if fused:
         normed = gn.gated_norm(y, packed, pl["gate_norm"], groups=G,
                                eps=cfg.norm_eps)

@@ -12,13 +12,12 @@ are printed with their ``op_name`` whatever their scope.
 
 Builds the cell's trainer and stages its batches as the scan driver does,
 runs one dispatch under a monitor session (the scan driver opens none, so
-this is where ``monitor.train.lm_head_rows_share``, the two
-``monitor.kernels.flash_*`` gauges and, for a sparse decoder, its
-``monitor.train.moe_*``, ``monitor.kernels.moe_*``,
-``monitor.kernels.flash_kv_blocks_*``,
-``flash_bwd_sweeps_*``, ``monitor.kernels.qk_rope_calls`` and
-``monitor.kernels.flash_delta_calls`` values and a looped stack's ``monitor.train.loop_passes``, ``layer_applications`` and
-``exit_*`` are read on the chip),
+this is where ``monitor.train.lm_head_rows_share`` and, for a decoder, its
+readings of the batch and the weights — ``monitor.train.moe_*``,
+``router_*``, ``retention_*``, ``mamba*``, ``attn_gate_*``, ``exit_*`` —
+and every ``monitor.kernels.*_calls`` count of what the trace did are read
+on the chip; what the configuration fixes is no gauge: the flash kernels'
+grid is printed from ``flash_attention.packed_grid`` at the cell's shape),
 traces one more, and joins the trace with THIS process's scope map
 (``monitor.devscope``; a map compiled elsewhere need not number its
 instructions the same way).  ``--ones`` replaces the mask by all ones, the
@@ -63,6 +62,7 @@ def main(argv=None):
     from benchmark.harness.cellrun import Ctx
     from benchmark.harness.spans import Spans
     from paddle_tpu import compile_cache, monitor
+    from paddle_tpu.kernels.flash_attention import packed_grid
     from paddle_tpu.monitor import devscope
     from paddle_tpu.parallel import transformer
 
@@ -103,22 +103,25 @@ def main(argv=None):
               % (mon.registry.gauge("monitor.train.lm_head_rows_share").value,
                  mon.registry.counter("monitor.train.lm_head_rows").value
                  - rows0, steps))
-        print("flash: monitor.kernels.flash_pairs_per_grid_step %s, "
-              "monitor.kernels.flash_grid_steps %s a layer and pass"
-              % tuple(mon.registry.gauge("monitor.kernels.flash_" + g).value
-                      for g in ("pairs_per_grid_step", "grid_steps")))
-        for row in mon.registry.snapshot():    # a sparse decoder's own
+        ids, cfg = staged["ids"], tr.cfg
+        heads, seq = cfg.n_heads // cfg.tp, ids.shape[-1]
+        blocks = transformer._packed_flash_blocks(
+            cfg, heads, seq, cfg.kv_heads // cfg.tp)
+        if cfg.attn_mode == "heads" and blocks:
+            print("flash: %s (batch row, head-block) pairs a grid step, %s "
+                  "grid steps a layer and pass (flash_attention.packed_grid)"
+                  % packed_grid(
+                      ids.shape[-2] // tr.mesh.shape["dp"], seq, heads,
+                      cfg.head_dim, *blocks, itemsize=cfg.jdtype.itemsize,
+                      n_kv_heads=cfg.kv_heads // cfg.tp, causal=cfg.causal))
+        for row in mon.registry.snapshot():    # a decoder's own
             if row["name"].startswith(("monitor.train.moe_",
-                                       "monitor.kernels.moe_",
                                        "monitor.train.router_",
-                                       "monitor.kernels.flash_kv_blocks_",
-                                       "monitor.kernels.flash_bwd_sweeps_",
-                                       "monitor.kernels.qk_rope_calls",
-                                       "monitor.kernels.flash_delta_calls",
-                                       # a looped stack's own
-                                       "monitor.train.loop_passes",
-                                       "monitor.train.layer_applications",
-                                       "monitor.train.exit_")):
+                                       "monitor.train.retention_",
+                                       "monitor.train.mamba",
+                                       "monitor.train.attn_gate_",
+                                       "monitor.train.exit_",
+                                       "monitor.kernels.")):
                 print("monitor: %s%s %s" % (
                     row["name"], row["labels"] or "", row.get("value")))
         monitor.disable()

@@ -102,16 +102,18 @@ def test_the_tiny_configuration_keeps_every_mechanism():
     assert cfg.per_position and cfg.n_periods == 2 and cfg.moe_layers == 0
     assert (cfg.n_heads, cfg.kv_heads, cfg.head_dim) == (10, 2, 128)
     assert cfg.qk_norm == "head" and not cfg.tie_head and not cfg.n_experts
-    assert decoder.retention_chunks(cfg, S) == 4
+    assert S // min(cfg.retention_chunk, S) == 4     # chunks a sequence
     assert pr.supported(cfg.head_dim, S, cfg.retention_chunk)
     big = brumby.brumby_14b_config()
     assert (big.n_layers, big.hidden, big.n_heads, big.kv_heads, big.head_dim,
             big.dense_ffn_hidden, big.vocab_size, big.rope_theta,
             big.norm_eps, big.retention_chunk) == (
         40, 5120, 40, 8, 128, 17408, 151936, 1e6, 1e-6, 1024)
-    assert decoder.retention_chunks(big, 16384) == 16
-    # 8 heads x 8,320 x 128 float32
-    np.testing.assert_allclose(decoder.retention_state_mb(big), 34.08, rtol=1e-3)
+    assert 16384 // min(big.retention_chunk, 16384) == 16
+    # the state a layer carries: 8 heads x 8,320 x 128 float32, in MB
+    np.testing.assert_allclose(
+        big.kv_heads * pr.STATE_COLUMNS * big.head_dim * 4 / 1e6, 34.08,
+        rtol=1e-3)
     assert pr.STATE_COLUMNS == 8320 and pr.DIAGONALS == 65
 
 
@@ -254,7 +256,9 @@ def test_a_group_that_does_not_fit_goes_in_parts_to_the_same_numbers(
             a, b, rtol=1e-5, atol=1e-6 * float(jnp.abs(b).max()),
             err_msg=name)
     cfg = brumby.brumby_tiny_config()
-    assert decoder.retention_state_sweeps(cfg, S) == 2 * 4 * 5
+    assert pr.state_sweeps(cfg.n_heads, cfg.kv_heads, S,
+                           min(cfg.retention_chunk, S),
+                           cfg.jdtype.itemsize) == 2 * 4 * 5
     # six heads a group go in threes where two such steps fit and six do not
     monkeypatch.setattr(pr, "VMEM_LIMIT", pr._step_vmem_bytes(3, 16, 4))
     assert pr.sweep_heads(6, 16, 4) == 3
@@ -539,12 +543,14 @@ def test_gauges_only_under_a_monitor_session(tmp_path):
     try:
         reg = mon.registry
         tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS, batches), 1e-3)
-        assert reg.gauge("monitor.train.retention_chunks").value == 4
+        cfg, chunk = tr.cfg, min(tr.cfg.retention_chunk, S)
+        assert S // chunk == 4
         # a sweep of the state's tiles a key/value head and chunk: the
         # five heads of a group ride one grid step
-        assert reg.gauge("monitor.train.retention_state_sweeps").value == 8
+        assert pr.state_sweeps(cfg.n_heads, cfg.kv_heads, S, chunk,
+                               cfg.jdtype.itemsize) == 8
         np.testing.assert_allclose(
-            reg.gauge("monitor.train.retention_state_mb").value,
+            cfg.kv_heads * pr.STATE_COLUMNS * cfg.head_dim * 4 / 1e6,
             2 * 8320 * 128 * 4 / 1e6)
         # seeded gates: sigmoid of a unit-scale projection, mean one half
         assert 0.4 < reg.gauge(

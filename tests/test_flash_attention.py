@@ -515,9 +515,9 @@ def test_one_block_ungrouped_is_one_kernel_whatever_vmem_holds(monkeypatch):
     """Every BERT shape: ``_bwd_fused``, which never asks the rule's bytes."""
     monkeypatch.setattr(fa, "SWEEP_VMEM", 0)
     assert fa.bwd_sweeps(512, 512, 128, 2) == 1
-    assert fa.packed_bwd_sweeps(512, 12, 64, 512) == 1
+    assert fa.bwd_sweeps(512, 512, 64 * fa._heads_per_block(64), 2) == 1
     assert fa.bwd_sweeps(512, 512, 128, 2, group=7) == 2
-    assert fa.packed_bwd_sweeps(4096, 16, 128, 512) == 2
+    assert fa.bwd_sweeps(4096, 512, 128, 2) == 2
 
 
 @pytest.mark.parametrize("S,bq,bk,window,blocks", [
@@ -626,8 +626,7 @@ def test_heads_ride_stacked_exactly_where_a_lane_block_reads_one_kv_head(
     against the whole key/value lane block where ``_Geom.halves`` > 1 (two
     heads a lane block AND grouped queries), a head's own [bq, D] everywhere
     else, and ``_stack_heads`` met for q (forward) and for q and do
-    (backward) or not at all; what the gauge's function says from the
-    shapes.  One block and grouped: the forward is the one-block kernel,
+    (backward) or not at all.  One block and grouped: the forward is the one-block kernel,
     which stacks nothing, the backward the sweep."""
     tiles, stacks = set(), []
     scores, stack = fa._scores, fa._stack_heads
@@ -641,7 +640,7 @@ def test_heads_ride_stacked_exactly_where_a_lane_block_reads_one_kv_head(
         *a, H, causal=True, block_q=block, block_k=block, n_kv_heads=Hkv) * w),
         argnums=(0, 1, 2)))(q, k, v)
     g = fa._Geom(q, k, H, block, block, Hkv=Hkv)
-    assert fa.packed_heads_stacked(H, D, Hkv) == g.halves == stacked, what
+    assert g.halves == stacked, what
     lanes = max(D, 128)
     if stacked == 1:
         assert not stacks and tiles == {((block, D), (block, D), None)}, what

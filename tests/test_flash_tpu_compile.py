@@ -201,7 +201,7 @@ def test_grouped_and_windowed_kernels_compile_for_a_v5e(
     text, grids, mosaic = _compiled(attn, xq, xk, xk, xq)
     for name in names:
         assert name in text, (what, name)
-    stacked = fa.packed_heads_stacked(H, D, Hkv)
+    stacked = fa._heads_per_block(D) if Hkv != H else 1
     assert stacked == (2 if shape is LFM2 else 1)
     assert stacked > 1 or mosaic == SWEEP_MOSAIC[what], what
     kv_blocks, group = Hkv * D // 128, H // Hkv
@@ -223,7 +223,7 @@ def test_a_sequence_past_the_rule_compiles_as_two_sweeps(one_chip):
     ``flash_bwd_dkv`` in Mosaic's own scope, as every several-block shape
     ran before the one sweep."""
     B, S, H, Hkv, D = 1, 65536, 4, 2, 128
-    assert fa.packed_bwd_sweeps(S, H, D, 512, n_kv_heads=Hkv) == 2
+    assert fa.bwd_sweeps(S, 512, D, 2, H // Hkv) == 2
     xq = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
     xk = jax.ShapeDtypeStruct((B, S, Hkv * D), jnp.bfloat16, sharding=one_chip)
     attn = lambda q, k, v: fa.flash_attention_packed(

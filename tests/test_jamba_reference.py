@@ -35,6 +35,7 @@ if ROOT not in sys.path:
 from benchmark.reference import jamba2_3b as reference  # noqa: E402
 from paddle_tpu import monitor  # noqa: E402
 from paddle_tpu.kernels import selective_scan as ss  # noqa: E402
+from paddle_tpu.kernels.flash_attention import packed_grid  # noqa: E402
 from paddle_tpu.models import jamba  # noqa: E402
 from paddle_tpu.monitor import devscope  # noqa: E402
 from paddle_tpu.parallel import decoder, optim, transformer as T  # noqa: E402
@@ -430,8 +431,14 @@ def test_the_gauges_of_a_call(ran):
                        fused=1, door="tiles").value > 0
     assert reg.counter("monitor.kernels.selective_scan_calls",
                        fused=0, door="copied").value == 0
-    # the one attention layer's grid: 5 heads on one key/value head
-    assert reg.gauge("monitor.kernels.flash_grid_steps").value > 0
+    # the one attention layer's grid: 5 heads on one key/value head, a
+    # grid step each (the function the kernels take their grid from)
+    cfg = jamba.jamba_tiny_config()
+    assert packed_grid(
+        B, S, cfg.n_heads, cfg.head_dim,
+        *T._packed_flash_blocks(cfg, cfg.n_heads, S, cfg.kv_heads),
+        itemsize=cfg.jdtype.itemsize, n_kv_heads=cfg.kv_heads,
+        causal=True) == (1, 10)
 
 
 def test_the_mixer_s_instructions_are_under_their_scopes(ran):

@@ -396,12 +396,13 @@ def test_counters_and_gauges_only_under_a_monitor_session(tmp_path):
     mon = monitor.enable(str(tmp_path), flight=False)
     try:
         reg = mon.registry
-        slots = reg.counter("monitor.train.moe_assignments")
         held = reg.counter("monitor.train.moe_rows_held")
-        start, held_start = slots.value, held.value
+        held_start = held.value
         tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS, batches), 1e-3)
-        pairs = 2 * B * S * 2 * 4       # batches x tokens x top-2 x MoE layers
-        assert slots.value - start == pairs
+        # batches x tokens x top-2 x MoE layers
+        pairs = 2 * batches[0]["ids"].size * tr.cfg.experts_per_token \
+            * tr.cfg.moe_layers
+        assert pairs == 2 * B * S * 2 * 4
         got = held.value - held_start
         assert 0 < got < pairs
         np.testing.assert_allclose(

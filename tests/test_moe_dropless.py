@@ -711,31 +711,20 @@ def test_a_share_under_remat_keeps_nothing_of_the_expert_ffn(small_granule):
 @pytest.mark.parametrize("model,held,n,k", [
     ("smallthinker", 2, 8, 2), ("lfm2", 2, 8, 2), ("mistral4", 2, 8, 2),
     ("olmoe", 8, 8, 2)])
-def test_the_row_kernel_s_gauges_follow_the_share_held(tmp_path, model, held,
-                                                       n, k):
-    """``monitor.kernels.moe_pair_slots`` and ``moe_rows_fetch_bound`` of
-    each sparse decoder's tiny configuration: set under a monitor session,
-    from the function the layer takes its capacities from (with every
-    expert held the rows are the slots), and nothing off the monitor."""
+def test_the_row_kernel_s_rows_follow_the_share_held(model, held, n, k):
+    """The (token, expert) pair slots a layer's sum back covers, T * k, and
+    the rows its row kernel can be asked for at the layer's first capacity,
+    of each sparse decoder's tiny configuration: from the function the
+    layer takes its capacities from (with every expert held the rows are
+    the slots)."""
     import importlib
-
-    from paddle_tpu import monitor
-    from paddle_tpu.parallel import decoder
 
     module = importlib.import_module("paddle_tpu.models." + model)
     cfg = getattr(module, model + "_tiny_config")()
     assert (cfg.experts_here, cfg.n_experts, cfg.experts_per_token) == (
         held, n, k)
-    decoder.gauge_moe_rows(cfg, 4096)            # off: nothing is touched
-    mon = monitor.enable(str(tmp_path), flight=False)
-    try:
-        gauges = [mon.registry.gauge("monitor.kernels." + name)
-                  for name in ("moe_pair_slots", "moe_rows_fetch_bound")]
-        for gauge in gauges:
-            gauge.set(-1)
-        decoder.gauge_moe_rows(cfg, 4096)
-        got = [gauge.value for gauge in gauges]
-    finally:
-        monitor.disable()
+    slots = 4096 * cfg.experts_per_token
     # 1.25 x the quarter of 8,192 slots that balance brings, in 512-row tiles
-    assert got == [8192, 2560 if held < n else 8192]
+    assert [slots, moe._held_capacities(
+        slots, cfg.experts_here, cfg.n_experts)[0]] == [
+            8192, 2560 if held < n else 8192]

@@ -35,6 +35,7 @@ if ROOT not in sys.path:
 from benchmark.reference import nemotron3_nano_30b_a3b as reference  # noqa: E402
 from paddle_tpu import monitor  # noqa: E402
 from paddle_tpu.kernels import moe_rows, ssd_scan as ssd  # noqa: E402
+from paddle_tpu.kernels.flash_attention import packed_grid  # noqa: E402
 from paddle_tpu.models import nemotron_h  # noqa: E402
 from paddle_tpu.monitor import devscope  # noqa: E402
 from paddle_tpu.parallel import decoder, moe, optim, transformer as T  # noqa: E402
@@ -357,7 +358,13 @@ def test_the_trainer_steps_under_remat_and_the_gauges_of_a_call():
     assert reg.counter("monitor.kernels.gated_norm_calls", fused=1).value > 0
     assert reg.gauge("monitor.train.moe_load_max_over_mean").value >= 1.0
     assert 0.0 < reg.gauge("monitor.train.moe_held_rows_share").value < 1.0
-    assert reg.gauge("monitor.kernels.flash_grid_steps").value > 0
+    # the attention layer's grid: a step a (sequence, head)
+    cfg = tr.cfg
+    assert packed_grid(
+        B, S, cfg.n_heads, cfg.head_dim,
+        *T._packed_flash_blocks(cfg, cfg.n_heads, S, cfg.kv_heads),
+        itemsize=cfg.jdtype.itemsize, n_kv_heads=cfg.kv_heads,
+        causal=True) == (1, 8)
     scopes = {devscope.classify(op)[1] for op in names.values()}
     assert {"mamba2", "ssd_scan", "attention", "moe", "router",
             "shared_expert"} <= scopes

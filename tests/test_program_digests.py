@@ -1,0 +1,124 @@
+"""The proof that a refactor left a program alone: the StableHLO digests of
+every tiny trainer's two programs (``<label>.step``, ``<label>.run_steps``),
+one case a trainer.  A PR that means to leave the step programs as they are
+runs this file before anything else; a PR that means to change one re-takes
+that one's digests and says so in the comment below."""
+
+import hashlib
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from paddle_tpu.models import (bert, brumby, jamba, lfm2,  # noqa: E402
+                               mistral4, nemotron_h, olmoe, ouro, resnet,
+                               smallthinker, trinity)
+from paddle_tpu.parallel import decoder  # noqa: E402
+from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
+from paddle_tpu.parallel.train import stack_batches  # noqa: E402
+
+
+# the first 16 hex digits of the sha256 of ``lower(...).as_text()`` of each
+# older tiny transformer's two programs (remat on, seed 3, batch 2, two
+# staged batches), taken on the parent commit (03fc114): what this PR's
+# options, off, leave as it was, byte for byte.  A PR that changes one of
+# these programs on purpose takes the digests anew: PR 40 did for the three
+# sparse decoders, whose expert layers sum back through the row kernel
+# (``kernels/moe_rows.py``), and PR 41 for LFM2 alone, whose grouped heads of
+# 64 ride the flash sweeps stacked (``kernels/flash_attention.py``: OLMoE's
+# and SmallThinker's held through it), and PR 46 for SmallThinker and LFM2,
+# whose held share's 256 rows in two groups now go in 128-row tiles
+# (``moe._tiling``; OLMoE's tiny layer is one tile either way and held
+# through it), and PR 47 for SmallThinker, LFM2 and Brumby, whose tiny q and
+# k are whole lane blocks (heads of 128, or two of 64) and so go through the
+# row kernel (``kernels/qk_rope.py``, their three projections through
+# ``_project``; OLMoE's 4 heads of 16 are half a lane block and keep the
+# plain matmuls and the ``rms_norm`` / ``rope`` lines, in their old order);
+# BERT's are still 03fc114's.  Mistral's and Trinity's joined the table in
+# PR 48, taken on its parent (3ea462c); Jamba's and Nemotron-H's in PR 54, on
+# its parent (37c698c): the passes of a looped stack (``loop_passes``) and
+# the one rule for the dense gated FFN (``cfg.dense_stack``) left all nine.
+# PR 55 took the six anew whose tiny backward is a several-block flash
+# backward at heads of whole lane blocks (SmallThinker, LFM2, Mistral,
+# Trinity, Jamba, Nemotron-H): its ``delta`` is the row kernel's
+# (``kernels/flash_delta.py``) where it was three ``jnp`` lines; BERT's (one
+# kv block: ``delta`` inside the kernel), OLMoE's (heads of 16: no flash
+# call) and Brumby's (retention) stand.  Ouro's and ResNet's joined in PR 56,
+# when the table got this file of its own, taken on that PR's parent
+# (ca40cf3) before any other edit: Ouro's at three passes over two layers,
+# ResNet's at depth 18 on 32 x 32 images (it has no remat to turn on), so
+# that all eleven trainers are held.  PR 56 re-took none of the eighteen.
+PROGRAMS = {"bert.step": "b07028186fd9c7b9",
+            "bert.run_steps": "00de5403506fdc87",
+            "olmoe.step": "231114fcd62341f2",
+            "olmoe.run_steps": "054338e92270f130",
+            "smallthinker.step": "7c77df5736c908d4",
+            "smallthinker.run_steps": "cdbc3b5d4be19b90",
+            "lfm2.step": "7d3dd46e83117d83",
+            "lfm2.run_steps": "55c703e13aaa5114",
+            "brumby.step": "84e6b6d548803a44",
+            "brumby.run_steps": "5a063ea89a19f1a4",
+            "mistral4.step": "835f4ec090f0f6c0",
+            "mistral4.run_steps": "c377a701ae4693ca",
+            "trinity.step": "86340ecdb523bc2f",
+            "trinity.run_steps": "c7581de1b602b474",
+            "jamba.step": "21dc4e9f64565ee9",
+            "jamba.run_steps": "3f4c6780114491e5",
+            "nemotron_h.step": "6bc4605ce6a59ef4",
+            "nemotron_h.run_steps": "3b52cbcf423cbc76",
+            "ouro.step": "770de97dd5bbc8af",
+            "ouro.run_steps": "64aaa9a06b2f9fbd",
+            "resnet.step": "350db1fba0d68284",
+            "resnet.run_steps": "dc9dd853700f9ab9"}
+OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
+         "olmoe": (olmoe.build_olmoe_trainer, olmoe.olmoe_tiny_config, 32),
+         "smallthinker": (smallthinker.build_smallthinker_trainer,
+                          smallthinker.smallthinker_tiny_config, 64),
+         "lfm2": (lfm2.build_lfm2_trainer, lfm2.lfm2_tiny_config, 64),
+         "brumby": (brumby.build_brumby_trainer, brumby.brumby_tiny_config,
+                    64),
+         "mistral4": (mistral4.build_mistral4_trainer,
+                      mistral4.mistral4_tiny_config, 64),
+         "trinity": (trinity.build_trinity_trainer,
+                     trinity.trinity_tiny_config, 64),
+         "jamba": (jamba.build_jamba_trainer, jamba.jamba_tiny_config, 64),
+         "nemotron_h": (nemotron_h.build_nemotron_h_trainer,
+                        nemotron_h.nemotron_h_tiny_config, 64),
+         "ouro": (ouro.build_ouro_trainer, ouro.ouro_tiny_config, 64),
+         "resnet": (resnet.build_resnet_trainer,
+                    lambda remat: resnet.resnet_tiny_config(), 32)}
+
+
+@pytest.mark.parametrize("name", list(OLDER))
+def test_the_older_transformers_programs_lower_to_the_parent_s_text(name):
+    build, config, seq = OLDER[name]
+    tr = build(config(remat=True), MeshSpec(dp=1), seed=3,
+               devices=jax.devices()[:1])
+    ids = np.zeros((2, seq), np.int32)
+    batch, specs = {"ids": ids}, decoder.BATCH_SPECS
+    if name == "bert":
+        batch = {"ids": ids, "labels": ids,
+                 "mask": np.ones((2, seq), np.float32)}
+        specs = bert.batch_specs(tuple(batch))
+    if name == "resnet":
+        batch = {"image": np.zeros((2, seq, seq, 3), np.float32),
+                 "label": np.zeros((2,), np.int32)}
+        specs = resnet.BATCH_SPECS
+    one = {k: jnp.asarray(v) for k, v in batch.items()}
+    many = stack_batches(tr.mesh, specs, [batch, batch])
+    for label, fn, args in (("step", tr.step_fn, (tr.state, one, 1e-3)),
+                            ("run_steps", tr.multi_fn,
+                             (tr.state, many, 1e-3))):
+        program = "%s.%s" % (name, label)
+        got = hashlib.sha256(fn.lower(*args).as_text().encode()).hexdigest()
+        assert got[:16] == PROGRAMS[program], (
+            "the program %s lowers to another text than the table holds: "
+            "re-take the digest only if the PR means to change this program"
+            % program)

@@ -26,6 +26,7 @@ if ROOT not in sys.path:
 
 from benchmark.reference import smallthinker_21b_a3b as reference  # noqa: E402
 from paddle_tpu import monitor  # noqa: E402
+from paddle_tpu.kernels.flash_attention import kv_blocks  # noqa: E402
 from paddle_tpu.models import bert, olmoe, smallthinker  # noqa: E402
 from paddle_tpu.monitor import devscope  # noqa: E402
 from paddle_tpu.parallel import (decoder, moe, optim,  # noqa: E402
@@ -331,17 +332,19 @@ def test_counters_and_gauges_only_under_a_monitor_session(tmp_path):
     tr = _trainer()
     assert monitor.active() is None
     tr._observe({"ids": _Unreadable()})         # off: nothing runs
-    assert tr._routing_fn is None
+    assert tr._probe_fn is None
     batches = [{"ids": i} for i in _ids(seed=8, n=2)]
     mon = monitor.enable(str(tmp_path), flight=False)
     try:
         reg = mon.registry
-        slots = reg.counter("monitor.train.moe_assignments")
         held = reg.counter("monitor.train.moe_rows_held")
-        start, held_start = slots.value, held.value
+        held_start = held.value
         tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS, batches), 1e-3)
-        pairs = 2 * B * S * 2 * 4               # batches x tokens x top-2 x L
-        assert slots.value - start == pairs
+        cfg = tr.cfg
+        # batches x tokens x top-2 x L
+        pairs = 2 * batches[0]["ids"].size * cfg.experts_per_token \
+            * cfg.moe_layers
+        assert pairs == 2 * B * S * 2 * 4
         got = held.value - held_start
         assert 0 < got < pairs
         share = reg.gauge("monitor.train.moe_held_rows_share").value
@@ -350,16 +353,17 @@ def test_counters_and_gauges_only_under_a_monitor_session(tmp_path):
         # what the sum back's row kernel is sized by: a layer's pair slots,
         # and the rows of its first capacity (at this size one 512-row tile
         # would pass the slots, so they are the only capacity)
-        assert reg.gauge("monitor.kernels.moe_pair_slots").value == B * S * 2
-        assert reg.gauge("monitor.kernels.moe_rows_fetch_bound").value == \
-            moe._held_capacities(B * S * 2, 2, 8)[0] == B * S * 2
+        slots = B * S * cfg.experts_per_token
+        assert moe._held_capacities(slots, cfg.experts_here,
+                                    cfg.n_experts)[0] == slots == B * S * 2
         # the flash kernels' grids by layer kind: S = 64 in 16-blocks, a
-        # window of 24 visits 2 or 3 kv blocks a q block; the grid is the
-        # table of visited blocks, so it skips none
-        for gauge, value in [("visited_full", 10), ("skipped_full", 0),
-                             ("visited_windowed", 9), ("skipped_windowed", 0)]:
-            assert reg.gauge(
-                "monitor.kernels.flash_kv_blocks_" + gauge).value == value
+        # window of 24 visits 2 or 3 kv blocks a q block (the grid is the
+        # table of visited blocks: it holds no other step)
+        blocks = T._packed_flash_blocks(cfg, cfg.n_heads, S, cfg.kv_heads)
+        window = max(k[0] or 0 for k in cfg.layer_kinds) or None
+        assert (blocks, window) == ((16, 16), 24)
+        assert kv_blocks(S, *blocks, True, None) == 10
+        assert kv_blocks(S, *blocks, True, window) == 9
     finally:
         monitor.disable()
 

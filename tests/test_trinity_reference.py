@@ -27,6 +27,7 @@ if ROOT not in sys.path:
 
 from benchmark.reference import trinity_large_preview as reference  # noqa: E402
 from paddle_tpu import monitor  # noqa: E402
+from paddle_tpu.kernels.flash_attention import packed_grid  # noqa: E402
 from paddle_tpu.models import trinity  # noqa: E402
 from paddle_tpu.monitor import devscope  # noqa: E402
 from paddle_tpu.parallel import (decoder, moe, optim,  # noqa: E402
@@ -566,18 +567,16 @@ def trained(tmp_path_factory):
                          flight=False)
     try:
         reg = mon.registry
-        counters = {n: reg.counter("monitor.train." + n)
-                    for n in ("moe_assignments", "moe_rows_held")}
-        start = {n: c.value for n, c in counters.items()}
+        held = reg.counter("monitor.train.moe_rows_held")
+        start = held.value
         out["scanned"] = np.asarray(scan.run_steps(
             stack_batches(scan.mesh, decoder.BATCH_SPECS, batches), 1e-3))
-        out["counted"] = {n: c.value - start[n] for n, c in counters.items()}
+        out["rows_held"] = held.value - start
         out["gauges"] = {n: reg.gauge(n).value for n in (
             "monitor.train.moe_held_rows_share",
             "monitor.train.moe_load_max_over_mean",
             "monitor.train.attn_gate_mean",
-            "monitor.train.router_bias_abs_max",
-            "monitor.kernels.flash_grid_steps")}
+            "monitor.train.router_bias_abs_max")}
     finally:
         monitor.disable()
     out["params"] = [jax.tree.map(np.asarray, t.state["params"])
@@ -607,17 +606,25 @@ def test_run_steps_over_two_batches_equals_two_steps(trained):
 
 
 def test_counters_and_gauges_only_under_a_monitor_session(trained):
-    pairs = 2 * B * S * 2 * 4       # batches x tokens x top-2 x MoE layers
-    counted, gauges = trained["counted"], trained["gauges"]
-    assert counted["moe_assignments"] == pairs
-    assert 0 < counted["moe_rows_held"] < pairs
+    cfg = trinity.trinity_tiny_config()
+    # batches x tokens x top-2 x MoE layers
+    pairs = 2 * B * S * cfg.experts_per_token * cfg.moe_layers
+    assert pairs == 2 * B * S * 2 * 4
+    rows_held, gauges = trained["rows_held"], trained["gauges"]
+    assert 0 < rows_held < pairs
     share = gauges["monitor.train.moe_held_rows_share"]
-    assert share == counted["moe_rows_held"] / pairs and 0.1 < share < 0.5
+    assert share == rows_held / pairs and 0.1 < share < 0.5
     assert gauges["monitor.train.moe_load_max_over_mean"] >= 1.0
     # seeded gates stand near one half: neither stuck shut nor open
     assert 0.4 < gauges["monitor.train.attn_gate_mean"] < 0.6
     assert gauges["monitor.train.router_bias_abs_max"] > 0
-    assert gauges["monitor.kernels.flash_grid_steps"] > 0
+    # a layer's grid: the causal triangle's 10 of 4 x 4 blocks a (sequence,
+    # head), from the function the kernels take their grid from
+    assert packed_grid(
+        B, S, cfg.n_heads, cfg.head_dim,
+        *T._packed_flash_blocks(cfg, cfg.n_heads, S, cfg.kv_heads),
+        itemsize=cfg.jdtype.itemsize, n_kv_heads=cfg.kv_heads,
+        causal=True) == (1, 120)
 
 
 def test_the_new_scopes_hold_their_instructions(trained):
