@@ -13,6 +13,15 @@ reference), computed in float32 and rounded ONCE:
                                                 ``pos * theta^(-2i / dh)``
                                                 (or y = n: no positions)
 
+With ``pairs`` (static) the rotation is the ADJACENT-pair convention's
+(``transformer.rope_pairs``, the latent form's): the partner of lane 2j is
+lane 2j + 1 and back, and everything else a head does to its lanes is in the
+tables (``pair_tables``): the sign by lane, cosine 1 and sine 0 in a head's
+lanes that carry no position, and any scale by position multiplied in.  With
+``shared`` [b, S, 128], ONE lane block a row that every head takes, the
+rotated ``n + shared``: a key ``[k_nope_i | 0] + [0 | kr]`` is assembled and
+rotated in the pass that reads it.
+
 Why a kernel (PERF.md section 6, PR 47): the compiled text of one rotary
 layer at Trinity's shape moves 9.0 GB outside its matmuls and flash kernels
 where this work needs 0.45: XLA broadcasts ``cos`` and ``sin`` to float32
@@ -25,13 +34,15 @@ nothing float32 reaches HBM but the angles' tables:
   a head's sum of squares is a lane reduce (masked to the head's lanes where
   a block holds several), ``rotate_half`` one lane rotation by ``dh / 2``
   (a select between two where a block holds several heads) with the sign in
-  the sine's table;
+  the sine's table; a pair's partner two lane rotations by one and a select
+  by lane parity;
 - the angles are two float32 tables ``[S, 128]`` (``angle_tables``: cosine,
   and sine with ``rotate_half``'s sign), made by XLA from ``first``, which
   may be traced, by ``rope``'s own formula: 3 to 8 MB a layer, read once a
   block of rows whatever the batch (the batch is the grid's inner axis);
 - the backward reads ``dy`` and the saved RAW projection (saved only where
-  a norm reads it: the rotation alone is linear), recomputes the statistics
+  a norm reads it: the rotation alone is linear, and ``shared``'s gradient
+  is the lane blocks' sum of dx), recomputes the statistics
   (no lane-narrow block of them is saved), and writes ``dx`` over ``dy``;
   the weight's gradient leaves as per-block partial sums ``[blocks * 8,
   lanes]`` float32 (eight sublanes a block: no cross-sublane reduce in the
@@ -52,8 +63,8 @@ from ._common import (LANES, SUBLANES, CompilerParams as _CompilerParams,
                       on_tpu as _on_tpu, sublane_sums as _sublane_sums,
                       sublane_tile as _tile)
 
-__all__ = ["qk_rope", "angle_tables", "supported", "block_rows",
-           "vmem_bytes"]
+__all__ = ["qk_rope", "angle_tables", "pair_tables", "supported",
+           "block_rows", "vmem_bytes"]
 
 ROW_BLOCKS = (256, 128, 64, 32, 16, 8)
 # what the backward's six pipelined blocks (x, dy, dx; two copies each) may
@@ -73,15 +84,16 @@ def block_rows(S, W, itemsize):
     return None
 
 
-def vmem_bytes(bs, W, itemsize):
+def vmem_bytes(bs, W, itemsize, shared=False):
     """What a call asks Mosaic for: the backward's six pipelined blocks of
     the projection, the angles', the weight's and the partial sums' blocks
     twice each, thirty-two float32 temporaries of a lane block's rows
     (Mosaic's stack does not reuse every one; the compiled kernels take 1 to
     7 MiB of the 8 to 17 asked, ``tests/test_flash_tpu_compile.py``), and
-    room."""
+    room; with ``shared`` its lane block and its gradient's, twice each."""
     return (6 * bs * W * itemsize + (4 + 32) * bs * LANES * 4
-            + 2 * (SUBLANES + 1) * W * 4 + 2 * 2 ** 20)
+            + 2 * (SUBLANES + 1) * W * 4 + 2 * 2 ** 20
+            + (4 * bs * LANES * itemsize if shared else 0))
 
 
 def supported(shape, head_dim, itemsize):
@@ -110,13 +122,41 @@ def angle_tables(S, head_dim, theta, first=0):
                      (1, heads)))
 
 
+def pair_tables(S, freqs, head_dim, first=0, factor=1.0, scale=None):
+    """(cos, signed sin) [S, 128] float32 of the adjacent-pair convention at
+    positions ``first``.. (``first`` may be traced): a head's LAST ``2 *
+    len(freqs)`` lanes are the pairs (2j, 2j + 1), turned by ``pos *
+    freqs[j]``, cosine and sine times ``factor``, the sine minus on a
+    pair's first lane (``transformer.rope_pairs``' sign); its lanes before
+    them carry no position, cosine 1 and sine 0.  ``scale`` [S] float32
+    multiplies a position's row of both tables."""
+    pos = jnp.arange(S, dtype=jnp.float32) + first
+    ang = jnp.repeat(pos[:, None] * jnp.asarray(freqs, jnp.float32)[None], 2,
+                     axis=1)
+    plain = head_dim - ang.shape[1]
+    sign = jnp.where(jnp.arange(ang.shape[1]) % 2 == 0, -1.0, 1.0)
+    cos = jnp.concatenate(
+        [jnp.ones((S, plain), jnp.float32), factor * jnp.cos(ang)], axis=1)
+    sin = jnp.concatenate(
+        [jnp.zeros((S, plain), jnp.float32), factor * sign * jnp.sin(ang)],
+        axis=1)
+    if scale is not None:
+        cos, sin = cos * scale[:, None], sin * scale[:, None]
+    heads = LANES // head_dim
+    return jnp.tile(cos, (1, heads)), jnp.tile(sin, (1, heads))
+
+
 def _lane(shape):
     return jax.lax.broadcasted_iota(jnp.int32, shape, 1)
 
 
-def _partner(y, dh):
+def _partner(y, dh, pairs=False):
     """``y[..., j ^ (dh / 2)]`` along the 128 lanes: the other half of lane
-    j's head, ``rotate_half`` without its sign (its own transpose)."""
+    j's head, ``rotate_half`` without its sign (its own transpose); with
+    ``pairs`` ``y[..., j ^ 1]``, the other lane of lane j's pair."""
+    if pairs:
+        return jnp.where((_lane(y.shape) & 1) != 0, pltpu.roll(y, 1, 1),
+                         pltpu.roll(y, LANES - 1, 1))
     half = dh // 2
     if dh == LANES:
         return pltpu.roll(y, half, 1)
@@ -138,8 +178,8 @@ def _head_sum(v, dh):
     return out
 
 
-def _rotate(y, cos, sin, dh):
-    return y * cos + _partner(y, dh) * sin
+def _rotate(y, cos, sin, dh, pairs):
+    return y * cos + _partner(y, dh, pairs) * sin
 
 
 def _over_blocks(ref, body, carry=None):
@@ -164,8 +204,10 @@ def _row_rstd(x_ref, eps):
         jnp.sum(acc, axis=1, keepdims=True) / x_ref.shape[-1] + eps)
 
 
-def _fwd_kernel(*refs, dh, norm, rotary, eps):
+def _fwd_kernel(*refs, dh, norm, rotary, eps, pairs, shared):
     x_ref, refs = refs[0], refs[1:]
+    if shared:
+        s_ref, refs = refs[0], refs[1:]
     if norm:
         w_ref, refs = refs[0], refs[1:]
     if rotary:
@@ -180,14 +222,16 @@ def _fwd_kernel(*refs, dh, norm, rotary, eps):
                 * w_ref[...]
         elif norm:
             y = y * rstd * w_ref[:, sl]
+        if shared:
+            y = y + s_ref[...].astype(jnp.float32)
         if rotary:
-            y = _rotate(y, cos_ref[...], sin_ref[...], dh)
+            y = _rotate(y, cos_ref[...], sin_ref[...], dh, pairs)
         o_ref[:, sl] = y.astype(o_ref.dtype)
 
     _over_blocks(x_ref, block)
 
 
-def _bwd_kernel(*refs, dh, norm, rotary, eps):
+def _bwd_kernel(*refs, dh, norm, rotary, eps, pairs, shared):
     if norm:
         x_ref, refs = refs[0], refs[1:]
     g_ref, refs = refs[0], refs[1:]
@@ -195,19 +239,27 @@ def _bwd_kernel(*refs, dh, norm, rotary, eps):
         w_ref, refs = refs[0], refs[1:]
     if rotary:
         cos_ref, sin_ref = refs[0], refs[1]
-    dx_ref = refs[-2] if norm else refs[-1]
+    # the results: dx, then the weight's gradient or ``shared``'s (a norm
+    # and ``shared`` never meet)
+    dx_ref = refs[-2] if norm or shared else refs[-1]
     dw_ref = refs[-1] if norm else None
+    ds_ref = refs[-1] if shared else None
 
     def d_normed(sl):
         g = g_ref[:, sl].astype(jnp.float32)
         # the rotation's transpose: its partner map is its own inverse
-        return g * cos_ref[...] + _partner(g * sin_ref[...], dh) \
+        return g * cos_ref[...] + _partner(g * sin_ref[...], dh, pairs) \
             if rotary else g
 
     if not norm:        # the rotation alone is linear: no x
-        def block(sl, _):
-            dx_ref[:, sl] = d_normed(sl).astype(dx_ref.dtype)
-        _over_blocks(g_ref, block)
+        def block(sl, ds):
+            d = d_normed(sl)
+            dx_ref[:, sl] = d.astype(dx_ref.dtype)
+            return ds + d if shared else ds
+        ds = _over_blocks(g_ref, block, jnp.zeros(
+            (g_ref.shape[0], LANES), jnp.float32) if shared else None)
+        if shared:      # every lane block took it: the blocks' sum
+            ds_ref[...] = ds.astype(ds_ref.dtype)
         return
     rows, W = x_ref.shape
     rstd = proj = None
@@ -244,17 +296,23 @@ def _bwd_kernel(*refs, dh, norm, rotary, eps):
 
 
 def _call(kernel, name, rows, weight, tables, dh, norm, eps, interpret,
-          backward=False):
+          pairs, shared, backward=False):
     """One pallas_call over grid (S / bs, b), the batch innermost so that a
     block of the angles' tables is fetched once for all b.  ``rows``: the
     operands of the projection's shape (x; in the backward x, where there
     is a norm, then dy, over which dx is written: nothing reads dy after
-    it)."""
+    it).  ``shared``: the forward's [b, S, 128] operand or None; in the
+    backward anything but None makes its gradient a result."""
     b, S, W = rows[0].shape
     dtype = rows[0].dtype
     bs = block_rows(S, W, dtype.itemsize)
     block = pl.BlockSpec((None, bs, W), lambda si, bi: (bi, si, 0))
+    lane_block = pl.BlockSpec((None, bs, LANES), lambda si, bi: (bi, si, 0))
     operands, specs = list(rows), [block] * len(rows)
+    has_shared = shared is not None
+    if has_shared and not backward:
+        operands.append(shared)
+        specs.append(lane_block)
     wl = LANES if norm == "head" else W
     if norm:
         operands.append(weight.reshape(1, wl))
@@ -269,58 +327,74 @@ def _call(kernel, name, rows, weight, tables, dh, norm, eps, interpret,
             (S // bs * b * SUBLANES, wl), jnp.float32))
         out_specs.append(pl.BlockSpec(
             (SUBLANES, wl), lambda si, bi: (si * b + bi, 0)))
+    if backward and has_shared:
+        out_shape.append(jax.ShapeDtypeStruct((b, S, LANES), dtype))
+        out_specs.append(lane_block)
     return pl.pallas_call(
         functools.partial(kernel, dh=dh, norm=norm,
-                          rotary=tables is not None, eps=eps),
+                          rotary=tables is not None, eps=eps, pairs=pairs,
+                          shared=has_shared),
         grid=(S // bs, b), in_specs=specs, out_specs=out_specs,
         out_shape=out_shape,
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "parallel"),
-            vmem_limit_bytes=vmem_bytes(bs, W, dtype.itemsize)),
+            vmem_limit_bytes=vmem_bytes(bs, W, dtype.itemsize, has_shared)),
         input_output_aliases={len(rows) - 1: 0} if backward else {},
         interpret=interpret, name=name,
     )(*operands)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _qk_rope(x, weight, tables, dh, norm, eps, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _qk_rope(x, weight, tables, shared, dh, norm, eps, interpret, pairs):
     return _call(_fwd_kernel, "qk_rope_fwd", [x], weight, tables, dh, norm,
-                 eps, interpret)[0]
+                 eps, interpret, pairs, shared)[0]
 
 
-def _qk_rope_fwd(x, weight, tables, dh, norm, eps, interpret):
-    # the RAW projection is the residual, and only where a norm reads it
-    return (_qk_rope(x, weight, tables, dh, norm, eps, interpret),
-            (x if norm else None, weight, tables))
+def _qk_rope_fwd(x, weight, tables, shared, dh, norm, eps, interpret, pairs):
+    # the RAW projection is the residual, and only where a norm reads it; of
+    # ``shared`` its (empty) place in the tree says whether it was there
+    return (_qk_rope(x, weight, tables, shared, dh, norm, eps, interpret,
+                     pairs),
+            (x if norm else None, weight, tables,
+             None if shared is None else ()))
 
 
-def _qk_rope_bwd(dh, norm, eps, interpret, res, dy):
-    x, weight, tables = res
+def _qk_rope_bwd(dh, norm, eps, interpret, pairs, res, dy):
+    x, weight, tables, shared = res
     out = _call(_bwd_kernel, "qk_rope_bwd", [x, dy] if norm else [dy],
-                weight, tables, dh, norm, eps, interpret, backward=True)
+                weight, tables, dh, norm, eps, interpret, pairs, shared,
+                backward=True)
     # the angles' tables hang on positions alone: no cotangent
     return (out[0], jnp.sum(out[1], axis=0) if norm else None,
-            jax.tree.map(jnp.zeros_like, tables))
+            jax.tree.map(jnp.zeros_like, tables),
+            None if shared is None else out[1])
 
 
 _qk_rope.defvjp(_qk_rope_fwd, _qk_rope_bwd)
 
 
 def qk_rope(x, weight=None, tables=None, *, head_dim, norm=None, eps=1e-5,
-            interpret=None):
+            pairs=False, shared=None, interpret=None):
     """``x`` [b, S, W] packed heads of ``head_dim``; ``norm`` "head" (RMS
     norm of each head, ``weight`` [head_dim]), "whole" (of the projection,
     ``weight`` [W]) or None; ``tables`` = ``angle_tables(S, head_dim, theta,
-    first)`` for rotary positions, or None.  ``supported(x.shape, head_dim,
-    itemsize)`` must hold.  Float32 inside, rounded once to ``x.dtype``."""
+    first)`` for rotary positions, or None; with ``pairs`` the rotation is of
+    adjacent pairs and ``tables`` = ``pair_tables(...)``; ``shared`` [b, S,
+    128] is added to every lane block before the rotation (no norm with it).
+    ``supported(x.shape, head_dim, itemsize)`` must hold.  Float32 inside,
+    rounded once to ``x.dtype``."""
     if not supported(x.shape, head_dim, x.dtype.itemsize):
         raise ValueError("qk_rope: shape %s at head_dim %d is not supported"
                          % (x.shape, head_dim))
+    if shared is not None and norm:
+        raise ValueError("qk_rope: a shared lane block goes with no norm")
     if interpret is None:
         interpret = not _on_tpu()
     if norm == "head":          # one weight for every head of a lane block
         weight = jnp.tile(weight.astype(jnp.float32), LANES // head_dim)
     elif norm:
         weight = weight.astype(jnp.float32)
-    return _qk_rope(x, weight, tables, head_dim, norm or None, float(eps),
-                    bool(interpret))
+    if shared is not None:
+        shared = shared.astype(x.dtype)
+    return _qk_rope(x, weight, tables, shared, head_dim, norm or None,
+                    float(eps), bool(interpret), bool(pairs))

@@ -1078,14 +1078,23 @@ def _qkv(pl, h_full, cfg, rotary, first=0):
     full sequence ``h_full`` [b, S, E] (or of its rows from position
     ``first`` on), with their biases, the configured q/k norm and, where
     ``rotary``, rotary positions: what attention and power retention both
-    start from.  The latent form: ``_latent_qkv``, a block of positions at a
-    time where the sequence is long (no whole-sequence float32 q stands)."""
+    start from.  The latent form: ``_latent_qkv``; where its ``rope_pairs``
+    lines run, a block of positions at a time where the sequence is long (no
+    whole-sequence float32 q stands)."""
     if cfg.latent:
-        # the rotation and the scale work in float32 (two bf16s a value) on
-        # q and k at once
+        # ``wkv_b``'s columns are taken apart ONCE a layer, not a block
+        columns = _latent_columns(pl["wkv_b"], cfg)
+        # the lines' rotation and scale work in float32 (two bf16s a value)
+        # on q and k at once; the row kernel's passes leave nothing float32
+        # and their backward holds the latents alone, so a long sequence
+        # goes whole: no third forward of the chain under the blocks' own
+        # checkpoint, no block cut out of a stacked array
+        width = cfg.q_lora_rank + cfg.kv_lora_rank + cfg.qk_rope_dim \
+            if _latent_fused(cfg, h_full.shape[:2], h_full.dtype.itemsize) \
+            else 2 * 3 * cfg.n_heads * cfg.head_dim
         return _by_row_blocks(
-            lambda rows, first: _latent_qkv(pl, rows, cfg, first), h_full,
-            2 * 3 * cfg.n_heads * cfg.head_dim)
+            lambda rows, first: _latent_qkv(pl, rows, cfg, first, columns),
+            h_full, width)
     b, S, E = h_full.shape
     hl, kvl = _local_heads(cfg)
     dh = cfg.head_dim
@@ -1141,7 +1150,7 @@ def _norm_and_rotate(xs, weights, heads, fused, cfg, rotary, first):
     norm = cfg.qk_norm and ("head" if cfg.qk_norm == "head" else "whole")
     for took in fused:
         count_call("qk_rope", dh=dh, norm=norm or "none", rotary=int(rotary),
-                   fused=int(took))
+                   convention="half", fused=int(took))
     tables = qk_rope.angle_tables(xs[0].shape[1], dh, cfg.rope_theta, first) \
         if rotary and any(fused) else None
 
@@ -1159,7 +1168,31 @@ def _norm_and_rotate(xs, weights, heads, fused, cfg, rotary, first):
             for x, n, took in zip(xs, heads, fused)]
 
 
-def _latent_qkv(pl, h, cfg, first=0):
+def _latent_columns(wkv_b, cfg):
+    """``wkv_b`` [rank, H * (dn + dv)], head i ``[k_nope_i | v_i]`` as
+    published, as the two matrices whose products ARE the packed arrays the
+    flash kernel reads: the keys' [rank, H * head_dim], head i ``[k_nope_i |
+    0]`` (the row kernel adds the rotary key into the zero lanes), and the
+    values' [rank, H * dv].  No activation is cut across lanes."""
+    H, dn, dr = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+    w = wkv_b.reshape(wkv_b.shape[0], H, dn + cfg.v_head_dim)
+    return (jnp.pad(w[..., :dn], ((0, 0), (0, 0), (0, dr))).reshape(
+        wkv_b.shape[0], -1), w[..., dn:].reshape(wkv_b.shape[0], -1))
+
+
+def _latent_fused(cfg, rows, itemsize):
+    """Whether the row kernel (``kernels/qk_rope.py``, ``pairs``) takes the
+    latent form's q and k of ``rows`` = (b, S): heads of ``dn + dr`` in whole
+    lane blocks, ``dr`` whole pairs, values a lane block wide."""
+    from ..kernels import qk_rope
+
+    width = cfg.qk_nope_dim + cfg.qk_rope_dim
+    return cfg.qk_rope_dim % 2 == 0 and cfg.v_head_dim % qk_rope.LANES == 0 \
+        and qk_rope.supported(tuple(rows) + (cfg.n_heads * width,), width,
+                              itemsize)
+
+
+def _latent_qkv(pl, h, cfg, first=0, columns=None):
     """The latent form's packed q, k and v, [b, S, H * head_dim] each, of
     the rows ``h`` [b, S, E] at positions ``first``..: ``q = rms(h @ wq_a)
     @ wq_b``, head i ``[q_nope_i | q_rope_i]``; ``[ckv | kr] = h @ wkv_a``,
@@ -1169,23 +1202,54 @@ def _latent_qkv(pl, h, cfg, first=0):
     ``rot(kr)`` in every head, and the whole query head is scaled by what
     the kernel's ``head_dim^(-1/2)`` lacks: ``yarn_softmax_scale`` and the
     position's ``1 + q_scale_beta * ln(1 + pos // rope_original_max)``.
-    Rotation and scale in float32."""
+    Rotation and scale in float32.
+
+    Where the row kernel takes the shape (``kernels/qk_rope.py``, its
+    ``pairs`` convention: heads of ``dn + dr`` in whole lane blocks), ONE
+    pass over q and one over k: the rotation, the factor and the scale are
+    in the kernel's tables, k and v come packed out of ``columns``
+    (``_latent_columns(wkv_b)``) and the kernel adds ``kr`` into every
+    key's last ``dr`` lanes as it rotates them.  Elsewhere the ``rope_pairs``
+    lines below, which the tests hold that kernel to.  Under a monitor
+    session q and k of a traced call count in ``monitor.kernels.
+    qk_rope_calls`` (``convention`` "pairs", ``fused`` 1 for the kernel)."""
+    from ..kernels import qk_rope
+    from ..kernels._common import count_call
+
     b, S, _ = h.shape
     H, dn, dr = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
     f32 = jnp.float32
+    fused = _latent_fused(cfg, (b, S), h.dtype.itemsize)
+    for _ in "qk":
+        count_call("qk_rope", dh=dn + dr, norm="none", rotary=1,
+                   convention="pairs", fused=int(fused))
     # the latents' norms stay under the caller's scope (``_rms``)
-    q = (_rms(h @ pl["wq_a"], pl["q_a_norm"], cfg.norm_eps)
-         @ pl["wq_b"]).reshape(b, S, H, dn + dr)
+    q = _rms(h @ pl["wq_a"], pl["q_a_norm"], cfg.norm_eps) @ pl["wq_b"]
     ckv, kr = jnp.split(h @ pl["wkv_a"], [cfg.kv_lora_rank], axis=-1)
-    kv = (_rms(ckv, pl["kv_a_norm"], cfg.norm_eps)
-          @ pl["wkv_b"]).reshape(b, S, H, dn + cfg.v_head_dim)
+    ckv = _rms(ckv, pl["kv_a_norm"], cfg.norm_eps)
     pos = jnp.arange(S, dtype=f32) + first
-    ang = pos[:, None] * jnp.asarray(yarn_frequencies(cfg), f32)[None]
+    freqs = yarn_frequencies(cfg)
     factor = yarn_rotary_factor(cfg)
     scale = jnp.full((S,), yarn_softmax_scale(cfg), f32)
     if cfg.q_scale_beta:
         scale = scale * (1.0 + cfg.q_scale_beta * jnp.log1p(
             jnp.floor(pos / cfg.rope_original_max)))
+    if fused:
+        k_columns, v_columns = columns or _latent_columns(pl["wkv_b"], cfg)
+        rotate = functools.partial(qk_rope.qk_rope, head_dim=dn + dr,
+                                   pairs=True)
+        q = rotate(q, None, qk_rope.pair_tables(S, freqs, dn + dr, first,
+                                                factor, scale))
+        # the ONE rotary key in a head's last lanes, every head of a block
+        kr = jnp.tile(jnp.pad(kr, ((0, 0), (0, 0), (dn, 0))),
+                      qk_rope.LANES // (dn + dr))
+        k = rotate(ckv @ k_columns, None,
+                   qk_rope.pair_tables(S, freqs, dn + dr, first, factor),
+                   shared=kr)
+        return q, k, ckv @ v_columns
+    q = q.reshape(b, S, H, dn + dr)
+    kv = (ckv @ pl["wkv_b"]).reshape(b, S, H, dn + cfg.v_head_dim)
+    ang = pos[:, None] * jnp.asarray(freqs, f32)[None]
     q = q.astype(f32)
     q = jnp.concatenate([q[..., :dn], rope_pairs(q[..., dn:], ang, factor)],
                         axis=-1) * scale[None, :, None, None]

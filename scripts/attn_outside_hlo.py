@@ -14,9 +14,10 @@ a described ``v5e:2x2``, the kernels' ``_on_tpu`` patched True in THIS
 process, shapes not arrays.  Bytes = operands + results of every
 instruction the entry computation runs (a ``while``'s body times its trip
 count; an async pair once; parameters, constants, tuples and bitcasts move
-nothing; an operand that a fusion cuts a slice from counts whole, so a
-loop over row blocks, Brumby's, reads high outside its kernels), in three
-groups:
+nothing; a ``dynamic-slice`` reads the block it cuts and an in-place
+``dynamic-update-slice`` writes the block it is given, alone or inside a
+fusion, so a loop over row blocks counts its blocks and not the whole array
+every trip), in three groups:
 
 - ``matmul``: fusions that hold a ``convolution`` / ``dot``;
 - ``kernel``: ``tpu_custom_call``s, by the kernels' names;
@@ -96,8 +97,8 @@ def computations(text):
         m = INSTRUCTION.match(line)
         if m and current is not None:
             names, attrs = _operands(m.group(4))
-            if m.group(3) == "constant":    # keep the value: "8), ..."
-                attrs = m.group(4)
+            if m.group(3) in ("constant", "parameter"):
+                attrs = m.group(4)          # keep the value: "8), ..."
             current.append((m.group(1), m.group(2), m.group(3), names, attrs))
     return comps, entry
 
@@ -112,6 +113,47 @@ def _cycles(attrs):
     none: a custom call, an async pair)."""
     m = re.search(r'"estimated_cycles":"(\d+)"', attrs)
     return int(m.group(1)) if m else 0
+
+
+def _sliced(body):
+    """What a fusion ``body`` moves where it cuts a block out of a larger
+    array or writes one into it (a row-block loop's ``dynamic-slice`` /
+    ``dynamic-update-slice``): {parameter index: bytes read of it} for the
+    parameters only slices read (the slices' bytes) or only an in-place
+    update writes into (none), and the bytes to count for the result in
+    place of the updated array's (None where no update is the result)."""
+    made = {n: (op, ops) for n, _, op, ops, _ in body}
+    size = {n: _bytes(t) for n, t, _, _, _ in body}
+
+    def source(n):      # through bitcasts, to what was cut or updated
+        while made.get(n, ("", ()))[0] == "bitcast":
+            n = made[n][1][0]
+        return n
+
+    index = {n: int(a.split(")")[0]) for n, _, op, _, a in body
+             if op == "parameter"}
+    reads = {n: [] for n in index}
+    for n, _, op, ops, _ in body:
+        if op == "bitcast":
+            continue
+        for at, o in enumerate(ops):
+            o = source(o)
+            if o in reads:
+                reads[o].append(
+                    size[n] if op == "dynamic-slice" and at == 0 else
+                    0 if op == "dynamic-update-slice" and at == 0 else None)
+    operands = {index[n]: sum(r) for n, r in reads.items()
+                if r and None not in r}
+    root = body[-1][0]
+    parts = made[root][1] if made[root][0] == "tuple" else [root]
+    result, updated = 0, False
+    for part in map(source, parts):
+        op, ops = made[part]
+        if op == "dynamic-update-slice" and source(ops[0]) in index:
+            result, updated = result + size[ops[1]], True
+        else:
+            result += size[part]
+    return operands, result if updated else None
 
 
 def account(text):
@@ -149,11 +191,19 @@ def account(text):
                 body = re.search(r"body=%?([\w.\-]+)", attrs).group(1)
                 walk(body, times * trips(attrs))
                 continue
-            moved = sum(size.get(n, 0) for n in names)
-            moved = times * (2 * moved if op.endswith("-start")
-                             else moved + size[name])
             called = re.search(r"calls=%?([\w.\-]+)", attrs)
             called = called and called.group(1)
+            read, result = [size.get(n, 0) for n in names], size[name]
+            if op == "dynamic-slice":           # the block it cuts
+                read = [result]
+            elif op == "dynamic-update-slice":  # in place: the update
+                read, result = [read[1]], read[1]
+            elif op == "fusion" and called:
+                cut, updated = _sliced(comps[called])
+                read = [cut.get(i, r) for i, r in enumerate(read)]
+                result = result if updated is None else updated
+            moved = times * (2 * sum(read) if op.endswith("-start")
+                             else sum(read) + result)
             kernel = _kernel_name(name) if (
                 op == "custom-call" and "tpu_custom_call" in attrs) \
                 else op == "fusion" and called and held_kernel[called]

@@ -1,7 +1,13 @@
 """The q/k norm and rotary positions of a packed projection, measured on the
 chip: the Pallas row kernel (``kernels.qk_rope.qk_rope``) against the lines
 it replaces in ``transformer._qkv`` (``rope(rms_norm(...))``, XLA), forward
-and backward, at the five decoders' q and k shapes (bf16):
+and backward, at the five decoders' q and k shapes (bf16), and its
+``pairs`` convention against ``_latent_qkv``'s lines at Mistral-Small-4's
+row block (``LATENT``: q ``[1, 1024, 32 x (64 + 64)]`` rotated and scaled
+by position; k assembled from ``k_nope`` ``[1, 1024, 32 x 64]`` and the ONE
+rotary key ``kr`` ``[1, 1024, 64]``: the kernel over the zero-padded heads
+``[k_nope_i | 0]`` that ``_latent_columns``' matmul writes, against
+``rope_pairs``, the broadcast and the concatenate):
 
     chiprun -- python3 scripts/qk_rope_bench.py [out.json] [--rows 64,128]
 
@@ -41,6 +47,10 @@ SHAPES = {
     "brumby.q": (1, 2048, 40, 128, "head", True),
     "brumby.k": (1, 2048, 8, 128, "head", True),
 }
+# Mistral-Small-4's row block: name: (batch, positions, heads, dn, dr)
+LATENT = {"mistral4.q": (1, 1024, 32, 64, 64),
+          "mistral4.k": (1, 1024, 32, 64, 64)}
+FREQS, FACTOR, ORIGINAL_MAX = 1e4, 1.0, 512
 HBM = 819e9
 ITERS = 10
 
@@ -62,6 +72,50 @@ def kernel(x, w, heads, dh, norm, rotary, first):
 
     tables = K.angle_tables(x.shape[1], dh, 1e4, first) if rotary else None
     return K.qk_rope(x, w, tables, head_dim=dh, norm=norm, eps=1e-5)
+
+
+def _latent_angles(S, dr, first):
+    pos = jnp.arange(S, dtype=jnp.float32) + first
+    freqs = FREQS ** (-jnp.arange(dr // 2, dtype=jnp.float32) / (dr // 2))
+    scale = 2.2 * (1.0 + 0.1 * jnp.log1p(jnp.floor(pos / ORIGINAL_MAX)))
+    return pos, freqs, scale
+
+
+def latent_reference(name, x, kr, heads, dn, dr, first):
+    """``_latent_qkv``'s lines on q (rotation of a head's last ``dr`` lanes,
+    the scale by position) or on k (x = k_nope: the ONE rotated key behind
+    every head's own lanes)."""
+    from paddle_tpu.parallel.transformer import rope_pairs
+
+    b, S, _ = x.shape
+    pos, freqs, scale = _latent_angles(S, dr, first)
+    ang = pos[:, None] * freqs[None]
+    if name.endswith(".q"):
+        q = x.astype(jnp.float32).reshape(b, S, heads, dn + dr)
+        q = jnp.concatenate(
+            [q[..., :dn], rope_pairs(q[..., dn:], ang, FACTOR)],
+            axis=-1) * scale[None, :, None, None]
+        return q.astype(x.dtype).reshape(b, S, -1)
+    kr = rope_pairs(kr.astype(jnp.float32)[:, :, None, :], ang, FACTOR)
+    return jnp.concatenate(
+        [x.reshape(b, S, heads, dn), jnp.broadcast_to(
+            kr.astype(x.dtype), (b, S, heads, dr))], axis=-1).reshape(b, S, -1)
+
+
+def latent_kernel(name, x, kr, heads, dn, dr, first):
+    """The kernel's pass; k's x is the padded ``[k_nope_i | 0]`` heads."""
+    from paddle_tpu.kernels import qk_rope as K
+
+    S = x.shape[1]
+    _, freqs, scale = _latent_angles(S, dr, first)
+    if name.endswith(".q"):
+        return K.qk_rope(x, None, K.pair_tables(S, freqs, dn + dr, first,
+                                                FACTOR, scale),
+                         head_dim=dn + dr, pairs=True)
+    return K.qk_rope(
+        x, None, K.pair_tables(S, freqs, dn + dr, first, FACTOR),
+        head_dim=dn + dr, pairs=True,
+        shared=jnp.pad(kr, ((0, 0), (0, 0), (dn, 0))))
 
 
 def both(fn, *static):
@@ -126,6 +180,41 @@ def main(argv):
         row["xla_us"], _ = device_us(xla, args)
         row["kernel_us"], row["by_name"] = device_us(fused, args)
         row["least_us"] = 5 * b * S * W * 2 / HBM * 1e6
+        bad |= row["err"] > max(4 * row["err_xla"], 2e-2)
+        report[name] = row
+        print(name, json.dumps(row), flush=True)
+    for name, (b, S, heads, dn, dr) in LATENT.items():
+        keys = jax.random.split(jax.random.PRNGKey(len(name)), 4)
+        is_q = name.endswith(".q")
+        nope = jax.random.normal(keys[0], (b, S, heads, dn + dr if is_q
+                                           else dn), jnp.float32) * 2
+        kr = jax.random.normal(keys[1], (b, S, dr), jnp.float32) * 2
+        g = jax.random.normal(keys[2], (b, S, heads * (dn + dr)), jnp.float32)
+        first = jnp.int32(3 * S)
+        padded = nope if is_q else jnp.pad(
+            nope, ((0, 0), (0, 0), (0, 0), (0, dr)))
+        static = (heads, dn, dr)
+        flat = lambda a: a.reshape(b, S, -1)
+        lines = both(lambda x, kr, *a: latent_reference(name, x, kr, *a),
+                     *static)
+        fused = both(lambda x, kr, *a: latent_kernel(name, x, kr, *a),
+                     *static)
+        exact = lines(flat(nope), kr, first, g)
+        bf = lambda a: a.astype(jnp.bfloat16)
+        args_lines = (bf(flat(nope)), bf(kr), first, bf(g))
+        args_fused = (bf(flat(padded)), bf(kr), first, bf(g))
+        got = fused(*args_fused)
+        if not is_q:        # dx of the padded heads: their own lanes
+            got = (got[0], flat(got[1].reshape(b, S, heads, -1)[..., :dn]),
+                   got[2])
+        row = {"rows": K.block_rows(S, heads * (dn + dr), 2),
+               "err_xla": worst(lines(*args_lines), exact),
+               "err": worst(got, exact)}
+        row["xla_us"], _ = device_us(lines, args_lines)
+        row["kernel_us"], row["by_name"] = device_us(fused, args_fused)
+        # q read and written each way; k: the padded heads read and the
+        # keys written, dy read and dx written over it
+        row["least_us"] = 4 * b * S * heads * (dn + dr) * 2 / HBM * 1e6
         bad |= row["err"] > max(4 * row["err_xla"], 2e-2)
         report[name] = row
         print(name, json.dumps(row), flush=True)

@@ -45,7 +45,8 @@ MOE = {"monitor.train.moe_load_max_over_mean",
        "monitor.kernels.moe_grouped_matmul_calls"}
 HELD = {"monitor.train.moe_held_rows_share", "monitor.train.moe_rows_held"}
 # since PR 47: a count a traced q or k of ``_qkv`` with a q/k norm or rotary
-# positions, by whether the row kernel took it (Mistral's latent chain: none)
+# positions, by whether the row kernel took it (since PR 57 Mistral's latent
+# q and k too, ``convention`` "pairs")
 QK = {"monitor.kernels.qk_rope_calls"}
 # since PR 55: a count a traced several-block flash backward, by whether the
 # row kernel made its ``delta`` (OLMoE's heads of 16: no flash call)
@@ -60,7 +61,7 @@ WRITTEN = {
     "smallthinker": MOE | HELD | QK | DELTA,
     "lfm2": MOE | HELD | QK | DELTA | {"monitor.train.router_bias_abs_max"},
     "brumby": QK | {"monitor.train.retention_gate_mean"},
-    "mistral4": MOE | HELD | DELTA,
+    "mistral4": MOE | HELD | QK | DELTA,
 }
 # tiny model -> (sequence, what ``decoder.probe``'s ONE program reads of
 # ``_staged``'s first batch at seed 3 (``moe_rows_held``: of both batches)):

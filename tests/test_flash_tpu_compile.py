@@ -115,8 +115,6 @@ def _compiled(attn, *shapes):
     backward: the flash kernels' own.  The row kernel in front of a
     several-block backward (``flash_delta``, PR 55) is another module's and
     has a test of its own below."""
-    from jaxlib.mlir import ir
-
     def both(q, k, v, do):
         o, vjp = jax.vjp(attn, q, k, v)
         return (o,) + vjp(do)
@@ -128,19 +126,28 @@ def _compiled(attn, *shapes):
                  re.S)}
     grids.pop("flash_delta", None)
     lowered = traced.lower()
-    mosaic = []
+    return (lowered.compile().as_text(), grids,
+            _mosaic_digests(lowered.as_text(), skip=("flash_delta",)))
+
+
+def _mosaic_digests(lowered_text, skip=()):
+    """sha1 (12 hex digits) of each ``tpu_custom_call`` body's Mosaic text
+    without debug info, in call order, but for the kernels named ``skip``."""
+    from jaxlib.mlir import ir
+
+    digests = []
     for body, name in re.findall(
             r'body\\22: \\22([A-Za-z0-9+/=]+).*?kernel_name = "(\w+)"',
-            lowered.as_text()):
-        if name == "flash_delta":
+            lowered_text):
+        if name in skip:
             continue
         context = ir.Context()
         context.allow_unregistered_dialects = True
         with context:
             module = ir.Module.parse(base64.b64decode(body))
-            mosaic.append(hashlib.sha1(module.operation.get_asm(
+            digests.append(hashlib.sha1(module.operation.get_asm(
                 enable_debug_info=False).encode()).hexdigest()[:12])
-    return lowered.compile().as_text(), grids, mosaic
+    return digests
 
 
 def _vmem(text, kernel):
@@ -559,6 +566,119 @@ def test_the_qk_rope_kernel_compiles_for_a_v5e(one_chip, what):
             asked, took = _vmem(text, kernel)
             assert asked == qr.vmem_bytes(rows, W, 2) < 20 * 2 ** 20, what
             assert took < asked, (what, n, kernel, took, asked)
+
+
+# The rotate-half row kernels' Mosaic modules, forward and backward, as PR
+# 57's parent (7080338) lowers them (sha1 of each ``tpu_custom_call`` body's
+# text without debug info, as ``_compiled`` takes it): the ``pairs``
+# convention and the shared lane block are static arguments of the same
+# kernel bodies, and the six rotary decoders' calls must not see them.
+# name: (batch, positions, heads, head width, norm, rotary), digests
+QK_ROPE_MOSAIC = {
+    "trinity q": ((1, 6144, 48, 128, "head", True),
+                  ["5ba19f1fc58f", "77886e8c8b5e"]),
+    "olmoe q": ((4, 4096, 16, 128, "whole", True),
+                ["f95e8cf9ebc5", "97a4b9729c57"]),
+    "lfm2 k": ((2, 8192, 8, 64, "head", True),
+               ["469429c99fd7", "58516d195468"]),
+    "smallthinker q": ((1, 16384, 28, 128, None, True),
+                       ["3f476868dd0c", "1b36c676b621"]),
+    "trinity q, a full layer": ((1, 6144, 48, 128, "head", False),
+                                ["cc75a56a54db", "9b0e63e2c42d"]),
+}
+
+
+@pytest.mark.parametrize("what", QK_ROPE_MOSAIC)
+def test_the_rotate_half_row_kernels_lower_to_the_parent_s_mosaic(one_chip,
+                                                                  what):
+    qr = importlib.import_module("paddle_tpu.kernels.qk_rope")
+    (b, S, heads, dh, norm, rotary), want = QK_ROPE_MOSAIC[what]
+    W = heads * dh
+    x = jax.ShapeDtypeStruct((b, S, W), jnp.bfloat16, sharding=one_chip)
+    w = norm and jax.ShapeDtypeStruct(
+        (dh if norm == "head" else W,), jnp.float32, sharding=one_chip)
+    first = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def both(x, w, first, g):
+        out, vjp = jax.vjp(lambda x, w: qr.qk_rope(
+            x, w, qr.angle_tables(S, dh, 1e4, first) if rotary else None,
+            head_dim=dh, norm=norm, eps=1e-5, interpret=False), x, w)
+        return (out,) + vjp(g)
+
+    got = _mosaic_digests(jax.jit(both).lower(x, w, first, x).as_text())
+    assert got == want, what
+
+
+@pytest.mark.parametrize("S,traced", [(16384, False), (1024, True)])
+def test_the_latent_q_and_k_passes_compile_for_a_v5e(one_chip, S, traced):
+    """``kernels/qk_rope.py``'s ``pairs`` convention at Mistral-Small-4's
+    shape ``[1, 16384, 32 x 128]`` (the cell's: the whole sequence from
+    position 0) and at a row block of 1,024 positions with a traced first
+    position, bf16: q rotated and scaled through its tables, k the padded
+    heads plus the shared lane block (its gradient the backward's second
+    result).  Two lane rotations by one and a select by lane parity are what
+    Mosaic has to take; a call asks for ``vmem_bytes`` and takes less."""
+    qr = importlib.import_module("paddle_tpu.kernels.qk_rope")
+    b, W, dr = 1, 4096, 64
+    freqs = [1e4 ** (-2 * j / dr) for j in range(dr // 2)]
+    x = jax.ShapeDtypeStruct((b, S, W), jnp.bfloat16, sharding=one_chip)
+    kr = jax.ShapeDtypeStruct((b, S, 128), jnp.bfloat16, sharding=one_chip)
+    first = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    scale = jax.ShapeDtypeStruct((S,), jnp.float32, sharding=one_chip)
+    rows = qr.block_rows(S, W, 2)
+    assert rows == 256 and qr.supported(x.shape, 128, 2)
+
+    def q_pass(x, scale, first, g):
+        out, vjp = jax.vjp(lambda x: qr.qk_rope(
+            x, None, qr.pair_tables(S, freqs, 128, first if traced else 0,
+                                    1.0, scale),
+            head_dim=128, pairs=True, interpret=False), x)
+        return (out,) + vjp(g)
+
+    def k_pass(x, kr, first, g):
+        out, vjp = jax.vjp(lambda x, kr: qr.qk_rope(
+            x, None, qr.pair_tables(S, freqs, 128, first if traced else 0),
+            head_dim=128, pairs=True, shared=kr, interpret=False), x, kr)
+        return (out,) + vjp(g)
+
+    for fn, args, shared in ((q_pass, (x, scale, first, x), False),
+                             (k_pass, (x, kr, first, x), True)):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        for kernel in ("qk_rope_fwd", "qk_rope_bwd"):
+            asked, took = _vmem(text, kernel)
+            assert asked == qr.vmem_bytes(rows, W, 2, shared) < 20 * 2 ** 20
+            assert took < asked, (kernel, shared, took, asked)
+
+
+def test_the_latent_layer_s_text_cuts_no_activation_across_lanes(one_chip):
+    """Mistral-Small-4's latent layer, recompute + backward, through
+    ``scripts/attn_outside_hlo.py`` (the no-chip reading ISSUE 57 was sized
+    by): both row kernels are in the text, and outside the matmuls and
+    kernels no 63- or 1-lane float32 slice of the rolls, no head 192 lanes
+    wide to cut k_nope and v from, no float32 array of q's size is left, in
+    sixteen row blocks or whole.  The parent moved 21.0 GB there by the same
+    count (7.9 of them the rotation's, the scale's and the assembly's
+    fusions; a row-block loop's slices counted by the block), this tree 2.0:
+    the hidden state transposed for the two down projections' dW, and the
+    latents."""
+    hlo = _script("attn_outside_hlo")
+    cfg, batch, seq = hlo.cell_config("mistral_small_4_119b.s16384_scan",
+                                      tiny=False)
+    kind = hlo.default_kind(cfg)
+    assert (batch, seq, kind) == (1, 16384, (None, True)) and cfg.latent
+    groups, by_kernel, others = hlo.account(
+        hlo.compiled_text(cfg, batch, seq, kind))
+    assert {"qk_rope_fwd", "qk_rope_bwd", "flash_fwd", "flash_delta",
+            "flash_bwd_fused"} == set(by_kernel)
+    # q and k each way: read and written once, with the tables and the
+    # shared lane block (its gradient in the backward)
+    assert by_kernel["qk_rope_fwd"] == by_kernel["qk_rope_bwd"] \
+        == 4 * seq * 4096 * 2 + seq * 128 * 2 + 4 * seq * 128 * 4
+    cut = [o for o in others if re.search(
+        r"f32\[1,\d+,32,(63|1|64|128)\]|bf16\[1,\d+,32,(192|64)\]", o[3])]
+    assert not cut, cut[:9]
+    assert not [o for o in others if o[0] > 140e6 and o[3].startswith("f32")]
+    assert groups["other"] < 3e9 and groups["matmul"] > 3e9
 
 
 def test_a_rotary_layer_s_text_holds_no_float32_heads_outside_the_kernels(

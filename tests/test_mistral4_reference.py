@@ -223,6 +223,52 @@ def test_the_rotated_key_is_one_vector_for_all_heads():
         np.hypot(kr[..., 0::2], kr[..., 1::2]), rtol=1e-4, atol=1e-6)
 
 
+def test_the_columns_taken_apart_are_wkv_b_s_own_numbers():
+    """``_latent_columns``: head i of the published ``[k_nope_i | v_i]``
+    layout as the keys' matrix ``[k_nope_i | 0]`` a head and the values'
+    ``v_i`` a head; the leaf keeps its layout."""
+    cfg, pl, _ = _layer0()
+    k_columns, v_columns = T._latent_columns(pl["wkv_b"], cfg)
+    assert pl["wkv_b"].shape == (16, 4 * (96 + 128))
+    assert k_columns.shape == v_columns.shape == (16, 4 * 128)
+    w = np.asarray(pl["wkv_b"]).reshape(16, 4, 224)
+    k_columns = np.asarray(k_columns).reshape(16, 4, 128)
+    np.testing.assert_array_equal(k_columns[..., :96], w[..., :96])
+    assert not k_columns[..., 96:].any()
+    np.testing.assert_array_equal(
+        np.asarray(v_columns).reshape(16, 4, 128), w[..., 96:])
+
+
+def test_the_row_kernel_s_q_k_v_and_gradients_equal_the_lines(monkeypatch):
+    """The tiny layer takes the row kernel (``tests/test_qk_rope_kernel.py::
+    ENGAGED``); with the kernel refused the same call runs the ``rope_pairs``
+    lines: the same q, k, v and the same gradients of the four chain leaves
+    in their PUBLISHED column order, and of the rows."""
+    from paddle_tpu.kernels import qk_rope
+
+    cfg, pl, h = _layer0()
+    w = jax.random.normal(jax.random.PRNGKey(9), (3, B, S, 512))
+
+    def run(pl, h):
+        out = T._latent_qkv(pl, h, cfg, 7)
+        return sum(jnp.sum(a * b) for a, b in zip(out, w)), out
+
+    assert qk_rope.supported((B, S, 512), 128, 4)
+    (_, got), got_grads = jax.value_and_grad(run, (0, 1), has_aux=True)(pl, h)
+    monkeypatch.setattr(qk_rope, "supported", lambda *a: False)
+    (_, want), want_grads = jax.value_and_grad(run, (0, 1), has_aux=True)(
+        pl, h)
+    for a, r in zip(got, want):
+        np.testing.assert_allclose(a, r, rtol=1e-5, atol=1e-5)
+    for name in ("wq_a", "wq_b", "wkv_a", "wkv_b", "q_a_norm", "kv_a_norm"):
+        a, r = got_grads[0][name], want_grads[0][name]
+        assert a.shape == pl[name].shape and np.abs(r).max() > 0
+        np.testing.assert_allclose(a, r, rtol=1e-4,
+                                   atol=1e-5 * np.abs(r).max(), err_msg=name)
+    np.testing.assert_allclose(got_grads[1], want_grads[1], rtol=1e-4,
+                               atol=1e-4)
+
+
 def test_rotation_is_of_adjacent_pairs():
     """``rope_pairs`` against complex multiplication of the pairs (2j, 2j +
     1) by e^(i pos f_j), and NOT the rotate-half convention's."""
@@ -291,20 +337,32 @@ def test_the_query_scale_steps_at_the_original_length():
                                atol=1e-6)
 
 
-def test_a_block_of_positions_at_a_time_gives_the_same_numbers(monkeypatch):
+@pytest.mark.parametrize("kernel,elements,width", [
+    (True, 1 << 13, 32 + 16 + 32), (False, 1 << 18, 2 * 3 * 512)])
+def test_a_block_of_positions_at_a_time_gives_the_same_numbers(
+        monkeypatch, kernel, elements, width):
     """Past ROW_BLOCK_ELEMENTS the chains, the rotation and the scale run a
-    block of positions at a time with the block's first position: the same
-    q, k, v and the same gradients."""
+    block of positions at a time with the block's first position (traced,
+    under ``lax.map`` and a checkpoint of the block's own): the same q, k, v
+    and the same gradients, through the row kernel (whose backward holds
+    the latents alone: a block is counted by their width) and through the
+    ``rope_pairs`` lines (by q's and k's float32 width)."""
+    from paddle_tpu.kernels import qk_rope
+
     cfg, pl, h = _layer0()
     w = jax.random.normal(jax.random.PRNGKey(9), (3, B, S, 512))
+    if not kernel:
+        monkeypatch.setattr(qk_rope, "supported", lambda *a: False)
+    assert T._latent_fused(cfg, (B, S), 4) == kernel
 
     def run(pl, h):
         return sum(jnp.sum(a * b) for a, b in zip(T._qkv(pl, h, cfg, True),
                                                   w))
 
+    assert T.row_block(S, B * width) == S
     whole = jax.value_and_grad(run, (0, 1))(pl, h)
-    monkeypatch.setattr(T, "ROW_BLOCK_ELEMENTS", 1 << 18)
-    assert T.row_block(S, B * 2 * 3 * 512) == 8
+    monkeypatch.setattr(T, "ROW_BLOCK_ELEMENTS", elements)
+    assert T.row_block(S, B * width) == 8
     blocked = jax.value_and_grad(run, (0, 1))(pl, h)
     for a, b in zip(jax.tree.leaves(blocked), jax.tree.leaves(whole)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)

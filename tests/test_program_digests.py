@@ -55,6 +55,13 @@ from paddle_tpu.parallel.train import stack_batches  # noqa: E402
 # (ca40cf3) before any other edit: Ouro's at three passes over two layers,
 # ResNet's at depth 18 on 32 x 32 images (it has no remat to turn on), so
 # that all eleven trainers are held.  PR 56 re-took none of the eighteen.
+# PR 57 took Mistral's two anew ON PURPOSE: its tiny heads of 96 + 32 are
+# whole lane blocks, so the latent q and k go through the row kernel's
+# ``pairs`` convention (``kernels/qk_rope.py``) and k and v come out of
+# ``wkv_b``'s own columns (``transformer._latent_columns``); the twenty others
+# stand (the six rotary decoders' ``qk_rope`` calls lower to the text, and on
+# the chip to the Mosaic modules, they had:
+# ``tests/test_flash_tpu_compile.py::QK_ROPE_MOSAIC``).
 PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "bert.run_steps": "00de5403506fdc87",
             "olmoe.step": "231114fcd62341f2",
@@ -65,8 +72,8 @@ PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "lfm2.run_steps": "55c703e13aaa5114",
             "brumby.step": "84e6b6d548803a44",
             "brumby.run_steps": "5a063ea89a19f1a4",
-            "mistral4.step": "835f4ec090f0f6c0",
-            "mistral4.run_steps": "c377a701ae4693ca",
+            "mistral4.step": "ffaa7601578581ff",
+            "mistral4.run_steps": "9100e873b1250116",
             "trinity.step": "86340ecdb523bc2f",
             "trinity.run_steps": "c7581de1b602b474",
             "jamba.step": "21dc4e9f64565ee9",

@@ -1,7 +1,10 @@
 """``kernels/qk_rope.py`` (q/k norm and rotary positions in one pass) in
 Pallas interpret mode against the lines it replaces, ``rope(rms_norm(...))``
 of ``parallel/transformer.py``: outputs and every gradient; and ``_qkv``
-taking the kernel where the shapes allow and those lines where not."""
+taking the kernel where the shapes allow and those lines where not; its
+``pairs`` convention and shared lane block (the latent form's q and k)
+against ``rope_pairs``, the scale and the concatenate-and-broadcast lines of
+``_latent_qkv``."""
 
 import jax
 import jax.numpy as jnp
@@ -113,6 +116,166 @@ def test_a_traced_first_under_lax_map_is_a_block_of_a_longer_sequence():
         np.testing.assert_allclose(a, r, rtol=2e-5, atol=5e-5)
 
 
+FREQS = {dr: 1e4 ** (-np.arange(dr // 2) / (dr // 2)) for dr in (32, 64, 128)}
+FACTOR = 1.25
+
+
+def _position_scale(S, first):
+    """``_latent_qkv``'s: a softmax scale times the position's step."""
+    pos = jnp.arange(S, dtype=jnp.float32) + first
+    return 1.3 * (1.0 + 0.1 * jnp.log1p(jnp.floor(pos / 16)))
+
+
+def pairs_reference(x, _, heads, dn, dr, scaled, first=0):
+    """``_latent_qkv``'s lines on the query: ``rope_pairs`` on a head's last
+    ``dr`` lanes, the whole head times the position's scale."""
+    b, S, W = x.shape
+    pos = jnp.arange(S, dtype=jnp.float32) + first
+    ang = pos[:, None] * jnp.asarray(FREQS[dr], jnp.float32)[None]
+    q = x.astype(jnp.float32).reshape(b, S, heads, dn + dr)
+    q = jnp.concatenate(
+        [q[..., :dn], T.rope_pairs(q[..., dn:], ang, FACTOR)], axis=-1)
+    if scaled:
+        q = q * _position_scale(S, first)[None, :, None, None]
+    return q.astype(x.dtype).reshape(b, S, W)
+
+
+def pairs_kernel(x, _, heads, dn, dr, scaled, first=0):
+    S = x.shape[1]
+    tables = K.pair_tables(S, FREQS[dr], dn + dr, first, FACTOR,
+                           _position_scale(S, first) if scaled else None)
+    return K.qk_rope(x, None, tables, head_dim=dn + dr, pairs=True)
+
+
+@pytest.mark.parametrize("scaled", [True, False])
+@pytest.mark.parametrize("dn,dr,heads,b,S", [
+    (64, 64, 3, 2, 48), (96, 32, 2, 1, 16), (0, 128, 2, 1, 24),
+    (32, 32, 4, 2, 16)])    # two heads of 32 + 32 a lane block
+def test_pairs_equal_rope_pairs_and_the_scale_lines(dn, dr, heads, b, S,
+                                                    scaled):
+    x, _, g = operands(b, S, heads, dn + dr, None, seed=3)
+    static = (heads, dn, dr, scaled)
+    got = value_and_grads(pairs_kernel, x, None, g, *static, first=9)
+    want = value_and_grads(pairs_reference, x, None, g, *static, first=9)
+    for name, a, r in zip(("out", "dx"), got, want):
+        assert a.shape == r.shape and a.dtype == r.dtype, name
+        np.testing.assert_allclose(a, r, rtol=2e-5, atol=5e-5, err_msg=name)
+    # and NOT the rotate-half convention's at the same angles
+    assert dr < 4 or np.abs(got[0] - K.qk_rope(
+        x, None, K.pair_tables(S, FREQS[dr], dn + dr, 9, FACTOR),
+        head_dim=dn + dr)).max() > 0.1
+
+
+@pytest.mark.parametrize("dn,dr", [(64, 64), (96, 32)])
+def test_pairs_in_bf16_are_rounded_once_and_no_further_from_float32(dn, dr):
+    x, _, g = operands(2, 32, 2, 128, None, jnp.bfloat16, seed=4)
+    static = (2, dn, dr, True)
+    exact = value_and_grads(pairs_reference, x.astype(jnp.float32), None, g,
+                            *static)
+    got = value_and_grads(pairs_kernel, x, None, g, *static)
+    old = value_and_grads(pairs_reference, x, None, g, *static)
+    assert got[0].dtype == got[1].dtype == jnp.bfloat16
+    f32 = lambda a: np.asarray(a, np.float32)
+    once = f32(exact[0].astype(jnp.bfloat16))
+    assert np.mean(f32(got[0]) != once) < 1e-3
+    np.testing.assert_allclose(f32(got[0]), once, rtol=2 ** -7, atol=1e-6)
+    for a, o, e in zip(got, old, exact):
+        assert np.abs(f32(a) - f32(e)).max() \
+            <= 1.001 * np.abs(f32(o) - f32(e)).max() + 1e-6
+
+
+def test_pairs_with_a_traced_first_under_lax_map_and_checkpoint():
+    """Mistral's call: ``_by_row_blocks`` hands ``_latent_qkv`` a block of
+    rows and its first position, traced."""
+    heads, dn, dr, block = 2, 64, 64, 16
+    x, _, g = operands(1, 4 * block, heads, 128, None, seed=5)
+
+    def blocked(x, _, *static_and_first):
+        def rows(turn):
+            return pairs_kernel(turn[0], None, heads, dn, dr, True, turn[1])
+        out = jax.lax.map(jax.checkpoint(rows), (
+            x.reshape(1, -1, block, heads * 128).swapaxes(0, 1),
+            jnp.arange(0, x.shape[1], block)))
+        return out.swapaxes(0, 1).reshape(x.shape)
+
+    got = value_and_grads(blocked, x, None, g)
+    want = value_and_grads(pairs_reference, x, None, g, heads, dn, dr, True)
+    for a, r in zip(got, want):
+        np.testing.assert_allclose(a, r, rtol=2e-5, atol=5e-5)
+
+
+def keys_reference(k_nope, kr, heads, dn, dr, first):
+    """``_latent_qkv``'s lines: the ONE rotary key rotated, rounded and
+    written behind every head's own ``dn`` lanes."""
+    b, S, _ = k_nope.shape
+    pos = jnp.arange(S, dtype=jnp.float32) + first
+    ang = pos[:, None] * jnp.asarray(FREQS[dr], jnp.float32)[None]
+    kr = T.rope_pairs(kr.astype(jnp.float32)[:, :, None, :], ang, FACTOR)
+    return jnp.concatenate(
+        [k_nope.reshape(b, S, heads, dn), jnp.broadcast_to(
+            kr.astype(k_nope.dtype), (b, S, heads, dr))],
+        axis=-1).reshape(b, S, -1)
+
+
+def keys_kernel(k_nope, kr, heads, dn, dr, first):
+    """The kernel's assembly: every head's ``[k_nope_i | 0]`` (what the
+    matmul by ``_latent_columns``' padded columns gives) plus the shared
+    ``[0 | kr]``, rotated in the same pass."""
+    b, S, _ = k_nope.shape
+    padded = jnp.pad(k_nope.reshape(b, S, heads, dn),
+                     ((0, 0), (0, 0), (0, 0), (0, dr))).reshape(b, S, -1)
+    shared = jnp.tile(jnp.pad(kr, ((0, 0), (0, 0), (dn, 0))),
+                      128 // (dn + dr))
+    return K.qk_rope(padded, None,
+                     K.pair_tables(S, FREQS[dr], dn + dr, first, FACTOR),
+                     head_dim=dn + dr, pairs=True, shared=shared)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("dn,dr,heads,b,S", [
+    (64, 64, 3, 2, 48), (96, 32, 4, 1, 16), (32, 32, 4, 2, 32)])
+def test_the_keys_assembly_equals_concatenate_and_broadcast(dn, dr, heads, b,
+                                                            S, dtype):
+    keys = jax.random.split(jax.random.PRNGKey(6), 3)
+    k_nope = jax.random.normal(keys[0], (b, S, heads * dn)).astype(dtype)
+    kr = (2 * jax.random.normal(keys[1], (b, S, dr))).astype(dtype)
+    g = jax.random.normal(keys[2], (b, S, heads * (dn + dr)))
+
+    def run(fn):
+        def loss(k_nope, kr):
+            out = fn(k_nope, kr, heads, dn, dr, 7)
+            return jnp.sum(out.astype(jnp.float32) * g), out
+        (_, out), grads = jax.value_and_grad(loss, (0, 1), has_aux=True)(
+            k_nope, kr)
+        return (out,) + grads
+
+    got, want = run(keys_kernel), run(keys_reference)
+    for name, a, r in zip(("k", "dk_nope", "dkr"), got, want):
+        assert a.shape == r.shape and a.dtype == r.dtype == dtype, name
+    f32 = lambda a: np.asarray(a, np.float32)
+    if dtype == jnp.float32:
+        for name, a, r in zip(("k", "dk_nope", "dkr"), got, want):
+            np.testing.assert_allclose(a, r, rtol=2e-5, atol=5e-5,
+                                       err_msg=name)
+        return
+    # a head's own lanes pass untouched, the rotated key is rounded once as
+    # the lines round it; dkr is the heads' sum in float32, rounded once,
+    # where the lines round every head's before and the sum after
+    np.testing.assert_array_equal(f32(got[0]), f32(want[0]))
+    np.testing.assert_array_equal(f32(got[1]), f32(want[1]))
+    exact = run(lambda *a: keys_reference(
+        a[0].astype(jnp.float32), a[1].astype(jnp.float32), *a[2:]))
+    assert np.abs(f32(got[2]) - f32(exact[2])).max() \
+        <= 1.001 * np.abs(f32(want[2]) - f32(exact[2])).max() + 1e-6
+
+
+def test_a_shared_lane_block_goes_with_no_norm():
+    x = jnp.zeros((1, 16, 256))
+    with pytest.raises(ValueError, match="no norm"):
+        K.qk_rope(x, jnp.ones((128,)), None, head_dim=128, norm="head",
+                  shared=jnp.zeros((1, 16, 128)))
+
+
 @pytest.mark.parametrize("shape,dh,itemsize,rows", [
     ((1, 6144, 6144), 128, 2, 128),     # Trinity's q: six blocks in 12 MiB
     ((1, 6144, 1024), 128, 2, 256),     # its k
@@ -120,6 +283,7 @@ def test_a_traced_first_under_lax_map_is_a_block_of_a_longer_sequence():
     ((2, 8192, 2048), 64, 2, 256),      # LFM2, two heads a lane block
     ((1, 16384, 3584), 128, 2, 256),    # SmallThinker
     ((1, 2048, 5120), 128, 2, 128),     # Brumby's row block
+    ((1, 1024, 4096), 128, 2, 256),     # Mistral's row block, q and k
     ((2, 48, 384), 128, 4, 16),
     ((2, 24, 256), 64, 2, None),        # 24 rows are no whole bf16 tiles
     ((2, 32, 64), 16, 4, None),         # tiny OLMoE: half a lane block
@@ -148,14 +312,14 @@ def _config(**kw):
 
 
 def _counted(tmp_path, trace):
-    """{(dh, norm, rotary, fused): calls} that ``trace()`` counts in
-    ``monitor.kernels.qk_rope_calls`` under a monitor session."""
+    """{(dh, norm, rotary, convention, fused): calls} that ``trace()``
+    counts in ``monitor.kernels.qk_rope_calls`` under a monitor session."""
     mon = monitor.enable(str(tmp_path), flight=False)
     try:
         mon.registry.reset()        # the registry is the process's
         trace()
-        return {tuple(r["labels"][k] for k in ("dh", "norm", "rotary",
-                                               "fused")): r["value"]
+        return {tuple(r["labels"][k] for k in (
+            "dh", "norm", "rotary", "convention", "fused")): r["value"]
                 for r in mon.registry.snapshot()
                 if r["name"] == "monitor.kernels.qk_rope_calls"}
     finally:
@@ -179,7 +343,7 @@ def test_qkv_takes_the_kernel_where_the_shapes_allow(tmp_path, S, fused):
     T._qkv(pl, h, cfg, True, 3)             # off the monitor: nothing counts
     out = []
     assert _counted(tmp_path, lambda: out.extend(
-        T._qkv(pl, h, cfg, True, 3))) == {(128, "head", 1, fused): 2}
+        T._qkv(pl, h, cfg, True, 3))) == {(128, "head", 1, "half", fused): 2}
     q, k, v = out
     np.testing.assert_allclose(q, reference(
         h @ pl["wq"], pl["q_norm"], 2, 128, "head", True, 3),
@@ -193,19 +357,42 @@ def test_qkv_takes_the_kernel_where_the_shapes_allow(tmp_path, S, fused):
         pl, h, _config(qk_norm=False), False)) == {}
 
 
+@pytest.mark.parametrize("S,fused", [(16, 1), (12, 0)])
+def test_latent_qkv_takes_the_kernel_where_the_shapes_allow(
+        tmp_path, monkeypatch, S, fused):
+    """Tiny Mistral-Small-4's layer (4 heads of 96 + 32, values of 128): the
+    kernel takes q and k at 16 positions; at 12 (no whole sublane tiles)
+    ``_latent_qkv`` runs the ``rope_pairs`` lines and counts ``fused=0``;
+    both ways q, k and v are those lines'."""
+    from paddle_tpu.models import mistral4
+
+    cfg = mistral4.mistral4_tiny_config()
+    params = T.init_transformer_params(jax.random.PRNGKey(4), cfg)
+    pl = jax.tree.map(lambda a: a[0], params["params_layers"])
+    h = jax.random.normal(jax.random.PRNGKey(5), (2, S, 64))
+    out = []
+    assert _counted(tmp_path, lambda: out.extend(
+        T._latent_qkv(pl, h, cfg, 5))) == {(128, "none", 1, "pairs", fused): 2}
+    monkeypatch.setattr(K, "supported", lambda *a: False)
+    for a, r in zip(out, T._latent_qkv(pl, h, cfg, 5)):
+        np.testing.assert_allclose(a, r, rtol=2e-5, atol=2e-5)
+
+
 # tiny model -> (sequence, the projections its forward counts): BERT (learned
-# positions, no q/k norm) and Mistral-Small-4 (``_latent_qkv``) make no call;
-# OLMoE's 4 heads of 16 are half a lane block, so it keeps the XLA lines; the
-# others' widths are whole lane blocks and take the kernel (SmallThinker's
-# and Trinity's layers without positions: no call, and the norm alone)
+# positions, no q/k norm) makes no call; Mistral-Small-4's latent q and k
+# (``_latent_qkv``: heads of 96 + 32 = 128, the adjacent-pair convention)
+# take the kernel; OLMoE's 4 heads of 16 are half a lane block, so it keeps
+# the XLA lines; the others' widths are whole lane blocks and take the kernel
+# (SmallThinker's and Trinity's layers without positions: no call, and the
+# norm alone)
 ENGAGED = {
     "bert": (32, set()),
-    "mistral4": (64, set()),
-    "olmoe": (32, {(16, "whole", 1, 0)}),
-    "smallthinker": (64, {(128, "none", 1, 1)}),
-    "lfm2": (64, {(64, "head", 1, 1)}),
-    "brumby": (64, {(128, "head", 1, 1)}),
-    "trinity": (64, {(128, "head", 1, 1), (128, "head", 0, 1)}),
+    "mistral4": (64, {(128, "none", 1, "pairs", 1)}),
+    "olmoe": (32, {(16, "whole", 1, "half", 0)}),
+    "smallthinker": (64, {(128, "none", 1, "half", 1)}),
+    "lfm2": (64, {(64, "head", 1, "half", 1)}),
+    "brumby": (64, {(128, "head", 1, "half", 1)}),
+    "trinity": (64, {(128, "head", 1, "half", 1), (128, "head", 0, "half", 1)}),
 }
 
 
