@@ -114,12 +114,18 @@ def _lanes_to(x, n):
     return x[:, :n]
 
 
-def packed_layout_supported(n_heads, head_dim, n_kv_heads=None):
+def packed_layout_supported(n_heads, head_dim, n_kv_heads=None,
+                            v_head_dim=None):
     """True when the packed [B, S, H*D] entry can address this head shape
     (Mosaic lane-tiling rule; see _heads_per_block).  Grouped queries need
     a lane block whose heads all read one key/value head: a block that is
     one head (D a multiple of 128), or a group that is whole blocks (D = 64:
-    an even group) over key/value heads that fill whole blocks too."""
+    an even group) over key/value heads that fill whole blocks too.  A value
+    width of its own (``v_head_dim`` other than ``head_dim``): both whole
+    lane blocks, every head its own key/value head."""
+    if v_head_dim not in (None, head_dim):
+        return (head_dim % LANES == 0 and v_head_dim % LANES == 0
+                and n_kv_heads in (None, n_heads))
     hpb = max(1, LANES // head_dim)
     if n_kv_heads not in (None, n_heads) and (
             n_heads % n_kv_heads or (n_heads // n_kv_heads) % hpb
@@ -204,18 +210,21 @@ def step_geometry(B, S, n_head_blocks, lanes, itemsize):
     return G, Hg
 
 
-def fused_sweep_vmem_bytes(Sk, lanes, itemsize):
+def fused_sweep_vmem_bytes(Sk, lanes, itemsize, v_lanes=None):
     """What ``flash_bwd_fused`` over several blocks asks of VMEM at a
-    key/value length of Sk and head-blocks ``lanes`` wide: the two float32
+    key/value length of Sk and head-blocks ``lanes`` wide (the values'
+    ``v_lanes``, where they have a width of their own): the two float32
     accumulators that hold dk and dv of the whole sequence, their two output
     blocks (one buffer each: they leave once a grid row), and Mosaic's own
     scope for what a step holds, which is what the two sweeps' steps live in
     (double-buffered [512, lanes] operand blocks, the [512, 512] tiles)."""
-    width = max(lanes, LANES)                      # narrow blocks pad to a tile
-    return 2 * Sk * width * 4 + 2 * Sk * width * itemsize + SCOPED_VMEM
+    # narrow blocks pad to a tile
+    width = max(lanes, LANES) + max(lanes if v_lanes is None else v_lanes,
+                                    LANES)
+    return Sk * width * 4 + Sk * width * itemsize + SCOPED_VMEM
 
 
-def bwd_sweeps(Sk, bk, lanes, itemsize, group=1):
+def bwd_sweeps(Sk, bk, lanes, itemsize, group=1, v_lanes=None):
     """The kernels of one layer's backward, from the shapes alone: 1
     (``flash_bwd_fused``: dq, dk and dv off one recomputed probability tile)
     where the Sk keys are one block of bk and the queries are not grouped,
@@ -223,7 +232,7 @@ def bwd_sweeps(Sk, bk, lanes, itemsize, group=1):
     SWEEP_VMEM; else 2 (``flash_bwd_dq``, ``flash_bwd_dkv``)."""
     if Sk == bk and group == 1:
         return 1
-    fits = fused_sweep_vmem_bytes(Sk, lanes, itemsize) <= SWEEP_VMEM
+    fits = fused_sweep_vmem_bytes(Sk, lanes, itemsize, v_lanes) <= SWEEP_VMEM
     return 1 if fits else 2
 
 
@@ -312,9 +321,14 @@ class _Geom:
 
     ``Hkv`` < H: grouped queries, k and v hold Hkv heads and q head h reads
     kv head ``h // group``.  ``window`` (None: none) and the causal mask
-    shape the several-block sweeps through their ``step_table`` alone."""
+    shape the several-block sweeps through their ``step_table`` alone.
 
-    def __init__(self, q, k, H, bq, bk, Hkv=None, window=None):
+    ``Dv`` (None: D): the values' head width where it is not q's and k's (v,
+    o, do and dv are [B, S, H*Dv]; both widths whole lane blocks, a block a
+    head, no grouping): ``vw`` is a value head-block's lanes where ``qw`` is
+    a query's, and every product with v or do runs at ``vw``."""
+
+    def __init__(self, q, k, H, bq, bk, Hkv=None, window=None, Dv=None):
         B, self.S, E = q.shape
         self.Sk = k.shape[1]
         if H is None:
@@ -336,16 +350,21 @@ class _Geom:
         # its heads ride the several-block sweeps stacked (``_stack_heads``)
         self.halves = self.hpb if self.group > 1 else 1
         self.qw = self.D * self.hpb   # width of one head-block (lane dim)
+        self.Dv = self.D if Dv is None else Dv
+        assert self.Dv == self.D or (self.hpb == self.group == 1
+                                     and self.Dv % LANES == 0), (self.D, Dv)
+        self.vw = self.Dv * self.hpb  # of a value's
         self.G, self.Hg, self.grid_b = grid_geometry(
             B, self.S, self.Sk, self.Hb, self.qw, q.dtype.itemsize, bq, bk,
             self.group)
         self.nq = self.S // bq
         self.one_block = self.Sk == bk
         self.bwd_sweeps = bwd_sweeps(self.Sk, bk, self.qw, q.dtype.itemsize,
-                                     self.group)
+                                     self.group, self.vw)
         self.window = window
-        self.o_shape = q.shape
-        self.dkv_shape = k.shape
+        self.o_shape = q.shape[:-1] + (E // self.D * self.Dv,)
+        self.dk_shape = k.shape
+        self.dv_shape = k.shape[:-1] + (k.shape[-1] // self.D * self.Dv,)
         # stats are 4-D so the block's last dim equals the array's (Mosaic
         # tiling rule): [row, head-block group, S, heads of the group]
         self.stat_shape = (B, self.Hb // self.Hg, self.S, self.Hg * self.hpb)
@@ -410,6 +429,16 @@ class _Geom:
 
     def kv_spec(self, bk, index_map=None):
         return pl.BlockSpec((self.G, bk, self.Hg * self.qw),
+                            index_map or self.kmap())
+
+    def o_spec(self, bq, index_map=None):
+        """Of o and do: q's rows at the values' width."""
+        return pl.BlockSpec((self.G, bq, self.Hg * self.vw),
+                            index_map or self.qmap())
+
+    def v_spec(self, bk, index_map=None):
+        """Of v and dv: k's rows at the values' width."""
+        return pl.BlockSpec((self.G, bk, self.Hg * self.vw),
                             index_map or self.kmap())
 
     def stat_spec(self, bq, index_map=None):
@@ -501,22 +530,24 @@ def _stack_sweep(stk, *refs):
 
 def _bwd_tiles(tile, stk, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                hpb, bq, bk):
-    """A backward step's tiles, ``tile(q, k, v, do, lse, delta, cs, wrap)``:
-    ONE over the stack ``stk`` (q, do, lse, delta: ``_stack_sweep``'s scratch
-    or ``_stacked``'s values) against the whole k and v lane blocks, or, with
-    no stack, a head at a time on its own columns ``cs``.  lse and delta go
+    """A backward step's tiles, ``tile(q, k, v, do, lse, delta, cs, vs,
+    wrap)``: ONE over the stack ``stk`` (q, do, lse, delta: ``_stack_sweep``'s
+    scratch or ``_stacked``'s values) against the whole k and v lane blocks,
+    or, with no stack, a head at a time on its own columns ``cs`` (of v and
+    do: ``vs``).  lse and delta go
     over as thunks, so ``tile`` reads them where it uses them."""
     if stk:
         q, do, lse, delta = stk
         tile(q[:], k_ref[0], v_ref[0], do[:], lambda: _lanes_to(lse[:], bk),
-             lambda: _lanes_to(delta[:], bk), slice(None), wrap=bq)
+             lambda: _lanes_to(delta[:], bk), slice(None), slice(None),
+             wrap=bq)
         return
-    D = q_ref.shape[-1] // hpb
+    D, Dv = q_ref.shape[-1] // hpb, v_ref.shape[-1] // hpb
     for hh in range(hpb):
-        cs = slice(hh * D, (hh + 1) * D)
-        tile(q_ref[0][:, cs], k_ref[0][:, cs], v_ref[0][:, cs],
-             do_ref[0][:, cs], lambda: lse_ref[0, 0][:, hh:hh + 1],
-             lambda: delta_ref[0, 0][:, hh:hh + 1], cs)
+        cs, vs = slice(hh * D, (hh + 1) * D), slice(hh * Dv, (hh + 1) * Dv)
+        tile(q_ref[0][:, cs], k_ref[0][:, cs], v_ref[0][:, vs],
+             do_ref[0][:, vs], lambda: lse_ref[0, 0][:, hh:hh + 1],
+             lambda: delta_ref[0, 0][:, hh:hh + 1], cs, vs)
 
 
 def _seen(shape, q0, k0, window, wrap=None):
@@ -553,7 +584,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq,
     (row, head) computed on its own.  The whole of K/V is in the block:
     softmax in one pass, no running statistics (the numbers are the sweep's
     own: its first block meets m = -inf, l = 0, acc = 0)."""
-    D = q_ref.shape[-1] // (Hg * hpb)
+    D, Dv = q_ref.shape[-1] // (Hg * hpb), v_ref.shape[-1] // (Hg * hpb)
     i = pl.program_id(1)
     window = geom.window
     half = geom.kv_half(pl.program_id(0) % geom.Hb)
@@ -561,12 +592,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq,
     def row(g):
         for hb in range(Hg):
             cols = pl.ds(hb * hpb * D, hpb * D)
-            qb, kb, vb = q_ref[g, :, cols], k_ref[g, :, cols], v_ref[g, :, cols]
+            vcols = pl.ds(hb * hpb * Dv, hpb * Dv)
+            qb, kb, vb = q_ref[g, :, cols], k_ref[g, :, cols], v_ref[g, :, vcols]
             out = []
             for hh in range(hpb):
                 cs = slice(hh * D, (hh + 1) * D)
                 kh = _kv_cols(kb, hh, D, half, geom.halves)
-                vh = _kv_cols(vb, hh, D, half, geom.halves)
+                vh = _kv_cols(vb, hh, Dv, half, geom.halves)
                 s = _scores(qb[:, cs], kh, scale, causal, i * bq, 0, window)
                 m = jnp.max(s, axis=1)[:, None]            # [bq, 1]
                 p = jnp.exp(s - m)                          # [bq, bk] f32
@@ -582,7 +614,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq,
                 # bench shapes; a lane-oriented [1, bq] output costs a
                 # Mosaic relayout per block — both measured slower)
                 lse_ref[g, 0, :, pl.ds(hb * hpb + hh, 1)] = m + jnp.log(l)
-            o_ref[g, :, cols] = _cat(out).astype(o_ref.dtype)
+            o_ref[g, :, vcols] = _cat(out).astype(o_ref.dtype)
 
     _rows(G, row)
 
@@ -600,7 +632,7 @@ def _fwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, o_ref,
     ``q_stk``), a step is ONE [hpb * bq, bk] tile against the whole k and v
     lane blocks, the statistics a row of the stack each, and the last step
     puts the heads side by side again."""
-    D = q_ref.shape[-1] // hpb
+    D, Dv = q_ref.shape[-1] // hpb, v_ref.shape[-1] // hpb
     t, q_block = geom.step()
     half = geom.kv_half(q_block)
 
@@ -612,8 +644,9 @@ def _fwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, o_ref,
         if q_stk:
             q_stk[0][:] = _stack_heads(q_ref[0], hpb, D, half)
 
-    def tile(q, cs, ls, wrap=None):
-        """The rows of q against columns ``cs`` of this kv block."""
+    def tile(q, cs, vs, ls, wrap=None):
+        """The rows of q against columns ``cs`` of this kv block's keys and
+        ``vs`` of its values."""
         s = _scores(q, k_ref[0][:, cs], scale, causal, q_of[t] * bq,
                     kv_of[t] * bk, geom.window, wrap)  # [rows, bk]
 
@@ -623,20 +656,21 @@ def _fwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, o_ref,
         p = jnp.exp(s - _lanes_to(m_new, bk))          # [rows, bk] f32
         alpha = jnp.exp(m_prev - m_new)                # [rows, LANES]
         l_scr[:, ls] = l_scr[:, ls] * alpha + jnp.sum(p, axis=1)[:, None]
-        acc_scr[:, cs] = acc_scr[:, cs] * _lanes_to(alpha, q.shape[1]) \
-            + jax.lax.dot_general(
-                p.astype(v_ref.dtype), v_ref[0][:, cs],
+        acc_scr[:, vs] = acc_scr[:, vs] * _lanes_to(
+            alpha, q.shape[1] // D * Dv) + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0][:, vs],
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
         m_scr[:, ls] = m_new
 
     if q_stk:
-        tile(q_stk[0][:], slice(None), slice(None), wrap=bq)
+        tile(q_stk[0][:], slice(None), slice(None), slice(None), wrap=bq)
     else:
         for hh in range(hpb):
-            cs = slice(hh * D, (hh + 1) * D)
-            tile(q_ref[0][:, cs], cs, slice(hh * LANES, (hh + 1) * LANES))
+            tile(q_ref[0][:, hh * D:(hh + 1) * D],
+                 slice(hh * D, (hh + 1) * D), slice(hh * Dv, (hh + 1) * Dv),
+                 slice(hh * LANES, (hh + 1) * LANES))
 
     @pl.when((flags[t] & LAST) != 0)
     def _final():
@@ -651,7 +685,7 @@ def _fwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, o_ref,
             return
         alpha_cols = jnp.concatenate(
             [_lanes_to(l[:, hh * LANES:(hh + 1) * LANES], D)
-             for hh in range(hpb)], axis=1) if hpb > 1 else _lanes_to(l, D)
+             for hh in range(hpb)], axis=1) if hpb > 1 else _lanes_to(l, Dv)
         o_ref[0] = (acc_scr[:] / alpha_cols).astype(o_ref.dtype)
         lse_ref[0, 0] = jnp.concatenate(
             [m_scr[:, hh * LANES:hh * LANES + 1]
@@ -686,10 +720,11 @@ def _sweep_call(kernel, g, table, operands, in_specs, out_specs, out_shape,
 
 
 def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None, Hkv=None,
-         window=None):
+         window=None, Dv=None):
     """H=None: q/k/v are [BH, S, D].  H=int: q/k/v are [B, S, H*D] (k, v
-    [B, S, Hkv*D] with grouped queries)."""
-    g = _Geom(q, k, H, bq, bk, Hkv, window)
+    [B, S, Hkv*D] with grouped queries; v [B, S, H*Dv] with a value width of
+    its own)."""
+    g = _Geom(q, k, H, bq, bk, Hkv, window, Dv)
     out_shape = [
         jax.ShapeDtypeStruct(g.o_shape, q.dtype),
         jax.ShapeDtypeStruct(g.stat_shape, jnp.float32),
@@ -702,11 +737,11 @@ def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None, Hkv=None,
             functools.partial(_fwd_sweep_kernel, scale=scale, causal=causal,
                               bq=bq, bk=bk, hpb=g.hpb, geom=g),
             g, step_table(g.S, g.Sk, bq, bk, causal, window), (q, k, v),
-            [g.q_spec(bq, qm), g.kv_spec(bk, km), g.kv_spec(bk, km)],
-            [g.q_spec(bq, qm), g.stat_spec(bq, sm)], out_shape,
+            [g.q_spec(bq, qm), g.kv_spec(bk, km), g.v_spec(bk, km)],
+            [g.o_spec(bq, qm), g.stat_spec(bq, sm)], out_shape,
             [pltpu.VMEM((rows, lanes), jnp.float32),
              pltpu.VMEM((rows, lanes), jnp.float32),
-             pltpu.VMEM((rows, g.qw), jnp.float32)]
+             pltpu.VMEM((rows, g.vw), jnp.float32)]
             + [pltpu.VMEM((rows, g.qw), q.dtype)] * (g.halves > 1),
             False, interpret, "fwd")
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -717,10 +752,10 @@ def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None, Hkv=None,
         in_specs=[
             g.q_spec(bq),
             g.kv_spec(bk),
-            g.kv_spec(bk),
+            g.v_spec(bk),
         ],
         out_specs=[
-            g.q_spec(bq),
+            g.o_spec(bq),
             # row stats as narrow-lane blocks (see the kernel)
             g.stat_spec(bq),
         ],
@@ -746,7 +781,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                       dq_ref, dk_ref, dv_ref, *scratch,
                       scale, causal, bq, bk, hpb, nq, G, Hg, window=None):
     i = pl.program_id(1)
-    D = q_ref.shape[-1] // (Hg * hpb)
+    D, Dv = q_ref.shape[-1] // (Hg * hpb), v_ref.shape[-1] // (Hg * hpb)
 
     if nq > 1:
         # several q blocks (one pair a step): dk, dv accumulate in scratch
@@ -761,12 +796,14 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         lse = lse_ref[g, 0]                                 # [bq, heads]
         for hb in range(Hg):
             cols = pl.ds(hb * hpb * D, hpb * D)
-            qb, kb, vb = q_ref[g, :, cols], k_ref[g, :, cols], v_ref[g, :, cols]
-            ob, dob = o_ref[g, :, cols], do_ref[g, :, cols]
+            vcols = pl.ds(hb * hpb * Dv, hpb * Dv)
+            qb, kb, vb = q_ref[g, :, cols], k_ref[g, :, cols], v_ref[g, :, vcols]
+            ob, dob = o_ref[g, :, vcols], do_ref[g, :, vcols]
             dq_cols, dk_cols, dv_cols = [], [], []
             for hh in range(hpb):
-                cs = slice(hh * D, (hh + 1) * D)
-                q, k, v, do = qb[:, cs], kb[:, cs], vb[:, cs], dob[:, cs]
+                cs, vs = slice(hh * D, (hh + 1) * D), \
+                    slice(hh * Dv, (hh + 1) * Dv)
+                q, k, v, do = qb[:, cs], kb[:, cs], vb[:, vs], dob[:, vs]
                 s = _scores(q, k, scale, causal, i * bq, 0, window)
                 h = hb * hpb + hh
                 p = jnp.exp(s - lse[:, h:h + 1])            # [bq, bk] — the ONE exp
@@ -774,7 +811,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                     p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32))
                 delta = jnp.sum(do.astype(jnp.float32)
-                                * ob[:, cs].astype(jnp.float32),
+                                * ob[:, vs].astype(jnp.float32),
                                 axis=1)[:, None]            # [bq, 1]
                 dov = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                           preferred_element_type=jnp.float32)
@@ -791,7 +828,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                 dv_scr[:] += _cat(dv_cols)
             else:
                 dk_ref[g, :, cols] = _cat(dk_cols).astype(dk_ref.dtype)
-                dv_ref[g, :, cols] = _cat(dv_cols).astype(dv_ref.dtype)
+                dv_ref[g, :, vcols] = _cat(dv_cols).astype(dv_ref.dtype)
 
     _rows(G, row)
 
@@ -803,30 +840,32 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 
 def _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=None,
-               window=None):
+               window=None, Dv=None):
     q, k, v, o, lse = res
-    g = _Geom(q, k, H, bq, bk, window=window)
+    g = _Geom(q, k, H, bq, bk, window=window, Dv=Dv)
     nq = g.S // bq
     # 2-arg index maps (grid has no kv axis): kv lives at block 0
     qm, km, sm = g.qmap(), g.kmap(), g.smap()
     qs = g.q_spec(bq, lambda b, i: qm(b, i, 0))
     ks = g.kv_spec(bk, lambda b, i: km(b, i, 0))
+    os = g.o_spec(bq, lambda b, i: qm(b, i, 0))
+    vs = g.v_spec(bk, lambda b, i: km(b, i, 0))
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, hpb=g.hpb, nq=nq, G=g.G, Hg=g.Hg,
                           window=window),
         grid=(g.grid_b, nq),
-        in_specs=[qs, ks, ks, qs, qs,
+        in_specs=[qs, ks, vs, os, os,
                   g.stat_spec(bq, lambda b, i: sm(b, i, 0))],
-        out_specs=[qs, ks, ks],
+        out_specs=[qs, ks, vs],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct(g.dkv_shape, k.dtype),
-            jax.ShapeDtypeStruct(g.dkv_shape, v.dtype),
+            jax.ShapeDtypeStruct(g.dk_shape, k.dtype),
+            jax.ShapeDtypeStruct(g.dv_shape, v.dtype),
         ],
         scratch_shapes=[] if nq == 1 else [
             pltpu.VMEM((bk, g.qw), jnp.float32),
-            pltpu.VMEM((bk, g.qw), jnp.float32),
+            pltpu.VMEM((bk, g.vw), jnp.float32),
         ],
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
@@ -857,7 +896,7 @@ def _bwd_dq_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
         if stk:
             _stack_sweep(stk, q_ref, do_ref, lse_ref, delta_ref, hpb, D, half)
 
-    def tile(q, k, v, do, lse, delta, cs, wrap=None):
+    def tile(q, k, v, do, lse, delta, cs, vs, wrap=None):
         s = _scores(q, k, scale, causal, q_of[t] * bq, kv_of[t] * bk,
                     geom.window, wrap)
         p = jnp.exp(s - lse())                         # [rows, bk]
@@ -894,7 +933,7 @@ def _bwd_dkv_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def tile(q, k, v, do, lse, delta, cs, wrap=None):
+    def tile(q, k, v, do, lse, delta, cs, vs, wrap=None):
         s = _scores(q, k, scale, causal, q_of[t] * bq, kv_of[t] * bk,
                     geom.window, wrap)
         p = jnp.exp(s - lse())                         # [rows, bk]
@@ -902,7 +941,7 @@ def _bwd_dkv_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
         dv = jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dv_scr[:, cs] += dv
+        dv_scr[:, vs] += dv
         dov = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
         ds = p * (dov - delta()) * scale
@@ -959,8 +998,12 @@ def _bwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
     @pl.when(t == 0)
     def _open():
         def zero(at):
-            dk_acc[at, :] = dv_acc[at, :] = jnp.zeros(
-                (bk, dk_acc.shape[1]), jnp.float32)
+            if dv_acc.shape == dk_acc.shape:
+                dk_acc[at, :] = dv_acc[at, :] = jnp.zeros(
+                    (bk, dk_acc.shape[1]), jnp.float32)
+                return
+            for acc in (dk_acc, dv_acc):
+                acc[at, :] = jnp.zeros((bk, acc.shape[1]), jnp.float32)
         kv_blocks_of_the_sequence(zero)
 
     @pl.when((flags[t] & FIRST) != 0)
@@ -969,8 +1012,9 @@ def _bwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
         if stk:
             _stack_sweep(stk, q_ref, do_ref, lse_ref, delta_ref, hpb, D, half)
 
-    def tile(q, k, v, do, lse, delta, cs, wrap=None):
-        """The rows of q and do against columns ``cs`` of this kv block."""
+    def tile(q, k, v, do, lse, delta, cs, vs, wrap=None):
+        """The rows of q and do against columns ``cs`` of this kv block's
+        keys and ``vs`` of its values."""
         s = _scores(q, k, scale, causal, q_of[t] * bq, kv_of[t] * bk,
                     geom.window, wrap)
         p = jnp.exp(s - lse())                     # [rows, bk] - the ONE exp
@@ -978,7 +1022,7 @@ def _bwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
         dv = jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dv_acc[rows, cs] += dv
+        dv_acc[rows, vs] += dv
         dov = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
         ds = (p * (dov - delta()) * scale).astype(q.dtype)     # [rows, bk]
@@ -1017,29 +1061,29 @@ def _delta(o, do, g, packed, interpret):
     call counts in ``monitor.kernels.flash_delta_calls`` (``fused`` 1 for
     the kernel)."""
     fused = packed and o.dtype == do.dtype and flash_delta.supported(
-        o.shape, g.D, o.dtype.itemsize)
-    _count_call("flash_delta", fused=int(fused), head_dim=g.D)
+        o.shape, g.Dv, o.dtype.itemsize)
+    _count_call("flash_delta", fused=int(fused), head_dim=g.Dv)
     if fused:
-        return flash_delta.flash_delta(o, do, head_dim=g.D,
+        return flash_delta.flash_delta(o, do, head_dim=g.Dv,
                                        interpret=interpret)
     if packed:
-        return flash_delta.flash_delta_reference(o, do, g.D)
+        return flash_delta.flash_delta_reference(o, do, g.Dv)
     return jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                    axis=-1, keepdims=True).reshape(g.stat_shape)
 
 
 def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
-         window=None):
+         window=None, Dv=None):
     q, k, v, o, lse = res
-    g = _Geom(q, k, H, bq, bk, Hkv, window)
+    g = _Geom(q, k, H, bq, bk, Hkv, window, Dv)
     if g.one_block and g.group == 1:
         return _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=H,
-                          window=window)
+                          window=window, Dv=Dv)
     delta = _delta(o, do, g, H is not None, interpret)
 
     dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
-    dkv_shapes = [jax.ShapeDtypeStruct(g.dkv_shape, k.dtype),
-                  jax.ShapeDtypeStruct(g.dkv_shape, v.dtype)]
+    dkv_shapes = [jax.ShapeDtypeStruct(g.dk_shape, k.dtype),
+                  jax.ShapeDtypeStruct(g.dv_shape, v.dtype)]
 
     stack = g.halves * bq       # rows of a step's tile, and what a q sweep
     stacked = [                 # keeps of its q block where it is a stack
@@ -1052,39 +1096,43 @@ def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
               walks_group=False, kv_major=False, **params):
         qm, km, sm = g.sweep_maps(walks_group)
         qs, ks = g.q_spec(bq, qm), g.kv_spec(bk, km)
+        os, vs = g.o_spec(bq, qm), g.v_spec(bk, km)
         return _sweep_call(
             functools.partial(kernel, scale=scale, causal=causal, bq=bq,
                               bk=bk, hpb=g.hpb, geom=g),
             g, step_table(g.S, g.Sk, bq, bk, causal, window,
                           g.group if walks_group else 1, kv_major),
             (q, k, v, do, lse, delta),
-            [qs, ks, ks, qs, g.stat_spec(bq, sm), g.stat_spec(bq, sm)],
-            out_specs(qs, ks), out_shape, scratch_shapes, walks_group,
+            [qs, ks, vs, os, g.stat_spec(bq, sm), g.stat_spec(bq, sm)],
+            out_specs(qs, ks, vs), out_shape, scratch_shapes, walks_group,
             interpret, name, **params)
 
     if g.bwd_sweeps == 1:
         # dk and dv of the whole sequence: one block a grid row, so one
         # buffer (it leaves VMEM once, and the next row's has nothing to
         # overlap with but that)
-        whole = pl.BlockSpec((1, g.Sk, g.qw),
-                             lambda r, kh, t, *table: (r, 0, kh),
-                             pipeline_mode=pl.Buffered(1))
+        def whole(lanes):
+            return pl.BlockSpec((1, g.Sk, lanes),
+                                lambda r, kh, t, *table: (r, 0, kh),
+                                pipeline_mode=pl.Buffered(1))
+
         return sweep(
             _bwd_sweep_kernel, "bwd_fused",
-            lambda qs, ks: [qs, whole, whole], [dq_shape] + dkv_shapes,
+            lambda qs, ks, vs: [qs, whole(g.qw), whole(g.vw)],
+            [dq_shape] + dkv_shapes,
             [pltpu.VMEM((stack, g.qw), jnp.float32),
              pltpu.VMEM((g.Sk, g.qw), jnp.float32),
-             pltpu.VMEM((g.Sk, g.qw), jnp.float32)] + stacked,
+             pltpu.VMEM((g.Sk, g.vw), jnp.float32)] + stacked,
             walks_group=True,
-            vmem_limit_bytes=fused_sweep_vmem_bytes(g.Sk, g.qw,
-                                                    k.dtype.itemsize))
-    dq = sweep(_bwd_dq_kernel, "bwd_dq", lambda qs, ks: qs, dq_shape,
+            vmem_limit_bytes=fused_sweep_vmem_bytes(
+                g.Sk, g.qw, k.dtype.itemsize, g.vw))
+    dq = sweep(_bwd_dq_kernel, "bwd_dq", lambda qs, ks, vs: qs, dq_shape,
                [pltpu.VMEM((stack, g.qw), jnp.float32)] + stacked)
     # the dk/dv sweep's rows run over the key/value heads
-    dk, dv = sweep(_bwd_dkv_kernel, "bwd_dkv", lambda qs, ks: [ks, ks],
+    dk, dv = sweep(_bwd_dkv_kernel, "bwd_dkv", lambda qs, ks, vs: [ks, vs],
                    dkv_shapes,
                    [pltpu.VMEM((bk, g.qw), jnp.float32),
-                    pltpu.VMEM((bk, g.qw), jnp.float32)],
+                    pltpu.VMEM((bk, g.vw), jnp.float32)],
                    walks_group=True, kv_major=True)
     return dq, dk, dv
 
@@ -1113,7 +1161,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_packed(q, k, v, heads, scale, causal, bq, bk, interpret):
-    """``heads`` = (H, Hkv, window)."""
+    """``heads`` = (H, Hkv, window[, Dv])."""
     o, _ = _fwd(q, k, v, scale, causal, bq, bk, interpret, *heads)
     return o
 
@@ -1157,7 +1205,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
 
 def flash_attention_packed(q, k, v, n_heads, causal=False, scale=None,
                            block_q=256, block_k=256, interpret=None,
-                           n_kv_heads=None, window=None):
+                           n_kv_heads=None, window=None, v_head_dim=None):
     """Packed-layout flash attention: q, k, v are [B, S, H*D] exactly as the
     qkv projections produce them; returns [B, S, H*D] ready for the output
     projection.  The per-head D-wide column slices are addressed by the
@@ -1167,20 +1215,28 @@ def flash_attention_packed(q, k, v, n_heads, causal=False, scale=None,
 
     ``n_kv_heads`` < ``n_heads``: grouped queries, k and v [B, S, Hkv*D].
     ``window``: query i sees keys j with i - window < j <= i (causal only;
-    a window of S or more is the causal mask and changes nothing)."""
+    a window of S or more is the causal mask and changes nothing).
+    ``v_head_dim`` other than D: v is [B, S, H*v_head_dim] and so is the
+    result; q's and k's heads and v's are whole lane blocks, each product
+    with v (``P V``, ``dP``, ``dV``) runs at the values' width, and no
+    grouping or window rides with it.  Give ``scale`` where D holds lanes
+    that are not the head's (zeros behind a head of 192 in 256 lanes)."""
     B, S, E = q.shape
     H = n_heads
     assert E % H == 0, (E, H)
     D = E // H
     Hkv = n_kv_heads or H
-    if not packed_layout_supported(H, D, Hkv):
+    Dv = D if v_head_dim is None else int(v_head_dim)
+    if not packed_layout_supported(H, D, Hkv, Dv):
         raise ValueError(
             "packed layout cannot tile H=%d (kv %d) heads of D=%d (needs "
             "D*hpb a multiple of %d lanes with hpb dividing H, and with "
             "grouped queries hpb dividing the group and the key/value "
             "heads); use flash_attention on [B, S, H, D]"
             % (H, Hkv, D, LANES))
-    assert k.shape[-1] == v.shape[-1] == Hkv * D, (k.shape, Hkv, D)
+    assert k.shape[-1] == Hkv * D and v.shape[-1] == Hkv * Dv, (
+        k.shape, v.shape, Hkv, D, Dv)
+    assert Dv == D or window is None, "a value width of its own: no window"
     if window is not None:
         assert causal and window >= 1 and k.shape[1] == S, (causal, window)
         if window >= S:
@@ -1193,5 +1249,6 @@ def flash_attention_packed(q, k, v, n_heads, causal=False, scale=None,
     bq = min(block_q, S)
     bk = min(block_k, Sk)
     assert S % bq == 0 and Sk % bk == 0, (S, Sk, bq, bk)
-    return _flash_packed(q, k, v, (H, Hkv, window), float(scale), bool(causal),
-                         bq, bk, bool(interpret))
+    heads = (H, Hkv, window) + ((Dv,) if Dv != D else ())
+    return _flash_packed(q, k, v, heads, float(scale), bool(causal), bq, bk,
+                         bool(interpret))

@@ -8,7 +8,8 @@ Two styles:
   designed for the parallel/ engine and the performance benchmarks; and the
   causal decoders, each a ``TransformerConfig`` and a label over
   ``parallel/decoder.py``'s one trainer (olmoe.py, smallthinker.py, lfm2.py,
-  brumby.py, mistral4.py, trinity.py, jamba.py, nemotron_h.py, ouro.py).
+  brumby.py, mistral4.py, trinity.py, jamba.py, nemotron_h.py, ouro.py,
+  kimi_linear.py).
 """
 
 from . import bert  # noqa: F401

@@ -68,6 +68,11 @@ MAMBA, SELECTIVE_SCAN = "mamba", "selective_scan"
 # filter, the step sizes, the gated group norm) and, inside it, the chunked
 # state-space dual form's kernels
 MAMBA2, SSD_SCAN = "mamba2", "ssd_scan"
+# Kimi Delta Attention where attention stands (the three projections and
+# their filters, the L2 norms, the decay's and the output's gates, the
+# head-wise norm, the output projection) and, inside it, the delta rule
+# itself: the chunks' own parts, the solve and the scan that carries the state
+KDA, KDA_CHUNK = "kda", "kda_chunk"
 # the scan over the stacked layers itself: its slices of each layer's leaves,
 # the activations it keeps for the backward pass and the gradients it stacks
 # (a layer's own work carries the layer's scopes, which lie further in)
@@ -82,7 +87,7 @@ VOCABULARY = (EMBED, ATTENTION, MLP, LAYER_NORM, LM_HEAD, CONV, BN, POOL, FC,
               LOSS, GRAD_SYNC, OPTIMIZER, MOE, ROUTER, SHORT_CONV, RETENTION,
               LATENT_ATTENTION, SHARED_EXPERT, LAYER_SCAN, ATTN_GATE,
               POST_NORM, MAMBA, SELECTIVE_SCAN, MAMBA2, SSD_SCAN, LOOP_SCAN,
-              EXIT_GATE)
+              EXIT_GATE, KDA, KDA_CHUNK)
 PHASES = ("forward", "backward", "recompute", GRAD_SYNC, OPTIMIZER)
 
 # `%fusion.12 = bf16[..] fusion(%p.1, %copy-done.2), ..., metadata={op_name="jit(multi)/..." ...}`:

@@ -3,7 +3,7 @@
 ``logits_at``, a call's observations and the builder, ONE of each for every
 decoder of this block.  A model (``models/olmoe.py``, ``smallthinker.py``,
 ``lfm2.py``, ``brumby.py``, ``mistral4.py``, ``trinity.py``, ``jamba.py``,
-``nemotron_h.py``, ``ouro.py``)
+``nemotron_h.py``, ``ouro.py``, ``kimi_linear.py``)
 is a ``TransformerConfig`` and a label; what its trainer computes and what
 it observes follow from the configuration, never from which model it is.
 
@@ -31,6 +31,7 @@ from .mesh import DP, MeshSpec, local_shard_map
 from .train import (StepTrainer, TrainState, make_train_step, shard_pytree,
                     state_specs)
 from .transformer import (
+    KDA,
     MAMBA,
     MAMBA2,
     RETENTION,
@@ -42,6 +43,7 @@ from .transformer import (
     grad_sync_axes,
     head_logits,
     init_transformer_params,
+    kda_log_decay,
     mamba2_operands,
     mamba_operands,
     retention_log_decay,
@@ -126,6 +128,23 @@ def _first_layer_input(params, ids, cfg):
                         cfg.norm_eps)
 
 
+def _first_of_kind(params, ids, cfg, kind):
+    """The leaves of the first position of ``kind`` (a leading layer's, else
+    the first period's) and the embedding's rows normed by that position's
+    own first norm: what its operator would read were it the first layer."""
+    if kind in cfg.prefix_kinds:
+        pl = params["prefix_layers"]["l%d" % cfg.prefix_kinds.index(kind)]
+    elif cfg.run_scan:      # [periods, run length, ...]
+        at = [k for _, k, _ in cfg.runs].index(kind)
+        pl = jax.tree.map(lambda a: a[0, 0],
+                          params["params_layers"]["r%d" % at])
+    else:
+        pl = jax.tree.map(lambda a: a[0], params["params_layers"][
+            "p%d" % cfg.layer_kinds.index(kind)])
+    return pl, rms_norm(embed(params, ids, cfg), pl["ln1_scale"],
+                        cfg.norm_eps)
+
+
 def probe(params, ids, cfg):
     """``{name: scalar, or [T] of the exits}`` of ``ids`` [b, S]: the
     readings ``DecoderTrainer._observe`` takes of one batch, the entries the
@@ -153,6 +172,10 @@ def probe(params, ids, cfg):
       reaches), of the period's first such position as the EMBEDDING hands
       the batch over (the stream as it enters the stack, not as that layer
       finds it: at seeded weights the step sizes are their bias's);
+    - a layer kind is KDA: ``kda_decay_mean``, the mean ``e^g`` over tokens,
+      heads and channels, and ``kda_decay_min``, the smallest (how far the
+      carry reaches), of the FIRST such position (a leading layer's, else the
+      period's) as the embedding hands the batch over;
     - ``attn_gate``: ``attn_gate_mean``, the mean of the output gate's
       sigmoid over tokens, heads and columns in the first layer: a gate
       stuck at 0 or 1 is a dead branch;
@@ -196,6 +219,11 @@ def probe(params, ids, cfg):
         out["mamba2_dt_mean"] = jnp.mean(dt)
         out["mamba2_decay_min"] = jnp.exp(-jnp.max(
             jnp.max(dt, axis=(0, 1)) * jnp.exp(pl2["a_log"])))
+    if KDA in cfg.prefix_kinds + cfg.layer_kinds:
+        decay = jnp.exp(kda_log_decay(*_first_of_kind(params, ids, cfg, KDA),
+                                      cfg))
+        out["kda_decay_mean"] = jnp.mean(decay)
+        out["kda_decay_min"] = jnp.min(decay)
     if cfg.attn_gate:
         out["attn_gate_mean"] = jnp.mean(jax.nn.sigmoid(
             (h @ pl["wz"]).astype(jnp.float32)))

@@ -262,9 +262,10 @@ def transformer_rules(cfg):
         # so does a stack whose runs are stacked [periods, run length, ...]
         # (``run_scan``), and the Mamba mixer's leaves wherever they stand
         (r"^params_layers/r\d+/", P()),
-        # (Mamba-1's or Mamba-2's)
+        # (Mamba-1's or Mamba-2's), and the KDA mixer's own
         (r"/(w_in|conv_b|w_x|dt_norm|b_norm|c_norm|w_dt|b_dt|a_log|d_skip"
          r"|gate_norm|w_out)$", P()),
+        (r"/(conv_[qkv]|w_fa|w_fb|dt_bias|w_beta|w_ga|w_gb|o_norm)$", P()),
         (r"/(conv_in|conv_w|conv_out|w_gate_up|w_down|ws_gate_up|ws_up"
          r"|ws_down)$", L(None, None)),
         # latent attention runs at tp == 1 (TransformerConfig)

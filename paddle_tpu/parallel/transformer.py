@@ -37,9 +37,11 @@ from ..monitor.recompile import compile_ledger
 from .ring_attention import ring_attention
 
 __all__ = ["TransformerConfig", "CONV", "RETENTION", "MAMBA", "MAMBA2", "FFN",
+           "KDA",
            "init_transformer_params", "transformer_param_specs",
            "grad_sync_axes", "embed", "transformer_layer", "run_layers",
            "mamba_mixer", "mamba_operands", "mamba2_mixer", "mamba2_operands",
+           "kda_mixer", "kda_log_decay",
            "rms_norm", "rope", "rope_pairs", "yarn_blend_range",
            "yarn_frequencies",
            "yarn_softmax_scale", "yarn_rotary_factor", "final_logits_loss",
@@ -61,8 +63,14 @@ MAMBA = "mamba"
 MAMBA2 = "mamba2"
 # a layer kind of a ``single_branch`` stack: the feed-forward part alone
 FFN = "ffn"
+# a layer kind: Kimi Delta Attention where attention stands: q, k and v each
+# through a causal depthwise filter, a log-decay for every CHANNEL of the key
+# and a write strength a head off low-rank gates, a [key, value] state a head
+# corrected by the delta rule and carried along the sequence, a head-wise
+# RMS norm and THEN a sigmoid gate on the output
+KDA = "kda"
 # kinds whose position owns other leaves
-_OWN_LEAVES = (CONV, RETENTION, MAMBA, MAMBA2, FFN)
+_OWN_LEAVES = (CONV, RETENTION, MAMBA, MAMBA2, FFN, KDA)
 
 
 def _kinds(pattern):
@@ -168,6 +176,13 @@ class TransformerConfig:
     # of heads that share B and C
     ssm_heads: int = 0
     ssm_groups: int = 0
+    # KDA (with d_conv, the taps of its three filters): the heads and a
+    # head's width (keys and values alike), the rank the decay's and the
+    # output gate's projections go through, and the tokens of a chunk
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_gate_rank: int = 0
+    kda_chunk: int = 64
     # every layer is ONE pre-norm residual branch, ``x + branch(norm(x))``:
     # a mixer position (attention, MAMBA2, ...) has no FFN and no second
     # norm, and the feed-forward part is a position of its own, FFN
@@ -177,15 +192,19 @@ class TransformerConfig:
     # inner scan of the period's body (``run_layers``), where each position
     # would else be a tree ``p<i>`` and a copy of its layer in the body
     run_scan: bool = False
-    # Latent attention (kv_lora_rank > 0; every layer, in place of wq / wk /
-    # wv): queries off a latent of q_lora_rank, RMS-normed; keys and values
-    # off ONE latent of kv_lora_rank, RMS-normed, beside which the same
-    # projection gives qk_rope_dim columns that are rotated and stand, the
-    # same for every head, as the last columns of each head's key.  A head
-    # is [qk_nope_dim | qk_rope_dim] = head_dim wide, its first part without
-    # positions; rotary pairs are ADJACENT columns (``rope_pairs``).  A
-    # value is v_head_dim wide, head_dim today (the flash kernels have one
-    # width)
+    # Latent attention (kv_lora_rank > 0; every ATTENTION position, in place
+    # of wq / wk / wv; full attention, whichever kinds stand beside it in a
+    # pattern): queries off a latent of q_lora_rank, RMS-normed (0: ONE
+    # matrix ``wq``, no latent and no norm); keys and values off ONE latent
+    # of kv_lora_rank, RMS-normed, beside which the same projection gives
+    # qk_rope_dim columns that stand, the same for every head, as the last
+    # columns of each head's key, rotated where ``positions`` is "rotary"
+    # and as they are where it is None.  A head is [qk_nope_dim |
+    # qk_rope_dim] = head_dim wide, its first part without positions; rotary
+    # pairs are ADJACENT columns (``rope_pairs``).  A value is v_head_dim
+    # wide, which need not be head_dim (192 / 128: the packed flash kernels'
+    # value width).  A latent position reads these five, ``n_heads`` and
+    # ``head_width``; ``n_kv_heads`` and ``qk_norm`` are unused by it
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0
@@ -299,14 +318,31 @@ class TransformerConfig:
         assert not self.single_branch or not (
             self.prefix_pattern or self.post_norm or self.run_scan)
         assert self.per_position or not self.run_scan
+        if KDA in self.layer_pattern + self.prefix_pattern:
+            assert self.kda_heads and self.kda_head_dim and self.d_conv \
+                and self.kda_gate_rank and self.kda_chunk > 0
         if self.latent:
-            assert self.positions == "rotary" and self.tp == 1 \
-                and not (self.bias or self.qk_norm or self.layer_pattern) \
-                and self.kv_heads == self.n_heads and self.q_lora_rank \
-                and self.qk_rope_dim % 2 == 0 and self.head_dim \
-                == self.qk_nope_dim + self.qk_rope_dim == self.v_head_dim
-            assert self.rope_original_max or not (
-                self.rope_factor or self.q_scale_beta)
+            # every head its own key/value head, each [nope | shared] wide
+            assert self.tp == 1 and not (self.bias or self.qk_norm) \
+                and self.kv_heads == self.n_heads and self.v_head_dim \
+                and self.head_dim == self.qk_nope_dim + self.qk_rope_dim
+            attention = [k for k in self.layer_pattern + self.prefix_pattern
+                         if k not in _OWN_LEAVES]
+            if self.positions == "rotary":
+                # the row kernel's tables and ``rope_pairs`` rotate whole
+                # pairs, and assemble a key in ``head_dim`` lanes beside a
+                # value as wide; every attention position of a pattern
+                # rotates
+                assert self.qk_rope_dim % 2 == 0 \
+                    and self.v_head_dim == self.head_dim \
+                    and all(k == (0, True) for k in attention)
+                assert self.rope_original_max or not (
+                    self.rope_factor or self.q_scale_beta)
+            else:
+                # no rotation at all: nothing that shapes one
+                assert self.positions is None and not (
+                    self.rope_factor or self.q_scale_beta) \
+                    and all(k == (0, False) for k in attention)
         if self.shared_ffn_hidden:
             assert self.n_experts and not self.bias
         if self.attn_gate:
@@ -345,8 +381,8 @@ class TransformerConfig:
 
     @property
     def layer_kinds(self):
-        """(window or None, rotary), or CONV, RETENTION, MAMBA, MAMBA2 or
-        FFN, of each layer of one period."""
+        """(window or None, rotary), or CONV, RETENTION, MAMBA, MAMBA2, KDA
+        or FFN, of each layer of one period."""
         if not self.layer_pattern:
             return ((None, self.positions == "rotary"),)
         return _kinds(self.layer_pattern)
@@ -517,6 +553,7 @@ def _stacked_layers(ks, cfg):
     parallelism)."""
     E, F, L = cfg.hidden, cfg.ffn_hidden, cfg.n_layers
     Q, KV = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    O = _heads_out_width(cfg)
     dt = cfg.jdtype
 
     def stack(fold, fan_in, shape, dtype=dt):
@@ -529,7 +566,7 @@ def _stacked_layers(ks, cfg):
         "wq": stack(0, E, (E, Q)),
         "wk": stack(1, E, (E, KV)),
         "wv": stack(2, E, (E, KV)),
-        "wo": stack(3, Q, (Q, E)),
+        "wo": stack(3, O, (O, E)),
         "ln2_scale": jnp.ones((L, E), jnp.float32),
     }
     if cfg.norm == "layer":
@@ -540,19 +577,9 @@ def _stacked_layers(ks, cfg):
         layer["bo"] = jnp.zeros((L, E), dt)
     layer.update(_qk_norm_leaves(cfg, L))
     if cfg.latent:
-        for name in ("wq", "wk", "wv"):
+        for name in ("wq", "wk", "wv")[not cfg.q_lora_rank:]:
             del layer[name]
-        rq, rkv, H = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.n_heads
-        layer.update(
-            wq_a=stack(9, E, (E, rq)),
-            q_a_norm=jnp.ones((L, rq), jnp.float32),
-            wq_b=stack(10, rq, (rq, Q)),
-            # the latent's columns, then the shared rotary key's
-            wkv_a=stack(11, E, (E, rkv + cfg.qk_rope_dim)),
-            kv_a_norm=jnp.ones((L, rkv), jnp.float32),
-            # head by head [k_nope | v]
-            wkv_b=stack(12, rkv, (rkv, H * (cfg.qk_nope_dim
-                                            + cfg.v_head_dim))))
+        layer.update(_latent_leaves(stack, cfg, L))
     layer.update(_branch_leaves(stack, cfg, L, 15, attention=True))
     if cfg.n_experts:
         layer.update(_ffn_leaves(stack, cfg, 6, dense=False))
@@ -569,6 +596,30 @@ def _stacked_layers(ks, cfg):
             lambda x: x.reshape((cfg.pp, cfg.layers_per_stage) + x.shape[1:]), layer
         )
     return layer
+
+
+def _heads_out_width(cfg):
+    """Rows of attention's ``wo``: the heads' values side by side."""
+    return cfg.n_heads * (cfg.v_head_dim if cfg.latent else cfg.head_dim)
+
+
+def _latent_leaves(stack, cfg, n):
+    """What the latent form holds where the others have ``wk`` / ``wv``
+    (and, with a query latent, ``wq``), of ``n`` stacked layers
+    (``stack(fold, fan_in, shape[, dtype])`` seeds a stacked leaf)."""
+    E, rq, rkv = cfg.hidden, cfg.q_lora_rank, cfg.kv_lora_rank
+    leaves = dict(
+        # the latent's columns, then the shared key's
+        wkv_a=stack(11, E, (E, rkv + cfg.qk_rope_dim)),
+        kv_a_norm=jnp.ones((n, rkv), jnp.float32),
+        # head by head [k_nope | v]
+        wkv_b=stack(12, rkv, (rkv, cfg.n_heads * (cfg.qk_nope_dim
+                                                  + cfg.v_head_dim))))
+    if rq:
+        leaves.update(wq_a=stack(9, E, (E, rq)),
+                      q_a_norm=jnp.ones((n, rq), jnp.float32),
+                      wq_b=stack(10, rq, (rq, cfg.n_heads * cfg.head_dim)))
+    return leaves
 
 
 def _qk_norm_leaves(cfg, n):
@@ -636,7 +687,9 @@ def _position_leaves(key, cfg, kind, n, dense):
     position t - taps + 1 + j) and ``conv_out`` [E, E], for RETENTION
     attention's and the gate projection ``wg`` [E, kv_heads] float32 (one
     log-decay a key/value head and token), for MAMBA ``_mamba_leaves``', for
-    MAMBA2 ``_mamba2_leaves``'; what ``_branch_leaves`` adds; then the FFN's
+    MAMBA2 ``_mamba2_leaves``', for KDA ``_kda_leaves``'; an attention
+    position of the latent form ``_latent_leaves``' where the others have
+    ``wk`` / ``wv``; what ``_branch_leaves`` adds; then the FFN's
     (``_ffn_leaves``), dense or the MoE's.  In a ``single_branch`` stack a
     position owns its ONE branch's leaves: the mixer's behind ``ln1_scale``,
     or (FFN) the feed-forward part's behind ``ln2_scale``."""
@@ -660,15 +713,22 @@ def _position_leaves(key, cfg, kind, n, dense):
         leaves.update(_mamba_leaves(stack, keys, cfg))
     elif kind == MAMBA2:
         leaves.update(_mamba2_leaves(stack, keys, cfg))
+    elif kind == KDA:
+        leaves.update(_kda_leaves(stack, keys, cfg))
     elif kind != FFN:
+        O = _heads_out_width(cfg)
         leaves.update(wq=stack(1, E, (E, Q)), wk=stack(2, E, (E, KV)),
-                      wv=stack(3, E, (E, KV)), wo=stack(4, Q, (Q, E)))
+                      wv=stack(3, E, (E, KV)), wo=stack(4, O, (O, E)))
         leaves.update(_qk_norm_leaves(cfg, n))
         if kind == RETENTION:
             leaves["wg"] = stack(10, E, (E, cfg.kv_heads), jnp.float32)
+        elif cfg.latent:
+            for name in ("wq", "wk", "wv")[not cfg.q_lora_rank:]:
+                del leaves[name]
+            leaves.update(_latent_leaves(stack, cfg, n))
     leaves.update(_branch_leaves(
         stack, cfg, n, 11,
-        attention=kind not in (CONV, MAMBA, MAMBA2, FFN)))
+        attention=kind not in (CONV, MAMBA, MAMBA2, FFN, KDA)))
     if kind == FFN or not cfg.single_branch:
         leaves["ln2_scale"] = jnp.ones((n, E), jnp.float32)
         leaves.update(_ffn_leaves(stack, cfg, 5 if dense else 7, dense))
@@ -750,6 +810,46 @@ def _mamba2_leaves(stack, keys, cfg):
         d_skip=jnp.ones((n, nh), f32),
         gate_norm=jnp.ones((n, d), f32),
         w_out=stack(34, d, (d, E)))
+
+
+def _kda_leaves(stack, keys, cfg):
+    """The KDA mixer's leaves of ``len(keys)`` stacked layers, P = heads x
+    head width: ``wq`` / ``wk`` / ``wv`` [E, P] and their filters ``conv_q``
+    / ``conv_k`` / ``conv_v`` [taps, P] (tap j meets position t - taps + 1 +
+    j; no bias); the decay's gate ``w_fa`` [E, R] / ``w_fb`` [R, P] with
+    ``dt_bias`` [P] and ``a_log`` [heads]; ``w_beta`` [E, heads]; the output
+    gate ``w_ga`` [E, R] / ``w_gb`` [R, P]; ``o_norm`` [head width], ONE
+    scale for every head; ``wo`` [P, E].  ``dt_bias``, ``a_log`` and
+    ``o_norm`` float32.
+
+    Matrices and filters at their fan-in's scale; ``a_log`` the log of a
+    rate drawn uniform in [1, 16] a head and ``dt_bias`` the inverse softplus
+    of a step drawn log-uniform in [1e-3, 1e-1] a channel (the family's
+    public constructor, as Mamba-2's): a channel's decay ``exp(-rate step)``
+    then runs from 0.999 to 0.2 a token, and in most channels a state
+    outlives many chunks."""
+    E, nh, R = cfg.hidden, cfg.kda_heads, cfg.kda_gate_rank
+    Pw = nh * cfg.kda_head_dim
+    n, f32 = len(keys), jnp.float32
+
+    def drawn(fold, shape, low, high):
+        return jax.vmap(lambda k: jax.random.uniform(
+            jax.random.fold_in(k, fold), shape, f32, low, high))(keys)
+
+    step = jnp.exp(drawn(52, (Pw,), math.log(1e-3), math.log(1e-1)))
+    return dict(
+        wq=stack(40, E, (E, Pw)), wk=stack(41, E, (E, Pw)),
+        wv=stack(42, E, (E, Pw)),
+        conv_q=stack(43, cfg.d_conv, (cfg.d_conv, Pw)),
+        conv_k=stack(44, cfg.d_conv, (cfg.d_conv, Pw)),
+        conv_v=stack(45, cfg.d_conv, (cfg.d_conv, Pw)),
+        w_fa=stack(46, E, (E, R)), w_fb=stack(47, R, (R, Pw)),
+        dt_bias=step + jnp.log(-jnp.expm1(-step)),      # softplus^-1(step)
+        a_log=jnp.log(drawn(53, (nh,), 1.0, 16.0)),
+        w_beta=stack(48, E, (E, nh)),
+        w_ga=stack(49, E, (E, R)), w_gb=stack(50, R, (R, Pw)),
+        o_norm=jnp.ones((n, cfg.kda_head_dim), f32),
+        wo=stack(51, Pw, (Pw, E)))
 
 
 def _router_bias(key, cfg):
@@ -1052,16 +1152,18 @@ def _local_attention_dispatch(q, k, v, cfg):
     return ring_attention(q, k, v, axis=None, causal=cfg.causal)
 
 
-def _packed_flash_blocks(cfg, hl, S, kvl=None):
+def _packed_flash_blocks(cfg, hl, S, kvl=None, widths=None):
     """(block_q, block_k) where attention over ``hl`` local heads (on
     ``kvl`` key/value heads) of S positions goes to the packed flash kernel,
-    else None."""
+    else None.  ``widths``: the lanes a head of q and k and a head of v
+    stand in, where they are not ``cfg.head_dim`` both."""
     from ..kernels.flash_attention import packed_layout_supported
 
     bq = min(cfg.flash_block_q, S)
     bk = min(cfg.flash_block_k, S)
+    qk, v = widths or (cfg.head_dim, None)
     if (S % bq == 0 and S % bk == 0
-            and packed_layout_supported(hl, cfg.head_dim, kvl)):
+            and packed_layout_supported(hl, qk, kvl, v)):
         return bq, bk
     return None
 
@@ -1081,6 +1183,8 @@ def _qkv(pl, h_full, cfg, rotary, first=0):
     start from.  The latent form: ``_latent_qkv``; where its ``rope_pairs``
     lines run, a block of positions at a time where the sequence is long (no
     whole-sequence float32 q stands)."""
+    if cfg.latent and not rotary:
+        return _latent_qkv_unrotated(pl, h_full, cfg)
     if cfg.latent:
         # ``wkv_b``'s columns are taken apart ONCE a layer, not a block
         columns = _latent_columns(pl["wkv_b"], cfg)
@@ -1180,6 +1284,49 @@ def _latent_columns(wkv_b, cfg):
         wkv_b.shape[0], -1), w[..., dn:].reshape(wkv_b.shape[0], -1))
 
 
+def _latent_head_lanes(cfg):
+    """The lanes a head of q and k stands in on its way to the flash
+    kernels: ``head_dim``, or, where a value is not that wide, ``head_dim``
+    up to whole lane blocks (192 in 256, the last 64 zero: the packed
+    kernels' value mode addresses q and k, and v, by lane block)."""
+    from ..kernels.flash_attention import LANES
+
+    if cfg.v_head_dim == cfg.head_dim:
+        return cfg.head_dim
+    return -(-cfg.head_dim // LANES) * LANES
+
+
+def _latent_qkv_unrotated(pl, h, cfg):
+    """The latent form WITHOUT positions, of the whole sequence ``h`` [b, S,
+    E]: ``q = h @ wq`` (off the query latent where there is one), head i ``[q_nope_i |
+    q_s_i]``; ``[ckv | ks] = h @ wkv_a``, ``rms(ckv) @ wkv_b`` head i
+    ``[k_nope_i | v_i]``; ``k_i = [k_nope_i | ks]``, the SAME unrotated
+    ``ks`` in every head.  Nothing is rotated and nothing scaled (the
+    caller's softmax scale is ``head_dim^(-1/2)``).  Packed q and k [b, S, H
+    * lanes], a head in ``_latent_head_lanes`` lanes with zeros behind its
+    ``head_dim`` columns (the zero columns of ``wq`` and of the keys' matrix:
+    no activation is padded or cut across lanes), and v [b, S, H * dv]."""
+    b, S, _ = h.shape
+    H, dn, dr = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+    lanes = _latent_head_lanes(cfg)
+    tail = lanes - dn - dr
+    rows, wq = (_rms(h @ pl["wq_a"], pl["q_a_norm"], cfg.norm_eps),
+                pl["wq_b"]) if cfg.q_lora_rank else (h, pl["wq"])
+    if tail:
+        wq = jnp.pad(wq.reshape(wq.shape[0], H, dn + dr),
+                     ((0, 0), (0, 0), (0, tail))).reshape(wq.shape[0], -1)
+    ckv, ks = jnp.split(h @ pl["wkv_a"], [cfg.kv_lora_rank], axis=-1)
+    ckv = _rms(ckv, pl["kv_a_norm"], cfg.norm_eps)
+    w = pl["wkv_b"].reshape(-1, H, dn + cfg.v_head_dim)
+    k_columns = jnp.pad(w[..., :dn], ((0, 0), (0, 0), (0, dr + tail)))
+    # the ONE shared key in lanes [dn, dn + dr) of every head: added into
+    # the zeros the keys' matrix leaves there
+    k = (ckv @ k_columns.reshape(w.shape[0], -1)).reshape(b, S, H, lanes) \
+        + jnp.pad(ks, ((0, 0), (0, 0), (dn, tail)))[:, :, None, :]
+    return (rows @ wq, k.reshape(b, S, -1),
+            ckv @ w[..., dn:].reshape(w.shape[0], -1))
+
+
 def _latent_fused(cfg, rows, itemsize):
     """Whether the row kernel (``kernels/qk_rope.py``, ``pairs``) takes the
     latent form's q and k of ``rows`` = (b, S): heads of ``dn + dr`` in whole
@@ -1276,8 +1423,11 @@ def _attention_heads_mode(pl, h_full, cfg, kind):
     dh = cfg.head_dim
     window, rotary = kind
     q2, k2, v2 = _qkv(pl, h_full, cfg, rotary)
-    blocks = _packed_flash_blocks(cfg, hl, S, kvl)
-    if blocks:
+    two_widths = cfg.latent and cfg.v_head_dim != dh
+    blocks = not two_widths and _packed_flash_blocks(cfg, hl, S, kvl)
+    if two_widths:
+        o = _attend_two_widths(q2, k2, v2, cfg)
+    elif blocks:
         # packed layout: the kernel reads each head's column slice in place —
         # no [b, hl, S, dh] transpose round-trips (flash_attention_packed)
         from ..kernels.flash_attention import flash_attention_packed
@@ -1300,6 +1450,31 @@ def _attention_heads_mode(pl, h_full, cfg, kind):
     out = o @ pl["wo"]                                          # row-parallel partial
     out = col.reduce_scatter(out, TP, dim=1)                    # sum + seq scatter
     return out + pl["bo"] if cfg.bias else out
+
+
+def _attend_two_widths(q2, k2, v2, cfg):
+    """Full causal attention of the latent form where a value is not as wide
+    as a head of q and k: q2, k2 [b, S, H * lanes] (``_latent_head_lanes``,
+    zeros behind ``head_dim``), v2 [b, S, H * dv]; the heads' outputs [b, S,
+    H * dv].  The packed flash kernels' value mode (scores over the lanes,
+    whose zeros add nothing, at ``head_dim^(-1/2)``; ``P V``, ``dP`` and
+    ``dV`` at dv) where it takes the shapes, else plain blockwise
+    attention."""
+    b, S, _ = q2.shape
+    H, dv = cfg.n_heads, cfg.v_head_dim
+    lanes = q2.shape[-1] // H
+    scale = cfg.head_dim ** -0.5
+    blocks = _packed_flash_blocks(cfg, H, S, widths=(lanes, dv))
+    if blocks:
+        from ..kernels.flash_attention import flash_attention_packed
+
+        return flash_attention_packed(
+            q2, k2, v2, H, causal=cfg.causal, scale=scale, block_q=blocks[0],
+            block_k=blocks[1], v_head_dim=dv)
+    o = ring_attention(q2.reshape(b, S, H, lanes), k2.reshape(b, S, H, lanes),
+                       v2.reshape(b, S, H, dv), axis=None, causal=cfg.causal,
+                       scale=scale)
+    return o.reshape(b, S, H * dv)
 
 
 def _attention_ring_mode(pl, h_sp, cfg):
@@ -1529,6 +1704,86 @@ def mamba2_mixer(pl, h, cfg):
     return normed @ pl["w_out"]
 
 
+def _kda_filtered(pl, h, cfg, name):
+    """``silu(filter(h @ w<name>))`` [b, S, P]: one of the KDA mixer's three
+    projections through its causal depthwise filter (``d_conv`` taps, no
+    bias, zero before position 0) and ``silu``: ``kernels/mamba_filter.py``'s
+    one pass each way where it takes the shapes (a zero bias), its ``jnp``
+    reference elsewhere."""
+    from ..kernels import mamba_filter as mf
+    from ..kernels._common import count_call
+
+    x = h @ pl["w" + name]
+    taps = pl["conv_" + name]
+    fused = mf.supported(x.shape, cfg.d_conv, x.dtype.itemsize)
+    count_call("mamba_filter", fused=int(fused), halo="zeros")
+    bias = jnp.zeros((x.shape[-1],), jnp.float32)
+    return (mf.mamba_filter if fused else mf.mamba_filter_reference)(
+        x, taps, bias)
+
+
+def kda_log_decay(pl, h, cfg):
+    """The log-decay of every token, head and key channel, [b, S, heads,
+    head width] float32: ``-exp(a_log) * softplus((h @ w_fa) @ w_fb +
+    dt_bias)``, in (-inf, 0)."""
+    nh, d = cfg.kda_heads, cfg.kda_head_dim
+    step = jax.nn.softplus(jnp.matmul(
+        h @ pl["w_fa"], pl["w_fb"], preferred_element_type=jnp.float32)
+        + pl["dt_bias"])
+    return -jnp.exp(pl["a_log"])[:, None] * step.reshape(
+        h.shape[:2] + (nh, d))
+
+
+def _l2_heads(x, nh, scale):
+    """x [b, S, nh * d] as heads [b, S, nh, d], each ``x / |x|_2 * scale``
+    (eps 1e-6 under the root), float32 inside."""
+    x = x.reshape(x.shape[:2] + (nh, -1)).astype(jnp.float32)
+    return (x * (jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+                 * scale))
+
+
+@devscope.scoped(devscope.KDA)
+def kda_mixer(pl, h, cfg):
+    """Kimi Delta Attention (Kimi Linear, arXiv:2510.26692) on ``h`` [b, S,
+    E], the whole sequence: q, k and v each ``silu(filter(h @ w))``
+    (``_kda_filtered``), per head ``q / |q| * d^(-1/2)`` and ``k / |k|``; the
+    log-decays ``kda_log_decay`` (one a CHANNEL of the key) and the write
+    strengths ``sigmoid(h @ w_beta)`` (one a head), float32; the delta rule
+    on a [d, d] state a head (``kernels/kda_chunk.py``, chunks of
+    ``cfg.kda_chunk`` tokens); each head's output RMS-normed by the ONE
+    scale ``o_norm`` and THEN gated by ``sigmoid((h @ w_ga) @ w_gb)`` (the
+    order Mamba-2's gated norm does not have); then ``wo``.  Norms, decays,
+    gates and the state in float32."""
+    from ..kernels import kda_chunk
+    from ..kernels._common import count_call
+
+    nh, d = cfg.kda_heads, cfg.kda_head_dim
+    b, S, _ = h.shape
+    q, k, v = (_kda_filtered(pl, h, cfg, name) for name in "qkv")
+    q = _l2_heads(q, nh, d ** -0.5).astype(h.dtype)
+    k = _l2_heads(k, nh, 1.0).astype(h.dtype)
+    beta = jax.nn.sigmoid(jnp.matmul(
+        h, pl["w_beta"], preferred_element_type=jnp.float32))
+    count_call("kda_chunk", fused=0)
+    with jax.named_scope(devscope.KDA_CHUNK):
+        # under a checkpoint of its own: what the chunked form keeps for its
+        # backward (the chunks' own parts, a state a chunk: 1.7 GB at
+        # [16384, 32 x 128]) then stands only while that backward runs, not
+        # beside the FFN's residuals through the layer's.  The price is a
+        # third forward of the delta rule; without it the cell's step needs
+        # 15.90 GB of the chip's 15.75 GiB, with the scan alone under one
+        # 16.22 GB where this reads 15.29 (PERF.md section 6, PR 58)
+        o = jax.checkpoint(functools.partial(
+            kda_chunk.kda_chunked, chunk=cfg.kda_chunk))(
+                q, k, v.reshape(b, S, nh, d), kda_log_decay(pl, h, cfg), beta)
+    gate = jax.nn.sigmoid(jnp.matmul(
+        h @ pl["w_ga"], pl["w_gb"], preferred_element_type=jnp.float32))
+    o = o.astype(jnp.float32)
+    y = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                          + cfg.norm_eps) * pl["o_norm"] * gate.reshape(o.shape)
+    return y.astype(h.dtype).reshape(b, S, nh * d) @ pl["wo"]
+
+
 # rows x width of a pointwise stage's widest activation (an FFN's hidden
 # rows, the projections between their matmuls and the kernel) past which it
 # runs a block of positions at a time, each block's forward run again in its
@@ -1602,7 +1857,7 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
     """One pre-norm block on the SP activation [b, S/tp, E]: the new
     activation and the FFN's auxiliary values (the MoE's, ``moe.route_top_k``;
     None for a dense FFN).  ``kind`` = (window or None, rotary), or CONV,
-    RETENTION, MAMBA or MAMBA2: which of ``cfg.layer_kinds`` this layer is
+    RETENTION, MAMBA, MAMBA2 or KDA: which of ``cfg.layer_kinds`` this layer is
     (None: the first).  In a ``single_branch`` stack the layer is ONE of the
     two branches: the mixer's alone (auxiliary values None), or where
     ``kind`` is FFN the feed-forward part's alone;
@@ -1634,6 +1889,10 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
     elif kind == MAMBA2:
         with jax.named_scope(devscope.MAMBA2):
             x_sp = _add_branch(x_sp, mamba2_mixer(
+                pl, _norm(x_sp, pl, "ln1", cfg), cfg), pl, "ln1", cfg)
+    elif kind == KDA:
+        with jax.named_scope(devscope.KDA):
+            x_sp = _add_branch(x_sp, kda_mixer(
                 pl, _norm(x_sp, pl, "ln1", cfg), cfg), pl, "ln1", cfg)
     else:
         with jax.named_scope(devscope.LATENT_ATTENTION if cfg.latent

@@ -1,0 +1,146 @@
+"""The delta rule's chunked form (``kernels/kda_chunk.py:kda_chunked``),
+compiled on the chip with the model's operands (q, k, v in bf16, log-decays
+and write strengths float32), against the recurrence a token at a time in
+float32 at ``highest`` precision (``kda_recurrence``), at the Kimi-Linear
+cell's shapes:
+
+    chiprun --timeout 1500 -- python3 scripts/kda_chunk_receipt.py [out.json]
+
+- the output at [1, 16384, 32, 128], chunks of 64, with decays near one (a
+  state that outlives every chunk) and at the seeded extremes (``a_log`` =
+  ln 16, a step of 0.7: the overflow case, which must come out finite);
+- the five gradients (q, k, v, g, beta) at 1,024 tokens (the recurrence's
+  gradient keeps a [32, 128, 128] state a token: 2.1 GB), both decays;
+- a control with the state dropped at every chunk edge (each chunk run from
+  a zero state), which must NOT pass at decays near one;
+- the milliseconds a call of the forward and of forward + backward at the
+  whole shape (host clock around ``block_until_ready``, the median of
+  CALLS), beside the least the requirement's bytes allow
+  (``benchmark/flops/kimi_linear_train.py:delta_rule``).
+
+No Pallas kernel ships for the chunk bodies (ROADMAP.md, Speed queue): when
+one does, this receipt is what holds it to ``kda_chunked``.  One JSON line,
+kept under ``chiprun_out/pr58/``.  Exit 1 where a reading is off, 2 off a
+TPU."""
+
+import json
+import math
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from benchmark.flops import kimi_linear_train  # noqa: E402
+from benchmark.harness.peaks import PEAKS  # noqa: E402
+from paddle_tpu.kernels import kda_chunk as K  # noqa: E402
+
+B, S, H, D, CHUNK = 1, 16384, 32, 128, 64
+S_COMPARED = 1024       # the recurrence's gradient keeps a state a token
+DECAYS = {"near_one": (0.0, 1e-3), "extreme": (math.log(16.0), 0.7)}
+NAMES = ("q", "k", "v", "g", "beta")
+# bf16 operands of a chain of products against float32; the log-decays'
+# gradient is a sum of both signs over every later token of the chunk
+LIMIT, DECAY_LIMIT = 2e-2, 6e-2
+CALLS = 5
+OUT = os.path.join(ROOT, "chiprun_out", "pr58", "kda_chunk_receipt.json")
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def operands(s, decays, seed=0):
+    """As the mixer hands them over: q and k L2-normalised a head (q times
+    d^-1/2) in bf16, v in bf16, g <= 0 and beta in (0, 1) float32."""
+    a_log, step = DECAYS[decays]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q, k = (jax.random.normal(key, (B, s, H, D)) for key in ks[:2])
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * D ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (B, s, H, D))
+    g = -math.exp(a_log) * jax.nn.softplus(
+        0.3 * jax.random.normal(ks[3], (B, s, H, D))
+        + math.log(math.expm1(step)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, s, H)))
+    return tuple(a.astype(jnp.bfloat16) for a in (q, k, v)) + (g, beta)
+
+
+def edges_dropped(q, k, v, g, beta):
+    """The control: every chunk from a zero state."""
+    cut = lambda a: a.reshape((-1, CHUNK) + a.shape[2:])    # noqa: E731
+    o = K.kda_chunked(*(cut(a) for a in (q, k, v, g, beta)), chunk=CHUNK)
+    return o.reshape(v.shape)
+
+
+def main(out_path=OUT):
+    if jax.devices()[0].platform != "tpu":
+        print("kda_chunk_receipt: no TPU here")
+        return 2
+    chunked = jax.jit(lambda *a: K.kda_chunked(*a, chunk=CHUNK))
+    recurrence = jax.jit(K.kda_recurrence)
+    out = {"shape": [B, S, H, D], "chunk": CHUNK, "limit": LIMIT,
+           "decay_limit": DECAY_LIMIT, "device": jax.devices()[0].device_kind,
+           "outputs": {}, "gradients": {}, "control": {}, "ms": {}}
+    ok = True
+    for decays in DECAYS:
+        args = operands(S, decays)
+        got = chunked(*args)
+        err = _rel(got, recurrence(*args))
+        finite = bool(jnp.isfinite(got.astype(jnp.float32)).all())
+        out["outputs"][decays] = {"relative_error": err, "finite": finite}
+        ok &= finite and err < LIMIT
+        short = operands(S_COMPARED, decays, seed=1)
+        w = jax.random.normal(jax.random.PRNGKey(7), (B, S_COMPARED, H, D))
+
+        def grads(fn):
+            return jax.jit(jax.grad(lambda *a: jnp.sum(
+                fn(*a).astype(jnp.float32) * w), argnums=(0, 1, 2, 3, 4)))(
+                    *short)
+
+        got_g, want_g = grads(lambda *a: K.kda_chunked(*a, chunk=CHUNK)), \
+            grads(K.kda_recurrence)
+        out["gradients"][decays] = {
+            n: _rel(a, b) for n, a, b in zip(NAMES, got_g, want_g)}
+        ok &= all(e < (DECAY_LIMIT if n == "g" else LIMIT)
+                  for n, e in out["gradients"][decays].items())
+        out["control"][decays] = _rel(jax.jit(edges_dropped)(*args),
+                                      recurrence(*args))
+    ok &= out["control"]["near_one"] > 10 * LIMIT
+    # the time of a call at the whole shape, seeded decays' mix
+    args = operands(S, "near_one", seed=2)
+    w = jax.random.normal(jax.random.PRNGKey(8), (B, S, H, D), jnp.bfloat16)
+    both = jax.jit(jax.grad(lambda *a: jnp.sum(
+        (K.kda_chunked(*a, chunk=CHUNK) * w).astype(jnp.float32)),
+        argnums=(0, 1, 2, 3, 4)))
+    for name, fn in (("forward", chunked), ("forward_and_backward", both)):
+        jax.block_until_ready(fn(*args))
+        took = []
+        for _ in range(CALLS):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(*args))
+            took.append((time.perf_counter() - t0) * 1e3)
+        out["ms"][name] = float(np.median(took))
+    model = {"linear_attn_config": {"num_heads": H, "head_dim": D}}
+    need = kimi_linear_train.delta_rule(model, B * S)
+    peaks = PEAKS["TPU v5 lite"]
+    out["least_ms_a_layer_and_step"] = 1e3 * max(
+        need["flops"] / peaks["bf16_flops"],
+        need["bytes"] / peaks["hbm_bytes_per_s"])
+    out["kept_state_bytes"] = K.kept_state_bytes(B, S, CHUNK, H, D, D)
+    out["ok"] = bool(ok)
+    print(json.dumps(out), flush=True)
+    os.makedirs(os.path.dirname(os.path.abspath(out_path)), exist_ok=True)
+    with open(out_path, "w") as f:
+        json.dump(out, f)
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main(*sys.argv[1:]))

@@ -82,6 +82,16 @@ def test_classify_on_literal_op_names():
         "mamba2/mamba2/ssd_scan/ssd_scan_bwd": ("backward", "ssd_scan"),
         "jit(multi)/jvp()/while/body/closed_call/mamba2/mamba2/"
         "dot_general": ("forward", "mamba2"),
+        # the delta rule inside the KDA mixer's scope, under the layer's
+        # checkpoint and its own, and the mixer's own
+        "jit(multi)/transpose(jvp())/while/body/closed_call/checkpoint/"
+        "kda/kda/kda_chunk/checkpoint/while/body/dot_general":
+            ("backward", "kda_chunk"),
+        "jit(multi)/transpose(jvp())/while/body/closed_call/checkpoint/"
+        "rematted_computation/kda/kda/kda_chunk/checkpoint/"
+        "rematted_computation/exp": ("recompute", "kda_chunk"),
+        "jit(multi)/jvp()/while/body/closed_call/kda/kda/dot_general":
+            ("forward", "kda"),
         # no vocabulary word on the path
         "jit(multi)/while/body/closed_call/jvp()/while/body/closed_call":
             ("forward", None),
@@ -120,7 +130,7 @@ def test_classify_on_literal_op_names():
     for op_name, want in table.items():
         assert devscope.classify(op_name) == want, op_name
     assert set(devscope.PHASES) >= {w[0] for w in table.values()}
-    assert len(set(devscope.VOCABULARY)) == len(devscope.VOCABULARY) == 27
+    assert len(set(devscope.VOCABULARY)) == len(devscope.VOCABULARY) == 29
 
 
 @pytest.mark.parametrize("op_name, want", [
@@ -210,6 +220,33 @@ def test_remat_layers_show_as_recompute():
     assert {("recompute", "attention"), ("recompute", "mlp"),
             ("backward", "attention"), ("backward", "mlp"),
             ("forward", "attention"), ("forward", "mlp")} <= got
+
+
+@pytest.fixture(scope="module")
+def kimi_classes():
+    """The (phase, scope) pairs of a tiny Kimi-Linear trainer's compiled
+    ``run_steps`` under per-layer remat."""
+    from paddle_tpu.models import kimi_linear
+    from paddle_tpu.parallel import decoder
+
+    tr = kimi_linear.build_kimi_linear_trainer(
+        kimi_linear.kimi_linear_tiny_config(remat=True), MeshSpec(dp=1),
+        optimizer=optim.adamw(), seed=0, devices=jax.devices()[:1])
+    ids = np.random.RandomState(0).randint(0, 256, (2, 2, 64)).astype("i4")
+    tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS,
+                               [{"ids": i} for i in ids]), 1e-3)
+    got = _classes(devscope.scope_maps()["kimi_linear.run_steps"])
+    del tr
+    gc.collect()
+    return got
+
+
+@pytest.mark.parametrize("scope", [devscope.KDA, devscope.KDA_CHUNK])
+def test_the_kda_scopes_cover_forward_recompute_and_backward(kimi_classes,
+                                                             scope):
+    assert scope in devscope.VOCABULARY
+    for phase in ("forward", "recompute", "backward"):
+        assert (phase, scope) in kimi_classes, (phase, scope)
 
 
 def test_registering_traces_lowers_and_compiles_nothing():

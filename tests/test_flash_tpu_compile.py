@@ -225,6 +225,31 @@ def test_grouped_and_windowed_kernels_compile_for_a_v5e(
     assert (_vmem(text, names[0])[1] > 6 * 2 ** 20) == (stacked > 1), what
 
 
+def test_the_value_width_kernels_compile_for_a_v5e(one_chip):
+    """kimi_linear_48b_a3b.s16384_scan's latent layer: 32 heads whose q and
+    k stand in 256 lanes (192 and 64 zeros) and whose v, o, do and dv are
+    128 wide, over 16,384 positions.  A query head-block is two lane blocks
+    and a value's one; the backward is ONE sweep, dk of the whole sequence
+    in a [16384, 256] float32 accumulator and dv in a [16384, 128] one (24
+    MiB of the 52 the call asks for, where one width of 256 would ask for
+    64); the grids are a one-width call's."""
+    B, S, H, D, Dv = 1, 16384, 32, 256, 128
+    xq = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
+    xv = jax.ShapeDtypeStruct((B, S, H * Dv), jnp.bfloat16, sharding=one_chip)
+    attn = lambda q, k, v: fa.flash_attention_packed(
+        q, k, v, H, causal=True, scale=192 ** -0.5, block_q=512, block_k=512,
+        interpret=False, v_head_dim=Dv)
+    assert jax.eval_shape(attn, xq, xq, xv).shape == xv.shape
+    text, grids, _ = _compiled(attn, xq, xq, xv, xv)
+    steps = fa.kv_blocks(S, 512, 512, True)
+    assert grids == {"flash_fwd": (B, H, 1, steps),
+                     "flash_bwd_fused": (B, H, steps)}
+    asked, took = _vmem(text, "flash_bwd_fused")
+    assert asked == fa.fused_sweep_vmem_bytes(S, D, 2, Dv) == 52 * 2 ** 20
+    assert fa.fused_sweep_vmem_bytes(S, D, 2) == 64 * 2 ** 20
+    assert S * (D + Dv) * (4 + 2) < took < asked
+
+
 def test_a_sequence_past_the_rule_compiles_as_two_sweeps(one_chip):
     """S = 65,536 at 128 lanes would ask for 112 MiB: ``flash_bwd_dq`` and
     ``flash_bwd_dkv`` in Mosaic's own scope, as every several-block shape

@@ -1,0 +1,495 @@
+"""The Kimi-Linear decoder through the normal path (``models/kimi_linear.py``
+over ``parallel/transformer.py``'s KDA mixer and its latent attention without
+positions inside a pattern, ``kernels/kda_chunk.py``'s chunked delta rule,
+the flash kernels' value-width mode in interpret mode and ``parallel/moe.py``'s
+held-experts path) against the benchmark's plain float32 reference
+(``benchmark/reference/kimi_linear_48b_a3b.py``: the recurrence a TOKEN at a
+time), on seeded weights at ``kimi_linear_tiny_config``: the five layers KDA
+(dense FFN), KDA, KDA, latent, KDA; 2 KDA heads of 16 in chunks of 16 under S
+= 64; 2 latent heads of 128 + 64 against values of 128; 8 experts top-2 of
+which 4 are held, a shared expert, vocab 256.
+
+The tiny configuration computes in float32, so the tolerance is 1e-5 (the
+two differ by accumulation order only)."""
+
+import importlib
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.reference import kimi_linear_48b_a3b as reference  # noqa: E402
+from paddle_tpu import monitor  # noqa: E402
+from paddle_tpu.models import kimi_linear, mistral4  # noqa: E402
+from paddle_tpu.monitor import devscope  # noqa: E402
+from paddle_tpu.parallel import (decoder, moe, optim,  # noqa: E402
+                                 transformer as T)
+from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
+from paddle_tpu.parallel.rules import leaf_paths  # noqa: E402
+from paddle_tpu.parallel.train import stack_batches  # noqa: E402
+
+# the module: ``paddle_tpu.kernels`` exports a function of the same name
+fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+B, S, TOL = 2, 64, 1e-5
+LINEAR = {"full_attn_layers": [4, 8, 12, 16, 20, 24, 27], "head_dim": 16,
+          "kda_layers": [1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19,
+                         21, 22, 23, 25, 26],
+          "num_heads": 2, "short_conv_kernel_size": 4}
+# the reference reads the published keys
+MODEL = {"num_attention_heads": 2, "q_lora_rank": None, "kv_lora_rank": 32,
+         "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
+         "rms_norm_eps": 1e-5, "mla_use_nope": True, "moe_renormalize": True,
+         "num_expert_group": 1, "topk_group": 1,
+         "moe_router_activation_func": "sigmoid", "linear_attn_config": LINEAR,
+         "num_experts_per_token": 2, "num_experts": 4, "moe_router_width": 8,
+         "moe_first_expert_held": 4, "num_shared_experts": 1,
+         "routed_scaling_factor": 2.446, "first_k_dense_replace": 1,
+         "num_hidden_layers": 5}
+KDA_NAMES = ("ln1_scale", "ln2_scale", "wq", "wk", "wv", "conv_q", "conv_k",
+             "conv_v", "w_fa", "w_fb", "dt_bias", "a_log", "w_beta", "w_ga",
+             "w_gb", "o_norm", "wo")
+SPARSE = ("router", "we_gate_up", "we_down", "ws_gate_up", "ws_down")
+LATENT_NAMES = ("ln1_scale", "ln2_scale", "wq", "wkv_a", "kv_a_norm", "wkv_b",
+                "wo")
+LEAVES = ["tok_emb", "lm_head", "lnf_scale"] \
+    + ["prefix_layers/l0/" + n for n in KDA_NAMES + ("w_gate_up", "w_down")] \
+    + ["params_layers/r0/" + n for n in KDA_NAMES + SPARSE] \
+    + ["params_layers/r1/" + n for n in LATENT_NAMES + SPARSE] \
+    + ["params_layers/r2/" + n for n in KDA_NAMES + SPARSE]
+
+
+def _trainer(seed=3, optimizer=None, **cfg):
+    return kimi_linear.build_kimi_linear_trainer(
+        kimi_linear.kimi_linear_tiny_config(**cfg), MeshSpec(dp=1),
+        optimizer=optimizer or optim.adamw(), seed=seed,
+        devices=jax.devices()[:1])
+
+
+def _ids(seed=0, n=1):
+    rng = np.random.RandomState(seed)
+    return [rng.randint(0, 256, (B, S)).astype(np.int32) for _ in range(n)]
+
+
+def _seeded_params(tr):
+    """The trainer's seeded weights with the norm scales moved off 1, so
+    that a missing or misplaced scale shows, a router steep enough that the
+    weights are not all alike, and branch outputs at the fan-in scale again
+    (the seeded 27^-1/2 would hide a wrong branch behind the embedding)."""
+    rng = np.random.RandomState(11)
+
+    def moved(path, a):
+        name = jax.tree_util.keystr(path)
+        if "scale" in name or "_norm" in name:
+            return np.asarray(a) * rng.uniform(0.5, 1.5, a.shape).astype("f4")
+        if "router_bias" in name:
+            return np.asarray(a)
+        if name.endswith("['wo']") or "down" in name:
+            return np.asarray(a) * 27 ** 0.5
+        return np.asarray(a) * (3.0 if "router" in name else 1.0)
+
+    return jax.tree_util.tree_map_with_path(moved, tr.state["params"])
+
+
+def _leaf(tree, path):
+    for part in path.split("/"):
+        tree = tree[part]
+    return tree
+
+
+@pytest.fixture(scope="module")
+def both():
+    """Loss and gradients of program and reference on the same weights."""
+    tr = _trainer()
+    params = _seeded_params(tr)
+    ids = _ids()[0]
+    loss_fn = decoder.make_loss_fn(tr.cfg)
+    got = jax.jit(jax.value_and_grad(
+        lambda p: loss_fn(p, {"ids": jnp.asarray(ids)})[0]))(params)
+    want = jax.value_and_grad(
+        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
+            jax.tree.map(jnp.asarray, params))
+    return tr.cfg, params, ids, got, want
+
+
+def test_the_tiny_configuration_keeps_every_mechanism():
+    cfg = kimi_linear.kimi_linear_tiny_config()
+    assert cfg.latent and cfg.per_position and cfg.run_scan
+    assert cfg.prefix_kinds == (T.KDA,) and cfg.layer_kinds == (
+        T.KDA, T.KDA, (None, False), T.KDA)
+    assert cfg.runs == ((0, T.KDA, 2), (2, (None, False), 1), (3, T.KDA, 1))
+    assert cfg.positions is None and cfg.q_lora_rank == 0
+    assert (cfg.n_heads, cfg.head_dim, cfg.qk_nope_dim, cfg.qk_rope_dim,
+            cfg.v_head_dim, cfg.kv_lora_rank) == (2, 192, 128, 64, 128, 32)
+    assert (cfg.kda_heads, cfg.kda_head_dim, cfg.kda_gate_rank,
+            cfg.kda_chunk, cfg.d_conv) == (2, 16, 8, 16, 4)
+    assert S // cfg.kda_chunk == 4                      # the carry matters
+    assert (cfg.n_experts, cfg.experts_here, cfg.first_expert,
+            cfg.experts_per_token, cfg.shared_ffn_hidden,
+            cfg.dense_ffn_hidden) == (8, 4, 4, 2, 48, 96)
+    assert cfg.routing == moe.SIGMOID_BIASED and cfg.route_scale == 2.446
+    # the kernels run: a head of 192 in 256 lanes, values of 128
+    assert T._latent_head_lanes(cfg) == 256
+    assert T._packed_flash_blocks(cfg, 2, S, widths=(256, 128)) == (16, 16)
+    assert not fa.packed_layout_supported(2, 192) \
+        and not fa.packed_layout_supported(2, 192, None, 128) \
+        and fa.packed_layout_supported(2, 256, None, 128) \
+        and not fa.packed_layout_supported(4, 256, 2, 128)
+    big = kimi_linear.kimi_linear_48b_a3b_config()
+    assert (big.n_layers, big.hidden, big.n_heads, big.head_dim,
+            big.q_lora_rank, big.kv_lora_rank, big.qk_nope_dim,
+            big.qk_rope_dim, big.v_head_dim, big.ffn_hidden,
+            big.dense_ffn_hidden, big.shared_ffn_hidden, big.n_experts,
+            big.experts_here, big.experts_per_token, big.vocab_size,
+            big.norm_eps, big.kda_heads, big.kda_head_dim,
+            big.kda_gate_rank, big.d_conv, big.n_periods) == (
+        25, 2304, 32, 192, 0, 512, 128, 64, 128, 1024, 9216, 1024, 256, 256,
+        8, 163840, 1e-5, 32, 128, 128, 4, 6)
+    # the published depth ends on half a period: 27 is not expressible
+    for depth in (27, 26, 4):
+        with pytest.raises(AssertionError):
+            kimi_linear.kimi_linear_48b_a3b_config(n_layers=depth)
+    # what each latent branch needs, and nothing else
+    with pytest.raises(AssertionError):     # a rotated latent pads nothing
+        mistral4.mistral4_tiny_config(v_head_dim=64)
+    with pytest.raises(AssertionError):     # no rotation: nothing shapes one
+        kimi_linear.kimi_linear_tiny_config(q_scale_beta=0.1)
+    with pytest.raises(AssertionError):     # a latent position is full
+        kimi_linear.kimi_linear_tiny_config(
+            layer_pattern=(T.KDA, T.KDA, (16, False), T.KDA))
+    with pytest.raises(AssertionError):     # KDA needs its widths
+        kimi_linear.kimi_linear_tiny_config(kda_gate_rank=0)
+
+
+def test_loss_equals_the_reference(both):
+    _, _, _, (got, _), (want, _) = both
+    assert abs(float(got) - float(want)) / float(want) < TOL
+
+
+def test_every_position_s_logits_equal_the_reference(both):
+    cfg, params, ids, _, _ = both
+    x, _ = jax.jit(lambda p, i: decoder.forward(p, i, cfg))(params, ids)
+    got = T.rms_norm(x, params["lnf_scale"], cfg.norm_eps) @ params["lm_head"].T
+    _, want = reference.forward(params, ids, MODEL)
+    np.testing.assert_allclose(got, np.stack(want), rtol=1e-4, atol=TOL)
+
+
+@pytest.mark.parametrize("path", LEAVES)
+def test_gradient_of_every_leaf_equals_the_reference(both, path):
+    _, params, _, (_, got), (_, want) = both
+    g, w = np.asarray(_leaf(got, path)), np.asarray(_leaf(want, path))
+    assert g.shape == _leaf(params, path).shape and np.abs(w).max() > 0
+    np.testing.assert_allclose(g, w, rtol=1e-4, atol=TOL * np.abs(w).max())
+
+
+def test_the_leaves_tested_are_all_there_are(both):
+    _, params, _, (_, got), _ = both
+    paths, _, _ = leaf_paths(params)
+    assert set(paths) == set(LEAVES) | {"router_bias"}
+    assert not np.asarray(got["router_bias"]).any()     # no gradient reaches
+    r0, r1 = params["params_layers"]["r0"], params["params_layers"]["r1"]
+    assert r0["wq"].shape == (1, 2, 64, 32) and r0["conv_k"].shape == (
+        1, 2, 4, 32)
+    assert r0["w_fa"].shape == (1, 2, 64, 8) and r0["w_gb"].shape == (
+        1, 2, 8, 32)
+    assert r0["a_log"].shape == (1, 2, 2) and r0["o_norm"].shape == (1, 2, 16)
+    assert r1["wq"].shape == (1, 1, 64, 2 * 192)
+    assert r1["wkv_a"].shape == (1, 1, 64, 32 + 64)
+    assert r1["wkv_b"].shape == (1, 1, 32, 2 * (128 + 128))
+    assert r1["wo"].shape == (1, 1, 2 * 128, 64)
+    assert not {"wq_a", "q_a_norm", "wk", "wv"} & set(r1)
+    assert params["router_bias"].shape == (4, 8)
+
+
+def test_sharding_specs_and_gradient_syncs_follow_the_tree():
+    for cfg in (kimi_linear.kimi_linear_tiny_config(),
+                kimi_linear.kimi_linear_tiny_config(run_scan=False)):
+        params = jax.eval_shape(
+            lambda: T.init_transformer_params(jax.random.PRNGKey(0), cfg))
+        for tree in (T.transformer_param_specs(cfg), T.grad_sync_axes(cfg)):
+            assert jax.tree.structure(
+                tree, is_leaf=lambda x: isinstance(x, (tuple, T.P))) == \
+                jax.tree.structure(params)
+    specs = T.transformer_param_specs(cfg)["params_layers"]
+    assert specs["p0"]["w_fb"] == specs["p0"]["o_norm"] == T.P()
+    assert specs["p2"]["wkv_b"] == T.P(None, None, None)
+
+
+def test_positions_of_the_period_without_run_scan_give_the_same_loss():
+    """``run_scan`` stacks the runs (K, K), (latent), (K); without it each
+    position is a tree of its own, seeded alike: the same numbers."""
+    ids = jnp.asarray(_ids(seed=4)[0])
+    losses = []
+    for run_scan in (True, False):
+        tr = _trainer(run_scan=run_scan)
+        losses.append(float(jax.jit(lambda p: decoder.make_loss_fn(tr.cfg)(
+            p, {"ids": ids})[0])(tr.state["params"])))
+    assert abs(losses[0] - losses[1]) < 1e-5 * losses[0]
+
+
+def _latent_layer(seed=4):
+    cfg = kimi_linear.kimi_linear_tiny_config()
+    params = T.init_transformer_params(jax.random.PRNGKey(seed), cfg)
+    pl = jax.tree.map(lambda a: a[0, 0], params["params_layers"]["r1"])
+    h = jax.random.normal(jax.random.PRNGKey(seed + 1), (B, S, 64))
+    return cfg, pl, h
+
+
+def test_the_shared_key_is_one_unrotated_vector_for_all_heads():
+    """q and k stand a head in 256 lanes: 128 of the head's own, the SAME 64
+    of ``wkv_a``'s last columns as they are (no position moves them), 64
+    zeros; v is the head's own 128."""
+    cfg, pl, h = _latent_layer()
+    q, k, v = T._qkv(pl, h, cfg, False)
+    assert q.shape == k.shape == (B, S, 2 * 256) and v.shape == (B, S, 2 * 128)
+    q, k = (np.asarray(a).reshape(B, S, 2, 256) for a in (q, k))
+    ks = np.asarray(h @ pl["wkv_a"])[..., 32:]
+    for head in range(2):
+        np.testing.assert_allclose(k[:, :, head, 128:192], ks, rtol=1e-6)
+        assert not k[:, :, head, 192:].any() and not q[:, :, head, 192:].any()
+    assert np.abs(k[:, :, 1, :128] - k[:, :, 0, :128]).max() > 1e-2
+    np.testing.assert_allclose(
+        q[..., :192], np.asarray(h @ pl["wq"]).reshape(B, S, 2, 192),
+        rtol=1e-5, atol=1e-6)
+    c = T._rms(np.asarray(h @ pl["wkv_a"])[..., :32], pl["kv_a_norm"], 1e-5)
+    kv = np.asarray(c @ pl["wkv_b"]).reshape(B, S, 2, 256)
+    np.testing.assert_allclose(k[..., :128], kv[..., :128], rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(np.asarray(v).reshape(B, S, 2, 128),
+                               kv[..., 128:], rtol=1e-5, atol=1e-6)
+
+
+def _plain(q, k, v, heads, scale):
+    b, s, _ = q.shape
+    q, k, v = (a.reshape(b, s, heads, -1) for a in (q, k, v))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") * scale
+    scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v,
+                      precision="highest").reshape(b, s, -1)
+
+
+# several blocks in ONE backward sweep; the two-sweep backward (SWEEP_VMEM
+# refused); one block
+@pytest.mark.parametrize("block,sweeps", [(16, 1), (16, 2), (64, 1)])
+def test_flash_at_192_128_equals_plain_attention(monkeypatch, block, sweeps):
+    """The packed kernels' value mode in interpret mode: q and k a head of
+    192 in 256 lanes, v and o at 128, the scale 192^-1/2; outputs and dq,
+    dk, dv against plain softmax attention."""
+    if sweeps == 2:
+        monkeypatch.setattr(fa, "SWEEP_VMEM", 0)
+    ks = jax.random.split(jax.random.PRNGKey(block + sweeps), 4)
+    q, k = (jax.random.normal(key, (B, S, 2, 256)).at[..., 192:].set(0)
+            .reshape(B, S, -1) for key in ks[:2])
+    v, w = (jax.random.normal(key, (B, S, 2 * 128)) for key in ks[2:])
+    scale = 192 ** -0.5
+
+    def flash(q, k, v):
+        return fa.flash_attention_packed(
+            q, k, v, 2, causal=True, scale=scale, block_q=block,
+            block_k=block, v_head_dim=128)
+
+    assert fa._Geom(q, k, 2, block, block, Dv=128).bwd_sweeps == sweeps
+    got, want = flash(q, k, v), _plain(q, k, v, 2, scale)
+    assert got.shape == (B, S, 256)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    grads = [jax.grad(lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2))(
+        q, k, v) for fn in (flash, lambda *a: _plain(*a, 2, scale))]
+    for g, wnt in zip(*grads):
+        np.testing.assert_allclose(g, wnt, rtol=1e-4, atol=1e-5)
+    # the zero lanes take no gradient that is not theirs
+    assert not np.asarray(grads[0][1]).reshape(B, S, 2, 256)[..., 192:].any()
+
+
+def test_one_width_callers_build_the_geometry_they_always_did():
+    """A caller that gives no value width: the same blocks, maps and budget
+    as before the mode (``vw`` is ``qw``; the fused sweep's bytes those of
+    two accumulators of one width)."""
+    q = jax.ShapeDtypeStruct((1, 1024, 4 * 128), jnp.bfloat16)
+    g = fa._Geom(q, q, 4, 512, 512)
+    assert (g.vw, g.Dv, g.o_shape, g.dk_shape, g.dv_shape) == (
+        g.qw, g.D, q.shape, q.shape, q.shape)
+    assert fa.fused_sweep_vmem_bytes(16384, 128, 2) == \
+        2 * 16384 * 128 * 4 + 2 * 16384 * 128 * 2 + fa.SCOPED_VMEM
+    # the cell's shape: dk at 256 lanes, dv at 128 fit one sweep
+    assert fa.fused_sweep_vmem_bytes(16384, 256, 2, 128) == \
+        16384 * 384 * 6 + fa.SCOPED_VMEM
+    assert fa.bwd_sweeps(16384, 512, 256, 2, 1, 128) == 1
+
+
+def test_the_mixer_normalises_then_gates(both):
+    """Step 4's order: ``rms_head(o) * o_norm * gate``; Mamba-2's
+    gate-then-norm gives other numbers (the reference's fault)."""
+    _, params, ids, _, _ = both
+    sound = reference.loss(params, {"ids": ids}, MODEL)
+    swapped = reference.loss(params, {"ids": ids}, MODEL,
+                             faults=("gate_before_norm",))
+    assert abs(swapped - sound) > 100 * TOL * sound
+
+
+def _layer_inputs():
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    whole = moe.init_dropless_moe_params(ks[0], 8, 64, 32)
+    whole["router"] = whole["router"] * 3.0
+    whole["ws_gate_up"] = jax.random.normal(ks[2], (64, 96)) / 8
+    whole["ws_down"] = jax.random.normal(ks[3], (48, 64)) / 7
+    bias = 0.1 * jax.random.normal(ks[4], (8,))
+    return whole, jax.random.normal(ks[1], (S, 64)), bias
+
+
+def test_the_shares_routed_parts_and_the_shared_expert_once_are_the_layer():
+    """The PROGRAM's FFN half of a sparse layer on each of the four shares
+    of 2 routed experts: every share computes the shared expert, so the
+    four routed parts summed, plus the shared expert counted ONCE, is the
+    REFERENCE's layer with all 8 experts held."""
+    whole, m, bias = _layer_inputs()
+    cfg = kimi_linear.kimi_linear_tiny_config()
+    routed_want = reference.moe_part(
+        m, whole["router"], bias, whole["we_gate_up"], whole["we_down"], 0,
+        2, 2.446)
+    shared_want = reference.dense_part(m, whole["ws_gate_up"],
+                                       whole["ws_down"])
+
+    def ffn_half(first):
+        share = dict(whole, we_gate_up=whole["we_gate_up"][first:first + 2],
+                     we_down=whole["we_down"][first:first + 2])
+        y, aux = moe.dropless_moe_ffn(
+            share, m, 2, rule=moe.SIGMOID_BIASED, first_held=first,
+            bias=bias, scale=2.446)
+        shared = T.gated_ffn({"w_gate_up": share["ws_gate_up"],
+                              "w_down": share["ws_down"]}, m[None], cfg)[0]
+        return y, shared, aux
+
+    parts = [ffn_half(first) for first in range(0, 8, 2)]
+    for first, (y, shared, aux) in zip(range(0, 8, 2), parts):
+        np.testing.assert_allclose(shared, shared_want, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(y, reference.moe_part(
+            m, whole["router"], bias, whole["we_gate_up"][first:first + 2],
+            whole["we_down"][first:first + 2], first, 2, 2.446),
+            rtol=1e-5, atol=1e-5)
+    assert sum(int(p[2]["rows_held"]) for p in parts) == 2 * S
+    assert all(float(jnp.abs(p[0]).max()) > 0 for p in parts)
+    np.testing.assert_allclose(sum(p[0] for p in parts) + parts[0][1],
+                               routed_want + shared_want, rtol=1e-5,
+                               atol=1e-5)
+    # ... and counted four times it is not
+    assert np.abs(sum(p[0] + p[1] for p in parts)
+                  - (routed_want + shared_want)).max() > 0.1
+
+
+@pytest.fixture(scope="module")
+def witnessed():
+    """A trainer's own logits at the witness's positions, its weights moved
+    as ``both``'s, on ONE sequence (the cell's batch)."""
+    tr = _trainer()
+    params = _seeded_params(tr)
+    tr.state["params"] = jax.tree.map(jnp.asarray, params)
+    ids = _ids(seed=9)[0][:1]
+    program = np.asarray(tr.logits_at(ids, reference.witness_positions(S)))
+    return params, ids, program
+
+
+def test_the_witness_stands_after_the_chunk_edges(witnessed):
+    params, ids, program = witnessed
+    big = reference.witness_groups(16384)
+    assert big["edge"].tolist() == [
+        at + i for at in (64, 512, 4096, 16320) for i in range(8)] + list(
+            range(16376, 16384))
+    assert len(big["spread"]) == 256 and not set(big["edge"]) & set(
+        big["spread"])
+    groups = reference.witness_groups(S)
+    assert groups["edge"].tolist() == [
+        at + i for at in (16, 32, 48) for i in range(8)] + list(range(56, 64))
+    each = reference.position_errors(program, params, {"ids": ids}, MODEL)
+    assert each.shape == (S,) and each.max() < TOL
+    parts = reference.group_errors(program, params, {"ids": ids}, MODEL)
+    assert reference.logits_error(program, params, {"ids": ids}, MODEL) \
+        == max(parts.values())
+
+
+@pytest.mark.parametrize("fault", reference.FAULTS[:-1])
+def test_the_witness_sees_every_fault(witnessed, fault):
+    """Each fault in the reference moves its logits away from the program's
+    by a thousand times what the two differ by when both are sound, at the
+    witness's own statistic."""
+    params, ids, program = witnessed
+    moved = reference.logits_error(program, params, {"ids": ids}, MODEL,
+                                   faults=(fault,))
+    assert moved > 1e3 * TOL
+
+
+def test_bfloat16_throughout_moves_the_reference_s_loss(both):
+    _, params, ids, _, (want, _) = both
+    bad = reference.loss(params, {"ids": ids}, MODEL,
+                         faults=("bfloat16_throughout",))
+    assert abs(bad - float(want)) / float(want) > 2 * TOL
+
+
+def test_the_reference_in_small_pieces_equals_itself_whole(both, monkeypatch):
+    _, params, ids, _, (want, _) = both
+    monkeypatch.setattr(reference, "QUERY_BLOCK", 16)
+    monkeypatch.setattr(reference, "HEAD_GROUP", 1)
+    monkeypatch.setattr(reference, "VOCAB_CHUNK", 100)
+    monkeypatch.setattr(reference, "EXPERT_GROUP", 1)
+    monkeypatch.setattr(reference, "DENSE_CHUNK", 20)
+    loss = reference.forward(jax.tree.map(jnp.asarray, params), ids, MODEL,
+                             keep_logits=False)[0]
+    assert abs(float(loss) - float(want)) / float(want) < 1e-6
+
+
+def test_run_steps_over_two_batches_equals_two_steps():
+    batches = [{"ids": i} for i in _ids(seed=5, n=2)]
+    one, scan = _trainer(remat=True), _trainer(remat=True)
+    singly = [float(one.step(b, 1e-3)) for b in batches]
+    scanned = scan.run_steps(
+        stack_batches(scan.mesh, decoder.BATCH_SPECS, batches), 1e-3)
+    np.testing.assert_allclose(scanned, singly, rtol=1e-5)
+    assert singly[0] != singly[1]
+    for a, b in zip(jax.tree.leaves(one.state["params"]),
+                    jax.tree.leaves(scan.state["params"])):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+
+
+def test_counters_and_gauges_only_under_a_monitor_session(tmp_path):
+    tr = _trainer()
+    assert monitor.active() is None
+    batches = [{"ids": i} for i in _ids(seed=8, n=2)]
+    mon = monitor.enable(str(tmp_path), flight=False)
+    try:
+        reg = mon.registry
+        tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS, batches), 1e-3)
+        # one call a KDA layer body traced: the leading layer's, and ONE a
+        # run of the period (the runs (K, K) and (K)), all on the jnp form
+        calls = reg.counter("monitor.kernels.kda_chunk_calls", fused=0)
+        assert calls.value > 0 and calls.value % 3 == 0
+        mean = reg.gauge("monitor.train.kda_decay_mean").value
+        least = reg.gauge("monitor.train.kda_decay_min").value
+        assert 0 < least < mean < 1
+        # the seeded decays: most channels outlive many chunks
+        assert mean > 0.5
+        assert reg.gauge("monitor.train.moe_load_max_over_mean").value >= 1
+        assert reg.gauge("monitor.train.router_bias_abs_max").value > 0
+        # a filter's three calls a KDA body, refused off whole lane blocks
+        assert reg.counter("monitor.kernels.mamba_filter_calls", fused=0,
+                           halo="zeros").value == calls.value * 3
+    finally:
+        monitor.disable()
+
+
+def test_the_new_scopes_hold_their_instructions():
+    tr = _trainer(remat=True)
+    tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS,
+                               [{"ids": i} for i in _ids(n=2)]), 1e-3)
+    names = devscope.scope_maps()["kimi_linear.run_steps"]
+    got = {devscope.classify(op) for op in names.values()}
+    for scope in ("kda", "kda_chunk", "latent_attention", "shared_expert",
+                  "moe", "router", "mlp", "layer_norm", "lm_head", "embed"):
+        assert ("forward", scope) in got and ("backward", scope) in got, scope
+    for scope in ("kda", "kda_chunk", "latent_attention"):
+        assert ("recompute", scope) in got, scope
+    assert "attention" not in {s for _, s in got}

@@ -17,9 +17,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from paddle_tpu.models import (bert, brumby, jamba, lfm2,  # noqa: E402
-                               mistral4, nemotron_h, olmoe, ouro, resnet,
-                               smallthinker, trinity)
+from paddle_tpu.models import (bert, brumby, jamba, kimi_linear,  # noqa: E402
+                               lfm2, mistral4, nemotron_h, olmoe, ouro,
+                               resnet, smallthinker, trinity)
 from paddle_tpu.parallel import decoder  # noqa: E402
 from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
 from paddle_tpu.parallel.train import stack_batches  # noqa: E402
@@ -61,7 +61,11 @@ from paddle_tpu.parallel.train import stack_batches  # noqa: E402
 # ``wkv_b``'s own columns (``transformer._latent_columns``); the twenty others
 # stand (the six rotary decoders' ``qk_rope`` calls lower to the text, and on
 # the chip to the Mosaic modules, they had:
-# ``tests/test_flash_tpu_compile.py::QK_ROPE_MOSAIC``).
+# ``tests/test_flash_tpu_compile.py::QK_ROPE_MOSAIC``).  PR 58 re-took NONE
+# of the twenty-two: the latent form's new branches (no positions, no query
+# latent, a value width of its own), the layer kind KDA and the flash
+# kernels' value-width mode left every older program's text as it was,
+# Mistral's included; Kimi-Linear's two joined, taken on that PR's tree.
 PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "bert.run_steps": "00de5403506fdc87",
             "olmoe.step": "231114fcd62341f2",
@@ -83,7 +87,9 @@ PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "ouro.step": "770de97dd5bbc8af",
             "ouro.run_steps": "64aaa9a06b2f9fbd",
             "resnet.step": "350db1fba0d68284",
-            "resnet.run_steps": "dc9dd853700f9ab9"}
+            "resnet.run_steps": "dc9dd853700f9ab9",
+            "kimi_linear.step": "00fafaa79be69c29",
+            "kimi_linear.run_steps": "768fb807ebe118b1"}
 OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
          "olmoe": (olmoe.build_olmoe_trainer, olmoe.olmoe_tiny_config, 32),
          "smallthinker": (smallthinker.build_smallthinker_trainer,
@@ -100,7 +106,9 @@ OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
                         nemotron_h.nemotron_h_tiny_config, 64),
          "ouro": (ouro.build_ouro_trainer, ouro.ouro_tiny_config, 64),
          "resnet": (resnet.build_resnet_trainer,
-                    lambda remat: resnet.resnet_tiny_config(), 32)}
+                    lambda remat: resnet.resnet_tiny_config(), 32),
+         "kimi_linear": (kimi_linear.build_kimi_linear_trainer,
+                         kimi_linear.kimi_linear_tiny_config, 64)}
 
 
 @pytest.mark.parametrize("name", list(OLDER))
