@@ -1,6 +1,7 @@
 """The gated DELTA RULE of Kimi Delta Attention (Kimi Linear,
 arXiv:2510.26692) with a decay of its own for every CHANNEL of the key, in
-its chunked form: ``kda_chunked``.
+its chunked form: ``kda_chunk`` (Pallas TPU kernels, forward and backward)
+and ``kda_chunked`` (the same chunks in ``jax.numpy``).
 
 Head by head, from a zero state ``S`` [dk (key), dv (value)] float32, with
 the per-token log-decays ``g_t`` [dk] <= 0 and the write strengths ``beta_t``
@@ -38,7 +39,7 @@ An underflow to 0 is exact enough; an ``inf`` is not, and none is formed.
 (I - A)(I + A^2)(I + A^4)...`` exactly, log2(C) squarings in float32 at the
 highest matmul precision (a row-by-row substitution is C dependent steps).
 
-Two phases.  What a chunk needs of ITSELF (``A``, ``T``, ``W``, ``U``, the
+``kda_chunked`` in two phases.  What a chunk needs of ITSELF (``A``, ``T``, ``W``, ``U``, the
 masked ``q k`` block, the three decayed copies) is made for ``GROUP`` chunks
 at a time, all heads at once, under a ``jax.checkpoint`` of its own (a
 backward holds one group's [SUB, SUB, dk] blocks and never the sequence's);
@@ -46,24 +47,73 @@ then ONE scan over the chunks carries the state: four matrix products a
 chunk.  The scan's backward keeps a state a chunk (``kept_state_bytes``), as
 ``ssd_scan``'s does.
 
-Plain ``jax.numpy``, differentiated by JAX: the path every shape takes
-today.  A Pallas kernel for the chunk bodies (the state in VMEM across a
-sequence's chunks) is ROADMAP.md's; ``count_call("kda_chunk", fused=0)`` at
-the call site says which ran.
+``kda_chunked`` is plain ``jax.numpy``, differentiated by JAX: where the
+kernels below do not take the shapes (``supported``), and the tests' second
+opinion beside ``kda_recurrence``.
+
+THE KERNELS (``kda_chunk``: ``kda_chunk_fwd`` / ``kda_chunk_bwd`` in a
+trace, under a ``jax.custom_vjp``).  Grid (batch, head, step), the last
+sequential; a step walks STEP_STACKS stacks in a loop, a STACK being the
+ROWS = 128 rows of 128 // C chunks, one under another.  q, k, v, g cross the
+door as lane blocks of the arrays the mixer has ([b, S, H * 128]: head h is
+lane block h, nothing is transposed or re-tiled in HBM; g float32), beta as
+a column a head of [b, S, H].  The state lives in VMEM scratch across a
+sequence's chunks, TRANSPOSED ([dv, dk]: a key channel a lane, as the
+decays are).  What a chunk needs of ITSELF is made for a whole stack at
+once, every matrix [ROWS, ROWS] with a chunk's block on its diagonal:
+
+- the decays' rule above taken down to single rows (``_level``): the pair
+  (i, j) belongs to the level whose aligned block of 2 s rows is the
+  smallest that holds both, j in its left half and i in its right; against
+  ``G_m`` at the right half's first row, ``f = exp(-|G - G_m|)`` is ``exp(G_i
+  - G_m)`` on the right and ``exp(G_m - G_j)`` on the left, never over 1, so
+  EVERY pair is a matrix product's (log2(C) products ``[q f; k f] (k f)^T``
+  a stack, each kept where its level's pairs are) and no [SUB, SUB, dk]
+  block is formed;
+- ``T = (I + diag(beta) P)^-1`` by the same squarings as
+  ``_unit_lower_inverse``, float32 on the MXU, the chunks' blocks side by
+  side so that a product's rows are one chunk's;
+- the forward takes ``[U | W] = T [beta v | beta k exp(G)]`` before the state
+  is at hand, so that a chunk's turn at the state is two products: ``X = U
+  - W S``, ``S' = diag(lam) S + kh^T X`` (``o = qt S + Q X`` behind them).
+
+Out: ``o`` in v's type and, for the backward, the state each chunk FOUND,
+float32 [b, chunks, H, dv, dk] (``kept_state_bytes``).  The backward walks
+the stacks in reverse with the state's gradient ``D`` in VMEM, re-makes a
+stack's own parts and ``X = T beta (v - kt S)`` from the kept states, and
+turns at ``D`` with two products a chunk (``dX = Q^T do + kh D``, ``D' =
+do^T qt + diag(lam) D - dX^T W``: ``(beta dr)^T kt = dX^T W``); then, for
+the whole stack, ``dr = T^T dX``, ``dA = -strict_tril(dr X^T)``, ``dQ =
+tril(do X^T)``, beta's and v's gradients, and q's, k's and G's through the
+levels' products by the SAME factors (``G_m``'s own share cancels: ``f_i
+f_j`` does not depend on it); ``dg`` is the reverse running sum of ``dG``
+inside the chunk.  Precision as ``kda_chunked``'s: matrix products take
+operands in q's type and sum in float32; decays, the solve and the carried
+state float32.
 """
 
 import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["kda_recurrence", "kda_chunked", "kept_state_bytes", "SUB",
-           "GROUP"]
+from ._common import CompilerParams as _CompilerParams, on_tpu as _on_tpu
+
+__all__ = ["kda_recurrence", "kda_chunked", "kda_chunk", "supported",
+           "vmem_bytes", "kept_state_bytes", "SUB", "GROUP", "LANES",
+           "ROWS", "STEP_STACKS"]
 
 SUB = 16        # rows of a block inside a chunk
 GROUP = 8       # chunks whose own parts are made at once
+LANES = 128     # the head width the kernels are written for
+ROWS = 128      # rows of a STACK: the chunks whose own parts are made at once
+STEP_STACKS = 8     # stacks a grid step of the kernels walks
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
 
 
 def kept_state_bytes(batch, seq, chunk, heads, dk, dv):
@@ -222,3 +272,411 @@ def kda_chunked(q, k, v, g, beta, *, chunk=64, state=None):
     # [n, b, H, chunk, dv] -> [b, S, H, dv]
     return jnp.moveaxis(o, (0, 3), (1, 2)).reshape(
         b, n * chunk, H, dv)[:, :S]
+
+
+# ---------------------------------------------------------------------------
+# The same chunks as Pallas TPU kernels: ``kda_chunk``
+
+
+def _step_stacks(n):
+    """Stacks a grid step walks of a sequence's ``n``: STEP_STACKS, all of a
+    shorter sequence, 0 where neither is whole."""
+    if n % STEP_STACKS == 0:
+        return STEP_STACKS
+    return n if n < STEP_STACKS else 0
+
+
+def supported(shape, dv, chunk, dtype):
+    """Whether the kernels take keys of ``shape`` = [b, S, H, dk] beside
+    values ``dv`` wide: a head one lane tile wide in both, chunks of whole
+    SUB-row blocks that fill a stack of ROWS rows, whole stacks, whole grid
+    steps of stacks, operands in bfloat16 or float32."""
+    _, S, _, dk = shape
+    return (dk == LANES and dv == LANES and chunk % SUB == 0
+            and ROWS % chunk == 0 and S % ROWS == 0
+            and _step_stacks(S // ROWS) > 0
+            and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
+                                     jnp.dtype(_F32)))
+
+
+def vmem_bytes(chunk, heads, itemsize, stacks=STEP_STACKS):
+    """What a grid step of the BACKWARD (the larger of the two) asks Mosaic
+    for: its pipelined blocks twice (q, k, v, do, dq, dk, dv a head wide in
+    the operands' type; g and dg float32; the write strengths a column a
+    head, padded to a lane tile; the states kept; beta's gradient a row a
+    stack), the state's gradient, and room for a stack's values."""
+    tokens = stacks * ROWS
+    blocks = 2 * (7 * tokens * LANES * itemsize + 2 * tokens * LANES * 4
+                  + tokens * -(-heads // LANES) * LANES * 4
+                  + tokens // chunk * LANES * LANES * 4 + stacks * ROWS * 4)
+    return blocks + LANES * LANES * 4 + 96 * ROWS * LANES * 4 + (8 << 20)
+
+
+def _indices(C):
+    """``row`` [ROWS, 128]: a token's place in its chunk.  ``code`` [ROWS,
+    ROWS] of the pair (i, j) of ONE chunk: ``i ^ j`` (>= 1) under the
+    diagonal, whose highest bit is the half-width of the smallest aligned
+    block that holds both, j in its left half and i in its right; 0 on the
+    diagonal; -1 above it and between chunks."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (ROWS, LANES), 0) & (C - 1)
+    i = jax.lax.broadcasted_iota(jnp.int32, (ROWS, ROWS), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (ROWS, ROWS), 1)
+    return row, jnp.where((j < i) & ((i ^ j) < C), i ^ j,
+                          jnp.where(j == i, 0, -1))
+
+
+def _running(x, row, C, back=False):
+    """The running sum down each chunk's rows of ``x`` [ROWS, 128], a row's
+    own included (``back``: up from the chunk's last row), by log2(C)
+    shifted adds."""
+    s = 1
+    while s < C:
+        if back:
+            x = x + jnp.where(row < C - s, pltpu.roll(x, ROWS - s, 0), 0.0)
+        else:
+            x = x + jnp.where(row >= s, pltpu.roll(x, s, 0), 0.0)
+        s *= 2
+    return x
+
+
+def _level(G, s, row):
+    """The decays of the pairs whose smallest aligned block is 2 ``s`` rows,
+    against ``G_m`` at the first row ``m`` of the block's right half: ``f``
+    = ``exp(-|G - G_m|)``, which is ``exp(G_i - G_m)`` on the right half's
+    rows and ``exp(G_m - G_j)`` on the left's: ``f_i f_j`` = ``exp(G_i -
+    G_j)`` for i right and j left, and no factor over 1 (the module's trap;
+    ``_decay_blocks``' rule taken down to single rows, so that every pair
+    is a matrix product's)."""
+    if 2 * s >= 8:      # whole sublane tiles: a row of G spread over each
+        ref = jnp.concatenate(
+            [jnp.broadcast_to(G[at + s:at + s + 1, :], (2 * s, LANES))
+             for at in range(0, ROWS, 2 * s)], axis=0)
+    else:               # inside a tile: m's row alone, rolled over its block
+        ref, t = jnp.where((row & (2 * s - 1)) == s, G, 0.0), 1
+        while t < s:
+            ref = ref + pltpu.roll(ref, t, 0)
+            t *= 2
+        ref = ref + pltpu.roll(ref, ROWS - s, 0)
+    return jnp.exp(-jnp.abs(G - ref))
+
+
+def _mm32(a, b, dims=(((1,), (0,)), ((), ()))):
+    """A float32 product of float32 operands (the solve's)."""
+    return jax.lax.dot_general(a, b, dims, precision=_HIGHEST,
+                               preferred_element_type=_F32)
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=_F32)
+
+
+def _column(x, at):
+    """Column ``at`` (traced) of a lane-narrow block x [rows, n], as [rows,
+    1]."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.sum(jnp.where(lane == at, x, 0.0), axis=1, keepdims=True)
+
+
+def _rows(parts):
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+
+class _Own:
+    """What the chunks of one STACK (ROWS rows of one head: 128 // C chunks,
+    one under another) need of THEMSELVES, whatever state they find, in the
+    module's notation and all at once: every matrix is [ROWS, ROWS] with a
+    chunk's block on its diagonal and zeros between chunks.  q, k [ROWS, dk]
+    in the operands' type; g [ROWS, dk] and beta [ROWS, 1] float32."""
+
+    def __init__(self, q, k, g, beta, row, code, C):
+        self.C, dt = C, q.dtype
+        self.chunks = [slice(at, at + C) for at in range(0, ROWS, C)]
+        self.qf, self.kf = qf, kf = q.astype(_F32), k.astype(_F32)
+        self.G = G = _running(g, row, C)
+        self.Q = jnp.where(code == 0, jnp.sum(qf * kf, axis=1, keepdims=True),
+                           0.0)
+        self.P = jnp.zeros((ROWS, ROWS), _F32)
+        self.levels, s = [], C // 2
+        while s:
+            # the level's half-width, where its pairs lie, the factor and
+            # the decayed ``q f`` and ``k f`` in the operands' type
+            f = _level(G, s, row)
+            self.levels.append((s, (code >= s) & (code < 2 * s), f,
+                                (qf * f).astype(dt), (kf * f).astype(dt)))
+            s //= 2
+        for _, here, _, qd, kd in self.levels:
+            # every pair of a right half's row with a left half's of ANY
+            # block (all finite: no factor is over 1); the level's are kept
+            pairs = _dot(jnp.concatenate([qd, kd], axis=0), kd, _NT)
+            self.Q = self.Q + jnp.where(here, pairs[:ROWS], 0.0)
+            self.P = self.P + jnp.where(here, pairs[ROWS:], 0.0)
+        self.T = self.inverse(beta * self.P)
+        lasts = [G[c.stop - 1:c.stop, :] for c in self.chunks]
+        self.lam = [jnp.exp(last) for last in lasts]        # [1, dk] each
+        self.eG = jnp.exp(G)
+        self.eH = jnp.exp(_rows([jnp.broadcast_to(last, (C, LANES))
+                                 for last in lasts]) - G)
+        self.kt, self.qt, self.kh = kf * self.eG, qf * self.eG, kf * self.eH
+
+    def inverse(self, A):
+        """``(I + A)^-1`` [ROWS, ROWS] of the chunks' strictly lower blocks
+        on ``A``'s diagonal, as ``_unit_lower_inverse``: ``(I - A)(I +
+        A^2)(I + A^4)...`` in float32 on the MXU, with the chunks' blocks
+        SIDE BY SIDE ([C, ROWS]) on a product's left, so that its rows are
+        one chunk's, and on a diagonal on its right."""
+        C = self.C
+        power = sum((A[c] for c in self.chunks[1:]), A[self.chunks[0]])
+        lane = jax.lax.broadcasted_iota(jnp.int32, power.shape, 1)
+        place = jax.lax.broadcasted_iota(jnp.int32, power.shape, 0)
+        own = [(lane >= c.start) & (lane < c.stop) for c in self.chunks]
+
+        def spread(side):
+            """The blocks side by side -> each on the diagonal."""
+            return _rows([jnp.where(mine, side, 0.0) for mine in own])
+
+        inv = jnp.where((lane & (C - 1)) == place, 1.0, 0.0) - power
+        wide, span = spread(power), 2
+        while span < C:
+            power = _mm32(power, wide)
+            wide = spread(power)
+            inv = inv + _mm32(inv, wide)
+            span *= 2
+        return spread(inv)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest, chunk,
+                save):
+    """The state TRANSPOSED, [dv, dk]: a key channel a lane, as the decays
+    are.  ``X = U - W S`` with ``[U | W] = T [beta v | beta kt]`` made
+    before the state is at hand: a chunk's turn at the state is two
+    products."""
+    if save:
+        kept_ref, s_ref = rest
+    else:
+        (s_ref,) = rest
+    dt = q_ref.dtype
+    head = pl.program_id(1)
+    row, code = _indices(chunk)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    def one(p, carry):
+        at = pl.ds(pl.multiple_of(p * ROWS, ROWS), ROWS)
+        beta = _column(beta_ref[at, :], head)
+        own = _Own(q_ref[at, :], k_ref[at, :], g_ref[at, :], beta, row,
+                   code, chunk)
+        uw = _mm32(own.T, jnp.concatenate(
+            [beta * v_ref[at, :].astype(_F32), beta * own.kt], axis=1))
+        U, W = uw[:, :LANES], uw[:, LANES:].astype(dt)
+        qt, kh = own.qt.astype(dt), own.kh.astype(dt)
+        reads, xs = [], []
+        for c, rows in enumerate(own.chunks):
+            st = s_ref[...]
+            if save:
+                kept_ref[p * len(own.chunks) + c] = st
+            sb = st.astype(dt)
+            x = (U[rows] - _dot(W[rows], sb, _NT)).astype(dt)
+            reads.append(_dot(qt[rows], sb, _NT))
+            s_ref[...] = st * own.lam[c] + _dot(x, kh[rows], _TN)
+            xs.append(x)
+        o_ref[at, :] = (_rows(reads) + _dot(own.Q.astype(dt), _rows(xs))
+                        ).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, q_ref.shape[0] // ROWS, one, 0)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, kept_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, d_ref, *, chunk):
+    """The stacks from the last to the first, each chunk from the state it
+    FOUND; ``D`` [dv, dk] the gradient of the state a chunk leaves.  A
+    chunk's turn at ``D`` is two products: ``dX = Q^T do + kh D`` and ``D'
+    = do^T qt + diag(lam) D - dX^T W`` (``W = T beta kt``: ``(beta dr)^T kt
+    = dX^T W``); everything else of the module's docstring is made for the
+    whole stack behind it."""
+    C, dt = chunk, q_ref.dtype
+    n = q_ref.shape[0] // ROWS
+    head = pl.program_id(1)
+    row, code = _indices(C)
+    which = jax.lax.broadcasted_iota(jnp.int32, (n, ROWS), 0)
+
+    @pl.when(pl.program_id(2) == 0)                 # the LAST stacks
+    def _():
+        d_ref[...] = jnp.zeros_like(d_ref)
+
+    def one(t, dbetas):
+        p = n - 1 - t
+        at = pl.ds(pl.multiple_of(p * ROWS, ROWS), ROWS)
+        beta = _column(beta_ref[at, :], head)
+        own = _Own(q_ref[at, :], k_ref[at, :], g_ref[at, :], beta, row,
+                   code, C)
+        qf, kf, do = own.qf, own.kf, do_ref[at, :]
+        qt, kt, kh = (a.astype(dt) for a in (own.qt, own.kt, own.kh))
+        W = _mm32(own.T, beta * own.kt).astype(dt)
+        found = [kept_ref[p * len(own.chunks) + c]
+                 for c in range(len(own.chunks))]
+        sbs = [st.astype(dt) for st in found]
+        rr = v_ref[at, :].astype(_F32) - _rows(
+            [_dot(kt[rows], sb, _NT) for rows, sb in zip(own.chunks, sbs)])
+        xb = _mm32(own.T, beta * rr).astype(dt)
+        qdo = _dot(own.Q.astype(dt), do, _TN)
+        D = d_ref[...]
+        dX, dkh, ends = ([None] * len(own.chunks) for _ in range(3))
+        for c, rows in reversed(list(enumerate(own.chunks))):
+            db = D.astype(dt)
+            dX[c] = qdo[rows] + _dot(kh[rows], db, _NT)
+            dkh[c] = _dot(xb[rows], db)
+            # d G_C: through kh = k exp(G_C - G) and lam = exp(G_C)
+            ends[c] = jnp.broadcast_to(
+                jnp.sum(own.kh[rows] * dkh[c], axis=0, keepdims=True)
+                + own.lam[c] * jnp.sum(found[c] * D, axis=0, keepdims=True),
+                (C, LANES))
+            D = _dot(do[rows], qt[rows], _TN) + D * own.lam[c] \
+                - _dot(dX[c].astype(dt), W[rows], _TN)
+        d_ref[...] = D
+        dX, dkh = _rows(dX), _rows(dkh)
+        dr = _mm32(own.T, dX, _TN)
+        bdr = beta * dr
+        bdrb = bdr.astype(dt)
+        dQ = jnp.where(code >= 0, _dot(do, xb, _NT), 0.0)
+        dA = -jnp.where(code >= 1, _dot(dr.astype(dt), xb, _NT), 0.0)
+        dbeta = jnp.sum(dr * rr, axis=1, keepdims=True) \
+            + jnp.sum(dA * own.P, axis=1, keepdims=True)
+        dkt = -_rows([_dot(bdrb[rows], sb)
+                      for rows, sb in zip(own.chunks, sbs)])
+        dqt = _rows([_dot(do[rows], sb)
+                     for rows, sb in zip(own.chunks, sbs)])
+        on = jnp.sum(jnp.where(code == 0, dQ, 0.0), axis=1, keepdims=True)
+        dq = dqt * own.eG + on * kf
+        dk = dkt * own.eG + dkh * own.eH + on * qf
+        dG = own.qt * dqt + own.kt * dkt - own.kh * dkh \
+            + jnp.where(row == C - 1, _rows(ends), 0.0)
+        dP = beta * dA
+        for _, here, f, qd, kd in own.levels:
+            m = jnp.concatenate([jnp.where(here, dQ, 0.0),
+                                 jnp.where(here, dP, 0.0)],
+                                axis=0).astype(dt)
+            # the rows' share (right halves: elsewhere m's rows are zero)
+            # and the columns' (left halves)
+            dl = _dot(m, kd)
+            dc = _dot(m, jnp.concatenate([qd, kd], axis=0), _TN)
+            dq = dq + dl[:ROWS] * f
+            dk = dk + (dl[ROWS:] + dc) * f
+            # G_m's own share cancels: f_i f_j does not depend on it
+            dG = dG + f * (dl[:ROWS] * qf + (dl[ROWS:] - dc) * kf)
+        dq_ref[at, :] = dq.astype(dq_ref.dtype)
+        dk_ref[at, :] = dk.astype(dk_ref.dtype)
+        dv_ref[at, :] = bdr.astype(dv_ref.dtype)
+        dg_ref[at, :] = _running(dG, row, C, back=True)
+        # the stack's column as a row of the step's [stacks, ROWS]
+        as_row = jnp.sum(jnp.where(code == 0, dbeta, 0.0), axis=0,
+                         keepdims=True)
+        return jnp.where(which == p, as_row, dbetas)
+
+    dbeta_ref[...] = jax.lax.fori_loop(0, n, one,
+                                       jnp.zeros((n, ROWS), _F32))
+
+
+class _Geom:
+    """The shapes of one call and its block specs; ``flip`` walks the grid
+    steps from the end."""
+
+    def __init__(self, q, heads, chunk, flip=False):
+        self.B, self.S, width = q.shape
+        assert width == heads * LANES and supported(
+            (self.B, self.S, heads, LANES), LANES, chunk, q.dtype), \
+            (q.shape, heads, chunk, q.dtype)
+        stacks = _step_stacks(self.S // ROWS)
+        steps = self.S // ROWS // stacks
+        at = (lambda i: steps - 1 - i) if flip else (lambda i: i)
+        tokens = stacks * ROWS
+        self.head = pl.BlockSpec((None, tokens, LANES),
+                                 lambda b, h, i: (b, at(i), h))
+        self.beta = pl.BlockSpec((None, tokens, heads),
+                                 lambda b, h, i: (b, at(i), 0))
+        self.kept = pl.BlockSpec(
+            (None, tokens // chunk, None, LANES, LANES),
+            lambda b, h, i: (b, at(i), h, 0, 0))
+        self.dbeta = pl.BlockSpec((None, None, stacks, ROWS),
+                                  lambda b, h, i: (b, h, at(i), 0))
+        self.grid = (self.B, heads, steps)
+        # the state, or its gradient
+        self.scratch = [pltpu.VMEM((LANES, LANES), _F32)]
+        self.params = _CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_bytes(chunk, heads, q.dtype.itemsize,
+                                        stacks))
+
+
+def _fwd(q, k, v, g, beta, static, save):
+    heads, chunk, interpret = static
+    geom = _Geom(q, heads, chunk)
+    out_specs = [geom.head]
+    out_shape = [jax.ShapeDtypeStruct(v.shape, v.dtype)]
+    if save:
+        out_specs.append(geom.kept)
+        out_shape.append(jax.ShapeDtypeStruct(
+            (geom.B, geom.S // chunk, heads, LANES, LANES), _F32))
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, chunk=chunk, save=save),
+        grid=geom.grid,
+        in_specs=[geom.head] * 4 + [geom.beta],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=geom.scratch,
+        compiler_params=geom.params, interpret=interpret,
+        name="kda_chunk_fwd",
+    )(q, k, v, g, beta)
+
+
+def _bwd(static, res, do):
+    heads, chunk, interpret = static
+    q, k, v, g, beta, kept = res
+    geom = _Geom(q, heads, chunk, flip=True)
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
+    dq, dk, dv, dg, dbeta = pl.pallas_call(
+        functools.partial(_bwd_kernel, chunk=chunk),
+        grid=geom.grid,
+        in_specs=[geom.head] * 4 + [geom.beta, geom.head, geom.kept],
+        out_specs=[geom.head] * 4 + [geom.dbeta],
+        out_shape=[like(q), like(k), like(v), like(g),
+                   jax.ShapeDtypeStruct(
+                       (geom.B, heads, geom.S // ROWS, ROWS), _F32)],
+        scratch_shapes=geom.scratch,
+        compiler_params=geom.params, interpret=interpret,
+        name="kda_chunk_bwd",
+    )(q, k, v, g, beta, do, kept)
+    # [B, H, stacks, ROWS] -> [B, S, H]
+    return dq, dk, dv, dg, dbeta.reshape(geom.B, heads, geom.S).swapaxes(1, 2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _delta(q, k, v, g, beta, static):
+    return _fwd(q, k, v, g, beta, static, False)[0]
+
+
+def _delta_fwd(q, k, v, g, beta, static):
+    o, kept = _fwd(q, k, v, g, beta, static, True)
+    return o, (q, k, v, g, beta, kept)
+
+
+_delta.defvjp(_delta_fwd, _bwd)
+
+
+def kda_chunk(q, k, v, g, beta, *, heads, chunk=64, interpret=None):
+    """``kda_chunked`` from a zero state by the kernels, on the arrays as the
+    mixer has them: q, k, v, g [b, S, heads * 128] (head h is lane block h;
+    g float32), beta [b, S, heads] float32; the outputs [b, S, heads * 128]
+    in v's type, at ``kda_chunked``'s precision (``supported`` must hold).
+    Differentiable in all five; what the backward needs is the operands and
+    the state each chunk found (``kept_state_bytes``)."""
+    b, S, width = k.shape
+    assert width == heads * LANES and q.shape == v.shape == g.shape \
+        == k.shape and k.dtype == v.dtype == q.dtype and supported(
+            (b, S, heads, LANES), LANES, chunk, q.dtype), \
+        (q.shape, k.shape, v.shape, g.shape, heads, chunk, q.dtype)
+    if interpret is None:
+        interpret = not _on_tpu()
+    return _delta(q, k, v, g.astype(_F32), beta.astype(_F32),
+                  (int(heads), int(chunk), bool(interpret)))

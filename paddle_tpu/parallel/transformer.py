@@ -1764,18 +1764,28 @@ def kda_mixer(pl, h, cfg):
     k = _l2_heads(k, nh, 1.0).astype(h.dtype)
     beta = jax.nn.sigmoid(jnp.matmul(
         h, pl["w_beta"], preferred_element_type=jnp.float32))
-    count_call("kda_chunk", fused=0)
+    fused = kda_chunk.supported(k.shape, d, cfg.kda_chunk, k.dtype)
+    count_call("kda_chunk", fused=int(fused))
     with jax.named_scope(devscope.KDA_CHUNK):
-        # under a checkpoint of its own: what the chunked form keeps for its
-        # backward (the chunks' own parts, a state a chunk: 1.7 GB at
-        # [16384, 32 x 128]) then stands only while that backward runs, not
-        # beside the FFN's residuals through the layer's.  The price is a
-        # third forward of the delta rule; without it the cell's step needs
-        # 15.90 GB of the chip's 15.75 GiB, with the scan alone under one
-        # 16.22 GB where this reads 15.29 (PERF.md section 6, PR 58)
-        o = jax.checkpoint(functools.partial(
-            kda_chunk.kda_chunked, chunk=cfg.kda_chunk))(
-                q, k, v.reshape(b, S, nh, d), kda_log_decay(pl, h, cfg), beta)
+        if fused:
+            # the kernels read a head as a lane block of [b, S, heads x d]
+            # and keep the operands (the layer's remat holds them anyway)
+            # and a state a chunk: 537 MB at [16384, 32 x 128]
+            flat = (b, S, nh * d)
+            o = kda_chunk.kda_chunk(
+                q.reshape(flat), k.reshape(flat), v,
+                kda_log_decay(pl, h, cfg).reshape(flat), beta, heads=nh,
+                chunk=cfg.kda_chunk).reshape(b, S, nh, d)
+        else:
+            # under a checkpoint of its own: what the ``jnp`` form keeps for
+            # its backward (the chunks' own parts, a state a chunk: 1.7 GB
+            # at [16384, 32 x 128]) then stands only while that backward
+            # runs, not beside the FFN's residuals through the layer's, at
+            # the price of a third forward (PERF.md section 6, PR 58)
+            o = jax.checkpoint(functools.partial(
+                kda_chunk.kda_chunked, chunk=cfg.kda_chunk))(
+                    q, k, v.reshape(b, S, nh, d), kda_log_decay(pl, h, cfg),
+                    beta)
     gate = jax.nn.sigmoid(jnp.matmul(
         h @ pl["w_ga"], pl["w_gb"], preferred_element_type=jnp.float32))
     o = o.astype(jnp.float32)

@@ -1,6 +1,7 @@
-"""The delta rule's chunked form (``kernels/kda_chunk.py:kda_chunked``),
-compiled on the chip with the model's operands (q, k, v in bf16, log-decays
-and write strengths float32), against the recurrence a token at a time in
+"""The delta rule's kernels (``kernels/kda_chunk.py:kda_chunk``: Pallas,
+forward and backward) on the chip with the model's operands (q, k, v in
+bf16, log-decays and write strengths float32), against the same chunks in
+``jnp`` (``kda_chunked``) and against the recurrence a token at a time in
 float32 at ``highest`` precision (``kda_recurrence``), at the Kimi-Linear
 cell's shapes:
 
@@ -14,14 +15,13 @@ cell's shapes:
 - a control with the state dropped at every chunk edge (each chunk run from
   a zero state), which must NOT pass at decays near one;
 - the milliseconds a call of the forward and of forward + backward at the
-  whole shape (host clock around ``block_until_ready``, the median of
-  CALLS), beside the least the requirement's bytes allow
+  whole shape, kernels and ``jnp`` form (host clock around
+  ``block_until_ready``, the median of CALLS), beside the least the
+  requirement's bytes allow
   (``benchmark/flops/kimi_linear_train.py:delta_rule``).
 
-No Pallas kernel ships for the chunk bodies (ROADMAP.md, Speed queue): when
-one does, this receipt is what holds it to ``kda_chunked``.  One JSON line,
-kept under ``chiprun_out/pr58/``.  Exit 1 where a reading is off, 2 off a
-TPU."""
+One JSON line, kept under ``chiprun_out/pr59/``.  Exit 1 where a reading is
+off, 2 off a TPU."""
 
 import json
 import math
@@ -48,7 +48,7 @@ NAMES = ("q", "k", "v", "g", "beta")
 # gradient is a sum of both signs over every later token of the chunk
 LIMIT, DECAY_LIMIT = 2e-2, 6e-2
 CALLS = 5
-OUT = os.path.join(ROOT, "chiprun_out", "pr58", "kda_chunk_receipt.json")
+OUT = os.path.join(ROOT, "chiprun_out", "pr59", "kda_chunk_receipt.json")
 
 
 def _rel(got, want):
@@ -79,11 +79,31 @@ def edges_dropped(q, k, v, g, beta):
     return o.reshape(v.shape)
 
 
+def kernels(q, k, v, g, beta):
+    """``kda_chunk`` on operands shaped as ``kda_chunked``'s: a head a lane
+    block of [b, S, heads x 128] at the kernels' door."""
+    flat = lambda a: a.reshape(a.shape[:2] + (-1,))         # noqa: E731
+    return K.kda_chunk(flat(q), flat(k), flat(v), flat(g), beta, heads=H,
+                       chunk=CHUNK).reshape(v.shape)
+
+
+def _ms(fn, args):
+    jax.block_until_ready(fn(*args))
+    took = []
+    for _ in range(CALLS):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        took.append((time.perf_counter() - t0) * 1e3)
+    return float(np.median(took))
+
+
 def main(out_path=OUT):
     if jax.devices()[0].platform != "tpu":
         print("kda_chunk_receipt: no TPU here")
         return 2
-    chunked = jax.jit(lambda *a: K.kda_chunked(*a, chunk=CHUNK))
+    assert K.supported((B, S, H, D), D, CHUNK, jnp.bfloat16)
+    forms = {"kernel": kernels,
+             "jnp": lambda *a: K.kda_chunked(*a, chunk=CHUNK)}
     recurrence = jax.jit(K.kda_recurrence)
     out = {"shape": [B, S, H, D], "chunk": CHUNK, "limit": LIMIT,
            "decay_limit": DECAY_LIMIT, "device": jax.devices()[0].device_kind,
@@ -91,11 +111,15 @@ def main(out_path=OUT):
     ok = True
     for decays in DECAYS:
         args = operands(S, decays)
-        got = chunked(*args)
-        err = _rel(got, recurrence(*args))
+        want = recurrence(*args)
+        got = jax.jit(forms["kernel"])(*args)
         finite = bool(jnp.isfinite(got.astype(jnp.float32)).all())
-        out["outputs"][decays] = {"relative_error": err, "finite": finite}
-        ok &= finite and err < LIMIT
+        out["outputs"][decays] = {
+            "relative_error": _rel(got, want), "finite": finite,
+            "against_jnp": _rel(got, jax.jit(forms["jnp"])(*args)),
+            "jnp_relative_error": _rel(jax.jit(forms["jnp"])(*args), want)}
+        ok &= finite and all(out["outputs"][decays][n] < LIMIT for n in (
+            "relative_error", "against_jnp"))
         short = operands(S_COMPARED, decays, seed=1)
         w = jax.random.normal(jax.random.PRNGKey(7), (B, S_COMPARED, H, D))
 
@@ -104,29 +128,31 @@ def main(out_path=OUT):
                 fn(*a).astype(jnp.float32) * w), argnums=(0, 1, 2, 3, 4)))(
                     *short)
 
-        got_g, want_g = grads(lambda *a: K.kda_chunked(*a, chunk=CHUNK)), \
-            grads(K.kda_recurrence)
+        got_g, jnp_g, want_g = (grads(fn) for fn in (
+            forms["kernel"], forms["jnp"], K.kda_recurrence))
         out["gradients"][decays] = {
-            n: _rel(a, b) for n, a, b in zip(NAMES, got_g, want_g)}
-        ok &= all(e < (DECAY_LIMIT if n == "g" else LIMIT)
-                  for n, e in out["gradients"][decays].items())
-        out["control"][decays] = _rel(jax.jit(edges_dropped)(*args),
-                                      recurrence(*args))
+            n: {"relative_error": _rel(a, c), "against_jnp": _rel(a, b),
+                "jnp_relative_error": _rel(b, c)}
+            for n, a, b, c in zip(NAMES, got_g, jnp_g, want_g)}
+        ok &= all(e[key] < (DECAY_LIMIT if n == "g" else LIMIT)
+                  for n, e in out["gradients"][decays].items()
+                  for key in ("relative_error", "against_jnp"))
+        out["control"][decays] = _rel(jax.jit(edges_dropped)(*args), want)
     ok &= out["control"]["near_one"] > 10 * LIMIT
     # the time of a call at the whole shape, seeded decays' mix
     args = operands(S, "near_one", seed=2)
     w = jax.random.normal(jax.random.PRNGKey(8), (B, S, H, D), jnp.bfloat16)
-    both = jax.jit(jax.grad(lambda *a: jnp.sum(
-        (K.kda_chunked(*a, chunk=CHUNK) * w).astype(jnp.float32)),
-        argnums=(0, 1, 2, 3, 4)))
-    for name, fn in (("forward", chunked), ("forward_and_backward", both)):
-        jax.block_until_ready(fn(*args))
-        took = []
-        for _ in range(CALLS):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(*args))
-            took.append((time.perf_counter() - t0) * 1e3)
-        out["ms"][name] = float(np.median(took))
+    flat = lambda a: a.reshape(a.shape[:2] + (-1,))         # noqa: E731
+    # each form on the arrays as it takes them at the mixer's door: the
+    # kernels a head a lane block (re-laying [b, S, H, d] out is a copy)
+    timed = {"kernel": (lambda *a: K.kda_chunk(*a, heads=H, chunk=CHUNK),
+                        tuple(flat(a) for a in args[:4]) + args[4:], flat(w)),
+             "jnp": (forms["jnp"], args, w)}
+    for form, (fn, operands_, w_) in timed.items():
+        both = jax.jit(jax.grad(lambda *a, fn=fn, w_=w_: jnp.sum(
+            (fn(*a) * w_).astype(jnp.float32)), argnums=(0, 1, 2, 3, 4)))
+        out["ms"][form] = {"forward": _ms(jax.jit(fn), operands_),
+                           "forward_and_backward": _ms(both, operands_)}
     model = {"linear_attn_config": {"num_heads": H, "head_dim": D}}
     need = kimi_linear_train.delta_rule(model, B * S)
     peaks = PEAKS["TPU v5 lite"]

@@ -548,6 +548,43 @@ def test_the_ssd_scan_compiles_for_a_v5e(one_chip, what, shape, heads, groups,
         assert took < asked < 64 * 2 ** 20, (what, kernel, took, asked)
 
 
+@pytest.mark.parametrize("what,shape,chunk,dtype", [
+    ("kimi_linear_48b_a3b.s16384_scan", (1, 16384, 32, 128), 64,
+     jnp.bfloat16),
+    ("float32 operands, four chunks a stack, one grid step",
+     (2, 512, 4, 128), 32, jnp.float32),
+])
+def test_the_kda_chunk_kernels_compile_for_a_v5e(one_chip, what, shape,
+                                                 chunk, dtype):
+    """Both kernels of the chunked delta rule through Mosaic at the cell's
+    shape (32 heads of 128, a lane block each of the mixer's [b, S, 4096]
+    arrays, 256 chunks of 64 a head in eight-stack grid steps) and in
+    float32 at chunks of 32, within the VMEM their call asks for."""
+    kda = importlib.import_module("paddle_tpu.kernels.kda_chunk")
+    b, S, H, d = shape
+
+    def sds(shape_, dtype_):
+        return jax.ShapeDtypeStruct(shape_, dtype_, sharding=one_chip)
+
+    flat = (b, S, H * d)
+    args = (sds(flat, dtype),) * 3 + (sds(flat, jnp.float32),
+                                      sds((b, S, H), jnp.float32))
+
+    def both(*a):
+        out, vjp = jax.vjp(lambda *q: kda.kda_chunk(
+            *q, heads=H, chunk=chunk, interpret=False), *a[:-1])
+        return (out,) + vjp(a[-1])
+
+    assert kda.supported(shape, d, chunk, dtype)
+    text = jax.jit(both).lower(*args, sds(flat, dtype)).compile().as_text()
+    for kernel in ("kda_chunk_fwd", "kda_chunk_bwd"):
+        asked, took = _vmem(text, kernel)
+        assert asked == kda.vmem_bytes(
+            chunk, H, jnp.dtype(dtype).itemsize,
+            kda._step_stacks(S // kda.ROWS))
+        assert took < asked < 64 * 2 ** 20, (what, kernel, took, asked)
+
+
 QK_ROPE_CELLS = {   # batch, positions, query heads, kv heads, head width, norm
     "trinity_large_preview.s6144_scan": (1, 6144, 48, 8, 128, "head"),
     "olmoe_1b_7b.s4096_scan": (4, 4096, 16, 16, 128, "whole"),

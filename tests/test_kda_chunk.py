@@ -20,28 +20,33 @@ DECAYS = {"extreme": (math.log(16.0), 0.7), "near_one": (0.0, 1e-3)}
 NAMES = ("q", "k", "v", "g", "beta")
 
 
-def _operands(S, decays, seed=1):
+def _operands(S, decays, seed=1, shape=(B, H, DK, DV), dtype=jnp.float32):
     """q (L2-normalised, scaled), k (L2-normalised), v, g <= 0 and beta in
-    (0, 1) as the mixer hands them over, float32."""
+    (0, 1) as the mixer hands them over: q, k, v in ``dtype``, g and beta
+    float32; ``shape`` = (batch, heads, dk, dv)."""
+    b, h, dk, dv = shape
     a_log, step = DECAYS[decays]
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    q = jax.random.normal(ks[0], (B, S, H, DK))
-    k = jax.random.normal(ks[1], (B, S, H, DK))
-    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * DK ** -0.5
+    q = jax.random.normal(ks[0], (b, S, h, dk))
+    k = jax.random.normal(ks[1], (b, S, h, dk))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * dk ** -0.5
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    v = jax.random.normal(ks[2], (B, S, H, DV))
+    v = jax.random.normal(ks[2], (b, S, h, dv))
     g = -math.exp(a_log) * jax.nn.softplus(
-        0.3 * jax.random.normal(ks[3], (B, S, H, DK))
+        0.3 * jax.random.normal(ks[3], (b, S, h, dk))
         + math.log(math.expm1(step)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, H)))
-    return q, k, v, g, beta
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, S, h)))
+    return tuple(a.astype(dtype) for a in (q, k, v)) + (g, beta)
 
 
 def _recurrence64(q, k, v, g, beta, state=None):
-    """The delta rule a token at a time in float64 numpy: outputs and the
-    last state."""
-    q, k, v, g, beta = (np.asarray(a, np.float64) for a in (q, k, v, g, beta))
-    S_ = np.zeros((B, H, DK, DV)) if state is None else state
+    """The delta rule a token at a time in float64 numpy, of what the
+    operands hold (bf16: of their rounded values): outputs and the last
+    state."""
+    q, k, v, g, beta = (np.asarray(jnp.asarray(a, jnp.float32), np.float64)
+                        for a in (q, k, v, g, beta))
+    S_ = np.zeros(k.shape[:1] + k.shape[2:] + v.shape[-1:]) \
+        if state is None else state
     out = np.zeros(v.shape)
     for t in range(q.shape[1]):
         S_ = S_ * np.exp(g[:, t])[..., None]
@@ -154,3 +159,178 @@ def test_bf16_operands_stay_near_float32_and_finite():
 def test_kept_state_bytes_is_a_state_a_chunk_and_head():
     assert K.kept_state_bytes(1, 16384, 64, 32, 128, 128) == 256 * 32 * 65536
     assert K.kept_state_bytes(2, 200, 64, 3, 16, 32) == 2 * 4 * 3 * 16 * 32 * 4
+
+
+# ---------------------------------------------------------------------------
+# The Pallas kernels (``kda_chunk``), in interpret mode: a head 128 wide
+
+KB, KH, KD = 1, 2, K.LANES
+
+
+def _kernel_operands(S, decays, seed=4, dtype=jnp.float32, heads=KH):
+    """``_operands`` at the kernels' head width."""
+    return _operands(S, decays, seed, (KB, heads, KD, KD), dtype)
+
+
+def _kernels(q, k, v, g, beta, chunk=CHUNK):
+    """``kda_chunk`` on operands shaped as ``kda_chunked``'s: a head a lane
+    block of [b, S, heads x 128] at the kernels' door."""
+    flat = lambda a: a.reshape(a.shape[:2] + (-1,))         # noqa: E731
+    return K.kda_chunk(flat(q), flat(k), flat(v), flat(g), beta,
+                       heads=k.shape[2], chunk=chunk).reshape(v.shape)
+
+
+# float32 operands: the kernels ARE the chunked form; bf16: both stand as far
+# from the float64 recurrence as bf16 operands of a chain of products do
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("decays", sorted(DECAYS))
+def test_the_kernels_outputs_equal_the_chunked_form_and_the_recurrence(
+        decays, dtype, tol):
+    args = _kernel_operands(4 * CHUNK, decays, dtype=dtype)
+    got = _kernels(*args)
+    assert got.dtype == dtype and got.shape == args[2].shape
+    got = np.asarray(got.astype(jnp.float32))
+    want, _ = _recurrence64(*args)
+    jnp_form = np.asarray(K.kda_chunked(*args, chunk=CHUNK)
+                          .astype(jnp.float32))
+    assert np.isfinite(got).all()
+    for other in (want, jnp_form):
+        assert np.abs(got - other).max() < tol * np.abs(want).max()
+
+
+@pytest.fixture(scope="module", params=[
+    (d, t) for d in sorted(DECAYS) for t in ("float32", "bfloat16")],
+    ids=lambda p: "-".join(p))
+def kernel_gradients(request):
+    """Of sum(o * w) over four chunks (two stacks): by the kernels, by the
+    chunked form and by the recurrence."""
+    decays, dtype = request.param
+    S = 4 * CHUNK
+    args = _kernel_operands(S, decays, seed=5, dtype=jnp.dtype(dtype))
+    w = jax.random.normal(jax.random.PRNGKey(10), (KB, S, KH, KD))
+    grad = lambda fn: jax.grad(                      # noqa: E731
+        lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
+        argnums=(0, 1, 2, 3, 4))(*args)
+    return (decays, dtype,
+            grad(_kernels),
+            grad(lambda *a: K.kda_chunked(*a, chunk=CHUNK)),
+            grad(K.kda_recurrence))
+
+
+@pytest.mark.parametrize("at", range(5), ids=NAMES)
+def test_every_gradient_of_the_kernels_equals_both_forms(kernel_gradients,
+                                                         at):
+    """float32: 2e-5 of the largest entry (the log-decays' at the extremes
+    3e-4, as the chunked form's own: the running sum's transpose adds up
+    what should cancel).  bf16 operands: 3e-2, 6e-2 for the log-decays (the
+    chip receipt's limits)."""
+    decays, dtype, got, jnp_form, want = kernel_gradients
+    g = np.asarray(got[at].astype(jnp.float32))
+    assert got[at].dtype == want[at].dtype if at > 2 else True
+    assert np.isfinite(g).all()
+    if dtype == "float32":
+        tol = 3e-4 if (NAMES[at], decays) == ("g", "extreme") else 2e-5
+    else:
+        tol = 6e-2 if NAMES[at] == "g" else 3e-2
+    for other in (jnp_form, want):
+        o = np.asarray(other[at].astype(jnp.float32))
+        assert np.abs(o).max() > 0
+        np.testing.assert_allclose(g, o, rtol=0, atol=tol * np.abs(o).max())
+
+
+def test_the_kernels_carry_over_eight_chunks_with_a_given_state():
+    """Thirty-two chunks, two grid steps of eight stacks of two: the second
+    half's outputs are the chunked form's FROM the state the first half left
+    (float64 recurrence), and far from a run of the second half alone, its
+    last eight chunks still."""
+    S = 32 * CHUNK
+    args = _kernel_operands(S, "near_one", seed=6, heads=1)
+    args = args[:4] + (0.02 * args[4],)
+    assert S // K.ROWS == 2 * K._step_stacks(S // K.ROWS)
+    whole = np.asarray(_kernels(*args))
+    tail = tuple(a[:, S // 2:] for a in args)
+    alone = np.asarray(_kernels(*tail))
+    scale = np.abs(whole[:, S // 2:]).max()
+    assert np.abs(alone - whole[:, S // 2:]).max() > 0.2 * scale
+    _, state = _recurrence64(*(a[:, :S // 2] for a in args))
+    carried = np.asarray(K.kda_chunked(
+        *tail, chunk=CHUNK, state=jnp.asarray(state, jnp.float32)))
+    np.testing.assert_allclose(whole[:, S // 2:], carried, rtol=1e-4,
+                               atol=1e-5 * scale)
+    assert np.abs(alone[:, -8 * CHUNK:]
+                  - whole[:, -8 * CHUNK:]).max() > 0.05 * scale
+
+
+@pytest.mark.parametrize("what,shape,dv,chunk,dtype,takes", [
+    ("kimi_linear_48b_a3b.s16384_scan", (1, 16384, 32, 128), 128, 64,
+     jnp.bfloat16, True),
+    ("float32 operands, fewer stacks than a grid step", (2, 256, 3, 128),
+     128, 64, jnp.float32, True),
+    ("four chunks of 32 a stack", (1, 1024, 2, 128), 128, 32, jnp.bfloat16,
+     True),
+    ("half a stack", (4, 64, 32, 128), 128, 64, jnp.bfloat16, False),
+    ("a ragged sequence", (1, 16384 + 40, 32, 128), 128, 64, jnp.bfloat16,
+     False),
+    ("stacks no grid step divides", (1, 11 * 128, 32, 128), 128, 64,
+     jnp.bfloat16, False),
+    ("a head 64 wide", (1, 16384, 32, 64), 64, 64, jnp.bfloat16, False),
+    ("values 64 wide", (1, 16384, 32, 128), 64, 64, jnp.bfloat16, False),
+    ("a chunk that fills no stack", (1, 16128, 32, 128), 128, 48,
+     jnp.bfloat16, False),
+    ("float16 operands", (1, 16384, 32, 128), 128, 64, jnp.float16, False),
+])
+def test_supported_follows_from_the_shapes_alone(what, shape, dv, chunk,
+                                                 dtype, takes):
+    assert K.supported(shape, dv, chunk, dtype) is takes, what
+
+
+@pytest.mark.parametrize("width,fused", [(128, 1), (16, 0)],
+                         ids=["a head 128 wide", "a head 16 wide"])
+def test_the_mixer_counts_the_form_that_ran(width, fused):
+    """``monitor.kernels.kda_chunk_calls{fused}``: 1 where the mixer's
+    shapes take the kernels, 0 where the ``jnp`` form ran, and the two give
+    the same layer."""
+    from paddle_tpu import monitor
+    from paddle_tpu.models import kimi_linear
+    from paddle_tpu.parallel import transformer as T
+
+    cfg = kimi_linear.kimi_linear_tiny_config(
+        kda_heads=1, kda_head_dim=width, kda_chunk=64, max_seq=128)
+    keys = jax.random.split(jax.random.PRNGKey(11), 1)
+    stack = lambda fold, fan, shape: jax.vmap(         # noqa: E731
+        lambda key: jax.random.normal(jax.random.fold_in(key, fold), shape)
+        * fan ** -0.5)(keys)
+    leaves = {n: a[0] for n, a in T._kda_leaves(stack, keys, cfg).items()}
+    h = jax.random.normal(jax.random.PRNGKey(12), (1, 128, cfg.hidden))
+    mon = monitor.enable()
+    try:
+        # the registry outlives a session: count from where it stood
+        calls = [mon.registry.counter("monitor.kernels.kda_chunk_calls",
+                                      fused=f) for f in (0, 1)]
+        before = [c.value for c in calls]
+        got = T.kda_mixer(leaves, h, cfg)
+    finally:
+        monitor.disable()
+    counted = [c.value - b for c, b in zip(calls, before)]
+    assert counted[fused] == 1 and counted[1 - fused] == 0
+    off_session = [c.value for c in calls]
+    T.kda_mixer(leaves, h, cfg)
+    assert [c.value for c in calls] == off_session
+    if fused:
+        import unittest.mock as mock
+        with mock.patch.object(K, "supported", lambda *a: False):
+            want = T.kda_mixer(leaves, h, cfg)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-4, atol=1e-5 * np.abs(want).max())
+
+
+def test_vmem_bytes_follows_the_blocks_of_a_grid_step():
+    # seven operand blocks and two float32 of 1,024 tokens, beta's lane
+    # tile, sixteen kept states, beta's gradient, twice; the state's
+    # gradient; a stack's values
+    assert K.vmem_bytes(64, 32, 2) == 2 * (
+        7 * 1024 * 128 * 2 + 2 * 1024 * 128 * 4 + 1024 * 128 * 4
+        + 16 * 128 * 128 * 4 + 8 * 128 * 4) + 128 * 128 * 4 \
+        + 96 * 128 * 128 * 4 + (8 << 20)
