@@ -9,7 +9,8 @@ through a causal depthwise filter of 4 taps and ``silu``, q and k
 L2-normalised a head, a log-decay for every CHANNEL of the key and a write
 strength a head off low-rank gates, a [128, 128] state a head corrected by
 the delta rule and carried along the sequence, a head-wise RMS norm and then
-a sigmoid gate (``transformer.kda_mixer``, ``kernels/kda_chunk.py``).  A
+a sigmoid gate (``transformer.kda_mixer``, ``kernels/kda_chunk.py``,
+``kernels/kda_rows.py``).  A
 latent layer (``mla_use_nope``: NO positions anywhere, the recurrence
 carries the order; ``q_lora_rank`` null: ONE query matrix): 32 heads of 128
 + 64 columns against keys ``[k_nope_i | k_s]`` off a latent of 512 whose 64

@@ -1726,20 +1726,39 @@ def kda_log_decay(pl, h, cfg):
     """The log-decay of every token, head and key channel, [b, S, heads,
     head width] float32: ``-exp(a_log) * softplus((h @ w_fa) @ w_fb +
     dt_bias)``, in (-inf, 0)."""
+    from ..kernels import kda_rows
+
+    return kda_rows.log_decay_reference(jnp.matmul(
+        h @ pl["w_fa"], pl["w_fb"], preferred_element_type=jnp.float32),
+        pl["dt_bias"], pl["a_log"])
+
+
+def _kda_flat(pl, h, cfg, q, k, v):
+    """``kda_mixer`` behind its filters on the FLAT arrays [b, S, heads x
+    128], a head a lane tile from the filters' outputs to ``wo``'s input:
+    ``kernels/kda_rows.py``'s one pass each way for the two L2 norms, the
+    log-decays and the norm-then-gate, ``kernels/kda_chunk.py``'s kernels
+    between them (which keep the operands, as the layer's remat does anyway,
+    and a state a chunk: 537 MB at [16384, 32 x 128]).  No [b, S, heads, d]
+    view of a sequence-sized array: on the chip that view is a copy."""
+    from ..kernels import kda_chunk, kda_rows
+
     nh, d = cfg.kda_heads, cfg.kda_head_dim
-    step = jax.nn.softplus(jnp.matmul(
-        h @ pl["w_fa"], pl["w_fb"], preferred_element_type=jnp.float32)
-        + pl["dt_bias"])
-    return -jnp.exp(pl["a_log"])[:, None] * step.reshape(
-        h.shape[:2] + (nh, d))
-
-
-def _l2_heads(x, nh, scale):
-    """x [b, S, nh * d] as heads [b, S, nh, d], each ``x / |x|_2 * scale``
-    (eps 1e-6 under the root), float32 inside."""
-    x = x.reshape(x.shape[:2] + (nh, -1)).astype(jnp.float32)
-    return (x * (jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-                 * scale))
+    q = kda_rows.l2_heads(q, scale=d ** -0.5)
+    k = kda_rows.l2_heads(k, scale=1.0)
+    beta = jax.nn.sigmoid(jnp.matmul(
+        h, pl["w_beta"], preferred_element_type=jnp.float32))
+    with jax.named_scope(devscope.KDA_CHUNK):
+        # the decays stand under the delta rule's scope, as
+        # ``kda_log_decay`` does on the other path
+        g = kda_rows.log_decay(jnp.matmul(
+            h @ pl["w_fa"], pl["w_fb"], preferred_element_type=jnp.float32),
+            pl["dt_bias"], pl["a_log"])
+        o = kda_chunk.kda_chunk(q, k, v, g, beta, heads=nh,
+                                chunk=cfg.kda_chunk)
+    return kda_rows.norm_gate(o, jnp.matmul(
+        h @ pl["w_ga"], pl["w_gb"], preferred_element_type=jnp.float32),
+        pl["o_norm"], eps=cfg.norm_eps)
 
 
 @devscope.scoped(devscope.KDA)
@@ -1753,44 +1772,40 @@ def kda_mixer(pl, h, cfg):
     ``cfg.kda_chunk`` tokens); each head's output RMS-normed by the ONE
     scale ``o_norm`` and THEN gated by ``sigmoid((h @ w_ga) @ w_gb)`` (the
     order Mamba-2's gated norm does not have); then ``wo``.  Norms, decays,
-    gates and the state in float32."""
-    from ..kernels import kda_chunk
+    gates and the state in float32.  Where a head is one lane tile and the
+    delta rule takes its kernels, all of it between the filters and ``wo``
+    runs on the flat arrays (``_kda_flat``); elsewhere the ``jnp`` lines
+    below feed ``kda_chunked``."""
+    from ..kernels import kda_chunk, kda_rows
     from ..kernels._common import count_call
 
     nh, d = cfg.kda_heads, cfg.kda_head_dim
     b, S, _ = h.shape
     q, k, v = (_kda_filtered(pl, h, cfg, name) for name in "qkv")
-    q = _l2_heads(q, nh, d ** -0.5).astype(h.dtype)
-    k = _l2_heads(k, nh, 1.0).astype(h.dtype)
+    fused = kda_chunk.supported((b, S, nh, d), d, cfg.kda_chunk, k.dtype) \
+        and kda_rows.supported(k.shape, d, k.dtype.itemsize)
+    count_call("kda_chunk", fused=int(fused))
+    for part in kda_rows.PARTS:
+        count_call("kda_rows", part=part, fused=int(fused))
+    if fused:
+        return _kda_flat(pl, h, cfg, q, k, v) @ pl["wo"]
+    q = kda_rows.l2_heads_reference(q, nh, d ** -0.5).astype(h.dtype)
+    k = kda_rows.l2_heads_reference(k, nh, 1.0).astype(h.dtype)
     beta = jax.nn.sigmoid(jnp.matmul(
         h, pl["w_beta"], preferred_element_type=jnp.float32))
-    fused = kda_chunk.supported(k.shape, d, cfg.kda_chunk, k.dtype)
-    count_call("kda_chunk", fused=int(fused))
     with jax.named_scope(devscope.KDA_CHUNK):
-        if fused:
-            # the kernels read a head as a lane block of [b, S, heads x d]
-            # and keep the operands (the layer's remat holds them anyway)
-            # and a state a chunk: 537 MB at [16384, 32 x 128]
-            flat = (b, S, nh * d)
-            o = kda_chunk.kda_chunk(
-                q.reshape(flat), k.reshape(flat), v,
-                kda_log_decay(pl, h, cfg).reshape(flat), beta, heads=nh,
-                chunk=cfg.kda_chunk).reshape(b, S, nh, d)
-        else:
-            # under a checkpoint of its own: what the ``jnp`` form keeps for
-            # its backward (the chunks' own parts, a state a chunk: 1.7 GB
-            # at [16384, 32 x 128]) then stands only while that backward
-            # runs, not beside the FFN's residuals through the layer's, at
-            # the price of a third forward (PERF.md section 6, PR 58)
-            o = jax.checkpoint(functools.partial(
-                kda_chunk.kda_chunked, chunk=cfg.kda_chunk))(
-                    q, k, v.reshape(b, S, nh, d), kda_log_decay(pl, h, cfg),
-                    beta)
-    gate = jax.nn.sigmoid(jnp.matmul(
-        h @ pl["w_ga"], pl["w_gb"], preferred_element_type=jnp.float32))
-    o = o.astype(jnp.float32)
-    y = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
-                          + cfg.norm_eps) * pl["o_norm"] * gate.reshape(o.shape)
+        # under a checkpoint of its own: what the ``jnp`` form keeps for
+        # its backward (the chunks' own parts, a state a chunk: 1.7 GB
+        # at [16384, 32 x 128]) then stands only while that backward
+        # runs, not beside the FFN's residuals through the layer's, at
+        # the price of a third forward (PERF.md section 6, PR 58)
+        o = jax.checkpoint(functools.partial(
+            kda_chunk.kda_chunked, chunk=cfg.kda_chunk))(
+                q, k, v.reshape(b, S, nh, d), kda_log_decay(pl, h, cfg),
+                beta)
+    y = kda_rows.norm_gate_reference(o, jnp.matmul(
+        h @ pl["w_ga"], pl["w_gb"], preferred_element_type=jnp.float32),
+        pl["o_norm"], cfg.norm_eps)
     return y.astype(h.dtype).reshape(b, S, nh * d) @ pl["wo"]
 
 

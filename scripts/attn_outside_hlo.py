@@ -1,12 +1,13 @@
-"""What ONE attention (or retention, Mamba or Mamba-2) layer of a cell moves
-through HBM outside its matmuls and kernels, read off the compiled text, no
-chip:
+"""What ONE attention (or retention, Mamba, Mamba-2 or KDA) layer of a cell
+moves through HBM outside its matmuls and kernels, read off the compiled
+text, no chip:
 
     python3 scripts/attn_outside_hlo.py trinity_large_preview.s6144_scan
         [--kind 3] [--top 30] [--tiny]
 
 The branch the block runs (``transformer._attention_heads_mode``,
-``power_retention``, ``mamba_mixer`` or ``mamba2_mixer``) on the cell's batch
+``power_retention``, ``mamba_mixer``, ``mamba2_mixer`` or ``kda_mixer``) on
+the cell's batch
 and sequence at the configuration's widths, under ``jax.checkpoint``; its vjp
 alone is compiled (the recomputed forward and the backward: what a layer
 costs a second time in the step) for
@@ -34,10 +35,10 @@ as zero gradients, broadcasts of their whole size that no step makes (0.51
 GB of Trinity's "other" before PR 55).
 
 ``--kind i`` takes the i-th layer kind of the period (default: the first with
-rotary positions, or retention, or a Mamba kind); ``--tiny`` takes the
+rotary positions, or retention, a Mamba kind or KDA); ``--tiny`` takes the
 model's tiny configuration at S = 256 (the smoke test's). This is the reading
-ISSUEs 47, 49, 53 and 55 were sized by (PERF.md section 6). Bytes over 819 GB/s are a LEAST
-time, not a time: a time comes from the chip."""
+ISSUEs 47, 49, 53, 55 and 60 were sized by (PERF.md section 6). Bytes over
+819 GB/s are a LEAST time, not a time: a time comes from the chip."""
 
 import argparse
 import collections
@@ -287,6 +288,8 @@ def branch_of(cfg, kind):
             return T.mamba_mixer(pl, h, cfg)
         if kind == T.MAMBA2:
             return T.mamba2_mixer(pl, h, cfg)
+        if kind == T.KDA:
+            return T.kda_mixer(pl, h, cfg)
         return T._attention_heads_mode(pl, h, cfg, kind)
 
     return branch
@@ -349,7 +352,7 @@ def default_kind(cfg):
 
     kinds = cfg.layer_kinds
     return next(k for k in kinds
-                if k in (T.RETENTION, T.MAMBA, T.MAMBA2)
+                if k in (T.RETENTION, T.MAMBA, T.MAMBA2, T.KDA)
                 or (isinstance(k, tuple) and k[1]))
 
 

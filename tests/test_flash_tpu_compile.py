@@ -783,6 +783,9 @@ def test_a_rotary_layer_s_text_holds_no_float32_heads_outside_the_kernels(
     ("nemotron3_nano_30b_a3b.s8192_scan", "mamba2",
      {"mamba_filter_fwd", "mamba_filter_bwd", "ssd_scan_fwd", "ssd_scan_bwd",
       "gated_norm_fwd", "gated_norm_bwd"}),
+    # Kimi-Linear's KDA mixer: the tiny heads of 16 (32 channels a filter)
+    # keep the ``jnp`` lines around ``kda_chunked`` and the filters'
+    ("kimi_linear_48b_a3b.s16384_scan", "kda", set()),
 ])
 def test_attn_outside_hlo_smoke(one_chip, capsys, cell, kind, kernels):
     """The script end to end at a tiny configuration, the kernels compiled
@@ -812,11 +815,15 @@ def test_attn_outside_hlo_smoke(one_chip, capsys, cell, kind, kernels):
     layer = hlo.default_kind(cfg)
     leaves, h = hlo.layer_shapes(cfg, batch, seq, layer)
     read = hlo.leaves_read(hlo.branch_of(cfg, layer), leaves, h)
-    assert set(leaves) - set(read) == (
-        {"ln1_scale"} if kind == "mamba2" else
-        {"ln1_scale", "ln2_scale", "router", "we_down", "we_gate_up"})
-    assert {"w_in", "w_out"} <= set(read) if kind == "mamba2" \
-        else {"wq", "wk", "wv", "wo", "q_norm", "k_norm"} == set(read)
+    experts = {"ln1_scale", "ln2_scale", "router", "we_down", "we_gate_up"}
+    assert set(leaves) - set(read) == {
+        "mamba2": {"ln1_scale"},
+        "kda": experts | {"ws_down", "ws_gate_up"}}.get(kind, experts)
+    assert {"mamba2": {"w_in", "w_out"},
+            "kda": {"a_log", "dt_bias", "o_norm", "w_fb", "w_gb", "wo"}}.get(
+                kind, set(read)) <= set(read)
+    assert kind in ("mamba2", "kda") or set(read) == {
+        "wq", "wk", "wv", "wo", "q_norm", "k_norm"}
 
 
 # --- the selective scan at its door (PR 49) ----------------------------------
@@ -1197,3 +1204,86 @@ def test_a_flash_layer_s_text_holds_no_float32_product_of_o_and_do(
     # XLA's own estimate rides beside the bytes
     assert all(len(o) == 5 for o in others)
     assert 0 < sum(o[4] for o in others) < 1.5e6, groups
+
+
+# --- the KDA mixer's passes around the delta rule (PR 60) --------------------
+
+@pytest.mark.parametrize("what,shape,dtype", [
+    ("kimi_linear_48b_a3b.s16384_scan", (1, 16384, 4096), jnp.bfloat16),
+    ("float32, two heads, one block of 40 rows", (2, 40, 256), jnp.float32),
+])
+def test_the_kda_row_kernels_compile_for_a_v5e(one_chip, what, shape, dtype):
+    """The five kernels of ``kernels/kda_rows.py`` through Mosaic at the
+    cell's shape (four heads a lane block, 1,024 rows a grid step walked
+    128 a turn) and in float32 at a shape off the row blocks' powers of
+    two (``log_decay``'s forward is XLA's: no kernel); a call asks for the
+    module's own count (``vmem_bytes``) and the compiled kernel takes
+    less."""
+    kr = importlib.import_module("paddle_tpu.kernels.kda_rows")
+    b, S, P = shape
+    itemsize = jnp.dtype(dtype).itemsize
+
+    def sds(shape_, dtype_):
+        return jax.ShapeDtypeStruct(shape_, dtype_, sharding=one_chip)
+
+    def both(fn):
+        def run(g, *args):
+            out, vjp = jax.vjp(fn, *args)
+            return (out,) + vjp(g)
+        return jax.jit(run)
+
+    f32 = jnp.float32
+    calls = {
+        "l2_heads": (lambda x: kr.l2_heads(x, scale=0.5, interpret=False),
+                     itemsize, [sds(shape, dtype)] * 2),
+        "log_decay": (lambda *a: kr.log_decay(*a, interpret=False), 4,
+                      [sds(shape, f32), sds(shape, f32), sds((P,), f32),
+                       sds((P // 128,), f32)]),
+        "norm_gate": (lambda *a: kr.norm_gate(*a, eps=1e-5, interpret=False),
+                      itemsize, [sds(shape, dtype), sds(shape, dtype),
+                                 sds(shape, f32), sds((128,), f32)]),
+    }
+    assert kr.supported(shape, 128, itemsize) and set(calls) == set(kr.PARTS)
+    for part, (fn, narrowest, args) in calls.items():
+        text = both(fn).lower(*args).compile().as_text()
+        bs, _, lanes = kr.geometry(S, P, narrowest)
+        assert "kda_log_decay_fwd" not in text
+        for way in ("bwd",) if part == "log_decay" else ("fwd", "bwd"):
+            asked, took = _vmem(text, "kda_%s_%s" % (part, way))
+            assert asked == kr.vmem_bytes(part, bs, lanes, narrowest)
+            assert took < asked < 20 * 2 ** 20, (what, part, way, took, asked)
+
+
+def test_a_kda_layer_s_text_holds_no_float32_view_by_heads(one_chip):
+    """The cell's KDA layer, recompute + backward, through
+    ``scripts/attn_outside_hlo.py`` (the no-chip reading ISSUE 60 was sized
+    by): its kernels are the filters', the delta rule's and the five row
+    kernels'; no float32 array of a projection's size ([1, 16384, 4096], or
+    [16384, 32, 128], or its [2048, 8, 32, 128] tiles) is moved by a
+    ``reshape``, ``copy``, ``broadcast``, ``convert`` or pointwise fusion of
+    the entry computation (the parent made thirty-nine such instructions,
+    nine float32 copies and six float32 reshapes among them, and moved 20.44
+    GB outside its matmuls and kernels where 0.77 is left)."""
+    hlo = _script("attn_outside_hlo")
+    cfg, batch, seq = hlo.cell_config("kimi_linear_48b_a3b.s16384_scan",
+                                      tiny=False)
+    kind = hlo.default_kind(cfg)
+    assert (batch, seq, kind, cfg.kda_heads, cfg.kda_head_dim) \
+        == (1, 16384, "kda", 32, 128)
+    text = hlo.compiled_text(cfg, batch, seq, kind)
+    groups, by_kernel, others = hlo.account(text)
+    assert set(by_kernel) == {
+        "mamba_filter_fwd", "mamba_filter_bwd", "kda_chunk_fwd",
+        "kda_chunk_bwd", "kda_l2_heads_fwd", "kda_l2_heads_bwd",
+        "kda_log_decay_bwd", "kda_norm_gate_fwd", "kda_norm_gate_bwd"}
+    elements = seq * cfg.kda_heads * cfg.kda_head_dim
+    assert not [o for o in others if o[3].lstrip("(").startswith("f32")
+                and o[0] >= 4 * elements], others[:9]
+    assert not [o for o in others if o[2] in (
+        "reshape", "copy", "broadcast", "convert") and o[0] >= elements], \
+        others[:9]
+    assert groups["other"] < 1.0e9 and groups["matmul"] > 5.0e9
+    # the five row kernels move what the work needs: 3.6 GB (the decays'
+    # forward is the epilogue of its matmul)
+    assert sum(v for k, v in by_kernel.items()
+               if k.startswith(("kda_l2", "kda_log", "kda_norm"))) < 3.8e9
