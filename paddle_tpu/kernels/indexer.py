@@ -1,0 +1,408 @@
+"""The indexer of a learned-sparse attention layer: which keys a query reads.
+
+Three pieces, each a (q block, k block) tile at a time, so that nothing
+[heads, S, S] ever stands (16 heads at S = 16,384 would be 17 GB):
+
+- ``indexer_scores`` (Pallas, forward and backward): ``I[t, s] = sum_j
+  w[t, j] * relu(q[t, j] . k[s])`` for ``s <= t``, float32, ``-inf`` where
+  ``s > t``; q [B, S, Hi * Di] packed, ONE key head k [B, S, Di], w [B, S,
+  Hi] float32.  The heads are looped inside the tile.  The backward
+  (``indexer_scores_bwd``) walks the causal triangle once: a tile recomputes
+  each head's products and gives dq and dw into the q block's scratch and dk
+  into a float32 accumulator that holds the WHOLE sequence (it is one head
+  of Di lanes: 4 MiB at S = 16,384) and leaves at the batch row's last step.
+- ``kth_largest`` (blocked ``jnp``): the k-th largest of each row of I,
+  exact, without a sort: 32 counting passes over the ordered-integer image
+  of float32 (``_ordered``: an unsigned integer whose order is the float's)
+  fix the answer a bit a pass.  A row with fewer than k finite entries
+  answers ``-inf``: every causal key is selected.
+- ``indexer_kl`` (Pallas, forward; its backward is a product): the mean over
+  rows of ``KL(p_t || softmax over S_t of I[t, .])``, ``S_t = {s <= t :
+  I[t, s] >= tau_t}``, ``p[t, s] = mean over the heads of the main
+  attention's probabilities``, recomputed a tile from q, k and the flash
+  forward's saved ``lse``, a head a grid step, summed in scratch.  The same
+  pass writes ``G = softmax_S(I) - p`` (0 off ``S_t``), which IS ``dKL/dI``:
+  the backward keeps no other [S, S] array and reads no q or k again.  p
+  carries a stop-gradient: q, k and lse get none.
+
+interpret=None auto-selects the Pallas interpreter off-TPU.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ._common import CompilerParams as _CompilerParams, on_tpu as _on_tpu
+from .flash_attention import FIRST, LAST, step_table
+
+__all__ = ["indexer_scores", "kth_largest", "indexer_kl", "selected"]
+
+NEG_INF = float("-inf")
+SELECT_ROWS = 256       # rows of I a counting pass of ``kth_largest`` reads
+
+
+def _causal(shape, q0, k0):
+    return q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0) \
+        >= k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+
+
+def _head_products(q, k, heads):
+    """``q[:, j] . k`` of each indexer head j: [bq, bk] float32 each."""
+    di = k.shape[-1]
+    for j in range(heads):
+        yield j, jax.lax.dot_general(
+            q[:, j * di:(j + 1) * di], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+
+def _scores_kernel(q_ref, k_ref, w_ref, o_ref, *, heads, bq, bk):
+    i, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j * bk > i * bq + bq - 1)
+    def _above():
+        o_ref[0] = jnp.full((bq, bk), NEG_INF, jnp.float32)
+
+    @pl.when(j * bk <= i * bq + bq - 1)
+    def _tile():
+        w = w_ref[0]
+        acc = jnp.zeros((bq, bk), jnp.float32)
+        for h, s in _head_products(q_ref[0], k_ref[0], heads):
+            acc += w[:, h:h + 1] * jnp.maximum(s, 0.0)
+        o_ref[0] = jnp.where(_causal((bq, bk), i * bq, j * bk), acc, NEG_INF)
+
+
+def _last_visible(i, bq, bk):
+    """The last k block a q block's rows see."""
+    return (i * bq + bq - 1) // bk
+
+
+def _scores_fwd_call(q, k, w, bq, bk, interpret):
+    B, S, _ = q.shape
+    heads = w.shape[-1]
+    # a block above the diagonal is filled, not computed: its operands are
+    # the diagonal's, which are there already
+    kmap = lambda b, i, j: (b, jnp.minimum(j, _last_visible(i, bq, bk)), 0)
+    return pl.pallas_call(
+        functools.partial(_scores_kernel, heads=heads, bq=bq, bk=bk),
+        grid=(B, S // bq, S // bk),
+        in_specs=[pl.BlockSpec((1, bq, q.shape[-1]), lambda b, i, j: (b, i, 0)),
+                  pl.BlockSpec((1, bk, k.shape[-1]), kmap),
+                  pl.BlockSpec((1, bq, heads), lambda b, i, j: (b, i, 0))],
+        out_specs=pl.BlockSpec((1, bq, bk), lambda b, i, j: (b, i, j)),
+        out_shape=jax.ShapeDtypeStruct((B, S, S), jnp.float32),
+        compiler_params=_CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret, name="indexer_scores_fwd",
+    )(q, k, w)
+
+
+def _scores_bwd_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, w_ref,
+                       g_ref, dq_ref, dk_ref, dw_ref, dq_scr, dw_scr, dk_acc,
+                       *, heads, bq, bk):
+    t = pl.program_id(1)
+    di = k_ref.shape[-1]
+    rows = pl.ds(pl.multiple_of(kv_of[t] * bk, bk), bk)
+
+    @pl.when(t == 0)
+    def _open():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+
+    @pl.when((flags[t] & FIRST) != 0)
+    def _init():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+        dw_scr[:] = jnp.zeros_like(dw_scr)
+
+    q, k, w = q_ref[0], k_ref[0], w_ref[0]
+    g = jnp.where(_causal((bq, bk), q_of[t] * bq, kv_of[t] * bk),
+                  g_ref[0], 0.0)
+    dk = jnp.zeros((bk, di), jnp.float32)
+    dws = []
+    for h, s in _head_products(q, k, heads):
+        dws.append(jnp.sum(g * jnp.maximum(s, 0.0), axis=1, keepdims=True))
+        ds = jnp.where(s > 0.0, g * w[:, h:h + 1], 0.0).astype(q.dtype)
+        dq_scr[:, h * di:(h + 1) * di] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk += jax.lax.dot_general(
+            ds, q[:, h * di:(h + 1) * di], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    dw_scr[:] += jnp.concatenate(dws, axis=1)
+    dk_acc[rows, :] += dk
+
+    @pl.when((flags[t] & LAST) != 0)
+    def _final():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        dw_ref[0] = dw_scr[:]
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _close():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+
+
+def _triangle_call(kernel, name, table, B, operands, in_specs, out_specs,
+                   out_shape, scratch_shapes, interpret, extra_axes=(),
+                   **params):
+    """A sweep over the causal triangle's (q block, k block) pairs, the
+    flash kernels' ``step_table`` as scalar-prefetch operands; ``extra_axes``
+    inner grid axes behind the step."""
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(table),
+            grid=(B, table.shape[1]) + tuple(extra_axes),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape,
+        compiler_params=_CompilerParams(
+            dimension_semantics=("parallel",) + ("arbitrary",) * (
+                1 + len(extra_axes)), **params),
+        interpret=interpret, name=name,
+    )(*(jnp.asarray(column) for column in table), *operands)
+
+
+def _scores_bwd_call(q, k, w, g, bq, bk, interpret):
+    B, S, W = q.shape
+    heads, di = w.shape[-1], k.shape[-1]
+    table = step_table(S, S, bq, bk, True)
+    qrow = lambda b, t, q_of, kv_of, head_of, flags: (b, q_of[t], 0)
+    krow = lambda b, t, q_of, kv_of, head_of, flags: (b, kv_of[t], 0)
+    tile = lambda b, t, q_of, kv_of, head_of, flags: (b, q_of[t], kv_of[t])
+    whole = lambda b, t, *table: (b, 0, 0)
+    return _triangle_call(
+        functools.partial(_scores_bwd_kernel, heads=heads, bq=bq, bk=bk),
+        "indexer_scores_bwd", table, B, (q, k, w, g),
+        [pl.BlockSpec((1, bq, W), qrow), pl.BlockSpec((1, bk, di), krow),
+         pl.BlockSpec((1, bq, heads), qrow), pl.BlockSpec((1, bq, bk), tile)],
+        [pl.BlockSpec((1, bq, W), qrow), pl.BlockSpec((1, S, di), whole),
+         pl.BlockSpec((1, bq, heads), qrow)],
+        [jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(w.shape, jnp.float32)],
+        [pltpu.VMEM((bq, W), jnp.float32),
+         pltpu.VMEM((bq, heads), jnp.float32),
+         pltpu.VMEM((S, di), jnp.float32)],
+        interpret,
+        vmem_limit_bytes=48 * 2 ** 20)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _scores(q, k, w, bq, bk, interpret):
+    return _scores_fwd_call(q, k, w, bq, bk, interpret)
+
+
+def _scores_fwd(q, k, w, bq, bk, interpret):
+    return _scores_fwd_call(q, k, w, bq, bk, interpret), (q, k, w)
+
+
+def _scores_bwd(bq, bk, interpret, res, g):
+    q, k, w = res
+    dq, dk, dw = _scores_bwd_call(q, k, w, g, bq, bk, interpret)
+    return dq, dk, dw.astype(w.dtype)
+
+
+_scores.defvjp(_scores_fwd, _scores_bwd)
+
+
+def indexer_scores(q, k, w, block_q=512, block_k=512, interpret=None):
+    """``I`` [B, S, S] float32 of q [B, S, Hi * Di], k [B, S, Di] and w [B,
+    S, Hi] (float32): ``sum_j w[t, j] relu(q[t, j] . k[s])`` where ``s <=
+    t``, ``-inf`` above the diagonal.  The gradient reads ``dI`` under the
+    diagonal alone."""
+    B, S, W = q.shape
+    heads = w.shape[-1]
+    assert W == heads * k.shape[-1] and k.shape[:2] == (B, S) \
+        and w.shape[:2] == (B, S), (q.shape, k.shape, w.shape)
+    bq, bk = min(block_q, S), min(block_k, S)
+    assert S % bq == 0 and S % bk == 0, (S, bq, bk)
+    if interpret is None:
+        interpret = not _on_tpu()
+    return _scores(q, k, w.astype(jnp.float32), bq, bk, bool(interpret))
+
+
+# ---------------------------------------------------------------------------
+# the k-th largest of a row
+# ---------------------------------------------------------------------------
+
+def _ordered(x):
+    """float32 -> uint32 whose unsigned order is the float's (``-inf``
+    lowest; -0.0 below +0.0)."""
+    u = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))
+
+
+def _unordered(u):
+    return jax.lax.bitcast_convert_type(
+        jnp.where(u >> 31 == 1, u & jnp.uint32((1 << 31) - 1), ~u),
+        jnp.float32)
+
+
+def _by_rows(block, B, S, rows):
+    """``block(i)`` [B, rows] of each block of ``rows`` rows in turn, [B, S]."""
+    out = jax.lax.map(block, jnp.arange(S // rows))        # [blocks, B, rows]
+    return jnp.moveaxis(out, 0, 1).reshape(B, S)
+
+
+def kth_largest(scores, k, rows=SELECT_ROWS):
+    """The ``k``-th largest entry of each row of ``scores`` [B, S, N]
+    float32, [B, S] float32, exact: the largest value v with at least k
+    entries >= v, found a bit a pass from the top over the ordered-integer
+    image, ``rows`` rows of every batch row a block.  ``k`` > N counts as N
+    (the row's least entry)."""
+    B, S, N = scores.shape
+    k = min(int(k), N)
+    rows = min(rows, S)
+    assert S % rows == 0, (S, rows)
+
+    def block(i):
+        u = _ordered(jax.lax.dynamic_slice_in_dim(scores, i * rows, rows, 1))
+
+        def bit(n, prefix):
+            cand = prefix | (jnp.uint32(1 << 31) >> n.astype(jnp.uint32))
+            count = jnp.sum(u >= cand[..., None], axis=-1, dtype=jnp.int32)
+            return jnp.where(count >= k, cand, prefix)
+
+        return _unordered(jax.lax.fori_loop(
+            0, 32, bit, jnp.zeros((B, rows), jnp.uint32)))
+
+    return _by_rows(block, B, S, rows)
+
+
+def selected(scores, tau):
+    """[B, S, S] bool: ``S_t``, the causal keys at or above the row's
+    threshold (``scores`` is ``-inf`` above the diagonal, where nothing is
+    selected even at ``tau = -inf``)."""
+    S = scores.shape[1]
+    causal = jnp.arange(S)[:, None] >= jnp.arange(S)[None]
+    return causal & (scores >= tau[..., None])
+
+
+# ---------------------------------------------------------------------------
+# the indexer's own loss term
+# ---------------------------------------------------------------------------
+
+def _kl_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, lse_ref, i_ref,
+               tau_ref, lsei_ref, kl_ref, g_ref, p_scr, *, scale, heads, bq,
+               bk):
+    t, n = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(n == 0)
+    def _zero():
+        p_scr[:] = jnp.zeros_like(p_scr)
+
+    s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    p_scr[:] += jnp.exp(s - lse_ref[0, 0])
+
+    @pl.when(n == heads - 1)
+    def _tile():
+        scores = i_ref[0]
+        keep = _causal((bq, bk), q_of[t] * bq, kv_of[t] * bk) \
+            & (scores >= tau_ref[0])
+        p = jnp.where(keep, p_scr[:] * (1.0 / heads), 0.0)
+        log_r = jnp.where(keep, scores - lsei_ref[0], 0.0)
+        g_ref[0] = jnp.where(keep, jnp.exp(log_r), 0.0) - p
+        part = jnp.sum(jnp.where(p > 0.0, p * (jnp.log(
+            jnp.where(p > 0.0, p, 1.0)) - log_r), 0.0), axis=1, keepdims=True)
+
+        @pl.when((flags[t] & FIRST) != 0)
+        def _first():
+            kl_ref[0] = part
+
+        @pl.when((flags[t] & FIRST) == 0)
+        def _later():
+            kl_ref[0] += part
+
+
+def _kl_call(q, k, lse, scores, tau, lse_i, n_heads, n_kv_heads, scale, bq,
+             bk, interpret):
+    B, S, _ = q.shape
+    D = q.shape[-1] // n_heads
+    group = n_heads // n_kv_heads
+    table = step_table(S, S, bq, bk, True)
+    qrow = lambda b, t, n, q_of, kv_of, head_of, flags: (b, q_of[t], n)
+    krow = lambda b, t, n, q_of, kv_of, head_of, flags: (
+        b, kv_of[t], n // group)
+    stat = lambda b, t, n, q_of, kv_of, head_of, flags: (b, n, q_of[t], 0)
+    row = lambda b, t, n, q_of, kv_of, head_of, flags: (b, q_of[t], 0)
+    tile = lambda b, t, n, q_of, kv_of, head_of, flags: (
+        b, q_of[t], kv_of[t])
+    return _triangle_call(
+        functools.partial(_kl_kernel, scale=scale, heads=n_heads, bq=bq,
+                          bk=bk),
+        "indexer_kl_fwd", table, B, (q, k, lse, scores, tau, lse_i),
+        [pl.BlockSpec((1, bq, D), qrow), pl.BlockSpec((1, bk, D), krow),
+         pl.BlockSpec((1, 1, bq, 1), stat), pl.BlockSpec((1, bq, bk), tile),
+         pl.BlockSpec((1, bq, 1), row), pl.BlockSpec((1, bq, 1), row)],
+        [pl.BlockSpec((1, bq, 1), row), pl.BlockSpec((1, bq, bk), tile)],
+        [jax.ShapeDtypeStruct((B, S, 1), jnp.float32),
+         jax.ShapeDtypeStruct((B, S, S), jnp.float32)],
+        [pltpu.VMEM((bq, bk), jnp.float32)], interpret,
+        extra_axes=(n_heads,))
+
+
+def _selected_lse(scores, tau, rows=SELECT_ROWS):
+    """``log sum over S_t of exp(I[t, s])`` [B, S, 1]: the selected keys'
+    normaliser, ``rows`` rows a block."""
+    B, S, _ = scores.shape
+    rows = min(rows, S)
+    at = jnp.arange(S)
+
+    def block(i):
+        x = jax.lax.dynamic_slice_in_dim(scores, i * rows, rows, 1)
+        th = jax.lax.dynamic_slice_in_dim(tau, i * rows, rows, 1)
+        keep = (at[None] <= (i * rows + jnp.arange(rows))[:, None]) \
+            & (x >= th[..., None])
+        return jax.nn.logsumexp(jnp.where(keep, x, NEG_INF), axis=-1)
+
+    return _by_rows(block, B, S, rows)[..., None]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _kl(scores, tau, q, k, lse, n_heads, n_kv_heads, scale, bq, bk,
+        interpret):
+    return _kl_fwd(scores, tau, q, k, lse, n_heads, n_kv_heads, scale, bq,
+                   bk, interpret)[0]
+
+
+def _kl_fwd(scores, tau, q, k, lse, n_heads, n_kv_heads, scale, bq, bk,
+            interpret):
+    B, S, _ = scores.shape
+    kl, g = _kl_call(q, k, lse, scores, tau[..., None],
+                     _selected_lse(scores, tau), n_heads, n_kv_heads, scale,
+                     bq, bk, interpret)
+    return jnp.sum(kl) / (B * S), (g, tau, q, k, lse)
+
+
+def _kl_bwd(n_heads, n_kv_heads, scale, bq, bk, interpret, res, ct):
+    g, tau, q, k, lse = res
+    B, S, _ = g.shape
+    # p, and with it q, k and lse, is a constant of the term; the threshold
+    # passes no gradient
+    return (g * (ct / (B * S)),) + tuple(
+        jnp.zeros_like(x) for x in (tau, q, k, lse))
+
+
+_kl.defvjp(_kl_fwd, _kl_bwd)
+
+
+def indexer_kl(scores, tau, q, k, lse, n_heads, n_kv_heads=None, scale=None,
+               block_q=512, block_k=512, interpret=None):
+    """The mean over batch rows and tokens of ``KL(p_t || softmax over S_t
+    of I[t, .])``.  ``scores`` [B, S, S] (``indexer_scores``), ``tau`` [B,
+    S]; q [B, S, H * D] and k [B, S, Hkv * D] as the masked flash call read
+    them and ``lse`` [B, H, S, 1] as it saved it.  Differentiable in
+    ``scores`` alone (under the diagonal and on ``S_t``)."""
+    B, S, _ = q.shape
+    D = q.shape[-1] // n_heads
+    assert D % 128 == 0, "a head is whole lane blocks"
+    bq, bk = min(block_q, S), min(block_k, S)
+    assert S % bq == 0 and S % bk == 0, (S, bq, bk)
+    if interpret is None:
+        interpret = not _on_tpu()
+    return _kl(scores, tau, q, k, lse, int(n_heads),
+               int(n_kv_heads or n_heads),
+               float(D ** -0.5 if scale is None else scale), bq, bk,
+               bool(interpret))

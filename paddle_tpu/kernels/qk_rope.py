@@ -56,6 +56,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as _np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -63,7 +64,8 @@ from ._common import (LANES, SUBLANES, CompilerParams as _CompilerParams,
                       on_tpu as _on_tpu, sublane_sums as _sublane_sums,
                       sublane_tile as _tile)
 
-__all__ = ["qk_rope", "angle_tables", "pair_tables", "supported",
+__all__ = ["qk_rope", "angle_tables", "pair_tables", "pair_streams",
+           "stream_angles", "supported",
            "block_rows", "vmem_bytes"]
 
 ROW_BLOCKS = (256, 128, 64, 32, 16, 8)
@@ -105,17 +107,42 @@ def supported(shape, head_dim, itemsize):
             and block_rows(S, W, itemsize) is not None)
 
 
-def angle_tables(S, head_dim, theta, first=0):
+def pair_streams(sections, half):
+    """Which position stream each of a head's ``half`` frequency pairs takes
+    its angle from, int [half]: ``sections`` pairs from each stream in turn;
+    none: the first stream for every pair."""
+    if not sections:
+        return _np.zeros((half,), _np.int32)
+    assert sum(sections) == half, (sections, half)
+    return _np.repeat(_np.arange(len(sections), dtype=_np.int32), sections)
+
+
+def stream_angles(positions, half, theta, sections=()):
+    """[b, S, half] float32: pair i's angle ``positions[stream of i] *
+    theta^(-i / half)`` of position streams ``positions`` [streams, b, S]."""
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    pos = positions.astype(jnp.float32)[pair_streams(sections, half)]
+    return jnp.moveaxis(pos, 0, -1) * inv_freq
+
+
+def angle_tables(S, head_dim, theta, first=0, positions=None, sections=()):
     """(cos, signed sin) [S, 128] float32 of positions ``first``..``first``
     + S - 1 (``first`` may be traced), each head's ``head_dim`` lanes
     ``rope``'s own ``tile(cos(pos * theta^(-i / half)), 2)``, the sine with
-    ``rotate_half``'s sign (minus on a head's first half)."""
+    ``rotate_half``'s sign (minus on a head's first half).  ``positions``
+    [streams, b, S]: the positions as DATA, pair i from the stream
+    ``sections`` gives it (``stream_angles``); the tables are then [b * S,
+    128], a row a (batch row, position)."""
     half = head_dim // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    pos = jnp.arange(S, dtype=jnp.float32)
-    if not (isinstance(first, int) and first == 0):
-        pos = pos + first
-    ang = pos[:, None] * inv_freq[None]
+    if positions is not None:
+        ang = stream_angles(positions, half, theta, sections).reshape(
+            -1, half)
+    else:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+        pos = jnp.arange(S, dtype=jnp.float32)
+        if not (isinstance(first, int) and first == 0):
+            pos = pos + first
+        ang = pos[:, None] * inv_freq[None]
     heads = LANES // head_dim
     return (jnp.tile(jnp.cos(ang), (1, 2 * heads)),
             jnp.tile(jnp.concatenate([-jnp.sin(ang), jnp.sin(ang)], axis=1),

@@ -73,6 +73,12 @@ MAMBA2, SSD_SCAN = "mamba2", "ssd_scan"
 # head-wise norm, the output projection) and, inside it, the delta rule
 # itself: the chunks' own parts, the solve and the scan that carries the state
 KDA, KDA_CHUNK = "kda", "kda_chunk"
+# learned-sparse attention, inside attention's scope: the indexer (its
+# projections, the key's norm, rotation, the scores' kernel), the selection
+# (the k-th largest score a row), the flash calls under that mask, and the
+# indexer's own loss term (the target from the main attention, the KL)
+INDEXER, INDEXER_SELECT, SPARSE_ATTN, INDEXER_KL = (
+    "indexer", "indexer_select", "sparse_attn", "indexer_kl")
 # the scan over the stacked layers itself: its slices of each layer's leaves,
 # the activations it keeps for the backward pass and the gradients it stacks
 # (a layer's own work carries the layer's scopes, which lie further in)
@@ -87,7 +93,8 @@ VOCABULARY = (EMBED, ATTENTION, MLP, LAYER_NORM, LM_HEAD, CONV, BN, POOL, FC,
               LOSS, GRAD_SYNC, OPTIMIZER, MOE, ROUTER, SHORT_CONV, RETENTION,
               LATENT_ATTENTION, SHARED_EXPERT, LAYER_SCAN, ATTN_GATE,
               POST_NORM, MAMBA, SELECTIVE_SCAN, MAMBA2, SSD_SCAN, LOOP_SCAN,
-              EXIT_GATE, KDA, KDA_CHUNK)
+              EXIT_GATE, KDA, KDA_CHUNK, INDEXER, INDEXER_SELECT, SPARSE_ATTN,
+              INDEXER_KL)
 PHASES = ("forward", "backward", "recompute", GRAD_SYNC, OPTIMIZER)
 
 # `%fusion.12 = bf16[..] fusion(%p.1, %copy-done.2), ..., metadata={op_name="jit(multi)/..." ...}`:

@@ -3,11 +3,13 @@
 ``logits_at``, a call's observations and the builder, ONE of each for every
 decoder of this block.  A model (``models/olmoe.py``, ``smallthinker.py``,
 ``lfm2.py``, ``brumby.py``, ``mistral4.py``, ``trinity.py``, ``jamba.py``,
-``nemotron_h.py``, ``ouro.py``, ``kimi_linear.py``)
+``nemotron_h.py``, ``ouro.py``, ``kimi_linear.py``, ``keye_vl2.py``)
 is a ``TransformerConfig`` and a label; what its trainer computes and what
 it observes follow from the configuration, never from which model it is.
 
-batch dict: ``ids`` int32 [B, S] alone.  The loss builds the next-token
+batch dict: ``ids`` int32 [B, S], and where the trainer was built for them
+(``build_decoder_trainer(positions=True)``) ``positions`` int32 [3, B, S],
+the rotation's three position streams.  The loss builds the next-token
 labels itself (``labels[t] = ids[t + 1]``, positions 0..S-2 count) and adds
 what the configuration's router asks for: the auxiliary losses, mean over
 layers (``ce + router_aux_coef * load_balance + router_z_coef * router_z``),
@@ -40,6 +42,8 @@ from .transformer import (
     exit_log_probs,
     exit_weighted_loss,
     final_logits_loss,
+    indexer_selection,
+    _qkv,
     grad_sync_axes,
     head_logits,
     init_transformer_params,
@@ -53,25 +57,31 @@ from .transformer import (
     transformer_param_specs,
 )
 
-__all__ = ["BATCH_SPECS", "STEPPED", "forward", "weighted_exit_logits",
+__all__ = ["BATCH_SPECS", "POSITION_SPECS", "STEPPED", "forward", "weighted_exit_logits",
            "make_loss_fn", "probe", "DecoderTrainer",
            "build_decoder_trainer"]
 
 BATCH_SPECS = {"ids": P(DP)}
+# of a batch that carries its rotation's three position streams [3, B, S]
+POSITION_SPECS = {"positions": P(None, DP)}
 STEPPED = {"router_bias"}       # leaves a step sets itself (make_train_step)
 
 
-def forward(params, ids, cfg):
+def forward(params, ids, cfg, positions=None):
     """The stack on ``ids`` [b, S]: the last activation and the layers'
-    router values, each stacked [L]; of a looped stack (``loop_passes`` >
-    1) every pass's last activation [T, b, S, E] and the exit gates' logits
-    [T, b, S] (``transformer.run_passes``)."""
+    auxiliary values (the router's, an indexer's loss term), each stacked
+    [L]; of a looped stack (``loop_passes`` > 1) every pass's last
+    activation [T, b, S, E] and the exit gates' logits [T, b, S]
+    (``transformer.run_passes``).  ``positions`` [3, b, S]: the rotation's
+    position streams (``cfg.mrope_sections``); None: the token index three
+    times, which is plain rotary."""
     x = embed(params, ids, cfg)
     if cfg.loop_passes > 1:
         return run_passes(params, x, cfg)
     return run_layers(params["params_layers"], x, cfg, with_aux=True,
                       prefix=params.get("prefix_layers"),
-                      router_bias=params.get("router_bias"))
+                      router_bias=params.get("router_bias"),
+                      positions=positions)
 
 
 def weighted_exit_logits(params, ids, at, cfg):
@@ -98,10 +108,13 @@ def make_loss_fn(cfg: TransformerConfig):
         mask = jnp.broadcast_to(
             (jnp.arange(ids.shape[1]) < ids.shape[1] - 1).astype(jnp.float32),
             ids.shape)
-        x, aux = forward(params, ids, cfg)
+        x, aux = forward(params, ids, cfg, batch.get("positions"))
         if cfg.loop_passes > 1:
             return exit_weighted_loss(params, x, aux, labels, mask, cfg)
         ce = final_logits_loss(params, x, labels, mask, cfg)
+        if cfg.indexer_heads:
+            # the indexer's own term, mean over layers: its leaves' alone
+            ce = ce + jnp.mean(aux["dsa_kl"])
         if cfg.routing == moe.SIGMOID_BIASED:
             return ce, {"router_bias": moe.balance_bias(
                 params["router_bias"], aux["load"], cfg.router_bias_rate)}
@@ -145,6 +158,27 @@ def _first_of_kind(params, ids, cfg, kind):
                         cfg.norm_eps)
 
 
+def _mass_selected(params, ids, cfg, queries=256):
+    """``dsa_mass_selected`` of ``probe``: the first layer's dense causal
+    softmax, a head a row, summed over the keys its indexer selects."""
+    pl, h = _first_layer_input(params, ids, cfg)
+    b, S, _ = h.shape
+    q, k, _ = _qkv(pl, h, cfg, True)
+    scores, tau = indexer_selection(pl, h, cfg)
+    first = min(cfg.indexer_topk, S - 1)
+    rows = jnp.linspace(first, S - 1, min(queries, S - first)).astype(
+        jnp.int32)
+    group = cfg.n_heads // cfg.kv_heads
+    qh = q[:, rows].reshape(b, -1, cfg.kv_heads, group, cfg.head_dim)
+    kh = k.reshape(b, S, cfg.kv_heads, cfg.head_dim)
+    s = jnp.einsum("brkgd,bskd->bkgrs", qh, kh,
+                   preferred_element_type=jnp.float32) * cfg.head_dim ** -0.5
+    causal = jnp.arange(S)[None] <= rows[:, None]
+    p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
+    keep = causal & (scores[:, rows] >= tau[:, rows, None])     # [b, r, S]
+    return jnp.mean(jnp.sum(jnp.where(keep[:, None, None], p, 0.0), axis=-1))
+
+
 def probe(params, ids, cfg):
     """``{name: scalar, or [T] of the exits}`` of ``ids`` [b, S]: the
     readings ``DecoderTrainer._observe`` takes of one batch, the entries the
@@ -182,7 +216,13 @@ def probe(params, ids, cfg):
     - ``loop_passes`` > 1: ``exit_prob_mean`` [T], the mean over tokens of
       each exit's probability (a gate stuck at 0 or 1 is a dead exit), and
       ``exit_entropy_mean``, the mean entropy of a token's exit
-      distribution in nats (ln T at most; 0 is a collapsed gate)."""
+      distribution in nats (ln T at most; 0 is a collapsed gate);
+    - ``indexer_heads``: ``dsa_kl_mean``, the indexer's loss term, mean over
+      layers, and ``dsa_mass_selected``, the share of the DENSE causal
+      softmax's mass that lies on the selected keys, in the first layer, at
+      up to 256 queries spread over the positions past ``indexer_topk``,
+      mean over heads: near the rows' own selected share, ``topk / (t +
+      1)``, the indexer knows nothing; at 1 it drops nothing."""
     out = {}
     if cfg.loop_passes > 1:
         log_p = exit_log_probs(forward(params, ids, cfg)[1])
@@ -194,6 +234,9 @@ def probe(params, ids, cfg):
         out["moe_load_max_over_mean"] = jnp.max(aux["load_max_over_mean"])
         if "rows_held" in aux:
             out["moe_rows_held"] = jnp.sum(aux["rows_held"])
+        if cfg.indexer_heads:
+            out["dsa_kl_mean"] = jnp.mean(aux["dsa_kl"])
+            out["dsa_mass_selected"] = _mass_selected(params, ids, cfg)
     if cfg.routing == moe.SIGMOID_BIASED:
         out["router_bias_abs_max"] = jnp.max(abs(params["router_bias"]))
     mamba_first = cfg.layer_kinds[0] == MAMBA and not cfg.prefix_pattern
@@ -306,11 +349,14 @@ class DecoderTrainer(StepTrainer):
 
 
 def build_decoder_trainer(cfg, mesh_spec: MeshSpec = None, optimizer=None,
-                          seed=0, devices=None, label="decoder"):
+                          seed=0, devices=None, label="decoder",
+                          positions=False):
     """Mesh, parameters on the mesh, the jitted sharded step and its scan.
     Data parallel only: the block has no tensor-, pipeline- or
     expert-parallel layout yet.  A router's selection biases, where the
-    parameters hold them, are the step's to set and not the optimizer's."""
+    parameters hold them, are the step's to set and not the optimizer's.
+    ``positions``: every batch carries ``positions`` int32 [3, B, S] beside
+    its ``ids`` (``POSITION_SPECS``)."""
     mesh_spec = mesh_spec or MeshSpec()
     assert mesh_spec.tp == mesh_spec.pp == cfg.tp == cfg.pp == 1, \
         "the decoder block runs at tp == pp == 1"
@@ -322,7 +368,9 @@ def build_decoder_trainer(cfg, mesh_spec: MeshSpec = None, optimizer=None,
     state = TrainState.create(params, optimizer)
     sspecs = state_specs(pspecs, state)
     build = make_train_step(make_loss_fn(cfg), mesh, pspecs,
-                            grad_sync_axes(cfg), optimizer, BATCH_SPECS,
+                            grad_sync_axes(cfg), optimizer,
+                            dict(BATCH_SPECS, **POSITION_SPECS) if positions
+                            else BATCH_SPECS,
                             stepped=tuple(STEPPED & set(params)))
     step_fn, multi_fn = build(state), build.multi(state)
     with mesh:

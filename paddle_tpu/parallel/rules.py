@@ -283,6 +283,9 @@ def transformer_rules(cfg):
         # qk_norm and the MoE FFN run at tp == 1 (TransformerConfig): their
         # leaves are whole on every device
         (r"/(q_norm|k_norm)$", L(None)),
+        # and so does an attention position's indexer
+        (r"/(wq_idx|wk_idx|w_idx)$", L(None, None)),
+        (r"/idx_k_norm_(scale|bias)$", L(None)),
         (r"/(router|wg)$", L(None, None)),
         (r"/we_(gate_up|up|down)$", L(None, None, None)),
     ]

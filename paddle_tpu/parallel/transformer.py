@@ -265,6 +265,31 @@ class TransformerConfig:
     # what the entropy of a token's distribution over the exits is rewarded
     # by in the loss (a uniform prior over the exits)
     exit_entropy_coef: float = 0.0
+    # LEARNED-SPARSE attention (indexer_heads > 0; every layer of a stack of
+    # ONE attention kind, grouped queries at a head of whole lane blocks):
+    # an indexer of ``indexer_heads`` heads of ``indexer_dim`` on ONE key
+    # head scores every causal key, ``I[t, s] = sum_j w[t, j] relu(qI[t, j]
+    # . kI[s])`` (``kernels/indexer.py``), a query reads the ``indexer_topk``
+    # best of them (ties at the threshold kept; fewer causal keys than that:
+    # all), and the indexer's own loss term ``mean over layers and tokens of
+    # KL(mean over heads of attention's probabilities || softmax over the
+    # selected keys of I)`` (coefficient 1) trains its leaves (``wq_idx``,
+    # ``wk_idx``, ``w_idx``, ``idx_k_norm_scale`` / ``_bias``) alone: the
+    # target and the indexer's input carry a stop-gradient and the selection
+    # passes none, so no other leaf hears of it and the indexer's hear of
+    # nothing else
+    indexer_heads: int = 0
+    indexer_dim: int = 0
+    indexer_topk: int = 0
+    # rotary positions from THREE streams (temporal, height, width; a batch's
+    # ``positions`` [3, b, S], the token index three times where it carries
+    # none): how many of a head's frequency pairs take their angle from each,
+    # in order.  Empty: one stream
+    mrope_sections: tuple = ()
+    # what ``q_norm`` / ``k_norm`` (and an indexer's key norm's scale) are
+    # SEEDED at: at 1 a head's scores are N(0, 1) over the keys at seeded
+    # weights, at 2^(1/2) N(0, 4) and a row's softmax visibly uneven
+    qk_norm_gain: float = 1.0
 
     def __post_init__(self):
         assert self.norm in ("layer", "rms") and \
@@ -354,13 +379,24 @@ class TransformerConfig:
             # the norm of a branch's output needs the whole row: no tp yet
             assert self.norm == "rms" and self.tp == 1 and not self.bias
         assert self.route_scale == 1.0 or self.n_experts
-        assert self.residual_out_gain == 1.0 or self.per_position
         if self.loop_passes != 1:
             # the router's values are one pass's, the gate reads an RMS norm
             assert self.loop_passes > 1 and self.causal \
                 and self.norm == "rms" and self.tp == self.pp == 1 \
                 and not self.n_experts
         assert not self.exit_entropy_coef or self.loop_passes > 1
+        self.mrope_sections = tuple(int(n) for n in self.mrope_sections)
+        if self.mrope_sections:
+            assert self.positions == "rotary" and not self.latent \
+                and len(self.mrope_sections) == 3 \
+                and 2 * sum(self.mrope_sections) == self.head_dim
+        if self.indexer_heads:
+            # the masked flash mode takes a lane block a head, several blocks
+            assert self.causal and self.tp == self.pp == 1 \
+                and self.attn_mode == "heads" and not self.layer_pattern \
+                and not (self.latent or self.attn_gate or self.bias) \
+                and self.head_dim % 128 == 0 and self.indexer_dim % 2 == 0 \
+                and self.indexer_topk > 0 and self.n_experts
 
     @property
     def head_dim(self):
@@ -581,6 +617,13 @@ def _stacked_layers(ks, cfg):
             del layer[name]
         layer.update(_latent_leaves(stack, cfg, L))
     layer.update(_branch_leaves(stack, cfg, L, 15, attention=True))
+    if cfg.indexer_heads:
+        Hi, Di = cfg.indexer_heads, cfg.indexer_dim
+        layer.update(
+            wq_idx=stack(16, E, (E, Hi * Di)), wk_idx=stack(17, E, (E, Di)),
+            w_idx=stack(18, E, (E, Hi)),
+            idx_k_norm_scale=jnp.full((L, Di), cfg.qk_norm_gain, jnp.float32),
+            idx_k_norm_bias=jnp.zeros((L, Di), jnp.float32))
     if cfg.n_experts:
         layer.update(_ffn_leaves(stack, cfg, 6, dense=False))
     elif cfg.dense_stack:
@@ -591,11 +634,23 @@ def _stacked_layers(ks, cfg):
         if cfg.bias:
             layer["b1"] = jnp.zeros((L, F), dt)
             layer["b2"] = jnp.zeros((L, E), dt)
+    _scale_branch_outputs(layer, cfg)
     if cfg.pp > 1:
         layer = jax.tree.map(
             lambda x: x.reshape((cfg.pp, cfg.layers_per_stage) + x.shape[1:]), layer
         )
     return layer
+
+
+def _scale_branch_outputs(leaves, cfg):
+    """Every branch's OUTPUT projection of ``leaves`` times
+    ``cfg.residual_out_gain``, in place."""
+    if cfg.residual_out_gain == 1.0:
+        return
+    for name in ("wo", "w_out", "w_down", "we_down", "ws_down"):
+        if name in leaves:
+            leaves[name] = (leaves[name].astype(jnp.float32)
+                            * cfg.residual_out_gain).astype(cfg.jdtype)
 
 
 def _heads_out_width(cfg):
@@ -630,7 +685,8 @@ def _qk_norm_leaves(cfg, n):
         return {}
     widths = (1, 1) if cfg.qk_norm == "head" \
         else (cfg.n_heads, cfg.kv_heads)
-    return {name: jnp.ones((n, heads * cfg.head_dim), jnp.float32)
+    return {name: jnp.full((n, heads * cfg.head_dim), cfg.qk_norm_gain,
+                           jnp.float32)
             for name, heads in zip(("q_norm", "k_norm"), widths)}
 
 
@@ -732,11 +788,7 @@ def _position_leaves(key, cfg, kind, n, dense):
     if kind == FFN or not cfg.single_branch:
         leaves["ln2_scale"] = jnp.ones((n, E), jnp.float32)
         leaves.update(_ffn_leaves(stack, cfg, 5 if dense else 7, dense))
-    if cfg.residual_out_gain != 1.0:
-        for name in ("wo", "w_out", "w_down", "we_down", "ws_down"):
-            if name in leaves:
-                leaves[name] = (leaves[name].astype(jnp.float32)
-                                * cfg.residual_out_gain).astype(dt)
+    _scale_branch_outputs(leaves, cfg)
     return leaves
 
 
@@ -1017,22 +1069,31 @@ def _norm(x, pl, name, cfg, fused=True):
                       eps=cfg.norm_eps, fused=fused)
 
 
-def rope(x, n_heads, theta=10000.0, first=0):
+def rope(x, n_heads, theta=10000.0, first=0, positions=None, sections=()):
     """Rotary position embedding on a packed projection x [b, S, H*dh],
     positions ``first``..``first`` + S - 1 (``first`` may be traced: a block
     of rows of a longer sequence), rotate-half convention (the halves of a
     head are the pairs): ``x * cos + rotate_half(x) * sin`` with angle
-    ``pos * theta^(-2i/dh)`` for both members of pair i.  float32 inside."""
+    ``pos * theta^(-2i/dh)`` for both members of pair i.  float32 inside.
+    ``positions`` [streams, b, S]: the angles' positions as DATA, pair i
+    from the stream ``sections`` gives it (``qk_rope.stream_angles``)."""
     b, S, W = x.shape
     dh = W // n_heads
     half = dh // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    pos = jnp.arange(S, dtype=jnp.float32)
-    if not (isinstance(first, int) and first == 0):
-        pos = pos + first
-    ang = pos[:, None] * inv_freq[None]
-    cos = jnp.tile(jnp.cos(ang), (1, 2))[None, :, None, :]      # [1,S,1,dh]
-    sin = jnp.tile(jnp.sin(ang), (1, 2))[None, :, None, :]
+    if positions is not None:
+        from ..kernels.qk_rope import stream_angles
+
+        ang = stream_angles(positions, half, theta, sections)   # [b,S,half]
+        cos = jnp.tile(jnp.cos(ang), (1, 1, 2))[:, :, None, :]
+        sin = jnp.tile(jnp.sin(ang), (1, 1, 2))[:, :, None, :]
+    else:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+        pos = jnp.arange(S, dtype=jnp.float32)
+        if not (isinstance(first, int) and first == 0):
+            pos = pos + first
+        ang = pos[:, None] * inv_freq[None]
+        cos = jnp.tile(jnp.cos(ang), (1, 2))[None, :, None, :]  # [1,S,1,dh]
+        sin = jnp.tile(jnp.sin(ang), (1, 2))[None, :, None, :]
     xf = x.astype(jnp.float32).reshape(b, S, n_heads, dh)
     rot = jnp.concatenate([-xf[..., half:], xf[..., :half]], axis=-1)
     return (xf * cos + rot * sin).reshape(b, S, W).astype(x.dtype)
@@ -1175,12 +1236,14 @@ def _local_heads(cfg):
         else (cfg.n_heads, cfg.kv_heads)
 
 
-def _qkv(pl, h_full, cfg, rotary, first=0):
+def _qkv(pl, h_full, cfg, rotary, first=0, positions=None):
     """The packed projections q [b, S, hl*dh] and k, v [b, S, kvl*dh] of the
     full sequence ``h_full`` [b, S, E] (or of its rows from position
     ``first`` on), with their biases, the configured q/k norm and, where
     ``rotary``, rotary positions: what attention and power retention both
-    start from.  The latent form: ``_latent_qkv``; where its ``rope_pairs``
+    start from.  ``positions`` [3, b, S]: the rotation's position streams
+    where they are data (``cfg.mrope_sections``).  The latent form:
+    ``_latent_qkv``; where its ``rope_pairs``
     lines run, a block of positions at a time where the sequence is long (no
     whole-sequence float32 q stands)."""
     if cfg.latent and not rotary:
@@ -1216,7 +1279,7 @@ def _qkv(pl, h_full, cfg, rotary, first=0):
     if cfg.qk_norm or rotary:
         q2, k2 = _norm_and_rotate(
             (q2, k2), (pl.get("q_norm"), pl.get("k_norm")), (hl, kvl), fused,
-            cfg, rotary, first)
+            cfg, rotary, first, positions)
     return q2, k2, v2
 
 
@@ -1239,7 +1302,8 @@ def _project_bwd(res, g):
 _project.defvjp(lambda h, w: (h @ w, (h, w)), _project_bwd)
 
 
-def _norm_and_rotate(xs, weights, heads, fused, cfg, rotary, first):
+def _norm_and_rotate(xs, weights, heads, fused, cfg, rotary, first,
+                     positions=None):
     """The configured q/k norm and, where ``rotary``, rotary positions on
     the packed projections ``xs`` ([b, S, heads * dh] each): ONE pass of the
     row kernel (``kernels/qk_rope.py``) over each that ``fused`` says it
@@ -1255,8 +1319,9 @@ def _norm_and_rotate(xs, weights, heads, fused, cfg, rotary, first):
     for took in fused:
         count_call("qk_rope", dh=dh, norm=norm or "none", rotary=int(rotary),
                    convention="half", fused=int(took))
-    tables = qk_rope.angle_tables(xs[0].shape[1], dh, cfg.rope_theta, first) \
-        if rotary and any(fused) else None
+    tables = qk_rope.angle_tables(
+        xs[0].shape[1], dh, cfg.rope_theta, first, positions,
+        cfg.mrope_sections) if rotary and any(fused) else None
 
     def normed(x, weight, n):
         if norm == "head":      # each head on its own, one weight for all
@@ -1265,10 +1330,17 @@ def _norm_and_rotate(xs, weights, heads, fused, cfg, rotary, first):
         # over the whole projection, before the heads
         return rms_norm(x, weight, cfg.norm_eps) if norm else x
 
-    xs = [qk_rope.qk_rope(x, w, tables, head_dim=dh, norm=norm,
-                          eps=cfg.norm_eps) if took else normed(x, w, n)
+    def kernel(x, w):
+        # positions that are data: a table row a (batch row, position), the
+        # batch folded into the rows
+        rows = x.reshape((1, -1, x.shape[-1])) if positions is not None else x
+        return qk_rope.qk_rope(rows, w, tables, head_dim=dh, norm=norm,
+                               eps=cfg.norm_eps).reshape(x.shape)
+
+    xs = [kernel(x, w) if took else normed(x, w, n)
           for x, w, n, took in zip(xs, weights, heads, fused)]
-    return [rope(x, n, cfg.rope_theta, first) if rotary and not took else x
+    return [rope(x, n, cfg.rope_theta, first, positions, cfg.mrope_sections)
+            if rotary and not took else x
             for x, n, took in zip(xs, heads, fused)]
 
 
@@ -1415,14 +1487,87 @@ def _gate_heads(o, z):
             * jax.nn.sigmoid(z.astype(jnp.float32))).astype(o.dtype)
 
 
-def _attention_heads_mode(pl, h_full, cfg, kind):
+def indexer_operands(pl, h, cfg, positions=None):
+    """What the indexer's scores are made of, from the normed rows ``h`` [b,
+    S, E]: its queries [b, S, Hi * Di] and its ONE key head [b, S, Di]
+    (LayerNorm, scale and bias), both rotated over all Di columns by the
+    temporal stream (the token index where ``positions`` [3, b, S] is None),
+    and the heads' weights [b, S, Hi] float32, ``(h w_idx) Hi^(-1/2)
+    Di^(-1/2)``."""
+    Hi, Di = cfg.indexer_heads, cfg.indexer_dim
+    k = (h @ pl["wk_idx"]).astype(jnp.float32)
+    k = k - jnp.mean(k, axis=-1, keepdims=True)
+    k = k * jax.lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True)
+                          + cfg.norm_eps)
+    k = (k * pl["idx_k_norm_scale"] + pl["idx_k_norm_bias"]).astype(h.dtype)
+    temporal = None if positions is None else positions[:1]
+    q, k = (rope(x, n, cfg.rope_theta, positions=temporal)
+            for x, n in ((h @ pl["wq_idx"], Hi), (k, 1)))
+    w = (h @ pl["w_idx"]).astype(jnp.float32) * (Hi ** -0.5 * Di ** -0.5)
+    return q, k, w
+
+
+DSA_TAU = "dsa_tau"     # the selection's thresholds, a layer's residual
+
+
+def indexer_selection(pl, h, cfg, positions=None):
+    """(scores [b, S, S] float32, thresholds [b, S]) of a layer's indexer on
+    the normed rows ``h``: query t reads the causal keys whose score is at
+    or above its threshold, the ``indexer_topk``-th largest of its row.  The
+    thresholds are a constant of the step (no gradient) and, under a layer's
+    remat, its residual: the second forward does not select again."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from ..kernels import indexer as ix
+
+    bq, bk = _clamped_blocks(cfg, h.shape[1])
+    with jax.named_scope(devscope.INDEXER):
+        scores = ix.indexer_scores(*indexer_operands(pl, h, cfg, positions),
+                                   block_q=bq, block_k=bk)
+    with jax.named_scope(devscope.INDEXER_SELECT):
+        tau = checkpoint_name(ix.kth_largest(
+            jax.lax.stop_gradient(scores), cfg.indexer_topk), DSA_TAU)
+    return scores, tau
+
+
+def _clamped_blocks(cfg, S):
+    """The flash kernels' (q block, kv block), clamped to S."""
+    return min(cfg.flash_block_q, S), min(cfg.flash_block_k, S)
+
+
+def _sparse_attention(pl, h, cfg, rotary, positions):
+    """Learned-sparse attention's branch [b, S, E] and the indexer's loss
+    term (a scalar): the masked flash calls under the indexer's selection,
+    then ``wo``.  The cross entropy reaches q, k and v alone (the mask passes
+    no gradient); the KL term reaches the indexer's leaves alone (its
+    target, the heads' mean probabilities, and the indexer's input are
+    constants)."""
+    from ..kernels import indexer as ix
+    from ..kernels.flash_attention import flash_dsa_packed
+
+    stop = jax.lax.stop_gradient
+    hl, kvl = _local_heads(cfg)
+    bq, bk = _clamped_blocks(cfg, h.shape[1])
+    q2, k2, v2 = _qkv(pl, h, cfg, rotary, positions=positions)
+    scores, tau = indexer_selection(pl, stop(h), cfg, positions)
+    with jax.named_scope(devscope.SPARSE_ATTN):
+        o, lse = flash_dsa_packed(q2, k2, v2, stop(scores), tau, hl, kvl,
+                                  block_q=bq, block_k=bk)
+    with jax.named_scope(devscope.INDEXER_KL):
+        kl = ix.indexer_kl(scores, tau, stop(q2), stop(k2), stop(lse), hl,
+                           kvl, block_q=bq, block_k=bk)
+    return o @ pl["wo"], kl
+
+
+def _attention_heads_mode(pl, h_full, cfg, kind, positions=None):
     """Megatron attention: input full-sequence [b,S,E], heads sharded over tp.
-    ``kind`` = (window or None, rotary) of this layer."""
+    ``kind`` = (window or None, rotary) of this layer.  ``positions`` [3, b,
+    S]: the rotation's position streams where they are data."""
     b, S, E = h_full.shape
     hl, kvl = _local_heads(cfg)
     dh = cfg.head_dim
     window, rotary = kind
-    q2, k2, v2 = _qkv(pl, h_full, cfg, rotary)
+    q2, k2, v2 = _qkv(pl, h_full, cfg, rotary, positions=positions)
     two_widths = cfg.latent and cfg.v_head_dim != dh
     blocks = not two_widths and _packed_flash_blocks(cfg, hl, S, kvl)
     if two_widths:
@@ -1878,7 +2023,7 @@ def _add_branch(x, branch, pl, name, cfg):
 
 
 def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
-                      dense=False, router_bias=None):
+                      dense=False, router_bias=None, positions=None):
     """One pre-norm block on the SP activation [b, S/tp, E]: the new
     activation and the FFN's auxiliary values (the MoE's, ``moe.route_top_k``;
     None for a dense FFN).  ``kind`` = (window or None, rotary), or CONV,
@@ -1888,9 +2033,12 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
     ``kind`` is FFN the feed-forward part's alone;
     ``dense``: a layer whose FFN is the dense gated one (a leading layer, or
     any layer of a stack without experts); ``router_bias`` [n]:
-    this layer's selection biases, where the routing rule has them."""
+    this layer's selection biases, where the routing rule has them;
+    ``positions`` [3, b, S]: the rotation's position streams, where the
+    batch carries them.  A layer with an indexer adds its loss term to the
+    auxiliary values (``dsa_kl``)."""
     heads_mode = cfg.attn_mode == "heads"
-    logits = None
+    logits, extras = None, {}
     if cfg.n_experts and cfg.router_input == "block" and not dense \
             and (kind == FFN or not cfg.single_branch):
         from .moe import router_logits
@@ -1923,10 +2071,13 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
         with jax.named_scope(devscope.LATENT_ATTENTION if cfg.latent
                              else devscope.ATTENTION):
             h = _norm(x_sp, pl, "ln1", cfg)
-            if heads_mode:
+            if cfg.indexer_heads:
+                attn, extras["dsa_kl"] = _sparse_attention(
+                    pl, h, cfg, (kind or cfg.layer_kinds[0])[1], positions)
+            elif heads_mode:
                 h = col.all_gather(h, TP, dim=1)
-                attn = _attention_heads_mode(pl, h, cfg,
-                                             kind or cfg.layer_kinds[0])
+                attn = _attention_heads_mode(
+                    pl, h, cfg, kind or cfg.layer_kinds[0], positions)
             else:
                 attn = _attention_ring_mode(pl, h, cfg)
             x_sp = _add_branch(x_sp, attn, pl, "ln1", cfg)
@@ -1949,6 +2100,7 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
                 first_held=cfg.first_expert, bias=router_bias,
                 scale=cfg.route_scale)
             y = y.reshape(h.shape)
+            aux = dict(aux, **extras)
             if not cfg.shared_ffn_hidden:
                 return _add_branch(x_sp, y, pl, "ln2", cfg), aux
             if not cfg.post_norm:
@@ -1983,7 +2135,7 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
 
 
 def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
-               prefix=None, router_bias=None):
+               prefix=None, router_bias=None, positions=None):
     """scan over the (local) stacked layers; remat per layer if configured.
     ``with_aux`` also returns the layers' auxiliary values, stacked [L]
     (``moe.route_top_k``'s of each MoE layer; None for a dense stack).
@@ -2008,17 +2160,26 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
     one of another is three bodies, not fourteen).  The scan's own work (its
     slices of the stacked leaves, what it keeps for the backward pass, the
     gradients it stacks: 4 % of a step where a layer's leaves are 0.5 GB)
-    goes under the scope ``layer_scan``; a layer's under the layer's."""
+    goes under the scope ``layer_scan``; a layer's under the layer's.
+
+    ``positions`` [3, b, S]: the rotation's position streams where the batch
+    carries them (a stack of one attention kind)."""
     kinds = cfg.layer_kinds
+    assert positions is None or (len(kinds) == 1 and not cfg.per_position)
     body = transformer_layer
     if cfg.remat:
-        body = jax.checkpoint(body, static_argnums=(2, 3, 4))
+        # an indexer's thresholds are kept: the second forward makes the
+        # scores again (the backward reads them) and selects nothing
+        body = jax.checkpoint(
+            body, static_argnums=(2, 3, 4),
+            policy=jax.checkpoint_policies.save_only_these_names(DSA_TAU)
+            if cfg.indexer_heads else None)
     unroll = max(int(cfg.scan_unroll), 1)
     if len(kinds) == 1 and not cfg.per_position:
         with jax.named_scope(devscope.LAYER_SCAN):
             x_sp, aux = jax.lax.scan(
                 lambda x, turn: body(turn[0], x, cfg, kinds[0],
-                                     cfg.dense_stack, turn[1]),
+                                     cfg.dense_stack, turn[1], positions),
                 x_sp, (layer_params, router_bias), unroll=unroll)
         return (x_sp, aux) if with_aux else x_sp
 

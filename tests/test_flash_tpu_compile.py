@@ -1287,3 +1287,104 @@ def test_a_kda_layer_s_text_holds_no_float32_view_by_heads(one_chip):
     # forward is the epilogue of its matmul)
     assert sum(v for k, v in by_kernel.items()
                if k.startswith(("kda_l2", "kda_log", "kda_norm"))) < 3.8e9
+
+
+DSA_CELL = (1, 16384, 32, 4, 128, 16, 64)   # B, S, H, Hkv, D, Hi, Di
+
+
+@pytest.mark.parametrize("kernel", ["flash_dsa", "indexer_scores",
+                                    "indexer_kl"])
+def test_the_learned_sparse_kernels_compile_for_a_v5e(one_chip, kernel):
+    """``keye_vl2_30b_a3b.s16384_scan``'s new kernels through Mosaic at the
+    cell's shapes, forward and backward: the masked flash sweeps (the
+    triangle's 528 steps, the scores' tile and the thresholds' rows beside
+    q, k, v; the backward ONE sweep with dk and dv of all 16,384 positions
+    in VMEM, as the causal mode's), the indexer's scores (the backward's dk
+    of the one key head whole in VMEM) and the KL pass (a head a grid
+    step)."""
+    ix = importlib.import_module("paddle_tpu.kernels.indexer")
+    B, S, H, Hkv, D, Hi, Di = DSA_CELL
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32 = jnp.float32
+    q, kv = sds((B, S, H * D)), sds((B, S, Hkv * D))
+    scores, tau = sds((B, S, S), f32), sds((B, S), f32)
+    steps = fa.kv_blocks(S, 512, 512, True)
+    assert steps == 528
+    if kernel == "flash_dsa":
+        def both(q, k, v, scores, tau, do):
+            (o, lse), vjp = jax.vjp(lambda *x: fa.flash_dsa_packed(
+                *x, scores, tau, H, Hkv, interpret=False), q, k, v)
+            return (o, lse) + vjp((do, jnp.zeros_like(lse)))
+        args, names = (q, kv, kv, scores, tau, q), {
+            "flash_dsa_fwd": (B, Hkv, H // Hkv, steps),
+            "flash_dsa_bwd_fused": (B, Hkv, H // Hkv * steps)}
+    elif kernel == "indexer_scores":
+        def both(q, k, w, g):
+            out, vjp = jax.vjp(lambda *x: ix.indexer_scores(
+                *x, interpret=False), q, k, w)
+            return (out,) + vjp(g)
+        args, names = (sds((B, S, Hi * Di)), sds((B, S, Di)),
+                       sds((B, S, Hi), f32), scores), {
+            "indexer_scores_fwd": (B, S // 512, S // 512),
+            "indexer_scores_bwd": (B, steps)}
+    else:
+        def both(scores, tau, q, k, lse):
+            return jax.value_and_grad(lambda x: ix.indexer_kl(
+                x, tau, q, k, lse, H, Hkv, interpret=False))(scores)
+        args, names = (scores, tau, q, kv, sds((B, H, S, 1), f32)), {
+            "indexer_kl_fwd": (B, steps, H)}
+    traced = jax.jit(both).trace(*args)
+    grids = {name: tuple(int(n) for n in grid.split(",") if n.strip())
+             for grid, name in re.findall(
+                 r"grid=\(([\d, ]*)\).*?name=((?:flash_dsa|indexer)_\w+)",
+                 str(traced.jaxpr), re.S)}
+    # the row kernel in front of the flash backward (``flash_delta``) has a
+    # grid of its own, which this pattern reads as the backward's
+    grids["flash_dsa_bwd_fused"] = names.get("flash_dsa_bwd_fused")
+    assert {n: grids[n] for n in names} == names
+    text = traced.lower().compile().as_text()
+    for name in names:
+        asked, took = _vmem(text, name)
+        assert took < (asked or fa.SCOPED_VMEM), (name, asked, took)
+    if kernel == "flash_dsa":
+        assert _vmem(text, "flash_dsa_bwd_fused")[0] \
+            == fa.fused_sweep_vmem_bytes(S, 128, 2)
+
+
+@pytest.mark.parametrize("b,S", [(1, 16384), (2, 8192)])
+def test_the_three_stream_rotary_pass_compiles_for_a_v5e(one_chip, b, S):
+    """The row kernel with positions that are DATA, as
+    ``transformer._norm_and_rotate`` calls it where a batch carries
+    ``positions`` [3, b, S] (temporal, height, width; sections [16, 24, 24]
+    of a head's 64 pairs): the tables [b * S, 128] from ``angle_tables(
+    positions=)``, the batch folded into the rows, the per-head norm in the
+    same pass, on q (32 heads of 128) and k (4), forward and backward, at
+    the cell's rows.  The cell itself sends text positions and no such
+    field, so it takes the plain tables: this compile is all that holds the
+    stream path to Mosaic's rules."""
+    qr = importlib.import_module("paddle_tpu.kernels.qk_rope")
+    dh, sections = 128, (16, 24, 24)
+    positions = jax.ShapeDtypeStruct((3, b, S), jnp.int32, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((dh,), jnp.float32, sharding=one_chip)
+    for heads in (32, 4):
+        W = heads * dh
+        x = jax.ShapeDtypeStruct((b, S, W), jnp.bfloat16, sharding=one_chip)
+
+        def both(x, w, positions, g):
+            tables = qr.angle_tables(S, dh, 1e7, 0, positions, sections)
+            assert tables[0].shape == (b * S, 128)
+            out, vjp = jax.vjp(lambda x, w: qr.qk_rope(
+                x.reshape(1, b * S, W), w, tables, head_dim=dh, norm="head",
+                eps=1e-6, interpret=False).reshape(x.shape), x, w)
+            return (out,) + vjp(g)
+
+        text = jax.jit(both).lower(x, w, positions, x).compile().as_text()
+        rows = qr.block_rows(b * S, W, 2)
+        assert qr.supported((1, b * S, W), dh, 2)
+        for kernel in ("qk_rope_fwd", "qk_rope_bwd"):
+            asked, took = _vmem(text, kernel)
+            assert asked == qr.vmem_bytes(rows, W, 2) < 20 * 2 ** 20
+            assert took < asked, (heads, kernel, took, asked)

@@ -1,0 +1,133 @@
+"""``kernels/indexer.py`` and the flash kernels' masked mode
+(``flash_dsa_packed``) in interpret mode, against the float32 formulas: the
+indexer's scores forward and backward, the k-th largest of a row against
+``numpy.partition``, attention under a mask that is data against a dense
+masked softmax, and the indexer's KL term.  ONE traced program for the
+file: every check reads it."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.kernels import indexer as ix
+from paddle_tpu.kernels.flash_attention import flash_dsa_packed
+
+B, S, HI, DI, H, HKV, D, K, BLOCK = 2, 64, 4, 16, 8, 2, 128, 8, 16
+TRI = np.tril(np.ones((S, S), bool))
+
+
+def ref_scores(q, k, w):
+    s = jnp.einsum("bthd,bsd->bhts", q.reshape(B, S, HI, DI), k)
+    return jnp.where(TRI, jnp.einsum("bth,bhts->bts", w, jax.nn.relu(s)),
+                     -jnp.inf)
+
+
+def dense(q, k, v, scores, tau):
+    """(o, lse, probabilities [B, H, S, S]) of the dense masked softmax."""
+    keep = ix.selected(scores, tau)
+    qh = q.reshape(B, S, H, D)
+    kh, vh = (jnp.repeat(x.reshape(B, S, HKV, D), H // HKV, 2)
+              for x in (k, v))
+    s = jnp.where(keep[:, None], jnp.einsum("bthd,bshd->bhts", qh, kh)
+                  / D ** 0.5, -jnp.inf)
+    a = jax.nn.softmax(s, -1)
+    return (jnp.einsum("bhts,bshd->bthd", a, vh).reshape(B, S, H * D),
+            jax.nn.logsumexp(s, -1), a)
+
+
+def ref_kl(scores, tau, a):
+    keep = ix.selected(scores, tau)
+    p = jnp.mean(a, 1)
+    log_r = jax.nn.log_softmax(jnp.where(keep, scores, -jnp.inf), -1)
+    return jnp.sum(jnp.where(p > 0, p * (jnp.log(jnp.where(p > 0, p, 1.0))
+                                         - jnp.where(keep, log_r, 0.0)),
+                             0.0)) / (B * S)
+
+
+@pytest.fixture(scope="module")
+def case():
+    """``{check: (largest absolute difference, scale)}`` of everything the
+    file holds, from one jitted program."""
+    r = np.random.RandomState(0)
+    f32 = lambda *shape: jnp.asarray(r.randn(*shape), jnp.float32)
+    qi, ki, w = f32(B, S, HI * DI), f32(B, S, DI), f32(B, S, HI)
+    q, k, v = f32(B, S, H * D), f32(B, S, HKV * D), f32(B, S, HKV * D)
+    c_scores, c_out = f32(B, S, S), f32(B, S, H * D)
+    blocks = dict(block_q=BLOCK, block_k=BLOCK)
+
+    def program():
+        weigh = lambda fn: lambda *a: jnp.sum(jnp.where(TRI, fn(*a)
+                                                        * c_scores, 0.0))
+        got, want = (ix.indexer_scores(qi, ki, w, **blocks),
+                     ref_scores(qi, ki, w))
+        g_got = jax.grad(weigh(lambda *a: ix.indexer_scores(*a, **blocks)),
+                         (0, 1, 2))(qi, ki, w)
+        g_want = jax.grad(weigh(ref_scores), (0, 1, 2))(qi, ki, w)
+        tau = ix.kth_largest(got, K, rows=BLOCK)
+        o, lse = flash_dsa_packed(q, k, v, got, tau, H, HKV, **blocks)
+        o_want, lse_want, a = dense(q, k, v, got, tau)
+        f_got = jax.grad(lambda *x: jnp.sum(flash_dsa_packed(
+            *x, got, tau, H, HKV, **blocks)[0] * c_out), (0, 1, 2))(q, k, v)
+        f_want = jax.grad(lambda *x: jnp.sum(dense(*x, got, tau)[0] * c_out),
+                          (0, 1, 2))(q, k, v)
+        kl = lambda x: ix.indexer_kl(x, tau, q, k, lse, H, HKV, **blocks)
+        kl_want = lambda x: ref_kl(x, tau, a)
+        finite = jnp.where(TRI, got, -1e9)
+        return dict(
+            scores=(got, want), tau=tau, o=(o, o_want),
+            lse=(lse[..., 0], lse_want),
+            kl=(kl(got), kl_want(got)),
+            kl_grad=(jnp.where(TRI, jax.grad(kl)(got), 0.0),
+                     jnp.where(TRI, jax.grad(kl_want)(finite), 0.0)),
+            **{"scores_d" + n: (a_, b_) for n, a_, b_ in zip(
+                ("q", "k", "w"), g_got, g_want)},
+            **{"flash_d" + n: (a_, b_) for n, a_, b_ in zip(
+                "qkv", f_got, f_want)})
+
+    with jax.default_matmul_precision("highest"):
+        return jax.device_get(jax.jit(program)())
+
+
+@pytest.mark.parametrize("check,tolerance", [
+    ("scores", 1e-5), ("scores_dq", 1e-5), ("scores_dk", 1e-5),
+    ("scores_dw", 2e-5), ("o", 1e-5), ("lse", 1e-5), ("flash_dq", 1e-5),
+    ("flash_dk", 1e-5), ("flash_dv", 1e-5), ("kl", 1e-6), ("kl_grad", 1e-6)])
+def test_a_kernel_agrees_with_its_float32_formula(case, check, tolerance):
+    got, want = (np.asarray(x) for x in case[check])
+    assert np.array_equal(np.isfinite(got), np.isfinite(want)), check
+    ok = np.isfinite(want)
+    assert np.max(np.abs(got[ok] - want[ok])) <= tolerance * max(
+        1.0, np.max(np.abs(want[ok]))), check
+
+
+def test_the_kth_largest_is_numpy_partition_s(case):
+    scores = np.asarray(case["scores"][0])
+    want = np.partition(scores, S - K, axis=-1)[..., S - K]
+    assert np.array_equal(case["tau"], want)
+    # fewer causal keys than k: no threshold, every causal key is read
+    assert np.all(np.isneginf(case["tau"][:, :K - 1]))
+    assert np.all(np.isfinite(case["tau"][:, K - 1:]))
+
+
+@pytest.mark.parametrize("k", [1, 3, 64, 100])
+def test_the_kth_largest_of_rows_with_ties_and_signs(k):
+    r = np.random.RandomState(k)
+    rows = r.randn(1, 16, 64).astype(np.float32)
+    rows[0, :4, ::2] = 0.0                  # exact zeros, half a row
+    rows[0, 4:8] = np.round(rows[0, 4:8])   # many ties
+    rows[0, 8, :] = -0.0
+    rows[0, 9, 10:] = -np.inf
+    got = np.asarray(ix.kth_largest(jnp.asarray(rows), k, rows=8))
+    n = min(k, 64)
+    assert np.array_equal(got, np.partition(rows, 64 - n, axis=-1)[..., 64 - n])
+
+
+def test_ties_at_the_threshold_are_all_kept():
+    scores = np.full((1, 16, 16), -np.inf, np.float32)
+    scores[np.tril(np.ones((1, 16, 16), bool))] = 1.0
+    scores[0, :, 0] = 2.0
+    tau = ix.kth_largest(jnp.asarray(scores), 4)
+    kept = np.asarray(ix.selected(jnp.asarray(scores), tau)).sum(-1)[0]
+    # the fourth largest is one of the tied 1.0s: every causal key stays
+    assert np.array_equal(kept, np.arange(1, 17))

@@ -5,7 +5,9 @@ runs this file before anything else; a PR that means to change one re-takes
 that one's digests and says so in the comment below."""
 
 import hashlib
+import json
 import os
+import subprocess
 import sys
 
 import jax
@@ -17,7 +19,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from paddle_tpu.models import (bert, brumby, jamba, kimi_linear,  # noqa: E402
+from paddle_tpu.models import (bert, brumby, jamba, keye_vl2,  # noqa: E402
+                               kimi_linear,
                                lfm2, mistral4, nemotron_h, olmoe, ouro,
                                resnet, smallthinker, trinity)
 from paddle_tpu.parallel import decoder  # noqa: E402
@@ -65,7 +68,11 @@ from paddle_tpu.parallel.train import stack_batches  # noqa: E402
 # of the twenty-two: the latent form's new branches (no positions, no query
 # latent, a value width of its own), the layer kind KDA and the flash
 # kernels' value-width mode left every older program's text as it was,
-# Mistral's included; Kimi-Linear's two joined, taken on that PR's tree.
+# Mistral's included; Kimi-Linear's two joined, taken on that PR's tree.  PR 61
+# re-took NONE of the twenty-four: the flash kernels' masked mode, the
+# indexer, the position streams of ``rope`` / ``angle_tables`` and the
+# checkpoint policy of a layer with an indexer left every older program's
+# text as it was; Keye-VL-2.0's two joined, taken on that PR's tree.
 PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "bert.run_steps": "00de5403506fdc87",
             "olmoe.step": "231114fcd62341f2",
@@ -89,7 +96,9 @@ PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "resnet.step": "350db1fba0d68284",
             "resnet.run_steps": "dc9dd853700f9ab9",
             "kimi_linear.step": "00fafaa79be69c29",
-            "kimi_linear.run_steps": "768fb807ebe118b1"}
+            "kimi_linear.run_steps": "768fb807ebe118b1",
+            "keye_vl2.step": "4322024e13a4c588",
+            "keye_vl2.run_steps": "995bef2d468f6be3"}
 OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
          "olmoe": (olmoe.build_olmoe_trainer, olmoe.olmoe_tiny_config, 32),
          "smallthinker": (smallthinker.build_smallthinker_trainer,
@@ -108,32 +117,71 @@ OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
          "resnet": (resnet.build_resnet_trainer,
                     lambda remat: resnet.resnet_tiny_config(), 32),
          "kimi_linear": (kimi_linear.build_kimi_linear_trainer,
-                         kimi_linear.kimi_linear_tiny_config, 64)}
+                         kimi_linear.kimi_linear_tiny_config, 64),
+         "keye_vl2": (keye_vl2.build_keye_vl2_trainer,
+                      keye_vl2.keye_vl2_tiny_config, 64)}
+
+
+def digests(names):
+    """``{program: the first 16 hex digits of its text's sha256}`` of the
+    two programs of each named trainer, in THIS process."""
+    out = {}
+    for name in names:
+        build, config, seq = OLDER[name]
+        tr = build(config(remat=True), MeshSpec(dp=1), seed=3,
+                   devices=jax.devices()[:1])
+        ids = np.zeros((2, seq), np.int32)
+        batch, specs = {"ids": ids}, decoder.BATCH_SPECS
+        if name == "bert":
+            batch = {"ids": ids, "labels": ids,
+                     "mask": np.ones((2, seq), np.float32)}
+            specs = bert.batch_specs(tuple(batch))
+        if name == "resnet":
+            batch = {"image": np.zeros((2, seq, seq, 3), np.float32),
+                     "label": np.zeros((2,), np.int32)}
+            specs = resnet.BATCH_SPECS
+        one = {k: jnp.asarray(v) for k, v in batch.items()}
+        many = stack_batches(tr.mesh, specs, [batch, batch])
+        for label, fn, args in (("step", tr.step_fn, (tr.state, one, 1e-3)),
+                                ("run_steps", tr.multi_fn,
+                                 (tr.state, many, 1e-3))):
+            out["%s.%s" % (name, label)] = hashlib.sha256(
+                fn.lower(*args).as_text().encode()).hexdigest()[:16]
+    return out
+
+
+# ONE worker takes this file, so ``fresh``'s process runs once: the tier-1
+# command's ``--dist loadfile`` keeps a file together, and under
+# ``--dist loadgroup`` this mark does
+pytestmark = pytest.mark.xdist_group("program_digests")
+
+
+@pytest.fixture(scope="module")
+def fresh():
+    """Every program's digest, taken in a process of its OWN.  A lowered
+    text names its private functions in the order the module met them, and
+    two call sites share one only where the process's trace cache hands both
+    the same jaxpr: in a worker that has traced other files' programs first,
+    a MoE step lowered with one more ``_where`` and every later symbol
+    renumbered (PR 61 met it when two new test files moved this file to
+    another xdist worker; the programs were the parent's).  What a PR did to
+    a program is what a fresh process lowers."""
+    run = subprocess.run([sys.executable, os.path.abspath(__file__)],
+                         capture_output=True, text=True)
+    assert run.returncode == 0, run.stderr[-3000:]
+    return json.loads(run.stdout.splitlines()[-1])
 
 
 @pytest.mark.parametrize("name", list(OLDER))
-def test_the_older_transformers_programs_lower_to_the_parent_s_text(name):
-    build, config, seq = OLDER[name]
-    tr = build(config(remat=True), MeshSpec(dp=1), seed=3,
-               devices=jax.devices()[:1])
-    ids = np.zeros((2, seq), np.int32)
-    batch, specs = {"ids": ids}, decoder.BATCH_SPECS
-    if name == "bert":
-        batch = {"ids": ids, "labels": ids,
-                 "mask": np.ones((2, seq), np.float32)}
-        specs = bert.batch_specs(tuple(batch))
-    if name == "resnet":
-        batch = {"image": np.zeros((2, seq, seq, 3), np.float32),
-                 "label": np.zeros((2,), np.int32)}
-        specs = resnet.BATCH_SPECS
-    one = {k: jnp.asarray(v) for k, v in batch.items()}
-    many = stack_batches(tr.mesh, specs, [batch, batch])
-    for label, fn, args in (("step", tr.step_fn, (tr.state, one, 1e-3)),
-                            ("run_steps", tr.multi_fn,
-                             (tr.state, many, 1e-3))):
+def test_the_older_transformers_programs_lower_to_the_parent_s_text(fresh,
+                                                                    name):
+    for label in ("step", "run_steps"):
         program = "%s.%s" % (name, label)
-        got = hashlib.sha256(fn.lower(*args).as_text().encode()).hexdigest()
-        assert got[:16] == PROGRAMS[program], (
+        assert fresh[program] == PROGRAMS[program], (
             "the program %s lowers to another text than the table holds: "
             "re-take the digest only if the PR means to change this program"
             % program)
+
+
+if __name__ == "__main__":
+    print(json.dumps(digests(list(OLDER))))
