@@ -16,14 +16,22 @@ Three pieces, each a (q block, k block) tile at a time, so that nothing
   of float32 (``_ordered``: an unsigned integer whose order is the float's)
   fix the answer a bit a pass.  A row with fewer than k finite entries
   answers ``-inf``: every causal key is selected.
-- ``indexer_kl`` (Pallas, forward; its backward is a product): the mean over
-  rows of ``KL(p_t || softmax over S_t of I[t, .])``, ``S_t = {s <= t :
-  I[t, s] >= tau_t}``, ``p[t, s] = mean over the heads of the main
-  attention's probabilities``, recomputed a tile from q, k and the flash
-  forward's saved ``lse``, a head a grid step, summed in scratch.  The same
-  pass writes ``G = softmax_S(I) - p`` (0 off ``S_t``), which IS ``dKL/dI``:
-  the backward keeps no other [S, S] array and reads no q or k again.  p
-  carries a stop-gradient: q, k and lse get none.
+- ``dsa_attend_kl`` (Pallas, forward; its backward is the masked flash
+  backward and the scores' backward): the attention's output under the
+  selection AND the mean over rows of ``KL(p_t || softmax over S_t of I[t,
+  .])``, ``S_t = {s <= t : I[t, s] >= tau_t}``, ``p[t, s] = mean over the
+  heads of the main attention's probabilities``, in ONE sweep with the
+  statistic known: a (tile, key/value head) pair a grid step, its group's
+  query heads looped inside, each head's tile ``exp(q k^T - lse)`` made
+  once and used twice, through ``p v`` into the head's accumulator (``o``)
+  and into the heads' sum in scratch (``p``).  The same pass writes ``G =
+  softmax_S(I) - p`` (0 off ``S_t``), which IS ``dKL/dI``: the backward
+  hands it to ``indexer_scores_bwd`` with the cotangent's scalar as that
+  kernel's gain, so no other [S, S] array is kept or made.  p carries a
+  stop-gradient: the KL term reaches the scores' operands alone.  ``lse``
+  is the masked online forward's (``dsa_lse``: ``flash_dsa_fwd``), which
+  under a layer's remat runs ONCE: the statistic is kept, the recompute is
+  this pass alone.
 
 interpret=None auto-selects the Pallas interpreter off-TPU.
 """
@@ -35,13 +43,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._common import CompilerParams as _CompilerParams, on_tpu as _on_tpu
-from .flash_attention import FIRST, LAST, step_table
+from ..monitor import devscope
+from ._common import (LANES, CompilerParams as _CompilerParams,
+                      on_tpu as _on_tpu)
+from .flash_attention import (FIRST, LAST, _bwd as _flash_bwd,
+                              _fwd as _flash_fwd, step_table)
 
-__all__ = ["indexer_scores", "kth_largest", "indexer_kl", "selected"]
+__all__ = ["indexer_scores", "kth_largest", "selected", "selected_lse",
+           "dsa_lse", "dsa_attend_kl"]
 
 NEG_INF = float("-inf")
 SELECT_ROWS = 256       # rows of I a counting pass of ``kth_largest`` reads
+
+
+def _blocks(S, block_q, block_k, interpret):
+    """(q block, k block) clamped to S, and whether to interpret."""
+    bq, bk = min(block_q, S), min(block_k, S)
+    assert S % bq == 0 and S % bk == 0, (S, bq, bk)
+    return bq, bk, bool(not _on_tpu() if interpret is None else interpret)
 
 
 def _causal(shape, q0, k0):
@@ -99,9 +118,9 @@ def _scores_fwd_call(q, k, w, bq, bk, interpret):
     )(q, k, w)
 
 
-def _scores_bwd_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, w_ref,
-                       g_ref, dq_ref, dk_ref, dw_ref, dq_scr, dw_scr, dk_acc,
-                       *, heads, bq, bk):
+def _scores_bwd_kernel(q_of, kv_of, head_of, flags, gain_ref, q_ref, k_ref,
+                       w_ref, g_ref, dq_ref, dk_ref, dw_ref, dq_scr, dw_scr,
+                       dk_acc, *, heads, bq, bk):
     t = pl.program_id(1)
     di = k_ref.shape[-1]
     rows = pl.ds(pl.multiple_of(kv_of[t] * bk, bk), bk)
@@ -117,7 +136,7 @@ def _scores_bwd_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, w_ref,
 
     q, k, w = q_ref[0], k_ref[0], w_ref[0]
     g = jnp.where(_causal((bq, bk), q_of[t] * bq, kv_of[t] * bk),
-                  g_ref[0], 0.0)
+                  g_ref[0] * gain_ref[0], 0.0)
     dk = jnp.zeros((bk, di), jnp.float32)
     dws = []
     for h, s in _head_products(q, k, heads):
@@ -163,7 +182,10 @@ def _triangle_call(kernel, name, table, B, operands, in_specs, out_specs,
     )(*(jnp.asarray(column) for column in table), *operands)
 
 
-def _scores_bwd_call(q, k, w, g, bq, bk, interpret):
+def _scores_bwd_call(q, k, w, g, bq, bk, interpret, gain=1.0):
+    """(dq, dk, dw) of the cotangent ``gain * g``, ``gain`` a scalar the
+    tile is multiplied by as it is read: a cotangent that is a kept [S, S]
+    array times a scalar (``dsa_attend_kl``'s) is never made."""
     B, S, W = q.shape
     heads, di = w.shape[-1], k.shape[-1]
     table = step_table(S, S, bq, bk, True)
@@ -173,8 +195,10 @@ def _scores_bwd_call(q, k, w, g, bq, bk, interpret):
     whole = lambda b, t, *table: (b, 0, 0)
     return _triangle_call(
         functools.partial(_scores_bwd_kernel, heads=heads, bq=bq, bk=bk),
-        "indexer_scores_bwd", table, B, (q, k, w, g),
-        [pl.BlockSpec((1, bq, W), qrow), pl.BlockSpec((1, bk, di), krow),
+        "indexer_scores_bwd", table, B,
+        (jnp.reshape(gain, (1,)).astype(jnp.float32), q, k, w, g),
+        [pl.BlockSpec(memory_space=pltpu.SMEM),
+         pl.BlockSpec((1, bq, W), qrow), pl.BlockSpec((1, bk, di), krow),
          pl.BlockSpec((1, bq, heads), qrow), pl.BlockSpec((1, bq, bk), tile)],
         [pl.BlockSpec((1, bq, W), qrow), pl.BlockSpec((1, S, di), whole),
          pl.BlockSpec((1, bq, heads), qrow)],
@@ -215,11 +239,8 @@ def indexer_scores(q, k, w, block_q=512, block_k=512, interpret=None):
     heads = w.shape[-1]
     assert W == heads * k.shape[-1] and k.shape[:2] == (B, S) \
         and w.shape[:2] == (B, S), (q.shape, k.shape, w.shape)
-    bq, bk = min(block_q, S), min(block_k, S)
-    assert S % bq == 0 and S % bk == 0, (S, bq, bk)
-    if interpret is None:
-        interpret = not _on_tpu()
-    return _scores(q, k, w.astype(jnp.float32), bq, bk, bool(interpret))
+    bq, bk, interpret = _blocks(S, block_q, block_k, interpret)
+    return _scores(q, k, w.astype(jnp.float32), bq, bk, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -280,71 +301,116 @@ def selected(scores, tau):
 
 
 # ---------------------------------------------------------------------------
-# the indexer's own loss term
+# attention under the selection with the statistic known, and the indexer's
+# own loss term, in one pass
 # ---------------------------------------------------------------------------
 
-def _kl_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, lse_ref, i_ref,
-               tau_ref, lsei_ref, kl_ref, g_ref, p_scr, *, scale, heads, bq,
-               bk):
-    t, n = pl.program_id(1), pl.program_id(2)
+def _attend_kl_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref,
+                      lse_ref, i_ref, tau_ref, lsei_ref, o_ref, kl_ref, g_ref,
+                      off_scr, p_scr, acc_scr, *, scale, heads, group, bq, bk):
+    t, kh = pl.program_id(1), pl.program_id(2)
+    first = (flags[t] & FIRST) != 0
+    d = k_ref.shape[-1]
 
-    @pl.when(n == 0)
-    def _zero():
+    @pl.when(kh == 0)
+    def _open():
+        # the tile's selection, once for its heads: 0 on S_t, -inf off it
+        keep = _causal((bq, bk), q_of[t] * bq, kv_of[t] * bk) \
+            & (i_ref[0] >= tau_ref[0])
+        off_scr[:] = jnp.where(keep, 0.0, NEG_INF)
         p_scr[:] = jnp.zeros_like(p_scr)
 
-    s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    p_scr[:] += jnp.exp(s - lse_ref[0, 0])
+    k, v = k_ref[0], v_ref[0]
+    for h in range(group):      # the query heads of this key/value head
+        s = jax.lax.dot_general(q_ref[0, :, h * d:(h + 1) * d], k,
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        # normalised already: no running max, no denominator
+        p = jnp.exp(s - lse_ref[0, h] + off_scr[:])
+        p_scr[:] += p
+        pv = jax.lax.dot_general(p.astype(v.dtype), v,
+                                 (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        n = kh * group + h
+        acc_scr[n] = jnp.where(first, pv, acc_scr[n] + pv)
 
-    @pl.when(n == heads - 1)
+    @pl.when(kh == heads // group - 1)
     def _tile():
-        scores = i_ref[0]
-        keep = _causal((bq, bk), q_of[t] * bq, kv_of[t] * bk) \
-            & (scores >= tau_ref[0])
-        p = jnp.where(keep, p_scr[:] * (1.0 / heads), 0.0)
-        log_r = jnp.where(keep, scores - lsei_ref[0], 0.0)
+        keep = off_scr[:] == 0.0
+        p = p_scr[:] * (1.0 / heads)
+        log_r = jnp.where(keep, i_ref[0] - lsei_ref[0], 0.0)
         g_ref[0] = jnp.where(keep, jnp.exp(log_r), 0.0) - p
         part = jnp.sum(jnp.where(p > 0.0, p * (jnp.log(
             jnp.where(p > 0.0, p, 1.0)) - log_r), 0.0), axis=1, keepdims=True)
 
-        @pl.when((flags[t] & FIRST) != 0)
+        @pl.when(first)
         def _first():
             kl_ref[0] = part
 
-        @pl.when((flags[t] & FIRST) == 0)
+        @pl.when(jnp.logical_not(first))
         def _later():
             kl_ref[0] += part
 
+        @pl.when((flags[t] & LAST) != 0)
+        def _out():
+            for h in range(heads):
+                o_ref[0, :, h * d:(h + 1) * d] = acc_scr[h].astype(o_ref.dtype)
 
-def _kl_call(q, k, lse, scores, tau, lse_i, n_heads, n_kv_heads, scale, bq,
-             bk, interpret):
-    B, S, _ = q.shape
-    D = q.shape[-1] // n_heads
+
+def attend_kl_vmem_bytes(n_heads, head_dim, itemsize, group, bq=512,
+                         bk=512):
+    """What ``dsa_attend_kl_fwd`` asks of VMEM: the q block's ``o`` of every
+    head twice (an output block) and once more in float32 (the accumulator),
+    the tiles of I and G twice each, the heads' sum and the selection, the
+    operands' blocks (q and ``lse`` of a group's heads, k, v and three
+    [bq, 1] columns, each padded to a lane tile) twice, and as much again
+    as a step's [bq, bk] float32 values (the products, the probabilities,
+    their rounded copy)."""
+    width = n_heads * head_dim
+    tile = bq * bk * 4
+    rows = bq * LANES * 4               # a [bq, 1] float32 block, padded
+    return (bq * width * (2 * itemsize + 4) + 6 * tile
+            + 2 * (group * bq + 2 * bk) * head_dim * itemsize
+            + 2 * (group + 3) * rows + 6 * tile)
+
+
+def _attend_kl_call(q, k, v, scores, tau, lse, lse_i, n_heads, n_kv_heads,
+                    scale, bq, bk, interpret):
+    B, S, W = q.shape
+    D = W // n_heads
     group = n_heads // n_kv_heads
     table = step_table(S, S, bq, bk, True)
+    # a grid step is a (tile, key/value head) pair: the group's query heads
+    # are looped inside it
     qrow = lambda b, t, n, q_of, kv_of, head_of, flags: (b, q_of[t], n)
-    krow = lambda b, t, n, q_of, kv_of, head_of, flags: (
-        b, kv_of[t], n // group)
+    krow = lambda b, t, n, q_of, kv_of, head_of, flags: (b, kv_of[t], n)
     stat = lambda b, t, n, q_of, kv_of, head_of, flags: (b, n, q_of[t], 0)
     row = lambda b, t, n, q_of, kv_of, head_of, flags: (b, q_of[t], 0)
     tile = lambda b, t, n, q_of, kv_of, head_of, flags: (
         b, q_of[t], kv_of[t])
     return _triangle_call(
-        functools.partial(_kl_kernel, scale=scale, heads=n_heads, bq=bq,
-                          bk=bk),
-        "indexer_kl_fwd", table, B, (q, k, lse, scores, tau, lse_i),
-        [pl.BlockSpec((1, bq, D), qrow), pl.BlockSpec((1, bk, D), krow),
-         pl.BlockSpec((1, 1, bq, 1), stat), pl.BlockSpec((1, bq, bk), tile),
-         pl.BlockSpec((1, bq, 1), row), pl.BlockSpec((1, bq, 1), row)],
-        [pl.BlockSpec((1, bq, 1), row), pl.BlockSpec((1, bq, bk), tile)],
-        [jax.ShapeDtypeStruct((B, S, 1), jnp.float32),
+        functools.partial(_attend_kl_kernel, scale=scale, heads=n_heads,
+                          group=group, bq=bq, bk=bk),
+        "dsa_attend_kl_fwd", table, B, (q, k, v, lse, scores, tau, lse_i),
+        [pl.BlockSpec((1, bq, group * D), qrow),
+         pl.BlockSpec((1, bk, D), krow), pl.BlockSpec((1, bk, D), krow),
+         pl.BlockSpec((1, group, bq, 1), stat),
+         pl.BlockSpec((1, bq, bk), tile), pl.BlockSpec((1, bq, 1), row),
+         pl.BlockSpec((1, bq, 1), row)],
+        [pl.BlockSpec((1, bq, W), row), pl.BlockSpec((1, bq, 1), row),
+         pl.BlockSpec((1, bq, bk), tile)],
+        [jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct((B, S, 1), jnp.float32),
          jax.ShapeDtypeStruct((B, S, S), jnp.float32)],
-        [pltpu.VMEM((bq, bk), jnp.float32)], interpret,
-        extra_axes=(n_heads,))
+        [pltpu.VMEM((bq, bk), jnp.float32), pltpu.VMEM((bq, bk), jnp.float32),
+         pltpu.VMEM((n_heads, bq, D), jnp.float32)], interpret,
+        extra_axes=(n_kv_heads,),
+        vmem_limit_bytes=attend_kl_vmem_bytes(n_heads, D, q.dtype.itemsize,
+                                              group, bq, bk))
 
 
-def _selected_lse(scores, tau, rows=SELECT_ROWS):
-    """``log sum over S_t of exp(I[t, s])`` [B, S, 1]: the selected keys'
+def selected_lse(scores, tau, rows=SELECT_ROWS):
+    """``log sum over S_t of exp(I[t, s])`` [B, S]: the selected keys'
     normaliser, ``rows`` rows a block."""
     B, S, _ = scores.shape
     rows = min(rows, S)
@@ -357,52 +423,106 @@ def _selected_lse(scores, tau, rows=SELECT_ROWS):
             & (x >= th[..., None])
         return jax.nn.logsumexp(jnp.where(keep, x, NEG_INF), axis=-1)
 
-    return _by_rows(block, B, S, rows)[..., None]
+    return _by_rows(block, B, S, rows)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
-def _kl(scores, tau, q, k, lse, n_heads, n_kv_heads, scale, bq, bk,
-        interpret):
-    return _kl_fwd(scores, tau, q, k, lse, n_heads, n_kv_heads, scale, bq,
-                   bk, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11, 12))
+def _attend_kl(q, k, v, indexer, scores, tau, lse, lse_i, heads, scale, bq,
+               bk, interpret):
+    """``heads`` = (H, Hkv); ``indexer`` = (q, k, w) of ``scores``; tau and
+    lse_i [B, S, 1], lse [B, H, S, 1]."""
+    return _attend_kl_fwd(q, k, v, indexer, scores, tau, lse, lse_i, heads,
+                          scale, bq, bk, interpret)[0]
 
 
-def _kl_fwd(scores, tau, q, k, lse, n_heads, n_kv_heads, scale, bq, bk,
-            interpret):
+def _attend_kl_fwd(q, k, v, indexer, scores, tau, lse, lse_i, heads, scale,
+                   bq, bk, interpret):
     B, S, _ = scores.shape
-    kl, g = _kl_call(q, k, lse, scores, tau[..., None],
-                     _selected_lse(scores, tau), n_heads, n_kv_heads, scale,
-                     bq, bk, interpret)
-    return jnp.sum(kl) / (B * S), (g, tau, q, k, lse)
+    o, kl, g = _attend_kl_call(q, k, v, scores, tau, lse, lse_i, *heads,
+                               scale, bq, bk, interpret)
+    # the pass attends (the caller's scope); the scalar is the loss term's
+    with jax.named_scope(devscope.INDEXER_KL):
+        kl = jnp.sum(kl) / (B * S)
+    return (o, kl), (q, k, v, o, lse, indexer, scores, tau, lse_i, g)
 
 
-def _kl_bwd(n_heads, n_kv_heads, scale, bq, bk, interpret, res, ct):
-    g, tau, q, k, lse = res
+def _attend_kl_bwd(heads, scale, bq, bk, interpret, res, cts):
+    q, k, v, o, lse, indexer, scores, tau, lse_i, g = res
+    do, ct = cts
     B, S, _ = g.shape
-    # p, and with it q, k and lse, is a constant of the term; the threshold
-    # passes no gradient
-    return (g * (ct / (B * S)),) + tuple(
-        jnp.zeros_like(x) for x in (tau, q, k, lse))
+    # the cross entropy reaches q, k and v through o, the statistic a
+    # constant (the flash backward's ``delta`` is its account of it); p is a
+    # constant of the KL term, whose dI = G ct / (B S) goes straight to the
+    # scores' operands: the scalar rides the scores' backward, and no third
+    # [S, S] array stands beside I and G.  The selection passes no gradient
+    d_qkv = tuple(_flash_bwd(scale, True, bq, bk, interpret,
+                             (q, k, v, o, lse), do, *heads,
+                             mask=(scores, tau)))
+    with jax.named_scope(devscope.INDEXER):
+        dqi, dki, dw = _scores_bwd_call(*indexer, g, bq, bk, interpret,
+                                        gain=ct / (B * S))
+    return d_qkv + ((dqi, dki, dw),) + tuple(
+        jnp.zeros_like(x) for x in (scores, tau, lse, lse_i))
 
 
-_kl.defvjp(_kl_fwd, _kl_bwd)
+_attend_kl.defvjp(_attend_kl_fwd, _attend_kl_bwd)
 
 
-def indexer_kl(scores, tau, q, k, lse, n_heads, n_kv_heads=None, scale=None,
-               block_q=512, block_k=512, interpret=None):
-    """The mean over batch rows and tokens of ``KL(p_t || softmax over S_t
-    of I[t, .])``.  ``scores`` [B, S, S] (``indexer_scores``), ``tau`` [B,
-    S]; q [B, S, H * D] and k [B, S, Hkv * D] as the masked flash call read
-    them and ``lse`` [B, H, S, 1] as it saved it.  Differentiable in
-    ``scores`` alone (under the diagonal and on ``S_t``)."""
-    B, S, _ = q.shape
+def dsa_lse(q, k, v, scores, tau, n_heads, n_kv_heads=None, scale=None,
+            block_q=512, block_k=512, interpret=None):
+    """[B, H, S] float32: each head's log-sum-exp over the keys its row
+    selects (``flash_dsa_packed``'s statistic, from the same kernel,
+    ``flash_dsa_fwd``), a constant: nothing differentiates through it."""
     D = q.shape[-1] // n_heads
-    assert D % 128 == 0, "a head is whole lane blocks"
-    bq, bk = min(block_q, S), min(block_k, S)
-    assert S % bq == 0 and S % bk == 0, (S, bq, bk)
-    if interpret is None:
-        interpret = not _on_tpu()
-    return _kl(scores, tau, q, k, lse, int(n_heads),
-               int(n_kv_heads or n_heads),
-               float(D ** -0.5 if scale is None else scale), bq, bk,
-               bool(interpret))
+    bq, bk, interpret = _blocks(q.shape[1], block_q, block_k, interpret)
+    stop = jax.lax.stop_gradient
+    _, lse = _flash_fwd(
+        stop(q), stop(k), stop(v),
+        float(D ** -0.5 if scale is None else scale), True, bq, bk, interpret,
+        n_heads, n_kv_heads or n_heads,
+        mask=(stop(scores), stop(tau)[..., None]))
+    return lse[..., 0]
+
+
+def dsa_attend_kl(q, k, v, indexer, scores, tau, lse, lse_i, n_heads,
+                  n_kv_heads=None, scale=None, block_q=512, block_k=512,
+                  interpret=None):
+    """``(o, kl)`` of a learned-sparse layer with the statistic KNOWN: the
+    attention's output under the selection, ``o[t] = sum over S_t of exp(s -
+    lse) v`` [B, S, H * D], and the mean over batch rows and tokens of
+    ``KL(p_t || softmax over S_t of I[t, .])``, ``S_t = {s <= t : I[t, s] >=
+    tau_t}``, ``p`` the mean over the heads of those same probabilities.
+    ONE sweep of the causal triangle (kernel ``dsa_attend_kl_fwd``): a grid
+    step is a (tile, key/value head) pair, the heads innermost and a group's
+    query heads looped inside the step (k and v are fetched once a group),
+    so the tile of I, the thresholds and the selection are the tile's and
+    not a head's; each head's normalised tile goes through ``p v`` into its
+    float32 accumulator and into the heads' sum; the tile's last step
+    writes the KL part and ``G = softmax_S(I) - p`` (0 off ``S_t``), which
+    IS ``dKL/dI``; a q block's last step writes its ``o`` for every head.
+
+    q [B, S, H * D], k and v [B, S, Hkv * D], D whole lane blocks;
+    ``scores`` [B, S, S] = ``indexer_scores(*indexer)``, ``indexer`` its
+    (q, k, w); ``tau`` [B, S]; ``lse`` [B, H, S] (``dsa_lse``) and ``lse_i``
+    [B, S] (``selected_lse``), both constants.  Gradients: o's to q, k and
+    v by the masked flash backward (``flash_dsa_bwd_*``, which re-makes p
+    from ``lse`` as this pass does); kl's to ``indexer`` alone, through
+    ``indexer_scores_bwd`` on ``G`` with the cotangent's scalar as its
+    gain (``scores`` itself gets zeros: its only way on is here)."""
+    B, S, E = q.shape
+    H, Hkv = int(n_heads), int(n_kv_heads or n_heads)
+    D = E // H
+    assert D % LANES == 0, "a head is whole lane blocks"
+    assert k.shape == v.shape == (B, S, Hkv * D), (k.shape, v.shape)
+    assert lse.shape == (B, H, S) and lse_i.shape == tau.shape == (B, S), (
+        lse.shape, lse_i.shape, tau.shape)
+    bq, bk, interpret = _blocks(S, block_q, block_k, interpret)
+    assert S > bk, "the masked flash backward sweeps several blocks"
+    qi, ki, w = indexer
+    assert scores.shape == (B, S, S) and qi.shape[:2] == (B, S), (
+        scores.shape, qi.shape)
+    return _attend_kl(q, k, v, (qi, ki, w.astype(jnp.float32)),
+                      jax.lax.stop_gradient(scores), tau[..., None],
+                      lse[..., None], lse_i[..., None], (H, Hkv),
+                      float(D ** -0.5 if scale is None else scale), bq, bk,
+                      interpret)

@@ -1507,27 +1507,44 @@ def indexer_operands(pl, h, cfg, positions=None):
     return q, k, w
 
 
-DSA_TAU = "dsa_tau"     # the selection's thresholds, a layer's residual
+# a learned-sparse layer's residuals under remat, by name: the selection's
+# thresholds [b, S], the selected keys' normaliser [b, S] and the masked
+# attention's statistic [b, H, S]
+DSA_TAU, DSA_LSE_I, DSA_LSE = "dsa_tau", "dsa_lse_i", "dsa_lse"
+DSA_KEPT = (DSA_TAU, DSA_LSE_I, DSA_LSE)
 
 
-def indexer_selection(pl, h, cfg, positions=None):
-    """(scores [b, S, S] float32, thresholds [b, S]) of a layer's indexer on
-    the normed rows ``h``: query t reads the causal keys whose score is at
-    or above its threshold, the ``indexer_topk``-th largest of its row.  The
-    thresholds are a constant of the step (no gradient) and, under a layer's
-    remat, its residual: the second forward does not select again."""
+def _selection(pl, h, cfg, positions=None):
+    """``indexer_selection`` and two more: the selected keys' normaliser [b,
+    S], ``log sum over S_t of exp(scores)``, which the indexer's loss term
+    reads (as the thresholds a constant of the step and a layer's residual),
+    and the scores' operands (``indexer_operands``), where the loss term's
+    gradient goes."""
     from jax.ad_checkpoint import checkpoint_name
 
     from ..kernels import indexer as ix
 
     bq, bk = _clamped_blocks(cfg, h.shape[1])
     with jax.named_scope(devscope.INDEXER):
-        scores = ix.indexer_scores(*indexer_operands(pl, h, cfg, positions),
-                                   block_q=bq, block_k=bk)
+        operands = indexer_operands(pl, h, cfg, positions)
+        scores = jax.lax.stop_gradient(ix.indexer_scores(
+            *operands, block_q=bq, block_k=bk))
     with jax.named_scope(devscope.INDEXER_SELECT):
-        tau = checkpoint_name(ix.kth_largest(
-            jax.lax.stop_gradient(scores), cfg.indexer_topk), DSA_TAU)
-    return scores, tau
+        tau = checkpoint_name(ix.kth_largest(scores, cfg.indexer_topk),
+                              DSA_TAU)
+    with jax.named_scope(devscope.INDEXER_KL):
+        lse_i = checkpoint_name(ix.selected_lse(scores, tau), DSA_LSE_I)
+    return scores, tau, lse_i, operands
+
+
+def indexer_selection(pl, h, cfg, positions=None):
+    """(scores [b, S, S] float32, thresholds [b, S]) of a layer's indexer on
+    the normed rows ``h``: query t reads the causal keys whose score is at
+    or above its threshold, the ``indexer_topk``-th largest of its row.
+    Both are constants of the step (no gradient: the scores' only way on is
+    ``dsa_attend_kl``'s own) and the thresholds, under a layer's remat, its
+    residual: the second forward does not select again."""
+    return _selection(pl, h, cfg, positions)[:2]
 
 
 def _clamped_blocks(cfg, S):
@@ -1537,25 +1554,33 @@ def _clamped_blocks(cfg, S):
 
 def _sparse_attention(pl, h, cfg, rotary, positions):
     """Learned-sparse attention's branch [b, S, E] and the indexer's loss
-    term (a scalar): the masked flash calls under the indexer's selection,
-    then ``wo``.  The cross entropy reaches q, k and v alone (the mask passes
-    no gradient); the KL term reaches the indexer's leaves alone (its
-    target, the heads' mean probabilities, and the indexer's input are
-    constants)."""
-    from ..kernels import indexer as ix
-    from ..kernels.flash_attention import flash_dsa_packed
+    term (a scalar), then ``wo``.  The masked online forward (``dsa_lse``)
+    gives each head's statistic alone, a constant and the layer's residual;
+    ONE pass with that statistic known (``dsa_attend_kl``) makes the
+    attention's output and the loss term, so a layer's second forward under
+    remat is that pass and no online softmax.  The cross entropy reaches q,
+    k and v alone (the mask passes no gradient); the KL term reaches the
+    indexer's leaves alone (its target, the heads' mean probabilities, and
+    the indexer's input are constants)."""
+    from jax.ad_checkpoint import checkpoint_name
 
-    stop = jax.lax.stop_gradient
+    from ..kernels import indexer as ix
+    from ..kernels._common import count_call
+
     hl, kvl = _local_heads(cfg)
     bq, bk = _clamped_blocks(cfg, h.shape[1])
+    blocks = dict(block_q=bq, block_k=bk)
     q2, k2, v2 = _qkv(pl, h, cfg, rotary, positions=positions)
-    scores, tau = indexer_selection(pl, stop(h), cfg, positions)
+    scores, tau, lse_i, indexer = _selection(pl, jax.lax.stop_gradient(h),
+                                             cfg, positions)
     with jax.named_scope(devscope.SPARSE_ATTN):
-        o, lse = flash_dsa_packed(q2, k2, v2, stop(scores), tau, hl, kvl,
-                                  block_q=bq, block_k=bk)
-    with jax.named_scope(devscope.INDEXER_KL):
-        kl = ix.indexer_kl(scores, tau, stop(q2), stop(k2), stop(lse), hl,
-                           kvl, block_q=bq, block_k=bk)
+        # named as the dense [b, H, S]: a minor dimension of 1 may stand
+        # padded to a lane tile (268 MB a layer where this is 2)
+        lse = checkpoint_name(ix.dsa_lse(q2, k2, v2, scores, tau, hl, kvl,
+                                         **blocks), DSA_LSE)
+        o, kl = ix.dsa_attend_kl(q2, k2, v2, indexer, scores, tau, lse,
+                                 lse_i, hl, kvl, **blocks)
+    count_call("dsa_attend_kl")
     return o @ pl["wo"], kl
 
 
@@ -2168,11 +2193,12 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
     assert positions is None or (len(kinds) == 1 and not cfg.per_position)
     body = transformer_layer
     if cfg.remat:
-        # an indexer's thresholds are kept: the second forward makes the
-        # scores again (the backward reads them) and selects nothing
+        # an indexer's thresholds and both statistics are kept: the second
+        # forward makes the scores again (the backward reads them), selects
+        # nothing and runs no online softmax
         body = jax.checkpoint(
             body, static_argnums=(2, 3, 4),
-            policy=jax.checkpoint_policies.save_only_these_names(DSA_TAU)
+            policy=jax.checkpoint_policies.save_only_these_names(*DSA_KEPT)
             if cfg.indexer_heads else None)
     unroll = max(int(cfg.scan_unroll), 1)
     if len(kinds) == 1 and not cfg.per_position:

@@ -1,14 +1,15 @@
 """The learned-sparse layer's kernels as Mosaic compiles them, against the
 float32 formulas, at the cell's shape:
 
-    chiprun -- python3 scripts/keye_vl2_kernels_receipt.py [--seq 16384] [--block 512] [--seed 0]
+    chiprun -- python3 scripts/keye_vl2_kernels_receipt.py [--seq 16384] [--block 512] [--seed 0] [--tree DIR] [--times-only]
 
 q [1, S, 32 x 128] on 4 key/value heads, an indexer of 16 heads of 64, the
 2,048 best keys a row (scaled with a shorter ``--seq``: an eighth).  Holds
 ``indexer_scores`` (forward, and dq / dk / dw from a random dI), the k-th
 largest a row (``kth_largest`` against ``jax.lax.top_k``), ``flash_dsa_*``
-(o, lse, and dq / dk / dv from a random do) and ``indexer_kl`` (the value and
-dI) to the formulas, computed in float32 at ``highest`` precision on the
+(o, lse, and dq / dk / dv from a random do) and ``dsa_attend_kl``, the pass
+with the statistic known (o, the KL term, dq / dk / dv from the same do and
+the KL's gradient of the scores' operands) to the formulas, computed in float32 at ``highest`` precision on the
 same bf16 operands, a block of query rows at a time (the dense [32, S, S]
 never stands); and, the CONTROL, the flash output against the formula
 WITHOUT the selection's mask, which must be far off.  Each reading is the
@@ -18,16 +19,25 @@ result.  And the rotary pass with positions that are DATA
 never takes): the row kernel on q with an image grid's three streams
 (temporal, height, width; sections [16, 24, 24]) against the float32
 formula, the value and dx, with the control that swaps the spatial sections.
-Writes ``chiprun_out/pr61/keye_vl2_kernels_receipt.json``; off a chip
+Then ``seconds``: the host's clock around each of the layer's calls alone,
+jitted, the mean of five after one (the masked online forward, the pass with
+the statistic known, the masked backward, the selected keys' normaliser; of
+a tree that still has it, ``indexer_kl``).  ``--tree DIR`` reads another
+checkout's kernels (the parent's, from ``git archive``), ``--times-only``
+skips the formulas.  Writes
+``chiprun_out/pr62/keye_vl2_kernels_receipt[_<tree>].json``; off a chip
 (interpret mode) give a short ``--seq``."""
 
 import argparse
 import json
 import os
 import sys
+import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
+TREE = os.path.abspath(sys.argv[sys.argv.index("--tree") + 1]) \
+    if "--tree" in sys.argv else ROOT
+sys.path.insert(0, TREE)
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -114,11 +124,48 @@ def f_rotary(x, weight, streams, sections):
         [-x[..., D // 2:], x[..., :D // 2]], -1) * sin
 
 
+def seconds(fn, *args, calls=5):
+    """The mean of ``calls`` calls of ``fn`` jitted, after one."""
+    fn = jax.jit(fn)
+    jax.block_until_ready(fn(*args))
+    start = time.perf_counter()
+    for _ in range(calls):
+        jax.block_until_ready(fn(*args))
+    return (time.perf_counter() - start) / calls
+
+
+def times(q, k, v, do, indexer, sc, tau, blocks):
+    """``{call: seconds}`` of the layer's calls, each alone."""
+    from paddle_tpu.kernels.flash_attention import _bwd
+
+    dsa = lambda *a: flash_dsa_packed(*a, sc, tau, H, HKV, **blocks)
+    o, lse = dsa(q, k, v)
+    bq = blocks["block_q"]
+    out = {"flash_dsa_fwd": seconds(dsa, q, k, v),
+           "flash_dsa_bwd": seconds(lambda *a: _bwd(
+               D ** -0.5, True, bq, bq, not ix._on_tpu(), a[:5], a[5], H, HKV,
+               mask=(sc, tau[..., None])), q, k, v, o, lse, do)}
+    if hasattr(ix, "dsa_attend_kl"):
+        lse_i = ix.selected_lse(sc, tau)
+        out["selected_lse"] = seconds(ix.selected_lse, sc, tau)
+        out["dsa_attend_kl_fwd"] = seconds(lambda *a: ix.dsa_attend_kl(
+            *a, indexer, sc, tau, lse[..., 0], lse_i, H, HKV, **blocks),
+            q, k, v)
+    else:
+        out["selected_lse"] = seconds(ix._selected_lse, sc, tau)
+        out["indexer_kl_fwd_and_selected_lse"] = seconds(
+            lambda *a: ix.indexer_kl(sc, tau, *a, H, HKV, **blocks),
+            q, k, lse)
+    return out
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--seq", type=int, default=16384)
     ap.add_argument("--block", type=int, default=512)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--tree", default=None)
+    ap.add_argument("--times-only", action="store_true")
     args = ap.parse_args()
     S, topk = args.seq, max(args.seq // 8, 1)
     blocks = dict(block_q=args.block, block_k=args.block)
@@ -145,6 +192,21 @@ def main():
     tri = np.tril(np.ones((S, S), bool))
     exact = jax.default_matmul_precision("highest")     # the formulas' alone
 
+    def write():
+        path = os.path.join(
+            ROOT, "chiprun_out", "pr62", "keye_vl2_kernels_receipt%s.json"
+            % ("_" + os.path.basename(TREE) if args.tree else ""))
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(path, "w") as f:
+            json.dump(out, f, indent=1)
+        print(json.dumps(out))
+
+    if args.times_only:
+        sc = ix.indexer_scores(qi, ki, w, **blocks)
+        out["seconds"] = times(q, k, v, do, (qi, ki, w), sc,
+                               ix.kth_largest(sc, topk), blocks)
+        return write()
+
     streams = grid_streams(S)
     assert not np.array_equal(streams[1], streams[2])
     weight = jnp.asarray(1 + r.randn(D) / 8, jnp.float32)
@@ -169,7 +231,8 @@ def main():
     assert np.all(np.isneginf(np.asarray(sc[0])[~tri]))
     for name, a, b in zip(("dq", "dk", "dw"), pull(d_scores), wants):
         read("indexer_scores_" + name, a[0], b)
-    del want, wants, pull, pull_want, d_scores
+    pull_scores = pull_want     # for the KL term's gradient, further down
+    del want, wants, pull, d_scores
     tau = ix.kth_largest(sc, topk)
     read("kth_largest", jnp.where(jnp.isfinite(tau[0]), tau[0], 0.0),
          jnp.where(jnp.arange(S) >= topk - 1,
@@ -187,20 +250,28 @@ def main():
     for name, a, b in zip("qkv", pull((do, jnp.zeros_like(lse))), wants):
         read("flash_dsa_d" + name, a[0], b)
     read("control_flash_dsa_o_against_no_mask", o[0], unmasked)
-    del o_want, pull, pull_want, wants, unmasked, o
-    value, g = jax.value_and_grad(lambda x: ix.indexer_kl(
-        x, tau, q, k, lse, H, HKV, **blocks))(sc)
+    del pull, pull_want, unmasked, o
+    # the pass with the statistic known: the same o, the KL term, and from
+    # (do, 1) the masked backward's dq / dk / dv and the KL's gradient of the
+    # scores' operands (the formula's dKL/dI pulled through the formula's
+    # scores)
+    (o, value), pull = jax.vjp(lambda q, k, v, *indexer: ix.dsa_attend_kl(
+        q, k, v, indexer, sc, tau, lse[..., 0], ix.selected_lse(sc, tau), H,
+        HKV, **blocks), q, k, v, qi, ki, w)
+    *d_qkv, dqi, dki, dw = pull((do, jnp.ones((), value.dtype)))
     with exact:
         want, g_want = jax.value_and_grad(lambda x: f_kl(x, tau[0], p))(
             jnp.where(tri, sc[0], -1e30))
-    read("indexer_kl", value, want)
-    read("indexer_kl_dscores", g[0], g_want, tri)
-    path = os.path.join(ROOT, "chiprun_out", "pr61",
-                        "keye_vl2_kernels_receipt.json")
-    os.makedirs(os.path.dirname(path), exist_ok=True)
-    with open(path, "w") as f:
-        json.dump(out, f, indent=1)
-    print(json.dumps(out))
+        d_indexer = pull_scores(jnp.where(tri, g_want, 0.0))
+    read("dsa_attend_kl_o", o[0], o_want)
+    for name, a, b in zip("qkv", d_qkv, wants):
+        read("dsa_attend_kl_d" + name, a[0], b)
+    read("dsa_attend_kl", value, want)
+    for name, a, b in zip(("dq", "dk", "dw"), (dqi, dki, dw), d_indexer):
+        read("dsa_attend_kl_indexer_" + name, a[0], b)
+    del o_want, wants, pull, o, g_want, p, d_qkv, pull_scores, d_indexer
+    out["seconds"] = times(q, k, v, do, (qi, ki, w), sc, tau, blocks)
+    write()
 
 
 if __name__ == "__main__":

@@ -1293,15 +1293,20 @@ DSA_CELL = (1, 16384, 32, 4, 128, 16, 64)   # B, S, H, Hkv, D, Hi, Di
 
 
 @pytest.mark.parametrize("kernel", ["flash_dsa", "indexer_scores",
-                                    "indexer_kl"])
+                                    "dsa_lse", "dsa_attend_kl"])
 def test_the_learned_sparse_kernels_compile_for_a_v5e(one_chip, kernel):
     """``keye_vl2_30b_a3b.s16384_scan``'s new kernels through Mosaic at the
     cell's shapes, forward and backward: the masked flash sweeps (the
     triangle's 528 steps, the scores' tile and the thresholds' rows beside
     q, k, v; the backward ONE sweep with dk and dv of all 16,384 positions
     in VMEM, as the causal mode's), the indexer's scores (the backward's dk
-    of the one key head whole in VMEM) and the KL pass (a head a grid
-    step)."""
+    of the one key head whole in VMEM), the statistic alone (the masked
+    online forward, its ``o`` unread) and the pass with the statistic known
+    (a (tile, key/value head) a grid step, its eight query heads looped
+    inside; the q block's ``o`` of all 32 heads one output block and a
+    float32 accumulator a head in scratch: the VMEM the call states; its
+    backward the masked flash backward's one sweep and the scores' backward
+    on ``G``, the cotangent's scalar its gain in SMEM)."""
     ix = importlib.import_module("paddle_tpu.kernels.indexer")
     B, S, H, Hkv, D, Hi, Di = DSA_CELL
 
@@ -1330,16 +1335,27 @@ def test_the_learned_sparse_kernels_compile_for_a_v5e(one_chip, kernel):
                        sds((B, S, Hi), f32), scores), {
             "indexer_scores_fwd": (B, S // 512, S // 512),
             "indexer_scores_bwd": (B, steps)}
+    elif kernel == "dsa_lse":
+        def both(q, k, v, scores, tau):
+            return ix.dsa_lse(q, k, v, scores, tau, H, Hkv, interpret=False)
+        args, names = (q, kv, kv, scores, tau), {
+            "flash_dsa_fwd": (B, Hkv, H // Hkv, steps)}
     else:
-        def both(scores, tau, q, k, lse):
-            return jax.value_and_grad(lambda x: ix.indexer_kl(
-                x, tau, q, k, lse, H, Hkv, interpret=False))(scores)
-        args, names = (scores, tau, q, kv, sds((B, H, S, 1), f32)), {
-            "indexer_kl_fwd": (B, steps, H)}
+        def both(q, k, v, qi, ki, w, scores, tau, lse, lse_i, do):
+            (o, kl), vjp = jax.vjp(lambda q, k, v, *indexer: ix.dsa_attend_kl(
+                q, k, v, indexer, scores, tau, lse, lse_i, H, Hkv,
+                interpret=False), q, k, v, qi, ki, w)
+            return (o, kl) + vjp((do, jnp.ones_like(kl)))
+        args, names = (q, kv, kv, sds((B, S, Hi * Di)), sds((B, S, Di)),
+                       sds((B, S, Hi), f32), scores, tau,
+                       sds((B, H, S), f32), tau, q), {
+            "dsa_attend_kl_fwd": (B, steps, Hkv),
+            "flash_dsa_bwd_fused": (B, Hkv, H // Hkv * steps),
+            "indexer_scores_bwd": (B, steps)}
     traced = jax.jit(both).trace(*args)
     grids = {name: tuple(int(n) for n in grid.split(",") if n.strip())
              for grid, name in re.findall(
-                 r"grid=\(([\d, ]*)\).*?name=((?:flash_dsa|indexer)_\w+)",
+                 r"grid=\(([\d, ]*)\).*?name=((?:flash_dsa|indexer|dsa)_\w+)",
                  str(traced.jaxpr), re.S)}
     # the row kernel in front of the flash backward (``flash_delta``) has a
     # grid of its own, which this pattern reads as the backward's
@@ -1349,9 +1365,14 @@ def test_the_learned_sparse_kernels_compile_for_a_v5e(one_chip, kernel):
     for name in names:
         asked, took = _vmem(text, name)
         assert took < (asked or fa.SCOPED_VMEM), (name, asked, took)
-    if kernel == "flash_dsa":
+    if "flash_dsa_bwd_fused" in names:
         assert _vmem(text, "flash_dsa_bwd_fused")[0] \
             == fa.fused_sweep_vmem_bytes(S, 128, 2)
+    if kernel == "dsa_attend_kl":
+        asked, took = _vmem(text, "dsa_attend_kl_fwd")
+        assert asked == ix.attend_kl_vmem_bytes(H, D, 2, H // Hkv) \
+            == 36 * 2 ** 20
+        assert 24 * 2 ** 20 < took < asked
 
 
 @pytest.mark.parametrize("b,S", [(1, 16384), (2, 8192)])

@@ -2,8 +2,9 @@
 (``flash_dsa_packed``) in interpret mode, against the float32 formulas: the
 indexer's scores forward and backward, the k-th largest of a row against
 ``numpy.partition``, attention under a mask that is data against a dense
-masked softmax, and the indexer's KL term.  ONE traced program for the
-file: every check reads it."""
+masked softmax, and the pass with the statistic known (``dsa_attend_kl``:
+the same output, the indexer's KL term, and the gradients of both).  ONE
+traced program for the file: every check reads it."""
 
 import jax
 import jax.numpy as jnp
@@ -13,7 +14,7 @@ import pytest
 from paddle_tpu.kernels import indexer as ix
 from paddle_tpu.kernels.flash_attention import flash_dsa_packed
 
-B, S, HI, DI, H, HKV, D, K, BLOCK = 2, 64, 4, 16, 8, 2, 128, 8, 16
+B, S, HI, DI, H, HKV, D, K, BLOCK = 2, 64, 4, 16, 8, 1, 128, 8, 16
 TRI = np.tril(np.ones((S, S), bool))
 
 
@@ -36,8 +37,10 @@ def dense(q, k, v, scores, tau):
             jax.nn.logsumexp(s, -1), a)
 
 
-def ref_kl(scores, tau, a):
-    keep = ix.selected(scores, tau)
+def ref_kl(scores, keep, a):
+    """``keep`` = ``ix.selected(the kernel's scores, tau)``: the selection
+    is a constant, and a formula that selected from its own scores would
+    drop the key AT the threshold for a last bit."""
     p = jnp.mean(a, 1)
     log_r = jax.nn.log_softmax(jnp.where(keep, scores, -jnp.inf), -1)
     return jnp.sum(jnp.where(p > 0, p * (jnp.log(jnp.where(p > 0, p, 1.0))
@@ -71,15 +74,44 @@ def case():
             *x, got, tau, H, HKV, **blocks)[0] * c_out), (0, 1, 2))(q, k, v)
         f_want = jax.grad(lambda *x: jnp.sum(dense(*x, got, tau)[0] * c_out),
                           (0, 1, 2))(q, k, v)
-        kl = lambda x: ix.indexer_kl(x, tau, q, k, lse, H, HKV, **blocks)
-        kl_want = lambda x: ref_kl(x, tau, a)
-        finite = jnp.where(TRI, got, -1e9)
+        # the pass with the statistic known: o, the KL term and, from a
+        # cotangent of each, dq / dk / dv (o's alone) and the gradient of
+        # the scores' operands (the KL's alone)
+        known = ix.dsa_lse(q, k, v, got, tau, H, HKV, **blocks)
+        lse_i = ix.selected_lse(got, tau, rows=BLOCK)
+        fused = lambda q, k, v, *indexer: ix.dsa_attend_kl(
+            q, k, v, indexer, got, tau, known, lse_i, H, HKV, **blocks)
+        (o2, kl), pull = jax.vjp(fused, q, k, v, qi, ki, w)
+        from_o = pull((c_out, jnp.zeros(())))
+        from_kl = pull((jnp.zeros_like(c_out), jnp.ones(())))
+        kl_want, kl_d_want = jax.value_and_grad(
+            lambda *x: ref_kl(jnp.where(TRI, ref_scores(*x), -1e9),
+                              ix.selected(got, tau), a),
+            (0, 1, 2))(qi, ki, w)
+        # a row that selects ONE key: the other block it sees holds none
+        # and computes zeros
+        high = tau.at[:, BLOCK + 3].set(jnp.max(got[:, BLOCK + 3], -1))
+        lone = ix.dsa_attend_kl(
+            q, k, v, (qi, ki, w), got, high,
+            ix.dsa_lse(q, k, v, got, high, H, HKV, **blocks),
+            ix.selected_lse(got, high), H, HKV, **blocks)
+        lone_a = dense(q, k, v, got, high)[2]
         return dict(
             scores=(got, want), tau=tau, o=(o, o_want),
             lse=(lse[..., 0], lse_want),
-            kl=(kl(got), kl_want(got)),
-            kl_grad=(jnp.where(TRI, jax.grad(kl)(got), 0.0),
-                     jnp.where(TRI, jax.grad(kl_want)(finite), 0.0)),
+            known_lse=(known, lse[..., 0]),
+            selected_lse=(lse_i, jax.nn.logsumexp(jnp.where(
+                ix.selected(got, tau), got, -jnp.inf), -1)),
+            fused_o=(o2, o_want),
+            kl=(kl, kl_want),
+            silent=(from_o[3:], from_kl[:3]),
+            **{"kl_d" + n: (a_, b_) for n, a_, b_ in zip(
+                ("q", "k", "w"), from_kl[3:], kl_d_want)},
+            lone_o=(lone[0], dense(q, k, v, got, high)[0]),
+            lone_kl=(lone[1], ref_kl(got, ix.selected(got, high), lone_a)),
+            lone_kept=jnp.sum(ix.selected(got, high)[:, BLOCK + 3], -1),
+            **{"fused_d" + n: (a_, b_) for n, a_, b_ in zip(
+                "qkv", from_o, f_want)},
             **{"scores_d" + n: (a_, b_) for n, a_, b_ in zip(
                 ("q", "k", "w"), g_got, g_want)},
             **{"flash_d" + n: (a_, b_) for n, a_, b_ in zip(
@@ -92,13 +124,25 @@ def case():
 @pytest.mark.parametrize("check,tolerance", [
     ("scores", 1e-5), ("scores_dq", 1e-5), ("scores_dk", 1e-5),
     ("scores_dw", 2e-5), ("o", 1e-5), ("lse", 1e-5), ("flash_dq", 1e-5),
-    ("flash_dk", 1e-5), ("flash_dv", 1e-5), ("kl", 1e-6), ("kl_grad", 1e-6)])
+    ("flash_dk", 1e-5), ("flash_dv", 1e-5), ("kl", 1e-6), ("kl_dq", 1e-5),
+    ("kl_dk", 1e-5), ("kl_dw", 1e-5), ("known_lse", 0.0), ("selected_lse", 1e-6), ("fused_o", 1e-5),
+    ("fused_dq", 1e-5), ("fused_dk", 1e-5), ("fused_dv", 1e-5),
+    ("lone_o", 1e-5), ("lone_kl", 1e-6)])
 def test_a_kernel_agrees_with_its_float32_formula(case, check, tolerance):
     got, want = (np.asarray(x) for x in case[check])
     assert np.array_equal(np.isfinite(got), np.isfinite(want)), check
     ok = np.isfinite(want)
     assert np.max(np.abs(got[ok] - want[ok])) <= tolerance * max(
         1.0, np.max(np.abs(want[ok]))), check
+
+
+def test_each_term_of_the_known_statistic_pass_reaches_its_own(case):
+    """o's cotangent gives the scores' operands EXACTLY nothing and the
+    KL's gives q, k and v exactly nothing; the lone row's case is what it
+    says."""
+    d_indexer, d_qkv = case["silent"]
+    assert not any(np.any(x) for x in d_indexer + d_qkv)
+    assert np.array_equal(case["lone_kept"], [1] * B)
 
 
 def test_the_kth_largest_is_numpy_partition_s(case):

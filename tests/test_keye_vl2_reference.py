@@ -3,7 +3,10 @@ reference (``benchmark/reference/keye_vl2_30b_a3b.py``) at the tiny size:
 logits, both loss terms and every leaf's gradient; which leaves hear which
 term; the selection (rows before ``topk``, the selected sets); the three
 position streams; the shares' parts adding up to the uncut layer; a trainer
-step.  ONE traced program of the model for the file's comparisons."""
+step; what a differentiated step under remat runs a layer and keeps.  ONE
+traced program of the model for the file's comparisons."""
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -179,6 +182,51 @@ def test_rows_before_topk_are_dense_causal_attention():
                                   block_k=16, n_kv_heads=2)
     assert close(sparse[:, :TOPK], full[:, :TOPK], 1e-6)
     assert not close(sparse[:, TOPK:], full[:, TOPK:], 1e-2)
+
+
+def test_a_step_under_remat_runs_one_online_forward_a_layer():
+    """The differentiated step of a stack under ``cfg.remat``, by its
+    jaxpr's kernels (the scan holds ONE layer, forward and backward): ONE
+    masked online forward (the statistic's, in the forward pass), the pass
+    with the statistic known TWICE (forward and recompute), one masked
+    backward.  What the policy keeps of a layer beside its input: the
+    thresholds and the selected keys' normaliser [b, S] and the statistic
+    [b, H, S] as dense arrays, and nothing [.., S, S]."""
+    from jax._src.ad_checkpoint import saved_residuals
+    from paddle_tpu import monitor
+
+    cfg = keye_vl2.keye_vl2_tiny_config(remat=True, max_seq=128)
+    b, s, layers, heads = 2, 128, cfg.n_layers, cfg.n_heads
+    params = jax.tree.map(
+        lambda a: jnp.zeros(a.shape, a.dtype), jax.eval_shape(
+            lambda: T.init_transformer_params(jax.random.PRNGKey(0), cfg)))
+    loss = lambda p: decoder.make_loss_fn(cfg)(
+        p, {"ids": np.zeros((b, s), np.int32)})
+    mon = monitor.enable()
+    try:
+        calls = mon.registry.counter("monitor.kernels.dsa_attend_kl_calls")
+        before = calls.value
+        forward = str(jax.make_jaxpr(loss)(params))
+        # one call a layer body traced: the scan's one layer
+        assert calls.value - before == 1
+    finally:
+        monitor.disable()
+    kernels = lambda text: {name: len(re.findall(
+        r"name=%s\w*" % name, text)) for name in (
+            "flash_dsa_fwd", "dsa_attend_kl_fwd", "flash_dsa_bwd_",
+            "indexer_scores_fwd", "indexer_scores_bwd", "indexer_kl")}
+    assert kernels(forward) == {
+        "flash_dsa_fwd": 1, "dsa_attend_kl_fwd": 1, "flash_dsa_bwd_": 0,
+        "indexer_scores_fwd": 1, "indexer_scores_bwd": 0, "indexer_kl": 0}
+    assert kernels(str(jax.make_jaxpr(jax.grad(loss))(params))) == {
+        "flash_dsa_fwd": 1, "dsa_attend_kl_fwd": 2, "flash_dsa_bwd_": 1,
+        "indexer_scores_fwd": 2, "indexer_scores_bwd": 1, "indexer_kl": 0}
+    kept = sorted(tuple(aval.shape) for aval, what in saved_residuals(
+        loss, params) if "output of scan" in what)
+    assert kept == sorted([(layers, b, s), (layers, b, s),
+                           (layers, b, heads, s),
+                           (layers, b, s, cfg.hidden)]), kept
+    assert T.DSA_KEPT == ("dsa_tau", "dsa_lse_i", "dsa_lse")
 
 
 def test_the_shares_parts_add_up_to_the_uncut_layer():

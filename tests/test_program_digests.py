@@ -72,7 +72,12 @@ from paddle_tpu.parallel.train import stack_batches  # noqa: E402
 # re-took NONE of the twenty-four: the flash kernels' masked mode, the
 # indexer, the position streams of ``rope`` / ``angle_tables`` and the
 # checkpoint policy of a layer with an indexer left every older program's
-# text as it was; Keye-VL-2.0's two joined, taken on that PR's tree.
+# text as it was; Keye-VL-2.0's two joined, taken on that PR's tree.  PR 62
+# took Keye's two anew ON PURPOSE (the layer's output comes from the pass
+# with the statistic known, ``dsa_attend_kl``, whose backward runs the
+# scores' backward itself, and the policy keeps three names); the
+# twenty-four others stand: no other configuration traces
+# ``_sparse_attention`` or ``kernels/indexer.py``.
 PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "bert.run_steps": "00de5403506fdc87",
             "olmoe.step": "231114fcd62341f2",
@@ -97,8 +102,8 @@ PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "resnet.run_steps": "dc9dd853700f9ab9",
             "kimi_linear.step": "00fafaa79be69c29",
             "kimi_linear.run_steps": "768fb807ebe118b1",
-            "keye_vl2.step": "4322024e13a4c588",
-            "keye_vl2.run_steps": "995bef2d468f6be3"}
+            "keye_vl2.step": "7c426adf13402711",
+            "keye_vl2.run_steps": "ea3eb37e8934f5a4"}
 OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
          "olmoe": (olmoe.build_olmoe_trainer, olmoe.olmoe_tiny_config, 32),
          "smallthinker": (smallthinker.build_smallthinker_trainer,
