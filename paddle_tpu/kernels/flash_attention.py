@@ -1228,8 +1228,8 @@ _flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def _flash_dsa(q, k, v, scores, tau, heads, scale, bq, bk, interpret):
-    """``heads`` = (H, Hkv).  (o, lse): the statistic leaves too, for the
-    indexer's own loss term, and takes no cotangent."""
+    """``heads`` = (H, Hkv[, None, Dv]).  (o, lse): the statistic leaves
+    too, for the indexer's own loss term, and takes no cotangent."""
     return tuple(_fwd(q, k, v, scale, True, bq, bk, interpret, *heads,
                       mask=(scores, tau)))
 
@@ -1251,8 +1251,18 @@ def _flash_dsa_bwd(heads, scale, bq, bk, interpret, res, cts):
 _flash_dsa.defvjp(_flash_dsa_fwd, _flash_dsa_bwd)
 
 
+def masked_heads(n_heads, n_kv_heads, head_dim, v_head_dim=None):
+    """``(H, Hkv)`` of a masked call, or ``(H, H, None, Dv)`` where a value
+    has a width of its own (``_fwd``'s and ``_bwd``'s trailing arguments)."""
+    if v_head_dim in (None, head_dim):
+        return n_heads, n_kv_heads
+    assert n_heads == n_kv_heads, "a value width of its own: no grouping"
+    return n_heads, n_kv_heads, None, int(v_head_dim)
+
+
 def flash_dsa_packed(q, k, v, scores, tau, n_heads, n_kv_heads=None,
-                     scale=None, block_q=512, block_k=512, interpret=None):
+                     scale=None, block_q=512, block_k=512, interpret=None,
+                     v_head_dim=None):
     """Causal attention under a mask that is DATA, packed layout: query t
     reads the keys ``s <= t`` with ``scores[t, s] >= tau[t]`` (``scores`` [B,
     S, S] float32, ``tau`` [B, S] float32; ``-inf``: every causal key).
@@ -1263,19 +1273,25 @@ def flash_dsa_packed(q, k, v, scores, tau, n_heads, n_kv_heads=None,
     SWEEP is the causal triangle's, built from the shapes as every other:
     a block with no selected key computes zeros.  Every row must select a
     key.  Gradients flow to q, k and v; kernels ``flash_dsa_fwd`` /
-    ``flash_dsa_bwd_*``."""
+    ``flash_dsa_bwd_*``.  ``v_head_dim`` other than D: v [B, S, H *
+    v_head_dim] and so is ``o`` (every head its own key/value head; give
+    ``scale`` where D holds lanes that are not the head's)."""
     B, S, E = q.shape
     H, Hkv = n_heads, n_kv_heads or n_heads
     D = E // H
-    assert D % LANES == 0 and packed_layout_supported(H, D, Hkv), (H, D, Hkv)
-    assert k.shape == v.shape == (B, S, Hkv * D), (k.shape, v.shape)
+    Dv = D if v_head_dim is None else int(v_head_dim)
+    assert D % LANES == 0 and packed_layout_supported(H, D, Hkv, Dv), (
+        H, D, Hkv, Dv)
+    assert k.shape == (B, S, Hkv * D) and v.shape == (B, S, Hkv * Dv), (
+        k.shape, v.shape)
     assert scores.shape == (B, S, S) and tau.shape == (B, S), (
         scores.shape, tau.shape)
     bq, bk = min(block_q, S), min(block_k, S)
     assert S % bq == 0 and S % bk == 0 and S > bk, (S, bq, bk)
     if interpret is None:
         interpret = not _on_tpu()
-    return _flash_dsa(q, k, v, scores, tau[..., None], (H, Hkv),
+    return _flash_dsa(q, k, v, scores, tau[..., None],
+                      masked_heads(H, Hkv, D, Dv),
                       float(D ** -0.5 if scale is None else scale), bq, bk,
                       bool(interpret))
 
@@ -1321,7 +1337,8 @@ def flash_attention_packed(q, k, v, n_heads, causal=False, scale=None,
     ``v_head_dim`` other than D: v is [B, S, H*v_head_dim] and so is the
     result; q's and k's heads and v's are whole lane blocks, each product
     with v (``P V``, ``dP``, ``dV``) runs at the values' width, and no
-    grouping or window rides with it.  Give ``scale`` where D holds lanes
+    grouping rides with it (a window does: the band's table and mask know
+    no width).  Give ``scale`` where D holds lanes
     that are not the head's (zeros behind a head of 192 in 256 lanes)."""
     B, S, E = q.shape
     H = n_heads
@@ -1338,7 +1355,6 @@ def flash_attention_packed(q, k, v, n_heads, causal=False, scale=None,
             % (H, Hkv, D, LANES))
     assert k.shape[-1] == Hkv * D and v.shape[-1] == Hkv * Dv, (
         k.shape, v.shape, Hkv, D, Dv)
-    assert Dv == D or window is None, "a value width of its own: no window"
     if window is not None:
         assert causal and window >= 1 and k.shape[1] == S, (causal, window)
         if window >= S:

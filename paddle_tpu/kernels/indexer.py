@@ -46,8 +46,8 @@ from jax.experimental.pallas import tpu as pltpu
 from ..monitor import devscope
 from ._common import (LANES, CompilerParams as _CompilerParams,
                       on_tpu as _on_tpu)
-from .flash_attention import (FIRST, LAST, _bwd as _flash_bwd,
-                              _fwd as _flash_fwd, step_table)
+from .flash_attention import (FIRST, LAST, SCOPED_VMEM, _bwd as _flash_bwd,
+                              _fwd as _flash_fwd, masked_heads, step_table)
 
 __all__ = ["indexer_scores", "kth_largest", "selected", "selected_lse",
            "dsa_lse", "dsa_attend_kl"]
@@ -113,9 +113,36 @@ def _scores_fwd_call(q, k, w, bq, bk, interpret):
         out_specs=pl.BlockSpec((1, bq, bk), lambda b, i, j: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, S, S), jnp.float32),
         compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            **_past_scoped(scores_vmem_bytes(bq, bk, q.shape[-1], heads,
+                                             q.dtype.itemsize))),
         interpret=interpret, name="indexer_scores_fwd",
     )(q, k, w)
+
+
+def scores_vmem_bytes(bq, bk, width, heads, itemsize, backward=False, S=0):
+    """What the scores' kernels hold in VMEM at a q block of ``bq`` rows of
+    ``width`` = heads x Di lanes: the q block twice (a pipelined operand),
+    the k block, the weights' and the [bq, bk] tile twice each and six
+    float32 tiles of a step's own; the backward also dq's block twice, its
+    float32 accumulator and dk's of all ``S`` keys with its output block."""
+    di = width // heads
+    tile = bq * bk * 4
+    rows = bq * max(heads, LANES) * 4
+    need = 2 * bq * width * itemsize + 2 * bk * di * itemsize + 2 * rows \
+        + 8 * tile
+    if backward:
+        # and as much again of the step's own values as the forward's: the
+        # compiled kernel took 71.4 MiB at 64 x 128 where those were left out
+        need += 2 * bq * width * itemsize + bq * width * 4 + 3 * rows \
+            + S * di * (4 + 2 * itemsize) + 8 * tile
+    return need + 2 * 2 ** 20
+
+
+def _past_scoped(need, least=SCOPED_VMEM):
+    """``vmem_limit_bytes`` where a kernel needs more than ``least`` (what
+    it has without asking), else nothing: the call stays as it was."""
+    return {"vmem_limit_bytes": int(need)} if need > least else {}
 
 
 def _scores_bwd_kernel(q_of, kv_of, head_of, flags, gain_ref, q_ref, k_ref,
@@ -209,7 +236,8 @@ def _scores_bwd_call(q, k, w, g, bq, bk, interpret, gain=1.0):
          pltpu.VMEM((bq, heads), jnp.float32),
          pltpu.VMEM((S, di), jnp.float32)],
         interpret,
-        vmem_limit_bytes=48 * 2 ** 20)
+        vmem_limit_bytes=max(48 * 2 ** 20, scores_vmem_bytes(
+            bq, bk, W, heads, q.dtype.itemsize, backward=True, S=S)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -310,7 +338,7 @@ def _attend_kl_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref,
                       off_scr, p_scr, acc_scr, *, scale, heads, group, bq, bk):
     t, kh = pl.program_id(1), pl.program_id(2)
     first = (flags[t] & FIRST) != 0
-    d = k_ref.shape[-1]
+    d, dv = k_ref.shape[-1], v_ref.shape[-1]
 
     @pl.when(kh == 0)
     def _open():
@@ -354,11 +382,12 @@ def _attend_kl_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref,
         @pl.when((flags[t] & LAST) != 0)
         def _out():
             for h in range(heads):
-                o_ref[0, :, h * d:(h + 1) * d] = acc_scr[h].astype(o_ref.dtype)
+                o_ref[0, :, h * dv:(h + 1) * dv] = acc_scr[h].astype(
+                    o_ref.dtype)
 
 
 def attend_kl_vmem_bytes(n_heads, head_dim, itemsize, group, bq=512,
-                         bk=512):
+                         bk=512, v_head_dim=None):
     """What ``dsa_attend_kl_fwd`` asks of VMEM: the q block's ``o`` of every
     head twice (an output block) and once more in float32 (the accumulator),
     the tiles of I and G twice each, the heads' sum and the selection, the
@@ -366,11 +395,12 @@ def attend_kl_vmem_bytes(n_heads, head_dim, itemsize, group, bq=512,
     [bq, 1] columns, each padded to a lane tile) twice, and as much again
     as a step's [bq, bk] float32 values (the products, the probabilities,
     their rounded copy)."""
-    width = n_heads * head_dim
+    dv = head_dim if v_head_dim is None else v_head_dim
+    width = n_heads * dv
     tile = bq * bk * 4
     rows = bq * LANES * 4               # a [bq, 1] float32 block, padded
     return (bq * width * (2 * itemsize + 4) + 6 * tile
-            + 2 * (group * bq + 2 * bk) * head_dim * itemsize
+            + 2 * ((group * bq + bk) * head_dim + bk * dv) * itemsize
             + 2 * (group + 3) * rows + 6 * tile)
 
 
@@ -378,6 +408,7 @@ def _attend_kl_call(q, k, v, scores, tau, lse, lse_i, n_heads, n_kv_heads,
                     scale, bq, bk, interpret):
     B, S, W = q.shape
     D = W // n_heads
+    Dv = v.shape[-1] // n_kv_heads      # a value's own width, or D
     group = n_heads // n_kv_heads
     table = step_table(S, S, bq, bk, True)
     # a grid step is a (tile, key/value head) pair: the group's query heads
@@ -393,20 +424,20 @@ def _attend_kl_call(q, k, v, scores, tau, lse, lse_i, n_heads, n_kv_heads,
                           group=group, bq=bq, bk=bk),
         "dsa_attend_kl_fwd", table, B, (q, k, v, lse, scores, tau, lse_i),
         [pl.BlockSpec((1, bq, group * D), qrow),
-         pl.BlockSpec((1, bk, D), krow), pl.BlockSpec((1, bk, D), krow),
+         pl.BlockSpec((1, bk, D), krow), pl.BlockSpec((1, bk, Dv), krow),
          pl.BlockSpec((1, group, bq, 1), stat),
          pl.BlockSpec((1, bq, bk), tile), pl.BlockSpec((1, bq, 1), row),
          pl.BlockSpec((1, bq, 1), row)],
-        [pl.BlockSpec((1, bq, W), row), pl.BlockSpec((1, bq, 1), row),
-         pl.BlockSpec((1, bq, bk), tile)],
-        [jax.ShapeDtypeStruct(q.shape, q.dtype),
+        [pl.BlockSpec((1, bq, n_heads * Dv), row),
+         pl.BlockSpec((1, bq, 1), row), pl.BlockSpec((1, bq, bk), tile)],
+        [jax.ShapeDtypeStruct((B, S, n_heads * Dv), q.dtype),
          jax.ShapeDtypeStruct((B, S, 1), jnp.float32),
          jax.ShapeDtypeStruct((B, S, S), jnp.float32)],
         [pltpu.VMEM((bq, bk), jnp.float32), pltpu.VMEM((bq, bk), jnp.float32),
-         pltpu.VMEM((n_heads, bq, D), jnp.float32)], interpret,
+         pltpu.VMEM((n_heads, bq, Dv), jnp.float32)], interpret,
         extra_axes=(n_kv_heads,),
         vmem_limit_bytes=attend_kl_vmem_bytes(n_heads, D, q.dtype.itemsize,
-                                              group, bq, bk))
+                                              group, bq, bk, Dv))
 
 
 def selected_lse(scores, tau, rows=SELECT_ROWS):
@@ -455,9 +486,10 @@ def _attend_kl_bwd(heads, scale, bq, bk, interpret, res, cts):
     # constant of the KL term, whose dI = G ct / (B S) goes straight to the
     # scores' operands: the scalar rides the scores' backward, and no third
     # [S, S] array stands beside I and G.  The selection passes no gradient
-    d_qkv = tuple(_flash_bwd(scale, True, bq, bk, interpret,
-                             (q, k, v, o, lse), do, *heads,
-                             mask=(scores, tau)))
+    d_qkv = tuple(_flash_bwd(
+        scale, True, bq, bk, interpret, (q, k, v, o, lse), do,
+        *masked_heads(*heads, q.shape[-1] // heads[0],
+                      v.shape[-1] // heads[1]), mask=(scores, tau)))
     with jax.named_scope(devscope.INDEXER):
         dqi, dki, dw = _scores_bwd_call(*indexer, g, bq, bk, interpret,
                                         gain=ct / (B * S))
@@ -469,7 +501,7 @@ _attend_kl.defvjp(_attend_kl_fwd, _attend_kl_bwd)
 
 
 def dsa_lse(q, k, v, scores, tau, n_heads, n_kv_heads=None, scale=None,
-            block_q=512, block_k=512, interpret=None):
+            block_q=512, block_k=512, interpret=None, v_head_dim=None):
     """[B, H, S] float32: each head's log-sum-exp over the keys its row
     selects (``flash_dsa_packed``'s statistic, from the same kernel,
     ``flash_dsa_fwd``), a constant: nothing differentiates through it."""
@@ -479,14 +511,14 @@ def dsa_lse(q, k, v, scores, tau, n_heads, n_kv_heads=None, scale=None,
     _, lse = _flash_fwd(
         stop(q), stop(k), stop(v),
         float(D ** -0.5 if scale is None else scale), True, bq, bk, interpret,
-        n_heads, n_kv_heads or n_heads,
+        *masked_heads(n_heads, n_kv_heads or n_heads, D, v_head_dim),
         mask=(stop(scores), stop(tau)[..., None]))
     return lse[..., 0]
 
 
 def dsa_attend_kl(q, k, v, indexer, scores, tau, lse, lse_i, n_heads,
                   n_kv_heads=None, scale=None, block_q=512, block_k=512,
-                  interpret=None):
+                  interpret=None, v_head_dim=None):
     """``(o, kl)`` of a learned-sparse layer with the statistic KNOWN: the
     attention's output under the selection, ``o[t] = sum over S_t of exp(s -
     lse) v`` [B, S, H * D], and the mean over batch rows and tokens of
@@ -501,7 +533,9 @@ def dsa_attend_kl(q, k, v, indexer, scores, tau, lse, lse_i, n_heads,
     writes the KL part and ``G = softmax_S(I) - p`` (0 off ``S_t``), which
     IS ``dKL/dI``; a q block's last step writes its ``o`` for every head.
 
-    q [B, S, H * D], k and v [B, S, Hkv * D], D whole lane blocks;
+    q [B, S, H * D], k and v [B, S, Hkv * D], D whole lane blocks (v and
+    ``o`` at ``v_head_dim`` a head where given: whole lane blocks too, no
+    grouping; give ``scale`` where D holds lanes that are not the head's);
     ``scores`` [B, S, S] = ``indexer_scores(*indexer)``, ``indexer`` its
     (q, k, w); ``tau`` [B, S]; ``lse`` [B, H, S] (``dsa_lse``) and ``lse_i``
     [B, S] (``selected_lse``), both constants.  Gradients: o's to q, k and
@@ -512,8 +546,11 @@ def dsa_attend_kl(q, k, v, indexer, scores, tau, lse, lse_i, n_heads,
     B, S, E = q.shape
     H, Hkv = int(n_heads), int(n_kv_heads or n_heads)
     D = E // H
-    assert D % LANES == 0, "a head is whole lane blocks"
-    assert k.shape == v.shape == (B, S, Hkv * D), (k.shape, v.shape)
+    Dv = D if v_head_dim is None else int(v_head_dim)
+    assert D % LANES == 0 and Dv % LANES == 0, "a head is whole lane blocks"
+    assert Dv == D or H == Hkv, "a value width of its own: no grouping"
+    assert k.shape == (B, S, Hkv * D) and v.shape == (B, S, Hkv * Dv), (
+        k.shape, v.shape)
     assert lse.shape == (B, H, S) and lse_i.shape == tau.shape == (B, S), (
         lse.shape, lse_i.shape, tau.shape)
     bq, bk, interpret = _blocks(S, block_q, block_k, interpret)

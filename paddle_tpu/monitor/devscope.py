@@ -79,6 +79,11 @@ KDA, KDA_CHUNK = "kda", "kda_chunk"
 # indexer's own loss term (the target from the main attention, the KL)
 INDEXER, INDEXER_SELECT, SPARSE_ATTN, INDEXER_KL = (
     "indexer", "indexer_select", "sparse_attn", "indexer_kl")
+# latent attention of two SHAPES in one stack, each kind's position under a
+# word of its own where ``latent_attention`` would stand: the full layers'
+# (under an indexer: ``indexer*`` and ``sparse_attn`` lie further in) and the
+# sliding layers' (their own ranks and widths, under a window)
+MLA_DSA, MLA_SWA = "mla_dsa", "mla_swa"
 # the scan over the stacked layers itself: its slices of each layer's leaves,
 # the activations it keeps for the backward pass and the gradients it stacks
 # (a layer's own work carries the layer's scopes, which lie further in)
@@ -94,7 +99,7 @@ VOCABULARY = (EMBED, ATTENTION, MLP, LAYER_NORM, LM_HEAD, CONV, BN, POOL, FC,
               LATENT_ATTENTION, SHARED_EXPERT, LAYER_SCAN, ATTN_GATE,
               POST_NORM, MAMBA, SELECTIVE_SCAN, MAMBA2, SSD_SCAN, LOOP_SCAN,
               EXIT_GATE, KDA, KDA_CHUNK, INDEXER, INDEXER_SELECT, SPARSE_ATTN,
-              INDEXER_KL)
+              INDEXER_KL, MLA_DSA, MLA_SWA)
 PHASES = ("forward", "backward", "recompute", GRAD_SYNC, OPTIMIZER)
 
 # `%fusion.12 = bf16[..] fusion(%p.1, %copy-done.2), ..., metadata={op_name="jit(multi)/..." ...}`:

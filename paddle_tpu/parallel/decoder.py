@@ -3,7 +3,8 @@
 ``logits_at``, a call's observations and the builder, ONE of each for every
 decoder of this block.  A model (``models/olmoe.py``, ``smallthinker.py``,
 ``lfm2.py``, ``brumby.py``, ``mistral4.py``, ``trinity.py``, ``jamba.py``,
-``nemotron_h.py``, ``ouro.py``, ``kimi_linear.py``, ``keye_vl2.py``)
+``nemotron_h.py``, ``ouro.py``, ``kimi_linear.py``, ``keye_vl2.py``,
+``dots3.py``)
 is a ``TransformerConfig`` and a label; what its trainer computes and what
 it observes follow from the configuration, never from which model it is.
 
@@ -43,6 +44,7 @@ from .transformer import (
     exit_weighted_loss,
     final_logits_loss,
     indexer_selection,
+    _local_heads,
     _qkv,
     grad_sync_axes,
     head_logits,
@@ -114,7 +116,7 @@ def make_loss_fn(cfg: TransformerConfig):
         ce = final_logits_loss(params, x, labels, mask, cfg)
         if cfg.indexer_heads:
             # the indexer's own term, mean over layers: its leaves' alone
-            ce = ce + jnp.mean(aux["dsa_kl"])
+            ce = ce + _dsa_kl_mean(aux, cfg)
         if cfg.routing == moe.SIGMOID_BIASED:
             return ce, {"router_bias": moe.balance_bias(
                 params["router_bias"], aux["load"], cfg.router_bias_rate)}
@@ -124,6 +126,14 @@ def make_loss_fn(cfg: TransformerConfig):
                 + cfg.router_z_coef * jnp.mean(aux["router_z"]))
 
     return loss_fn
+
+
+def _dsa_kl_mean(aux, cfg):
+    """The indexer's loss term, the mean over the layers THAT HAVE an
+    indexer (a layer without one reports zero)."""
+    if cfg.indexer_layers == cfg.n_layers:
+        return jnp.mean(aux["dsa_kl"])
+    return jnp.sum(aux["dsa_kl"]) / cfg.indexer_layers
 
 
 def _first_layer_input(params, ids, cfg):
@@ -159,24 +169,30 @@ def _first_of_kind(params, ids, cfg, kind):
 
 
 def _mass_selected(params, ids, cfg, queries=256):
-    """``dsa_mass_selected`` of ``probe``: the first layer's dense causal
-    softmax, a head a row, summed over the keys its indexer selects."""
+    """``(dsa_mass_selected, dsa_pairs_selected)`` of ``probe``: the first
+    layer's dense causal softmax, a head a row, summed over the keys its
+    indexer selects, and how many (query, key) pairs that layer selects."""
+    from ..kernels import indexer as ix
+
     pl, h = _first_layer_input(params, ids, cfg)
+    # the first layer's own shape (a leading layer's, else the period's)
+    cfg = cfg.position((cfg.prefix_kinds + cfg.layer_kinds)[0])[0]
     b, S, _ = h.shape
     q, k, _ = _qkv(pl, h, cfg, True)
     scores, tau = indexer_selection(pl, h, cfg)
     first = min(cfg.indexer_topk, S - 1)
     rows = jnp.linspace(first, S - 1, min(queries, S - first)).astype(
         jnp.int32)
-    group = cfg.n_heads // cfg.kv_heads
-    qh = q[:, rows].reshape(b, -1, cfg.kv_heads, group, cfg.head_dim)
-    kh = k.reshape(b, S, cfg.kv_heads, cfg.head_dim)
+    heads, kv_heads = _local_heads(cfg)
+    qh = q[:, rows].reshape(b, len(rows), kv_heads, heads // kv_heads, -1)
+    kh = k.reshape(b, S, kv_heads, -1)
     s = jnp.einsum("brkgd,bskd->bkgrs", qh, kh,
                    preferred_element_type=jnp.float32) * cfg.head_dim ** -0.5
     causal = jnp.arange(S)[None] <= rows[:, None]
     p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
     keep = causal & (scores[:, rows] >= tau[:, rows, None])     # [b, r, S]
-    return jnp.mean(jnp.sum(jnp.where(keep[:, None, None], p, 0.0), axis=-1))
+    return (jnp.mean(jnp.sum(jnp.where(keep[:, None, None], p, 0.0), axis=-1)),
+            jnp.sum(ix.selected(scores, tau)))
 
 
 def probe(params, ids, cfg):
@@ -222,7 +238,11 @@ def probe(params, ids, cfg):
       softmax's mass that lies on the selected keys, in the first layer, at
       up to 256 queries spread over the positions past ``indexer_topk``,
       mean over heads: near the rows' own selected share, ``topk / (t +
-      1)``, the indexer knows nothing; at 1 it drops nothing."""
+      1)``, the indexer knows nothing; at 1 it drops nothing; and
+      ``dsa_pairs_selected``, the (query, key) pairs the first layer's
+      indexer selects (ties at a threshold kept: at least ``topk`` a row
+      past the first ``topk``);
+    - ``attn_gate`` "head": ``attn_gate_mean`` over tokens and heads."""
     out = {}
     if cfg.loop_passes > 1:
         log_p = exit_log_probs(forward(params, ids, cfg)[1])
@@ -235,8 +255,9 @@ def probe(params, ids, cfg):
         if "rows_held" in aux:
             out["moe_rows_held"] = jnp.sum(aux["rows_held"])
         if cfg.indexer_heads:
-            out["dsa_kl_mean"] = jnp.mean(aux["dsa_kl"])
-            out["dsa_mass_selected"] = _mass_selected(params, ids, cfg)
+            out["dsa_kl_mean"] = _dsa_kl_mean(aux, cfg)
+            out["dsa_mass_selected"], out["dsa_pairs_selected"] = \
+                _mass_selected(params, ids, cfg)
     if cfg.routing == moe.SIGMOID_BIASED:
         out["router_bias_abs_max"] = jnp.max(abs(params["router_bias"]))
     mamba_first = cfg.layer_kinds[0] == MAMBA and not cfg.prefix_pattern

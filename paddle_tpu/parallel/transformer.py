@@ -21,6 +21,7 @@ row-sharded distributed_lookup_table_op.cc), and the LM loss is a
 vocab-parallel softmax cross-entropy that never materializes gathered logits.
 """
 
+import copy
 import dataclasses
 import functools
 import math
@@ -36,7 +37,7 @@ from ..monitor import devscope
 from ..monitor.recompile import compile_ledger
 from .ring_attention import ring_attention
 
-__all__ = ["TransformerConfig", "CONV", "RETENTION", "MAMBA", "MAMBA2", "FFN",
+__all__ = ["TransformerConfig", "AttentionShape", "CONV", "RETENTION", "MAMBA", "MAMBA2", "FFN",
            "KDA",
            "init_transformer_params", "transformer_param_specs",
            "grad_sync_axes", "embed", "transformer_layer", "run_layers",
@@ -73,9 +74,54 @@ KDA = "kda"
 _OWN_LEAVES = (CONV, RETENTION, MAMBA, MAMBA2, FFN, KDA)
 
 
+@dataclasses.dataclass(frozen=True)
+class AttentionShape:
+    """An attention position that carries its OWN shape: where a pattern
+    holds one of these in place of ``(window, rotary)``, that position reads
+    every field given here in place of the configuration's field of the same
+    name (None: the configuration's), in its leaves and in its layer alike
+    (``TransformerConfig.position``).  ``indexer`` off: this position has no
+    indexer where the configuration gives one.  ``scope``: the
+    ``monitor.devscope`` word the position's attention goes under."""
+    window: int = 0
+    rotary: bool = True
+    n_heads: int = None
+    heads_held: int = None
+    first_head: int = None
+    head_width: int = None
+    q_lora_rank: int = None
+    kv_lora_rank: int = None
+    qk_nope_dim: int = None
+    qk_rope_dim: int = None
+    v_head_dim: int = None
+    rope_theta: float = None
+    indexer: bool = True
+    scope: str = None
+
+    def own(self):
+        """The fields this position reads in the configuration's place."""
+        return {f.name: getattr(self, f.name)
+                for f in dataclasses.fields(self)
+                if f.name not in ("window", "rotary", "indexer", "scope")
+                and getattr(self, f.name) is not None}
+
+
+def _is_attention(kind):
+    return kind not in _OWN_LEAVES
+
+
+def _named(k):
+    """Whether a pattern's entry is more than ``(window, rotary)``."""
+    return k in _OWN_LEAVES or isinstance(k, AttentionShape)
+
+
+def _kind(k):
+    """A pattern's entry as the configuration keeps it."""
+    return k if _named(k) else (int(k[0]), bool(k[1]))
+
+
 def _kinds(pattern):
-    return tuple(k if k in _OWN_LEAVES else (k[0] or None, k[1])
-                 for k in pattern)
+    return tuple(k if _named(k) else (k[0] or None, k[1]) for k in pattern)
 
 
 @dataclasses.dataclass
@@ -140,9 +186,19 @@ class TransformerConfig:
     # n_heads (else grouped queries: wk, wv [E, n_kv_heads * head_dim])
     head_width: int = 0
     n_kv_heads: int = 0
+    # the heads this device holds, of the latent form's n_heads (0: all):
+    # heads [first_head, first_head + heads_held).  ``wq_b``, ``wkv_b`` and
+    # ``wo`` hold the held heads' columns and rows alone and the branch's
+    # output is that partial sum; a head-wise gate's ``wz`` is whole [E,
+    # n_heads] (as a router is whole beside held experts) and the share reads
+    # its own columns of it
+    heads_held: int = 0
+    first_head: int = 0
     # One period of the stack's layer kinds, a position either (window,
     # rotary), an attention layer: window 0 is full attention, rotary off is
-    # NO positional encoding in that layer; or CONV, a layer whose operator
+    # NO positional encoding in that layer; or an ``AttentionShape``, an
+    # attention layer with a shape of its own (heads, latent ranks, widths,
+    # ``rope_theta``, an indexer or none) beside its window; or CONV, a layer whose operator
     # is the gated short convolution (``short_conv``) and which has no
     # attention leaves; or RETENTION, a layer that keeps attention's
     # projections (and ``qk_norm``, rotary positions, the grouping on
@@ -193,8 +249,8 @@ class TransformerConfig:
     # would else be a tree ``p<i>`` and a copy of its layer in the body
     run_scan: bool = False
     # Latent attention (kv_lora_rank > 0; every ATTENTION position, in place
-    # of wq / wk / wv; full attention, whichever kinds stand beside it in a
-    # pattern): queries off a latent of q_lora_rank, RMS-normed (0: ONE
+    # of wq / wk / wv; full or under a window, whichever kinds stand beside
+    # it in a pattern): queries off a latent of q_lora_rank, RMS-normed (0: ONE
     # matrix ``wq``, no latent and no norm); keys and values off ONE latent
     # of kv_lora_rank, RMS-normed, beside which the same projection gives
     # qk_rope_dim columns that stand, the same for every head, as the last
@@ -204,12 +260,15 @@ class TransformerConfig:
     # pairs are ADJACENT columns (``rope_pairs``).  A value is v_head_dim
     # wide, which need not be head_dim (192 / 128: the packed flash kernels'
     # value width).  A latent position reads these five, ``n_heads`` and
-    # ``head_width``; ``n_kv_heads`` and ``qk_norm`` are unused by it
+    # ``head_width`` (its own where its kind is an ``AttentionShape``);
+    # ``n_kv_heads`` and ``qk_norm`` are unused by it
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
+    # each normed latent times (hidden / its rank)^(1/2)
+    latent_rescale: bool = False
     # YaRN positions of the latent form (rope_factor > 1; ``yarn_frequencies``,
     # ``yarn_softmax_scale``): the factor, the positions the extension starts
     # from, the rotations that bound the blend, the two mscales
@@ -226,8 +285,9 @@ class TransformerConfig:
     shared_ffn_hidden: int = 0
     # a sigmoid OUTPUT GATE on attention: a fifth projection ``wz`` [E,
     # n_heads * head_dim] off the same normed input as q, k and v, whose
-    # sigmoid multiplies the heads' output before ``wo``
-    attn_gate: bool = False
+    # sigmoid multiplies the heads' output before ``wo``; "head": ONE scalar
+    # a head and token, ``wz`` [E, n_heads]
+    attn_gate: object = False
     # sandwich norms: an RMS norm on each branch's OUTPUT beside the one on
     # its input (``ln1_post_scale``, ``ln2_post_scale``); in a layer with a
     # shared expert the FFN's takes the SUM of the routed part and the shared
@@ -265,8 +325,10 @@ class TransformerConfig:
     # what the entropy of a token's distribution over the exits is rewarded
     # by in the loss (a uniform prior over the exits)
     exit_entropy_coef: float = 0.0
-    # LEARNED-SPARSE attention (indexer_heads > 0; every layer of a stack of
-    # ONE attention kind, grouped queries at a head of whole lane blocks):
+    # LEARNED-SPARSE attention (indexer_heads > 0; every attention position
+    # but an ``AttentionShape`` that says otherwise; grouped queries at a
+    # head of whole lane blocks, or the latent form at heads and values of
+    # whole lane blocks):
     # an indexer of ``indexer_heads`` heads of ``indexer_dim`` on ONE key
     # head scores every causal key, ``I[t, s] = sum_j w[t, j] relu(qI[t, j]
     # . kI[s])`` (``kernels/indexer.py``), a query reads the ``indexer_topk``
@@ -281,6 +343,13 @@ class TransformerConfig:
     indexer_heads: int = 0
     indexer_dim: int = 0
     indexer_topk: int = 0
+    # how many of an indexer head's FIRST columns are rotated (its queries'
+    # and its key's alike; 0: all of them)
+    indexer_rope_dim: int = 0
+    # "latent": the indexer's queries come off the normed QUERY latent
+    # (``wq_idx`` [q_lora_rank, Hi * Di]) where they else come off the
+    # layer's normed input (``wq_idx`` [E, Hi * Di])
+    indexer_query: str = "input"
     # rotary positions from THREE streams (temporal, height, width; a batch's
     # ``positions`` [3, b, S], the token index three times where it carries
     # none): how many of a head's frequency pairs take their angle from each,
@@ -308,14 +377,12 @@ class TransformerConfig:
             assert self.tp == 1 and self.attn_mode == "heads" \
                 and not self.bias and self.n_heads % self.kv_heads == 0
         self.layer_pattern, self.prefix_pattern = (
-            tuple(k if k in _OWN_LEAVES else (int(k[0]), bool(k[1]))
-                  for k in pattern)
+            tuple(_kind(k) for k in pattern)
             for pattern in (self.layer_pattern, self.prefix_pattern))
         if self.positions is None:
             # no table and no rotation: every attention position says so
             assert self.layer_pattern and not any(
-                k[1] for k in self.layer_pattern + self.prefix_pattern
-                if k not in _OWN_LEAVES)
+                rotary for _, rotary in self.attention_kinds)
         if self.layer_pattern:
             assert self.positions != "learned" and self.causal \
                 and self.tp == self.pp == 1 \
@@ -347,34 +414,31 @@ class TransformerConfig:
             assert self.kda_heads and self.kda_head_dim and self.d_conv \
                 and self.kda_gate_rank and self.kda_chunk > 0
         if self.latent:
-            # every head its own key/value head, each [nope | shared] wide
-            assert self.tp == 1 and not (self.bias or self.qk_norm) \
-                and self.kv_heads == self.n_heads and self.v_head_dim \
-                and self.head_dim == self.qk_nope_dim + self.qk_rope_dim
-            attention = [k for k in self.layer_pattern + self.prefix_pattern
-                         if k not in _OWN_LEAVES]
+            assert self.tp == 1 and not (self.bias or self.qk_norm)
             if self.positions == "rotary":
-                # the row kernel's tables and ``rope_pairs`` rotate whole
-                # pairs, and assemble a key in ``head_dim`` lanes beside a
-                # value as wide; every attention position of a pattern
-                # rotates
-                assert self.qk_rope_dim % 2 == 0 \
-                    and self.v_head_dim == self.head_dim \
-                    and all(k == (0, True) for k in attention)
+                # every attention position of a pattern rotates
+                assert all(rotary for _, rotary in self.attention_kinds)
                 assert self.rope_original_max or not (
                     self.rope_factor or self.q_scale_beta)
             else:
-                # no rotation at all: nothing that shapes one
+                # no rotation at all: nothing that shapes one, no window
                 assert self.positions is None and not (
                     self.rope_factor or self.q_scale_beta) \
-                    and all(k == (0, False) for k in attention)
+                    and all(k == (None, False) for k in self.attention_kinds)
+            for kind in set(self.layer_kinds + self.prefix_kinds):
+                if _is_attention(kind):
+                    self.position(kind)[0]._check_latent()
+        else:
+            assert not (self.heads_held or self.latent_rescale) and not any(
+                isinstance(k, AttentionShape)
+                for k in self.layer_pattern + self.prefix_pattern)
         if self.shared_ffn_hidden:
             assert self.n_experts and not self.bias
+        assert self.attn_gate in (False, True, "head"), self.attn_gate
         if self.attn_gate:
-            # RETENTION owns a gate of its own; the latent form has none yet
+            # RETENTION owns a gate of its own
             assert self.tp == 1 and self.attn_mode == "heads" \
-                and not (self.bias or self.latent) \
-                and RETENTION not in self.layer_pattern
+                and not self.bias and RETENTION not in self.layer_pattern
         if self.post_norm:
             # the norm of a branch's output needs the whole row: no tp yet
             assert self.norm == "rms" and self.tp == 1 and not self.bias
@@ -391,12 +455,18 @@ class TransformerConfig:
                 and len(self.mrope_sections) == 3 \
                 and 2 * sum(self.mrope_sections) == self.head_dim
         if self.indexer_heads:
-            # the masked flash mode takes a lane block a head, several blocks
+            # the masked flash mode takes a lane block a head, several
+            # blocks (the latent form: ``_check_latent``); a stack of
+            # several kinds owns its leaves by position
             assert self.causal and self.tp == self.pp == 1 \
-                and self.attn_mode == "heads" and not self.layer_pattern \
-                and not (self.latent or self.attn_gate or self.bias) \
-                and self.head_dim % 128 == 0 and self.indexer_dim % 2 == 0 \
+                and self.attn_mode == "heads" and not self.bias \
+                and (self.latent or self.head_dim % 128 == 0) \
+                and (self.per_position or not self.layer_pattern) \
+                and self.indexer_dim % 2 == 0 \
+                and self.indexer_rope_dim % 2 == 0 \
                 and self.indexer_topk > 0 and self.n_experts
+            assert self.indexer_query == "input" or (
+                self.indexer_query == "latent" and self.q_lora_rank)
 
     @property
     def head_dim(self):
@@ -410,6 +480,64 @@ class TransformerConfig:
     def latent(self):
         """Whether attention is the latent form."""
         return self.kv_lora_rank > 0
+
+    def _check_latent(self):
+        """A latent position's shape, as the position reads it: every head
+        its own key/value head, [nope | shared] wide; rotation of whole
+        pairs; a share of the heads inside them; under an indexer heads and
+        values of whole lane blocks."""
+        assert self.kv_heads == self.n_heads and self.v_head_dim \
+            and self.head_dim == self.qk_nope_dim + self.qk_rope_dim \
+            and self.qk_rope_dim % 2 == 0 \
+            and 0 <= self.first_head \
+            and self.first_head + self.heads_here <= self.n_heads, self
+        if self.v_head_dim != self.head_dim:
+            # the value mode of the flash kernels: no YaRN on that path
+            assert not (self.rope_factor or self.q_scale_beta)
+        if self.indexer_heads:
+            assert self.v_head_dim % 128 == 0
+
+    @property
+    def heads_here(self):
+        """The heads this device holds of a latent position's."""
+        return self.heads_held or self.n_heads
+
+    def position(self, kind):
+        """``(the configuration as the attention position ``kind`` reads it,
+        (window or None, rotary))``: itself for a plain ``(window, rotary)``,
+        a copy with the position's own fields for an ``AttentionShape``; the
+        copy's ``indexer_heads`` is 0 where the position has no indexer and
+        its ``scope`` the position's ``monitor.devscope`` word (None: by
+        the form)."""
+        if not isinstance(kind, AttentionShape):
+            return self, kind
+        at = copy.copy(self)
+        vars(at).update(kind.own())
+        if not kind.indexer:
+            at.indexer_heads = 0
+        at.scope = kind.scope
+        return at, (kind.window or None, kind.rotary)
+
+    scope = None
+
+    @property
+    def attention_kinds(self):
+        """``(window or None, rotary)`` of every attention position, the
+        leading layers' and one period's."""
+        return tuple(self.position(k)[1]
+                     for k in self.prefix_kinds + self.layer_kinds
+                     if _is_attention(k))
+
+    @property
+    def indexer_layers(self):
+        """How many of the stack's layers have an indexer."""
+        if not self.indexer_heads:
+            return 0
+        has = [int(bool(self.position(k)[0].indexer_heads))
+               if _is_attention(k) else 0
+               for k in self.prefix_kinds + self.layer_kinds]
+        n = len(self.prefix_kinds)
+        return sum(has[:n]) + self.n_periods * sum(has[n:])
 
     @property
     def experts_here(self):
@@ -617,13 +745,7 @@ def _stacked_layers(ks, cfg):
             del layer[name]
         layer.update(_latent_leaves(stack, cfg, L))
     layer.update(_branch_leaves(stack, cfg, L, 15, attention=True))
-    if cfg.indexer_heads:
-        Hi, Di = cfg.indexer_heads, cfg.indexer_dim
-        layer.update(
-            wq_idx=stack(16, E, (E, Hi * Di)), wk_idx=stack(17, E, (E, Di)),
-            w_idx=stack(18, E, (E, Hi)),
-            idx_k_norm_scale=jnp.full((L, Di), cfg.qk_norm_gain, jnp.float32),
-            idx_k_norm_bias=jnp.zeros((L, Di), jnp.float32))
+    layer.update(_indexer_leaves(stack, cfg, L))
     if cfg.n_experts:
         layer.update(_ffn_leaves(stack, cfg, 6, dense=False))
     elif cfg.dense_stack:
@@ -654,8 +776,25 @@ def _scale_branch_outputs(leaves, cfg):
 
 
 def _heads_out_width(cfg):
-    """Rows of attention's ``wo``: the heads' values side by side."""
-    return cfg.n_heads * (cfg.v_head_dim if cfg.latent else cfg.head_dim)
+    """Rows of attention's ``wo``: the (held) heads' values side by side."""
+    if cfg.latent:
+        return cfg.heads_here * cfg.v_head_dim
+    return cfg.n_heads * cfg.head_dim
+
+
+def _indexer_leaves(stack, cfg, n):
+    """An indexer's five leaves of ``n`` stacked layers (none without one):
+    ``wq_idx`` [E or q_lora_rank, Hi * Di] (``indexer_query``), ``wk_idx``
+    [E, Di], ``w_idx`` [E, Hi] and the key norm's scale and bias [Di]."""
+    if not cfg.indexer_heads:
+        return {}
+    E, Hi, Di = cfg.hidden, cfg.indexer_heads, cfg.indexer_dim
+    rows = cfg.q_lora_rank if cfg.indexer_query == "latent" else E
+    return dict(
+        wq_idx=stack(16, rows, (rows, Hi * Di)),
+        wk_idx=stack(17, E, (E, Di)), w_idx=stack(18, E, (E, Hi)),
+        idx_k_norm_scale=jnp.full((n, Di), cfg.qk_norm_gain, jnp.float32),
+        idx_k_norm_bias=jnp.zeros((n, Di), jnp.float32))
 
 
 def _latent_leaves(stack, cfg, n):
@@ -667,13 +806,13 @@ def _latent_leaves(stack, cfg, n):
         # the latent's columns, then the shared key's
         wkv_a=stack(11, E, (E, rkv + cfg.qk_rope_dim)),
         kv_a_norm=jnp.ones((n, rkv), jnp.float32),
-        # head by head [k_nope | v]
-        wkv_b=stack(12, rkv, (rkv, cfg.n_heads * (cfg.qk_nope_dim
-                                                  + cfg.v_head_dim))))
+        # head by head [k_nope | v], the held heads'
+        wkv_b=stack(12, rkv, (rkv, cfg.heads_here * (cfg.qk_nope_dim
+                                                     + cfg.v_head_dim))))
     if rq:
         leaves.update(wq_a=stack(9, E, (E, rq)),
                       q_a_norm=jnp.ones((n, rq), jnp.float32),
-                      wq_b=stack(10, rq, (rq, cfg.n_heads * cfg.head_dim)))
+                      wq_b=stack(10, rq, (rq, cfg.heads_here * cfg.head_dim)))
     return leaves
 
 
@@ -720,13 +859,15 @@ def _ffn_leaves(stack, cfg, fold, dense):
 
 def _branch_leaves(stack, cfg, n, fold, attention):
     """What the configuration adds to the two branches of ``n`` stacked
-    layers: the output gate's projection ``wz`` [E, n_heads * head_dim]
+    layers: the output gate's projection ``wz`` [E, n_heads * head_dim], or
+    [E, n_heads] head-wise
     (``attn_gate``; an ``attention`` layer's alone) and the output norms'
     scales ``ln1_post_scale`` / ``ln2_post_scale`` (``post_norm``)."""
     leaves = {}
     if cfg.attn_gate and attention:
-        leaves["wz"] = stack(fold, cfg.hidden,
-                             (cfg.hidden, cfg.n_heads * cfg.head_dim))
+        leaves["wz"] = stack(fold, cfg.hidden, (
+            cfg.hidden, cfg.n_heads * (1 if cfg.attn_gate == "head"
+                                       else cfg.head_dim)))
     if cfg.post_norm:
         # a buffer each: a step donates its state's leaves
         leaves.update({name: jnp.full((n, cfg.hidden), cfg.post_norm_gain,
@@ -745,11 +886,15 @@ def _position_leaves(key, cfg, kind, n, dense):
     log-decay a key/value head and token), for MAMBA ``_mamba_leaves``', for
     MAMBA2 ``_mamba2_leaves``', for KDA ``_kda_leaves``'; an attention
     position of the latent form ``_latent_leaves``' where the others have
-    ``wk`` / ``wv``; what ``_branch_leaves`` adds; then the FFN's
+    ``wk`` / ``wv`` and, where it has one, its indexer's
+    (``_indexer_leaves``), all at the position's OWN shape
+    (``cfg.position``); what ``_branch_leaves`` adds; then the FFN's
     (``_ffn_leaves``), dense or the MoE's.  In a ``single_branch`` stack a
     position owns its ONE branch's leaves: the mixer's behind ``ln1_scale``,
     or (FFN) the feed-forward part's behind ``ln2_scale``."""
     assert cfg.norm == "rms" and not cfg.bias
+    if _is_attention(kind):
+        cfg = cfg.position(kind)[0]     # as this position reads it
     E, dt = cfg.hidden, cfg.jdtype
     Q, KV = cfg.n_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
     keys = jax.random.split(key, n)
@@ -773,15 +918,21 @@ def _position_leaves(key, cfg, kind, n, dense):
         leaves.update(_kda_leaves(stack, keys, cfg))
     elif kind != FFN:
         O = _heads_out_width(cfg)
-        leaves.update(wq=stack(1, E, (E, Q)), wk=stack(2, E, (E, KV)),
-                      wv=stack(3, E, (E, KV)), wo=stack(4, O, (O, E)))
+        projections = dict(wq=(1, Q), wk=(2, KV), wv=(3, KV))
+        if cfg.latent and kind != RETENTION:
+            # the latent form's own chains stand where these would
+            for name in ("wq", "wk", "wv")[not cfg.q_lora_rank:]:
+                del projections[name]
+        leaves.update({name: stack(fold, E, (E, width))
+                       for name, (fold, width) in projections.items()})
+        leaves["wo"] = stack(4, O, (O, E))
         leaves.update(_qk_norm_leaves(cfg, n))
         if kind == RETENTION:
             leaves["wg"] = stack(10, E, (E, cfg.kv_heads), jnp.float32)
-        elif cfg.latent:
-            for name in ("wq", "wk", "wv")[not cfg.q_lora_rank:]:
-                del leaves[name]
-            leaves.update(_latent_leaves(stack, cfg, n))
+        else:
+            if cfg.latent:
+                leaves.update(_latent_leaves(stack, cfg, n))
+            leaves.update(_indexer_leaves(stack, cfg, n))
     leaves.update(_branch_leaves(
         stack, cfg, n, 11,
         attention=kind not in (CONV, MAMBA, MAMBA2, FFN, KDA)))
@@ -1150,15 +1301,20 @@ def yarn_rotary_factor(cfg):
         / _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
 
 
-def rope_pairs(x, ang, factor=1.0):
+def rope_pairs(x, ang, factor=1.0, tiles=1):
     """Rotary positions on x [b, S, ..., d] float32 in the ADJACENT-pair
     convention: columns (2j, 2j + 1) are pair j, rotated by ``ang`` [S, d/2]:
     ``(x0 cos - x1 sin, x0 sin + x1 cos)``, cos and sin times ``factor``.
-    In place, by a swap of neighbours: no column leaves its lane."""
+    In place, by a swap of neighbours: no column leaves its lane.  ``tiles``:
+    the last axis is that many heads side by side and ``ang`` [S, d / 2 /
+    tiles] one head's (cosine and sine are made once, not once a head)."""
     d = x.shape[-1]
     shape = (1, ang.shape[0]) + (1,) * (x.ndim - 3) + (d,)
-    cos = (factor * jnp.repeat(jnp.cos(ang), 2, axis=-1)).reshape(shape)
-    sin = (factor * jnp.repeat(jnp.sin(ang), 2, axis=-1)).reshape(shape)
+    cos = factor * jnp.repeat(jnp.cos(ang), 2, axis=-1)
+    sin = factor * jnp.repeat(jnp.sin(ang), 2, axis=-1)
+    if tiles > 1:
+        cos, sin = (jnp.tile(t, (1, tiles)) for t in (cos, sin))
+    cos, sin = cos.reshape(shape), sin.reshape(shape)
     even = jnp.arange(d) % 2 == 0
     # the other member of a column's pair, its sign as the rotation has it
     other = jnp.where(even, -jnp.roll(x, -1, axis=-1), jnp.roll(x, 1, axis=-1))
@@ -1231,6 +1387,8 @@ def _packed_flash_blocks(cfg, hl, S, kvl=None, widths=None):
 
 def _local_heads(cfg):
     """(query heads, key/value heads) this device holds."""
+    if cfg.latent:      # tp == 1: every held head its own key/value head
+        return cfg.heads_here, cfg.heads_here
     ntp = col.axis_size_in(TP)
     return (cfg.n_heads // ntp, cfg.kv_heads // ntp) if ntp > 1 \
         else (cfg.n_heads, cfg.kv_heads)
@@ -1246,8 +1404,8 @@ def _qkv(pl, h_full, cfg, rotary, first=0, positions=None):
     ``_latent_qkv``; where its ``rope_pairs``
     lines run, a block of positions at a time where the sequence is long (no
     whole-sequence float32 q stands)."""
-    if cfg.latent and not rotary:
-        return _latent_qkv_unrotated(pl, h_full, cfg)
+    if cfg.latent and (not rotary or cfg.v_head_dim != cfg.head_dim):
+        return _latent_qkv_lanes(pl, h_full, cfg, rotary)
     if cfg.latent:
         # ``wkv_b``'s columns are taken apart ONCE a layer, not a block
         columns = _latent_columns(pl["wkv_b"], cfg)
@@ -1258,7 +1416,7 @@ def _qkv(pl, h_full, cfg, rotary, first=0, positions=None):
         # checkpoint, no block cut out of a stacked array
         width = cfg.q_lora_rank + cfg.kv_lora_rank + cfg.qk_rope_dim \
             if _latent_fused(cfg, h_full.shape[:2], h_full.dtype.itemsize) \
-            else 2 * 3 * cfg.n_heads * cfg.head_dim
+            else 2 * 3 * cfg.heads_here * cfg.head_dim
         return _by_row_blocks(
             lambda rows, first: _latent_qkv(pl, rows, cfg, first, columns),
             h_full, width)
@@ -1350,7 +1508,7 @@ def _latent_columns(wkv_b, cfg):
     flash kernel reads: the keys' [rank, H * head_dim], head i ``[k_nope_i |
     0]`` (the row kernel adds the rotary key into the zero lanes), and the
     values' [rank, H * dv].  No activation is cut across lanes."""
-    H, dn, dr = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+    H, dn, dr = cfg.heads_here, cfg.qk_nope_dim, cfg.qk_rope_dim
     w = wkv_b.reshape(wkv_b.shape[0], H, dn + cfg.v_head_dim)
     return (jnp.pad(w[..., :dn], ((0, 0), (0, 0), (0, dr))).reshape(
         wkv_b.shape[0], -1), w[..., dn:].reshape(wkv_b.shape[0], -1))
@@ -1368,34 +1526,62 @@ def _latent_head_lanes(cfg):
     return -(-cfg.head_dim // LANES) * LANES
 
 
-def _latent_qkv_unrotated(pl, h, cfg):
-    """The latent form WITHOUT positions, of the whole sequence ``h`` [b, S,
-    E]: ``q = h @ wq`` (off the query latent where there is one), head i ``[q_nope_i |
-    q_s_i]``; ``[ckv | ks] = h @ wkv_a``, ``rms(ckv) @ wkv_b`` head i
-    ``[k_nope_i | v_i]``; ``k_i = [k_nope_i | ks]``, the SAME unrotated
-    ``ks`` in every head.  Nothing is rotated and nothing scaled (the
-    caller's softmax scale is ``head_dim^(-1/2)``).  Packed q and k [b, S, H
-    * lanes], a head in ``_latent_head_lanes`` lanes with zeros behind its
-    ``head_dim`` columns (the zero columns of ``wq`` and of the keys' matrix:
-    no activation is padded or cut across lanes), and v [b, S, H * dv]."""
+def _latent_norm_scale(pl, name, cfg, rank):
+    """The weight of a latent's norm, float32 [rank]: ``pl[name]``, times
+    ``(hidden / rank)^(1/2)`` with ``cfg.latent_rescale``."""
+    if not cfg.latent_rescale:
+        return pl[name]
+    return pl[name] * (cfg.hidden / rank) ** 0.5
+
+
+def _latent_qkv_lanes(pl, h, cfg, rotary=False):
+    """The latent form of the whole sequence ``h`` [b, S, E] with a head of
+    q and k in WHOLE LANE BLOCKS (``_latent_head_lanes``: what the flash
+    kernels' value mode addresses), without positions or with a value
+    narrower than the head: ``q = h @ wq`` (off the query latent where there
+    is one), head i ``[q_nope_i | q_s_i]``; ``[ckv | ks] = h @ wkv_a``,
+    ``rms(ckv) @ wkv_b`` head i ``[k_nope_i | v_i]``; ``k_i = [k_nope_i |
+    ks]``, the SAME ``ks`` in every head.  Where ``rotary``, ``q_s_i`` and
+    ``ks`` are rotated (``rope_pairs`` at ``yarn_frequencies``, plain: no
+    YaRN rides this path), q on the flat array by angles that are zero in
+    every lane but a head's ``dr`` rotated ones; nothing is scaled (the
+    caller's softmax scale is ``head_dim^(-1/2)``).  The heads are the HELD
+    ones (``cfg.heads_here``).  Packed q and k [b, S, H * lanes], zeros
+    behind a head's ``head_dim`` columns (the zero columns of ``wq`` and of
+    the keys' matrix: no activation is padded or cut across lanes), and v
+    [b, S, H * dv]."""
     b, S, _ = h.shape
-    H, dn, dr = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+    H, dn, dr = cfg.heads_here, cfg.qk_nope_dim, cfg.qk_rope_dim
     lanes = _latent_head_lanes(cfg)
     tail = lanes - dn - dr
-    rows, wq = (_rms(h @ pl["wq_a"], pl["q_a_norm"], cfg.norm_eps),
+    rows, wq = (_rms(h @ pl["wq_a"],
+                     _latent_norm_scale(pl, "q_a_norm", cfg, cfg.q_lora_rank),
+                     cfg.norm_eps),
                 pl["wq_b"]) if cfg.q_lora_rank else (h, pl["wq"])
     if tail:
         wq = jnp.pad(wq.reshape(wq.shape[0], H, dn + dr),
                      ((0, 0), (0, 0), (0, tail))).reshape(wq.shape[0], -1)
     ckv, ks = jnp.split(h @ pl["wkv_a"], [cfg.kv_lora_rank], axis=-1)
-    ckv = _rms(ckv, pl["kv_a_norm"], cfg.norm_eps)
+    ckv = _rms(ckv, _latent_norm_scale(pl, "kv_a_norm", cfg,
+                                       cfg.kv_lora_rank), cfg.norm_eps)
     w = pl["wkv_b"].reshape(-1, H, dn + cfg.v_head_dim)
     k_columns = jnp.pad(w[..., :dn], ((0, 0), (0, 0), (0, dr + tail)))
+    if rotary:
+        assert dn % 2 == 0 and tail % 2 == 0, (dn, tail)
+        f32 = jnp.float32
+        ang = jnp.arange(S, dtype=f32)[:, None] \
+            * jnp.asarray(yarn_frequencies(cfg), f32)[None]     # [S, dr / 2]
+        ks = rope_pairs(ks.astype(f32), ang).astype(h.dtype)
     # the ONE shared key in lanes [dn, dn + dr) of every head: added into
     # the zeros the keys' matrix leaves there
     k = (ckv @ k_columns.reshape(w.shape[0], -1)).reshape(b, S, H, lanes) \
         + jnp.pad(ks, ((0, 0), (0, 0), (dn, tail)))[:, :, None, :]
-    return (rows @ wq, k.reshape(b, S, -1),
+    q = rows @ wq
+    if rotary:
+        # angle 0 turns nothing: the lanes before and behind the rotated
+        q = rope_pairs(q.astype(f32), jnp.pad(
+            ang, ((0, 0), (dn // 2, tail // 2))), tiles=H).astype(h.dtype)
+    return (q, k.reshape(b, S, -1),
             ckv @ w[..., dn:].reshape(w.shape[0], -1))
 
 
@@ -1407,8 +1593,8 @@ def _latent_fused(cfg, rows, itemsize):
 
     width = cfg.qk_nope_dim + cfg.qk_rope_dim
     return cfg.qk_rope_dim % 2 == 0 and cfg.v_head_dim % qk_rope.LANES == 0 \
-        and qk_rope.supported(tuple(rows) + (cfg.n_heads * width,), width,
-                              itemsize)
+        and qk_rope.supported(tuple(rows) + (cfg.heads_here * width,),
+                              width, itemsize)
 
 
 def _latent_qkv(pl, h, cfg, first=0, columns=None):
@@ -1436,16 +1622,19 @@ def _latent_qkv(pl, h, cfg, first=0, columns=None):
     from ..kernels._common import count_call
 
     b, S, _ = h.shape
-    H, dn, dr = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+    H, dn, dr = cfg.heads_here, cfg.qk_nope_dim, cfg.qk_rope_dim
     f32 = jnp.float32
     fused = _latent_fused(cfg, (b, S), h.dtype.itemsize)
     for _ in "qk":
         count_call("qk_rope", dh=dn + dr, norm="none", rotary=1,
                    convention="pairs", fused=int(fused))
     # the latents' norms stay under the caller's scope (``_rms``)
-    q = _rms(h @ pl["wq_a"], pl["q_a_norm"], cfg.norm_eps) @ pl["wq_b"]
+    q = _rms(h @ pl["wq_a"],
+             _latent_norm_scale(pl, "q_a_norm", cfg, cfg.q_lora_rank),
+             cfg.norm_eps) @ pl["wq_b"]
     ckv, kr = jnp.split(h @ pl["wkv_a"], [cfg.kv_lora_rank], axis=-1)
-    ckv = _rms(ckv, pl["kv_a_norm"], cfg.norm_eps)
+    ckv = _rms(ckv, _latent_norm_scale(pl, "kv_a_norm", cfg,
+                                       cfg.kv_lora_rank), cfg.norm_eps)
     pos = jnp.arange(S, dtype=f32) + first
     freqs = yarn_frequencies(cfg)
     factor = yarn_rotary_factor(cfg)
@@ -1482,18 +1671,40 @@ def _latent_qkv(pl, h, cfg, first=0, columns=None):
 @devscope.scoped(devscope.ATTN_GATE)
 def _gate_heads(o, z):
     """The heads' output ``o`` [b, S, H * dh] times ``sigmoid(z)``, ``z`` the
-    gate's projection of the same shape; in float32, rounded once."""
-    return (o.astype(jnp.float32)
-            * jax.nn.sigmoid(z.astype(jnp.float32))).astype(o.dtype)
+    gate's projection of the same shape, or [b, S, H], one scalar a head;
+    in float32, rounded once."""
+    wide = o.astype(jnp.float32)
+    gate = jax.nn.sigmoid(z.astype(jnp.float32))
+    if z.shape[-1] != o.shape[-1]:
+        gate = jnp.repeat(gate, o.shape[-1] // z.shape[-1], axis=-1)
+    return (wide * gate).astype(o.dtype)
+
+
+def _gated(pl, h, o, cfg):
+    """``o`` through the configuration's output gate off the normed rows
+    ``h`` (none: ``o``).  Head-wise, the columns of ``wz`` that are this
+    share's heads'."""
+    if not cfg.attn_gate:
+        return o
+    if cfg.attn_gate == "head":
+        wz = pl["wz"]
+        if cfg.latent and cfg.heads_here != cfg.n_heads:
+            wz = jax.lax.dynamic_slice_in_dim(wz, cfg.first_head,
+                                              cfg.heads_here, axis=1)
+        return _gate_heads(o, h @ wz)
+    # the gate's projection is as wide as q: its dW beside its dx too
+    return _gate_heads(o, _project(h, pl["wz"]))
 
 
 def indexer_operands(pl, h, cfg, positions=None):
     """What the indexer's scores are made of, from the normed rows ``h`` [b,
     S, E]: its queries [b, S, Hi * Di] and its ONE key head [b, S, Di]
     (LayerNorm, scale and bias), both rotated over all Di columns by the
-    temporal stream (the token index where ``positions`` [3, b, S] is None),
-    and the heads' weights [b, S, Hi] float32, ``(h w_idx) Hi^(-1/2)
-    Di^(-1/2)``."""
+    temporal stream (the token index where ``positions`` [3, b, S] is None)
+    or over their first ``cfg.indexer_rope_dim``, and the heads' weights [b,
+    S, Hi] float32, ``(h w_idx) Hi^(-1/2) Di^(-1/2)``.  With
+    ``cfg.indexer_query`` "latent" the queries come off the normed query
+    latent ``rms(h wq_a)``."""
     Hi, Di = cfg.indexer_heads, cfg.indexer_dim
     k = (h @ pl["wk_idx"]).astype(jnp.float32)
     k = k - jnp.mean(k, axis=-1, keepdims=True)
@@ -1501,10 +1712,52 @@ def indexer_operands(pl, h, cfg, positions=None):
                           + cfg.norm_eps)
     k = (k * pl["idx_k_norm_scale"] + pl["idx_k_norm_bias"]).astype(h.dtype)
     temporal = None if positions is None else positions[:1]
-    q, k = (rope(x, n, cfg.rope_theta, positions=temporal)
-            for x, n in ((h @ pl["wq_idx"], Hi), (k, 1)))
+    rows = h
+    if cfg.indexer_query == "latent":
+        # the normed query latent, made again from the constant rows: the
+        # indexer's loss term reaches neither ``wq_a`` nor its norm
+        rows = jax.lax.stop_gradient(_rms(
+            h @ pl["wq_a"],
+            _latent_norm_scale(pl, "q_a_norm", cfg, cfg.q_lora_rank),
+            cfg.norm_eps))
+    q = rows @ pl["wq_idx"]
+    if cfg.indexer_rope_dim in (0, Di):
+        q, k = (rope(x, n, cfg.rope_theta, positions=temporal)
+                for x, n in ((q, Hi), (k, 1)))
+    else:
+        assert temporal is None, "a partly rotated indexer: the token index"
+        q, k = (_rope_first_columns(x, Di, cfg.indexer_rope_dim,
+                                    cfg.rope_theta) for x in (q, k))
     w = (h @ pl["w_idx"]).astype(jnp.float32) * (Hi ** -0.5 * Di ** -0.5)
     return q, k, w
+
+
+def _rope_first_columns(x, dh, dr, theta):
+    """``rope`` (rotate-half, pair i of a head is columns (i, i + dr / 2),
+    angle ``pos * theta^(-2i / dr)``) over the FIRST ``dr`` columns of each
+    head of the packed x [b, S, heads * dh]; the others as they are.  Where
+    a head is one lane block and ``dr`` half of it, ONE pass of the row
+    kernel (``kernels/qk_rope.py`` at heads of ``dr``, whose tables turn
+    nothing in a block's second half); elsewhere the ``rope`` line on the
+    heads' first columns, which the tests hold that kernel to."""
+    from ..kernels import qk_rope
+    from ..kernels._common import count_call
+
+    b, S, W = x.shape
+    fused = dh == qk_rope.LANES == 2 * dr and qk_rope.supported(
+        x.shape, dr, x.dtype.itemsize)
+    count_call("qk_rope", dh=dh, norm="none", rotary=1, convention="half",
+               fused=int(fused))
+    if fused:
+        cos, sin = qk_rope.angle_tables(S, dr, theta)
+        still = jnp.arange(qk_rope.LANES) >= dr
+        return qk_rope.qk_rope(
+            x, None, (jnp.where(still, 1.0, cos), jnp.where(still, 0.0, sin)),
+            head_dim=dr)
+    heads = x.reshape(b, S, W // dh, dh)
+    turned = rope(heads[..., :dr].reshape(b, S, -1), W // dh, theta)
+    return jnp.concatenate([turned.reshape(b, S, W // dh, dr),
+                            heads[..., dr:]], axis=-1).reshape(b, S, W)
 
 
 # a learned-sparse layer's residuals under remat, by name: the selection's
@@ -1569,7 +1822,11 @@ def _sparse_attention(pl, h, cfg, rotary, positions):
 
     hl, kvl = _local_heads(cfg)
     bq, bk = _clamped_blocks(cfg, h.shape[1])
-    blocks = dict(block_q=bq, block_k=bk)
+    shape = dict(block_q=bq, block_k=bk)
+    if cfg.latent:
+        # a head of q and k in whole lane blocks (zeros behind its own
+        # columns), a value of its own width
+        shape.update(scale=cfg.head_dim ** -0.5, v_head_dim=cfg.v_head_dim)
     q2, k2, v2 = _qkv(pl, h, cfg, rotary, positions=positions)
     scores, tau, lse_i, indexer = _selection(pl, jax.lax.stop_gradient(h),
                                              cfg, positions)
@@ -1577,11 +1834,11 @@ def _sparse_attention(pl, h, cfg, rotary, positions):
         # named as the dense [b, H, S]: a minor dimension of 1 may stand
         # padded to a lane tile (268 MB a layer where this is 2)
         lse = checkpoint_name(ix.dsa_lse(q2, k2, v2, scores, tau, hl, kvl,
-                                         **blocks), DSA_LSE)
+                                         **shape), DSA_LSE)
         o, kl = ix.dsa_attend_kl(q2, k2, v2, indexer, scores, tau, lse,
-                                 lse_i, hl, kvl, **blocks)
+                                 lse_i, hl, kvl, **shape)
     count_call("dsa_attend_kl")
-    return o @ pl["wo"], kl
+    return _gated(pl, h, o, cfg) @ pl["wo"], kl
 
 
 def _attention_heads_mode(pl, h_full, cfg, kind, positions=None):
@@ -1596,7 +1853,7 @@ def _attention_heads_mode(pl, h_full, cfg, kind, positions=None):
     two_widths = cfg.latent and cfg.v_head_dim != dh
     blocks = not two_widths and _packed_flash_blocks(cfg, hl, S, kvl)
     if two_widths:
-        o = _attend_two_widths(q2, k2, v2, cfg)
+        o = _attend_two_widths(q2, k2, v2, cfg, window)
     elif blocks:
         # packed layout: the kernel reads each head's column slice in place —
         # no [b, hl, S, dh] transpose round-trips (flash_attention_packed)
@@ -1614,24 +1871,22 @@ def _attention_heads_mode(pl, h_full, cfg, kind, positions=None):
             "only (flash_attention.packed_layout_supported: a lane block " \
             "of whole heads that share one key/value head; S whole blocks)"
         o = _local_attention_dispatch(q, k, v, cfg).reshape(b, S, hl * dh)
-    if cfg.attn_gate:
-        # the gate's projection is as wide as q: its dW beside its dx too
-        o = _gate_heads(o, _project(h_full, pl["wz"]))
+    o = _gated(pl, h_full, o, cfg)
     out = o @ pl["wo"]                                          # row-parallel partial
     out = col.reduce_scatter(out, TP, dim=1)                    # sum + seq scatter
     return out + pl["bo"] if cfg.bias else out
 
 
-def _attend_two_widths(q2, k2, v2, cfg):
-    """Full causal attention of the latent form where a value is not as wide
-    as a head of q and k: q2, k2 [b, S, H * lanes] (``_latent_head_lanes``,
+def _attend_two_widths(q2, k2, v2, cfg, window=None):
+    """Causal attention (under ``window``, where given) of the latent form
+    where a value is not as wide as a head of q and k: q2, k2 [b, S, H * lanes] (``_latent_head_lanes``,
     zeros behind ``head_dim``), v2 [b, S, H * dv]; the heads' outputs [b, S,
     H * dv].  The packed flash kernels' value mode (scores over the lanes,
     whose zeros add nothing, at ``head_dim^(-1/2)``; ``P V``, ``dP`` and
     ``dV`` at dv) where it takes the shapes, else plain blockwise
     attention."""
     b, S, _ = q2.shape
-    H, dv = cfg.n_heads, cfg.v_head_dim
+    H, dv = cfg.heads_here, cfg.v_head_dim
     lanes = q2.shape[-1] // H
     scale = cfg.head_dim ** -0.5
     blocks = _packed_flash_blocks(cfg, H, S, widths=(lanes, dv))
@@ -1640,7 +1895,9 @@ def _attend_two_widths(q2, k2, v2, cfg):
 
         return flash_attention_packed(
             q2, k2, v2, H, causal=cfg.causal, scale=scale, block_q=blocks[0],
-            block_k=blocks[1], v_head_dim=dv)
+            block_k=blocks[1], v_head_dim=dv, **(
+                {} if window is None else {"window": window}))
+    assert window is None, "a window runs on the packed flash kernel only"
     o = ring_attention(q2.reshape(b, S, H, lanes), k2.reshape(b, S, H, lanes),
                        v2.reshape(b, S, H, dv), axis=None, causal=cfg.causal,
                        scale=scale)
@@ -2093,26 +2350,33 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
             x_sp = _add_branch(x_sp, kda_mixer(
                 pl, _norm(x_sp, pl, "ln1", cfg), cfg), pl, "ln1", cfg)
     else:
-        with jax.named_scope(devscope.LATENT_ATTENTION if cfg.latent
-                             else devscope.ATTENTION):
+        # the configuration as THIS position reads it (its own heads, ranks,
+        # widths, theta, indexer or none) and its (window or None, rotary)
+        at, kind = cfg.position(kind or cfg.layer_kinds[0])
+        with jax.named_scope(at.scope or (
+                devscope.LATENT_ATTENTION if cfg.latent
+                else devscope.ATTENTION)):
             h = _norm(x_sp, pl, "ln1", cfg)
-            if cfg.indexer_heads:
+            if at.indexer_heads:
                 attn, extras["dsa_kl"] = _sparse_attention(
-                    pl, h, cfg, (kind or cfg.layer_kinds[0])[1], positions)
+                    pl, h, at, kind[1], positions)
             elif heads_mode:
                 h = col.all_gather(h, TP, dim=1)
-                attn = _attention_heads_mode(
-                    pl, h, cfg, kind or cfg.layer_kinds[0], positions)
+                attn = _attention_heads_mode(pl, h, at, kind, positions)
             else:
                 attn = _attention_ring_mode(pl, h, cfg)
             x_sp = _add_branch(x_sp, attn, pl, "ln1", cfg)
+    if cfg.indexer_heads and kind != FFN:
+        # a position without an indexer adds nothing to the loss term
+        extras.setdefault("dsa_kl", jnp.zeros((), jnp.float32))
 
     if cfg.single_branch and kind != FFN:
         return x_sp, None
     if dense:
         with jax.named_scope(devscope.MLP):
             return _add_branch(x_sp, gated_ffn(
-                pl, _norm(x_sp, pl, "ln2", cfg), cfg), pl, "ln2", cfg), None
+                pl, _norm(x_sp, pl, "ln2", cfg), cfg), pl, "ln2",
+                cfg), extras or None
 
     if cfg.n_experts:
         with jax.named_scope(devscope.MOE):
@@ -2209,9 +2473,11 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
                 x_sp, (layer_params, router_bias), unroll=unroll)
         return (x_sp, aux) if with_aux else x_sp
 
+    leading = []        # the leading layers' auxiliary values
     if cfg.per_position:
         for i, kind in enumerate(cfg.prefix_kinds):
-            x_sp, _ = body(prefix["l%d" % i], x_sp, cfg, kind, True)
+            x_sp, aux = body(prefix["l%d" % i], x_sp, cfg, kind, True)
+            leading.append(aux)
         at_position = layer_params if cfg.run_scan \
             else [layer_params["p%d" % i] for i in range(len(kinds))]
     else:
@@ -2257,6 +2523,10 @@ def run_layers(layer_params, x_sp, cfg: TransformerConfig, with_aux=False,
                                  x_sp, (at_position, router_bias),
                                  unroll=unroll)
     aux = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), aux)
+    if cfg.indexer_heads and leading:
+        # a leading layer's FFN is dense: its indexer's term is all it adds
+        aux = dict(aux, dsa_kl=jnp.concatenate([
+            jnp.stack([a["dsa_kl"] for a in leading]), aux["dsa_kl"]]))
     return (x_sp, aux) if with_aux else x_sp
 
 

@@ -1409,3 +1409,115 @@ def test_the_three_stream_rotary_pass_compiles_for_a_v5e(one_chip, b, S):
             asked, took = _vmem(text, kernel)
             assert asked == qr.vmem_bytes(rows, W, 2) < 20 * 2 ** 20
             assert took < asked, (heads, kernel, took, asked)
+
+
+DOTS3_FULL = (1, 8192, 32, 256, 128, 64, 128)    # B, S, H, lanes, Dv, Hi, Di
+DOTS3_SLIDING = (1, 8192, 16, 256, 128, 513)     # B, S, H, D, Dv, window
+
+
+@pytest.mark.parametrize("kernel", ["indexer_scores", "dsa_lse",
+                                    "dsa_attend_kl", "flash_swa",
+                                    "rope_first_columns"])
+def test_the_dots3_kernels_compile_for_a_v5e(one_chip, kernel):
+    """``dots3_note_prev.s8192_scan``'s kernel modes through Mosaic at the
+    cell's shapes, forward and backward: the indexer's scores at 64 heads of
+    128 (a q block of 8,192 lanes: both calls state their VMEM, the
+    backward's dq accumulator 16 MiB of it); the masked online forward and
+    the pass with the statistic known at 32 heads of 192 in 256 lanes
+    against values of 128 (its backward the masked flash backward's one
+    sweep, dk at 256 and dv at 128 lanes of all 8,192 positions in VMEM);
+    the windowed mode at 16 heads of 256 against values of 128 under a
+    window of 513 (two kv blocks a q block: 31 steps); and the indexer's
+    rotation of a head's first 64 columns as ONE pass of the row kernel."""
+    ix = importlib.import_module("paddle_tpu.kernels.indexer")
+    B, S, H, lanes, Dv, Hi, Di = DOTS3_FULL
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32 = jnp.float32
+    q, v = sds((B, S, H * lanes)), sds((B, S, H * Dv))
+    scores, tau = sds((B, S, S), f32), sds((B, S), f32)
+    steps = fa.kv_blocks(S, 512, 512, True)
+    assert steps == 136
+    shape = dict(scale=192 ** -0.5, v_head_dim=Dv, interpret=False)
+    if kernel == "indexer_scores":
+        def both(q, k, w, g):
+            out, vjp = jax.vjp(lambda *x: ix.indexer_scores(
+                *x, interpret=False), q, k, w)
+            return (out,) + vjp(g)
+        args, names = (sds((B, S, Hi * Di)), sds((B, S, Di)),
+                       sds((B, S, Hi), f32), scores), {
+            "indexer_scores_fwd": (B, S // 512, S // 512),
+            "indexer_scores_bwd": (B, steps)}
+    elif kernel == "dsa_lse":
+        def both(q, k, v, scores, tau):
+            return ix.dsa_lse(q, k, v, scores, tau, H, **shape)
+        args, names = (q, q, v, scores, tau), {
+            "flash_dsa_fwd": (B, H, 1, steps)}
+    elif kernel == "dsa_attend_kl":
+        def both(q, k, v, qi, ki, w, scores, tau, lse, lse_i, do):
+            (o, kl), vjp = jax.vjp(lambda q, k, v, *indexer: ix.dsa_attend_kl(
+                q, k, v, indexer, scores, tau, lse, lse_i, H, **shape),
+                q, k, v, qi, ki, w)
+            return (o, kl) + vjp((do, jnp.ones_like(kl)))
+        # the scores' backward at 4 heads here: its 64 are the case above
+        args, names = (q, q, v, sds((B, S, 4 * Di)), sds((B, S, Di)),
+                       sds((B, S, 4), f32), scores, tau,
+                       sds((B, H, S), f32), tau, v), {
+            "dsa_attend_kl_fwd": (B, steps, H),
+            "flash_dsa_bwd_fused": (B, H, steps),
+            "indexer_scores_bwd": (B, steps)}
+    elif kernel == "flash_swa":
+        B, S, H, D, Dv, window = DOTS3_SLIDING
+        band = fa.kv_blocks(S, 512, 512, True, window)
+        assert band == 31
+
+        def both(q, k, v, do):
+            o, vjp = jax.vjp(lambda *x: fa.flash_attention_packed(
+                *x, H, causal=True, block_q=512, block_k=512, window=window,
+                v_head_dim=Dv, interpret=False), q, k, v)
+            return (o,) + vjp(do)
+        x, y = sds((B, S, H * D)), sds((B, S, H * Dv))
+        args, names = (x, x, y, y), {
+            "flash_swa_fwd": (B, H, 1, band),
+            "flash_swa_bwd_fused": (B, H, band)}
+    else:
+        T = importlib.import_module("paddle_tpu.parallel.transformer")
+        rope = importlib.import_module("paddle_tpu.kernels.qk_rope")
+        rope._on_tpu, was = (lambda: True), rope._on_tpu
+        try:
+            def both(x, do):
+                out, vjp = jax.vjp(
+                    lambda x: T._rope_first_columns(x, Di, 64, 8e7), x)
+                return (out,) + vjp(do)
+            x = sds((B, S, Hi * Di))
+            text = jax.jit(both).trace(x, x).lower().compile().as_text()
+        finally:
+            rope._on_tpu = was
+        assert text.count("tpu_custom_call") == 2
+        return
+    traced = jax.jit(both).trace(*args)
+    grids = {name: tuple(int(n) for n in grid.split(",") if n.strip())
+             for grid, name in re.findall(
+                 r"grid=\(([\d, ]*)\).*?name=((?:flash_dsa|flash_swa|indexer"
+                 r"|dsa)_\w+)", str(traced.jaxpr), re.S)}
+    for name in ("flash_dsa_bwd_fused", "flash_swa_bwd_fused"):
+        # the row kernel in front of the flash backward (``flash_delta``)
+        # has a grid of its own, which this pattern reads as the backward's
+        if name in names:
+            grids[name] = names[name]
+    assert {n: grids[n] for n in names} == names
+    text = traced.lower().compile().as_text()
+    for name in names:
+        asked, took = _vmem(text, name)
+        assert took < (asked or fa.SCOPED_VMEM), (name, asked, took)
+    if kernel == "indexer_scores":
+        asked = {n: _vmem(text, n)[0] for n in names}
+        assert asked["indexer_scores_fwd"] == ix.scores_vmem_bytes(
+            512, 512, Hi * Di, Hi, 2)
+        assert asked["indexer_scores_bwd"] == ix.scores_vmem_bytes(
+            512, 512, Hi * Di, Hi, 2, True, S) < 96 * 2 ** 20
+    if "flash_dsa_bwd_fused" in names:
+        assert _vmem(text, "flash_dsa_bwd_fused")[0] \
+            == fa.fused_sweep_vmem_bytes(S, 256, 2, 128)

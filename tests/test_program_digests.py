@@ -19,8 +19,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from paddle_tpu.models import (bert, brumby, jamba, keye_vl2,  # noqa: E402
-                               kimi_linear,
+from paddle_tpu.models import (bert, brumby, dots3, jamba,  # noqa: E402
+                               keye_vl2, kimi_linear,
                                lfm2, mistral4, nemotron_h, olmoe, ouro,
                                resnet, smallthinker, trinity)
 from paddle_tpu.parallel import decoder  # noqa: E402
@@ -77,7 +77,14 @@ from paddle_tpu.parallel.train import stack_batches  # noqa: E402
 # with the statistic known, ``dsa_attend_kl``, whose backward runs the
 # scores' backward itself, and the policy keeps three names); the
 # twenty-four others stand: no other configuration traces
-# ``_sparse_attention`` or ``kernels/indexer.py``.
+# ``_sparse_attention`` or ``kernels/indexer.py``.  PR 63 re-took NONE of
+# the twenty-six: an attention position that reads its OWN shape
+# (``TransformerConfig.position``, which hands a plain ``(window, rotary)``
+# the configuration itself), the latent form under a window, an indexer and
+# a head-wise gate, a share of the heads and the flash and indexer kernels'
+# value-width modes left every older program's text as it was, Keye's and
+# Kimi-Linear's included; dots3-note-prev's two joined, taken on that PR's
+# tree.
 PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "bert.run_steps": "00de5403506fdc87",
             "olmoe.step": "231114fcd62341f2",
@@ -103,7 +110,9 @@ PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "kimi_linear.step": "00fafaa79be69c29",
             "kimi_linear.run_steps": "768fb807ebe118b1",
             "keye_vl2.step": "7c426adf13402711",
-            "keye_vl2.run_steps": "ea3eb37e8934f5a4"}
+            "keye_vl2.run_steps": "ea3eb37e8934f5a4",
+            "dots3.step": "4edfb625dc948fb3",
+            "dots3.run_steps": "cd2be27c6cafe090"}
 OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
          "olmoe": (olmoe.build_olmoe_trainer, olmoe.olmoe_tiny_config, 32),
          "smallthinker": (smallthinker.build_smallthinker_trainer,
@@ -124,7 +133,8 @@ OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
          "kimi_linear": (kimi_linear.build_kimi_linear_trainer,
                          kimi_linear.kimi_linear_tiny_config, 64),
          "keye_vl2": (keye_vl2.build_keye_vl2_trainer,
-                      keye_vl2.keye_vl2_tiny_config, 64)}
+                      keye_vl2.keye_vl2_tiny_config, 64),
+         "dots3": (dots3.build_dots3_trainer, dots3.dots3_tiny_config, 64)}
 
 
 def digests(names):
