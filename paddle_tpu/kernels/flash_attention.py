@@ -93,7 +93,7 @@ from . import flash_delta
 from ._common import (CompilerParams as _CompilerParams,
                       count_call as _count_call, on_tpu as _on_tpu)
 
-__all__ = ["flash_attention", "flash_attention_packed", "flash_dsa_packed"]
+__all__ = ["flash_attention", "flash_attention_packed"]
 
 NEG_INF = -1e30
 
@@ -328,12 +328,8 @@ class _Geom:
     head, no grouping): ``vw`` is a value head-block's lanes where ``qw`` is
     a query's, and every product with v or do runs at ``vw``."""
 
-    def __init__(self, q, k, H, bq, bk, Hkv=None, window=None, Dv=None,
-                 masked=False):
+    def __init__(self, q, k, H, bq, bk, Hkv=None, window=None, Dv=None):
         B, self.S, E = q.shape
-        # a mask that is an OPERAND beside the causal predicate
-        # (``flash_dsa_packed``): kernels named ``flash_dsa_*``
-        self.masked = masked
         self.Sk = k.shape[1]
         if H is None:
             self.D, self.hpb, self.Hb = E, 1, 1
@@ -407,29 +403,16 @@ class _Geom:
         operands.  No map divides: on the chip a (row, head-block) pair
         unpacked from one grid index by ``//`` and ``%`` cost each of the
         sweep's steps 30 to 60 ns (PERF.md section 6, PR 34)."""
-        at = functools.partial(self._at, walks_group)
+        def at(pick):
+            if walks_group:
+                return lambda r, kh, t, q_of, kv_of, head_of, flags: pick(
+                    r, kh, kh * self.group + head_of[t], q_of[t], kv_of[t])
+            return lambda r, kh, g, t, q_of, kv_of, head_of, flags: pick(
+                r, kh, kh * self.group + g, q_of[t], kv_of[t])
+
         return (at(lambda r, kh, qh, i, j: (r, i, qh)),
                 at(lambda r, kh, qh, i, j: (r, j, kh)),
                 at(lambda r, kh, qh, i, j: (r, qh, i, 0)))
-
-    def _at(self, walks_group, pick):
-        """An index map of a several-block sweep: ``pick(batch row,
-        key/value head-block, query head-block, q block, kv block)``."""
-        if walks_group:
-            return lambda r, kh, t, q_of, kv_of, head_of, flags: pick(
-                r, kh, kh * self.group + head_of[t], q_of[t], kv_of[t])
-        return lambda r, kh, g, t, q_of, kv_of, head_of, flags: pick(
-            r, kh, kh * self.group + g, q_of[t], kv_of[t])
-
-    def mask_specs(self, bq, bk, walks_group=False):
-        """Of a sweep's mask operands: the scores' [B, S, Sk] tile of the
-        step and the thresholds' [B, S, 1] rows of its q block, the same
-        for every head."""
-        at = functools.partial(self._at, walks_group)
-        return [pl.BlockSpec((1, bq, bk),
-                             at(lambda r, kh, qh, i, j: (r, i, j))),
-                pl.BlockSpec((1, bq, 1),
-                             at(lambda r, kh, qh, i, j: (r, i, 0)))]
 
     def step(self, head_of=None):
         """(t, query head-block) of a sweep's grid position; ``head_of``:
@@ -579,33 +562,14 @@ def _seen(shape, q0, k0, window, wrap=None):
     return (qpos >= kpos) & (qpos - kpos < window)
 
 
-def _scores(q, k, scale, causal, q0, k0, window=None, wrap=None, keep=None):
+def _scores(q, k, scale, causal, q0, k0, window=None, wrap=None):
     """[bq, bk] f32 scaled scores of one head (of ``_stack_heads``' rows:
-    ``wrap``), future positions (and those behind the window) masked, and
-    where ``keep`` [bq, bk] bool is given every pair it drops."""
+    ``wrap``), future positions (and those behind the window) masked."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if causal:
-        seen = _seen(s.shape, q0, k0, window, wrap)
-        s = jnp.where(seen if keep is None else seen & keep, s, NEG_INF)
-    elif keep is not None:
-        s = jnp.where(keep, s, NEG_INF)
+        s = jnp.where(_seen(s.shape, q0, k0, window, wrap), s, NEG_INF)
     return s
-
-
-def _kept(mask):
-    """[bq, bk] bool of a step's mask operands, the indexer's scores' tile
-    and the thresholds of its rows (``flash_dsa_packed``): score >=
-    threshold.  None without them."""
-    return None if mask is None else mask[0][0] >= mask[1][0]
-
-
-def _masked(kernel, n_in):
-    """``kernel`` with the two mask operands behind its ``n_in`` inputs."""
-    def body(*refs, **kw):
-        at = 4 + n_in               # behind the step table's four columns
-        return kernel(*refs[:at], *refs[at + 2:], mask=refs[at:at + 2], **kw)
-    return body
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq,
@@ -657,7 +621,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq,
 
 def _fwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, o_ref,
                       lse_ref, m_scr, l_scr, acc_scr, *q_stk, scale, causal,
-                      bq, bk, hpb, geom, mask=None):
+                      bq, bk, hpb, geom):
     """Several kv blocks, one (row, head-block) pair a grid row and one
     (q block, kv block) pair a step (``step_table``): running max,
     denominator and accumulator in scratch across a q block's sweep.
@@ -684,7 +648,7 @@ def _fwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, o_ref,
         """The rows of q against columns ``cs`` of this kv block's keys and
         ``vs`` of its values."""
         s = _scores(q, k_ref[0][:, cs], scale, causal, q_of[t] * bq,
-                    kv_of[t] * bk, geom.window, wrap, _kept(mask))  # [rows, bk]
+                    kv_of[t] * bk, geom.window, wrap)  # [rows, bk]
 
         m_prev = m_scr[:, ls]                          # [rows, LANES]
         m_cur = jnp.max(s, axis=1)[:, None]            # [rows, 1]
@@ -730,10 +694,7 @@ def _fwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, o_ref,
 
 
 def _name(kernel, g):
-    """``flash_<kernel>``; ``flash_swa_<kernel>`` for a windowed call,
-    ``flash_dsa_<kernel>`` for one whose mask is an operand."""
-    if g.masked:
-        return "flash_dsa_" + kernel
+    """``flash_<kernel>``; ``flash_swa_<kernel>`` for a windowed call."""
     return ("flash_swa_" if g.window is not None else "flash_") + kernel
 
 
@@ -759,14 +720,11 @@ def _sweep_call(kernel, g, table, operands, in_specs, out_specs, out_shape,
 
 
 def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None, Hkv=None,
-         window=None, Dv=None, mask=None):
+         window=None, Dv=None):
     """H=None: q/k/v are [BH, S, D].  H=int: q/k/v are [B, S, H*D] (k, v
     [B, S, Hkv*D] with grouped queries; v [B, S, H*Dv] with a value width of
-    its own).  ``mask`` = (scores [B, S, Sk], thresholds [B, S, 1]), float32:
-    a pair counts where its score is at or above its row's threshold (the
-    several-block sweeps alone, a lane block a head)."""
-    g = _Geom(q, k, H, bq, bk, Hkv, window, Dv, masked=mask is not None)
-    assert mask is None or (not g.one_block and g.halves == 1), (g.S, bk)
+    its own)."""
+    g = _Geom(q, k, H, bq, bk, Hkv, window, Dv)
     out_shape = [
         jax.ShapeDtypeStruct(g.o_shape, q.dtype),
         jax.ShapeDtypeStruct(g.stat_shape, jnp.float32),
@@ -776,13 +734,10 @@ def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None, Hkv=None,
         # statistics: a head a group of lanes, or a row of the stack
         rows, lanes = g.halves * bq, g.hpb // g.halves * LANES
         return _sweep_call(
-            functools.partial(
-                _masked(_fwd_sweep_kernel, 3) if mask else _fwd_sweep_kernel,
-                scale=scale, causal=causal, bq=bq, bk=bk, hpb=g.hpb, geom=g),
-            g, step_table(g.S, g.Sk, bq, bk, causal, window),
-            (q, k, v) + tuple(mask or ()),
-            [g.q_spec(bq, qm), g.kv_spec(bk, km), g.v_spec(bk, km)]
-            + (g.mask_specs(bq, bk) if mask else []),
+            functools.partial(_fwd_sweep_kernel, scale=scale, causal=causal,
+                              bq=bq, bk=bk, hpb=g.hpb, geom=g),
+            g, step_table(g.S, g.Sk, bq, bk, causal, window), (q, k, v),
+            [g.q_spec(bq, qm), g.kv_spec(bk, km), g.v_spec(bk, km)],
             [g.o_spec(bq, qm), g.stat_spec(bq, sm)], out_shape,
             [pltpu.VMEM((rows, lanes), jnp.float32),
              pltpu.VMEM((rows, lanes), jnp.float32),
@@ -928,7 +883,7 @@ def _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=None,
 
 def _bwd_dq_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, delta_ref, dq_ref, acc_scr, *stk, scale, causal,
-                   bq, bk, geom, hpb=1, mask=None):
+                   bq, bk, geom, hpb=1):
     """The q-major sweep of the two: dq of a q block over its kv blocks
     (``stk``: as ``_bwd_sweep_kernel``)."""
     t, q_block = geom.step()
@@ -943,7 +898,7 @@ def _bwd_dq_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
 
     def tile(q, k, v, do, lse, delta, cs, vs, wrap=None):
         s = _scores(q, k, scale, causal, q_of[t] * bq, kv_of[t] * bk,
-                    geom.window, wrap, _kept(mask))
+                    geom.window, wrap)
         p = jnp.exp(s - lse())                         # [rows, bk]
         dov = jax.lax.dot_general(do, v,
                                   (((1,), (1,)), ((), ())),
@@ -965,7 +920,7 @@ def _bwd_dq_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
 
 def _bwd_dkv_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                    scale, causal, bq, bk, geom, hpb=1, mask=None):
+                    scale, causal, bq, bk, geom, hpb=1):
     """The kv-major sweep of the two: dk and dv of a kv block over the q
     blocks that see it.  Every step meets another q block, so where the
     heads ride stacked (``geom.halves`` > 1) a step stacks its own."""
@@ -980,7 +935,7 @@ def _bwd_dkv_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
 
     def tile(q, k, v, do, lse, delta, cs, vs, wrap=None):
         s = _scores(q, k, scale, causal, q_of[t] * bq, kv_of[t] * bk,
-                    geom.window, wrap, _kept(mask))
+                    geom.window, wrap)
         p = jnp.exp(s - lse())                         # [rows, bk]
         # dv_j += p^T dO
         dv = jax.lax.dot_general(
@@ -1010,7 +965,7 @@ def _bwd_dkv_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
 def _bwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
                       lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_scr,
                       dk_acc, dv_acc, *stk, scale, causal, bq, bk, geom,
-                      hpb=1, mask=None):
+                      hpb=1):
     """Several blocks, ONE sweep: a grid row is a (batch row, key/value
     head-block) pair and its steps walk the group's query head-blocks, of
     each its q blocks, of each its visible kv blocks (``step_table``,
@@ -1061,7 +1016,7 @@ def _bwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
         """The rows of q and do against columns ``cs`` of this kv block's
         keys and ``vs`` of its values."""
         s = _scores(q, k, scale, causal, q_of[t] * bq, kv_of[t] * bk,
-                    geom.window, wrap, _kept(mask))
+                    geom.window, wrap)
         p = jnp.exp(s - lse())                     # [rows, bk] - the ONE exp
         # dv_j += p^T dO
         dv = jax.lax.dot_general(
@@ -1118,10 +1073,9 @@ def _delta(o, do, g, packed, interpret):
 
 
 def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
-         window=None, Dv=None, mask=None):
+         window=None, Dv=None):
     q, k, v, o, lse = res
-    g = _Geom(q, k, H, bq, bk, Hkv, window, Dv, masked=mask is not None)
-    assert mask is None or (not g.one_block and g.halves == 1), (g.S, bk)
+    g = _Geom(q, k, H, bq, bk, Hkv, window, Dv)
     if g.one_block and g.group == 1:
         return _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=H,
                           window=window, Dv=Dv)
@@ -1144,14 +1098,12 @@ def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
         qs, ks = g.q_spec(bq, qm), g.kv_spec(bk, km)
         os, vs = g.o_spec(bq, qm), g.v_spec(bk, km)
         return _sweep_call(
-            functools.partial(_masked(kernel, 6) if mask else kernel,
-                              scale=scale, causal=causal, bq=bq,
+            functools.partial(kernel, scale=scale, causal=causal, bq=bq,
                               bk=bk, hpb=g.hpb, geom=g),
             g, step_table(g.S, g.Sk, bq, bk, causal, window,
                           g.group if walks_group else 1, kv_major),
-            (q, k, v, do, lse, delta) + tuple(mask or ()),
-            [qs, ks, vs, os, g.stat_spec(bq, sm), g.stat_spec(bq, sm)]
-            + (g.mask_specs(bq, bk, walks_group) if mask else []),
+            (q, k, v, do, lse, delta),
+            [qs, ks, vs, os, g.stat_spec(bq, sm), g.stat_spec(bq, sm)],
             out_specs(qs, ks, vs), out_shape, scratch_shapes, walks_group,
             interpret, name, **params)
 
@@ -1224,76 +1176,6 @@ def _flash_packed_bwd(heads, scale, causal, bq, bk, interpret, res, do):
 
 
 _flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash_dsa(q, k, v, scores, tau, heads, scale, bq, bk, interpret):
-    """``heads`` = (H, Hkv[, None, Dv]).  (o, lse): the statistic leaves
-    too, for the indexer's own loss term, and takes no cotangent."""
-    return tuple(_fwd(q, k, v, scale, True, bq, bk, interpret, *heads,
-                      mask=(scores, tau)))
-
-
-def _flash_dsa_fwd(q, k, v, scores, tau, heads, scale, bq, bk, interpret):
-    o, lse = _flash_dsa(q, k, v, scores, tau, heads, scale, bq, bk,
-                        interpret)
-    return (o, lse), (q, k, v, o, lse, scores, tau)
-
-
-def _flash_dsa_bwd(heads, scale, bq, bk, interpret, res, cts):
-    *res, scores, tau = res
-    # the selection passes no gradient: the mask's operands get none
-    return tuple(_bwd(scale, True, bq, bk, interpret, res, cts[0], *heads,
-                      mask=(scores, tau))) + (
-        jnp.zeros_like(scores), jnp.zeros_like(tau))
-
-
-_flash_dsa.defvjp(_flash_dsa_fwd, _flash_dsa_bwd)
-
-
-def masked_heads(n_heads, n_kv_heads, head_dim, v_head_dim=None):
-    """``(H, Hkv)`` of a masked call, or ``(H, H, None, Dv)`` where a value
-    has a width of its own (``_fwd``'s and ``_bwd``'s trailing arguments)."""
-    if v_head_dim in (None, head_dim):
-        return n_heads, n_kv_heads
-    assert n_heads == n_kv_heads, "a value width of its own: no grouping"
-    return n_heads, n_kv_heads, None, int(v_head_dim)
-
-
-def flash_dsa_packed(q, k, v, scores, tau, n_heads, n_kv_heads=None,
-                     scale=None, block_q=512, block_k=512, interpret=None,
-                     v_head_dim=None):
-    """Causal attention under a mask that is DATA, packed layout: query t
-    reads the keys ``s <= t`` with ``scores[t, s] >= tau[t]`` (``scores`` [B,
-    S, S] float32, ``tau`` [B, S] float32; ``-inf``: every causal key).
-    q [B, S, H*D], k and v [B, S, Hkv*D], D whole lane blocks; returns ``(o
-    [B, S, H*D], lse [B, H, S, 1] float32)``, the row's log-sum-exp over
-    its selected keys beside the output.  The in-block mask is an operand
-    of every step (the scores' tile against the thresholds' rows); the
-    SWEEP is the causal triangle's, built from the shapes as every other:
-    a block with no selected key computes zeros.  Every row must select a
-    key.  Gradients flow to q, k and v; kernels ``flash_dsa_fwd`` /
-    ``flash_dsa_bwd_*``.  ``v_head_dim`` other than D: v [B, S, H *
-    v_head_dim] and so is ``o`` (every head its own key/value head; give
-    ``scale`` where D holds lanes that are not the head's)."""
-    B, S, E = q.shape
-    H, Hkv = n_heads, n_kv_heads or n_heads
-    D = E // H
-    Dv = D if v_head_dim is None else int(v_head_dim)
-    assert D % LANES == 0 and packed_layout_supported(H, D, Hkv, Dv), (
-        H, D, Hkv, Dv)
-    assert k.shape == (B, S, Hkv * D) and v.shape == (B, S, Hkv * Dv), (
-        k.shape, v.shape)
-    assert scores.shape == (B, S, S) and tau.shape == (B, S), (
-        scores.shape, tau.shape)
-    bq, bk = min(block_q, S), min(block_k, S)
-    assert S % bq == 0 and S % bk == 0 and S > bk, (S, bq, bk)
-    if interpret is None:
-        interpret = not _on_tpu()
-    return _flash_dsa(q, k, v, scores, tau[..., None],
-                      masked_heads(H, Hkv, D, Dv),
-                      float(D ** -0.5 if scale is None else scale), bq, bk,
-                      bool(interpret))
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=256,

@@ -1,6 +1,6 @@
 """The indexer of a learned-sparse attention layer: which keys a query reads.
 
-Three pieces, each a (q block, k block) tile at a time, so that nothing
+Four pieces, each a (q block, k block) tile at a time, so that nothing
 [heads, S, S] ever stands (16 heads at S = 16,384 would be 17 GB):
 
 - ``indexer_scores`` (Pallas, forward and backward): ``I[t, s] = sum_j
@@ -16,22 +16,32 @@ Three pieces, each a (q block, k block) tile at a time, so that nothing
   of float32 (``_ordered``: an unsigned integer whose order is the float's)
   fix the answer a bit a pass.  A row with fewer than k finite entries
   answers ``-inf``: every causal key is selected.
-- ``dsa_attend_kl`` (Pallas, forward; its backward is the masked flash
-  backward and the scores' backward): the attention's output under the
-  selection AND the mean over rows of ``KL(p_t || softmax over S_t of I[t,
-  .])``, ``S_t = {s <= t : I[t, s] >= tau_t}``, ``p[t, s] = mean over the
-  heads of the main attention's probabilities``, in ONE sweep with the
-  statistic known: a (tile, key/value head) pair a grid step, its group's
-  query heads looped inside, each head's tile ``exp(q k^T - lse)`` made
-  once and used twice, through ``p v`` into the head's accumulator (``o``)
-  and into the heads' sum in scratch (``p``).  The same pass writes ``G =
-  softmax_S(I) - p`` (0 off ``S_t``), which IS ``dKL/dI``: the backward
-  hands it to ``indexer_scores_bwd`` with the cotangent's scalar as that
-  kernel's gain, so no other [S, S] array is kept or made.  p carries a
+- ``dsa_attend_kl`` (Pallas, forward; its backward is the masked sweep
+  ``flash_dsa_bwd_fused`` and the scores' backward): the attention's output
+  under the selection AND the mean over rows of ``KL(p_t || softmax over
+  S_t of I[t, .])``, ``S_t = {s <= t : I[t, s] >= tau_t}``, ``p[t, s] = mean
+  over the heads of the main attention's probabilities``, in ONE sweep with
+  the statistic known: a (tile, key/value head) pair a grid step, its
+  group's query heads looped inside, each head's tile ``exp(q k^T - lse)``
+  made once and used twice, through ``p v`` into the head's accumulator
+  (``o``) and into the heads' sum in scratch (``p``).  The same pass writes
+  ``G = softmax_S(I) - p`` (0 off ``S_t``), which IS ``dKL/dI``: the
+  backward hands it to ``indexer_scores_bwd`` with the cotangent's scalar as
+  that kernel's gain, so no other [S, S] array is kept or made.  p carries a
   stop-gradient: the KL term reaches the scores' operands alone.  ``lse``
-  is the masked online forward's (``dsa_lse``: ``flash_dsa_fwd``), which
+  is the masked online sweep's (``dsa_lse``: ``flash_dsa_fwd``), which
   under a layer's remat runs ONCE: the statistic is kept, the recompute is
   this pass alone.
+- the two masked sweeps (Pallas): ``flash_dsa_fwd``, the online softmax's
+  statistic ALONE (running max and denominator a head, no ``P V``, no
+  accumulator, no ``o``), and ``flash_dsa_bwd_fused``, the flash backward's
+  one sweep (five matmuls and one ``exp`` a (tile, head); dk and dv of the
+  whole sequence in VMEM) under the selection.  In both a grid row is a
+  (batch row, key/value head) pair and a grid step a tile of the causal
+  triangle: the tile of I, the thresholds and the selection are built once
+  a step and the group's query heads are looped inside it, k and v fetched
+  once for all of them, dk and dv summed over them before ONE
+  read-modify-write of the accumulators.
 
 interpret=None auto-selects the Pallas interpreter off-TPU.
 """
@@ -45,9 +55,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..monitor import devscope
 from ._common import (LANES, CompilerParams as _CompilerParams,
-                      on_tpu as _on_tpu)
-from .flash_attention import (FIRST, LAST, SCOPED_VMEM, _bwd as _flash_bwd,
-                              _fwd as _flash_fwd, masked_heads, step_table)
+                      count_call as _count_call, on_tpu as _on_tpu)
+from .flash_attention import (FIRST, LAST, NEG_INF as MASKED, SCOPED_VMEM,
+                              SWEEP_VMEM, _Geom, _delta, _divisors,
+                              _lanes_to, step_table)
 
 __all__ = ["indexer_scores", "kth_largest", "selected", "selected_lse",
            "dsa_lse", "dsa_attend_kl"]
@@ -190,21 +201,23 @@ def _scores_bwd_kernel(q_of, kv_of, head_of, flags, gain_ref, q_ref, k_ref,
 
 def _triangle_call(kernel, name, table, B, operands, in_specs, out_specs,
                    out_shape, scratch_shapes, interpret, extra_axes=(),
-                   **params):
+                   row_axes=(), **params):
     """A sweep over the causal triangle's (q block, k block) pairs, the
-    flash kernels' ``step_table`` as scalar-prefetch operands; ``extra_axes``
-    inner grid axes behind the step."""
+    flash kernels' ``step_table`` as scalar-prefetch operands; ``row_axes``
+    outer grid axes in front of the step (a sweep each), ``extra_axes``
+    inner ones behind it."""
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(table),
-            grid=(B, table.shape[1]) + tuple(extra_axes),
+            grid=(B,) + tuple(row_axes) + (table.shape[1],)
+            + tuple(extra_axes),
             in_specs=in_specs, out_specs=out_specs,
             scratch_shapes=scratch_shapes),
         out_shape=out_shape,
         compiler_params=_CompilerParams(
-            dimension_semantics=("parallel",) + ("arbitrary",) * (
-                1 + len(extra_axes)), **params),
+            dimension_semantics=("parallel",) * (1 + len(row_axes))
+            + ("arbitrary",) * (1 + len(extra_axes)), **params),
         interpret=interpret, name=name,
     )(*(jnp.asarray(column) for column in table), *operands)
 
@@ -440,6 +453,248 @@ def _attend_kl_call(q, k, v, scores, tau, lse, lse_i, n_heads, n_kv_heads,
                                               group, bq, bk, Dv))
 
 
+# ---------------------------------------------------------------------------
+# the two masked sweeps: the online softmax's statistic alone, and the flash
+# backward's one sweep, a (tile, key/value head) a grid step
+# ---------------------------------------------------------------------------
+
+def _selection_off(i_ref, tau_ref, q0, k0, bq, bk):
+    """[bq, bk] float32 of a step's tile of I and its rows' thresholds: 0 on
+    ``S_t``, ``MASKED`` off it (the flash sweeps' finite minus infinity: a
+    scaled score plus it IS it, so a head's masked scores are ``s + off``)."""
+    keep = _causal((bq, bk), q0, k0) & (i_ref[0] >= tau_ref[0])
+    return jnp.where(keep, 0.0, MASKED)
+
+
+def _lse_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, i_ref, tau_ref,
+                lse_ref, m_scr, l_scr, *, scale, heads, bq, bk):
+    """``flash_dsa_fwd``: the running max and denominator of ``heads`` query
+    heads of one key/value head over a q block's sweep, the recurrence of
+    ``flash_attention._fwd_sweep_kernel`` a head; the statistic leaves at
+    the sweep's last step and nothing else is made."""
+    t = pl.program_id(2)
+    d = k_ref.shape[-1]
+
+    @pl.when((flags[t] & FIRST) != 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, MASKED)
+        l_scr[:] = jnp.zeros_like(l_scr)
+
+    off = _selection_off(i_ref, tau_ref, q_of[t] * bq, kv_of[t] * bk, bq, bk)
+    k = k_ref[0]
+    for h in range(heads):
+        s = jax.lax.dot_general(q_ref[0, :, h * d:(h + 1) * d], k,
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = s + off
+        m_prev = m_scr[h]                                  # [bq, LANES]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
+        p = jnp.exp(s - _lanes_to(m_new, bk))
+        l_scr[h] = l_scr[h] * jnp.exp(m_prev - m_new) \
+            + jnp.sum(p, axis=1)[:, None]
+        m_scr[h] = m_new
+
+    @pl.when((flags[t] & LAST) != 0)
+    def _final():
+        for h in range(heads):
+            lse_ref[0, h] = m_scr[h][:, :1] + jnp.log(
+                jnp.maximum(l_scr[h][:, :1], 1e-30))
+
+
+def _dsa_bwd_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
+                    lse_ref, delta_ref, i_ref, tau_ref, dq_ref, dk_ref,
+                    dv_ref, dq_scr, dk_acc, dv_acc, *, scale, heads, bq, bk):
+    """``flash_dsa_bwd_fused``: a grid row is a (batch row, key/value head)
+    pair, a step a tile of the triangle with ``heads`` query heads looped
+    inside: of each the five products and ONE ``exp`` of
+    ``flash_attention._bwd_sweep_kernel``'s tile, dq into the q sweep's
+    scratch (which leaves at its last step), dk and dv summed over the
+    heads, then ONE read-modify-write of rows ``kv block`` of the two
+    float32 accumulators that hold the whole sequence and are the key/value
+    head's own; both leave once, at the grid row's last step."""
+    t = pl.program_id(2)
+    d, dv_w = k_ref.shape[-1], v_ref.shape[-1]
+
+    def rows_of(kv_block):
+        return pl.ds(pl.multiple_of(kv_block * bk, bk), bk)
+
+    def kv_blocks_of_the_sequence(block):
+        def step(n, carry):
+            block(rows_of(n))
+            return carry
+        jax.lax.fori_loop(0, dk_acc.shape[0] // bk, step, 0)
+
+    @pl.when(t == 0)
+    def _open():
+        def zero(at):
+            dk_acc[at, :] = jnp.zeros((bk, d), jnp.float32)
+            dv_acc[at, :] = jnp.zeros((bk, dv_w), jnp.float32)
+        kv_blocks_of_the_sequence(zero)
+
+    @pl.when((flags[t] & FIRST) != 0)
+    def _init():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    off = _selection_off(i_ref, tau_ref, q_of[t] * bq, kv_of[t] * bk, bq, bk)
+    k, v = k_ref[0], v_ref[0]
+    dk = dv = None
+    for h in range(heads):
+        q = q_ref[0, :, h * d:(h + 1) * d]
+        do = do_ref[0, :, h * dv_w:(h + 1) * dv_w]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        p = jnp.exp(s + off - lse_ref[0, h])       # [bq, bk] - the ONE exp
+        dv_h = jax.lax.dot_general(                # p^T dO
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dov = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        ds = (p * (dov - delta_ref[0, h]) * scale).astype(q.dtype)
+        dq_scr[:, h * d:(h + 1) * d] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_h = jax.lax.dot_general(                # ds^T q
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk, dv = (dk_h, dv_h) if dk is None else (dk + dk_h, dv + dv_h)
+    rows = rows_of(kv_of[t])
+    dk_acc[rows, :] += dk
+    dv_acc[rows, :] += dv
+
+    @pl.when((flags[t] & LAST) != 0)
+    def _final():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+    @pl.when(t == pl.num_programs(2) - 1)
+    def _close():
+        def leave(at):
+            dk_ref[0, at, :] = dk_acc[at, :].astype(dk_ref.dtype)
+            dv_ref[0, at, :] = dv_acc[at, :].astype(dv_ref.dtype)
+        kv_blocks_of_the_sequence(leave)
+
+
+def dsa_fwd_vmem_bytes(heads, head_dim, itemsize, bq=512, bk=512):
+    """What ``flash_dsa_fwd`` asks of VMEM at ``heads`` query heads a step:
+    the q block of those heads, the k block, the tile of I and the
+    thresholds' column (padded to a lane tile) twice each, the heads'
+    statistic twice (an output block, a column a head padded to a lane
+    tile), their running max and denominator, and six [bq, bk] float32
+    values of a step's own."""
+    tile, rows = bq * bk * 4, bq * LANES * 4
+    return (2 * (heads * bq + bk) * head_dim * itemsize + 2 * tile
+            + 2 * rows + 4 * heads * rows + 6 * tile)
+
+
+def dsa_bwd_vmem_bytes(S, heads, head_dim, v_head_dim, itemsize, bq=512,
+                       bk=512):
+    """What ``flash_dsa_bwd_fused`` asks of VMEM at ``heads`` query heads a
+    step and S keys: the two float32 accumulators of dk and dv of the whole
+    sequence and their output blocks (one buffer each: they leave once a
+    grid row); the blocks of q and dq (at q's width) and do (at the
+    values') of the step's heads twice each and dq's float32 scratch; the
+    heads' ``lse`` and ``delta``, a column each padded to a lane tile, twice;
+    the tile of I, the thresholds' column, k and v twice; and eight [bq, bk]
+    float32 values of a step's own (the selection, a head's scores,
+    probabilities, ``dP``, ``dS`` and their rounded copies, the next head's
+    beside them)."""
+    tile, rows = bq * bk * 4, bq * LANES * 4
+    width = head_dim + v_head_dim
+    return (S * width * (4 + itemsize)
+            + heads * bq * (2 * (2 * head_dim + v_head_dim) * itemsize
+                            + 4 * head_dim)
+            + 4 * heads * rows + 2 * (tile + rows + bk * width * itemsize)
+            + 8 * tile)
+
+
+def heads_a_step(group, vmem_bytes):
+    """The query heads of a group that ride one grid step of a masked sweep:
+    the most (a divisor of the group) whose ``vmem_bytes(heads)`` fits
+    SWEEP_VMEM.  From the shapes alone; the group's other heads are further
+    sweeps of the same grid row (``step_table``'s ``group``)."""
+    fit = [n for n in _divisors(group) if vmem_bytes(n) <= SWEEP_VMEM]
+    assert fit, "dk and dv of the whole sequence do not fit VMEM"
+    return fit[-1]
+
+
+def _sweep_maps(chunks):
+    """Index maps of a masked sweep's grid (batch row, key/value head, step
+    of the table): (q rows of the step's heads, k rows, the heads' row
+    statistics, the tile of I, a [.., S, 1] column's rows, a key/value
+    head's whole sequence).  ``chunks`` sweeps a grid row: the step's heads
+    are the ``head_of[t]``-th of them."""
+    def at(pick):
+        return lambda b, n, t, q_of, kv_of, head_of, flags: pick(
+            b, n, n * chunks + head_of[t], q_of[t], kv_of[t])
+    return (at(lambda b, n, c, i, j: (b, i, c)),
+            at(lambda b, n, c, i, j: (b, j, n)),
+            at(lambda b, n, c, i, j: (b, c, i, 0)),
+            at(lambda b, n, c, i, j: (b, i, j)),
+            at(lambda b, n, c, i, j: (b, i, 0)),
+            at(lambda b, n, c, i, j: (b, 0, n)))
+
+
+def _lse_call(q, k, scores, tau, n_heads, n_kv_heads, scale, bq, bk,
+              interpret):
+    """[B, H, S, 1] float32 of q [B, S, H * D], k [B, S, Hkv * D], the
+    scores [B, S, S] and the thresholds [B, S, 1]."""
+    B, S, W = q.shape
+    D, group = W // n_heads, n_heads // n_kv_heads
+    need = functools.partial(dsa_fwd_vmem_bytes, head_dim=D,
+                             itemsize=q.dtype.itemsize, bq=bq, bk=bk)
+    heads = heads_a_step(group, need)
+    _count_call("flash_dsa", part="fwd", group=group, heads_in_step=heads,
+                statistic_only=1)
+    qrow, krow, stat, tile, row, _ = _sweep_maps(group // heads)
+    return _triangle_call(
+        functools.partial(_lse_kernel, scale=scale, heads=heads, bq=bq,
+                          bk=bk),
+        "flash_dsa_fwd", step_table(S, S, bq, bk, True, group=group // heads),
+        B, (q, k, scores, tau),
+        [pl.BlockSpec((1, bq, heads * D), qrow),
+         pl.BlockSpec((1, bk, D), krow), pl.BlockSpec((1, bq, bk), tile),
+         pl.BlockSpec((1, bq, 1), row)],
+        pl.BlockSpec((1, heads, bq, 1), stat),
+        jax.ShapeDtypeStruct((B, n_heads, S, 1), jnp.float32),
+        [pltpu.VMEM((heads, bq, LANES), jnp.float32)] * 2, interpret,
+        row_axes=(n_kv_heads,), **_past_scoped(need(heads)))
+
+
+def _dsa_bwd_call(q, k, v, do, lse, delta, scores, tau, n_heads, n_kv_heads,
+                  scale, bq, bk, interpret):
+    """(dq, dk, dv) of ``o``'s cotangent ``do`` under the selection, ``lse``
+    and ``delta`` [B, H, S, 1] float32."""
+    B, S, W = q.shape
+    D, Dv = W // n_heads, v.shape[-1] // n_kv_heads
+    group = n_heads // n_kv_heads
+    need = functools.partial(dsa_bwd_vmem_bytes, S, head_dim=D,
+                             v_head_dim=Dv, itemsize=q.dtype.itemsize, bq=bq,
+                             bk=bk)
+    heads = heads_a_step(group, need)
+    _count_call("flash_dsa", part="bwd", group=group, heads_in_step=heads,
+                statistic_only=0)
+    qrow, krow, stat, tile, row, whole = _sweep_maps(group // heads)
+    held = lambda lanes: pl.BlockSpec((1, S, lanes), whole,
+                                      pipeline_mode=pl.Buffered(1))
+    stats = pl.BlockSpec((1, heads, bq, 1), stat)
+    return _triangle_call(
+        functools.partial(_dsa_bwd_kernel, scale=scale, heads=heads, bq=bq,
+                          bk=bk),
+        "flash_dsa_bwd_fused",
+        step_table(S, S, bq, bk, True, group=group // heads), B,
+        (q, k, v, do, lse, delta, scores, tau),
+        [pl.BlockSpec((1, bq, heads * D), qrow),
+         pl.BlockSpec((1, bk, D), krow), pl.BlockSpec((1, bk, Dv), krow),
+         pl.BlockSpec((1, bq, heads * Dv), qrow), stats, stats,
+         pl.BlockSpec((1, bq, bk), tile), pl.BlockSpec((1, bq, 1), row)],
+        [pl.BlockSpec((1, bq, heads * D), qrow), held(D), held(Dv)],
+        [jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        [pltpu.VMEM((bq, heads * D), jnp.float32),
+         pltpu.VMEM((S, D), jnp.float32), pltpu.VMEM((S, Dv), jnp.float32)],
+        interpret, row_axes=(n_kv_heads,), vmem_limit_bytes=need(heads))
+
+
 def selected_lse(scores, tau, rows=SELECT_ROWS):
     """``log sum over S_t of exp(I[t, s])`` [B, S]: the selected keys'
     normaliser, ``rows`` rows a block."""
@@ -486,10 +741,11 @@ def _attend_kl_bwd(heads, scale, bq, bk, interpret, res, cts):
     # constant of the KL term, whose dI = G ct / (B S) goes straight to the
     # scores' operands: the scalar rides the scores' backward, and no third
     # [S, S] array stands beside I and G.  The selection passes no gradient
-    d_qkv = tuple(_flash_bwd(
-        scale, True, bq, bk, interpret, (q, k, v, o, lse), do,
-        *masked_heads(*heads, q.shape[-1] // heads[0],
-                      v.shape[-1] // heads[1]), mask=(scores, tau)))
+    H, Hkv = heads
+    geom = _Geom(q, k, H, bq, bk, Hkv, Dv=v.shape[-1] // Hkv)
+    d_qkv = tuple(_dsa_bwd_call(
+        q, k, v, do, lse, _delta(o, do, geom, True, interpret), scores, tau,
+        H, Hkv, scale, bq, bk, interpret))
     with jax.named_scope(devscope.INDEXER):
         dqi, dki, dw = _scores_bwd_call(*indexer, g, bq, bk, interpret,
                                         gain=ct / (B * S))
@@ -500,20 +756,25 @@ def _attend_kl_bwd(heads, scale, bq, bk, interpret, res, cts):
 _attend_kl.defvjp(_attend_kl_fwd, _attend_kl_bwd)
 
 
-def dsa_lse(q, k, v, scores, tau, n_heads, n_kv_heads=None, scale=None,
-            block_q=512, block_k=512, interpret=None, v_head_dim=None):
+def dsa_lse(q, k, scores, tau, n_heads, n_kv_heads=None, scale=None,
+            block_q=512, block_k=512, interpret=None):
     """[B, H, S] float32: each head's log-sum-exp over the keys its row
-    selects (``flash_dsa_packed``'s statistic, from the same kernel,
-    ``flash_dsa_fwd``), a constant: nothing differentiates through it."""
-    D = q.shape[-1] // n_heads
-    bq, bk, interpret = _blocks(q.shape[1], block_q, block_k, interpret)
+    selects, ``log sum over S_t of exp(scale q_t . k_s)``, by the online
+    softmax's running max and denominator alone (kernel ``flash_dsa_fwd``:
+    no ``P V`` and no output but the statistic), a constant: nothing
+    differentiates through it.  Every row must select a key."""
+    B, S, E = q.shape
+    H, Hkv = int(n_heads), int(n_kv_heads or n_heads)
+    D = E // H
+    assert D % LANES == 0 and H % Hkv == 0 and k.shape == (B, S, Hkv * D), (
+        q.shape, k.shape, H, Hkv)
+    assert scores.shape == (B, S, S) and tau.shape == (B, S), (
+        scores.shape, tau.shape)
+    bq, bk, interpret = _blocks(S, block_q, block_k, interpret)
     stop = jax.lax.stop_gradient
-    _, lse = _flash_fwd(
-        stop(q), stop(k), stop(v),
-        float(D ** -0.5 if scale is None else scale), True, bq, bk, interpret,
-        *masked_heads(n_heads, n_kv_heads or n_heads, D, v_head_dim),
-        mask=(stop(scores), stop(tau)[..., None]))
-    return lse[..., 0]
+    return _lse_call(stop(q), stop(k), stop(scores), stop(tau)[..., None], H,
+                     Hkv, float(D ** -0.5 if scale is None else scale), bq,
+                     bk, interpret)[..., 0]
 
 
 def dsa_attend_kl(q, k, v, indexer, scores, tau, lse, lse_i, n_heads,
@@ -539,8 +800,8 @@ def dsa_attend_kl(q, k, v, indexer, scores, tau, lse, lse_i, n_heads,
     ``scores`` [B, S, S] = ``indexer_scores(*indexer)``, ``indexer`` its
     (q, k, w); ``tau`` [B, S]; ``lse`` [B, H, S] (``dsa_lse``) and ``lse_i``
     [B, S] (``selected_lse``), both constants.  Gradients: o's to q, k and
-    v by the masked flash backward (``flash_dsa_bwd_*``, which re-makes p
-    from ``lse`` as this pass does); kl's to ``indexer`` alone, through
+    v by the masked backward sweep (``flash_dsa_bwd_fused``, which re-makes
+    p from ``lse`` as this pass does); kl's to ``indexer`` alone, through
     ``indexer_scores_bwd`` on ``G`` with the cotangent's scalar as its
     gain (``scores`` itself gets zeros: its only way on is here)."""
     B, S, E = q.shape
@@ -554,7 +815,6 @@ def dsa_attend_kl(q, k, v, indexer, scores, tau, lse, lse_i, n_heads,
     assert lse.shape == (B, H, S) and lse_i.shape == tau.shape == (B, S), (
         lse.shape, lse_i.shape, tau.shape)
     bq, bk, interpret = _blocks(S, block_q, block_k, interpret)
-    assert S > bk, "the masked flash backward sweeps several blocks"
     qi, ki, w = indexer
     assert scores.shape == (B, S, S) and qi.shape[:2] == (B, S), (
         scores.shape, qi.shape)

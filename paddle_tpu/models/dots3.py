@@ -41,7 +41,7 @@ on ``latent_rescale``, ``attn_gate="head"``, ``indexer_query="latent"``,
 ``indexer_rope_dim``, ``heads_held`` / ``first_head`` beside ``experts_held``
 / ``first_expert``, ``prefix_pattern`` / ``dense_ffn_hidden``,
 ``shared_ffn_hidden`` and ``routing``; on the flash kernels' window mode and
-masked mode at a value width of their own, ``kernels/indexer.py`` and
+``kernels/indexer.py``'s masked sweeps at a value width of their own and
 ``parallel/moe.py``'s ``dropless_moe_ffn``; forward, loss, trainer and
 builder are ``parallel/decoder.py``'s.  The vision tower, the audio encoder
 and the multi-token-prediction module are not here.

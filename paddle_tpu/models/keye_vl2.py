@@ -23,9 +23,8 @@ coefficient 1 are ASSUMED).
 Nothing here is a second block: it is ``parallel/transformer.py``'s, by
 configuration (``indexer_heads`` / ``indexer_dim`` / ``indexer_topk`` /
 ``mrope_sections`` / ``qk_norm="head"`` / ``n_kv_heads`` / ``experts_held``),
-on ``kernels/indexer.py`` (scores, selection, the KL pass), the flash
-kernels' masked mode (``flash_dsa_packed``) and ``parallel/moe.py``'s
-``dropless_moe_ffn``; forward, loss, trainer and builder are
+on ``kernels/indexer.py`` (scores, selection, the masked sweeps, the KL
+pass) and ``parallel/moe.py``'s ``dropless_moe_ffn``; forward, loss, trainer and builder are
 ``parallel/decoder.py``'s.  The vision tower is not here: the program trains
 the language model on token ids, and the three position streams are an input
 (``batch["positions"]`` [3, B, S] of a trainer built with

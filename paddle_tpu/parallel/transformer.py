@@ -1807,14 +1807,15 @@ def _clamped_blocks(cfg, S):
 
 def _sparse_attention(pl, h, cfg, rotary, positions):
     """Learned-sparse attention's branch [b, S, E] and the indexer's loss
-    term (a scalar), then ``wo``.  The masked online forward (``dsa_lse``)
-    gives each head's statistic alone, a constant and the layer's residual;
-    ONE pass with that statistic known (``dsa_attend_kl``) makes the
-    attention's output and the loss term, so a layer's second forward under
-    remat is that pass and no online softmax.  The cross entropy reaches q,
-    k and v alone (the mask passes no gradient); the KL term reaches the
-    indexer's leaves alone (its target, the heads' mean probabilities, and
-    the indexer's input are constants)."""
+    term (a scalar), then ``wo``.  The masked online sweep (``dsa_lse``)
+    gives each head's statistic and nothing else, a constant and the
+    layer's residual; ONE pass with that statistic known
+    (``dsa_attend_kl``) makes the attention's output and the loss term, so
+    a layer's second forward under remat is that pass and no online
+    softmax.  The cross entropy reaches q, k and v alone (the mask passes
+    no gradient); the KL term reaches the indexer's leaves alone (its
+    target, the heads' mean probabilities, and the indexer's input are
+    constants)."""
     from jax.ad_checkpoint import checkpoint_name
 
     from ..kernels import indexer as ix
@@ -1822,21 +1823,22 @@ def _sparse_attention(pl, h, cfg, rotary, positions):
 
     hl, kvl = _local_heads(cfg)
     bq, bk = _clamped_blocks(cfg, h.shape[1])
-    shape = dict(block_q=bq, block_k=bk)
+    shape, values = dict(block_q=bq, block_k=bk), {}
     if cfg.latent:
         # a head of q and k in whole lane blocks (zeros behind its own
         # columns), a value of its own width
-        shape.update(scale=cfg.head_dim ** -0.5, v_head_dim=cfg.v_head_dim)
+        shape.update(scale=cfg.head_dim ** -0.5)
+        values.update(v_head_dim=cfg.v_head_dim)
     q2, k2, v2 = _qkv(pl, h, cfg, rotary, positions=positions)
     scores, tau, lse_i, indexer = _selection(pl, jax.lax.stop_gradient(h),
                                              cfg, positions)
     with jax.named_scope(devscope.SPARSE_ATTN):
         # named as the dense [b, H, S]: a minor dimension of 1 may stand
         # padded to a lane tile (268 MB a layer where this is 2)
-        lse = checkpoint_name(ix.dsa_lse(q2, k2, v2, scores, tau, hl, kvl,
+        lse = checkpoint_name(ix.dsa_lse(q2, k2, scores, tau, hl, kvl,
                                          **shape), DSA_LSE)
         o, kl = ix.dsa_attend_kl(q2, k2, v2, indexer, scores, tau, lse,
-                                 lse_i, hl, kvl, **shape)
+                                 lse_i, hl, kvl, **shape, **values)
     count_call("dsa_attend_kl")
     return _gated(pl, h, o, cfg) @ pl["wo"], kl
 

@@ -7,10 +7,11 @@ A full layer: q and k [1, S, 32 x 256] (heads of 192, zeros behind them), v
 [1, S, 32 x 128], an indexer of 64 heads of 128, the 2,048 best keys a row
 (a quarter of a shorter ``--seq``).  A sliding layer: q and k [1, S, 16 x
 256], v [1, S, 16 x 128], a window of 513.  Holds ``indexer_scores``
-(forward, and dq / dk / dw from a random dI), the masked online forward's
-statistic (``dsa_lse``), the pass with the statistic known
-(``dsa_attend_kl``: o, the KL term, dq / dk / dv from a random do and the
-KL's gradient of the scores' operands), the windowed flash mode at a value
+(forward, and dq / dk / dw from a random dI), the masked online sweep's
+statistic (``dsa_lse``: ``flash_dsa_fwd``, a head a step, no value read),
+the pass with the statistic known (``dsa_attend_kl``: o, the KL term, dq /
+dk / dv from a random do by the masked backward sweep
+``flash_dsa_bwd_fused``, and the KL's gradient of the scores' operands), the windowed flash mode at a value
 width of its own (o, dq / dk / dv) and the rotation of a head's first 64
 columns by the row kernel, to the formulas computed in float32 at
 ``highest`` precision on the same bf16 operands, a block of query rows at a
@@ -20,7 +21,7 @@ the formula at a window of 512, which must be far off.  Each reading is the
 largest absolute difference over the largest absolute value of the
 formula's result.  Then ``seconds``: the host's clock around each call
 alone, jitted, the mean of five after one.  Writes
-``chiprun_out/pr63/dots3_kernels_receipt.json``; off a chip (interpret
+``chiprun_out/pr64/dots3_kernels_receipt.json``; off a chip (interpret
 mode) give a short ``--seq``."""
 
 import argparse
@@ -150,8 +151,9 @@ def main(argv=None):
         want[topk - 1:], topk)[0][:, -1])
     selected = lambda first: causal(first) & (
         rows_of(got[0], first) >= rows_of(tau[0], first)[:, None])
-    lse = jax.jit(lambda *a: ix.dsa_lse(*a, got, tau, H, **masked))(
-        q, k, v)
+    statistic = jax.jit(lambda *a: ix.dsa_lse(
+        *a, got, tau, H, scale=masked["scale"], **blocks))
+    lse = statistic(q, k)
     lse_i = jax.jit(ix.selected_lse)(got, tau)
     fused = lambda q, k, v, *indexer: ix.dsa_attend_kl(
         q, k, v, indexer, got, tau, lse, lse_i, H, **masked)
@@ -224,8 +226,7 @@ def main(argv=None):
             tri, d_scores, 0.0)), (0, 1, 2))), qi, ki, w)
     sec["kth_largest"] = timed(jax.jit(lambda s: ix.kth_largest(s, topk)),
                                got)
-    sec["dsa_lse"] = timed(jax.jit(lambda *a: ix.dsa_lse(
-        *a, got, tau, H, **masked)), q, k, v)
+    sec["dsa_lse"] = timed(statistic, q, k)
     sec["dsa_attend_kl_fwd"] = timed(jax.jit(fused), q, k, v, qi, ki, w)
     sec["dsa_attend_kl_fwd_and_bwd"] = timed(jax.jit(jax.grad(
         lambda *a: jnp.sum(fused(*a)[0].astype(jnp.float32)
@@ -237,7 +238,7 @@ def main(argv=None):
                            * dos.astype(jnp.float32)), (0, 1, 2))),
         qs, ks, vs)
     print(json.dumps(out), flush=True)
-    path = os.path.join(ROOT, "chiprun_out", "pr63",
+    path = os.path.join(ROOT, "chiprun_out", "pr64",
                         "dots3_kernels_receipt.json")
     os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(path, "w") as f:

@@ -6,13 +6,15 @@ float32 formulas, at the cell's shape:
 q [1, S, 32 x 128] on 4 key/value heads, an indexer of 16 heads of 64, the
 2,048 best keys a row (scaled with a shorter ``--seq``: an eighth).  Holds
 ``indexer_scores`` (forward, and dq / dk / dw from a random dI), the k-th
-largest a row (``kth_largest`` against ``jax.lax.top_k``), ``flash_dsa_*``
-(o, lse, and dq / dk / dv from a random do) and ``dsa_attend_kl``, the pass
-with the statistic known (o, the KL term, dq / dk / dv from the same do and
-the KL's gradient of the scores' operands) to the formulas, computed in float32 at ``highest`` precision on the
-same bf16 operands, a block of query rows at a time (the dense [32, S, S]
-never stands); and, the CONTROL, the flash output against the formula
-WITHOUT the selection's mask, which must be far off.  Each reading is the
+largest a row (``kth_largest`` against ``jax.lax.top_k``), the masked
+sweeps (``flash_dsa_fwd``: the statistic alone, ``dsa_lse``;
+``flash_dsa_bwd_fused``: dq / dk / dv from a random do, behind
+``dsa_attend_kl``) and ``dsa_attend_kl``, the pass with the statistic known
+(o, the KL term and the KL's gradient of the scores' operands) to the
+formulas, computed in float32 at ``highest`` precision on the same bf16
+operands, a block of query rows at a time (the dense [32, S, S] never
+stands); and, the CONTROL, the output against the formula WITHOUT the
+selection's mask, which must be far off.  Each reading is the
 largest absolute difference over the largest absolute value of the formula's
 result.  And the rotary pass with positions that are DATA
 (``qk_rope.angle_tables(positions=)``, which the cell, at text positions,
@@ -20,15 +22,16 @@ never takes): the row kernel on q with an image grid's three streams
 (temporal, height, width; sections [16, 24, 24]) against the float32
 formula, the value and dx, with the control that swaps the spatial sections.
 Then ``seconds``: the host's clock around each of the layer's calls alone,
-jitted, the mean of five after one (the masked online forward, the pass with
-the statistic known, the masked backward, the selected keys' normaliser; of
-a tree that still has it, ``indexer_kl``).  ``--tree DIR`` reads another
-checkout's kernels (the parent's, from ``git archive``), ``--times-only``
-skips the formulas.  Writes
-``chiprun_out/pr62/keye_vl2_kernels_receipt[_<tree>].json``; off a chip
-(interpret mode) give a short ``--seq``."""
+jitted, the mean of five after one (the masked online sweep, the pass with
+the statistic known, the masked backward with its ``delta``, the selected
+keys' normaliser).  ``--times-only`` skips the formulas; with it ``--tree
+DIR`` times another checkout's kernels (the parent's, from ``git archive``:
+a tree whose masked sweeps are the shared flash kernels' ``mask=`` mode).
+Writes ``chiprun_out/pr64/keye_vl2_kernels_receipt[_<tree>].json``; off a
+chip (interpret mode) give a short ``--seq``."""
 
 import argparse
+import importlib
 import json
 import os
 import sys
@@ -44,7 +47,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from paddle_tpu.kernels import indexer as ix, qk_rope  # noqa: E402
-from paddle_tpu.kernels.flash_attention import flash_dsa_packed  # noqa: E402
 
 H, HKV, D, HI, DI = 32, 4, 128, 16, 64
 THETA, SECTIONS, EPS = 1e7, (16, 24, 24), 1e-6
@@ -136,27 +138,29 @@ def seconds(fn, *args, calls=5):
 
 def times(q, k, v, do, indexer, sc, tau, blocks):
     """``{call: seconds}`` of the layer's calls, each alone."""
-    from paddle_tpu.kernels.flash_attention import _bwd
-
-    dsa = lambda *a: flash_dsa_packed(*a, sc, tau, H, HKV, **blocks)
-    o, lse = dsa(q, k, v)
-    bq = blocks["block_q"]
-    out = {"flash_dsa_fwd": seconds(dsa, q, k, v),
-           "flash_dsa_bwd": seconds(lambda *a: _bwd(
-               D ** -0.5, True, bq, bq, not ix._on_tpu(), a[:5], a[5], H, HKV,
-               mask=(sc, tau[..., None])), q, k, v, o, lse, do)}
-    if hasattr(ix, "dsa_attend_kl"):
-        lse_i = ix.selected_lse(sc, tau)
-        out["selected_lse"] = seconds(ix.selected_lse, sc, tau)
-        out["dsa_attend_kl_fwd"] = seconds(lambda *a: ix.dsa_attend_kl(
-            *a, indexer, sc, tau, lse[..., 0], lse_i, H, HKV, **blocks),
-            q, k, v)
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    bq, scale, interpret = blocks["block_q"], D ** -0.5, not ix._on_tpu()
+    if hasattr(fa, "flash_dsa_packed"):
+        # a tree before PR 64: the shared sweeps under ``mask=``
+        lse_of = lambda q, k: fa.flash_dsa_packed(q, k, v, sc, tau, H, HKV,
+                                                  **blocks)[1][..., 0]
+        bwd = lambda q, k, v, o, lse, do: fa._bwd(
+            scale, True, bq, bq, interpret, (q, k, v, o, lse[..., None]), do,
+            H, HKV, mask=(sc, tau[..., None]))
     else:
-        out["selected_lse"] = seconds(ix._selected_lse, sc, tau)
-        out["indexer_kl_fwd_and_selected_lse"] = seconds(
-            lambda *a: ix.indexer_kl(sc, tau, *a, H, HKV, **blocks),
-            q, k, lse)
-    return out
+        lse_of = lambda q, k: ix.dsa_lse(q, k, sc, tau, H, HKV, **blocks)
+        bwd = lambda q, k, v, o, lse, do: ix._dsa_bwd_call(
+            q, k, v, do, lse[..., None], fa._delta(
+                o, do, fa._Geom(q, k, H, bq, bq, HKV), True, interpret),
+            sc, tau[..., None], H, HKV, scale, bq, bq, interpret)
+    lse, lse_i = lse_of(q, k), ix.selected_lse(sc, tau)
+    attend = lambda q, k, v: ix.dsa_attend_kl(
+        q, k, v, indexer, sc, tau, lse, lse_i, H, HKV, **blocks)
+    return {"flash_dsa_fwd": seconds(lse_of, q, k),
+            "flash_dsa_bwd": seconds(bwd, q, k, v, attend(q, k, v)[0], lse,
+                                     do),
+            "selected_lse": seconds(ix.selected_lse, sc, tau),
+            "dsa_attend_kl_fwd": seconds(attend, q, k, v)}
 
 
 def main():
@@ -194,7 +198,7 @@ def main():
 
     def write():
         path = os.path.join(
-            ROOT, "chiprun_out", "pr62", "keye_vl2_kernels_receipt%s.json"
+            ROOT, "chiprun_out", "pr64", "keye_vl2_kernels_receipt%s.json"
             % ("_" + os.path.basename(TREE) if args.tree else ""))
         os.makedirs(os.path.dirname(path), exist_ok=True)
         with open(path, "w") as f:
@@ -237,39 +241,36 @@ def main():
     read("kth_largest", jnp.where(jnp.isfinite(tau[0]), tau[0], 0.0),
          jnp.where(jnp.arange(S) >= topk - 1,
                    jax.lax.top_k(sc[0], topk)[0][:, -1], 0.0))
-    (o, lse), pull = jax.vjp(lambda *a: flash_dsa_packed(
-        *a, sc, tau, H, HKV, **blocks), q, k, v)
+    lse = ix.dsa_lse(q, k, sc, tau, H, HKV, **blocks)
     with exact:
         (o_want, lse_want, p), pull_want = jax.vjp(
             lambda *a: f_attend(*a, sc[0], tau[0]), up(q), up(k), up(v))
         wants = pull_want((up(do), jnp.zeros_like(lse_want),
                            jnp.zeros_like(p)))
         unmasked = f_attend(up(q), up(k), up(v), None, None, masked=False)[0]
-    read("flash_dsa_o", o[0], o_want)
-    read("flash_dsa_lse", lse[0, :, :, 0], lse_want)
-    for name, a, b in zip("qkv", pull((do, jnp.zeros_like(lse))), wants):
-        read("flash_dsa_d" + name, a[0], b)
-    read("control_flash_dsa_o_against_no_mask", o[0], unmasked)
-    del pull, pull_want, unmasked, o
-    # the pass with the statistic known: the same o, the KL term, and from
-    # (do, 1) the masked backward's dq / dk / dv and the KL's gradient of the
+    read("flash_dsa_lse", lse[0], lse_want)
+    del pull_want
+    # the pass with the statistic known: o, the KL term, and from (do, 1)
+    # the masked backward's dq / dk / dv and the KL's gradient of the
     # scores' operands (the formula's dKL/dI pulled through the formula's
     # scores)
     (o, value), pull = jax.vjp(lambda q, k, v, *indexer: ix.dsa_attend_kl(
-        q, k, v, indexer, sc, tau, lse[..., 0], ix.selected_lse(sc, tau), H,
-        HKV, **blocks), q, k, v, qi, ki, w)
+        q, k, v, indexer, sc, tau, lse, ix.selected_lse(sc, tau), H, HKV,
+        **blocks), q, k, v, qi, ki, w)
     *d_qkv, dqi, dki, dw = pull((do, jnp.ones((), value.dtype)))
     with exact:
         want, g_want = jax.value_and_grad(lambda x: f_kl(x, tau[0], p))(
             jnp.where(tri, sc[0], -1e30))
         d_indexer = pull_scores(jnp.where(tri, g_want, 0.0))
     read("dsa_attend_kl_o", o[0], o_want)
+    read("control_dsa_attend_kl_o_against_no_mask", o[0], unmasked)
     for name, a, b in zip("qkv", d_qkv, wants):
-        read("dsa_attend_kl_d" + name, a[0], b)
+        read("flash_dsa_d" + name, a[0], b)
     read("dsa_attend_kl", value, want)
     for name, a, b in zip(("dq", "dk", "dw"), (dqi, dki, dw), d_indexer):
         read("dsa_attend_kl_indexer_" + name, a[0], b)
     del o_want, wants, pull, o, g_want, p, d_qkv, pull_scores, d_indexer
+    del unmasked
     out["seconds"] = times(q, k, v, do, (qi, ki, w), sc, tau, blocks)
     write()
 

@@ -1,8 +1,10 @@
 """The kernel modes dots3-note-prev's two attention shapes bring, in interpret
-mode against the float32 formulas: the flash kernels' MASKED mode at a value
-width of its own (heads of 192 in 256 lanes, values of 128; the online
-forward and the pass with the statistic known), their WINDOW mode at a value
-width of its own (256 / 128 under a window a key longer than a block), the
+mode against the float32 formulas: the MASKED sweeps at a value width of its
+own and a head a step (heads of 192 in 256 lanes, values of 128: the
+statistic alone, the backward's dq / dk / dv, under the seeded selection and
+with every causal key selected, and the pass with the statistic known), the
+flash kernels' WINDOW mode at a value width of its own (256 / 128 under a
+window a key longer than a block), the
 indexer's scores at 64 heads of 128, and the rotation of a head's first
 columns by the row kernel.  ONE traced program for the file."""
 
@@ -12,8 +14,8 @@ import numpy as np
 import pytest
 
 from paddle_tpu.kernels import indexer as ix
-from paddle_tpu.kernels.flash_attention import (flash_attention_packed,
-                                                flash_dsa_packed)
+from paddle_tpu.kernels.flash_attention import (_fwd as causal_flash_fwd,
+                                                flash_attention_packed)
 from paddle_tpu.parallel import transformer as T
 
 B, S, H, D, LANES, DV, K, BLOCK, WINDOW = 1, 64, 2, 192, 256, 128, 8, 16, 17
@@ -74,13 +76,24 @@ def case():
         g_want = jax.grad(weigh(ref_scores), (0, 1, 2))(qi, ki, w)
         tau = ix.kth_largest(got, K, rows=BLOCK)
         keep = ix.selected(got, tau)
-        o, lse = flash_dsa_packed(q, k, v, got, tau, H, **masked)
         o_want, lse_want, a = dense(q, k, v, keep, D)
-        f_got = jax.grad(lambda *x: jnp.sum(flash_dsa_packed(
-            *x, got, tau, H, **masked)[0] * c_out), (0, 1, 2))(q, k, v)
         f_want = jax.grad(lambda *x: jnp.sum(dense(*x, keep, D)[0] * c_out),
                           (0, 1, 2))(q, k, v)
-        known = ix.dsa_lse(q, k, v, got, tau, H, **masked)
+        # every causal key selected: the statistic is the causal flash
+        # kernel's, o and its gradients the causal softmax's
+        every = jnp.full_like(tau, -jnp.inf)
+        every_lse = ix.dsa_lse(q, k, got, every, H, scale=D ** -0.5, **blocks)
+        attend = lambda *x: ix.dsa_attend_kl(
+            *x, (qi, ki, w), got, every, every_lse,
+            ix.selected_lse(got, every, rows=BLOCK), H, **masked)[0]
+        o = attend(q, k, v)
+        f_got = jax.grad(lambda *x: jnp.sum(attend(*x) * c_out),
+                         (0, 1, 2))(q, k, v)
+        causal_o, causal_lse = causal_flash_fwd(
+            q, k, v, D ** -0.5, True, BLOCK, BLOCK, True, H, H, None, DV)
+        causal_d = jax.grad(lambda *x: jnp.sum(dense(
+            *x, jnp.asarray(TRI)[None], D)[0] * c_out), (0, 1, 2))(q, k, v)
+        known = ix.dsa_lse(q, k, got, tau, H, scale=D ** -0.5, **blocks)
         lse_i = ix.selected_lse(got, tau, rows=BLOCK)
         fused = lambda q, k, v, *indexer: ix.dsa_attend_kl(
             q, k, v, indexer, got, tau, known, lse_i, H, **masked)
@@ -106,8 +119,8 @@ def case():
                 B, S, HI, 64), heads[..., 64:]], -1).reshape(qi.shape)
         narrow = T._rope_first_columns(qi[..., :HI * 32], 32, 16, 8e7)
         return dict(
-            scores=(got, want), o=(o, o_want), lse=(lse[..., 0], lse_want),
-            known_lse=(known, lse[..., 0]), fused_o=(o2, o_want),
+            scores=(got, want), o=(o, causal_o), lse=(known, lse_want),
+            known_lse=(every_lse, causal_lse[..., 0]), fused_o=(o2, o_want),
             kl=(kl, kl_want), window_o=(window(qw, kw, v),
                                         dense(qw, kw, v, band, LANES)[0]),
             rope_first=(turned, lines),
@@ -121,7 +134,7 @@ def case():
             **{"scores_d" + n: (x, y) for n, x, y in zip(
                 ("q", "k", "w"), g_got, g_want)},
             **{"flash_d" + n: (x, y) for n, x, y in zip(
-                "qkv", f_got, f_want)},
+                "qkv", f_got, causal_d)},
             **{"window_d" + n: (x, y) for n, x, y in zip(
                 "qkv", w_got, w_want)},
             kept=jnp.sum(keep, -1))
@@ -134,7 +147,7 @@ def case():
     ("scores", 1e-5), ("scores_dq", 1e-5), ("scores_dk", 1e-5),
     ("scores_dw", 2e-5), ("o", 1e-5), ("lse", 1e-5), ("flash_dq", 1e-5),
     ("flash_dk", 1e-5), ("flash_dv", 1e-5), ("kl", 1e-6), ("kl_dq", 1e-5),
-    ("kl_dk", 1e-5), ("kl_dw", 1e-5), ("known_lse", 0.0), ("fused_o", 1e-5),
+    ("kl_dk", 1e-5), ("kl_dw", 1e-5), ("known_lse", 1e-6), ("fused_o", 1e-5),
     ("fused_dq", 1e-5), ("fused_dk", 1e-5), ("fused_dv", 1e-5),
     ("window_o", 1e-5), ("window_dq", 1e-5), ("window_dk", 1e-5),
     ("window_dv", 1e-5), ("rope_first", 1e-6), ("rope_first_lines", 0.0)])

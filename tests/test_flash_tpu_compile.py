@@ -150,6 +150,22 @@ def _mosaic_digests(lowered_text, skip=()):
     return digests
 
 
+def _grids(jaxpr_text):
+    """``{kernel name: grid}`` of a traced program's Pallas calls: a call
+    prints its grid, its kernel's body, then its name on a line of its own,
+    so a name's grid is the last one printed before it (the row kernel in
+    front of a flash backward, ``flash_delta``, has both of its own)."""
+    grids = [(m.start(), m.group(1)) for m in re.finditer(
+        r"grid=\(([\d, ]*)\)", jaxpr_text)]
+    out = {}
+    for m in re.finditer(r"^\s*name=(\w+)$", jaxpr_text, re.M):
+        before = [grid for at, grid in grids if at < m.start()]
+        if before:
+            out[m.group(1)] = tuple(
+                int(n) for n in before[-1].split(",") if n.strip())
+    return out
+
+
 def _vmem(text, kernel):
     """(bytes of VMEM the call of ``kernel`` asks Mosaic for, None where it
     leaves the scope at its default; bytes the compiled kernel took).  XLA
@@ -1295,18 +1311,18 @@ DSA_CELL = (1, 16384, 32, 4, 128, 16, 64)   # B, S, H, Hkv, D, Hi, Di
 @pytest.mark.parametrize("kernel", ["flash_dsa", "indexer_scores",
                                     "dsa_lse", "dsa_attend_kl"])
 def test_the_learned_sparse_kernels_compile_for_a_v5e(one_chip, kernel):
-    """``keye_vl2_30b_a3b.s16384_scan``'s new kernels through Mosaic at the
-    cell's shapes, forward and backward: the masked flash sweeps (the
-    triangle's 528 steps, the scores' tile and the thresholds' rows beside
-    q, k, v; the backward ONE sweep with dk and dv of all 16,384 positions
-    in VMEM, as the causal mode's), the indexer's scores (the backward's dk
-    of the one key head whole in VMEM), the statistic alone (the masked
-    online forward, its ``o`` unread) and the pass with the statistic known
-    (a (tile, key/value head) a grid step, its eight query heads looped
-    inside; the q block's ``o`` of all 32 heads one output block and a
-    float32 accumulator a head in scratch: the VMEM the call states; its
-    backward the masked flash backward's one sweep and the scores' backward
-    on ``G``, the cotangent's scalar its gain in SMEM)."""
+    """``keye_vl2_30b_a3b.s16384_scan``'s kernels through Mosaic at the
+    cell's shapes, forward and backward: the two masked sweeps (a grid row a
+    (batch row, key/value head) pair, a step one of the triangle's 528 tiles
+    with the group's eight query heads looped inside; the forward the
+    statistic alone, the backward ONE sweep with dk and dv of all 16,384
+    positions in VMEM, what it asks stated by ``dsa_bwd_vmem_bytes`` and
+    under ``SWEEP_VMEM``), the indexer's scores (the backward's dk of the
+    one key head whole in VMEM) and the pass with the statistic known (a
+    (tile, key/value head) a grid step, heads innermost; the q block's ``o``
+    of all 32 heads one output block and a float32 accumulator a head in
+    scratch: the VMEM the call states; its backward the masked sweep and the
+    scores' backward on ``G``, the cotangent's scalar its gain in SMEM)."""
     ix = importlib.import_module("paddle_tpu.kernels.indexer")
     B, S, H, Hkv, D, Hi, Di = DSA_CELL
 
@@ -1318,14 +1334,18 @@ def test_the_learned_sparse_kernels_compile_for_a_v5e(one_chip, kernel):
     scores, tau = sds((B, S, S), f32), sds((B, S), f32)
     steps = fa.kv_blocks(S, 512, 512, True)
     assert steps == 528
+    stats = sds((B, H, S, 1), f32)
     if kernel == "flash_dsa":
-        def both(q, k, v, scores, tau, do):
-            (o, lse), vjp = jax.vjp(lambda *x: fa.flash_dsa_packed(
-                *x, scores, tau, H, Hkv, interpret=False), q, k, v)
-            return (o, lse) + vjp((do, jnp.zeros_like(lse)))
-        args, names = (q, kv, kv, scores, tau, q), {
-            "flash_dsa_fwd": (B, Hkv, H // Hkv, steps),
-            "flash_dsa_bwd_fused": (B, Hkv, H // Hkv * steps)}
+        # the two sweeps alone, as the layer's calls reach them
+        def both(q, k, v, do, lse, delta, scores, tau):
+            return (ix._lse_call(q, k, scores, tau, H, Hkv, D ** -0.5, 512,
+                                 512, False),) + tuple(ix._dsa_bwd_call(
+                q, k, v, do, lse, delta, scores, tau, H, Hkv, D ** -0.5,
+                512, 512, False))
+        args, names = (q, kv, kv, q, stats, stats, scores,
+                       sds((B, S, 1), f32)), {
+            "flash_dsa_fwd": (B, Hkv, steps),
+            "flash_dsa_bwd_fused": (B, Hkv, steps)}
     elif kernel == "indexer_scores":
         def both(q, k, w, g):
             out, vjp = jax.vjp(lambda *x: ix.indexer_scores(
@@ -1336,10 +1356,10 @@ def test_the_learned_sparse_kernels_compile_for_a_v5e(one_chip, kernel):
             "indexer_scores_fwd": (B, S // 512, S // 512),
             "indexer_scores_bwd": (B, steps)}
     elif kernel == "dsa_lse":
-        def both(q, k, v, scores, tau):
-            return ix.dsa_lse(q, k, v, scores, tau, H, Hkv, interpret=False)
-        args, names = (q, kv, kv, scores, tau), {
-            "flash_dsa_fwd": (B, Hkv, H // Hkv, steps)}
+        def both(q, k, scores, tau):
+            return ix.dsa_lse(q, k, scores, tau, H, Hkv, interpret=False)
+        args, names = (q, kv, scores, tau), {
+            "flash_dsa_fwd": (B, Hkv, steps)}
     else:
         def both(q, k, v, qi, ki, w, scores, tau, lse, lse_i, do):
             (o, kl), vjp = jax.vjp(lambda q, k, v, *indexer: ix.dsa_attend_kl(
@@ -1350,29 +1370,66 @@ def test_the_learned_sparse_kernels_compile_for_a_v5e(one_chip, kernel):
                        sds((B, S, Hi), f32), scores, tau,
                        sds((B, H, S), f32), tau, q), {
             "dsa_attend_kl_fwd": (B, steps, Hkv),
-            "flash_dsa_bwd_fused": (B, Hkv, H // Hkv * steps),
+            "flash_dsa_bwd_fused": (B, Hkv, steps),
             "indexer_scores_bwd": (B, steps)}
     traced = jax.jit(both).trace(*args)
-    grids = {name: tuple(int(n) for n in grid.split(",") if n.strip())
-             for grid, name in re.findall(
-                 r"grid=\(([\d, ]*)\).*?name=((?:flash_dsa|indexer|dsa)_\w+)",
-                 str(traced.jaxpr), re.S)}
-    # the row kernel in front of the flash backward (``flash_delta``) has a
-    # grid of its own, which this pattern reads as the backward's
-    grids["flash_dsa_bwd_fused"] = names.get("flash_dsa_bwd_fused")
+    grids = _grids(str(traced.jaxpr))
     assert {n: grids[n] for n in names} == names
     text = traced.lower().compile().as_text()
     for name in names:
         asked, took = _vmem(text, name)
         assert took < (asked or fa.SCOPED_VMEM), (name, asked, took)
+    if "flash_dsa_fwd" in names:
+        # eight heads' running statistics: past what Mosaic gives unasked
+        assert _vmem(text, "flash_dsa_fwd")[0] \
+            == ix.dsa_fwd_vmem_bytes(H // Hkv, D, 2) < 32 * 2 ** 20
     if "flash_dsa_bwd_fused" in names:
-        assert _vmem(text, "flash_dsa_bwd_fused")[0] \
-            == fa.fused_sweep_vmem_bytes(S, 128, 2)
+        asked = ix.dsa_bwd_vmem_bytes(S, H // Hkv, D, D, 2)
+        assert ix.heads_a_step(H // Hkv, lambda n: ix.dsa_bwd_vmem_bytes(
+            S, n, D, D, 2)) == H // Hkv
+        assert _vmem(text, "flash_dsa_bwd_fused")[0] == asked < fa.SWEEP_VMEM
     if kernel == "dsa_attend_kl":
         asked, took = _vmem(text, "dsa_attend_kl_fwd")
         assert asked == ix.attend_kl_vmem_bytes(H, D, 2, H // Hkv) \
             == 36 * 2 ** 20
         assert 24 * 2 ** 20 < took < asked
+
+
+# tiny model -> the masked sweeps one traced forward + backward counts in
+# ``monitor.kernels.flash_dsa_calls``, (part, group, heads in a step,
+# statistic only): Keye's sixteen heads on two (a group of eight, all in a
+# step), dots3's full layers a head a step; under remat the statistic is
+# kept, so the scanned layer's sweep is traced once and its backward once
+MASKED_SWEEPS = {
+    "keye_vl2": {("fwd", 8, 8, 1): 1, ("bwd", 8, 8, 0): 1},
+    "dots3": {("fwd", 1, 1, 1): 2, ("bwd", 1, 1, 0): 2},
+}
+
+
+@pytest.mark.parametrize("model", list(MASKED_SWEEPS))
+def test_the_masked_sweeps_a_tiny_program_traces(tmp_path, model):
+    """No chip and no compile: a monitor session around one trace of the
+    tiny model's differentiated loss (``kernels/_common.count_call``)."""
+    from paddle_tpu import monitor
+    from paddle_tpu.parallel import decoder, transformer as T
+
+    module = importlib.import_module("paddle_tpu.models." + model)
+    cfg = getattr(module, model + "_tiny_config")(remat=True)
+    params = jax.eval_shape(lambda: T._init_params(jax.random.PRNGKey(0), cfg))
+    ids = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    loss = lambda p, i: jnp.sum(decoder.forward(p, i, cfg)[0].astype(
+        jnp.float32))
+    mon = monitor.enable(str(tmp_path), flight=False)
+    try:
+        mon.registry.reset()        # the registry is the process's
+        jax.eval_shape(jax.grad(loss), params, ids)
+        got = {tuple(r["labels"][n] for n in (
+            "part", "group", "heads_in_step", "statistic_only")): r["value"]
+            for r in mon.registry.snapshot()
+            if r["name"] == "monitor.kernels.flash_dsa_calls"}
+    finally:
+        monitor.disable()
+    assert got == MASKED_SWEEPS[model]
 
 
 @pytest.mark.parametrize("b,S", [(1, 16384), (2, 8192)])
@@ -1422,10 +1479,11 @@ def test_the_dots3_kernels_compile_for_a_v5e(one_chip, kernel):
     """``dots3_note_prev.s8192_scan``'s kernel modes through Mosaic at the
     cell's shapes, forward and backward: the indexer's scores at 64 heads of
     128 (a q block of 8,192 lanes: both calls state their VMEM, the
-    backward's dq accumulator 16 MiB of it); the masked online forward and
-    the pass with the statistic known at 32 heads of 192 in 256 lanes
-    against values of 128 (its backward the masked flash backward's one
-    sweep, dk at 256 and dv at 128 lanes of all 8,192 positions in VMEM);
+    backward's dq accumulator 16 MiB of it); the masked sweeps (the
+    statistic alone, which reads no value; a head a step) and the pass with
+    the statistic known at 32 heads of 192 in 256 lanes against values of
+    128 (its backward the masked sweep, dk at 256 and dv at 128 lanes of all
+    8,192 positions in VMEM);
     the windowed mode at 16 heads of 256 against values of 128 under a
     window of 513 (two kv blocks a q block: 31 steps); and the indexer's
     rotation of a head's first 64 columns as ONE pass of the row kernel."""
@@ -1451,10 +1509,11 @@ def test_the_dots3_kernels_compile_for_a_v5e(one_chip, kernel):
             "indexer_scores_fwd": (B, S // 512, S // 512),
             "indexer_scores_bwd": (B, steps)}
     elif kernel == "dsa_lse":
-        def both(q, k, v, scores, tau):
-            return ix.dsa_lse(q, k, v, scores, tau, H, **shape)
-        args, names = (q, q, v, scores, tau), {
-            "flash_dsa_fwd": (B, H, 1, steps)}
+        def both(q, k, scores, tau):
+            return ix.dsa_lse(q, k, scores, tau, H, scale=shape["scale"],
+                              interpret=False)
+        args, names = (q, q, scores, tau), {
+            "flash_dsa_fwd": (B, H, steps)}
     elif kernel == "dsa_attend_kl":
         def both(q, k, v, qi, ki, w, scores, tau, lse, lse_i, do):
             (o, kl), vjp = jax.vjp(lambda q, k, v, *indexer: ix.dsa_attend_kl(
@@ -1498,15 +1557,7 @@ def test_the_dots3_kernels_compile_for_a_v5e(one_chip, kernel):
         assert text.count("tpu_custom_call") == 2
         return
     traced = jax.jit(both).trace(*args)
-    grids = {name: tuple(int(n) for n in grid.split(",") if n.strip())
-             for grid, name in re.findall(
-                 r"grid=\(([\d, ]*)\).*?name=((?:flash_dsa|flash_swa|indexer"
-                 r"|dsa)_\w+)", str(traced.jaxpr), re.S)}
-    for name in ("flash_dsa_bwd_fused", "flash_swa_bwd_fused"):
-        # the row kernel in front of the flash backward (``flash_delta``)
-        # has a grid of its own, which this pattern reads as the backward's
-        if name in names:
-            grids[name] = names[name]
+    grids = _grids(str(traced.jaxpr))
     assert {n: grids[n] for n in names} == names
     text = traced.lower().compile().as_text()
     for name in names:
@@ -1518,6 +1569,9 @@ def test_the_dots3_kernels_compile_for_a_v5e(one_chip, kernel):
             512, 512, Hi * Di, Hi, 2)
         assert asked["indexer_scores_bwd"] == ix.scores_vmem_bytes(
             512, 512, Hi * Di, Hi, 2, True, S) < 96 * 2 ** 20
+    if "flash_dsa_fwd" in names:
+        assert ix._past_scoped(ix.dsa_fwd_vmem_bytes(1, lanes, 2)) == {}
+        assert _vmem(text, "flash_dsa_fwd")[0] is None
     if "flash_dsa_bwd_fused" in names:
         assert _vmem(text, "flash_dsa_bwd_fused")[0] \
-            == fa.fused_sweep_vmem_bytes(S, 256, 2, 128)
+            == ix.dsa_bwd_vmem_bytes(S, 1, lanes, Dv, 2) < fa.SWEEP_VMEM

@@ -1,10 +1,13 @@
-"""``kernels/indexer.py`` and the flash kernels' masked mode
-(``flash_dsa_packed``) in interpret mode, against the float32 formulas: the
-indexer's scores forward and backward, the k-th largest of a row against
-``numpy.partition``, attention under a mask that is data against a dense
-masked softmax, and the pass with the statistic known (``dsa_attend_kl``:
-the same output, the indexer's KL term, and the gradients of both).  ONE
-traced program for the file: every check reads it."""
+"""``kernels/indexer.py`` in interpret mode, against the float32 formulas:
+the indexer's scores forward and backward, the k-th largest of a row against
+``numpy.partition``, the two masked sweeps (``flash_dsa_fwd``: the statistic
+alone; ``flash_dsa_bwd_fused``: dq, dk and dv, a group's query heads looped
+inside a (tile, key/value head) step) against a dense masked softmax, under
+the seeded selection and with every causal key selected (``tau = -inf``,
+where the statistic is the causal flash kernel's), and the pass with the
+statistic known (``dsa_attend_kl``: the output, the indexer's KL term, and
+the gradients of both).  ONE traced program a geometry (a group of 8, of 4,
+and a q block of two kv blocks): every check reads it."""
 
 import jax
 import jax.numpy as jnp
@@ -12,10 +15,13 @@ import numpy as np
 import pytest
 
 from paddle_tpu.kernels import indexer as ix
-from paddle_tpu.kernels.flash_attention import flash_dsa_packed
+from paddle_tpu.kernels.flash_attention import _fwd as causal_flash_fwd
 
-B, S, HI, DI, H, HKV, D, K, BLOCK = 2, 64, 4, 16, 8, 1, 128, 8, 16
+B, S, HI, DI, H, D, K, BLOCK = 2, 64, 4, 16, 8, 128, 8, 16
 TRI = np.tril(np.ones((S, S), bool))
+# (query heads a key/value head, q block): the second q block's last kv
+# block is the diagonal's at either
+GEOMETRIES = [(8, BLOCK), (4, BLOCK), (8, 2 * BLOCK)]
 
 
 def ref_scores(q, k, w):
@@ -28,7 +34,7 @@ def dense(q, k, v, scores, tau):
     """(o, lse, probabilities [B, H, S, S]) of the dense masked softmax."""
     keep = ix.selected(scores, tau)
     qh = q.reshape(B, S, H, D)
-    kh, vh = (jnp.repeat(x.reshape(B, S, HKV, D), H // HKV, 2)
+    kh, vh = (jnp.repeat(x.reshape(B, S, -1, D), H * D // x.shape[-1], 2)
               for x in (k, v))
     s = jnp.where(keep[:, None], jnp.einsum("bthd,bshd->bhts", qh, kh)
                   / D ** 0.5, -jnp.inf)
@@ -48,16 +54,19 @@ def ref_kl(scores, keep, a):
                              0.0)) / (B * S)
 
 
-@pytest.fixture(scope="module")
-def case():
-    """``{check: (largest absolute difference, scale)}`` of everything the
-    file holds, from one jitted program."""
+@pytest.fixture(scope="module", params=GEOMETRIES,
+                ids=lambda g: "group%d_bq%d" % g)
+def case(request):
+    """``{check: (got, want)}`` of everything the file holds, from one
+    jitted program a geometry."""
+    group, block_q = request.param
+    HKV = H // group
     r = np.random.RandomState(0)
     f32 = lambda *shape: jnp.asarray(r.randn(*shape), jnp.float32)
     qi, ki, w = f32(B, S, HI * DI), f32(B, S, DI), f32(B, S, HI)
     q, k, v = f32(B, S, H * D), f32(B, S, HKV * D), f32(B, S, HKV * D)
     c_scores, c_out = f32(B, S, S), f32(B, S, H * D)
-    blocks = dict(block_q=BLOCK, block_k=BLOCK)
+    blocks = dict(block_q=block_q, block_k=BLOCK)
 
     def program():
         weigh = lambda fn: lambda *a: jnp.sum(jnp.where(TRI, fn(*a)
@@ -68,16 +77,27 @@ def case():
                          (0, 1, 2))(qi, ki, w)
         g_want = jax.grad(weigh(ref_scores), (0, 1, 2))(qi, ki, w)
         tau = ix.kth_largest(got, K, rows=BLOCK)
-        o, lse = flash_dsa_packed(q, k, v, got, tau, H, HKV, **blocks)
         o_want, lse_want, a = dense(q, k, v, got, tau)
-        f_got = jax.grad(lambda *x: jnp.sum(flash_dsa_packed(
-            *x, got, tau, H, HKV, **blocks)[0] * c_out), (0, 1, 2))(q, k, v)
         f_want = jax.grad(lambda *x: jnp.sum(dense(*x, got, tau)[0] * c_out),
                           (0, 1, 2))(q, k, v)
+        # every causal key selected: the sweeps' statistic is the causal
+        # flash kernel's, o and its gradients the causal softmax's
+        every = jnp.full_like(tau, -jnp.inf)
+        every_lse = ix.dsa_lse(q, k, got, every, H, HKV, **blocks)
+        attend = lambda *x: ix.dsa_attend_kl(
+            *x, (qi, ki, w), got, every, every_lse,
+            ix.selected_lse(got, every, rows=BLOCK), H, HKV, **blocks)[0]
+        o = attend(q, k, v)
+        f_got = jax.grad(lambda *x: jnp.sum(attend(*x) * c_out),
+                         (0, 1, 2))(q, k, v)
+        causal_o, causal_lse = causal_flash_fwd(
+            q, k, v, D ** -0.5, True, block_q, BLOCK, True, H, HKV)
+        causal_d = jax.grad(lambda *x: jnp.sum(dense(*x, got, every)[0]
+                                               * c_out), (0, 1, 2))(q, k, v)
         # the pass with the statistic known: o, the KL term and, from a
         # cotangent of each, dq / dk / dv (o's alone) and the gradient of
         # the scores' operands (the KL's alone)
-        known = ix.dsa_lse(q, k, v, got, tau, H, HKV, **blocks)
+        known = ix.dsa_lse(q, k, got, tau, H, HKV, **blocks)
         lse_i = ix.selected_lse(got, tau, rows=BLOCK)
         fused = lambda q, k, v, *indexer: ix.dsa_attend_kl(
             q, k, v, indexer, got, tau, known, lse_i, H, HKV, **blocks)
@@ -93,13 +113,13 @@ def case():
         high = tau.at[:, BLOCK + 3].set(jnp.max(got[:, BLOCK + 3], -1))
         lone = ix.dsa_attend_kl(
             q, k, v, (qi, ki, w), got, high,
-            ix.dsa_lse(q, k, v, got, high, H, HKV, **blocks),
+            ix.dsa_lse(q, k, got, high, H, HKV, **blocks),
             ix.selected_lse(got, high), H, HKV, **blocks)
         lone_a = dense(q, k, v, got, high)[2]
         return dict(
-            scores=(got, want), tau=tau, o=(o, o_want),
-            lse=(lse[..., 0], lse_want),
-            known_lse=(known, lse[..., 0]),
+            scores=(got, want), tau=tau, o=(o, causal_o),
+            lse=(known, lse_want),
+            known_lse=(every_lse, causal_lse[..., 0]),
             selected_lse=(lse_i, jax.nn.logsumexp(jnp.where(
                 ix.selected(got, tau), got, -jnp.inf), -1)),
             fused_o=(o2, o_want),
@@ -115,7 +135,7 @@ def case():
             **{"scores_d" + n: (a_, b_) for n, a_, b_ in zip(
                 ("q", "k", "w"), g_got, g_want)},
             **{"flash_d" + n: (a_, b_) for n, a_, b_ in zip(
-                "qkv", f_got, f_want)})
+                "qkv", f_got, causal_d)})
 
     with jax.default_matmul_precision("highest"):
         return jax.device_get(jax.jit(program)())
@@ -125,7 +145,8 @@ def case():
     ("scores", 1e-5), ("scores_dq", 1e-5), ("scores_dk", 1e-5),
     ("scores_dw", 2e-5), ("o", 1e-5), ("lse", 1e-5), ("flash_dq", 1e-5),
     ("flash_dk", 1e-5), ("flash_dv", 1e-5), ("kl", 1e-6), ("kl_dq", 1e-5),
-    ("kl_dk", 1e-5), ("kl_dw", 1e-5), ("known_lse", 0.0), ("selected_lse", 1e-6), ("fused_o", 1e-5),
+    ("kl_dk", 1e-5), ("kl_dw", 1e-5), ("known_lse", 1e-6),
+    ("selected_lse", 1e-6), ("fused_o", 1e-5),
     ("fused_dq", 1e-5), ("fused_dk", 1e-5), ("fused_dv", 1e-5),
     ("lone_o", 1e-5), ("lone_kl", 1e-6)])
 def test_a_kernel_agrees_with_its_float32_formula(case, check, tolerance):
@@ -175,3 +196,40 @@ def test_ties_at_the_threshold_are_all_kept():
     kept = np.asarray(ix.selected(jnp.asarray(scores), tau)).sum(-1)[0]
     # the fourth largest is one of the tied 1.0s: every causal key stays
     assert np.array_equal(kept, np.arange(1, 17))
+
+
+def test_the_heads_a_step_come_from_the_shapes(monkeypatch):
+    """Where a group's heads do not fit VMEM in one step the most that do
+    (a divisor of the group) ride it and the others are further sweeps of
+    the same grid row: the statistic and dq the same numbers, dk and dv the
+    same sums in another order."""
+    assert ix.heads_a_step(8, lambda n: n * 2 ** 20) == 8
+    assert ix.heads_a_step(8, lambda n: n * 20 * 2 ** 20) == 2
+    assert ix.heads_a_step(6, lambda n: n * 20 * 2 ** 20) == 3
+    with pytest.raises(AssertionError):
+        ix.heads_a_step(8, lambda n: 65 * 2 ** 20)
+    r = np.random.RandomState(3)
+    f32 = lambda *shape: jnp.asarray(r.randn(*shape), jnp.float32)
+    qi, ki, w = f32(1, S, HI * DI), f32(1, S, DI), f32(1, S, HI)
+    q, k, v, c_out = f32(1, S, H * D), f32(1, S, D), f32(1, S, D), \
+        f32(1, S, H * D)
+    blocks = dict(block_q=BLOCK, block_k=BLOCK)
+    scores = ix.indexer_scores(qi, ki, w, **blocks)
+    tau = ix.kth_largest(scores, K, rows=BLOCK)
+
+    def sweeps():
+        lse = ix.dsa_lse(q, k, scores, tau, H, 1, **blocks)
+        return (lse,) + jax.grad(lambda *x: jnp.sum(ix.dsa_attend_kl(
+            *x, (qi, ki, w), scores, tau, lse, ix.selected_lse(scores, tau),
+            H, 1, **blocks)[0] * c_out), (0, 1, 2))(q, k, v)
+
+    whole = sweeps()
+    # room for the accumulators and two heads of the eight
+    monkeypatch.setattr(ix, "SWEEP_VMEM", ix.dsa_bwd_vmem_bytes(
+        S, 2, D, D, 4, BLOCK, BLOCK))
+    assert ix.heads_a_step(8, lambda n: ix.dsa_bwd_vmem_bytes(
+        S, n, D, D, 4, BLOCK, BLOCK)) == 2
+    lse, dq, dk, dv = sweeps()
+    assert np.array_equal(lse, whole[0]) and np.array_equal(dq, whole[1])
+    for got, want in ((dk, whole[2]), (dv, whole[3])):
+        assert np.max(np.abs(got - want)) <= 1e-5 * np.max(np.abs(want))

@@ -167,8 +167,7 @@ def test_equal_streams_are_plain_rotary_and_a_grid_s_agree(case):
 
 
 def test_rows_before_topk_are_dense_causal_attention():
-    from paddle_tpu.kernels.flash_attention import (flash_attention_packed,
-                                                    flash_dsa_packed)
+    from paddle_tpu.kernels.flash_attention import flash_attention_packed
 
     r = np.random.RandomState(2)
     q, k, v = (jnp.asarray(r.randn(1, S, n * 128), jnp.float32)
@@ -176,10 +175,15 @@ def test_rows_before_topk_are_dense_causal_attention():
     scores = jnp.where(np.tril(np.ones((S, S), bool)),
                        jnp.asarray(r.randn(1, S, S), jnp.float32), -jnp.inf)
     tau = ix.kth_largest(scores, TOPK)
-    sparse = flash_dsa_packed(q, k, v, scores, tau, 8, 2, block_q=16,
-                              block_k=16)[0]
-    full = flash_attention_packed(q, k, v, 8, causal=True, block_q=16,
-                                  block_k=16, n_kv_heads=2)
+    blocks = dict(block_q=16, block_k=16)
+    # the scores' operands are read by the backward alone
+    indexer = tuple(jnp.zeros((1, S, n), jnp.float32) for n in (16, 16, 1))
+    sparse = ix.dsa_attend_kl(
+        q, k, v, indexer, scores, tau,
+        ix.dsa_lse(q, k, scores, tau, 8, 2, **blocks),
+        ix.selected_lse(scores, tau), 8, 2, **blocks)[0]
+    full = flash_attention_packed(q, k, v, 8, causal=True, n_kv_heads=2,
+                                  **blocks)
     assert close(sparse[:, :TOPK], full[:, :TOPK], 1e-6)
     assert not close(sparse[:, TOPK:], full[:, TOPK:], 1e-2)
 

@@ -84,7 +84,11 @@ from paddle_tpu.parallel.train import stack_batches  # noqa: E402
 # a head-wise gate, a share of the heads and the flash and indexer kernels'
 # value-width modes left every older program's text as it was, Keye's and
 # Kimi-Linear's included; dots3-note-prev's two joined, taken on that PR's
-# tree.
+# tree.  PR 64 took Keye's and dots3's four anew ON PURPOSE (the two masked
+# sweeps are kernels of ``kernels/indexer.py``, a (tile, key/value head) a
+# grid step, the forward's only output the statistic, and ``dsa_lse`` reads
+# no value); the twenty-four others stand: the ``mask=`` mode left
+# ``kernels/flash_attention.py`` and took nothing of theirs with it.
 PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "bert.run_steps": "00de5403506fdc87",
             "olmoe.step": "231114fcd62341f2",
@@ -109,10 +113,10 @@ PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "resnet.run_steps": "dc9dd853700f9ab9",
             "kimi_linear.step": "00fafaa79be69c29",
             "kimi_linear.run_steps": "768fb807ebe118b1",
-            "keye_vl2.step": "7c426adf13402711",
-            "keye_vl2.run_steps": "ea3eb37e8934f5a4",
-            "dots3.step": "4edfb625dc948fb3",
-            "dots3.run_steps": "cd2be27c6cafe090"}
+            "keye_vl2.step": "4fdc203023887275",
+            "keye_vl2.run_steps": "b3dfb8265faa6541",
+            "dots3.step": "925774ee831b76df",
+            "dots3.run_steps": "dc10deb567ff9f61"}
 OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
          "olmoe": (olmoe.build_olmoe_trainer, olmoe.olmoe_tiny_config, 32),
          "smallthinker": (smallthinker.build_smallthinker_trainer,
