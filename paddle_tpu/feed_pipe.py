@@ -216,8 +216,6 @@ class DeviceFeedPipe:
                 mon = _registry()
                 if mon is not None:
                     mon.registry.histogram(
-                        "monitor.pipe.convert_ms").observe(convert_ms)
-                    mon.registry.histogram(
                         "monitor.pipe.put_wait_ms").observe(put_wait_ms)
         except BaseException as e:       # delivered in order to the consumer
             self._err.append(e)
@@ -368,9 +366,6 @@ class InFlightWindow:
             # skipping the wait keeps the bound loose by one step at worst
             if "deleted" not in str(e) and "donated" not in str(e):
                 raise
-            mon = _registry()
-            if mon is not None:
-                mon.registry.counter("monitor.pipe.wait_skipped").incr()
             return
         mon = _registry()
         if mon is not None:

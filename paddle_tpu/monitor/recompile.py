@@ -18,10 +18,20 @@ off ``jax.monitoring`` instead, by the process's one ``compile_ledger()``,
 together with the phases of set-up that the program marks itself
 (``CompileLedger.phase``).  Always on: a listener fires on compile events
 only, which jit's cached call path never reaches.
+
+The same ledger keeps what the HOST did once training runs, on the same
+clock: a ``call`` record a ``StepTrainer.step`` / ``run_steps``, a ``gc``
+record a collection of Python's collector and a ``stall`` record a late
+beat of its watch thread; ``CompileLedger.window(t0, t1)`` puts a slow
+stretch of a job down to them.
 """
 
 import collections
 import contextlib
+import gc
+import os
+import resource
+import statistics
 import threading
 import time
 import warnings
@@ -184,6 +194,15 @@ _MAX_RECORDS = 16384
 # a backend compile of the same name after it has closed, under no phase, is
 # a step that compiled again
 FIRST_CALL = "first_call"
+# The host's records (``call``, ``gc``, ``stall``) have a ring of their own:
+# a host-fed job writes a ``call`` a step and Python collects its youngest
+# generation many times a second, and neither may push set-up's records out
+# of ``records``.  At 22 steps a second the ring holds ten minutes.
+_MAX_HOST_RECORDS = 16384
+# the watch thread sleeps ``_BEAT_S`` a turn and records a beat that wakes
+# more than ``_LATE_S`` after it was due
+_BEAT_S, _LATE_S = 0.010, 0.050
+WATCH_THREAD = "paddle_tpu.ledger_watch"
 
 
 def union_seconds(intervals):
@@ -218,22 +237,59 @@ class CompileLedger:
       device, ``estimated`` where the backend keeps no counters).  The peaks
       only rise, so two records say which stretch raised one.
 
-    ``compile_ledger()`` is the process's one; a test makes its own and
-    feeds it events by hand."""
+    ``host_records`` holds what the host did once training runs, in a ring
+    of its own (``between(t0, t1, host=True)``, ``window``):
 
-    def __init__(self, registry):
+    - ``kind`` ``call``: one ``StepTrainer.step`` / ``run_steps``, enter to
+      return (``call``): ``name`` (``<label>.step`` / ``.run_steps``),
+      ``thread``, ``cpu_s`` and ``thread_cpu_s`` (the process's and the
+      thread's CPU seconds across it: ``time.process_time``,
+      ``time.thread_time``) and, from the thread's second call on, ``gap_s``,
+      ``gap_cpu_s`` and ``gap_thread_cpu_s``: the same three across the
+      stretch since the previous call returned.  A turn (gap and call) of a
+      second of wall beside a second of CPU was the process's own work; with
+      none, it was blocked or descheduled;
+    - ``kind`` ``gc``: one collection of Python's collector (``on_gc``, a
+      ``gc.callbacks`` entry): ``generation``, ``collected``, ``thread``;
+    - ``kind`` ``stall``: a beat of the watch thread that woke more than
+      ``_LATE_S`` late (``beat``): ``t0`` when it was due, ``t1`` when it
+      ran, ``cpu_s`` the process's CPU seconds since the beat before,
+      ``gc`` whether a collection was open or closed in it, ``switches`` the
+      process's involuntary context switches and ``throttled_usec`` the
+      growth of its cgroup's throttled time across it (None where no
+      ``cpu.stat`` says).  With CPU: the process's own work (a thread that
+      kept the interpreter's lock); without CPU and with ``throttled_usec``:
+      the host's quota; without either: descheduled, or stopped.
+
+    ``compile_ledger()`` is the process's one, the only one with a watch
+    thread and a place in ``gc.callbacks``; a test makes its own and feeds
+    it events, collections and beats by hand."""
+
+    def __init__(self, registry, watch=False):
         self.registry = registry
         self.records = collections.deque(maxlen=_MAX_RECORDS)
         self.total_records = 0         # lifetime, survives the ring
+        # no lock: a collection can start inside any allocation, one made
+        # under ``_lock`` too, and its callback appends here.  An append and
+        # ``list(deque)`` are each one step under the interpreter's lock
+        self.host_records = collections.deque(maxlen=_MAX_HOST_RECORDS)
         self._lock = threading.Lock()
         self._local = threading.local()
         self._first_called = set()     # programs that had their first call
+        self._watch = watch            # start the thread at a first call
+        self._watching = False
+        self._annotation = None        # jax.profiler.TraceAnnotation
+        self._gc_open = None           # start of the collection under way
+        self._gc_closed = float("-inf")    # end of the last one
+        self._beat = None              # (due, cpu, switches, throttled)
+        self._cpu_stat = None          # (fd, key, units a microsecond)
 
     def _state(self):
         st = self._local
         if not hasattr(st, "stack"):
             st.stack = []              # open phases: [name, last program]
             st.pending = {}            # cache events awaiting their program
+            st.returned = None         # the last call's (t1, cpu, own cpu)
         return st
 
     def _append(self, record):
@@ -290,6 +346,8 @@ class CompileLedger:
         parent = st.stack[-1][0] if st.stack else None
         frame = [name, None]
         st.stack.append(frame)
+        if name == FIRST_CALL and self._watch:
+            self.start_watch()
         t0 = time.perf_counter()
         try:
             with _trace.span("setup." + name, **labels):
@@ -312,10 +370,173 @@ class CompileLedger:
             self.registry.histogram("monitor.setup.phase_ms",
                                     phase=name).observe((t1 - t0) * 1e3)
 
-    def between(self, t0, t1):
-        """The records that lie inside ``[t0, t1]``, oldest first."""
+    @contextlib.contextmanager
+    def call(self, name):
+        """One call of a trainer's program on this thread, as a ``call``
+        record: two reads of each of three clocks and one append.  A profile
+        shows it as a ``jax.profiler.TraceAnnotation`` of the same name (a
+        no-op while no profiler session is on), a monitor session's trace as
+        a span."""
+        if self._annotation is None:
+            import jax.profiler
+
+            self._annotation = jax.profiler.TraceAnnotation
+        st = self._state()
+        t0, cpu0, own0 = (time.perf_counter(), time.process_time(),
+                          time.thread_time())
+        try:
+            with self._annotation(name), _trace.span(name):
+                yield
+        finally:
+            t1, cpu1, own1 = (time.perf_counter(), time.process_time(),
+                              time.thread_time())
+            last, st.returned = st.returned, (t1, cpu1, own1)
+            gap = (None,) * 3 if last is None else (
+                t0 - last[0], cpu0 - last[1], own0 - last[2])
+            self.host_records.append({
+                "kind": "call", "name": name, "t0": t0, "t1": t1,
+                "thread": threading.current_thread().name,
+                "cpu_s": cpu1 - cpu0, "thread_cpu_s": own1 - own0,
+                "gap_s": gap[0], "gap_cpu_s": gap[1],
+                "gap_thread_cpu_s": gap[2]})
+
+    def on_gc(self, phase, info):
+        """The ``gc.callbacks`` entry: a ``gc`` record a collection.  Costs
+        nothing between collections."""
+        now = time.perf_counter()
+        if phase == "start":
+            self._gc_open = now
+        elif self._gc_open is not None:
+            t0, self._gc_open, self._gc_closed = self._gc_open, None, now
+            self.host_records.append({
+                "kind": "gc", "generation": info["generation"], "t0": t0,
+                "t1": now, "collected": info["collected"],
+                "thread": threading.current_thread().name})
+
+    def start_watch(self):
+        """Starts the watch thread, once: a daemon that calls ``beat``
+        every ``_BEAT_S``."""
         with self._lock:
-            return [r for r in self.records if r["t0"] >= t0 and r["t1"] <= t1]
+            if self._watching:
+                return
+            self._watching = True
+            self._cpu_stat = _open_cpu_stat()
+            threading.Thread(target=self._watch_loop, name=WATCH_THREAD,
+                             daemon=True).start()
+
+    def _watch_loop(self):
+        while True:
+            time.sleep(_BEAT_S)
+            self.beat(time.perf_counter())
+
+    def beat(self, now):
+        """One beat of the watch at ``now`` (``time.perf_counter()``): due
+        ``_BEAT_S`` after the beat before, and a ``stall`` record if it
+        comes more than ``_LATE_S`` after that.  A thread that slept 10 ms
+        and woke late was kept off the CPU or off the interpreter's lock,
+        and so was every other thread of the process, the one that feeds
+        the device among them.  Returns the record, or None."""
+        cpu = time.process_time()
+        switches = resource.getrusage(resource.RUSAGE_SELF).ru_nivcsw
+        throttled = self._throttled_usec()
+        last, self._beat = self._beat, (now + _BEAT_S, cpu, switches,
+                                        throttled)
+        if last is None or now - last[0] <= _LATE_S:
+            return None
+        record = {"kind": "stall", "t0": last[0], "t1": now,
+                  "cpu_s": cpu - last[1],
+                  "gc": self._gc_open is not None
+                  or self._gc_closed > last[0],
+                  "switches": switches - last[2],
+                  "throttled_usec": None if None in (throttled, last[3])
+                  else throttled - last[3],
+                  "thread": threading.current_thread().name}
+        self.host_records.append(record)
+        return record
+
+    def _throttled_usec(self):
+        """Microseconds the process's cgroup has been throttled for, or
+        None where no ``cpu.stat`` says."""
+        if self._cpu_stat is None:
+            return None
+        fd, key, per_usec = self._cpu_stat
+        try:
+            for line in os.pread(fd, 4096, 0).split(b"\n"):
+                if line.startswith(key):
+                    return int(line.split()[1]) / per_usec
+        except (OSError, ValueError, IndexError):
+            pass
+        return None
+
+    def between(self, t0, t1, host=False):
+        """The records that lie inside ``[t0, t1]``, oldest first: set-up's
+        (``records``) or, with ``host``, the ``call``, ``gc`` and ``stall``
+        records (``host_records``)."""
+        if host:
+            held = list(self.host_records)
+        else:
+            with self._lock:
+                held = list(self.records)
+        return [r for r in held if r["t0"] >= t0 and r["t1"] <= t1]
+
+    def window(self, t0, t1):
+        """What the host did in ``[t0, t1]``, a slow stretch of a job or a
+        benchmark's measured window, by the ledger's records:
+
+        - ``calls``, ``call_p50_s``, ``call_max_s``: the ``call`` records
+          inside it, the median and the longest of their durations (None
+          without one);
+        - ``compile_s``: the union of the ``trace``, ``lower`` and
+          ``backend`` records (a program traced, lowered, compiled or
+          loaded while training runs); ``gc_s``: of the ``gc`` records;
+          ``stall_s``: of the ``stall`` records OUTSIDE both (a beat is late
+          for the length of a collection, which keeps the interpreter's
+          lock, and is counted once, as the collection).  A record that
+          straddles an end counts for its part inside;
+        - ``turn``: the longest turn of a thread, from one call's return to
+          the next one's, as ``name``, ``t0``, ``t1``, ``wall_s`` and the
+          process's ``cpu_s`` and the thread's ``thread_cpu_s`` beside it
+          (None under two calls);
+        - ``longest``: per kind (``call``, ``gc``, ``stall``), its three
+          longest records that touch the stretch."""
+        def touching(records, kinds):
+            return [r for r in records if r["kind"] in kinds
+                    and r["t1"] > t0 and r["t0"] < t1]
+
+        def clipped(records):
+            return [(max(r["t0"], t0), min(r["t1"], t1)) for r in records]
+
+        def seconds(r):
+            return r["t1"] - r["t0"]
+
+        with self._lock:
+            records = list(self.records)
+        host = list(self.host_records)
+        of = {k: touching(host, (k,)) for k in ("call", "gc", "stall")}
+        calls = [r for r in of["call"] if r["t0"] >= t0 and r["t1"] <= t1]
+        busy = clipped(touching(records, _DURATION_KINDS.values()))
+        collecting = clipped(of["gc"])
+        turns = [r for r in calls if r["gap_s"] is not None
+                 and r["t0"] - r["gap_s"] >= t0]
+        turn = max(turns, key=lambda r: r["gap_s"] + seconds(r), default=None)
+        return {
+            "calls": len(calls),
+            "call_p50_s": statistics.median(map(seconds, calls))
+            if calls else None,
+            "call_max_s": max(map(seconds, calls), default=None),
+            "compile_s": union_seconds(busy),
+            "gc_s": union_seconds(collecting),
+            "stall_s": union_seconds(busy + collecting
+                                     + clipped(of["stall"]))
+            - union_seconds(busy + collecting),
+            "turn": turn and {
+                "name": turn["name"], "t0": turn["t0"] - turn["gap_s"],
+                "t1": turn["t1"], "wall_s": turn["gap_s"] + seconds(turn),
+                "cpu_s": turn["gap_cpu_s"] + turn["cpu_s"],
+                "thread_cpu_s": turn["gap_thread_cpu_s"]
+                + turn["thread_cpu_s"]},
+            "longest": {k: sorted(rs, key=seconds, reverse=True)[:3]
+                        for k, rs in of.items()}}
 
     def table(self, records=None):
         """Rows per program and parent phase, costliest first, of
@@ -348,6 +569,36 @@ class CompileLedger:
         return rows
 
 
+def _open_cpu_stat():
+    """``(fd, key, units a microsecond)`` of the ``cpu.stat`` of the
+    process's cgroup that counts its throttled time (``throttled_usec``
+    under cgroup v2, ``throttled_time`` in ns under v1), or None.  Kept open
+    for the life of the process: the watch reads it at every beat."""
+    paths = []
+    try:
+        with open("/proc/self/cgroup") as f:
+            for line in f:
+                _, controllers, path = line.strip().split(":", 2)
+                if not controllers:
+                    paths.append("/sys/fs/cgroup" + path)
+                elif "cpu" in controllers.split(","):
+                    paths.append("/sys/fs/cgroup/cpu" + path)
+    except (OSError, ValueError):
+        pass
+    for path in paths + ["/sys/fs/cgroup", "/sys/fs/cgroup/cpu"]:
+        try:
+            fd = os.open(os.path.join(path, "cpu.stat"), os.O_RDONLY)
+        except OSError:
+            continue
+        text = os.pread(fd, 4096, 0)
+        for key, per_usec in ((b"throttled_usec", 1), (b"throttled_time",
+                                                       1000)):
+            if key in text:
+                return fd, key, per_usec
+        os.close(fd)
+    return None
+
+
 def _bare(fun_name):
     """``jit(multi)`` (lowering's and the backend's name) -> ``multi`` (the
     trace's)."""
@@ -371,9 +622,10 @@ def compile_ledger():
             if _ledger is None:
                 import jax.monitoring
 
-                ledger = CompileLedger(default_registry())
+                ledger = CompileLedger(default_registry(), watch=True)
                 jax.monitoring.register_event_duration_secs_listener(
                     ledger.on_duration)
                 jax.monitoring.register_event_listener(ledger.on_event)
+                gc.callbacks.append(ledger.on_gc)
                 _ledger = ledger
     return _ledger

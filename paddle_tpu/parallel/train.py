@@ -243,16 +243,19 @@ class StepTrainer:
         is a function, and its test calls the function."""
 
     def step(self, batch, lr):
-        self._observe(batch)
-        if not self._step_seen:
-            # the call that traces, lowers and compiles or loads the program
-            program = self.label + ".step"
-            with compile_ledger().phase(FIRST_CALL, program=program):
-                self._step_seen = _devscope.register(
-                    program, self.step_fn, (self.state, batch, lr))
+        ledger, program = compile_ledger(), self.label + ".step"
+        if self._step_seen:
+            with ledger.call(program):
+                self._observe(batch)
                 self.state, loss = self.step_fn(self.state, batch, lr)
             return loss
-        self.state, loss = self.step_fn(self.state, batch, lr)
+        # the call that traces, lowers and compiles or loads the program (a
+        # probe that ``_observe`` compiles is not the phase's)
+        self._observe(batch)
+        with ledger.phase(FIRST_CALL, program=program), ledger.call(program):
+            self._step_seen = _devscope.register(
+                program, self.step_fn, (self.state, batch, lr))
+            self.state, loss = self.step_fn(self.state, batch, lr)
         return loss
 
     def run_steps(self, batches, lr):
@@ -261,18 +264,20 @@ class StepTrainer:
         axis, already staged via stack_batches.  Returns losses [N]."""
         if self.multi_fn is None:
             raise RuntimeError("trainer built without multi-step support")
-        self._observe(batches)
-        if not self._multi_seen:
-            program = self.label + ".run_steps"
-            with compile_ledger().phase(FIRST_CALL, program=program):
-                self._multi_seen = _devscope.register(
-                    program, self.multi_fn, (self.state, batches, lr))
-                # whoever staged them (``stack_batches``, or a caller's own
-                # program on the device): what a scan runs over is staged
-                _memscope.track_arrays("staged_batches", batches)
+        ledger, program = compile_ledger(), self.label + ".run_steps"
+        if self._multi_seen:
+            with ledger.call(program):
+                self._observe(batches)
                 self.state, losses = self.multi_fn(self.state, batches, lr)
             return losses
-        self.state, losses = self.multi_fn(self.state, batches, lr)
+        self._observe(batches)
+        with ledger.phase(FIRST_CALL, program=program), ledger.call(program):
+            self._multi_seen = _devscope.register(
+                program, self.multi_fn, (self.state, batches, lr))
+            # whoever staged them (``stack_batches``, or a caller's own
+            # program on the device): what a scan runs over is staged
+            _memscope.track_arrays("staged_batches", batches)
+            self.state, losses = self.multi_fn(self.state, batches, lr)
         return losses
 
 
