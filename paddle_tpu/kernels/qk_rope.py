@@ -22,6 +22,18 @@ lanes that carry no position, and any scale by position multiplied in.  With
 rotated ``n + shared``: a key ``[k_nope_i | 0] + [0 | kr]`` is assembled and
 rotated in the pass that reads it.
 
+A head may also be n WHOLE lane blocks (``head_dim`` 256: the latent form
+whose value is narrower than its head, ``transformer._latent_qkv_lanes``),
+with ``pairs`` or with no tables, and no norm: the tables are then ``[S, n *
+128]`` and ``shared`` ``[b, S, n * 128]``, a lane block of the HEAD each, and
+lane block i of a row takes block ``i % n`` of them (a pair's partner never
+leaves its lane block, so a block's body is the one-block head's).  Such a
+call is ``_call_touched``: x is ALIASED to the result and a grid step is ONE
+lane block, so the first ``plain_blocks`` lane blocks of every head, which
+carry no position and none of ``shared`` (``[q_nope 128 | ...]``), are not
+visited and never leave HBM (a full dots3 layer's q pass 270 µs so where the
+whole rows copied took 412: PERF.md section 6, PR 66).
+
 Why a kernel (PERF.md section 6, PR 47): the compiled text of one rotary
 layer at Trinity's shape moves 9.0 GB outside its matmuls and flash kernels
 where this work needs 0.45: XLA broadcasts ``cos`` and ``sin`` to float32
@@ -65,7 +77,7 @@ from ._common import (LANES, SUBLANES, CompilerParams as _CompilerParams,
                       sublane_tile as _tile)
 
 __all__ = ["qk_rope", "angle_tables", "pair_tables", "pair_streams",
-           "stream_angles", "supported",
+           "stream_angles", "supported", "head_blocks",
            "block_rows", "vmem_bytes"]
 
 ROW_BLOCKS = (256, 128, 64, 32, 16, 8)
@@ -98,11 +110,20 @@ def vmem_bytes(bs, W, itemsize, shared=False):
             + (4 * bs * LANES * itemsize if shared else 0))
 
 
+def head_blocks(head_dim):
+    """The lane blocks a head stands in: 1 for a head that divides one."""
+    return max(1, head_dim // LANES)
+
+
 def supported(shape, head_dim, itemsize):
     """Whether ``qk_rope`` takes a packed projection of this shape: W whole
-    lane blocks of whole heads (``head_dim`` 128 or a divisor of it), S in
-    whole sublane tiles, and a block of rows within BLOCK_VMEM."""
+    lane blocks of whole heads (``head_dim`` a divisor of 128, or whole lane
+    blocks), S in whole sublane tiles, and a block of rows within
+    BLOCK_VMEM."""
     _, S, W = shape
+    if head_dim > LANES:
+        return (head_dim % LANES == 0 and W % head_dim == 0
+                and touched_rows(S, itemsize) is not None)
     return (W % LANES == 0 and LANES % head_dim == 0 and head_dim % 2 == 0
             and block_rows(S, W, itemsize) is not None)
 
@@ -149,27 +170,31 @@ def angle_tables(S, head_dim, theta, first=0, positions=None, sections=()):
                      (1, heads)))
 
 
-def pair_tables(S, freqs, head_dim, first=0, factor=1.0, scale=None):
-    """(cos, signed sin) [S, 128] float32 of the adjacent-pair convention at
-    positions ``first``.. (``first`` may be traced): a head's LAST ``2 *
-    len(freqs)`` lanes are the pairs (2j, 2j + 1), turned by ``pos *
-    freqs[j]``, cosine and sine times ``factor``, the sine minus on a
-    pair's first lane (``transformer.rope_pairs``' sign); its lanes before
-    them carry no position, cosine 1 and sine 0.  ``scale`` [S] float32
-    multiplies a position's row of both tables."""
+def pair_tables(S, freqs, head_dim, first=0, factor=1.0, scale=None, tail=0):
+    """(cos, signed sin) float32 of the adjacent-pair convention at positions
+    ``first``.. (``first`` may be traced), [S, 128] or, for a head of whole
+    lane blocks, [S, head_dim]: ``2 * len(freqs)`` lanes of a head, its last
+    but for ``tail`` lanes behind them, are the pairs (2j, 2j + 1), turned
+    by ``pos * freqs[j]``, cosine and sine times ``factor``, the sine minus
+    on a pair's first lane (``transformer.rope_pairs``' sign); its lanes
+    before and behind them carry no position, cosine 1 and sine 0.
+    ``scale`` [S] float32 multiplies a position's row of both tables."""
     pos = jnp.arange(S, dtype=jnp.float32) + first
     ang = jnp.repeat(pos[:, None] * jnp.asarray(freqs, jnp.float32)[None], 2,
                      axis=1)
-    plain = head_dim - ang.shape[1]
+    plain = head_dim - ang.shape[1] - tail
     sign = jnp.where(jnp.arange(ang.shape[1]) % 2 == 0, -1.0, 1.0)
+    behind = lambda fill: [jnp.full((S, tail), fill, jnp.float32)] \
+        if tail else []
     cos = jnp.concatenate(
-        [jnp.ones((S, plain), jnp.float32), factor * jnp.cos(ang)], axis=1)
+        [jnp.ones((S, plain), jnp.float32), factor * jnp.cos(ang)]
+        + behind(1.0), axis=1)
     sin = jnp.concatenate(
-        [jnp.zeros((S, plain), jnp.float32), factor * sign * jnp.sin(ang)],
-        axis=1)
+        [jnp.zeros((S, plain), jnp.float32), factor * sign * jnp.sin(ang)]
+        + behind(0.0), axis=1)
     if scale is not None:
         cos, sin = cos * scale[:, None], sin * scale[:, None]
-    heads = LANES // head_dim
+    heads = LANES // head_dim or 1
     return jnp.tile(cos, (1, heads)), jnp.tile(sin, (1, heads))
 
 
@@ -371,26 +396,142 @@ def _call(kernel, name, rows, weight, tables, dh, norm, eps, interpret,
     )(*operands)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _qk_rope(x, weight, tables, shared, dh, norm, eps, interpret, pairs):
+# rows of a grid step's ONE lane block at a head of several: 256 KiB a block
+# in bf16, the step's overhead under its DMA
+TOUCHED_ROWS = (1024, 512) + ROW_BLOCKS
+
+
+def touched_rows(S, itemsize):
+    """Rows of a step's lane block at a head of several lane blocks."""
+    return next((bs for bs in TOUCHED_ROWS
+                 if bs % _tile(itemsize) == 0 and S % bs == 0), None)
+
+
+def touched_vmem_bytes(bs, itemsize):
+    """What such a call asks Mosaic for: the backward's blocks of dy, dx and
+    ``shared``'s gradient and the tables' two twice each, the float32 block
+    the gradient is summed in, six float32 temporaries, and room (the
+    compiled kernels take 1.8 to 4.6 MiB of the 9 asked at 1,024 rows of
+    bf16, ``tests/test_flash_tpu_compile.py``)."""
+    return 6 * bs * LANES * itemsize + (4 + 1 + 6) * bs * LANES * 4 \
+        + 2 * 2 ** 20
+
+
+def _touched_fwd(*refs, rotary, shared, pairs):
+    """One lane block: x, ``shared``'s block, the tables' blocks, the
+    result."""
+    (x_ref, *refs), o_ref = refs[:-1], refs[-1]
+    y = x_ref[...].astype(jnp.float32)
+    if shared:
+        y = y + refs.pop(0)[...].astype(jnp.float32)
+    if rotary:
+        y = _rotate(y, refs[0][...], refs[1][...], LANES, pairs)
+    o_ref[...] = y.astype(o_ref.dtype)
+
+
+def _touched_bwd(*refs, rotary, shared, pairs):
+    """One lane block: dy, the tables' blocks; dx and, with ``shared``, its
+    gradient's block and the float32 scratch block it is summed in."""
+    d = refs[0][...].astype(jnp.float32)
+    if rotary:      # the rotation's transpose, as ``_bwd_kernel``'s
+        d = d * refs[1][...] + _partner(d * refs[2][...], LANES, pairs)
+    if not shared:
+        refs[-1][...] = d.astype(refs[-1].dtype)
+        return
+    dx_ref, ds_ref, acc_ref = refs[-3:]
+    dx_ref[...] = d.astype(dx_ref.dtype)
+    head = pl.program_id(3)     # block j of every head took block j of it
+
+    @pl.when(head == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    acc_ref[...] += d
+
+    @pl.when(head == pl.num_programs(3) - 1)
+    def _():
+        ds_ref[...] = acc_ref[...].astype(ds_ref.dtype)
+
+
+def _call_touched(name, x, tables, dh, interpret, pairs, shared, plain,
+                  backward=False):
+    """A head of n lane blocks whose first ``plain`` pass as they are: x is
+    ALIASED to the result and the grid visits the other blocks alone, (rows,
+    batch, touched block of a head, head) with ONE lane block a step, so the
+    plain blocks never leave HBM.  The head is the inner axis: a block of
+    the tables and of ``shared`` is fetched once for all heads, and the
+    backward sums ``shared``'s gradient (a result [b, S, (n - plain) * 128],
+    float32 in a scratch block) over it."""
+    b, S, W = x.shape
+    n = head_blocks(dh)
+    bs = touched_rows(S, x.dtype.itemsize)
+    has_shared = shared is not None
+    column = pl.BlockSpec((None, bs, LANES),
+                          lambda si, bi, j, h: (bi, si, h * n + plain + j))
+    operands, specs = [x], [column]
+    if has_shared and not backward:
+        operands.append(shared)
+        specs.append(pl.BlockSpec((None, bs, LANES),
+                                  lambda si, bi, j, h: (bi, si, plain + j)))
+    if tables is not None:
+        operands += list(tables)
+        specs += [pl.BlockSpec((bs, LANES),
+                               lambda si, bi, j, h: (si, plain + j))] * 2
+    out_shape, out_specs = [jax.ShapeDtypeStruct(x.shape, x.dtype)], [column]
+    summed = backward and has_shared
+    if summed:
+        out_shape.append(jax.ShapeDtypeStruct(
+            (b, S, (n - plain) * LANES), x.dtype))
+        out_specs.append(pl.BlockSpec((None, bs, LANES),
+                                      lambda si, bi, j, h: (bi, si, j)))
+    return pl.pallas_call(
+        functools.partial(_touched_bwd if backward else _touched_fwd,
+                          rotary=tables is not None, shared=has_shared,
+                          pairs=pairs),
+        grid=(S // bs, b, n - plain, W // (n * LANES)), in_specs=specs,
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((bs, LANES), jnp.float32)] if summed
+        else [],
+        compiler_params=_CompilerParams(
+            dimension_semantics=("parallel",) * 3 + (
+                "arbitrary" if summed else "parallel",),
+            vmem_limit_bytes=touched_vmem_bytes(bs, x.dtype.itemsize)),
+        input_output_aliases={0: 0}, interpret=interpret, name=name,
+    )(*operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _qk_rope(x, weight, tables, shared, dh, norm, eps, interpret, pairs,
+             plain):
+    if head_blocks(dh) > 1:
+        return _call_touched("qk_rope_fwd", x, tables, dh, interpret, pairs,
+                             shared, plain)[0]
     return _call(_fwd_kernel, "qk_rope_fwd", [x], weight, tables, dh, norm,
                  eps, interpret, pairs, shared)[0]
 
 
-def _qk_rope_fwd(x, weight, tables, shared, dh, norm, eps, interpret, pairs):
+def _qk_rope_fwd(x, weight, tables, shared, dh, norm, eps, interpret, pairs,
+                 plain):
     # the RAW projection is the residual, and only where a norm reads it; of
     # ``shared`` its (empty) place in the tree says whether it was there
     return (_qk_rope(x, weight, tables, shared, dh, norm, eps, interpret,
-                     pairs),
+                     pairs, plain),
             (x if norm else None, weight, tables,
              None if shared is None else ()))
 
 
-def _qk_rope_bwd(dh, norm, eps, interpret, pairs, res, dy):
+def _qk_rope_bwd(dh, norm, eps, interpret, pairs, plain, res, dy):
     x, weight, tables, shared = res
-    out = _call(_bwd_kernel, "qk_rope_bwd", [x, dy] if norm else [dy],
-                weight, tables, dh, norm, eps, interpret, pairs, shared,
-                backward=True)
+    if head_blocks(dh) > 1:
+        out = _call_touched("qk_rope_bwd", dy, tables, dh, interpret, pairs,
+                            shared, plain, backward=True)
+        if shared is not None and plain:    # nothing of it in those blocks
+            out = (out[0], jnp.pad(out[1], (
+                (0, 0), (0, 0), (plain * LANES, 0))))
+    else:
+        out = _call(_bwd_kernel, "qk_rope_bwd", [x, dy] if norm else [dy],
+                    weight, tables, dh, norm, eps, interpret, pairs, shared,
+                    backward=True)
     # the angles' tables hang on positions alone: no cotangent
     return (out[0], jnp.sum(out[1], axis=0) if norm else None,
             jax.tree.map(jnp.zeros_like, tables),
@@ -401,13 +542,17 @@ _qk_rope.defvjp(_qk_rope_fwd, _qk_rope_bwd)
 
 
 def qk_rope(x, weight=None, tables=None, *, head_dim, norm=None, eps=1e-5,
-            pairs=False, shared=None, interpret=None):
+            pairs=False, shared=None, plain_blocks=0, interpret=None):
     """``x`` [b, S, W] packed heads of ``head_dim``; ``norm`` "head" (RMS
     norm of each head, ``weight`` [head_dim]), "whole" (of the projection,
     ``weight`` [W]) or None; ``tables`` = ``angle_tables(S, head_dim, theta,
     first)`` for rotary positions, or None; with ``pairs`` the rotation is of
     adjacent pairs and ``tables`` = ``pair_tables(...)``; ``shared`` [b, S,
     128] is added to every lane block before the rotation (no norm with it).
+    A head of n whole lane blocks (``pairs`` or no tables, no norm): tables
+    [S, n * 128], ``shared`` [b, S, n * 128], and the first ``plain_blocks``
+    lane blocks of every head, where the tables turn nothing and ``shared``
+    holds nothing, stay where they are (x aliased to the result).
     ``supported(x.shape, head_dim, itemsize)`` must hold.  Float32 inside,
     rounded once to ``x.dtype``."""
     if not supported(x.shape, head_dim, x.dtype.itemsize):
@@ -415,6 +560,12 @@ def qk_rope(x, weight=None, tables=None, *, head_dim, norm=None, eps=1e-5,
                          % (x.shape, head_dim))
     if shared is not None and norm:
         raise ValueError("qk_rope: a shared lane block goes with no norm")
+    if head_dim > LANES and (norm or not (pairs or tables is None)):
+        raise ValueError("qk_rope: a head of several lane blocks goes with "
+                         "no norm and the pairs' rotation")
+    if not 0 <= plain_blocks < head_blocks(head_dim):
+        raise ValueError("qk_rope: %d plain blocks of a head's %d"
+                         % (plain_blocks, head_blocks(head_dim)))
     if interpret is None:
         interpret = not _on_tpu()
     if norm == "head":          # one weight for every head of a lane block
@@ -424,4 +575,5 @@ def qk_rope(x, weight=None, tables=None, *, head_dim, norm=None, eps=1e-5,
     if shared is not None:
         shared = shared.astype(x.dtype)
     return _qk_rope(x, weight, tables, shared, head_dim, norm or None,
-                    float(eps), bool(interpret), bool(pairs))
+                    float(eps), bool(interpret), bool(pairs),
+                    int(plain_blocks))

@@ -1549,11 +1549,29 @@ def _latent_qkv_lanes(pl, h, cfg, rotary=False):
     ones (``cfg.heads_here``).  Packed q and k [b, S, H * lanes], zeros
     behind a head's ``head_dim`` columns (the zero columns of ``wq`` and of
     the keys' matrix: no activation is padded or cut across lanes), and v
-    [b, S, H * dv]."""
+    [b, S, H * dv].
+
+    Where the row kernel takes the shape (``kernels/qk_rope.py`` at a head of
+    whole lane blocks, its ``pairs`` convention), ONE pass over q, where
+    ``rotary``, and one over k: the kernel adds ``ks`` into its lanes of
+    every head and rotates them as it reads the keys' matmul, a head's
+    leading lane blocks that hold neither left where they are, and the
+    projections' dW are made beside their dX (``_project``).  Elsewhere the ``rope_pairs``
+    lines and the broadcast add, which the tests hold that kernel to.  Under
+    a monitor session k, and q where ``rotary``, of a traced call count in
+    ``monitor.kernels.qk_rope_calls`` (``fused`` 1 for the kernel)."""
+    from ..kernels import qk_rope
+    from ..kernels._common import count_call
+
     b, S, _ = h.shape
     H, dn, dr = cfg.heads_here, cfg.qk_nope_dim, cfg.qk_rope_dim
     lanes = _latent_head_lanes(cfg)
     tail = lanes - dn - dr
+    fused = dr % 2 == 0 and lanes % qk_rope.LANES == 0 and qk_rope.supported(
+        (b, S, H * lanes), lanes, h.dtype.itemsize)
+    for _ in "qk" if rotary else "k":
+        count_call("qk_rope", dh=lanes, norm="none", rotary=int(rotary),
+                   convention="pairs", fused=int(fused))
     rows, wq = (_rms(h @ pl["wq_a"],
                      _latent_norm_scale(pl, "q_a_norm", cfg, cfg.q_lora_rank),
                      cfg.norm_eps),
@@ -1566,11 +1584,26 @@ def _latent_qkv_lanes(pl, h, cfg, rotary=False):
                                        cfg.kv_lora_rank), cfg.norm_eps)
     w = pl["wkv_b"].reshape(-1, H, dn + cfg.v_head_dim)
     k_columns = jnp.pad(w[..., :dn], ((0, 0), (0, 0), (0, dr + tail)))
+    v = ckv @ w[..., dn:].reshape(w.shape[0], -1)
     if rotary:
         assert dn % 2 == 0 and tail % 2 == 0, (dn, tail)
         f32 = jnp.float32
+        freqs = yarn_frequencies(cfg)
+    if fused:
+        rotate = functools.partial(qk_rope.qk_rope, head_dim=lanes,
+                                   pairs=True,
+                                   plain_blocks=dn // qk_rope.LANES)
+        tables = qk_rope.pair_tables(S, freqs, lanes, tail=tail) \
+            if rotary else None
+        # the ONE shared key at its lanes of a head: added into the zeros
+        # the keys' matrix leaves there and rotated in the pass that reads k
+        k = rotate(_project(ckv, k_columns.reshape(w.shape[0], -1)), None,
+                   tables, shared=jnp.pad(ks, ((0, 0), (0, 0), (dn, tail))))
+        q = _project(rows, wq)
+        return rotate(q, None, tables) if rotary else q, k, v
+    if rotary:
         ang = jnp.arange(S, dtype=f32)[:, None] \
-            * jnp.asarray(yarn_frequencies(cfg), f32)[None]     # [S, dr / 2]
+            * jnp.asarray(freqs, f32)[None]                     # [S, dr / 2]
         ks = rope_pairs(ks.astype(f32), ang).astype(h.dtype)
     # the ONE shared key in lanes [dn, dn + dr) of every head: added into
     # the zeros the keys' matrix leaves there
@@ -1581,8 +1614,7 @@ def _latent_qkv_lanes(pl, h, cfg, rotary=False):
         # angle 0 turns nothing: the lanes before and behind the rotated
         q = rope_pairs(q.astype(f32), jnp.pad(
             ang, ((0, 0), (dn // 2, tail // 2))), tiles=H).astype(h.dtype)
-    return (q, k.reshape(b, S, -1),
-            ckv @ w[..., dn:].reshape(w.shape[0], -1))
+    return q, k.reshape(b, S, -1), v
 
 
 def _latent_fused(cfg, rows, itemsize):

@@ -35,10 +35,13 @@ as zero gradients, broadcasts of their whole size that no step makes (0.51
 GB of Trinity's "other" before PR 55).
 
 ``--kind i`` takes the i-th layer kind of the period (default: the first with
-rotary positions, or retention, a Mamba kind or KDA); ``--tiny`` takes the
-model's tiny configuration at S = 256 (the smoke test's). This is the reading
-ISSUEs 47, 49, 53, 55 and 60 were sized by (PERF.md section 6). Bytes over
-819 GB/s are a LEAST time, not a time: a time comes from the chip."""
+rotary positions, or retention, a Mamba kind or KDA; a position with a shape
+of its own, an ``AttentionShape``, runs as the block runs it: through
+``cfg.position(kind)``, the learned-sparse branch where it has an indexer);
+``--tiny`` takes the model's tiny configuration at S = 256 (the smoke test's).
+This is the reading ISSUEs 47, 49, 53, 55 and 60 were sized by, and PR 66
+after the fact (PERF.md section 6). Bytes over 819 GB/s are a LEAST time, not
+a time: a time comes from the chip."""
 
 import argparse
 import collections
@@ -290,7 +293,12 @@ def branch_of(cfg, kind):
             return T.mamba2_mixer(pl, h, cfg)
         if kind == T.KDA:
             return T.kda_mixer(pl, h, cfg)
-        return T._attention_heads_mode(pl, h, cfg, kind)
+        # the configuration as this position reads it (an ``AttentionShape``
+        # has its own heads, ranks, widths, window, indexer or none)
+        at, plain = cfg.position(kind)
+        if at.indexer_heads:    # the branch alone: the loss term apart
+            return T._sparse_attention(pl, h, at, plain[1], None)[0]
+        return T._attention_heads_mode(pl, h, at, plain)
 
     return branch
 
@@ -353,7 +361,7 @@ def default_kind(cfg):
     kinds = cfg.layer_kinds
     return next(k for k in kinds
                 if k in (T.RETENTION, T.MAMBA, T.MAMBA2, T.KDA)
-                or (isinstance(k, tuple) and k[1]))
+                or (T._is_attention(k) and cfg.position(k)[1][1]))
 
 
 def main(argv=None):

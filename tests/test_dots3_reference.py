@@ -140,3 +140,74 @@ def test_the_selected_sets_are_the_reference_s(case):
                           np.broadcast_to(np.arange(1, TOPK + 1), (B, TOPK)))
     assert np.all(kept[:, TOPK:] >= TOPK)
     assert np.mean(kept[:, TOPK:] == TOPK) > 0.9
+
+
+def _counted(tmp_path, trace):
+    """{(dh, convention, rotary, fused): calls} that ``trace()`` counts in
+    ``monitor.kernels.qk_rope_calls`` under a monitor session."""
+    from paddle_tpu import monitor
+
+    mon = monitor.enable(str(tmp_path), flight=False)
+    try:
+        mon.registry.reset()
+        trace()
+        return {tuple(r["labels"][k] for k in (
+            "dh", "convention", "rotary", "fused")): r["value"]
+                for r in mon.registry.snapshot()
+                if r["name"] == "monitor.kernels.qk_rope_calls"}
+    finally:
+        monitor.disable()
+
+
+def test_every_latent_layer_counts_both_projections_fused(case, tmp_path):
+    """Both shapes' q and k go through ``kernels/qk_rope.py`` at a head of
+    two lane blocks (``_latent_qkv_lanes``; the indexer's queries and key
+    through the same kernel at a head of one), counted ``fused`` 1."""
+    params, ids, _, _ = case
+    counted = _counted(tmp_path, lambda: jax.eval_shape(
+        lambda p: decoder.forward(p, ids, CFG)[0], params))
+    assert set(counted) == {(256, "pairs", 1, 1), (128, "half", 1, 1)}
+    # layer 0, the period's full layer and its run of sliding ones: q and k
+    assert counted[(256, "pairs", 1, 1)] == 3 * 2
+
+
+@pytest.mark.parametrize("layer", ["full", "sliding"])
+def test_the_row_kernel_s_q_k_v_and_gradients_equal_the_lines(
+        case, monkeypatch, layer):
+    """A tiny layer of each shape takes the row kernel; with the kernel
+    refused the same call runs the ``rope_pairs`` lines and the broadcast
+    add: the same q, k, v and the same gradients of the chain's leaves and
+    of the rows."""
+    from paddle_tpu.kernels import qk_rope
+
+    params = case[0]
+    pl, kind = (params["prefix_layers"]["l0"], CFG.prefix_kinds[0]) \
+        if layer == "full" else (jax.tree.map(
+            lambda a: a[0, 0], params["params_layers"]["r1"]),
+            CFG.layer_kinds[1])
+    at, (_, rotary) = CFG.position(kind)
+    lanes, H = 256, at.heads_here
+    assert rotary and T._latent_head_lanes(at) == lanes
+    h = jax.random.normal(jax.random.PRNGKey(8), (2, S, CFG.hidden))
+    w = [jax.random.normal(jax.random.PRNGKey(9 + i), (2, S, H * n))
+         for i, n in enumerate((lanes, lanes, 128))]
+
+    def run(pl, h):
+        out = T._qkv(pl, h, at, rotary)
+        return sum(jnp.sum(a * b) for a, b in zip(out, w)), out
+
+    assert qk_rope.supported((2, S, H * lanes), lanes, 4)
+    (_, got), got_grads = jax.value_and_grad(run, (0, 1), has_aux=True)(pl, h)
+    monkeypatch.setattr(qk_rope, "supported", lambda *a: False)
+    (_, want), want_grads = jax.value_and_grad(run, (0, 1), has_aux=True)(
+        pl, h)
+    for a, r in zip(got, want):
+        assert a.shape == r.shape
+        np.testing.assert_allclose(a, r, rtol=1e-5, atol=1e-5)
+    for name in ("wq_a", "wq_b", "wkv_a", "wkv_b", "q_a_norm", "kv_a_norm"):
+        a, r = got_grads[0][name], want_grads[0][name]
+        assert a.shape == pl[name].shape and np.abs(r).max() > 0
+        np.testing.assert_allclose(a, r, rtol=1e-4,
+                                   atol=1e-5 * np.abs(r).max(), err_msg=name)
+    np.testing.assert_allclose(got_grads[1], want_grads[1], rtol=1e-4,
+                               atol=1e-4)

@@ -5,6 +5,7 @@ such compiles live in this one file and describe the chip inside a fixture,
 so that only the worker that is given the file loads the TPU's library."""
 
 import base64
+import functools
 import hashlib
 import importlib
 import json
@@ -728,6 +729,67 @@ def test_the_latent_q_and_k_passes_compile_for_a_v5e(one_chip, S, traced):
             assert took < asked, (kernel, shared, took, asked)
 
 
+# cell: positions, held heads, (plain, rotated, tail) lanes of a head of 256,
+# rotary
+WIDE_HEAD_CELLS = {
+    "dots3_note_prev.s8192_scan, a full layer":
+        (8192, 32, (128, 64, 64), True),
+    "dots3_note_prev.s8192_scan, a sliding layer":
+        (8192, 16, (192, 64, 0), True),
+    "kimi_linear_48b_a3b.s16384_scan, the latent layer's k":
+        (16384, 32, (128, 64, 64), False),
+    # no cell's: a head whose rotated lanes lie across both lane blocks, so
+    # that both are visited (the same kernels, the grid's third axis 2 long,
+    # the shared key's gradient two lane blocks wide)
+    "a head of 96 + 64 in 256 lanes": (8192, 16, (96, 64, 96), True),
+}
+
+
+@pytest.mark.parametrize("what", WIDE_HEAD_CELLS)
+def test_the_row_kernel_at_a_head_of_two_lane_blocks_compiles_for_a_v5e(
+        one_chip, what):
+    """``kernels/qk_rope.py`` at a head of TWO lane blocks, bf16, forward and
+    backward, at dots3's two shapes (q rotated; k the padded heads plus the
+    shared key, rotated) and at Kimi-Linear's k (the shared key added, no
+    tables): a head's first lane block, which holds nothing rotated and
+    nothing shared, never moved (x aliased to the result, ONE lane block of
+    1,024 rows a grid step, the shared key's gradient summed over the heads
+    in a scratch block).  A call asks for ``touched_vmem_bytes`` and takes
+    less."""
+    qr = importlib.import_module("paddle_tpu.kernels.qk_rope")
+    S, heads, (plain, dr, tail), rotary = WIDE_HEAD_CELLS[what]
+    lanes = plain + dr + tail
+    W = heads * lanes
+    freqs = [1e4 ** (-2 * j / dr) for j in range(dr // 2)]
+    x = jax.ShapeDtypeStruct((1, S, W), jnp.bfloat16, sharding=one_chip)
+    ks = jax.ShapeDtypeStruct((1, S, lanes), jnp.bfloat16, sharding=one_chip)
+    assert qr.supported(x.shape, lanes, 2) and qr.touched_rows(S, 2) == 1024
+    rotate = functools.partial(qr.qk_rope, head_dim=lanes, pairs=True,
+                               plain_blocks=plain // 128, interpret=False)
+    tables = lambda: qr.pair_tables(S, freqs, lanes, tail=tail) \
+        if rotary else None
+
+    def q_pass(x, g):
+        out, vjp = jax.vjp(lambda x: rotate(x, None, tables()), x)
+        return (out,) + vjp(g)
+
+    def k_pass(x, ks, g):
+        out, vjp = jax.vjp(lambda x, ks: rotate(x, None, tables(),
+                                                shared=ks), x, ks)
+        return (out,) + vjp(g)
+
+    for fn, args, shared in ((q_pass, (x, x), False), (k_pass, (x, ks, x),
+                                                       True)):
+        if not (rotary or shared):
+            continue        # a q without positions makes no call
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        for kernel in ("qk_rope_fwd", "qk_rope_bwd"):
+            asked, took = _vmem(text, kernel)
+            assert asked == qr.touched_vmem_bytes(1024, 2) == 9 * 2 ** 20
+            # a lane block a step takes 1.8 to 4.6 MiB
+            assert took < 5 * 2 ** 20, (what, kernel, shared, took, asked)
+
+
 def test_the_latent_layer_s_text_cuts_no_activation_across_lanes(one_chip):
     """Mistral-Small-4's latent layer, recompute + backward, through
     ``scripts/attn_outside_hlo.py`` (the no-chip reading ISSUE 57 was sized
@@ -757,6 +819,54 @@ def test_the_latent_layer_s_text_cuts_no_activation_across_lanes(one_chip):
     assert not cut, cut[:9]
     assert not [o for o in others if o[0] > 140e6 and o[3].startswith("f32")]
     assert groups["other"] < 3e9 and groups["matmul"] > 3e9
+
+
+@pytest.mark.parametrize("layer", ["full", "sliding"])
+def test_dots3_s_latent_layers_hold_no_float32_heads_outside_the_kernels(
+        one_chip, layer):
+    """dots3-note-prev's two attention shapes at the cell's size, recompute
+    + backward, through ``scripts/attn_outside_hlo.py`` (which reads an
+    ``AttentionShape`` position through ``cfg.position``, the learned-sparse
+    branch where it has an indexer): both row kernels are in the text, and
+    outside the matmuls and kernels no float32 array of q's size and no
+    ``[.., H, 256]`` view of the keys is left (the parent's ``rope_pairs``
+    lines and broadcast add moved 13.4 GB a full layer beside the counting
+    select's 8.6 and 7.2 a sliding one by the same count; this tree 4.9 and
+    2.6: the head-wise gate's float32 ``[8192, H x 128]``, the latents, the
+    hidden state transposed for the down projections' dW)."""
+    hlo = _script("attn_outside_hlo")
+    cfg, batch, seq = hlo.cell_config("dots3_note_prev.s8192_scan",
+                                      tiny=False)
+    kind = cfg.layer_kinds[layer == "sliding"]
+    assert kind == (hlo.default_kind(cfg) if layer == "full"
+                    else cfg.layer_kinds[-1]) and (batch, seq) == (1, 8192)
+    heads = cfg.position(kind)[0].heads_here
+    assert heads == {"full": 32, "sliding": 16}[layer]
+    groups, by_kernel, others = hlo.account(
+        hlo.compiled_text(cfg, batch, seq, kind))
+    flash = {"full": {"indexer_scores_fwd", "indexer_scores_bwd",
+                      "flash_dsa_fwd", "dsa_attend_kl_fwd", "flash_delta",
+                      "flash_dsa_bwd_fused"},
+             "sliding": {"flash_swa_fwd", "flash_delta",
+                         "flash_swa_bwd_fused"}}[layer]
+    assert flash | {"qk_rope_fwd", "qk_rope_bwd"} == set(by_kernel)
+    # q and k each way by the calls' operands and results (aliased: of
+    # each the kernel MOVES a head's second lane block alone, half of it),
+    # with the tables and the shared key a HEAD's lanes wide, its gradient
+    # the one touched lane block; the full layer's indexer rotates its
+    # queries and key by the same kernels
+    W = heads * 256
+    indexer = 2 * seq * (64 + 1) * 128 * 2 + 4 * seq * 128 * 4 \
+        if layer == "full" else 0
+    assert by_kernel["qk_rope_fwd"] == by_kernel["qk_rope_bwd"] \
+        + seq * 128 * 2 \
+        == 4 * seq * W * 2 + seq * 256 * 2 + 4 * seq * 256 * 4 + indexer
+    # the activations' (a weight [rank, H, 256] is padded once a layer)
+    wide = r"\[(1,)?8192,%d,256\]|\[1024,8,%d,256\]|f32\[(1,)?8192,%d\]" % (
+        heads, heads, W)
+    assert not [o for o in others if re.search(wide, o[3])], others[:9]
+    select = sum(o[0] for o in others if "convert_reduce" in o[1])
+    assert groups["other"] - select < {"full": 5.5e9, "sliding": 3e9}[layer]
 
 
 def test_a_rotary_layer_s_text_holds_no_float32_heads_outside_the_kernels(

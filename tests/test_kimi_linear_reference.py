@@ -265,6 +265,53 @@ def test_the_shared_key_is_one_unrotated_vector_for_all_heads():
                                kv[..., 128:], rtol=1e-5, atol=1e-6)
 
 
+def test_the_key_s_assembly_takes_the_row_kernel_and_equals_the_line(
+        tmp_path, monkeypatch):
+    """The tiny latent layer's k goes through ``kernels/qk_rope.py`` at a
+    head of two lane blocks with the shared key and no tables (q is a plain
+    matmul: no call), counted ``fused`` 1; with the kernel refused the
+    broadcast add through the [b, S, H, 256] view gives the same q, k, v and
+    the same gradients of the chain's leaves and of the rows."""
+    from paddle_tpu.kernels import qk_rope
+
+    cfg, pl, h = _latent_layer()
+    w = [jax.random.normal(jax.random.PRNGKey(9 + i), (B, S, 2 * n))
+         for i, n in enumerate((256, 256, 128))]
+
+    def run(pl, h):
+        out = T._qkv(pl, h, cfg, False)
+        return sum(jnp.sum(a * b) for a, b in zip(out, w)), out
+
+    def counted():
+        mon = monitor.enable(str(tmp_path), flight=False)
+        try:
+            mon.registry.reset()
+            jax.eval_shape(lambda pl, h: run(pl, h)[0], pl, h)
+            return {tuple(r["labels"][k] for k in (
+                "dh", "convention", "rotary", "fused")): r["value"]
+                    for r in mon.registry.snapshot()
+                    if r["name"] == "monitor.kernels.qk_rope_calls"}
+        finally:
+            monitor.disable()
+
+    assert qk_rope.supported((B, S, 512), 256, 4)
+    assert counted() == {(256, "pairs", 0, 1): 1}
+    (_, got), got_grads = jax.value_and_grad(run, (0, 1), has_aux=True)(pl, h)
+    monkeypatch.setattr(qk_rope, "supported", lambda *a: False)
+    assert counted() == {(256, "pairs", 0, 0): 1}
+    (_, want), want_grads = jax.value_and_grad(run, (0, 1), has_aux=True)(
+        pl, h)
+    for a, r in zip(got, want):
+        np.testing.assert_allclose(a, r, rtol=1e-5, atol=1e-5)
+    for name in ("wq", "wkv_a", "wkv_b", "kv_a_norm"):
+        a, r = got_grads[0][name], want_grads[0][name]
+        assert a.shape == pl[name].shape and np.abs(r).max() > 0
+        np.testing.assert_allclose(a, r, rtol=1e-4,
+                                   atol=1e-5 * np.abs(r).max(), err_msg=name)
+    np.testing.assert_allclose(got_grads[1], want_grads[1], rtol=1e-4,
+                               atol=1e-4)
+
+
 def _plain(q, k, v, heads, scale):
     b, s, _ = q.shape
     q, k, v = (a.reshape(b, s, heads, -1) for a in (q, k, v))

@@ -88,7 +88,12 @@ from paddle_tpu.parallel.train import stack_batches  # noqa: E402
 # sweeps are kernels of ``kernels/indexer.py``, a (tile, key/value head) a
 # grid step, the forward's only output the statistic, and ``dsa_lse`` reads
 # no value); the twenty-four others stand: the ``mask=`` mode left
-# ``kernels/flash_attention.py`` and took nothing of theirs with it.
+# ``kernels/flash_attention.py`` and took nothing of theirs with it.  PR 66
+# took dots3's two and Kimi-Linear's two anew ON PURPOSE (``_latent_qkv_lanes``
+# sends q, where rotated, and k through ``kernels/qk_rope.py`` at a head of
+# two lane blocks, the projections through ``_project``); the twenty-four
+# others stand: the row kernel at a head of one lane block traces the
+# operations it did (Mistral's ``pairs`` calls and the rotate-half ones).
 PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "bert.run_steps": "00de5403506fdc87",
             "olmoe.step": "231114fcd62341f2",
@@ -111,12 +116,12 @@ PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "ouro.run_steps": "64aaa9a06b2f9fbd",
             "resnet.step": "350db1fba0d68284",
             "resnet.run_steps": "dc9dd853700f9ab9",
-            "kimi_linear.step": "00fafaa79be69c29",
-            "kimi_linear.run_steps": "768fb807ebe118b1",
+            "kimi_linear.step": "5a34b11e1dde7e94",
+            "kimi_linear.run_steps": "e5f0e15d922e959c",
             "keye_vl2.step": "4fdc203023887275",
             "keye_vl2.run_steps": "b3dfb8265faa6541",
-            "dots3.step": "925774ee831b76df",
-            "dots3.run_steps": "dc10deb567ff9f61"}
+            "dots3.step": "d7d1e352464fcf9e",
+            "dots3.run_steps": "73b06e01bdc7bd2d"}
 OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
          "olmoe": (olmoe.build_olmoe_trainer, olmoe.olmoe_tiny_config, 32),
          "smallthinker": (smallthinker.build_smallthinker_trainer,

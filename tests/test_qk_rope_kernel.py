@@ -6,6 +6,8 @@ taking the kernel where the shapes allow and those lines where not; its
 against ``rope_pairs``, the scale and the concatenate-and-broadcast lines of
 ``_latent_qkv``."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -269,6 +271,129 @@ def test_the_keys_assembly_equals_concatenate_and_broadcast(dn, dr, heads, b,
         <= 1.001 * np.abs(f32(want[2]) - f32(exact[2])).max() + 1e-6
 
 
+# a head of TWO lane blocks: (plain, rotated, tail) lanes of a head, dots3's
+# full layer [q_nope 128 | q_rope 64 | zero 64] and its sliding one [192 | 64]
+# (a head's first lane block never moved: the grid visits the second alone),
+# and a head whose rotated lanes lie across both (no plain block: both visited,
+# the shared key's gradient two lane blocks wide)
+WIDE = [(128, 64, 64), (192, 64, 0), (96, 64, 96)]
+
+
+def wide_reference(x, ks, heads, plain, dr, tail, rotary):
+    """``_latent_qkv_lanes``' lines on q (``ks`` None) or on k: the shared
+    key rotated apart and rounded, padded to the head's lanes and added
+    through the [b, S, H, lanes] view; q turned on the flat array by angles
+    that are zero off a head's rotated lanes."""
+    b, S, W = x.shape
+    lanes, f32 = plain + dr + tail, jnp.float32
+    ang = (jnp.arange(S, dtype=f32) + 0)[:, None] \
+        * jnp.asarray(FREQS[dr], f32)[None]
+    if ks is None:
+        return T.rope_pairs(x.astype(f32), jnp.pad(
+            ang, ((0, 0), (plain // 2, tail // 2))), tiles=heads).astype(
+                x.dtype)
+    if rotary:
+        ks = T.rope_pairs(ks.astype(f32), ang).astype(x.dtype)
+    return (_padded(x, heads, lanes).reshape(b, S, heads, lanes) + jnp.pad(
+        ks, ((0, 0), (0, 0), (plain, tail)))[:, :, None, :]).reshape(
+            b, S, -1)
+
+
+def _padded(k_nope, heads, lanes):
+    """What the matmul by the keys' zero-padded columns gives: every head's
+    own lanes, zeros behind them."""
+    b, S, W = k_nope.shape
+    return jnp.pad(k_nope.reshape(b, S, heads, -1), (
+        (0, 0), (0, 0), (0, 0), (0, lanes - W // heads))).reshape(b, S, -1)
+
+
+def wide_kernel(x, ks, heads, plain, dr, tail, rotary):
+    S, lanes = x.shape[1], plain + dr + tail
+    tables = K.pair_tables(S, FREQS[dr], lanes, tail=tail) if rotary else None
+    rotate = functools.partial(K.qk_rope, head_dim=lanes, pairs=True,
+                               plain_blocks=plain // 128)
+    if ks is None:
+        return rotate(x, None, tables)
+    return rotate(_padded(x, heads, lanes), None, tables,
+                  shared=jnp.pad(ks, ((0, 0), (0, 0), (plain, tail))))
+
+
+def _wide_operands(b, S, heads, plain, dr, tail, dtype, shared):
+    """q [b, S, heads * lanes] with zeros where the zero columns of ``wq``
+    leave them, or the keys' own lanes [b, S, heads * plain]; the shared key
+    and a cotangent [b, S, heads * lanes]."""
+    lanes = plain + dr + tail
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    x = 2 * jax.random.normal(keys[0], (b, S, heads, lanes)) \
+        * (jnp.arange(lanes) < plain + dr)
+    x = (x[..., :plain] if shared else x).astype(dtype).reshape(b, S, -1)
+    ks = (2 * jax.random.normal(keys[1], (b, S, dr))).astype(dtype)
+    return x, ks, jax.random.normal(keys[2], (b, S, heads * lanes))
+
+
+def _wide_run(fn, x, ks, g, *static):
+    def loss(x, ks):
+        out = fn(x, ks, *static)
+        return jnp.sum(out.astype(jnp.float32) * g), out
+    (_, out), grads = jax.value_and_grad(
+        loss, (0, 1) if ks is not None else (0,), has_aux=True)(x, ks)
+    return (out,) + tuple(grads)
+
+
+# q rotated; k assembled and rotated; k assembled without positions (Kimi's)
+@pytest.mark.parametrize("shared,rotary", [(False, True), (True, True),
+                                           (True, False)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("plain,dr,tail", WIDE)
+@pytest.mark.parametrize("heads,b,S", [(3, 2, 48), (2, 1, 16)])
+def test_a_head_of_two_lane_blocks_equals_the_lanes_lines(
+        heads, b, S, plain, dr, tail, dtype, shared, rotary):
+    x, ks, g = _wide_operands(b, S, heads, plain, dr, tail, dtype, shared)
+    static = (heads, plain, dr, tail, rotary)
+    ks = ks if shared else None
+    got = _wide_run(wide_kernel, x, ks, g, *static)
+    want = _wide_run(wide_reference, x, ks, g, *static)
+    names = ("out", "dx", "dks")
+    for name, a, r in zip(names, got, want):
+        assert a.shape == r.shape and a.dtype == r.dtype == dtype, name
+    f32 = lambda a: np.asarray(a, np.float32)
+    if dtype == jnp.float32:
+        for name, a, r in zip(names, got, want):
+            np.testing.assert_allclose(a, r, rtol=2e-5, atol=5e-5,
+                                       err_msg=name)
+        return
+    # float32 inside and one rounding, both ways: the lines' own bf16 result
+    # but for a value in a thousand whose float32 sums, in another order (a
+    # fused multiply-add here, none there), lie either side of a rounding
+    # boundary (on the chip to the bit: ``scripts/dots3_kernels_receipt.py``)
+    for name, a, r in zip(names[:2], got, want):
+        assert np.mean(f32(a) != f32(r)) < 1e-3, name
+        np.testing.assert_allclose(f32(a), f32(r), rtol=2 ** -7, atol=1e-6,
+                                   err_msg=name)
+    if shared:
+        # the heads' sum in float32 rounded once, where the lines round the
+        # sum and then its rotation back
+        exact = _wide_run(wide_reference, x.astype(jnp.float32),
+                          ks.astype(jnp.float32), g, *static)
+        assert np.abs(f32(got[2]) - f32(exact[2])).max() \
+            <= 1.001 * np.abs(f32(want[2]) - f32(exact[2])).max() + 1e-6
+
+
+def test_what_a_head_of_several_lane_blocks_goes_with():
+    x = jnp.zeros((1, 16, 512))
+    tables = K.pair_tables(16, FREQS[64], 256, tail=64)
+    assert tables[0].shape == tables[1].shape == (16, 256)
+    with pytest.raises(ValueError, match="no norm and the pairs"):
+        K.qk_rope(x, None, tables, head_dim=256)     # rotate-half
+    with pytest.raises(ValueError, match="no norm and the pairs"):
+        K.qk_rope(x, jnp.ones((256,)), tables, head_dim=256, norm="head",
+                  pairs=True)
+    with pytest.raises(ValueError, match="plain blocks"):
+        K.qk_rope(x, None, tables, head_dim=256, pairs=True, plain_blocks=2)
+    with pytest.raises(ValueError, match="plain blocks"):
+        K.qk_rope(x, None, None, head_dim=128, plain_blocks=1)
+
+
 def test_a_shared_lane_block_goes_with_no_norm():
     x = jnp.zeros((1, 16, 256))
     with pytest.raises(ValueError, match="no norm"):
@@ -288,11 +413,19 @@ def test_a_shared_lane_block_goes_with_no_norm():
     ((2, 24, 256), 64, 2, None),        # 24 rows are no whole bf16 tiles
     ((2, 32, 64), 16, 4, None),         # tiny OLMoE: half a lane block
     ((2, 32, 768), 96, 4, None),        # a head across lane blocks
+    ((1, 8192, 8192), 256, 2, 1024),    # dots3's full layer: 32 heads of 256
+    ((1, 8192, 4096), 256, 2, 1024),    # its sliding layer: 16
+    ((1, 16384, 8192), 256, 2, 1024),   # Kimi-Linear's latent k
+    ((2, 32, 768), 192, 4, None),       # 192: not whole lane blocks
+    ((2, 32, 640), 256, 4, None),       # no whole heads of 256
     ((1, 16, 256 * 1024), 128, 4, None),  # no block within BLOCK_VMEM
 ])
 def test_the_shapes_the_kernel_takes(shape, dh, itemsize, rows):
     assert K.supported(shape, dh, itemsize) == (rows is not None)
-    if rows:
+    if rows and dh > 128:       # ONE lane block a grid step
+        assert K.touched_rows(shape[1], itemsize) == rows
+        assert K.touched_vmem_bytes(rows, itemsize) < 12 * 2 ** 20
+    elif rows:
         assert K.block_rows(shape[1], shape[2], itemsize) == rows
         assert 6 * rows * shape[2] * itemsize <= K.BLOCK_VMEM
         assert K.vmem_bytes(rows, shape[2], itemsize) < 24 * 2 ** 20
@@ -393,6 +526,11 @@ ENGAGED = {
     "lfm2": (64, {(64, "head", 1, "half", 1)}),
     "brumby": (64, {(128, "head", 1, "half", 1)}),
     "trinity": (64, {(128, "head", 1, "half", 1), (128, "head", 0, "half", 1)}),
+    # a head of TWO lane blocks (``_latent_qkv_lanes``): dots3's two shapes'
+    # q and k, beside its indexer's queries and key at a head of one; of
+    # Kimi-Linear's latent layer without positions the k alone
+    "dots3": (64, {(256, "none", 1, "pairs", 1), (128, "none", 1, "half", 1)}),
+    "kimi_linear": (64, {(256, "none", 0, "pairs", 1)}),
 }
 
 
@@ -410,7 +548,8 @@ def test_which_tiny_models_take_the_kernel(tmp_path, model):
     got = _counted(tmp_path, lambda: jax.eval_shape(
         lambda p, i: decoder.forward(p, i, cfg)[0], params, ids))
     assert set(got) == want
-    assert all(n % 2 == 0 for n in got.values())    # q and k, every call
+    # q and k, every call (a q without positions or a norm makes none)
+    assert all(n % 2 == 0 for n in got.values()) or model == "kimi_linear"
 
 
 def test_project_is_the_matmul_and_its_gradients():
