@@ -5,7 +5,8 @@ and ``kda_chunked`` (the same chunks in ``jax.numpy``).
 
 Head by head, from a zero state ``S`` [dk (key), dv (value)] float32, with
 the per-token log-decays ``g_t`` [dk] <= 0 and the write strengths ``beta_t``
-in (0, 1):
+in (0, 2) (Kimi-Linear's ``sigmoid`` keeps them under 1; Solar-Open2's
+``kda_allow_neg_eigval`` doubles it, arXiv:2411.12537):
 
     S' = diag(exp(g_t)) S_{t-1}                         the state decays,
     S_t = S' + beta_t k_t (v_t - S'^T k_t)^T            is corrected toward
@@ -39,6 +40,31 @@ An underflow to 0 is exact enough; an ``inf`` is not, and none is formed.
 (I - A)(I + A^2)(I + A^4)...`` exactly, log2(C) squarings in float32 at the
 highest matmul precision (a row-by-row substitution is C dependent steps).
 
+THE RANGE OF ``beta``.  Along ``k_t`` (a unit vector) a write multiplies what
+the state held by ``1 - beta_t``: under 1 it shrinks it, AT 1 it replaces it
+(``S^T k_t = v_t`` exactly, whatever stood there), past 1 it turns its sign,
+and at 2 it would be a reflection, ``I - 2 k k^T``, that shrinks nothing.
+Nothing in the chunks' algebra asks for ``beta`` < 1 (``A`` is nilpotent
+whatever its entries), but two things change.  An ERROR the state carries
+along ``k`` is no longer damped by a write there, it is carried on with its
+sign turned, so what leaves such errors behind is the decay alone.  And the
+squarings stop being exact enough: ``(I + A)^-1`` stays well conditioned
+(its entries under 2, its condition about 30 at ``beta`` = 1.999), but
+``A^2 .. A^32`` are formed on the way, and where a chunk's keys lean one way
+(the mixer's do: a ``silu`` stands before the L2 norm, any two keys of a
+head at a cosine near 0.1 to 0.3) every entry of ``A`` is positive, ``A^16``
+reaches 4.6e3 and cancels in float32 to three digits: 1.8e-3 of ``T`` at
+``beta`` = 1.999, 1.4e-4 at 1.5, 5e-6 at 1, 2e-7 at 0.5
+(``tests/test_kda_chunk.py::test_the_solve_by_doubling_..``; PERF.md section
+6, PR 67).  So where the strengths may pass 1 (``over_one``) the solve is BY
+DOUBLING: the inverse of a block of 2 s rows from those of its halves,
+``[[T1, 0], [-T2 A21 T1, T2]]``, from single rows up; level ``s`` is ``T <- T
+- T (A on the level's pairs) T`` on the block-diagonal ``T`` so far, two
+products a level as the squarings take, the first level none (``T = I``),
+and nothing larger than the inverse's own blocks is ever formed (2e-7 at
+every strength).  ``over_one`` False is the squarings, bit for bit what
+Kimi-Linear's programs had.
+
 ``kda_chunked`` in two phases.  What a chunk needs of ITSELF (``A``, ``T``, ``W``, ``U``, the
 masked ``q k`` block, the three decayed copies) is made for ``GROUP`` chunks
 at a time, all heads at once, under a ``jax.checkpoint`` of its own (a
@@ -71,8 +97,9 @@ once, every matrix [ROWS, ROWS] with a chunk's block on its diagonal:
   a stack, each kept where its level's pairs are) and no [SUB, SUB, dk]
   block is formed;
 - ``T = (I + diag(beta) P)^-1`` by the same squarings as
-  ``_unit_lower_inverse``, float32 on the MXU, the chunks' blocks side by
-  side so that a product's rows are one chunk's;
+  ``_unit_lower_inverse`` (``over_one``: by the same doubling, its levels'
+  pairs the decays' own ``code`` masks), float32 on the MXU, the chunks'
+  blocks side by side so that a product's rows are one chunk's;
 - the forward takes ``[U | W] = T [beta v | beta k exp(G)]`` before the state
   is at hand, so that a chunk's turn at the state is two products: ``X = U
   - W S``, ``S' = diag(lam) S + kh^T X`` (``o = qt S + Q X`` behind them).
@@ -118,7 +145,8 @@ _TN = (((0,), (0,)), ((), ()))      # a.T @ b
 
 def kept_state_bytes(batch, seq, chunk, heads, dk, dv):
     """What the scan over chunks keeps for its backward: a float32 state
-    [dk, dv] a chunk and head."""
+    [dk, dv] a chunk and head (537 MB a layer at Kimi-Linear's [16384, 32 x
+    128], 268 MB at Solar-Open2's [4096, 64 x 128])."""
     return batch * -(-seq // chunk) * heads * dk * dv * 4
 
 
@@ -189,12 +217,23 @@ def _decayed_product(a, b, blocks, dtype):
         a.shape[:-2] + (C, C))
 
 
-def _unit_lower_inverse(A):
+def _unit_lower_inverse(A, over_one=False):
     """``(I + A)^-1`` of strictly lower triangular ``A`` [..., C, C]: ``(I -
-    A)(I + A^2)(I + A^4)...``, exact since ``A^C = 0``."""
+    A)(I + A^2)(I + A^4)...``, exact since ``A^C = 0``; ``over_one``: by
+    DOUBLING (the module's docstring), which no power of ``A`` enters."""
     C = A.shape[-1]
     eye = jnp.eye(C, dtype=_F32)
     mm = functools.partial(jnp.matmul, precision=_HIGHEST)
+    if over_one:
+        i = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+        j = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+        code = jnp.where(j < i, i ^ j, 0)
+        inv, s = eye - jnp.where(code == 1, A, 0.0), 2
+        while s < C:
+            inv = inv - mm(mm(inv, jnp.where((code >= s) & (code < 2 * s),
+                                             A, 0.0)), inv)
+            s *= 2
+        return inv
     inv, power, span = eye - A, A, 2
     while span < C:
         power = mm(power, power)
@@ -203,7 +242,7 @@ def _unit_lower_inverse(A):
     return inv
 
 
-def _chunk_parts(q, k, v, g, beta, dtype):
+def _chunk_parts(q, k, v, g, beta, dtype, over_one=False):
     """What each chunk needs of itself: q, k, g [..., C, dk], v [..., C,
     dv], beta [..., C] (the work is float32, whatever they arrive in).  ``(W, U, M, qg, kg, last)``: ``W`` [..., C,
     dk] and ``U`` [..., C, dv] behind the solve, ``M`` [..., C, C] the masked
@@ -216,7 +255,7 @@ def _chunk_parts(q, k, v, g, beta, dtype):
     C = q.shape[-2]
     strict = jnp.tril(jnp.ones((C, C), _F32), -1)
     A = _decayed_product(k, k, blocks, dtype) * strict * beta[..., None]
-    T = _unit_lower_inverse(A).astype(dtype)
+    T = _unit_lower_inverse(A, over_one).astype(dtype)
     decay = jnp.exp(G)
     W = jnp.matmul(T, (beta[..., None] * k * decay).astype(dtype),
                    preferred_element_type=_F32)
@@ -228,14 +267,15 @@ def _chunk_parts(q, k, v, g, beta, dtype):
             kg.astype(dtype), decay[..., -1, :])
 
 
-def kda_chunked(q, k, v, g, beta, *, chunk=64, state=None):
+def kda_chunked(q, k, v, g, beta, *, chunk=64, state=None, over_one=False):
     """The delta rule in chunks of ``chunk`` tokens (a multiple of SUB; a
     ragged last chunk is filled with tokens that write nothing): q, k [b, S,
     H, dk], v [b, S, H, dv], g [b, S, H, dk] float32 <= 0, beta [b, S, H]
     float32; the outputs [b, S, H, dv] in v's type.  The matrix products
     take their operands in q's type and sum in float32; decays, the solve
     and the carried state are float32.  ``state`` [b, H, dk, dv]: the state
-    before the first token (None: zeros; the tests' handle on the carry)."""
+    before the first token (None: zeros; the tests' handle on the carry).
+    ``over_one``: strengths may pass 1, the solve by doubling."""
     b, S, H, dk = k.shape
     dv = v.shape[-1]
     dtype = q.dtype
@@ -250,7 +290,8 @@ def kda_chunked(q, k, v, g, beta, *, chunk=64, state=None):
         return jnp.moveaxis(a, (1, 2, 0, 4), (0, 1, 2, 3))
 
     parts = jax.lax.map(
-        jax.checkpoint(lambda xs: _chunk_parts(*xs, dtype=dtype)),
+        jax.checkpoint(lambda xs: _chunk_parts(*xs, dtype=dtype,
+                                               over_one=over_one)),
         tuple(chunks(a) for a in (q, k, v, g, beta)))
     # [n / group, group, ...] -> [n, ...]: the scan's turns
     W, U, M, qg, kg, last = (a.reshape((n,) + a.shape[2:]) for a in parts)
@@ -304,7 +345,10 @@ def vmem_bytes(chunk, heads, itemsize, stacks=STEP_STACKS):
     for: its pipelined blocks twice (q, k, v, do, dq, dk, dv a head wide in
     the operands' type; g and dg float32; the write strengths a column a
     head, padded to a lane tile; the states kept; beta's gradient a row a
-    stack), the state's gradient, and room for a stack's values."""
+    stack), the state's gradient, and room for a stack's values.  The heads
+    enter through beta's block alone: 22.6 MiB at 32 heads and at 64 alike
+    (a lane tile holds either; a grid step is ONE head's lane block whatever
+    the array's width, 4,096 or 8,192 lanes)."""
     tokens = stacks * ROWS
     blocks = 2 * (7 * tokens * LANES * itemsize + 2 * tokens * LANES * 4
                   + tokens * -(-heads // LANES) * LANES * 4
@@ -388,8 +432,8 @@ class _Own:
     chunk's block on its diagonal and zeros between chunks.  q, k [ROWS, dk]
     in the operands' type; g [ROWS, dk] and beta [ROWS, 1] float32."""
 
-    def __init__(self, q, k, g, beta, row, code, C):
-        self.C, dt = C, q.dtype
+    def __init__(self, q, k, g, beta, row, code, C, over_one=False):
+        self.C, self.over_one, dt = C, over_one, q.dtype
         self.chunks = [slice(at, at + C) for at in range(0, ROWS, C)]
         self.qf, self.kf = qf, kf = q.astype(_F32), k.astype(_F32)
         self.G = G = _running(g, row, C)
@@ -423,9 +467,17 @@ class _Own:
         on ``A``'s diagonal, as ``_unit_lower_inverse``: ``(I - A)(I +
         A^2)(I + A^4)...`` in float32 on the MXU, with the chunks' blocks
         SIDE BY SIDE ([C, ROWS]) on a product's left, so that its rows are
-        one chunk's, and on a diagonal on its right."""
+        one chunk's, and on a diagonal on its right.  ``over_one``: by
+        doubling (the module's docstring), in the same two products a
+        level: ``inv`` side by side times the level's pairs of ``A``, times
+        ``inv`` on the diagonal."""
         C = self.C
-        power = sum((A[c] for c in self.chunks[1:]), A[self.chunks[0]])
+
+        def beside(X):
+            """Each chunk's block on the diagonal -> side by side."""
+            return sum((X[c] for c in self.chunks[1:]), X[self.chunks[0]])
+
+        power = beside(A)
         lane = jax.lax.broadcasted_iota(jnp.int32, power.shape, 1)
         place = jax.lax.broadcasted_iota(jnp.int32, power.shape, 0)
         own = [(lane >= c.start) & (lane < c.stop) for c in self.chunks]
@@ -434,7 +486,16 @@ class _Own:
             """The blocks side by side -> each on the diagonal."""
             return _rows([jnp.where(mine, side, 0.0) for mine in own])
 
-        inv = jnp.where((lane & (C - 1)) == place, 1.0, 0.0) - power
+        eye = jnp.where((lane & (C - 1)) == place, 1.0, 0.0)
+        if self.over_one:
+            # the levels from single rows up; the first finds inv = I
+            pairs = [jnp.where(here, A, 0.0)
+                     for _, here, _, _, _ in reversed(self.levels)]
+            inv = eye - beside(pairs[0])
+            for level in pairs[1:]:
+                inv = inv - _mm32(_mm32(inv, level), spread(inv))
+            return spread(inv)
+        inv = eye - power
         wide, span = spread(power), 2
         while span < C:
             power = _mm32(power, wide)
@@ -445,7 +506,7 @@ class _Own:
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest, chunk,
-                save):
+                save, over_one):
     """The state TRANSPOSED, [dv, dk]: a key channel a lane, as the decays
     are.  ``X = U - W S`` with ``[U | W] = T [beta v | beta kt]`` made
     before the state is at hand: a chunk's turn at the state is two
@@ -466,7 +527,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest, chunk,
         at = pl.ds(pl.multiple_of(p * ROWS, ROWS), ROWS)
         beta = _column(beta_ref[at, :], head)
         own = _Own(q_ref[at, :], k_ref[at, :], g_ref[at, :], beta, row,
-                   code, chunk)
+                   code, chunk, over_one)
         uw = _mm32(own.T, jnp.concatenate(
             [beta * v_ref[at, :].astype(_F32), beta * own.kt], axis=1))
         U, W = uw[:, :LANES], uw[:, LANES:].astype(dt)
@@ -489,7 +550,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest, chunk,
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, kept_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, d_ref, *, chunk):
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, d_ref, *, chunk,
+                over_one):
     """The stacks from the last to the first, each chunk from the state it
     FOUND; ``D`` [dv, dk] the gradient of the state a chunk leaves.  A
     chunk's turn at ``D`` is two products: ``dX = Q^T do + kh D`` and ``D'
@@ -511,7 +573,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, kept_ref,
         at = pl.ds(pl.multiple_of(p * ROWS, ROWS), ROWS)
         beta = _column(beta_ref[at, :], head)
         own = _Own(q_ref[at, :], k_ref[at, :], g_ref[at, :], beta, row,
-                   code, C)
+                   code, C, over_one)
         qf, kf, do = own.qf, own.kf, do_ref[at, :]
         qt, kt, kh = (a.astype(dt) for a in (own.qt, own.kt, own.kh))
         W = _mm32(own.T, beta * own.kt).astype(dt)
@@ -611,7 +673,7 @@ class _Geom:
 
 
 def _fwd(q, k, v, g, beta, static, save):
-    heads, chunk, interpret = static
+    heads, chunk, interpret, over_one = static
     geom = _Geom(q, heads, chunk)
     out_specs = [geom.head]
     out_shape = [jax.ShapeDtypeStruct(v.shape, v.dtype)]
@@ -620,7 +682,8 @@ def _fwd(q, k, v, g, beta, static, save):
         out_shape.append(jax.ShapeDtypeStruct(
             (geom.B, geom.S // chunk, heads, LANES, LANES), _F32))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, chunk=chunk, save=save),
+        functools.partial(_fwd_kernel, chunk=chunk, save=save,
+                          over_one=over_one),
         grid=geom.grid,
         in_specs=[geom.head] * 4 + [geom.beta],
         out_specs=out_specs, out_shape=out_shape,
@@ -631,12 +694,12 @@ def _fwd(q, k, v, g, beta, static, save):
 
 
 def _bwd(static, res, do):
-    heads, chunk, interpret = static
+    heads, chunk, interpret, over_one = static
     q, k, v, g, beta, kept = res
     geom = _Geom(q, heads, chunk, flip=True)
     like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
     dq, dk, dv, dg, dbeta = pl.pallas_call(
-        functools.partial(_bwd_kernel, chunk=chunk),
+        functools.partial(_bwd_kernel, chunk=chunk, over_one=over_one),
         grid=geom.grid,
         in_specs=[geom.head] * 4 + [geom.beta, geom.head, geom.kept],
         out_specs=[geom.head] * 4 + [geom.dbeta],
@@ -664,13 +727,15 @@ def _delta_fwd(q, k, v, g, beta, static):
 _delta.defvjp(_delta_fwd, _bwd)
 
 
-def kda_chunk(q, k, v, g, beta, *, heads, chunk=64, interpret=None):
+def kda_chunk(q, k, v, g, beta, *, heads, chunk=64, interpret=None,
+              over_one=False):
     """``kda_chunked`` from a zero state by the kernels, on the arrays as the
     mixer has them: q, k, v, g [b, S, heads * 128] (head h is lane block h;
     g float32), beta [b, S, heads] float32; the outputs [b, S, heads * 128]
     in v's type, at ``kda_chunked``'s precision (``supported`` must hold).
     Differentiable in all five; what the backward needs is the operands and
-    the state each chunk found (``kept_state_bytes``)."""
+    the state each chunk found (``kept_state_bytes``).  ``over_one``: the
+    strengths may pass 1 (``beta`` in (0, 2)), the solve by doubling."""
     b, S, width = k.shape
     assert width == heads * LANES and q.shape == v.shape == g.shape \
         == k.shape and k.dtype == v.dtype == q.dtype and supported(
@@ -679,4 +744,4 @@ def kda_chunk(q, k, v, g, beta, *, heads, chunk=64, interpret=None):
     if interpret is None:
         interpret = not _on_tpu()
     return _delta(q, k, v, g.astype(_F32), beta.astype(_F32),
-                  (int(heads), int(chunk), bool(interpret)))
+                  (int(heads), int(chunk), bool(interpret), bool(over_one)))

@@ -13,6 +13,16 @@ in float32 and rounded ONCE:
     norm_gate   o * rsqrt(mean_head(o^2) + eps) * o_norm * sigmoid(gate_pre)
                 the norm THEN the gate (``gated_norm`` gates first)  o's type
 
+The write strength is none of these passes: ``beta`` [b, S, heads] is a
+matmul's epilogue in the mixer (``transformer.kda_write_strength``: a sigmoid,
+times 2 where ``kda_beta_scale`` is 2), in (0, 1) or (0, 2); the three passes
+neither read it nor depend on its range (what a strength past 1 does to an
+error the state carries is ``kernels/kda_chunk.py``'s to say).  At 64 heads
+(``solar_open2_250b.s4096_scan``: [1, 4096, 8192], sixteen lane blocks of
+four heads where Kimi-Linear's [1, 16384, 4096] has eight) the geometry and
+``vmem_bytes`` are the same, (1024, 128, 512) and 18.1 MiB for the widest
+backward: a grid step is a block, whatever the array's width.
+
 Why kernels (PERF.md section 6, PR 60): the lines reduce over a RESHAPED
 minor dimension, and on this chip a ``reshape`` between ``[16384, 4096]`` and
 ``[16384, 32, 128]`` is a copy: one KDA layer's recompute + backward at the

@@ -4,7 +4,7 @@
 decoder of this block.  A model (``models/olmoe.py``, ``smallthinker.py``,
 ``lfm2.py``, ``brumby.py``, ``mistral4.py``, ``trinity.py``, ``jamba.py``,
 ``nemotron_h.py``, ``ouro.py``, ``kimi_linear.py``, ``keye_vl2.py``,
-``dots3.py``)
+``dots3.py``, ``solar_open2.py``)
 is a ``TransformerConfig`` and a label; what its trainer computes and what
 it observes follow from the configuration, never from which model it is.
 
@@ -50,6 +50,7 @@ from .transformer import (
     head_logits,
     init_transformer_params,
     kda_log_decay,
+    kda_write_strength,
     mamba2_operands,
     mamba_operands,
     retention_log_decay,
@@ -225,7 +226,11 @@ def probe(params, ids, cfg):
     - a layer kind is KDA: ``kda_decay_mean``, the mean ``e^g`` over tokens,
       heads and channels, and ``kda_decay_min``, the smallest (how far the
       carry reaches), of the FIRST such position (a leading layer's, else the
-      period's) as the embedding hands the batch over;
+      period's) as the embedding hands the batch over; where the write
+      strengths reach past 1 (``kda_beta_scale`` 2) also
+      ``kda_write_over_one_share``, the share of that position's (token,
+      head) writes with ``beta`` > 1: the negative eigenvalues in use, about
+      one half at seeded weights;
     - ``attn_gate``: ``attn_gate_mean``, the mean of the output gate's
       sigmoid over tokens, heads and columns in the first layer: a gate
       stuck at 0 or 1 is a dead branch;
@@ -284,10 +289,13 @@ def probe(params, ids, cfg):
         out["mamba2_decay_min"] = jnp.exp(-jnp.max(
             jnp.max(dt, axis=(0, 1)) * jnp.exp(pl2["a_log"])))
     if KDA in cfg.prefix_kinds + cfg.layer_kinds:
-        decay = jnp.exp(kda_log_decay(*_first_of_kind(params, ids, cfg, KDA),
-                                      cfg))
+        first = _first_of_kind(params, ids, cfg, KDA)
+        decay = jnp.exp(kda_log_decay(*first, cfg))
         out["kda_decay_mean"] = jnp.mean(decay)
         out["kda_decay_min"] = jnp.min(decay)
+        if cfg.kda_beta_scale > 1.0:
+            out["kda_write_over_one_share"] = jnp.mean(
+                kda_write_strength(*first, cfg) > 1.0)
     if cfg.attn_gate:
         out["attn_gate_mean"] = jnp.mean(jax.nn.sigmoid(
             (h @ pl["wz"]).astype(jnp.float32)))

@@ -42,7 +42,7 @@ __all__ = ["TransformerConfig", "AttentionShape", "CONV", "RETENTION", "MAMBA", 
            "init_transformer_params", "transformer_param_specs",
            "grad_sync_axes", "embed", "transformer_layer", "run_layers",
            "mamba_mixer", "mamba_operands", "mamba2_mixer", "mamba2_operands",
-           "kda_mixer", "kda_log_decay",
+           "kda_mixer", "kda_log_decay", "kda_write_strength",
            "rms_norm", "rope", "rope_pairs", "yarn_blend_range",
            "yarn_frequencies",
            "yarn_softmax_scale", "yarn_rotary_factor", "final_logits_loss",
@@ -239,6 +239,10 @@ class TransformerConfig:
     kda_head_dim: int = 0
     kda_gate_rank: int = 0
     kda_chunk: int = 64
+    # what the sigmoid of a head's write strength is multiplied by: 1, beta
+    # in (0, 1); 2, beta in (0, 2) (the transition along the key, ``1 -
+    # beta``, in (-1, 1): negative eigenvalues, arXiv:2411.12537)
+    kda_beta_scale: float = 1.0
     # every layer is ONE pre-norm residual branch, ``x + branch(norm(x))``:
     # a mixer position (attention, MAMBA2, ...) has no FFN and no second
     # norm, and the feed-forward part is a position of its own, FFN
@@ -412,7 +416,8 @@ class TransformerConfig:
         assert self.per_position or not self.run_scan
         if KDA in self.layer_pattern + self.prefix_pattern:
             assert self.kda_heads and self.kda_head_dim and self.d_conv \
-                and self.kda_gate_rank and self.kda_chunk > 0
+                and self.kda_gate_rank and self.kda_chunk > 0 \
+                and self.kda_beta_scale in (1.0, 2.0)
         if self.latent:
             assert self.tp == 1 and not (self.bias or self.qk_norm)
             if self.positions == "rotary":
@@ -2194,6 +2199,16 @@ def kda_log_decay(pl, h, cfg):
         pl["dt_bias"], pl["a_log"])
 
 
+def kda_write_strength(pl, h, cfg):
+    """The write strength of every token and head, [b, S, heads] float32:
+    ``sigmoid(h @ w_beta)`` in (0, 1), times ``cfg.kda_beta_scale`` where
+    that is not 1 (2: in (0, 2), the transition along the key ``1 - beta``
+    in (-1, 1))."""
+    beta = jax.nn.sigmoid(jnp.matmul(
+        h, pl["w_beta"], preferred_element_type=jnp.float32))
+    return beta if cfg.kda_beta_scale == 1.0 else cfg.kda_beta_scale * beta
+
+
 def _kda_flat(pl, h, cfg, q, k, v):
     """``kda_mixer`` behind its filters on the FLAT arrays [b, S, heads x
     128], a head a lane tile from the filters' outputs to ``wo``'s input:
@@ -2207,8 +2222,7 @@ def _kda_flat(pl, h, cfg, q, k, v):
     nh, d = cfg.kda_heads, cfg.kda_head_dim
     q = kda_rows.l2_heads(q, scale=d ** -0.5)
     k = kda_rows.l2_heads(k, scale=1.0)
-    beta = jax.nn.sigmoid(jnp.matmul(
-        h, pl["w_beta"], preferred_element_type=jnp.float32))
+    beta = kda_write_strength(pl, h, cfg)
     with jax.named_scope(devscope.KDA_CHUNK):
         # the decays stand under the delta rule's scope, as
         # ``kda_log_decay`` does on the other path
@@ -2216,7 +2230,8 @@ def _kda_flat(pl, h, cfg, q, k, v):
             h @ pl["w_fa"], pl["w_fb"], preferred_element_type=jnp.float32),
             pl["dt_bias"], pl["a_log"])
         o = kda_chunk.kda_chunk(q, k, v, g, beta, heads=nh,
-                                chunk=cfg.kda_chunk)
+                                chunk=cfg.kda_chunk,
+                                over_one=cfg.kda_beta_scale > 1.0)
     return kda_rows.norm_gate(o, jnp.matmul(
         h @ pl["w_ga"], pl["w_gb"], preferred_element_type=jnp.float32),
         pl["o_norm"], eps=cfg.norm_eps)
@@ -2228,7 +2243,8 @@ def kda_mixer(pl, h, cfg):
     E], the whole sequence: q, k and v each ``silu(filter(h @ w))``
     (``_kda_filtered``), per head ``q / |q| * d^(-1/2)`` and ``k / |k|``; the
     log-decays ``kda_log_decay`` (one a CHANNEL of the key) and the write
-    strengths ``sigmoid(h @ w_beta)`` (one a head), float32; the delta rule
+    strengths ``kda_write_strength`` (one a head; ``sigmoid(h @ w_beta)``
+    times ``kda_beta_scale``), float32; the delta rule
     on a [d, d] state a head (``kernels/kda_chunk.py``, chunks of
     ``cfg.kda_chunk`` tokens); each head's output RMS-normed by the ONE
     scale ``o_norm`` and THEN gated by ``sigmoid((h @ w_ga) @ w_gb)`` (the
@@ -2252,8 +2268,7 @@ def kda_mixer(pl, h, cfg):
         return _kda_flat(pl, h, cfg, q, k, v) @ pl["wo"]
     q = kda_rows.l2_heads_reference(q, nh, d ** -0.5).astype(h.dtype)
     k = kda_rows.l2_heads_reference(k, nh, 1.0).astype(h.dtype)
-    beta = jax.nn.sigmoid(jnp.matmul(
-        h, pl["w_beta"], preferred_element_type=jnp.float32))
+    beta = kda_write_strength(pl, h, cfg)
     with jax.named_scope(devscope.KDA_CHUNK):
         # under a checkpoint of its own: what the ``jnp`` form keeps for
         # its backward (the chunks' own parts, a state a chunk: 1.7 GB
@@ -2261,7 +2276,8 @@ def kda_mixer(pl, h, cfg):
         # runs, not beside the FFN's residuals through the layer's, at
         # the price of a third forward (PERF.md section 6, PR 58)
         o = jax.checkpoint(functools.partial(
-            kda_chunk.kda_chunked, chunk=cfg.kda_chunk))(
+            kda_chunk.kda_chunked, chunk=cfg.kda_chunk,
+            over_one=cfg.kda_beta_scale > 1.0))(
                 q, k, v.reshape(b, S, nh, d), kda_log_decay(pl, h, cfg),
                 beta)
     y = kda_rows.norm_gate_reference(o, jnp.matmul(

@@ -3,9 +3,13 @@ forward and backward) on the chip with the model's operands (q, k, v in
 bf16, log-decays and write strengths float32), against the same chunks in
 ``jnp`` (``kda_chunked``) and against the recurrence a token at a time in
 float32 at ``highest`` precision (``kda_recurrence``), at the Kimi-Linear
-cell's shapes:
+cell's shapes, or with ``solar`` at ``solar_open2_250b.s4096_scan``'s ([1,
+4096, 64 x 128], write strengths ``2 sigmoid(.)`` in (0, 2), q, k and v
+behind a ``silu`` as the mixer's filters leave them, the solve by doubling:
+``over_one``; beside it what the squarings' solve reads there and with every
+write at 1.999):
 
-    chiprun --timeout 1500 -- python3 scripts/kda_chunk_receipt.py [out.json]
+    chiprun --timeout 1500 -- python3 scripts/kda_chunk_receipt.py [out.json] [kimi|solar]
 
 - the output at [1, 16384, 32, 128], chunks of 64, with decays near one (a
   state that outlives every chunk) and at the seeded extremes (``a_log`` =
@@ -41,6 +45,7 @@ from benchmark.harness.peaks import PEAKS  # noqa: E402
 from paddle_tpu.kernels import kda_chunk as K  # noqa: E402
 
 B, S, H, D, CHUNK = 1, 16384, 32, 128, 64
+OVER_ONE = False        # ``solar``: strengths in (0, 2), operands past a silu
 S_COMPARED = 1024       # the recurrence's gradient keeps a state a token
 DECAYS = {"near_one": (0.0, 1e-3), "extreme": (math.log(16.0), 0.7)}
 NAMES = ("q", "k", "v", "g", "beta")
@@ -56,35 +61,43 @@ def _rel(got, want):
     return float(np.linalg.norm(got - want) / np.linalg.norm(want))
 
 
-def operands(s, decays, seed=0):
+def operands(s, decays, seed=0, strength=None):
     """As the mixer hands them over: q and k L2-normalised a head (q times
-    d^-1/2) in bf16, v in bf16, g <= 0 and beta in (0, 1) float32."""
+    d^-1/2) in bf16, v in bf16, g <= 0 and beta in (0, 1) float32; OVER_ONE:
+    q, k, v behind a ``silu`` and beta in (0, 2) (``strength``: every write
+    that)."""
     a_log, step = DECAYS[decays]
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    q, k = (jax.random.normal(key, (B, s, H, D)) for key in ks[:2])
+    q, k, v = (jax.random.normal(key, (B, s, H, D)) for key in ks[:3])
+    if OVER_ONE:
+        q, k, v = (jax.nn.silu(a) for a in (q, k, v))
     q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * D ** -0.5
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    v = jax.random.normal(ks[2], (B, s, H, D))
     g = -math.exp(a_log) * jax.nn.softplus(
         0.3 * jax.random.normal(ks[3], (B, s, H, D))
         + math.log(math.expm1(step)))
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, s, H)))
+    if OVER_ONE:
+        beta = 2.0 * beta if strength is None else jnp.full_like(beta,
+                                                                 strength)
     return tuple(a.astype(jnp.bfloat16) for a in (q, k, v)) + (g, beta)
 
 
 def edges_dropped(q, k, v, g, beta):
     """The control: every chunk from a zero state."""
     cut = lambda a: a.reshape((-1, CHUNK) + a.shape[2:])    # noqa: E731
-    o = K.kda_chunked(*(cut(a) for a in (q, k, v, g, beta)), chunk=CHUNK)
+    o = K.kda_chunked(*(cut(a) for a in (q, k, v, g, beta)), chunk=CHUNK,
+                      over_one=OVER_ONE)
     return o.reshape(v.shape)
 
 
-def kernels(q, k, v, g, beta):
+def kernels(q, k, v, g, beta, over_one=None):
     """``kda_chunk`` on operands shaped as ``kda_chunked``'s: a head a lane
     block of [b, S, heads x 128] at the kernels' door."""
     flat = lambda a: a.reshape(a.shape[:2] + (-1,))         # noqa: E731
-    return K.kda_chunk(flat(q), flat(k), flat(v), flat(g), beta, heads=H,
-                       chunk=CHUNK).reshape(v.shape)
+    return K.kda_chunk(
+        flat(q), flat(k), flat(v), flat(g), beta, heads=H, chunk=CHUNK,
+        over_one=OVER_ONE if over_one is None else over_one).reshape(v.shape)
 
 
 def _ms(fn, args):
@@ -97,15 +110,37 @@ def _ms(fn, args):
     return float(np.median(took))
 
 
-def main(out_path=OUT):
+def solves_compared(recurrence):
+    """``solar``: the kernels' output by either solve, float32 OPERANDS (so
+    that the solve's own digits show), strengths in (0, 2) and every write at
+    1.999, decays near one: each one's relative error against the
+    recurrence."""
+    out = {}
+    for name, strength in (("under_two", None), ("all_1.999", 1.999)):
+        args = operands(S_COMPARED, "near_one", seed=3, strength=strength)
+        args = tuple(a.astype(jnp.float32) for a in args)
+        want = recurrence(*args)
+        out[name] = {
+            solve: _rel(jax.jit(lambda *a, o=over_one: kernels(
+                *a, over_one=o))(*args), want)
+            for solve, over_one in (("doubling", True), ("squarings", False))}
+    return out
+
+
+def main(out_path=OUT, shape="kimi"):
+    global S, H, OVER_ONE
+    if shape == "solar":
+        S, H, OVER_ONE = 4096, 64, True
     if jax.devices()[0].platform != "tpu":
         print("kda_chunk_receipt: no TPU here")
         return 2
     assert K.supported((B, S, H, D), D, CHUNK, jnp.bfloat16)
     forms = {"kernel": kernels,
-             "jnp": lambda *a: K.kda_chunked(*a, chunk=CHUNK)}
+             "jnp": lambda *a: K.kda_chunked(*a, chunk=CHUNK,
+                                             over_one=OVER_ONE)}
     recurrence = jax.jit(K.kda_recurrence)
     out = {"shape": [B, S, H, D], "chunk": CHUNK, "limit": LIMIT,
+           "over_one": OVER_ONE,
            "decay_limit": DECAY_LIMIT, "device": jax.devices()[0].device_kind,
            "outputs": {}, "gradients": {}, "control": {}, "ms": {}}
     ok = True
@@ -145,7 +180,8 @@ def main(out_path=OUT):
     flat = lambda a: a.reshape(a.shape[:2] + (-1,))         # noqa: E731
     # each form on the arrays as it takes them at the mixer's door: the
     # kernels a head a lane block (re-laying [b, S, H, d] out is a copy)
-    timed = {"kernel": (lambda *a: K.kda_chunk(*a, heads=H, chunk=CHUNK),
+    timed = {"kernel": (lambda *a: K.kda_chunk(*a, heads=H, chunk=CHUNK,
+                                               over_one=OVER_ONE),
                         tuple(flat(a) for a in args[:4]) + args[4:], flat(w)),
              "jnp": (forms["jnp"], args, w)}
     for form, (fn, operands_, w_) in timed.items():
@@ -160,6 +196,12 @@ def main(out_path=OUT):
         need["flops"] / peaks["bf16_flops"],
         need["bytes"] / peaks["hbm_bytes_per_s"])
     out["kept_state_bytes"] = K.kept_state_bytes(B, S, CHUNK, H, D, D)
+    if OVER_ONE:
+        out["solves"] = solves_compared(recurrence)
+        out["ms"]["kernel_squarings"] = {"forward_and_backward": _ms(
+            jax.jit(jax.grad(lambda *a: jnp.sum((K.kda_chunk(
+                *a, heads=H, chunk=CHUNK) * flat(w)).astype(jnp.float32)),
+                argnums=(0, 1, 2, 3, 4))), timed["kernel"][1])}
     out["ok"] = bool(ok)
     print(json.dumps(out), flush=True)
     os.makedirs(os.path.dirname(os.path.abspath(out_path)), exist_ok=True)

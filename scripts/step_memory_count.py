@@ -11,7 +11,9 @@ section 4 gives for every decoder cell (PR 37's recipe), printed beside
 ``memory_analysis()``, the program's NEED by it (``memscope.need_line``:
 the line a chip run's memory account prints for the same program, one
 definition) and the largest buffers of the report.  ``key=value``
-overrides the configuration factory's arguments (``n_layers=28``).  A
+overrides the configuration factory's arguments (``n_layers=28``); ``S=256``
+the traffic's sequence length (``count``'s ``dims``: the tests' tiny-shape
+case).  The kernels' ``_on_tpu`` are put back as ``count`` returns.  A
 compile that passes is not a chip run: what the chip reserves is read off
 the cell's own run (``peak_hbm_gb`` and the line's ``memory`` fields)."""
 
@@ -46,11 +48,23 @@ from paddle_tpu.parallel.train import (TrainState, make_train_step,  # noqa: E40
                                        state_specs)
 
 
-def main(cell, *overrides):
+def count(cell, *overrides):
+    """``(compiled run_steps, parameters, state bytes)`` of ``cell`` for ONE
+    described v5e device, the kernels' ``_on_tpu`` True while it lowers."""
+    patched = []
     for info in pkgutil.iter_modules(kernels.__path__):
         module = importlib.import_module("paddle_tpu.kernels." + info.name)
         if hasattr(module, "_on_tpu"):
+            patched.append((module, module._on_tpu))
             module._on_tpu = lambda: True
+    try:
+        return _count(cell, *overrides)
+    finally:
+        for module, was in patched:
+            module._on_tpu = was
+
+
+def _count(cell, *overrides):
     manifest = mf.load(ROOT)
     entry = mf.cell(manifest, cell)
     config = mf.read_json(ROOT, "benchmark", "configs",
@@ -59,7 +73,10 @@ def main(cell, *overrides):
     kwargs = dict(config["config_factory"]["kwargs"])
     for item in overrides:
         key, value = item.split("=")
-        kwargs[key] = json.loads(value)
+        if key in traffic["dims"]:
+            traffic["dims"][key] = json.loads(value)
+        else:
+            kwargs[key] = json.loads(value)
     path, name = config["config_factory"]["path"].rsplit(".", 1)
     cfg = getattr(importlib.import_module(path), name)(**kwargs)
     topo = topologies.get_topology_desc(platform="tpu",
@@ -84,12 +101,17 @@ def main(cell, *overrides):
         (int(traffic["staged_batches"]), dims["B"], dims["S"]), jnp.int32,
         sharding=NamedSharding(mesh, P(None, DP)))}
     n_params = sum(a.size for a in jax.tree.leaves(params))
+    return (multi.lower(state, batches, 1e-5).compile(), n_params,
+            sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state)))
+
+
+def main(cell, *overrides):
+    compiled, n_params, state_bytes = count(cell, *overrides)
     print("parameters: %.1f M; state leaves: %.3f GB"
-          % (n_params / 1e6, sum(a.size * a.dtype.itemsize
-                                 for a in jax.tree.leaves(state)) / 1e9))
-    compiled = multi.lower(state, batches, 1e-5).compile()
+          % (n_params / 1e6, state_bytes / 1e9))
     print("memory_analysis():", compiled.memory_analysis())
-    print(memscope.need_line(entry["config"] + ".run_steps (described v5e)",
+    print(memscope.need_line(cell.split(".")[0]
+                             + ".run_steps (described v5e)",
                              memscope.program_ledger(compiled)))
     reports = sorted(glob.glob(os.path.join(DUMP, "*memory-usage-report*")),
                      key=os.path.getsize)

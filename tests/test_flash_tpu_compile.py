@@ -416,6 +416,31 @@ def test_the_moe_row_kernel_compiles_for_a_v5e(one_chip, what, slots, k,
                      % (m, width // 2), text), what
 
 
+def test_the_moe_row_kernel_compiles_at_ten_held_of_a_router_of_320(one_chip):
+    """``moe_rows_sum`` at one layer's shape of ``solar_open2_250b.
+    s4096_scan`` (32,768 pair slots, k = 8, rows of 4,096 in bf16): 10 held
+    of a router 320 wide (two and a half lane tiles; a share that is no
+    multiple of 8) make a first capacity of 1,536 rows, 1.5 x the 1,024
+    uniform routing brings; at eight rows of 2,048 words a token a grid step
+    holds 128 tokens, within the VMEM the call asks for."""
+    mr = importlib.import_module("paddle_tpu.kernels.moe_rows")
+    moe = importlib.import_module("paddle_tpu.parallel.moe")
+    slots, k, width = 4096 * 8, 8, 4096
+    m = moe._held_capacities(slots, 10, 320)[0]
+    assert m == 1536
+    rows = jax.ShapeDtypeStruct((m, width), jnp.bfloat16, sharding=one_chip)
+    inv = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    text = jax.jit(lambda r, i: mr.moe_rows_sum(r, i, k, interpret=False)
+                   ).lower(rows, inv).compile().as_text()
+    tb = mr.token_block(k, width // 2)
+    assert tb == 128 and text.count("tpu_custom_call") == 2
+    asked, took = _vmem(text, "moe_rows_sum")
+    assert asked == mr.vmem_bytes(k, tb, width // 2) <= 18 * 2 ** 20
+    assert took < asked
+    assert re.search(r"u32\[%d,1,%d\]\S* bitcast\(\S*moe_rows_words"
+                     % (m, width // 2), text)
+
+
 MOE_CELLS = {      # rows at the first capacity, groups, E, F of one layer
     "olmoe_1b_7b.s4096_scan": (131072, 64, 2048, 1024),
     "lfm2_8b_a1b.s8192_scan": (20480, 8, 2048, 1792),
@@ -565,14 +590,17 @@ def test_the_ssd_scan_compiles_for_a_v5e(one_chip, what, shape, heads, groups,
         assert took < asked < 64 * 2 ** 20, (what, kernel, took, asked)
 
 
-@pytest.mark.parametrize("what,shape,chunk,dtype", [
+@pytest.mark.parametrize("what,shape,chunk,dtype,over_one", [
     ("kimi_linear_48b_a3b.s16384_scan", (1, 16384, 32, 128), 64,
-     jnp.bfloat16),
+     jnp.bfloat16, False),
     ("float32 operands, four chunks a stack, one grid step",
-     (2, 512, 4, 128), 32, jnp.float32),
+     (2, 512, 4, 128), 32, jnp.float32, False),
+    # 64 heads (8,192 lanes), strengths in (0, 2): the solve by doubling
+    ("solar_open2_250b.s4096_scan", (1, 4096, 64, 128), 64, jnp.bfloat16,
+     True),
 ])
 def test_the_kda_chunk_kernels_compile_for_a_v5e(one_chip, what, shape,
-                                                 chunk, dtype):
+                                                 chunk, dtype, over_one):
     """Both kernels of the chunked delta rule through Mosaic at the cell's
     shape (32 heads of 128, a lane block each of the mixer's [b, S, 4096]
     arrays, 256 chunks of 64 a head in eight-stack grid steps) and in
@@ -589,7 +617,8 @@ def test_the_kda_chunk_kernels_compile_for_a_v5e(one_chip, what, shape,
 
     def both(*a):
         out, vjp = jax.vjp(lambda *q: kda.kda_chunk(
-            *q, heads=H, chunk=chunk, interpret=False), *a[:-1])
+            *q, heads=H, chunk=chunk, interpret=False, over_one=over_one),
+            *a[:-1])
         return (out,) + vjp(a[-1])
 
     assert kda.supported(shape, d, chunk, dtype)
@@ -1337,6 +1366,7 @@ def test_a_flash_layer_s_text_holds_no_float32_product_of_o_and_do(
 @pytest.mark.parametrize("what,shape,dtype", [
     ("kimi_linear_48b_a3b.s16384_scan", (1, 16384, 4096), jnp.bfloat16),
     ("float32, two heads, one block of 40 rows", (2, 40, 256), jnp.float32),
+    ("solar_open2_250b.s4096_scan, 64 heads", (1, 4096, 8192), jnp.bfloat16),
 ])
 def test_the_kda_row_kernels_compile_for_a_v5e(one_chip, what, shape, dtype):
     """The five kernels of ``kernels/kda_rows.py`` through Mosaic at the
@@ -1413,6 +1443,70 @@ def test_a_kda_layer_s_text_holds_no_float32_view_by_heads(one_chip):
     # forward is the epilogue of its matmul)
     assert sum(v for k, v in by_kernel.items()
                if k.startswith(("kda_l2", "kda_log", "kda_norm"))) < 3.8e9
+
+
+def test_solar_open2_s_gated_nope_gqa_position_compiles_for_a_v5e(one_chip):
+    """``solar_open2_250b.s4096_scan``'s attention position, recompute +
+    backward, at the published shape (64 query heads on 8 key/value heads of
+    128, a group of 8 a key/value head-block; no positions: NO row kernel
+    rotates or norms q and k; the element-wise gate XLA's): its kernels are
+    the flash forward, the delta pass and ONE fused backward sweep, and no
+    K or V is repeated to the query heads' width in HBM ([4096, 8192] in
+    bf16 would be 64 MB an array)."""
+    hlo = _script("attn_outside_hlo")
+    cfg, batch, seq = hlo.cell_config("solar_open2_250b.s4096_scan",
+                                      tiny=False)
+    kind = cfg.layer_kinds[0]
+    assert (batch, seq, kind, cfg.n_heads, cfg.kv_heads, cfg.head_dim,
+            cfg.attn_gate, cfg.positions) == (
+        1, 4096, (None, False), 64, 8, 128, True, None)
+    text = hlo.compiled_text(cfg, batch, seq, kind)
+    groups, by_kernel, others = hlo.account(text)
+    assert set(by_kernel) == {"flash_fwd", "flash_delta", "flash_bwd_fused"}
+    assert fa.kv_blocks(seq, 512, 512, True, None) == 36
+    # q, o, do, dq at 64 heads, k, v, dk, dv at 8: the kernels move under
+    # 0.56 GB (0.524 read; a K and V repeated to 64 heads would add 0.35)
+    assert sum(by_kernel.values()) < 0.56e9, by_kernel
+    assert groups["matmul"] > groups["other"]
+
+
+@pytest.mark.parametrize("what,overrides,need_gb", [
+    pytest.param("the published widths at S = 4,096", (), (13.5, 14.5),
+                 marks=pytest.mark.slow),
+    # one period at every kind's own kernels, the odd router (320) and
+    # share (10) kept: 64 KDA heads -> 4, GQA 64 / 8 -> 8 / 1 (the group of
+    # 8), the stream 512, experts of 256, 2,048 rows of vocabulary, S = 256
+    # (two stacks of the delta rule's 128 rows)
+    ("a tiny shape of the same step", (
+        "S=256", "vocab_size=2048", "hidden=512", "kda_heads=4", "n_heads=8",
+        "n_kv_heads=1", "ffn_hidden=256", "shared_ffn_hidden=256"),
+     (0.2, 0.4)),
+])
+def test_solar_open2_s_whole_step_compiles_for_a_v5e(one_chip, what,
+                                                     overrides, need_gb):
+    """``solar_open2_250b.s4096_scan``'s whole ``run_steps`` (two staged
+    batches, AdamW, per-layer remat) compiled for the described chip as
+    ``scripts/step_memory_count.py`` compiles it, every kernel through
+    Mosaic: the step's NEED by the program's own account
+    (``memscope.need_bytes``) at the published widths is the 13.97 GB the
+    configuration's file quotes, under the 16.4 GB a step is held to (90 s:
+    ``slow``); the tiny shape of it stays in tier-1 (20 s)."""
+    count = _script("step_memory_count")
+    memscope = importlib.import_module("paddle_tpu.monitor.memscope")
+    kda = importlib.import_module("paddle_tpu.kernels.kda_chunk")
+    compiled, n_params, _ = count.count("solar_open2_250b.s4096_scan",
+                                        *overrides)
+    assert not kda._on_tpu()            # put back as ``count`` returned
+    text = compiled.as_text()
+    for kernel in ("kda_chunk_fwd", "kda_chunk_bwd", "kda_l2_heads_fwd",
+                   "kda_norm_gate_bwd", "kda_log_decay_bwd",
+                   "mamba_filter_fwd", "flash_fwd", "flash_bwd_fused",
+                   "moe_rows_sum"):
+        assert kernel in text, (what, kernel)
+    need = memscope.need_bytes(memscope.program_ledger(compiled)) / 1e9
+    assert need_gb[0] < need < need_gb[1] < 16.4, (what, need)
+    if not overrides:
+        assert n_params == 1_420_916_544
 
 
 DSA_CELL = (1, 16384, 32, 4, 128, 16, 64)   # B, S, H, Hkv, D, Hi, Di

@@ -20,10 +20,17 @@ DECAYS = {"extreme": (math.log(16.0), 0.7), "near_one": (0.0, 1e-3)}
 NAMES = ("q", "k", "v", "g", "beta")
 
 
-def _operands(S, decays, seed=1, shape=(B, H, DK, DV), dtype=jnp.float32):
+# write strengths: a sigmoid, in (0, 1); twice one, in (0, 2) (negative
+# eigenvalues: half the writes past 1); every write 1 (the state's component
+# along k REPLACED) or 1.999 (all but a reflection)
+STRENGTHS = {"under_one": None, "under_two": 2.0, "one": 1.0, "1.999": 1.999}
+
+
+def _operands(S, decays, seed=1, shape=(B, H, DK, DV), dtype=jnp.float32,
+              strength="under_one"):
     """q (L2-normalised, scaled), k (L2-normalised), v, g <= 0 and beta in
-    (0, 1) as the mixer hands them over: q, k, v in ``dtype``, g and beta
-    float32; ``shape`` = (batch, heads, dk, dv)."""
+    (0, 1) (``strength``: STRENGTHS) as the mixer hands them over: q, k, v
+    in ``dtype``, g and beta float32; ``shape`` = (batch, heads, dk, dv)."""
     b, h, dk, dv = shape
     a_log, step = DECAYS[decays]
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
@@ -36,6 +43,10 @@ def _operands(S, decays, seed=1, shape=(B, H, DK, DV), dtype=jnp.float32):
         0.3 * jax.random.normal(ks[3], (b, S, h, dk))
         + math.log(math.expm1(step)))
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, S, h)))
+    if strength == "under_two":
+        beta = 2.0 * beta
+    elif strength != "under_one":
+        beta = jnp.full_like(beta, STRENGTHS[strength])
     return tuple(a.astype(dtype) for a in (q, k, v)) + (g, beta)
 
 
@@ -72,17 +83,102 @@ def test_outputs_equal_the_recurrence(S, decays):
                                rtol=1e-4, atol=1e-5 * np.abs(want).max())
 
 
-@pytest.fixture(scope="module", params=sorted(DECAYS))
+@pytest.mark.parametrize("strength", ["under_two", "one", "1.999"])
+@pytest.mark.parametrize("decays", sorted(DECAYS))
+def test_outputs_equal_the_recurrence_at_strengths_up_to_two(decays,
+                                                             strength):
+    """``beta`` in (0, 2): the transition along ``k_t`` is ``1 - beta_t`` in
+    (-1, 1), the solve ``(I + diag(beta) P)^-1`` is as exact (``A`` is
+    nilpotent whatever its entries) and an error along ``k`` is carried with
+    its sign turned, not damped: three whole chunks and a ragged one at the
+    float32 tolerance of the strengths under 1."""
+    args = _operands(200, decays, strength=strength)
+    got = np.asarray(K.kda_chunked(*args, chunk=CHUNK, over_one=True))
+    want, _ = _recurrence64(*args)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=1e-4,
+                               atol=1e-5 * np.abs(want).max())
+    np.testing.assert_allclose(np.asarray(K.kda_recurrence(*args)), want,
+                               rtol=1e-4, atol=1e-5 * np.abs(want).max())
+
+
+def test_a_write_of_one_replaces_and_a_write_of_two_reflects():
+    """Without decay, reading the state at the key just written: ``beta`` =
+    1 returns ``v_t`` itself (what the state held along ``k_t`` is gone),
+    ``beta`` = 2 returns ``2 v_t - seen`` (it is turned round: the
+    eigenvalue -1 that ``beta`` < 2 stays short of)."""
+    q, k, v, g, _ = _operands(40, "near_one", seed=7)
+    g = jnp.zeros_like(g)
+    for strength, mix in ((1.0, 0.0), (2.0, -1.0)):
+        beta = jnp.full(k.shape[:3], strength)
+        for form in (K.kda_recurrence,
+                     lambda *a: K.kda_chunked(*a, chunk=16, over_one=True)):
+            # q = k: the state is read where it was written; ``held``: the
+            # same run with the last write left out
+            o = np.asarray(form(k, k, v, g, beta))
+            held = np.asarray(form(k, k, v, g, beta.at[:, -1].set(0.0)))
+            np.testing.assert_allclose(
+                o[:, -1], (1 - mix) * np.asarray(v)[:, -1] + mix * held[:, -1],
+                rtol=1e-4, atol=1e-5)
+
+
+@pytest.fixture(scope="module", params=[
+    (d, "under_one") for d in sorted(DECAYS)] + [("extreme", "under_two"),
+                                                  ("near_one", "1.999")],
+    ids=lambda p: p[0] if p[1] == "under_one" else "-".join(p))
 def gradients(request):
     """Of sum(o * w): by the chunked form and by the recurrence."""
     S = 200             # three whole chunks and a ragged one
-    args = _operands(S, request.param, seed=2)
+    decays, strength = request.param
+    args = _operands(S, decays, seed=2, strength=strength)
     w = jax.random.normal(jax.random.PRNGKey(9), (B, S, H, DV))
     grad = lambda fn: jax.grad(                      # noqa: E731
         lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2, 3, 4))(*args)
-    return (request.param,
-            grad(lambda *a: K.kda_chunked(*a, chunk=CHUNK)),
+    return (decays,
+            grad(lambda *a: K.kda_chunked(*a, chunk=CHUNK,
+                                          over_one=strength != "under_one")),
             grad(K.kda_recurrence))
+
+
+def _correlated_keys(S, heads=2, d=K.LANES, seed=3):
+    """Operands as the MIXER makes them: q, k and v behind a ``silu`` (a
+    positive mean: any two keys of a head at a cosine near 0.3, where
+    Gaussian ones stand at 128^-1/2), decays near 1, every write 1.999."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q, k, v = (jax.nn.silu(jax.random.normal(key, (1, S, heads, d)))
+               for key in ks[:3])
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * d ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    g = -jax.nn.softplus(0.3 * jax.random.normal(ks[3], (1, S, heads, d))
+                         + math.log(math.expm1(1e-3)))
+    return q, k, v, g, jnp.full((1, S, heads), 1.999)
+
+
+def test_the_solve_by_doubling_keeps_the_digits_the_squarings_lose():
+    """Why ``over_one`` is another solve and not another range: ``(I + A)^-1``
+    is as well conditioned at ``beta`` near 2 (its entries stay under 1), but
+    the squarings form ``A^2, A^4, .. A^32`` on the way, whose entries reach
+    thousands where the keys of a chunk lean one way (the mixer's do: a
+    ``silu`` stands before the norm) and cancel in float32 to three digits.
+    Doubling forms nothing larger than the inverse's own blocks."""
+    args = _correlated_keys(4 * CHUNK)
+    want, _ = _recurrence64(*args)
+    scale = np.abs(want).max()
+    err = lambda got: np.abs(np.asarray(got) - want).max() / scale  # noqa: E731
+    squarings = err(K.kda_chunked(*args, chunk=CHUNK))
+    assert squarings > 1e-4
+    assert err(K.kda_chunked(*args, chunk=CHUNK, over_one=True)) < 1e-5
+    assert err(_kernels(*args, over_one=True)) < 1e-5
+    assert err(_kernels(*args)) > 1e-4
+    # the inverse itself, at ONE key for the whole chunk: entries 1.999 at
+    # most, and the squarings' powers of A four thousand times that
+    k = np.tile(np.asarray(args[1])[0, :1, 0], (CHUNK, 1))
+    A = np.tril(1.999 * (k @ k.T), -1)
+    exact = np.linalg.inv(np.eye(CHUNK) + A)
+    assert np.abs(exact).max() < 2.0
+    assert np.abs(np.linalg.matrix_power(A, 16)).max() > 4e3
+    got = np.asarray(K._unit_lower_inverse(jnp.asarray(A, jnp.float32), True))
+    assert np.abs(got - exact).max() < 1e-4
 
 
 @pytest.mark.parametrize("at", range(5), ids=NAMES)
@@ -167,17 +263,19 @@ def test_kept_state_bytes_is_a_state_a_chunk_and_head():
 KB, KH, KD = 1, 2, K.LANES
 
 
-def _kernel_operands(S, decays, seed=4, dtype=jnp.float32, heads=KH):
+def _kernel_operands(S, decays, seed=4, dtype=jnp.float32, heads=KH,
+                     strength="under_one"):
     """``_operands`` at the kernels' head width."""
-    return _operands(S, decays, seed, (KB, heads, KD, KD), dtype)
+    return _operands(S, decays, seed, (KB, heads, KD, KD), dtype, strength)
 
 
-def _kernels(q, k, v, g, beta, chunk=CHUNK):
+def _kernels(q, k, v, g, beta, chunk=CHUNK, over_one=False):
     """``kda_chunk`` on operands shaped as ``kda_chunked``'s: a head a lane
     block of [b, S, heads x 128] at the kernels' door."""
     flat = lambda a: a.reshape(a.shape[:2] + (-1,))         # noqa: E731
     return K.kda_chunk(flat(q), flat(k), flat(v), flat(g), beta,
-                       heads=k.shape[2], chunk=chunk).reshape(v.shape)
+                       heads=k.shape[2], chunk=chunk,
+                       over_one=over_one).reshape(v.shape)
 
 
 # float32 operands: the kernels ARE the chunked form; bf16: both stand as far
@@ -200,22 +298,47 @@ def test_the_kernels_outputs_equal_the_chunked_form_and_the_recurrence(
         assert np.abs(got - other).max() < tol * np.abs(want).max()
 
 
+@pytest.mark.parametrize("strength,heads", [
+    ("under_two", KH), ("one", KH), ("1.999", KH), ("under_two", 64)],
+    ids=["under_two", "one", "1.999", "under_two-64_heads"])
+def test_the_kernels_outputs_at_strengths_up_to_two(strength, heads):
+    """The kernels at ``beta`` in (0, 2), at 1 and at 1.999, and at the 64
+    heads of 128 (8,192 lanes) of ``solar_open2_250b``: the chunked form's
+    numbers and the float64 recurrence's, at the tolerance of the strengths
+    under 1."""
+    args = _kernel_operands(2 * CHUNK if heads > KH else 4 * CHUNK,
+                            "near_one", seed=8, heads=heads,
+                            strength=strength)
+    got = np.asarray(_kernels(*args, over_one=True))
+    want, _ = _recurrence64(*args)
+    jnp_form = np.asarray(K.kda_chunked(*args, chunk=CHUNK, over_one=True))
+    assert np.isfinite(got).all()
+    for other in (want, jnp_form):
+        assert np.abs(got - other).max() < 1e-5 * np.abs(want).max()
+
+
 @pytest.fixture(scope="module", params=[
-    (d, t) for d in sorted(DECAYS) for t in ("float32", "bfloat16")],
-    ids=lambda p: "-".join(p))
+    (d, t, "under_one") for d in sorted(DECAYS)
+    for t in ("float32", "bfloat16")] + [
+        ("extreme", "float32", "under_two"), ("near_one", "float32", "1.999"),
+        ("near_one", "float32", "one"), ("near_one", "bfloat16", "under_two")],
+    ids=lambda p: "-".join(p[:2] if p[2] == "under_one" else p))
 def kernel_gradients(request):
     """Of sum(o * w) over four chunks (two stacks): by the kernels, by the
     chunked form and by the recurrence."""
-    decays, dtype = request.param
+    decays, dtype, strength = request.param
     S = 4 * CHUNK
-    args = _kernel_operands(S, decays, seed=5, dtype=jnp.dtype(dtype))
+    args = _kernel_operands(S, decays, seed=5, dtype=jnp.dtype(dtype),
+                            strength=strength)
     w = jax.random.normal(jax.random.PRNGKey(10), (KB, S, KH, KD))
     grad = lambda fn: jax.grad(                      # noqa: E731
         lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
         argnums=(0, 1, 2, 3, 4))(*args)
+    over_one = strength != "under_one"
     return (decays, dtype,
-            grad(_kernels),
-            grad(lambda *a: K.kda_chunked(*a, chunk=CHUNK)),
+            grad(lambda *a: _kernels(*a, over_one=over_one)),
+            grad(lambda *a: K.kda_chunked(*a, chunk=CHUNK,
+                                          over_one=over_one)),
             grad(K.kda_recurrence))
 
 
@@ -265,6 +388,8 @@ def test_the_kernels_carry_over_eight_chunks_with_a_given_state():
 
 @pytest.mark.parametrize("what,shape,dv,chunk,dtype,takes", [
     ("kimi_linear_48b_a3b.s16384_scan", (1, 16384, 32, 128), 128, 64,
+     jnp.bfloat16, True),
+    ("solar_open2_250b.s4096_scan", (1, 4096, 64, 128), 128, 64,
      jnp.bfloat16, True),
     ("float32 operands, fewer stacks than a grid step", (2, 256, 3, 128),
      128, 64, jnp.float32, True),

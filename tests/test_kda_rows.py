@@ -14,7 +14,7 @@ import pytest
 
 from paddle_tpu import monitor
 from paddle_tpu.kernels import gated_norm, kda_chunk, kda_rows as K
-from paddle_tpu.models import kimi_linear
+from paddle_tpu.models import kimi_linear, solar_open2
 from paddle_tpu.parallel import transformer as T
 
 EPS = 1e-5
@@ -184,6 +184,7 @@ def test_a_head_s_statistic_is_its_own_and_eps_holds_a_zero_row(dtype):
 
 @pytest.mark.parametrize("shape,head_dim,itemsize,takes", [
     ((1, 16384, 4096), 128, 2, True),   # kimi_linear_48b_a3b.s16384_scan
+    ((1, 4096, 8192), 128, 2, True),    # solar_open2_250b.s4096_scan, 64 heads
     ((2, 64, 512), 128, 4, True),
     ((1, 128, 128), 128, 2, True),      # one head
     ((2, 64, 32), 16, 4, False),        # the tiny configuration's heads of 16
@@ -208,6 +209,9 @@ def test_the_cell_s_geometry():
     a grid step walked 128 rows a turn (PERF.md section 6, PR 60, has the
     geometries tried); the widest backward's blocks within 20 MiB."""
     assert K.geometry(16384, 4096, 2) == (1024, 128, 512)
+    # solar_open2_250b.s4096_scan: 64 heads are sixteen lane blocks of the
+    # same four, four grid steps of rows: the same blocks, the same VMEM
+    assert K.geometry(4096, 8192, 2) == (1024, 128, 512)
     assert max(K.vmem_bytes(part, 1024, 512, 2) for part in K.PARTS) \
         == K.vmem_bytes("norm_gate", 1024, 512, 2) < 20 * 2 ** 20
 
@@ -241,6 +245,7 @@ def _mixer_leaves(cfg, seed=None):
 # configuration's heads of 16; heads of 128 at 60 positions (no whole stack)
 ENGAGED = {
     "kimi_linear_48b_a3b.s16384_scan": (None, 1, 16384, 1),
+    "solar_open2_250b.s4096_scan": ("solar", 1, 4096, 1),
     "tiny": (dict(), 2, 64, 0),
     "tiny, a head 128 wide": (dict(kda_heads=2, kda_head_dim=128,
                                    kda_chunk=64, max_seq=128), 1, 128, 1),
@@ -252,8 +257,12 @@ ENGAGED = {
 @pytest.mark.parametrize("what", list(ENGAGED))
 def test_which_shapes_take_the_flat_path(what):
     kw, b, S, fused = ENGAGED[what]
-    cfg = kimi_linear.kimi_linear_48b_a3b_config(n_layers=5) if kw is None \
-        else kimi_linear.kimi_linear_tiny_config(**kw)
+    if kw == "solar":       # 64 heads of 128, strengths in (0, 2)
+        cfg = solar_open2.solar_open2_250b_config(n_layers=4)
+        assert (cfg.kda_heads, cfg.kda_beta_scale) == (64, 2.0)
+    else:
+        cfg = kimi_linear.kimi_linear_48b_a3b_config(n_layers=5) \
+            if kw is None else kimi_linear.kimi_linear_tiny_config(**kw)
     pl = _mixer_leaves(cfg)
     h = jax.ShapeDtypeStruct((b, S, cfg.hidden), cfg.jdtype)
     trace = lambda: jax.eval_shape(                     # noqa: E731
@@ -262,18 +271,29 @@ def test_which_shapes_take_the_flat_path(what):
     assert trace().shape == h.shape     # off the monitor: nothing counts
 
 
-def test_kda_mixer_gives_the_lines_numbers_either_way(monkeypatch):
+@pytest.mark.parametrize("beta_scale", [1.0, 2.0],
+                         ids=["strengths under 1", "strengths under 2"])
+def test_kda_mixer_gives_the_lines_numbers_either_way(monkeypatch,
+                                                      beta_scale):
     """The mixer at two heads of 128, output and the gradients of its input
     and of every leaf, on the flat path and with ``kda_rows.supported``
     patched false (the ``jnp`` lines around ``kda_chunked``): the same
-    numbers, and the counter reads ``fused=1`` and ``fused=0``."""
+    numbers, and the counter reads ``fused=1`` and ``fused=0``; at
+    ``kda_beta_scale`` 2 (``w_beta`` steep enough that the strengths reach
+    both ends of (0, 2)) as at 1."""
     cfg = kimi_linear.kimi_linear_tiny_config(
-        kda_heads=2, kda_head_dim=128, kda_chunk=64, max_seq=128)
+        kda_heads=2, kda_head_dim=128, kda_chunk=64, max_seq=128,
+        kda_beta_scale=beta_scale)
     pl = _mixer_leaves(cfg, seed=11)
+    if beta_scale > 1:
+        pl["w_beta"] = 4.0 * pl["w_beta"]
     pl["o_norm"] = 1.0 + 0.2 * jax.random.normal(
         jax.random.PRNGKey(3), pl["o_norm"].shape)
     h = jax.random.normal(jax.random.PRNGKey(12), (1, 128, cfg.hidden))
     g = jax.random.normal(jax.random.PRNGKey(13), h.shape)
+    if beta_scale > 1:
+        beta = T.kda_write_strength(pl, h, cfg)
+        assert float(beta.min()) < 0.2 and float(beta.max()) > 1.8
 
     def run():
         return jax.value_and_grad(lambda pl, h: jnp.sum(
