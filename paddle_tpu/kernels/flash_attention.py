@@ -13,19 +13,31 @@ models use (``flash_attention_packed``: the projections' own layout, each
 cannot tile.
 
 Several kv blocks (S above the block size): a grid row is one (batch row,
-head-block) pair and a grid step one (q block, kv block) pair of
+key/value head-block) pair and a grid step one (q block, kv block) tile of
 ``step_table``, built on the host from the shapes and the mask and read by
 the index maps as scalar-prefetch operands: only the pairs the mask lets
 something through are steps (the triangle under the diagonal, a window's
-band, the rectangle).  The running max
+band, the rectangle).  A step holds ``heads_a_step`` query head-blocks of
+the key/value head-block's group, looped inside it (PR 68; one where the
+queries are not grouped, which is the step as it always was): their q and o
+blocks are adjacent lane blocks of the packed array (one DMA), k and v
+arrive once a step for all of them, the tile's mask is built once, and the
+heads' chains (matmul, reduce, ``exp``, matmul) stand side by side in the
+body for the compiler to interleave; ``heads`` is the largest divisor of
+the group whose step fits SWEEP_VMEM, from the shapes alone, and the
+group's other chunks are positions of the grid (forward) or further sweeps
+of the same grid row (backward).  The running max
 (m), denominator (l) and output accumulator live in VMEM scratch across a q
-block's sweep (the standard TPU flash schedule).  The backward recomputes
+block's sweep, a slot a head (the standard TPU flash schedule).  The
+backward recomputes
 the probabilities blockwise from the saved row logsumexp, in ONE kernel
 (``flash_bwd_fused``): a grid row is a (batch row, key/value head-block)
-pair, its steps the forward's table over each query head-block of the
-group in turn, and a step computes one score tile, one ``exp`` and from
+pair, its steps the forward's table over each chunk of the group's query
+head-blocks in turn, and a step computes of each of its heads one score
+tile, one ``exp`` and from
 them all three products (5 matmuls): dq into the q sweep's scratch, dk and
-dv into rows ``kv block`` of two float32 accumulators that hold the WHOLE
+dv, summed over the step's heads, ONCE a step into rows ``kv block`` of two
+float32 accumulators that hold the WHOLE
 sequence in VMEM ([Sk, lanes] each: 16 MiB at S = 16,384), which sum over
 the group because they are the key/value head's own, and leave once at the
 grid row's last step.  That runs wherever the accumulators and their output
@@ -66,9 +78,10 @@ Two more modes of the packed entry, both of the same kernels:
   last step puts the two heads side by side again (``_unstack_heads``).
   Only the one-block forward still selects the half of k and v a head.
   The backward runs once per KEY/VALUE lane block and its table walks the
-  query blocks that read it, so dk and dv are summed over the group in the
-  kernel's accumulators.  One (row, head-block) pair a grid row whatever S
-  is, and the several-block backward even at one block.
+  query blocks that read it (``heads_a_step`` of them a step, each stacked
+  on rows of its own), so dk and dv are summed over the group in the
+  kernel's accumulators.  One (row, key/value head-block) pair a grid row
+  whatever S is, and the several-block backward even at one block.
 - a sliding window (``window`` = W < S, causal): query i sees keys j with
   i - W < j <= i.  The sweeps' tables hold the BAND (at most 9 kv blocks of
   512 a q block for W = 4096, not S / 512), and the kernels carry names of
@@ -210,18 +223,67 @@ def step_geometry(B, S, n_head_blocks, lanes, itemsize):
     return G, Hg
 
 
-def fused_sweep_vmem_bytes(Sk, lanes, itemsize, v_lanes=None):
+def heads_a_step(group, vmem_bytes):
+    """The query head-blocks of a group that ride one grid step of a
+    several-block sweep: the most (a divisor of the group) whose
+    ``vmem_bytes(heads)`` fits SWEEP_VMEM, and one where none does (a step
+    of one lives in what its call asks anyway).  From the shapes alone; the
+    group's other heads are further sweeps of the same grid row
+    (``step_table``'s ``group``) or positions of the grid's own axis."""
+    fit = [n for n in _divisors(group) if vmem_bytes(n) <= SWEEP_VMEM]
+    return fit[-1] if fit else 1
+
+
+def past_scoped(need, least=SCOPED_VMEM):
+    """``vmem_limit_bytes`` where a kernel needs more than ``least`` (what
+    it has without asking), else nothing: the call stays as it was."""
+    return {"vmem_limit_bytes": int(need)} if need > least else {}
+
+
+def fwd_sweep_vmem_bytes(heads, lanes, itemsize, v_lanes=None, halves=1,
+                         bq=512, bk=512):
+    """What a several-block forward asks of VMEM at ``heads`` query
+    head-blocks a step: their q and o blocks and the k and v block twice
+    each, their statistic twice (an output block, a column a head padded to
+    a lane tile), their running max, denominator and accumulator (and the
+    stack of q where ``halves`` heads of a lane block ride stacked), and
+    four [rows, bk] float32 values of a step's own and one more a head
+    (Mosaic keeps about one a head that it interleaves: 6.5 MiB of them at
+    6 heads of 128, 8.3 at 8, 15.8 at 16, by its own count for a v5e)."""
+    vw, rows = lanes if v_lanes is None else v_lanes, halves * bq
+    return (2 * (heads * bq + bk) * (lanes + vw) * itemsize
+            + 2 * heads * bq * LANES * 4
+            + heads * rows * (2 * LANES + vw) * 4
+            + (halves > 1) * heads * rows * lanes * itemsize
+            + (4 + heads) * rows * bk * 4)
+
+
+def fused_sweep_vmem_bytes(Sk, lanes, itemsize, v_lanes=None, heads=1,
+                           halves=1, bq=512, bk=512):
     """What ``flash_bwd_fused`` over several blocks asks of VMEM at a
     key/value length of Sk and head-blocks ``lanes`` wide (the values'
     ``v_lanes``, where they have a width of their own): the two float32
     accumulators that hold dk and dv of the whole sequence, their two output
-    blocks (one buffer each: they leave once a grid row), and Mosaic's own
-    scope for what a step holds, which is what the two sweeps' steps live in
-    (double-buffered [512, lanes] operand blocks, the [512, 512] tiles)."""
+    blocks (one buffer each: they leave once a grid row), and what a step
+    holds.  One query head-block a step: Mosaic's own scope, which is what
+    the two sweeps' steps live in (double-buffered [512, lanes] operand
+    blocks, the [512, 512] tiles).  ``heads`` of a group a step: their
+    blocks of q, dq and do twice each and dq's float32 scratch, their
+    ``lse`` and ``delta`` (a column a head padded to a lane tile) twice, k
+    and v twice, the stack's scratch where ``halves`` heads of a lane block
+    ride stacked, and six [rows, bk] float32 values of a step's own (Mosaic
+    keeps 3.9 MiB of them at 6, 7, 8 and 16 heads of 128 alike, by its own
+    count for a v5e)."""
     # narrow blocks pad to a tile
-    width = max(lanes, LANES) + max(lanes if v_lanes is None else v_lanes,
-                                    LANES)
-    return Sk * width * 4 + Sk * width * itemsize + SCOPED_VMEM
+    lanes, vw = max(lanes, LANES), max(lanes if v_lanes is None else v_lanes,
+                                       LANES)
+    width, rows = lanes + vw, halves * bq
+    step = SCOPED_VMEM if heads == 1 else (
+        heads * bq * (2 * (lanes + width) * itemsize + 2 * 2 * LANES * 4)
+        + heads * rows * lanes * 4 + 2 * bk * width * itemsize
+        + (halves > 1) * heads * rows * (width * itemsize + 2 * LANES * 4)
+        + 6 * rows * bk * 4)
+    return Sk * width * 4 + Sk * width * itemsize + step
 
 
 def bwd_sweeps(Sk, bk, lanes, itemsize, group=1, v_lanes=None):
@@ -237,10 +299,12 @@ def bwd_sweeps(Sk, bk, lanes, itemsize, group=1, v_lanes=None):
 
 
 def grid_geometry(B, S, Sk, n_head_blocks, lanes, itemsize, bq, bk, group=1):
-    """(G, Hg, grid steps along the batch/head axis) for blocks of bq x bk:
-    ``step_geometry`` where the sequence is one block both ways, one
-    (row, head-block) pair a step otherwise (and wherever ``group`` query
-    heads share a key/value head)."""
+    """(G, Hg, (row, head-block) pairs over the steps' G x Hg) for blocks of
+    bq x bk: ``step_geometry`` where the sequence is one block both ways;
+    otherwise (and wherever ``group`` query heads share a key/value head)
+    the blocks are of one row and the several-block sweeps' steps hold
+    ``heads_a_step`` head-blocks of a group (``_Geom.heads_in_step``), one
+    where the queries are not grouped."""
     G, Hg = (step_geometry(B, max(S, Sk), n_head_blocks, lanes, itemsize)
              if S == bq and Sk == bk and group == 1 else (1, 1))
     return G, Hg, (B // G) * (n_head_blocks // Hg)
@@ -260,12 +324,16 @@ def step_table(S, Sk, bq, bk, causal, window=None, group=1, kv_major=False):
 
     q-major (the forward and the dq sweep, whose grids hold the group as an
     axis and ask for ``group`` 1; the fused backward, whose table walks it):
-    the ``group`` query head-blocks of a key/value head-block in turn
-    (``head``), of each its q blocks, of each its kv blocks ascending.
+    the ``group`` chunks of a key/value head-block's query head-blocks in
+    turn (``head``: a chunk is the ``heads_a_step`` head-blocks one grid
+    step holds, so ``group`` here is the callers' group over that; one
+    head-block a chunk in the two sweeps), of each its q blocks, of each its
+    kv blocks ascending.
     kv-major (the dk/dv sweep): by kv block, then the query head-blocks that
     read it, then q block ascending, so the sum over the group stays in the
     kernel's scratch.  Either way a kv block meets its (head, q block) pairs
-    in the same order, head first: the order dk and dv are summed in.
+    in the same order, head first: the order dk and dv are summed in (inside
+    a chunk, its heads first).
     Flags: FIRST and LAST open and close a sweep (zero the scratch, write the
     output block).  Every step masks its scores: a third flag for the blocks
     the mask cuts, with an unmasked body for the others, was slower on the
@@ -296,19 +364,23 @@ def kv_blocks(S, bq, bk, causal=True, window=None):
 
 
 def packed_grid(B, S, n_heads, head_dim, block_q, block_k, itemsize=2,
-                n_kv_heads=None, causal=False, window=None):
+                n_kv_heads=None, causal=False, window=None, part="fwd"):
     """What ``flash_attention_packed`` runs for these shapes, for whoever
     wants to say so without tracing it (the tests, ``scripts/``):
-    (pairs per grid step, grid steps of one layer's forward pass).  Several
-    blocks: a (row, head-block) pair times its sweeps' ``step_table``."""
-    hpb = _heads_per_block(head_dim)
+    (pairs per grid step, grid steps of one layer's forward pass; ``part``
+    "bwd": of its backward, where that is one kernel).  Several blocks: a
+    step is a tile of ``step_table`` for a (row, key/value head-block) pair
+    and the ``heads_a_step`` query head-blocks of its group that ride it."""
     bq, bk = min(block_q, S), min(block_k, S)
-    G, Hg, steps = grid_geometry(B, S, S, n_heads // hpb, head_dim * hpb,
-                                 itemsize, bq, bk,
-                                 n_heads // (n_kv_heads or n_heads))
-    if S == bk:
-        return G * Hg, steps * (S // bq)
-    return 1, steps * kv_blocks(S, bq, bk, causal, window)
+    # shapes and an element size are all the geometry reads of q and k
+    g = _Geom(*(jax.ShapeDtypeStruct((B, S, n * head_dim),
+                                     np.dtype("V%d" % itemsize))
+                for n in (n_heads, n_kv_heads or n_heads)),
+              n_heads, bq, bk, n_kv_heads, window)
+    if S == bk and part == "fwd":
+        return g.G * g.Hg, g.grid_b * (S // bq)
+    heads, _ = g.heads_in_step(part)
+    return heads, g.grid_b // heads * kv_blocks(S, bq, bk, causal, window)
 
 
 class _Geom:
@@ -317,7 +389,9 @@ class _Geom:
     addressed by the BlockSpec index maps, so the model never materializes a
     [B, H, S, D] transpose (the r2 wrapper's main HBM cost).  A block is G
     rows of the leading axis by Hg head-blocks (``grid_geometry``; 1 by 1
-    wherever the sequence is more than one block).
+    wherever the sequence is more than one block, where a grouped step's q,
+    o and statistics blocks are ``heads_in_step`` head-blocks of a group
+    wide: ``q_spec(.., heads)``).
 
     ``Hkv`` < H: grouped queries, k and v hold Hkv heads and q head h reads
     kv head ``h // group``.  ``window`` (None: none) and the causal mask
@@ -354,6 +428,7 @@ class _Geom:
         assert self.Dv == self.D or (self.hpb == self.group == 1
                                      and self.Dv % LANES == 0), (self.D, Dv)
         self.vw = self.Dv * self.hpb  # of a value's
+        self.bq, self.bk, self.itemsize = bq, bk, q.dtype.itemsize
         self.G, self.Hg, self.grid_b = grid_geometry(
             B, self.S, self.Sk, self.Hb, self.qw, q.dtype.itemsize, bq, bk,
             self.group)
@@ -368,6 +443,19 @@ class _Geom:
         # stats are 4-D so the block's last dim equals the array's (Mosaic
         # tiling rule): [row, head-block group, S, heads of the group]
         self.stat_shape = (B, self.Hb // self.Hg, self.S, self.Hg * self.hpb)
+
+    def heads_in_step(self, part):
+        """(query head-blocks of a group a grid step holds, the bytes of
+        VMEM such a step's call needs) of the several-block forward
+        (``part`` "fwd") or one-sweep backward ("bwd"): ``heads_a_step``
+        of the shapes."""
+        sizes = dict(itemsize=self.itemsize, v_lanes=self.vw,
+                     halves=self.halves, bq=self.bq, bk=self.bk)
+        need = functools.partial(fwd_sweep_vmem_bytes, lanes=self.qw, **sizes)\
+            if part == "fwd" else functools.partial(
+                fused_sweep_vmem_bytes, self.Sk, self.qw, **sizes)
+        heads = heads_a_step(self.group, lambda n: need(heads=n))
+        return heads, need(heads=heads)
 
     def kv_half(self, q_block):
         """Which head of its key/value lane block a query head-block reads;
@@ -393,47 +481,56 @@ class _Geom:
         n = self.Hb // self.Hg
         return lambda b, i, j=0: (b // n, b % n, i, 0)
 
-    def sweep_maps(self, walks_group=False):
+    def sweep_maps(self, heads=1, walks_group=False):
         """(q rows, kv rows, row statistics) index maps of a several-block
-        sweep.  Its grid is (batch row, key/value head-block, query
-        head-block of that one's group, step t of its ``step_table``), or
-        without the third axis where the table walks the group
-        (``head_of``: the dk/dv sweep and the fused backward, which sum
-        over it); the table's columns arrive as scalar-prefetch
-        operands.  No map divides: on the chip a (row, head-block) pair
-        unpacked from one grid index by ``//`` and ``%`` cost each of the
-        sweep's steps 30 to 60 ns (PERF.md section 6, PR 34)."""
+        sweep whose step holds ``heads`` query head-blocks of a group (one
+        block of the q map, ``heads`` head-blocks wide; ``heads`` rows of
+        the statistics').  Its grid is (batch row, key/value head-block,
+        chunk of ``heads`` of that one's group, step t of its
+        ``step_table``), or without the third axis where the table walks
+        the chunks (``head_of``: the dk/dv sweep and the fused backward,
+        which sum over the group); the table's columns arrive as
+        scalar-prefetch operands.  No map divides: on the chip a
+        (row, head-block) pair unpacked from one grid index by ``//`` and
+        ``%`` cost each of the sweep's steps 30 to 60 ns (PERF.md section 6,
+        PR 34)."""
+        chunks = self.group // heads
+
         def at(pick):
             if walks_group:
                 return lambda r, kh, t, q_of, kv_of, head_of, flags: pick(
-                    r, kh, kh * self.group + head_of[t], q_of[t], kv_of[t])
+                    r, kh, kh * chunks + head_of[t], q_of[t], kv_of[t])
             return lambda r, kh, g, t, q_of, kv_of, head_of, flags: pick(
-                r, kh, kh * self.group + g, q_of[t], kv_of[t])
+                r, kh, kh * chunks + g, q_of[t], kv_of[t])
 
         return (at(lambda r, kh, qh, i, j: (r, i, qh)),
                 at(lambda r, kh, qh, i, j: (r, j, kh)),
                 at(lambda r, kh, qh, i, j: (r, qh, i, 0)))
 
-    def step(self, head_of=None):
-        """(t, query head-block) of a sweep's grid position; ``head_of``:
-        of a sweep whose table walks the group."""
+    def step(self, heads=1, head_of=None):
+        """(t, chunk) of a sweep's grid position: the step's query
+        head-blocks are ``chunk * heads`` and the ``heads - 1`` behind it;
+        ``head_of``: of a sweep whose table walks the chunks."""
+        chunks = self.group // heads
         if head_of is None:
             return pl.program_id(3), \
-                pl.program_id(1) * self.group + pl.program_id(2)
+                pl.program_id(1) * chunks + pl.program_id(2)
         t = pl.program_id(2)
-        return t, pl.program_id(1) * self.group + head_of[t]
+        return t, pl.program_id(1) * chunks + head_of[t]
 
-    def q_spec(self, bq, index_map=None):
-        return pl.BlockSpec((self.G, bq, self.Hg * self.qw),
+    def q_spec(self, bq, index_map=None, heads=1):
+        """``heads``: the query head-blocks of a several-block sweep's step
+        (``heads_a_step``), adjacent lane blocks of the packed array."""
+        return pl.BlockSpec((self.G, bq, heads * self.Hg * self.qw),
                             index_map or self.qmap())
 
     def kv_spec(self, bk, index_map=None):
         return pl.BlockSpec((self.G, bk, self.Hg * self.qw),
                             index_map or self.kmap())
 
-    def o_spec(self, bq, index_map=None):
+    def o_spec(self, bq, index_map=None, heads=1):
         """Of o and do: q's rows at the values' width."""
-        return pl.BlockSpec((self.G, bq, self.Hg * self.vw),
+        return pl.BlockSpec((self.G, bq, heads * self.Hg * self.vw),
                             index_map or self.qmap())
 
     def v_spec(self, bk, index_map=None):
@@ -441,8 +538,8 @@ class _Geom:
         return pl.BlockSpec((self.G, bk, self.Hg * self.vw),
                             index_map or self.kmap())
 
-    def stat_spec(self, bq, index_map=None):
-        return pl.BlockSpec((self.G, 1, bq, self.Hg * self.hpb),
+    def stat_spec(self, bq, index_map=None, heads=1):
+        return pl.BlockSpec((self.G, heads, bq, self.Hg * self.hpb),
                             index_map or self.smap())
 
 
@@ -515,39 +612,58 @@ def _stack_stat(stat, hpb):
          for hh in range(hpb)], axis=0)
 
 
-def _stacked(q_ref, do_ref, lse_ref, delta_ref, hpb, D, half):
-    """(q, do, lse, delta) of a backward step's q block, stacked."""
-    return (_stack_heads(q_ref[0], hpb, D, half),
-            _stack_heads(do_ref[0], hpb, D, half),
-            _stack_stat(lse_ref[0, 0], hpb), _stack_stat(delta_ref[0, 0], hpb))
+def _cols(n, width):
+    """Columns of the n-th of adjacent blocks ``width`` wide."""
+    return slice(n * width, (n + 1) * width)
 
 
-def _stack_sweep(stk, *refs):
-    """A backward q sweep's first step: ``_stacked`` into the scratch."""
-    for scr, value in zip(stk, _stacked(*refs)):
-        scr[:] = value
+def _stacked(q_ref, do_ref, lse_ref, delta_ref, hpb, D, half, h=0):
+    """(q, do, lse, delta) of head-block ``h`` of a backward step's q block,
+    stacked."""
+    qw = hpb * D
+    return (_stack_heads(q_ref[0, :, _cols(h, qw)], hpb, D, half),
+            _stack_heads(do_ref[0, :, _cols(h, qw)], hpb, D, half),
+            _stack_stat(lse_ref[0, h], hpb), _stack_stat(delta_ref[0, h], hpb))
+
+
+def _stack_sweep(stk, *refs, halves):
+    """A backward q sweep's first step: ``_stacked`` into the scratch, the
+    step's head-blocks (one of ``halves`` each) one under the other."""
+    rows = stk[0].shape[0] // len(halves)
+    for h, half in enumerate(halves):
+        for scr, value in zip(stk, _stacked(*refs, half, h)):
+            scr[_cols(h, rows)] = value
 
 
 def _bwd_tiles(tile, stk, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               hpb, bq, bk):
-    """A backward step's tiles, ``tile(q, k, v, do, lse, delta, cs, vs,
-    wrap)``: ONE over the stack ``stk`` (q, do, lse, delta: ``_stack_sweep``'s
+               hpb, bq, bk, heads=1):
+    """A backward step's tiles, ``tile(q, k, v, do, lse, delta, cs, vs, qs,
+    last, wrap)``, of its ``heads`` query head-blocks in turn: of each ONE
+    over its rows of the stack ``stk`` (q, do, lse, delta: ``_stack_sweep``'s
     scratch or ``_stacked``'s values) against the whole k and v lane blocks,
-    or, with no stack, a head at a time on its own columns ``cs`` (of v and
-    do: ``vs``).  lse and delta go
-    over as thunks, so ``tile`` reads them where it uses them."""
-    if stk:
-        q, do, lse, delta = stk
-        tile(q[:], k_ref[0], v_ref[0], do[:], lambda: _lanes_to(lse[:], bk),
-             lambda: _lanes_to(delta[:], bk), slice(None), slice(None),
-             wrap=bq)
-        return
-    D, Dv = q_ref.shape[-1] // hpb, v_ref.shape[-1] // hpb
-    for hh in range(hpb):
-        cs, vs = slice(hh * D, (hh + 1) * D), slice(hh * Dv, (hh + 1) * Dv)
-        tile(q_ref[0][:, cs], k_ref[0][:, cs], v_ref[0][:, vs],
-             do_ref[0][:, vs], lambda: lse_ref[0, 0][:, hh:hh + 1],
-             lambda: delta_ref[0, 0][:, hh:hh + 1], cs, vs)
+    or, with no stack, a head at a time on its own columns ``cs`` of k (of v:
+    ``vs``) and ``qs`` of the step's q block (where its dq lies).  ``last``:
+    no further head of the step lands in these columns of dk and dv.  lse
+    and delta go over as thunks, so ``tile`` reads them where it uses
+    them."""
+    D, Dv = k_ref.shape[-1] // hpb, v_ref.shape[-1] // hpb
+    for h in range(heads):
+        last = h == heads - 1
+        if stk:
+            (q, do, lse, delta), rows = stk, _cols(h, hpb * bq)
+            tile(q[rows], k_ref[0], v_ref[0], do[rows],
+                 lambda: _lanes_to(lse[rows], bk),
+                 lambda: _lanes_to(delta[rows], bk), slice(None), slice(None),
+                 _cols(h, hpb * D), last, wrap=bq)
+            continue
+        for hh in range(hpb):
+            cs, vs, n = _cols(hh, D), _cols(hh, Dv), h * hpb + hh
+            # of the wide blocks, the head-block's lanes alone are read
+            tile(q_ref[0, :, _cols(h, hpb * D)][:, cs], k_ref[0][:, cs],
+                 v_ref[0][:, vs], do_ref[0, :, _cols(h, hpb * Dv)][:, vs],
+                 lambda: lse_ref[0, h][:, hh:hh + 1],
+                 lambda: delta_ref[0, h][:, hh:hh + 1], cs, vs, _cols(n, D),
+                 last)
 
 
 def _seen(shape, q0, k0, window, wrap=None):
@@ -562,13 +678,21 @@ def _seen(shape, q0, k0, window, wrap=None):
     return (qpos >= kpos) & (qpos - kpos < window)
 
 
-def _scores(q, k, scale, causal, q0, k0, window=None, wrap=None):
+def _scores(q, k, scale, causal, q0, k0, window=None, wrap=None, seen=None):
     """[bq, bk] f32 scaled scores of one head (of ``_stack_heads``' rows:
-    ``wrap``), future positions (and those behind the window) masked."""
+    ``wrap``), future positions (and those behind the window) masked.
+    ``seen`` (a list, empty at first): the mask of a grid step's tile, built
+    by the first of the step's heads and shared by the others."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if causal:
-        s = jnp.where(_seen(s.shape, q0, k0, window, wrap), s, NEG_INF)
+        if seen:
+            mask = seen[0]
+        else:
+            mask = _seen(s.shape, q0, k0, window, wrap)
+            if seen is not None:
+                seen.append(mask)
+        s = jnp.where(mask, s, NEG_INF)
     return s
 
 
@@ -621,34 +745,47 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, bq,
 
 def _fwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, o_ref,
                       lse_ref, m_scr, l_scr, acc_scr, *q_stk, scale, causal,
-                      bq, bk, hpb, geom):
-    """Several kv blocks, one (row, head-block) pair a grid row and one
-    (q block, kv block) pair a step (``step_table``): running max,
-    denominator and accumulator in scratch across a q block's sweep.
+                      bq, bk, hpb, heads, geom):
+    """Several kv blocks.  A grid row is a (batch row, key/value head-block)
+    pair and a step one (q block, kv block) tile of ``step_table`` with
+    ``heads`` query head-blocks of that key/value block's group looped
+    inside (``heads_a_step``; 1 where the queries are not grouped): the k
+    and v block arrive once a step for all of them, the mask of the tile is
+    built once and shared, and the heads' chains (product, max, ``exp``,
+    product) stand side by side for the compiler to interleave.  Running
+    max, denominator and accumulator live in scratch across a q block's
+    sweep, a slot of lanes a head.
 
     Where the heads of the lane block read one key/value head
     (``geom.halves`` > 1) they ride the sweep stacked along rows: q is
     restacked once, at the sweep's first step (``_stack_heads`` into
-    ``q_stk``), a step is ONE [hpb * bq, bk] tile against the whole k and v
-    lane blocks, the statistics a row of the stack each, and the last step
-    puts the heads side by side again."""
-    D, Dv = q_ref.shape[-1] // hpb, v_ref.shape[-1] // hpb
-    t, q_block = geom.step()
-    half = geom.kv_half(q_block)
+    ``q_stk``, a head-block under the other), a head-block's tile is ONE
+    [hpb * bq, bk] tile against the whole k and v lane blocks, the
+    statistics a row of the stack each, and the last step puts the heads
+    side by side again."""
+    D, Dv = k_ref.shape[-1] // hpb, v_ref.shape[-1] // hpb
+    qw, vw, rows = hpb * D, hpb * Dv, m_scr.shape[0]
+    t, chunk = geom.step(heads)
+    half = [geom.kv_half(chunk * heads + h) for h in range(heads)] \
+        if q_stk else None
 
     @pl.when((flags[t] & FIRST) != 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
-        if q_stk:
-            q_stk[0][:] = _stack_heads(q_ref[0], hpb, D, half)
+        for h in range(heads if q_stk else 0):
+            q_stk[0][_cols(h, rows)] = _stack_heads(
+                q_ref[0, :, _cols(h, qw)], hpb, D, half[h])
 
-    def tile(q, cs, vs, ls, wrap=None):
+    seen = []       # the tile's mask, the step's heads' own
+
+    def tile(q, cs, vs, ls, os, wrap=None):
         """The rows of q against columns ``cs`` of this kv block's keys and
-        ``vs`` of its values."""
+        ``vs`` of its values: the statistics at lanes ``ls`` of their
+        scratch, the accumulator at columns ``os``."""
         s = _scores(q, k_ref[0][:, cs], scale, causal, q_of[t] * bq,
-                    kv_of[t] * bk, geom.window, wrap)  # [rows, bk]
+                    kv_of[t] * bk, geom.window, wrap, seen)  # [rows, bk]
 
         m_prev = m_scr[:, ls]                          # [rows, LANES]
         m_cur = jnp.max(s, axis=1)[:, None]            # [rows, 1]
@@ -656,7 +793,7 @@ def _fwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, o_ref,
         p = jnp.exp(s - _lanes_to(m_new, bk))          # [rows, bk] f32
         alpha = jnp.exp(m_prev - m_new)                # [rows, LANES]
         l_scr[:, ls] = l_scr[:, ls] * alpha + jnp.sum(p, axis=1)[:, None]
-        acc_scr[:, vs] = acc_scr[:, vs] * _lanes_to(
+        acc_scr[:, os] = acc_scr[:, os] * _lanes_to(
             alpha, q.shape[1] // D * Dv) + jax.lax.dot_general(
                 p.astype(v_ref.dtype), v_ref[0][:, vs],
                 (((1,), (0,)), ((), ())),
@@ -664,33 +801,38 @@ def _fwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, o_ref,
             )
         m_scr[:, ls] = m_new
 
-    if q_stk:
-        tile(q_stk[0][:], slice(None), slice(None), slice(None), wrap=bq)
-    else:
+    for h in range(heads):
+        if q_stk:
+            tile(q_stk[0][_cols(h, rows)], slice(None), slice(None),
+                 _cols(h, LANES), _cols(h, vw), wrap=bq)
+            continue
+        qb = q_ref[0, :, _cols(h, qw)]      # the head-block's lanes alone
         for hh in range(hpb):
-            tile(q_ref[0][:, hh * D:(hh + 1) * D],
-                 slice(hh * D, (hh + 1) * D), slice(hh * Dv, (hh + 1) * Dv),
-                 slice(hh * LANES, (hh + 1) * LANES))
+            tile(qb[:, _cols(hh, D)], _cols(hh, D), _cols(hh, Dv),
+                 _cols(h * hpb + hh, LANES), _cols(h * hpb + hh, Dv))
 
     @pl.when((flags[t] & LAST) != 0)
     def _final():
         l = jnp.maximum(l_scr[:], 1e-30)
-        if q_stk:
-            o_ref[0] = _unstack_heads(
-                acc_scr[:] / _lanes_to(l, hpb * D), hpb, D, half
-            ).astype(o_ref.dtype)
-            lse = m_scr[:, :1] + jnp.log(l[:, :1])     # [hpb * bq, 1]
-            lse_ref[0, 0] = jnp.concatenate(
-                [lse[hh * bq:(hh + 1) * bq] for hh in range(hpb)], axis=1)
-            return
-        alpha_cols = jnp.concatenate(
-            [_lanes_to(l[:, hh * LANES:(hh + 1) * LANES], D)
-             for hh in range(hpb)], axis=1) if hpb > 1 else _lanes_to(l, Dv)
-        o_ref[0] = (acc_scr[:] / alpha_cols).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.concatenate(
-            [m_scr[:, hh * LANES:hh * LANES + 1]
-             + jnp.log(l[:, hh * LANES:hh * LANES + 1]) for hh in range(hpb)],
-            axis=1)
+        for h in range(heads):
+            if q_stk:
+                lh = l[:, _cols(h, LANES)]
+                o_ref[0, :, _cols(h, vw)] = _unstack_heads(
+                    acc_scr[:, _cols(h, vw)] / _lanes_to(lh, vw), hpb, D,
+                    half[h]).astype(o_ref.dtype)
+                lse = m_scr[:, h * LANES:h * LANES + 1] + jnp.log(lh[:, :1])
+                lse_ref[0, h] = jnp.concatenate(       # [hpb * bq, 1] each
+                    [lse[hh * bq:(hh + 1) * bq] for hh in range(hpb)], axis=1)
+                continue
+            at = [_cols(h * hpb + hh, LANES) for hh in range(hpb)]
+            alpha_cols = jnp.concatenate(
+                [_lanes_to(l[:, ls], D) for ls in at], axis=1) \
+                if hpb > 1 else _lanes_to(l[:, at[0]], Dv)
+            o_ref[0, :, _cols(h, vw)] = (
+                acc_scr[:, _cols(h, vw)] / alpha_cols).astype(o_ref.dtype)
+            lse_ref[0, h] = jnp.concatenate(
+                [m_scr[:, ls.start:ls.start + 1]
+                 + jnp.log(l[:, ls.start:ls.start + 1]) for ls in at], axis=1)
 
 
 def _name(kernel, g):
@@ -699,11 +841,12 @@ def _name(kernel, g):
 
 
 def _sweep_call(kernel, g, table, operands, in_specs, out_specs, out_shape,
-                scratch_shapes, walks_group, interpret, name, **params):
+                scratch_shapes, chunks, interpret, name, **params):
     """One several-block sweep over the steps of ``table`` (its columns
-    scalar-prefetched), for the grid positions ``_Geom.sweep_maps`` names;
-    ``params``: further compiler parameters."""
-    heads = (g.Hb // g.group,) + (() if walks_group else (g.group,))
+    scalar-prefetched), for the grid positions ``_Geom.sweep_maps`` names
+    (``chunks`` of a group's query head-blocks as a grid axis; None: the
+    table walks them); ``params``: further compiler parameters."""
+    heads = (g.Hb // g.group,) + (() if chunks is None else (chunks,))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -730,20 +873,23 @@ def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None, Hkv=None,
         jax.ShapeDtypeStruct(g.stat_shape, jnp.float32),
     ]
     if not g.one_block:
-        qm, km, sm = g.sweep_maps()
+        heads, need = g.heads_in_step("fwd")
+        _count_call("flash_sweep", part="fwd", group=g.group,
+                    heads_in_step=heads)
+        qm, km, sm = g.sweep_maps(heads)
         # statistics: a head a group of lanes, or a row of the stack
         rows, lanes = g.halves * bq, g.hpb // g.halves * LANES
         return _sweep_call(
             functools.partial(_fwd_sweep_kernel, scale=scale, causal=causal,
-                              bq=bq, bk=bk, hpb=g.hpb, geom=g),
+                              bq=bq, bk=bk, hpb=g.hpb, heads=heads, geom=g),
             g, step_table(g.S, g.Sk, bq, bk, causal, window), (q, k, v),
-            [g.q_spec(bq, qm), g.kv_spec(bk, km), g.v_spec(bk, km)],
-            [g.o_spec(bq, qm), g.stat_spec(bq, sm)], out_shape,
-            [pltpu.VMEM((rows, lanes), jnp.float32),
-             pltpu.VMEM((rows, lanes), jnp.float32),
-             pltpu.VMEM((rows, g.vw), jnp.float32)]
-            + [pltpu.VMEM((rows, g.qw), q.dtype)] * (g.halves > 1),
-            False, interpret, "fwd")
+            [g.q_spec(bq, qm, heads), g.kv_spec(bk, km), g.v_spec(bk, km)],
+            [g.o_spec(bq, qm, heads), g.stat_spec(bq, sm, heads)], out_shape,
+            [pltpu.VMEM((rows, heads * lanes), jnp.float32),
+             pltpu.VMEM((rows, heads * lanes), jnp.float32),
+             pltpu.VMEM((rows, heads * g.vw), jnp.float32)]
+            + [pltpu.VMEM((heads * rows, g.qw), q.dtype)] * (g.halves > 1),
+            g.group // heads, interpret, "fwd", **past_scoped(need))
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                bq=bq, hpb=g.hpb, G=g.G, Hg=g.Hg, geom=g)
     o, lse = pl.pallas_call(
@@ -894,9 +1040,10 @@ def _bwd_dq_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
     def _init():
         acc_scr[:] = jnp.zeros_like(acc_scr)
         if stk:
-            _stack_sweep(stk, q_ref, do_ref, lse_ref, delta_ref, hpb, D, half)
+            _stack_sweep(stk, q_ref, do_ref, lse_ref, delta_ref, hpb, D,
+                         halves=[half])
 
-    def tile(q, k, v, do, lse, delta, cs, vs, wrap=None):
+    def tile(q, k, v, do, lse, delta, cs, vs, qs, last, wrap=None):
         s = _scores(q, k, scale, causal, q_of[t] * bq, kv_of[t] * bk,
                     geom.window, wrap)
         p = jnp.exp(s - lse())                         # [rows, bk]
@@ -924,8 +1071,9 @@ def _bwd_dkv_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
     """The kv-major sweep of the two: dk and dv of a kv block over the q
     blocks that see it.  Every step meets another q block, so where the
     heads ride stacked (``geom.halves`` > 1) a step stacks its own."""
-    t, q_block = geom.step(head_of)    # q blocks innermost here (of each
-    D = q_ref.shape[-1] // hpb         # of the group's query heads in turn)
+    # q blocks innermost here (of each of the group's query heads in turn)
+    t, q_block = geom.step(head_of=head_of)
+    D = q_ref.shape[-1] // hpb
     half = geom.kv_half(q_block)
 
     @pl.when((flags[t] & FIRST) != 0)
@@ -933,7 +1081,7 @@ def _bwd_dkv_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def tile(q, k, v, do, lse, delta, cs, vs, wrap=None):
+    def tile(q, k, v, do, lse, delta, cs, vs, qs, last, wrap=None):
         s = _scores(q, k, scale, causal, q_of[t] * bq, kv_of[t] * bk,
                     geom.window, wrap)
         p = jnp.exp(s - lse())                         # [rows, bk]
@@ -965,25 +1113,33 @@ def _bwd_dkv_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
 def _bwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
                       lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_scr,
                       dk_acc, dv_acc, *stk, scale, causal, bq, bk, geom,
-                      hpb=1):
+                      hpb=1, heads=1):
     """Several blocks, ONE sweep: a grid row is a (batch row, key/value
-    head-block) pair and its steps walk the group's query head-blocks, of
-    each its q blocks, of each its visible kv blocks (``step_table``,
-    q-major over the group).  A step computes one probability tile and from
-    it all three products: dq into the q sweep's scratch, dk and dv into
-    rows ``kv block`` of two float32 accumulators that hold the whole
-    sequence and are the key/value head's own, so they sum over the group;
-    both leave once, at the grid row's last step.
+    head-block) pair and its steps walk the group's query head-blocks in
+    chunks of ``heads`` (``heads_a_step``), of each chunk its q blocks, of
+    each its visible kv blocks (``step_table``, q-major over the chunks).  A
+    step is one (q block, kv block) tile with the chunk's heads looped
+    inside: of each one probability tile and from it all three products, dq
+    into its columns of the q sweep's scratch, dk and dv SUMMED over the
+    step's heads and added ONCE a step into rows ``kv block`` of two float32
+    accumulators that hold the whole sequence and are the key/value head's
+    own, so they sum over the group; both leave once, at the grid row's last
+    step.  The mask of the tile is built once a step and the heads' chains
+    stand side by side for the compiler to interleave.
 
     Where the heads of the lane block read one key/value head
     (``geom.halves`` > 1) they ride the q sweep stacked along rows
-    (``stk``: q, do, lse and delta restacked at its first step): ONE
-    [hpb * bq, bk] tile a step against the whole k and v lane blocks, dk and
-    dv contracted over both heads' rows into the accumulators' full width
-    (``_stack_heads``: zeros beside the half), dq unstacked at the last."""
-    t, q_block = geom.step(head_of)
-    D = q_ref.shape[-1] // hpb
-    half = geom.kv_half(q_block)
+    (``stk``: q, do, lse and delta restacked at its first step, a head-block
+    under the other): ONE [hpb * bq, bk] tile a head-block against the whole
+    k and v lane blocks, dk and dv contracted over both heads' rows into the
+    accumulators' full width (``_stack_heads``: zeros beside the half), dq
+    unstacked at the last."""
+    t, chunk = geom.step(heads, head_of)
+    D = k_ref.shape[-1] // hpb
+    half = [geom.kv_half(chunk * heads + h) for h in range(heads)] \
+        if stk else None
+    assert heads == 1 or stk or hpb == 1    # one column range of dk a step
+
     def rows_of(kv_block):
         return pl.ds(pl.multiple_of(kv_block * bk, bk), bk)
 
@@ -1010,39 +1166,55 @@ def _bwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
         if stk:
-            _stack_sweep(stk, q_ref, do_ref, lse_ref, delta_ref, hpb, D, half)
+            _stack_sweep(stk, q_ref, do_ref, lse_ref, delta_ref, hpb, D,
+                         halves=half)
 
-    def tile(q, k, v, do, lse, delta, cs, vs, wrap=None):
+    seen, sums = [], {}     # the tile's mask; dk and dv of the step's heads
+
+    def add(acc, cols, part, last):
+        """``part`` of one head into rows ``kv block`` of ``acc``: summed
+        over the step's heads, one read-modify-write a step."""
+        if id(acc) in sums:
+            part = sums.pop(id(acc)) + part
+        if last:
+            acc[rows, cols] += part
+        else:
+            sums[id(acc)] = part
+
+    def tile(q, k, v, do, lse, delta, cs, vs, qs, last, wrap=None):
         """The rows of q and do against columns ``cs`` of this kv block's
-        keys and ``vs`` of its values."""
+        keys and ``vs`` of its values; dq at columns ``qs``."""
         s = _scores(q, k, scale, causal, q_of[t] * bq, kv_of[t] * bk,
-                    geom.window, wrap)
+                    geom.window, wrap, seen)
         p = jnp.exp(s - lse())                     # [rows, bk] - the ONE exp
         # dv_j += p^T dO
         dv = jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dv_acc[rows, vs] += dv
+        add(dv_acc, vs, dv, last)
         dov = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
         ds = (p * (dov - delta()) * scale).astype(q.dtype)     # [rows, bk]
-        dq_scr[:, cs] += jax.lax.dot_general(
+        dq_scr[:, qs] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         # dk_j += ds^T q
         dk = jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dk_acc[rows, cs] += dk
+        add(dk_acc, cs, dk, last)
 
     _bwd_tiles(tile, stk, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               hpb, bq, bk)
+               hpb, bq, bk, heads)
 
     @pl.when((flags[t] & LAST) != 0)
     def _final():
-        dq = dq_scr[:]
-        dq_ref[0] = (_unstack_heads(dq, hpb, D, half) if stk else dq
-                     ).astype(dq_ref.dtype)
+        if not stk:
+            dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        for h in range(heads if stk else 0):
+            at = _cols(h, hpb * D)
+            dq_ref[0, :, at] = _unstack_heads(
+                dq_scr[:, at], hpb, D, half[h]).astype(dq_ref.dtype)
 
     @pl.when(t == pl.num_programs(2) - 1)
     def _close():
@@ -1085,27 +1257,31 @@ def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
     dkv_shapes = [jax.ShapeDtypeStruct(g.dk_shape, k.dtype),
                   jax.ShapeDtypeStruct(g.dv_shape, v.dtype)]
 
-    stack = g.halves * bq       # rows of a step's tile, and what a q sweep
-    stacked = [                 # keeps of its q block where it is a stack
-        pltpu.VMEM((stack, g.qw), q.dtype),
-        pltpu.VMEM((stack, g.qw), do.dtype),
-        pltpu.VMEM((stack, LANES), jnp.float32),            # lse, delta
-        pltpu.VMEM((stack, LANES), jnp.float32)] * (g.halves > 1)
+    stack = g.halves * bq       # rows of a head-block's tile
+
+    def stacked(heads=1):
+        """What a q sweep keeps of its q block where it is a stack."""
+        return [pltpu.VMEM((heads * stack, g.qw), q.dtype),
+                pltpu.VMEM((heads * stack, g.qw), do.dtype),
+                pltpu.VMEM((heads * stack, LANES), jnp.float32),  # lse, delta
+                pltpu.VMEM((heads * stack, LANES), jnp.float32)
+                ] * (g.halves > 1)
 
     def sweep(kernel, name, out_specs, out_shape, scratch_shapes,
-              walks_group=False, kv_major=False, **params):
-        qm, km, sm = g.sweep_maps(walks_group)
-        qs, ks = g.q_spec(bq, qm), g.kv_spec(bk, km)
-        os, vs = g.o_spec(bq, qm), g.v_spec(bk, km)
+              walks_group=False, kv_major=False, heads=1, **params):
+        qm, km, sm = g.sweep_maps(heads, walks_group)
+        qs, ks = g.q_spec(bq, qm, heads), g.kv_spec(bk, km)
+        os, vs = g.o_spec(bq, qm, heads), g.v_spec(bk, km)
+        stats = g.stat_spec(bq, sm, heads)
+        chunks = g.group // heads
         return _sweep_call(
             functools.partial(kernel, scale=scale, causal=causal, bq=bq,
                               bk=bk, hpb=g.hpb, geom=g),
             g, step_table(g.S, g.Sk, bq, bk, causal, window,
-                          g.group if walks_group else 1, kv_major),
-            (q, k, v, do, lse, delta),
-            [qs, ks, vs, os, g.stat_spec(bq, sm), g.stat_spec(bq, sm)],
-            out_specs(qs, ks, vs), out_shape, scratch_shapes, walks_group,
-            interpret, name, **params)
+                          chunks if walks_group else 1, kv_major),
+            (q, k, v, do, lse, delta), [qs, ks, vs, os, stats, stats],
+            out_specs(qs, ks, vs), out_shape, scratch_shapes,
+            None if walks_group else chunks, interpret, name, **params)
 
     if g.bwd_sweeps == 1:
         # dk and dv of the whole sequence: one block a grid row, so one
@@ -1116,18 +1292,19 @@ def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
                                 lambda r, kh, t, *table: (r, 0, kh),
                                 pipeline_mode=pl.Buffered(1))
 
+        heads, need = g.heads_in_step("bwd")
+        _count_call("flash_sweep", part="bwd", group=g.group,
+                    heads_in_step=heads)
         return sweep(
-            _bwd_sweep_kernel, "bwd_fused",
+            functools.partial(_bwd_sweep_kernel, heads=heads), "bwd_fused",
             lambda qs, ks, vs: [qs, whole(g.qw), whole(g.vw)],
             [dq_shape] + dkv_shapes,
-            [pltpu.VMEM((stack, g.qw), jnp.float32),
+            [pltpu.VMEM((stack, heads * g.qw), jnp.float32),
              pltpu.VMEM((g.Sk, g.qw), jnp.float32),
-             pltpu.VMEM((g.Sk, g.vw), jnp.float32)] + stacked,
-            walks_group=True,
-            vmem_limit_bytes=fused_sweep_vmem_bytes(
-                g.Sk, g.qw, k.dtype.itemsize, g.vw))
+             pltpu.VMEM((g.Sk, g.vw), jnp.float32)] + stacked(heads),
+            walks_group=True, heads=heads, vmem_limit_bytes=need)
     dq = sweep(_bwd_dq_kernel, "bwd_dq", lambda qs, ks, vs: qs, dq_shape,
-               [pltpu.VMEM((stack, g.qw), jnp.float32)] + stacked)
+               [pltpu.VMEM((stack, g.qw), jnp.float32)] + stacked())
     # the dk/dv sweep's rows run over the key/value heads
     dk, dv = sweep(_bwd_dkv_kernel, "bwd_dkv", lambda qs, ks, vs: [ks, vs],
                    dkv_shapes,

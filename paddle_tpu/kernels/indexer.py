@@ -56,9 +56,9 @@ from jax.experimental.pallas import tpu as pltpu
 from ..monitor import devscope
 from ._common import (LANES, CompilerParams as _CompilerParams,
                       count_call as _count_call, on_tpu as _on_tpu)
-from .flash_attention import (FIRST, LAST, NEG_INF as MASKED, SCOPED_VMEM,
-                              SWEEP_VMEM, _Geom, _delta, _divisors,
-                              _lanes_to, step_table)
+from .flash_attention import (FIRST, LAST, NEG_INF as MASKED, SWEEP_VMEM,
+                              _Geom, _delta, _lanes_to, heads_a_step,
+                              past_scoped as _past_scoped, step_table)
 
 __all__ = ["indexer_scores", "kth_largest", "selected", "selected_lse",
            "dsa_lse", "dsa_attend_kl"]
@@ -148,12 +148,6 @@ def scores_vmem_bytes(bq, bk, width, heads, itemsize, backward=False, S=0):
         need += 2 * bq * width * itemsize + bq * width * 4 + 3 * rows \
             + S * di * (4 + 2 * itemsize) + 8 * tile
     return need + 2 * 2 ** 20
-
-
-def _past_scoped(need, least=SCOPED_VMEM):
-    """``vmem_limit_bytes`` where a kernel needs more than ``least`` (what
-    it has without asking), else nothing: the call stays as it was."""
-    return {"vmem_limit_bytes": int(need)} if need > least else {}
 
 
 def _scores_bwd_kernel(q_of, kv_of, head_of, flags, gain_ref, q_ref, k_ref,
@@ -606,16 +600,6 @@ def dsa_bwd_vmem_bytes(S, heads, head_dim, v_head_dim, itemsize, bq=512,
             + 8 * tile)
 
 
-def heads_a_step(group, vmem_bytes):
-    """The query heads of a group that ride one grid step of a masked sweep:
-    the most (a divisor of the group) whose ``vmem_bytes(heads)`` fits
-    SWEEP_VMEM.  From the shapes alone; the group's other heads are further
-    sweeps of the same grid row (``step_table``'s ``group``)."""
-    fit = [n for n in _divisors(group) if vmem_bytes(n) <= SWEEP_VMEM]
-    assert fit, "dk and dv of the whole sequence do not fit VMEM"
-    return fit[-1]
-
-
 def _sweep_maps(chunks):
     """Index maps of a masked sweep's grid (batch row, key/value head, step
     of the table): (q rows of the step's heads, k rows, the heads' row
@@ -670,6 +654,8 @@ def _dsa_bwd_call(q, k, v, do, lse, delta, scores, tau, n_heads, n_kv_heads,
                              v_head_dim=Dv, itemsize=q.dtype.itemsize, bq=bq,
                              bk=bk)
     heads = heads_a_step(group, need)
+    assert need(heads) <= SWEEP_VMEM, \
+        "dk and dv of the whole sequence do not fit VMEM"
     _count_call("flash_dsa", part="bwd", group=group, heads_in_step=heads,
                 statistic_only=0)
     qrow, krow, stat, tile, row, whole = _sweep_maps(group // heads)

@@ -3,7 +3,8 @@
     chiprun -- python3 scripts/attn_kernel_cmp.py --batch 256 --seq 128 \
         [--heads 12 --head-dim 64 --block 512 --causal] \
         [--kv-heads 4 --window 4096] [--tree _checkout/parent] \
-        [--two-sweeps] [--force 1x1,4x1,1x6] [--others]
+        [--two-sweeps] [--force 1x1,4x1,1x6] [--heads-a-step 1,2]
+        [--others]
 
 Times ``flash_attention_packed`` (the entry the models call), forward and
 backward, by DEVICE time per kernel name read from a profiler trace, as the
@@ -21,9 +22,15 @@ as a sequence past the rule does.
 ``--force GxHg,...`` also times the kernels with the grid step's geometry
 forced to G batch rows by Hg head-blocks (``step_geometry`` replaced for
 that compile: an experiment of this script, not an option of the program)
-and holds every output to the unforced one.  ``--others`` adds, by host
-clock, the [B, S, H, D] entry, JAX's own TPU flash kernel and plain XLA
-softmax attention.  Needs a TPU.
+and holds every output to the unforced one.  ``--heads-a-step n,...`` does
+the same for the query head-blocks of a group that ride one grid step of
+the several-block sweeps (``heads_a_step`` replaced: 1 is the step before
+PR 68; dk and dv sum the group in another order, so their last bits may
+differ from the rule's).  A grouped call's line says what a step holds
+(heads a step, forward / backward), its grid steps a layer and pass, and
+the microseconds a (tile, head) forward and backward.  ``--others`` adds,
+by host clock, the [B, S, H, D] entry, JAX's own TPU flash kernel and plain
+XLA softmax attention.  Needs a TPU.
 """
 
 import argparse
@@ -109,6 +116,7 @@ def main(argv=None):
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--two-sweeps", action="store_true")
     ap.add_argument("--force", default="")
+    ap.add_argument("--heads-a-step", default="")
     ap.add_argument("--vmem-mib", type=int, default=0)
     ap.add_argument("--others", action="store_true")
     args = ap.parse_args(argv)
@@ -161,7 +169,9 @@ def main(argv=None):
         fa.SWEEP_VMEM = 0
     rule = getattr(fa, "step_geometry", None)   # a checkout before PR 28
     forced = [tuple(int(n) for n in g.split("x"))
-              for g in args.force.split(",") if g]
+              for g in args.force.split(",") if g] \
+        + [int(n) for n in args.heads_a_step.split(",") if n]
+    heads_rule = getattr(fa, "heads_a_step", None)  # a checkout before PR 68
     if args.vmem_mib:       # for a forced geometry over Mosaic's default scope
         import functools
         fa._CompilerParams = functools.partial(
@@ -178,14 +188,23 @@ def main(argv=None):
         if rule is None:
             pairs, steps = 1, -1
         else:
-            fa.step_geometry = rule if geom is None else (lambda *a, g=geom: g)
+            if isinstance(geom, int):       # heads a step
+                fa.heads_a_step = lambda group, need, n=geom: n
+            else:
+                fa.step_geometry = rule if geom is None else (
+                    lambda *a, g=geom: g)
             try:
-                pairs, steps = fa.packed_grid(
-                    B, S, H, D, args.block, args.block, n_kv_heads=Hkv,
-                    causal=args.causal, window=window)
+                grid = dict(n_kv_heads=Hkv, causal=args.causal, window=window)
+                pairs, steps = fa.packed_grid(B, S, H, D, args.block,
+                                              args.block, **grid)
             except TypeError:       # a checkout whose grids are not tables
                 pairs, steps = fa.packed_grid(B, S, H, D, args.block,
                                               args.block, n_kv_heads=Hkv)
+            if heads_rule is not None and S > args.block:
+                back = fa.packed_grid(B, S, H, D, args.block, args.block,
+                                      part="bwd", **grid)
+                print("         heads a step: forward %d (%d grid steps a "
+                      "layer), backward %d (%d)" % ((pairs, steps) + back))
         fn = both()
         t0 = time.perf_counter()
         got = [np.asarray(x.astype(jnp.float32)) for x in fn(q, k, v, do)]
@@ -201,19 +220,29 @@ def main(argv=None):
         kernels = {n: per.pop(n) for n in list(per) if n.startswith("flash_")}
         fwd = sum(t for n, t in kernels.items() if n.endswith("fwd"))
         bwd = sum(kernels.values()) - fwd
+        label = "rule" if geom is None else "%d heads" % geom \
+            if isinstance(geom, int) else "%dx%d" % geom
         print("%-8s %2d pairs a step, %5d steps: fwd %8.1f us, bwd %8.1f us, "
               "roofline %5.1f %%; first call %.1f s; off the rule's by %.3g, "
               "off XLA's softmax attention by %.3g; digest %s"
-              % ("rule" if geom is None else "%dx%d" % geom, pairs, steps,
+              % (label, pairs, steps,
                  fwd, bwd,
                  100e6 * (least["fwd"][0] + least["bwd"][0]) / (fwd + bwd or 1e30),
                  compiled, worst, off, digest[:12]), flush=True)
         print("         by kernel: " + ", ".join(
             "%s %.1f us" % kv for kv in sorted(kernels.items())))
+        if S > args.block and rule is not None:
+            tiles = B * H * D // max(D, 128) * fa.kv_blocks(
+                S, args.block, args.block, args.causal, window)
+            print("         a (tile, head-block): forward %.3f us, backward "
+                  "%.3f us over %d of them" % (fwd / tiles, bwd / tiles,
+                                               tiles))
         print("         beside them: " + ", ".join(
             "%s %.1f" % kv for kv in sorted(per.items(), key=lambda kv: -kv[1])[:6]))
     if rule is not None:
         fa.step_geometry = rule
+    if heads_rule is not None:
+        fa.heads_a_step = heads_rule
 
     if args.others:
         others(args, q, k, v, do)

@@ -51,17 +51,21 @@ QK = {"monitor.kernels.qk_rope_calls"}
 # since PR 55: a count a traced several-block flash backward, by whether the
 # row kernel made its ``delta`` (OLMoE's heads of 16: no flash call)
 DELTA = {"monitor.kernels.flash_delta_calls"}
+# since PR 68: a count a traced several-block sweep, forward and one-sweep
+# backward, by the query head-blocks of a group that ride one grid step
+SWEEP = {"monitor.kernels.flash_sweep_calls"}
 # tiny model -> the names one run_steps writes, by its observation and by
 # its trace: of those the five trainer
 # classes of ad87b08 wrote, the readings of the batch and the weights, and
 # PR 46's counter of compiled grouped-matmul calls, PR 47's of q/k passes,
-# PR 55's of several-block flash backwards
+# PR 55's of several-block flash backwards, PR 68's of several-block sweeps
 WRITTEN = {
     "olmoe": MOE | QK,
-    "smallthinker": MOE | HELD | QK | DELTA,
-    "lfm2": MOE | HELD | QK | DELTA | {"monitor.train.router_bias_abs_max"},
+    "smallthinker": MOE | HELD | QK | DELTA | SWEEP,
+    "lfm2": MOE | HELD | QK | DELTA | SWEEP
+    | {"monitor.train.router_bias_abs_max"},
     "brumby": QK | {"monitor.train.retention_gate_mean"},
-    "mistral4": MOE | HELD | QK | DELTA,
+    "mistral4": MOE | HELD | QK | DELTA | SWEEP,
 }
 # tiny model -> (sequence, what ``decoder.probe``'s ONE program reads of
 # ``_staged``'s first batch at seed 3 (``moe_rows_held``: of both batches)):

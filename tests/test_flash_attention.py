@@ -2,6 +2,9 @@
 the XLA blockwise reference, in interpret mode on CPU (the kernel itself is
 identical code on TPU; only the Mosaic lowering differs)."""
 
+import functools
+import re
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -385,8 +388,6 @@ def test_grouped_and_windowed_modes_equal_plain_attention(
 
 
 def _kernel_names(fn, *args):
-    import re
-
     return sorted(re.findall(r"name=(flash_\w+)", str(jax.make_jaxpr(fn)(*args))))
 
 
@@ -455,7 +456,11 @@ def test_one_sweep_backward_equals_plain_attention_and_the_two_sweeps(
     """dq, dk, dv of ``flash_bwd_fused`` over several blocks against plain
     attention's, and BIT FOR BIT against ``flash_bwd_dq`` / ``flash_bwd_dkv``
     (the same call with no VMEM for the accumulators): one probability tile
-    a step in place of two, the same sums in the same order."""
+    a step in place of two, the same sums in the same order.  Grouped
+    queries (PR 68): a step holds ``heads_a_step`` of a group and sums their
+    dk and dv before ONE add into the accumulators, so those two keep dq bit
+    for bit and dk and dv to the last bits; at one head a step
+    (``heads_a_step`` replaced) they are the two sweeps' bit for bit too."""
     rng = np.random.RandomState(31)
     mk = lambda s, h: jnp.array((rng.randn(B, s, h, D) * 0.5)
                                 .astype(np.float32))
@@ -479,12 +484,26 @@ def test_one_sweep_backward_equals_plain_attention_and_the_two_sweeps(
         assert a.shape == b.shape
         np.testing.assert_allclose(a, b, atol=5e-5, rtol=1e-4,
                                    err_msg="d%s of %s" % (n, what))
-    monkeypatch.setattr(fa, "SWEEP_VMEM", 0)
-    assert len([n for n in _kernel_names(grads(attn), q, k, v)
-                if "bwd" in n]) == 2, what
-    for a, b, n in zip(one, grads(attn)(q, k, v), "qkv"):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                      err_msg="d%s of %s" % (n, what))
+    with monkeypatch.context() as m:
+        m.setattr(fa, "SWEEP_VMEM", 0)
+        assert len([n for n in _kernel_names(grads(attn), q, k, v)
+                    if "bwd" in n]) == 2, what
+        two = [np.asarray(x) for x in grads(attn)(q, k, v)]
+
+    def held(got, exact):
+        for a, b, n in zip(got, two, "qkv"):
+            if exact or n == "q":
+                np.testing.assert_array_equal(
+                    np.asarray(a), b, err_msg="d%s of %s" % (n, what))
+            else:
+                assert np.abs(np.asarray(a) - b).max() \
+                    <= 1e-6 * np.abs(b).max(), (n, what)
+
+    grouped = entry == "packed" and H != Hkv
+    held(one, exact=not grouped)
+    if grouped:
+        monkeypatch.setattr(fa, "heads_a_step", lambda group, need: 1)
+        held(grads(attn)(q, k, v), exact=True)
 
 
 @pytest.mark.parametrize("what,S,lanes,group,sweeps,mib", [
@@ -646,11 +665,15 @@ def test_heads_ride_stacked_exactly_where_a_lane_block_reads_one_kv_head(
         assert not stacks and tiles == {((block, D), (block, D), None)}, what
         return
     several = {((stacked * block, lanes), (block, lanes), block)}
+    # a step holds the group's query blocks, each stacked on its own
+    heads = g.heads_in_step("bwd")[0]
+    assert heads == g.heads_in_step("fwd")[0] == g.group
     if S == block:      # the one-block forward takes the half of k and v
         assert tiles == several | {((block, D), (block, D), None)}, what
-        assert stacks == [(block, lanes)] * 2, what
+        assert stacks == [(block, lanes)] * 2 * heads, what
     else:
-        assert tiles == several and stacks == [(block, lanes)] * 3, what
+        assert tiles == several, what
+        assert stacks == [(block, lanes)] * 3 * heads, what
 
 
 def test_a_block_nobody_sees_is_refused():
@@ -675,16 +698,23 @@ def test_grouped_queries_need_whole_head_blocks():
 
 
 @pytest.mark.parametrize("what,B,S,H,Hkv,D,want", [
-    # several blocks: steps = B x head-blocks x the triangle's blocks; one
-    # block: (row, head-block) pairs a step, steps = B x head-blocks / pairs
-    ("smallthinker_21b_a3b.s16384_scan", 1, 16384, 28, 4, 128, (1, 28 * 528)),
-    ("lfm2_8b_a1b.s8192_scan", 2, 8192, 32, 8, 64, (1, 2 * 16 * 136)),
+    # several blocks: a group's head-blocks a step, steps = B x key/value
+    # head-blocks x the triangle's blocks; one block: (row, head-block)
+    # pairs a step, steps = B x head-blocks / pairs
+    ("smallthinker_21b_a3b.s16384_scan", 1, 16384, 28, 4, 128, (7, 4 * 528)),
+    ("lfm2_8b_a1b.s8192_scan", 2, 8192, 32, 8, 64, (4, 2 * 4 * 136)),
+    ("trinity_large_preview.s6144_scan", 1, 6144, 48, 8, 128, (6, 8 * 78)),
+    ("nemotron3_nano_30b_a3b.s8192_scan", 2, 8192, 32, 2, 128,
+     (16, 2 * 2 * 136)),
+    ("olmoe_1b_7b.s4096_scan, ungrouped", 4, 4096, 16, None, 128,
+     (1, 4 * 16 * 36)),
     ("bert_base.s128_scan, ungrouped", 256, 128, 12, 12, 64, (6, 256)),
     ("bert_base.s512_scan, ungrouped", 64, 512, 12, None, 64, (1, 384)),
 ])
 def test_packed_grid_of_the_grouped_cells(what, B, S, H, Hkv, D, want):
-    """The grids of the grouped modes are a (row, head-block) pair's
-    triangle of blocks; BERT's ungrouped width-64 mode is what it was before
+    """The grids of the grouped modes are a (row, key/value head-block)
+    pair's triangle of blocks, the group's query head-blocks riding each
+    step (PR 68); BERT's ungrouped width-64 mode is what it was before
     grouped queries ran at two heads a lane block."""
     assert fa.packed_grid(B, S, H, D, 512, 512, n_kv_heads=Hkv,
                           causal=S > 512) == want
@@ -724,3 +754,177 @@ def test_a_query_block_reads_one_half_of_its_key_value_block():
                     512, 512)
     assert wide.kv_half(3) is None and bert.kv_half(3) is None
     assert bert.halves == 1
+
+
+# ---------------------------------------------------------------------------
+# a group's query heads INSIDE a grid step of the several-block sweeps
+# (PR 68): one (q block, kv block) tile of a key/value head-block a step
+# with ``heads_a_step`` of its group looped in it
+# ---------------------------------------------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def _swept(group, heads, window, dtype):
+    """(o, lse, dq, dk, dv) of ``group`` query heads of 128 on one key/value
+    head over 128 positions in blocks of 32, ``heads`` of them a grid step
+    (``heads_a_step`` replaced), float32 numpy; then the same five by plain
+    ``jnp`` in float32 of the same (rounded) operands, and the grids."""
+    S, D, block, scale = 128, 128, 32, 128 ** -0.5
+    q, k, v, w = (t.astype(dtype) for t in _packed_qkv(68, 1, S, group, 1, D))
+    rule, fa.heads_a_step = fa.heads_a_step, lambda g, need: heads
+    try:
+        def run(q, k, v, w):
+            o, lse = fa._fwd(q, k, v, scale, True, block, block, True, group,
+                             1, window)
+            return (o, lse) + tuple(fa._bwd(
+                scale, True, block, block, True, (q, k, v, o, lse), w, group,
+                1, window))
+        grids = re.findall(r"grid=\(([\d, ]*)\)", str(jax.make_jaxpr(run)(
+            q, k, v, w)))
+        got = run(q, k, v, w)
+    finally:
+        fa.heads_a_step = rule
+
+    def plain(q, k, v):
+        heads_of = lambda t, h: t.reshape(1, S, h, D)
+        qh, kh, vh = heads_of(q, group), heads_of(k, 1), heads_of(v, 1)
+        s = jnp.einsum("bqhd,bkhd->bhqk", qh, jnp.repeat(kh, group, 2)) * scale
+        i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+        seen = (j <= i) & (i - j < (window or S))
+        lse = jax.nn.logsumexp(jnp.where(seen, s, -jnp.inf), axis=-1)
+        return _plain(qh, kh, vh, True, window).reshape(1, S, -1), \
+            lse[..., None]
+
+    f32 = [t.astype(jnp.float32) for t in (q, k, v)]
+    (o, lse), vjp = jax.vjp(plain, *f32)
+    want = (o, lse) + vjp((w.astype(jnp.float32), jnp.zeros_like(lse)))
+    as_np = lambda ts: [np.asarray(t.astype(jnp.float32)) for t in ts]
+    return as_np(got), as_np(want), grids
+
+
+#   group, heads a step: one, a proper divisor, the whole group
+HEADS_A_STEP = [(6, 1), (6, 3), (6, 6), (7, 1), (7, 7), (16, 1), (16, 4),
+                (16, 16)]
+
+
+# full in bfloat16 and a window that is no multiple of the block in float32
+# at every geometry, the cross of mask and type at one
+RIDES = [(g, h) + m for g, h in HEADS_A_STEP
+         for m in ((None, "bfloat16"), (40, "float32"))] \
+    + [(6, 3, None, "float32"), (6, 3, 40, "bfloat16")]
+
+
+@pytest.mark.parametrize("group,heads,window,dtype", RIDES)
+def test_a_group_s_heads_ride_one_grid_step(group, heads, window, dtype):
+    """o, lse, dq, dk, dv against plain ``jnp`` attention; against the same
+    call at ONE head a step (the step before PR 68) o, lse and dq bit for
+    bit (a head's recurrence is unchanged) and dk and dv to the last bits
+    (the step's heads are summed before the one add into the
+    accumulators).  The grids: (row, key/value head-block, chunks of the
+    group, tiles), the backward's table walking the chunks."""
+    got, want, grids = _swept(group, heads, window, dtype)
+    one, _, _ = _swept(group, 1, window, dtype)
+    tol = 2e-5 if dtype == "float32" else 3e-2
+    for a, b, n in zip(got, want, ("o", "lse", "dq", "dk", "dv")):
+        assert a.shape == b.shape, n
+        assert np.abs(a - b).max() <= tol * max(1.0, np.abs(b).max()), n
+    for a, b, n in zip(got, one, ("o", "lse", "dq")):
+        np.testing.assert_array_equal(a, b, err_msg=n)
+    ulp = 1e-6 if dtype == "float32" else 2.0 ** -7
+    for a, b in zip(got[3:], one[3:]):
+        assert np.abs(a - b).max() <= ulp * np.abs(b).max()
+    tiles = fa.kv_blocks(128, 32, 32, True, window)
+    assert grids[0] == "1, 1, %d, %d" % (group // heads, tiles)
+    assert grids[-1] == "1, 1, %d" % (group // heads * tiles)
+
+
+def test_two_heads_of_64_a_lane_block_join_a_step_stacked():
+    """LFM2's shape: the four query lane blocks of a key/value lane block in
+    ONE step, each stacked on its own [2 * bq, 128] rows (``_stack_heads``);
+    against one block a step, o, lse and dq bit for bit."""
+    four, one = _stacked_sweep(4), _stacked_sweep(1)
+    for a, b in zip(four[:3], one[:3]):
+        np.testing.assert_array_equal(a, b)
+    for a, b in zip(four[3:], one[3:]):
+        assert np.abs(a - b).max() <= 1e-6 * np.abs(b).max()
+
+
+def _stacked_sweep(heads):
+    S, H, Hkv, D, block = 128, 8, 2, 64, 32
+    q, k, v, w = _packed_qkv(69, 1, S, H, Hkv, D)
+    rule, fa.heads_a_step = fa.heads_a_step, lambda g, need: heads
+    try:
+        o, lse = fa._fwd(q, k, v, D ** -0.5, True, block, block, True, H, Hkv)
+        return [np.asarray(t) for t in (o, lse) + tuple(fa._bwd(
+            D ** -0.5, True, block, block, True, (q, k, v, o, lse), w, H,
+            Hkv))]
+    finally:
+        fa.heads_a_step = rule
+
+
+@pytest.mark.parametrize("what,S,group,lanes,halves,fwd,bwd", [
+    # a prime group that fits: all seven in a step, both ways
+    ("smallthinker_21b_a3b.s16384_scan", 16384, 7, 128, 1, 7, 7),
+    # and one that does not: dk and dv of 32,768 positions leave the
+    # backward's step room for one head; the forward holds no sequence
+    ("twice its sequence", 32768, 7, 128, 1, 7, 1),
+    ("trinity_large_preview.s6144_scan", 6144, 6, 128, 1, 6, 6),
+    ("nemotron3_nano_30b_a3b.s8192_scan", 8192, 16, 128, 1, 16, 16),
+    ("solar_open2_250b.s4096_scan", 4096, 8, 128, 1, 8, 8),
+    ("jamba2_3b.s8192_scan", 8192, 20, 128, 1, 20, 20),
+    ("lfm2_8b_a1b.s8192_scan", 8192, 4, 128, 2, 4, 4),
+    ("ungrouped", 16384, 1, 128, 1, 1, 1),
+])
+def test_the_heads_a_step_come_from_the_shapes(monkeypatch, what, S, group,
+                                               lanes, halves, fwd, bwd):
+    """``heads_a_step``: the largest divisor of the group whose step fits
+    SWEEP_VMEM by ``fwd_sweep_vmem_bytes`` / ``fused_sweep_vmem_bytes``; no
+    configuration field, flag or name.  One head a step asks what the
+    parent's call asked (Mosaic's own scope for the step)."""
+    need_f = lambda n: fa.fwd_sweep_vmem_bytes(n, lanes, 2, halves=halves)
+    need_b = lambda n: fa.fused_sweep_vmem_bytes(S, lanes, 2, heads=n,
+                                                 halves=halves)
+    assert fa.heads_a_step(group, need_f) == fwd, what
+    assert fa.heads_a_step(group, need_b) == bwd, what
+    assert need_b(1) == 2 * S * lanes * 6 + fa.SCOPED_VMEM
+    assert not fa.past_scoped(need_f(1))
+    assert need_f(fwd) <= fa.SWEEP_VMEM and need_b(bwd) <= fa.SWEEP_VMEM
+    g = fa._Geom(jax.ShapeDtypeStruct((1, S, group * halves * lanes),
+                                      jnp.bfloat16),
+                 jax.ShapeDtypeStruct((1, S, halves * lanes), jnp.bfloat16),
+                 group * halves * lanes // (lanes // halves), 512, 512,
+                 Hkv=halves * halves * lanes // lanes)
+    assert (g.group, g.halves) == (group, halves), what
+    assert g.heads_in_step("fwd") == (fwd, need_f(fwd))
+    assert g.heads_in_step("bwd") == (bwd, need_b(bwd))
+    # no VMEM for a sweep (the tests' way to the two sweeps): one head
+    monkeypatch.setattr(fa, "SWEEP_VMEM", 0)
+    assert fa.heads_a_step(group, need_f) == 1
+
+
+def test_a_traced_sweep_counts_the_heads_in_its_step(tmp_path):
+    """``monitor.kernels.flash_sweep_calls{part, group, heads_in_step}``: one
+    count a traced several-block call (``kernels/_common.count_call``), the
+    ungrouped and the two-sweep backward's forward too; the one-block
+    kernels count nothing."""
+    from paddle_tpu import monitor
+
+    def traced(H, Hkv, S, block):
+        q, k, v, w = _packed_qkv(70, 1, S, H, Hkv, 128)
+        jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(fa.flash_attention_packed(
+            *a, H, causal=True, block_q=block, block_k=block,
+            n_kv_heads=Hkv) * w), argnums=(0, 1, 2)))(q, k, v)
+
+    mon = monitor.enable(str(tmp_path), flight=False)
+    try:
+        mon.registry.reset()        # the registry is the process's
+        traced(6, 1, 128, 32)
+        traced(2, 2, 128, 32)
+        traced(2, 2, 128, 128)
+        got = {tuple(r["labels"][n] for n in (
+            "part", "group", "heads_in_step")): r["value"]
+            for r in mon.registry.snapshot()
+            if r["name"] == "monitor.kernels.flash_sweep_calls"}
+    finally:
+        monitor.disable()
+    assert got == {("fwd", 6, 6): 1, ("bwd", 6, 6): 1,
+                   ("fwd", 1, 1): 1, ("bwd", 1, 1): 1}

@@ -97,14 +97,18 @@ ONE_BLOCK_MOSAIC = {
 # The several-block kernels at a head a lane block (width 128), forward and
 # backward, as PR 41's parent (c1b744a) lowers them: stacking the heads of a
 # 64-wide lane block (LFM2's row, not pinned) left every other cell's module
-# as it was.  Replaced like ``ONE_BLOCK_MOSAIC``.
+# as it was.  Replaced like ``ONE_BLOCK_MOSAIC``.  PR 68 took SmallThinker's
+# two entries anew ON PURPOSE (a group's seven query heads ride one grid
+# step; they were 55126c1bd654 / 170f86a03873 full and 985173cce04a /
+# 42134345da2e windowed): the three UNGROUPED entries are c1b744a's still,
+# one head-block a step lowers to the text it did.
 SWEEP_MOSAIC = {
     "olmoe_1b_7b.s4096_scan": ["05626943d473", "eba4a626459f"],
     "ouro_2_6b.s4096_scan": ["8aec72d32a18", "afa8aab872da"],
     "smallthinker_21b_a3b.s16384_scan, a full layer":
-        ["55126c1bd654", "170f86a03873"],
+        ["465f38b6b128", "1d46a9c9d9f7"],
     "smallthinker_21b_a3b.s16384_scan, a windowed layer":
-        ["985173cce04a", "42134345da2e"],
+        ["e3a26b4fe57d", "987d580b7182"],
     "mistral_small_4_119b.s16384_scan, the latent expanded to 32 heads":
         ["e267681a781d", "ad2a365dd0c8"],
 }
@@ -188,33 +192,49 @@ def _vmem(text, kernel):
 
 SMALLTHINKER, LFM2 = (1, 16384, 28, 4, 128), (2, 8192, 32, 8, 64)
 MISTRAL4 = (1, 16384, 32, 32, 128)      # every head its own key and value
+TRINITY, NEMOTRON = (1, 6144, 48, 8, 128), (2, 8192, 32, 2, 128)
+SOLAR, JAMBA = (1, 4096, 64, 8, 128), (1, 8192, 20, 1, 128)
 
 
-@pytest.mark.parametrize("what,shape,window,names,steps,mib", [
+@pytest.mark.parametrize("what,shape,window,names,steps,heads,mib", [
     ("smallthinker_21b_a3b.s16384_scan, a full layer", SMALLTHINKER, None,
-     ("flash_fwd", "flash_bwd_fused"), 528, 40),
+     ("flash_fwd", "flash_bwd_fused"), 528, 7, (23.75, 44.5)),
     ("smallthinker_21b_a3b.s16384_scan, a windowed layer", SMALLTHINKER, 4096,
-     ("flash_swa_fwd", "flash_swa_bwd_fused"), 252, 40),
+     ("flash_swa_fwd", "flash_swa_bwd_fused"), 252, 7, (23.75, 44.5)),
     ("lfm2_8b_a1b.s8192_scan, two heads a lane block", LFM2, None,
-     ("flash_fwd", "flash_bwd_fused"), 136, 28),
+     ("flash_fwd", "flash_bwd_fused"), 136, 4, (27.5, 39.5)),
     ("mistral_small_4_119b.s16384_scan, the latent expanded to 32 heads",
-     MISTRAL4, None, ("flash_fwd", "flash_bwd_fused"), 528, 40),
+     MISTRAL4, None, ("flash_fwd", "flash_bwd_fused"), 528, 1, (16, 40)),
+    ("trinity_large_preview.s6144_scan, a full layer", TRINITY, None,
+     ("flash_fwd", "flash_bwd_fused"), 78, 6, (21, 27.5)),
+    ("trinity_large_preview.s6144_scan, a windowed layer", TRINITY, 4096,
+     ("flash_swa_fwd", "flash_swa_bwd_fused"), 72, 6, (21, 27.5)),
+    ("nemotron3_nano_30b_a3b.s8192_scan", NEMOTRON, None,
+     ("flash_fwd", "flash_bwd_fused"), 136, 16, (48.5, 50.5)),
+    ("solar_open2_250b.s4096_scan", SOLAR, None,
+     ("flash_fwd", "flash_bwd_fused"), 36, 8, (26.5, 28.5)),
+    ("jamba2_3b.s8192_scan", JAMBA, None,
+     ("flash_fwd", "flash_bwd_fused"), 136, 20, (59.5, 58.5)),
 ])
 def test_grouped_and_windowed_kernels_compile_for_a_v5e(
-        one_chip, what, shape, window, names, steps, mib):
+        one_chip, what, shape, window, names, steps, heads, mib):
     """28 query heads on 4 key/value heads of 128 over 16,384 positions:
     the index maps' reads of the scalar-prefetched step table and the
     backward's one sweep over a group's heads, dk and dv of the whole
-    sequence in two float32 accumulators (16 MiB of the 40 the call asks
+    sequence in two float32 accumulators (16 MiB of the 44.5 the call asks
     for), are what Mosaic has to take; at 32 on 8 heads of 64, the two heads
     of a lane block stacked along rows (PR 41): the lane rotation that moves
     a head to its key/value head's columns, the [1024, 512] tiles of a step
-    and the stack's scratch (q, do, lse, delta, dq: 18.8 MiB where the
-    parent took 16.7 of the 28 asked for; the forward 6.4 MiB where 4.1).
-    The width-128 modules are the parent's (``SWEEP_MOSAIC``).  The grids
-    are the tables: (row, key/value head-block, query head-block of its
-    group) by the blocks under the diagonal (in the band), the backward's
-    (row, key/value head-block) by the group's times as many."""
+    and the stack's scratch.  A group's ``heads`` query head-blocks ride ONE
+    grid step (PR 68: seven, Trinity's six, Solar's eight, Nemotron's
+    sixteen, Jamba's twenty, LFM2's four stacked blocks), unrolled in the
+    body; the calls ask the VMEM ``_Geom.heads_in_step`` counts (the
+    forward too, past Mosaic's own 16 MiB) and Mosaic takes less.  The
+    ungrouped modules are the parent's (``SWEEP_MOSAIC``) and ask what they
+    asked.  The grids are the tables: (row, key/value head-block, chunk of
+    ``heads`` of its group) by the blocks under the diagonal (in the band),
+    the backward's (row, key/value head-block) by the chunks' times as
+    many."""
     B, S, H, Hkv, D = shape
     xq = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
     xk = jax.ShapeDtypeStruct((B, S, Hkv * D), jnp.bfloat16, sharding=one_chip)
@@ -227,19 +247,31 @@ def test_grouped_and_windowed_kernels_compile_for_a_v5e(
         assert name in text, (what, name)
     stacked = fa._heads_per_block(D) if Hkv != H else 1
     assert stacked == (2 if shape is LFM2 else 1)
-    assert stacked > 1 or mosaic == SWEEP_MOSAIC[what], what
-    kv_blocks, group = Hkv * D // 128, H // Hkv
+    if what in SWEEP_MOSAIC:
+        assert mosaic == SWEEP_MOSAIC[what], what
+    kv_blocks, chunks = Hkv * D // 128, H // Hkv // heads
     assert fa.kv_blocks(S, 512, 512, True, window) == steps
-    assert grids == dict(zip(names, [(B, kv_blocks, group, steps),
-                                     (B, kv_blocks, group * steps)])), what
-    asked, took = _vmem(text, names[1])
-    assert asked == fa.fused_sweep_vmem_bytes(S, 128, 2) == mib * 2 ** 20
+    assert fa.packed_grid(B, S, H, D, 512, 512, n_kv_heads=Hkv, causal=True,
+                          window=window) == (heads,
+                                             B * kv_blocks * chunks * steps)
+    assert grids == dict(zip(names, [(B, kv_blocks, chunks, steps),
+                                     (B, kv_blocks, chunks * steps)])), what
+    g = fa._Geom(xq, xk, H, 512, 512, Hkv, window)
+    (asked_f, took_f), (asked, took) = (_vmem(text, n) for n in names)
+    assert (heads, asked_f or fa.SCOPED_VMEM) == (
+        g.heads_in_step("fwd")[0],
+        max(g.heads_in_step("fwd")[1], fa.SCOPED_VMEM))
+    assert (heads, asked) == g.heads_in_step("bwd")
+    # (one head a step: the default scope, which the text spells out where
+    # XLA keeps an array of its own in VMEM beside the call)
+    assert (asked_f or fa.SCOPED_VMEM, asked) == tuple(
+        int(m * 2 ** 20) for m in mib), what
     # the accumulators and the single-buffered output blocks, and a step's
-    # own blocks and tiles beside them: [stacked * 512, 512] float32, five
-    # of them live at the most, and the stack's scratch
-    least = S * 128 * (4 + 2) * 2 + (stacked - 1) * 6 * 2 ** 20
+    # own blocks and tiles beside them
+    least = S * 128 * (4 + 2) * 2 + (stacked * heads - 1) * 2 ** 20
     assert least < took < asked, what
-    assert (_vmem(text, names[0])[1] > 6 * 2 ** 20) == (stacked > 1), what
+    assert took_f < (asked_f or fa.SCOPED_VMEM), what
+    assert (took_f > 6 * 2 ** 20) == (stacked * heads > 1), what
 
 
 def test_the_value_width_kernels_compile_for_a_v5e(one_chip):
@@ -280,7 +312,9 @@ def test_a_sequence_past_the_rule_compiles_as_two_sweeps(one_chip):
         n_kv_heads=Hkv, window=4096)
     text, grids, _ = _compiled(attn, xq, xk, xk, xq)
     steps = fa.kv_blocks(S, 512, 512, True, 4096)
-    assert grids == {"flash_swa_fwd": (B, 2, 2, steps),
+    # the forward holds no sequence: its group's two heads ride one step;
+    # the two backward sweeps stay a head-block a step
+    assert grids == {"flash_swa_fwd": (B, 2, 1, steps),
                      "flash_swa_bwd_dq": (B, 2, 2, steps),
                      "flash_swa_bwd_dkv": (B, 2, 2 * steps)}
     # the default scope, which the text spells out where XLA keeps an array
@@ -1634,6 +1668,50 @@ def test_the_masked_sweeps_a_tiny_program_traces(tmp_path, model):
     finally:
         monitor.disable()
     assert got == MASKED_SWEEPS[model]
+
+
+# cell -> the several-block sweeps one traced forward + backward of its
+# PUBLISHED configuration counts in ``monitor.kernels.flash_sweep_calls``,
+# (part, group, heads in a step): SmallThinker's full and windowed layer
+# kinds (a forward each and one recomputed under remat), a group's seven
+# heads in every step; Nemotron's sixteen; LFM2's four stacked lane blocks
+SWEEPS = {
+    "smallthinker": ("smallthinker_21b_a3b_config", (1, 16384),
+                     {("fwd", 7, 7): 4, ("bwd", 7, 7): 2}),
+    "nemotron_h": ("nemotron3_nano_30b_a3b_config", (2, 8192),
+                   {("fwd", 16, 16): 2, ("bwd", 16, 16): 1}),
+    "lfm2": ("lfm2_8b_a1b_config", (2, 8192),
+             {("fwd", 4, 4): 2, ("bwd", 4, 4): 1}),
+}
+
+
+@pytest.mark.parametrize("model", list(SWEEPS))
+def test_the_grouped_sweeps_a_cell_s_program_traces(tmp_path, model):
+    """No chip and no compile: shapes alone through the cell's own
+    configuration, so what the counter says here is what a trace of the
+    cell says (``kernels/_common.count_call`` counts when a call is
+    traced)."""
+    from paddle_tpu import monitor
+    from paddle_tpu.parallel import decoder, transformer as T
+
+    config, batch, want = SWEEPS[model]
+    module = importlib.import_module("paddle_tpu.models." + model)
+    cfg = getattr(module, config)(remat=True)
+    params = jax.eval_shape(lambda: T._init_params(jax.random.PRNGKey(0), cfg))
+    loss = lambda p, i: jnp.sum(decoder.forward(p, i, cfg)[0].astype(
+        jnp.float32))
+    mon = monitor.enable(str(tmp_path), flight=False)
+    try:
+        mon.registry.reset()        # the registry is the process's
+        jax.eval_shape(jax.grad(loss), params,
+                       jax.ShapeDtypeStruct(batch, jnp.int32))
+        got = {tuple(r["labels"][n] for n in (
+            "part", "group", "heads_in_step")): r["value"]
+            for r in mon.registry.snapshot()
+            if r["name"] == "monitor.kernels.flash_sweep_calls"}
+    finally:
+        monitor.disable()
+    assert got == want
 
 
 @pytest.mark.parametrize("b,S", [(1, 16384), (2, 8192)])

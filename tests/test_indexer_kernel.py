@@ -9,6 +9,8 @@ statistic known (``dsa_attend_kl``: the output, the indexer's KL term, and
 the gradients of both).  ONE traced program a geometry (a group of 8, of 4,
 and a q block of two kv blocks): every check reads it."""
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,6 +18,8 @@ import pytest
 
 from paddle_tpu.kernels import indexer as ix
 from paddle_tpu.kernels.flash_attention import _fwd as causal_flash_fwd
+
+fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
 
 B, S, HI, DI, H, D, K, BLOCK = 2, 64, 4, 16, 8, 128, 8, 16
 TRI = np.tril(np.ones((S, S), bool))
@@ -206,8 +210,10 @@ def test_the_heads_a_step_come_from_the_shapes(monkeypatch):
     assert ix.heads_a_step(8, lambda n: n * 2 ** 20) == 8
     assert ix.heads_a_step(8, lambda n: n * 20 * 2 ** 20) == 2
     assert ix.heads_a_step(6, lambda n: n * 20 * 2 ** 20) == 3
-    with pytest.raises(AssertionError):
-        ix.heads_a_step(8, lambda n: 65 * 2 ** 20)
+    # the one function of both files; where not even one head fits it says
+    # one and the backward's call refuses (below)
+    assert ix.heads_a_step is fa.heads_a_step
+    assert ix.heads_a_step(8, lambda n: 65 * 2 ** 20) == 1
     r = np.random.RandomState(3)
     f32 = lambda *shape: jnp.asarray(r.randn(*shape), jnp.float32)
     qi, ki, w = f32(1, S, HI * DI), f32(1, S, DI), f32(1, S, HI)
@@ -225,7 +231,7 @@ def test_the_heads_a_step_come_from_the_shapes(monkeypatch):
 
     whole = sweeps()
     # room for the accumulators and two heads of the eight
-    monkeypatch.setattr(ix, "SWEEP_VMEM", ix.dsa_bwd_vmem_bytes(
+    monkeypatch.setattr(fa, "SWEEP_VMEM", ix.dsa_bwd_vmem_bytes(
         S, 2, D, D, 4, BLOCK, BLOCK))
     assert ix.heads_a_step(8, lambda n: ix.dsa_bwd_vmem_bytes(
         S, n, D, D, 4, BLOCK, BLOCK)) == 2
@@ -233,3 +239,7 @@ def test_the_heads_a_step_come_from_the_shapes(monkeypatch):
     assert np.array_equal(lse, whole[0]) and np.array_equal(dq, whole[1])
     for got, want in ((dk, whole[2]), (dv, whole[3])):
         assert np.max(np.abs(got - want)) <= 1e-5 * np.max(np.abs(want))
+    # dk and dv of a sequence that one head's step cannot hold: refused
+    monkeypatch.setattr(ix, "dsa_bwd_vmem_bytes", lambda *a, **k: 65 * 2 ** 20)
+    with pytest.raises(AssertionError, match="do not fit VMEM"):
+        sweeps()

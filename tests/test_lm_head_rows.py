@@ -380,7 +380,9 @@ def _flash_grid(cfg, b, S):
 def test_flash_grid_is_a_function_of_the_shapes_and_no_gauge(tmp_path):
     """(pairs a grid step, grid steps a layer and pass) are what
     ``kernels.flash_attention.packed_grid`` says of a call's shapes, the
-    function the kernels take their grid from, and the heads one kv step
+    function the kernels take their grid from (a grouped several-block
+    step: the query head-blocks of a group that ride it, PR 68), and the
+    heads one kv step
     computes as one tile 2 where the two heads of a 64-wide lane block read
     one key/value head (LFM2's 32 on 8), 1 in every other cell.  The
     configuration fixes all three, so a trainer writes none of them."""
@@ -415,11 +417,13 @@ def test_flash_grid_is_a_function_of_the_shapes_and_no_gauge(tmp_path):
             (dataclasses.replace(base, tp=2), 256, 128, (6, 128), 1),
             # the causal triangle: 36 of 8 x 8 blocks a (row, head)
             (olmoe.olmoe_1b_7b_config(), 4, 4096, (1, 4 * 16 * 36), 1),
-            # 28 on 4 heads of 128: a lane block is one head
+            # 28 on 4 heads of 128: a lane block is one head, a group's
+            # seven ride one step
             (smallthinker.smallthinker_21b_a3b_config(), 1, 16384,
-             (1, 28 * 528), 1),
-            # 32 on 8 heads of 64: the two heads of a lane block stacked
-            (lfm2.lfm2_8b_a1b_config(), 2, 8192, (1, 2 * 16 * 136), 2)]:
+             (7, 4 * 528), 1),
+            # 32 on 8 heads of 64: the two heads of a lane block stacked,
+            # the four query blocks of a key/value block in one step
+            (lfm2.lfm2_8b_a1b_config(), 2, 8192, (4, 2 * 4 * 136), 2)]:
         assert _flash_grid(c, b, s) == want + (heads,)
     # heads the packed layout cannot tile take another path
     assert T._packed_flash_blocks(bert.bert_tiny_config(), 4, S, 4) is None

@@ -94,24 +94,31 @@ from paddle_tpu.parallel.train import stack_batches  # noqa: E402
 # two lane blocks, the projections through ``_project``); the twenty-four
 # others stand: the row kernel at a head of one lane block traces the
 # operations it did (Mistral's ``pairs`` calls and the rotate-half ones).
+# PR 68 took the five GROUPED decoders' ten anew ON PURPOSE (SmallThinker,
+# LFM2, Trinity, Jamba, Nemotron-H: a several-block sweep's grid step holds
+# ``heads_a_step`` query head-blocks of a group, looped inside it, forward
+# and fused backward, ``kernels/flash_attention.py``); the eighteen others
+# stand: an ungrouped call is one head-block a step and traces the
+# operations it did (Mistral's, Ouro's, Kimi-Linear's and dots3's several-
+# block sweeps among them), and Keye's sweeps only import ``heads_a_step``.
 PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "bert.run_steps": "00de5403506fdc87",
             "olmoe.step": "231114fcd62341f2",
             "olmoe.run_steps": "054338e92270f130",
-            "smallthinker.step": "7c77df5736c908d4",
-            "smallthinker.run_steps": "cdbc3b5d4be19b90",
-            "lfm2.step": "7d3dd46e83117d83",
-            "lfm2.run_steps": "55c703e13aaa5114",
+            "smallthinker.step": "d2639bebb0a92617",
+            "smallthinker.run_steps": "db06137cf7e3521f",
+            "lfm2.step": "8d9d81a68daa3b51",
+            "lfm2.run_steps": "659cbb6a76ec3dc3",
             "brumby.step": "84e6b6d548803a44",
             "brumby.run_steps": "5a063ea89a19f1a4",
             "mistral4.step": "ffaa7601578581ff",
             "mistral4.run_steps": "9100e873b1250116",
-            "trinity.step": "86340ecdb523bc2f",
-            "trinity.run_steps": "c7581de1b602b474",
-            "jamba.step": "21dc4e9f64565ee9",
-            "jamba.run_steps": "3f4c6780114491e5",
-            "nemotron_h.step": "6bc4605ce6a59ef4",
-            "nemotron_h.run_steps": "3b52cbcf423cbc76",
+            "trinity.step": "78ef368cbdb2aeb1",
+            "trinity.run_steps": "f0d4a67c2fda2c5c",
+            "jamba.step": "7280f2f3e3854358",
+            "jamba.run_steps": "edaa74f73acd187a",
+            "nemotron_h.step": "cb51796539d97199",
+            "nemotron_h.run_steps": "d5d45b2ee3b29900",
             "ouro.step": "770de97dd5bbc8af",
             "ouro.run_steps": "64aaa9a06b2f9fbd",
             "resnet.step": "350db1fba0d68284",

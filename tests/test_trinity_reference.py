@@ -619,12 +619,13 @@ def test_counters_and_gauges_only_under_a_monitor_session(trained):
     assert 0.4 < gauges["monitor.train.attn_gate_mean"] < 0.6
     assert gauges["monitor.train.router_bias_abs_max"] > 0
     # a layer's grid: the causal triangle's 10 of 4 x 4 blocks a (sequence,
-    # head), from the function the kernels take their grid from
+    # key/value head), a group's three query heads riding each step (PR
+    # 68), from the function the kernels take their grid from
     assert packed_grid(
         B, S, cfg.n_heads, cfg.head_dim,
         *T._packed_flash_blocks(cfg, cfg.n_heads, S, cfg.kv_heads),
         itemsize=cfg.jdtype.itemsize, n_kv_heads=cfg.kv_heads,
-        causal=True) == (1, 120)
+        causal=True) == (3, 40)
 
 
 def test_the_new_scopes_hold_their_instructions(trained):
