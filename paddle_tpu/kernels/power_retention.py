@@ -452,7 +452,7 @@ def _step_vmem_bytes(heads, chunk, itemsize):
     their columns as values, four scratch arrays.
 
     Checked against the v5e compiler's own count (MiB, the backward's;
-    ``tests/test_flash_tpu_compile.py`` pins the first two, the Brumby
+    ``tests/test_chip_compile_scans.py`` pins the first two, the Brumby
     cell's, the others were compiled once for a described v5e and are not
     pinned): bf16, group 5, chunks of 1,024: 49.8 (66.7 here); the same at
     2,048: five heads would take 142, over the limit here too (121.2), so

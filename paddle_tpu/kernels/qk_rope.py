@@ -103,7 +103,7 @@ def vmem_bytes(bs, W, itemsize, shared=False):
     the projection, the angles', the weight's and the partial sums' blocks
     twice each, thirty-two float32 temporaries of a lane block's rows
     (Mosaic's stack does not reuse every one; the compiled kernels take 1 to
-    7 MiB of the 8 to 17 asked, ``tests/test_flash_tpu_compile.py``), and
+    7 MiB of the 8 to 17 asked, ``tests/test_chip_compile_rows.py``), and
     room; with ``shared`` its lane block and its gradient's, twice each."""
     return (6 * bs * W * itemsize + (4 + 32) * bs * LANES * 4
             + 2 * (SUBLANES + 1) * W * 4 + 2 * 2 ** 20
@@ -412,7 +412,7 @@ def touched_vmem_bytes(bs, itemsize):
     ``shared``'s gradient and the tables' two twice each, the float32 block
     the gradient is summed in, six float32 temporaries, and room (the
     compiled kernels take 1.8 to 4.6 MiB of the 9 asked at 1,024 rows of
-    bf16, ``tests/test_flash_tpu_compile.py``)."""
+    bf16, ``tests/test_chip_compile_rows.py``)."""
     return 6 * bs * LANES * itemsize + (4 + 1 + 6) * bs * LANES * 4 \
         + 2 * 2 ** 20
 

@@ -71,7 +71,7 @@ interpret=None auto-selects the Pallas interpreter off-TPU, so the CPU tests
 run the same code (kernels/flash_attention.py idiom), but for ONE hint: the
 eight tokens of a group are unrolled on the chip and stay a loop under the
 interpreter, whose XLA compile of eight copies of every body was two of the
-CPU tests' minutes (``tests/test_flash_tpu_compile.py`` compiles the
+CPU tests' minutes (``tests/test_chip_compile_scans.py`` compiles the
 unrolled bodies for a described v5e).
 """
 

@@ -20,9 +20,8 @@ program's memory ledger and for what is large in it (``value_sizes``).
     for label, names in devscope.scope_maps().items():
         phase, scope = devscope.classify(names["fusion.2345"])
 
-Only plain ``jax.jit`` programs are registered.  A step built with a
-``warm_key`` is a ``warm.WarmCallable``, which has no ``lower``: it is
-skipped (neither ``build_*_trainer`` nor the benchmark passes one).
+Only plain ``jax.jit`` programs are registered.  A ``warm.WarmCallable``
+has no ``lower``: it is skipped (``make_train_step`` builds none).
 """
 
 import collections

@@ -249,7 +249,7 @@ def _vmem_bytes(tm, tk, tn, itemsize, dw=False):
     reads [tm, tk] and [tm, tn] rows for a [tk, tn] block of dW.  The
     compiled kernels take up to 2.1 MiB more at the cells' shapes (the
     masks' float32 copies, a transposed weight block's copy):
-    ``tests/test_flash_tpu_compile.py`` holds that against the scope."""
+    ``tests/test_chip_compile_moe.py`` holds that against the scope."""
     read = tm * tk + (tm * tn if dw else tk * tn)
     out = tk * tn if dw else tm * tn
     return 2 * (read + out) * itemsize + 4 * out

@@ -19,7 +19,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import collectives as col
 from .mesh import local_shard_map
-from .. import warm as _warm
 from ..monitor import devscope as _devscope, memscope as _memscope
 from ..monitor.recompile import FIRST_CALL, compile_ledger
 
@@ -85,7 +84,7 @@ def _tree_bytes(tree):
 
 
 def make_train_step(loss_fn, mesh, param_specs, grad_syncs, optimizer,
-                    batch_specs, donate=True, warm_key=None, stepped=()):
+                    batch_specs, donate=True, stepped=()):
     """Build the jitted sharded train step: the one builder every
     ``build_*_trainer`` goes through.  It decides where the gradients are
     summed, what is donated, how N steps become one dispatch and, through
@@ -119,14 +118,6 @@ def make_train_step(loss_fn, mesh, param_specs, grad_syncs, optimizer,
     batch_specs: pytree of PartitionSpec for the batch dict.
     Returns build(state) -> step(state, batch, lr) -> (state, loss), and
     build.multi(state) for the scan over staged batches.
-
-    warm_key: a durable model identity (e.g. ``"bert_base"``) that routes
-    compilation through the WarmStart executable store (warm.py): the step
-    AOT-compiles on first call, persists next to the checkpoints, and a
-    respawned process deserializes instead of re-paying XLA — with the
-    rule-derived specs, the mesh topology and the donation flag all in the
-    cache key.  ``None`` (default) keeps the plain in-process jit (a bare
-    loss_fn has no content fingerprint, so persistence is opt-in by name).
     """
     _, opt_update = optimizer
 
@@ -169,24 +160,9 @@ def make_train_step(loss_fn, mesh, param_specs, grad_syncs, optimizer,
             out_specs=(sspecs, P()),
         )
 
-    def _warm_parts(kind):
-        return {"kind": kind, "key": warm_key,
-                "mesh": _warm.mesh_desc(mesh),
-                "specs": [repr(param_specs), repr(batch_specs),
-                          repr(grad_syncs)],
-                # an edited loss or optimizer must not be served the old
-                # math from disk even when every shape/spec is unchanged
-                "code": _warm.code_fingerprint(loss_fn, opt_update),
-                "donate": bool(donate)}
-
     def build(state_template):
-        mapped = _mapped(state_template)
-        kw = {"donate_argnums": (0,) if donate else ()}
-        if warm_key is None:
-            return jax.jit(mapped, **kw)
-        return _warm.WarmCallable(mapped, _warm_parts("train_step"),
-                                  jit_kwargs=kw,
-                                  label="train_step:%s" % warm_key)
+        return jax.jit(_mapped(state_template),
+                       donate_argnums=(0,) if donate else ())
 
     def build_multi(state_template):
         """Device-side training loop: ONE dispatch runs N steps via lax.scan
@@ -200,12 +176,7 @@ def make_train_step(loss_fn, mesh, param_specs, grad_syncs, optimizer,
         def multi(state, batches, lr):
             return jax.lax.scan(lambda st, b: mapped(st, b, lr), state, batches)
 
-        kw = {"donate_argnums": (0,) if donate else ()}
-        if warm_key is None:
-            return jax.jit(multi, **kw)
-        return _warm.WarmCallable(multi, _warm_parts("train_multi"),
-                                  jit_kwargs=kw,
-                                  label="train_multi:%s" % warm_key)
+        return jax.jit(multi, donate_argnums=(0,) if donate else ())
 
     build.multi = build_multi
     return build

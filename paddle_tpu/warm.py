@@ -247,8 +247,8 @@ def program_fingerprint(program):
 def code_fingerprint(*fns):
     """Best-effort content hash of python callables (bytecode + consts +
     names + qualname, recursing one level into code-object consts).  Keys
-    that name a model (``warm_key``) fold this in so editing the loss or
-    optimizer math invalidates the persisted executable even when every
+    that name a model (``WarmCallable``'s ``key_parts``) fold this in so
+    editing the loss or optimizer math invalidates the persisted executable even when every
     shape and spec stays the same.  Closure VALUES are not hashable here —
     a fn closing over changed data still needs a new key from the caller."""
     h = hashlib.sha256()

@@ -103,7 +103,7 @@ def kernel_seconds(run, names):
 
 def kernel_grids(fn, *args):
     """The grid of each kernel ``fn`` calls, by kernel name, read off the
-    traced program as ``tests/test_flash_tpu_compile.py`` reads it."""
+    traced program as ``tests/test_chip_compile_scans.py`` reads it."""
     return {name: [int(n) for n in grid.split(",") if n.strip()]
             for grid, name in re.findall(
                 r"grid=\(([\d, ]*)\).*?name=(power_retention_\w+)",

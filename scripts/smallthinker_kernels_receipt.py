@@ -5,7 +5,7 @@ cell's shapes (B = 1, S = 16,384; 28 query heads on 4 key/value heads of
     chiprun -- python3 scripts/smallthinker_kernels_receipt.py [out.json]
 
 The CPU tests hold these paths to a reference in interpret mode at tiny
-sizes, and ``tests/test_flash_tpu_compile.py`` reads kernel names in the
+sizes, and ``tests/test_chip_compile_flash.py`` reads kernel names in the
 compiled text; this holds what Mosaic compiled, at the published shapes, to
 plain ``jax.numpy`` in float32 at ``highest`` precision on the same
 bf16-rounded inputs, forward and every gradient against a random cotangent:

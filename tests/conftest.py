@@ -13,6 +13,9 @@ if "host_platform_device_count" not in flags:
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
+# the helper modules' asserts explain themselves as a test file's do
+pytest.register_assert_rewrite("decoder_reference", "tpu_compile")
+
 
 def pytest_configure(config):
     # tier-1 runs with -m 'not slow' (ROADMAP.md): register the marker so

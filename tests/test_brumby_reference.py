@@ -13,28 +13,18 @@ single logit row or gradient element, against the largest of its leaf: the
 quotient of two sums of up to 64 squared products rounds more than a
 softmax does."""
 
-import os
 import re
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark.reference import brumby_14b as reference  # noqa: E402
-from paddle_tpu import monitor  # noqa: E402
-from paddle_tpu.kernels import power_retention as pr  # noqa: E402
-from paddle_tpu.models import (bert, brumby, lfm2, olmoe,  # noqa: E402
-                               smallthinker)
-from paddle_tpu.monitor import devscope  # noqa: E402
-from paddle_tpu.parallel import decoder, optim, transformer as T  # noqa: E402
-from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
-from paddle_tpu.parallel.train import stack_batches  # noqa: E402
+import decoder_reference as H
+from benchmark.reference import brumby_14b as reference
+from paddle_tpu.kernels import power_retention as pr
+from paddle_tpu.models import bert, brumby, lfm2, olmoe, smallthinker
+from paddle_tpu.parallel import decoder, transformer as T
 
 B, S, TOL = 2, 64, 1e-5
 EACH = 3 * TOL         # one logit row, one gradient element
@@ -48,55 +38,7 @@ LEAVES = ["tok_emb", "lm_head", "lnf_scale"] \
     + ["params_layers/p0/" + n for n in NAMES]
 
 
-def _trainer(seed=3, optimizer=None, **cfg):
-    return brumby.build_brumby_trainer(
-        brumby.brumby_tiny_config(**cfg), MeshSpec(dp=1),
-        optimizer=optimizer or optim.adamw(), seed=seed,
-        devices=jax.devices()[:1])
-
-
-def _ids(seed=0, n=1):
-    rng = np.random.RandomState(seed)
-    return [rng.randint(0, 256, (B, S)).astype(np.int32) for _ in range(n)]
-
-
-def _seeded_params(tr):
-    """The trainer's seeded weights with the norm scales moved off 1, so
-    that a missing or misplaced scale shows, and a gate projection steep
-    enough that the decays differ from token to token."""
-    rng = np.random.RandomState(11)
-
-    def moved(path, a):
-        name = jax.tree_util.keystr(path)
-        if "scale" in name or "_norm" in name:
-            return np.asarray(a) * rng.uniform(0.5, 1.5, a.shape).astype("f4")
-        return np.asarray(a) * (3.0 if "wg" in name else 1.0)
-
-    return jax.tree_util.tree_map_with_path(moved, tr.state["params"])
-
-
-def _leaf(tree, path):
-    for part in path.split("/"):
-        tree = tree[part]
-    return tree
-
-
-@pytest.fixture(scope="module")
-def both():
-    """Loss and gradients of program and reference on the same weights."""
-    tr = _trainer()
-    params = _seeded_params(tr)
-    ids = _ids()[0]
-    loss_fn = decoder.make_loss_fn(tr.cfg)
-    got = jax.jit(jax.value_and_grad(
-        lambda p: loss_fn(p, {"ids": jnp.asarray(ids)})))(params)
-    want = jax.value_and_grad(
-        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
-            jax.tree.map(jnp.asarray, params))
-    return tr.cfg, params, ids, got, want
-
-
-def test_the_tiny_configuration_keeps_every_mechanism():
+def _mechanism():
     cfg = brumby.brumby_tiny_config()
     assert cfg.layer_kinds == (T.RETENTION,) and cfg.prefix_kinds == ()
     assert cfg.per_position and cfg.n_periods == 2 and cfg.moe_layers == 0
@@ -117,48 +59,11 @@ def test_the_tiny_configuration_keeps_every_mechanism():
     assert pr.STATE_COLUMNS == 8320 and pr.DIAGONALS == 65
 
 
-def test_loss_equals_the_reference(both):
-    _, _, _, (got, _), (want, _) = both
-    assert abs(float(got) - float(want)) / float(want) < TOL
-
-
-def test_every_position_s_logits_equal_the_reference(both):
-    cfg, params, ids, _, _ = both
-    x, _ = jax.jit(lambda p, i: decoder.forward(p, i, cfg))(params, ids)
-    got = T.rms_norm(x, params["lnf_scale"], cfg.norm_eps) @ params["lm_head"].T
-    want = np.stack(reference.forward(params, ids, MODEL)[1])
-    np.testing.assert_allclose(got, want, rtol=1e-4,
-                               atol=EACH * np.abs(want).max())
-
-
-@pytest.mark.parametrize("path", LEAVES)
-def test_gradient_of_every_leaf_equals_the_reference(both, path):
-    _, params, _, (_, got), (_, want) = both
-    g, w = np.asarray(_leaf(got, path)), np.asarray(_leaf(want, path))
-    assert g.shape == _leaf(params, path).shape and np.abs(w).max() > 0
-    np.testing.assert_allclose(g, w, rtol=1e-4, atol=EACH * np.abs(w).max())
-
-
-def test_the_leaves_tested_are_all_there_are(both):
-    _, params, _, _, _ = both
-    paths, _, _ = __import__(
-        "paddle_tpu.parallel.rules", fromlist=["leaf_paths"]).leaf_paths(params)
-    assert set(paths) == set(LEAVES)
+def _shapes(both):
+    params = both.params
     assert params["params_layers"]["p0"]["wg"].shape == (2, 64, 2)
     assert params["params_layers"]["p0"]["wg"].dtype == np.float32
     assert params["params_layers"]["p0"]["q_norm"].shape == (2, 128)
-
-
-def test_sharding_specs_and_gradient_syncs_follow_the_tree():
-    cfg = brumby.brumby_tiny_config()
-    params = jax.eval_shape(
-        lambda: T.init_transformer_params(jax.random.PRNGKey(0), cfg))
-    for tree in (T.transformer_param_specs(cfg), T.grad_sync_axes(cfg)):
-        assert jax.tree.structure(
-            tree, is_leaf=lambda x: isinstance(x, (tuple, T.P))) == \
-            jax.tree.structure(params)
-    assert T.transformer_param_specs(cfg)["params_layers"]["p0"]["wg"] == \
-        T.P(None, None, None)
 
 
 # ---------------------------------------------------------------------------
@@ -330,7 +235,7 @@ def test_the_eight_vocabulary_slices_logits_are_the_uncut_model_s_columns():
     """A chip's slice of the vocabulary is a smaller vocabulary: with the
     head's rows [lo, lo + V/8) the logits are those columns of the uncut
     model's, for ids inside the slice."""
-    ids = jnp.asarray(_ids(seed=4)[0] % 32)
+    ids = jnp.asarray(H.ids(CASE, seed=4)[0] % 32)
 
     def logits(cfg):
         return jax.jit(lambda p: T.head_logits(
@@ -453,22 +358,12 @@ SEEDED = {'bert_tiny_config': {"['lnf_bias']": 0.0,
                               "['tok_emb']": 13071.30987293271}}
 
 
-@pytest.fixture(scope="module")
-def witnessed():
-    tr = _trainer()
-    params = _seeded_params(tr)
-    tr.state["params"] = jax.tree.map(jnp.asarray, params)
-    ids = _ids(seed=9)[0][:1]       # one sequence: the cell's batch
-    program = np.asarray(tr.logits_at(ids, reference.witness_positions(S)))
-    return params, ids, program
-
-
 def test_the_witness_reads_both_groups(witnessed):
     """What ``benchmark/drivers/train_scan_witnessed.py`` checks on the chip:
     the trainer's own forward at the witness's positions against the
     reference's logits; the statistic is the larger group's third
     quartile."""
-    params, ids, program = witnessed
+    params, ids, program, _ = witnessed
     groups = reference.witness_groups(S)
     assert groups["edge"].tolist() == [
         at + i for at in (16, 32, 48) for i in range(8)]
@@ -485,86 +380,49 @@ def test_the_witness_reads_both_groups(witnessed):
     assert parts["edge"] == np.quantile(per[:, :24], 0.75)
 
 
-@pytest.mark.parametrize("fault", reference.FAULTS[:-1])
-def test_the_witness_sees_every_fault(witnessed, fault):
-    """Each fault in the reference moves its logits away from the program's
-    by a thousand times what the two differ by when both are sound, at the
-    witness's own statistic; the three faults of the carried state show in
-    the ``edge`` group."""
-    params, ids, program = witnessed
-    args = (program, params, {"ids": ids}, MODEL)
-    assert reference.logits_error(*args, faults=(fault,)) > 1e3 * TOL
+def _edge(args, fault):
+    """The three faults of the carried state show in the ``edge`` group."""
     if fault in ("state_dropped_at_chunk_edges", "state_read_undecayed",
                  "sqrt2_left_out_of_state"):
         assert reference.group_errors(*args, faults=(fault,))["edge"] \
             > 1e3 * TOL
 
 
-def test_bfloat16_throughout_moves_the_reference_s_loss(both):
-    _, params, ids, _, (want, _) = both
-    bad = reference.loss(params, {"ids": ids}, MODEL,
-                         faults=("bfloat16_throughout",))
-    assert abs(bad - float(want)) / float(want) > 2 * TOL
+def _specs(specs):
+    assert specs["params_layers"]["p0"]["wg"] == T.P(None, None, None)
 
 
-def test_the_reference_in_small_pieces_equals_itself_whole(both, monkeypatch):
-    _, params, ids, _, (want, want_grad) = both
-    params = jax.tree.map(jnp.asarray, params)
-    monkeypatch.setattr(reference, "QUERY_BLOCK", 16)       # 4 blocks of 64
-    monkeypatch.setattr(reference, "VOCAB_CHUNK", 100)      # 100, 100, 56
-    monkeypatch.setattr(reference, "DENSE_CHUNK", 40)       # 40, 40, 16
-    loss, grad = jax.value_and_grad(
-        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
-            params)
-    assert abs(float(loss) - float(want)) / float(want) < 1e-6
-    for g, w in zip(jax.tree.leaves(grad), jax.tree.leaves(want_grad)):
-        np.testing.assert_allclose(g, w, rtol=1e-4,
-                                   atol=1e-5 * max(np.abs(w).max(), 1e-30))
+CASE = H.Case(
+    "brumby", reference, MODEL, tuple(LEAVES), each=EACH,
+    # a gate projection steep enough that the decays differ from token to
+    # token
+    gain=H.steep("wg"),
+    mechanism=_mechanism, spec_configs=({},), bfloat16=True,
+    # 4 row blocks of 64; chunks of 100, 100, 56; the FFN's 40, 40, 16
+    pieces={"QUERY_BLOCK": 16, "VOCAB_CHUNK": 100, "DENSE_CHUNK": 40},
+    # ``both``'s trainer and weights, on ONE sequence (the cell's batch)
+    witness=H.Witness(), steps=2,
+    trained_cfg={"remat": True, "n_layers": 1},
+    also={"leaves": _shapes, "specs": _specs, "fault": _edge})
+globals().update(H.common(CASE))
 
 
-def test_run_steps_over_two_batches_equals_two_steps():
-    batches = [{"ids": i} for i in _ids(seed=5, n=2)]
-    one, scan = (_trainer(remat=True, n_layers=1) for _ in range(2))
-    singly = [float(one.step(b, 1e-3)) for b in batches]
-    scanned = scan.run_steps(
-        stack_batches(scan.mesh, decoder.BATCH_SPECS, batches), 1e-3)
-    np.testing.assert_allclose(scanned, singly, rtol=1e-5)
-    assert singly[0] != singly[1]
-    for a, b in zip(jax.tree.leaves(one.state["params"]),
-                    jax.tree.leaves(scan.state["params"])):
-        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+def test_gauges_only_under_a_monitor_session(trained):
+    cfg, chunk = trained.scan.cfg, min(trained.scan.cfg.retention_chunk, S)
+    assert S // chunk == 4
+    # a sweep of the state's tiles a key/value head and chunk: the
+    # five heads of a group ride one grid step
+    assert pr.state_sweeps(cfg.n_heads, cfg.kv_heads, S, chunk,
+                           cfg.jdtype.itemsize) == 8
+    np.testing.assert_allclose(
+        cfg.kv_heads * pr.STATE_COLUMNS * cfg.head_dim * 4 / 1e6,
+        2 * 8320 * 128 * 4 / 1e6)
+    # seeded gates: sigmoid of a unit-scale projection, mean one half
+    assert 0.4 < trained.value("monitor.train.retention_gate_mean") < 0.6
 
 
-def test_gauges_only_under_a_monitor_session(tmp_path):
-    tr = _trainer(n_layers=1)
-    assert monitor.active() is None
-    batches = [{"ids": i} for i in _ids(seed=8, n=2)]
-    mon = monitor.enable(str(tmp_path), flight=False)
-    try:
-        reg = mon.registry
-        tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS, batches), 1e-3)
-        cfg, chunk = tr.cfg, min(tr.cfg.retention_chunk, S)
-        assert S // chunk == 4
-        # a sweep of the state's tiles a key/value head and chunk: the
-        # five heads of a group ride one grid step
-        assert pr.state_sweeps(cfg.n_heads, cfg.kv_heads, S, chunk,
-                               cfg.jdtype.itemsize) == 8
-        np.testing.assert_allclose(
-            cfg.kv_heads * pr.STATE_COLUMNS * cfg.head_dim * 4 / 1e6,
-            2 * 8320 * 128 * 4 / 1e6)
-        # seeded gates: sigmoid of a unit-scale projection, mean one half
-        assert 0.4 < reg.gauge(
-            "monitor.train.retention_gate_mean").value < 0.6
-    finally:
-        monitor.disable()
-
-
-def test_the_retention_s_instructions_are_under_their_scope():
-    tr = _trainer(remat=True, n_layers=1)
-    tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS,
-                               [{"ids": i} for i in _ids(n=2)]), 1e-3)
-    names = devscope.scope_maps()["brumby.run_steps"]
-    got = {devscope.classify(op) for op in names.values()}
+def test_the_retention_s_instructions_are_under_their_scope(trained):
+    got = trained.scopes()
     for scope in ("retention", "mlp", "layer_norm", "lm_head", "embed"):
         assert ("forward", scope) in got and ("backward", scope) in got, scope
     assert ("recompute", "retention") in got

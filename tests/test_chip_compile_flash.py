@@ -1,0 +1,324 @@
+"""The flash kernels compiled for a described v5e, at the benchmark cells'
+real shapes: what interpret mode cannot see (Mosaic's tiling rules, the
+scoped VMEM a grid step may hold).  The several-block and one-block sweeps,
+their Mosaic digests (``ONE_BLOCK_MOSAIC``, ``SWEEP_MOSAIC``) and the grouped
+sweeps a cell's program traces.  Nothing runs; no chip is needed
+(``tests/tpu_compile.py``)."""
+
+import importlib
+import json
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from tpu_compile import (JAMBA, LFM2, MISTRAL4, NEMOTRON, SMALLTHINKER, SOLAR,
+                         TRINITY, _compiled, _vmem, one_chip)  # noqa: F401
+
+fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+
+
+@pytest.mark.parametrize("what,entry,B,S,H,D,causal,pairs", [
+    ("bert_base.s128_scan", "packed", 256, 128, 12, 64, False, 6),
+    ("bert_base.s512_scan", "packed", 64, 512, 12, 64, False, 1),
+    ("fine-tuning at 384", "packed", 32, 384, 12, 64, False, 2),
+    ("olmoe_1b_7b.s4096_scan", "packed", 4, 4096, 16, 128, True, 1),
+    # the same heads and length at the looped stack's batch: 48 layer
+    # applications a step call these
+    ("ouro_2_6b.s4096_scan", "packed", 2, 4096, 16, 128, True, 1),
+    ("a prime batch, causal", "packed", 7, 128, 12, 64, True, 21),
+    ("heads the packed layout cannot tile", "bshd", 32, 128, 3, 64, False, 4),
+])
+def test_forward_and_backward_compile_for_a_v5e(one_chip, what, entry, B, S,
+                                                H, D, causal, pairs):
+    if entry == "packed":
+        assert fa.packed_grid(B, S, H, D, 512, 512)[0] == pairs
+        x = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16,
+                                 sharding=one_chip)
+        attn = lambda q, k, v: fa.flash_attention_packed(
+            q, k, v, H, causal=causal, block_q=512, block_k=512,
+            interpret=False)
+    else:
+        assert fa.grid_geometry(B * H, S, S, 1, D, 2, S, S)[0] == pairs
+        x = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16,
+                                 sharding=one_chip)
+        attn = lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=causal, block_q=512, block_k=512,
+            interpret=False)
+
+    text, grids, mosaic = _compiled(attn, x, x, x, x)
+    assert text.count("tpu_custom_call") >= 2, what
+    if S > 512:     # several blocks: the sweeps' step tables are the grids,
+        # and the backward ONE sweep, dk and dv of all 4,096 positions in
+        # VMEM (22 MiB asked of Mosaic)
+        heads, steps = H * D // 128, fa.kv_blocks(S, 512, 512, causal)
+        assert grids == {"flash_fwd": (B, heads, 1, steps),
+                         "flash_bwd_fused": (B, heads, steps)} and steps == 36, what
+        asked, took = _vmem(text, "flash_bwd_fused")
+        assert asked == fa.fused_sweep_vmem_bytes(S, 128, 2) == 22 * 2 ** 20
+        assert 6 * 2 ** 20 < took < asked, what
+        assert mosaic == SWEEP_MOSAIC[what], what
+    else:           # one block: the kernels PR 28 left, to the letter
+        assert set(grids) == {"flash_fwd", "flash_bwd_fused"}, what
+        assert _vmem(text, "flash_bwd_fused")[0] is None, what
+        assert mosaic == ONE_BLOCK_MOSAIC[what], what
+
+
+# The one-block kernels' Mosaic modules as the several-block backward's
+# parent (6d15aec) lowers them, forward and backward: sha1 of each
+# ``tpu_custom_call`` body's text without debug info (``_compiled``).  A PR
+# that means to change these kernels replaces the digests; any other finds
+# here that it changed what every BERT cell runs.
+ONE_BLOCK_MOSAIC = {
+    "bert_base.s128_scan": ["1d482125fe7a", "496403bedea7"],
+    "bert_base.s512_scan": ["d6584da870c2", "a0feeacea941"],
+    "fine-tuning at 384": ["a2edfc8f7477", "4de8b028195f"],
+    "a prime batch, causal": ["f7cb281f682b", "40b5c4ca2615"],
+    "heads the packed layout cannot tile": ["72dc7b47f49c", "85a570491d08"],
+}
+
+
+# The several-block kernels at a head a lane block (width 128), forward and
+# backward, as PR 41's parent (c1b744a) lowers them: stacking the heads of a
+# 64-wide lane block (LFM2's row, not pinned) left every other cell's module
+# as it was.  Replaced like ``ONE_BLOCK_MOSAIC``.  PR 68 took SmallThinker's
+# two entries anew ON PURPOSE (a group's seven query heads ride one grid
+# step; they were 55126c1bd654 / 170f86a03873 full and 985173cce04a /
+# 42134345da2e windowed): the three UNGROUPED entries are c1b744a's still,
+# one head-block a step lowers to the text it did.
+SWEEP_MOSAIC = {
+    "olmoe_1b_7b.s4096_scan": ["05626943d473", "eba4a626459f"],
+    "ouro_2_6b.s4096_scan": ["8aec72d32a18", "afa8aab872da"],
+    "smallthinker_21b_a3b.s16384_scan, a full layer":
+        ["465f38b6b128", "1d46a9c9d9f7"],
+    "smallthinker_21b_a3b.s16384_scan, a windowed layer":
+        ["e3a26b4fe57d", "987d580b7182"],
+    "mistral_small_4_119b.s16384_scan, the latent expanded to 32 heads":
+        ["e267681a781d", "ad2a365dd0c8"],
+}
+
+
+@pytest.mark.parametrize("what,shape,window,names,steps,heads,mib", [
+    ("smallthinker_21b_a3b.s16384_scan, a full layer", SMALLTHINKER, None,
+     ("flash_fwd", "flash_bwd_fused"), 528, 7, (23.75, 44.5)),
+    ("smallthinker_21b_a3b.s16384_scan, a windowed layer", SMALLTHINKER, 4096,
+     ("flash_swa_fwd", "flash_swa_bwd_fused"), 252, 7, (23.75, 44.5)),
+    ("lfm2_8b_a1b.s8192_scan, two heads a lane block", LFM2, None,
+     ("flash_fwd", "flash_bwd_fused"), 136, 4, (27.5, 39.5)),
+    ("mistral_small_4_119b.s16384_scan, the latent expanded to 32 heads",
+     MISTRAL4, None, ("flash_fwd", "flash_bwd_fused"), 528, 1, (16, 40)),
+    ("trinity_large_preview.s6144_scan, a full layer", TRINITY, None,
+     ("flash_fwd", "flash_bwd_fused"), 78, 6, (21, 27.5)),
+    ("trinity_large_preview.s6144_scan, a windowed layer", TRINITY, 4096,
+     ("flash_swa_fwd", "flash_swa_bwd_fused"), 72, 6, (21, 27.5)),
+    ("nemotron3_nano_30b_a3b.s8192_scan", NEMOTRON, None,
+     ("flash_fwd", "flash_bwd_fused"), 136, 16, (48.5, 50.5)),
+    ("solar_open2_250b.s4096_scan", SOLAR, None,
+     ("flash_fwd", "flash_bwd_fused"), 36, 8, (26.5, 28.5)),
+    ("jamba2_3b.s8192_scan", JAMBA, None,
+     ("flash_fwd", "flash_bwd_fused"), 136, 20, (59.5, 58.5)),
+])
+def test_grouped_and_windowed_kernels_compile_for_a_v5e(
+        one_chip, what, shape, window, names, steps, heads, mib):
+    """28 query heads on 4 key/value heads of 128 over 16,384 positions:
+    the index maps' reads of the scalar-prefetched step table and the
+    backward's one sweep over a group's heads, dk and dv of the whole
+    sequence in two float32 accumulators (16 MiB of the 44.5 the call asks
+    for), are what Mosaic has to take; at 32 on 8 heads of 64, the two heads
+    of a lane block stacked along rows (PR 41): the lane rotation that moves
+    a head to its key/value head's columns, the [1024, 512] tiles of a step
+    and the stack's scratch.  A group's ``heads`` query head-blocks ride ONE
+    grid step (PR 68: seven, Trinity's six, Solar's eight, Nemotron's
+    sixteen, Jamba's twenty, LFM2's four stacked blocks), unrolled in the
+    body; the calls ask the VMEM ``_Geom.heads_in_step`` counts (the
+    forward too, past Mosaic's own 16 MiB) and Mosaic takes less.  The
+    ungrouped modules are the parent's (``SWEEP_MOSAIC``) and ask what they
+    asked.  The grids are the tables: (row, key/value head-block, chunk of
+    ``heads`` of its group) by the blocks under the diagonal (in the band),
+    the backward's (row, key/value head-block) by the chunks' times as
+    many."""
+    B, S, H, Hkv, D = shape
+    xq = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
+    xk = jax.ShapeDtypeStruct((B, S, Hkv * D), jnp.bfloat16, sharding=one_chip)
+    attn = lambda q, k, v: fa.flash_attention_packed(
+        q, k, v, H, causal=True, block_q=512, block_k=512, interpret=False,
+        n_kv_heads=Hkv, window=window)
+
+    text, grids, mosaic = _compiled(attn, xq, xk, xk, xq)
+    for name in names:
+        assert name in text, (what, name)
+    stacked = fa._heads_per_block(D) if Hkv != H else 1
+    assert stacked == (2 if shape is LFM2 else 1)
+    if what in SWEEP_MOSAIC:
+        assert mosaic == SWEEP_MOSAIC[what], what
+    kv_blocks, chunks = Hkv * D // 128, H // Hkv // heads
+    assert fa.kv_blocks(S, 512, 512, True, window) == steps
+    assert fa.packed_grid(B, S, H, D, 512, 512, n_kv_heads=Hkv, causal=True,
+                          window=window) == (heads,
+                                             B * kv_blocks * chunks * steps)
+    assert grids == dict(zip(names, [(B, kv_blocks, chunks, steps),
+                                     (B, kv_blocks, chunks * steps)])), what
+    g = fa._Geom(xq, xk, H, 512, 512, Hkv, window)
+    (asked_f, took_f), (asked, took) = (_vmem(text, n) for n in names)
+    assert (heads, asked_f or fa.SCOPED_VMEM) == (
+        g.heads_in_step("fwd")[0],
+        max(g.heads_in_step("fwd")[1], fa.SCOPED_VMEM))
+    assert (heads, asked) == g.heads_in_step("bwd")
+    # (one head a step: the default scope, which the text spells out where
+    # XLA keeps an array of its own in VMEM beside the call)
+    assert (asked_f or fa.SCOPED_VMEM, asked) == tuple(
+        int(m * 2 ** 20) for m in mib), what
+    # the accumulators and the single-buffered output blocks, and a step's
+    # own blocks and tiles beside them
+    least = S * 128 * (4 + 2) * 2 + (stacked * heads - 1) * 2 ** 20
+    assert least < took < asked, what
+    assert took_f < (asked_f or fa.SCOPED_VMEM), what
+    assert (took_f > 6 * 2 ** 20) == (stacked * heads > 1), what
+
+
+def test_the_value_width_kernels_compile_for_a_v5e(one_chip):
+    """kimi_linear_48b_a3b.s16384_scan's latent layer: 32 heads whose q and
+    k stand in 256 lanes (192 and 64 zeros) and whose v, o, do and dv are
+    128 wide, over 16,384 positions.  A query head-block is two lane blocks
+    and a value's one; the backward is ONE sweep, dk of the whole sequence
+    in a [16384, 256] float32 accumulator and dv in a [16384, 128] one (24
+    MiB of the 52 the call asks for, where one width of 256 would ask for
+    64); the grids are a one-width call's."""
+    B, S, H, D, Dv = 1, 16384, 32, 256, 128
+    xq = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
+    xv = jax.ShapeDtypeStruct((B, S, H * Dv), jnp.bfloat16, sharding=one_chip)
+    attn = lambda q, k, v: fa.flash_attention_packed(
+        q, k, v, H, causal=True, scale=192 ** -0.5, block_q=512, block_k=512,
+        interpret=False, v_head_dim=Dv)
+    assert jax.eval_shape(attn, xq, xq, xv).shape == xv.shape
+    text, grids, _ = _compiled(attn, xq, xq, xv, xv)
+    steps = fa.kv_blocks(S, 512, 512, True)
+    assert grids == {"flash_fwd": (B, H, 1, steps),
+                     "flash_bwd_fused": (B, H, steps)}
+    asked, took = _vmem(text, "flash_bwd_fused")
+    assert asked == fa.fused_sweep_vmem_bytes(S, D, 2, Dv) == 52 * 2 ** 20
+    assert fa.fused_sweep_vmem_bytes(S, D, 2) == 64 * 2 ** 20
+    assert S * (D + Dv) * (4 + 2) < took < asked
+
+
+def test_a_sequence_past_the_rule_compiles_as_two_sweeps(one_chip):
+    """S = 65,536 at 128 lanes would ask for 112 MiB: ``flash_bwd_dq`` and
+    ``flash_bwd_dkv`` in Mosaic's own scope, as every several-block shape
+    ran before the one sweep."""
+    B, S, H, Hkv, D = 1, 65536, 4, 2, 128
+    assert fa.bwd_sweeps(S, 512, D, 2, H // Hkv) == 2
+    xq = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
+    xk = jax.ShapeDtypeStruct((B, S, Hkv * D), jnp.bfloat16, sharding=one_chip)
+    attn = lambda q, k, v: fa.flash_attention_packed(
+        q, k, v, H, causal=True, block_q=512, block_k=512, interpret=False,
+        n_kv_heads=Hkv, window=4096)
+    text, grids, _ = _compiled(attn, xq, xk, xk, xq)
+    steps = fa.kv_blocks(S, 512, 512, True, 4096)
+    # the forward holds no sequence: its group's two heads ride one step;
+    # the two backward sweeps stay a head-block a step
+    assert grids == {"flash_swa_fwd": (B, 2, 1, steps),
+                     "flash_swa_bwd_dq": (B, 2, 2, steps),
+                     "flash_swa_bwd_dkv": (B, 2, 2 * steps)}
+    # the default scope, which the text spells out where XLA keeps an array
+    # of its own in VMEM beside the call (``_vmem``)
+    for kernel in ("flash_swa_bwd_dq", "flash_swa_bwd_dkv"):
+        assert _vmem(text, kernel)[0] in (None, fa.SCOPED_VMEM)
+
+
+@pytest.mark.parametrize("cell,kind,kernels", [
+    # LFM2's two heads a lane block
+    ("lfm2_8b_a1b.s8192_scan", "(None, True)",
+     {"qk_rope_fwd", "qk_rope_bwd", "flash_fwd", "flash_delta",
+      "flash_bwd_fused"}),
+    # Nemotron-H's Mamba-2 mixer: two groups of 128 channels, float32
+    ("nemotron3_nano_30b_a3b.s8192_scan", "mamba2",
+     {"mamba_filter_fwd", "mamba_filter_bwd", "ssd_scan_fwd", "ssd_scan_bwd",
+      "gated_norm_fwd", "gated_norm_bwd"}),
+    # Kimi-Linear's KDA mixer: the tiny heads of 16 (32 channels a filter)
+    # keep the ``jnp`` lines around ``kda_chunked`` and the filters'
+    ("kimi_linear_48b_a3b.s16384_scan", "kda", set()),
+])
+def test_attn_outside_hlo_smoke(one_chip, capsys, cell, kind, kernels):
+    """The script end to end at a tiny configuration, the kernels compiled
+    for the described chip."""
+    import sys
+
+    sys.path.insert(0, os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "scripts"))
+    hlo = importlib.import_module("attn_outside_hlo")
+    report = hlo.main([cell, "--tiny", "--top", "3"])
+    assert report["kind"] == kind and report["seq"] == 256
+    assert set(report["kernels_gb"]) == kernels
+    assert 0 < report["gb"]["other"]
+    if kind != "mamba2":        # a tiny mixer's matmuls are its least part
+        assert report["gb"]["other"] < report["gb"]["matmul"]
+    printed = capsys.readouterr().out.splitlines()
+    assert json.loads(printed[-1]) == report
+    # XLA's estimated cycles beside each listed instruction's bytes, and
+    # their sum over "other" in milliseconds (PR 55)
+    assert len(printed) == 4 and all(
+        re.match(r"\s*[\d.]+ MB\s+\d+ cycles  %", l) for l in printed[:3])
+    assert 0 < report["other_estimated_ms"] < 1
+    # the gradients are the leaves' the branch reads (PR 55): a layer's
+    # experts and norms of the other branch are none of them
+    cfg, batch, seq = hlo.cell_config(cell, tiny=True)
+    layer = hlo.default_kind(cfg)
+    leaves, h = hlo.layer_shapes(cfg, batch, seq, layer)
+    read = hlo.leaves_read(hlo.branch_of(cfg, layer), leaves, h)
+    experts = {"ln1_scale", "ln2_scale", "router", "we_down", "we_gate_up"}
+    assert set(leaves) - set(read) == {
+        "mamba2": {"ln1_scale"},
+        "kda": experts | {"ws_down", "ws_gate_up"}}.get(kind, experts)
+    assert {"mamba2": {"w_in", "w_out"},
+            "kda": {"a_log", "dt_bias", "o_norm", "w_fb", "w_gb", "wo"}}.get(
+                kind, set(read)) <= set(read)
+    assert kind in ("mamba2", "kda") or set(read) == {
+        "wq", "wk", "wv", "wo", "q_norm", "k_norm"}
+
+
+# cell -> the several-block sweeps one traced forward + backward of its
+# PUBLISHED configuration counts in ``monitor.kernels.flash_sweep_calls``,
+# (part, group, heads in a step): SmallThinker's full and windowed layer
+# kinds (a forward each and one recomputed under remat), a group's seven
+# heads in every step; Nemotron's sixteen; LFM2's four stacked lane blocks
+SWEEPS = {
+    "smallthinker": ("smallthinker_21b_a3b_config", (1, 16384),
+                     {("fwd", 7, 7): 4, ("bwd", 7, 7): 2}),
+    "nemotron_h": ("nemotron3_nano_30b_a3b_config", (2, 8192),
+                   {("fwd", 16, 16): 2, ("bwd", 16, 16): 1}),
+    "lfm2": ("lfm2_8b_a1b_config", (2, 8192),
+             {("fwd", 4, 4): 2, ("bwd", 4, 4): 1}),
+}
+
+
+@pytest.mark.parametrize("model", list(SWEEPS))
+def test_the_grouped_sweeps_a_cell_s_program_traces(tmp_path, model):
+    """No chip and no compile: shapes alone through the cell's own
+    configuration, so what the counter says here is what a trace of the
+    cell says (``kernels/_common.count_call`` counts when a call is
+    traced)."""
+    from paddle_tpu import monitor
+    from paddle_tpu.parallel import decoder, transformer as T
+
+    config, batch, want = SWEEPS[model]
+    module = importlib.import_module("paddle_tpu.models." + model)
+    cfg = getattr(module, config)(remat=True)
+    params = jax.eval_shape(lambda: T._init_params(jax.random.PRNGKey(0), cfg))
+    loss = lambda p, i: jnp.sum(decoder.forward(p, i, cfg)[0].astype(
+        jnp.float32))
+    mon = monitor.enable(str(tmp_path), flight=False)
+    try:
+        mon.registry.reset()        # the registry is the process's
+        jax.eval_shape(jax.grad(loss), params,
+                       jax.ShapeDtypeStruct(batch, jnp.int32))
+        got = {tuple(r["labels"][n] for n in (
+            "part", "group", "heads_in_step")): r["value"]
+            for r in mon.registry.snapshot()
+            if r["name"] == "monitor.kernels.flash_sweep_calls"}
+    finally:
+        monitor.disable()
+    assert got == want

@@ -20,27 +20,19 @@ The tiny configuration computes in float32, so the tolerance is 1e-5 on the
 loss (the two differ by accumulation order only) and three times that on a
 single logit row or gradient element, against the largest of its leaf."""
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark.reference import jamba2_3b as reference  # noqa: E402
-from paddle_tpu import monitor  # noqa: E402
-from paddle_tpu.kernels import selective_scan as ss  # noqa: E402
-from paddle_tpu.kernels.flash_attention import packed_grid  # noqa: E402
-from paddle_tpu.models import jamba  # noqa: E402
-from paddle_tpu.monitor import devscope  # noqa: E402
-from paddle_tpu.parallel import decoder, optim, transformer as T  # noqa: E402
-from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
-from paddle_tpu.parallel.train import stack_batches  # noqa: E402
+import decoder_reference as H
+from benchmark.reference import jamba2_3b as reference
+from paddle_tpu import monitor
+from paddle_tpu.kernels import selective_scan as ss
+from paddle_tpu.kernels.flash_attention import packed_grid
+from paddle_tpu.models import jamba
+from paddle_tpu.monitor import devscope
+from paddle_tpu.parallel import decoder, transformer as T
 
 B, S, TOL = 2, 64, 1e-5
 EACH = 3 * TOL         # one logit row, one gradient element
@@ -57,54 +49,7 @@ LEAVES = ["tok_emb", "lnf_scale"] \
     + ["params_layers/r1/" + n for n in FFN + reference.ATTENTION_LEAVES]
 
 
-def _trainer(seed=3, optimizer=None, **cfg):
-    return jamba.build_jamba_trainer(
-        jamba.jamba_tiny_config(**cfg), MeshSpec(dp=1),
-        optimizer=optimizer or optim.adamw(), seed=seed,
-        devices=jax.devices()[:1])
-
-
-def _ids(seed=0, n=1):
-    rng = np.random.RandomState(seed)
-    return [rng.randint(0, 256, (B, S)).astype(np.int32) for _ in range(n)]
-
-
-def _seeded_params(tr):
-    """The trainer's seeded weights with the norm scales, the skip and the
-    rates moved off their seeds, so that a missing or misplaced one shows."""
-    rng = np.random.RandomState(11)
-
-    def moved(path, a):
-        name = jax.tree_util.keystr(path)
-        if any(w in name for w in ("scale", "_norm", "d_skip", "a_log")):
-            return np.asarray(a) * rng.uniform(0.5, 1.5, a.shape).astype("f4")
-        return np.asarray(a)
-
-    return jax.tree_util.tree_map_with_path(moved, tr.state["params"])
-
-
-def _leaf(tree, path):
-    for part in path.split("/"):
-        tree = tree[part]
-    return tree
-
-
-@pytest.fixture(scope="module")
-def both():
-    """Loss and gradients of program and reference on the same weights."""
-    tr = _trainer()
-    params = _seeded_params(tr)
-    ids = _ids()[0]
-    loss_fn = decoder.make_loss_fn(tr.cfg)
-    got = jax.jit(jax.value_and_grad(
-        lambda p: loss_fn(p, {"ids": jnp.asarray(ids)})))(params)
-    want = jax.value_and_grad(
-        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
-            jax.tree.map(jnp.asarray, params))
-    return tr.cfg, params, ids, got, want
-
-
-def test_the_tiny_configuration_keeps_every_mechanism():
+def _mechanism():
     cfg = jamba.jamba_tiny_config()
     attention = (None, False)
     assert cfg.layer_kinds == (T.MAMBA, T.MAMBA, attention, T.MAMBA)
@@ -124,43 +69,24 @@ def test_the_tiny_configuration_keeps_every_mechanism():
     assert ss.supported((1, 8192, big.d_inner), big.d_state, big.scan_chunk)
 
 
-def test_loss_equals_the_reference(both):
-    _, _, _, (got, _), (want, _) = both
-    assert abs(float(got) - float(want)) / float(want) < TOL
-
-
-def test_every_position_s_logits_equal_the_reference(both):
-    cfg, params, ids, _, _ = both
-    x, _ = jax.jit(lambda p, i: decoder.forward(p, i, cfg))(params, ids)
-    got = T.rms_norm(x, params["lnf_scale"], cfg.norm_eps) @ params["tok_emb"].T
-    want = np.stack(reference.forward(params, ids, MODEL)[1])
-    np.testing.assert_allclose(got, want, rtol=1e-4,
-                               atol=EACH * np.abs(want).max())
-
-
-@pytest.mark.parametrize("path", LEAVES)
-def test_gradient_of_every_leaf_equals_the_reference(both, path):
-    _, params, _, (_, got), (_, want) = both
-    g, w = np.asarray(_leaf(got, path)), np.asarray(_leaf(want, path))
-    assert g.shape == _leaf(params, path).shape and np.abs(w).max() > 0
-    np.testing.assert_allclose(g, w, rtol=1e-4, atol=EACH * np.abs(w).max())
-
-
-def test_the_leaves_tested_are_all_there_are(both):
-    _, params, _, _, _ = both
-    flat = jax.tree_util.tree_leaves_with_path(params)
-    assert sorted("/".join(k.key for k in p) for p, _ in flat) \
-        == sorted(LEAVES)
+def _floats(both):
     floats = {"a_log", "d_skip", "b_dt", "dt_norm", "b_norm", "c_norm"}
-    for p, a in flat:
+    for p, a in jax.tree_util.tree_leaves_with_path(both.params):
         if p[-1].key in floats:
             assert a.dtype == np.float32, p
 
 
+CASE = H.Case(
+    "jamba", reference, MODEL, tuple(LEAVES), each=EACH,
+    # the norm scales, the skip and the rates off their seeds
+    off_one=("scale", "_norm", "d_skip", "a_log"), mechanism=_mechanism,
+    also={"leaves": _floats})
+globals().update(H.common(CASE))
+
+
 def test_logits_at_reads_the_step_s_own_forward(both):
     _, params, ids, _, _ = both
-    tr = _trainer()
-    tr.state = dict(tr.state, params=jax.tree.map(jnp.asarray, params))
+    tr = H.at_weights(both.tr, params)
     at = reference.witness_positions(S)
     got = np.asarray(tr.logits_at(ids, at))
     assert reference.logits_error(got, params, {"ids": ids}, MODEL) < EACH
@@ -354,7 +280,7 @@ def test_the_run_scan_equals_the_inlined_period(both):
     alone = T.init_transformer_params(jax.random.PRNGKey(3), inlined)
     np.testing.assert_array_equal(
         alone["params_layers"]["p1"]["w_in"],
-        _trainer().state["params"]["params_layers"]["r0"]["w_in"][:, 1])
+        both.tr.state["params"]["params_layers"]["r0"]["w_in"][:, 1])
     got = jax.jit(lambda p, i: decoder.forward(p, i, cfg)[0])(params, ids)
     want = jax.jit(lambda p, i: decoder.forward(p, i, inlined)[0])(
         dict(params, params_layers=by_position), ids)
@@ -402,13 +328,13 @@ def test_a_per_position_stack_may_carry_no_positions():
 def ran():
     """One trainer under remat, a step and a scan of six under a monitor
     session: the losses, the session's registry and the program's scopes."""
-    tr = _trainer(optimizer=optim.adamw(), remat=True)
-    batches = [{"ids": i} for i in _ids(n=2)]
+    tr = H.trainer(CASE, remat=True)
+    batches = [{"ids": i} for i in H.ids(CASE, n=2)]
     mon = monitor.enable()
     try:
         first = float(tr.step(batches[0], 1e-3))
         many = np.asarray(tr.run_steps(
-            stack_batches(tr.mesh, decoder.BATCH_SPECS, batches * 3), 1e-3))
+            H.staged(tr, batches * 3), 1e-3))
         names = devscope.scope_maps()["jamba.run_steps"]
         return first, many, mon.registry, names
     finally:

@@ -13,27 +13,17 @@ The tiny configuration computes in float32, so the tolerance is 1e-5 (the
 two differ by accumulation order only)."""
 
 import importlib
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark.reference import kimi_linear_48b_a3b as reference  # noqa: E402
-from paddle_tpu import monitor  # noqa: E402
-from paddle_tpu.models import kimi_linear, mistral4  # noqa: E402
-from paddle_tpu.monitor import devscope  # noqa: E402
-from paddle_tpu.parallel import (decoder, moe, optim,  # noqa: E402
-                                 transformer as T)
-from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
-from paddle_tpu.parallel.rules import leaf_paths  # noqa: E402
-from paddle_tpu.parallel.train import stack_batches  # noqa: E402
+import decoder_reference as H
+from benchmark.reference import kimi_linear_48b_a3b as reference
+from paddle_tpu import monitor
+from paddle_tpu.models import kimi_linear, mistral4
+from paddle_tpu.parallel import decoder, moe, transformer as T
 
 # the module: ``paddle_tpu.kernels`` exports a function of the same name
 fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
@@ -65,60 +55,7 @@ LEAVES = ["tok_emb", "lm_head", "lnf_scale"] \
     + ["params_layers/r2/" + n for n in KDA_NAMES + SPARSE]
 
 
-def _trainer(seed=3, optimizer=None, **cfg):
-    return kimi_linear.build_kimi_linear_trainer(
-        kimi_linear.kimi_linear_tiny_config(**cfg), MeshSpec(dp=1),
-        optimizer=optimizer or optim.adamw(), seed=seed,
-        devices=jax.devices()[:1])
-
-
-def _ids(seed=0, n=1):
-    rng = np.random.RandomState(seed)
-    return [rng.randint(0, 256, (B, S)).astype(np.int32) for _ in range(n)]
-
-
-def _seeded_params(tr):
-    """The trainer's seeded weights with the norm scales moved off 1, so
-    that a missing or misplaced scale shows, a router steep enough that the
-    weights are not all alike, and branch outputs at the fan-in scale again
-    (the seeded 27^-1/2 would hide a wrong branch behind the embedding)."""
-    rng = np.random.RandomState(11)
-
-    def moved(path, a):
-        name = jax.tree_util.keystr(path)
-        if "scale" in name or "_norm" in name:
-            return np.asarray(a) * rng.uniform(0.5, 1.5, a.shape).astype("f4")
-        if "router_bias" in name:
-            return np.asarray(a)
-        if name.endswith("['wo']") or "down" in name:
-            return np.asarray(a) * 27 ** 0.5
-        return np.asarray(a) * (3.0 if "router" in name else 1.0)
-
-    return jax.tree_util.tree_map_with_path(moved, tr.state["params"])
-
-
-def _leaf(tree, path):
-    for part in path.split("/"):
-        tree = tree[part]
-    return tree
-
-
-@pytest.fixture(scope="module")
-def both():
-    """Loss and gradients of program and reference on the same weights."""
-    tr = _trainer()
-    params = _seeded_params(tr)
-    ids = _ids()[0]
-    loss_fn = decoder.make_loss_fn(tr.cfg)
-    got = jax.jit(jax.value_and_grad(
-        lambda p: loss_fn(p, {"ids": jnp.asarray(ids)})[0]))(params)
-    want = jax.value_and_grad(
-        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
-            jax.tree.map(jnp.asarray, params))
-    return tr.cfg, params, ids, got, want
-
-
-def test_the_tiny_configuration_keeps_every_mechanism():
+def _mechanism():
     cfg = kimi_linear.kimi_linear_tiny_config()
     assert cfg.latent and cfg.per_position and cfg.run_scan
     assert cfg.prefix_kinds == (T.KDA,) and cfg.layer_kinds == (
@@ -167,32 +104,8 @@ def test_the_tiny_configuration_keeps_every_mechanism():
         kimi_linear.kimi_linear_tiny_config(kda_gate_rank=0)
 
 
-def test_loss_equals_the_reference(both):
-    _, _, _, (got, _), (want, _) = both
-    assert abs(float(got) - float(want)) / float(want) < TOL
-
-
-def test_every_position_s_logits_equal_the_reference(both):
-    cfg, params, ids, _, _ = both
-    x, _ = jax.jit(lambda p, i: decoder.forward(p, i, cfg))(params, ids)
-    got = T.rms_norm(x, params["lnf_scale"], cfg.norm_eps) @ params["lm_head"].T
-    _, want = reference.forward(params, ids, MODEL)
-    np.testing.assert_allclose(got, np.stack(want), rtol=1e-4, atol=TOL)
-
-
-@pytest.mark.parametrize("path", LEAVES)
-def test_gradient_of_every_leaf_equals_the_reference(both, path):
-    _, params, _, (_, got), (_, want) = both
-    g, w = np.asarray(_leaf(got, path)), np.asarray(_leaf(want, path))
-    assert g.shape == _leaf(params, path).shape and np.abs(w).max() > 0
-    np.testing.assert_allclose(g, w, rtol=1e-4, atol=TOL * np.abs(w).max())
-
-
-def test_the_leaves_tested_are_all_there_are(both):
-    _, params, _, (_, got), _ = both
-    paths, _, _ = leaf_paths(params)
-    assert set(paths) == set(LEAVES) | {"router_bias"}
-    assert not np.asarray(got["router_bias"]).any()     # no gradient reaches
+def _shapes(both):
+    params = both.params
     r0, r1 = params["params_layers"]["r0"], params["params_layers"]["r1"]
     assert r0["wq"].shape == (1, 2, 64, 32) and r0["conv_k"].shape == (
         1, 2, 4, 32)
@@ -207,27 +120,13 @@ def test_the_leaves_tested_are_all_there_are(both):
     assert params["router_bias"].shape == (4, 8)
 
 
-def test_sharding_specs_and_gradient_syncs_follow_the_tree():
-    for cfg in (kimi_linear.kimi_linear_tiny_config(),
-                kimi_linear.kimi_linear_tiny_config(run_scan=False)):
-        params = jax.eval_shape(
-            lambda: T.init_transformer_params(jax.random.PRNGKey(0), cfg))
-        for tree in (T.transformer_param_specs(cfg), T.grad_sync_axes(cfg)):
-            assert jax.tree.structure(
-                tree, is_leaf=lambda x: isinstance(x, (tuple, T.P))) == \
-                jax.tree.structure(params)
-    specs = T.transformer_param_specs(cfg)["params_layers"]
-    assert specs["p0"]["w_fb"] == specs["p0"]["o_norm"] == T.P()
-    assert specs["p2"]["wkv_b"] == T.P(None, None, None)
-
-
 def test_positions_of_the_period_without_run_scan_give_the_same_loss():
     """``run_scan`` stacks the runs (K, K), (latent), (K); without it each
     position is a tree of its own, seeded alike: the same numbers."""
-    ids = jnp.asarray(_ids(seed=4)[0])
+    ids = jnp.asarray(H.ids(CASE, seed=4)[0])
     losses = []
     for run_scan in (True, False):
-        tr = _trainer(run_scan=run_scan)
+        tr = H.trainer(CASE, run_scan=run_scan)
         losses.append(float(jax.jit(lambda p: decoder.make_loss_fn(tr.cfg)(
             p, {"ids": ids})[0])(tr.state["params"])))
     assert abs(losses[0] - losses[1]) < 1e-5 * losses[0]
@@ -429,20 +328,8 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_are_the_layer():
                   - (routed_want + shared_want)).max() > 0.1
 
 
-@pytest.fixture(scope="module")
-def witnessed():
-    """A trainer's own logits at the witness's positions, its weights moved
-    as ``both``'s, on ONE sequence (the cell's batch)."""
-    tr = _trainer()
-    params = _seeded_params(tr)
-    tr.state["params"] = jax.tree.map(jnp.asarray, params)
-    ids = _ids(seed=9)[0][:1]
-    program = np.asarray(tr.logits_at(ids, reference.witness_positions(S)))
-    return params, ids, program
-
-
 def test_the_witness_stands_after_the_chunk_edges(witnessed):
-    params, ids, program = witnessed
+    params, ids, program, _ = witnessed
     big = reference.witness_groups(16384)
     assert big["edge"].tolist() == [
         at + i for at in (64, 512, 4096, 16320) for i in range(8)] + list(
@@ -459,81 +346,58 @@ def test_the_witness_stands_after_the_chunk_edges(witnessed):
         == max(parts.values())
 
 
-@pytest.mark.parametrize("fault", reference.FAULTS[:-1])
-def test_the_witness_sees_every_fault(witnessed, fault):
-    """Each fault in the reference moves its logits away from the program's
-    by a thousand times what the two differ by when both are sound, at the
-    witness's own statistic."""
-    params, ids, program = witnessed
-    moved = reference.logits_error(program, params, {"ids": ids}, MODEL,
-                                   faults=(fault,))
-    assert moved > 1e3 * TOL
+def _counters(trained):
+    # one call a KDA layer body traced: the leading layer's, and ONE a
+    # run of the period (the runs (K, K) and (K)), all on the jnp form
+    calls = trained.value("monitor.kernels.kda_chunk_calls", fused=0)
+    assert calls > 0 and calls % 3 == 0
+    mean = trained.value("monitor.train.kda_decay_mean")
+    least = trained.value("monitor.train.kda_decay_min")
+    assert 0 < least < mean < 1
+    # the seeded decays: most channels outlive many chunks
+    assert mean > 0.5
+    assert trained.value("monitor.train.moe_load_max_over_mean") >= 1
+    assert trained.value("monitor.train.router_bias_abs_max") > 0
+    # a filter's three calls a KDA body, refused off whole lane blocks
+    assert trained.value("monitor.kernels.mamba_filter_calls", fused=0,
+                         halo="zeros") == calls * 3
 
 
-def test_bfloat16_throughout_moves_the_reference_s_loss(both):
-    _, params, ids, _, (want, _) = both
-    bad = reference.loss(params, {"ids": ids}, MODEL,
-                         faults=("bfloat16_throughout",))
-    assert abs(bad - float(want)) / float(want) > 2 * TOL
+def _specs(specs):
+    specs = specs["params_layers"]
+    assert specs["p0"]["w_fb"] == specs["p0"]["o_norm"] == T.P()
+    assert specs["p2"]["wkv_b"] == T.P(None, None, None)
 
 
-def test_the_reference_in_small_pieces_equals_itself_whole(both, monkeypatch):
-    _, params, ids, _, (want, _) = both
-    monkeypatch.setattr(reference, "QUERY_BLOCK", 16)
-    monkeypatch.setattr(reference, "HEAD_GROUP", 1)
-    monkeypatch.setattr(reference, "VOCAB_CHUNK", 100)
-    monkeypatch.setattr(reference, "EXPERT_GROUP", 1)
-    monkeypatch.setattr(reference, "DENSE_CHUNK", 20)
-    loss = reference.forward(jax.tree.map(jnp.asarray, params), ids, MODEL,
-                             keep_logits=False)[0]
-    assert abs(float(loss) - float(want)) / float(want) < 1e-6
+def _gain(name):
+    """A router steep enough that the weights are not all alike, and branch
+    outputs at the fan-in scale again (the seeded 27^-1/2 would hide a wrong
+    branch behind the embedding)."""
+    if "router_bias" in name:
+        return 1.0
+    if name.endswith("['wo']") or "down" in name:
+        return 27 ** 0.5
+    return 3.0 if "router" in name else 1.0
 
 
-def test_run_steps_over_two_batches_equals_two_steps():
-    batches = [{"ids": i} for i in _ids(seed=5, n=2)]
-    one, scan = _trainer(remat=True), _trainer(remat=True)
-    singly = [float(one.step(b, 1e-3)) for b in batches]
-    scanned = scan.run_steps(
-        stack_batches(scan.mesh, decoder.BATCH_SPECS, batches), 1e-3)
-    np.testing.assert_allclose(scanned, singly, rtol=1e-5)
-    assert singly[0] != singly[1]
-    for a, b in zip(jax.tree.leaves(one.state["params"]),
-                    jax.tree.leaves(scan.state["params"])):
-        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+CASE = H.Case(
+    "kimi_linear", reference, MODEL, tuple(LEAVES), aux=True, biased=True,
+    gain=_gain, mechanism=_mechanism,
+    # ``run_scan`` stacks the runs; without it each position is its own tree
+    spec_configs=({}, {"run_scan": False}), bfloat16=True,
+    pieces={"QUERY_BLOCK": 16, "HEAD_GROUP": 1, "VOCAB_CHUNK": 100,
+            "EXPERT_GROUP": 1, "DENSE_CHUNK": 20},
+    pieces_hold=("loss",),
+    # ``both``'s trainer and weights, on ONE sequence (the cell's batch)
+    # the counters' own trainer, without ``remat``: under it the runs (K, K)
+    # and (K) share ONE cached trace of the layer body and count once
+    witness=H.Witness(), steps=2, counters={},
+    also={"leaves": _shapes, "specs": _specs, "counters": _counters})
+globals().update(H.common(CASE))
 
 
-def test_counters_and_gauges_only_under_a_monitor_session(tmp_path):
-    tr = _trainer()
-    assert monitor.active() is None
-    batches = [{"ids": i} for i in _ids(seed=8, n=2)]
-    mon = monitor.enable(str(tmp_path), flight=False)
-    try:
-        reg = mon.registry
-        tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS, batches), 1e-3)
-        # one call a KDA layer body traced: the leading layer's, and ONE a
-        # run of the period (the runs (K, K) and (K)), all on the jnp form
-        calls = reg.counter("monitor.kernels.kda_chunk_calls", fused=0)
-        assert calls.value > 0 and calls.value % 3 == 0
-        mean = reg.gauge("monitor.train.kda_decay_mean").value
-        least = reg.gauge("monitor.train.kda_decay_min").value
-        assert 0 < least < mean < 1
-        # the seeded decays: most channels outlive many chunks
-        assert mean > 0.5
-        assert reg.gauge("monitor.train.moe_load_max_over_mean").value >= 1
-        assert reg.gauge("monitor.train.router_bias_abs_max").value > 0
-        # a filter's three calls a KDA body, refused off whole lane blocks
-        assert reg.counter("monitor.kernels.mamba_filter_calls", fused=0,
-                           halo="zeros").value == calls.value * 3
-    finally:
-        monitor.disable()
-
-
-def test_the_new_scopes_hold_their_instructions():
-    tr = _trainer(remat=True)
-    tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS,
-                               [{"ids": i} for i in _ids(n=2)]), 1e-3)
-    names = devscope.scope_maps()["kimi_linear.run_steps"]
-    got = {devscope.classify(op) for op in names.values()}
+def test_the_new_scopes_hold_their_instructions(trained):
+    got = trained.scopes()
     for scope in ("kda", "kda_chunk", "latent_attention", "shared_expert",
                   "moe", "router", "mlp", "layer_norm", "lm_head", "embed"):
         assert ("forward", scope) in got and ("backward", scope) in got, scope

@@ -11,26 +11,15 @@ top-2, vocab 256, S = 64.
 The tiny configuration computes in float32, so the tolerance is 1e-5 (the
 two differ by accumulation order only)."""
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark.reference import lfm2_8b_a1b as reference  # noqa: E402
-from paddle_tpu import monitor  # noqa: E402
-from paddle_tpu.models import lfm2  # noqa: E402
-from paddle_tpu.monitor import devscope  # noqa: E402
-from paddle_tpu.parallel import (decoder, moe, optim,  # noqa: E402
-                                 transformer as T)
-from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
-from paddle_tpu.parallel.train import stack_batches  # noqa: E402
+import decoder_reference as H
+from benchmark.reference import lfm2_8b_a1b as reference
+from paddle_tpu.models import lfm2
+from paddle_tpu.parallel import decoder, moe, optim, transformer as T
 
 B, S, TOL = 2, 64, 1e-5
 # the reference reads the published keys
@@ -51,56 +40,7 @@ LEAVES = (["tok_emb", "lnf_scale"]
              for n in CONV + EXPERTS])
 
 
-def _trainer(seed=3, optimizer=None, **cfg):
-    return lfm2.build_lfm2_trainer(
-        lfm2.lfm2_tiny_config(**cfg), MeshSpec(dp=1),
-        optimizer=optimizer or optim.adamw(), seed=seed,
-        devices=jax.devices()[:1])
-
-
-def _ids(seed=0, n=1):
-    rng = np.random.RandomState(seed)
-    return [rng.randint(0, 256, (B, S)).astype(np.int32) for _ in range(n)]
-
-
-def _seeded_params(tr):
-    """The trainer's seeded weights with the norm scales moved off 1, so
-    that a missing or misplaced scale shows, a router steep enough that the
-    scores are not all one half, and biases large enough to change who is
-    chosen at many tokens."""
-    rng = np.random.RandomState(11)
-
-    def moved(path, a):
-        name = jax.tree_util.keystr(path)
-        if "scale" in name or "_norm" in name:
-            return np.asarray(a) * rng.uniform(0.5, 1.5, a.shape).astype("f4")
-        return np.asarray(a) * (3.0 if "router" in name else 1.0)
-
-    return jax.tree_util.tree_map_with_path(moved, tr.state["params"])
-
-
-def _leaf(tree, path):
-    for part in path.split("/"):
-        tree = tree[part]
-    return tree
-
-
-@pytest.fixture(scope="module")
-def both():
-    """Loss and gradients of program and reference on the same weights."""
-    tr = _trainer()
-    params = _seeded_params(tr)
-    ids = _ids()[0]
-    loss_fn = decoder.make_loss_fn(tr.cfg)
-    got = jax.jit(jax.value_and_grad(
-        lambda p: loss_fn(p, {"ids": jnp.asarray(ids)}), has_aux=True))(params)
-    want = jax.value_and_grad(
-        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
-            jax.tree.map(jnp.asarray, params))
-    return tr.cfg, params, ids, got, want
-
-
-def test_the_tiny_configuration_keeps_every_mechanism():
+def _mechanism():
     cfg = lfm2.lfm2_tiny_config()
     assert cfg.prefix_kinds == (T.CONV,)
     assert cfg.layer_kinds == ((None, True), T.CONV, T.CONV, T.CONV)
@@ -124,36 +64,62 @@ def test_the_tiny_configuration_keeps_every_mechanism():
         lfm2.lfm2_8b_a1b_config(n_layers=24)     # no whole period past 18
 
 
-def test_loss_equals_the_reference(both):
-    _, _, _, ((got, _), _), (want, _) = both
-    assert abs(float(got) - float(want)) / float(want) < TOL
+def _bias(both):
+    assert both.params["router_bias"].shape == (4, 8)
 
 
-def test_every_position_s_logits_equal_the_reference(both):
-    cfg, params, ids, _, _ = both
-    x, _ = jax.jit(lambda p, i: decoder.forward(p, i, cfg))(params, ids)
-    got = T.rms_norm(x, params["lnf_scale"], cfg.norm_eps) @ params["tok_emb"].T
-    _, want = reference.forward(params, ids, MODEL)
-    np.testing.assert_allclose(got, np.stack(want), rtol=1e-4, atol=TOL)
+def _specs(specs):
+    assert specs["router_bias"] == T.P()
+    assert specs["prefix_layers"]["l0"]["conv_in"] == T.P()
 
 
-@pytest.mark.parametrize("path", LEAVES)
-def test_gradient_of_every_leaf_equals_the_reference(both, path):
-    _, params, _, (_, got), (_, want) = both
-    g, w = np.asarray(_leaf(got, path)), np.asarray(_leaf(want, path))
-    assert g.shape == _leaf(params, path).shape and np.abs(w).max() > 0
-    np.testing.assert_allclose(g, w, rtol=1e-4, atol=TOL * np.abs(w).max())
+def _bfloat16(both):
+    assert reference.witness_positions(8192)[[0, 1, -1]].tolist() == [
+        16, 48, 8176]
 
 
-def test_the_leaves_tested_are_all_there_are_but_the_bias(both):
-    _, params, _, (_, got), (_, want) = both
-    paths, _, _ = __import__(
-        "paddle_tpu.parallel.rules", fromlist=["leaf_paths"]).leaf_paths(params)
-    assert set(paths) == set(LEAVES) | {"router_bias"}
-    # no gradient reaches the bias: it decides who is chosen, nothing else
-    assert params["router_bias"].shape == (4, 8)
-    assert not np.asarray(got["router_bias"]).any()
-    assert not np.asarray(want["router_bias"]).any()
+def _steps(trained):
+    assert np.abs(trained.params["router_bias"]).max() > 0
+
+
+def _counters(trained):
+    cfg = trained.scan.cfg
+    # batches x tokens x top-2 x MoE layers
+    pairs = 3 * trained.batches[0]["ids"].size * cfg.experts_per_token \
+        * cfg.moe_layers
+    assert pairs == 3 * B * S * 2 * 4
+    got = trained.value("monitor.train.moe_rows_held")
+    assert 0 < got < pairs
+    np.testing.assert_allclose(
+        trained.value("monitor.train.moe_held_rows_share"), got / pairs)
+    bias = trained.value("monitor.train.router_bias_abs_max")
+    assert 0.1 < bias < 0.6                 # N(0, 0.1^2), 32 draws
+
+
+CASE = H.Case(
+    "lfm2", reference, MODEL, tuple(LEAVES), aux=True, biased=True,
+    # a router steep enough that the scores are not all one half, and biases
+    # large enough to change who is chosen at many tokens
+    gain=H.steep("router"),
+    mechanism=_mechanism,
+    leaves_test="test_the_leaves_tested_are_all_there_are_but_the_bias",
+    spec_configs=({},), bfloat16=True,
+    # 4 row blocks of 64; chunks of 100, 100, 56; an expert at a time; the
+    # dense layer's 96 columns as 40, 40, 16
+    pieces={"QUERY_BLOCK": 16, "VOCAB_CHUNK": 100, "EXPERT_GROUP": 1,
+            "DENSE_CHUNK": 40},
+    # a trainer that holds HALF the experts (4 of 8, the second half): with 2
+    # of 8 held, half the positions meet no held expert in any layer, a
+    # routing fault does not touch them and the witness's first quartile is
+    # theirs; with 4 held, two in a thousand are such (and at the cell's
+    # sizes, 8 layers of top-4 with 8 of 32 held, six in a hundred thousand)
+    witness=H.Witness(cfg={"experts_held": 4, "first_expert": 4},
+                      model={"num_experts": 4, "moe_first_expert_held": 4},
+                      rows=None, pieces={"DENSE_CHUNK": 32}),  # 3 chunks of 96
+    steps=3, counters=True,
+    also={"leaves": _bias, "specs": _specs, "bfloat16": _bfloat16,
+          "steps": _steps, "counters": _counters})
+globals().update(H.common(CASE))
 
 
 def test_every_share_seeds_the_same_biases():
@@ -173,83 +139,16 @@ def test_every_share_seeds_the_same_biases():
     assert len(np.unique(whole)) == 32          # every expert its own
 
 
-def test_sharding_specs_and_gradient_syncs_follow_the_tree():
-    cfg = lfm2.lfm2_tiny_config()
-    params = jax.eval_shape(
-        lambda: T.init_transformer_params(jax.random.PRNGKey(0), cfg))
-    for tree in (T.transformer_param_specs(cfg), T.grad_sync_axes(cfg)):
-        assert jax.tree.structure(
-            tree, is_leaf=lambda x: isinstance(x, (tuple, T.P))) == \
-            jax.tree.structure(params)
-    specs = T.transformer_param_specs(cfg)
-    assert specs["router_bias"] == T.P()
-    assert specs["prefix_layers"]["l0"]["conv_in"] == T.P()
-
-
-@pytest.fixture(scope="module")
-def witnessed():
-    """A trainer that holds HALF the experts (4 of 8, the second half), its
-    weights moved as ``both``'s, and its own logits at the witness's
-    positions: with 2 of 8 held, half the positions meet no held expert in
-    any layer, a routing fault does not touch them and the witness's first
-    quartile is theirs; with 4 held, two in a thousand are such (and at the
-    cell's sizes, 8 layers of top-4 with 8 of 32 held, six in a hundred
-    thousand)."""
-    tr = _trainer(experts_held=4, first_expert=4)
-    params = _seeded_params(tr)
-    tr.state["params"] = jax.tree.map(jnp.asarray, params)
-    ids = _ids(seed=9)[0]
-    program = np.asarray(tr.logits_at(ids, reference.witness_positions(S)))
-    assert program.shape == (B, S, 256)
-    return params, ids, program, dict(MODEL, num_experts=4,
-                                      moe_first_expert_held=4)
-
-
 def test_the_witness_holds_the_program_s_logits(witnessed):
     """What ``benchmark/drivers/train_scan_witnessed.py`` checks on the chip:
     ``StepTrainer``'s own forward at the witness's positions against the
     reference's logits, as one relative error."""
     params, ids, program, model = witnessed
+    assert program.shape == (B, S, 256)
     each = reference.position_errors(program, params, {"ids": ids}, model)
     assert each.shape == (B * S,) and each.max() < TOL
     assert reference.logits_error(program, params, {"ids": ids}, model) \
         == np.quantile(each, 0.25)
-
-
-@pytest.mark.parametrize("fault", reference.FAULTS[:-1])
-def test_the_witness_sees_every_fault(witnessed, fault, monkeypatch):
-    """Each fault in the reference moves its logits away from the program's
-    by a thousand times what the two differ by when both are sound, at the
-    witness's own statistic."""
-    params, ids, program, model = witnessed
-    monkeypatch.setattr(reference, "DENSE_CHUNK", 32)   # 3 chunks of 96
-    assert reference.logits_error(program, params, {"ids": ids}, model,
-                                  faults=(fault,)) > 1e3 * TOL
-
-
-def test_bfloat16_throughout_moves_the_reference_s_loss(both):
-    _, params, ids, _, (want, _) = both
-    bad = reference.loss(params, {"ids": ids}, MODEL,
-                         faults=("bfloat16_throughout",))
-    assert abs(bad - float(want)) / float(want) > 2 * TOL
-    assert reference.witness_positions(8192)[[0, 1, -1]].tolist() == [
-        16, 48, 8176]
-
-
-def test_the_reference_in_small_pieces_equals_itself_whole(both, monkeypatch):
-    _, params, ids, _, (want, want_grad) = both
-    params = jax.tree.map(jnp.asarray, params)
-    monkeypatch.setattr(reference, "QUERY_BLOCK", 16)       # 4 blocks of 64
-    monkeypatch.setattr(reference, "VOCAB_CHUNK", 100)      # 100, 100, 56
-    monkeypatch.setattr(reference, "EXPERT_GROUP", 1)
-    monkeypatch.setattr(reference, "DENSE_CHUNK", 40)       # 40, 40, 16
-    loss, grad = jax.value_and_grad(
-        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
-            params)
-    assert abs(float(loss) - float(want)) / float(want) < 1e-6
-    for g, w in zip(jax.tree.leaves(grad), jax.tree.leaves(want_grad)):
-        np.testing.assert_allclose(g, w, rtol=1e-4,
-                                   atol=1e-5 * max(np.abs(w).max(), 1e-30))
 
 
 def _layer_inputs():
@@ -313,8 +212,8 @@ def test_a_step_moves_the_bias_by_the_rule_and_nothing_else_does():
     """After one step ``b += u * sign(mean_load - load)`` from that step's
     own counts; Adam's update and a weight decay large enough to show have
     not touched it, and have moved the rest."""
-    tr = _trainer(optimizer=optim.adamw(weight_decay=0.5))
-    ids = _ids(seed=6)[0]
+    tr = H.trainer(CASE, optimizer=optim.adamw(weight_decay=0.5))
+    ids = H.ids(CASE, seed=6)[0]
     params0 = jax.tree.map(np.asarray, tr.state["params"])
     _, aux = jax.jit(lambda p, i: decoder.forward(p, i, tr.cfg))(
         tr.state["params"], ids)
@@ -328,7 +227,7 @@ def test_a_step_moves_the_bias_by_the_rule_and_nothing_else_does():
     assert (np.abs(after["router_bias"] - params0["router_bias"])
             <= 1.0001e-3).all()
     router = "params_layers/p1/router"
-    assert np.abs(_leaf(after, router) - _leaf(params0, router)).max() > 1e-3
+    assert np.abs(H.leaf(after, router) - H.leaf(params0, router)).max() > 1e-3
     # the balance rule itself
     np.testing.assert_allclose(
         moe.balance_bias(jnp.zeros(4), jnp.array([5, 1, 3, 3]), 0.5),
@@ -359,29 +258,14 @@ def test_the_convolution_is_causal_and_starts_from_zeros():
         rtol=1e-4, atol=1e-5)
 
 
-def test_run_steps_over_three_batches_equals_three_steps():
-    batches = [{"ids": i} for i in _ids(seed=5, n=3)]
-    one, scan = _trainer(remat=True), _trainer(remat=True)
-    singly = [float(one.step(b, 1e-3)) for b in batches]
-    scanned = scan.run_steps(
-        stack_batches(scan.mesh, decoder.BATCH_SPECS, batches), 1e-3)
-    np.testing.assert_allclose(scanned, singly, rtol=1e-5)
-    assert singly[0] != singly[1]
-    for a, b in zip(jax.tree.leaves(one.state["params"]),
-                    jax.tree.leaves(scan.state["params"])):
-        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
-    moved = np.asarray(scan.state["params"]["router_bias"])
-    assert np.abs(moved).max() > 0
-
-
 def test_two_periods_scanned_equal_the_reference():
     """9 layers are the dense layer and two periods: the scan's second turn
     runs the same four kinds on the second half of each position's leaves
     and the second four rows of the biases."""
-    tr = _trainer(n_layers=9)
+    tr = H.trainer(CASE, n_layers=9)
     assert tr.cfg.n_periods == 2 and tr.cfg.moe_layers == 8
-    params = _seeded_params(tr)
-    ids = _ids(seed=2)[0]
+    params = H.moved(CASE, tr.state["params"])
+    ids = H.ids(CASE, seed=2)[0]
     got, _ = jax.jit(decoder.make_loss_fn(tr.cfg))(
         params, {"ids": jnp.asarray(ids)})
     want = reference.loss(params, {"ids": ids},
@@ -389,36 +273,8 @@ def test_two_periods_scanned_equal_the_reference():
     assert abs(float(got) - want) / want < TOL
 
 
-def test_counters_and_gauges_only_under_a_monitor_session(tmp_path):
-    tr = _trainer()
-    assert monitor.active() is None
-    batches = [{"ids": i} for i in _ids(seed=8, n=2)]
-    mon = monitor.enable(str(tmp_path), flight=False)
-    try:
-        reg = mon.registry
-        held = reg.counter("monitor.train.moe_rows_held")
-        held_start = held.value
-        tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS, batches), 1e-3)
-        # batches x tokens x top-2 x MoE layers
-        pairs = 2 * batches[0]["ids"].size * tr.cfg.experts_per_token \
-            * tr.cfg.moe_layers
-        assert pairs == 2 * B * S * 2 * 4
-        got = held.value - held_start
-        assert 0 < got < pairs
-        np.testing.assert_allclose(
-            reg.gauge("monitor.train.moe_held_rows_share").value, got / pairs)
-        bias = reg.gauge("monitor.train.router_bias_abs_max").value
-        assert 0.1 < bias < 0.6                 # N(0, 0.1^2), 32 draws
-    finally:
-        monitor.disable()
-
-
-def test_the_short_convolution_s_instructions_are_under_their_scope():
-    tr = _trainer(remat=True)
-    tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS,
-                               [{"ids": i} for i in _ids(n=2)]), 1e-3)
-    names = devscope.scope_maps()["lfm2.run_steps"]
-    got = {devscope.classify(op) for op in names.values()}
+def test_the_short_convolution_s_instructions_are_under_their_scope(trained):
+    got = trained.scopes()
     for scope in ("short_conv", "moe", "router", "attention", "mlp",
                   "layer_norm", "lm_head", "embed"):
         assert ("forward", scope) in got and ("backward", scope) in got, scope

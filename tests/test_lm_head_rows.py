@@ -223,7 +223,7 @@ def test_the_partition_covers_the_vocabulary_once_and_in_order():
 def test_the_cells_vocabularies_are_cut_on_whole_kilorows(what, V_, rows):
     """37,984 / 4 = 8 x 1,187, a prime: the equal cut the head made until
     PR 32 left the TPU compiler one 8-row window to tile a chunk's gradient
-    with (``tests/test_flash_tpu_compile.py`` holds the compiled head to
+    with (``tests/test_chip_compile_steps.py`` holds the compiled head to
     it)."""
     chunks = T._vocab_chunks(jax.ShapeDtypeStruct((V_, 64), jnp.bfloat16))
     assert [r for _, r in chunks] == rows, what

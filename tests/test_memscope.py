@@ -3,7 +3,9 @@ owner-tagged live-buffer attribution, the headroom predictor / admission
 gate, the induced-OOM postmortem drill, and the trace_summary memory
 gates."""
 
+import gc
 import json
+import math
 import os
 import subprocess
 import sys
@@ -37,6 +39,34 @@ def _fresh():
     chaos.disarm()
     emb._HBM_BYTES_PER_CHIP = None
     emb._HBM_TABLE_FRACTION = 0.6
+
+
+@pytest.fixture(params=[0, 4 << 20], ids=["nothing", "4MiB"])
+def leftover(request):
+    """What an earlier file of this xdist worker left alive at module level
+    (``--dist loadfile`` decides which file precedes this one): nothing, or
+    a 4 MiB array.  On the CPU ``bytes_in_use`` is the sum of the WHOLE
+    process's ``jax.live_arrays()``, so a test that states a limit states it
+    over ``_held_before()``."""
+    import jax.numpy as jnp
+
+    kept = jnp.ones((request.param // 4,), jnp.float32) if request.param \
+        else None
+    yield request.param
+    del kept
+
+
+def _held_before():
+    """The most bytes a device holds of the arrays alive before the test's
+    own, which are registered with an owner so that attribution names
+    them."""
+    import jax
+
+    gc.collect()
+    earlier = jax.live_arrays()
+    memscope.register_owner("earlier_tests", lambda: earlier)
+    return max([math.ceil(owners.get("earlier_tests", 0)) for owners in
+                memscope.attribution()["device_owners"].values()] or [0])
 
 
 def _build_program(hidden=128):
@@ -177,17 +207,19 @@ def test_headroom_predictor_warns_before_dispatch(tmp_path):
     assert hr[0]["estimated"] is True     # CPU: framework-estimated in_use
 
 
-def test_refuse_mode_raises_instead_of_dispatching(tmp_path):
+def test_refuse_mode_raises_instead_of_dispatching(tmp_path, leftover):
     import jax.numpy as jnp
 
+    held = _held_before()
+    assert held >= leftover
     main, startup, loss = _build_program()
     monitor.enable(str(tmp_path))
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(startup)          # admit startup BEFORE the squeeze
     ballast = [jnp.ones((128, 128), jnp.float32) for _ in range(4)]
     memscope.register_owner("ballast", lambda: ballast)
-    memscope.configure(bytes_limit=sum(b.nbytes for b in ballast) + 64,
-                       refuse=True)
+    memscope.configure(
+        bytes_limit=held + sum(b.nbytes for b in ballast) + 64, refuse=True)
     feed = {"x": np.zeros((16, 8), "f4")}
     with pytest.raises(monitor.MemoryBudgetError):
         exe.run(main, feed=feed, fetch_list=[loss.name])
@@ -305,7 +337,7 @@ def test_train_from_dataset_oom_single_dump(tmp_path):
 
 # -- trace_summary memory gates --------------------------------------------
 
-def test_trace_summary_memory_gates(tmp_path):
+def test_trace_summary_memory_gates(tmp_path, leftover):
     """A monitored train_from_dataset run passes ``--check
     --max-unattributed-frac`` / ``--max-hbm-frac`` (the acceptance gate)
     and the summary carries the per-program ledger table + owner
@@ -314,7 +346,8 @@ def test_trace_summary_memory_gates(tmp_path):
 
     from paddle_tpu.dataset import DatasetFactory
 
-    memscope.configure(bytes_limit=256 * 2**20)   # arms hbm_frac on CPU
+    # arms hbm_frac on CPU
+    memscope.configure(bytes_limit=_held_before() + 256 * 2**20)
     files = []
     rng = np.random.RandomState(0)
     for fi in range(2):

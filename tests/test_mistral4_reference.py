@@ -11,27 +11,16 @@ holds 2, top-2, a shared expert of width 48, vocab 256.
 The tiny configuration computes in float32, so the tolerance is 1e-5 (the
 two differ by accumulation order only)."""
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark.reference import mistral_small_4_119b as reference  # noqa: E402
-from paddle_tpu import monitor  # noqa: E402
-from paddle_tpu.kernels.flash_attention import packed_grid  # noqa: E402
-from paddle_tpu.models import brumby, mistral4  # noqa: E402
-from paddle_tpu.monitor import devscope  # noqa: E402
-from paddle_tpu.parallel import (decoder, moe, optim,  # noqa: E402
-                                 transformer as T)
-from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
-from paddle_tpu.parallel.train import stack_batches  # noqa: E402
+import decoder_reference as H
+from benchmark.reference import mistral_small_4_119b as reference
+from paddle_tpu.kernels.flash_attention import packed_grid
+from paddle_tpu.models import brumby, mistral4
+from paddle_tpu.parallel import moe, transformer as T
 
 B, S, TOL = 2, 64, 1e-5
 ROPE = {"beta_fast": 4, "beta_slow": 0.5, "factor": 8,
@@ -56,55 +45,7 @@ LEAVES = ["tok_emb", "lm_head", "lnf_scale"] \
     + ["params_layers/" + n for n in NAMES]
 
 
-def _trainer(seed=3, optimizer=None, **cfg):
-    return mistral4.build_mistral4_trainer(
-        mistral4.mistral4_tiny_config(**cfg), MeshSpec(dp=1),
-        optimizer=optimizer or optim.adamw(), seed=seed,
-        devices=jax.devices()[:1])
-
-
-def _ids(seed=0, n=1):
-    rng = np.random.RandomState(seed)
-    return [rng.randint(0, 256, (B, S)).astype(np.int32) for _ in range(n)]
-
-
-def _seeded_params(tr):
-    """The trainer's seeded weights with the norm scales moved off 1, so
-    that a missing or misplaced scale shows, and a router steep enough that
-    the weights are not all one half."""
-    rng = np.random.RandomState(11)
-
-    def moved(path, a):
-        name = jax.tree_util.keystr(path)
-        if "scale" in name or "_norm" in name:
-            return np.asarray(a) * rng.uniform(0.5, 1.5, a.shape).astype("f4")
-        return np.asarray(a) * (3.0 if "router" in name else 1.0)
-
-    return jax.tree_util.tree_map_with_path(moved, tr.state["params"])
-
-
-def _leaf(tree, path):
-    for part in path.split("/"):
-        tree = tree[part]
-    return tree
-
-
-@pytest.fixture(scope="module")
-def both():
-    """Loss and gradients of program and reference on the same weights."""
-    tr = _trainer()
-    params = _seeded_params(tr)
-    ids = _ids()[0]
-    loss_fn = decoder.make_loss_fn(tr.cfg)
-    got = jax.jit(jax.value_and_grad(
-        lambda p: loss_fn(p, {"ids": jnp.asarray(ids)})))(params)
-    want = jax.value_and_grad(
-        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
-            jax.tree.map(jnp.asarray, params))
-    return tr.cfg, params, ids, got, want
-
-
-def test_the_tiny_configuration_keeps_every_mechanism():
+def _mechanism():
     cfg = mistral4.mistral4_tiny_config()
     assert cfg.latent and not cfg.per_position and cfg.layer_kinds == (
         (None, True),)
@@ -142,33 +83,8 @@ def test_the_tiny_configuration_keeps_every_mechanism():
         mistral4.mistral4_tiny_config(v_head_dim=64)
 
 
-def test_loss_equals_the_reference(both):
-    _, _, _, (got, _), (want, _) = both
-    assert abs(float(got) - float(want)) / float(want) < TOL
-
-
-def test_every_position_s_logits_equal_the_reference(both):
-    cfg, params, ids, _, _ = both
-    x, _ = jax.jit(lambda p, i: decoder.forward(p, i, cfg))(params, ids)
-    got = T.rms_norm(x, params["lnf_scale"], cfg.norm_eps) @ params["lm_head"].T
-    _, want = reference.forward(params, ids, MODEL)
-    np.testing.assert_allclose(got, np.stack(want), rtol=1e-4, atol=TOL)
-
-
-@pytest.mark.parametrize("path", LEAVES)
-def test_gradient_of_every_leaf_equals_the_reference(both, path):
-    _, params, _, (_, got), (_, want) = both
-    g, w = np.asarray(_leaf(got, path)), np.asarray(_leaf(want, path))
-    assert g.shape == _leaf(params, path).shape and np.abs(w).max() > 0
-    np.testing.assert_allclose(g, w, rtol=1e-4, atol=TOL * np.abs(w).max())
-
-
-def test_the_leaves_tested_are_all_there_are(both):
-    _, params, _, _, _ = both
-    paths, _, _ = __import__(
-        "paddle_tpu.parallel.rules", fromlist=["leaf_paths"]).leaf_paths(params)
-    assert set(paths) == set(LEAVES)
-    layers = params["params_layers"]
+def _shapes(both):
+    layers = both.params["params_layers"]
     assert layers["wq_a"].shape == (2, 64, 32)
     assert layers["wq_b"].shape == (2, 32, 4 * 128)
     assert layers["wkv_a"].shape == (2, 64, 16 + 32)
@@ -177,19 +93,6 @@ def test_the_leaves_tested_are_all_there_are(both):
     assert layers["ws_gate_up"].shape == (2, 64, 96)
     assert layers["we_gate_up"].shape == (2, 2, 64, 64)
     assert layers["router"].shape == (2, 64, 8)
-
-
-def test_sharding_specs_and_gradient_syncs_follow_the_tree():
-    cfg = mistral4.mistral4_tiny_config()
-    params = jax.eval_shape(
-        lambda: T.init_transformer_params(jax.random.PRNGKey(0), cfg))
-    for tree in (T.transformer_param_specs(cfg), T.grad_sync_axes(cfg)):
-        assert jax.tree.structure(
-            tree, is_leaf=lambda x: isinstance(x, (tuple, T.P))) == \
-            jax.tree.structure(params)
-    specs = T.transformer_param_specs(cfg)["params_layers"]
-    assert specs["wkv_b"] == specs["ws_down"] == T.P(None, None, None)
-    assert specs["kv_a_norm"] == T.P(None, None)
 
 
 def _layer0(seed=4):
@@ -435,21 +338,6 @@ def test_the_layer_adds_the_shared_expert_to_the_routed_result():
     np.testing.assert_allclose((out - bare)[0], want, rtol=1e-4, atol=1e-5)
 
 
-@pytest.fixture(scope="module")
-def witnessed():
-    """A trainer that holds HALF the experts (4 of 8, the second half), its
-    weights moved as ``both``'s, and its own logits at the witness's
-    positions (with 2 of 8 held, many positions meet no held expert in
-    either layer and a routing fault does not touch them)."""
-    tr = _trainer(experts_held=4, first_expert=4)
-    params = _seeded_params(tr)
-    tr.state["params"] = jax.tree.map(jnp.asarray, params)
-    ids = _ids(seed=9)[0][:1]       # one sequence: the cell's batch
-    program = np.asarray(tr.logits_at(ids, reference.witness_positions(S)))
-    return params, ids, program, dict(MODEL, n_routed_experts=4,
-                                      moe_first_expert_held=4)
-
-
 def test_the_witness_reads_both_sides_of_the_original_length(witnessed):
     """What ``benchmark/drivers/train_scan_witnessed.py`` checks on the chip:
     the trainer's own forward at the witness's positions against the
@@ -472,97 +360,62 @@ def test_the_witness_reads_both_sides_of_the_original_length(witnessed):
     assert parts["edge"] == np.quantile(each[:28], 0.75)
 
 
-@pytest.mark.parametrize("fault", reference.FAULTS[:-1])
-def test_the_witness_sees_every_fault(witnessed, fault):
-    """Each fault in the reference moves its logits away from the program's
-    by a thousand times what the two differ by when both are sound, at the
-    witness's own statistic (a bf16 router by a hundred times: it changes a
-    weight's last bits, and who is chosen at few tokens)."""
-    params, ids, program, model = witnessed
-    moved = reference.logits_error(program, params, {"ids": ids}, model,
-                                   faults=(fault,))
-    assert moved > (1e2 if fault == "bfloat16_router" else 1e3) * TOL
+def _counters(trained):
+    cfg, ids = trained.scan.cfg, trained.batches[0]["ids"]
+    # batches x tokens x top-2 x layers
+    pairs = 2 * ids.size * cfg.experts_per_token * cfg.moe_layers
+    assert pairs == 2 * B * S * 2 * 2
+    held = trained.value("monitor.train.moe_rows_held")
+    assert 0 < held < pairs
+    assert trained.value("monitor.train.moe_held_rows_share") == held / pairs
+    # what a layer's keys and values come from (the latent and the
+    # shared rotary key) beside what the flash kernels read (every
+    # head's key and value), bytes a token
+    itemsize = cfg.jdtype.itemsize
+    assert (cfg.kv_lora_rank + cfg.qk_rope_dim) * itemsize == (16 + 32) * 4
+    assert cfg.n_heads * (cfg.head_dim + cfg.v_head_dim) * itemsize == \
+        4 * 256 * 4
+    assert (T.yarn_blend_range(cfg)[1], cfg.qk_rope_dim // 2 - 1) == (3, 15)
+    assert max(S - cfg.rope_original_max, 0) == 48
+    # a layer's grid: the causal triangle's 10 blocks a (sequence, head)
+    assert packed_grid(
+        B, S, cfg.n_heads, cfg.head_dim,
+        *T._packed_flash_blocks(cfg, cfg.n_heads, S, cfg.kv_heads),
+        itemsize=itemsize, n_kv_heads=cfg.kv_heads,
+        causal=True) == (1, 80)
 
 
-def test_bfloat16_throughout_moves_the_reference_s_loss(both):
-    _, params, ids, _, (want, _) = both
-    bad = reference.loss(params, {"ids": ids}, MODEL,
-                         faults=("bfloat16_throughout",))
-    assert abs(bad - float(want)) / float(want) > 2 * TOL
+def _specs(specs):
+    specs = specs["params_layers"]
+    assert specs["wkv_b"] == specs["ws_down"] == T.P(None, None, None)
+    assert specs["kv_a_norm"] == T.P(None, None)
 
 
-def test_the_reference_in_small_pieces_equals_itself_whole(both, monkeypatch):
-    _, params, ids, _, (want, want_grad) = both
-    params = jax.tree.map(jnp.asarray, params)
-    monkeypatch.setattr(reference, "QUERY_BLOCK", 16)       # 4 blocks of 64
-    monkeypatch.setattr(reference, "HEAD_GROUP", 1)
-    monkeypatch.setattr(reference, "VOCAB_CHUNK", 100)      # 100, 100, 56
-    monkeypatch.setattr(reference, "EXPERT_GROUP", 1)
-    monkeypatch.setattr(reference, "DENSE_CHUNK", 20)       # 20, 20, 8
-    loss, grad = jax.value_and_grad(
-        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
-            params)
-    assert abs(float(loss) - float(want)) / float(want) < 1e-6
-    for g, w in zip(jax.tree.leaves(grad), jax.tree.leaves(want_grad)):
-        np.testing.assert_allclose(g, w, rtol=1e-4,
-                                   atol=1e-5 * max(np.abs(w).max(), 1e-30))
+CASE = H.Case(
+    "mistral4", reference, MODEL, tuple(LEAVES),
+    # a router steep enough that the weights are not all one half
+    gain=H.steep("router"),
+    mechanism=_mechanism, spec_configs=({},), bfloat16=True,
+    # 4 row blocks of 64; a head at a time; chunks of 100, 100, 56; an expert
+    # at a time; the shared expert's columns as 20, 20, 8
+    pieces={"QUERY_BLOCK": 16, "HEAD_GROUP": 1, "VOCAB_CHUNK": 100,
+            "EXPERT_GROUP": 1, "DENSE_CHUNK": 20},
+    # a trainer that holds HALF the experts (4 of 8, the second half): with 2
+    # of 8 held, many positions meet no held expert in either layer and a
+    # routing fault does not touch them; a bf16 router moves the witness by a
+    # hundred times the sound difference (it changes a weight's last bits,
+    # and who is chosen at few tokens)
+    witness=H.Witness(cfg={"experts_held": 4, "first_expert": 4},
+                      model={"n_routed_experts": 4,
+                             "moe_first_expert_held": 4},
+                      floors={"bfloat16_router": 1e2}),
+    steps=2, counters=True,
+    also={"leaves": _shapes, "specs": _specs, "counters": _counters})
+globals().update(H.common(CASE))
 
 
-def test_run_steps_over_two_batches_equals_two_steps():
-    batches = [{"ids": i} for i in _ids(seed=5, n=2)]
-    one, scan = _trainer(remat=True), _trainer(remat=True)
-    singly = [float(one.step(b, 1e-3)) for b in batches]
-    scanned = scan.run_steps(
-        stack_batches(scan.mesh, decoder.BATCH_SPECS, batches), 1e-3)
-    np.testing.assert_allclose(scanned, singly, rtol=1e-5)
-    assert singly[0] != singly[1]
-    for a, b in zip(jax.tree.leaves(one.state["params"]),
-                    jax.tree.leaves(scan.state["params"])):
-        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
-
-
-def test_counters_and_gauges_only_under_a_monitor_session(tmp_path):
-    tr = _trainer()
-    assert monitor.active() is None
-    batches = [{"ids": i} for i in _ids(seed=8, n=2)]
-    mon = monitor.enable(str(tmp_path), flight=False)
-    try:
-        reg = mon.registry
-        held = reg.counter("monitor.train.moe_rows_held")
-        held_start = held.value
-        tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS, batches), 1e-3)
-        cfg, ids = tr.cfg, batches[0]["ids"]
-        # batches x tokens x top-2 x layers
-        pairs = 2 * ids.size * cfg.experts_per_token * cfg.moe_layers
-        assert pairs == 2 * B * S * 2 * 2
-        assert 0 < held.value - held_start < pairs
-        assert reg.gauge("monitor.train.moe_held_rows_share").value == \
-            (held.value - held_start) / pairs
-        # what a layer's keys and values come from (the latent and the
-        # shared rotary key) beside what the flash kernels read (every
-        # head's key and value), bytes a token
-        itemsize = cfg.jdtype.itemsize
-        assert (cfg.kv_lora_rank + cfg.qk_rope_dim) * itemsize == (16 + 32) * 4
-        assert cfg.n_heads * (cfg.head_dim + cfg.v_head_dim) * itemsize == \
-            4 * 256 * 4
-        assert (T.yarn_blend_range(cfg)[1], cfg.qk_rope_dim // 2 - 1) == (3, 15)
-        assert max(S - cfg.rope_original_max, 0) == 48
-        # a layer's grid: the causal triangle's 10 blocks a (sequence, head)
-        assert packed_grid(
-            B, S, cfg.n_heads, cfg.head_dim,
-            *T._packed_flash_blocks(cfg, cfg.n_heads, S, cfg.kv_heads),
-            itemsize=itemsize, n_kv_heads=cfg.kv_heads,
-            causal=True) == (1, 80)
-    finally:
-        monitor.disable()
-
-
-def test_the_new_scopes_hold_their_instructions_and_attention_none():
-    tr = _trainer(remat=True)
-    tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS,
-                               [{"ids": i} for i in _ids(n=2)]), 1e-3)
-    names = devscope.scope_maps()["mistral4.run_steps"]
-    got = {devscope.classify(op) for op in names.values()}
+def test_the_new_scopes_hold_their_instructions_and_attention_none(trained):
+    got = trained.scopes()
     for scope in ("latent_attention", "shared_expert", "moe", "router",
                   "layer_norm", "lm_head", "embed"):
         assert ("forward", scope) in got and ("backward", scope) in got, scope

@@ -20,27 +20,19 @@ The tiny configuration computes in float32, so the tolerance is 1e-5 on the
 loss (the two differ by accumulation order only) and three times that on a
 single logit row or gradient element, against the largest of its leaf."""
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark.reference import nemotron3_nano_30b_a3b as reference  # noqa: E402
-from paddle_tpu import monitor  # noqa: E402
-from paddle_tpu.kernels import moe_rows, ssd_scan as ssd  # noqa: E402
-from paddle_tpu.kernels.flash_attention import packed_grid  # noqa: E402
-from paddle_tpu.models import nemotron_h  # noqa: E402
-from paddle_tpu.monitor import devscope  # noqa: E402
-from paddle_tpu.parallel import decoder, moe, optim, transformer as T  # noqa: E402
-from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
-from paddle_tpu.parallel.train import stack_batches  # noqa: E402
+import decoder_reference as H
+from benchmark.reference import nemotron3_nano_30b_a3b as reference
+from paddle_tpu import monitor
+from paddle_tpu.kernels import moe_rows, ssd_scan as ssd
+from paddle_tpu.kernels.flash_attention import packed_grid
+from paddle_tpu.models import nemotron_h
+from paddle_tpu.monitor import devscope
+from paddle_tpu.parallel import moe, transformer as T
 
 B, S, TOL = 2, 64, 1e-5
 EACH = 3 * TOL         # one logit row, one gradient element
@@ -63,53 +55,7 @@ LEAVES = ["tok_emb", "lm_head", "lnf_scale"] \
        for n in ("ln1_scale",) + reference.ATTENTION_LEAVES]
 
 
-def _trainer(seed=3, **cfg):
-    return nemotron_h.build_nemotron_h_trainer(
-        nemotron_h.nemotron_h_tiny_config(**cfg), MeshSpec(dp=1),
-        optimizer=optim.adamw(), seed=seed, devices=jax.devices()[:1])
-
-
-def _ids(seed=0):
-    return np.random.RandomState(seed).randint(0, 256, (B, S)).astype(
-        np.int32)
-
-
-def _seeded_params(tr):
-    """The trainer's seeded weights with the norm scales, the skip and the
-    rates moved off their seeds, so that a missing or misplaced one shows."""
-    rng = np.random.RandomState(11)
-
-    def moved(path, a):
-        name = jax.tree_util.keystr(path)
-        if any(w in name for w in ("scale", "_norm", "d_skip", "a_log")):
-            return np.asarray(a) * rng.uniform(0.5, 1.5, a.shape).astype("f4")
-        return np.asarray(a)
-
-    return jax.tree_util.tree_map_with_path(moved, tr.state["params"])
-
-
-def _leaf(tree, path):
-    for part in path.split("/"):
-        tree = tree[part]
-    return tree
-
-
-@pytest.fixture(scope="module")
-def both():
-    """Loss and gradients of program and reference on the same weights."""
-    tr = _trainer()
-    params = _seeded_params(tr)
-    ids = _ids()
-    loss_fn = decoder.make_loss_fn(tr.cfg)
-    got = jax.jit(jax.value_and_grad(
-        lambda p: loss_fn(p, {"ids": jnp.asarray(ids)})[0]))(params)
-    want = jax.value_and_grad(
-        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
-            jax.tree.map(jnp.asarray, params))
-    return tr, params, ids, got, want
-
-
-def test_the_tiny_configuration_keeps_every_mechanism():
+def _mechanism():
     cfg = nemotron_h.nemotron_h_tiny_config()
     attention = (None, False)
     assert cfg.layer_kinds == (T.FFN, T.MAMBA2, attention, T.FFN, T.MAMBA2)
@@ -144,16 +90,20 @@ def test_the_tiny_configuration_keeps_every_mechanism():
     assert ssd.supported((2, 8192, 6144), 64, 8, 128, 128)
 
 
-def test_loss_equals_the_reference(both):
-    _, _, _, (got, _), (want, _) = both
-    assert abs(float(got) - float(want)) / float(want) < TOL
+CASE = H.Case(
+    "nemotron_h", reference, MODEL, tuple(LEAVES), each=EACH, aux=True,
+    # the norm scales, the skip and the rates off their seeds
+    off_one=("scale", "_norm", "d_skip", "a_log"), mechanism=_mechanism,
+    logits=False, leaves_test=None, grad_rtol=1e-3,
+    grad_test="test_every_leaf_s_gradient_equals_the_reference")
+globals().update(H.common(CASE))
 
 
 def test_logits_at_the_witness_positions_equal_the_reference(both):
-    tr, params, ids, _, _ = both
+    _, params, ids, _, _ = both
     at = reference.witness_positions(S)
     assert len(at) and set(range(16, 20)) <= set(at.tolist())
-    tr.state["params"] = jax.tree.map(jnp.asarray, params)
+    tr = H.at_weights(both.tr, params)
     got = np.asarray(tr.logits_at(ids, at))
     want = reference.logits(params, {"ids": ids}, MODEL)
     np.testing.assert_allclose(got, want, rtol=1e-4,
@@ -161,22 +111,12 @@ def test_logits_at_the_witness_positions_equal_the_reference(both):
     assert reference.logits_error(got, params, {"ids": ids}, MODEL) < 1e-4
 
 
-@pytest.mark.parametrize("path", LEAVES)
-def test_every_leaf_s_gradient_equals_the_reference(both, path):
-    _, _, _, (_, got), (_, want) = both
-    g, w = np.asarray(_leaf(got, path)), np.asarray(_leaf(want, path))
-    assert np.abs(w).max() > 0, path
-    np.testing.assert_allclose(g, w, rtol=1e-3, atol=EACH * np.abs(w).max())
-
-
 def test_the_selection_bias_takes_no_gradient_and_a_step_moves_it(both):
-    tr, params, ids, (_, got), _ = both
+    cfg, params, _, (_, got), _ = both
     assert not np.asarray(got["router_bias"]).any()
-    loss_fn = decoder.make_loss_fn(tr.cfg)
-    _, stepped = jax.jit(loss_fn)(params, {"ids": jnp.asarray(ids)})
-    moved = np.asarray(stepped["router_bias"]) - params["router_bias"]
+    moved = np.asarray(both.stepped["router_bias"]) - params["router_bias"]
     assert moved.shape == (2, 8) and np.allclose(
-        np.abs(moved)[moved != 0], tr.cfg.router_bias_rate)
+        np.abs(moved)[moved != 0], cfg.router_bias_rate)
 
 
 # the ninth, ``bfloat16_throughout``, is a precision: read on the chip at the
@@ -339,9 +279,9 @@ def test_the_grouped_matmul_s_backward_at_a_width_off_the_lane_tile(width):
 
 
 def test_the_trainer_steps_under_remat_and_the_gauges_of_a_call():
-    tr = _trainer(remat=True)
-    batches = stack_batches(tr.mesh, decoder.BATCH_SPECS,
-                            [{"ids": _ids(seed)} for seed in (0, 1)] * 3)
+    tr = H.trainer(CASE, remat=True)
+    batches = H.staged(
+        tr, [{"ids": H.ids(CASE, seed)[0]} for seed in (0, 1)] * 3)
     mon = monitor.enable()
     try:
         losses = np.asarray(tr.run_steps(batches, 1e-3))

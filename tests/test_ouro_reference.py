@@ -21,25 +21,17 @@ here; bf16 at the benchmark's sizes, one rounding a pass more than a plain
 stack has, which only a chip run sees), the reference by ``jax.grad``
 through its Python loop."""
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark.reference import ouro_2_6b as reference  # noqa: E402
-from paddle_tpu import monitor  # noqa: E402
-from paddle_tpu.models import bert, brumby, ouro  # noqa: E402
-from paddle_tpu.monitor import devscope  # noqa: E402
-from paddle_tpu.parallel import decoder, optim, transformer as T  # noqa: E402
-from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
-from paddle_tpu.parallel.train import stack_batches  # noqa: E402
+import decoder_reference as H
+from benchmark.reference import ouro_2_6b as reference
+from paddle_tpu import monitor
+from paddle_tpu.models import bert, brumby, ouro
+from paddle_tpu.monitor import devscope
+from paddle_tpu.parallel import decoder, transformer as T
 
 B, S, TOL = 2, 32, 1e-5
 EACH = 3 * TOL         # one logit row, one gradient element
@@ -59,53 +51,7 @@ CAUGHT_BY_LOSS = ("last_exit_takes_lambda", "entropy_term_dropped",
                   "uniform_exit_weights", "one_pass")
 
 
-def _trainer(seed=3, **cfg):
-    return ouro.build_ouro_trainer(
-        ouro.ouro_tiny_config(**cfg), MeshSpec(dp=1),
-        optimizer=optim.adamw(), seed=seed, devices=jax.devices()[:1])
-
-
-def _ids(seed=0):
-    return np.random.RandomState(seed).randint(0, 256, (B, S)).astype(
-        np.int32)
-
-
-def _seeded_params(tr):
-    """The trainer's seeded weights with the norm scales moved off one, so
-    that a missing or misplaced one shows."""
-    rng = np.random.RandomState(11)
-
-    def moved(path, a):
-        if "scale" in jax.tree_util.keystr(path):
-            return np.asarray(a) * rng.uniform(0.5, 1.5, a.shape).astype("f4")
-        return np.asarray(a)
-
-    return jax.tree_util.tree_map_with_path(moved, tr.state["params"])
-
-
-def _leaf(tree, path):
-    for part in path.split("/"):
-        tree = tree[part]
-    return tree
-
-
-@pytest.fixture(scope="module")
-def both():
-    """Loss and gradients of program and reference on the same weights, and
-    the reference's view of the exits."""
-    tr = _trainer()
-    params = _seeded_params(tr)
-    ids = _ids()
-    loss_fn = decoder.make_loss_fn(tr.cfg)
-    got = jax.jit(jax.value_and_grad(
-        lambda p: loss_fn(p, {"ids": jnp.asarray(ids)})))(params)
-    (want, exits), grads = jax.value_and_grad(
-        lambda p: reference.forward(p, ids, MODEL)[::2], has_aux=True)(
-            jax.tree.map(jnp.asarray, params))
-    return tr, params, ids, got, (want, exits, grads)
-
-
-def test_the_tiny_configuration_keeps_every_mechanism():
+def _mechanism():
     cfg = ouro.ouro_tiny_config()
     assert cfg.loop_passes == PASSES and cfg.exit_entropy_coef == 0.1
     assert cfg.post_norm and cfg.dense_stack and not cfg.per_position \
@@ -127,37 +73,27 @@ def test_the_tiny_configuration_keeps_every_mechanism():
     assert count(ouro.ouro_2_6b_config(n_layers=12)) == 817_991_681
 
 
-def test_loss_equals_the_reference(both):
-    _, _, _, (got, _), (want, _, _) = both
-    assert abs(float(got) - float(want)) / float(want) < TOL
-
-
-@pytest.mark.parametrize("path", LEAVES)
-def test_every_leaf_s_gradient_equals_the_reference(both, path):
-    """The layers' leaves among them: each one's gradient is the sum over
-    the three passes that read it."""
-    _, _, _, (_, got), (_, _, want) = both
-    g, w = np.asarray(_leaf(got, path)), np.asarray(_leaf(want, path))
-    assert np.abs(w).max() > 0, path
-    np.testing.assert_allclose(g, w, rtol=1e-3, atol=EACH * np.abs(w).max())
-
-
-def test_the_leaves_tested_are_all_there_are(both):
-    flat = jax.tree_util.tree_leaves_with_path(both[1])
-    assert sorted(LEAVES) == sorted(
-        "/".join(k.key for k in path) for path, _ in flat)
+CASE = H.Case(
+    "ouro", reference, MODEL, tuple(LEAVES), S=S, each=EACH,
+    off_one=("scale",), mechanism=_mechanism, logits=False, grad_rtol=1e-3,
+    # the reference's view of the exits beside its loss
+    forward=lambda p, ids: reference.forward(p, ids, MODEL)[::2],
+    # the layers' leaves among them: each one's gradient is the sum over the
+    # three passes that read it
+    grad_test="test_every_leaf_s_gradient_equals_the_reference")
+globals().update(H.common(CASE))
 
 
 def test_the_exit_distribution_sums_to_one_and_logits_at_weights_by_it(both):
-    tr, params, ids, _, (_, exits, _) = both
-    p = np.asarray(exits["p"])                              # [B, T, S]
+    cfg, params, ids, _, _ = both
+    p = np.asarray(both.seen["p"])                              # [B, T, S]
     assert p.shape == (B, PASSES, S)
     np.testing.assert_allclose(p.sum(axis=1), 1.0, atol=1e-6)
     # every exit weighs in, and no two alike
     means = p.mean(axis=(0, 2))
     assert means.min() > 0.05 and len(set(np.round(means, 3))) == PASSES
     # the program's own distribution, from its gates
-    gates = jax.jit(lambda q: decoder.forward(q, jnp.asarray(ids), tr.cfg)[1])(
+    gates = jax.jit(lambda q: decoder.forward(q, jnp.asarray(ids), cfg)[1])(
         params)
     mine = np.exp(np.asarray(T.exit_log_probs(gates)))      # [T, B, S]
     np.testing.assert_allclose(mine.swapaxes(0, 1), p, atol=EACH)
@@ -166,8 +102,7 @@ def test_the_exit_distribution_sums_to_one_and_logits_at_weights_by_it(both):
     # the witness's unit: this reference in bfloat16 against itself
     unit = reference.precision_unit(params, {"ids": ids}, MODEL)
     assert unit.shape == (B * len(at),) and 1e-3 < np.median(unit) < 0.1
-    tr.state["params"] = jax.tree.map(jnp.asarray, params)
-    got = np.asarray(tr.logits_at(ids, at))
+    got = np.asarray(H.at_weights(both.tr, params).logits_at(ids, at))
     want = reference.logits(params, {"ids": ids}, MODEL)
     assert got.shape == want.shape == (B, len(at), 256)
     np.testing.assert_allclose(got, want, rtol=1e-4,
@@ -192,7 +127,7 @@ def test_the_precision_below_in_the_program_s_place_is_not_correct(both):
 @pytest.mark.parametrize("fault", reference.FAULTS)
 def test_every_fault_of_the_reference_moves_a_reading_past_its_limit(
         both, fault):
-    _, params, ids, _, (want, _, _) = both
+    _, params, ids, _, (want, _) = both
     batch = {"ids": ids}
     sound = reference.logits(params, batch, MODEL)
     moved = abs(reference.loss(params, batch, MODEL, faults=(fault,))
@@ -217,7 +152,7 @@ def test_with_one_pass_it_is_today_s_decoder():
     mine = {k: v for k, v in mine.items() if k in theirs}
     assert jax.tree.all(jax.tree.map(
         lambda a, b: bool((a == b).all()), mine, theirs))
-    ids = jnp.asarray(_ids())
+    ids = jnp.asarray(H.ids(CASE)[0])
 
     def loss(params, batch):
         """What ``make_loss_fn`` was before the passes."""
@@ -252,7 +187,7 @@ def test_the_one_tree_gated_ffn_is_the_per_position_stacks():
     assert tree["w_gate_up"].shape == (2, 64, 192) == theirs["w_gate_up"].shape
     assert {"w1", "w2"} <= set(T._init_params(
         jax.random.PRNGKey(5), bert.bert_tiny_config())["params_layers"])
-    x = T.embed(params, jnp.asarray(_ids()), cfg)
+    x = T.embed(params, jnp.asarray(H.ids(CASE)[0]), cfg)
     got = T.run_layers(tree, x, cfg)
     want = x
     for i in range(cfg.n_layers):
@@ -272,9 +207,9 @@ def test_the_one_tree_gated_ffn_is_the_per_position_stacks():
 def ran():
     """One trainer under remat, a scan of six steps under a monitor session:
     the losses, the session's registry and the program's scopes."""
-    tr = _trainer(remat=True)
-    batches = stack_batches(tr.mesh, decoder.BATCH_SPECS,
-                            [{"ids": _ids(seed)} for seed in (0, 1)] * 3)
+    tr = H.trainer(CASE, remat=True)
+    batches = H.staged(
+        tr, [{"ids": H.ids(CASE, seed)[0]} for seed in (0, 1)] * 3)
     mon = monitor.enable()
     try:
         losses = np.asarray(tr.run_steps(batches, 1e-3))

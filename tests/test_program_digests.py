@@ -22,7 +22,7 @@ if ROOT not in sys.path:
 from paddle_tpu.models import (bert, brumby, dots3, jamba,  # noqa: E402
                                keye_vl2, kimi_linear,
                                lfm2, mistral4, nemotron_h, olmoe, ouro,
-                               resnet, smallthinker, trinity)
+                               resnet, smallthinker, solar_open2, trinity)
 from paddle_tpu.parallel import decoder  # noqa: E402
 from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
 from paddle_tpu.parallel.train import stack_batches  # noqa: E402
@@ -64,7 +64,7 @@ from paddle_tpu.parallel.train import stack_batches  # noqa: E402
 # ``wkv_b``'s own columns (``transformer._latent_columns``); the twenty others
 # stand (the six rotary decoders' ``qk_rope`` calls lower to the text, and on
 # the chip to the Mosaic modules, they had:
-# ``tests/test_flash_tpu_compile.py::QK_ROPE_MOSAIC``).  PR 58 re-took NONE
+# ``tests/test_chip_compile_rows.py::QK_ROPE_MOSAIC``).  PR 58 re-took NONE
 # of the twenty-two: the latent form's new branches (no positions, no query
 # latent, a value width of its own), the layer kind KDA and the flash
 # kernels' value-width mode left every older program's text as it was,
@@ -101,6 +101,10 @@ from paddle_tpu.parallel.train import stack_batches  # noqa: E402
 # stand: an ungrouped call is one head-block a step and traces the
 # operations it did (Mistral's, Ouro's, Kimi-Linear's and dots3's several-
 # block sweeps among them), and Keye's sweeps only import ``heads_a_step``.
+# Solar-Open2's two joined in PR 69 (PR 67 brought the trainer and no
+# digest), taken on that PR's parent (2e410fb) before any other edit, so that
+# all fifteen trainers are held; PR 69 re-took NONE of the twenty-eight
+# (``make_train_step`` lost an argument every caller left at ``None``).
 PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "bert.run_steps": "00de5403506fdc87",
             "olmoe.step": "231114fcd62341f2",
@@ -128,7 +132,9 @@ PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "keye_vl2.step": "4fdc203023887275",
             "keye_vl2.run_steps": "b3dfb8265faa6541",
             "dots3.step": "d7d1e352464fcf9e",
-            "dots3.run_steps": "73b06e01bdc7bd2d"}
+            "dots3.run_steps": "73b06e01bdc7bd2d",
+            "solar_open2.step": "d31a2a5637029741",
+            "solar_open2.run_steps": "185e1103978dbdda"}
 OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
          "olmoe": (olmoe.build_olmoe_trainer, olmoe.olmoe_tiny_config, 32),
          "smallthinker": (smallthinker.build_smallthinker_trainer,
@@ -150,7 +156,9 @@ OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
                          kimi_linear.kimi_linear_tiny_config, 64),
          "keye_vl2": (keye_vl2.build_keye_vl2_trainer,
                       keye_vl2.keye_vl2_tiny_config, 64),
-         "dots3": (dots3.build_dots3_trainer, dots3.dots3_tiny_config, 64)}
+         "dots3": (dots3.build_dots3_trainer, dots3.dots3_tiny_config, 64),
+         "solar_open2": (solar_open2.build_solar_open2_trainer,
+                         solar_open2.solar_open2_tiny_config, 64)}
 
 
 def digests(names):
@@ -181,26 +189,37 @@ def digests(names):
     return out
 
 
-# ONE worker takes this file, so ``fresh``'s process runs once: the tier-1
+# ONE worker takes this file, so ``fresh``'s processes run once: the tier-1
 # command's ``--dist loadfile`` keeps a file together, and under
 # ``--dist loadgroup`` this mark does
 pytestmark = pytest.mark.xdist_group("program_digests")
+PROCESSES = 3       # the trainers' digests are taken in as many at a time
 
 
 @pytest.fixture(scope="module")
 def fresh():
-    """Every program's digest, taken in a process of its OWN.  A lowered
-    text names its private functions in the order the module met them, and
-    two call sites share one only where the process's trace cache hands both
-    the same jaxpr: in a worker that has traced other files' programs first,
-    a MoE step lowered with one more ``_where`` and every later symbol
-    renumbered (PR 61 met it when two new test files moved this file to
-    another xdist worker; the programs were the parent's).  What a PR did to
-    a program is what a fresh process lowers."""
-    run = subprocess.run([sys.executable, os.path.abspath(__file__)],
-                         capture_output=True, text=True)
-    assert run.returncode == 0, run.stderr[-3000:]
-    return json.loads(run.stdout.splitlines()[-1])
+    """Every program's digest, taken in a process that has traced nothing
+    else (``PROCESSES`` of them side by side, each a share of the trainers:
+    the suite's six workers leave cores idle, and one process took 160 to
+    250 s of this file's worker).  A lowered text names its private
+    functions in the order the module met them, and two call sites share
+    one only where the process's trace cache hands both the same jaxpr: in a
+    worker that has traced other files' programs first, a MoE step lowered
+    with one more ``_where`` and every later symbol renumbered (PR 61 met it
+    when two new test files moved this file to another xdist worker; the
+    programs were the parent's).  What a PR did to a program is what a fresh
+    process lowers."""
+    names = list(OLDER)
+    runs = [subprocess.Popen(
+        [sys.executable, os.path.abspath(__file__)] + names[i::PROCESSES],
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+        for i in range(PROCESSES)]
+    out = {}
+    for run in runs:
+        stdout, stderr = run.communicate()
+        assert run.returncode == 0, stderr[-3000:]
+        out.update(json.loads(stdout.splitlines()[-1]))
+    return out
 
 
 @pytest.mark.parametrize("name", list(OLDER))
@@ -215,4 +234,4 @@ def test_the_older_transformers_programs_lower_to_the_parent_s_text(fresh,
 
 
 if __name__ == "__main__":
-    print(json.dumps(digests(list(OLDER))))
+    print(json.dumps(digests(sys.argv[1:] or list(OLDER))))

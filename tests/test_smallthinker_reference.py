@@ -11,28 +11,19 @@ The tiny configuration computes in float32, so the tolerance is 1e-5 (the
 two differ by accumulation order only): computing in bfloat16 moves the
 loss by more and fails it, as ``test_a_bfloat16_shortcut_...`` shows."""
 
-import os
 import re
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark.reference import smallthinker_21b_a3b as reference  # noqa: E402
-from paddle_tpu import monitor  # noqa: E402
-from paddle_tpu.kernels.flash_attention import kv_blocks  # noqa: E402
-from paddle_tpu.models import bert, olmoe, smallthinker  # noqa: E402
-from paddle_tpu.monitor import devscope  # noqa: E402
-from paddle_tpu.parallel import (decoder, moe, optim,  # noqa: E402
-                                 transformer as T)
-from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
-from paddle_tpu.parallel.train import stack_batches  # noqa: E402
+import decoder_reference as H
+from benchmark.reference import smallthinker_21b_a3b as reference
+from paddle_tpu.kernels.flash_attention import kv_blocks
+from paddle_tpu.models import bert, olmoe, smallthinker
+from paddle_tpu.monitor import devscope
+from paddle_tpu.parallel import decoder, moe, transformer as T
 
 B, S, TOL = 2, 64, 1e-5
 # the reference reads the published keys
@@ -46,52 +37,7 @@ LEAVES = ("tok_emb", "lm_head", "lnf_scale", "ln1_scale", "ln2_scale", "wq",
           "wk", "wv", "wo", "router", "we_gate_up", "we_down")
 
 
-def _trainer(seed=3, **cfg):
-    return smallthinker.build_smallthinker_trainer(
-        smallthinker.smallthinker_tiny_config(**cfg), MeshSpec(dp=1),
-        optimizer=optim.adamw(), seed=seed, devices=jax.devices()[:1])
-
-
-def _ids(seed=0, n=1):
-    rng = np.random.RandomState(seed)
-    return [rng.randint(0, 256, (B, S)).astype(np.int32) for _ in range(n)]
-
-
-def _seeded_params(tr):
-    """The trainer's seeded weights with the norm scales moved off 1, so
-    that a missing or misplaced scale shows, and a router steep enough that
-    the top-2 weights are not all one half."""
-    rng = np.random.RandomState(11)
-
-    def moved(path, a):
-        name = jax.tree_util.keystr(path)
-        if "scale" in name:
-            return np.asarray(a) * rng.uniform(0.5, 1.5, a.shape).astype("f4")
-        return np.asarray(a) * (3.0 if "router" in name else 1.0)
-
-    return jax.tree_util.tree_map_with_path(moved, tr.state["params"])
-
-
-@pytest.fixture(scope="module")
-def both():
-    """Loss and gradients of program and reference on the same weights."""
-    tr = _trainer()
-    params = _seeded_params(tr)
-    ids = _ids()[0]
-    loss_fn = decoder.make_loss_fn(tr.cfg)
-    got = jax.jit(jax.value_and_grad(
-        lambda p: loss_fn(p, {"ids": jnp.asarray(ids)})))(params)
-    want = jax.value_and_grad(
-        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
-            jax.tree.map(jnp.asarray, params))
-    return tr.cfg, params, ids, got, want
-
-
-def _leaf(tree, name):
-    return tree[name] if name in tree else tree["params_layers"][name]
-
-
-def test_the_tiny_configuration_keeps_every_mechanism():
+def _mechanism():
     cfg = smallthinker.smallthinker_tiny_config()
     assert cfg.n_heads // cfg.kv_heads == 3
     assert cfg.n_heads * cfg.head_dim != cfg.hidden
@@ -105,34 +51,6 @@ def test_the_tiny_configuration_keeps_every_mechanism():
             big.head_dim, big.ffn_hidden, big.n_experts,
             big.experts_per_token, big.experts_here, big.vocab_size) == (
         52, 2560, 28, 4, 128, 768, 64, 6, 64, 151936)
-
-
-def test_loss_equals_the_reference(both):
-    _, _, _, (got, _), (want, _) = both
-    assert abs(float(got) - float(want)) / float(want) < TOL
-
-
-def test_every_position_s_logits_equal_the_reference(both):
-    cfg, params, ids, _, _ = both
-    x, _ = jax.jit(lambda p, i: decoder.forward(p, i, cfg))(params, ids)
-    got = T.rms_norm(x, params["lnf_scale"], cfg.norm_eps) @ params["lm_head"].T
-    _, want = reference.forward(params, ids, MODEL)
-    np.testing.assert_allclose(got, np.stack(want), rtol=1e-4, atol=TOL)
-
-
-@pytest.mark.parametrize("name", LEAVES)
-def test_gradient_of_every_leaf_equals_the_reference(both, name):
-    _, params, _, (_, got), (_, want) = both
-    g, w = np.asarray(_leaf(got, name)), np.asarray(_leaf(want, name))
-    assert g.shape == _leaf(params, name).shape and np.abs(w).max() > 0
-    np.testing.assert_allclose(g, w, rtol=1e-4, atol=TOL * np.abs(w).max())
-
-
-def test_the_leaves_tested_are_all_there_are(both):
-    _, params, _, _, _ = both
-    names = {re.findall(r"'(\w+)'", jax.tree_util.keystr(p))[-1]
-             for p, _ in jax.tree_util.tree_leaves_with_path(params)}
-    assert names == set(LEAVES)
 
 
 def test_a_bfloat16_shortcut_would_fail_the_tolerance(both):
@@ -165,57 +83,19 @@ def test_the_reference_s_faults_move_its_loss(both, fault):
     assert abs(bad - float(want)) / float(want) > 2 * TOL
 
 
-@pytest.fixture(scope="module")
-def program_logits(both):
-    """The trainer's own logits at the witness's positions, on the weights
-    and ids of ``both``."""
-    _, params, ids, _, _ = both
-    tr = _trainer()
-    tr.state["params"] = jax.tree.map(jnp.asarray, params)
-    at = reference.witness_positions(S)
-    assert len(at) == S and (at == np.arange(S)).all()      # S < WITNESS_ROWS
-    return np.asarray(tr.logits_at(ids, at))
-
-
-def test_the_witness_holds_the_program_s_logits(both, program_logits):
+def test_the_witness_holds_the_program_s_logits(witnessed):
     """What ``benchmark/drivers/train_scan_witnessed.py`` checks on the chip:
     ``StepTrainer``'s own forward at the witness's positions against the
-    reference's logits, as one relative error."""
-    _, params, ids, _, _ = both
+    reference's logits, as one relative error, on the weights and ids of
+    ``both``."""
+    params, ids, program_logits, model = witnessed
+    at = reference.witness_positions(S)
+    assert len(at) == S and (at == np.arange(S)).all()      # S < WITNESS_ROWS
     assert program_logits.shape == (B, S, 256)
     assert reference.logits_error(program_logits, params, {"ids": ids},
-                                  MODEL) < TOL
+                                  model) < TOL
     assert reference.witness_positions(16384)[[0, 1, -1]].tolist() == [
         32, 96, 16352]
-
-
-@pytest.mark.parametrize("fault", reference.FAULTS)
-def test_the_witness_sees_every_fault(both, program_logits, fault):
-    """Each fault in the reference moves its logits away from the program's
-    by a thousand times what the two differ by when both are sound."""
-    _, params, ids, _, _ = both
-    assert reference.logits_error(program_logits, params, {"ids": ids},
-                                  MODEL, faults=(fault,)) > 1e3 * TOL
-
-
-def test_the_reference_in_small_pieces_equals_itself_whole(both, monkeypatch):
-    """Cut as the published size cuts it (several row blocks, chunks that
-    do not divide the vocabulary, one expert at a time), the reference gives
-    the same loss, logits and gradient."""
-    _, params, ids, _, (want, want_grad) = both
-    params = jax.tree.map(jnp.asarray, params)
-    _, whole = reference.forward(params, ids, MODEL)
-    monkeypatch.setattr(reference, "QUERY_BLOCK", 16)       # 4 blocks of 64
-    monkeypatch.setattr(reference, "VOCAB_CHUNK", 100)      # 100, 100, 56
-    monkeypatch.setattr(reference, "EXPERT_GROUP", 1)
-    (loss, logits), grad = jax.value_and_grad(
-        lambda p: reference.forward(p, ids, MODEL), has_aux=True)(params)
-    assert abs(float(loss) - float(want)) / float(want) < 1e-6
-    np.testing.assert_allclose(np.stack(logits), np.stack(whole),
-                               rtol=1e-5, atol=1e-5)
-    for g, w in zip(jax.tree.leaves(grad), jax.tree.leaves(want_grad)):
-        np.testing.assert_allclose(g, w, rtol=1e-4,
-                                   atol=1e-5 * np.abs(w).max())
 
 
 def test_the_four_shares_add_up_to_the_uncut_reference_layer():
@@ -248,25 +128,12 @@ def test_the_four_shares_add_up_to_the_uncut_reference_layer():
     np.testing.assert_allclose(sum(parts), want, rtol=1e-5, atol=1e-5)
 
 
-def test_run_steps_over_three_batches_equals_three_steps():
-    batches = [{"ids": i} for i in _ids(seed=5, n=3)]
-    one, scan = _trainer(remat=True), _trainer(remat=True)
-    singly = [float(one.step(b, 1e-3)) for b in batches]
-    scanned = scan.run_steps(
-        stack_batches(scan.mesh, decoder.BATCH_SPECS, batches), 1e-3)
-    np.testing.assert_allclose(scanned, singly, rtol=1e-5)
-    assert singly[0] != singly[1]
-    for a, b in zip(jax.tree.leaves(one.state["params"]),
-                    jax.tree.leaves(scan.state["params"])):
-        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
-
-
 def test_two_periods_scanned_equal_the_reference():
     """8 layers are two periods: the scan's second turn runs the same four
     kinds on the second half of the stacked leaves."""
-    tr = _trainer(n_layers=8)
-    params = _seeded_params(tr)
-    ids = _ids(seed=2)[0]
+    tr = H.trainer(CASE, n_layers=8)
+    params = H.moved(CASE, tr.state["params"])
+    ids = H.ids(CASE, seed=2)[0]
     got = jax.jit(decoder.make_loss_fn(tr.cfg))(params, {"ids": jnp.asarray(ids)})
     model = dict(MODEL, num_hidden_layers=8, rope_layout=[0, 1, 1, 1] * 2,
                  sliding_window_layout=[0, 1, 1, 1] * 2)
@@ -277,11 +144,11 @@ def test_two_periods_scanned_equal_the_reference():
 def test_heads_the_kernel_cannot_tile_are_refused():
     """Heads of 16 (no 128-lane block holds one): grouped and windowed
     attention has no path but the packed kernel, and says so."""
-    tr = _trainer(head_width=16)
+    tr = H.trainer(CASE, head_width=16)
     assert T._packed_flash_blocks(tr.cfg, 6, S, 2) is None
     with pytest.raises(AssertionError, match="packed flash kernel"):
         decoder.make_loss_fn(tr.cfg)(tr.state["params"],
-                                   {"ids": jnp.asarray(_ids(seed=4)[0])})
+                                   {"ids": jnp.asarray(H.ids(CASE, seed=4)[0])})
 
 
 def _lowered(stack, layers, x):
@@ -321,62 +188,52 @@ def test_a_period_of_one_layer_is_the_scan_over_layers_it_was(model):
                         x) == was
 
 
-class _Unreadable:
-    shape, size = (B, S), B * S
-
-    def __array__(self, *a, **k):
-        raise AssertionError("the ids were read back with no monitor on")
-
-
-def test_counters_and_gauges_only_under_a_monitor_session(tmp_path):
-    tr = _trainer()
-    assert monitor.active() is None
-    tr._observe({"ids": _Unreadable()})         # off: nothing runs
-    assert tr._probe_fn is None
-    batches = [{"ids": i} for i in _ids(seed=8, n=2)]
-    mon = monitor.enable(str(tmp_path), flight=False)
-    try:
-        reg = mon.registry
-        held = reg.counter("monitor.train.moe_rows_held")
-        held_start = held.value
-        tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS, batches), 1e-3)
-        cfg = tr.cfg
-        # batches x tokens x top-2 x L
-        pairs = 2 * batches[0]["ids"].size * cfg.experts_per_token \
-            * cfg.moe_layers
-        assert pairs == 2 * B * S * 2 * 4
-        got = held.value - held_start
-        assert 0 < got < pairs
-        share = reg.gauge("monitor.train.moe_held_rows_share").value
-        np.testing.assert_allclose(share, got / pairs)
-        assert 0.1 < share < 0.5                # 2 of 8 experts held
-        # what the sum back's row kernel is sized by: a layer's pair slots,
-        # and the rows of its first capacity (at this size one 512-row tile
-        # would pass the slots, so they are the only capacity)
-        slots = B * S * cfg.experts_per_token
-        assert moe._held_capacities(slots, cfg.experts_here,
-                                    cfg.n_experts)[0] == slots == B * S * 2
-        # the flash kernels' grids by layer kind: S = 64 in 16-blocks, a
-        # window of 24 visits 2 or 3 kv blocks a q block (the grid is the
-        # table of visited blocks: it holds no other step)
-        blocks = T._packed_flash_blocks(cfg, cfg.n_heads, S, cfg.kv_heads)
-        window = max(k[0] or 0 for k in cfg.layer_kinds) or None
-        assert (blocks, window) == ((16, 16), 24)
-        assert kv_blocks(S, *blocks, True, None) == 10
-        assert kv_blocks(S, *blocks, True, window) == 9
-    finally:
-        monitor.disable()
+def _counters(trained):
+    cfg = trained.scan.cfg
+    # batches x tokens x top-2 x L
+    pairs = 3 * trained.batches[0]["ids"].size * cfg.experts_per_token \
+        * cfg.moe_layers
+    assert pairs == 3 * B * S * 2 * 4
+    got = trained.value("monitor.train.moe_rows_held")
+    assert 0 < got < pairs
+    share = trained.value("monitor.train.moe_held_rows_share")
+    np.testing.assert_allclose(share, got / pairs)
+    assert 0.1 < share < 0.5                # 2 of 8 experts held
+    # what the sum back's row kernel is sized by: a layer's pair slots,
+    # and the rows of its first capacity (at this size one 512-row tile
+    # would pass the slots, so they are the only capacity)
+    slots = B * S * cfg.experts_per_token
+    assert moe._held_capacities(slots, cfg.experts_here,
+                                cfg.n_experts)[0] == slots == B * S * 2
+    # the flash kernels' grids by layer kind: S = 64 in 16-blocks, a
+    # window of 24 visits 2 or 3 kv blocks a q block (the grid is the
+    # table of visited blocks: it holds no other step)
+    blocks = T._packed_flash_blocks(cfg, cfg.n_heads, S, cfg.kv_heads)
+    window = max(k[0] or 0 for k in cfg.layer_kinds) or None
+    assert (blocks, window) == ((16, 16), 24)
+    assert kv_blocks(S, *blocks, True, None) == 10
+    assert kv_blocks(S, *blocks, True, window) == 9
 
 
-def test_the_pre_attention_router_s_instructions_are_under_router():
+CASE = H.Case(
+    "smallthinker", reference, MODEL, LEAVES, off_one=("scale",),
+    # a router steep enough that the top-2 weights are not all one half
+    gain=H.steep("router"),
+    mechanism=_mechanism,
+    # 4 row blocks of 64; chunks of 100, 100, 56; one expert at a time
+    pieces={"QUERY_BLOCK": 16, "VOCAB_CHUNK": 100, "EXPERT_GROUP": 1},
+    pieces_hold=("loss", "logits", "grads"),
+    # the trainer's own logits on the weights and ids of ``both``
+    witness=H.Witness(seed=None, faults=reference.FAULTS),
+    steps=3, counters=True, also={"counters": _counters})
+globals().update(H.common(CASE))
+
+
+def test_the_pre_attention_router_s_instructions_are_under_router(trained):
     """The router's logits are computed before the attention scope, from
     the block's input: in the compiled step they carry the scope ``router``
     (forward and backward), and every scope of the block is there."""
-    tr = _trainer(remat=True)
-    tr.run_steps(stack_batches(tr.mesh, decoder.BATCH_SPECS,
-                               [{"ids": i} for i in _ids(n=2)]), 1e-3)
-    names = devscope.scope_maps()["smallthinker.run_steps"]
-    got = {devscope.classify(op) for op in names.values()}
+    names, got = trained.names, trained.scopes()
     for scope in ("moe", "router", "attention", "layer_norm", "lm_head",
                   "embed"):
         assert ("forward", scope) in got and ("backward", scope) in got, scope

@@ -16,27 +16,17 @@ model (``both``: loss, every position's logits and every leaf's gradient)
 serves the reference tests; one trainer's ``run_steps`` (``ran``) serves
 the counters' and the scopes'."""
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark.reference import solar_open2_250b as reference  # noqa: E402
-from paddle_tpu import monitor  # noqa: E402
-from paddle_tpu.models import solar_open2  # noqa: E402
-from paddle_tpu.monitor import devscope  # noqa: E402
-from paddle_tpu.parallel import (decoder, moe, optim,  # noqa: E402
-                                 transformer as T)
-from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
-from paddle_tpu.parallel.rules import leaf_paths  # noqa: E402
-from paddle_tpu.parallel.train import stack_batches  # noqa: E402
+import decoder_reference as H
+from benchmark.reference import solar_open2_250b as reference
+from paddle_tpu import monitor
+from paddle_tpu.models import solar_open2
+from paddle_tpu.monitor import devscope
+from paddle_tpu.parallel import decoder, moe, transformer as T
 
 B, S, TOL = 2, 64, 1e-5
 # the reference reads the published keys
@@ -60,68 +50,7 @@ LEAVES = ["tok_emb", "lm_head", "lnf_scale"] \
     + ["params_layers/r1/" + n for n in KDA_NAMES + SPARSE]
 
 
-def _trainer(seed=3, **cfg):
-    return solar_open2.build_solar_open2_trainer(
-        solar_open2.solar_open2_tiny_config(**cfg), MeshSpec(dp=1),
-        optimizer=optim.adamw(), seed=seed, devices=jax.devices()[:1])
-
-
-def _ids(seed=0, n=1):
-    rng = np.random.RandomState(seed)
-    return [rng.randint(0, 256, (B, S)).astype(np.int32) for _ in range(n)]
-
-
-def _seeded_params(tr):
-    """The trainer's seeded weights with the norm scales moved off 1, so
-    that a missing or misplaced scale shows, a router steep enough that the
-    weights are not all alike, ``w_beta`` steep enough that the strengths
-    spread over (0, 2), and branch outputs at the fan-in scale again (the
-    seeded 48^-1/2 would hide a wrong branch behind the embedding)."""
-    rng = np.random.RandomState(11)
-
-    def moved(path, a):
-        name = jax.tree_util.keystr(path)
-        if "scale" in name or "_norm" in name:
-            return np.asarray(a) * rng.uniform(0.5, 1.5, a.shape).astype("f4")
-        if "router_bias" in name:
-            return np.asarray(a)
-        if name.endswith("['wo']") or "down" in name:
-            return np.asarray(a) * 48 ** 0.5
-        return np.asarray(a) * (
-            3.0 if "router" in name or "w_beta" in name else 1.0)
-
-    return jax.tree_util.tree_map_with_path(moved, tr.state["params"])
-
-
-def _leaf(tree, path):
-    for part in path.split("/"):
-        tree = tree[part]
-    return tree
-
-
-@pytest.fixture(scope="module")
-def both():
-    """Loss, every position's logits and the gradients of program (ONE
-    traced program) and reference on the same weights."""
-    tr = _trainer()
-    # ONE sequence (the cell's batch): the reference walks a sequence at a
-    # time, op by op, and a second one doubles the file's longest fixture
-    cfg, params, ids = tr.cfg, _seeded_params(tr), _ids()[0][:1]
-    loss_fn = decoder.make_loss_fn(cfg)
-
-    def program(p):
-        x, _ = decoder.forward(p, jnp.asarray(ids), cfg)
-        return loss_fn(p, {"ids": jnp.asarray(ids)})[0], \
-            T.head_logits(p, x, cfg)
-
-    got = jax.jit(jax.value_and_grad(program, has_aux=True))(params)
-    want = jax.value_and_grad(
-        lambda p: reference.forward(p, ids, MODEL), has_aux=True)(
-            jax.tree.map(jnp.asarray, params))
-    return cfg, params, ids, got, want
-
-
-def test_the_tiny_configuration_keeps_every_mechanism():
+def _mechanism():
     cfg = solar_open2.solar_open2_tiny_config()
     assert not cfg.latent and cfg.per_position and cfg.run_scan
     assert cfg.prefix_kinds == () and cfg.layer_kinds == (
@@ -156,29 +85,8 @@ def test_the_tiny_configuration_keeps_every_mechanism():
     assert T.TransformerConfig.kda_beta_scale == 1.0
 
 
-def test_loss_equals_the_reference(both):
-    _, _, _, ((got, _), _), ((want, _), _) = both
-    assert abs(float(got) - float(want)) / float(want) < TOL
-
-
-def test_every_position_s_logits_equal_the_reference(both):
-    _, _, _, ((_, got), _), ((_, want), _) = both
-    np.testing.assert_allclose(got, np.stack(want), rtol=1e-4, atol=TOL)
-
-
-@pytest.mark.parametrize("path", LEAVES)
-def test_gradient_of_every_leaf_equals_the_reference(both, path):
-    _, params, _, (_, got), (_, want) = both
-    g, w = np.asarray(_leaf(got, path)), np.asarray(_leaf(want, path))
-    assert g.shape == _leaf(params, path).shape and np.abs(w).max() > 0
-    np.testing.assert_allclose(g, w, rtol=1e-4, atol=TOL * np.abs(w).max())
-
-
-def test_the_leaves_tested_are_all_there_are(both):
-    _, params, _, (_, got), _ = both
-    paths, _, _ = leaf_paths(params)
-    assert set(paths) == set(LEAVES) | {"router_bias"}
-    assert not np.asarray(got["router_bias"]).any()     # no gradient reaches
+def _shapes(both):
+    params = both.params
     r0, r1 = params["params_layers"]["r0"], params["params_layers"]["r1"]
     assert r0["wq"].shape == r0["wz"].shape == (1, 1, 64, 4 * 128)
     assert r0["wk"].shape == r0["wv"].shape == (1, 1, 64, 2 * 128)
@@ -198,15 +106,32 @@ def test_the_leaves_tested_are_all_there_are(both):
     assert 0 < beta.min() < 0.5 and 1.5 < beta.max() < 2
 
 
-def test_sharding_specs_and_gradient_syncs_follow_the_tree():
-    for cfg in (solar_open2.solar_open2_tiny_config(),
-                solar_open2.solar_open2_tiny_config(run_scan=False)):
-        params = jax.eval_shape(
-            lambda: T.init_transformer_params(jax.random.PRNGKey(0), cfg))
-        for tree in (T.transformer_param_specs(cfg), T.grad_sync_axes(cfg)):
-            assert jax.tree.structure(
-                tree, is_leaf=lambda x: isinstance(x, (tuple, T.P))) == \
-                jax.tree.structure(params)
+def _gain(name):
+    """A router steep enough that the weights are not all alike, ``w_beta``
+    steep enough that the strengths spread over (0, 2), and branch outputs
+    at the fan-in scale again (the seeded 48^-1/2 would hide a wrong branch
+    behind the embedding)."""
+    if "router_bias" in name:
+        return 1.0
+    if name.endswith("['wo']") or "down" in name:
+        return 48 ** 0.5
+    return 3.0 if "router" in name or "w_beta" in name else 1.0
+
+
+CASE = H.Case(
+    "solar_open2", reference, MODEL, tuple(LEAVES), aux=True, biased=True,
+    gain=_gain, mechanism=_mechanism,
+    # ONE sequence (the cell's batch): the reference walks a sequence at a
+    # time, op by op, and a second one doubles the file's longest fixture;
+    # ONE traced program: loss, every position's logits and the gradients
+    rows=1, one_program=True,
+    forward=lambda p, ids: reference.forward(p, ids, MODEL),
+    spec_configs=({}, {"run_scan": False}), bfloat16=True,
+    pieces={"QUERY_BLOCK": 16, "HEAD_GROUP": 1, "VOCAB_CHUNK": 100,
+            "EXPERT_GROUP": 1, "DENSE_CHUNK": 20},
+    pieces_hold=("loss",), witness=H.Witness(from_logits=True),
+    also={"leaves": _shapes})
+globals().update(H.common(CASE))
 
 
 def _layer_inputs():
@@ -259,8 +184,8 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_are_the_layer():
                   - (routed_want + shared_want)).max() > 0.1
 
 
-def test_the_witness_stands_after_the_chunk_edges(both):
-    _, params, ids, ((_, program), _), _ = both
+def test_the_witness_stands_after_the_chunk_edges(witnessed):
+    params, ids, got, _ = witnessed
     big = reference.witness_groups(4096)
     assert big["edge"].tolist() == [
         at + i for at in (64, 512, 4032) for i in range(8)] + list(
@@ -272,8 +197,6 @@ def test_the_witness_stands_after_the_chunk_edges(both):
     groups = reference.witness_groups(S)
     assert groups["edge"].tolist() == [
         at + i for at in (16, 32, 48) for i in range(8)] + list(range(56, 64))
-    at = reference.witness_positions(S)
-    got = np.asarray(program)[:, at]
     batch = {"ids": ids}
     each = reference.position_errors(got, params, batch, MODEL)
     assert each.shape == (S,) and each.max() < TOL
@@ -282,48 +205,17 @@ def test_the_witness_stands_after_the_chunk_edges(both):
         == max(parts.values())
 
 
-@pytest.mark.parametrize("fault", reference.FAULTS[:-1])
-def test_the_witness_sees_every_fault(both, fault):
-    """Each fault in the reference moves its logits away from the program's
-    by a thousand times what the two differ by when both are sound, at the
-    witness's own statistic."""
-    _, params, ids, ((_, program), _), _ = both
-    got = np.asarray(program)[:, reference.witness_positions(S)]
-    moved = reference.logits_error(got, params, {"ids": ids}, MODEL,
-                                   faults=(fault,))
-    assert moved > 1e3 * TOL
-
-
-def test_bfloat16_throughout_moves_the_reference_s_loss(both):
-    _, params, ids, _, ((want, _), _) = both
-    bad = reference.loss(params, {"ids": ids}, MODEL,
-                         faults=("bfloat16_throughout",))
-    assert abs(bad - float(want)) / float(want) > 2 * TOL
-
-
-def test_the_reference_in_small_pieces_equals_itself_whole(both, monkeypatch):
-    _, params, ids, _, ((want, _), _) = both
-    monkeypatch.setattr(reference, "QUERY_BLOCK", 16)
-    monkeypatch.setattr(reference, "HEAD_GROUP", 1)
-    monkeypatch.setattr(reference, "VOCAB_CHUNK", 100)
-    monkeypatch.setattr(reference, "EXPERT_GROUP", 1)
-    monkeypatch.setattr(reference, "DENSE_CHUNK", 20)
-    loss = reference.forward(jax.tree.map(jnp.asarray, params), ids, MODEL,
-                             keep_logits=False)[0]
-    assert abs(float(loss) - float(want)) / float(want) < 1e-6
-
-
 @pytest.fixture(scope="module")
 def ran(tmp_path_factory):
     """One trainer's ``run_steps`` over two batches under a monitor session:
     the losses, the registry's snapshot and the program's scope map."""
-    batches = [{"ids": i} for i in _ids(seed=5, n=2)]
-    tr = _trainer(remat=True)
+    batches = [{"ids": i} for i in H.ids(CASE, seed=5, n=2)]
+    tr = H.trainer(CASE, remat=True)
     assert monitor.active() is None
     mon = monitor.enable(str(tmp_path_factory.mktemp("mon")), flight=False)
     try:
         losses = tr.run_steps(
-            stack_batches(tr.mesh, decoder.BATCH_SPECS, batches), 1e-3)
+            H.staged(tr, batches), 1e-3)
         rows = mon.registry.snapshot()
     finally:
         monitor.disable()
@@ -337,7 +229,7 @@ def test_run_steps_first_loss_is_the_loss_at_the_seeded_weights(ran):
     no step taken), and the second step's, on another batch after an
     update, is another number."""
     batches, scanned, _, _ = ran
-    fresh = _trainer(remat=True)
+    fresh = H.trainer(CASE, remat=True)
     first = jax.jit(lambda p, ids: decoder.make_loss_fn(fresh.cfg)(
         p, {"ids": ids})[0])(fresh.state["params"], batches[0]["ids"])
     np.testing.assert_allclose(scanned[0], float(first), rtol=1e-5)

@@ -13,28 +13,18 @@ The tiny configuration computes in float32, so the tolerance is 1e-5 (the
 two differ by accumulation order only)."""
 
 import math
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark.reference import trinity_large_preview as reference  # noqa: E402
-from paddle_tpu import monitor  # noqa: E402
-from paddle_tpu.kernels.flash_attention import packed_grid  # noqa: E402
-from paddle_tpu.models import trinity  # noqa: E402
-from paddle_tpu.monitor import devscope  # noqa: E402
-from paddle_tpu.parallel import (decoder, moe, optim,  # noqa: E402
-                                 transformer as T)
-from paddle_tpu.parallel.mesh import MeshSpec  # noqa: E402
-from paddle_tpu.parallel.rules import leaf_paths  # noqa: E402
-from paddle_tpu.parallel.train import stack_batches  # noqa: E402
+import decoder_reference as H
+from benchmark.reference import trinity_large_preview as reference
+from paddle_tpu.kernels.flash_attention import packed_grid
+from paddle_tpu.models import trinity
+from paddle_tpu.monitor import devscope
+from paddle_tpu.parallel import decoder, moe, transformer as T
 
 B, S, TOL = 2, 64, 1e-5
 # the reference reads the published keys
@@ -56,56 +46,7 @@ LEAVES = ["tok_emb", "lm_head", "lnf_scale"] \
     + ["params_layers/p%d/%s" % (i, n) for i in range(4) for n in SPARSE]
 
 
-def _trainer(seed=3, optimizer=None, **cfg):
-    return trinity.build_trinity_trainer(
-        trinity.trinity_tiny_config(**cfg), MeshSpec(dp=1),
-        optimizer=optimizer or optim.adamw(), seed=seed,
-        devices=jax.devices()[:1])
-
-
-def _ids(seed=0, n=1):
-    rng = np.random.RandomState(seed)
-    return [rng.randint(0, 256, (B, S)).astype(np.int32) for _ in range(n)]
-
-
-def _moved(params):
-    """Seeded weights with the norm scales moved off 1, so that a missing
-    or misplaced scale shows, a router steep enough that the scores are not
-    all one half, and biases large enough to change who is chosen at many
-    tokens."""
-    rng = np.random.RandomState(11)
-
-    def moved(path, a):
-        name = jax.tree_util.keystr(path)
-        if "scale" in name or "_norm" in name:
-            return np.asarray(a) * rng.uniform(0.5, 1.5, a.shape).astype("f4")
-        return np.asarray(a) * (3.0 if "router" in name else 1.0)
-
-    return jax.tree_util.tree_map_with_path(moved, params)
-
-
-def _leaf(tree, path):
-    for part in path.split("/"):
-        tree = tree[part]
-    return tree
-
-
-@pytest.fixture(scope="module")
-def both():
-    """Loss and gradients of program and reference on the same weights."""
-    tr = _trainer()
-    params = _moved(tr.state["params"])
-    ids = _ids()[0]
-    loss_fn = decoder.make_loss_fn(tr.cfg)
-    got = jax.jit(jax.value_and_grad(
-        lambda p: loss_fn(p, {"ids": jnp.asarray(ids)}), has_aux=True))(params)
-    want = jax.value_and_grad(
-        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
-            jax.tree.map(jnp.asarray, params))
-    return tr.cfg, params, ids, got, want
-
-
-def test_the_tiny_configuration_keeps_every_mechanism():
+def _mechanism():
     cfg = trinity.trinity_tiny_config()
     assert cfg.prefix_kinds == ((24, True),)
     assert cfg.layer_kinds == ((24, True), (None, False), (24, True),
@@ -140,35 +81,9 @@ def test_the_tiny_configuration_keeps_every_mechanism():
         trinity.trinity_large_preview_config(n_layers=60)
 
 
-def test_loss_equals_the_reference(both):
-    _, _, _, ((got, _), _), (want, _) = both
-    assert abs(float(got) - float(want)) / float(want) < TOL
-
-
-def test_every_position_s_logits_equal_the_reference(both):
-    cfg, params, ids, _, _ = both
-    x, _ = jax.jit(lambda p, i: decoder.forward(p, i, cfg))(params, ids)
-    got = T.rms_norm(x, params["lnf_scale"], cfg.norm_eps) @ params["lm_head"].T
-    _, want = reference.forward(params, ids, MODEL)
-    np.testing.assert_allclose(got, np.stack(want), rtol=1e-4, atol=TOL)
-
-
-@pytest.mark.parametrize("path", LEAVES)
-def test_gradient_of_every_leaf_equals_the_reference(both, path):
-    _, params, _, (_, got), (_, want) = both
-    g, w = np.asarray(_leaf(got, path)), np.asarray(_leaf(want, path))
-    assert g.shape == _leaf(params, path).shape and np.abs(w).max() > 0
-    np.testing.assert_allclose(g, w, rtol=1e-4, atol=TOL * np.abs(w).max())
-
-
-def test_the_leaves_tested_are_all_there_are(both):
-    _, params, _, (_, got), (_, want) = both
-    paths, _, _ = leaf_paths(params)
-    assert set(paths) == set(LEAVES) | {"router_bias"}
-    # the bias chooses and nothing else: no gradient reaches it
+def _shapes(both):
+    params = both.params
     assert params["router_bias"].shape == (4, 8)
-    assert not np.asarray(got["router_bias"]).any()
-    assert not np.asarray(want["router_bias"]).any()
     p1 = params["params_layers"]["p1"]
     assert p1["wz"].shape == p1["wq"].shape == (1, 64, 6 * 128)
     assert p1["wk"].shape == (1, 64, 2 * 128)
@@ -179,22 +94,6 @@ def test_the_leaves_tested_are_all_there_are(both):
     assert params["prefix_layers"]["l0"]["w_gate_up"].shape == (64, 192)
 
 
-def test_sharding_specs_and_gradient_syncs_follow_the_tree():
-    cfg = trinity.trinity_tiny_config()
-    params = jax.eval_shape(
-        lambda: T.init_transformer_params(jax.random.PRNGKey(0), cfg))
-    for tree in (T.transformer_param_specs(cfg), T.grad_sync_axes(cfg)):
-        assert jax.tree.structure(
-            tree, is_leaf=lambda x: isinstance(x, (tuple, T.P))) == \
-            jax.tree.structure(params)
-    specs = T.transformer_param_specs(cfg)
-    # the gate's projection is cut as the queries' are (columns by head)
-    assert specs["params_layers"]["p0"]["wz"] \
-        == specs["params_layers"]["p0"]["wq"] == T.P(None, None, "tp")
-    assert specs["params_layers"]["p0"]["ln1_post_scale"] == T.P(None, None)
-    assert specs["prefix_layers"]["l0"]["wz"] == specs["router_bias"] == T.P()
-
-
 def test_one_rule_builds_the_ffn_leaves_of_both_trees(both):
     """Without the dense prefix the layers own the same leaves and the tree
     is ONE stack: it holds the shared expert, the selection biases, the gate
@@ -202,12 +101,12 @@ def test_one_rule_builds_the_ffn_leaves_of_both_trees(both):
     reference's (read a position at a time)."""
     cfg = trinity.trinity_tiny_config(n_layers=4, n_dense_layers=0)
     assert not cfg.per_position and cfg.moe_layers == 4
-    params = _moved(T.init_transformer_params(jax.random.PRNGKey(5), cfg))
+    params = H.moved(CASE, T.init_transformer_params(jax.random.PRNGKey(5), cfg))
     stacked = params["params_layers"]
     assert set(stacked) == set(SPARSE) and "prefix_layers" not in params
     assert params["router_bias"].shape == (4, 8)
     assert np.abs(params["router_bias"]).min() > 0
-    ids = _ids(seed=2)[0]
+    ids = H.ids(CASE, seed=2)[0]
     got, stepped = jax.jit(decoder.make_loss_fn(cfg))(
         params, {"ids": jnp.asarray(ids)})
     by_position = dict(params, params_layers={
@@ -224,7 +123,7 @@ def _layer(seed=4, at="p1", **kw):
     """A sparse layer's leaves (position 1: the full layer), unstacked, and
     a stream to run it on."""
     cfg = trinity.trinity_tiny_config(**kw)
-    params = _moved(T.init_transformer_params(jax.random.PRNGKey(seed), cfg))
+    params = H.moved(CASE, T.init_transformer_params(jax.random.PRNGKey(seed), cfg))
     pl = jax.tree.map(lambda a: jnp.asarray(a[0]),
                       params["params_layers"][at])
     x = jax.random.normal(jax.random.PRNGKey(seed + 1), (B, S, 64))
@@ -461,7 +360,7 @@ def test_the_bias_chooses_and_does_not_weigh():
 def test_the_embedding_enters_the_stream_times_the_multiplier():
     cfg = trinity.trinity_tiny_config()
     params = T.init_transformer_params(jax.random.PRNGKey(2), cfg)
-    ids = jnp.asarray(_ids()[0])
+    ids = jnp.asarray(H.ids(CASE)[0])
     got = T.embed(params, ids, cfg)
     np.testing.assert_allclose(got, 8.0 * params["tok_emb"][ids], rtol=1e-6)
     # the rows are seeded at the fan-in scale: the stream starts at unit scale
@@ -470,21 +369,6 @@ def test_the_embedding_enters_the_stream_times_the_multiplier():
     assert abs(float(jnp.std(T.embed(
         big, jnp.arange(4096)[None], trinity.trinity_tiny_config(
             vocab_size=4096)))) - 1.0) < 0.02
-
-
-@pytest.fixture(scope="module")
-def witnessed():
-    """A trainer that holds HALF the experts (4 of 8, the second half), its
-    weights moved as ``both``'s, and its own logits at the witness's
-    positions (with 2 of 8 held, many positions meet no held expert and a
-    routing fault does not touch them)."""
-    tr = _trainer(experts_held=4, first_expert=4)
-    params = _moved(tr.state["params"])
-    tr.state["params"] = jax.tree.map(jnp.asarray, params)
-    ids = _ids(seed=9)[0][:1]       # one sequence: the cell's batch
-    program = np.asarray(tr.logits_at(ids, reference.witness_positions(S)))
-    return params, ids, program, dict(MODEL, num_experts=4,
-                                      moe_first_expert_held=4)
 
 
 def test_the_witness_reads_both_sides_of_the_window_s_edge(witnessed):
@@ -509,115 +393,34 @@ def test_the_witness_reads_both_sides_of_the_window_s_edge(witnessed):
     assert parts["edge"] == np.quantile(each[:12], 0.75)
 
 
-@pytest.mark.parametrize("fault", reference.FAULTS[:-1])
-def test_the_witness_sees_every_fault(witnessed, fault):
-    """Each fault in the reference (the gate dropped, fed the un-normed
-    stream or moved onto the values; an output norm dropped, or the shared
-    expert added past it; rotary on the full layer; no window; the
-    multiplier dropped; the route scale 1; the bias leaking into the
-    weights or left out of the choice; ...) moves its logits away from the
-    program's by a thousand times what the two differ by when both are
-    sound, at the witness's own statistic."""
-    params, ids, program, model = witnessed
-    moved = reference.logits_error(program, params, {"ids": ids}, model,
-                                   faults=(fault,))
-    assert moved > 1e3 * TOL
-
-
-def test_bfloat16_throughout_moves_the_reference_s_loss(both):
-    _, params, ids, _, (want, _) = both
-    bad = reference.loss(params, {"ids": ids}, MODEL,
-                         faults=("bfloat16_throughout",))
-    assert abs(bad - float(want)) / float(want) > 2 * TOL
-
-
-def test_the_reference_in_small_pieces_equals_itself_whole(both, monkeypatch):
-    _, params, ids, _, (want, want_grad) = both
-    params = jax.tree.map(jnp.asarray, params)
-    monkeypatch.setattr(reference, "QUERY_BLOCK", 16)       # 4 blocks of 64
-    monkeypatch.setattr(reference, "VOCAB_CHUNK", 100)      # 100, 100, 56
-    monkeypatch.setattr(reference, "EXPERT_GROUP", 1)
-    monkeypatch.setattr(reference, "DENSE_CHUNK", 20)       # 20, 20, 8
-    loss, grad = jax.value_and_grad(
-        lambda p: reference.forward(p, ids, MODEL, keep_logits=False)[0])(
-            params)
-    assert abs(float(loss) - float(want)) / float(want) < 1e-6
-    for g, w in zip(jax.tree.leaves(grad), jax.tree.leaves(want_grad)):
-        np.testing.assert_allclose(g, w, rtol=1e-4,
-                                   atol=1e-5 * max(np.abs(w).max(), 1e-30))
-
-
-@pytest.fixture(scope="module")
-def trained(tmp_path_factory):
-    """Two trainers of one seed (remat on) over the same two batches: ``one``
-    takes two steps, ``scan`` one ``run_steps`` under a monitor session; what
-    the tests below read of them, gathered once (each trainer compiles its
-    programs anew)."""
-    batches = [{"ids": i} for i in _ids(seed=5, n=2)]
-    one, scan = _trainer(remat=True), _trainer(remat=True)
-    out = {"bias0": np.asarray(one.state["params"]["router_bias"])}
-    _, aux = jax.jit(lambda p, i: decoder.forward(p, i, one.cfg))(
-        one.state["params"], batches[0]["ids"])
-    out["load"] = np.asarray(aux["load"], np.float32)
-    out["singly"] = [float(one.step(batches[0], 1e-3))]
-    out["bias1"] = np.asarray(one.state["params"]["router_bias"])
-    out["singly"].append(float(one.step(batches[1], 1e-3)))
-    assert monitor.active() is None
-    mon = monitor.enable(str(tmp_path_factory.mktemp("monitor")),
-                         flight=False)
-    try:
-        reg = mon.registry
-        held = reg.counter("monitor.train.moe_rows_held")
-        start = held.value
-        out["scanned"] = np.asarray(scan.run_steps(
-            stack_batches(scan.mesh, decoder.BATCH_SPECS, batches), 1e-3))
-        out["rows_held"] = held.value - start
-        out["gauges"] = {n: reg.gauge(n).value for n in (
-            "monitor.train.moe_held_rows_share",
-            "monitor.train.moe_load_max_over_mean",
-            "monitor.train.attn_gate_mean",
-            "monitor.train.router_bias_abs_max")}
-    finally:
-        monitor.disable()
-    out["params"] = [jax.tree.map(np.asarray, t.state["params"])
-                     for t in (one, scan)]
-    out["names"] = devscope.scope_maps()["trinity.run_steps"]
-    return out
-
-
 def test_a_step_moves_the_biases_by_the_rate_against_the_load(trained):
     """``load_balance_coeff`` as the sign rule's rate: after one step every
     layer's biases stand 5e-5 up or down, against that layer's load over
     this chip's tokens, for all 8 experts."""
-    load = trained["load"]
+    _, aux = jax.jit(lambda p, i: decoder.forward(p, i, trained.scan.cfg))(
+        trained.after[0], trained.batches[0]["ids"])
+    load = np.asarray(aux["load"], np.float32)
+    bias0, bias1 = (p["router_bias"] for p in trained.after[:2])
     assert load.shape == (4, 8) and (load.sum(-1) == B * S * 2).all()
-    want = trained["bias0"] + np.float32(5e-5) * np.sign(
+    want = bias0 + np.float32(5e-5) * np.sign(
         load.mean(-1, keepdims=True) - load)
-    np.testing.assert_array_equal(trained["bias1"], want.astype("f4"))
-    assert np.abs(trained["bias1"] - trained["bias0"]).max() > 0
+    np.testing.assert_array_equal(bias1, want.astype("f4"))
+    assert np.abs(bias1 - bias0).max() > 0
 
 
-def test_run_steps_over_two_batches_equals_two_steps(trained):
-    singly = trained["singly"]
-    np.testing.assert_allclose(trained["scanned"], singly, rtol=1e-5)
-    assert singly[0] != singly[1]
-    for a, b in zip(*(jax.tree.leaves(p) for p in trained["params"])):
-        np.testing.assert_allclose(a, b, rtol=1e-4, atol=5e-5)
-
-
-def test_counters_and_gauges_only_under_a_monitor_session(trained):
+def _counters(trained):
     cfg = trinity.trinity_tiny_config()
     # batches x tokens x top-2 x MoE layers
     pairs = 2 * B * S * cfg.experts_per_token * cfg.moe_layers
     assert pairs == 2 * B * S * 2 * 4
-    rows_held, gauges = trained["rows_held"], trained["gauges"]
+    rows_held = trained.value("monitor.train.moe_rows_held")
     assert 0 < rows_held < pairs
-    share = gauges["monitor.train.moe_held_rows_share"]
+    share = trained.value("monitor.train.moe_held_rows_share")
     assert share == rows_held / pairs and 0.1 < share < 0.5
-    assert gauges["monitor.train.moe_load_max_over_mean"] >= 1.0
+    assert trained.value("monitor.train.moe_load_max_over_mean") >= 1.0
     # seeded gates stand near one half: neither stuck shut nor open
-    assert 0.4 < gauges["monitor.train.attn_gate_mean"] < 0.6
-    assert gauges["monitor.train.router_bias_abs_max"] > 0
+    assert 0.4 < trained.value("monitor.train.attn_gate_mean") < 0.6
+    assert trained.value("monitor.train.router_bias_abs_max") > 0
     # a layer's grid: the causal triangle's 10 of 4 x 4 blocks a (sequence,
     # key/value head), a group's three query heads riding each step (PR
     # 68), from the function the kernels take their grid from
@@ -628,9 +431,36 @@ def test_counters_and_gauges_only_under_a_monitor_session(trained):
         causal=True) == (3, 40)
 
 
+def _specs(specs):
+    # the gate's projection is cut as the queries' are (columns by head)
+    assert specs["params_layers"]["p0"]["wz"] \
+        == specs["params_layers"]["p0"]["wq"] == T.P(None, None, "tp")
+    assert specs["params_layers"]["p0"]["ln1_post_scale"] == T.P(None, None)
+    assert specs["prefix_layers"]["l0"]["wz"] == specs["router_bias"] == T.P()
+
+
+CASE = H.Case(
+    "trinity", reference, MODEL, tuple(LEAVES), aux=True, biased=True,
+    # a router steep enough that the scores are not all one half, and biases
+    # large enough to change who is chosen at many tokens
+    gain=H.steep("router"),
+    mechanism=_mechanism, spec_configs=({},), bfloat16=True,
+    # 4 row blocks of 64; chunks of 100, 100, 56; an expert at a time; the
+    # dense layer's columns as 20, 20, 8
+    pieces={"QUERY_BLOCK": 16, "VOCAB_CHUNK": 100, "EXPERT_GROUP": 1,
+            "DENSE_CHUNK": 20},
+    # a trainer that holds HALF the experts (4 of 8, the second half): with 2
+    # of 8 held, many positions meet no held expert and a routing fault does
+    # not touch them
+    witness=H.Witness(cfg={"experts_held": 4, "first_expert": 4},
+                      model={"num_experts": 4, "moe_first_expert_held": 4}),
+    steps=2, steps_atol=5e-5, counters=True,
+    also={"leaves": _shapes, "specs": _specs, "counters": _counters})
+globals().update(H.common(CASE))
+
+
 def test_the_new_scopes_hold_their_instructions(trained):
-    names = trained["names"]
-    got = {devscope.classify(op) for op in names.values()}
+    names, got = trained.names, trained.scopes()
     for scope in ("attention", "attn_gate", "post_norm", "shared_expert",
                   "moe", "router", "mlp", "layer_norm", "lm_head", "embed"):
         assert ("forward", scope) in got and ("backward", scope) in got, scope

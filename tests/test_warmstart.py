@@ -389,13 +389,34 @@ def test_topology_precompiler_after_commit(tmp_path):
     assert wc.ensure(*args) == "disk"
 
 
-def test_warm_train_step_key(tmp_path):
-    """make_train_step(warm_key=...) persists the step executable and a
-    rebuilt step over the same rules/mesh loads it, bit-identically."""
+def _warm_step(loss_fn, opt, mesh, params, key, donate):
+    """``make_train_step``'s program under the store, as a caller that names
+    its model wraps it: ``warm.WarmCallable`` with the mesh, the specs, the
+    donation flag and the loss's and optimizer's code in the key."""
     from jax.sharding import PartitionSpec as P
 
-    from paddle_tpu.parallel.mesh import make_mesh
     from paddle_tpu.parallel.train import TrainState, make_train_step
+
+    specs = ({"w": P()}, {"w": ()}, {"x": P()})
+    build = make_train_step(loss_fn, mesh, specs[0], specs[1], opt, specs[2],
+                            donate=False)
+    return warm.WarmCallable(
+        build(TrainState.create(params, opt)),
+        {"kind": "train_step", "key": key, "mesh": warm.mesh_desc(mesh),
+         "specs": [repr(s) for s in specs],
+         # an edited loss or optimizer must not be served the old math from
+         # disk even when every shape/spec is unchanged
+         "code": warm.code_fingerprint(loss_fn, opt[1]),
+         "donate": bool(donate)},
+        jit_kwargs={"donate_argnums": (0,) if donate else ()},
+        label="train_step:%s" % key)
+
+
+def test_warm_train_step_key(tmp_path):
+    """A train step under ``warm.WarmCallable`` persists its executable and
+    a rebuilt step over the same rules/mesh loads it, bit-identically."""
+    from paddle_tpu.parallel.mesh import make_mesh
+    from paddle_tpu.parallel.train import TrainState
 
     _store(tmp_path)
     os.environ["PADDLE_TPU_WARM_SYNC_PUBLISH"] = "1"
@@ -409,10 +430,7 @@ def test_warm_train_step_key(tmp_path):
             return ((b["x"] @ p["w"]) ** 2).mean()
 
         def one(donate):
-            build = make_train_step(loss_fn, mesh, {"w": P()}, {"w": ()},
-                                    opt, {"x": P()}, donate=donate,
-                                    warm_key="ut_step")
-            step = build(TrainState.create(params, opt))
+            step = _warm_step(loss_fn, opt, mesh, params, "ut_step", donate)
             st, loss = step(TrainState.create(params, opt),
                             {"x": np.ones((2, 4), np.float32)}, 0.1)
             return step, float(loss), np.asarray(st["params"]["w"])
@@ -500,12 +518,10 @@ def test_version_skew_refusal_leaves_entry_for_peers(tmp_path, monkeypatch):
 
 
 def test_train_step_code_drift_new_key(tmp_path):
-    """Editing the loss math (same warm_key, same shapes/specs) must not
-    be served the OLD executable from disk."""
-    from jax.sharding import PartitionSpec as P
-
+    """Editing the loss math (same key, same shapes/specs) must not be
+    served the OLD executable from disk."""
     from paddle_tpu.parallel.mesh import make_mesh
-    from paddle_tpu.parallel.train import TrainState, make_train_step
+    from paddle_tpu.parallel.train import TrainState
 
     _store(tmp_path)
     os.environ["PADDLE_TPU_WARM_SYNC_PUBLISH"] = "1"
@@ -516,10 +532,8 @@ def test_train_step_code_drift_new_key(tmp_path):
             {k: p[k] - lr * g[k] for k in p}, o))
 
         def run(loss_fn):
-            build = make_train_step(loss_fn, mesh, {"w": P()}, {"w": ()},
-                                    opt, {"x": P()}, donate=False,
-                                    warm_key="code_drift")
-            step = build(TrainState.create(params, opt))
+            step = _warm_step(loss_fn, opt, mesh, params, "code_drift",
+                              False)
             _st, loss = step(TrainState.create(params, opt),
                              {"x": np.ones((2, 4), np.float32)}, 0.1)
             return step.last_source, float(loss)
