@@ -17,16 +17,20 @@ key/value head-block) pair and a grid step one (q block, kv block) tile of
 ``step_table``, built on the host from the shapes and the mask and read by
 the index maps as scalar-prefetch operands: only the pairs the mask lets
 something through are steps (the triangle under the diagonal, a window's
-band, the rectangle).  A step holds ``heads_a_step`` query head-blocks of
-the key/value head-block's group, looped inside it (PR 68; one where the
-queries are not grouped, which is the step as it always was): their q and o
-blocks are adjacent lane blocks of the packed array (one DMA), k and v
-arrive once a step for all of them, the tile's mask is built once, and the
+band, the rectangle).  A step holds ``heads_a_step`` query head-blocks,
+looped inside it: of the key/value head-block's group where the queries are
+grouped (PR 68), and where they are not, that many ADJACENT head-blocks of
+the batch row, each with k and v of its own (PR 70: the k and v blocks are
+as many head-blocks wide and the grid's key/value axis counts the row's
+head-blocks a step's worth at a time; SWEEP_HEAD_BLOCKS at most, in the
+backward SWEEP_BWD_HEAD_BLOCKS).  Either
+way their q and o blocks are adjacent lane blocks of the packed array (one
+DMA), k and v arrive once a step, the tile's mask is built once, and the
 heads' chains (matmul, reduce, ``exp``, matmul) stand side by side in the
 body for the compiler to interleave; ``heads`` is the largest divisor of
-the group whose step fits SWEEP_VMEM, from the shapes alone, and the
-group's other chunks are positions of the grid (forward) or further sweeps
-of the same grid row (backward).  The running max
+the group (of the row's head-blocks) whose step fits SWEEP_VMEM, from the
+shapes alone, and a group's other chunks are positions of the grid
+(forward) or further sweeps of the same grid row (backward).  The running max
 (m), denominator (l) and output accumulator live in VMEM scratch across a q
 block's sweep, a slot a head (the standard TPU flash schedule).  The
 backward recomputes
@@ -40,7 +44,10 @@ dv, summed over the step's heads, ONCE a step into rows ``kv block`` of two
 float32 accumulators that hold the WHOLE
 sequence in VMEM ([Sk, lanes] each: 16 MiB at S = 16,384), which sum over
 the group because they are the key/value head's own, and leave once at the
-grid row's last step.  That runs wherever the accumulators and their output
+grid row's last step.  Ungrouped heads of a step sum over nothing: the
+accumulators and their output blocks are ``heads`` head-blocks wide and a
+head adds into columns of its own, so every sum keeps its order and all
+five results are the one-head step's bit for bit.  That runs wherever the accumulators and their output
 blocks fit SWEEP_VMEM (``bwd_sweeps``, from the shapes alone; the call
 states its ``vmem_limit_bytes``); a longer sequence takes the
 FlashAttention-2 schedule of two kernels (``flash_bwd_dq`` q-major,
@@ -175,12 +182,46 @@ VMEM_BUDGET = 12 * 2 ** 20   # bytes one step may hold: the double-buffered
                              # operand and statistics blocks of the widest
                              # kernel (flash_bwd_fused) and its live f32 tiles;
                              # under the 16 MiB Mosaic scopes by default
+SWEEP_HEAD_BLOCKS = 8        # head-blocks of a row a several-block step holds
+                             # at most where the queries are NOT grouped:
+                             # each brings k and v of its own, so past the
+                             # step's own cost nothing is shared and the
+                             # gain flattens while every unrolled head adds
+                             # trace and compile seconds.  32 heads of 128,
+                             # S=16,384 on a v5e, forward us a layer (first
+                             # call, with the backward): 1 head-block 18,106
+                             # (0.9 s), 2: 15,965, 4: 14,312 (2.4 s), 8:
+                             # 13,882 (3.7 s), 16: 13,525 (8.2 s); a layer's
+                             # kernels as a remat step runs them (forward
+                             # twice, backward at 2) 65,296 / 64,436 / 63,722
+                             # at 4 / 8 / 16: 8 is the least within 2 % of
+                             # the best
+SWEEP_BWD_HEAD_BLOCKS = 2    # and a step of the one-sweep backward: its call
+                             # asks VMEM for the heads' whole-sequence
+                             # accumulators too (40 MiB at four heads of
+                             # 4,096 positions, 23 at two), and what the
+                             # largest kernel scope takes of the 128 MiB XLA
+                             # no longer has for arrays of its own.  Four a
+                             # step is the kernel's best by 7 % (B=2, S=4,096,
+                             # 16 heads of 128: 2,882 / 2,568 / 2,391 us a
+                             # layer at 1 / 2 / 4), but in Ouro's step one
+                             # bf16[2, 4096, 2048] then left VMEM for HBM
+                             # (+34.6 MB, a fusion 9 ms slower: 5,505 tokens/s
+                             # where two a step read 5,526) while OLMoE's read
+                             # 43,073 against 42,942: a wash end to end, so
+                             # the smaller scope (PERF.md 7 (cx))
 SCOPED_VMEM = 16 * 2 ** 20  # what Mosaic gives a kernel unless told otherwise
-SWEEP_VMEM = 64 * 2 ** 20   # bytes the one-sweep backward of several blocks
-                            # may ask for (``fused_sweep_vmem_bytes``): half
-                            # of a v5e core's 128 MiB.  S = 16,384 at 128
-                            # lanes asks for 40 MiB, 32,768 for 64; past it
-                            # the backward is two sweeps
+SWEEP_VMEM = 64 * 2 ** 20   # bytes a several-block sweep may ask for
+                            # (``fwd_sweep_vmem_bytes``,
+                            # ``fused_sweep_vmem_bytes``): half of a v5e
+                            # core's 128 MiB.  The one-sweep backward at one
+                            # head-block a step: S = 16,384 at 128 lanes
+                            # asks for 40 MiB (24 of accumulators and output
+                            # blocks, Mosaic's 16), 32,768 for 64; past it
+                            # the backward is two sweeps.  Two ungrouped
+                            # head-blocks a step at 16,384 ask for 59 (48
+                            # and a step's 11; Mosaic takes 54.7), two at
+                            # 4,096 for 23 (four would ask 40, eight 74)
 
 
 def _divisors(n):
@@ -223,14 +264,18 @@ def step_geometry(B, S, n_head_blocks, lanes, itemsize):
     return G, Hg
 
 
-def heads_a_step(group, vmem_bytes):
+def heads_a_step(group, vmem_bytes, most=None):
     """The query head-blocks of a group that ride one grid step of a
-    several-block sweep: the most (a divisor of the group) whose
-    ``vmem_bytes(heads)`` fits SWEEP_VMEM, and one where none does (a step
-    of one lives in what its call asks anyway).  From the shapes alone; the
-    group's other heads are further sweeps of the same grid row
-    (``step_table``'s ``group``) or positions of the grid's own axis."""
-    fit = [n for n in _divisors(group) if vmem_bytes(n) <= SWEEP_VMEM]
+    several-block sweep: the most (a divisor of the group, ``most`` or
+    fewer where given) whose ``vmem_bytes(heads)`` fits SWEEP_VMEM, and one
+    where none does (a step of one lives in what its call asks anyway).
+    From the shapes alone; the group's other heads are further sweeps of the
+    same grid row (``step_table``'s ``group``) or positions of the grid's
+    own axis.  Where the queries are not grouped the "group" is the row's
+    head-blocks and ``most`` SWEEP_HEAD_BLOCKS or SWEEP_BWD_HEAD_BLOCKS
+    (``_Geom.heads_in_step``)."""
+    fit = [n for n in _divisors(group)
+           if vmem_bytes(n) <= SWEEP_VMEM and n <= (most or group)]
     return fit[-1] if fit else 1
 
 
@@ -241,17 +286,19 @@ def past_scoped(need, least=SCOPED_VMEM):
 
 
 def fwd_sweep_vmem_bytes(heads, lanes, itemsize, v_lanes=None, halves=1,
-                         bq=512, bk=512):
+                         bq=512, bk=512, kv_heads=1):
     """What a several-block forward asks of VMEM at ``heads`` query
-    head-blocks a step: their q and o blocks and the k and v block twice
-    each, their statistic twice (an output block, a column a head padded to
-    a lane tile), their running max, denominator and accumulator (and the
-    stack of q where ``halves`` heads of a lane block ride stacked), and
-    four [rows, bk] float32 values of a step's own and one more a head
-    (Mosaic keeps about one a head that it interleaves: 6.5 MiB of them at
-    6 heads of 128, 8.3 at 8, 15.8 at 16, by its own count for a v5e)."""
+    head-blocks a step: their q and o blocks and the k and v block
+    (``kv_heads`` head-blocks wide: a group's one, or each head's own where
+    the queries are not grouped) twice each, their statistic twice (an
+    output block, a column a head padded to a lane tile), their running
+    max, denominator and accumulator (and the stack of q where ``halves``
+    heads of a lane block ride stacked), and four [rows, bk] float32 values
+    of a step's own and one more a head (Mosaic keeps about one a head that
+    it interleaves: 6.5 MiB of them at 6 heads of 128, 8.3 at 8, 15.8 at
+    16, by its own count for a v5e)."""
     vw, rows = lanes if v_lanes is None else v_lanes, halves * bq
-    return (2 * (heads * bq + bk) * (lanes + vw) * itemsize
+    return (2 * (heads * bq + kv_heads * bk) * (lanes + vw) * itemsize
             + 2 * heads * bq * LANES * 4
             + heads * rows * (2 * LANES + vw) * 4
             + (halves > 1) * heads * rows * lanes * itemsize
@@ -259,15 +306,18 @@ def fwd_sweep_vmem_bytes(heads, lanes, itemsize, v_lanes=None, halves=1,
 
 
 def fused_sweep_vmem_bytes(Sk, lanes, itemsize, v_lanes=None, heads=1,
-                           halves=1, bq=512, bk=512):
+                           halves=1, bq=512, bk=512, kv_heads=1):
     """What ``flash_bwd_fused`` over several blocks asks of VMEM at a
     key/value length of Sk and head-blocks ``lanes`` wide (the values'
     ``v_lanes``, where they have a width of their own): the two float32
     accumulators that hold dk and dv of the whole sequence, their two output
-    blocks (one buffer each: they leave once a grid row), and what a step
-    holds.  One query head-block a step: Mosaic's own scope, which is what
+    blocks (one buffer each: they leave once a grid row), all four
+    ``kv_heads`` head-blocks wide (a group's one; each head's own where the
+    queries are not grouped, so ``heads`` times the 24 MiB that 16,384
+    positions at 128 lanes ask for), and what a step holds.  One query
+    head-block a step: Mosaic's own scope, which is what
     the two sweeps' steps live in (double-buffered [512, lanes] operand
-    blocks, the [512, 512] tiles).  ``heads`` of a group a step: their
+    blocks, the [512, 512] tiles).  ``heads`` a step: their
     blocks of q, dq and do twice each and dq's float32 scratch, their
     ``lse`` and ``delta`` (a column a head padded to a lane tile) twice, k
     and v twice, the stack's scratch where ``halves`` heads of a lane block
@@ -280,10 +330,10 @@ def fused_sweep_vmem_bytes(Sk, lanes, itemsize, v_lanes=None, heads=1,
     width, rows = lanes + vw, halves * bq
     step = SCOPED_VMEM if heads == 1 else (
         heads * bq * (2 * (lanes + width) * itemsize + 2 * 2 * LANES * 4)
-        + heads * rows * lanes * 4 + 2 * bk * width * itemsize
+        + heads * rows * lanes * 4 + 2 * kv_heads * bk * width * itemsize
         + (halves > 1) * heads * rows * (width * itemsize + 2 * LANES * 4)
         + 6 * rows * bk * 4)
-    return Sk * width * 4 + Sk * width * itemsize + step
+    return kv_heads * Sk * width * (4 + itemsize) + step
 
 
 def bwd_sweeps(Sk, bk, lanes, itemsize, group=1, v_lanes=None):
@@ -303,8 +353,8 @@ def grid_geometry(B, S, Sk, n_head_blocks, lanes, itemsize, bq, bk, group=1):
     bq x bk: ``step_geometry`` where the sequence is one block both ways;
     otherwise (and wherever ``group`` query heads share a key/value head)
     the blocks are of one row and the several-block sweeps' steps hold
-    ``heads_a_step`` head-blocks of a group (``_Geom.heads_in_step``), one
-    where the queries are not grouped."""
+    ``heads_a_step`` head-blocks of a group or, where the queries are not
+    grouped, of the row (``_Geom.heads_in_step``)."""
     G, Hg = (step_geometry(B, max(S, Sk), n_head_blocks, lanes, itemsize)
              if S == bq and Sk == bk and group == 1 else (1, 1))
     return G, Hg, (B // G) * (n_head_blocks // Hg)
@@ -370,7 +420,8 @@ def packed_grid(B, S, n_heads, head_dim, block_q, block_k, itemsize=2,
     (pairs per grid step, grid steps of one layer's forward pass; ``part``
     "bwd": of its backward, where that is one kernel).  Several blocks: a
     step is a tile of ``step_table`` for a (row, key/value head-block) pair
-    and the ``heads_a_step`` query head-blocks of its group that ride it."""
+    and the ``heads_a_step`` query head-blocks of its group that ride it
+    (ungrouped: for that many head-blocks of the row)."""
     bq, bk = min(block_q, S), min(block_k, S)
     # shapes and an element size are all the geometry reads of q and k
     g = _Geom(*(jax.ShapeDtypeStruct((B, S, n * head_dim),
@@ -389,9 +440,10 @@ class _Geom:
     addressed by the BlockSpec index maps, so the model never materializes a
     [B, H, S, D] transpose (the r2 wrapper's main HBM cost).  A block is G
     rows of the leading axis by Hg head-blocks (``grid_geometry``; 1 by 1
-    wherever the sequence is more than one block, where a grouped step's q,
-    o and statistics blocks are ``heads_in_step`` head-blocks of a group
-    wide: ``q_spec(.., heads)``).
+    wherever the sequence is more than one block, where a step's q, o and
+    statistics blocks are ``heads_in_step`` head-blocks wide, of a group or,
+    ungrouped, of the row, and then its k and v blocks too:
+    ``q_spec(.., heads)``, ``kv_spec(.., kv_heads(heads))``).
 
     ``Hkv`` < H: grouped queries, k and v hold Hkv heads and q head h reads
     kv head ``h // group``.  ``window`` (None: none) and the causal mask
@@ -445,17 +497,40 @@ class _Geom:
         self.stat_shape = (B, self.Hb // self.Hg, self.S, self.Hg * self.hpb)
 
     def heads_in_step(self, part):
-        """(query head-blocks of a group a grid step holds, the bytes of
-        VMEM such a step's call needs) of the several-block forward
-        (``part`` "fwd") or one-sweep backward ("bwd"): ``heads_a_step``
-        of the shapes."""
+        """(query head-blocks a grid step holds, the bytes of VMEM such a
+        step's call needs) of the several-block forward (``part`` "fwd") or
+        one-sweep backward ("bwd"): ``heads_a_step`` of the shapes, over a
+        group's head-blocks or, where the queries are not grouped, over the
+        row's (adjacent ones, each with k and v of its own:
+        SWEEP_HEAD_BLOCKS at most, SWEEP_BWD_HEAD_BLOCKS in the backward,
+        and a lane block of one head)."""
         sizes = dict(itemsize=self.itemsize, v_lanes=self.vw,
                      halves=self.halves, bq=self.bq, bk=self.bk)
-        need = functools.partial(fwd_sweep_vmem_bytes, lanes=self.qw, **sizes)\
+        count = functools.partial(fwd_sweep_vmem_bytes, lanes=self.qw, **sizes)\
             if part == "fwd" else functools.partial(
                 fused_sweep_vmem_bytes, self.Sk, self.qw, **sizes)
-        heads = heads_a_step(self.group, lambda n: need(heads=n))
-        return heads, need(heads=heads)
+
+        def need(n):
+            return count(heads=n, kv_heads=self.kv_heads(n))
+
+        if self.group > 1:
+            heads = heads_a_step(self.group, need)
+        else:
+            heads = heads_a_step(
+                self.Hb if self.hpb == 1 else 1, need,
+                SWEEP_HEAD_BLOCKS if part == "fwd" else SWEEP_BWD_HEAD_BLOCKS)
+        return heads, need(heads)
+
+    def kv_heads(self, heads):
+        """The key/value head-blocks a several-block step of ``heads`` query
+        head-blocks holds: their group's one, or each head's own."""
+        return 1 if self.group > 1 else heads
+
+    def chunks(self, heads):
+        """The chunks of ``heads`` query head-blocks a key/value block's
+        group makes; one where the queries are not grouped (the step's
+        ``heads`` key/value head-blocks are the grid row's)."""
+        return max(self.group // heads, 1)
 
     def kv_half(self, q_block):
         """Which head of its key/value lane block a query head-block reads;
@@ -487,14 +562,16 @@ class _Geom:
         block of the q map, ``heads`` head-blocks wide; ``heads`` rows of
         the statistics').  Its grid is (batch row, key/value head-block,
         chunk of ``heads`` of that one's group, step t of its
-        ``step_table``), or without the third axis where the table walks
+        ``step_table``; ungrouped, the key/value blocks are ``heads``
+        head-blocks wide too, the second axis counts those and the third is
+        1), or without the third axis where the table walks
         the chunks (``head_of``: the dk/dv sweep and the fused backward,
         which sum over the group); the table's columns arrive as
         scalar-prefetch operands.  No map divides: on the chip a
         (row, head-block) pair unpacked from one grid index by ``//`` and
         ``%`` cost each of the sweep's steps 30 to 60 ns (PERF.md section 6,
         PR 34)."""
-        chunks = self.group // heads
+        chunks = self.chunks(heads)
 
         def at(pick):
             if walks_group:
@@ -511,7 +588,7 @@ class _Geom:
         """(t, chunk) of a sweep's grid position: the step's query
         head-blocks are ``chunk * heads`` and the ``heads - 1`` behind it;
         ``head_of``: of a sweep whose table walks the chunks."""
-        chunks = self.group // heads
+        chunks = self.chunks(heads)
         if head_of is None:
             return pl.program_id(3), \
                 pl.program_id(1) * chunks + pl.program_id(2)
@@ -524,8 +601,10 @@ class _Geom:
         return pl.BlockSpec((self.G, bq, heads * self.Hg * self.qw),
                             index_map or self.qmap())
 
-    def kv_spec(self, bk, index_map=None):
-        return pl.BlockSpec((self.G, bk, self.Hg * self.qw),
+    def kv_spec(self, bk, index_map=None, heads=1):
+        """``heads``: the key/value head-blocks of a several-block sweep's
+        step (``kv_heads``), adjacent lane blocks of the packed array."""
+        return pl.BlockSpec((self.G, bk, heads * self.Hg * self.qw),
                             index_map or self.kmap())
 
     def o_spec(self, bq, index_map=None, heads=1):
@@ -533,9 +612,9 @@ class _Geom:
         return pl.BlockSpec((self.G, bq, heads * self.Hg * self.vw),
                             index_map or self.qmap())
 
-    def v_spec(self, bk, index_map=None):
+    def v_spec(self, bk, index_map=None, heads=1):
         """Of v and dv: k's rows at the values' width."""
-        return pl.BlockSpec((self.G, bk, self.Hg * self.vw),
+        return pl.BlockSpec((self.G, bk, heads * self.Hg * self.vw),
                             index_map or self.kmap())
 
     def stat_spec(self, bq, index_map=None, heads=1):
@@ -642,13 +721,16 @@ def _bwd_tiles(tile, stk, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     over its rows of the stack ``stk`` (q, do, lse, delta: ``_stack_sweep``'s
     scratch or ``_stacked``'s values) against the whole k and v lane blocks,
     or, with no stack, a head at a time on its own columns ``cs`` of k (of v:
-    ``vs``) and ``qs`` of the step's q block (where its dq lies).  ``last``:
+    ``vs``; of k and v blocks as wide as the q block, which hold those of
+    each of the step's head-blocks and not one group's, the head-block's
+    own) and ``qs`` of the step's q block (where its dq lies).  ``last``:
     no further head of the step lands in these columns of dk and dv.  lse
     and delta go over as thunks, so ``tile`` reads them where it uses
     them."""
-    D, Dv = k_ref.shape[-1] // hpb, v_ref.shape[-1] // hpb
+    D, Dv = q_ref.shape[-1] // (heads * hpb), do_ref.shape[-1] // (heads * hpb)
+    own = k_ref.shape[-1] > hpb * D     # k and v columns of a head's own
     for h in range(heads):
-        last = h == heads - 1
+        last = own or h == heads - 1
         if stk:
             (q, do, lse, delta), rows = stk, _cols(h, hpb * bq)
             tile(q[rows], k_ref[0], v_ref[0], do[rows],
@@ -657,10 +739,13 @@ def _bwd_tiles(tile, stk, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  _cols(h, hpb * D), last, wrap=bq)
             continue
         for hh in range(hpb):
-            cs, vs, n = _cols(hh, D), _cols(hh, Dv), h * hpb + hh
+            n = h * hpb + hh
+            kv = n if own else hh
+            cs, vs = _cols(kv, D), _cols(kv, Dv)
             # of the wide blocks, the head-block's lanes alone are read
-            tile(q_ref[0, :, _cols(h, hpb * D)][:, cs], k_ref[0][:, cs],
-                 v_ref[0][:, vs], do_ref[0, :, _cols(h, hpb * Dv)][:, vs],
+            tile(q_ref[0, :, _cols(h, hpb * D)][:, _cols(hh, D)],
+                 k_ref[0][:, cs], v_ref[0][:, vs],
+                 do_ref[0, :, _cols(h, hpb * Dv)][:, _cols(hh, Dv)],
                  lambda: lse_ref[0, h][:, hh:hh + 1],
                  lambda: delta_ref[0, h][:, hh:hh + 1], cs, vs, _cols(n, D),
                  last)
@@ -748,9 +833,11 @@ def _fwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, o_ref,
                       bq, bk, hpb, heads, geom):
     """Several kv blocks.  A grid row is a (batch row, key/value head-block)
     pair and a step one (q block, kv block) tile of ``step_table`` with
-    ``heads`` query head-blocks of that key/value block's group looped
-    inside (``heads_a_step``; 1 where the queries are not grouped): the k
-    and v block arrive once a step for all of them, the mask of the tile is
+    ``heads`` query head-blocks looped inside (``heads_a_step``): of that
+    key/value block's group, which share the step's k and v block, or,
+    where the queries are not grouped, adjacent head-blocks of the row, each
+    on its own columns of k and v blocks ``heads`` head-blocks wide.  The
+    mask of the tile is
     built once and shared, and the heads' chains (product, max, ``exp``,
     product) stand side by side for the compiler to interleave.  Running
     max, denominator and accumulator live in scratch across a q block's
@@ -763,8 +850,9 @@ def _fwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, o_ref,
     [hpb * bq, bk] tile against the whole k and v lane blocks, the
     statistics a row of the stack each, and the last step puts the heads
     side by side again."""
-    D, Dv = k_ref.shape[-1] // hpb, v_ref.shape[-1] // hpb
+    D, Dv = q_ref.shape[-1] // (heads * hpb), o_ref.shape[-1] // (heads * hpb)
     qw, vw, rows = hpb * D, hpb * Dv, m_scr.shape[0]
+    own = k_ref.shape[-1] > qw          # k and v columns of a head's own
     t, chunk = geom.step(heads)
     half = [geom.kv_half(chunk * heads + h) for h in range(heads)] \
         if q_stk else None
@@ -808,8 +896,10 @@ def _fwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, o_ref,
             continue
         qb = q_ref[0, :, _cols(h, qw)]      # the head-block's lanes alone
         for hh in range(hpb):
-            tile(qb[:, _cols(hh, D)], _cols(hh, D), _cols(hh, Dv),
-                 _cols(h * hpb + hh, LANES), _cols(h * hpb + hh, Dv))
+            n = h * hpb + hh
+            kv = n if own else hh
+            tile(qb[:, _cols(hh, D)], _cols(kv, D), _cols(kv, Dv),
+                 _cols(n, LANES), _cols(n, Dv))
 
     @pl.when((flags[t] & LAST) != 0)
     def _final():
@@ -841,22 +931,24 @@ def _name(kernel, g):
 
 
 def _sweep_call(kernel, g, table, operands, in_specs, out_specs, out_shape,
-                scratch_shapes, chunks, interpret, name, **params):
+                scratch_shapes, heads, walks_group, interpret, name, **params):
     """One several-block sweep over the steps of ``table`` (its columns
-    scalar-prefetched), for the grid positions ``_Geom.sweep_maps`` names
-    (``chunks`` of a group's query head-blocks as a grid axis; None: the
-    table walks them); ``params``: further compiler parameters."""
-    heads = (g.Hb // g.group,) + (() if chunks is None else (chunks,))
+    scalar-prefetched), for the grid positions ``_Geom.sweep_maps`` names at
+    ``heads`` query head-blocks a step (the key/value head-blocks a step at
+    a time, and the chunks of a group's query head-blocks as a grid axis
+    unless the table walks them); ``params``: further compiler parameters."""
+    rows = (g.Hb // g.group // g.kv_heads(heads),) + (
+        () if walks_group else (g.chunks(heads),))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(table),
-            grid=(operands[0].shape[0],) + heads + (table.shape[1],),
+            grid=(operands[0].shape[0],) + rows + (table.shape[1],),
             in_specs=in_specs, out_specs=out_specs,
             scratch_shapes=scratch_shapes),
         out_shape=out_shape,
         compiler_params=_CompilerParams(dimension_semantics=(
-            "parallel",) * (1 + len(heads)) + ("arbitrary",), **params),
+            "parallel",) * (1 + len(rows)) + ("arbitrary",), **params),
         interpret=interpret,
         name=_name(name, g),
     )(*(jnp.asarray(column) for column in table), *operands)
@@ -877,19 +969,21 @@ def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None, Hkv=None,
         _count_call("flash_sweep", part="fwd", group=g.group,
                     heads_in_step=heads)
         qm, km, sm = g.sweep_maps(heads)
+        kvh = g.kv_heads(heads)
         # statistics: a head a group of lanes, or a row of the stack
         rows, lanes = g.halves * bq, g.hpb // g.halves * LANES
         return _sweep_call(
             functools.partial(_fwd_sweep_kernel, scale=scale, causal=causal,
                               bq=bq, bk=bk, hpb=g.hpb, heads=heads, geom=g),
             g, step_table(g.S, g.Sk, bq, bk, causal, window), (q, k, v),
-            [g.q_spec(bq, qm, heads), g.kv_spec(bk, km), g.v_spec(bk, km)],
+            [g.q_spec(bq, qm, heads), g.kv_spec(bk, km, kvh),
+             g.v_spec(bk, km, kvh)],
             [g.o_spec(bq, qm, heads), g.stat_spec(bq, sm, heads)], out_shape,
             [pltpu.VMEM((rows, heads * lanes), jnp.float32),
              pltpu.VMEM((rows, heads * lanes), jnp.float32),
              pltpu.VMEM((rows, heads * g.vw), jnp.float32)]
             + [pltpu.VMEM((heads * rows, g.qw), q.dtype)] * (g.halves > 1),
-            g.group // heads, interpret, "fwd", **past_scoped(need))
+            heads, False, interpret, "fwd", **past_scoped(need))
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                bq=bq, hpb=g.hpb, G=g.G, Hg=g.Hg, geom=g)
     o, lse = pl.pallas_call(
@@ -1124,7 +1218,11 @@ def _bwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
     step's heads and added ONCE a step into rows ``kv block`` of two float32
     accumulators that hold the whole sequence and are the key/value head's
     own, so they sum over the group; both leave once, at the grid row's last
-    step.  The mask of the tile is built once a step and the heads' chains
+    step.  Where the queries are not grouped the step's heads are adjacent
+    head-blocks of the row with k, v, dk and dv of their own: blocks and
+    accumulators ``heads`` head-blocks wide, a head's dk and dv added into
+    its own columns and summed with no other's.  The mask of the tile is
+    built once a step and the heads' chains
     stand side by side for the compiler to interleave.
 
     Where the heads of the lane block read one key/value head
@@ -1135,10 +1233,9 @@ def _bwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
     accumulators' full width (``_stack_heads``: zeros beside the half), dq
     unstacked at the last."""
     t, chunk = geom.step(heads, head_of)
-    D = k_ref.shape[-1] // hpb
+    D = q_ref.shape[-1] // (heads * hpb)
     half = [geom.kv_half(chunk * heads + h) for h in range(heads)] \
         if stk else None
-    assert heads == 1 or stk or hpb == 1    # one column range of dk a step
 
     def rows_of(kv_block):
         return pl.ds(pl.multiple_of(kv_block * bk, bk), bk)
@@ -1270,18 +1367,18 @@ def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
     def sweep(kernel, name, out_specs, out_shape, scratch_shapes,
               walks_group=False, kv_major=False, heads=1, **params):
         qm, km, sm = g.sweep_maps(heads, walks_group)
-        qs, ks = g.q_spec(bq, qm, heads), g.kv_spec(bk, km)
-        os, vs = g.o_spec(bq, qm, heads), g.v_spec(bk, km)
+        kvh = g.kv_heads(heads)
+        qs, ks = g.q_spec(bq, qm, heads), g.kv_spec(bk, km, kvh)
+        os, vs = g.o_spec(bq, qm, heads), g.v_spec(bk, km, kvh)
         stats = g.stat_spec(bq, sm, heads)
-        chunks = g.group // heads
         return _sweep_call(
             functools.partial(kernel, scale=scale, causal=causal, bq=bq,
                               bk=bk, hpb=g.hpb, geom=g),
             g, step_table(g.S, g.Sk, bq, bk, causal, window,
-                          chunks if walks_group else 1, kv_major),
+                          g.chunks(heads) if walks_group else 1, kv_major),
             (q, k, v, do, lse, delta), [qs, ks, vs, os, stats, stats],
             out_specs(qs, ks, vs), out_shape, scratch_shapes,
-            None if walks_group else chunks, interpret, name, **params)
+            heads, walks_group, interpret, name, **params)
 
     if g.bwd_sweeps == 1:
         # dk and dv of the whole sequence: one block a grid row, so one
@@ -1295,13 +1392,16 @@ def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
         heads, need = g.heads_in_step("bwd")
         _count_call("flash_sweep", part="bwd", group=g.group,
                     heads_in_step=heads)
+        # dk and dv: the key/value head's own, so a group's one head-block
+        # wide and, ungrouped, a head-block of each of the step's heads
+        kw, vw = g.kv_heads(heads) * g.qw, g.kv_heads(heads) * g.vw
         return sweep(
             functools.partial(_bwd_sweep_kernel, heads=heads), "bwd_fused",
-            lambda qs, ks, vs: [qs, whole(g.qw), whole(g.vw)],
+            lambda qs, ks, vs: [qs, whole(kw), whole(vw)],
             [dq_shape] + dkv_shapes,
             [pltpu.VMEM((stack, heads * g.qw), jnp.float32),
-             pltpu.VMEM((g.Sk, g.qw), jnp.float32),
-             pltpu.VMEM((g.Sk, g.vw), jnp.float32)] + stacked(heads),
+             pltpu.VMEM((g.Sk, kw), jnp.float32),
+             pltpu.VMEM((g.Sk, vw), jnp.float32)] + stacked(heads),
             walks_group=True, heads=heads, vmem_limit_bytes=need)
     dq = sweep(_bwd_dq_kernel, "bwd_dq", lambda qs, ks, vs: qs, dq_shape,
                [pltpu.VMEM((stack, g.qw), jnp.float32)] + stacked())

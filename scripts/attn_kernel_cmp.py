@@ -2,9 +2,9 @@
 
     chiprun -- python3 scripts/attn_kernel_cmp.py --batch 256 --seq 128 \
         [--heads 12 --head-dim 64 --block 512 --causal] \
-        [--kv-heads 4 --window 4096] [--tree _checkout/parent] \
-        [--two-sweeps] [--force 1x1,4x1,1x6] [--heads-a-step 1,2]
-        [--others]
+        [--kv-heads 4 --window 4096] [--v-head-dim 128] \
+        [--tree _checkout/parent] [--two-sweeps] [--force 1x1,4x1,1x6] \
+        [--heads-a-step 1,2] [--others]
 
 Times ``flash_attention_packed`` (the entry the models call), forward and
 backward, by DEVICE time per kernel name read from a profiler trace, as the
@@ -23,12 +23,20 @@ as a sequence past the rule does.
 forced to G batch rows by Hg head-blocks (``step_geometry`` replaced for
 that compile: an experiment of this script, not an option of the program)
 and holds every output to the unforced one.  ``--heads-a-step n,...`` does
-the same for the query head-blocks of a group that ride one grid step of
-the several-block sweeps (``heads_a_step`` replaced: 1 is the step before
-PR 68; dk and dv sum the group in another order, so their last bits may
-differ from the rule's).  A grouped call's line says what a step holds
-(heads a step, forward / backward), its grid steps a layer and pass, and
-the microseconds a (tile, head) forward and backward.  ``--others`` adds,
+the same for the query head-blocks that ride one grid step of the
+several-block sweeps (``heads_a_step`` replaced: the most of n or fewer
+that divide the group, or, where the queries are not grouped, the row's
+head-blocks, each with k and v of its own since PR 70, and whose step fits
+``SWEEP_VMEM`` by the kernels' own count, so a backward whose accumulators
+do not fit n heads takes fewer; 1 is the step before PR 68; grouped, dk
+and dv sum the group in another order, so their last bits may differ from
+the rule's; ungrouped, every output is the one-head step's bit for bit).
+A several-block call's line says what a step holds (heads a step, forward
+/ backward), its grid steps a layer and pass, and the microseconds a
+(tile, head) forward and backward.  ``--v-head-dim`` gives the values a
+width of their own (Kimi-Linear's latent layer: ``--head-dim 256
+--v-head-dim 128``; the least time is then of heads as wide as the mean of
+the two).  ``--others`` adds,
 by host clock, the [B, S, H, D] entry, JAX's own TPU flash kernel and plain
 XLA softmax attention.  Needs a TPU.
 """
@@ -87,8 +95,9 @@ def xla_attention(q, k, v, H, causal, window=None):
 
     B, S, E = q.shape
     q4 = q.reshape(B, S, H, E // H)
-    k4, v4 = (jnp.repeat(t.reshape(B, S, -1, E // H),
-                         E // t.shape[-1], axis=2) for t in (k, v))
+    Hkv = k.shape[-1] // (E // H)       # v's heads may have a width of their own
+    k4, v4 = (jnp.repeat(t.reshape(B, S, Hkv, -1), H // Hkv, axis=2)
+              for t in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q4, k4,
                    preferred_element_type=jnp.float32) * (E // H) ** -0.5
     if causal:
@@ -99,7 +108,7 @@ def xla_attention(q, k, v, H, causal, window=None):
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v4,
                       preferred_element_type=jnp.float32
-                      ).astype(v.dtype).reshape(B, S, E)
+                      ).astype(v.dtype).reshape(B, S, -1)
 
 
 def main(argv=None):
@@ -112,6 +121,7 @@ def main(argv=None):
     ap.add_argument("--causal", action="store_true")
     ap.add_argument("--kv-heads", type=int, default=0)
     ap.add_argument("--window", type=int, default=0)
+    ap.add_argument("--v-head-dim", type=int, default=0)
     ap.add_argument("--tree", default=ROOT)
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--two-sweeps", action="store_true")
@@ -140,13 +150,16 @@ def main(argv=None):
 
     B, S, H, D = args.batch, args.seq, args.heads, args.head_dim
     Hkv, window = args.kv_heads or H, args.window or None
+    Dv = args.v_head_dim or D
     sparse = Hkv != H or window is not None      # the sparse decoders' modes
     key = jax.random.PRNGKey(0)
     q, k, v, do = (jax.random.normal(jax.random.fold_in(key, n),
-                                     (B, S, h * D), jnp.bfloat16)
-                   for n, h in enumerate((H, Hkv, Hkv, H)))
+                                     (B, S, h * d), jnp.bfloat16)
+                   for n, (h, d) in enumerate(((H, D), (Hkv, D), (Hkv, Dv),
+                                               (H, Dv))))
     need = (flash_attention_gqa.required(B, S, H, Hkv, D, window) if sparse
-            else need_of.required(B, S, H * D, causal=args.causal))
+            else need_of.required(B, S, H * (D + Dv) // 2,
+                                  causal=args.causal))
     peak = peaks.peaks_for(jax.devices()[0].device_kind)
     least = {p: flops.least_seconds(need[p]["flops"], need[p]["bytes"], peak)
              for p in ("fwd", "bwd")}
@@ -156,6 +169,8 @@ def main(argv=None):
              args.causal, window, least["fwd"][0] * 1e6, least["fwd"][1],
              least["bwd"][0] * 1e6, least["bwd"][1]))
     more = dict(n_kv_heads=Hkv, window=window) if sparse else {}
+    if Dv != D:
+        more["v_head_dim"] = Dv
 
     def both():
         def f(q, k, v, do):
@@ -189,7 +204,9 @@ def main(argv=None):
             pairs, steps = 1, -1
         else:
             if isinstance(geom, int):       # heads a step
-                fa.heads_a_step = lambda group, need, n=geom: n
+                fa.heads_a_step = lambda group, need, most=None, n=geom: max(
+                    d for d in range(1, min(n, group) + 1) if group % d == 0
+                    and (d == 1 or need(d) <= fa.SWEEP_VMEM))
             else:
                 fa.step_geometry = rule if geom is None else (
                     lambda *a, g=geom: g)

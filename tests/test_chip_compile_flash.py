@@ -25,10 +25,6 @@ fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
     ("bert_base.s128_scan", "packed", 256, 128, 12, 64, False, 6),
     ("bert_base.s512_scan", "packed", 64, 512, 12, 64, False, 1),
     ("fine-tuning at 384", "packed", 32, 384, 12, 64, False, 2),
-    ("olmoe_1b_7b.s4096_scan", "packed", 4, 4096, 16, 128, True, 1),
-    # the same heads and length at the looped stack's batch: 48 layer
-    # applications a step call these
-    ("ouro_2_6b.s4096_scan", "packed", 2, 4096, 16, 128, True, 1),
     ("a prime batch, causal", "packed", 7, 128, 12, 64, True, 21),
     ("heads the packed layout cannot tile", "bshd", 32, 128, 3, 64, False, 4),
 ])
@@ -51,20 +47,69 @@ def test_forward_and_backward_compile_for_a_v5e(one_chip, what, entry, B, S,
 
     text, grids, mosaic = _compiled(attn, x, x, x, x)
     assert text.count("tpu_custom_call") >= 2, what
-    if S > 512:     # several blocks: the sweeps' step tables are the grids,
-        # and the backward ONE sweep, dk and dv of all 4,096 positions in
-        # VMEM (22 MiB asked of Mosaic)
-        heads, steps = H * D // 128, fa.kv_blocks(S, 512, 512, causal)
-        assert grids == {"flash_fwd": (B, heads, 1, steps),
-                         "flash_bwd_fused": (B, heads, steps)} and steps == 36, what
-        asked, took = _vmem(text, "flash_bwd_fused")
-        assert asked == fa.fused_sweep_vmem_bytes(S, 128, 2) == 22 * 2 ** 20
-        assert 6 * 2 ** 20 < took < asked, what
+    # one block: the kernels PR 28 left, to the letter (the several-block
+    # shapes: ``test_the_ungrouped_sweeps_compile_for_a_v5e``)
+    assert set(grids) == {"flash_fwd", "flash_bwd_fused"}, what
+    assert _vmem(text, "flash_bwd_fused")[0] is None, what
+    assert mosaic == ONE_BLOCK_MOSAIC[what], what
+
+
+DOTS3_SLIDING = (1, 8192, 16, 256, 128, 513)    # the share's 16 of 64 heads
+
+
+@pytest.mark.parametrize("what,shape,steps,fwd,bwd,mib", [
+    # four rows of sixteen heads of 128: eight head-blocks of a row a
+    # forward step, two a backward one (dk and dv of 4,096 positions: 6 MiB
+    # a head; four would fit and ask 40 MiB: ``SWEEP_BWD_HEAD_BLOCKS``)
+    ("olmoe_1b_7b.s4096_scan", (4, 4096, 16, 128, 128, None), 36, 8, 2,
+     (30, 23)),
+    # the same heads and length at the looped stack's batch: 48 layer
+    # applications a step call these
+    ("ouro_2_6b.s4096_scan", (2, 4096, 16, 128, 128, None), 36, 8, 2,
+     (30, 23)),
+    # 24 MiB of accumulators and output blocks a head at 16,384 positions:
+    # two heads a backward step, 54.7 MiB by Mosaic's count of the 59 asked
+    ("mistral_small_4_119b.s16384_scan, the latent expanded to 32 heads",
+     MISTRAL4[:3] + (128, 128, None), 528, 8, 2, (30, 59)),
+    # 16 heads of 256 / 128 under a window of 513: the band's 31 tiles
+    ("dots3_note_prev.s8192_scan, a sliding layer", DOTS3_SLIDING, 31, 8, 2,
+     (34, 49)),
+])
+def test_the_ungrouped_sweeps_compile_for_a_v5e(one_chip, what, shape, steps,
+                                                fwd, bwd, mib):
+    """Every head its own key/value head over several blocks (PR 70): a grid
+    step holds ``fwd`` (``bwd``) ADJACENT head-blocks of the batch row, each
+    with k and v columns of its own in blocks that many head-blocks wide,
+    unrolled in the body; the backward's two whole-sequence accumulators and
+    their output blocks are ``bwd`` head-blocks wide.  The grids are the
+    tables over (row, the row's head-blocks a step's worth at a time); the
+    calls ask what ``_Geom.heads_in_step`` counts and Mosaic takes less."""
+    B, S, H, D, Dv, window = shape
+    xq = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
+    xv = jax.ShapeDtypeStruct((B, S, H * Dv), jnp.bfloat16, sharding=one_chip)
+    more = dict(v_head_dim=Dv, scale=192 ** -0.5) if Dv != D else {}
+    attn = lambda q, k, v: fa.flash_attention_packed(
+        q, k, v, H, causal=True, block_q=512, block_k=512, interpret=False,
+        window=window, **more)
+    names = ("flash_swa_fwd", "flash_swa_bwd_fused") if window else (
+        "flash_fwd", "flash_bwd_fused")
+    text, grids, mosaic = _compiled(attn, xq, xq, xv, xv)
+    assert fa.kv_blocks(S, 512, 512, True, window) == steps
+    assert grids == dict(zip(names, [(B, H // fwd, 1, steps),
+                                     (B, H // bwd, steps)])), what
+    assert fa.packed_grid(B, S, H, D, 512, 512, causal=True, window=window,
+                          part="bwd") == (bwd, B * H // bwd * steps)
+    if what in SWEEP_MOSAIC:
         assert mosaic == SWEEP_MOSAIC[what], what
-    else:           # one block: the kernels PR 28 left, to the letter
-        assert set(grids) == {"flash_fwd", "flash_bwd_fused"}, what
-        assert _vmem(text, "flash_bwd_fused")[0] is None, what
-        assert mosaic == ONE_BLOCK_MOSAIC[what], what
+    g = fa._Geom(xq, xq, H, 512, 512, window=window, Dv=Dv)
+    (asked_f, took_f), (asked, took) = (_vmem(text, n) for n in names)
+    assert (fwd, asked_f) == g.heads_in_step("fwd")
+    assert (bwd, asked) == g.heads_in_step("bwd")
+    assert (asked_f, asked) == tuple(int(m * 2 ** 20) for m in mib), what
+    # the accumulators and the single-buffered output blocks of the step's
+    # heads, and a step's own blocks and tiles beside them
+    assert bwd * S * (D + Dv) * (4 + 2) < took < asked, what
+    assert 6 * 2 ** 20 < took_f < asked_f, what
 
 
 # The one-block kernels' Mosaic modules as the several-block backward's
@@ -82,22 +127,25 @@ ONE_BLOCK_MOSAIC = {
 
 
 # The several-block kernels at a head a lane block (width 128), forward and
-# backward, as PR 41's parent (c1b744a) lowers them: stacking the heads of a
-# 64-wide lane block (LFM2's row, not pinned) left every other cell's module
-# as it was.  Replaced like ``ONE_BLOCK_MOSAIC``.  PR 68 took SmallThinker's
+# backward.  Replaced like ``ONE_BLOCK_MOSAIC``.  PR 68 took SmallThinker's
 # two entries anew ON PURPOSE (a group's seven query heads ride one grid
 # step; they were 55126c1bd654 / 170f86a03873 full and 985173cce04a /
-# 42134345da2e windowed): the three UNGROUPED entries are c1b744a's still,
-# one head-block a step lowers to the text it did.
+# 42134345da2e windowed).  PR 70 took the three UNGROUPED entries anew ON
+# PURPOSE (eight adjacent head-blocks of the row ride a forward step, two a
+# backward one, each with k and v of its own; as PR 41's parent c1b744a lowered them, one head-block a step,
+# they were 05626943d473 / eba4a626459f (OLMoE), 8aec72d32a18 /
+# afa8aab872da (Ouro) and e267681a781d / ad2a365dd0c8 (Mistral), and a call
+# whose rule answers 1 lowers to those still): the GROUPED entries are
+# PR 68's, byte for byte.
 SWEEP_MOSAIC = {
-    "olmoe_1b_7b.s4096_scan": ["05626943d473", "eba4a626459f"],
-    "ouro_2_6b.s4096_scan": ["8aec72d32a18", "afa8aab872da"],
+    "olmoe_1b_7b.s4096_scan": ["b712f2862fca", "82bfd1492339"],
+    "ouro_2_6b.s4096_scan": ["74c52422a398", "fd28d46cc1f8"],
     "smallthinker_21b_a3b.s16384_scan, a full layer":
         ["465f38b6b128", "1d46a9c9d9f7"],
     "smallthinker_21b_a3b.s16384_scan, a windowed layer":
         ["e3a26b4fe57d", "987d580b7182"],
     "mistral_small_4_119b.s16384_scan, the latent expanded to 32 heads":
-        ["e267681a781d", "ad2a365dd0c8"],
+        ["03bb65ba345d", "eaa2a244e9a1"],
 }
 
 
@@ -108,8 +156,6 @@ SWEEP_MOSAIC = {
      ("flash_swa_fwd", "flash_swa_bwd_fused"), 252, 7, (23.75, 44.5)),
     ("lfm2_8b_a1b.s8192_scan, two heads a lane block", LFM2, None,
      ("flash_fwd", "flash_bwd_fused"), 136, 4, (27.5, 39.5)),
-    ("mistral_small_4_119b.s16384_scan, the latent expanded to 32 heads",
-     MISTRAL4, None, ("flash_fwd", "flash_bwd_fused"), 528, 1, (16, 40)),
     ("trinity_large_preview.s6144_scan, a full layer", TRINITY, None,
      ("flash_fwd", "flash_bwd_fused"), 78, 6, (21, 27.5)),
     ("trinity_large_preview.s6144_scan, a windowed layer", TRINITY, 4096,
@@ -135,8 +181,7 @@ def test_grouped_and_windowed_kernels_compile_for_a_v5e(
     sixteen, Jamba's twenty, LFM2's four stacked blocks), unrolled in the
     body; the calls ask the VMEM ``_Geom.heads_in_step`` counts (the
     forward too, past Mosaic's own 16 MiB) and Mosaic takes less.  The
-    ungrouped modules are the parent's (``SWEEP_MOSAIC``) and ask what they
-    asked.  The grids are the tables: (row, key/value head-block, chunk of
+    grids are the tables: (row, key/value head-block, chunk of
     ``heads`` of its group) by the blocks under the diagonal (in the band),
     the backward's (row, key/value head-block) by the chunks' times as
     many."""
@@ -186,7 +231,9 @@ def test_the_value_width_kernels_compile_for_a_v5e(one_chip):
     and a value's one; the backward is ONE sweep, dk of the whole sequence
     in a [16384, 256] float32 accumulator and dv in a [16384, 128] one (24
     MiB of the 52 the call asks for, where one width of 256 would ask for
-    64); the grids are a one-width call's."""
+    64), so a backward step has room for ONE head (36 MiB of accumulators
+    and output blocks each); the forward holds no sequence and takes eight
+    head-blocks of the row a step (PR 70: 34 MiB asked)."""
     B, S, H, D, Dv = 1, 16384, 32, 256, 128
     xq = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
     xv = jax.ShapeDtypeStruct((B, S, H * Dv), jnp.bfloat16, sharding=one_chip)
@@ -196,8 +243,11 @@ def test_the_value_width_kernels_compile_for_a_v5e(one_chip):
     assert jax.eval_shape(attn, xq, xq, xv).shape == xv.shape
     text, grids, _ = _compiled(attn, xq, xq, xv, xv)
     steps = fa.kv_blocks(S, 512, 512, True)
-    assert grids == {"flash_fwd": (B, H, 1, steps),
+    assert grids == {"flash_fwd": (B, H // 8, 1, steps),
                      "flash_bwd_fused": (B, H, steps)}
+    asked_f, took_f = _vmem(text, "flash_fwd")
+    assert asked_f == fa.fwd_sweep_vmem_bytes(
+        8, D, 2, Dv, kv_heads=8) == 34 * 2 ** 20 and took_f < asked_f
     asked, took = _vmem(text, "flash_bwd_fused")
     assert asked == fa.fused_sweep_vmem_bytes(S, D, 2, Dv) == 52 * 2 ** 20
     assert fa.fused_sweep_vmem_bytes(S, D, 2) == 64 * 2 ** 20
@@ -284,7 +334,8 @@ def test_attn_outside_hlo_smoke(one_chip, capsys, cell, kind, kernels):
 # PUBLISHED configuration counts in ``monitor.kernels.flash_sweep_calls``,
 # (part, group, heads in a step): SmallThinker's full and windowed layer
 # kinds (a forward each and one recomputed under remat), a group's seven
-# heads in every step; Nemotron's sixteen; LFM2's four stacked lane blocks
+# heads in every step; Nemotron's sixteen; LFM2's four stacked lane blocks;
+# Mistral-Small-4's and Ouro's ungrouped heads, eight head-blocks of the row
 SWEEPS = {
     "smallthinker": ("smallthinker_21b_a3b_config", (1, 16384),
                      {("fwd", 7, 7): 4, ("bwd", 7, 7): 2}),
@@ -292,6 +343,12 @@ SWEEPS = {
                    {("fwd", 16, 16): 2, ("bwd", 16, 16): 1}),
     "lfm2": ("lfm2_8b_a1b_config", (2, 8192),
              {("fwd", 4, 4): 2, ("bwd", 4, 4): 1}),
+    # ungrouped (PR 70): eight head-blocks of the row a forward step, two a
+    # backward one
+    "mistral4": ("mistral_small_4_config", (1, 16384),
+                 {("fwd", 1, 8): 2, ("bwd", 1, 2): 1}),
+    "ouro": ("ouro_2_6b_config", (2, 4096),
+             {("fwd", 1, 8): 2, ("bwd", 1, 2): 1}),
 }
 
 

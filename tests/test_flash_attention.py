@@ -229,8 +229,9 @@ def test_step_geometry_stays_under_the_vmem_budget(S):
 @pytest.mark.parametrize("B,S,H,D,causal,want", [
     (256, 128, 12, 64, False, (6, 256)),  # bert_base.s128_scan: 1,536 before
     (64, 512, 12, 64, False, (1, 384)),   # bert_base.s512_scan: untouched
-    # olmoe: the triangle of 8 x 8 blocks, 36 steps a (row, head) where 64
-    (4, 4096, 16, 128, True, (1, 4 * 16 * 36)),
+    # olmoe: the triangle of 8 x 8 blocks, 36 steps where 64, and since
+    # PR 70 eight of the row's sixteen head-blocks a step
+    (4, 4096, 16, 128, True, (8, 4 * 2 * 36)),
     (8, 640, 12, 64, False, (1, 8 * 6 * 25)),  # S = 640 in five blocks of 128
 ])
 def test_packed_grid_of_the_cells(B, S, H, D, causal, want):
@@ -551,8 +552,9 @@ def test_the_sweeps_visit_the_triangle_and_the_band(S, bq, bk, window, blocks):
     """A sweep's grid is its table: the blocks under the diagonal (and
     inside the band), none skipped; what the gauges say of a layer kind."""
     assert fa.kv_blocks(S, bq, bk, True, window) == blocks
+    # (the row's four head-blocks ride one step since PR 70)
     assert fa.packed_grid(3, S, 4, 128, bq, bk, causal=True, window=window) \
-        == (1, 3 * 4 * blocks)
+        == (4, 3 * blocks)
     if S <= 4096:
         assert _tiles(S, S, bq, bk, True, window).sum() == blocks
 
@@ -706,16 +708,21 @@ def test_grouped_queries_need_whole_head_blocks():
     ("trinity_large_preview.s6144_scan", 1, 6144, 48, 8, 128, (6, 8 * 78)),
     ("nemotron3_nano_30b_a3b.s8192_scan", 2, 8192, 32, 2, 128,
      (16, 2 * 2 * 136)),
+    # ungrouped over several blocks (PR 70): SWEEP_HEAD_BLOCKS adjacent
+    # head-blocks of the row a step
     ("olmoe_1b_7b.s4096_scan, ungrouped", 4, 4096, 16, None, 128,
-     (1, 4 * 16 * 36)),
+     (8, 4 * 2 * 36)),
+    ("mistral_small_4_119b.s16384_scan, ungrouped", 1, 16384, 32, None, 128,
+     (8, 4 * 528)),
     ("bert_base.s128_scan, ungrouped", 256, 128, 12, 12, 64, (6, 256)),
     ("bert_base.s512_scan, ungrouped", 64, 512, 12, None, 64, (1, 384)),
 ])
 def test_packed_grid_of_the_grouped_cells(what, B, S, H, Hkv, D, want):
     """The grids of the grouped modes are a (row, key/value head-block)
     pair's triangle of blocks, the group's query head-blocks riding each
-    step (PR 68); BERT's ungrouped width-64 mode is what it was before
-    grouped queries ran at two heads a lane block."""
+    step (PR 68), of the ungrouped several-block mode a (row, eight
+    head-blocks) step's (PR 70); BERT's ungrouped width-64 mode is what it
+    was before grouped queries ran at two heads a lane block."""
     assert fa.packed_grid(B, S, H, D, 512, 512, n_kv_heads=Hkv,
                           causal=S > 512) == want
 
@@ -763,21 +770,27 @@ def test_a_query_block_reads_one_half_of_its_key_value_block():
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=None)
-def _swept(group, heads, window, dtype):
-    """(o, lse, dq, dk, dv) of ``group`` query heads of 128 on one key/value
-    head over 128 positions in blocks of 32, ``heads`` of them a grid step
-    (``heads_a_step`` replaced), float32 numpy; then the same five by plain
-    ``jnp`` in float32 of the same (rounded) operands, and the grids."""
-    S, D, block, scale = 128, 128, 32, 128 ** -0.5
-    q, k, v, w = (t.astype(dtype) for t in _packed_qkv(68, 1, S, group, 1, D))
-    rule, fa.heads_a_step = fa.heads_a_step, lambda g, need: heads
+def _swept(H, Hkv, D, Dv, heads, window, dtype, seed=68):
+    """(o, lse, dq, dk, dv) of ``H`` query heads of ``D`` on ``Hkv``
+    key/value heads (values ``Dv`` wide) over 128 positions in blocks of 32,
+    ``heads`` head-blocks a grid step (``heads_a_step`` replaced), float32
+    numpy; then the same five by plain ``jnp`` in float32 of the same
+    (rounded) operands, and the grids."""
+    S, block, scale = 128, 32, D ** -0.5
+    rng = np.random.RandomState(seed)
+    q, k, v, w = (jnp.array((rng.randn(1, S, h * d) * 0.5).astype(np.float32)
+                            ).astype(dtype)
+                  for h, d in ((H, D), (Hkv, D), (Hkv, Dv), (H, Dv)))
+    more = (Dv,) if Dv != D else ()
+    rule = fa.heads_a_step
+    fa.heads_a_step = lambda g, need, most=None: heads
     try:
         def run(q, k, v, w):
-            o, lse = fa._fwd(q, k, v, scale, True, block, block, True, group,
-                             1, window)
+            o, lse = fa._fwd(q, k, v, scale, True, block, block, True, H, Hkv,
+                             window, *more)
             return (o, lse) + tuple(fa._bwd(
-                scale, True, block, block, True, (q, k, v, o, lse), w, group,
-                1, window))
+                scale, True, block, block, True, (q, k, v, o, lse), w, H, Hkv,
+                window, *more))
         grids = re.findall(r"grid=\(([\d, ]*)\)", str(jax.make_jaxpr(run)(
             q, k, v, w)))
         got = run(q, k, v, w)
@@ -785,14 +798,17 @@ def _swept(group, heads, window, dtype):
         fa.heads_a_step = rule
 
     def plain(q, k, v):
-        heads_of = lambda t, h: t.reshape(1, S, h, D)
-        qh, kh, vh = heads_of(q, group), heads_of(k, 1), heads_of(v, 1)
-        s = jnp.einsum("bqhd,bkhd->bhqk", qh, jnp.repeat(kh, group, 2)) * scale
+        qh, kh, vh = (t.reshape(1, S, h, -1)
+                      for t, h in ((q, H), (k, Hkv), (v, Hkv)))
+        s = jnp.einsum("bqhd,bkhd->bhqk", qh,
+                       jnp.repeat(kh, H // Hkv, 2)) * scale
         i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
         seen = (j <= i) & (i - j < (window or S))
         lse = jax.nn.logsumexp(jnp.where(seen, s, -jnp.inf), axis=-1)
+        # the statistic's layout: [row, head-block, S, heads of the block]
+        hpb = max(1, 128 // D)
         return _plain(qh, kh, vh, True, window).reshape(1, S, -1), \
-            lse[..., None]
+            lse.reshape(1, H // hpb, hpb, S).transpose(0, 1, 3, 2)
 
     f32 = [t.astype(jnp.float32) for t in (q, k, v)]
     (o, lse), vjp = jax.vjp(plain, *f32)
@@ -821,8 +837,8 @@ def test_a_group_s_heads_ride_one_grid_step(group, heads, window, dtype):
     (the step's heads are summed before the one add into the
     accumulators).  The grids: (row, key/value head-block, chunks of the
     group, tiles), the backward's table walking the chunks."""
-    got, want, grids = _swept(group, heads, window, dtype)
-    one, _, _ = _swept(group, 1, window, dtype)
+    got, want, grids = _swept(group, 1, 128, 128, heads, window, dtype)
+    one, _, _ = _swept(group, 1, 128, 128, 1, window, dtype)
     tol = 2e-5 if dtype == "float32" else 3e-2
     for a, b, n in zip(got, want, ("o", "lse", "dq", "dk", "dv")):
         assert a.shape == b.shape, n
@@ -901,6 +917,102 @@ def test_the_heads_a_step_come_from_the_shapes(monkeypatch, what, S, group,
     assert fa.heads_a_step(group, need_f) == 1
 
 
+# ---------------------------------------------------------------------------
+# UNGROUPED queries: several adjacent head-blocks of a batch row INSIDE a
+# grid step of the several-block sweeps, each with its own k and v (PR 70)
+# ---------------------------------------------------------------------------
+
+#   what, heads of the row, D, Dv, window, dtype
+ROWS = [("128 / 128, causal", 4, 128, 128, None, "bfloat16"),
+        ("128 / 128, causal, float32", 4, 128, 128, None, "float32"),
+        ("the value-width mode, 256 / 128", 4, 256, 128, None, "bfloat16"),
+        ("a window that is no multiple of the block", 4, 128, 128, 40,
+         "float32"),
+        ("the value-width mode under a window", 4, 256, 128, 40, "bfloat16"),
+        # no cell runs it and the rule keeps it at one (``heads_in_step``):
+        # the body's lines take it as they are
+        ("two heads of 64 a lane block", 8, 64, 64, None, "bfloat16")]
+
+
+@pytest.mark.parametrize("heads", [2, 4])
+@pytest.mark.parametrize("what,H,D,Dv,window,dtype", ROWS,
+                         ids=[m[0] for m in ROWS])
+def test_a_row_s_head_blocks_ride_one_grid_step(what, H, D, Dv, window, dtype,
+                                                heads):
+    """o, lse, dq, dk, dv against plain ``jnp`` attention, and ALL FIVE bit
+    for bit against the same call at one head-block a step (the step before
+    PR 70): a head's recurrence is unchanged, and its dk and dv add into
+    columns of the accumulators that are its own, in the order they did (no
+    sum over the step's heads: the order of every sum is unchanged, hence no
+    tolerance).  The grids: (row, the row's head-blocks over ``heads``, 1,
+    tiles), the backward's without the third axis."""
+    got, want, grids = _swept(H, H, D, Dv, heads, window, dtype, 71)
+    one, _, one_grids = _swept(H, H, D, Dv, 1, window, dtype, 71)
+    tol = 2e-5 if dtype == "float32" else 3e-2
+    for a, b, n in zip(got, want, ("o", "lse", "dq", "dk", "dv")):
+        assert a.shape == b.shape, n
+        assert np.abs(a - b).max() <= tol * max(1.0, np.abs(b).max()), n
+    for a, b, n in zip(got, one, ("o", "lse", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(a, b, err_msg=n)
+    tiles, blocks = fa.kv_blocks(128, 32, 32, True, window), H * D // max(D, 128)
+    assert (grids[0], grids[-1]) == (
+        "1, %d, 1, %d" % (blocks // heads, tiles),
+        "1, %d, %d" % (blocks // heads, tiles))
+    assert (one_grids[0], one_grids[-1]) == (
+        "1, %d, 1, %d" % (blocks, tiles), "1, %d, %d" % (blocks, tiles))
+
+
+@pytest.mark.parametrize("what,S,H,D,Dv,fwd,bwd,mib", [
+    # the forward holds no sequence: its stop; the backward's accumulators
+    # are a head's own, 24 MiB a head at 16,384 positions of 128 lanes, and
+    # its stop is two
+    ("mistral_small_4_119b.s16384_scan", 16384, 32, 128, 128, 8, 2,
+     (30, 59)),
+    ("ouro_2_6b.s4096_scan / olmoe_1b_7b.s4096_scan", 4096, 16, 128, 128, 8,
+     2, (30, 23)),
+    # q and k two lane blocks a head, v one: 36 MiB of accumulators a head
+    ("kimi_linear_48b_a3b.s16384_scan", 16384, 32, 256, 128, 8, 1, (34, 52)),
+    ("twelve head-blocks: the most under the stop that divides them", 4096,
+     12, 128, 128, 6, 2, (23.5, 23)),
+    ("a prime count of head-blocks past the stop", 4096, 11, 128, 128, 1, 1,
+     (7.25, 22)),
+    ("two heads of 64 a lane block stay one block a step", 4096, 16, 64, 64,
+     1, 1, (7.25, 22)),
+    ("[BH, S, D]: one head-block a row", 4096, None, 128, 128, 1, 1,
+     (7.25, 22)),
+])
+def test_the_ungrouped_heads_a_step_come_from_the_shapes(monkeypatch, what, S,
+                                                         H, D, Dv, fwd, bwd,
+                                                         mib):
+    """``_Geom.heads_in_step`` where the queries are not grouped: the most
+    head-blocks of the row (a divisor of their count, SWEEP_HEAD_BLOCKS or
+    fewer, SWEEP_BWD_HEAD_BLOCKS in the backward, whose scope is what XLA
+    loses for arrays of its own) whose step fits SWEEP_VMEM by the two
+    counts with the k and v
+    blocks, the accumulators and their output blocks ``heads`` wide; no
+    configuration field, flag or name."""
+    assert (fa.SWEEP_HEAD_BLOCKS, fa.SWEEP_BWD_HEAD_BLOCKS) == (8, 2)
+    q = jax.ShapeDtypeStruct((1, S, (H or 1) * D), jnp.bfloat16)
+    g = fa._Geom(q, q, H, 512, 512, Dv=Dv)
+    assert g.group == 1 and (g.kv_heads(3), g.chunks(3)) == (3, 1)
+    got = g.heads_in_step("fwd"), g.heads_in_step("bwd")
+    assert (got[0][0], got[1][0]) == (fwd, bwd), what
+    assert (got[0][1], got[1][1]) == tuple(int(m * 2 ** 20) for m in mib)
+    lanes, vw = max(D, 128), max(Dv, 128)
+    assert got[0][1] == fa.fwd_sweep_vmem_bytes(fwd, lanes, 2, vw,
+                                                kv_heads=fwd)
+    assert got[1][1] == fa.fused_sweep_vmem_bytes(S, lanes, 2, vw, heads=bwd,
+                                                  kv_heads=bwd)
+    # a head more would not fit, or pass the stop, or not divide the row
+    more = [n for n in range(bwd + 1, fa.SWEEP_BWD_HEAD_BLOCKS + 1)
+            if g.hpb == 1 and g.Hb % n == 0]
+    assert all(fa.fused_sweep_vmem_bytes(S, lanes, 2, vw, heads=n, kv_heads=n)
+               > fa.SWEEP_VMEM for n in more), what
+    # no VMEM for a sweep (the tests' way to the two sweeps): one head
+    monkeypatch.setattr(fa, "SWEEP_VMEM", 0)
+    assert g.heads_in_step("fwd")[0] == 1
+
+
 def test_a_traced_sweep_counts_the_heads_in_its_step(tmp_path):
     """``monitor.kernels.flash_sweep_calls{part, group, heads_in_step}``: one
     count a traced several-block call (``kernels/_common.count_call``), the
@@ -926,5 +1038,6 @@ def test_a_traced_sweep_counts_the_heads_in_its_step(tmp_path):
             if r["name"] == "monitor.kernels.flash_sweep_calls"}
     finally:
         monitor.disable()
+    # (the ungrouped call's two head-blocks ride one step since PR 70)
     assert got == {("fwd", 6, 6): 1, ("bwd", 6, 6): 1,
-                   ("fwd", 1, 1): 1, ("bwd", 1, 1): 1}
+                   ("fwd", 1, 2): 1, ("bwd", 1, 2): 1}

@@ -415,8 +415,9 @@ def test_flash_grid_is_a_function_of_the_shapes_and_no_gauge(tmp_path):
             (base, 256, 128, (6, 256), 1),  # bert_base.s128_scan
             (base, 64, 512, (1, 384), 1),   # bert_base.s512_scan, _dp4
             (dataclasses.replace(base, tp=2), 256, 128, (6, 128), 1),
-            # the causal triangle: 36 of 8 x 8 blocks a (row, head)
-            (olmoe.olmoe_1b_7b_config(), 4, 4096, (1, 4 * 16 * 36), 1),
+            # the causal triangle: 36 of 8 x 8 blocks, eight of a row's
+            # sixteen heads a step (PR 70)
+            (olmoe.olmoe_1b_7b_config(), 4, 4096, (8, 4 * 2 * 36), 1),
             # 28 on 4 heads of 128: a lane block is one head, a group's
             # seven ride one step
             (smallthinker.smallthinker_21b_a3b_config(), 1, 16384,

@@ -377,12 +377,13 @@ def _counters(trained):
         4 * 256 * 4
     assert (T.yarn_blend_range(cfg)[1], cfg.qk_rope_dim // 2 - 1) == (3, 15)
     assert max(S - cfg.rope_original_max, 0) == 48
-    # a layer's grid: the causal triangle's 10 blocks a (sequence, head)
+    # a layer's grid: the causal triangle's 10 blocks a sequence, its four
+    # heads in one step (PR 70)
     assert packed_grid(
         B, S, cfg.n_heads, cfg.head_dim,
         *T._packed_flash_blocks(cfg, cfg.n_heads, S, cfg.kv_heads),
         itemsize=itemsize, n_kv_heads=cfg.kv_heads,
-        causal=True) == (1, 80)
+        causal=True) == (4, 20)
 
 
 def _specs(specs):

@@ -105,6 +105,17 @@ from paddle_tpu.parallel.train import stack_batches  # noqa: E402
 # digest), taken on that PR's parent (2e410fb) before any other edit, so that
 # all fifteen trainers are held; PR 69 re-took NONE of the twenty-eight
 # (``make_train_step`` lost an argument every caller left at ``None``).
+# PR 70 took Mistral's, Kimi-Linear's and dots3's six anew ON PURPOSE (the
+# three tiny trainers whose ungrouped heads of a whole lane block run over
+# several blocks: a several-block sweep's grid step holds ``heads_a_step``
+# adjacent head-blocks of the batch row, each with k and v of its own,
+# forward and fused backward, ``kernels/flash_attention.py``; they were
+# ffaa7601578581ff / 9100e873b1250116, 5a34b11e1dde7e94 / e5f0e15d922e959c
+# and d7d1e352464fcf9e / 73b06e01bdc7bd2d); the twenty-four others stand:
+# a grouped call keeps PR 68's step, OLMoE's and Ouro's tiny heads of 16
+# are no whole lane block (one head-block a row, or no packed call), one
+# block is the one-block kernel (BERT), and Keye's sweeps only import
+# ``heads_a_step``.
 PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "bert.run_steps": "00de5403506fdc87",
             "olmoe.step": "231114fcd62341f2",
@@ -115,8 +126,8 @@ PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "lfm2.run_steps": "659cbb6a76ec3dc3",
             "brumby.step": "84e6b6d548803a44",
             "brumby.run_steps": "5a063ea89a19f1a4",
-            "mistral4.step": "ffaa7601578581ff",
-            "mistral4.run_steps": "9100e873b1250116",
+            "mistral4.step": "9b477dde18d6febe",
+            "mistral4.run_steps": "d6db9b93012f56a9",
             "trinity.step": "78ef368cbdb2aeb1",
             "trinity.run_steps": "f0d4a67c2fda2c5c",
             "jamba.step": "7280f2f3e3854358",
@@ -127,12 +138,12 @@ PROGRAMS = {"bert.step": "b07028186fd9c7b9",
             "ouro.run_steps": "64aaa9a06b2f9fbd",
             "resnet.step": "350db1fba0d68284",
             "resnet.run_steps": "dc9dd853700f9ab9",
-            "kimi_linear.step": "5a34b11e1dde7e94",
-            "kimi_linear.run_steps": "e5f0e15d922e959c",
+            "kimi_linear.step": "b7667ee42efe7797",
+            "kimi_linear.run_steps": "d242e3ba75315750",
             "keye_vl2.step": "4fdc203023887275",
             "keye_vl2.run_steps": "b3dfb8265faa6541",
-            "dots3.step": "d7d1e352464fcf9e",
-            "dots3.run_steps": "73b06e01bdc7bd2d",
+            "dots3.step": "62af1a57cdc5d912",
+            "dots3.run_steps": "9322ff58ae775066",
             "solar_open2.step": "d31a2a5637029741",
             "solar_open2.run_steps": "185e1103978dbdda"}
 OLDER = {"bert": (bert.build_bert_trainer, bert.bert_tiny_config, 32),
