@@ -17,7 +17,8 @@ key/value head-block) pair and a grid step one (q block, kv block) tile of
 ``step_table``, built on the host from the shapes and the mask and read by
 the index maps as scalar-prefetch operands: only the pairs the mask lets
 something through are steps (the triangle under the diagonal, a window's
-band, the rectangle).  A step holds ``heads_a_step`` query head-blocks,
+band, the rectangle, the block-diffusion rule's three parts).  A step holds
+``heads_a_step`` query head-blocks,
 looped inside it: of the key/value head-block's group where the queries are
 grouped (PR 68), and where they are not, that many ADJACENT head-blocks of
 the batch row, each with k and v of its own (PR 70: the k and v blocks are
@@ -64,7 +65,7 @@ step's blocks; the kernel bodies loop over the rows and unroll over the
 head-blocks (the compiler interleaves the independent heads), each
 (row, head) computed exactly as a step of its own would.
 
-Two more modes of the packed entry, both of the same kernels:
+Three more modes of the packed entry, all of the same kernels:
 
 - grouped queries (``n_kv_heads`` < ``n_heads``): k and v are
   [B, S, n_kv_heads*D] and query head h reads key/value head
@@ -96,6 +97,27 @@ Two more modes of the packed entry, both of the same kernels:
   and ``_dkv``) so that a trace's reader can tell a windowed layer's calls
   from a full one's.  A window of S or more is the causal mask and runs the
   causal kernels.
+- the block-diffusion rule (``block_diffusion`` = Bd, not causal): the S rows
+  are a noised copy of a sequence over its clean copy, S / 2 positions each
+  in blocks of Bd, and a noised query sees the noised keys of its own block
+  (both directions) and the clean keys of EARLIER blocks, a clean query the
+  clean keys of its own and earlier blocks, nothing else
+  (``blockdiff_seen``).  ONE sweep over all S queries and S keys, not two
+  (clean on clean; noised on both): the rule is a fourth table of
+  ``step_table`` (a copy is whole tiles and a tile whole blocks, so a tile's
+  quadrant and the blocks it spans say whether it holds a pair: ``nq (nq +
+  1) + nq`` tiles a head at nq tiles a copy, 288 of 1,024 at S / 2 = 8,192;
+  ``blockdiff_live_share``) and a mask ``_seen`` builds in the tile from the
+  quadrant (scalars of the table's entries) and the rows' and columns'
+  blocks; everything else (the grouped heads inside a step, the fused
+  backward's whole-sequence accumulators, which are the key/value rows' of
+  BOTH copies) is the sweeps' as they stand, where two calls would read k
+  and v of the clean copy twice and sum a clean key's dk and dv outside the
+  kernel.  The noised-on-noised part stands alone as nq diagonal tiles of
+  which Bd x Bd squares are live (0.8 % at 512-row tiles and Bd = 4): 5.6 %
+  of the sweep's tiles, the price of no second kernel.  No mask is an
+  operand and nothing [S, S] stands anywhere; the kernels carry the names
+  ``flash_bd_fwd`` / ``flash_bd_bwd_fused`` (``_dq``, ``_dkv``).
 
 All matmuls feed the MXU in the input dtype with f32 accumulation.
 interpret=True (CPU tests) is selected automatically off-TPU.
@@ -363,13 +385,59 @@ def grid_geometry(B, S, Sk, n_head_blocks, lanes, itemsize, bq, bk, group=1):
 FIRST, LAST = 1, 2    # a step's flags in ``step_table``
 
 
-def step_table(S, Sk, bq, bk, causal, window=None, group=1, kv_major=False):
+def blockdiff_seen(S, bq, bk, blocks):
+    """``seen(i, j)`` of ``step_table`` under the BLOCK-DIFFUSION rule: the S
+    rows are a noised copy of a sequence over its clean copy, ``[x_t ; x_0]``
+    of S / 2 positions each, in blocks of ``blocks`` positions, ``b(r) = (r
+    mod S / 2) // blocks``.  A noised query sees the noised keys of its own
+    block (both directions) and the clean keys of EARLIER blocks; a clean
+    query the clean keys of its own and earlier blocks; nothing else.  A
+    tile lies in one quadrant (the halves are whole tiles) and holds whole
+    blocks (``blocks`` divides both tile heights), so whether a pair of it is
+    let through follows from the blocks its rows and columns span: at bq ==
+    bk, nq = S / 2 / bq tiles a half, nq on the noised diagonal and nq (nq +
+    1) / 2 in each of the two triangles over the clean keys, ``nq (nq + 1) +
+    nq`` of the square's ``4 nq^2`` (288 of 1,024 at S / 2 = 8,192 in
+    512-row tiles; of a diagonal noised tile ``blocks / bq`` is live, 0.8 %
+    at blocks of 4)."""
+    half = S // 2
+    assert S == 2 * half and half % bq == 0 and half % bk == 0 \
+        and bq % blocks == 0 and bk % blocks == 0, (S, bq, bk, blocks)
+
+    def span(n, rows):
+        """Tile n of ``rows`` rows: noised?, its first and last block."""
+        first = n * rows % half
+        return n * rows < half, first // blocks, (first + rows - 1) // blocks
+
+    def seen(i, j):
+        noised_q, q_lo, q_hi = span(i, bq)
+        noised_k, k_lo, k_hi = span(j, bk)
+        if noised_k:
+            return noised_q and k_lo <= q_hi and q_lo <= k_hi
+        # clean keys: of earlier blocks, and a clean query's own
+        return k_lo < q_hi + (not noised_q)
+
+    return seen
+
+
+def blockdiff_live_share(S, bq, bk, blocks):
+    """(tiles of one head's sweep under the rule, the share of their (query,
+    key) pairs the rule lets through): S / 2 (S / 2 + blocks) pairs over
+    ``tiles * bq * bk``."""
+    tiles = kv_blocks(S, bq, bk, False, blocks=blocks)
+    return tiles, (S // 2) * (S // 2 + blocks) / (tiles * bq * bk)
+
+
+def step_table(S, Sk, bq, bk, causal, window=None, group=1, kv_major=False,
+               blocks=None):
     """The grid steps of one multi-block sweep, in order: int32 [4, steps],
     the columns ``(q block, kv block, head, flags)`` of each step.  A step
     is a (q block, kv block) pair that holds at least one (query, key) pair
     the mask lets through (key <= query, and query - key < ``window`` where
-    there is one; every pair where not ``causal``), so the triangle, the band
-    and the rectangle are three tables of this one builder and no step of a
+    there is one; every pair where not ``causal``; under ``blocks``, a block
+    length, the block-diffusion rule over a noised and a clean copy:
+    ``blockdiff_seen``), so the triangle, the band, the rectangle and the
+    rule's three parts are four tables of this one builder and no step of a
     grid is empty.
 
     q-major (the forward and the dq sweep, whose grids hold the group as an
@@ -396,6 +464,9 @@ def step_table(S, Sk, bq, bk, causal, window=None, group=1, kv_major=False):
         lo, hi = i * bq - (j * bk + bk - 1), i * bq + bq - 1 - j * bk
         return not causal or (hi >= 0 and lo <= far)
 
+    if blocks:
+        assert S == Sk and not causal and window is None, (S, Sk, causal)
+        seen = blockdiff_seen(S, bq, bk, blocks)
     nq, nk = S // bq, Sk // bk
     sweeps = [[(i, j, h) for h in range(group) for i in range(nq)
                if seen(i, j)] for j in range(nk)] if kv_major else \
@@ -408,30 +479,34 @@ def step_table(S, Sk, bq, bk, causal, window=None, group=1, kv_major=False):
         for sweep in sweeps for n, (i, j, h) in enumerate(sweep)], np.int32).T
 
 
-def kv_blocks(S, bq, bk, causal=True, window=None):
+def kv_blocks(S, bq, bk, causal=True, window=None, blocks=None):
     """The (q block, kv block) grid steps of one head's forward sweep."""
-    return step_table(S, S, bq, bk, causal, window).shape[1]
+    return step_table(S, S, bq, bk, causal, window, blocks=blocks).shape[1]
 
 
 def packed_grid(B, S, n_heads, head_dim, block_q, block_k, itemsize=2,
-                n_kv_heads=None, causal=False, window=None, part="fwd"):
+                n_kv_heads=None, causal=False, window=None, part="fwd",
+                blocks=None):
     """What ``flash_attention_packed`` runs for these shapes, for whoever
     wants to say so without tracing it (the tests, ``scripts/``):
     (pairs per grid step, grid steps of one layer's forward pass; ``part``
     "bwd": of its backward, where that is one kernel).  Several blocks: a
     step is a tile of ``step_table`` for a (row, key/value head-block) pair
     and the ``heads_a_step`` query head-blocks of its group that ride it
-    (ungrouped: for that many head-blocks of the row)."""
+    (ungrouped: for that many head-blocks of the row).  ``blocks``: the
+    block-diffusion rule's block length, S the rows of both copies (its
+    tiles a head and their live share: ``blockdiff_live_share``)."""
     bq, bk = min(block_q, S), min(block_k, S)
     # shapes and an element size are all the geometry reads of q and k
     g = _Geom(*(jax.ShapeDtypeStruct((B, S, n * head_dim),
                                      np.dtype("V%d" % itemsize))
                 for n in (n_heads, n_kv_heads or n_heads)),
-              n_heads, bq, bk, n_kv_heads, window)
+              n_heads, bq, bk, n_kv_heads, window, blocks=blocks)
     if S == bk and part == "fwd":
         return g.G * g.Hg, g.grid_b * (S // bq)
     heads, _ = g.heads_in_step(part)
-    return heads, g.grid_b // heads * kv_blocks(S, bq, bk, causal, window)
+    return heads, g.grid_b // heads * kv_blocks(S, bq, bk, causal, window,
+                                                blocks)
 
 
 class _Geom:
@@ -452,11 +527,19 @@ class _Geom:
     ``Dv`` (None: D): the values' head width where it is not q's and k's (v,
     o, do and dv are [B, S, H*Dv]; both widths whole lane blocks, a block a
     head, no grouping): ``vw`` is a value head-block's lanes where ``qw`` is
-    a query's, and every product with v or do runs at ``vw``."""
+    a query's, and every product with v or do runs at ``vw``.
 
-    def __init__(self, q, k, H, bq, bk, Hkv=None, window=None, Dv=None):
+    ``blocks`` (None: none): the block-diffusion rule's block length, the
+    rows a noised copy over a clean one (``blockdiff_seen``): several blocks
+    always, the table and the in-tile mask the rule's, the kernels' names
+    ``flash_bd_*``."""
+
+    def __init__(self, q, k, H, bq, bk, Hkv=None, window=None, Dv=None,
+                 blocks=None):
         B, self.S, E = q.shape
         self.Sk = k.shape[1]
+        # (rows of a copy, block length): what the in-tile mask reads
+        self.rule = (self.S // 2, blocks) if blocks else None
         if H is None:
             self.D, self.hpb, self.Hb = E, 1, 1
         else:
@@ -751,30 +834,55 @@ def _bwd_tiles(tile, stk, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  last)
 
 
-def _seen(shape, q0, k0, window, wrap=None):
+def _block_of(first, offset, rule):
+    """The rule's block of row ``first + offset`` (``first`` a tile's first
+    row, a scalar) within its copy."""
+    half, length = rule
+    pos = jnp.where(first < half, first, first - half) + offset
+    return pos >> (length.bit_length() - 1)     # a power of two
+
+
+def _seen(shape, q0, k0, window, wrap=None, rule=None):
     """[bq, bk] bool: key position <= query position, and inside the window
     (query - key < window) where there is one.  ``wrap``: the rows are
-    several heads' query blocks of ``wrap`` rows, one under the other."""
+    several heads' query blocks of ``wrap`` rows, one under the other.
+    ``rule`` = (rows of a copy, block length): the block-diffusion rule
+    instead (``blockdiff_seen``).  The tile lies in ONE quadrant, which its
+    first row and column say: with d = the query's block - the key's, noised
+    on noised lets d == 0 through, noised on clean d >= 1, clean on clean d
+    >= 0, clean on noised nothing (no table holds such a tile)."""
     row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    qpos = q0 + (row if wrap is None else jax.lax.rem(row, wrap))
+    if wrap is not None:
+        row = jax.lax.rem(row, wrap)
+    if rule:
+        col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        noised_q, noised_k = q0 < rule[0], k0 < rule[0]
+        d = _block_of(q0, row, rule) - _block_of(k0, col, rule)
+        least = jnp.where(noised_q & ~noised_k, 1, 0)
+        most = jnp.where(noised_k, jnp.where(noised_q, 0, -1),
+                         jnp.iinfo(jnp.int32).max)
+        return (d >= least) & (d <= most)
+    qpos = q0 + row
     kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     if window is None:
         return qpos >= kpos
     return (qpos >= kpos) & (qpos - kpos < window)
 
 
-def _scores(q, k, scale, causal, q0, k0, window=None, wrap=None, seen=None):
+def _scores(q, k, scale, causal, q0, k0, window=None, wrap=None, seen=None,
+            rule=None):
     """[bq, bk] f32 scaled scores of one head (of ``_stack_heads``' rows:
-    ``wrap``), future positions (and those behind the window) masked.
+    ``wrap``), future positions (and those behind the window) masked, or
+    what the block-diffusion ``rule`` shuts out (``_seen``).
     ``seen`` (a list, empty at first): the mask of a grid step's tile, built
     by the first of the step's heads and shared by the others."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    if causal:
+    if causal or rule:
         if seen:
             mask = seen[0]
         else:
-            mask = _seen(s.shape, q0, k0, window, wrap)
+            mask = _seen(s.shape, q0, k0, window, wrap, rule)
             if seen is not None:
                 seen.append(mask)
         s = jnp.where(mask, s, NEG_INF)
@@ -873,7 +981,8 @@ def _fwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, o_ref,
         ``vs`` of its values: the statistics at lanes ``ls`` of their
         scratch, the accumulator at columns ``os``."""
         s = _scores(q, k_ref[0][:, cs], scale, causal, q_of[t] * bq,
-                    kv_of[t] * bk, geom.window, wrap, seen)  # [rows, bk]
+                    kv_of[t] * bk, geom.window, wrap, seen,
+                    geom.rule)                          # [rows, bk]
 
         m_prev = m_scr[:, ls]                          # [rows, LANES]
         m_cur = jnp.max(s, axis=1)[:, None]            # [rows, 1]
@@ -926,7 +1035,10 @@ def _fwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, o_ref,
 
 
 def _name(kernel, g):
-    """``flash_<kernel>``; ``flash_swa_<kernel>`` for a windowed call."""
+    """``flash_<kernel>``; ``flash_swa_<kernel>`` for a windowed call,
+    ``flash_bd_<kernel>`` for one under the block-diffusion rule."""
+    if g.rule:
+        return "flash_bd_" + kernel
     return ("flash_swa_" if g.window is not None else "flash_") + kernel
 
 
@@ -955,11 +1067,14 @@ def _sweep_call(kernel, g, table, operands, in_specs, out_specs, out_shape,
 
 
 def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None, Hkv=None,
-         window=None, Dv=None):
+         window=None, Dv=None, blocks=None):
     """H=None: q/k/v are [BH, S, D].  H=int: q/k/v are [B, S, H*D] (k, v
     [B, S, Hkv*D] with grouped queries; v [B, S, H*Dv] with a value width of
     its own)."""
-    g = _Geom(q, k, H, bq, bk, Hkv, window, Dv)
+    g = _Geom(q, k, H, bq, bk, Hkv, window, Dv, blocks)
+    if blocks:
+        assert not g.one_block, (g.S, bk)   # a copy is whole tiles
+        _count_call("flash_blockdiff", part="fwd", fused=1, blocks=blocks)
     out_shape = [
         jax.ShapeDtypeStruct(g.o_shape, q.dtype),
         jax.ShapeDtypeStruct(g.stat_shape, jnp.float32),
@@ -975,7 +1090,8 @@ def _fwd(q, k, v, scale, causal, bq, bk, interpret, H=None, Hkv=None,
         return _sweep_call(
             functools.partial(_fwd_sweep_kernel, scale=scale, causal=causal,
                               bq=bq, bk=bk, hpb=g.hpb, heads=heads, geom=g),
-            g, step_table(g.S, g.Sk, bq, bk, causal, window), (q, k, v),
+            g, step_table(g.S, g.Sk, bq, bk, causal, window, blocks=blocks),
+            (q, k, v),
             [g.q_spec(bq, qm, heads), g.kv_spec(bk, km, kvh),
              g.v_spec(bk, km, kvh)],
             [g.o_spec(bq, qm, heads), g.stat_spec(bq, sm, heads)], out_shape,
@@ -1139,7 +1255,7 @@ def _bwd_dq_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
 
     def tile(q, k, v, do, lse, delta, cs, vs, qs, last, wrap=None):
         s = _scores(q, k, scale, causal, q_of[t] * bq, kv_of[t] * bk,
-                    geom.window, wrap)
+                    geom.window, wrap, rule=geom.rule)
         p = jnp.exp(s - lse())                         # [rows, bk]
         dov = jax.lax.dot_general(do, v,
                                   (((1,), (1,)), ((), ())),
@@ -1177,7 +1293,7 @@ def _bwd_dkv_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
 
     def tile(q, k, v, do, lse, delta, cs, vs, qs, last, wrap=None):
         s = _scores(q, k, scale, causal, q_of[t] * bq, kv_of[t] * bk,
-                    geom.window, wrap)
+                    geom.window, wrap, rule=geom.rule)
         p = jnp.exp(s - lse())                         # [rows, bk]
         # dv_j += p^T dO
         dv = jax.lax.dot_general(
@@ -1282,7 +1398,7 @@ def _bwd_sweep_kernel(q_of, kv_of, head_of, flags, q_ref, k_ref, v_ref, do_ref,
         """The rows of q and do against columns ``cs`` of this kv block's
         keys and ``vs`` of its values; dq at columns ``qs``."""
         s = _scores(q, k, scale, causal, q_of[t] * bq, kv_of[t] * bk,
-                    geom.window, wrap, seen)
+                    geom.window, wrap, seen, geom.rule)
         p = jnp.exp(s - lse())                     # [rows, bk] - the ONE exp
         # dv_j += p^T dO
         dv = jax.lax.dot_general(
@@ -1342,9 +1458,12 @@ def _delta(o, do, g, packed, interpret):
 
 
 def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
-         window=None, Dv=None):
+         window=None, Dv=None, blocks=None):
     q, k, v, o, lse = res
-    g = _Geom(q, k, H, bq, bk, Hkv, window, Dv)
+    g = _Geom(q, k, H, bq, bk, Hkv, window, Dv, blocks)
+    if blocks:
+        _count_call("flash_blockdiff", part="bwd", fused=1, blocks=blocks,
+                    sweeps=g.bwd_sweeps)
     if g.one_block and g.group == 1:
         return _bwd_fused(scale, causal, bq, bk, interpret, res, do, H=H,
                           window=window, Dv=Dv)
@@ -1375,7 +1494,8 @@ def _bwd(scale, causal, bq, bk, interpret, res, do, H=None, Hkv=None,
             functools.partial(kernel, scale=scale, causal=causal, bq=bq,
                               bk=bk, hpb=g.hpb, geom=g),
             g, step_table(g.S, g.Sk, bq, bk, causal, window,
-                          g.chunks(heads) if walks_group else 1, kv_major),
+                          g.chunks(heads) if walks_group else 1, kv_major,
+                          blocks),
             (q, k, v, do, lse, delta), [qs, ks, vs, os, stats, stats],
             out_specs(qs, ks, vs), out_shape, scratch_shapes,
             heads, walks_group, interpret, name, **params)
@@ -1438,7 +1558,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_packed(q, k, v, heads, scale, causal, bq, bk, interpret):
-    """``heads`` = (H, Hkv, window[, Dv])."""
+    """``heads`` = (H, Hkv, window[, Dv[, blocks]])."""
     o, _ = _fwd(q, k, v, scale, causal, bq, bk, interpret, *heads)
     return o
 
@@ -1482,7 +1602,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
 
 def flash_attention_packed(q, k, v, n_heads, causal=False, scale=None,
                            block_q=256, block_k=256, interpret=None,
-                           n_kv_heads=None, window=None, v_head_dim=None):
+                           n_kv_heads=None, window=None, v_head_dim=None,
+                           block_diffusion=None):
     """Packed-layout flash attention: q, k, v are [B, S, H*D] exactly as the
     qkv projections produce them; returns [B, S, H*D] ready for the output
     projection.  The per-head D-wide column slices are addressed by the
@@ -1498,7 +1619,12 @@ def flash_attention_packed(q, k, v, n_heads, causal=False, scale=None,
     with v (``P V``, ``dP``, ``dV``) runs at the values' width, and no
     grouping rides with it (a window does: the band's table and mask know
     no width).  Give ``scale`` where D holds lanes
-    that are not the head's (zeros behind a head of 192 in 256 lanes)."""
+    that are not the head's (zeros behind a head of 192 in 256 lanes).
+    ``block_diffusion`` = Bd: the S rows are a noised copy of a sequence over
+    its clean copy, S / 2 positions each in blocks of Bd, and the mask is the
+    block-diffusion rule's three parts (``blockdiff_seen``: not ``causal``,
+    no window; a copy is whole tiles and a tile whole blocks).  The rule
+    follows from the shapes: no mask operand, nothing [S, S] anywhere."""
     B, S, E = q.shape
     H = n_heads
     assert E % H == 0, (E, H)
@@ -1527,5 +1653,11 @@ def flash_attention_packed(q, k, v, n_heads, causal=False, scale=None,
     bk = min(block_k, Sk)
     assert S % bq == 0 and Sk % bk == 0, (S, Sk, bq, bk)
     heads = (H, Hkv, window) + ((Dv,) if Dv != D else ())
+    if block_diffusion:
+        # (a block length that is a power of two: the in-tile mask shifts)
+        assert not causal and window is None and Sk == S \
+            and block_diffusion & (block_diffusion - 1) == 0, (
+                causal, window, block_diffusion)
+        heads = (H, Hkv, None, Dv, int(block_diffusion))
     return _flash_packed(q, k, v, heads, float(scale), bool(causal), bq, bk,
                          bool(interpret))

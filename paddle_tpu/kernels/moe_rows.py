@@ -48,7 +48,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._common import CompilerParams as _CompilerParams, on_tpu as _on_tpu
+from ._common import (CompilerParams as _CompilerParams,
+                      count_call as _count_call, on_tpu as _on_tpu)
 
 __all__ = ["moe_rows_sum", "token_block", "vmem_bytes"]
 
@@ -254,7 +255,10 @@ def moe_rows_sum(rows, inv, k, interpret=None):
     """``rows`` [M, E] (bfloat16 with E even, or a 32-bit type), ``inv``
     [T*k] int (a row's index, or >= M for a pair with no row): [T, E], token
     t the float32 sum of ``rows[inv[t*k + j]]`` over its held slots in order
-    j = 0..k-1, rounded once to ``rows.dtype``."""
+    j = 0..k-1, rounded once to ``rows.dtype``.  Under a monitor session
+    every traced call counts in ``monitor.kernels.moe_rows_sum_calls``
+    (``fused`` 1: there is no other path)."""
     if interpret is None:
         interpret = not _on_tpu()
+    _count_call("moe_rows_sum", fused=1, k=k)
     return _rows_sum(rows, inv, k, bool(interpret))

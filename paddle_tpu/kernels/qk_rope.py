@@ -146,14 +146,16 @@ def stream_angles(positions, half, theta, sections=()):
     return jnp.moveaxis(pos, 0, -1) * inv_freq
 
 
-def angle_tables(S, head_dim, theta, first=0, positions=None, sections=()):
+def angle_tables(S, head_dim, theta, first=0, positions=None, sections=(),
+                 period=None):
     """(cos, signed sin) [S, 128] float32 of positions ``first``..``first``
     + S - 1 (``first`` may be traced), each head's ``head_dim`` lanes
     ``rope``'s own ``tile(cos(pos * theta^(-i / half)), 2)``, the sine with
     ``rotate_half``'s sign (minus on a head's first half).  ``positions``
     [streams, b, S]: the positions as DATA, pair i from the stream
     ``sections`` gives it (``stream_angles``); the tables are then [b * S,
-    128], a row a (batch row, position)."""
+    128], a row a (batch row, position).  ``period``: row r's position is
+    ``r mod period`` (``transformer.rope``)."""
     half = head_dim // 2
     if positions is not None:
         ang = stream_angles(positions, half, theta, sections).reshape(
@@ -163,6 +165,8 @@ def angle_tables(S, head_dim, theta, first=0, positions=None, sections=()):
         pos = jnp.arange(S, dtype=jnp.float32)
         if not (isinstance(first, int) and first == 0):
             pos = pos + first
+        if period:
+            pos = pos % period
         ang = pos[:, None] * inv_freq[None]
     heads = LANES // head_dim
     return (jnp.tile(jnp.cos(ang), (1, 2 * heads)),

@@ -93,12 +93,17 @@ LAYER_SCAN = "layer_scan"
 # exit gate at the end of every pass with what the loss makes of it (the
 # distribution over the exits, its entropy, the weighted sum)
 LOOP_SCAN, EXIT_GATE = "loop_scan", "exit_gate"
+# block-diffusion training outside the layers: the noising of a batch's ids
+# (the mask from its noise, the mask token put in) and the two copies put one
+# over the other (the rows ``[x_t ; x_0]`` the stack runs on), and the noised
+# rows cut out again in front of the head
+NOISE = "noise"
 VOCABULARY = (EMBED, ATTENTION, MLP, LAYER_NORM, LM_HEAD, CONV, BN, POOL, FC,
               LOSS, GRAD_SYNC, OPTIMIZER, MOE, ROUTER, SHORT_CONV, RETENTION,
               LATENT_ATTENTION, SHARED_EXPERT, LAYER_SCAN, ATTN_GATE,
               POST_NORM, MAMBA, SELECTIVE_SCAN, MAMBA2, SSD_SCAN, LOOP_SCAN,
               EXIT_GATE, KDA, KDA_CHUNK, INDEXER, INDEXER_SELECT, SPARSE_ATTN,
-              INDEXER_KL, MLA_DSA, MLA_SWA)
+              INDEXER_KL, MLA_DSA, MLA_SWA, NOISE)
 PHASES = ("forward", "backward", "recompute", GRAD_SYNC, OPTIMIZER)
 
 # `%fusion.12 = bf16[..] fusion(%p.1, %copy-done.2), ..., metadata={op_name="jit(multi)/..." ...}`:

@@ -4,13 +4,15 @@
 decoder of this block.  A model (``models/olmoe.py``, ``smallthinker.py``,
 ``lfm2.py``, ``brumby.py``, ``mistral4.py``, ``trinity.py``, ``jamba.py``,
 ``nemotron_h.py``, ``ouro.py``, ``kimi_linear.py``, ``keye_vl2.py``,
-``dots3.py``, ``solar_open2.py``)
+``dots3.py``, ``solar_open2.py``, ``sdar.py``)
 is a ``TransformerConfig`` and a label; what its trainer computes and what
 it observes follow from the configuration, never from which model it is.
 
 batch dict: ``ids`` int32 [B, S], and where the trainer was built for them
 (``build_decoder_trainer(positions=True)``) ``positions`` int32 [3, B, S],
-the rotation's three position streams.  The loss builds the next-token
+the rotation's three position streams; a BLOCK-DIFFUSION trainer's
+(``cfg.block_diffusion``) carries its noise, ``t`` float32 [B, S / Bd] and
+``u`` float32 [B, S] (``make_loss_fn``).  The loss builds the next-token
 labels itself (``labels[t] = ids[t + 1]``, positions 0..S-2 count) and adds
 what the configuration's router asks for: the auxiliary losses, mean over
 layers (``ce + router_aux_coef * load_balance + router_z_coef * router_z``),
@@ -30,6 +32,7 @@ from jax.sharding import PartitionSpec as P
 
 from . import moe, optim
 from .. import monitor
+from ..monitor import devscope
 from .mesh import DP, MeshSpec, local_shard_map
 from .train import (StepTrainer, TrainState, make_train_step, shard_pytree,
                     state_specs)
@@ -45,9 +48,11 @@ from .transformer import (
     final_logits_loss,
     indexer_selection,
     _local_heads,
+    _packed_flash_blocks,
     _qkv,
     grad_sync_axes,
     head_logits,
+    head_rows_computed,
     init_transformer_params,
     kda_log_decay,
     kda_write_strength,
@@ -60,14 +65,25 @@ from .transformer import (
     transformer_param_specs,
 )
 
-__all__ = ["BATCH_SPECS", "POSITION_SPECS", "STEPPED", "forward", "weighted_exit_logits",
+__all__ = ["BATCH_SPECS", "POSITION_SPECS", "NOISE_SPECS", "STEPPED",
+           "batch_specs", "forward", "weighted_exit_logits", "noised_rows",
            "make_loss_fn", "probe", "DecoderTrainer",
            "build_decoder_trainer"]
 
 BATCH_SPECS = {"ids": P(DP)}
 # of a batch that carries its rotation's three position streams [3, B, S]
 POSITION_SPECS = {"positions": P(None, DP)}
+# of a block-diffusion batch: a noise level a block, t [B, S / Bd], and a
+# uniform draw a token, u [B, S]
+NOISE_SPECS = {"t": P(DP), "u": P(DP)}
+NOISE = tuple(NOISE_SPECS)
 STEPPED = {"router_bias"}       # leaves a step sets itself (make_train_step)
+
+
+def batch_specs(cfg, positions=False):
+    """The fields a trainer of ``cfg`` reads of a batch, with their specs."""
+    return dict(BATCH_SPECS, **(POSITION_SPECS if positions else {}),
+                **(NOISE_SPECS if cfg.block_diffusion else {}))
 
 
 def forward(params, ids, cfg, positions=None):
@@ -98,14 +114,58 @@ def weighted_exit_logits(params, ids, at, cfg):
                for t in range(cfg.loop_passes))
 
 
+@devscope.scoped(devscope.NOISE)
+def noised_rows(batch, cfg):
+    """``(rows [b, 2 S], masked [b, S] bool, level [b, S])`` of a
+    block-diffusion batch: token i of a sequence is masked where ``u_i <
+    t_{i // Bd}`` (one noise level a block of ``cfg.block_diffusion``
+    tokens, ``level`` that level a token), the noised copy ``x_t = where(
+    masked, cfg.mask_token_id, ids)`` and under it the clean one: the rows
+    the stack runs on."""
+    ids = batch["ids"]
+    level = jnp.repeat(batch["t"], cfg.block_diffusion, axis=-1)
+    masked = batch["u"] < level
+    rows = jnp.concatenate(
+        [jnp.where(masked, jnp.asarray(cfg.mask_token_id, ids.dtype), ids),
+         ids], axis=-1)
+    return rows, masked, level
+
+
+def _denoising_loss(params, batch, cfg):
+    """Block diffusion's training loss: the noised rows' logits held to the
+    UNSHIFTED clean ids at the masked positions, ``(1 / S) sum_i m_i /
+    t_{b(i)} CE(logits_i, ids_i)`` a sequence (BD3-LM's linear schedule;
+    the divisor is the sequence's length, not the weights' sum), mean over
+    the batch.  The clean rows feed keys and values and no loss; the head
+    computes the masked rows' blocks alone."""
+    ids = batch["ids"]
+    rows, masked, level = noised_rows(batch, cfg)
+    x, aux = forward(params, rows, cfg)
+    with jax.named_scope(devscope.NOISE):
+        x = x[:, :ids.shape[1]]
+        weight = jnp.where(masked, 1.0 / level, 0.0).astype(jnp.float32)
+    return final_logits_loss(params, x, ids, weight, cfg,
+                             divisor=float(ids.size)), aux
+
+
 def make_loss_fn(cfg: TransformerConfig):
-    """Per-device training loss on a batch of ``ids``.  Where the routing
+    """Per-device training loss on a batch.  The fields a batch carries, by
+    the kind of trainer: ``ids`` int32 [B, S], every one; ``positions``
+    int32 [3, B, S], one built with ``positions=True`` (the rotation's three
+    streams); ``t`` float32 [B, S / Bd] in (0, 1) and ``u`` float32 [B, S]
+    uniform, a block-diffusion one (``cfg.block_diffusion`` = Bd: the noise,
+    a level a block and a draw a token; ``_denoising_loss``).
+
+    Where the routing
     rule has selection biases (``moe.SIGMOID_BIASED``): ``(loss, {"router_bias":
     their next values})``, each layer's moved against that layer's load in
     this step (``moe.balance_bias``; ``make_train_step``'s ``stepped``).
     Of a looped stack the exit-weighted loss of its passes."""
 
     def loss_fn(params, batch):
+        if cfg.block_diffusion:
+            ce, aux = _denoising_loss(params, batch, cfg)
+            return _with_router_terms(ce, aux, params)
         ids = batch["ids"]
         labels = jnp.roll(ids, -1, axis=1)
         mask = jnp.broadcast_to(
@@ -118,6 +178,9 @@ def make_loss_fn(cfg: TransformerConfig):
         if cfg.indexer_heads:
             # the indexer's own term, mean over layers: its leaves' alone
             ce = ce + _dsa_kl_mean(aux, cfg)
+        return _with_router_terms(ce, aux, params)
+
+    def _with_router_terms(ce, aux, params):
         if cfg.routing == moe.SIGMOID_BIASED:
             return ce, {"router_bias": moe.balance_bias(
                 params["router_bias"], aux["load"], cfg.router_bias_rate)}
@@ -322,6 +385,11 @@ class DecoderTrainer(StepTrainer):
         """The head's float32 logits [B, P, V] at ``positions`` [P] of
         ``ids`` [B, S], at the weights as they stand: the step's own forward
         (block, kernels, MoE, the head's norm and matmul) without the loss.
+        A block-diffusion trainer takes the whole batch in ``ids``' place (a
+        dict: ``ids`` and its noise ``t``, ``u``) and gives the NOISED rows'
+        logits, the stack run on both copies as the step runs it.  Under a
+        monitor session the call observes its batch as a step's would
+        (``_observe``).
         What a check against a reference reads where the scalar loss cannot
         tell (``benchmark/drivers/train_scan_witnessed.py``).  Of a looped
         stack (``loop_passes`` > 1) the WEIGHTED-EXIT logits ``sum_t p_t
@@ -329,16 +397,25 @@ class DecoderTrainer(StepTrainer):
         every gate (its bias, the survival product, the last exit taking
         what is left) and every call of the head shows."""
         cfg = self.cfg
+        noise = NOISE if cfg.block_diffusion else ()
         if self._logits_fn is None:
-            def logits(params, ids, at):
+            def logits(params, ids, at, *drawn):
                 if cfg.loop_passes > 1:
                     return weighted_exit_logits(params, ids, at, cfg)
+                if drawn:       # ``at`` < S: rows of the noised copy
+                    ids = noised_rows(dict(zip(noise, drawn), ids=ids),
+                                      cfg)[0]
                 return head_logits(
                     params, forward(params, ids, cfg)[0][:, at], cfg)
 
-            self._logits_fn = self._on_mesh(logits, P(DP), P())
-        return self._logits_fn(self.state["params"], jnp.asarray(ids),
-                               jnp.asarray(positions, jnp.int32))
+            self._logits_fn = self._on_mesh(
+                logits, P(DP), P(), *(NOISE_SPECS[k] for k in noise))
+        batch = ids if noise else {"ids": ids}
+        self._observe(batch)    # under a monitor session, as a step's call
+        return self._logits_fn(
+            self.state["params"], jnp.asarray(batch["ids"]),
+            jnp.asarray(positions, jnp.int32),
+            *(jnp.asarray(batch[k]) for k in noise))
 
     def _observe(self, batch):
         """Under a monitor session, of a call on ``batch["ids"]`` [..., B,
@@ -356,6 +433,8 @@ class DecoderTrainer(StepTrainer):
         if mon is None:
             return
         cfg, ids, params = self.cfg, batch["ids"], self.state["params"]
+        if cfg.block_diffusion:
+            ids = self._observe_noise(mon, batch)
         batches = ids.reshape((-1,) + ids.shape[-2:])
         if self._probe_fn is None:
             self._probe_fn = self._on_mesh(
@@ -377,6 +456,40 @@ class DecoderTrainer(StepTrainer):
                 held / pairs)
 
 
+    def _observe_noise(self, mon, batch):
+        """A block-diffusion call's own readings, and the rows ``[x_t ;
+        x_0]`` of its batches (what the stack's readings are taken on):
+        ``bd_masked_share``, the masked tokens over all of the call's;
+        ``lm_head_rows``, the rows the head computes for them (whole blocks
+        a dp shard, by the function the device code takes its trip count
+        from) and their share ``lm_head_rows_share``; ``bd_tiles_a_layer``,
+        the steps of the rule's table a head's forward sweep walks; and,
+        where a share of the experts is held, ``moe_capacity_rows``, the
+        first static capacity a layer's step is compiled for."""
+        cfg = self.cfg
+        rows, masked, _ = noised_rows(
+            {k: jnp.asarray(batch[k]) for k in ("ids",) + NOISE}, cfg)
+        dp, (b, s) = self.mesh.shape[DP], masked.shape[-2:]
+        live = jax.device_get(masked).reshape(-1, dp, b // dp * s)
+        head = int(head_rows_computed(live.sum(-1), live.shape[-1]).sum())
+        for name, value in (("bd_masked_share", live.mean()),
+                            ("lm_head_rows_share", head / live.size)):
+            mon.registry.gauge("monitor.train." + name).set(float(value))
+        mon.registry.counter("monitor.train.lm_head_rows").incr(head)
+        from ..kernels.flash_attention import kv_blocks
+
+        heads, kv_heads = _local_heads(cfg)
+        mon.registry.gauge("monitor.train.bd_tiles_a_layer").set(kv_blocks(
+            2 * s, *_packed_flash_blocks(cfg, heads, 2 * s, kv_heads), False,
+            blocks=cfg.block_diffusion))
+        if cfg.experts_held:
+            mon.registry.gauge("monitor.train.moe_capacity_rows").set(
+                moe._held_capacities(
+                    b // dp * 2 * s * cfg.experts_per_token,
+                    cfg.experts_here, cfg.n_experts)[0])
+        return rows
+
+
 def build_decoder_trainer(cfg, mesh_spec: MeshSpec = None, optimizer=None,
                           seed=0, devices=None, label="decoder",
                           positions=False):
@@ -385,7 +498,8 @@ def build_decoder_trainer(cfg, mesh_spec: MeshSpec = None, optimizer=None,
     expert-parallel layout yet.  A router's selection biases, where the
     parameters hold them, are the step's to set and not the optimizer's.
     ``positions``: every batch carries ``positions`` int32 [3, B, S] beside
-    its ``ids`` (``POSITION_SPECS``)."""
+    its ``ids`` (``POSITION_SPECS``); a block-diffusion configuration's
+    carries its noise (``NOISE_SPECS``)."""
     mesh_spec = mesh_spec or MeshSpec()
     assert mesh_spec.tp == mesh_spec.pp == cfg.tp == cfg.pp == 1, \
         "the decoder block runs at tp == pp == 1"
@@ -398,8 +512,7 @@ def build_decoder_trainer(cfg, mesh_spec: MeshSpec = None, optimizer=None,
     sspecs = state_specs(pspecs, state)
     build = make_train_step(make_loss_fn(cfg), mesh, pspecs,
                             grad_sync_axes(cfg), optimizer,
-                            dict(BATCH_SPECS, **POSITION_SPECS) if positions
-                            else BATCH_SPECS,
+                            batch_specs(cfg, positions),
                             stepped=tuple(STEPPED & set(params)))
     step_fn, multi_fn = build(state), build.multi(state)
     with mesh:

@@ -363,6 +363,30 @@ class TransformerConfig:
     # SEEDED at: at 1 a head's scores are N(0, 1) over the keys at seeded
     # weights, at 2^(1/2) N(0, 4) and a row's softmax visibly uneven
     qk_norm_gain: float = 1.0
+    # BLOCK DIFFUSION (BD3-LM's vectorised training form): the block length
+    # Bd.  The stack then runs on the rows ``[x_t ; x_0]`` of a sequence, a
+    # noised copy over the clean one (2 S rows; ``decoder.make_loss_fn``
+    # makes them from the batch's noise and holds the noised rows to the
+    # clean ids): attention's mask is the rule's three parts over blocks of
+    # Bd positions (``kernels/flash_attention.blockdiff_seen``: a noised
+    # query its own noised block and the earlier clean ones, a clean query
+    # its own and the earlier clean ones; ``causal`` off), and a rotation's
+    # positions repeat, row r's is ``r mod S``.  ``mask_token_id``: what a
+    # masked token reads (-1: the vocabulary's last row)
+    block_diffusion: int = 0
+    mask_token_id: int = -1
+    # what the mask token's embedding row is SEEDED at, times a token's.
+    # Every masked token reads that ONE row, and where a branch's output is
+    # small beside the stream (``residual_out_gain``) all of a sequence's
+    # masked rows, a third of the stack's rows, meet the same k experts in
+    # every layer: a share's rows, and its step's time, then follow which of
+    # them it holds, by the seed (PERF.md section 6, PR 71).  Seeded small, a
+    # masked row's stream is what attention brings it, which follows its
+    # position: the routing is balanced at seeded weights and stays so as
+    # the routers train.  The price: such a row's stream, hence its logits,
+    # carry bf16's rounding of a whole branch (a few per cent of float32's,
+    # where a token's own row keeps the others to half a per cent)
+    mask_embed_gain: float = 1.0
 
     def __post_init__(self):
         assert self.norm in ("layer", "rms") and \
@@ -454,6 +478,17 @@ class TransformerConfig:
                 and self.norm == "rms" and self.tp == self.pp == 1 \
                 and not self.n_experts
         assert not self.exit_entropy_coef or self.loop_passes > 1
+        if self.block_diffusion:
+            # the rule is the packed flash kernels' alone, rows whole tiles
+            assert not self.causal and self.tp == self.pp == 1 \
+                and self.attn_mode == "heads" and not self.latent \
+                and not self.layer_pattern and not self.indexer_heads \
+                and self.loop_passes == 1 and self.positions != "learned" \
+                and not self.mrope_sections
+            if self.mask_token_id < 0:
+                self.mask_token_id = self.vocab_size - 1
+            assert self.mask_token_id < self.vocab_size
+        assert self.mask_embed_gain == 1.0 or self.block_diffusion
         self.mrope_sections = tuple(int(n) for n in self.mrope_sections)
         if self.mrope_sections:
             assert self.positions == "rotary" and not self.latent \
@@ -691,6 +726,9 @@ def _init_params(key, cfg):
         **layers,
         **_router_bias(ks[5], cfg),
     }
+    if cfg.mask_embed_gain != 1.0:
+        params["tok_emb"] = params["tok_emb"].at[cfg.mask_token_id].multiply(
+            cfg.mask_embed_gain)
     if cfg.positions == "learned":
         params["pos_emb"] = _dense_init(ks[2], E, (cfg.max_seq, E), dt)
     if cfg.norm == "layer":
@@ -1225,14 +1263,17 @@ def _norm(x, pl, name, cfg, fused=True):
                       eps=cfg.norm_eps, fused=fused)
 
 
-def rope(x, n_heads, theta=10000.0, first=0, positions=None, sections=()):
+def rope(x, n_heads, theta=10000.0, first=0, positions=None, sections=(),
+         period=None):
     """Rotary position embedding on a packed projection x [b, S, H*dh],
     positions ``first``..``first`` + S - 1 (``first`` may be traced: a block
     of rows of a longer sequence), rotate-half convention (the halves of a
     head are the pairs): ``x * cos + rotate_half(x) * sin`` with angle
     ``pos * theta^(-2i/dh)`` for both members of pair i.  float32 inside.
     ``positions`` [streams, b, S]: the angles' positions as DATA, pair i
-    from the stream ``sections`` gives it (``qk_rope.stream_angles``)."""
+    from the stream ``sections`` gives it (``qk_rope.stream_angles``).
+    ``period``: the positions repeat, row r's is ``r mod period`` (the
+    copies of a sequence one under the other)."""
     b, S, W = x.shape
     dh = W // n_heads
     half = dh // 2
@@ -1247,6 +1288,8 @@ def rope(x, n_heads, theta=10000.0, first=0, positions=None, sections=()):
         pos = jnp.arange(S, dtype=jnp.float32)
         if not (isinstance(first, int) and first == 0):
             pos = pos + first
+        if period:
+            pos = pos % period
         ang = pos[:, None] * inv_freq[None]
         cos = jnp.tile(jnp.cos(ang), (1, 2))[None, :, None, :]  # [1,S,1,dh]
         sin = jnp.tile(jnp.sin(ang), (1, 2))[None, :, None, :]
@@ -1482,9 +1525,11 @@ def _norm_and_rotate(xs, weights, heads, fused, cfg, rotary, first,
     for took in fused:
         count_call("qk_rope", dh=dh, norm=norm or "none", rotary=int(rotary),
                    convention="half", fused=int(took))
+    # under block diffusion both copies of a sequence carry its positions
+    period = {"period": xs[0].shape[1] // 2} if cfg.block_diffusion else {}
     tables = qk_rope.angle_tables(
         xs[0].shape[1], dh, cfg.rope_theta, first, positions,
-        cfg.mrope_sections) if rotary and any(fused) else None
+        cfg.mrope_sections, **period) if rotary and any(fused) else None
 
     def normed(x, weight, n):
         if norm == "head":      # each head on its own, one weight for all
@@ -1502,7 +1547,8 @@ def _norm_and_rotate(xs, weights, heads, fused, cfg, rotary, first,
 
     xs = [kernel(x, w) if took else normed(x, w, n)
           for x, w, n, took in zip(xs, weights, heads, fused)]
-    return [rope(x, n, cfg.rope_theta, first, positions, cfg.mrope_sections)
+    return [rope(x, n, cfg.rope_theta, first, positions, cfg.mrope_sections,
+                 **period)
             if rotary and not took else x
             for x, n, took in zip(xs, heads, fused)]
 
@@ -1898,17 +1944,21 @@ def _attention_heads_mode(pl, h_full, cfg, kind, positions=None):
         # no [b, hl, S, dh] transpose round-trips (flash_attention_packed)
         from ..kernels.flash_attention import flash_attention_packed
 
+        # the block-diffusion rule, where the rows are the two copies
+        rule = {"block_diffusion": cfg.block_diffusion} \
+            if cfg.block_diffusion else {}
         o = flash_attention_packed(q2, k2, v2, hl, causal=cfg.causal,
                                    block_q=blocks[0], block_k=blocks[1],
-                                   n_kv_heads=kvl, window=window)
+                                   n_kv_heads=kvl, window=window, **rule)
     else:
         q = q2.reshape(b, S, hl, dh)
         k = k2.reshape(b, S, kvl, dh)
         v = v2.reshape(b, S, kvl, dh)
-        assert kvl == hl and window is None, \
-            "grouped queries and a window run on the packed flash kernel " \
-            "only (flash_attention.packed_layout_supported: a lane block " \
-            "of whole heads that share one key/value head; S whole blocks)"
+        assert kvl == hl and window is None and not cfg.block_diffusion, \
+            "grouped queries, a window and the block-diffusion rule run on " \
+            "the packed flash kernel only (flash_attention." \
+            "packed_layout_supported: a lane block of whole heads that " \
+            "share one key/value head; S whole blocks)"
         o = _local_attention_dispatch(q, k, v, cfg).reshape(b, S, hl * dh)
     o = _gated(pl, h_full, o, cfg)
     out = o @ pl["wo"]                                          # row-parallel partial
@@ -2830,11 +2880,15 @@ def head_logits(params, x, cfg: TransformerConfig):
 
 
 @devscope.scoped(devscope.LM_HEAD)
-def final_logits_loss(params, x_sp, labels, mask, cfg: TransformerConfig):
+def final_logits_loss(params, x_sp, labels, mask, cfg: TransformerConfig,
+                      divisor=None):
     """Softmax cross-entropy with the LM head (``tok_emb``, or the head's
     own ``lm_head`` where ``cfg.tie_head`` is off) on the configuration's
     final norm, averaged over the positions ``mask`` weights:
-    ``sum(nll * mask) / max(sum(mask), 1)`` over the dp-sharded global batch.
+    ``sum(nll * mask) / max(sum(mask), 1)`` over the dp-sharded global batch;
+    ``divisor``: over that number a dp shard instead of the weights' sum
+    (a denoising loss divides by the tokens, not by the masked ones'
+    weights).
 
     x_sp is sequence-sharded over tp; labels/mask are FULL [b, S].  ``mask``
     alone says which positions count (MLM: the predicted positions, causal
@@ -2853,8 +2907,11 @@ def final_logits_loss(params, x_sp, labels, mask, cfg: TransformerConfig):
             params.get("lnf_bias"), emb, labels.reshape(-1),
             mask.reshape(-1), norm=(cfg.norm, cfg.norm_eps))
         total = col.psum(jnp.sum(nll * mask.reshape(-1)), DP)
+        if divisor is not None:
+            return total / (divisor * col.axis_size_in(DP))
         count = col.psum(jnp.sum(mask.astype(jnp.float32)), DP)
         return total / jnp.maximum(count, 1.0)
+    assert divisor is None
     x = _norm(x_sp, params, "lnf", cfg, fused=False)
     x = col.all_gather(x, TP, dim=1)                            # [b, S, E]
     logits = (x @ emb.T).astype(jnp.float32)                    # [b, S, V/tp]

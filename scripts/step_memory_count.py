@@ -40,6 +40,7 @@ from jax.experimental import topologies  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from benchmark.harness import build, manifest as mf  # noqa: E402
+from benchmark.harness.batches import resolve_shape  # noqa: E402
 from paddle_tpu import kernels  # noqa: E402
 from paddle_tpu.monitor import memscope  # noqa: E402
 from paddle_tpu.parallel import decoder, optim, transformer as T  # noqa: E402
@@ -90,16 +91,19 @@ def _count(cell, *overrides):
     sspecs = state_specs(pspecs, state)
     multi = make_train_step(
         decoder.make_loss_fn(cfg), mesh, pspecs, T.grad_sync_axes(cfg),
-        optimizer, decoder.BATCH_SPECS,
+        optimizer, decoder.batch_specs(cfg),
         stepped=tuple(decoder.STEPPED & set(params))).multi(state)
     state = jax.tree.map(
         lambda a, spec: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=NamedSharding(mesh, spec)),
         state, sspecs)
     dims = build.cell_dims(config, traffic)
-    batches = {"ids": jax.ShapeDtypeStruct(
-        (int(traffic["staged_batches"]), dims["B"], dims["S"]), jnp.int32,
-        sharding=NamedSharding(mesh, P(None, DP)))}
+    # ``ids``, and what else the trainer reads of a batch (its noise)
+    batches = {f["name"]: jax.ShapeDtypeStruct(
+        (int(traffic["staged_batches"]),) + resolve_shape(f["shape"], dims),
+        jnp.dtype(f["dtype"]), sharding=NamedSharding(mesh, P(None, DP)))
+        for f in config["batch_fields"]
+        if f["name"] in decoder.batch_specs(cfg)}
     n_params = sum(a.size for a in jax.tree.leaves(params))
     return (multi.lower(state, batches, 1e-5).compile(), n_params,
             sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state)))

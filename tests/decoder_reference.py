@@ -95,6 +95,8 @@ class Case:
     #                             own trainer's (where the readings depend on
     #                             ``remat``: a cached trace counts once)
     also: dict = dataclasses.field(default_factory=dict)    # key -> more
+    fields: object = None       # ids [b, S] -> the batch's other fields (a
+    #                             block-diffusion batch's noise); None: none
 
     @property
     def module(self):
@@ -115,6 +117,17 @@ def ids(case, seed=0, n=1):
     rng = np.random.RandomState(seed)
     return [rng.randint(0, 256, (case.B, case.S)).astype(np.int32)
             for _ in range(n)]
+
+
+def batch_of(case, ids):
+    """The batch of ``ids``: with what ``case.fields`` makes beside them."""
+    return dict({"ids": ids}, **(case.fields(ids) if case.fields else {}))
+
+
+def read_of(case, ids):
+    """What a reader of ONE input takes (``logits_at``, a reference's
+    ``forward``): the ids, or the whole batch where it has other fields."""
+    return batch_of(case, ids) if case.fields else ids
 
 
 def moved(case, params):
@@ -151,7 +164,7 @@ def leaf(tree, path):
 
 
 def staged(tr, batches):
-    return stack_batches(tr.mesh, decoder.BATCH_SPECS, batches)
+    return stack_batches(tr.mesh, decoder.batch_specs(tr.cfg), batches)
 
 
 def loss_agrees(got, want, tol):
@@ -200,7 +213,7 @@ def both(case):
     loss_fn = decoder.make_loss_fn(cfg)
 
     def program(p):
-        out = loss_fn(p, {"ids": jnp.asarray(rows)})
+        out = loss_fn(p, jax.tree.map(jnp.asarray, batch_of(case, rows)))
         loss, stepped = out if case.aux else (out, None)
         logits = None
         if case.one_program:
@@ -245,7 +258,8 @@ def witnessed(case, both):
         params = moved(case, tr.state["params"])
     tr = at_weights(tr, params)
     rows = both.ids if w.seed is None else ids(case, seed=w.seed)[0][:w.rows]
-    return params, rows, np.asarray(tr.logits_at(rows, at)), model
+    return params, rows, np.asarray(
+        tr.logits_at(read_of(case, rows), at)), model
 
 
 def scope_map(tr):
@@ -285,7 +299,7 @@ class Trained:
 def observed(case, tmp, seed, n, **cfg):
     """``(trainer, batches, losses, the registry's rows)`` of ONE
     ``run_steps`` over ``n`` batches under a monitor session of its own."""
-    batches = [{"ids": i} for i in ids(case, seed=seed, n=n)]
+    batches = [batch_of(case, i) for i in ids(case, seed=seed, n=n)]
     tr = trainer(case, **cfg)
     assert monitor.active() is None
     tr._observe({"ids": Unreadable(case)})      # off a session: nothing runs
@@ -410,8 +424,8 @@ def common(case):
         @install
         def test_bfloat16_throughout_moves_the_reference_s_loss(both):
             want = float(both.want[0])
-            bad = reference.loss(both.params, {"ids": both.ids}, case.model,
-                                 faults=("bfloat16_throughout",))
+            bad = reference.loss(both.params, batch_of(case, both.ids),
+                                 case.model, faults=("bfloat16_throughout",))
             assert abs(bad - want) / want > 2 * tol
             more("bfloat16", both)
 
@@ -427,13 +441,14 @@ def common(case):
             params = jax.tree.map(jnp.asarray, both.params)
             logits = "logits" in case.pieces_hold
             if logits:
-                _, whole = reference.forward(params, both.ids, case.model)
+                _, whole = reference.forward(params, read_of(case, both.ids),
+                                             case.model)
             for name, value in case.pieces.items():
                 monkeypatch.setattr(reference, name, value)
 
             def run(p):
-                return reference.forward(p, both.ids, case.model,
-                                         keep_logits=logits)[:2]
+                return reference.forward(p, read_of(case, both.ids),
+                                         case.model, keep_logits=logits)[:2]
 
             if "grads" in case.pieces_hold:
                 (loss, seen), grad = jax.value_and_grad(
@@ -466,7 +481,7 @@ def common(case):
             params, rows, program, model = witnessed
             for name, value in case.witness.pieces.items():
                 monkeypatch.setattr(reference, name, value)
-            args = (program, params, {"ids": rows}, model)
+            args = (program, params, batch_of(case, rows), model)
             moved = reference.logits_error(*args, faults=(fault,))
             assert moved > case.witness.floors.get(fault, 1e3) * tol
             more("fault", args, fault)

@@ -379,3 +379,47 @@ def test_the_grouped_sweeps_a_cell_s_program_traces(tmp_path, model):
     finally:
         monitor.disable()
     assert got == want
+
+
+@pytest.mark.parametrize("what,half,heads,kv_heads,tiles,fwd,bwd", [
+    # tier-1's case: one key/value head's group at four tiles a copy
+    ("a group of 8 at four tiles a copy", 2048, 8, 1, 24, 8, 8),
+    pytest.param("sdar_30b_a3b_chat.s8192_scan", 8192, 32, 4, 288, 8, 8,
+                 marks=pytest.mark.slow),
+])
+def test_the_block_diffusion_rule_s_kernels_compile_for_a_v5e(
+        one_chip, what, half, heads, kv_heads, tiles, fwd, bwd):
+    """A noised copy over a clean one, ``half`` positions each in blocks of
+    4, 512-row tiles, heads of 128 in groups of 8: the rule's table (``nq
+    (nq + 1) + nq`` tiles a head: 288 of the square's 1,024 at S = 8,192)
+    scalar-prefetched as the other three are, the in-tile mask from the
+    tile's quadrant (scalar selects on the table's entries) and the rows'
+    and columns' blocks, a group's eight heads looped inside a grid step
+    (PR 68), forward and the ONE-sweep fused backward (dk and dv of all 2 S
+    rows in VMEM: 48.8 MB asked at 16,384 rows).  Kernels of names of their
+    own, no mask operand."""
+    S, D = 2 * half, 128
+    xq = jax.ShapeDtypeStruct((1, S, heads * D), jnp.bfloat16,
+                              sharding=one_chip)
+    xk = jax.ShapeDtypeStruct((1, S, kv_heads * D), jnp.bfloat16,
+                              sharding=one_chip)
+    attn = lambda q, k, v: fa.flash_attention_packed(
+        q, k, v, heads, block_q=512, block_k=512, interpret=False,
+        n_kv_heads=kv_heads, block_diffusion=4)
+    text, grids, _ = _compiled(attn, xq, xk, xk, xq)
+    nq = half // 512
+    assert fa.kv_blocks(S, 512, 512, False, blocks=4) == tiles \
+        == nq * (nq + 1) + nq
+    g = fa._Geom(xq, xk, heads, 512, 512, kv_heads, blocks=4)
+    assert (g.heads_in_step("fwd")[0], g.heads_in_step("bwd")[0],
+            g.bwd_sweeps) == (fwd, bwd, 1)
+    group = heads // kv_heads
+    assert grids == {"flash_bd_fwd": (1, kv_heads, group // fwd, tiles),
+                     "flash_bd_bwd_fused": (1, kv_heads,
+                                            group // bwd * tiles)}, what
+    assert fa.packed_grid(1, S, heads, D, 512, 512, n_kv_heads=kv_heads,
+                          blocks=4) == (fwd, kv_heads * group // fwd * tiles)
+    for name, part in (("flash_bd_fwd", "fwd"), ("flash_bd_bwd_fused", "bwd")):
+        asked, took = _vmem(text, name)
+        assert asked == g.heads_in_step(part)[1] and took < asked, what
+    assert "flash_fwd" not in grids and "flash_bwd_fused" not in grids

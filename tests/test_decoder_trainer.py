@@ -42,7 +42,10 @@ FIXED = {"monitor.kernels." + g for g in (
             "loop_passes", "layer_applications")}
 MOE = {"monitor.train.moe_load_max_over_mean",
        # since PR 46: a count a compiled gmm / tgmm call, by its tiles
-       "monitor.kernels.moe_grouped_matmul_calls"}
+       "monitor.kernels.moe_grouped_matmul_calls",
+       # since PR 71: a count a traced sum back of the experts' rows (the row
+       # kernel, ``fused`` 1: it has no other path)
+       "monitor.kernels.moe_rows_sum_calls"}
 HELD = {"monitor.train.moe_held_rows_share", "monitor.train.moe_rows_held"}
 # since PR 47: a count a traced q or k of ``_qkv`` with a q/k norm or rotary
 # positions, by whether the row kernel took it (since PR 57 Mistral's latent
