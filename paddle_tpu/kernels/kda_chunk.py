@@ -70,8 +70,7 @@ masked ``q k`` block, the three decayed copies) is made for ``GROUP`` chunks
 at a time, all heads at once, under a ``jax.checkpoint`` of its own (a
 backward holds one group's [SUB, SUB, dk] blocks and never the sequence's);
 then ONE scan over the chunks carries the state: four matrix products a
-chunk.  The scan's backward keeps a state a chunk (``kept_state_bytes``), as
-``ssd_scan``'s does.
+chunk.  The scan's backward keeps a state a chunk, as ``ssd_scan``'s does.
 
 ``kda_chunked`` is plain ``jax.numpy``, differentiated by JAX: where the
 kernels below do not take the shapes (``supported``), and the tests' second
@@ -104,10 +103,20 @@ once, every matrix [ROWS, ROWS] with a chunk's block on its diagonal:
   is at hand, so that a chunk's turn at the state is two products: ``X = U
   - W S``, ``S' = diag(lam) S + kh^T X`` (``o = qt S + Q X`` behind them).
 
-Out: ``o`` in v's type and, for the backward, the state each chunk FOUND,
-float32 [b, chunks, H, dv, dk] (``kept_state_bytes``).  The backward walks
-the stacks in reverse with the state's gradient ``D`` in VMEM, re-makes a
-stack's own parts and ``X = T beta (v - kt S)`` from the kept states, and
+Out: ``o`` in v's type and, from the forward that runs for a backward
+(``save``), what that backward READS, float32 a STACK (``kept_state_bytes``:
+three quarters of a state a chunk): the state the stack FOUND [b, stacks, H,
+dv, dk] and ``T`` of its chunks side by side [b, H, stacks, C, ROWS], as
+either solve left it (``save`` False, the forward where nothing is
+differentiated, writes neither and is the text it was; under a layer's remat
+BOTH of a step's forwards are the saving one, the first one's kept values
+dropped: PERF.md section 6, PR 72).  The backward walks the stacks in
+reverse with the state's gradient ``D`` in VMEM, re-makes a stack's own
+parts BUT the solve,
+takes ``[U | W]`` by the forward's one product and the states the stack's
+later chunks found and ``X`` by the forward's two lines (single-pass
+products: no ``highest`` product stands between one chunk's state and the
+next, and what is rebuilt is the forward's bit for bit), and
 turns at ``D`` with two products a chunk (``dX = Q^T do + kh D``, ``D' =
 do^T qt + diag(lam) D - dX^T W``: ``(beta dr)^T kt = dX^T W``); then, for
 the whole stack, ``dr = T^T dX``, ``dA = -strict_tril(dr X^T)``, ``dQ =
@@ -129,7 +138,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ._common import CompilerParams as _CompilerParams, on_tpu as _on_tpu
 
 __all__ = ["kda_recurrence", "kda_chunked", "kda_chunk", "supported",
-           "vmem_bytes", "kept_state_bytes", "SUB", "GROUP", "LANES",
+           "vmem_bytes", "kept_state_bytes", "KEPT", "SUB", "GROUP", "LANES",
            "ROWS", "STEP_STACKS"]
 
 SUB = 16        # rows of a block inside a chunk
@@ -137,6 +146,9 @@ GROUP = 8       # chunks whose own parts are made at once
 LANES = 128     # the head width the kernels are written for
 ROWS = 128      # rows of a STACK: the chunks whose own parts are made at once
 STEP_STACKS = 8     # stacks a grid step of the kernels walks
+# what the kernels' backward reads of the saving forward, beside the operands
+# (the mixer's ``kda_chunk_calls{kept}``): a state and the solve ``T`` a stack
+KEPT = "stack+T"
 _F32 = jnp.float32
 _HIGHEST = jax.lax.Precision.HIGHEST
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
@@ -144,10 +156,15 @@ _TN = (((0,), (0,)), ((), ()))      # a.T @ b
 
 
 def kept_state_bytes(batch, seq, chunk, heads, dk, dv):
-    """What the scan over chunks keeps for its backward: a float32 state
-    [dk, dv] a chunk and head (537 MB a layer at Kimi-Linear's [16384, 32 x
-    128], 268 MB at Solar-Open2's [4096, 64 x 128])."""
-    return batch * -(-seq // chunk) * heads * dk * dv * 4
+    """What the kernels' saving forward keeps for their backward beside the
+    operands, float32, a STACK of ROWS rows and head: the state [dk, dv] the
+    stack found and its chunks' solves side by side [chunk, ROWS].  403 MB
+    a layer at Kimi-Linear's [16384, 32 x 128] and 201 MB at Solar-Open2's
+    [4096, 64 x 128], chunks of 64: three quarters of a state a CHUNK (537
+    and 268 MB, what ``kda_chunked``'s scan keeps and these kernels kept
+    before PR 72); five eighths at chunks of 32; at ONE chunk a stack, which
+    no configuration has, a state and a solve as large."""
+    return batch * -(-seq // ROWS) * heads * (dk * dv + chunk * ROWS) * 4
 
 
 def kda_recurrence(q, k, v, g, beta, state=None):
@@ -344,15 +361,16 @@ def vmem_bytes(chunk, heads, itemsize, stacks=STEP_STACKS):
     """What a grid step of the BACKWARD (the larger of the two) asks Mosaic
     for: its pipelined blocks twice (q, k, v, do, dq, dk, dv a head wide in
     the operands' type; g and dg float32; the write strengths a column a
-    head, padded to a lane tile; the states kept; beta's gradient a row a
-    stack), the state's gradient, and room for a stack's values.  The heads
-    enter through beta's block alone: 22.6 MiB at 32 heads and at 64 alike
-    (a lane tile holds either; a grid step is ONE head's lane block whatever
-    the array's width, 4,096 or 8,192 lanes)."""
+    head, padded to a lane tile; a state and a solve kept a stack; beta's
+    gradient a row a stack), the state's gradient, and room for a stack's
+    values.  The heads enter through beta's block alone: 22.1 MiB at 32
+    heads and at 64 alike (a lane tile holds either; a grid step is ONE
+    head's lane block whatever the array's width, 4,096 or 8,192 lanes)."""
     tokens = stacks * ROWS
     blocks = 2 * (7 * tokens * LANES * itemsize + 2 * tokens * LANES * 4
                   + tokens * -(-heads // LANES) * LANES * 4
-                  + tokens // chunk * LANES * LANES * 4 + stacks * ROWS * 4)
+                  + stacks * (LANES * LANES + chunk * ROWS) * 4
+                  + stacks * ROWS * 4)
     return blocks + LANES * LANES * 4 + 96 * ROWS * LANES * 4 + (8 << 20)
 
 
@@ -430,9 +448,12 @@ class _Own:
     one under another) need of THEMSELVES, whatever state they find, in the
     module's notation and all at once: every matrix is [ROWS, ROWS] with a
     chunk's block on its diagonal and zeros between chunks.  q, k [ROWS, dk]
-    in the operands' type; g [ROWS, dk] and beta [ROWS, 1] float32."""
+    in the operands' type; g [ROWS, dk] and beta [ROWS, 1] float32.  ``side``:
+    the solve a forward KEPT (``inverse``'s [C, ROWS]), read and not made
+    again."""
 
-    def __init__(self, q, k, g, beta, row, code, C, over_one=False):
+    def __init__(self, q, k, g, beta, row, code, C, over_one=False,
+                 side=None):
         self.C, self.over_one, dt = C, over_one, q.dtype
         self.chunks = [slice(at, at + C) for at in range(0, ROWS, C)]
         self.qf, self.kf = qf, kf = q.astype(_F32), k.astype(_F32)
@@ -454,7 +475,11 @@ class _Own:
             pairs = _dot(jnp.concatenate([qd, kd], axis=0), kd, _NT)
             self.Q = self.Q + jnp.where(here, pairs[:ROWS], 0.0)
             self.P = self.P + jnp.where(here, pairs[ROWS:], 0.0)
-        self.T = self.inverse(beta * self.P)
+        if side is None:
+            side = self.inverse(beta * self.P)
+        else:
+            self._lanes()
+        self.side, self.T = side, self.spread(side)
         lasts = [G[c.stop - 1:c.stop, :] for c in self.chunks]
         self.lam = [jnp.exp(last) for last in lasts]        # [1, dk] each
         self.eG = jnp.exp(G)
@@ -462,30 +487,36 @@ class _Own:
                                  for last in lasts]) - G)
         self.kt, self.qt, self.kh = kf * self.eG, qf * self.eG, kf * self.eH
 
+    def _lanes(self):
+        """``lane`` and ``place`` of the chunks' blocks SIDE BY SIDE ([C,
+        ROWS]: chunk c in lanes c C onward); ``own``: each chunk's lanes."""
+        lane = jax.lax.broadcasted_iota(jnp.int32, (self.C, ROWS), 1)
+        place = jax.lax.broadcasted_iota(jnp.int32, (self.C, ROWS), 0)
+        self.own = [(lane >= c.start) & (lane < c.stop) for c in self.chunks]
+        return lane, place
+
+    def spread(self, side):
+        """The blocks side by side -> each on the diagonal of [ROWS, ROWS]."""
+        return _rows([jnp.where(mine, side, 0.0) for mine in self.own])
+
     def inverse(self, A):
-        """``(I + A)^-1`` [ROWS, ROWS] of the chunks' strictly lower blocks
-        on ``A``'s diagonal, as ``_unit_lower_inverse``: ``(I - A)(I +
-        A^2)(I + A^4)...`` in float32 on the MXU, with the chunks' blocks
-        SIDE BY SIDE ([C, ROWS]) on a product's left, so that its rows are
-        one chunk's, and on a diagonal on its right.  ``over_one``: by
-        doubling (the module's docstring), in the same two products a
-        level: ``inv`` side by side times the level's pairs of ``A``, times
-        ``inv`` on the diagonal."""
-        C = self.C
+        """``(I + A)^-1`` of the chunks' strictly lower blocks on ``A``'s
+        diagonal, the chunks' SIDE BY SIDE ([C, ROWS]: what a saving forward
+        keeps, ``spread`` puts on the diagonal), as ``_unit_lower_inverse``:
+        ``(I - A)(I + A^2)(I + A^4)...`` in float32 on the MXU, with the
+        blocks side by side on a product's left, so that its rows are one
+        chunk's, and on a diagonal on its right.  ``over_one``: by doubling
+        (the module's docstring), in the same two products a level: ``inv``
+        side by side times the level's pairs of ``A``, times ``inv`` on the
+        diagonal."""
+        C, spread = self.C, self.spread
 
         def beside(X):
             """Each chunk's block on the diagonal -> side by side."""
             return sum((X[c] for c in self.chunks[1:]), X[self.chunks[0]])
 
         power = beside(A)
-        lane = jax.lax.broadcasted_iota(jnp.int32, power.shape, 1)
-        place = jax.lax.broadcasted_iota(jnp.int32, power.shape, 0)
-        own = [(lane >= c.start) & (lane < c.stop) for c in self.chunks]
-
-        def spread(side):
-            """The blocks side by side -> each on the diagonal."""
-            return _rows([jnp.where(mine, side, 0.0) for mine in own])
-
+        lane, place = self._lanes()
         eye = jnp.where((lane & (C - 1)) == place, 1.0, 0.0)
         if self.over_one:
             # the levels from single rows up; the first finds inv = I
@@ -494,7 +525,7 @@ class _Own:
             inv = eye - beside(pairs[0])
             for level in pairs[1:]:
                 inv = inv - _mm32(_mm32(inv, level), spread(inv))
-            return spread(inv)
+            return inv
         inv = eye - power
         wide, span = spread(power), 2
         while span < C:
@@ -502,7 +533,15 @@ class _Own:
             wide = spread(power)
             inv = inv + _mm32(inv, wide)
             span *= 2
-        return spread(inv)
+        return inv
+
+
+def _solved(own, beta, vf, dt):
+    """``[U | W] = T [beta v | beta kt]`` of a stack in ONE float32 product,
+    before any state is at hand: ``U`` float32, ``W`` in the operands'
+    type."""
+    uw = _mm32(own.T, jnp.concatenate([beta * vf, beta * own.kt], axis=1))
+    return uw[:, :LANES], uw[:, LANES:].astype(dt)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest, chunk,
@@ -510,9 +549,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest, chunk,
     """The state TRANSPOSED, [dv, dk]: a key channel a lane, as the decays
     are.  ``X = U - W S`` with ``[U | W] = T [beta v | beta kt]`` made
     before the state is at hand: a chunk's turn at the state is two
-    products."""
+    products.  ``save``: for the backward, the solve of every stack side by
+    side (``_Own.side``) and the state each STACK found."""
     if save:
-        kept_ref, s_ref = rest
+        t_ref, kept_ref, s_ref = rest
     else:
         (s_ref,) = rest
     dt = q_ref.dtype
@@ -528,15 +568,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest, chunk,
         beta = _column(beta_ref[at, :], head)
         own = _Own(q_ref[at, :], k_ref[at, :], g_ref[at, :], beta, row,
                    code, chunk, over_one)
-        uw = _mm32(own.T, jnp.concatenate(
-            [beta * v_ref[at, :].astype(_F32), beta * own.kt], axis=1))
-        U, W = uw[:, :LANES], uw[:, LANES:].astype(dt)
+        U, W = _solved(own, beta, v_ref[at, :].astype(_F32), dt)
         qt, kh = own.qt.astype(dt), own.kh.astype(dt)
+        if save:
+            t_ref[p], kept_ref[p] = own.side, s_ref[...]
         reads, xs = [], []
         for c, rows in enumerate(own.chunks):
             st = s_ref[...]
-            if save:
-                kept_ref[p * len(own.chunks) + c] = st
             sb = st.astype(dt)
             x = (U[rows] - _dot(W[rows], sb, _NT)).astype(dt)
             reads.append(_dot(qt[rows], sb, _NT))
@@ -549,15 +587,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest, chunk,
     jax.lax.fori_loop(0, q_ref.shape[0] // ROWS, one, 0)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, kept_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, d_ref, *, chunk,
-                over_one):
-    """The stacks from the last to the first, each chunk from the state it
-    FOUND; ``D`` [dv, dk] the gradient of the state a chunk leaves.  A
-    chunk's turn at ``D`` is two products: ``dX = Q^T do + kh D`` and ``D'
-    = do^T qt + diag(lam) D - dX^T W`` (``W = T beta kt``: ``(beta dr)^T kt
-    = dX^T W``); everything else of the module's docstring is made for the
-    whole stack behind it."""
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, t_ref,
+                kept_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, d_ref, *,
+                chunk):
+    """The stacks from the last to the first, each from the solve the saving
+    forward made of it (``t_ref``: whichever solve that was) and the state
+    it FOUND; the states its later chunks found and ``X`` are the forward's
+    own lines again (``[U | W] = T [beta v | beta kt]``, ``X = U - W S``,
+    ``S' = diag(lam) S + kh^T X``), bit for bit.  ``D`` [dv, dk] the
+    gradient of the state a chunk leaves.  A chunk's turn at ``D`` is two
+    products: ``dX = Q^T do + kh D`` and ``D' = do^T qt + diag(lam) D - dX^T
+    W`` (``W = T beta kt``: ``(beta dr)^T kt = dX^T W``); everything else of
+    the module's docstring is made for the whole stack behind it."""
     C, dt = chunk, q_ref.dtype
     n = q_ref.shape[0] // ROWS
     head = pl.program_id(1)
@@ -573,16 +614,21 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, kept_ref,
         at = pl.ds(pl.multiple_of(p * ROWS, ROWS), ROWS)
         beta = _column(beta_ref[at, :], head)
         own = _Own(q_ref[at, :], k_ref[at, :], g_ref[at, :], beta, row,
-                   code, C, over_one)
+                   code, C, side=t_ref[p])
         qf, kf, do = own.qf, own.kf, do_ref[at, :]
         qt, kt, kh = (a.astype(dt) for a in (own.qt, own.kt, own.kh))
-        W = _mm32(own.T, beta * own.kt).astype(dt)
-        found = [kept_ref[p * len(own.chunks) + c]
-                 for c in range(len(own.chunks))]
-        sbs = [st.astype(dt) for st in found]
-        rr = v_ref[at, :].astype(_F32) - _rows(
+        vf = v_ref[at, :].astype(_F32)
+        U, W = _solved(own, beta, vf, dt)
+        found, sbs, xs = [kept_ref[p]], [], []
+        for c, rows in enumerate(own.chunks):
+            sbs.append(found[c].astype(dt))
+            xs.append((U[rows] - _dot(W[rows], sbs[c], _NT)).astype(dt))
+            if c + 1 < len(own.chunks):
+                found.append(found[c] * own.lam[c]
+                             + _dot(xs[c], kh[rows], _TN))
+        xb = _rows(xs)
+        rr = vf - _rows(
             [_dot(kt[rows], sb, _NT) for rows, sb in zip(own.chunks, sbs)])
-        xb = _mm32(own.T, beta * rr).astype(dt)
         qdo = _dot(own.Q.astype(dt), do, _TN)
         D = d_ref[...]
         dX, dkh, ends = ([None] * len(own.chunks) for _ in range(3))
@@ -658,9 +704,12 @@ class _Geom:
                                  lambda b, h, i: (b, at(i), h))
         self.beta = pl.BlockSpec((None, tokens, heads),
                                  lambda b, h, i: (b, at(i), 0))
-        self.kept = pl.BlockSpec(
-            (None, tokens // chunk, None, LANES, LANES),
-            lambda b, h, i: (b, at(i), h, 0, 0))
+        # what a saving forward keeps for the backward, a stack: the solve
+        # side by side and the state found
+        self.solve = pl.BlockSpec((None, None, stacks, chunk, ROWS),
+                                  lambda b, h, i: (b, h, at(i), 0, 0))
+        self.kept = pl.BlockSpec((None, stacks, None, LANES, LANES),
+                                 lambda b, h, i: (b, at(i), h, 0, 0))
         self.dbeta = pl.BlockSpec((None, None, stacks, ROWS),
                                   lambda b, h, i: (b, h, at(i), 0))
         self.grid = (self.B, heads, steps)
@@ -678,9 +727,12 @@ def _fwd(q, k, v, g, beta, static, save):
     out_specs = [geom.head]
     out_shape = [jax.ShapeDtypeStruct(v.shape, v.dtype)]
     if save:
-        out_specs.append(geom.kept)
-        out_shape.append(jax.ShapeDtypeStruct(
-            (geom.B, geom.S // chunk, heads, LANES, LANES), _F32))
+        out_specs += [geom.solve, geom.kept]
+        out_shape += [
+            jax.ShapeDtypeStruct(
+                (geom.B, heads, geom.S // ROWS, chunk, ROWS), _F32),
+            jax.ShapeDtypeStruct(
+                (geom.B, geom.S // ROWS, heads, LANES, LANES), _F32)]
     return pl.pallas_call(
         functools.partial(_fwd_kernel, chunk=chunk, save=save,
                           over_one=over_one),
@@ -694,14 +746,15 @@ def _fwd(q, k, v, g, beta, static, save):
 
 
 def _bwd(static, res, do):
-    heads, chunk, interpret, over_one = static
-    q, k, v, g, beta, kept = res
+    heads, chunk, interpret, _ = static
+    q, k, v, g, beta, solve, kept = res
     geom = _Geom(q, heads, chunk, flip=True)
     like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
     dq, dk, dv, dg, dbeta = pl.pallas_call(
-        functools.partial(_bwd_kernel, chunk=chunk, over_one=over_one),
+        functools.partial(_bwd_kernel, chunk=chunk),
         grid=geom.grid,
-        in_specs=[geom.head] * 4 + [geom.beta, geom.head, geom.kept],
+        in_specs=[geom.head] * 4 + [geom.beta, geom.head, geom.solve,
+                                    geom.kept],
         out_specs=[geom.head] * 4 + [geom.dbeta],
         out_shape=[like(q), like(k), like(v), like(g),
                    jax.ShapeDtypeStruct(
@@ -709,7 +762,7 @@ def _bwd(static, res, do):
         scratch_shapes=geom.scratch,
         compiler_params=geom.params, interpret=interpret,
         name="kda_chunk_bwd",
-    )(q, k, v, g, beta, do, kept)
+    )(q, k, v, g, beta, do, solve, kept)
     # [B, H, stacks, ROWS] -> [B, S, H]
     return dq, dk, dv, dg, dbeta.reshape(geom.B, heads, geom.S).swapaxes(1, 2)
 
@@ -720,8 +773,8 @@ def _delta(q, k, v, g, beta, static):
 
 
 def _delta_fwd(q, k, v, g, beta, static):
-    o, kept = _fwd(q, k, v, g, beta, static, True)
-    return o, (q, k, v, g, beta, kept)
+    o, solve, kept = _fwd(q, k, v, g, beta, static, True)
+    return o, (q, k, v, g, beta, solve, kept)
 
 
 _delta.defvjp(_delta_fwd, _bwd)
@@ -733,8 +786,9 @@ def kda_chunk(q, k, v, g, beta, *, heads, chunk=64, interpret=None,
     mixer has them: q, k, v, g [b, S, heads * 128] (head h is lane block h;
     g float32), beta [b, S, heads] float32; the outputs [b, S, heads * 128]
     in v's type, at ``kda_chunked``'s precision (``supported`` must hold).
-    Differentiable in all five; what the backward needs is the operands and
-    the state each chunk found (``kept_state_bytes``).  ``over_one``: the
+    Differentiable in all five; what the backward needs is the operands and,
+    a stack, the state found and the solve (``kept_state_bytes``, ``KEPT``).
+    ``over_one``: the
     strengths may pass 1 (``beta`` in (0, 2)), the solve by doubling."""
     b, S, width = k.shape
     assert width == heads * LANES and q.shape == v.shape == g.shape \

@@ -2265,7 +2265,8 @@ def _kda_flat(pl, h, cfg, q, k, v):
     ``kernels/kda_rows.py``'s one pass each way for the two L2 norms, the
     log-decays and the norm-then-gate, ``kernels/kda_chunk.py``'s kernels
     between them (which keep the operands, as the layer's remat does anyway,
-    and a state a chunk: 537 MB at [16384, 32 x 128]).  No [b, S, heads, d]
+    and a state and a solve a STACK of 128 rows: 268 + 134 MB at [16384, 32 x
+    128], where a state a chunk was 537).  No [b, S, heads, d]
     view of a sequence-sized array: on the chip that view is a copy."""
     from ..kernels import kda_chunk, kda_rows
 
@@ -2311,7 +2312,9 @@ def kda_mixer(pl, h, cfg):
     q, k, v = (_kda_filtered(pl, h, cfg, name) for name in "qkv")
     fused = kda_chunk.supported((b, S, nh, d), d, cfg.kda_chunk, k.dtype) \
         and kda_rows.supported(k.shape, d, k.dtype.itemsize)
-    count_call("kda_chunk", fused=int(fused))
+    # ``kept``: what the delta rule's backward reads beside the operands
+    count_call("kda_chunk", fused=int(fused),
+               kept=kda_chunk.KEPT if fused else "chunk")
     for part in kda_rows.PARTS:
         count_call("kda_rows", part=part, fused=int(fused))
     if fused:

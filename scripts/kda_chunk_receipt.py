@@ -18,15 +18,25 @@ write at 1.999):
   gradient keeps a [32, 128, 128] state a token: 2.1 GB), both decays;
 - a control with the state dropped at every chunk edge (each chunk run from
   a zero state), which must NOT pass at decays near one;
-- the milliseconds a call of the forward and of forward + backward at the
-  whole shape, kernels and ``jnp`` form (host clock around
-  ``block_until_ready``, the median of CALLS), beside the least the
-  requirement's bytes allow
-  (``benchmark/flops/kimi_linear_train.py:delta_rule``).
+- the milliseconds a call at the whole shape, kernels and ``jnp`` form (host
+  clock around ``block_until_ready``, the median of CALLS): the forward
+  that keeps nothing (``forward``), the forward that runs for a backward
+  (``saving_forward``: ``jax.vjp``'s, which writes what the backward reads),
+  the backward ALONE (``backward``: the ``vjp`` function of those kept
+  values) and both in one program (``forward_and_backward``), beside the
+  least the requirement's bytes allow
+  (``benchmark/flops/kimi_linear_train.py:delta_rule``); ``kept_bytes``:
+  what the saving forward handed the backward less the operands' own bytes,
+  by the arrays themselves; ``first_forward_sha256``: of the kernels' output
+  at the whole shape, decays near one (two trees that read the same made the
+  same ``o``).
 
-One JSON line, kept under ``chiprun_out/pr59/``.  Exit 1 where a reading is
-off, 2 off a TPU."""
+One JSON line, kept under ``chiprun_out/pr72/`` (PR 59's and PR 67's under
+their own).  The script reads the tree it lies in: copied into
+``_checkout/parent/scripts/`` it reads the parent's kernels, so one chip call
+holds parent beside change.  Exit 1 where a reading is off, 2 off a TPU."""
 
+import hashlib
 import json
 import math
 import os
@@ -52,8 +62,8 @@ NAMES = ("q", "k", "v", "g", "beta")
 # bf16 operands of a chain of products against float32; the log-decays'
 # gradient is a sum of both signs over every later token of the chunk
 LIMIT, DECAY_LIMIT = 2e-2, 6e-2
-CALLS = 5
-OUT = os.path.join(ROOT, "chiprun_out", "pr59", "kda_chunk_receipt.json")
+CALLS = 9
+OUT = os.path.join(ROOT, "chiprun_out", "pr72", "kda_chunk_receipt.json")
 
 
 def _rel(got, want):
@@ -187,8 +197,23 @@ def main(out_path=OUT, shape="kimi"):
     for form, (fn, operands_, w_) in timed.items():
         both = jax.jit(jax.grad(lambda *a, fn=fn, w_=w_: jnp.sum(
             (fn(*a) * w_).astype(jnp.float32)), argnums=(0, 1, 2, 3, 4)))
-        out["ms"][form] = {"forward": _ms(jax.jit(fn), operands_),
-                           "forward_and_backward": _ms(both, operands_)}
+        # the backward alone: ``jax.vjp``'s function is a pytree of what
+        # the saving forward kept
+        saving = jax.jit(lambda *a, fn=fn: jax.vjp(fn, *a))
+        o, kept = jax.block_until_ready(saving(*operands_))
+        out["ms"][form] = {
+            "forward": _ms(jax.jit(fn), operands_),
+            "saving_forward": _ms(saving, operands_),
+            "backward": _ms(jax.jit(lambda f, c: f(c)), (kept, w_.astype(
+                o.dtype))),
+            "forward_and_backward": _ms(both, operands_),
+            "kept_bytes": sum(a.nbytes for a in jax.tree_util.tree_leaves(
+                kept)) - sum(a.nbytes for a in operands_)}
+        if form == "kernel":
+            out["first_forward_sha256"] = hashlib.sha256(np.asarray(
+                jax.jit(fn)(*operands_).astype(jnp.float32)).tobytes()
+            ).hexdigest()
+        del o, kept
     model = {"linear_attn_config": {"num_heads": H, "head_dim": D}}
     need = kimi_linear_train.delta_rule(model, B * S)
     peaks = PEAKS["TPU v5 lite"]

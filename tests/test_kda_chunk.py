@@ -6,6 +6,7 @@ extremes (``a_log`` = ln 16, a step of 0.7: ``G`` falls by 11 a token, and
 case that must come out finite and right) and near 1 (a state that outlives
 every chunk: the carry must matter)."""
 
+import functools
 import math
 
 import jax
@@ -253,8 +254,27 @@ def test_bf16_operands_stay_near_float32_and_finite():
 
 
 def test_kept_state_bytes_is_a_state_a_chunk_and_head():
-    assert K.kept_state_bytes(1, 16384, 64, 32, 128, 128) == 256 * 32 * 65536
-    assert K.kept_state_bytes(2, 200, 64, 3, 16, 32) == 2 * 4 * 3 * 16 * 32 * 4
+    """Since PR 72 a state and the chunks' solves side by side a STACK of 128
+    rows and head, and THE RULE that paid for the solves: never more than
+    the state a chunk the kernels kept before (537 MB a layer at
+    Kimi-Linear's shape, 268 MB at Solar-Open2's), at chunks of two or more a
+    stack (every configuration's: 64; ONE chunk a stack keeps its state and
+    a solve as large)."""
+    state, side = 128 * 128 * 4, 64 * 128 * 4
+    kimi = K.kept_state_bytes(1, 16384, 64, 32, 128, 128)
+    solar = K.kept_state_bytes(1, 4096, 64, 64, 128, 128)
+    assert kimi == 128 * 32 * (state + side) == 402_653_184
+    assert solar == 32 * 64 * (state + side) == 201_326_592
+    assert kimi <= 256 * 32 * state == 536_870_912
+    assert solar <= 64 * 64 * state == 268_435_456
+    for chunk in (16, 32, 64):
+        assert K.kept_state_bytes(2, 1024, chunk, 3, 128, 128) \
+            <= 2 * (1024 // chunk) * 3 * state
+    assert K.kept_state_bytes(2, 1024, 128, 3, 128, 128) \
+        == 2 * 2 * 8 * 3 * state
+    # a ragged sequence: whole stacks
+    assert K.kept_state_bytes(2, 200, 64, 3, 128, 128) \
+        == 2 * 2 * 3 * (state + side)
 
 
 # ---------------------------------------------------------------------------
@@ -317,17 +337,29 @@ def test_the_kernels_outputs_at_strengths_up_to_two(strength, heads):
         assert np.abs(got - other).max() < 1e-5 * np.abs(want).max()
 
 
+# chunks of 64: two a stack, the second from a state the backward REBUILDS
+# from the stack's kept one; of 32: four a stack, three rebuilt; of 128: one,
+# nothing rebuilt
 @pytest.fixture(scope="module", params=[
-    (d, t, "under_one") for d in sorted(DECAYS)
+    (d, t, "under_one", CHUNK) for d in sorted(DECAYS)
     for t in ("float32", "bfloat16")] + [
-        ("extreme", "float32", "under_two"), ("near_one", "float32", "1.999"),
-        ("near_one", "float32", "one"), ("near_one", "bfloat16", "under_two")],
-    ids=lambda p: "-".join(p[:2] if p[2] == "under_one" else p))
+        ("extreme", "float32", "under_two", CHUNK),
+        ("near_one", "float32", "1.999", CHUNK),
+        ("near_one", "float32", "one", CHUNK),
+        ("near_one", "bfloat16", "under_two", CHUNK),
+        ("near_one", "float32", "under_one", 32),
+        ("extreme", "float32", "under_two", 32),
+        ("near_one", "bfloat16", "under_two", 32),
+        ("near_one", "float32", "under_one", 128),
+        ("near_one", "float32", "1.999", 128),
+        ("extreme", "bfloat16", "under_one", 128)],
+    ids=lambda p: "-".join(p[:2] if p[2] == "under_one" else p[:3])
+    + ("" if p[3] == CHUNK else "-chunks_of_%d" % p[3]))
 def kernel_gradients(request):
-    """Of sum(o * w) over four chunks (two stacks): by the kernels, by the
-    chunked form and by the recurrence."""
-    decays, dtype, strength = request.param
-    S = 4 * CHUNK
+    """Of sum(o * w) over two stacks (four chunks of 64, eight of 32, two of
+    128): by the kernels, by the chunked form and by the recurrence."""
+    decays, dtype, strength, chunk = request.param
+    S = 2 * K.ROWS
     args = _kernel_operands(S, decays, seed=5, dtype=jnp.dtype(dtype),
                             strength=strength)
     w = jax.random.normal(jax.random.PRNGKey(10), (KB, S, KH, KD))
@@ -336,8 +368,8 @@ def kernel_gradients(request):
         argnums=(0, 1, 2, 3, 4))(*args)
     over_one = strength != "under_one"
     return (decays, dtype,
-            grad(lambda *a: _kernels(*a, over_one=over_one)),
-            grad(lambda *a: K.kda_chunked(*a, chunk=CHUNK,
+            grad(lambda *a: _kernels(*a, chunk=chunk, over_one=over_one)),
+            grad(lambda *a: K.kda_chunked(*a, chunk=chunk,
                                           over_one=over_one)),
             grad(K.kda_recurrence))
 
@@ -361,6 +393,129 @@ def test_every_gradient_of_the_kernels_equals_both_forms(kernel_gradients,
         o = np.asarray(other[at].astype(jnp.float32))
         assert np.abs(o).max() > 0
         np.testing.assert_allclose(g, o, rtol=0, atol=tol * np.abs(o).max())
+
+
+def _saved(args, chunk, over_one, save=True):
+    """``(o, T, kept)`` of the forward that runs for a backward (``save``
+    False: ``(o,)`` of the one that keeps nothing)."""
+    flat = lambda a: a.reshape(a.shape[:2] + (-1,))         # noqa: E731
+    q, k, v, g, beta = args
+    return K._fwd(flat(q), flat(k), flat(v), flat(g), beta,
+                  (k.shape[2], chunk, True, over_one), save)
+
+
+@pytest.mark.parametrize("over_one,strength,chunk", [
+    (False, "under_one", 64), (True, "under_two", 64), (True, "1.999", 64),
+    (False, "under_one", 32), (True, "1.999", 32), (True, "under_two", 128)])
+def test_the_saving_forward_keeps_the_solve_and_a_state_a_stack(
+        over_one, strength, chunk):
+    """What ``kda_chunk_bwd`` reads and no longer makes: ``T`` of every
+    chunk, side by side a stack, is ``_unit_lower_inverse`` of the same ``A``
+    by the same solve (and an inverse: ``T (I + A) = I``); the kept states
+    are the recurrence's at each stack's first token; ``o`` is the first
+    forward's bit for bit."""
+    S = 2 * K.ROWS
+    args = q, k, v, g, beta = _kernel_operands(S, "near_one", seed=13,
+                                               strength=strength)
+    o, solve, kept = _saved(args, chunk, over_one)
+    per, n = K.ROWS // chunk, S // chunk
+    assert solve.shape == (KB, KH, S // K.ROWS, chunk, K.ROWS) \
+        and kept.shape == (KB, S // K.ROWS, KH, KD, KD) \
+        and solve.dtype == kept.dtype == jnp.float32
+    assert solve.nbytes + kept.nbytes == K.kept_state_bytes(
+        KB, S, chunk, KH, KD, KD)
+    # [b, H, stacks, C, per x C] -> [b, H, n, C, C]
+    got = np.asarray(jnp.moveaxis(solve.reshape(
+        KB, KH, S // K.ROWS, chunk, per, chunk), 4, 3)).reshape(
+            KB, KH, n, chunk, chunk)
+    chunks = lambda a: jnp.moveaxis(                        # noqa: E731
+        a.reshape((KB, n, chunk, KH) + a.shape[3:]), 3, 1)
+    kc, G, bc = chunks(k), jnp.cumsum(chunks(g), axis=-2), chunks(beta)
+    A = K._decayed_product(kc, kc, K._decay_blocks(G), jnp.float32) \
+        * jnp.tril(jnp.ones((chunk, chunk)), -1) * bc[..., None]
+    want = np.asarray(K._unit_lower_inverse(A, over_one))
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-5 * np.abs(
+        want).max())
+    # the strengths under 1 and the doubling keep the digits; the squarings
+    # past 1 are what ``over_one`` is for
+    np.testing.assert_allclose(
+        got.astype(np.float64) @ (np.eye(chunk) + np.asarray(A, np.float64)),
+        np.broadcast_to(np.eye(chunk), got.shape), atol=2e-5)
+    for stack in range(S // K.ROWS):
+        _, state = _recurrence64(*(a[:, :stack * K.ROWS] for a in args))
+        # the kernels' state is transposed: [dv, dk]
+        np.testing.assert_allclose(
+            np.asarray(kept[:, stack]), np.swapaxes(state, -1, -2),
+            rtol=1e-4, atol=1e-5 * max(np.abs(state).max(), 1.0))
+    first = _saved(args, chunk, over_one, save=False)
+    assert len(first) == 1
+    np.testing.assert_array_equal(np.asarray(first[0]), np.asarray(o))
+
+
+# the first 16 hex digits of the sha256 of the kernel of the forward that
+# keeps nothing (``save`` False: its jaxpr inside the ``pallas_call``, bf16
+# operands, two heads, two stacks), taken on PR 72's parent (bacf4c1): it is
+# the text it was, so its outputs are the parent's (and the saving forward's
+# ``o`` is its ``o``: the test above)
+@pytest.mark.parametrize("over_one,chunk,digest", [
+    (False, 64, "b2dcb1e4ec2e268c"), (False, 32, "4217069676a8981f"),
+    (False, 128, "65df2af1c93876e8"), (True, 64, "4b011043ebe26858"),
+    (True, 32, "81bf38fca3e658b9"), (True, 128, "780a89a40eb1a046")])
+def test_the_forward_that_keeps_nothing_is_the_parent_s_text(over_one, chunk,
+                                                             digest):
+    import hashlib
+
+    sds = jax.ShapeDtypeStruct
+    flat = (1, 2 * K.ROWS, 2 * K.LANES)
+    args = (sds(flat, jnp.bfloat16),) * 3 + (
+        sds(flat, jnp.float32), sds(flat[:2] + (2,), jnp.float32))
+    jaxpr = jax.make_jaxpr(lambda *a: K._fwd(
+        *a, (2, chunk, True, over_one), False))(*args)
+    call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert hashlib.sha256(str(call.params["jaxpr"]).encode()).hexdigest()[
+        :16] == digest
+
+
+def _kernel_calls(jaxpr, found):
+    """``(name, number of outputs)`` of every ``pallas_call`` under
+    ``jaxpr``, in order."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((eqn.params["name"], len(eqn.outvars)))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _kernel_calls(sub, found)
+    return found
+
+
+def test_under_a_layer_s_remat_both_forwards_are_the_saving_kernel():
+    """What the step runs, as a trace says it: a layer under
+    ``jax.checkpoint`` in a scan, as the decoder's, differentiates through
+    ``kda_chunk``'s ``custom_vjp`` forward in BOTH passes, so the FIRST
+    forward is the saving kernel too and what it keeps is written and
+    dropped (``save`` False runs where nothing is differentiated).  PR 72
+    measured the step with the first forward keeping nothing (a
+    ``custom_dce`` rule): its kernel 12.89 -> 11.27 ms a call in the trace
+    and NOTHING end to end (PERF.md section 6), so the plain form stands."""
+    args = _kernel_operands(2 * K.ROWS, "near_one", dtype=jnp.bfloat16)
+    flat = tuple(a.reshape(a.shape[:2] + (-1,)) for a in args[:4]) + args[4:]
+    rule = lambda *a: K.kda_chunk(*a, heads=KH, chunk=CHUNK)   # noqa: E731
+
+    def loss(layer, q, *rest):
+        y, _ = jax.lax.scan(lambda c, _: (layer(c, *rest), None), q, None,
+                            length=2)
+        return jnp.sum(y.astype(jnp.float32))
+
+    grad = lambda layer: jax.make_jaxpr(jax.grad(         # noqa: E731
+        functools.partial(loss, layer), argnums=(0, 1, 2, 3, 4)))(*flat).jaxpr
+    assert _kernel_calls(grad(jax.checkpoint(rule)), []) == [
+        ("kda_chunk_fwd", 3), ("kda_chunk_fwd", 3), ("kda_chunk_bwd", 5)]
+    assert _kernel_calls(grad(rule), []) == [
+        ("kda_chunk_fwd", 3), ("kda_chunk_bwd", 5)]
+    assert _kernel_calls(jax.make_jaxpr(rule)(*flat).jaxpr, []) == [
+        ("kda_chunk_fwd", 1)]
 
 
 def test_the_kernels_carry_over_eight_chunks_with_a_given_state():
@@ -414,9 +569,10 @@ def test_supported_follows_from_the_shapes_alone(what, shape, dv, chunk,
 @pytest.mark.parametrize("width,fused", [(128, 1), (16, 0)],
                          ids=["a head 128 wide", "a head 16 wide"])
 def test_the_mixer_counts_the_form_that_ran(width, fused):
-    """``monitor.kernels.kda_chunk_calls{fused}``: 1 where the mixer's
-    shapes take the kernels, 0 where the ``jnp`` form ran, and the two give
-    the same layer."""
+    """``monitor.kernels.kda_chunk_calls{fused, kept}``: 1 where the mixer's
+    shapes take the kernels (``kept``: what their backward reads beside the
+    operands, ``K.KEPT``: a state and the solve a stack), 0 where the ``jnp``
+    form ran (a state a chunk), and the two give the same layer."""
     from paddle_tpu import monitor
     from paddle_tpu.models import kimi_linear
     from paddle_tpu.parallel import transformer as T
@@ -432,8 +588,10 @@ def test_the_mixer_counts_the_form_that_ran(width, fused):
     mon = monitor.enable()
     try:
         # the registry outlives a session: count from where it stood
+        assert K.KEPT == "stack+T"
         calls = [mon.registry.counter("monitor.kernels.kda_chunk_calls",
-                                      fused=f) for f in (0, 1)]
+                                      fused=f, kept=kept)
+                 for f, kept in ((0, "chunk"), (1, K.KEPT))]
         before = [c.value for c in calls]
         got = T.kda_mixer(leaves, h, cfg)
     finally:
@@ -453,9 +611,13 @@ def test_the_mixer_counts_the_form_that_ran(width, fused):
 
 def test_vmem_bytes_follows_the_blocks_of_a_grid_step():
     # seven operand blocks and two float32 of 1,024 tokens, beta's lane
-    # tile, sixteen kept states, beta's gradient, twice; the state's
-    # gradient; a stack's values
+    # tile, eight kept states and eight solves of two chunks side by side,
+    # beta's gradient, twice; the state's gradient; a stack's values
     assert K.vmem_bytes(64, 32, 2) == 2 * (
         7 * 1024 * 128 * 2 + 2 * 1024 * 128 * 4 + 1024 * 128 * 4
-        + 16 * 128 * 128 * 4 + 8 * 128 * 4) + 128 * 128 * 4 \
-        + 96 * 128 * 128 * 4 + (8 << 20)
+        + 8 * 128 * 128 * 4 + 8 * 64 * 128 * 4 + 8 * 128 * 4) \
+        + 128 * 128 * 4 + 96 * 128 * 128 * 4 + (8 << 20)
+    # the kept blocks of a grid step: never more than a state a chunk's
+    for chunk in (32, 64, 128):
+        assert K.vmem_bytes(chunk, 32, 2) - K.vmem_bytes(64, 32, 2) \
+            == 2 * 8 * (chunk - 64) * 128 * 4
