@@ -9,7 +9,8 @@ Two styles:
   causal decoders, each a ``TransformerConfig`` and a label over
   ``parallel/decoder.py``'s one trainer (olmoe.py, smallthinker.py, lfm2.py,
   brumby.py, mistral4.py, trinity.py, jamba.py, nemotron_h.py, ouro.py,
-  kimi_linear.py, keye_vl2.py, dots3.py, solar_open2.py, sdar.py).
+  kimi_linear.py, keye_vl2.py, dots3.py, solar_open2.py, sdar.py,
+  kanana2.py).
 """
 
 from . import bert  # noqa: F401

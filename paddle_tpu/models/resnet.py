@@ -281,7 +281,10 @@ def make_loss_fn(cfg: ResNetConfig):
         with jax.named_scope(devscope.LOSS):
             logp = jax.nn.log_softmax(logits, axis=-1)
             nll = -jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
-            loss = col.psum(jnp.sum(nll), DP) / col.psum(
+            # the value summed over dp, each shard's cotangent its own (a
+            # plain psum hands every shard the cotangents of ALL dp copies
+            # of the loss: a gradient dp times too large)
+            loss = col.psum_forward(jnp.sum(nll), DP) / col.psum(
                 jnp.asarray(nll.shape[0], jnp.float32), DP)
         with jax.named_scope(devscope.GRAD_SYNC):
             new_state = jax.tree.map(lambda a: col.pmean(a, DP), new_state)

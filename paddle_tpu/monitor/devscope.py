@@ -98,12 +98,17 @@ LOOP_SCAN, EXIT_GATE = "loop_scan", "exit_gate"
 # over the other (the rows ``[x_t ; x_0]`` the stack runs on), and the noised
 # rows cut out again in front of the head
 NOISE = "noise"
+# expert parallelism outside the matmuls, inside an expert FFN's scope: the
+# pack of a device's (token, expert) rows by destination, the ``all_to_all``
+# out and back, the sort by local expert on arrival and its inverse, forward
+# and backward
+EXCHANGE = "exchange"
 VOCABULARY = (EMBED, ATTENTION, MLP, LAYER_NORM, LM_HEAD, CONV, BN, POOL, FC,
               LOSS, GRAD_SYNC, OPTIMIZER, MOE, ROUTER, SHORT_CONV, RETENTION,
               LATENT_ATTENTION, SHARED_EXPERT, LAYER_SCAN, ATTN_GATE,
               POST_NORM, MAMBA, SELECTIVE_SCAN, MAMBA2, SSD_SCAN, LOOP_SCAN,
               EXIT_GATE, KDA, KDA_CHUNK, INDEXER, INDEXER_SELECT, SPARSE_ATTN,
-              INDEXER_KL, MLA_DSA, MLA_SWA, NOISE)
+              INDEXER_KL, MLA_DSA, MLA_SWA, NOISE, EXCHANGE)
 PHASES = ("forward", "backward", "recompute", GRAD_SYNC, OPTIMIZER)
 
 # `%fusion.12 = bf16[..] fusion(%p.1, %copy-done.2), ..., metadata={op_name="jit(multi)/..." ...}`:

@@ -19,6 +19,7 @@ from jax import lax
 __all__ = [
     "axis_present",
     "psum",
+    "psum_forward",
     "pmean",
     "pmax",
     "all_gather",
@@ -56,6 +57,21 @@ def psum(x, axis):
     if not axis_present(axis) or axis_size_in(axis) == 1:
         return x
     return lax.psum(x, axis)
+
+
+def psum_forward(x, axis):
+    """The all-reduce sum of a LOSS TERM: the value is ``psum(x)``, the
+    cotangent that reaches ``x`` is the result's own.  Inside a ``shard_map``
+    without the replication check ``psum`` transposes to ``psum``: every
+    device differentiates its own copy of the replicated loss, so a plain
+    ``psum`` in the loss hands each device's local terms the cotangents of
+    ALL the copies, the axis' size times too much, in every leaf alike
+    (Adam's update does not see a common factor; SGD's, a clip's or a
+    reference's gradient does).  ``global_mean_loss`` is this over a
+    count."""
+    if not axis_present(axis) or axis_size_in(axis) == 1:
+        return x
+    return x + lax.stop_gradient(lax.psum(x, axis) - x)
 
 
 def pmean(x, axis):

@@ -4,7 +4,7 @@
 decoder of this block.  A model (``models/olmoe.py``, ``smallthinker.py``,
 ``lfm2.py``, ``brumby.py``, ``mistral4.py``, ``trinity.py``, ``jamba.py``,
 ``nemotron_h.py``, ``ouro.py``, ``kimi_linear.py``, ``keye_vl2.py``,
-``dots3.py``, ``solar_open2.py``, ``sdar.py``)
+``dots3.py``, ``solar_open2.py``, ``sdar.py``, ``kanana2.py``)
 is a ``TransformerConfig`` and a label; what its trainer computes and what
 it observes follow from the configuration, never from which model it is.
 
@@ -28,7 +28,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import moe, optim
 from .. import monitor
@@ -271,6 +271,16 @@ def probe(params, ids, cfg):
       busiest expert over the mean, the largest over layers; where a share
       of the experts is held, ``moe_rows_held``, the (token, expert) pairs
       that meet a held expert, summed over layers (the layer counts them);
+      where the experts ride dp over more than one device
+      (``expert_parallel``), what the exchange did
+      (``moe._exchange_aux``): ``moe_rows_sent``, the pairs of the whole
+      batch that left their device, summed over layers;
+      ``moe_rows_received``, the rows the fullest device's grouped matmuls
+      ran over in its fullest layer; ``moe_exchange_fullest``, the most
+      rows any device had for one destination in any layer, beside
+      ``moe_exchange_capacity``, the rows a round carries a destination;
+      ``moe_exchange_tier``, the rounds past the first that the fullest
+      layer needed (0 under balance);
     - ``routing`` with selection biases: ``router_bias_abs_max``, the
       largest, any layer;
     - a layer kind is RETENTION: ``retention_gate_mean``, the mean ``e^g``
@@ -322,6 +332,12 @@ def probe(params, ids, cfg):
         out["moe_load_max_over_mean"] = jnp.max(aux["load_max_over_mean"])
         if "rows_held" in aux:
             out["moe_rows_held"] = jnp.sum(aux["rows_held"])
+        if "exchange_tier" in aux:      # the experts ride dp, and dp > 1
+            out["moe_rows_sent"] = jnp.sum(aux["rows_sent"])
+            out["moe_rows_received"] = jnp.max(aux["rows_received"])
+            out["moe_exchange_fullest"] = jnp.max(aux["exchange_fullest"])
+            out["moe_exchange_capacity"] = aux["exchange_capacity"][0]
+            out["moe_exchange_tier"] = jnp.max(aux["exchange_tier"])
         if cfg.indexer_heads:
             out["dsa_kl_mean"] = _dsa_kl_mean(aux, cfg)
             out["dsa_mass_selected"], out["dsa_pairs_selected"] = \
@@ -494,8 +510,13 @@ def build_decoder_trainer(cfg, mesh_spec: MeshSpec = None, optimizer=None,
                           seed=0, devices=None, label="decoder",
                           positions=False):
     """Mesh, parameters on the mesh, the jitted sharded step and its scan.
-    Data parallel only: the block has no tensor-, pipeline- or
-    expert-parallel layout yet.  A router's selection biases, where the
+    Meshes: ``dp`` any, ``tp == pp == 1`` (the block has no tensor- or
+    pipeline-parallel layout yet).  Sequences are split over ``dp`` and
+    every leaf is replicated and its gradient all-reduced, EXCEPT the routed
+    experts of a configuration with ``expert_parallel``: they ride ``dp``
+    (``dp`` has to divide ``n_experts``; each device holds n / dp experts of
+    every layer, seeds them in place and keeps their moments; the MoE layer
+    exchanges rows, ``moe.dropless_moe_ffn``).  A router's selection biases, where the
     parameters hold them, are the step's to set and not the optimizer's.
     ``positions``: every batch carries ``positions`` int32 [3, B, S] beside
     its ``ids`` (``POSITION_SPECS``); a block-diffusion configuration's
@@ -503,11 +524,20 @@ def build_decoder_trainer(cfg, mesh_spec: MeshSpec = None, optimizer=None,
     mesh_spec = mesh_spec or MeshSpec()
     assert mesh_spec.tp == mesh_spec.pp == cfg.tp == cfg.pp == 1, \
         "the decoder block runs at tp == pp == 1"
+    assert not cfg.expert_parallel or cfg.n_experts % mesh_spec.dp == 0, \
+        "expert_parallel: dp %d does not divide the %d experts" % (
+            mesh_spec.dp, cfg.n_experts)
     mesh = mesh_spec.build(devices=devices)
     optimizer = optimizer or optim.adamw()
 
-    params = init_transformer_params(jax.random.PRNGKey(seed), cfg)
     pspecs = transformer_param_specs(cfg)
+    # an expert-parallel stack is seeded on the mesh, each device its own
+    # experts, and its moments are made from the placed leaves (zeros of a
+    # placed array lie where it lies): no device ever holds the whole state
+    params = init_transformer_params(
+        jax.random.PRNGKey(seed), cfg,
+        shardings=jax.tree.map(lambda spec: NamedSharding(mesh, spec), pspecs)
+        if cfg.expert_parallel else None)
     state = TrainState.create(params, optimizer)
     sspecs = state_specs(pspecs, state)
     build = make_train_step(make_loss_fn(cfg), mesh, pspecs,

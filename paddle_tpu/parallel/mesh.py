@@ -18,8 +18,10 @@ from jax.sharding import Mesh, PartitionSpec
 __all__ = ["MeshSpec", "make_mesh", "axis_size", "local_shard_map"]
 
 # Canonical axis names.  dp = data parallel (batch), pp = pipeline stages,
-# tp = tensor parallel (also carries sequence parallelism and, by default,
-# expert parallelism rides dp).
+# tp = tensor parallel (also carries sequence parallelism).  Expert
+# parallelism rides dp: ``TransformerConfig.expert_parallel`` splits a
+# decoder's routed experts over it (``rules.transformer_rules``) and the MoE
+# layer exchanges rows over it (``moe.dropless_moe_ffn(ep_axis=DP)``).
 DP, PP, TP = "dp", "pp", "tp"
 
 

@@ -1,7 +1,8 @@
-"""Mixture-of-Experts FFNs.  Two layers, for two layouts:
+"""Mixture-of-Experts FFNs.  ONE dropless layer in three layouts, and what
+is left of an older one:
 
-- ``dropless_moe_ffn``: top-k routing with NO capacity and no dropped token,
-  every expert on this device.  The T*k (token, expert) pairs are sorted by
+- ``dropless_moe_ffn``: top-k routing with NO capacity and no dropped token.
+  The T*k (token, expert) pairs are sorted by
   expert, the rows gathered in that order, and two GROUPED matmuls run over
   the sorted rows (the Pallas ``megablox`` kernels that ship with JAX: row i
   meets the weights of its own group only, so nothing is computed for a
@@ -22,22 +23,42 @@
   experts chosen by score PLUS a per-expert bias and weighted by the
   scores WITHOUT it, renormalised, the bias a running state that
   ``balance_bias`` moves against the load and no gradient reaches), router
-  logits the caller computed from another input, the gate's activation (``ACTIVATIONS``), and WHICH EXPERTS
-  THIS DEVICE HOLDS (``first_held`` and the leading size of the experts'
-  leaves): the router still ranks all n, the pairs whose expert is held are
-  sorted to the front, only a static number of rows that covers them is
-  gathered and multiplied (``_held_capacities``), the sum back fetches the
-  rows that exist and skips the pair slots that have none (a gather would
-  fetch a row or the zero row for every slot), and what the absent experts
-  would add is left out.  No pair that meets a held expert is dropped,
-  whatever the routing.
+  logits the caller computed from another input, the gate's activation
+  (``ACTIVATIONS``), and WHERE THE EXPERTS ARE:
+
+  * ALL HELD: every expert on this device (OLMoE);
+  * A SHARE (``first_held`` and the leading size of the experts' leaves):
+    the router still ranks all n, the pairs whose expert is held are
+    sorted to the front, only a static number of rows that covers them is
+    gathered and multiplied (``_held_capacities``), the sum back fetches the
+    rows that exist and skips the pair slots that have none (a gather would
+    fetch a row or the zero row for every slot), and what the absent experts
+    would add is left out.  No pair that meets a held expert is dropped,
+    whatever the routing;
+  * EXPERT-PARALLEL (``ep_axis``, by convention ``dp``: "EP rides DP";
+    ``TransformerConfig.expert_parallel``): the devices of the axis hold
+    n / ep experts each (``parallel/rules.py`` splits the experts' leaves
+    over the axis) and EXCHANGE rows.  A device's pairs are sorted by expert,
+    hence by destination, packed into a static number of rows a destination
+    (``_held_capacities``' first capacity: 1.25 times what uniform routing
+    sends), sent with ``lax.all_to_all``, sorted there by local expert, run
+    through the same grouped matmuls, sent back and summed a token by the
+    same row kernel.  NO PAIR IS DROPPED whatever the routing: a step makes
+    as many ROUNDS of that exchange as the fullest destination of any
+    device needs (``pmax`` over the axis, so every device makes the same
+    number and the collectives line up): one under balance, ``ceil(T k /
+    capacity)`` where every pair of every device meets one device's experts
+    (``_exchange_ffn``).  On an axis of size 1 this is the all-held layer:
+    no collective, no packing.
+
 - ``switch_moe_ffn``: top-1 (Switch) routing with a capacity limit that
-  DROPS the overflow, experts sharded over a mesh axis (by default ``dp``,
-  "EP rides DP") and exchanged with ``lax.all_to_all`` over ICI.  Net-new
+  DROPS the overflow, experts sharded over a mesh axis and exchanged with
+  ``lax.all_to_all``.  Net-new
   against the reference (SURVEY.md section 2.9: it has no expert
   parallelism; its sparse story is the PSLib parameter server,
-  fleet/fleet_wrapper.h:55).  The multichip dry run is its one caller; the
-  two fold into one when expert parallelism gets a model (ROADMAP.md).
+  fleet/fleet_wrapper.h:55).  The multichip dry run is its one caller; it is
+  what is LEFT TO FOLD into the dropless exchange above, which now has a
+  model and a cell (ROADMAP.md Design 11: a ``simplicity`` PR).
 
 Per-device code for use inside shard_map bodies (parallel/train.py).
 """
@@ -168,9 +189,10 @@ def route_top_k(router, x, k, rule=SOFTMAX_TOP_K, logits=None, bias=None,
         top_p = jax.nn.softmax(top_l, axis=-1)
     counts = col.psum(_per_expert(top_e, n), DP)
     share = counts.astype(jnp.float32) / (tokens * k)
-    mean_p = col.psum(jnp.sum(probs, axis=0), DP) / tokens
+    mean_p = col.psum_forward(jnp.sum(probs, axis=0), DP) / tokens
     aux = {"load_balance": n * jnp.sum(share * mean_p),
-           "router_z": col.psum(jnp.sum(jnp.square(lse)), DP) / tokens,
+           "router_z": col.psum_forward(jnp.sum(jnp.square(lse)), DP)
+           / tokens,
            "load_max_over_mean": jnp.max(share) * n}
     return _scaled(top_p, scale), top_e, aux
 
@@ -404,7 +426,6 @@ def _expert_ffn(x, top_p, top_e, w_gate_up, w_down, k, act, first, rows_max):
     Where ``w_gate_up`` is as wide as ``w_down`` is tall ([count, E, F]) the
     experts are UNGATED: ``down_e(act(up_e x_t))``."""
     count, absent = w_gate_up.shape[0], rows_max is not None
-    gated = w_gate_up.shape[2] == 2 * w_down.shape[1]
     key = top_e
     if absent:
         held = _held(top_e.reshape(-1), first, count)
@@ -425,16 +446,24 @@ def _expert_ffn(x, top_p, top_e, w_gate_up, w_down, k, act, first, rows_max):
         # as no pair's place points at them
         live = jnp.arange(rows_max) < jnp.sum(group_sizes)
         weight = jnp.where(live, weight[:rows_max], 0.0)
+    out = _sorted_rows_ffn(rows, weight, w_gate_up, w_down, group_sizes, act)
+    return _combine(out, *place, k)
+
+
+def _sorted_rows_ffn(rows, weight, w_gate_up, w_down, group_sizes, act):
+    """The experts' FFN over ``rows`` [M, E] sorted by group, the router
+    weight of each row riding its HIDDEN row (the triple product in float32,
+    rounded once): the two grouped matmuls of every layout."""
     up = _grouped_matmul(rows, w_gate_up, group_sizes)
-    if gated:
+    if w_gate_up.shape[2] == 2 * w_down.shape[1]:
         gate, up = jnp.split(up, 2, axis=-1)
         hidden = (ACTIVATIONS[act](gate.astype(jnp.float32))
-                  * up.astype(jnp.float32) * weight[:, None]).astype(x.dtype)
+                  * up.astype(jnp.float32)
+                  * weight[:, None]).astype(rows.dtype)
     else:
         hidden = (ACTIVATIONS[act](up.astype(jnp.float32))
-                  * weight[:, None]).astype(x.dtype)
-    out = _grouped_matmul(hidden, w_down, group_sizes)
-    return _combine(out, *place, k)
+                  * weight[:, None]).astype(rows.dtype)
+    return _grouped_matmul(hidden, w_down, group_sizes)
 
 
 def _held_tier(top_e, first, count, caps):
@@ -485,9 +514,234 @@ _held_expert_ffn.defvjp(lambda *a: (_held_expert_ffn(*a), a[:5]),
                         _held_expert_ffn_bwd)
 
 
+# -- expert-parallel: the experts ride a mesh axis and rows are exchanged ----
+
+_exchanged = devscope.scoped(devscope.EXCHANGE)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _pack(x, slot_pair, pair_row, k):
+    """Slot i of the send buffer is token ``slot_pair[i] // k``: ``_dispatch``
+    over the slots of one round, under the exchange's scope.  ``pair_row``
+    [T*k] is a pair's slot, or the slots' count for a pair that travels in
+    another round; a slot past a destination's pairs holds some token's row,
+    and nothing reads what is computed from it."""
+    return x[slot_pair // k]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _unpack(rows, slot_pair, pair_row, k):
+    """Token t of the result is the float32 sum of the rows that came home
+    for its pairs IN THIS ROUND (``kernels/moe_rows.py``; a pair that has no
+    slot in it adds zero and costs no fetch): ``_pack``'s transpose, as it
+    is its."""
+    return moe_rows_sum(rows, pair_row, k, interpret=not on_tpu())
+
+
+@jax.custom_vjp
+def _permute(rows, perm, inv):
+    """``rows[perm]``, ``perm`` a permutation with inverse ``inv``: the
+    transpose is the same gather by ``inv``."""
+    return rows[perm]
+
+
+_pack.defvjp(lambda *a: (_pack(*a), a[1:3]),
+             _exchanged(lambda k, res, g: (_unpack(g, *res, k), None, None)))
+_unpack.defvjp(lambda *a: (_unpack(*a), a[1:3]),
+               _exchanged(lambda k, res, g: (_pack(g, *res, k), None, None)))
+_permute.defvjp(lambda rows, perm, inv: (rows[perm], (inv, perm)),
+                _exchanged(lambda res, g: (_permute(g, *res), None, None)))
+
+
+def _exchange_capacity(pairs, ep):
+    """The static rows a DESTINATION is sent in one round of the exchange:
+    ``_held_capacities``' first capacity for a device that holds 1 / ep of
+    the experts (1.25 times the ``pairs / ep`` rows uniform routing sends
+    it, in whole HELD_GRANULE rows; all ``pairs`` where that is no fewer)."""
+    return _held_capacities(pairs, 1, ep)[0]
+
+
+def _exchange_plan(top_e, per, axis, cap):
+    """What a step's exchange follows, from its routing ``top_e`` [T, k]
+    alone (integers: nothing here has a gradient; ``per`` experts a device),
+    the same for every round:
+
+    - ``order`` [P]: the pairs sorted by expert, hence by DESTINATION
+      (expert // per), ``inv`` its inverse, ``dest`` [P] each pair's
+      destination and ``pos`` [P] its place among the rows this device sends
+      there;
+    - ``sent`` [ep] the rows for each destination and ``start`` [ep] where
+      each one's begin in the sort;
+    - ``recv`` [ep, per]: the rows each SOURCE sends this device for each of
+      its experts (the senders' counts, exchanged);
+    - ``rounds``: how many rounds of ``cap`` rows a destination the fullest
+      destination of ANY device needs (``pmax`` over the axis: every device
+      makes the same number of rounds, so the collectives line up)."""
+    ep = col.axis_size_in(axis)
+    flat = top_e.reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    send = _per_expert(flat, ep * per).reshape(ep, per)
+    sent = jnp.sum(send, axis=1)
+    start = jnp.cumsum(sent) - sent
+    dest = (flat // per).astype(jnp.int32)
+    inv = jnp.argsort(order).astype(jnp.int32)
+    # ``start[dest]`` without a gather: ep compares a pair
+    pos = inv - jnp.sum(jnp.where(dest[:, None] == jnp.arange(ep), start, 0),
+                        axis=1)
+    rounds = jnp.maximum(col.pmax(jnp.max(-(-sent // cap)), axis), 1)
+    return {"order": order, "inv": inv, "dest": dest, "pos": pos,
+            "sent": sent, "start": start, "rounds": rounds,
+            "recv": jax.lax.all_to_all(send, axis, 0, 0)}
+
+
+def _slices(sorted_values, first, cap):
+    """[ep, cap]: ``cap`` places of the sorted vector from each of ``first``
+    [ep] on (a destination's rows stand together in the sort, so a round's
+    slots are ep SLICES of it, not a gather); the vector is padded by
+    ``cap``, so a slice that runs past a destination's rows, or past the
+    end, reads places that no slot that counts uses."""
+    padded = jnp.concatenate(
+        [sorted_values, jnp.zeros((cap,), sorted_values.dtype)])
+    return jnp.stack([jax.lax.dynamic_slice(padded, (first[d],), (cap,))
+                      for d in range(first.shape[0])])
+
+
+def _exchange_round(x, sorted_p, w_gate_up, w_down, plan, r, static):
+    """Round ``r`` of the exchange: what the experts of every device add to
+    this device's tokens for the pairs whose place among their
+    destination's rows lies in [r cap, (r + 1) cap).
+
+    ``sorted_p`` [T k]: the router's weights in the sort's order (moved
+    once a step, ``_move``).  PACK (scope ``exchange``): slot (d, j) of the
+    send buffer [ep, cap, E]
+    is the pair at place ``r cap + j`` among destination d's, its router
+    weight beside it in a float32 buffer of its own, so that the weight
+    still rides the HIDDEN row on the device that computes it and nothing
+    after the down projection is kept for the backward.  One row a PAIR is
+    sent: a token that meets two experts of one device travels twice.
+    ``all_to_all`` over the axis: chunk d leaves for device d.  UNPACK: the
+    received chunks (source s in chunk s, each sorted by local expert, its
+    unused slots last) are sorted by local expert into one run a group,
+    slots that hold no pair behind the last group, where the kernels skip
+    them.  The grouped matmuls are the all-held layer's.  The way home is
+    the way out, transposed: the inverse permutation, ``all_to_all``, and
+    the row kernel's sum a token over the slots its pairs have."""
+    k, act, axis, cap = static
+    ep = plan["recv"].shape[0]
+    slots, lo = ep * cap, r * cap
+    with jax.named_scope(devscope.EXCHANGE):
+        place = lo + jnp.arange(cap, dtype=jnp.int32)
+        slot_pair = _slices(plan["order"], plan["start"] + lo,
+                            cap).reshape(slots)
+        used = place[None] < plan["sent"][:, None]
+        here = (plan["pos"] >= lo) & (plan["pos"] < lo + cap)
+        pair_row = jnp.where(here, plan["dest"] * cap + plan["pos"] - lo,
+                             slots)
+        rows = _pack(x, slot_pair, pair_row, k).reshape(ep, cap, -1)
+        weight = jnp.where(used, _slices(sorted_p, plan["start"] + lo, cap),
+                           0.0)
+        rows = jax.lax.all_to_all(rows, axis, 0, 0)
+        weight = jax.lax.all_to_all(weight, axis, 0, 0)
+        # the rows of source s are sorted by local expert; slot j of its
+        # chunk is that expert's whose running count first passes lo + j
+        ends = jnp.cumsum(plan["recv"], axis=1)                 # [ep, per]
+        group = jnp.sum(place[None, :, None] >= ends[:, None, :], axis=-1)
+        group_sizes = jnp.sum(
+            jnp.clip(ends, lo, lo + cap)
+            - jnp.clip(ends - plan["recv"], lo, lo + cap), axis=0)
+        order = jnp.argsort(group.reshape(slots), stable=True).astype(
+            jnp.int32)
+        inv = jnp.argsort(order).astype(jnp.int32)
+        rows = _permute(rows.reshape(slots, -1), order, inv)
+        # slots past the last group hold no pair: the kernels leave their
+        # outputs unwritten, and their weight's gradient is cut here
+        weight = jnp.where(jnp.arange(slots) < jnp.sum(group_sizes),
+                           _move(weight.reshape(slots), inv, order), 0.0)
+    out = _sorted_rows_ffn(rows, weight, w_gate_up, w_down, group_sizes, act)
+    with jax.named_scope(devscope.EXCHANGE):
+        out = _permute(out, inv, order).reshape(ep, cap, -1)
+        out = jax.lax.all_to_all(out, axis, 0, 0)
+        return _unpack(out.reshape(slots, -1), slot_pair, pair_row, k)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _exchange_ffn(x, top_p, top_e, w_gate_up, w_down, static):
+    """The expert-parallel layer: ``_exchange_round`` summed over as many
+    rounds as this step's routing needs.  ``static`` = (k, act, axis, cap).
+
+    Round 0 always runs; rounds 1 .. ``rounds`` - 1 are a loop whose trip
+    count is the step's (the same on every device of the axis), so a step
+    under balance pays for one round and a step whose every pair meets one
+    device's experts for ``ceil(T k / cap)``, each at the FIRST capacity's
+    buffers: no routing makes the layer hold more than one round's rows.
+    (``lax.switch`` over static capacities, as a share's
+    ``_held_expert_ffn`` has it, would compile the step for the LAST
+    capacity's buffers, ep T k rows on every device.)  A token whose pairs
+    travel in different rounds has its rows summed in float32 within a
+    round and in the stream's type across them.
+
+    A ``custom_vjp`` whose residuals are its arguments: the loop is not
+    differentiated, the backward is the same loop over each round's own
+    ``jax.vjp`` with the gradients summed, and under ``jax.checkpoint`` the
+    layer's recomputed forward has nothing of the expert FFN to keep."""
+    plan = _exchange_plan(top_e, w_gate_up.shape[0], *static[2:])
+    one = functools.partial(
+        _exchange_round, x, _sorted_weights(top_p, plan), w_gate_up, w_down,
+        plan, static=static)
+    return jax.lax.fori_loop(1, plan["rounds"],
+                             lambda r, y: y + one(r), one(0))
+
+
+def _sorted_weights(top_p, plan):
+    with jax.named_scope(devscope.EXCHANGE):
+        return _move(top_p.reshape(-1), plan["inv"], plan["order"])
+
+
+@devscope.scoped(devscope.MOE)
+def _exchange_ffn_bwd(static, res, g):
+    x, top_p, top_e, w_gate_up, w_down = res
+    plan = _exchange_plan(top_e, w_gate_up.shape[0], *static[2:])
+    sorted_p, unsort = jax.vjp(lambda p: _sorted_weights(p, plan), top_p)
+
+    def one(r):
+        return jax.vjp(lambda *a: _exchange_round(*a, plan, r, static),
+                       x, sorted_p, w_gate_up, w_down)[1](g)
+
+    dx, dp, dgu, dd = jax.lax.fori_loop(
+        1, plan["rounds"],
+        lambda r, acc: jax.tree.map(jnp.add, acc, one(r)), one(0))
+    return dx, unsort(dp)[0], None, dgu, dd
+
+
+_exchange_ffn.defvjp(lambda *a: (_exchange_ffn(*a), a[:5]), _exchange_ffn_bwd)
+
+
+def _exchange_aux(top_e, n, axis, cap):
+    """What a step's exchange did, the same on every device of the axis:
+    ``rows_sent``, the (token, expert) pairs of the whole batch that LEFT
+    their device; ``rows_received``, the rows the fullest device's grouped
+    matmuls ran over (its own included); ``exchange_fullest``, the most
+    rows any device had for one destination, which ``exchange_capacity``
+    rows a round carry; ``exchange_tier``, the rounds past the first (0
+    under balance)."""
+    ep = col.axis_size_in(axis)
+    by_dest = jnp.sum(_per_expert(top_e.reshape(-1), n).reshape(ep, -1),
+                      axis=1)
+    received = col.psum(by_dest, axis)          # [ep]: by every device
+    fullest = col.pmax(jnp.max(by_dest), axis)
+    return {
+        "rows_sent": jnp.sum(received) - col.psum(
+            by_dest[col.axis_index(axis)], axis),
+        "rows_received": jnp.max(received),
+        "exchange_fullest": fullest,
+        "exchange_capacity": jnp.int32(cap),
+        "exchange_tier": jnp.maximum(-(-fullest // cap), 1) - 1}
+
+
 @devscope.scoped(devscope.MOE)
 def dropless_moe_ffn(params, x, k, rule=SOFTMAX_TOP_K, act="silu",
-                     logits=None, first_held=0, bias=None, scale=1.0):
+                     logits=None, first_held=0, bias=None, scale=1.0,
+                     ep_axis=None):
     """Top-k dropless expert FFN.  x [T, E] (flatten batch and sequence
     before the call); returns ``(y [T, E], aux)`` with
     ``y_t = sum_{e in top k, held} p_te * down_e(act(gate_e x_t) * up_e x_t)``
@@ -499,6 +753,12 @@ def dropless_moe_ffn(params, x, k, rule=SOFTMAX_TOP_K, act="silu",
     them (OLMoE), or this device's share, and then ``y`` is the part of the
     layer's result that its experts give, and ``aux`` also counts the pairs
     that met one of them (``rows_held``, over the dp-global batch).
+    ``ep_axis``: the mesh axis the experts ride (EXPERT-PARALLEL: the
+    leaves hold this device's n / ep experts, device c those from c n / ep
+    on); ``y`` is then the WHOLE layer's result for this device's tokens,
+    whichever devices computed its parts, and ``aux`` also says what the
+    exchange did (``_exchange_aux``).  On an axis of size 1, or outside a
+    mesh, it is the all-held layer.
 
     ``p_te`` multiplies the HIDDEN rows (the triple product in float32,
     rounded once); the down projection is linear, so ``y`` is the same.  Its
@@ -507,10 +767,19 @@ def dropless_moe_ffn(params, x, k, rule=SOFTMAX_TOP_K, act="silu",
     projection, and a rematerialised forward stops at the gate/up matmul."""
     w_up = params["we_gate_up" if "we_gate_up" in params else "we_up"]
     n, count = params["router"].shape[-1], w_up.shape[0]
-    assert 0 <= first_held and first_held + count <= n, (first_held, count, n)
+    ep = col.axis_size_in(ep_axis)
+    if ep > 1:
+        assert first_held == 0 and count * ep == n, (first_held, count, ep, n)
+    else:
+        assert 0 <= first_held and first_held + count <= n, \
+            (first_held, count, n)
     top_p, top_e, aux = route_top_k(params["router"], x, k, rule, logits,
                                     bias, scale)
     ffn = (x, top_p, top_e, w_up, params["we_down"])
+    if ep > 1:
+        cap = _exchange_capacity(top_e.size, ep)
+        aux = dict(aux, **_exchange_aux(top_e, n, ep_axis, cap))
+        return _exchange_ffn(*ffn, (k, act, ep_axis, cap)), aux
     if count == n:
         return _expert_ffn(*ffn, k, act, 0, None), aux
     caps = _held_capacities(top_e.size, count, n)

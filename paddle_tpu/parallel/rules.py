@@ -247,14 +247,26 @@ def transformer_rules(cfg):
     """Rule list reproducing the transformer layout: tp shards attention /
     mlp weights when attn_mode == "heads" (ring mode replicates over tp),
     pp leads the stacked-layer arrays when cfg.pp > 1, tok_emb is
-    vocab-parallel over tp."""
+    vocab-parallel over tp, and an expert-parallel configuration's routed
+    experts ride dp."""
     tp = TP if cfg.attn_mode == "heads" else None
     lead = (PP, None) if cfg.pp > 1 else (None,)
 
     def L(*dims):       # a [L, ...] (or [pp, L/pp, ...]) stacked-layer leaf
         return P(*(lead + dims))
 
-    return [
+    # EXPERT-PARALLEL (``cfg.expert_parallel``): the routed experts' leaves,
+    # [..., n_experts, E, F] behind their stacking axes, are split over dp on
+    # the EXPERTS' axis, device c holding experts c n / dp on; the optimizer's
+    # moments follow (``train.state_specs``), ``grad_sync_axes`` sums no
+    # gradient over an axis a leaf is split on, and the checkpoint re-sharder
+    # reads the same rule, so a state saved at dp 4 loads at dp 2 or 1.
+    # Ahead of the rules that hold a per-position stack whole
+    riding = [(r"^params_layers/r\d+/we_(gate_up|up|down)$",
+               P(None, None, DP)),
+              (r"/we_(gate_up|up|down)$", L(DP))] \
+        if getattr(cfg, "expert_parallel", False) else []
+    return riding + [
         # a stack whose layers own different leaves runs at tp == pp == 1
         # (TransformerConfig): its leading layers' leaves are not stacked,
         # and all of it is whole on every device
@@ -304,8 +316,11 @@ def deepfm_rules(axis=DP):
 def moe_rules(ep_axis=DP):
     """MoE: the Switch layer's experts (``w1``, ``w2``) sharded over
     `ep_axis`, the router replicated (its grads must be psum'd over ep); the
-    dropless layer's experts (``we_gate_up``, ``we_down``) whole on every
-    device, as it has no expert parallelism yet."""
+    dropless layer's experts (``we_gate_up``, ``we_down``) of a BARE layer
+    (``moe.init_dropless_moe_params``) whole on every device.  A decoder's
+    experts are ``transformer_rules``': whole, or, with
+    ``TransformerConfig.expert_parallel``, split over ``dp`` on the experts'
+    axis, which is how the dropless layer is expert-parallel."""
     return [
         (r"^router$", P()),
         (r"^w[12]$", P(ep_axis)),

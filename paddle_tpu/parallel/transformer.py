@@ -182,6 +182,16 @@ class TransformerConfig:
     # experts [first_expert, first_expert + experts_held)
     experts_held: int = 0
     first_expert: int = 0
+    # EXPERT-PARALLEL: the routed experts ride the mesh's ``dp`` axis ("EP
+    # rides DP").  Device c of the dp devices holds experts c n / dp .. (c +
+    # 1) n / dp - 1 of every layer (``parallel/rules.py`` splits the experts'
+    # leaves and their moments over ``dp``; their gradients are not summed
+    # over it) and the layer exchanges rows with ``lax.all_to_all``
+    # (``moe.dropless_moe_ffn(ep_axis=)``): the result is what one device
+    # holding all n would give.  Everything else stays replicated and
+    # data-parallel.  At dp 1 it is the all-held layer.  It and
+    # ``experts_held`` (a share WITHOUT the exchange) exclude each other
+    expert_parallel: bool = False
     # Attention's shape.  head_width 0: hidden // n_heads; n_kv_heads 0:
     # n_heads (else grouped queries: wk, wv [E, n_kv_heads * head_dim])
     head_width: int = 0
@@ -401,6 +411,8 @@ class TransformerConfig:
         if self.n_experts:
             assert 0 < self.experts_per_token <= self.n_experts
             assert self.first_expert + self.experts_here <= self.n_experts
+            assert not (self.expert_parallel and self.experts_held), \
+                "expert_parallel and experts_held exclude each other"
         if self.kv_heads != self.n_heads or self.head_width:
             assert self.tp == 1 and self.attn_mode == "heads" \
                 and not self.bias and self.n_heads % self.kv_heads == 0
@@ -662,8 +674,12 @@ def _dense_init(key, fan_in, shape, dtype):
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
-def init_transformer_params(key, cfg: TransformerConfig):
-    """The parameter tree of ``cfg``'s block.  Which leaves exist follows the
+def init_transformer_params(key, cfg: TransformerConfig, shardings=None):
+    """The parameter tree of ``cfg``'s block.  ``shardings`` (a tree of
+    ``NamedSharding`` to match): the leaves are made ON THE MESH by one
+    program, each device its own part (an expert-parallel stack, whose
+    experts no one device has room to seed whole beside anything else).
+    Which leaves exist follows the
     configuration: ``*_bias`` of the norms only with LayerNorm, ``bqkv`` /
     ``bo`` / ``b1`` / ``b2`` only with ``bias``, ``pos_emb`` only with
     learned positions, ``q_norm`` / ``k_norm`` with ``qk_norm``, ``lm_head``
@@ -697,7 +713,10 @@ def init_transformer_params(key, cfg: TransformerConfig):
     # the leaves are made eagerly, one small program each: the ledger shows
     # them beneath this phase, which waits for their device time as well
     with compile_ledger().phase("init_params") as labels:
-        params = jax.block_until_ready(_init_params(key, cfg))
+        init = functools.partial(_init_params, cfg=cfg)
+        if shardings is not None:
+            init = jax.jit(init, out_shardings=shardings)
+        params = jax.block_until_ready(init(key))
         labels["leaves"] = len(jax.tree.leaves(params))
     return params
 
@@ -1174,7 +1193,8 @@ def transformer_param_specs(cfg: TransformerConfig, params=None):
 def grad_sync_axes(cfg: TransformerConfig):
     """Per-leaf list of mesh axes whose gradient contributions must be summed
     (the explicit-SPMD analogue of the AllReduceOpHandle placement decision,
-    details/all_reduce_op_handle.cc:48).  dp always; tp for leaves whose
+    details/all_reduce_op_handle.cc:48).  dp for every leaf that is not
+    split over it; tp for leaves whose
     params are replicated over tp but fed tp-varying activations (sequence
     parallel shards / ring mode); pp for leaves replicated over pp."""
     specs = transformer_param_specs(cfg)
@@ -1182,7 +1202,10 @@ def grad_sync_axes(cfg: TransformerConfig):
     def axes(spec_leaf):
         used = {a for part in spec_leaf if part for a in
                 ((part,) if isinstance(part, str) else tuple(part))}
-        sync = [DP]
+        # a leaf split over dp (expert-parallel experts) holds its own
+        # experts' whole gradient: the exchange's backward brought it the
+        # rows of every device's tokens
+        sync = [] if DP in used else [DP]
         if TP not in used:
             sync.append(TP)   # replicated over tp -> partial grads per seq shard
         if PP not in used:
@@ -2490,7 +2513,8 @@ def transformer_layer(pl, x_sp, cfg: TransformerConfig, kind=None,
                 pl, h.reshape(-1, h.shape[-1]), cfg.experts_per_token,
                 rule=cfg.routing, act=cfg.expert_act, logits=logits,
                 first_held=cfg.first_expert, bias=router_bias,
-                scale=cfg.route_scale)
+                scale=cfg.route_scale,
+                ep_axis=DP if cfg.expert_parallel else None)
             y = y.reshape(h.shape)
             aux = dict(aux, **extras)
             if not cfg.shared_ffn_hidden:
@@ -2909,7 +2933,7 @@ def final_logits_loss(params, x_sp, labels, mask, cfg: TransformerConfig,
             x_sp.reshape(-1, x_sp.shape[-1]), params["lnf_scale"],
             params.get("lnf_bias"), emb, labels.reshape(-1),
             mask.reshape(-1), norm=(cfg.norm, cfg.norm_eps))
-        total = col.psum(jnp.sum(nll * mask.reshape(-1)), DP)
+        total = col.psum_forward(jnp.sum(nll * mask.reshape(-1)), DP)
         if divisor is not None:
             return total / (divisor * col.axis_size_in(DP))
         count = col.psum(jnp.sum(mask.astype(jnp.float32)), DP)
@@ -2931,7 +2955,7 @@ def final_logits_loss(params, x_sp, labels, mask, cfg: TransformerConfig,
     picked = col.psum(jnp.where(hit, picked, 0.0), TP)
     nll = (lse - picked) * mask
     # token-mean over the dp-sharded global batch (nll is tp-replicated)
-    total = col.psum(jnp.sum(nll), DP)
+    total = col.psum_forward(jnp.sum(nll), DP)
     count = col.psum(jnp.sum(mask.astype(jnp.float32)), DP)
     return total / jnp.maximum(count, 1.0)
 
@@ -2959,6 +2983,6 @@ def exit_weighted_loss(params, exits, gates, labels, mask,
         log_p = exit_log_probs(gates.reshape(T, -1))
         p = jnp.exp(log_p)
         each = jnp.sum(p * (nll + cfg.exit_entropy_coef * log_p), axis=0)
-    total = col.psum(jnp.sum(each * mask), DP)
+    total = col.psum_forward(jnp.sum(each * mask), DP)
     count = col.psum(jnp.sum(mask.astype(jnp.float32)), DP)
     return total / jnp.maximum(count, 1.0)

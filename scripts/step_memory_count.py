@@ -3,8 +3,9 @@
     JAX_PLATFORMS=cpu python3 scripts/step_memory_count.py <cell> [key=value ...]
 
 The cell's trainer is built from SHAPES (``jax.eval_shape`` of the seeded
-tree and of the optimizer's state) on a mesh of one described ``v5e:2x2``
-device, the kernels' ``_on_tpu`` patched True in THIS process, and
+tree and of the optimizer's state) on the cell's mesh of described
+``v5e:2x2`` devices (one; all four for a ``dp`` 4 cell, whose report is ONE
+device's, collectives and all), the kernels' ``_on_tpu`` patched True in THIS process, and
 ``run_steps`` over the cell's staged batches is compiled for it with
 ``--xla_dump_to`` set: the dump's memory-usage report is the count PERF.md
 section 4 gives for every decoder cell (PR 37's recipe), printed beside
@@ -50,8 +51,9 @@ from paddle_tpu.parallel.train import (TrainState, make_train_step,  # noqa: E40
 
 
 def count(cell, *overrides):
-    """``(compiled run_steps, parameters, state bytes)`` of ``cell`` for ONE
-    described v5e device, the kernels' ``_on_tpu`` True while it lowers."""
+    """``(compiled run_steps, parameters, a device's state bytes)`` of
+    ``cell`` for the described v5e devices of its mesh (one, or the host's
+    four), the kernels' ``_on_tpu`` True while it lowers."""
     patched = []
     for info in pkgutil.iter_modules(kernels.__path__):
         module = importlib.import_module("paddle_tpu.kernels." + info.name)
@@ -82,7 +84,10 @@ def _count(cell, *overrides):
     cfg = getattr(importlib.import_module(path), name)(**kwargs)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
-    mesh = MeshSpec(dp=1).build(devices=topo.devices[:1])
+    # the cell's own mesh (a four-chip cell's dp = 4: the described host's
+    # four devices, the step's collectives compiled with the rest)
+    spec = MeshSpec(**traffic["mesh"])
+    mesh = spec.build(devices=topo.devices[:spec.size])
     optimizer = optim.adamw()
     params = jax.eval_shape(
         lambda: T._init_params(jax.random.PRNGKey(0), cfg))
@@ -105,13 +110,15 @@ def _count(cell, *overrides):
         for f in config["batch_fields"]
         if f["name"] in decoder.batch_specs(cfg)}
     n_params = sum(a.size for a in jax.tree.leaves(params))
+    # a DEVICE's bytes: a leaf split over the mesh counts its shard
     return (multi.lower(state, batches, 1e-5).compile(), n_params,
-            sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state)))
+            sum(math.prod(a.sharding.shard_shape(a.shape)) * a.dtype.itemsize
+                for a in jax.tree.leaves(state)))
 
 
 def main(cell, *overrides):
     compiled, n_params, state_bytes = count(cell, *overrides)
-    print("parameters: %.1f M; state leaves: %.3f GB"
+    print("parameters: %.1f M; state leaves a device: %.3f GB"
           % (n_params / 1e6, state_bytes / 1e9))
     print("memory_analysis():", compiled.memory_analysis())
     print(memscope.need_line(cell.split(".")[0]

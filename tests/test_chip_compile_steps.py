@@ -117,3 +117,44 @@ def test_solar_open2_s_whole_step_compiles_for_a_v5e(one_chip, what,
     assert need_gb[0] < need < need_gb[1] < 16.4, (what, need)
     if not overrides:
         assert n_params == 1_420_916_544
+
+
+@pytest.mark.parametrize("what,overrides,need_gb", [
+    pytest.param("the published widths at S = 8,192", (), (13.0, 14.2),
+                 marks=pytest.mark.slow),
+    # the dense layer and two sparse ones at the exchange's own granule: 4
+    # heads of 128 + 64 against values of 128, the stream 512, 8 experts of
+    # 256 top-2 (two a chip), 2,048 rows of vocabulary, S = 1,024: 2,048
+    # pairs a chip, a round of 1,024 rows a destination
+    ("a tiny shape of the same step", (
+        "S=1024", "n_layers=3", "vocab_size=2048", "hidden=512", "n_heads=4",
+        "kv_lora_rank=128", "ffn_hidden=256", "dense_ffn_hidden=1024",
+        "shared_ffn_hidden=512", "n_experts=8", "experts_per_token=2"),
+     (0.1, 0.5)),
+])
+def test_kanana2_s_four_chip_step_compiles_for_a_v5e_host(one_chip, what,
+                                                          overrides, need_gb):
+    """``kanana_2_30b_a3b.s8192_ep4``'s whole ``run_steps`` (dp = 4: the
+    experts ride it; two staged batches, AdamW, per-layer remat) compiled for
+    the described host's FOUR devices as ``scripts/step_memory_count.py``
+    compiles it, every kernel through Mosaic and the collectives with the
+    rest: the exchange's ``all-to-all`` stands in the program beside the
+    gradients' ``all-reduce``, and ONE device's NEED by the program's own
+    account at the published widths is the 13.60 GB the configuration's file
+    quotes, under the 16.4 GB a step is held to (three minutes: ``slow``);
+    the tiny shape of it stays in tier-1."""
+    count = _script("step_memory_count")
+    memscope = importlib.import_module("paddle_tpu.monitor.memscope")
+    compiled, n_params, state_bytes = count.count(
+        "kanana_2_30b_a3b.s8192_ep4", *overrides)
+    text = compiled.as_text()
+    for kernel in ("flash_fwd", "flash_bwd_fused", "gmm", "tgmm",
+                   "moe_rows_sum"):
+        assert kernel in text, (what, kernel)
+    assert " all-to-all(" in text and "all-reduce" in text, what
+    need = memscope.need_bytes(memscope.program_ledger(compiled)) / 1e9
+    assert need_gb[0] < need < need_gb[1] < 16.4, (what, need)
+    if not overrides:
+        assert n_params == 3_149_554_688
+        # a device's share of the state: its 32 experts' leaves and moments
+        assert round(state_bytes / 1e9, 2) == 8.03

@@ -305,12 +305,12 @@ def test_dp4_every_device_runs_its_own_count():
     batch = _batch(rng, counts=[1, 1, 4, 5, 12, 12, 32, 32])
     assert T.head_row_block(2 * S) == 8
     one, four = _trainer(dp=1), _trainer(dp=4)
-    # make_train_step sums over dp the shards' gradients of a loss that is
-    # already the global mean, so a dp=4 gradient is 4 times the one-device
-    # one (before this head too; LAMB does not see a scale): a quarter of
-    # the learning rate makes the same step
+    # the SAME learning rate makes the same step (since PR 73: the loss's
+    # sum over dp hands each shard its own cotangent,
+    # ``collectives.psum_forward``; before it a dp=4 gradient was 4 times the
+    # one-device one and this test took a quarter of the rate)
     before = _flat(one.state["params"])
-    l1, l4 = float(one.step(batch, 0.1)), float(four.step(batch, 0.025))
+    l1, l4 = float(one.step(batch, 0.1)), float(four.step(batch, 0.1))
     np.testing.assert_allclose(l4, l1, rtol=2e-6)
     got, want = _flat(four.state["params"]), _flat(one.state["params"])
     assert np.abs(got - want).max() <= GRAD_TOL * np.abs(want - before).max()
@@ -320,7 +320,7 @@ def test_dp4_every_device_runs_its_own_count():
     s1 = np.asarray(one.run_steps(
         stack_batches(one.mesh, bert.batch_specs(), staged), 0.1))
     s4 = np.asarray(four.run_steps(
-        stack_batches(four.mesh, bert.batch_specs(), staged), 0.025))
+        stack_batches(four.mesh, bert.batch_specs(), staged), 0.1))
     np.testing.assert_allclose(s4, s1, rtol=1e-4)
 
 

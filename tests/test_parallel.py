@@ -70,6 +70,28 @@ def test_loss_parity_vs_single_device(mesh_spec, cfg_kw, mb):
     np.testing.assert_allclose(got, ref, atol=2e-3, rtol=2e-3)
 
 
+def test_a_data_parallel_sgd_update_is_the_one_device_update():
+    """How FAR a step moves the parameters, which Adam's scale-free update
+    cannot show: under SGD every leaf's update on eight shards is the
+    one-device update of the same global batch.  (Before PR 73 it was dp
+    times that: the loss's ``psum`` over dp transposes to a ``psum`` of the
+    dp copies' cotangents; ``collectives.psum_forward``.)"""
+    cfg = bert.bert_tiny_config()
+    batch = _batch(np.random.RandomState(7), 8, 32, cfg.vocab_size)
+    updates = []
+    for dp in (1, 8):
+        tr = bert.build_bert_trainer(cfg, MeshSpec(dp, 1, 1),
+                                     optimizer=optim.sgd(), seed=0)
+        before = jax.tree.map(np.asarray, tr.state["params"])
+        tr.step(batch, 1.0)
+        updates.append(jax.tree.map(lambda a, b: np.asarray(a) - b,
+                                    tr.state["params"], before))
+    for one, many in zip(*map(jax.tree.leaves, updates)):
+        np.testing.assert_allclose(many, one, rtol=1e-3,
+                                   atol=1e-5 * np.abs(one).max())
+        assert np.abs(one).max() > 0
+
+
 def test_ring_attention_matches_local():
     """Ring attention over a sharded axis == plain attention, causal+not."""
     rng = np.random.RandomState(0)

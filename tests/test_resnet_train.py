@@ -164,9 +164,20 @@ def test_run_steps_is_three_steps(dp):
     batches = [_batch(seed, 16, 64) for seed in range(3)]
     one, scan = _trainer(cfg, dp), _trainer(cfg, dp)
     start = _host(one.state)
-    want = [float(one.step(b, 1e-3)) for b in batches]
+    # 1e-3 a SHARD: the trajectory this test has held since it was written.
+    # Until PR 73 a dp step's gradient was dp times the global batch's
+    # (``collectives.psum_forward``), so ``step(b, 1e-3)`` moved the state as
+    # ``step(b, dp * 1e-3)`` does now, bit for bit (dp is a power of two: the
+    # losses and every leaf's distance below read the same digits on the
+    # parent and here).  Which trajectory matters: where the two compilations'
+    # last bits tip something discrete, leaves part ways by more than the
+    # tolerance, on the parent as here (dp 2: 24 leaves past 1e-3, the worst
+    # the velocity of ``s1_b1.bn2.bias`` at 3.9e-3, at 5e-4 on the parent and
+    # at 1e-3 here; none at 2.5e-4 / 5e-4, at 1e-3 / 2e-3, at 3e-3, at 4e-3)
+    lr = dp * 1e-3
+    want = [float(one.step(b, lr)) for b in batches]
     got = scan.run_steps(
-        stack_batches(scan.mesh, resnet.BATCH_SPECS, batches), 1e-3)
+        stack_batches(scan.mesh, resnet.BATCH_SPECS, batches), lr)
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4)
     _close(_minus(scan.state, start), _minus(one.state, start))
 
@@ -193,10 +204,10 @@ def test_dp_step_statistics_and_direction(dp):
            jax.tree.map(lambda *xs: np.mean(xs, axis=0), *_host(shards)))
 
     # with it, the dp step sees the one-device loss of the same global batch
-    # and moves the parameters the same WAY as the one-device step.  How FAR
-    # is not asserted: the gradient of a data-parallel step is dp times the
-    # one-device gradient today (ROADMAP Design 7), and the PR that repairs
-    # it in make_train_step adds the magnitude here.  (Images stay 32 wide:
+    # and moves the parameters the same way AND as far as the one-device step
+    # (since PR 73: the loss's sum over dp hands each shard its own cotangent,
+    # ``collectives.psum_forward``; before it every update was dp times the
+    # one-device one, which SGD shows and LAMB does not).  (Images stay 32 wide:
     # over the 16k values of a 64-wide stem the one-pass float32 variance
     # E[x^2] - E[x]^2 of the CPU's sequential sums is itself 1e-2 off, and
     # the shards' shorter sums are not, which is no fault of the step.)
@@ -215,6 +226,7 @@ def test_dp_step_statistics_and_direction(dp):
     u1, un = update(one), update(many)
     cosine = u1 @ un / np.sqrt((u1 @ u1) * (un @ un))
     assert cosine >= 1 - 1e-6, cosine
+    assert abs(np.sqrt((un @ un) / (u1 @ u1)) - 1) < 1e-3
     _close(many.state[RUNNING], one.state[RUNNING])
 
 
