@@ -12,7 +12,7 @@ TPU-native form (Pallas/XLA attention + parallel/optim.py lamb).
 batch dict: ids/labels int32 [B, S], mask float32 [B, S] (1 where the label
 position counts — MLM masked positions, or every position for causal LM).
 ``mask`` alone says which positions count: with tp=1 the LM head computes
-only the rows whose mask is non-zero (transformer._chunked_vocab_nll), so an
+only the rows whose mask is non-zero (transformer._weighted_vocab_nll), so an
 MLM batch pays for its 80 predicted positions and not for all 512.
 """
 

@@ -2663,7 +2663,7 @@ def run_passes(params, x_sp, cfg: TransformerConfig):
     model's one final norm at the end of every pass, whose output the next
     pass reads.  Returns ``(exits, gates)``: every pass's last activation
     BEFORE that norm, [T, b, S, E] (the head norms the rows it reads with
-    the same weight: ``_chunked_vocab_nll``, ``head_logits``), and the exit
+    the same weight: ``_weighted_vocab_nll``, ``head_logits``), and the exit
     gate's logit on the normed state, ``h_t . exit_gate_w + exit_gate_b``,
     float32 [T, b, S].
 
@@ -2779,119 +2779,161 @@ def _head_norm(norm, x, scale, bias):
     return layer_norm(x, scale, bias, eps=eps, fused=False)
 
 
-def _chunked_vocab_nll(x, scale, bias, emb, labels, mask,
-                       norm=("layer", 1e-6)):
-    """Per-row ``nll = logsumexp(norm(x) @ emb.T) - picked`` for the rows
-    whose ``mask`` is non-zero, exactly 0 for the others (single-device
-    vocab, tp=1).  x [N, E], labels and mask [N]; ``emb`` [V, E] is the head
-    matrix, tied or not; ``norm`` = (kind, eps) of the final norm, whose
-    ``bias`` is None for RMS norm.
+def _lse_start(block):
+    """A row block's running max, running sum and picked logit before its
+    first vocabulary chunk."""
+    return (jnp.full((block,), -jnp.inf, jnp.float32),
+            jnp.zeros((block,), jnp.float32), jnp.zeros((block,), jnp.float32))
+
+
+def _lse_step(stat, logits, local, hit):
+    """One vocabulary chunk's logits [R, sz] into the block's running max,
+    sum and picked logit (the online softmax's statistic)."""
+    m_run, s_run, picked = stat
+    m_new = jnp.maximum(m_run, jnp.max(logits, axis=-1))
+    s_run = s_run * jnp.exp(m_run - m_new) + jnp.sum(
+        jnp.exp(logits - m_new[:, None]), axis=-1)
+    pc = jnp.take_along_axis(logits, local[:, None], axis=-1)[:, 0]
+    return m_new, s_run, picked + jnp.where(hit, pc, 0.0)
+
+
+def _chunk_grads(dh, demb, h, w, logits, local, hit, lse_b, gb, lo):
+    """One vocabulary chunk's part of a row block's gradient from its
+    logits [R, sz]: ``d = (softmax - onehot) * gb`` in the head matrix's
+    dtype, ``dh += d @ w`` and rows [lo, lo + sz) of the float32 ``demb``
+    ``+= d.T @ h``."""
+    sz = w.shape[0]
+    p = jnp.exp(logits - lse_b[:, None])                        # softmax chunk
+    onehot = (jax.nn.one_hot(local, sz, dtype=jnp.float32)
+              * hit[:, None].astype(jnp.float32))
+    d = ((p - onehot) * gb[:, None]).astype(w.dtype)               # [R, sz]
+    dh = dh + jax.lax.dot_general(
+        d, w, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dw = jax.lax.dot_general(
+        d, h, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                     # [sz, E]
+    return dh, jax.lax.dynamic_update_slice_in_dim(
+        demb, jax.lax.dynamic_slice_in_dim(demb, lo, sz, 0) + dw, lo, 0)
+
+
+def _weighted_vocab_nll(x, scale, bias, emb, labels, wgt,
+                        norm=("layer", 1e-6)):
+    """``(sum_r wgt_r * nll_r, nll)``: per row ``nll = logsumexp(norm(x) @
+    emb.T) - picked`` for the rows whose ``wgt`` is non-zero, exactly 0 for
+    the others (single-device vocab, tp=1), and their weighted sum, the only
+    differentiable result: its gradient reaches x, scale, bias, emb and
+    ``wgt`` (``nll_r``; a row of weight 0 hands its weight none).  x [N, E],
+    labels and wgt [N]; ``emb`` [V, E] is the head matrix, tied or not;
+    ``norm`` = (kind, eps) of the final norm, whose ``bias`` is None for RMS
+    norm.
 
     Only the live rows are computed.  They are moved to the front (a stable
-    partition by ``mask != 0``) and the head runs over row blocks of
+    partition by ``wgt != 0``) and the head runs over row blocks of
     ``head_row_block(N)``, ``ceil(count / R)`` of them: a ``fori_loop`` whose
-    trip count is the mask's own count, in the forward and in the backward
-    below.  An MLM batch predicts 80 positions of 512, so the head does a
-    sixth of the dense work; a causal-LM mask of ones runs every block.
+    trip count is the weights' own count.  An MLM batch predicts 80
+    positions of 512, so the head does a sixth of the dense work; a
+    causal-LM mask of ones runs every block.
 
     Inside a block the vocab axis is processed in chunks with a running
-    max/sum, so the [R, V] f32 logits never materialize (the flash-attention
-    trick applied to the LM head); the backward recomputes each chunk's
-    logits and feeds their gradients to the MXU in the head matrix's dtype
-    (bf16 at training sizes).
-    """
-    return _chunked_nll(norm, x, scale, bias, emb, labels, mask)
+    max/sum.  Asked for no gradient (evaluation, a witness's loss) that is
+    all: a chunk's [R, sz] float32 logits are dropped once read.  Asked for
+    one, the forward rule makes a block's logits ONCE: every chunk's is kept
+    for the block while the running max and sum make ``lse``, then each
+    chunk's gradient is formed from the kept logits and fed to the MXU in
+    the head matrix's dtype (bf16 at training sizes), so a live block costs
+    three [R, E] x [E, V] matmul passes and ``R * V * 4`` bytes of
+    temporaries; the [N, V] logits never materialize.  The gradients leave
+    the forward rule as residuals and the backward rule multiplies them by
+    the sum's cotangent: hand the loss's normaliser in with ``wgt`` and that
+    is 1."""
+    return _weighted_nll(norm, x, scale, bias, emb, labels, wgt)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _chunked_nll(norm, x, scale, bias, emb, labels, mask):
-    nll, _ = _chunked_vocab_nll_fwd(norm, x, scale, bias, emb, labels, mask)
-    return nll
-
-
 @devscope.scoped(devscope.LM_HEAD)
-def _chunked_vocab_nll_fwd(norm, x, scale, bias, emb, labels, mask):
+def _weighted_nll(norm, x, scale, bias, emb, labels, wgt):
     n = x.shape[0]
     block = head_row_block(n)
-    order, inv, count = _live_first(mask)
+    order, inv, count = _live_first(wgt)
 
-    def body(i, carry):
-        nll, lse = carry
+    def body(i, nll):
         idx, live = _block_rows(i, block, order, count)
         lb = labels[idx]
         h = _head_norm(norm, x[idx], scale, bias)
-        m_run = jnp.full((block,), -jnp.inf, jnp.float32)
-        s_run = jnp.zeros((block,), jnp.float32)
-        picked = jnp.zeros((block,), jnp.float32)
+        stat = _lse_start(block)
         for lo, sz in _vocab_chunks(emb):
-            _, logits, local, hit = _vocab_chunk(h, emb, lb, lo, sz)
-            m_new = jnp.maximum(m_run, jnp.max(logits, axis=-1))
-            s_run = s_run * jnp.exp(m_run - m_new) + jnp.sum(
-                jnp.exp(logits - m_new[:, None]), axis=-1)
-            m_run = m_new
-            pc = jnp.take_along_axis(logits, local[:, None], axis=-1)[:, 0]
-            picked = picked + jnp.where(hit, pc, 0.0)
-        lse_b = m_run + jnp.log(s_run)
-        nll_b = jnp.where(live, lse_b - picked, 0.0)
-        return (jax.lax.dynamic_update_slice_in_dim(nll, nll_b, i * block, 0),
-                jax.lax.dynamic_update_slice_in_dim(lse, lse_b, i * block, 0))
+            stat = _lse_step(stat, *_vocab_chunk(h, emb, lb, lo, sz)[1:])
+        m_run, s_run, picked = stat
+        nll_b = jnp.where(live, m_run + jnp.log(s_run) - picked, 0.0)
+        return jax.lax.dynamic_update_slice_in_dim(nll, nll_b, i * block, 0)
 
-    zeros = jnp.zeros(order.shape, jnp.float32)
     n_blocks = head_rows_computed(count, n) // block
-    nll, lse = jax.lax.fori_loop(0, n_blocks, body, (zeros, zeros))
-    # rows past the last block were never touched: their nll is the zero the
-    # carry started from
-    return nll[inv], (x, scale, bias, emb, labels, order, inv, count, lse)
+    # rows past the last block are never touched: their nll is the zero the
+    # carry starts from
+    nll = jax.lax.fori_loop(0, n_blocks, body,
+                            jnp.zeros(order.shape, jnp.float32))[inv]
+    return jnp.sum(wgt * nll), nll
 
 
-# a custom_vjp backward is traced on its own, in the backward pass: it names
-# its scope itself
+# a custom_vjp's rules are traced on their own: each names its scope itself
 @devscope.scoped(devscope.LM_HEAD)
-def _chunked_vocab_nll_bwd(norm, res, g):
-    x, scale, bias, emb, labels, order, inv, count, lse = res
+def _weighted_nll_fwd(norm, x, scale, bias, emb, labels, wgt):
     n = x.shape[0]
     block = head_row_block(n)
+    order, inv, count = _live_first(wgt)
+    chunks = _vocab_chunks(emb)
 
     def body(i, carry):
-        dx, dnorm, demb = carry
+        nll, dx, dnorm, demb = carry
         idx, live = _block_rows(i, block, order, count)
         lb = labels[idx]
-        gb = jnp.where(live, g[idx], 0.0)
-        lse_b = jax.lax.dynamic_slice_in_dim(lse, i * block, block)
+        gb = jnp.where(live, wgt[idx], 0.0)
         h, ln_vjp = jax.vjp(functools.partial(_head_norm, norm), x[idx],
                             scale, bias)
+        kept, stat = [], _lse_start(block)
+        for lo, sz in chunks:
+            kept.append(_vocab_chunk(h, emb, lb, lo, sz))
+            stat = _lse_step(stat, *kept[-1][1:])
+        m_run, s_run, picked = stat
+        lse_b = m_run + jnp.log(s_run)
+        nll_b = jnp.where(live, lse_b - picked, 0.0)
         dh = jnp.zeros(h.shape, jnp.float32)
-        for lo, sz in _vocab_chunks(emb):
-            w, logits, local, hit = _vocab_chunk(h, emb, lb, lo, sz)
-            p = jnp.exp(logits - lse_b[:, None])                # softmax chunk
-            onehot = (jax.nn.one_hot(local, sz, dtype=jnp.float32)
-                      * hit[:, None].astype(jnp.float32))
-            d = ((p - onehot) * gb[:, None]).astype(emb.dtype)     # [R, sz]
-            dh = dh + jax.lax.dot_general(
-                d, w, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dw = jax.lax.dot_general(
-                d, h, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)             # [sz, E]
-            demb = jax.lax.dynamic_update_slice_in_dim(
-                demb, jax.lax.dynamic_slice_in_dim(demb, lo, sz, 0) + dw,
-                lo, 0)
+        for (lo, _), chunk in zip(chunks, kept):
+            dh, demb = _chunk_grads(dh, demb, h, *chunk, lse_b, gb, lo)
         dxb, *dnb = ln_vjp(dh.astype(h.dtype))
-        dx = jax.lax.dynamic_update_slice_in_dim(dx, dxb, i * block, 0)
         # (scale, bias); an RMS norm's bias is None, an empty pytree
-        return dx, jax.tree.map(jnp.add, dnorm, tuple(dnb)), demb
+        return (jax.lax.dynamic_update_slice_in_dim(nll, nll_b, i * block, 0),
+                jax.lax.dynamic_update_slice_in_dim(dx, dxb, i * block, 0),
+                jax.tree.map(jnp.add, dnorm, tuple(dnb)), demb)
 
     n_blocks = head_rows_computed(count, n) // block
-    dx, (dscale, dbias), demb = jax.lax.fori_loop(0, n_blocks, body, (
+    nll, dx, dnorm, demb = jax.lax.fori_loop(0, n_blocks, body, (
+        jnp.zeros(order.shape, jnp.float32),
         jnp.zeros((order.shape[0], x.shape[1]), x.dtype),
         jax.tree.map(jnp.zeros_like, (scale, bias)),
         jnp.zeros(emb.shape, jnp.float32)))
+    nll = nll[inv]
     # a dead row's place in the compacted order holds the zero it started
-    # with, so the way back is a gather through the inverse permutation
-    return dx[inv], dscale, dbias, demb.astype(emb.dtype), None, None
+    # with, so the way back is a gather through the inverse permutation;
+    # ``like``: the head matrix's dtype, which the backward rule casts to
+    like = jnp.zeros((0,), emb.dtype)
+    return (jnp.sum(wgt * nll), nll), (dx[inv], dnorm, demb, like, nll)
 
 
-_chunked_nll.defvjp(_chunked_vocab_nll_fwd, _chunked_vocab_nll_bwd)
+@devscope.scoped(devscope.LM_HEAD)
+def _weighted_nll_bwd(norm, res, cts):
+    (dx, dnorm, demb, like, nll), g = res, cts[0]
+
+    def times_g(a):
+        return (g * a).astype(a.dtype)
+
+    dscale, dbias = jax.tree.map(times_g, dnorm)
+    return (times_g(dx), dscale, dbias, (g * demb).astype(like.dtype), None,
+            g * nll)
+
+
+_weighted_nll.defvjp(_weighted_nll_fwd, _weighted_nll_bwd)
 
 
 @devscope.scoped(devscope.LM_HEAD)
@@ -2920,24 +2962,26 @@ def final_logits_loss(params, x_sp, labels, mask, cfg: TransformerConfig,
     x_sp is sequence-sharded over tp; labels/mask are FULL [b, S].  ``mask``
     alone says which positions count (MLM: the predicted positions, causal
     LM: all ones; a weight other than 0/1 is exact).  With one vocab shard
-    (tp=1) the head computes only the rows whose mask is non-zero
-    (``_chunked_vocab_nll``).  With tp>1 it gathers the sequence (transpose:
-    the gradient reduce-scatters it back), runs on every row and keeps logits
-    vocab-sharded [b, S, V/tp] — the [*, V] logits never materialize (the
-    vocab-parallel loss the reference's softmax_with_cross_entropy op cannot
-    express).
+    (tp=1) the head computes only the rows whose mask is non-zero, makes a
+    row block's logits once and takes the mask over the normaliser as the
+    rows' weights (``_weighted_vocab_nll``).  With tp>1 it gathers the
+    sequence (transpose: the gradient reduce-scatters it back), runs on
+    every row unchunked and keeps logits vocab-sharded [b, S, V/tp] — the
+    [*, V] logits never materialize (the vocab-parallel loss the
+    reference's softmax_with_cross_entropy op cannot express).
     """
     emb = params["tok_emb" if cfg.tie_head else "lm_head"]      # [V/tp, E] local
     if col.axis_size_in(TP) == 1:
-        nll = _chunked_vocab_nll(
-            x_sp.reshape(-1, x_sp.shape[-1]), params["lnf_scale"],
-            params.get("lnf_bias"), emb, labels.reshape(-1),
-            mask.reshape(-1), norm=(cfg.norm, cfg.norm_eps))
-        total = col.psum_forward(jnp.sum(nll * mask.reshape(-1)), DP)
-        if divisor is not None:
-            return total / (divisor * col.axis_size_in(DP))
-        count = col.psum(jnp.sum(mask.astype(jnp.float32)), DP)
-        return total / jnp.maximum(count, 1.0)
+        head = (x_sp.reshape(-1, x_sp.shape[-1]), params["lnf_scale"],
+                params.get("lnf_bias"), emb, labels.reshape(-1))
+        # the normaliser goes in with the weights: the sum's cotangent is 1
+        # and the head's forward rule makes the gradient
+        over = divisor * col.axis_size_in(DP) if divisor is not None \
+            else jnp.maximum(
+                col.psum(jnp.sum(mask.astype(jnp.float32)), DP), 1.0)
+        total, _ = _weighted_vocab_nll(*head, mask.reshape(-1) / over,
+                                       norm=(cfg.norm, cfg.norm_eps))
+        return col.psum_forward(total, DP)
     assert divisor is None
     x = _norm(x_sp, params, "lnf", cfg, fused=False)
     x = col.all_gather(x, TP, dim=1)                            # [b, S, E]
@@ -2969,20 +3013,21 @@ def exit_weighted_loss(params, exits, gates, labels, mask,
     the position's exit distribution (``exit_log_probs``), ``H(p) = -sum_t
     p_t ln p_t``; averaged over the positions ``mask`` weights as
     ``final_logits_loss`` does.  The T exits' rows go through the head in
-    ONE call (one float32 [V, E] gradient buffer, one loop over row blocks);
-    the weights multiply its per-row ``nll`` outside it, so the gradient
-    reaches the gate and, through the state it reads, the stack.  tp == 1."""
+    ONE call (one float32 [V, E] gradient buffer, one loop over row blocks):
+    a row's weight ``p_t * mask / count`` goes INTO the head and the head's
+    gradient with respect to it, the row's ``nll``, comes back, so the
+    gradient reaches the gate and, through the state it reads, the stack.
+    tp == 1."""
     T = cfg.loop_passes
     emb = params["tok_emb" if cfg.tie_head else "lm_head"]
     mask = mask.reshape(-1)
-    nll = _chunked_vocab_nll(
-        exits.reshape(-1, exits.shape[-1]), params["lnf_scale"],
-        params.get("lnf_bias"), emb, jnp.tile(labels.reshape(-1), T),
-        jnp.tile(mask, T), norm=(cfg.norm, cfg.norm_eps)).reshape(T, -1)
+    head = (exits.reshape(-1, exits.shape[-1]), params["lnf_scale"],
+            params.get("lnf_bias"), emb, jnp.tile(labels.reshape(-1), T))
+    count = col.psum(jnp.sum(mask.astype(jnp.float32)), DP)
     with jax.named_scope(devscope.EXIT_GATE):
         log_p = exit_log_probs(gates.reshape(T, -1))
-        p = jnp.exp(log_p)
-        each = jnp.sum(p * (nll + cfg.exit_entropy_coef * log_p), axis=0)
-    total = col.psum_forward(jnp.sum(each * mask), DP)
-    count = col.psum(jnp.sum(mask.astype(jnp.float32)), DP)
-    return total / jnp.maximum(count, 1.0)
+        wgt = jnp.exp(log_p) * (mask / jnp.maximum(count, 1.0))
+        entropy = cfg.exit_entropy_coef * jnp.sum(wgt * log_p)
+    ce, _ = _weighted_vocab_nll(*head, wgt.reshape(-1),
+                                norm=(cfg.norm, cfg.norm_eps))
+    return col.psum_forward(ce + entropy, DP)

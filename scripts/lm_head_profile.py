@@ -21,7 +21,11 @@ grid is printed from ``flash_attention.packed_grid`` at the cell's shape),
 traces one more, and joins the trace with THIS process's scope map
 (``monitor.devscope``; a map compiled elsewhere need not number its
 instructions the same way).  ``--ones`` replaces the mask by all ones, the
-causal-LM shape no cell sends.  The head's partition of the vocabulary
+causal-LM shape no cell sends.  The scope's milliseconds under the head's
+forward and under its backward rule are printed apart (since PR 74 the
+head makes a block's logits once and its gradient in the forward rule: the
+backward rule's line, a multiply, is the receipt that the second pass is
+gone).  The head's partition of the vocabulary
 (``transformer._vocab_chunks`` of the cell's head matrix) is printed beside
 its milliseconds; ``--cut 9728,9728,9728,8800`` replaces it for this
 process's compile (chunk rows that sum to the vocabulary: the script's
@@ -151,6 +155,11 @@ def main(argv=None):
           % (stats["peak_bytes_in_use"], stats.get("peak_bytes_reserved", 0)))
     for key, ns in sorted(totals.items(), key=lambda kv: -kv[1])[:14]:
         print("  %-10s %-12s %8.3f ms a step" % (key + (ns / steps / 1e6,)))
+    # a custom_vjp's forward rule is traced under jvp(, its backward rule
+    # under transpose(: the head does its work in the first
+    print("lm_head: forward rule %.3f ms a step, backward rule %.3f ms a step"
+          % tuple(totals.get((phase, "lm_head"), 0.0) / steps / 1e6
+                  for phase in ("forward", "backward")))
     for title, found, top in (
             ("the step's busiest instructions", busiest, 12),
             ("instructions under " + ", ".join(scopes), rows, args.top)):

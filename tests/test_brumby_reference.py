@@ -423,6 +423,9 @@ def test_gauges_only_under_a_monitor_session(trained):
 
 def test_the_retention_s_instructions_are_under_their_scope(trained):
     got = trained.scopes()
-    for scope in ("retention", "mlp", "layer_norm", "lm_head", "embed"):
+    for scope in ("retention", "mlp", "layer_norm", "embed"):
         assert ("forward", scope) in got and ("backward", scope) in got, scope
+    # the head makes its gradient in its forward rule (PR 74): its backward
+    # rule is a multiply by a cotangent of 1, which folds away
+    assert ("forward", "lm_head") in got
     assert ("recompute", "retention") in got

@@ -22,23 +22,27 @@ fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
     # a looped stack's four exits' rows through the head in one call
     ("ouro_2_6b.s4096_scan", 4 * 8192, 49152, 2048, "rms"),
     ("bert_base.s512_scan", 32768, 30528, 768, "layer"),
+    # the widest vocabulary of all, a chip's rows of it (PR 74)
+    ("kanana_2_30b_a3b.s8192_ep4", 8192, 128256, 2048, "rms"),
 ])
 def test_head_matrix_gradient_is_tiled_in_a_few_windows(one_chip, what, N, V,
                                                         E, norm):
-    """The tp=1 head's backward at a cell's head shape.  A vocabulary
-    chunk's float32 dW matmul, accumulated into ``demb`` in place, is one
-    fusion a chunk whose result is ``f32[V, E]``; the compiler walks it in
-    ``iteration_bounds`` windows.  With chunks of 9,496 = 8 x 1,187 rows
-    (37,984 / 4; 1,187 is prime) it found 1,187 windows of one 8-row tile,
-    and the four fusions took a fifth of the SmallThinker cell's step."""
+    """The tp=1 head's gradient at a cell's head shape (since PR 74 made in
+    the forward rule's loop, beside a row block's kept logits).  A
+    vocabulary chunk's float32 dW matmul, accumulated into ``demb`` in
+    place, is one fusion a chunk whose result is ``f32[V, E]``; the compiler
+    walks it in ``iteration_bounds`` windows.  With chunks of 9,496 = 8 x
+    1,187 rows (37,984 / 4; 1,187 is prime) it found 1,187 windows of one
+    8-row tile, and the four fusions took a fifth of the SmallThinker cell's
+    step."""
     T = importlib.import_module("paddle_tpu.parallel.transformer")
     sds = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
         shape, dtype, sharding=one_chip)
     emb = sds((V, E))
 
-    def loss(x, scale, bias, emb, labels, mask):
-        return jnp.sum(T._chunked_vocab_nll(x, scale, bias, emb, labels, mask,
-                                            norm=(norm, 1e-5)) * mask)
+    def loss(x, scale, bias, emb, labels, wgt):
+        return T._weighted_vocab_nll(x, scale, bias, emb, labels, wgt,
+                                     norm=(norm, 1e-5))[0]
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 3))).lower(
         sds((N, E)), sds((E,)), sds((E,)) if norm == "layer" else None, emb,

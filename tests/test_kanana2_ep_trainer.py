@@ -120,8 +120,11 @@ def test_the_exchange_s_scope_holds_its_instructions(trained):
     got = {H.devscope.classify(op)
            for op in H.scope_map(trained[4][0]).values()}
     for scope in ("exchange", "latent_attention", "shared_expert", "moe",
-                  "router", "mlp", "lm_head", "embed"):
+                  "router", "mlp", "embed"):
         assert ("forward", scope) in got and ("backward", scope) in got, scope
+    # the head makes its gradient in its forward rule (PR 74): its backward
+    # rule is a multiply by a cotangent of 1, which folds away
+    assert ("forward", "lm_head") in got
     assert ("grad_sync", "grad_sync") in got
     assert "attention" not in {s for _, s in got}
     text = trained[4][0].multi_fn.lower(

@@ -1,5 +1,5 @@
 """The tp=1 LM head computes only the rows the mask counts
-(``transformer._chunked_vocab_nll``): live rows compacted to the front, the
+(``transformer._weighted_vocab_nll``): live rows compacted to the front, the
 chunked-vocab head run over ``ceil(count / R)`` row blocks.  Held here
 against the plain formula, under ``jit(scan)``, across a dp=4 mesh with a
 different count on every device, through the tiny trainer, and in the
@@ -41,8 +41,9 @@ def _plain_loss(x, scale, bias, emb, labels, mask, norm=LAYER_NORM):
 
 
 def _compact_loss(x, scale, bias, emb, labels, mask, norm=LAYER_NORM):
-    nll = T._chunked_vocab_nll(x, scale, bias, emb, labels, mask, norm=norm)
-    return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    return T._weighted_vocab_nll(
+        x, scale, bias, emb, labels, mask / jnp.maximum(jnp.sum(mask), 1.0),
+        norm=norm)[0]
 
 
 def _inputs(seed=0):
@@ -104,7 +105,7 @@ def test_compact_head_matches_the_plain_formula(kind):
 
 def test_nll_is_zero_on_dead_rows_and_exact_on_live_ones():
     args, mask = _inputs(), _mask("ragged")
-    nll = T._chunked_vocab_nll(*args, mask)
+    _, nll = T._weighted_vocab_nll(*args, mask)
     h = T.layer_norm(args[0], args[1], args[2], fused=False)
     logits = h @ args[3].T
     want = jax.nn.logsumexp(logits, -1) - jnp.take_along_axis(
@@ -480,6 +481,7 @@ def test_no_head_matmul_has_all_the_rows():
         if "lm_head" in names.get(m.group(3), ""):
             head_dots.append({int(d) for t in _DIMS.findall(
                 m.group(1) + m.group(2)) for d in t.split("x") if d})
-    # 4 vocab chunks: 4 matmuls forward, 12 backward
-    assert len(head_dots) == 16
+    # 4 vocab chunks: the logits, dh and demb, each made once (PR 74: the
+    # backward made the logits a second time, 16)
+    assert len(head_dots) == 12
     assert all(rows in dims and B * S not in dims for dims in head_dots)

@@ -462,8 +462,11 @@ globals().update(H.common(CASE))
 def test_the_new_scopes_hold_their_instructions(trained):
     names, got = trained.names, trained.scopes()
     for scope in ("attention", "attn_gate", "post_norm", "shared_expert",
-                  "moe", "router", "mlp", "layer_norm", "lm_head", "embed"):
+                  "moe", "router", "mlp", "layer_norm", "embed"):
         assert ("forward", scope) in got and ("backward", scope) in got, scope
+    # the head makes its gradient in its forward rule (PR 74): its backward
+    # rule is a multiply by a cotangent of 1, which folds away
+    assert ("forward", "lm_head") in got
     for scope in ("attention", "attn_gate", "post_norm", "shared_expert"):
         assert ("recompute", scope) in got, scope
     # the gate and the output norms lie INSIDE the layer's scopes
